@@ -468,12 +468,11 @@ _barrier_seq = 0
 def _dist_client():
     """The process's distributed-runtime KV client, when multi-process
     jax was initialized (parallel.init_distributed); else None."""
-    try:
-        from jax._src import distributed
+    # jax 0.9 exposes the KV client only here; a rename must raise,
+    # not read as "single process"
+    from jax._src import distributed
 
-        return distributed.global_state.client
-    except Exception:  # noqa: BLE001 — private API, version-dependent
-        return None
+    return distributed.global_state.client
 
 
 def barrier_timeout_s() -> float:

@@ -260,6 +260,7 @@ class DecoderLM:
             next_tok = layers.argmax(logits, axis=1)       # (S,) int
             result = {"main": main, "startup": startup,
                       "next_token": next_tok.name,
+                      "logits": logits.name,
                       "cache_outs": cache_out_names}
             if mode == "verify":
                 # fold (S*(k+1),) predictions back to (S, k+1) and

@@ -10,7 +10,7 @@ step-path hooks, and the step lowering is byte-identical with the
 engine running or absent (pinned by tests/test_alerts.py, the same
 guard discipline as goodput/reqtrace).
 
-Rule taxonomy:
+Rule kinds:
 
 - **ThresholdRule** — value vs a fixed target, with optional
   `window_s` turning a cumulative counter into a per-second rate

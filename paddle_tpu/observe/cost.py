@@ -178,8 +178,7 @@ class Instr:
     __slots__ = ("name", "opcode", "shape", "op_name", "id",
                  "operand_ids", "called_ids", "dot_dnums_buf",
                  "window_buf", "conv_dnums_buf", "feature_group_count",
-                 "custom_call_target", "literal_buf", "tuple_index",
-                 "comparison_direction")
+                 "custom_call_target", "backend_config")
 
     def __init__(self, buf: bytes):
         self.name = ""
@@ -194,9 +193,7 @@ class Instr:
         self.conv_dnums_buf = b""
         self.feature_group_count = 1
         self.custom_call_target = ""
-        self.literal_buf = b""
-        self.tuple_index = 0
-        self.comparison_direction = ""
+        self.backend_config = b""
         for f, _wt, v in _fields(buf):
             if f == 1:
                 self.name = _utf8(v)
@@ -206,10 +203,6 @@ class Instr:
                 self.shape = Shape(v)
             elif f == 7:
                 self.op_name = _utf8(_first(v, 2, b""))
-            elif f == 8:
-                self.literal_buf = v
-            elif f == 13:
-                self.tuple_index = int(v)
             elif f == 15:
                 self.window_buf = v
             elif f == 16:
@@ -224,10 +217,10 @@ class Instr:
                 self.operand_ids.extend(_varints(v))
             elif f == 38:
                 self.called_ids.extend(_varints(v))
+            elif f == 43:
+                self.backend_config = v
             elif f == 50:
                 self.feature_group_count = max(int(v), 1)
-            elif f == 63:
-                self.comparison_direction = _utf8(v)
 
 
 class Computation:
@@ -284,108 +277,25 @@ class HloModule:
 # while-loop trip counts (the scan undercount fix)
 # --------------------------------------------------------------------------
 
-def _literal_int(buf: bytes) -> Optional[int]:
-    """First integer of a LiteralProto (s32s=4 s64s=5 u32s=6 u64s=7
-    packed varints; u8s=3/s8s=15 raw bytes)."""
-    if not buf:
-        return None
-    for f, _wt, v in _fields(buf):
-        if f in (4, 5, 6, 7):
-            vals = _varints(v)
-            if vals:
-                return vals[0]
-        if f in (3, 15) and isinstance(v, bytes) and v:
-            return v[0]
-    return None
-
-
-def _resolve_through(comp: Computation, o: Optional[Instr]):
-    """Follow value-preserving wrappers (convert/copy/bitcast) to the
-    producing instruction."""
-    while (o is not None and o.opcode in ("convert", "copy", "bitcast")
-           and o.operand_ids):
-        o = comp.by_id.get(o.operand_ids[0])
-    return o
-
-
 def while_trip_count(module: HloModule, comp: Computation,
                      instr: Instr) -> Optional[int]:
     """Known trip count of a counted `while` (the lax.scan / fori_loop
-    induction pattern), or None when unrecoverable.
+    pattern), or None when XLA found none.
 
-    The scan-emitted pattern: the condition computation's root is
-    `compare(get-tuple-element(param, i), constant_T, LT)` and the body
-    increments tuple element i by a constant step from a constant init.
-    The bound comes from the condition; init/step are refined from the
-    while's operand tuple and the body root when visible and default to
-    the counted-loop convention (0, 1) otherwise.  A loop whose
-    CONDITION does not match (a genuine data-dependent `while` op
-    decode loop) returns None — callers fall back to ×1 with the loud
+    XLA's own loop analysis annotates every counted while with
+    `backend_config={"known_trip_count":{"n":"T"}, ...}` after
+    optimization; that annotation is read here rather than re-derived
+    from the condition's compare (which this XLA wraps in a fusion).
+    A genuine data-dependent `while` (a decode loop) carries no such
+    key and returns None — callers fall back to ×1 with the loud
     `[loop?]` bucket, never a silent guess.
     """
-    if instr.opcode != "while":
+    if instr.opcode != "while" or not instr.backend_config:
         return None
-    called = [module.computations.get(c) for c in instr.called_ids]
-    called = [c for c in called if c is not None]
-    cond = next((c for c in called if c.root is not None
-                 and c.root.opcode == "compare"), None)
-    body = next((c for c in called if c is not cond), None)
-    if cond is None or body is None:
-        return None
-    root = cond.root
-    ops = [_resolve_through(cond, cond.by_id.get(i))
-           for i in root.operand_ids]
-    if len(ops) != 2 or any(o is None for o in ops):
-        return None
+    import json
 
-    def gte_index(o):
-        if o.opcode != "get-tuple-element" or not o.operand_ids:
-            return None
-        src = cond.by_id.get(o.operand_ids[0])
-        if src is None or src.opcode != "parameter":
-            return None
-        return o.tuple_index
-
-    direction = root.comparison_direction or "LT"
-    a, b = ops
-    if gte_index(a) is not None and b.opcode == "constant":
-        idx, bound, dir_ok = (gte_index(a), _literal_int(b.literal_buf),
-                              direction == "LT")
-    elif gte_index(b) is not None and a.opcode == "constant":
-        idx, bound, dir_ok = (gte_index(b), _literal_int(a.literal_buf),
-                              direction == "GT")
-    else:
-        return None
-    if bound is None or not dir_ok:
-        return None
-
-    # refine init from the while operand's tuple element, step from the
-    # body root's add-by-constant; both default to the (0, 1) counted-
-    # loop convention when optimization hid them
-    init, step = 0, 1
-    if instr.operand_ids:
-        arg = comp.by_id.get(instr.operand_ids[0])
-        if arg is not None and arg.opcode == "tuple" \
-                and idx < len(arg.operand_ids):
-            o = _resolve_through(comp, comp.by_id.get(arg.operand_ids[idx]))
-            if o is not None and o.opcode == "constant":
-                v = _literal_int(o.literal_buf)
-                if v is not None:
-                    init = v
-    broot = body.root
-    if broot is not None and broot.opcode == "tuple" \
-            and idx < len(broot.operand_ids):
-        o = _resolve_through(body, body.by_id.get(broot.operand_ids[idx]))
-        if o is not None and o.opcode == "add":
-            for oid in o.operand_ids:
-                c = _resolve_through(body, body.by_id.get(oid))
-                if c is not None and c.opcode == "constant":
-                    v = _literal_int(c.literal_buf)
-                    if v:
-                        step = v
-    if step <= 0:
-        return None
-    return max(0, -(-(bound - init) // step))
+    known = json.loads(instr.backend_config).get("known_trip_count")
+    return int(known["n"]) if known else None
 
 
 # --------------------------------------------------------------------------
@@ -625,6 +535,7 @@ def instruction_costs(proto: bytes) -> List[Dict[str, Any]]:
     """
     # force kernel-cost registration before walking custom calls
     from ..ops.pallas import flash_attention as _fa  # noqa: F401
+    from ..ops.pallas import paged_attention as _pa  # noqa: F401
     from ..ops.pallas import recurrence as _rc  # noqa: F401
     from ..ops.pallas import vocab_ce as _vc  # noqa: F401
 
@@ -664,6 +575,7 @@ def instruction_costs(proto: bytes) -> List[Dict[str, Any]]:
         if instr.opcode == "while":
             row["trip_count"] = while_trip_count(module, entry, instr)
         if instr.opcode == "custom-call":
+            row["custom_call_target"] = instr.custom_call_target
             kernel = _pallas_kernel_of(instr.op_name)
             if kernel is not None:
                 cost = _registry_cost(kernel, instr, operands)
@@ -682,10 +594,14 @@ def total_costs(proto: bytes) -> Dict[str, Any]:
 
     flops = analytic flops INCLUDING injected Pallas registry costs;
     `pallas_flops` is the injected share, `custom_calls` /
-    `pallas_matched` make an unmatched (uncounted) custom call visible
-    instead of silently reading as zero flops."""
+    `pallas_matched` make an unmatched (uncounted) KERNEL visible
+    instead of silently reading as zero flops.  Only Mosaic kernels
+    (`tpu_custom_call`) count: the TPU compiler also emits zero-flop
+    bookkeeping custom calls of its own (ConcatBitcast,
+    AssumeGatherIndicesInBound, ...), which no registry could cover."""
     rows = instruction_costs(proto)
-    custom = [r for r in rows if r["opcode"] == "custom-call"]
+    custom = [r for r in rows
+              if r.get("custom_call_target") == "tpu_custom_call"]
     matched = [r for r in custom if r["pallas_kernel"]]
     return {
         "flops": sum(r["flops"] for r in rows),
@@ -712,10 +628,7 @@ def _sum_by(rows: Iterable[Dict[str, Any]], key: str) -> Dict[str, float]:
 
 def compiled_hlo_proto(compiled) -> bytes:
     """Serialized optimized HloModuleProto of a jax Compiled object."""
-    try:
-        modules = compiled.runtime_executable().hlo_modules()
-    except AttributeError:  # jax version drift: go through _executable
-        modules = compiled._executable.xla_executable.hlo_modules()
+    modules = compiled.runtime_executable().hlo_modules()
     return modules[0].as_serialized_hlo_module_proto()
 
 
@@ -732,8 +645,7 @@ def program_costs(program, feed=None, fetch_list=None, scope=None,
     Executor.cost_analysis) and return `total_costs` of the optimized
     module plus XLA's own aggregate flops for cross-checking and the
     step's peak device memory (`peak_hbm_bytes`, the buffer-assignment
-    allocation total from the same compile; None when the backend
-    exposes no memory analysis)."""
+    allocation total from the same compile)."""
     from ..core.executor import Executor
 
     exe = exe or Executor()

@@ -12,18 +12,19 @@ makes HBM a first-class observed quantity:
 
 - **buffer attribution** (`memory_report` / `memory_table` /
   `format_memory_table`): parse the optimized module's
-  BufferAssignmentProto — `compiled.memory_analysis()` hands back an
-  HloProto whose field 3 carries it, read with the same dependency-free
-  wire scanner as trace/cost — and attribute every logical buffer to
-  its fluid op through the `metadata.op_name` scope join cost.py
-  already uses.  Peak = the sum of allocation sizes: XLA's heap
-  simulation has ALREADY packed temp buffers into arenas with
-  liveness-based reuse, so the allocation total IS what the device
-  must hold (cross-checked against CompiledMemoryStats
-  args+outputs+temps-aliased within 0.1% on CPU).  Without a buffer
-  assignment (backend doesn't expose one) the report falls back to a
-  live-range sweep over the instruction sequence from our own proto
-  walk, tagged `source: "module-shapes"`.
+  BufferAssignmentProto — `compiled.memory_analysis()
+  .serialized_buffer_assignment_proto`, read with the same
+  dependency-free wire scanner as trace/cost — and attribute every
+  logical buffer to its fluid op through the `metadata.op_name` scope
+  join cost.py already uses.  Peak = the sum of allocation sizes:
+  XLA's heap simulation has ALREADY packed temp buffers into arenas
+  with liveness-based reuse, so the allocation total IS what the
+  device must hold (cross-checked against CompiledMemoryStats
+  args+outputs+temps-aliased within 0.1% on CPU).  Where the backend
+  hands back an EMPTY assignment (the TPU's compiler does) the report
+  falls back to a live-range sweep over the instruction sequence from
+  our own proto walk, tagged `source: "module-shapes"`; a missing or
+  renamed jax attribute raises instead.
 
 - **buckets**: every buffer lands in params / optimizer_state /
   gradients / activations / workspace, with donated bytes tallied
@@ -72,7 +73,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .cost import HloModule, _varints
-from .trace import _fields, _first, fluid_op_of
+from .trace import _fields, fluid_op_of
 
 # --------------------------------------------------------------------------
 # device HBM budgets (planning denominators; memory_stats()["bytes_limit"]
@@ -206,6 +207,8 @@ class Allocation:
                     elif af == 3:
                         sz = av
                 self.assigned.append((bid, off, sz))
+        if self.is_param and self.param_number is None:
+            self.param_number = 0  # proto3 omits the zero default
 
 
 class BufferAssignment:
@@ -229,60 +232,35 @@ class BufferAssignment:
         return int(sum(a.size for a in self.allocations))
 
 
-def parse_buffer_assignment(proto: bytes) -> Optional[BufferAssignment]:
-    """BufferAssignment of an HloProto wrapper (field 3), or None when
-    the proto is a bare module / carries no assignment."""
-    ba = _first(proto, 3)
-    if not isinstance(ba, bytes) or not ba:
-        return None
-    parsed = BufferAssignment(ba)
-    if not parsed.allocations:
-        return None
-    return parsed
-
-
-def compiled_memory_proto(compiled) -> Tuple[bytes, Optional[Any]]:
-    """(proto, CompiledMemoryStats|None) for a jax Compiled object.
-    Prefers memory_analysis() — its serialized HloProto carries the
-    buffer assignment — and falls back to the bare optimized module
-    (attribution still works; peak comes from a live-range sweep)."""
-    try:
-        stats = compiled.memory_analysis()
-        if isinstance(stats, (list, tuple)):
-            stats = stats[0]
-        proto = stats.serialized_hlo_proto
-        if isinstance(proto, bytes) and proto:
-            return proto, stats
-    except Exception:  # noqa: BLE001 — backend-dependent API
-        pass
+def compiled_memory(compiled) -> Tuple[bytes, Optional[BufferAssignment],
+                                       Any]:
+    """(optimized HloModuleProto, BufferAssignment|None,
+    CompiledMemoryStats) of a jax Compiled object.  The assignment is
+    `memory_analysis().serialized_buffer_assignment_proto` (jax 0.9);
+    it is None only where the backend hands back an empty one (libtpu
+    0.0.34 does) — callers then tag their result
+    `source: "module-shapes"`.  A renamed attribute raises here."""
     from .cost import compiled_hlo_proto
 
-    return compiled_hlo_proto(compiled), None
+    stats = compiled.memory_analysis()
+    if isinstance(stats, (list, tuple)):
+        stats = stats[0]
+    ba = BufferAssignment(stats.serialized_buffer_assignment_proto)
+    return (compiled_hlo_proto(compiled),
+            ba if ba.allocations else None, stats)
 
 
-def compiled_peak_bytes(compiled) -> Optional[int]:
+def compiled_peak_bytes(compiled) -> int:
     """Predicted-peak device bytes of one compiled executable: the
-    buffer-assignment allocation total, falling back to the
-    CompiledMemoryStats arithmetic, else None (backend reports
-    nothing)."""
-    try:
-        stats = compiled.memory_analysis()
-        if isinstance(stats, (list, tuple)):
-            stats = stats[0]
-    except Exception:  # noqa: BLE001
-        return None
-    proto = getattr(stats, "serialized_hlo_proto", None)
-    if isinstance(proto, bytes) and proto:
-        ba = parse_buffer_assignment(proto)
-        if ba is not None:
-            return ba.total_bytes
-    try:
-        return int(stats.argument_size_in_bytes
-                   + stats.output_size_in_bytes
-                   + stats.temp_size_in_bytes
-                   - stats.alias_size_in_bytes)
-    except Exception:  # noqa: BLE001
-        return None
+    buffer-assignment allocation total, or the CompiledMemoryStats
+    arithmetic where the backend exposes no assignment."""
+    _proto, ba, stats = compiled_memory(compiled)
+    if ba is not None:
+        return ba.total_bytes
+    return int(stats.argument_size_in_bytes
+               + stats.output_size_in_bytes
+               + stats.temp_size_in_bytes
+               - stats.alias_size_in_bytes)
 
 
 # --------------------------------------------------------------------------
@@ -356,11 +334,10 @@ def _arg_labels(state, feed_arrays, compiled=None
                         for p in path[1:])
         labels.append((kind, name))
     if compiled is not None:
-        try:  # private API; absence degrades to the nameless fallback
-            kept = compiled._executable._kept_var_idx
-            labels = [lb for i, lb in enumerate(labels) if i in kept]
-        except AttributeError:
-            pass
+        # jax exposes the kept-argument set only privately (0.9.0); a
+        # rename raises here rather than shifting every label
+        kept = compiled._executable._kept_var_idx
+        labels = [lb for i, lb in enumerate(labels) if i in kept]
     return labels
 
 
@@ -427,8 +404,7 @@ def memory_report(program=None, feed=None, fetch_list=None, scope=None,
     if program is not None:
         params, opt = _program_var_buckets(program)
 
-    proto, stats = compiled_memory_proto(compiled)
-    ba = parse_buffer_assignment(proto)
+    proto, ba, stats = compiled_memory(compiled)
     module = HloModule(proto)
     entry, pos, comp_pos, instr_comp = _module_positions(module)
     by_id = {i.id: i for comp in module.computations.values()
@@ -564,13 +540,12 @@ def memory_report(program=None, feed=None, fetch_list=None, scope=None,
     breakdown["peak_bytes"] = int(peak)
     out = {"rows": rows, "peak_bytes": int(peak),
            "breakdown": breakdown, "source": source}
-    if stats is not None:
-        out["stats"] = {
-            "argument_bytes": int(stats.argument_size_in_bytes),
-            "output_bytes": int(stats.output_size_in_bytes),
-            "temp_bytes": int(stats.temp_size_in_bytes),
-            "alias_bytes": int(stats.alias_size_in_bytes),
-        }
+    out["stats"] = {
+        "argument_bytes": int(stats.argument_size_in_bytes),
+        "output_bytes": int(stats.output_size_in_bytes),
+        "temp_bytes": int(stats.temp_size_in_bytes),
+        "alias_bytes": int(stats.alias_size_in_bytes),
+    }
     return out
 
 
@@ -725,8 +700,7 @@ def memory_timeline(program=None, feed=None, fetch_list=None, scope=None,
         exe = exe or Executor()
         compiled = exe.compiled_step(program, feed=feed,
                                      fetch_list=fetch_list, scope=scope)
-    proto, _stats = compiled_memory_proto(compiled)
-    ba = parse_buffer_assignment(proto)
+    proto, ba, _stats = compiled_memory(compiled)
     module = HloModule(proto)
     entry, pos, comp_pos, instr_comp = _module_positions(module)
     by_id = {i.id: i for comp in module.computations.values()
@@ -941,12 +915,7 @@ def plan_fit(program, feed, fetch_list=None, scope=None, exe=None,
     def peak_at(b: int) -> Tuple[int, Any]:
         compiled = exe.compiled_step(program, feed=at_batch(b),
                                      fetch_list=fetch_list, scope=scope)
-        peak = compiled_peak_bytes(compiled)
-        if peak is None:
-            raise RuntimeError(
-                "backend exposes no memory analysis — plan_fit cannot "
-                "probe on this platform")
-        return peak, compiled
+        return compiled_peak_bytes(compiled), compiled
 
     b0, b1 = sorted(int(b) for b in probe_batches)
     if not (0 < b0 < b1):

@@ -33,7 +33,7 @@ the serving stack threads a `RequestTrace` through:
   request that failed over draws queue -> dispatch -> failover-hop ->
   completion ACROSS replica rows.
 
-Span taxonomy (docs/OBSERVE.md pillar 7): single-shot serving uses
+Span kinds (docs/OBSERVE.md pillar 7): single-shot serving uses
 `queue_wait` / `batch_form` / `dispatch`; decode uses `join_wait` /
 `dispatch`(kind=prefill|decode, one per chunk) plus `preempt` /
 `evacuated` point markers; the fleet router adds `route`, `failover`

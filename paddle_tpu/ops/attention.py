@@ -161,15 +161,14 @@ def flash_attention(ctx, ins, attrs):
             return True
 
         if bias is not None and not _kernel_bias_ok(bias):
-            # richer biases ((Tq, Tk) shapes, per-head biases) take the
-            # documented XLA fallback — express causal+padding as
-            # causal=True + a key bias to stay on the kernel
-            if layout == "nthd":
-                o = _xla_attention_nthd(q, k, v, bias, scale, causal,
-                                        h_count)
-            else:
-                o = _xla_attention(q, k, v, bias, scale, causal)
-            return out(Out=o)
+            # a program that asks for the kernel gets the kernel or an
+            # error, never a silent XLA composition
+            raise ValueError(
+                f"flash_attention(use_pallas=True): the Pallas kernel "
+                f"takes only a key-padding bias broadcastable to "
+                f"(N, 1, 1, Tk); got {tuple(bias.shape)}.  Express "
+                f"causal+padding as causal=True plus a key bias, or "
+                f"leave use_pallas unset for the XLA composition")
         from .pallas.flash_attention import pallas_flash_attention
 
         o = pallas_flash_attention(q, k, v, bias, scale, causal,

@@ -77,13 +77,9 @@ def moe_ffn(ctx, ins, attrs):
     # of all-gathering the dispatch tensor.  Capacity is then per
     # GROUP (C = ceil(B/G * k / E * cf)) — the published GShard
     # semantics.
-    ectx = None
-    try:
-        from ..parallel.mesh import get_exec_context
+    from ..parallel.mesh import get_exec_context
 
-        ectx = get_exec_context()
-    except ImportError:  # pragma: no cover
-        pass
+    ectx = get_exec_context()
     g = 1
     ep_ax = mp_ax = batch_ax = None
     if ectx is not None:

@@ -714,6 +714,10 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
             raise ValueError(f"nthd minor dim {hd} not divisible by "
                              f"n_head {n_head}")
         h, d = n_head, hd // n_head
+        # NOTE for the TPU: one head's (block, d) tile is a lane slice
+        # of the grouped minor dim, which Mosaic takes only at whole
+        # 128-lane tiles — at d_head 64 the lowering refuses the block
+        # spec (tests/test_chip_compile.py pins both sides)
         t_k = k.shape[1]
         qf, kf, vf = q, k, v
     elif layout == "nhtd":

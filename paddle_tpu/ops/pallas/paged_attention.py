@@ -133,9 +133,14 @@ def _paged_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
         # static head loop over lane slices of the grouped minor dim —
         # one token's scores per head are a VPU reduction, kept
         # (page, 1) so the per-slot scalars broadcast along sublanes
+        # the product is formed at FULL width and sliced per head
+        # afterwards: Mosaic keeps the one-row q sublane-replicated and
+        # refuses a lane-offset slice of such a value (q[:, hs]), while
+        # the row broadcast and the slice of a (page, H*D) tile are fine
+        kq = k * q                                         # (page, H*D)
         for h in range(n_head):
             hs = slice(h * d, (h + 1) * d)
-            s_col = jnp.sum(k[:, hs] * q[:, hs], axis=1,
+            s_col = jnp.sum(kq[:, hs], axis=1,
                             keepdims=True) * scale         # (page, 1)
             s_col = jnp.where(valid, s_col, NEG_INF)
             m_prev = m_scr[:, h:h + 1]                     # (1, 1)

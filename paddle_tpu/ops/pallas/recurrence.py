@@ -41,13 +41,24 @@ import jax
 import jax.numpy as jnp
 
 # Time-block default — lives ONLY here (CLAUDE.md VMEM lesson: a stale
-# fallback at a call site silently overrides a retune).  VMEM budget at
-# the bench shape (N=128, H=512, f32): the x-slab is N*4H*4B = 1 MB per
-# timestep and Pallas double-buffers it, the hs/cs out-slabs are 256 KB
-# per step each (double-buffered), and W is 4 MB — so block_t=4 keeps
-# the working set ~(2*4 + 2*2*1 + 4 + 0.5) ≈ 16 MB.  UNTUNED on a real
-# chip (no chip contact this round); retune here, nowhere else.
+# fallback at a call site silently overrides a retune).  Untuned on a
+# chip: retune here, nowhere else.
 DEFAULT_BLOCK_T = 4
+
+# Mosaic gives a kernel 16 MiB of scoped VMEM unless told otherwise.
+# The backward at the bench shape (N=128, H=512, f32) takes 27 MiB at
+# block_t=4 by the compiler's count (x and dx slabs are N*4H*4B = 1 MiB
+# per timestep each, W / dW / the dW accumulator 4 MiB each), so both
+# kernels raise the limit — a limit reserves nothing — to half of a
+# v5e core's 128 MiB.  What does not fit, Mosaic refuses at compile.
+_VMEM_LIMIT = 64 << 20
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
 
 # -- kernel cost registry (observe/cost.py injects these at the custom
 # -- call instructions) ------------------------------------------------
@@ -241,6 +252,7 @@ def _fwd_call(xs, w, h0, c0, sl, t_true, rev, block_t):
             jax.ShapeDtypeStruct((t_pad, n, h_dim), xs.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((n, h_dim), jnp.float32)] * 2,
+        compiler_params=_compiler_params(),
     )(xs, w, h0, c0, sl)
 
 
@@ -286,6 +298,7 @@ def _bwd_call(xs, w, hp, cp, sl, dhs, dcs, t_true, rev, block_t):
             pltpu.VMEM((n, h_dim), jnp.float32),
             pltpu.VMEM((h_dim, g4), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
     )(xs, w, hp, cp, sl, dhs, dcs)
 
 

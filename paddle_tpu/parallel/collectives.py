@@ -20,39 +20,22 @@ from jax.sharding import PartitionSpec as P
 
 def compat_shard_map(fn, mesh, in_specs, out_specs, check=False,
                      auto=frozenset()):
-    """shard_map with two jax API drifts smoothed over: the import
-    location (jax.shard_map vs jax.experimental.shard_map) and the
-    replication-check kwarg rename (check_rep -> check_vma).  `check`
-    feeds whichever kwarg this jax has.
+    """`jax.shard_map` (jax 0.9) with this repo's defaults: the
+    replication check off unless asked for, and partial-manual maps
+    spelled by the axes left to GSPMD.
 
-    `auto`: mesh axes left to GSPMD (partial-auto shard_map) — the
-    composed grad-sync path maps manually over the data axes while mp
-    stays auto-partitioned.  CAUTION: only psum-family collectives
-    (psum/pmean/pmax) survive partial-auto on this XLA; all_gather /
-    all_to_all hard-abort the SPMD partitioner (the reason
+    `auto`: mesh axes left to GSPMD — the composed grad-sync path maps
+    manually over the data axes while mp stays auto-partitioned; jax
+    names the MANUAL axes instead (`axis_names`), so the complement is
+    passed.  CAUTION: only psum-family collectives (psum/pmean/pmax)
+    survive partial-manual on this XLA; all_gather / all_to_all
+    hard-abort the SPMD partitioner (the reason
     quantized_all_reduce_psum exists)."""
-    import inspect
-
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
-    params = inspect.signature(shard_map).parameters
-    kw = {("check_vma" if "check_vma" in params else "check_rep"):
-          check}
-    if auto:
-        if "auto" not in params:
-            raise NotImplementedError(
-                "this jax's shard_map has no partial-auto support; "
-                "composed-mesh grad sync needs it")
-        kw["auto"] = frozenset(auto)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    return compat_shard_map(fn, mesh, in_specs, out_specs)
+    manual = (frozenset(mesh.axis_names) - frozenset(auto)
+              if auto else frozenset())
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=check)
 
 
 def psum(x, axis_name):
@@ -79,7 +62,7 @@ def all_reduce(x, mesh, axis: str, shard_dim: int = 0, op: str = "sum"):
         return jax.numpy.squeeze(r, shard_dim)
 
     out_spec = [None] * (x.ndim - 1)
-    return _shard_map(f, mesh, (P(*spec),), P(*out_spec))(x)
+    return compat_shard_map(f, mesh, (P(*spec),), P(*out_spec))(x)
 
 
 def all_gather(x, mesh, axis: str, shard_dim: int = 0):
@@ -89,7 +72,7 @@ def all_gather(x, mesh, axis: str, shard_dim: int = 0):
     def f(xs):
         return jax.lax.all_gather(xs, axis, axis=shard_dim, tiled=True)
 
-    return _shard_map(f, mesh, (P(*spec),), P(*[None] * x.ndim))(x)
+    return compat_shard_map(f, mesh, (P(*spec),), P(*[None] * x.ndim))(x)
 
 
 def reduce_scatter(x, mesh, axis: str, shard_dim: int = 0):
@@ -101,7 +84,7 @@ def reduce_scatter(x, mesh, axis: str, shard_dim: int = 0):
 
     out_spec = [None] * x.ndim
     out_spec[shard_dim] = axis
-    return _shard_map(f, mesh, (P(*[None] * x.ndim),), P(*out_spec))(x)
+    return compat_shard_map(f, mesh, (P(*[None] * x.ndim),), P(*out_spec))(x)
 
 
 def ppermute(x, mesh, axis: str, perm, shard_dim: int = 0):
@@ -113,7 +96,7 @@ def ppermute(x, mesh, axis: str, perm, shard_dim: int = 0):
     def f(xs):
         return jax.lax.ppermute(xs, axis, perm)
 
-    return _shard_map(f, mesh, (P(*spec),), P(*spec))(x)
+    return compat_shard_map(f, mesh, (P(*spec),), P(*spec))(x)
 
 
 def all_to_all(x, mesh, axis: str, split_dim: int, concat_dim: int):
@@ -128,7 +111,7 @@ def all_to_all(x, mesh, axis: str, split_dim: int, concat_dim: int):
 
     out_spec = [None] * x.ndim
     out_spec[split_dim] = axis
-    return _shard_map(f, mesh, (P(*in_spec),), P(*out_spec))(x)
+    return compat_shard_map(f, mesh, (P(*in_spec),), P(*out_spec))(x)
 
 
 def barrier(mesh, axis: str):
@@ -137,7 +120,7 @@ def barrier(mesh, axis: str):
     def f():
         return jax.lax.psum(jnp.ones(()), axis)
 
-    return _shard_map(f, mesh, (), P())()
+    return compat_shard_map(f, mesh, (), P())()
 
 
 # ---------------------------------------------------------------------------
@@ -337,4 +320,4 @@ def quantized_all_reduce(x, mesh, axis: str, shard_dim: int = 0,
             min_quant_numel=min_quant_numel, op=op)
 
     out_spec = [None] * (x.ndim - 1)
-    return _shard_map(f, mesh, (P(*spec),), P(*out_spec))(x)
+    return compat_shard_map(f, mesh, (P(*spec),), P(*out_spec))(x)

@@ -95,13 +95,7 @@ def shutdown_distributed():
     from ..resilience import health as _health
 
     _health.stop_health_plane()
-    try:
-        from jax._src import distributed
-
-        client = distributed.global_state.client
-    except Exception:  # noqa: BLE001 — private API, version-dependent
-        client = None
-    if client is None:
+    if not jax.distributed.is_initialized():
         return  # never initialized (or already shut down)
     try:
         jax.distributed.shutdown()

@@ -50,18 +50,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _shard_map():
-    """shard_map with the check_rep/check_vma rename smoothed over
-    (the shared shim lives in collectives.compat_shard_map)."""
-    from .collectives import compat_shard_map
-
-    def sm(f, mesh, in_specs, out_specs, check_rep):
-        return compat_shard_map(f, mesh, in_specs, out_specs,
-                                check=check_rep)
-
-    return sm
-
-
 def gpipe(stage_fn, mesh, axis: str = "pp", batch_axis=None,
           scatter_inputs=None):
     """Build a pipelined apply: `fn(stacked_params, micro_x) -> out`.
@@ -83,7 +71,6 @@ def gpipe(stage_fn, mesh, axis: str = "pp", batch_axis=None,
     """
     from jax.sharding import PartitionSpec as P
 
-    shard_map = _shard_map()
     s = mesh.shape[axis]
     perm_fwd = [(i, i + 1) for i in range(s - 1)]
     # input conveyor: a full ring rotated one slot toward rank 0 per
@@ -152,10 +139,10 @@ def gpipe(stage_fn, mesh, axis: str = "pp", batch_axis=None,
             param_spec = jax.tree.map(lambda _: P(axis), stacked_params)
 
         @partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(param_spec, in_x_spec),
             out_specs=out_spec,
-            check_rep=False)
+            check_vma=False)
         def run(params, xs):
             rank = lax.axis_index(axis)
             if multi_axis:

@@ -181,11 +181,12 @@ class Supervisor:
         generous service-side heartbeat windows so the SERVICE never
         declares a task dead before our health plane does (its verdict
         would hard-abort the surviving clients)."""
-        from jaxlib import xla_extension
+        # jax 0.9 keeps the service constructor private (it is what
+        # jax.distributed.initialize itself calls on process 0)
+        from jax._src.lib import _jax
 
-        self._service = xla_extension.get_distributed_runtime_service(
-            coordinator, self.num_workers, heartbeat_interval=10,
-            max_missing_heartbeats=10)
+        self._service = _jax.get_distributed_runtime_service(
+            coordinator, self.num_workers, heartbeat_timeout=100)
 
     def _stop_service(self) -> None:
         if self._service is not None:

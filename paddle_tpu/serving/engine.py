@@ -681,13 +681,7 @@ class ServingEngine:
             for b in probe_bs:
                 compiled = self.predictor.compile_signature(
                     self._spec_for(b, sl), donate_feeds=self._donate)
-                peak = compiled_peak_bytes(compiled)
-                if peak is None:
-                    self.fit_plan = {
-                        "skipped": "backend exposes no memory analysis",
-                        "budget_bytes": int(budget)}
-                    return
-                peaks.append(int(peak))
+                peaks.append(compiled_peak_bytes(compiled))
             if len(peaks) == 2:
                 slope = (peaks[1] - peaks[0]) / float(
                     probe_bs[1] - probe_bs[0])

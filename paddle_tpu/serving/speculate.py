@@ -192,11 +192,13 @@ class ModelDrafter(Drafter):
         s = cfg.num_slots
         vec = jax.ShapeDtypeStruct((s,), i32)
         pt = jax.ShapeDtypeStruct((s, cfg.max_pages_per_slot), i32)
-        donate = (5,) if engine._donate else ()
+        # the pools are the LAST argument of both signatures
         self._draft_exec = jax.jit(
             self._build_draft_fn(),
-            donate_argnums=donate).lower(
-                params_spec, vec, vec, vec, pt, pool_specs).compile()
+            donate_argnums=(6,) if engine._donate else ()).lower(
+                params_spec, vec, vec, vec, vec, pt,
+                pool_specs).compile()
+        donate = (5,) if engine._donate else ()
         # the full bucket ladder compiles here even on a decode-role
         # worker (the ENGINE skips its own prefill execs there; the
         # DRAFT pool still needs prompt KV on every import)
@@ -214,8 +216,14 @@ class ModelDrafter(Drafter):
         """k sequential draft steps as ONE jitted fori_loop: write the
         pending token's K/V, attend, argmax, advance — the engine's
         chunk loop shape with a static trip count (no early exit: a
-        draft past the budget is capped by the engine, and its pool
-        rows are overwritten before ever being read)."""
+        draft past the budget is capped by the engine; a step whose
+        write position is past the slot's allocated pages (`limit`)
+        drops its write — its page-table entry is 0, ANOTHER slot's
+        physical page).  The loop runs
+        k + 1 steps: the last one only writes the k-th draft's K/V —
+        when a round accepts every draft the engine commits k + 1
+        tokens, and without that row the draft pool would carry a hole
+        at `committed - 1` that every later draft attends over."""
         import jax
         import jax.numpy as jnp
 
@@ -229,16 +237,17 @@ class ModelDrafter(Drafter):
         fetches = (next_name, *cache_outs)
         k = self.k
 
-        def draft_fn(params, tokens, write_pos, active, page_table,
-                     pools):
-            buf0 = jnp.zeros((tokens.shape[0], k), jnp.int32)
+        def draft_fn(params, tokens, write_pos, active, limit,
+                     page_table, pools):
+            buf0 = jnp.zeros((tokens.shape[0], k + 1), jnp.int32)
 
             def body(j, c):
                 tok, wp, pls, buf = c
                 env = dict(params)
                 env.update(pls)
                 env.update(tokens=tok, write_pos=wp, lengths=wp + 1,
-                           active=active, page_table=page_table)
+                           active=active * (wp < limit),
+                           page_table=page_table)
                 env = interpret_program(program, env, None,
                                         fetch_names=fetches)
                 nxt = env[next_name].astype(jnp.int32)
@@ -249,8 +258,8 @@ class ModelDrafter(Drafter):
                 return (new_tok, wp + active, new_pools, buf)
 
             _tok, _wp, pls, buf = jax.lax.fori_loop(
-                0, k, body, (tokens, write_pos, pools, buf0))
-            return buf, pls
+                0, k + 1, body, (tokens, write_pos, pools, buf0))
+            return buf[:, :k], pls
 
         return draft_fn
 
@@ -323,16 +332,18 @@ class ModelDrafter(Drafter):
         tokens = np.zeros((s,), np.int32)
         wp = np.zeros((s,), np.int32)
         act = np.zeros((s,), np.int32)
+        limit = np.zeros((s,), np.int32)
         draft_len = np.zeros((s,), np.int32)
         for i in active_ids:
             slot = engine._slots[i]
             tokens[i] = slot.cur_tok
             wp[i] = slot.committed
             act[i] = 1
+            limit[i] = len(slot.pages) * engine.config.page_size
             draft_len[i] = self.k
         buf, pools = self._draft_exec(
             self._params, jnp.asarray(tokens), jnp.asarray(wp),
-            jnp.asarray(act), jnp.asarray(engine._page_tables),
-            self._pools)
+            jnp.asarray(act), jnp.asarray(limit),
+            jnp.asarray(engine._page_tables), self._pools)
         self._pools = pools
         return np.asarray(buf), draft_len

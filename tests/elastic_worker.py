@@ -32,12 +32,11 @@ import time
 # one virtual mesh of 4 CPU devices per rank: big enough for the
 # world-2 fsdp=4 mesh, and the shrunken world-1 fsdp=2 mesh uses a
 # prefix of it.  Must be set before jax import (conftest-less script).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=4")
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 

@@ -1,0 +1,237 @@
+"""The chip's compiler on the main path's kernels, at real widths.
+
+Interpret mode and `jax.export` (tests/test_pallas_lowering.py) both
+stop before Mosaic compiles: kernels that passed every such test were
+refused by the TPU's compiler for a lane slice not aligned to the
+tiling (paged attention) and for more scoped VMEM than a kernel may
+claim (fused LSTM).  The compiler is installed here and compiles for a
+chip that is DESCRIBED, not attached — so each kernel of the main path
+is compiled once for one v5e device at the width chip_smoke.py and
+bench.py run it, and the compiled text must hold the Mosaic custom
+call.  Nothing runs: this says nothing about results or times.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may hold the TPU library, and every xdist
+worker imports every test file), in the test's own process, with the
+persistent compilation cache off.  All such tests live in THIS file.
+"""
+
+from __future__ import annotations
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import force_mosaic_lowering
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever the library raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile_args(fn, *args):
+    """Compile an already-jittable `fn` for the described chip from
+    ShapeDtypeStruct arguments that carry its sharding."""
+    # conftest asks for "highest" matmul precision (f64 references);
+    # the program runs at the default, and Mosaic refuses an fp32
+    # contraction of bf16 operands
+    with force_mosaic_lowering(), jax.default_matmul_precision("default"):
+        return fn.lower(*args).compile()
+
+
+def _compile(fn, sharding, *specs):
+    """Compile `fn` for the described chip from (shape, dtype) specs
+    and return the compiled text."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in specs]
+    return _compile_args(jax.jit(fn), *args).as_text()
+
+
+def _kernels(text):
+    return text.count("tpu_custom_call")
+
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+# (N, H, T, D): the Transformer bench's shape and the longctx one
+@pytest.mark.parametrize("shape", [(64, 8, 256, 64), (2, 8, 8192, 64)],
+                         ids=["bs64_len256", "bs2_len8192"])
+def test_flash_attention_fwd_bwd(one_chip, shape):
+    from paddle_tpu.ops.pallas.flash_attention import \
+        pallas_flash_attention
+
+    def loss(q, k, v):
+        o = pallas_flash_attention(q, k, v, None, shape[3] ** -0.5, True)
+        return jnp.sum(o.astype(F32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    *[(shape, BF16)] * 3)
+    assert _kernels(text) >= 2, "forward and backward kernels expected"
+
+
+def test_flash_attention_head_major_entry(one_chip):
+    """layout="nthd": (N, T, H*D) head-grouped operands with the
+    key-padding bias, at d_head 128 — the width at which one head is a
+    whole lane tile of the grouped minor dim."""
+    from paddle_tpu.ops.pallas.flash_attention import \
+        pallas_flash_attention
+
+    n, t, h, d = 64, 256, 4, 128
+
+    def loss(q, k, v, bias):
+        o = pallas_flash_attention(q, k, v, bias, d ** -0.5, True,
+                                   layout="nthd", n_head=h)
+        return jnp.sum(o.astype(F32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    *[((n, t, h * d), BF16)] * 3, ((n, 1, 1, t), F32))
+    assert _kernels(text) >= 2
+
+
+def test_flash_attention_head_major_is_refused_at_d_head_64(one_chip):
+    """At d_head 64 (the Transformer's and the longctx bench's width)
+    a head is half a lane tile of the (N, T, H*D) operand and the
+    lowering refuses the block: the head-major Pallas path exists only
+    at d_head 128 until a kernel blocks head pairs (ROADMAP S4).  The
+    request is an error, never a silent fallback."""
+    from paddle_tpu.ops.pallas.flash_attention import \
+        pallas_flash_attention
+
+    def fn(q, k, v):
+        return pallas_flash_attention(q, k, v, None, 0.125, True,
+                                      layout="nthd", n_head=8)
+
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        _compile(fn, one_chip, *[((2, 256, 8 * 64), BF16)] * 3)
+
+
+def test_fused_vocab_ce_fwd_bwd(one_chip):
+    from paddle_tpu.ops.pallas.vocab_ce import fused_vocab_ce
+
+    tokens, d, vocab = 64 * 256, 512, 32000
+
+    def loss(hidden, w, labels):
+        return jnp.sum(fused_vocab_ce(hidden, w, labels, 0.1))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+                    ((tokens, d), BF16), ((d, vocab), BF16),
+                    ((tokens,), I32))
+    assert _kernels(text) >= 2
+
+
+# (S, H, d, P, page, maxp): chip_smoke's serve_decode geometry (16
+# slots, 8 heads x 64, 384 pages of 16, 512-token slots) and d_head 128
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("geom", [(16, 8, 64, 384, 16, 32),
+                                  (16, 4, 128, 384, 16, 32)],
+                         ids=["serve_decode", "d_head128"])
+def test_paged_attention(one_chip, geom, int8):
+    from paddle_tpu.ops.pallas.paged_attention import \
+        ragged_paged_attention
+
+    s, h, d, p, page, maxp = geom
+    pool = ((p, page, h * d), I8 if int8 else BF16)
+    specs = [((s, h * d), BF16), pool, pool, ((s, maxp), I32),
+             ((s,), I32)]
+    if int8:
+        specs += [((p, page, 1), F32)] * 2
+
+    def fn(q, k, v, pt, ln, ks=None, vs=None):
+        return ragged_paged_attention(q, k, v, pt, ln, n_head=h,
+                                      k_scales=ks, v_scales=vs)
+
+    assert _kernels(_compile(fn, one_chip, *specs)) == 1
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_fused_lstm_fwd_bwd(one_chip, dtype):
+    """bench_lstm's width (N=128, H=512; f32 is what it builds): the
+    backward takes 27 MiB of VMEM at the default time block, over
+    Mosaic's 16 MiB default; the kernel raises the limit."""
+    from paddle_tpu.ops.pallas.recurrence import fused_lstm
+
+    n, t, hid = 128, 128, 512
+
+    def loss(x, w):
+        hs, _cs, _h, c_last = fused_lstm(x, w)
+        return jnp.sum(hs.astype(F32)) + jnp.sum(c_last.astype(F32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+                    ((n, t, 4 * hid), dtype), ((hid, 4 * hid), dtype))
+    assert _kernels(text) == 2
+
+
+def test_fused_lstm_never_blocks_shape_inference():
+    """Build-time shape inference traces the kernel with a huge
+    stand-in batch and must get its shapes: the kernel leaves the VMEM
+    verdict to Mosaic at compile time and raises nothing before (an
+    early raise left the LSTM layer's output shapeless and the next
+    fc's weight (1, 4H) — found on the chip, PR 21)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import stacked_dynamic_lstm as lstm
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        lstm.build_model(max_len=16, use_amp=False, pallas_rnn=True)
+    assert main.global_block().var("lstm_0.tmp_0").shape == (-1, 16, 512)
+
+
+def test_kernel_cost_registry_covers_a_whole_step_on_the_tpu(one_chip):
+    """The stacked-LSTM train step with the fused kernel, compiled for
+    the chip: every Mosaic kernel in it has a registered cost, and the
+    TPU compiler's own bookkeeping custom calls (ConcatBitcast, ...)
+    are not mistaken for kernels — that miscount made
+    `bench.py --model lstm --pallas-rnn` refuse to report on the chip
+    (PR 21)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import stacked_dynamic_lstm as lstm
+    from paddle_tpu.observe import cost
+
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        model = lstm.build_model(max_len=16, use_amp=False,
+                                 pallas_rnn=True)
+        exe = fluid.Executor()
+        exe.run(startup)
+        feed = {k: jnp.asarray(v)
+                for k, v in lstm.make_fake_batch(8, 16).items()}
+        step, state, feeds = exe._prepare(
+            main, feed, [model["loss"].name], scope, 1, True)
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=one_chip)
+
+        compiled = _compile_args(step, jax.tree.map(described, state),
+                                 jax.tree.map(described, feeds))
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    targets = {r["custom_call_target"] for r in rows
+               if r["opcode"] == "custom-call"}
+    assert "tpu_custom_call" in targets
+    totals = cost.total_costs(cost.compiled_hlo_proto(compiled))
+    assert totals["custom_calls"] == totals["pallas_matched"] > 0, totals
+    assert totals["pallas_flops"] > 0

@@ -12,30 +12,11 @@ import sys
 import numpy as np
 import pytest
 
-import jax
-
 import paddle_tpu as fluid
 from paddle_tpu import layers
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "dist_worker.py")
-
-# The true 2-process trainers need cross-process XLA collectives on the
-# CPU backend.  jax >= 0.4.32 dropped that path unless jaxlib ships a
-# CPU collectives (gloo/mpi) build — this container's 0.4.37 does not,
-# and every cross-process device_put dies with "Multiprocess
-# computations aren't implemented on the CPU backend" (pre-existing,
-# verified identical at clean f4a9170).  Version-gated skip instead of
-# three guaranteed failures: a jax downgrade or a collectives-enabled
-# jaxlib turns these back on automatically.  The dead-peer chaos test
-# below stays live — it deliberately avoids cross-process XLA.
-_JAX_VERSION = tuple(int(p) for p in jax.__version__.split(".")[:3])
-_CPU_MULTIPROCESS_BROKEN = pytest.mark.skipif(
-    _JAX_VERSION >= (0, 4, 32),
-    reason=f"jax {jax.__version__} without CPU collectives: "
-           "'Multiprocess computations aren't implemented on the CPU "
-           "backend' (container jax drift, pre-existing at f4a9170)")
-
 
 def _free_port():
     s = socket.socket()
@@ -109,7 +90,6 @@ def _extract_losses(outs):
     return losses
 
 
-@_CPU_MULTIPROCESS_BROKEN
 @pytest.mark.slow
 def test_two_trainer_loss_parity():
     """2-process dp training must match the single-process trajectory on
@@ -122,7 +102,6 @@ def test_two_trainer_loss_parity():
     np.testing.assert_allclose(l0, ref, rtol=1e-4, atol=1e-6)
 
 
-@_CPU_MULTIPROCESS_BROKEN
 @pytest.mark.slow
 def test_two_trainer_sharded_ckpt_roundtrip(tmp_path):
     """True MULTI-PROCESS sharded checkpointing: each of the 2 trainer
@@ -186,7 +165,6 @@ def test_dead_peer_in_sharded_save_is_barrier_timeout_not_hang(tmp_path):
         assert "shards_p0.crc.json" not in files, files
 
 
-@_CPU_MULTIPROCESS_BROKEN
 @pytest.mark.slow
 def test_two_trainer_with_gradient_accumulation():
     """dp × gradient accumulation (batch-merge) still matches the

@@ -58,7 +58,7 @@ def test_flash_padding_bias():
 @pytest.mark.parametrize("t,causal", [(320, False), (384, True), (320, True)])
 def test_flash_nondivisible_tk(t, causal):
     """Regression: t_k % block_k != 0 must mask the padded k-tail
-    (ADVICE.md round-1 high finding)."""
+    (round-1 review finding)."""
     import paddle_tpu.ops.pallas.flash_attention as fa
 
     rng = np.random.RandomState(3)
@@ -195,3 +195,26 @@ def test_transformer_flash_pallas_matches_xla_flash():
     xla = run(False)
     assert pallas[-1] < pallas[0]
     np.testing.assert_allclose(pallas, xla, rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.parametrize("layout", ["nhtd", "nthd"])
+def test_pallas_request_with_rich_bias_is_an_error(layout):
+    """A program that asks for the Pallas kernel gets the kernel or an
+    error: a (Tq, Tk)-shaped bias, which the kernel cannot take, used to
+    fall to the XLA composition without a word."""
+    from op_test import run_op
+
+    n, h, t, d = 1, 2, 128, 64
+    rng = np.random.RandomState(0)
+    shape = (n, h, t, d) if layout == "nhtd" else (n, t, h * d)
+    x = rng.randn(*shape).astype(np.float32)
+    bias = np.zeros((n, 1, t, t), np.float32)
+    attrs = {"use_pallas": True, "scale": d ** -0.5, "layout": layout,
+             "n_head": h}
+    with pytest.raises(ValueError, match="key-padding bias"):
+        run_op("flash_attention",
+               {"Q": x, "K": x, "V": x, "Bias": bias}, attrs)
+    # the same request without use_pallas is the XLA composition
+    out = run_op("flash_attention", {"Q": x, "K": x, "V": x, "Bias": bias},
+                 dict(attrs, use_pallas=False))
+    assert np.isfinite(out).all()

@@ -330,8 +330,9 @@ def test_nthd_tpu_export_has_zero_transposes():
     contains zero stablehlo.transpose — the operands reach the kernels
     and the gradients leave them in the model's layout."""
     import paddle_tpu.ops.pallas.flash_attention as fa
+    import jax.export
+
     from paddle_tpu.ops.pallas import force_mosaic_lowering
-    from tests.test_pallas_lowering import _export_fn
 
     n, h, t, d = 1, 2, 256, 128
     q = jnp.zeros((n, t, h * d), jnp.float32)
@@ -345,7 +346,8 @@ def test_nthd_tpu_export_has_zero_transposes():
         return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(q, k, v, b)
 
     with force_mosaic_lowering():
-        exp = _export_fn()(step, q, q, q, bias)
+        exp = jax.export.export(jax.jit(step), platforms=["tpu"])(
+            q, q, q, bias)
     mlir = exp.mlir_module()
     assert mlir.count("tpu_custom_call") >= 3, \
         "expected fwd+dkv+dq Mosaic custom calls"

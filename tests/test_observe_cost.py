@@ -243,10 +243,20 @@ def test_op_cost_table_against_xla_aggregate():
         totals = observe.program_costs(main, feed=feed,
                                        fetch_list=[model["loss"]],
                                        exe=exe)
+        compiled = exe.compiled_step(main, feed=feed,
+                                     fetch_list=[model["loss"]],
+                                     scope=scope)
     xla = totals["xla_aggregate_flops"]
     assert xla > 0
-    assert abs(totals["flops"] - xla) / xla < 0.05, (totals["flops"],
-                                                     xla)
+    # XLA's aggregate counts a while body ONCE; the analytic total
+    # carries the trip count (the dropout RNG's 5-round threefry loops
+    # here), so take that excess out before comparing
+    loops = [r for r in cost.instruction_costs(
+        cost.compiled_hlo_proto(compiled)) if r["opcode"] == "while"]
+    assert all(r["trip_count"] for r in loops), loops
+    once = totals["flops"] - sum(
+        r["flops"] * (1.0 - 1.0 / r["trip_count"]) for r in loops)
+    assert abs(once - xla) / xla < 0.05, (totals["flops"], once, xla)
 
 
 def test_op_cost_table_joins_profile_time(tmp_path):

@@ -269,7 +269,7 @@ def _tiny_engine(tracer=None, num_pages=None):
 def test_decode_trace_tail_keeps_preemption():
     """sample_rate=0 on a pool sized to force preemption: the ONLY
     kept traces are the preempted ones (tail keep), and they carry the
-    join_wait/dispatch span taxonomy plus the preempt marker."""
+    join_wait/dispatch span kinds plus the preempt marker."""
     from paddle_tpu.models.decoder_lm import make_prompts
 
     tracer = ReqTracer(sample_rate=0.0)

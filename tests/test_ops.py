@@ -301,7 +301,7 @@ def test_conv2d_grad():
 
 def test_pool2d_with_index_argmax():
     """Mask must contain real flattened-H*W argmax positions
-    (ADVICE.md round-1 finding)."""
+    (round-1 review finding)."""
     x = rng.randn(2, 3, 6, 6).astype(np.float32)
     attrs = {"ksize": [2, 2], "strides": [2, 2]}
     outs = run_op("pool2d_with_index", {"X": x}, attrs=attrs)

@@ -15,30 +15,10 @@ import numpy as np
 import pytest
 
 import jax
+import jax.export  # a submodule: not auto-imported
 import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import force_mosaic_lowering
-
-
-def _export_fn():
-    """Version-tolerant jax.export accessor: newer jax ships it as the
-    `jax.export` SUBMODULE (not auto-imported — plain attribute access
-    raises AttributeError), older jax as jax.experimental.export, and
-    the keyword drifted lowering_platforms -> platforms along the way."""
-    import inspect
-
-    try:
-        import jax.export as jexp  # jax >= 0.4.30
-    except ImportError:
-        from jax.experimental import export as jexp  # older jax
-    sig = inspect.signature(jexp.export)
-    kw = ("platforms" if "platforms" in sig.parameters
-          else "lowering_platforms")
-
-    def export(fn, *args):
-        return jexp.export(jax.jit(fn), **{kw: ["tpu"]})(*args)
-
-    return export
 
 
 def _export_tpu(fn, *args):
@@ -47,7 +27,7 @@ def _export_tpu(fn, *args):
     the check would be vacuous."""
 
     with force_mosaic_lowering():
-        exp = _export_fn()(fn, *args)
+        exp = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
     # prove the Mosaic custom call is actually in the artifact
     mlir = exp.mlir_module()
     assert "tpu_custom_call" in mlir, \

@@ -43,9 +43,7 @@ import pytest
 if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observe

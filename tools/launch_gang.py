@@ -13,6 +13,11 @@ attempt), translates the exit-code registry (0 ok, 77 preempt-drain,
 until the restart budget runs out.  Workers are expected to resume
 from their newest valid checkpoint themselves (contrib.Trainer does).
 
+On ONE host this is for CPU ranks only (JAX_PLATFORMS=cpu in the
+workers): a chip belongs to one process at a time, so several ranks
+cannot each take the host's chips — one process drives all four.  A
+gang of chip-holding ranks needs one host per rank.
+
 Prints one `GANG_ATTEMPT {json}` line per attempt and a final
 `GANG_RESULT {json}` (or `GANG_FAILED {json}`); exits 0 on clean gang
 completion, 1 on budget exhaustion.

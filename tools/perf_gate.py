@@ -8,10 +8,11 @@ Two modes, matching what each environment can actually verify:
   examples_per_sec (regression = relative drop beyond tolerance) and
   serving compute_ms (regression = relative increase).  Exit 1 on any
   regression, with a per-metric report.  Candidates tagged `profiled`
-  or `probe_hazard.probe_loop_pids` are rejected outright — profiler-
-  inflated or attach-degraded numbers must never be gated (or
-  baselined) as if clean.
-- SCHEMA mode (--schema; the CPU-smoke half run by tools/run_ci.sh):
+  are rejected outright — profiler-inflated numbers must never be
+  gated (or baselined) as if clean.
+- SCHEMA mode (--schema; run on every bench line a chip call brings
+  back — `bench.py` measures on the chip only, so no CPU step runs it;
+  the chip procedure in .claude/skills/verify/SKILL.md does):
   validate that a bench JSON line carries the observability contract —
   metric/value/unit/vs_baseline/detail plus compile_s/retraces/
   peak_mem_bytes/run_id/git_sha (docs/OBSERVE.md), and per training
@@ -19,8 +20,9 @@ Two modes, matching what each environment can actually verify:
   docs/RESILIENCE.md), the numerics-observability fields
   (grad_norm_last / update_ratio_worst, docs/OBSERVE.md pillar 6) and
   the goodput-ledger fields (goodput / effective_mfu /
-  badput_breakdown, pillar 8) — so a chip-less CI still catches a
-  broken artifact shape before it burns a chip run.
+  badput_breakdown, pillar 8) — so a line that lost a contract field
+  is caught in the call that produced it, before anything is gated or
+  baselined on it.
 
 Baselines load from either a raw bench JSON line/file or a driver
 wrapper ({"tail": ..., "parsed": ...}); a truncated wrapper tail (the
@@ -531,7 +533,7 @@ def main() -> int:
                         "file holding it, or a driver wrapper)")
     p.add_argument("--schema", action="store_true",
                    help="validate the bench-line observability schema "
-                        "instead of comparing numbers (CPU-smoke mode)")
+                        "instead of comparing numbers")
     p.add_argument("--tol-mfu", type=float, default=0.05,
                    help="tolerated relative MFU drop (default 5%%)")
     p.add_argument("--tol-throughput", type=float, default=0.07,
@@ -607,10 +609,6 @@ def main() -> int:
     if candidate.get("profiled"):
         print("perf_gate: candidate was captured under --profile — "
               "profiler-inflated numbers are not gateable", file=sys.stderr)
-        return 2
-    if candidate.get("probe_hazard", {}).get("probe_loop_pids"):
-        print("perf_gate: candidate ran with probe_loop.sh attached "
-              "(~5x hazard) — not gateable", file=sys.stderr)
         return 2
     if candidate.get("nonfinite_flag") or \
             candidate.get("skipped_update_steps"):

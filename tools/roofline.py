@@ -32,10 +32,8 @@ checked — a ceiling below a recorded measurement raises instead of
 writing another impossible artifact.
 
 Run on the real chip: `python tools/roofline.py [--model all|resnet50|
-transformer] [--flash] [--out ROOFLINE_r06.json]`.  On CPU
-(BENCH_PLATFORM=cpu) fusion decisions differ — the JSON records the
-producing backend so approximate numbers are never mistaken for chip
-numbers.
+transformer] [--flash] [--out ROOFLINE_r06.json]`.  It needs the
+chip: bench._peak_flops raises on a device it has no peak for.
 """
 
 from __future__ import annotations
@@ -232,11 +230,6 @@ def main():
                         + ", ".join(_DEFAULT_MEASURED) + ")")
     p.add_argument("--out", default="ROOFLINE_r06.json")
     args = p.parse_args()
-
-    if os.environ.get("BENCH_PLATFORM"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
 
     from bench import _peak_flops
     from paddle_tpu.observe import cost as obs_cost

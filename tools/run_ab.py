@@ -4,9 +4,10 @@ AB artifact with the winners, so every bench default reflects a
 measured win.
 
 Usage: python tools/run_ab.py [--steps N] [--out AB_r12.json]
-Each variant is a separate bench.py subprocess (fresh backend, no cache
-cross-talk); the probe inside bench.py keeps a dead backend from
-burning the timeout.  r11: every pair's summary carries goodput
+Each variant is a separate bench.py subprocess, one after the other;
+this parent never imports jax, so each child gets the chip.  The
+children share the persistent compilation cache
+(paddle_tpu/compile_cache.py).  r11: every pair's summary carries goodput
 context (`<name>_goodput` — each side's harness-wall step fraction +
 effective_mfu, observe pillar 8) so a throughput verdict bought with
 badput is visible in the artifact itself.  r12: the speculative-decode
@@ -34,10 +35,8 @@ does deleting the boundary transposes flip it?) — and
 longctx_8k_headmajor is the headline lever (the r05 profile's ~15.9 s
 of copy/transpose).  Every transformer/longctx entry now carries
 `layout_share` so the summary's throughput verdicts come with the
-layout-traffic delta attached.  Entries recorded off-chip carry
-their producing backend in each entry's `device` field — a
-CPU-recorded win ("cpu (assumed v5e peak)") documents the harness but
-does NOT flip a TPU bench default.
+layout-traffic delta attached.  bench.py refuses to run without a
+chip, so every entry written from here on is a chip entry.
 """
 
 from __future__ import annotations
@@ -171,22 +170,27 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run_tag():
-    """This invocation's provenance stamp (paddle_tpu.observe.events).
-    Falls back to a bare uuid when the package can't import (foreign
-    checkout) — the tag must exist either way."""
+    """This invocation's provenance stamp.  Made here, without
+    paddle_tpu.observe.events: importing the package imports jax, and
+    this parent stays off jax so that each child gets the chip."""
+    import uuid
+
     try:
-        if _ROOT not in sys.path:
-            sys.path.insert(0, _ROOT)
-        from paddle_tpu.observe.events import git_sha, new_run_id
-
-        return {"run_id": new_run_id(), "git_sha": git_sha(_ROOT)}
-    except Exception:  # noqa: BLE001 — provenance must not kill the run
-        import uuid
-
-        return {"run_id": uuid.uuid4().hex[:12], "git_sha": None}
+        r = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
+                           capture_output=True, text=True, timeout=5,
+                           cwd=_ROOT)
+        sha = r.stdout.strip() if r.returncode == 0 else None
+    except (OSError, subprocess.SubprocessError):
+        sha = None
+    return {"run_id": uuid.uuid4().hex[:12], "git_sha": sha or None}
 
 
 def run_variant(args, extra):
+    # one process per chip: the bench.py child needs it, so this parent
+    # must never have touched jax (a parent that holds the chip makes
+    # the child fail or hang)
+    assert "jax" not in sys.modules, \
+        "run_ab's parent imported jax; its bench.py children need the chip"
     cmd = ([sys.executable, "bench.py", "--steps", str(args.steps)]
            + (args.bench_args.split() if args.bench_args else [])
            + extra)
@@ -522,9 +526,7 @@ def main():
                    help="comma-separated variant keys to run")
     p.add_argument("--bench-args", default=None,
                    help="extra bench.py args prepended to every "
-                        "variant (e.g. '--batch 16' for an off-chip "
-                        "CPU recording — each entry's `device` field "
-                        "records the producing backend either way)")
+                        "variant (e.g. '--batch 16')")
     args = p.parse_args()
 
     run_tag = _run_tag()
