@@ -1176,15 +1176,19 @@ class Executor:
         fn, state, feed_arrays = self._prepare(
             program, feed, fetch_names, scope, iterations,
             use_program_cache, accumulation_steps)
-        from ..observe.monitoring import dispatch_timer
+        from ..observe.monitoring import runtime_stats
 
-        with dispatch_timer():
+        with runtime_stats.phase("call"):
             new_state, fetches = fn(state, feed_arrays)
-        for name, val in new_state.items():
-            scope.set_var(name, val)
-        _debug_checks(fetch_names, fetches, new_state)
-        if return_numpy:
-            fetches = [np.asarray(f) for f in fetches]
+        with runtime_stats.phase("writeback"):
+            for name, val in new_state.items():
+                scope.set_var(name, val)
+            # the last references to the donated arrays: freeing some
+            # 600 of them is host time of the step, so it is timed
+            del state
+            _debug_checks(fetch_names, fetches, new_state)
+            if return_numpy:
+                fetches = [np.asarray(f) for f in fetches]
         return fetches
 
     def close(self):
@@ -1249,8 +1253,34 @@ class Executor:
     def _prepare(self, program: Program, feed, fetch_names, scope,
                  iterations: int, use_program_cache: bool,
                  accumulation_steps: int = 1):
-        """Shared run()/cost_analysis() setup: RNG init, state gathering,
-        program-cache lookup, feed conversion."""
+        """Shared run()/cost_analysis() setup, as the step's first two
+        host phases (observe.monitoring): `prepare` (`_lookup_step`)
+        and `place` (feed conversion, and the retrace check, which
+        reads the converted feed's dtypes)."""
+        from ..observe.monitoring import runtime_stats
+
+        with runtime_stats.phase("prepare"):
+            key, fn, state = self._lookup_step(
+                program, feed, fetch_names, scope, iterations,
+                use_program_cache, accumulation_steps)
+        with runtime_stats.phase("place"):
+            block = program.global_block()
+            feed_arrays = {n: _to_array(v, block) for n, v in feed.items()}
+            sig = tuple(
+                (n, tuple(getattr(v, "shape", ()) or ()),
+                 str(getattr(v, "dtype", type(v).__name__)))
+                for n, v in sorted(feed_arrays.items()))
+            seen = self._sig_seen.setdefault(key, set())
+            if seen and sig not in seen:
+                runtime_stats.record_retrace()
+            seen.add(sig)
+        return fn, state, feed_arrays
+
+    def _lookup_step(self, program: Program, feed, fetch_names, scope,
+                     iterations: int, use_program_cache: bool,
+                     accumulation_steps: int):
+        """RNG init, state gathering, program-cache lookup and, on a
+        miss, the step's build.  Returns (cache key, step fn, state)."""
         import jax
 
         block = program.global_block()
@@ -1295,16 +1325,7 @@ class Executor:
                 self._cache[key] = fn
         state = {n: scope.find_var(n) for n in state_names}
         state[RNG_STATE_VAR] = scope.find_var(RNG_STATE_VAR)
-        feed_arrays = {n: _to_array(v, block) for n, v in feed.items()}
-        sig = tuple(
-            (n, tuple(getattr(v, "shape", ()) or ()),
-             str(getattr(v, "dtype", type(v).__name__)))
-            for n, v in sorted(feed_arrays.items()))
-        seen = self._sig_seen.setdefault(key, set())
-        if seen and sig not in seen:
-            runtime_stats.record_retrace()
-        seen.add(sig)
-        return fn, state, feed_arrays
+        return key, fn, state
 
     # -- compilation -----------------------------------------------------
     def _build_step_fn(self, program: Program, feed_names, fetch_names,

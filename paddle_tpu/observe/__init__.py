@@ -13,7 +13,8 @@ Three pillars (docs/OBSERVE.md):
    state, no host round-trips, no callbacks) and is fetched every N
    steps in one sync; host-side
    `runtime_stats` counts XLA compiles (+wall time, via
-   jax.monitoring), executor retraces, and dispatch latency.
+   jax.monitoring), executor retraces, and the four host phases of a
+   step (prepare / place / call / writeback; `call` is the dispatch).
 
 3. STRUCTURED RUN EVENTS — `RunEventLog` writes JSONL records with
    run-id/git-sha/backend/mesh provenance, consumed by

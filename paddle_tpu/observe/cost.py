@@ -257,6 +257,7 @@ class HloModule:
             inner = _first(proto, 1)
             if isinstance(inner, bytes) and _first(inner, 3) is not None:
                 proto = inner
+        self.name = _utf8(_first(proto, 1, b""))
         self.entry_id = _first(proto, 6, 0)
         self.computations: Dict[int, Computation] = {}
         for f, _wt, v in _fields(proto):
@@ -348,21 +349,58 @@ def _dot_flops(instr: Instr, operands: List[Instr]) -> float:
     return 2.0 * instr.shape.elements * k
 
 
+def _valid_positions(size: int, kernel: int, out: int,
+                     dim: bytes) -> int:
+    """(output position, kernel position) pairs of one spatial
+    dimension that read a real input element: not padding, not a hole
+    of the base dilation.  `dim` is the serialized WindowDimension
+    (stride=2 padding_low=3 window_dilation=5 base_dilation=6)."""
+    w = {f: v for f, _wt, v in _fields(dim)}
+    stride, pad_low = w.get(2) or 1, w.get(3, 0)
+    if pad_low >= 1 << 63:           # a negative int64 on the wire
+        pad_low -= 1 << 64
+    rhs_dilate, lhs_dilate = w.get(5) or 1, w.get(6) or 1
+    dilated = (size - 1) * lhs_dilate + 1
+    n = 0
+    for o in range(out):
+        for k in range(kernel):
+            p = o * stride + k * rhs_dilate - pad_low
+            n += 0 <= p < dilated and p % lhs_dilate == 0
+    return n
+
+
 def _conv_flops(instr: Instr, operands: List[Instr]) -> float:
-    # fma(2) * output elements * kernel spatial size * input features
-    # per group.  Exact for VALID padding; overcounts clipped window
-    # positions at padded edges (small for large feature maps).
+    # fma(2) * output batch * output features * input features per
+    # group * the valid window positions of each spatial dimension:
+    # HloCostAnalysis::HandleConvolution.  Counting positions, not
+    # window sizes, matters on a TPU, whose compiler writes a batched
+    # dot as a convolution over the batch dimensions with a window as
+    # large as the batch and a dilation that leaves one position of it
+    # valid.
     if len(operands) < 2:
         return 0.0
-    kernel = operands[1].shape.dims
-    spatial = _repeated_ints(instr.conv_dnums_buf, 6)
-    kin = _repeated_ints(instr.conv_dnums_buf, 3)
-    window = 1
-    for dim in spatial:
-        if dim < len(kernel):
-            window *= kernel[dim]
-    cin = kernel[kin[0]] if kin and kin[0] < len(kernel) else 1
-    return 2.0 * instr.shape.elements * window * cin
+    dn = instr.conv_dnums_buf
+    lhs, kernel, out = (operands[0].shape.dims, operands[1].shape.dims,
+                        instr.shape.dims)
+
+    def dim(dims, fno):              # a dimension number; 0 is omitted
+        i = _first(dn, fno, 0)
+        return dims[i] if i < len(dims) else 1
+
+    flops = 2.0 * dim(out, 9) * dim(out, 10) * dim(kernel, 3)
+    windows = [v for f, _wt, v in _fields(instr.window_buf) if f == 1]
+    for li, ki, oi, window in zip(_repeated_ints(dn, 11),
+                                  _repeated_ints(dn, 6),
+                                  _repeated_ints(dn, 12), windows):
+        flops *= _valid_positions(lhs[li], kernel[ki], out[oi], window)
+    return flops
+
+
+def _is_dot(instr: Instr) -> bool:
+    """A convolution that jax traced as a `dot_general`: the TPU
+    compiler writes every dot as a convolution, and only the
+    instruction's `op_name` still says which it was."""
+    return instr.op_name.rpartition("/")[2].startswith("dot_general")
 
 
 def _reduce_ops(module: HloModule, instr: Instr) -> int:
@@ -493,22 +531,39 @@ def _bucket(module: HloModule, comp: Computation, instr: Instr) -> str:
     if op == "dot":
         return "matmul"
     if op == "convolution":
-        return "conv"
+        return "matmul" if _is_dot(instr) else "conv"
     if op in _COMM:
         return "comm"
+    if op in ("async-start", "async-update", "async-done"):
+        # the TPU compiler's own asynchronous slices and copies
+        # (`slice-start` / `slice-done`): data movement, unless what
+        # the async computation wraps is a collective
+        start = instr
+        while start is not None and start.opcode != "async-start":
+            start = (comp.by_id.get(start.operand_ids[0])
+                     if start.operand_ids else None)
+        for cid in start.called_ids if start is not None else ():
+            sub = module.computations.get(cid)
+            if sub is not None and sub.root is not None \
+                    and sub.root.opcode in _COMM:
+                return "comm"
+        return "layout"
     if op == "fusion":
         ops_inside = set()
+        convs = []
         root_op = None
         for cid in instr.called_ids:
             sub = module.computations.get(cid)
             if sub is None:
                 continue
             ops_inside.update(i.opcode for i in sub.instructions)
+            convs += [i for i in sub.instructions
+                      if i.opcode == "convolution"]
             if root_op is None and sub.root is not None:
                 root_op = sub.root.opcode
-        if "dot" in ops_inside:
+        if "dot" in ops_inside or (convs and all(map(_is_dot, convs))):
             return "matmul"
-        if "convolution" in ops_inside:
+        if convs:
             return "conv"
         if root_op in _LAYOUT:
             return "layout"
@@ -520,9 +575,10 @@ def _bucket(module: HloModule, comp: Computation, instr: Instr) -> str:
     return "elementwise"
 
 
-def instruction_costs(proto: bytes) -> List[Dict[str, Any]]:
-    """Analytic per-instruction cost rows for the module's entry
-    computation (one row per post-fusion kernel).
+def instruction_costs(proto) -> List[Dict[str, Any]]:
+    """Analytic per-instruction cost rows for the entry computation of
+    a serialized module, or of an `HloModule` already parsed (one row
+    per post-fusion kernel).
 
     Row keys: name, opcode, op_type (fluid attribution or None),
     bucket, flops, transcendentals, bytes, pallas_kernel (set when a
@@ -539,7 +595,7 @@ def instruction_costs(proto: bytes) -> List[Dict[str, Any]]:
     from ..ops.pallas import recurrence as _rc  # noqa: F401
     from ..ops.pallas import vocab_ce as _vc  # noqa: F401
 
-    module = HloModule(proto)
+    module = proto if isinstance(proto, HloModule) else HloModule(proto)
     entry = module.entry
     rows: List[Dict[str, Any]] = []
     for instr in entry.instructions:
@@ -664,7 +720,8 @@ def op_cost_table(program=None, feed=None, fetch_list=None, scope=None,
                   exe=None, profile_dir: Optional[str] = None,
                   peak_flops: Optional[float] = None,
                   hbm_bw: Optional[float] = None,
-                  proto: Optional[bytes] = None) -> List[Dict[str, Any]]:
+                  proto: Optional[bytes] = None,
+                  windows=None) -> List[Dict[str, Any]]:
     """Per-framework-op cost rows for a program's optimized step.
 
     Each row aggregates the entry instructions attributed to one
@@ -674,9 +731,13 @@ def op_cost_table(program=None, feed=None, fetch_list=None, scope=None,
          time_ms, arith_intensity, achieved_flops_frac,
          roofline_time_ms}
 
-    - `time_ms` joins measured per-instruction device time from a
-      jax.profiler trace under `profile_dir` (None when no trace is
-      given or no event matched — cost attribution works chip-free).
+    - `time_ms` joins measured per-instruction device self time from
+      a jax.profiler trace under `profile_dir`, summed over its chips
+      (None when no trace is given or no event matched — cost
+      attribution works chip-free).  Of the programs that ran in the
+      trace the one with this module's name and the most time is
+      taken; `windows` is `{chip: (lo, hi)}` seconds on the trace's
+      clock (observe/trace.py).
     - `achieved_flops_frac` = (flops / time) / peak_flops when both a
       time and a peak are known, else None.
     - `roofline_time_ms` = max(flops/peak, bytes/bw): the row's own
@@ -694,14 +755,22 @@ def op_cost_table(program=None, feed=None, fetch_list=None, scope=None,
         compiled = exe.compiled_step(program, feed=feed,
                                      fetch_list=fetch_list, scope=scope)
         proto = compiled_hlo_proto(compiled)
-    rows = instruction_costs(proto)
+    module = HloModule(proto)
+    rows = instruction_costs(module)
 
     times: Dict[str, float] = {}
     if profile_dir is not None:
         from .trace import instr_time_table
 
-        times = {name: t["total_ms"]
-                 for name, t in instr_time_table(profile_dir).items()}
+        by_program: Dict[str, Dict[str, float]] = {}
+        for (prog, name), t in instr_time_table(profile_dir,
+                                                windows).items():
+            # "jit_step(1025)" is a run of the module "jit_step"
+            if prog and prog.rpartition("(")[0] == module.name:
+                by_program.setdefault(prog, {})[name] = t["total_ms"]
+        if by_program:
+            times = max(by_program.values(),
+                        key=lambda m: sum(m.values()))
 
     if peak_flops is None and hbm_bw is None:
         peak_flops, hbm_bw = device_peaks()

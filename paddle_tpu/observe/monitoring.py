@@ -9,15 +9,21 @@ Compile events come from `jax.monitoring` (the jit/pjit internals emit
 `/jax/core/compile/backend_compile_duration` per backend compile);
 retraces are detected in `Executor._prepare` by input-signature change
 on an already-built step fn (jax re-traces per new shape/dtype
-signature); dispatch timing is the host cost of enqueueing one
-`Executor.run` (async — device completion is NOT included).
+signature).  The host side of one step is split into four phases,
+`prepare`, `place`, `call` and `writeback`, entered through
+`runtime_stats.phase(name)` in `Executor.run` and
+`CompiledProgram.run`: each is a `paddle_tpu.step.<name>` span in a
+profiler trace and a counter here.  `call` is the jitted call alone
+(async: device completion is NOT included) and also feeds
+`dispatches` / `dispatch_time_s`.
 """
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 # wraps compile_or_get_cached: a persistent-cache hit is counted too,
 # at its (short) retrieval time
@@ -31,6 +37,33 @@ _TRACE_EVENT_PREFIXES = ("/jax/core/compile/jaxpr_trace_duration",
 
 _FIELDS = ("compiles", "compile_time_s", "trace_time_s", "builds",
            "retraces", "dispatches", "dispatch_time_s")
+# the host phases of one step, in the order a step enters them
+STEP_PHASES = ("prepare", "place", "call", "writeback")
+SPAN_PREFIX = "paddle_tpu.step."
+_RECENT = 4096          # durations kept per phase
+
+
+class _Phase:
+    """One entry of `RuntimeStats.phase`: a profiler span and a timed
+    region.  A class, not a generator, because a step enters four."""
+
+    __slots__ = ("_stats", "_name", "_span", "_t0")
+
+    def __init__(self, stats, name):
+        import jax
+
+        self._stats, self._name = stats, name
+        self._span = jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._stats._record_phase(self._name,
+                                  time.perf_counter() - self._t0)
+        return self._span.__exit__(*exc)
 
 
 class RuntimeStats:
@@ -45,10 +78,13 @@ class RuntimeStats:
         self.builds = 0             # Executor step fns traced (cache miss)
         self.retraces = 0           # re-compiles of an existing step fn
         #                             caused by a feed signature change
-        self.dispatches = 0         # Executor.run dispatch count
+        self.dispatches = 0         # step dispatches: the `call` phase
         self.dispatch_time_s = 0.0  # host enqueue time (async; excludes
         #                             device execution)
-        self.last_dispatch_s = 0.0
+        # per-phase totals and the most recent durations
+        self._phase_time_s: Dict[str, float] = {}
+        self._phase_count: Dict[str, int] = {}
+        self._recent: Dict[str, collections.deque] = {}
 
     def record_compile(self, duration_s: float):
         with self._lock:
@@ -67,19 +103,45 @@ class RuntimeStats:
         with self._lock:
             self.retraces += 1
 
-    def record_dispatch(self, duration_s: float):
+    def phase(self, name: str) -> _Phase:
+        """Context manager around one host phase of a step: a
+        `paddle_tpu.step.<name>` span on the profiler's host line (the
+        device planes' clock), and its `time.perf_counter()` duration
+        added to `<name>_time_s` / `<name>_count` and to `recent(name)`.
+        `call` is the dispatch: it feeds `dispatches` too."""
+        return _Phase(self, name)
+
+    def _record_phase(self, name: str, duration_s: float):
         with self._lock:
-            self.dispatches += 1
-            self.dispatch_time_s += float(duration_s)
-            self.last_dispatch_s = float(duration_s)
+            self._phase_time_s[name] = (self._phase_time_s.get(name, 0.0)
+                                        + duration_s)
+            self._phase_count[name] = self._phase_count.get(name, 0) + 1
+            ring = self._recent.get(name)
+            if ring is None:
+                ring = self._recent[name] = collections.deque(
+                    maxlen=_RECENT)
+            ring.append(duration_s)
+            if name == "call":
+                self.dispatches += 1
+                self.dispatch_time_s += duration_s
+
+    def recent(self, name: str) -> List[float]:
+        """The last (at most 4096) durations of phase `name`, seconds,
+        oldest first."""
+        with self._lock:
+            return list(self._recent.get(name, ()))
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
-            return {f: getattr(self, f) for f in _FIELDS}
+            out = {f: getattr(self, f) for f in _FIELDS}
+            for name, total in self._phase_time_s.items():
+                out[name + "_time_s"] = total
+                out[name + "_count"] = self._phase_count[name]
+            return out
 
     def delta(self, since: Dict[str, Any]) -> Dict[str, Any]:
         now = self.snapshot()
-        return {f: now[f] - since.get(f, 0) for f in _FIELDS}
+        return {f: v - since.get(f, 0) for f, v in now.items()}
 
 
 runtime_stats = RuntimeStats()
@@ -259,15 +321,3 @@ class LatencyHistogram:
             v = self.percentile(p)
             out[f"p{p}_ms"] = round(v, 3) if v is not None else None
         return out
-
-
-class dispatch_timer:
-    """Context manager stamping one dispatch into runtime_stats."""
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        runtime_stats.record_dispatch(time.perf_counter() - self._t0)
-        return False

@@ -1,26 +1,44 @@
-"""Post-trace attribution: parse a captured jax.profiler trace into a
-per-fluid-op time table.
+"""Post-trace attribution: join a captured jax.profiler trace to the
+program's own names, into per-instruction rows and a per-fluid-op time
+table.
 
 The reference Fluid profiler printed a per-op summary table after the
 profiled region (python/paddle/fluid/profiler.py `sorted_key`; the data
-came from RecordEvent ranges + the CUPTI DeviceTracer).  On TPU the
-equivalent raw material is the XPlane protobuf jax.profiler writes:
-device planes carry one timed event per executed HLO instruction, and
-the trace's serialized HLO modules carry each instruction's
-`metadata.op_name` — which contains the `<op_type>:<op_index>` named
-scopes the executor emits around every op lowering
-(core/executor.py _run_one_op).  Joining the two recovers fluid-op
-attribution from a device timeline without any host-side hooks.
+came from RecordEvent ranges + the CUPTI DeviceTracer).  On TPU the raw
+material is the XPlane protobuf jax.profiler writes: a device plane
+carries one timed event per executed HLO instruction, and the trace's
+serialized HLO modules carry each instruction's `metadata.op_name`,
+which contains the `<op_type>:<op_index>` named scopes the executor
+emits around every op lowering (core/executor.py _run_one_op).  Joining
+the two recovers fluid-op attribution from a device timeline without
+any host-side hooks.
 
-Everything here is dependency-free: the XPlane and HLO protos are read
-with a minimal protobuf wire-format scanner (the schemas' field numbers
-are stable in XLA/tsl), so no tensorflow / tensorboard import is needed
-— those are multi-second imports that also link a second copy of XLA
-into the process.
+Two readers, one each for what the other cannot reach:
+
+- `jax.profiler.ProfileData` reads planes, lines, events and the
+  clock (`read_events`): the one path from an xplane to events.
+- the minimal protobuf wire-format scanner below reads the
+  `/host:metadata` plane's `Hlo Proto` stats (`hlo_protos`), which
+  `ProfileData` does not expose (the plane has no lines), and skips
+  every other plane's bytes unread.  `observe/cost.py` and
+  `observe/memory.py` read HLO and buffer-assignment protos with the
+  same scanner, so no tensorflow / tensorboard import is needed.
+
+What a trace looks like (PERF.md section 3).  A TPU writes one plane
+per chip, `/device:TPU:<n>`, with the lines `XLA Ops` (one event per
+executed instruction, named by the WHOLE instruction text,
+`%fusion.157 = (f32[2048]{0:T(1024)}, ...) fusion(...)`; a `while`
+event contains its body's events), `XLA Modules` (one event per
+program run, `jit_step(<fingerprint>)`), `Steps` and `Async XLA Ops`
+(neither is op time).  XLA:CPU writes no device plane: its instruction
+events sit on the host's thread lines, named by the bare instruction
+name, each with `hlo_module` and `program_id` stats.  `join_events` is
+a pure function of plain tuples shaped either way.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -82,143 +100,56 @@ def _utf8(v, default: str = "") -> str:
 
 
 # --------------------------------------------------------------------------
-# XPlane schema (tsl/profiler/protobuf/xplane.proto — stable field numbers)
+# the /host:metadata plane: program name -> serialized HloProto
 # --------------------------------------------------------------------------
 
 # XSpace:           planes=1
 # XPlane:           name=2 lines=3 event_metadata=4(map) stat_metadata=5(map)
-# XLine:            name=2 events=4
-# XEvent:           metadata_id=1 duration_ps=3 stats=4
 # XEventMetadata:   id=1 name=2 display_name=3 stats=5
 # XStatMetadata:    id=1 name=2
-# XStat:            metadata_id=1 double=2 uint64=3 int64=4 str=5 bytes=6 ref=7
+# XStat:            metadata_id=1 ... bytes=6
+
+METADATA_PLANE = "/host:metadata"
+HLO_PROTO_STAT = "Hlo Proto"
 
 
-def _parse_stat(buf: bytes, stat_names: Dict[int, str]):
-    mid, val = 0, None
-    for f, wt, v in _fields(buf):
-        if f == 1:
-            mid = v
-        elif f in (3, 4, 7):
-            val = v
-        elif f == 5:
-            val = _utf8(v)
-        elif f == 6:
-            val = v  # bytes payloads (e.g. serialized HLO)
-        elif f == 2:
-            import struct
-
-            val = struct.unpack("<d", v)[0] if wt == 1 else v
-    return stat_names.get(mid, str(mid)), val
+def _map_value(entry) -> bytes:
+    return _first(entry, 2, b"")
 
 
-def _parse_map_entry(buf: bytes) -> Tuple[int, bytes]:
-    key, val = 0, b""
-    for f, _wt, v in _fields(buf):
-        if f == 1:
-            key = v
-        elif f == 2:
-            val = v
-    return key, val
-
-
-class XPlane:
-    def __init__(self, name: str):
-        self.name = name
-        # line name -> [(event_meta_name, duration_ps, stats_dict)]
-        self.lines: Dict[str, List[Tuple[str, int, Dict[str, Any]]]] = {}
-        # event-metadata name -> stats dict (program-level metadata such
-        # as the serialized "Hlo Proto" lives here, not on timed events)
-        self.event_meta_stats: Dict[str, Dict[str, Any]] = {}
-
-
-def parse_xspace(path: str) -> List[XPlane]:
-    """Parse one .xplane.pb file into a list of XPlane views."""
-    space = open(path, "rb").read()
-    planes = []
-    for f, _wt, pbuf in _fields(space):
-        if f != 1:
+def hlo_protos(path: str) -> Dict[str, bytes]:
+    """{program name: serialized HloProto} of one .xplane.pb file: the
+    `Hlo Proto` stat of each event-metadata entry of the
+    `/host:metadata` plane (`jit_step(1025)` on XLA:CPU,
+    `jit_step(<fingerprint>)` on a TPU).  Every other plane is skipped
+    by its length, unread."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out: Dict[str, bytes] = {}
+    for f, _wt, plane in _fields(space):
+        if f != 1 or _utf8(bytes(_first(plane, 2, b""))) != METADATA_PLANE:
             continue
-        stat_names: Dict[int, str] = {}
-        event_meta: Dict[int, Tuple[str, bytes]] = {}
-        line_bufs: List[bytes] = []
-        name = ""
-        for pf, _pwt, pv in _fields(pbuf):
-            if pf == 2:
-                name = _utf8(pv)
-            elif pf == 3:
-                line_bufs.append(pv)
-            elif pf == 4:
-                mid, mbuf = _parse_map_entry(pv)
-                event_meta[mid] = (_utf8(_first(mbuf, 2, b"")), mbuf)
-            elif pf == 5:
-                mid, mbuf = _parse_map_entry(pv)
-                stat_names[mid] = _utf8(_first(mbuf, 2, b""))
-        plane = XPlane(name)
-        for mid, (mname, mbuf) in event_meta.items():
-            stats: Dict[str, Any] = {}
-            for mf, _mwt, mv in _fields(mbuf):
-                if mf == 5:  # XEventMetadata.stats
-                    k, v = _parse_stat(mv, stat_names)
-                    stats[k] = v
-            if stats:
-                plane.event_meta_stats[mname] = stats
-        for lbuf in line_bufs:
-            lname, events = "", []
-            for lf, _lwt, lv in _fields(lbuf):
-                if lf == 2:
-                    lname = _utf8(lv)
-                elif lf == 4:
-                    mid, dur = 0, 0
-                    estats: Dict[str, Any] = {}
-                    for ef, _ewt, ev in _fields(lv):
-                        if ef == 1:
-                            mid = ev
-                        elif ef == 3:
-                            dur = ev
-                        elif ef == 4:
-                            k, v = _parse_stat(ev, stat_names)
-                            estats[k] = v
-                    events.append((event_meta.get(mid, ("?", b""))[0],
-                                   dur, estats))
-            plane.lines.setdefault(lname, []).extend(events)
-        planes.append(plane)
-    return planes
-
-
-# --------------------------------------------------------------------------
-# HLO proto: instruction name -> metadata.op_name
-# --------------------------------------------------------------------------
-
-# HloProto:            hlo_module=1
-# HloModuleProto:      computations=3
-# HloComputationProto: instructions=2
-# HloInstructionProto: name=1 metadata=7
-# OpMetadata:          op_type=1 op_name=2
-
-
-def hlo_op_names(hlo_proto: bytes) -> Dict[str, str]:
-    """{instruction_name: metadata.op_name} for one serialized HloProto."""
-    out: Dict[str, str] = {}
-    module = _first(hlo_proto, 1, b"")
-    for f, _wt, comp in _fields(module):
-        if f != 3:
-            continue
-        for cf, _cwt, instr in _fields(comp):
-            if cf != 2:
+        plane = bytes(plane)
+        stat_ids = {_first(_map_value(v), 1, 0)
+                    for pf, _pwt, v in _fields(plane) if pf == 5
+                    and _utf8(_first(_map_value(v), 2, b""))
+                    == HLO_PROTO_STAT}
+        for pf, _pwt, v in _fields(plane):
+            if pf != 4:
                 continue
-            iname, opname = None, None
-            for inf, _iwt, iv in _fields(instr):
-                if inf == 1:
-                    iname = _utf8(iv)
-                elif inf == 7:
-                    opname = _utf8(_first(iv, 2, b""))
-            if iname and opname:
-                out[iname] = opname
+            meta = _map_value(v)
+            for mf, _mwt, stat in _fields(meta):
+                if mf == 5 and _first(stat, 1, 0) in stat_ids:
+                    proto = _first(stat, 6, b"")
+                    if proto:
+                        out[_utf8(_first(meta, 2, b""))] = proto
     return out
 
 
-_PROGRAM_ID_RE = re.compile(r"\((\d+)\)$")
+# --------------------------------------------------------------------------
+# the program's names inside an HLO op_name
+# --------------------------------------------------------------------------
+
 # the executor's scope convention: "<op_type>:<op_index>".  jax
 # transforms WRAP scope segments — under value_and_grad the forward
 # lowers as "jvp(mul:3)" and the backward as "transpose(jvp(mul:3))" —
@@ -236,9 +167,140 @@ def fluid_op_of(op_name: str) -> Optional[str]:
     return hits[-1][0] if hits else None
 
 
+def phase_of(op_name: str) -> str:
+    """`backward` for an op_name under `transpose(jvp(`, `forward`
+    under `jvp(`, else `other` (optimizer and feed ops, which the
+    executor lowers outside value_and_grad)."""
+    if "transpose(jvp(" in op_name:
+        return "backward"
+    return "forward" if "jvp(" in op_name else "other"
+
+
+def instruction_name(event_name: str) -> str:
+    """A TPU op event is named by the whole instruction text: cut
+    `%fusion.157 = (f32[2048]{0:T(1024)}, ...) fusion(...)` to
+    `fusion.157`.  XLA:CPU's bare names pass through."""
+    return event_name.partition(" = ")[0].lstrip("%")
+
+
+def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
+    """{instruction name: {op_name, bucket, flops, bytes}} of one
+    serialized HloProto: every computation's instructions with their
+    `metadata.op_name`, and for the entry computation's the bucket,
+    FLOPs and bytes of `cost.instruction_costs` (elsewhere None)."""
+    from . import cost
+
+    module = cost.HloModule(proto)
+    out: Dict[str, Dict[str, Any]] = {}
+    for comp in module.computations.values():
+        for instr in comp.instructions:
+            out[instr.name] = {"op_name": instr.op_name, "bucket": None,
+                               "flops": None, "bytes": None}
+    for row in cost.instruction_costs(module):
+        out[row["name"]].update(bucket=row["bucket"], flops=row["flops"],
+                                bytes=row["bytes"])
+    return out
+
+
+# --------------------------------------------------------------------------
+# the join, pure on plain tuples
+# --------------------------------------------------------------------------
+
+UNJOINED_BUCKET = "unknown"     # the instruction is in no map
+BODY_BUCKET = "loop"            # in the map, no cost row: a while body
+
+
+def _enclosing(modules, starts, t) -> Optional[str]:
+    """Name of the module event that contains time `t` (the latest
+    started one: a chip runs one program at a time)."""
+    i = bisect.bisect_right(starts, t) - 1
+    if i >= 0 and t < modules[i][1] + modules[i][2]:
+        return modules[i][0]
+    return None
+
+
+def join_events(ops, modules, programs, window=None, chip=0
+                ) -> List[Dict[str, Any]]:
+    """Join one line of instruction events to the programs' own names.
+
+    `ops`: `(name, start_s, duration_s)` events of ONE line (a chip's
+    `XLA Ops`, or one XLA:CPU thread), or 4-tuples whose last item is
+    the program they ran in.  `modules`: the same chip's `XLA Modules`
+    events; a 3-tuple op belongs to the module event that contains its
+    start.  `programs`: `{module name: program_map(...)}`; an op is
+    looked up in ITS program's map, never in a merged one.  `window`:
+    `(lo, hi)`; ops that start in `[lo, hi)` count.
+
+    An event nested inside another on the line (a `while`'s body)
+    keeps its time and takes it from its parent: `self_s` is time no
+    child covers, so the rows of a window sum to its busy union.
+
+    One row per (module, instruction): chip, module, instruction,
+    op_name, op_type (fluid), phase, bucket, flops and bytes (per
+    call), joined (found in its program's map), calls, self_s,
+    total_s, max_s, min_s (of one call's self time).
+    """
+    modules = sorted(modules, key=lambda e: e[1])
+    starts = [m[1] for m in modules]
+    events = sorted(ops, key=lambda e: (e[1], -e[2]))
+    self_s = [e[2] for e in events]
+    stack: List[int] = []               # indices of open events
+    for i, ev in enumerate(events):
+        while stack and events[stack[-1]][1] + events[stack[-1]][2] \
+                <= ev[1]:
+            stack.pop()
+        if stack:       # the part of it that its parent covers
+            parent = events[stack[-1]]
+            self_s[stack[-1]] -= min(ev[2], parent[1] + parent[2] - ev[1])
+        stack.append(i)
+    rows: Dict[Tuple[Optional[str], str], Dict[str, Any]] = {}
+    for ev, own in zip(events, self_s):
+        if window is not None and not window[0] <= ev[1] < window[1]:
+            continue
+        module = ev[3] if len(ev) > 3 else _enclosing(modules, starts,
+                                                      ev[1])
+        name = instruction_name(ev[0])
+        r = rows.get((module, name))
+        if r is None:
+            info = programs.get(module, {}).get(name)
+            op_name = info["op_name"] if info else None
+            r = rows[(module, name)] = {
+                "chip": chip, "module": module, "instruction": name,
+                "op_name": op_name,
+                "op_type": fluid_op_of(op_name) if op_name else None,
+                "phase": phase_of(op_name or ""),
+                "bucket": (UNJOINED_BUCKET if info is None
+                           else info["bucket"] or BODY_BUCKET),
+                "flops": info["flops"] if info else None,
+                "bytes": info["bytes"] if info else None,
+                "joined": info is not None,
+                "calls": 0, "self_s": 0.0, "total_s": 0.0,
+                "max_s": 0.0, "min_s": float("inf")}
+        own = max(own, 0.0)
+        r["calls"] += 1
+        r["self_s"] += own
+        r["total_s"] += ev[2]
+        r["max_s"] = max(r["max_s"], own)
+        r["min_s"] = min(r["min_s"], own)
+    return list(rows.values())
+
+
+# --------------------------------------------------------------------------
+# from a profiler log dir to rows
+# --------------------------------------------------------------------------
+
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+HOST_PLANE = "/host:CPU"
+_DEVICE_PLANE_RE = re.compile(r"/device:[A-Za-z]+:(\d+)\s*$")
+
+
 def _trace_files(profile_dir: str) -> List[str]:
     """Newest run's .xplane.pb files under a jax.profiler log dir (the
-    dir itself, or profile_dir/plugins/profile/<timestamp>/)."""
+    dir itself, or profile_dir/plugins/profile/<timestamp>/), or the
+    one file `profile_dir` names."""
+    if os.path.isfile(profile_dir):
+        return [profile_dir]
     direct = sorted(glob.glob(os.path.join(profile_dir, "*.xplane.pb")))
     if direct:
         return direct
@@ -254,102 +316,125 @@ def _trace_files(profile_dir: str) -> List[str]:
     return files
 
 
-def _load_planes(profile_dir: str):
-    """(planes, per_program_instruction_maps, merged_instruction_map)
-    for the newest run under a profiler log dir — the shared setup of
-    op_time_table / instr_time_table."""
-    per_program: Dict[str, Dict[str, str]] = {}
-    merged: Dict[str, str] = {}
-    planes: List[XPlane] = []
-    for path in _trace_files(profile_dir):
-        planes.extend(parse_xspace(path))
-    for plane in planes:
-        for mname, stats in plane.event_meta_stats.items():
-            hlo = stats.get("Hlo Proto")
-            if not isinstance(hlo, bytes) or not hlo:
+def _line_events(line) -> List[Tuple[str, float, float]]:
+    return [(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
+            for e in line.events]
+
+
+def read_events(path: str, chips=None) -> Dict[str, Any]:
+    """Instruction and module events of one .xplane.pb file, read with
+    `jax.profiler.ProfileData`:
+    `{"chips": {n: {"ops": [...], "modules": [...]}}, "host": [[...]]}`
+    with `(name, start_s, duration_s)` events on the trace's one
+    clock.  A device plane gives its `XLA Ops` and `XLA Modules` lines
+    and nothing else (`Steps`, `Async XLA Ops` are not op time);
+    `chips` keeps only those chips.  Where the file holds no device
+    plane (XLA:CPU), `host` holds one list per host thread of the
+    events that carry an `hlo_op` stat, as 4-tuples ending in their
+    program, `<hlo_module>(<program_id>)`."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path)
+    out: Dict[str, Any] = {"chips": {}, "host": []}
+    host_plane = None
+    for plane in data.planes:
+        m = _DEVICE_PLANE_RE.match(plane.name)
+        if m:
+            chip = int(m.group(1))
+            if chips is not None and chip not in chips:
                 continue
-            names = hlo_op_names(hlo)
-            m = _PROGRAM_ID_RE.search(mname)
-            if m:
-                per_program.setdefault(m.group(1), {}).update(names)
-            merged.update(names)
-    return planes, per_program, merged
-
-
-def _instruction_events(planes, per_program, merged) -> Iterator[
-        Tuple[str, Optional[str], float]]:
-    """Yield (instruction_name, hlo_op_name or None, duration_ms) for
-    every timed event that is attributable instruction work."""
-    for plane in planes:
-        is_device = plane.name.startswith("/device:")
-        for _lname, events in plane.lines.items():
-            for ename, dur_ps, estats in events:
-                if dur_ps <= 0:
-                    continue
-                pid = estats.get("program_id")
-                imap = per_program.get(str(pid), merged) if pid \
-                    else merged
-                op_name = imap.get(ename) or merged.get(ename)
-                if op_name is None and not is_device:
-                    # host event that is not an HLO instruction (python
-                    # frames, thread-pool bookkeeping) — not op time.
-                    # Instruction events land on host lines too: XLA:CPU
-                    # executes small thunks INLINE on the calling
-                    # thread, so the instruction-name map, not the line
-                    # name, decides what counts.
-                    continue
-                yield ename, op_name, dur_ps / 1e9
-
-
-def instr_time_table(profile_dir: str) -> Dict[str, Dict[str, Any]]:
-    """Per-HLO-instruction measured time from a captured trace:
-    {instruction_name: {total_ms, calls, op_name}} — the join key for
-    observe.cost's analytic per-instruction flop/byte rows."""
-    planes, per_program, merged = _load_planes(profile_dir)
-    out: Dict[str, Dict[str, Any]] = {}
-    for ename, op_name, dur_ms in _instruction_events(
-            planes, per_program, merged):
-        r = out.setdefault(ename, {"total_ms": 0.0, "calls": 0,
-                                   "op_name": op_name})
-        r["total_ms"] += dur_ms
-        r["calls"] += 1
+            found = {"ops": [], "modules": []}
+            for line in plane.lines:
+                key = {OPS_LINE: "ops", MODULES_LINE: "modules"}.get(
+                    line.name)
+                if key:
+                    found[key] = _line_events(line)
+            out["chips"][chip] = found
+        elif plane.name == HOST_PLANE:
+            host_plane = plane
+    if not out["chips"] and host_plane is not None:
+        for line in host_plane.lines:
+            events = []
+            for e in line.events:
+                stats = dict(e.stats)
+                if "hlo_op" in stats:
+                    events.append((
+                        e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9,
+                        f"{stats.get('hlo_module')}"
+                        f"({stats.get('program_id')})"))
+            if events:
+                out["host"].append(events)
     return out
 
 
-def op_time_table(profile_dir: str) -> List[Dict[str, Any]]:
+def op_rows(profile_dir: str, windows=None, chips=None
+            ) -> List[Dict[str, Any]]:
+    """The rows of `join_events` for every chip (or XLA:CPU thread) of
+    the newest trace under `profile_dir` (a log dir or one
+    `.xplane.pb`).  `windows`: `{chip: (lo, hi)}` in seconds on the
+    trace's clock, for the chips it names; `chips`: read only those.
+    Only the programs that ran in the trace are parsed."""
+    rows: List[Dict[str, Any]] = []
+    for path in _trace_files(profile_dir):
+        events = read_events(path, chips=chips)
+        ran = {m[0] for c in events["chips"].values()
+               for m in c["modules"]}
+        ran.update(e[3] for line in events["host"] for e in line)
+        programs = {name: program_map(proto)
+                    for name, proto in hlo_protos(path).items()
+                    if name in ran}
+        for chip, c in sorted(events["chips"].items()):
+            rows += join_events(c["ops"], c["modules"], programs,
+                                window=(windows or {}).get(chip),
+                                chip=chip)
+        for line in events["host"]:
+            rows += join_events(line, (), programs,
+                                window=(windows or {}).get(0))
+    return rows
+
+
+def instr_time_table(profile_dir: str, windows=None
+                     ) -> Dict[Tuple[str, str], Dict[str, Any]]:
+    """Measured self time per (program, HLO instruction) from a
+    captured trace, over all chips and threads:
+    {(module, instruction): {total_ms, calls, op_name}}: the join key
+    for observe.cost's analytic per-instruction flop/byte rows."""
+    out: Dict[Tuple[str, str], Dict[str, Any]] = {}
+    for r in op_rows(profile_dir, windows):
+        t = out.setdefault((r["module"], r["instruction"]),
+                           {"total_ms": 0.0, "calls": 0,
+                            "op_name": r["op_name"]})
+        t["total_ms"] += r["self_s"] * 1e3
+        t["calls"] += r["calls"]
+    return out
+
+
+def op_time_table(profile_dir: str, windows=None) -> List[Dict[str, Any]]:
     """Aggregate a captured trace into per-fluid-op-type rows.
 
     Returns [{op_type, calls, total_ms, avg_ms, max_ms, min_ms, ratio}]
-    sorted by total time.  Rows whose device events carry no
-    `<op>:<idx>` scope (infra, un-annotated programs) aggregate under
-    "[unattributed]"; host python events and profiler bookkeeping lines
-    are excluded.
+    sorted by total time; times are self times, so the rows sum to the
+    device's busy time.  Instructions that carry no `<op>:<idx>` scope
+    (infra, un-annotated programs, an instruction its program's map
+    does not hold) aggregate under "[unattributed]"; host python
+    events, `Steps`, `XLA Modules` and `Async XLA Ops` are not op time.
+    `windows`: `{chip: (lo, hi)}` seconds on the trace's clock.
     """
-    planes, per_program, merged = _load_planes(profile_dir)
-
     rows: Dict[str, Dict[str, Any]] = {}
-
-    def add(op: str, dur_ms: float):
-        r = rows.setdefault(op, {"op_type": op, "calls": 0,
+    for r in op_rows(profile_dir, windows):
+        op = r["op_type"] or "[unattributed]"
+        t = rows.setdefault(op, {"op_type": op, "calls": 0,
                                  "total_ms": 0.0, "max_ms": 0.0,
                                  "min_ms": float("inf")})
-        r["calls"] += 1
-        r["total_ms"] += dur_ms
-        r["max_ms"] = max(r["max_ms"], dur_ms)
-        r["min_ms"] = min(r["min_ms"], dur_ms)
-
-    for _ename, op_name, dur_ms in _instruction_events(
-            planes, per_program, merged):
-        fluid_op = fluid_op_of(op_name) if op_name else None
-        add(fluid_op or "[unattributed]", dur_ms)
-
+        t["calls"] += r["calls"]
+        t["total_ms"] += r["self_s"] * 1e3
+        t["max_ms"] = max(t["max_ms"], r["max_s"] * 1e3)
+        t["min_ms"] = min(t["min_ms"], r["min_s"] * 1e3)
     out = sorted(rows.values(), key=lambda r: -r["total_ms"])
     total = sum(r["total_ms"] for r in out) or 1.0
     for r in out:
         r["avg_ms"] = r["total_ms"] / r["calls"]
         r["ratio"] = r["total_ms"] / total
-        if r["min_ms"] == float("inf"):
-            r["min_ms"] = 0.0
     return out
 
 

@@ -170,18 +170,22 @@ class CompiledProgram:
     def run(self, executor, feed: Dict[str, Any], fetch_names, scope,
             return_numpy: bool = True, iterations: int = 1,
             accumulation_steps: int = 1):
-        import jax
+        from ..core.executor import _debug_checks
+        from ..observe.monitoring import runtime_stats
 
         fn, state, feed_arrays, _, _ = self._prepare_step(
             feed, fetch_names, scope, iterations, accumulation_steps)
-        new_state, fetches = fn(state, feed_arrays)
-        for name, val in new_state.items():
-            scope.set_var(name, val)
-        from ..core.executor import _debug_checks
-
-        _debug_checks(fetch_names, fetches, new_state)
-        if return_numpy:
-            fetches = [np.asarray(f) for f in fetches]
+        with runtime_stats.phase("call"):
+            new_state, fetches = fn(state, feed_arrays)
+        with runtime_stats.phase("writeback"):
+            for name, val in new_state.items():
+                scope.set_var(name, val)
+            # the last references to the donated arrays: freeing some
+            # 600 of them is host time of the step, so it is timed
+            del state
+            _debug_checks(fetch_names, fetches, new_state)
+            if return_numpy:
+                fetches = [np.asarray(f) for f in fetches]
         return fetches
 
     def compiled_hlo_text(self, feed: Dict[str, Any], fetch_names,
@@ -236,6 +240,36 @@ class CompiledProgram:
 
     def _prepare_step(self, feed, fetch_names, scope, iterations,
                       accumulation_steps):
+        """The step's first two host phases (observe.monitoring):
+        `prepare` (`_lookup_step`) and `place` (every state array and
+        every feed array through `jax.device_put`, a no-op for what is
+        already placed; the two child spans say which of them costs)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
+
+        from ..observe.monitoring import SPAN_PREFIX, runtime_stats
+
+        with runtime_stats.phase("prepare"):
+            (fn, state_shardings, feed_shardings), state = \
+                self._lookup_step(feed, fetch_names, scope, iterations,
+                                  accumulation_steps)
+        with runtime_stats.phase("place"):
+            with TraceAnnotation(SPAN_PREFIX + "place_state"):
+                state = {n: jax.device_put(v, state_shardings[n])
+                         for n, v in state.items()}
+            with TraceAnnotation(SPAN_PREFIX + "place_feed"):
+                feed_arrays = {
+                    n: jax.device_put(jnp.asarray(v), feed_shardings[n])
+                    for n, v in feed.items()}
+        return fn, state, feed_arrays, state_shardings, feed_shardings
+
+    def _lookup_step(self, feed, fetch_names, scope, iterations,
+                     accumulation_steps):
+        """RNG and telemetry state, state names, feed shardings, cache
+        key and look-up, and on a miss the step's build.  Returns
+        ((fn, state_shardings, feed_shardings), state as the scope
+        holds it)."""
         import jax
 
         # an explicit per-run override wins over the BuildStrategy knob
@@ -344,12 +378,4 @@ class CompiledProgram:
             entry = (fn, state_shardings, feed_shardings)
             self._cache[key] = entry
 
-        fn, state_shardings, feed_shardings = entry
-        # place inputs according to shardings (no-op when already placed)
-        state = {n: jax.device_put(v, state_shardings[n])
-                 for n, v in state.items()}
-        import jax.numpy as jnp
-
-        feed_arrays = {n: jax.device_put(jnp.asarray(v), feed_shardings[n])
-                       for n, v in feed.items()}
-        return fn, state, feed_arrays, state_shardings, feed_shardings
+        return entry, state

@@ -235,3 +235,49 @@ def test_kernel_cost_registry_covers_a_whole_step_on_the_tpu(one_chip):
     totals = cost.total_costs(cost.compiled_hlo_proto(compiled))
     assert totals["custom_calls"] == totals["pallas_matched"] > 0, totals
     assert totals["pallas_flops"] > 0
+
+
+def test_tpu_dots_are_matmul_rows_with_xlas_flops(one_chip):
+    """The TPU compiler writes every dot as a `convolution` (a batched
+    one over its batch dimensions, with a window as large as the batch
+    of which a dilation leaves one position valid): observe.cost must
+    still bucket it `matmul`, a real convolution `conv`, and count the
+    FLOPs XLA's own cost analysis counts."""
+    from paddle_tpu.observe import cost
+
+    def forward(q, k, v):
+        with jax.named_scope("flash_attention:3"):
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+            p = jax.nn.softmax(s.astype(F32), -1).astype(BF16)
+            return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(F32).sum()
+
+    # forward and backward: five batched dots (the forward alone is
+    # matched to a fused attention of the compiler's own)
+    attention = jax.grad(forward, argnums=(0, 1, 2))
+
+    def stem(x, w):
+        with jax.named_scope("conv2d:0"):
+            return jax.lax.conv_general_dilated(x, w, (2, 2), "SAME")
+
+    qkv = [jax.ShapeDtypeStruct((8, 8, 256, 64), BF16, sharding=one_chip)] * 3
+    img = [jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+           for s in ((8, 3, 224, 224), (64, 3, 7, 7))]
+    for fn, args, bucket, op, dots in ((attention, qkv, "matmul",
+                                        "flash_attention", 5),
+                                       (stem, img, "conv", "conv2d", 1)):
+        compiled = _compile_args(jax.jit(fn), *args)
+        assert " convolution(" in compiled.as_text()
+        assert " dot(" not in compiled.as_text()
+        rows = [r for r in cost.instruction_costs(
+            cost.compiled_hlo_proto(compiled))
+            if r["bucket"] in ("matmul", "conv")]
+        assert len(rows) == dots
+        assert {r["bucket"] for r in rows} == {bucket}
+        assert {r["op_type"] for r in rows} == {op}
+        total = sum(r["flops"] for r in cost.instruction_costs(
+            cost.compiled_hlo_proto(compiled)))
+        assert total == pytest.approx(cost.compiled_xla_flops(compiled),
+                                      rel=0.02)
+    # the stem by hand: SAME padding clips 3 of 7 window positions at
+    # each edge, which a count of window sizes would miss
+    assert sum(r["flops"] for r in rows) < 2 * 8 * 64 * 112 * 112 * 3 * 49

@@ -20,6 +20,7 @@ from __future__ import annotations
 import os
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -396,3 +397,87 @@ def test_op_cost_table_lstm_step_attributes_trip_multiplied_flops():
     buckets = {r["bucket"] for r in rows
                if r["op_type"] == "dynamic_lstm"}
     assert "loop" in buckets, buckets
+
+
+def _varint(n):
+    out = b""
+    while True:
+        out += bytes([(n & 0x7F) | (0x80 if n > 0x7F else 0)])
+        n >>= 7
+        if not n:
+            return out
+
+
+def _window_dim(**fields):
+    """A serialized WindowDimension from its field numbers."""
+    numbers = {"size": 1, "stride": 2, "pad_low": 3, "pad_high": 4,
+               "rhs_dilate": 5, "lhs_dilate": 6}
+    return b"".join(_varint(numbers[k] << 3) + _varint(v % (1 << 64))
+                    for k, v in fields.items())
+
+
+@pytest.mark.parametrize("size,kernel,out,dim,want", [
+    # VALID 3-tap window over 8: every pair reads an element
+    (8, 3, 6, dict(size=3, stride=1), 18),
+    # SAME padding: the two edge outputs lose one tap each
+    (8, 3, 8, dict(size=3, stride=1, pad_low=1, pad_high=1), 22),
+    # ResNet's stem: 7 taps, stride 2, padding 2/3 over 224 -> 112;
+    # outputs 0, 111 and 110 lose 2, 3 and 1 taps
+    (224, 7, 112, dict(size=7, stride=2, pad_low=2, pad_high=3), 778),
+    # a TPU batched dot: the batch of 64 as a spatial dimension, a
+    # window of 64 of which the dilation leaves one tap per output
+    (64, 64, 64, dict(size=64, stride=63, lhs_dilate=64), 64),
+    # negative padding (a cropped input)
+    (8, 3, 4, dict(size=3, stride=1, pad_low=-2), 12),
+])
+def test_conv_flops_count_valid_window_positions(size, kernel, out, dim,
+                                                 want):
+    assert cost._valid_positions(size, kernel, out,
+                                 _window_dim(**dim)) == want
+
+
+def _ld(fno, payload):
+    return _varint((fno << 3) | 2) + _varint(len(payload)) + payload
+
+
+def _vi(fno, n):
+    return _varint(fno << 3) + _varint(n)
+
+
+def _instr(name, opcode, iid, operands=(), called=()):
+    """A serialized HloInstructionProto (name=1 opcode=2 id=35
+    operand_ids=36 called_computation_ids=38)."""
+    return (_ld(1, name.encode()) + _ld(2, opcode.encode()) + _vi(35, iid)
+            + b"".join(_vi(36, o) for o in operands)
+            + b"".join(_vi(38, c) for c in called))
+
+
+def _comp(name, cid, instrs, root):
+    return (_ld(1, name.encode()) + b"".join(_ld(2, i) for i in instrs)
+            + _vi(5, cid) + _vi(6, root))
+
+
+def test_async_wrappers_are_layout_unless_they_wrap_a_collective():
+    """The TPU compiler's `slice-start` / `slice-done` pairs are
+    `async-start` / `async-done` around a computation: data movement,
+    and `comm` where the computation's root is a collective."""
+    module = (
+        _ld(1, b"jit_step")
+        + _ld(3, _comp("async_slice", 1, [
+            _instr("p", "parameter", 1), _instr("s", "slice", 2, [1])], 2))
+        + _ld(3, _comp("async_ar", 2, [
+            _instr("p", "parameter", 1),
+            _instr("ar", "all-reduce", 2, [1])], 2))
+        + _ld(3, _comp("main", 3, [
+            _instr("x", "parameter", 1),
+            _instr("slice-start.1", "async-start", 2, [1], [1]),
+            _instr("slice-done.1", "async-done", 3, [2]),
+            _instr("ar-start.1", "async-start", 4, [3], [2]),
+            _instr("ar-update.1", "async-update", 5, [4]),
+            _instr("ar-done.1", "async-done", 6, [5])], 6))
+        + _vi(6, 3))
+    buckets = {r["name"]: r["bucket"] for r in cost.instruction_costs(module)}
+    assert buckets == {"x": "noop", "slice-start.1": "layout",
+                       "slice-done.1": "layout", "ar-start.1": "comm",
+                       "ar-update.1": "comm", "ar-done.1": "comm"}
+    assert cost.HloModule(module).name == "jit_step"
