@@ -426,7 +426,12 @@ def dropout(ctx, ins, attrs):
             impl == "downgrade_in_infer"
         y = x * (1.0 - p) if scale_at_infer else x
         return {"Out": [y], "Mask": [jnp.ones_like(x)]}
-    keep = jax.random.bernoulli(ctx.rng(), 1.0 - p, x.shape)
+    # pinned: the generator (20 rounds of threefry an element) is
+    # cheap elementwise HLO, and unpinned XLA clones it into every
+    # fusion that reads the mask, the backward's too, rather than
+    # write one byte an element once (PERF.md, PR 25)
+    keep = jax.lax.optimization_barrier(
+        jax.random.bernoulli(ctx.rng(), 1.0 - p, x.shape))
     if impl == "upscale_in_train":
         y = jnp.where(keep, x / (1.0 - p), 0.0)
     else:

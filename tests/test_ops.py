@@ -4,10 +4,13 @@ Forward checks against numpy reference math; gradient checks analytic
 (jax AD) vs numeric finite differences via the OpTest harness.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from op_test import check_grad, check_output, run_op
+from paddle_tpu.core.registry import OpContext, get_op_impl
 
 rng = np.random.RandomState(42)
 
@@ -165,6 +168,98 @@ def test_dropout_test_mode_scales():
     got = run_op("dropout", {"X": x},
                  attrs={"dropout_prob": 0.3, "is_test": True})
     np.testing.assert_allclose(got, 0.7, rtol=1e-6)
+
+
+def _dropout(key, idx, x, **attrs):
+    outs = get_op_impl("dropout")(OpContext(key, idx),
+                                  {"X": [jnp.asarray(x)]}, attrs)
+    return outs["Out"][0], outs["Mask"][0]
+
+
+@pytest.mark.parametrize("impl", ["upscale_in_train", "downgrade_in_infer"])
+@pytest.mark.parametrize("p,idx,shape,dtype", [
+    (0.1, 7, (4, 16, 32), "float32"),
+    (0.5, 0, (8, 128), "bfloat16"),
+    (0.3, 31, (2, 2, 8, 8), "float32"),
+])
+def test_dropout_masks_are_jax_bernoulli_of_the_ops_key(impl, p, idx, shape,
+                                                        dtype):
+    """The mask is pinned (generated once), not changed: bit for bit
+    `bernoulli(fold_in(step key, op index), 1 - p)`, and `Out` is the
+    plain select on it."""
+    key = jax.random.PRNGKey(1234)
+    x = jnp.asarray(rng.randn(*shape), dtype)
+    got, mask = _dropout(key, idx, x, dropout_prob=p,
+                         dropout_implementation=impl)
+    keep = jax.random.bernoulli(jax.random.fold_in(key, idx), 1.0 - p,
+                                shape)
+    kept = x / (1.0 - p) if impl == "upscale_in_train" else x
+    want = jnp.where(keep, kept, 0.0).astype(dtype)
+    assert got.dtype == mask.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(mask.astype("float32")),
+                                  np.asarray(keep, np.float32))
+    np.testing.assert_array_equal(np.asarray(got.astype("float32")),
+                                  np.asarray(want.astype("float32")))
+    assert 0.0 < float(keep.mean()) < 1.0
+
+
+@pytest.mark.parametrize("impl,scale", [("upscale_in_train", 1.0 / 0.75),
+                                        ("downgrade_in_infer", 1.0)])
+def test_dropout_gradient_is_the_mask_times_the_cotangent(impl, scale):
+    key = jax.random.PRNGKey(5)
+    x = jnp.asarray(rng.randn(6, 40).astype(np.float32))
+    g = jnp.asarray(rng.randn(6, 40).astype(np.float32))
+    attrs = dict(dropout_prob=0.25, dropout_implementation=impl)
+    (out, mask), vjp = jax.vjp(lambda v: _dropout(key, 3, v, **attrs), x)
+    dx, = vjp((g, jnp.zeros_like(mask)))
+    want = jnp.where(mask.astype(bool), g * np.float32(scale), 0.0)
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(want),
+                               rtol=1e-6, atol=0)
+    np.testing.assert_array_equal(np.asarray(dx) == 0,
+                                  np.asarray(mask) == 0)
+
+
+@pytest.mark.parametrize("attrs,factor", [
+    (dict(dropout_prob=0.3, is_test=True), 0.7),
+    (dict(dropout_prob=0.3, is_test=True,
+          dropout_implementation="upscale_in_train"), 1.0),
+    (dict(dropout_prob=0.0), 1.0),
+    (dict(dropout_prob=0.0, dropout_implementation="upscale_in_train"), 1.0),
+])
+def test_dropout_without_a_mask_draws_nothing(attrs, factor):
+    """`is_test` and `p == 0` need no key at all, and give a mask of
+    ones."""
+    x = rng.randn(4, 4).astype(np.float32)
+    got, mask = _dropout(None, 0, x, **attrs)
+    np.testing.assert_allclose(np.asarray(got), x * np.float32(factor),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(mask), 1.0)
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+def test_dropout_under_checkpoint_recomputes_the_same_mask(jit):
+    """Inside a recompute segment the backward regenerates the mask
+    from the same key: the gradient it applies is the forward's."""
+    key = jax.random.PRNGKey(9)
+    x = jnp.asarray(rng.randn(8, 64).astype(np.float32))
+    attrs = dict(dropout_prob=0.4,
+                 dropout_implementation="upscale_in_train")
+
+    def loss(v, k):
+        out, mask = _dropout(k, 11, v * 2.0, **attrs)
+        return jnp.sum(out * out), mask
+
+    plain = jax.value_and_grad(loss, has_aux=True)
+    remat = jax.value_and_grad(jax.checkpoint(loss), has_aux=True)
+    if jit:
+        plain, remat = jax.jit(plain), jax.jit(remat)
+    (l0, m0), g0 = plain(x, key)
+    (l1, m1), g1 = remat(x, key)
+    np.testing.assert_array_equal(np.asarray(m0), np.asarray(m1))
+    np.testing.assert_array_equal(np.asarray(g0), np.asarray(g1))
+    assert float(l0) == float(l1)
+    # where the forward dropped, the recomputed backward drops too
+    np.testing.assert_array_equal(np.asarray(g1) == 0, np.asarray(m0) == 0)
 
 
 def test_sequence_pool_masks_padding():
