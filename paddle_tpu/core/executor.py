@@ -19,6 +19,7 @@ buffer liveness analysis subsumes it.
 from __future__ import annotations
 
 import functools
+import weakref
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -38,10 +39,15 @@ class Scope:
     device) or numpy arrays.
     """
 
+    # every Scope alive in the process: lets a reader that was handed
+    # no scope (observe/routing.py) find device-side counters
+    live: "weakref.WeakSet[Scope]" = weakref.WeakSet()
+
     def __init__(self, parent: Optional["Scope"] = None):
         self.parent = parent
         self.vars: Dict[str, Any] = {}
         self.kids: List["Scope"] = []
+        Scope.live.add(self)
 
     def new_scope(self) -> "Scope":
         kid = Scope(self)
@@ -428,6 +434,9 @@ def interpret_program(program: Program, env: Dict[str, Any], rng_key,
     fwd_keep = set(fetch_names) | persist | {loss_name}
     for op in rest_ops:
         fwd_keep.update(op.desc.input_names())
+    tracked = getattr(program, "_tracked_scalars", None)
+    if tracked and getattr(program, "_telemetry_enabled", False):
+        fwd_keep.update(tracked.values())
 
     # numerics observability (observe pillar 6): seed the per-step
     # finite bitmap BEFORE the forward closure captures env — every
@@ -594,7 +603,7 @@ def interpret_program(program: Program, env: Dict[str, Any], rng_key,
         if _obs_metrics.TELEMETRY_VAR in env:
             env[_obs_metrics.TELEMETRY_VAR] = _obs_metrics.device_update(
                 env[_obs_metrics.TELEMETRY_VAR], loss_val, grads,
-                trainable, env)
+                trainable, env, tracked=tracked)
             if finite is not None:
                 from ..resilience import guard as _guard
 

@@ -131,6 +131,107 @@ def switch_moe(input, num_experts, d_inner, top_k=1,
     return out_v, aux, frac
 
 
+def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
+                 param_attr=None, name=None):
+    """Dropless routed SwiGLU experts (ops/moe_dropless.py): every
+    token goes to its `top_k` of `num_experts` experts, none is
+    dropped, shapes are static.  Returns (out, aux_loss, z_loss,
+    counts, experts): the load-balancing and router z losses are (1,)
+    float32 for the objective; `counts` (E,) is this step's rows per
+    expert and `experts` (tokens, top_k) each token's choice.
+    The layer also keeps `<moe_expert...>.token_count`, int32 (E,)
+    persistable state the op adds `counts` to on the device
+    (observe/routing.py reads it).
+
+    Parameter names keep the `moe_gate` / `moe_expert` prefixes (the ep
+    sharding rules and the numerics groups key on them): `.w_0` the
+    gate projection W1 (E, D, H), `.w_1` the down projection W2
+    (E, H, D), `.w_2` the up projection W3 (E, D, H)."""
+    if isinstance(param_attr, ParamAttr) and param_attr.name:
+        raise ValueError(
+            "dropless_moe: a NAMED ParamAttr cannot apply to its four "
+            "parameters; use name= to tell layers apart")
+    d = int(input.shape[-1])
+    dtype = input.dtype
+    gate_h = LayerHelper("moe_gate", name=name and f"moe_gate_{name}")
+    gate_w = gate_h.create_parameter(param_attr, shape=[d, num_experts],
+                                     dtype=dtype)
+    eh = LayerHelper("moe_expert", name=name and f"moe_expert_{name}")
+
+    def expert_weight(fan_in, fan_out):
+        # per-expert fans, as switch_moe: the rank-3 default would
+        # read (E, D, H) as a conv kernel
+        return eh.create_parameter(
+            param_attr, shape=[num_experts, fan_in, fan_out], dtype=dtype,
+            default_initializer=Xavier(fan_in=fan_in, fan_out=fan_out))
+
+    w1 = expert_weight(d, d_inner)
+    w2 = expert_weight(d_inner, d)
+    w3 = expert_weight(d, d_inner)
+    total = eh.create_or_get_global_variable(
+        f"{w1.name}.token_count", [num_experts], "int32")
+    out_v = eh.create_variable_for_type_inference(dtype)
+    aux = eh.create_variable_for_type_inference("float32")
+    z = eh.create_variable_for_type_inference("float32")
+    counts = eh.create_variable_for_type_inference("int32")
+    experts = eh.create_variable_for_type_inference("int32")
+    for v in (counts, experts, total):
+        v.desc.stop_gradient = True
+    eh.append_op(
+        type="moe_dropless",
+        inputs={"X": [input], "GateW": [gate_w], "W1": [w1], "W3": [w3],
+                "W2": [w2], "TokenCount": [total]},
+        outputs={"Out": [out_v], "AuxLoss": [aux], "ZLoss": [z],
+                 "Counts": [counts], "Experts": [experts],
+                 "TokenCountOut": [total]},
+        attrs={"top_k": int(top_k),
+               "norm_topk_prob": bool(norm_topk_prob)})
+    out_v.desc.shape = tuple(input.shape)
+    aux.desc.shape = z.desc.shape = (1,)
+    counts.desc.shape = (num_experts,)
+    return out_v, aux, z, counts, experts
+
+
+def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
+             name=None):
+    """Root-mean-square norm over the axes from `begin_norm_axis`, with
+    a learned scale initialised to 1 (no shift, no mean subtraction)."""
+    helper = LayerHelper("rms_norm", name=name)
+    begin = begin_norm_axis % len(input.shape)
+    scale = helper.create_parameter(
+        param_attr, shape=[int(np.prod(input.shape[begin:]))],
+        dtype=input.dtype, default_initializer=Constant(1.0))
+    y = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="rms_norm",
+                     inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [y]},
+                     attrs={"begin_norm_axis": begin, "epsilon": epsilon})
+    return y
+
+
+def rope(input, n_head, theta=10000.0, offset=None, name=None):
+    """Rotate-half rotary position embedding of a head-grouped
+    (N, T, n_head * D) projection (ops/decoder.py).  `offset`: a (1,)
+    integer variable, the position of the first row (0 if None)."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    ins = {"X": [input]}
+    if offset is not None:
+        ins["Offset"] = [offset]
+    helper.append_op(type="rope", inputs=ins, outputs={"Out": [out]},
+                     attrs={"n_head": int(n_head), "theta": float(theta)})
+    return out
+
+
+def swiglu(x, y, name=None):
+    """silu(x) * y: the gate of a SwiGLU feed-forward layer."""
+    helper = LayerHelper("swiglu", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="swiglu", inputs={"X": [x], "Y": [y]},
+                     outputs={"Out": [out]})
+    return out
+
+
 def embedding(input, size, is_sparse=False, is_distributed=False,
               padding_idx=None, param_attr=None, dtype="float32"):
     """reference layers/nn.py embedding → lookup_table op.  is_sparse /

@@ -8,6 +8,7 @@ and returning the interesting vars.
 """
 
 from . import bert  # noqa: F401
+from . import decoder  # noqa: F401
 from . import deepfm  # noqa: F401
 from . import mnist  # noqa: F401
 from . import resnet  # noqa: F401

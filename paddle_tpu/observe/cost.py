@@ -52,6 +52,7 @@ HloModuleProto (read with trace.py's dependency-free wire scanner):
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
@@ -513,6 +514,41 @@ def _registry_cost(kernel: str, instr: Instr, operands: List[Instr]):
     return fn(op_shapes, res_shapes)
 
 
+# XLA's own grouped matmul.  The TPU compiler lowers `ragged-dot`
+# (jax.lax.ragged_dot and its two transposes) to Mosaic kernels of its
+# own and REPLACES the instruction's op_name with "ragged-dot-<mode>":
+# the fluid scope is gone, the custom call reports zero flops.
+_XLA_RAGGED_DOT = "ragged-dot"
+RAGGED_DOT_KERNEL = "ragged_dot"
+
+
+def _xla_kernel_of(op_name: str) -> Optional[str]:
+    """Name of a Mosaic kernel the TPU compiler wrote itself, from the
+    op_name it stamped on the custom call: `ragged_dot`, or
+    `ragged_dot_metadata` for its helper (group offsets, no matmul)."""
+    if not (op_name or "").startswith(_XLA_RAGGED_DOT):
+        return None
+    return (RAGGED_DOT_KERNEL + "_metadata" if "metadata" in op_name
+            else RAGGED_DOT_KERNEL)
+
+
+def ragged_dot_cost(operand_shapes, result_shapes):
+    """(flops, bytes) of one grouped matmul over M sorted rows in G
+    groups: 2*M*K*N whatever the routing, because a row meets one
+    group's (K, N) weight, never all G (counting G x dense would put
+    a routed-expert layer past the chip's peak).  The matrices are the
+    last two operands (tiling metadata comes first): `(M,K) x (G,K,N)
+    -> (M,N)` forward and for dX, `(M,K) x (M,N) -> (G,K,N)` for dW."""
+    (lhs, lhs_b), (rhs, rhs_b) = operand_shapes[-2:]
+    out, out_b = result_shapes[0]
+    if len(out) == 3:                   # dW: the rows are contracted
+        flops = 2.0 * lhs[0] * lhs[1] * rhs[1]
+    else:                               # rows kept: out is (M, N)
+        flops = 2.0 * out[0] * out[1] * lhs[1]
+    return flops, float(math.prod(lhs) * lhs_b + math.prod(rhs) * rhs_b
+                        + math.prod(out) * out_b)
+
+
 # --------------------------------------------------------------------------
 # per-instruction cost rows + bucketing
 # --------------------------------------------------------------------------
@@ -583,6 +619,9 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
     Row keys: name, opcode, op_type (fluid attribution or None),
     bucket, flops, transcendentals, bytes, pallas_kernel (set when a
     registered Pallas kernel's cost was injected at a custom call),
+    kernel (every `tpu_custom_call` row: the Pallas kernel's `name`
+    from its `pallas_<name>` scope, registered or not, or
+    `ragged_dot` for the compiler's own grouped matmul; else None),
     trip_count (while rows: the recovered loop trip count, already
     multiplied into flops; None = unrecoverable, body counted once and
     bucketed "[loop?]").
@@ -627,6 +666,7 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
             "transcendentals": transc,
             "bytes": float(nbytes),
             "pallas_kernel": None,
+            "kernel": None,
         }
         if instr.opcode == "while":
             row["trip_count"] = while_trip_count(module, entry, instr)
@@ -641,6 +681,15 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
                     row["flops"] = float(kflops)
                     if kbytes is not None:
                         row["bytes"] = float(kbytes)
+            else:
+                kernel = _xla_kernel_of(instr.op_name)
+            if kernel == RAGGED_DOT_KERNEL:
+                row["flops"], row["bytes"] = ragged_dot_cost(
+                    [(tuple(o.shape.dims), o.shape.elem_bytes)
+                     for o in operands],
+                    [(tuple(instr.shape.dims), instr.shape.elem_bytes)])
+            if instr.custom_call_target == "tpu_custom_call":
+                row["kernel"] = kernel
         rows.append(row)
     return rows
 
@@ -651,14 +700,17 @@ def total_costs(proto: bytes) -> Dict[str, Any]:
     flops = analytic flops INCLUDING injected Pallas registry costs;
     `pallas_flops` is the injected share, `custom_calls` /
     `pallas_matched` make an unmatched (uncounted) KERNEL visible
-    instead of silently reading as zero flops.  Only Mosaic kernels
+    instead of silently reading as zero flops (the compiler's own
+    grouped matmul and its metadata helper count as matched: their
+    cost is `ragged_dot_cost`).  Only Mosaic kernels
     (`tpu_custom_call`) count: the TPU compiler also emits zero-flop
     bookkeeping custom calls of its own (ConcatBitcast,
     AssumeGatherIndicesInBound, ...), which no registry could cover."""
     rows = instruction_costs(proto)
     custom = [r for r in rows
               if r.get("custom_call_target") == "tpu_custom_call"]
-    matched = [r for r in custom if r["pallas_kernel"]]
+    matched = [r for r in custom if r["pallas_kernel"]
+               or (r["kernel"] or "").startswith(RAGGED_DOT_KERNEL)]
     return {
         "flops": sum(r["flops"] for r in rows),
         "transcendentals": sum(r["transcendentals"] for r in rows),

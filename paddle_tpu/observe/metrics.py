@@ -38,6 +38,23 @@ _I32_FIELDS = ("steps", "nonfinite_grad_steps", "nonfinite_loss_steps",
 _PERSISTENT_FIELDS = ("loss_scale", "ls_good_steps", "ls_bad_steps")
 
 
+# scalars a model asks to see beside the loss (`track_scalars`): two
+# accumulator fields each, "<prefix><name>.sum" and "<prefix><name>.last"
+SCALAR_PREFIX = "scalar."
+
+
+def track_scalars(program, **variables) -> None:
+    """Name (1,)-shaped variables of `program` (an auxiliary loss, a
+    router z-loss) whose value the telemetry accumulator sums and
+    latches each step, beside the loss: `fetch_telemetry(...).scalars`
+    then holds `{name: {"last", "mean"}}`.  Costs nothing until the
+    program opts into telemetry."""
+    tracked = dict(getattr(program, "_tracked_scalars", None) or {})
+    tracked.update({name: var.name for name, var in variables.items()})
+    program._tracked_scalars = tracked
+    program._bump()
+
+
 def enable_telemetry(program) -> None:
     """Opt a Program's compiled step into device-side telemetry.  Must
     be set before the Executor builds/caches the step fn for this
@@ -71,6 +88,9 @@ def init_telemetry_for(program) -> Dict[str, Any]:
     guard_cfg = getattr(program, "_update_guard", None)
     out = init_telemetry(loss_scale=guard_cfg.init_loss_scale
                          if guard_cfg is not None else 1.0)
+    for name in getattr(program, "_tracked_scalars", None) or ():
+        out[f"{SCALAR_PREFIX}{name}.sum"] = np.float32(0.0)
+        out[f"{SCALAR_PREFIX}{name}.last"] = np.float32(0.0)
     if getattr(program, "_numerics_enabled", False):
         from . import numerics as _numerics
 
@@ -101,11 +121,14 @@ def ensure_numerics_fields(program, tel: Dict[str, Any]) -> Dict[str, Any]:
 
 def device_update(tel: Dict[str, Any], loss, grads: Dict[str, Any],
                   params_before: Dict[str, Any],
-                  env: Dict[str, Any]) -> Dict[str, Any]:
+                  env: Dict[str, Any],
+                  tracked: Optional[Dict[str, str]] = None
+                  ) -> Dict[str, Any]:
     """One step's accumulation — runs INSIDE the jit trace (pure, no
     callbacks).  grads may contain SparseGrad pytrees (their touched
     rows carry the whole gradient mass, so the norm over rows is the
-    true table-grad norm up to duplicate-id merging)."""
+    true table-grad norm up to duplicate-id merging).  `tracked`:
+    `{name: variable name}` of `track_scalars`, read from `env`."""
     import jax.numpy as jnp
 
     from ..core.selected_rows import SparseGrad
@@ -143,6 +166,12 @@ def device_update(tel: Dict[str, Any], loss, grads: Dict[str, Any],
         + (nonfinite > 0).astype(jnp.int32),
         "nonfinite_loss_steps": tel["nonfinite_loss_steps"] + loss_bad,
     })
+    for name, var in (tracked or {}).items():
+        key = f"{SCALAR_PREFIX}{name}"
+        if var in env and key + ".sum" in tel:
+            v = jnp.asarray(env[var]).astype(jnp.float32).reshape(())
+            out[key + ".sum"] = tel[key + ".sum"] + v
+            out[key + ".last"] = v
     return out
 
 
@@ -166,6 +195,8 @@ class StepTelemetry:
     # did not opt in): per-group dynamics + first-nonfinite provenance
     groups: Optional[Dict[str, Dict[str, float]]] = None
     first_nonfinite_op: Optional[Dict[str, Any]] = None
+    # `track_scalars` values: {name: {"last", "mean"}} (None: none)
+    scalars: Optional[Dict[str, Dict[str, float]]] = None
 
     def as_dict(self) -> Dict[str, Any]:
         out = {
@@ -185,6 +216,8 @@ class StepTelemetry:
             out["groups"] = self.groups
         if self.first_nonfinite_op is not None:
             out["first_nonfinite_op"] = self.first_nonfinite_op
+        if self.scalars is not None:
+            out["scalars"] = self.scalars
         return out
 
     @property
@@ -232,6 +265,11 @@ def fetch_telemetry(scope, reset: bool = True,
             first = _numerics.join_first_nonfinite(
                 host[_numerics.NONFINITE_WORDS], program=program)
     n = max(int(host["steps"]), 1)
+    scalars = {
+        k[len(SCALAR_PREFIX):-len(".sum")]: {
+            "last": host[k[:-len(".sum")] + ".last"], "mean": v / n}
+        for k, v in host.items()
+        if k.startswith(SCALAR_PREFIX) and k.endswith(".sum")}
     return StepTelemetry(
         steps=int(host["steps"]),
         loss_last=host["loss_last"],
@@ -246,4 +284,5 @@ def fetch_telemetry(scope, reset: bool = True,
         loss_scale=float(host.get("loss_scale", 1.0)),
         groups=groups,
         first_nonfinite_op=first,
+        scalars=scalars or None,
     )

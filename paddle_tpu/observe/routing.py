@@ -1,0 +1,48 @@
+"""Expert routing counters: what the dropless routed-expert op
+(`ops/moe_dropless.py`) counts on the device.
+
+Each `layers.dropless_moe` keeps `<moe_expert...>.token_count`, an
+int32 (E,) persistable variable that its op adds the step's rows per
+expert to INSIDE the jitted step: no fetch, no callback, nothing on
+the host until someone reads the scope.  Reading is one device-to-host
+copy of E numbers per layer, made when the reader chooses (after a
+benchmark window, every N steps of a trainer), never by the step.
+The sum wraps after 2^31 rows to one expert: a trainer that runs that
+long reads and resets.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional
+
+import numpy as np
+
+TOKEN_COUNT_SUFFIX = ".token_count"
+
+
+def expert_token_counts(scope=None, reset: bool = False
+                        ) -> Dict[str, np.ndarray]:
+    """`{variable name: (E,) int64 rows routed to each expert so far}`
+    for every routed-expert layer whose state lives in `scope`; with no
+    scope, in any Scope alive in the process (a benchmark reader is
+    handed none).  `reset` zeroes what was read."""
+    from ..core.executor import Scope
+
+    out: Dict[str, np.ndarray] = {}
+    for s in ([scope] if scope is not None else list(Scope.live)):
+        for name in s.local_var_names():
+            value = s.vars[name]
+            if name.endswith(TOKEN_COUNT_SUFFIX) and value is not None:
+                out[name] = np.asarray(value).astype(np.int64)
+                if reset:
+                    s.set_var(name, np.zeros(out[name].shape, np.int32))
+    return out
+
+
+def load_max_over_mean(counts) -> Optional[float]:
+    """The fullest expert's rows over the mean expert's, over all the
+    layers of `counts` (as `expert_token_counts` gives them): 1.0 is a
+    perfectly even router, E everything to one expert.  None before
+    any token was routed."""
+    ratios = [c.max() / c.mean() for c in counts.values() if c.sum() > 0]
+    return float(np.mean(ratios)) if ratios else None

@@ -184,10 +184,11 @@ def instruction_name(event_name: str) -> str:
 
 
 def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
-    """{instruction name: {op_name, bucket, flops, bytes}} of one
-    serialized HloProto: every computation's instructions with their
-    `metadata.op_name`, and for the entry computation's the bucket,
-    FLOPs and bytes of `cost.instruction_costs` (elsewhere None)."""
+    """{instruction name: {op_name, bucket, flops, bytes, kernel}} of
+    one serialized HloProto: every computation's instructions with
+    their `metadata.op_name`, and for the entry computation's the
+    bucket, FLOPs, bytes and Mosaic kernel name of
+    `cost.instruction_costs` (elsewhere None)."""
     from . import cost
 
     module = cost.HloModule(proto)
@@ -195,10 +196,11 @@ def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
     for comp in module.computations.values():
         for instr in comp.instructions:
             out[instr.name] = {"op_name": instr.op_name, "bucket": None,
-                               "flops": None, "bytes": None}
+                               "flops": None, "bytes": None,
+                               "kernel": None}
     for row in cost.instruction_costs(module):
         out[row["name"]].update(bucket=row["bucket"], flops=row["flops"],
-                                bytes=row["bytes"])
+                                bytes=row["bytes"], kernel=row["kernel"])
     return out
 
 
@@ -237,8 +239,9 @@ def join_events(ops, modules, programs, window=None, chip=0
 
     One row per (module, instruction): chip, module, instruction,
     op_name, op_type (fluid), phase, bucket, flops and bytes (per
-    call), joined (found in its program's map), calls, self_s,
-    total_s, max_s, min_s (of one call's self time).
+    call), kernel (a Mosaic kernel's name, else None), joined (found
+    in its program's map), calls, self_s, total_s, max_s, min_s (of
+    one call's self time).
     """
     modules = sorted(modules, key=lambda e: e[1])
     starts = [m[1] for m in modules]
@@ -273,6 +276,7 @@ def join_events(ops, modules, programs, window=None, chip=0
                            else info["bucket"] or BODY_BUCKET),
                 "flops": info["flops"] if info else None,
                 "bytes": info["bytes"] if info else None,
+                "kernel": info.get("kernel") if info else None,
                 "joined": info is not None,
                 "calls": 0, "self_s": 0.0, "total_s": 0.0,
                 "max_s": 0.0, "min_s": float("inf")}

@@ -9,9 +9,11 @@ from ..core.registry import register_op, registered_ops  # noqa: F401
 from . import attention  # noqa: F401
 from . import basic  # noqa: F401
 from . import control_flow  # noqa: F401
+from . import decoder  # noqa: F401
 from . import detection  # noqa: F401
 from . import misc  # noqa: F401
 from . import moe  # noqa: F401
+from . import moe_dropless  # noqa: F401
 from . import nn  # noqa: F401
 from . import optim  # noqa: F401
 from . import paged_kv  # noqa: F401

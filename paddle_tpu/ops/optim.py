@@ -83,6 +83,10 @@ def adam(ctx, ins, attrs):
     beta1 = attrs.get("beta1", 0.9)
     beta2 = attrs.get("beta2", 0.999)
     eps = attrs.get("epsilon", 1e-8)
+    # decoupled weight decay (AdamW, Loshchilov & Hutter 2019): the
+    # parameter shrinks by lr * weight_decay of itself, outside the
+    # moments; 0 (the default) leaves the trace as it was
+    decay = attrs.get("weight_decay", 0.0)
     lr = _lr(ins) * jnp.sqrt(1 - b2p) / (1 - b1p)
     beta_pows = {"Beta1PowOut": [(b1p * beta1).reshape((1,))],
                  "Beta2PowOut": [(b2p * beta2).reshape((1,))]}
@@ -94,6 +98,8 @@ def adam(ctx, ins, attrs):
         m1r = beta1 * m1[ids] + (1 - beta1) * rows
         m2r = beta2 * m2[ids] + (1 - beta2) * jnp.square(rows)
         p_delta = -lr * m1r / (jnp.sqrt(m2r) + eps)
+        if decay:
+            p_delta = p_delta - _lr(ins) * decay * p[ids]   # touched rows
         keep = valid[:, None]
         m1n = m1.at[ids].add(jnp.where(keep, m1r - m1[ids], 0.0))
         m2n = m2.at[ids].add(jnp.where(keep, m2r - m2[ids], 0.0))
@@ -103,6 +109,8 @@ def adam(ctx, ins, attrs):
     m1n = beta1 * m1 + (1 - beta1) * g
     m2n = beta2 * m2 + (1 - beta2) * jnp.square(g)
     p_new = p - lr * m1n / (jnp.sqrt(m2n) + eps)
+    if decay:
+        p_new = p_new - _lr(ins) * decay * p
     return {
         "ParamOut": [p_new], "Moment1Out": [m1n], "Moment2Out": [m2n],
         **beta_pows,

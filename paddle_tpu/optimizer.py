@@ -167,11 +167,16 @@ class LarsMomentumOptimizer(Optimizer):
 
 
 class AdamOptimizer(Optimizer):
+    """Adam; with `weight_decay` > 0, AdamW: each step also shrinks
+    every parameter by learning_rate * weight_decay of itself,
+    decoupled from the gradient and the moments."""
+
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, regularization=None, name=None,
-                 lazy_mode=False):
+                 lazy_mode=False, weight_decay=0.0):
         super().__init__(learning_rate, regularization, name)
         self._beta1, self._beta2, self._epsilon = beta1, beta2, epsilon
+        self._weight_decay = float(weight_decay)
 
     def _create_accumulators(self, block, param):
         self._add_accumulator("moment1", param)
@@ -194,7 +199,8 @@ class AdamOptimizer(Optimizer):
                      "Moment2Out": [m2], "Beta1PowOut": [b1p],
                      "Beta2PowOut": [b2p]},
             attrs={"beta1": self._beta1, "beta2": self._beta2,
-                   "epsilon": self._epsilon})
+                   "epsilon": self._epsilon,
+                   "weight_decay": self._weight_decay})
 
 
 class AdamaxOptimizer(Optimizer):

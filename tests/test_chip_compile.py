@@ -350,3 +350,65 @@ def test_dropout_mask_is_generated_once_and_outside_the_dots(one_chip, loss,
                    for i in holders[0].instructions)
     assert " convolution(" in compiled.as_text()    # the dots are there
     assert list(reader.rng_instructions(module).values()) == [1]
+
+
+def test_olmoe_step_kernels_at_the_published_shapes(one_chip):
+    """What `olmoe-4k`'s step hands the chip's compiler that no other
+    cell does, at OLMoE-1B-7B's widths (4 x 4096 tokens, 16 heads of
+    128, 64 experts of 2048 x 1024, 8 a token): the causal head-major
+    flash call with no bias, forward and backward, and the dropless
+    expert op, whose nine ragged dots the TPU compiler lowers to
+    Mosaic grouped matmuls of its own, static shapes whatever the
+    routing.  `observe.cost` must name every kernel and count T*k rows
+    of work for a grouped matmul, never E x dense."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.ops.pallas.flash_attention import \
+        pallas_flash_attention
+
+    n, t, heads, d, e, h, k = 4, 4096, 16, 128, 64, 1024, 8
+    hidden = heads * d
+
+    def attention(q, k_, v):
+        with jax.named_scope("flash_attention:9"):
+            o = pallas_flash_attention(q, k_, v, None, d ** -0.5, True,
+                                       layout="nthd", n_head=heads)
+        return jnp.sum(o.astype(F32))
+
+    compiled = _compile_args(
+        jax.jit(jax.grad(attention, argnums=(0, 1, 2))),
+        *[jax.ShapeDtypeStruct((n, t, hidden), BF16, sharding=one_chip)] * 3)
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+    assert {r["op_type"] for r in rows if r["kernel"]} == {
+        "flash_attention"}
+
+    impl = get_op_impl("moe_dropless")
+
+    def experts(x, gate, w1, w3, w2):
+        with jax.named_scope("moe_dropless:12"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"X": [x], "GateW": [gate], "W1": [w1], "W3": [w3],
+                      "W2": [w2]}, {"top_k": k})
+        return (jnp.sum(o["Out"][0].astype(F32)) + o["AuxLoss"][0][0]
+                + o["ZLoss"][0][0])
+
+    shapes = [(n, t, hidden), (hidden, e), (e, hidden, h), (e, hidden, h),
+              (e, h, hidden)]
+    compiled = _compile_args(
+        jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3, 4))),
+        *[jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+          for s in shapes])
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    matmuls = [r for r in rows if r["kernel"] == "ragged_dot"]
+    assert len(matmuls) == 9            # 3 forward, 3 dX, 3 dW
+    per_matmul = 2.0 * n * t * k * hidden * h
+    assert {r["flops"] for r in matmuls} == {per_matmul}
+    assert {r["bucket"] for r in matmuls} == {"custom_call"}
+    assert not any(r["bucket"] == "matmul" and r["flops"] > per_matmul
+                   for r in rows)       # nothing E x dense beside them
+    totals = cost.total_costs(cost.compiled_hlo_proto(compiled))
+    assert totals["custom_calls"] == totals["pallas_matched"] >= 9
+    # the routing never reaches a shape: no dynamic dimension anywhere
+    assert "<=" not in compiled.as_text().split("ENTRY")[1].split("\n")[0]

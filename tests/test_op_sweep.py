@@ -978,6 +978,24 @@ S["moe_ffn"] = dict(
     attrs={"top_k": 1, "capacity_factor": 2.0}, out="Out",
     shape=(6, 8), grad=["X", "GateW", "W1", "W2"], tol=2e-2)
 
+# -- decoder block (ops/decoder.py, ops/moe_dropless.py) ---------------------
+S["rms_norm"] = dict(
+    ins={"X": f32((2, 6), 268), "Scale": pos((6,), 269)},
+    attrs={"begin_norm_axis": 1}, out="Y", shape=(2, 6),
+    grad=["X", "Scale"], tol=2e-2)
+S["rope"] = dict(ins={"X": f32((1, 3, 8), 270)}, attrs={"n_head": 2},
+                 out="Out", shape=(1, 3, 8), grad=["X"])
+S["swiglu"] = dict(ins={"X": f32((2, 5), 271), "Y": f32((2, 5), 272)},
+                   out="Out", shape=(2, 5), grad=["X", "Y"])
+S["moe_dropless"] = dict(
+    ins={"X": f32((6, 8), 273), "GateW": f32((8, 4), 274),
+         "W1": f32((4, 8, 6), 275), "W3": f32((4, 8, 6), 276),
+         "W2": f32((4, 6, 8), 277)},
+    attrs={"top_k": 2}, out="Out", shape=(6, 8),
+    nondiff="jax.lax.ragged_dot has no batching rule for the vmapped "
+            "finite differences: every input's gradient is checked "
+            "against the dense form in test_decoder_ops.py")
+
 # Ops exempt from the sweep, each with the reason it cannot be driven
 # through the plain run_op harness.
 #
