@@ -6,12 +6,14 @@ calls (`import paddle_tpu as fluid`; Program/program_guard -> layers ->
 optimizer.minimize -> Executor.run; DecodeEngine.submit), at the full
 width of the models bench.py measures, with random weights from a seed:
 
-    python chip_smoke.py             one chip: train_transformer,
+    python chip_smoke.py             one chip: dropout_mask,
+                                     train_transformer,
                                      train_resnet50, serve_decode
-    python chip_smoke.py --chips 4   the four-chip host: the same
-                                     Transformer on one device, then on
-                                     dp=4 and on dp=2 x mp=2, and
-                                     nothing else
+    python chip_smoke.py --chips 4   the four-chip host: dropout_mask
+                                     over dp=4, then the same
+                                     Transformer on one device and on
+                                     dp=4, on one device and on dp=2 x
+                                     mp=2, and nothing else
 
 One process, JAX touched once, no children.  Every phase prints one
 JSON line (compile seconds, run seconds, first/last loss or tokens
@@ -27,6 +29,8 @@ non-zero exit; nothing is caught and carried past.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import functools
 import gc
 import json
 import sys
@@ -107,10 +111,12 @@ def require_tpu(count):
 # training phases
 # --------------------------------------------------------------------------
 
-def _train(phase, build, feed, mesh_axes=None, inspect=None):
+def _train(phase, build, feed, mesh_axes=None, inspect=None,
+           masks=(0, 0)):
     """Build under a fresh Program pair and run STEPS Executor steps
     on one fixed batch; the loss must be finite and fall, and no step
-    after the first may compile.
+    after the first may compile.  `masks`: the `dropout` ops the step's
+    build must count as drawn by (the Pallas kernel, jax.random).
     `inspect(main, scope, loss, feed)` runs last, inside the guards;
     its dict joins the phase line.  Returns (per-step losses, that
     dict)."""
@@ -149,6 +155,10 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None):
         extra = inspect(main, scope, loss, feed) if inspect else {}
     assert np.isfinite(losses).all(), (phase, losses)
     assert late == 0, f"{phase}: {late} compile(s) after the first step"
+    drawn = (cold["dropout_masks_kernel"], cold["dropout_masks_xla"])
+    assert drawn == masks, \
+        f"{phase}: dropout masks by (kernel, jax.random) {drawn}, " \
+        f"expected {masks}"
     assert losses[-1] < losses[0], \
         f"{phase}: loss did not fall over {STEPS} steps: {losses}"
     emit(phase, steps=STEPS, compiles=cold["compiles"],
@@ -156,17 +166,22 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None):
          first_step_s=round(first_s, 2), run_s=round(run_s, 3),
          step_ms=round(1e3 * run_s / (STEPS - 1), 2),
          first_loss=losses[0], last_loss=losses[-1],
+         dropout_masks_kernel=drawn[0], dropout_masks_xla=drawn[1],
          peak_bytes=peak_bytes(), **extra)
     del exe, scope, main, startup
     gc.collect()
     return losses, extra
 
 
-def _transformer_build():
+# `dropout` ops in the Transformer's step
+TRANSFORMER_MASKS = 38
+
+
+def _transformer_build(**changed):
     from paddle_tpu.models import transformer
 
-    return transformer.build_model(warmup_steps=TRANSFORMER_WARMUP,
-                                   **TRANSFORMER)
+    return transformer.build_model(**{
+        "warmup_steps": TRANSFORMER_WARMUP, **TRANSFORMER, **changed})
 
 
 def _transformer_feed():
@@ -178,7 +193,8 @@ def _transformer_feed():
 
 
 def train_transformer():
-    _train("train_transformer", _transformer_build, _transformer_feed())
+    _train("train_transformer", _transformer_build, _transformer_feed(),
+           masks=(TRANSFORMER_MASKS, 0))
 
 
 def train_resnet50():
@@ -194,6 +210,126 @@ def train_resnet50():
                                       class_dim=1000, learning_rate=0.01,
                                       use_amp=True),
            feed)
+
+
+# --------------------------------------------------------------------------
+# the dropout keep-mask kernel (ops/pallas/dropout_mask.py)
+# --------------------------------------------------------------------------
+
+# the Transformer step's two mask shapes: residual / embedding dropout
+# and the decoder's cross-attention weights
+MASK_SHAPES = ((64, 256, 512), (64, 8, 256, 256))
+SIGMAS = 4.0
+
+
+def _within(name, got, want, n):
+    """`got`, a share of `n` independent positions, within SIGMAS
+    standard deviations of `want`."""
+    sigma = (want * (1.0 - want) / n) ** 0.5
+    assert abs(got - want) <= SIGMAS * sigma, \
+        f"dropout_mask: {name} {got!r} is {abs(got - want) / sigma:.1f} " \
+        f"sigma from {want} (n {n})"
+    return round((got - want) / sigma, 2)
+
+
+def dropout_mask(mesh_axes=None):
+    """The `dropout` op on this backend, at the step's two mask shapes:
+    the masks must come from the kernel, keep 1 - p of the positions,
+    be a function of the key alone, and be independent between ops,
+    steps, adjacent blocks, neighbouring rows and columns at the
+    tilings' periods, and (on a mesh) ranks; the gradient is the mask
+    over 1 - p exactly.  A share of n positions may lie SIGMAS standard
+    deviations from its expectation."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas.dropout_mask import tiling
+    from paddle_tpu.parallel import make_mesh
+    from paddle_tpu.parallel.mesh import executing_mesh
+
+    ranks = mesh_axes["dp"] if mesh_axes else 1
+    mesh = make_mesh(mesh_axes) if mesh_axes else None
+
+    def op(key, op_index, x, p):
+        return get_op_impl("dropout")(
+            OpContext(key, op_index), {"X": [x]},
+            {"dropout_prob": p,
+             "dropout_implementation": "upscale_in_train"})
+
+    def masks(keys, x, p):
+        """Masks (as the op's `Mask` output, 1 = keep) of: step 0 op 3,
+        the same again, step 0 op 4, step 1 op 3; and d sum(Out) / dx
+        of the first."""
+        with (executing_mesh(mesh, "dp") if mesh is not None
+              else contextlib.nullcontext()):
+            out = [op(keys[0], 3, x, p)["Mask"][0],
+                   op(keys[0], 3, x, p)["Mask"][0],
+                   op(keys[0], 4, x, p)["Mask"][0],
+                   op(keys[1], 3, x, p)["Mask"][0]]
+            grad = jax.grad(lambda v: jnp.sum(
+                op(keys[0], 3, v, p)["Out"][0]))(x)
+        return out, grad
+
+    def stats(keys, x, p, b, chunk):
+        (a, again, other_op, other_step), grad = masks(keys, x, p)
+        m = a.reshape(-1, a.shape[-1]) > 0
+        blocks = m.reshape(-1, b, m.shape[-1])
+        per_rank = m.reshape(ranks, -1)
+        share = lambda v: jnp.mean(v.astype(jnp.float32))  # noqa: E731
+        return {
+            "keep": share(m),
+            "same_key_equal": jnp.all(a == again),
+            "op_diff": share(a != other_op),
+            "step_diff": share(a != other_step),
+            "block_diff": share(blocks[0] != blocks[1]),
+            "rank_diff": (share(per_rank[0] != per_rank[1])
+                          if ranks > 1 else jnp.float32(0)),
+            # the periods of the tilings: a sublane, a 32-bit tile, an
+            # 8-bit tile, the kernel's inner-loop trip; a lane, a lane
+            # tile
+            **{f"row_lag{k}_diff": share(m[k:] != m[:-k])
+               for k in (1, 8, 32, chunk)},
+            **{f"col_lag{k}_diff": share(m[:, k:] != m[:, :-k])
+               for k in (1, 128)},
+            "constant_rows": jnp.sum(jnp.all(m, 1) | ~jnp.any(m, 1)),
+            "constant_block_cols": jnp.sum(jnp.all(blocks, 1)
+                                           | ~jnp.any(blocks, 1)),
+            "grad_is_mask_over_keep": jnp.all(
+                grad == a * jnp.float32(1.0 / (1.0 - p))),
+        }
+
+    for shape, p in [(s, 0.1) for s in MASK_SHAPES] + [(MASK_SHAPES[0],
+                                                        0.5)]:
+        shape = (shape[0] * ranks,) + shape[1:]
+        local_rows = int(np.prod(shape[:-1])) // ranks
+        b, chunk = tiling(local_rows, shape[-1])
+        assert local_rows // b >= 2, (shape, b)
+        n = int(np.prod(shape))
+        keys = jax.random.split(jax.random.PRNGKey(20270927 + n), 2)
+        x = jnp.ones(shape, jnp.float32)
+        if mesh is not None:
+            x = jax.device_put(x, NamedSharding(mesh, P("dp")))
+        snap = runtime_stats.snapshot()
+        got = jax.jit(stats, static_argnums=(2, 3, 4))(keys, x, p, b, chunk)
+        drawn = runtime_stats.delta(snap)
+        got = {k: np.asarray(v).item() for k, v in got.items()}
+        assert (drawn["dropout_masks_kernel"], drawn["dropout_masks_xla"]) \
+            == (5, 0), drawn
+        z = {"keep": _within("keep rate", got["keep"], 1.0 - p, n)}
+        for k, v in got.items():
+            if not k.endswith("_diff") or (k == "rank_diff" and ranks == 1):
+                continue
+            count = {"block_diff": b * shape[-1],
+                     "rank_diff": n // ranks}.get(k, n)
+            z[k] = _within(k, v, 2.0 * p * (1.0 - p), count)
+        assert got["same_key_equal"] and got["grad_is_mask_over_keep"], got
+        assert got["constant_rows"] == 0 \
+            and got["constant_block_cols"] == 0, got
+        emit("dropout_mask", shape=list(shape), p=p, ranks=ranks,
+             block_rows=b, chunk_rows=chunk, sigmas=z, **got)
 
 
 # --------------------------------------------------------------------------
@@ -355,14 +491,27 @@ def _residency(main, scope, loss, feed):
 
 
 def four_chips():
-    build, feed = _transformer_build, _transformer_feed()
-    single, _ = _train("transformer_1dev", build, feed)
-    single = np.asarray(single)
+    """One device against a mesh, step for step.  dp=4 draws its
+    dropout masks with the kernel, each chip its own rows, and they
+    must be the one-device step's masks: dropout stays on.  dp=2 x
+    mp=2 keeps jax.random.bernoulli (ops/pallas/dropout_mask.py), other
+    masks than the kernel's, so that pair runs without dropout, as
+    every parity test of the repo does; and with twice the warm-up,
+    because without dropout the 40-step schedule overshoots at step 6
+    (loss 9.58 -> 9.95 -> 9.61) and the overshoot magnifies rounding to
+    0.6% (my chip run, PR 27)."""
+    feed = _transformer_feed()
     seen = {}
-    for name, axes in (("transformer_dp4", {"dp": 4}),
-                       ("transformer_dp2mp2", {"dp": 2, "mp": 2})):
+    for name, axes, changed, masks in (
+            ("transformer_dp4", {"dp": 4}, {}, (TRANSFORMER_MASKS, 0)),
+            ("transformer_dp2mp2", {"dp": 2, "mp": 2},
+             {"dropout": 0.0, "warmup_steps": 2 * TRANSFORMER_WARMUP},
+             (0, 0))):
+        build = functools.partial(_transformer_build, **changed)
+        single, _ = _train(name + "_1dev", build, feed, masks=masks)
+        single = np.asarray(single)
         losses, r = _train(name, build, feed, mesh_axes=axes,
-                           inspect=_residency)
+                           inspect=_residency, masks=masks)
         r["rel"] = np.abs(np.asarray(losses) - single) / np.abs(single)
         emit(name + "_parity", losses=losses, single=single.tolist(),
              step0_rel_diff=float(r["rel"][0]),
@@ -399,8 +548,10 @@ def main():
     emit("start", cache_dir=cache_dir, **device)
     t0 = time.perf_counter()
     if args.chips == 4:
+        dropout_mask({"dp": 4})
         four_chips()
     else:
+        dropout_mask()
         train_transformer()
         train_resnet50()
         serve_decode()

@@ -72,13 +72,17 @@ def infer_op_shapes(op_desc, block) -> bool:
 
     impl = get_op_impl(op_desc.type)
 
-    def absfn(abstract_ins):
-        ctx = OpContext(jax.random.PRNGKey(0), op_index=0,
+    # the key is abstract too: a concrete one makes every random op
+    # RUN inside the trace, at the stand-in batch (a 131 GB dropout
+    # mask that the device refuses, an exception swallowed below)
+    def absfn(abstract_ins, key):
+        ctx = OpContext(key, op_index=0,
                         is_test=bool(op_desc.attrs.get("is_test", False)))
         return impl(ctx, abstract_ins, op_desc.attrs)
 
     try:
-        outs = jax.eval_shape(absfn, ins)
+        outs = jax.eval_shape(absfn, ins,
+                              jax.ShapeDtypeStruct((2,), jnp.uint32))
     except Exception:
         return False  # leave declared shapes; executor will still run it
 

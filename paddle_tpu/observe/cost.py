@@ -629,6 +629,7 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
     carries the pre-injection analytic count.
     """
     # force kernel-cost registration before walking custom calls
+    from ..ops.pallas import dropout_mask as _dm  # noqa: F401
     from ..ops.pallas import flash_attention as _fa  # noqa: F401
     from ..ops.pallas import paged_attention as _pa  # noqa: F401
     from ..ops.pallas import recurrence as _rc  # noqa: F401

@@ -36,7 +36,8 @@ _TRACE_EVENT_PREFIXES = ("/jax/core/compile/jaxpr_trace_duration",
                          "/jax/core/compile/jaxpr_to_mlir_module_duration")
 
 _FIELDS = ("compiles", "compile_time_s", "trace_time_s", "builds",
-           "retraces", "dispatches", "dispatch_time_s")
+           "retraces", "dispatches", "dispatch_time_s",
+           "dropout_masks_kernel", "dropout_masks_xla")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
 SPAN_PREFIX = "paddle_tpu.step."
@@ -81,6 +82,11 @@ class RuntimeStats:
         self.dispatches = 0         # step dispatches: the `call` phase
         self.dispatch_time_s = 0.0  # host enqueue time (async; excludes
         #                             device execution)
+        # `dropout` ops traced, by where their keep-mask is drawn: the
+        # Pallas kernel on the chip's generator, or jax.random.bernoulli
+        # (a step that fell back says so; delta() around a build)
+        self.dropout_masks_kernel = 0
+        self.dropout_masks_xla = 0
         # per-phase totals and the most recent durations
         self._phase_time_s: Dict[str, float] = {}
         self._phase_count: Dict[str, int] = {}
@@ -102,6 +108,13 @@ class RuntimeStats:
     def record_retrace(self):
         with self._lock:
             self.retraces += 1
+
+    def record_dropout_mask(self, kernel: bool):
+        with self._lock:
+            if kernel:
+                self.dropout_masks_kernel += 1
+            else:
+                self.dropout_masks_xla += 1
 
     def phase(self, name: str) -> _Phase:
         """Context manager around one host phase of a step: a

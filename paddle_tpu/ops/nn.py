@@ -426,12 +426,25 @@ def dropout(ctx, ins, attrs):
             impl == "downgrade_in_infer"
         y = x * (1.0 - p) if scale_at_infer else x
         return {"Out": [y], "Mask": [jnp.ones_like(x)]}
-    # pinned: the generator (20 rounds of threefry an element) is
-    # cheap elementwise HLO, and unpinned XLA clones it into every
-    # fusion that reads the mask, the backward's too, rather than
-    # write one byte an element once (PERF.md, PR 25)
-    keep = jax.lax.optimization_barrier(
-        jax.random.bernoulli(ctx.rng(), 1.0 - p, x.shape))
+    from ..observe.monitoring import runtime_stats
+    from .pallas import interpret
+
+    key = ctx.rng()
+    keep = None
+    if not interpret():     # off the TPU not even imported: Pallas is 1 s
+        from .pallas.dropout_mask import dropout_keep_mask
+
+        # the chip's own generator draws the mask (a Pallas kernel,
+        # PERF.md PR 27); None where that kernel does not engage
+        keep = dropout_keep_mask(key, p, x.shape)
+    runtime_stats.record_dropout_mask(kernel=keep is not None)
+    if keep is None:
+        # pinned: the generator (20 rounds of threefry an element) is
+        # cheap elementwise HLO, and unpinned XLA clones it into every
+        # fusion that reads the mask, the backward's too, rather than
+        # write one byte an element once (PERF.md, PR 25)
+        keep = jax.lax.optimization_barrier(
+            jax.random.bernoulli(key, 1.0 - p, x.shape))
     if impl == "upscale_in_train":
         y = jnp.where(keep, x / (1.0 - p), 0.0)
     else:

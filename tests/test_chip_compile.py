@@ -29,10 +29,9 @@ from paddle_tpu.ops.pallas import force_mosaic_lowering
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -45,9 +44,24 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def dp4_mesh(topology):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topology.devices).reshape(4), ("dp",))
 
 
 def _compile_args(fn, *args):
@@ -283,6 +297,20 @@ def test_tpu_dots_are_matmul_rows_with_xlas_flops(one_chip):
     assert sum(r["flops"] for r in rows) < 2 * 8 * 64 * 112 * 112 * 3 * 49
 
 
+def _rng_reader():
+    """The benchmark's own reader of `rng_evals_per_step`."""
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:       # the reader imports step_anatomy
+        sys.path.insert(0, bench)
+    from run import load_module
+
+    return load_module(os.path.join(bench, "layer_metrics",
+                                    "rng_evals_per_step.py"))
+
+
 def _residual_loss(dropout, h, w, res, gamma, beta):
     """dot -> dropout -> residual add -> layer norm, as a Transformer
     sublayer ends: every cotangent the backward needs."""
@@ -306,25 +334,17 @@ def _attention_loss(dropout, scores, v):
 def test_dropout_mask_is_generated_once_and_outside_the_dots(one_chip, loss,
                                                              shapes):
     """The `dropout` op's own lowering, forward and backward, compiled
-    for the chip: one fused computation holds the generator's rounds,
-    and it holds no dot (the TPU compiler writes dots as
-    `convolution`).  Unpinned, XLA cloned the generator into the
-    forward dot fusion and into each backward fusion that reads the
-    mask: 3 and 2 such computations (PERF.md, PR 25).  The judge is
-    the benchmark's own reader of `rng_evals_per_step`."""
-    import sys
-
+    for the chip: the mask is one `pallas_dropout_mask` custom call a
+    `dropout` op (a custom call cannot be cloned into the fusions that
+    read it, which is what XLA did to the threefry generator: PERF.md,
+    PR 25), the step holds no XLA generator at all, and its dots are
+    still there (the TPU compiler writes dots as `convolution`).  The
+    judge is the benchmark's own reader of `rng_evals_per_step`."""
     from paddle_tpu.core.registry import OpContext, get_op_impl
     from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
 
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks")
-    if bench not in sys.path:       # the reader imports step_anatomy
-        sys.path.insert(0, bench)
-    from run import load_module
-
-    reader = load_module(os.path.join(bench, "layer_metrics",
-                                      "rng_evals_per_step.py"))
+    reader = _rng_reader()
 
     def step(key, *args):
         def dropout(x):
@@ -340,16 +360,74 @@ def test_dropout_mask_is_generated_once_and_outside_the_dots(one_chip, loss,
 
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in (((2,), jnp.uint32),) + shapes]
+    snap = runtime_stats.snapshot()
+    compiled = _compile_args(jax.jit(step), *args)
+    drawn = runtime_stats.delta(snap)
+    assert (drawn["dropout_masks_kernel"], drawn["dropout_masks_xla"]) \
+        == (1, 0)
+    module = cost.HloModule(cost.compiled_hlo_proto(compiled))
+    assert reader.rng_instructions(module) == {}
+    assert not any(reader.generators(c)
+                   for c in module.computations.values()
+                   if c.id != module.entry_id)
+    rows = [r for r in cost.instruction_costs(module) if r["kernel"]]
+    assert [(r["kernel"], r["op_type"], r["flops"]) for r in rows] == [
+        ("dropout_mask", "dropout", 0)]
+    # the registered cost: the mask's byte an element and the seeds
+    n_mask = 1
+    for d in shapes[0][0]:
+        n_mask *= d
+    assert rows[0]["bytes"] == n_mask + 3 * 4
+    assert " convolution(" in compiled.as_text()    # the dots are there
+
+
+def test_dropout_mask_under_a_dp_mesh_is_drawn_per_chip(dp4_mesh):
+    """GSPMD cannot partition a custom call: under the `{"dp": 4}`
+    mesh of the described 2x2 the op maps the kernel over the batch
+    axis itself, so each chip draws its own quarter of the mask (the
+    custom call's result has the per-chip leading dimension) and no
+    mask is gathered."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.parallel.mesh import executing_mesh
+
+    mesh = dp4_mesh
+    reader = _rng_reader()
+    shape = (4 * 16, 256, 512)
+
+    def step(key, h, w, res, gamma, beta):
+        def dropout(x):
+            with jax.named_scope("dropout:7"), executing_mesh(mesh, "dp"):
+                return get_op_impl("dropout")(
+                    OpContext(key, 7), {"X": [x]},
+                    {"dropout_prob": 0.1,
+                     "dropout_implementation": "upscale_in_train"}
+                )["Out"][0]
+
+        return jax.grad(lambda *a: _residual_loss(dropout, *a),
+                        argnums=(0, 1))(h, w, res, gamma, beta)
+
+    rep, batch = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    args = [jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
+            jax.ShapeDtypeStruct(shape, BF16, sharding=batch),
+            jax.ShapeDtypeStruct((512, 512), BF16, sharding=rep),
+            jax.ShapeDtypeStruct(shape, BF16, sharding=batch),
+            jax.ShapeDtypeStruct((512,), F32, sharding=rep),
+            jax.ShapeDtypeStruct((512,), F32, sharding=rep)]
     compiled = _compile_args(jax.jit(step), *args)
     module = cost.HloModule(cost.compiled_hlo_proto(compiled))
-    holders = [c for c in module.computations.values()
-               if c.id != module.entry_id and reader.generators(c)]
-    assert [reader.generators(c) for c in holders] == [1], \
-        [c.name for c in holders]
-    assert not any(i.opcode == "convolution"
-                   for i in holders[0].instructions)
-    assert " convolution(" in compiled.as_text()    # the dots are there
-    assert list(reader.rng_instructions(module).values()) == [1]
+    assert reader.rng_instructions(module) == {}
+    calls = [i for c in module.computations.values()
+             for i in c.instructions if i.opcode == "custom-call"
+             and "pallas_dropout_mask" in i.op_name]
+    assert [tuple(i.shape.dims) for i in calls] == [(16 * 256, 512)]
+    text = compiled.as_text()
+    assert " all-reduce(" in text       # dW is summed over the chips
+    assert not [line for line in text.splitlines()
+                if " all-gather(" in line
+                and (" s8[" in line or " pred[" in line)]
 
 
 def test_olmoe_step_kernels_at_the_published_shapes(one_chip):

@@ -216,3 +216,27 @@ def test_fused_lstm_bwd_lowers_for_tpu():
     # fwd kernel (residual recompute path) + bwd kernel both reach
     # Mosaic
     assert exp.mlir_module().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("p", [0.1, 0.5])
+@pytest.mark.parametrize("shape", [(64, 256, 512), (64, 8, 256, 256)],
+                         ids=["residual", "attention_weights"])
+def test_dropout_mask_lowers_for_tpu(shape, p):
+    """The keep-mask kernel at the Transformer step's two mask shapes,
+    through the `dropout` op itself: under the Mosaic gate the op draws
+    its mask with the kernel and the export holds no threefry."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+
+    def op(key, x):
+        return get_op_impl("dropout")(
+            OpContext(key, 3), {"X": [x]},
+            {"dropout_prob": p,
+             "dropout_implementation": "upscale_in_train"})["Out"][0]
+
+    exp = _export_tpu(op, jax.random.PRNGKey(0),
+                      jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+    mlir = exp.mlir_module()
+    # the op's own scalar fold_in is threefry's one call; a sampler
+    # would add `_bernoulli` / `_uniform`
+    assert "_bernoulli" not in mlir and "_uniform" not in mlir
+    assert exp.out_avals[0].shape == shape
