@@ -19,8 +19,7 @@ Honesty rules:
   dense-equivalent cost (ops/pallas KERNEL_COSTS via observe.cost —
   the standard flash-attention MFU convention: same logical math,
   skipped masked blocks not credited, backward recompute not
-  double-counted).  tools/check_twin_flops.py asserts registry-vs-
-  dense-twin parity; the twin (`_dense_equiv_flops`) remains the
+  double-counted).  The twin (`_dense_equiv_flops`) remains the
   numerator only for recompute configs (remat double-counts in any
   HLO-side count) and for the XLA flash composition (bert).
 - No stand-in: a missing backend, a device kind without a row in
@@ -41,8 +40,8 @@ key `<model>_dp8` / `<model>_dp2mp2` and carry per_device_*
 throughput next to the aggregate, MFU against the aggregate peak, the
 sharded step's comm-bucket bytes, and opt_state_bytes_per_device;
 `--grad-sync int8` swaps the gradient all-reduce for the EQuARX
-blockwise-quantized exchange (opt-in, A/B'd in AB_r08.json;
-psum-form on composed meshes).
+blockwise-quantized exchange (opt-in, no ledger row; psum-form on
+composed meshes).
 """
 
 from __future__ import annotations
@@ -621,8 +620,7 @@ def _registry_flops(exe, program, feed, loss):
     zero there) plus each custom call's dense-equivalent cost from the
     Pallas kernel registry (ops/pallas KERNEL_COSTS, injected by
     observe.cost at the custom-call instructions).  Replaces the
-    dense-twin workaround as the primary numerator;
-    tools/check_twin_flops.py keeps asserting registry-vs-twin parity.
+    dense-twin workaround as the primary numerator.
 
     Returns (step_flops, flop_count_tag)."""
     from paddle_tpu.observe import cost as obs_cost
@@ -654,9 +652,7 @@ def _dense_equiv_flops(feed, build_no_flash, platform=None):
     long sequence the dense twin CANNOT exist on the TPU (seq 8k needs
     a 73 GB dense-score program — XLA:TPU refuses at compile time,
     which is the whole point of flash).  Flop counts are a property of
-    the HLO, not the backend; the dominant dot flops are identical
-    (cpu-vs-tpu twin parity is checked at seq 256 by
-    tools/check_twin_flops.py)."""
+    the HLO, not the backend; the dominant dot flops are identical."""
     import contextlib
 
     import jax
@@ -837,15 +833,14 @@ def bench_lstm(batch_size: int, steps: int, warmup: int,
     for context but throughput is the tracked axis (perf_gate compares
     tokens_per_sec/examples_per_sec, numerator-free).
 
-    The two scan-bound levers (docs/RNN.md, A/B'd by run_ab lstm
-    variants): --rnn-unroll N unrolls the lax.scan body; --pallas-rnn
-    swaps the recurrence for the blocked fused Pallas kernel
+    The two scan-bound levers (docs/RNN.md): --rnn-unroll N unrolls
+    the lax.scan body; --pallas-rnn swaps the recurrence for the blocked fused Pallas kernel
     (ops/pallas/recurrence.py), whose custom calls take their MFU
     numerator from the kernel cost registry.  The scan path's MFU
     numerator is XLA's aggregate, which counts while BODIES ONCE
     (undercounts the recurrence by ~T) — tagged, kept for artifact
-    continuity with r05; the trip-corrected analytic number lives in
-    tools/roofline.py."""
+    continuity with r05; the trip-corrected analytic number is
+    observe.cost's (`while_trip_count`)."""
     import jax.numpy as jnp
 
     import paddle_tpu as fluid
@@ -1211,9 +1206,8 @@ def bench_serving_decode(n_requests: int = 0, kv_int8: bool = False,
     which MUST be 0: any compile after warmup means a shape leaked
     across a join/leave/preempt pattern.
 
-    kv_int8=True swaps the KV pools for int8 + per-row scale sidecars
-    (the AB_r09 A/B pair); the default stays bf16 pending a recorded
-    chip wall-clock win, per the device-tag rule.
+    kv_int8=True swaps the KV pools for int8 + per-row scale
+    sidecars; the default stays bf16 (no ledger row on either side).
 
     speculate=K runs the ISSUE 20 acceptance protocol: a sequential
     twin engine runs the SAME stream first (token parity is asserted,
@@ -1707,9 +1701,8 @@ def main():
                         "psum (the A/B control arm); int8 = EQuARX "
                         "blockwise-int8 two-phase quantized "
                         "all-reduce (collectives.quantized_all_reduce,"
-                        " docs/DIST.md).  A/B candidate: default "
-                        "stays none pending a chip throughput win in "
-                        "AB_r08.json")
+                        " docs/DIST.md).  Default none: no ledger "
+                        "row on either side")
     p.add_argument("--seq", type=int, default=0,
                    help="longctx: sequence length (default 8192)")
     p.add_argument("--steps", type=int, default=60)
@@ -1726,10 +1719,10 @@ def main():
                         "kernel (ops/pallas/vocab_ce.py).  Default OFF "
                         "at len256: its reported MFU (0.3289, dense-"
                         "equivalent numerator) exceeds base but WALL "
-                        "CLOCK loses 154.0k vs 157.1k tok/s "
-                        "(AB_r05.json) — throughput decides; the "
-                        "kernel pays at 8k where it defaults ON "
-                        "(longctx)")
+                        "CLOCK lost 154.0k vs 157.1k tok/s (r05, "
+                        "pre-ledger; not measured on the current "
+                        "code) — throughput decides; defaults ON at "
+                        "8k (longctx)")
     p.add_argument("--no-fused-ce", dest="fused_ce",
                    action="store_false",
                    help="disable the fused vocab-CE kernel everywhere "
@@ -1748,13 +1741,12 @@ def main():
     p.add_argument("--pallas-rnn", action="store_true",
                    help="lstm: route every dynamic_lstm recurrence "
                         "through the blocked fused Pallas kernel "
-                        "(ops/pallas/recurrence.py; A/B candidate — "
-                        "default stays scan until a recorded "
-                        "throughput win in AB_r06.json)")
+                        "(ops/pallas/recurrence.py; default stays "
+                        "scan: no ledger row on either side)")
     p.add_argument("--rnn-unroll", type=int, default=1,
                    help="lstm: lax.scan unroll factor for the "
-                        "recurrence (A/B candidate, bit-identical "
-                        "numerics; default 1 until a recorded win)")
+                        "recurrence (bit-identical numerics; "
+                        "default 1: no ledger row)")
     p.add_argument("--pallas-attn", action="store_true",
                    help="transformer: route flash attention through "
                         "the tiled Pallas kernel instead of the XLA "
@@ -1765,16 +1757,14 @@ def main():
                         "head-grouped layout end-to-end — zero "
                         "transpose traffic at kernel boundaries "
                         "(ISSUE 8, docs/LAYOUT.md).  Forces the flash "
-                        "op for decoder cross attention.  A/B "
-                        "candidate: default stays off until a recorded "
-                        "throughput win in AB_r07.json")
+                        "op for decoder cross attention.  Default "
+                        "off: no ledger row on either side")
     p.add_argument("--kv-int8", action="store_true",
                    help="serving_decode: int8 KV-cache pools with "
                         "per-row scale sidecars (the blockwise scheme "
                         "of parallel/collectives.py) instead of the "
-                        "bf16 default — A/B candidate, recorded in "
-                        "AB_r09.json; the default only flips on a "
-                        "chip wall-clock win")
+                        "bf16 default (no ledger row on either "
+                        "side)")
     p.add_argument("--speculate", type=int, default=0, metavar="K",
                    help="serving_decode/serving_fleet/serving_disagg: "
                         "speculative decoding with K-token n-gram "
@@ -1833,8 +1823,7 @@ def main():
         p.error("--grad-sync needs --mesh (it is the dp gradient-"
                 "exchange mode)")
     # run provenance (observe pillar 3): every JSON line — including
-    # the backend-failure one — is traceable to a run-id + git sha, so
-    # mixed-run artifacts (run_ab --only merges) stay auditable
+    # the backend-failure one — is traceable to a run-id + git sha
     from paddle_tpu.observe import events as _obs_events
 
     run_id = _obs_events.new_run_id()
@@ -2093,8 +2082,8 @@ def main():
         # per-layer recompute.  Runs AFTER the headline models so a
         # long-sequence OOM/compile failure can't cost their entries.
         # recompute default OFF here: bs2/8k activations fit in HBM and
-        # the A/B measured 0.306 vs 0.243 MFU (AB_r05.json
-        # longctx_8k_recompute) — remat is for when memory does NOT
+        # r05 read 0.306 vs 0.243 MFU (pre-ledger; not measured on the
+        # current code) — remat is for when memory does NOT
         # fit (--recompute re-enables; the recompute variant stays
         # recorded in the artifact).  fused-CE default ON at 8k+
         # (unlike the short-seq transformer) — --no-fused-ce still

@@ -14,6 +14,12 @@ device stream, the whole program — forward ops, the autodiff boundary
 with the state argument donated.  XLA then fuses/schedules everything; eager
 per-op garbage collection (executor.cc:45-134) is unnecessary because XLA's
 buffer liveness analysis subsumes it.
+
+This module owns the step: its body, its jit, its cache, the host phases
+`prepare` / `place` / `call` / `writeback` and the AOT memo, once.  A mesh
+is an optional placement that one path takes (parallel/compiler.py
+CompiledProgram: shardings, the trace-time mesh context, where the cache
+lives); nothing here reads a placement except through that argument.
 """
 
 from __future__ import annotations
@@ -506,12 +512,11 @@ def interpret_program(program: Program, env: Dict[str, Any], rng_key,
         _ectx = get_exec_context()
         if _ectx is not None:
             # the DATA axes of the mesh: the batch axis plus the
-            # ZeRO/fsdp axis when the wrapper's rules name one
+            # ZeRO/fsdp axis when the placement's rules name one
             # (strategies.data_axes_for) — fsdp is dp with sharded
             # optimizer state, so the explicit exchange spans both
-            _wrapper = getattr(program, "_compiled_wrapper", None)
-            if _wrapper is not None and _wrapper._rules is not None:
-                gs_axes = _wrapper._rules.data_axes_for(
+            if _ectx.rules is not None:
+                gs_axes = _ectx.rules.data_axes_for(
                     _ectx.mesh, _ectx.batch_axis)
             else:
                 gs_axes = tuple(
@@ -545,8 +550,7 @@ def interpret_program(program: Program, env: Dict[str, Any], rng_key,
                 "grad_sync without accumulation.")
         loss_val, grads, env = _dp_sync_value_and_grad(
             grad_fwd, fwd_ops, sparse_lookups, trainable, env, rng_key,
-            gs_ectx, gs_cfg, feed_names, fwd_keep, gs_axes,
-            program=program)
+            gs_ectx, gs_cfg, feed_names, fwd_keep, gs_axes)
     elif accum_steps <= 1:
         if sparse_lookups:
             loss_val, grads, env = _sparse_value_and_grad(
@@ -700,7 +704,7 @@ def _sparse_value_and_grad(fwd, fwd_ops, sparse_lookups, trainable, env,
 
 def _dp_sync_value_and_grad(fwd, fwd_ops, sparse_lookups, trainable, env,
                             rng_key, ectx, cfg, feed_names, keep_names,
-                            data_axes=None, program=None):
+                            data_axes=None):
     """Data-parallel fwd+bwd with an EXPLICIT gradient exchange
     (docs/DIST.md).  The forward/backward runs inside a shard_map over
     the mesh's DATA axes (the batch axis, plus the fsdp/ZeRO axis when
@@ -763,9 +767,7 @@ def _dp_sync_value_and_grad(fwd, fwd_ops, sparse_lookups, trainable, env,
     # DATA axis cannot enter the exchange replicated (it would
     # all-gather the model); mp/ep-sharded params are fine — they ride
     # the auto axes with their shardings intact
-    _wrapper = getattr(program, "_compiled_wrapper", None) \
-        if program is not None else None
-    if _wrapper is not None and _wrapper._rules is not None:
+    if ectx.rules is not None:
         def _spec_axes(spec):
             for e in spec:
                 if e is None:
@@ -775,7 +777,7 @@ def _dp_sync_value_and_grad(fwd, fwd_ops, sparse_lookups, trainable, env,
         bad = sorted(
             pname for pname, v in trainable.items()
             if any(ax in axes for ax in _spec_axes(
-                _wrapper._rules.spec_for(pname, v.shape, mesh))))
+                ectx.rules.spec_for(pname, v.shape, mesh))))
         if bad:
             raise ValueError(
                 f"grad_sync={cfg.mode!r} cannot run with params "
@@ -1133,11 +1135,11 @@ class Executor:
 
     def __init__(self, place=None):
         self.place = place
-        self._cache: Dict[Any, Any] = {}
-        # feed-signature sets per cache entry: a NEW shape/dtype
+        # key -> (step fn, its state shardings under a placement, the
+        # feed signatures it has been called with: a NEW shape/dtype
         # signature on an already-built step fn means jax will retrace
-        # and recompile it — counted as a retrace (observe pillar 2)
-        self._sig_seen: Dict[Any, set] = {}
+        # and recompile it — counted as a retrace, observe pillar 2)
+        self._cache: Dict[Any, Any] = {}
         # AOT-compiled steps for cost analysis / optimized-HLO access
         # (compiled_step): memoized so cost_analysis + observe.cost on
         # the same program pay one extra compile, not two
@@ -1156,37 +1158,17 @@ class Executor:
             iterations: int = 1,
             accumulation_steps: int = 1):
         from .program import default_main_program
+        from ..observe.monitoring import runtime_stats
 
-        import jax
-        import jax.numpy as jnp
-
-        program = program or default_main_program()
+        program, placement = _resolve_placement(
+            program or default_main_program())
         scope = scope or global_scope()
         feed = dict(feed or {})
-        fetch_names = [
-            f.name if isinstance(f, Variable) else str(f)
-            for f in (fetch_list or [])
-        ]
-
-        # `program` may be a CompiledProgram (passed directly, fluid style)
-        # or a Program that was wrapped by CompiledProgram.
-        if hasattr(program, "_program") and hasattr(program, "run"):
-            return program.run(self, feed, fetch_names, scope,
-                               return_numpy=return_numpy,
-                               iterations=iterations,
-                               accumulation_steps=accumulation_steps)
-        compiled = getattr(program, "_compiled_wrapper", None)
-        if compiled is not None:
-            return compiled.run(self, feed, fetch_names, scope,
-                                return_numpy=return_numpy,
-                                iterations=iterations,
-                                accumulation_steps=accumulation_steps)
+        fetch_names = _fetch_names(fetch_list)
 
         fn, state, feed_arrays = self._prepare(
             program, feed, fetch_names, scope, iterations,
-            use_program_cache, accumulation_steps)
-        from ..observe.monitoring import runtime_stats
-
+            use_program_cache, accumulation_steps, placement)
         with runtime_stats.phase("call"):
             new_state, fetches = fn(state, feed_arrays)
         with runtime_stats.phase("writeback"):
@@ -1215,31 +1197,37 @@ class Executor:
         traced step fn itself is shared via the program cache, and the
         Compiled is memoized per (program, feed-signature) so
         cost_analysis + observe.cost/.memory on the same step compile
-        once.
+        once.  Always the ONE-DEVICE step, also of a Program that
+        carries a placement (its unsharded twin); the sharded step is
+        CompiledProgram.compiled_step.
 
         with_names=True returns (compiled, arg_names): one
         ("state"|"feed", var_name) label per flattened step argument in
         jax's pytree leaf order — the HLO entry parameter order —
         which is how observe.memory attributes entry-parameter buffers
         to named state vars (params vs optimizer accumulators)."""
-        feed = dict(feed or {})
-        fetch_names = [f.name if isinstance(f, Variable) else str(f)
-                       for f in (fetch_list or [])]
+        return self._compiled_step(program, dict(feed or {}),
+                                   _fetch_names(fetch_list), scope, 1,
+                                   with_names)
+
+    def _compiled_step(self, program: Program, feed, fetch_names, scope,
+                       iterations: int, with_names: bool, placement=None):
         fn, state, feed_arrays = self._prepare(
-            program, feed, fetch_names, scope or global_scope(), 1, True)
+            program, feed, fetch_names, scope or global_scope(),
+            iterations, True, 1, placement)
         key = (program._uid, program._version, tuple(sorted(feed)),
-               tuple(fetch_names),
-               tuple((n, tuple(getattr(v, "shape", ()) or ()),
-                      str(getattr(v, "dtype", type(v).__name__)))
-                     for n, v in sorted(feed_arrays.items())))
-        entry = self._aot_cache.get(key)
+               tuple(fetch_names), iterations,
+               _feed_signature(feed_arrays))
+        aot_cache = (self._aot_cache if placement is None
+                     else placement._aot_cache)
+        entry = aot_cache.get(key)
         if entry is None:
             from ..observe.memory import _arg_labels
 
             compiled = fn.lower(state, feed_arrays).compile()
             entry = (compiled,
                      _arg_labels(state, feed_arrays, compiled=compiled))
-            self._aot_cache[key] = entry
+            aot_cache[key] = entry
         return entry if with_names else entry[0]
 
     def cost_analysis(self, program: Program, feed=None, fetch_list=None,
@@ -1261,25 +1249,38 @@ class Executor:
 
     def _prepare(self, program: Program, feed, fetch_names, scope,
                  iterations: int, use_program_cache: bool,
-                 accumulation_steps: int = 1):
-        """Shared run()/cost_analysis() setup, as the step's first two
+                 accumulation_steps: int = 1, placement=None):
+        """Shared run()/compiled_step() setup, as the step's first two
         host phases (observe.monitoring): `prepare` (`_lookup_step`)
-        and `place` (feed conversion, and the retrace check, which
-        reads the converted feed's dtypes)."""
-        from ..observe.monitoring import runtime_stats
+        and `place` (feed conversion, under a placement every state and
+        feed array through `jax.device_put`, a no-op for what is
+        already placed, with a child span each; and the retrace check,
+        which reads the converted feed's dtypes)."""
+        from ..observe.monitoring import SPAN_PREFIX, runtime_stats
 
         with runtime_stats.phase("prepare"):
-            key, fn, state = self._lookup_step(
+            fn, state, shardings, seen = self._lookup_step(
                 program, feed, fetch_names, scope, iterations,
-                use_program_cache, accumulation_steps)
+                use_program_cache, accumulation_steps, placement)
         with runtime_stats.phase("place"):
-            block = program.global_block()
-            feed_arrays = {n: _to_array(v, block) for n, v in feed.items()}
-            sig = tuple(
-                (n, tuple(getattr(v, "shape", ()) or ()),
-                 str(getattr(v, "dtype", type(v).__name__)))
-                for n, v in sorted(feed_arrays.items()))
-            seen = self._sig_seen.setdefault(key, set())
+            if placement is None:
+                block = program.global_block()
+                feed_arrays = {n: _to_array(v, block)
+                               for n, v in feed.items()}
+            else:
+                import jax
+                import jax.numpy as jnp
+                from jax.profiler import TraceAnnotation
+
+                state_shardings, feed_shardings = shardings
+                with TraceAnnotation(SPAN_PREFIX + "place_state"):
+                    state = {n: jax.device_put(v, state_shardings[n])
+                             for n, v in state.items()}
+                with TraceAnnotation(SPAN_PREFIX + "place_feed"):
+                    feed_arrays = {
+                        n: jax.device_put(jnp.asarray(v), feed_shardings[n])
+                        for n, v in feed.items()}
+            sig = _feed_signature(feed_arrays)
             if seen and sig not in seen:
                 runtime_stats.record_retrace()
             seen.add(sig)
@@ -1287,9 +1288,12 @@ class Executor:
 
     def _lookup_step(self, program: Program, feed, fetch_names, scope,
                      iterations: int, use_program_cache: bool,
-                     accumulation_steps: int):
-        """RNG init, state gathering, program-cache lookup and, on a
-        miss, the step's build.  Returns (cache key, step fn, state)."""
+                     accumulation_steps: int, placement=None):
+        """RNG and telemetry state, state gathering, program-cache
+        lookup and, on a miss, the step's build.  Returns (step fn,
+        state as the scope holds it, under a placement the (state,
+        feed) shardings `place` puts them to, the feed signatures the
+        step fn has seen)."""
         import jax
 
         block = program.global_block()
@@ -1321,25 +1325,58 @@ class Executor:
                 if patched is not tel_cur:
                     scope.set_var(_obs_metrics.TELEMETRY_VAR, patched)
             state_names = state_names + (_obs_metrics.TELEMETRY_VAR,)
-        key = (program._uid, program._version, tuple(sorted(feed)),
-               tuple(fetch_names), state_names, iterations,
-               accumulation_steps)
-        fn = self._cache.get(key) if use_program_cache else None
-        if fn is None:
-            fn = self._build_step_fn(program, tuple(sorted(feed)),
-                                     tuple(fetch_names), state_names,
-                                     iterations, accumulation_steps)
-            runtime_stats.record_build()
-            if use_program_cache:
-                self._cache[key] = fn
         state = {n: scope.find_var(n) for n in state_names}
         state[RNG_STATE_VAR] = scope.find_var(RNG_STATE_VAR)
-        return key, fn, state
+
+        feed_names = tuple(sorted(feed))
+        if placement is None:
+            cache, feed_key, feed_shardings = self._cache, feed_names, None
+        else:
+            # the step cache lives on the placement: a second Executor
+            # over one CompiledProgram does not recompile
+            cache = placement._cache
+            mesh = placement._ensure_mesh()
+            if accumulation_steps == 1:
+                # an explicit per-run override wins over the
+                # BuildStrategy knob
+                accumulation_steps = placement._accum_steps
+            feed_shardings = {n: placement._feed_sharding(n, v)
+                              for n, v in feed.items()}
+            # the chosen feed shardings are part of the key: a final
+            # partial batch that is no longer dp-divisible must
+            # recompile with a replicated layout rather than reuse the
+            # sharded executable
+            feed_key = (id(mesh),) + tuple(sorted(
+                (n, str(s.spec)) for n, s in feed_shardings.items()))
+        key = (program._uid, program._version, feed_key,
+               tuple(fetch_names), state_names, iterations,
+               accumulation_steps)
+        entry = cache.get(key) if use_program_cache else None
+        if entry is None:
+            trace_context, state_shardings = contextlib.nullcontext, None
+            if placement is not None:
+                trace_context = placement._trace_context
+                state_shardings = {n: placement._state_sharding(n, v)
+                                   for n, v in state.items()}
+            fn = self._build_step_fn(
+                program, feed_names, tuple(fetch_names), iterations,
+                accumulation_steps, trace_context, state_shardings,
+                feed_shardings)
+            runtime_stats.record_build()
+            entry = (fn, state_shardings, set())
+            if use_program_cache:
+                cache[key] = entry
+        fn, state_shardings, seen = entry
+        return fn, state, (state_shardings, feed_shardings), seen
 
     # -- compilation -----------------------------------------------------
     def _build_step_fn(self, program: Program, feed_names, fetch_names,
-                       state_names, iterations: int = 1,
-                       accumulation_steps: int = 1):
+                       iterations: int = 1, accumulation_steps: int = 1,
+                       trace_context=contextlib.nullcontext,
+                       state_shardings=None, feed_shardings=None):
+        """The jitted step.  A placement adds `trace_context` (the
+        executing-mesh context mesh-aware op impls read, entered around
+        the trace) and the shardings of the step's arguments."""
         import jax
 
         persistable_names = tuple(sorted(
@@ -1353,10 +1390,11 @@ class Executor:
             env.update({k: v for k, v in state.items()
                         if k != RNG_STATE_VAR})
             env.update(feeds)
-            env = interpret_program(program, env, rng_key,
-                                    fetch_names=fetch_names,
-                                    accum_steps=accumulation_steps,
-                                    feed_names=feed_names)
+            with trace_context():
+                env = interpret_program(program, env, rng_key,
+                                        fetch_names=fetch_names,
+                                        accum_steps=accumulation_steps,
+                                        feed_names=feed_names)
             new_state = {
                 n: env[n] for n in persistable_names if n in env
             }
@@ -1370,8 +1408,39 @@ class Executor:
             fetches = [env[n] for n in fetch_names]
             return new_state, fetches
 
+        placed = {}
+        if state_shardings is not None:
+            # pin the updated state to the SAME shardings it came in
+            # with: without this XLA may infer a different (replicated)
+            # output layout for ZeRO-sharded optimizer state, which
+            # silently breaks donation — per-device opt-state bytes
+            # then DOUBLE (input + undonated output) and an all-gather
+            # sneaks into every step
+            placed = dict(in_shardings=(state_shardings, feed_shardings),
+                          out_shardings=(state_shardings, None))
         return jax.jit(chain_iterations(step, iterations),
-                       donate_argnums=(0,))
+                       donate_argnums=(0,), **placed)
+
+
+def _resolve_placement(program):
+    """(Program, placement): the placement is the CompiledProgram that
+    was passed in the Program's place (fluid style) or that
+    `with_data_parallel` hung on the Program; None on one device."""
+    if hasattr(program, "_program") and hasattr(program, "_state_sharding"):
+        return program._program, program
+    return program, getattr(program, "_compiled_wrapper", None)
+
+
+def _fetch_names(fetch_list) -> List[str]:
+    return [f.name if isinstance(f, Variable) else str(f)
+            for f in (fetch_list or [])]
+
+
+def _feed_signature(feed_arrays) -> tuple:
+    return tuple(
+        (n, tuple(getattr(v, "shape", ()) or ()),
+         str(getattr(v, "dtype", type(v).__name__)))
+        for n, v in sorted(feed_arrays.items()))
 
 
 def _to_array(value, block):

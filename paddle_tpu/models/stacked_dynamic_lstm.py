@@ -17,8 +17,8 @@ def build_model(vocab_size=5147, emb_dim=512, hidden_dim=512,
                 use_amp=False, pallas_rnn=False, rnn_unroll=1):
     """`pallas_rnn` routes every dynamic_lstm through the blocked fused
     Pallas recurrence kernel; `rnn_unroll` unrolls the lax.scan path by
-    that factor — the two scan-bound levers (docs/RNN.md), A/B'd by
-    tools/run_ab.py lstm variants."""
+    that factor — the two scan-bound levers (docs/RNN.md; no ledger
+    row on either)."""
     data = layers.data(name="words", shape=[max_len], dtype="int64",
                        lod_level=1, append_batch_size=True)
     label = layers.data(name="label", shape=[1], dtype="int64")

@@ -18,14 +18,14 @@ Three pillars (docs/OBSERVE.md):
 
 3. STRUCTURED RUN EVENTS — `RunEventLog` writes JSONL records with
    run-id/git-sha/backend/mesh provenance, consumed by
-   contrib.Trainer(telemetry=...), bench.py, and tools/run_ab.py.
+   contrib.Trainer(telemetry=...) and bench.py.
 
 4. COST ATTRIBUTION — `cost.py` walks the *optimized* HLO module with
    the same wire scanner, computing analytic per-instruction flops and
    materialized-buffer bytes, injecting the Pallas kernel cost
    registry at custom calls, and joining to fluid ops + measured
-   device time (`op_cost_table`); tools/roofline.py and bench.py's
-   Pallas MFU numerators are built on it.
+   device time (`op_cost_table`); bench.py's Pallas MFU numerators
+   are built on it.
 
 5. MEMORY — `memory.py` parses the optimized module's buffer
    assignment (compiled.memory_analysis()), attributing every HBM

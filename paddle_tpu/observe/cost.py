@@ -5,8 +5,8 @@ Why XLA's own aggregates are not enough (the r05 roofline lesson):
 
 - `cost_analysis()["bytes accessed"]` OVERCOUNTS real HBM traffic —
   per-instruction estimates inside fusions are summed with utilization
-  heuristics, which produced the impossible ROOFLINE_r05 result of an
-  MFU "ceiling" (0.269) below an actually measured MFU (0.309).
+  heuristics, which produced the impossible r05 result of an MFU
+  "ceiling" (0.269) below an actually measured MFU (0.309).
 - Pallas custom calls report ZERO flops, forcing bench.py's
   dense-twin workaround for every Pallas-active config.
 - The aggregate has no attribution: r05's longctx device profile found
@@ -46,8 +46,8 @@ HloModuleProto (read with trace.py's dependency-free wire scanner):
   (`ops/pallas` KERNEL_COSTS, populated next to each kernel's
   DEFAULT_BLOCK_*) get that kernel's declared dense-equivalent
   (flops, bytes) injected at the instruction, so Pallas-active
-  programs compute MFU numerators natively (tools/check_twin_flops.py
-  asserts registry-vs-dense-twin parity).
+  programs compute MFU numerators natively
+  (tests/test_observe_cost.py pins the formulas against the dense twin).
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 from .trace import _fields, _first, _utf8, fluid_op_of
 
 # --------------------------------------------------------------------------
-# device peaks (shared by tools/roofline.py and op_cost_table)
+# device peaks (op_cost_table)
 # --------------------------------------------------------------------------
 
 # bf16 MXU peak FLOP/s and HBM bandwidth by device kind prefix

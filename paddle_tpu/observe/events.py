@@ -2,9 +2,8 @@
 
 Every training/bench run gets a run-id + git-sha + backend/mesh stamp
 and a stream of typed event records (telemetry windows, checkpoints,
-compile storms) — the artifact a dashboards/alerting layer tails, and
-the provenance stamp tools/run_ab.py uses to keep mixed-run A/B
-artifacts auditable.  One JSON object per line; the file is valid to
+compile storms) — the artifact a dashboards/alerting layer tails.
+One JSON object per line; the file is valid to
 tail mid-run (each line is flushed whole).
 """
 
@@ -295,7 +294,7 @@ def git_sha(cwd: Optional[str] = None) -> Optional[str]:
 def _backend_info() -> Dict[str, Any]:
     """Backend/device provenance WITHOUT forcing backend init: only
     reports when jax is already imported and initialized (events logs
-    must stay usable from pure-host tools like run_ab)."""
+    must stay usable from pure-host tools)."""
     if "jax" not in sys.modules:
         return {}
     try:

@@ -57,8 +57,8 @@ DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
 
 # -- kernel cost registry (observe/cost.py injects these at the custom
-# -- call instructions; tools/check_twin_flops.py asserts parity with
-# -- the dense twin) ---------------------------------------------------
+# -- call instructions; tests/test_observe_cost.py holds them to the
+# -- dense twin) -------------------------------------------------------
 #
 # Dense-equivalent convention: full Tq*Tk scores regardless of causal
 # (the twin computes the masked positions too), backward recompute of

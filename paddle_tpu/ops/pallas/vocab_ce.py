@@ -42,7 +42,8 @@ NEG = -1e30
 #    compiles and measured fastest — bench sweep on chip:
 #    (256,512) 0.3146 MFU < (512,512) 0.3204 ~ (1024,512) 0.3205
 #    < (512,1024) 0.3250 (ties the unfused baseline at len256 and
-#    beats it as part of the longctx stack: 0.3036 -> 0.3063, AB_r05.json).
+#    beats it as part of the longctx stack: 0.3036 -> 0.3063; r05,
+#    pre-ledger, not measured on the current code).
 DEFAULT_BLOCK_T = 512
 DEFAULT_BLOCK_V = 1024
 

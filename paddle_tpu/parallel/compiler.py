@@ -1,4 +1,4 @@
-"""CompiledProgram: multi-device compilation of a Program.
+"""CompiledProgram: the placement of a Program's step on a mesh.
 
 reference: python/paddle/fluid/compiler.py:33 CompiledProgram
 .with_data_parallel (the forward-looking API wrapping ParallelExecutor,
@@ -8,6 +8,12 @@ with NamedShardings: feeds sharded over the batch ("dp") axis, params
 replicated (AllReduce mode) or sharded (Reduce/FSDP mode, or tensor-
 parallel rules) — XLA GSPMD partitions the computation and inserts the
 ICI collectives, including the gradient all-reduce.
+
+This module owns WHERE things lie: the mesh, the sharding rules, the
+sharding of every state and feed array, the trace-time mesh context and
+the step cache of its program.  `core/executor.py` owns the step: it
+builds, caches, places, calls and writes back, and takes a
+CompiledProgram as the optional placement of that one path.
 """
 
 from __future__ import annotations
@@ -16,9 +22,9 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from ..core.executor import RNG_STATE_VAR, interpret_program
+from ..core.executor import RNG_STATE_VAR, global_scope
 from ..core.program import Program
-from .mesh import get_default_mesh
+from .mesh import executing_mesh, get_default_mesh, make_mesh
 from .strategies import ShardingRules
 
 
@@ -76,6 +82,7 @@ class CompiledProgram:
         self._mesh = None
         self._batch_axis = "dp"
         self._rules: Optional[ShardingRules] = None
+        # the executor's step cache and AOT memo for this placement
         self._cache: Dict[Any, Any] = {}
         self._loss_name = None
         self._accum_steps = 1
@@ -166,36 +173,38 @@ class CompiledProgram:
                                          batch_axis=self._batch_axis)
         return NamedSharding(self._mesh, P(*spec))
 
-    # -- execution -------------------------------------------------------
+    def _ensure_mesh(self):
+        """The mesh; a bare CompiledProgram(program) compiles for one
+        device, like fluid without with_data_parallel."""
+        if self._mesh is None:
+            self._mesh = make_mesh({"dp": 1})
+            if self._rules is None:
+                self._rules = ShardingRules()
+        return self._mesh
+
+    def _trace_context(self):
+        """Entered around the step's trace: what mesh-aware op impls and
+        the explicit grad_sync body read (parallel/mesh.py ExecContext)."""
+        return executing_mesh(self._mesh, batch_axis=self._batch_axis,
+                              pipeline_microbatches=self._pp_microbatches,
+                              rules=self._rules)
+
+    # -- execution: the executor's one path, with self as placement ------
     def run(self, executor, feed: Dict[str, Any], fetch_names, scope,
             return_numpy: bool = True, iterations: int = 1,
             accumulation_steps: int = 1):
-        from ..core.executor import _debug_checks
-        from ..observe.monitoring import runtime_stats
-
-        fn, state, feed_arrays, _, _ = self._prepare_step(
-            feed, fetch_names, scope, iterations, accumulation_steps)
-        with runtime_stats.phase("call"):
-            new_state, fetches = fn(state, feed_arrays)
-        with runtime_stats.phase("writeback"):
-            for name, val in new_state.items():
-                scope.set_var(name, val)
-            # the last references to the donated arrays: freeing some
-            # 600 of them is host time of the step, so it is timed
-            del state
-            _debug_checks(fetch_names, fetches, new_state)
-            if return_numpy:
-                fetches = [np.asarray(f) for f in fetches]
-        return fetches
+        return executor.run(self, feed=feed, fetch_list=fetch_names,
+                            scope=scope, return_numpy=return_numpy,
+                            iterations=iterations,
+                            accumulation_steps=accumulation_steps)
 
     def compiled_hlo_text(self, feed: Dict[str, Any], fetch_names,
                           scope, iterations: int = 1) -> str:
         """AOT-lower the sharded step and return the compiled
         (post-SPMD-partitioning) HLO text — for inspecting which
         collectives GSPMD inserted (e.g. asserting MoE dispatch lowers
-        to all-to-all, tests/test_moe.py) and for roofline tooling.
-        One extra XLA compile; the traced fn comes from the same
-        cache as run()."""
+        to all-to-all, tests/test_moe.py).  One extra XLA compile; the
+        traced fn comes from the same cache as run()."""
         return self.compiled_step(feed, fetch_names, scope,
                                   iterations=iterations).as_text()
 
@@ -204,178 +213,19 @@ class CompiledProgram:
                       with_names: bool = False):
         """AOT-compile the SHARDED step and return the jax Compiled
         object — the multi-device analog of Executor.compiled_step.
-        This is what the dp bench's comm accounting reads: the
-        post-SPMD module's collective instructions land in
+        The post-SPMD module's collective instructions land in
         observe.cost's `comm` bucket (all-reduce/all-gather/
-        reduce-scatter/all-to-all/collective-permute), so
-        `comm_bytes` comes from the SAME analytic accounting as every
-        other bucket.  Memoized per (feed signature, fetches,
-        iterations) — bench's comm fields reuse one compile.
+        reduce-scatter/all-to-all/collective-permute), so `comm_bytes`
+        comes from the SAME analytic accounting as every other bucket.
+        Memoized per (feed signature, fetches, iterations).
 
         with_names=True returns (compiled, arg_names) like
         Executor.compiled_step: the per-entry-parameter
         ("state"|"feed", var_name) labels observe.memory uses to
-        attribute PER-DEVICE buffer bytes to named state vars — how
-        the fsdp A/B proves opt-state bytes actually dropped on the
-        sharded step."""
-        from ..core.executor import global_scope
+        attribute PER-DEVICE buffer bytes to named state vars."""
+        from ..core.executor import Executor
 
-        fn, state, feed_arrays, _, _ = self._prepare_step(
-            feed, list(fetch_names), scope or global_scope(),
-            iterations, 1)
-        key = (self._program._uid, self._program._version,
-               tuple(sorted(feed)), tuple(fetch_names), iterations,
-               tuple((n, tuple(getattr(v, "shape", ()) or ()),
-                      str(getattr(v, "dtype", type(v).__name__)))
-                     for n, v in sorted(feed_arrays.items())))
-        entry = self._aot_cache.get(key)
-        if entry is None:
-            from ..observe.memory import _arg_labels
-
-            compiled = fn.lower(state, feed_arrays).compile()
-            entry = (compiled,
-                     _arg_labels(state, feed_arrays, compiled=compiled))
-            self._aot_cache[key] = entry
-        return entry if with_names else entry[0]
-
-    def _prepare_step(self, feed, fetch_names, scope, iterations,
-                      accumulation_steps):
-        """The step's first two host phases (observe.monitoring):
-        `prepare` (`_lookup_step`) and `place` (every state array and
-        every feed array through `jax.device_put`, a no-op for what is
-        already placed; the two child spans say which of them costs)."""
-        import jax
-        import jax.numpy as jnp
-        from jax.profiler import TraceAnnotation
-
-        from ..observe.monitoring import SPAN_PREFIX, runtime_stats
-
-        with runtime_stats.phase("prepare"):
-            (fn, state_shardings, feed_shardings), state = \
-                self._lookup_step(feed, fetch_names, scope, iterations,
-                                  accumulation_steps)
-        with runtime_stats.phase("place"):
-            with TraceAnnotation(SPAN_PREFIX + "place_state"):
-                state = {n: jax.device_put(v, state_shardings[n])
-                         for n, v in state.items()}
-            with TraceAnnotation(SPAN_PREFIX + "place_feed"):
-                feed_arrays = {
-                    n: jax.device_put(jnp.asarray(v), feed_shardings[n])
-                    for n, v in feed.items()}
-        return fn, state, feed_arrays, state_shardings, feed_shardings
-
-    def _lookup_step(self, feed, fetch_names, scope, iterations,
-                     accumulation_steps):
-        """RNG and telemetry state, state names, feed shardings, cache
-        key and look-up, and on a miss the step's build.  Returns
-        ((fn, state_shardings, feed_shardings), state as the scope
-        holds it)."""
-        import jax
-
-        # an explicit per-run override wins over the BuildStrategy knob
-        accum = (accumulation_steps if accumulation_steps != 1
-                 else self._accum_steps)
-
-        if self._mesh is None:
-            # bare CompiledProgram(program): single-device compilation,
-            # like fluid without with_data_parallel
-            from .mesh import make_mesh
-
-            self._mesh = make_mesh({"dp": 1})
-            if self._rules is None:
-                self._rules = ShardingRules()
-
-        program = self._program
-        block = program.global_block()
-        if RNG_STATE_VAR not in scope.vars:
-            scope.set_var(RNG_STATE_VAR,
-                          jax.random.PRNGKey(program.random_seed))
-        state_names = tuple(sorted(
-            v.name for v in block.vars.values()
-            if v.persistable and scope.has_var(v.name)))
-        from ..observe import metrics as _obs_metrics
-
-        telemetry = getattr(program, "_telemetry_enabled", False)
-        if telemetry:
-            # mirror Executor._prepare: the device-side accumulator
-            # rides the (donated) state pytree so enable_telemetry()
-            # works identically under a mesh — bench dp entries carry
-            # the same honesty counters as single-device ones (and the
-            # same numerics fields when the program opted in)
-            tel_cur = scope.find_var(_obs_metrics.TELEMETRY_VAR)
-            if tel_cur is None:
-                scope.set_var(_obs_metrics.TELEMETRY_VAR,
-                              _obs_metrics.init_telemetry_for(program))
-            else:
-                patched = _obs_metrics.ensure_numerics_fields(
-                    program, tel_cur)
-                if patched is not tel_cur:
-                    scope.set_var(_obs_metrics.TELEMETRY_VAR, patched)
-            state_names = state_names + (_obs_metrics.TELEMETRY_VAR,)
-        feed_shardings = {n: self._feed_sharding(n, v)
-                          for n, v in feed.items()}
-        # the chosen feed shardings are part of the key: a final partial
-        # batch that is no longer dp-divisible must recompile with a
-        # replicated layout rather than reuse the sharded executable
-        feed_sig = tuple(sorted(
-            (n, str(s.spec)) for n, s in feed_shardings.items()))
-        key = (program._uid, program._version, feed_sig,
-               tuple(fetch_names), state_names, id(self._mesh), iterations,
-               accum)
-        entry = self._cache.get(key)
-
-        state = {n: scope.find_var(n) for n in state_names}
-        state[RNG_STATE_VAR] = scope.find_var(RNG_STATE_VAR)
-
-        if entry is None:
-            state_shardings = {n: self._state_sharding(n, v)
-                               for n, v in state.items()}
-            persistable_names = tuple(sorted(
-                v.name for v in block.vars.values() if v.persistable))
-
-            feed_names = tuple(sorted(feed))
-
-            def step(st, feeds):
-                from .mesh import executing_mesh
-
-                rng_key = st[RNG_STATE_VAR]
-                env = {k: v for k, v in st.items() if k != RNG_STATE_VAR}
-                env.update(feeds)
-                with executing_mesh(
-                        self._mesh, batch_axis=self._batch_axis,
-                        pipeline_microbatches=self._pp_microbatches):
-                    env = interpret_program(program, env, rng_key,
-                                            fetch_names=fetch_names,
-                                            accum_steps=accum,
-                                            feed_names=feed_names)
-                new_state = {n: env[n] for n in persistable_names
-                             if n in env}
-                from ..observe.metrics import TELEMETRY_VAR
-
-                if TELEMETRY_VAR in env:
-                    # executor-private state (not a block var): threads
-                    # the step + chain_iterations carry, same as the
-                    # single-device step fn
-                    new_state[TELEMETRY_VAR] = env[TELEMETRY_VAR]
-                new_state[RNG_STATE_VAR] = jax.random.split(rng_key, 1)[0]
-                fetches = [env[n] for n in fetch_names]
-                return new_state, fetches
-
-            from ..core.executor import chain_iterations
-
-            fn = jax.jit(
-                chain_iterations(step, iterations),
-                in_shardings=(state_shardings, feed_shardings),
-                # pin the updated state to the SAME shardings it came
-                # in with: without this XLA may infer a different
-                # (replicated) output layout for ZeRO-sharded optimizer
-                # state, which silently breaks donation — per-device
-                # opt-state bytes then DOUBLE (input + undonated
-                # output) and an all-gather sneaks into every step
-                out_shardings=(state_shardings, None),
-                donate_argnums=(0,),
-            )
-            entry = (fn, state_shardings, feed_shardings)
-            self._cache[key] = entry
-
-        return entry, state
+        # the caches are this placement's, so any executor serves
+        return Executor()._compiled_step(
+            self._program, feed, list(fetch_names), scope or global_scope(),
+            iterations, with_names, placement=self)

@@ -46,12 +46,15 @@ class ExecContext(NamedTuple):
     """What a CompiledProgram trace exposes to mesh-aware op impls:
     the mesh, the name of the mesh axis the batch dim is sharded over
     (so sp/pp shard_maps keep dp-sharded activations sharded instead of
-    assuming the axis is literally called "dp"), and the pipeline
-    microbatch count (0 = pipelining off)."""
+    assuming the axis is literally called "dp"), the pipeline
+    microbatch count (0 = pipelining off), and the placement's
+    ShardingRules (the explicit grad_sync body asks them for the data
+    axes and for each param's spec)."""
 
     mesh: object
     batch_axis: str = "dp"
     pipeline_microbatches: int = 0
+    rules: object = None
 
 
 # ContextVar, not a module global: two CompiledPrograms tracing
@@ -66,11 +69,12 @@ class executing_mesh:
     under.  Mesh-aware op impls (sequence-parallel flash attention, the
     pipeline engine) read it via get_executing_mesh() /
     get_exec_context() to route onto shard_map collectives; it is set
-    only while the wrapper traces its step."""
+    only while the executor traces a step that has a placement."""
 
     def __init__(self, mesh, batch_axis: str = "dp",
-                 pipeline_microbatches: int = 0):
-        self._ctx = ExecContext(mesh, batch_axis, pipeline_microbatches)
+                 pipeline_microbatches: int = 0, rules=None):
+        self._ctx = ExecContext(mesh, batch_axis, pipeline_microbatches,
+                                rules)
 
     def __enter__(self):
         self._token = _exec_ctx.set(self._ctx)

@@ -116,8 +116,7 @@ class DecodeConfig:
     eos_id: optional stop token.
     kv_dtype: pool storage — "float32" (exact parity), "bfloat16"
         (default production), or "int8" (per-row scale sidecars,
-        opt-in; A/B'd in AB_r09.json, default stays bf16 pending a
-        chip wall-clock win).
+        opt-in; default stays bf16, no ledger row on either side).
     """
 
     def __init__(self, num_slots: int = 8, page_size: int = 16,

@@ -116,12 +116,22 @@ def test_telemetry_off_is_zero_footprint():
     assert observe.fetch_telemetry(scope) is None
 
 
-def test_retrace_counter_increments_exactly_once_on_shape_change():
+def _wrap(main, loss, mesh):
+    if mesh:
+        from paddle_tpu.parallel import make_mesh
+
+        fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name, mesh=make_mesh(mesh))
+
+
+@pytest.mark.parametrize("mesh", [None, {"dp": 2}])
+def test_retrace_counter_increments_exactly_once_on_shape_change(mesh):
     main, startup, scope, loss = _linreg_program()
     rng = np.random.RandomState(0)
     with fluid.scope_guard(scope):
         exe = fluid.Executor()
         exe.run(startup)
+        _wrap(main, loss, mesh)
         exe.run(main, feed=_feed(rng, 8), fetch_list=[loss])
         snap = observe.runtime_stats.snapshot()
         # same signature: cached, no retrace
@@ -134,20 +144,26 @@ def test_retrace_counter_increments_exactly_once_on_shape_change():
         # seen signature again: still one
         exe.run(main, feed=_feed(rng, 6), fetch_list=[loss])
         assert observe.runtime_stats.delta(snap)["retraces"] == 1
+        assert observe.runtime_stats.delta(snap)["builds"] == 0
 
 
-def test_compile_accounting_sees_backend_compiles():
+@pytest.mark.parametrize("mesh", [None, {"dp": 2}])
+def test_compile_accounting_sees_backend_compiles(mesh):
     main, startup, scope, loss = _linreg_program()
     rng = np.random.RandomState(1)
     snap = observe.runtime_stats.snapshot()
     with fluid.scope_guard(scope):
         exe = fluid.Executor()
         exe.run(startup)
+        _wrap(main, loss, mesh)
+        started = observe.runtime_stats.snapshot()
+        exe.run(main, feed=_feed(rng), fetch_list=[loss])
         exe.run(main, feed=_feed(rng), fetch_list=[loss])
     d = observe.runtime_stats.delta(snap)
     assert d["compiles"] >= 1
     assert d["compile_time_s"] > 0.0
-    assert d["builds"] >= 1
+    assert d["builds"] == 2       # the start-up program, the step
+    assert observe.runtime_stats.delta(started)["builds"] == 1
     assert d["dispatches"] >= 1
 
 
@@ -296,11 +312,7 @@ def test_every_run_records_four_phases_and_one_dispatch(mesh):
     with fluid.scope_guard(scope):
         exe = fluid.Executor()
         exe.run(startup)
-        if mesh:
-            from paddle_tpu.parallel import make_mesh
-
-            fluid.CompiledProgram(main).with_data_parallel(
-                loss_name=loss.name, mesh=make_mesh(mesh))
+        _wrap(main, loss, mesh)
         exe.run(main, feed=_feed(rng), fetch_list=[loss])
         snap = observe.runtime_stats.snapshot()
         tails = {p: len(observe.runtime_stats.recent(p))
@@ -326,11 +338,7 @@ def test_phase_spans_lie_in_a_trace_nested_in_order(tmp_path, mesh):
     with fluid.scope_guard(scope):
         exe = fluid.Executor()
         exe.run(startup)
-        if mesh:
-            from paddle_tpu.parallel import make_mesh
-
-            fluid.CompiledProgram(main).with_data_parallel(
-                loss_name=loss.name, mesh=make_mesh(mesh))
+        _wrap(main, loss, mesh)
         feed = _feed(rng)
         exe.run(main, feed=feed, fetch_list=[loss])
         with jax.profiler.trace(str(tmp_path)):
