@@ -117,6 +117,8 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None,
     on one fixed batch; the loss must be finite and fall, and no step
     after the first may compile.  `masks`: the `dropout` ops the step's
     build must count as drawn by (the Pallas kernel, jax.random).
+    Over a mesh every step after the first must put its feeds and
+    nothing else: the state lies where the step left it.
     `inspect(main, scope, loss, feed)` runs last, inside the guards;
     its dict joins the phase line.  Returns (per-step losses, that
     dict)."""
@@ -151,10 +153,19 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None,
         t0 = time.perf_counter()
         losses += [step() for _ in range(STEPS - 1)]
         run_s = time.perf_counter() - t0
-        late = runtime_stats.delta(snap)["compiles"]
+        late = runtime_stats.delta(snap)
+        # the step's state: the persistable vars and the RNG key
+        n_state = 1 + sum(v.persistable and scope.has_var(v.name)
+                          for v in main.global_block().vars.values())
         extra = inspect(main, scope, loss, feed) if inspect else {}
     assert np.isfinite(losses).all(), (phase, losses)
-    assert late == 0, f"{phase}: {late} compile(s) after the first step"
+    assert late["compiles"] == 0, \
+        f"{phase}: {late['compiles']} compile(s) after the first step"
+    placed = (late["place_puts"] / (STEPS - 1),
+              late["place_skips"] / (STEPS - 1))
+    assert placed == ((len(feed), n_state) if mesh_axes else (0, 0)), \
+        f"{phase}: a step (put, passed through) {placed} arrays, " \
+        f"feeds {len(feed)}, state {n_state}"
     drawn = (cold["dropout_masks_kernel"], cold["dropout_masks_xla"])
     assert drawn == masks, \
         f"{phase}: dropout masks by (kernel, jax.random) {drawn}, " \
@@ -167,6 +178,7 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None,
          step_ms=round(1e3 * run_s / (STEPS - 1), 2),
          first_loss=losses[0], last_loss=losses[-1],
          dropout_masks_kernel=drawn[0], dropout_masks_xla=drawn[1],
+         place_puts_per_step=placed[0], place_skips_per_step=placed[1],
          peak_bytes=peak_bytes(), **extra)
     del exe, scope, main, startup
     gc.collect()

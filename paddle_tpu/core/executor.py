@@ -1252,10 +1252,10 @@ class Executor:
                  accumulation_steps: int = 1, placement=None):
         """Shared run()/compiled_step() setup, as the step's first two
         host phases (observe.monitoring): `prepare` (`_lookup_step`)
-        and `place` (feed conversion, under a placement every state and
-        feed array through `jax.device_put`, a no-op for what is
-        already placed, with a child span each; and the retrace check,
-        which reads the converted feed's dtypes)."""
+        and `place` (feed conversion; under a placement `_place` for
+        the state and for the feed, with a child span each, counted in
+        `place_puts` / `place_skips`; and the retrace check, which reads
+        the converted feed's dtypes)."""
         from ..observe.monitoring import SPAN_PREFIX, runtime_stats
 
         with runtime_stats.phase("prepare"):
@@ -1268,18 +1268,15 @@ class Executor:
                 feed_arrays = {n: _to_array(v, block)
                                for n, v in feed.items()}
             else:
-                import jax
-                import jax.numpy as jnp
                 from jax.profiler import TraceAnnotation
 
                 state_shardings, feed_shardings = shardings
                 with TraceAnnotation(SPAN_PREFIX + "place_state"):
-                    state = {n: jax.device_put(v, state_shardings[n])
-                             for n, v in state.items()}
+                    state = _place(state, state_shardings)
                 with TraceAnnotation(SPAN_PREFIX + "place_feed"):
-                    feed_arrays = {
-                        n: jax.device_put(jnp.asarray(v), feed_shardings[n])
-                        for n, v in feed.items()}
+                    feed_arrays = _place(
+                        {n: _one_array(v) for n, v in feed.items()},
+                        feed_shardings)
             sig = _feed_signature(feed_arrays)
             if seen and sig not in seen:
                 runtime_stats.record_retrace()
@@ -1441,6 +1438,51 @@ def _feed_signature(feed_arrays) -> tuple:
         (n, tuple(getattr(v, "shape", ()) or ()),
          str(getattr(v, "dtype", type(v).__name__)))
         for n, v in sorted(feed_arrays.items()))
+
+
+def _place(values, shardings):
+    """`values` laid out as `shardings` say, for the step's
+    `in_shardings`: what already lies there is passed through as the
+    object it is (after the first step the whole state: the step's
+    `out_shardings` are these shardings); anything else goes to its
+    sharding in ONE `jax.device_put`, which cuts a host value on the
+    host and sends each chip its own piece while the previous step
+    runs.  A host value must not pass through one chip on the way
+    (`jnp.asarray`): resharding from there is a program on that chip's
+    stream, behind the running step, and chip-to-chip copies the next
+    step then waits for.  Counted in `runtime_stats.place_puts` /
+    `place_skips`."""
+    import jax
+
+    from ..observe.monitoring import runtime_stats
+
+    placed, puts = {}, 0
+    for name, value in values.items():
+        if not _lies_at(value, shardings[name]):
+            value = jax.device_put(value, shardings[name])
+            puts += 1
+        placed[name] = value
+    runtime_stats.record_place(puts, len(placed) - puts)
+    return placed
+
+
+def _lies_at(value, sharding) -> bool:
+    """An array whose real sharding is `sharding`; of the telemetry
+    accumulator (a dict of arrays under one sharding), every leaf."""
+    import jax
+
+    if isinstance(value, dict):
+        return all(_lies_at(v, sharding) for v in value.values())
+    return isinstance(value, jax.Array) and value.sharding == sharding
+
+
+def _one_array(value):
+    """A feed as `jax.device_put` reads ONE array from.  Arrays and
+    scalars go as they are: `device_put` gives them the dtype
+    `jnp.asarray` would (int64 -> int32, float64 -> float32, a Python
+    scalar weakly typed).  A list it would read as a pytree of
+    scalars: the numpy array of it."""
+    return np.asarray(value) if isinstance(value, (list, tuple)) else value
 
 
 def _to_array(value, block):

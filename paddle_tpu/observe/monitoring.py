@@ -37,6 +37,7 @@ _TRACE_EVENT_PREFIXES = ("/jax/core/compile/jaxpr_trace_duration",
 
 _FIELDS = ("compiles", "compile_time_s", "trace_time_s", "builds",
            "retraces", "dispatches", "dispatch_time_s",
+           "place_puts", "place_skips",
            "dropout_masks_kernel", "dropout_masks_xla")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -82,6 +83,11 @@ class RuntimeStats:
         self.dispatches = 0         # step dispatches: the `call` phase
         self.dispatch_time_s = 0.0  # host enqueue time (async; excludes
         #                             device execution)
+        # state and feed arrays the `place` phase met under a placement:
+        # put to their sharding / passed through because they lay there
+        # already (a steady step: the feeds / the whole state)
+        self.place_puts = 0
+        self.place_skips = 0
         # `dropout` ops traced, by where their keep-mask is drawn: the
         # Pallas kernel on the chip's generator, or jax.random.bernoulli
         # (a step that fell back says so; delta() around a build)
@@ -108,6 +114,11 @@ class RuntimeStats:
     def record_retrace(self):
         with self._lock:
             self.retraces += 1
+
+    def record_place(self, puts: int, skips: int):
+        with self._lock:
+            self.place_puts += puts
+            self.place_skips += skips
 
     def record_dropout_mask(self, kernel: bool):
         with self._lock:
