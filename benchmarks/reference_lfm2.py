@@ -1,41 +1,22 @@
-"""Plain float32 reference of the decoder-only MoE language model that
-`models/decoder.py` builds (OLMoE-1B-7B, Muennighoff et al. 2024,
-arXiv:2409.02060; layer equations as the published `modeling_olmoe`).
+"""Plain float32 reference of LFM2-24B-A2B's forward pass, loss and
+gradients (layer equations as the public `lfm2_moe`
+implementation): the benchmark's own copy of the `lfm2_*`
+half of `paddle_tpu/models/decoder_reference.py`, so that the
+comparison that decides a cell's correctness does not move when the
+program does.  `tests/benchmark/test_lfm2_cell.py` holds the two to
+the same numbers.
 
 Straightforward `jax.numpy`, float32, every matmul under
 `jax.default_matmul_precision("highest")`.  No Program, no Executor,
-no AMP, no kernel, no sort: attention materialises the (T, T) scores,
-and the expert layer is a python loop over ALL experts, each a dense
-SwiGLU FFN applied to every token and weighted by the router
-probability where the expert is among the token's top k and by zero
-where it is not.  Gradients are `jax.grad` of `loss`.
-
-It exists to be compared with (tests/test_decoder_parity.py on the
-CPU at a small size, benchmarks/olmoe_parity.py on the chip at the
-published widths), never to be fast.
+no AMP, no kernel, no sort: the short convolution is one shifted
+product a tap, attention materialises its scores (`q_block` rows at a
+time where 8192 positions would not fit otherwise) and repeats the
+key/value heads, and the expert layer is a python loop over the held
+experts, each a dense SwiGLU FFN applied to every token and weighted
+by the router's weight where the expert is among the token's four and
+by zero where it is not.
 
 Departures from the published description, each deliberate:
-
-- the load-balancing loss takes `f_e` as the share of the T*k
-  (token, expert) assignments that went to expert e, as the paper's
-  training code (megablocks) computes it; the `transformers` port
-  divides by T alone and so reads k times larger;
-- both auxiliary losses are averaged over layers (the training code's
-  convention; the weights 0.01 and 0.001 apply to those means);
-- no dropout (the model has none) and no `clip_qkv` (null in the
-  published configuration);
-- RoPE's frequencies `theta^(-2i/D)` are computed on the host (numpy
-  float32), as a checkpoint's `inv_freq` buffer is, not with
-  `jax.numpy`: run eagerly on a TPU, float32 `pow` is off by 3.6e-6,
-  which at position 4095 turns a head by 1.5e-2 rad (measured, PERF.md
-  PR 26).  Positions times frequencies, and the sines, stay float32.
-
-The second half of the file is LFM2-24B-A2B's forward
-(layer equations as the public `lfm2_moe` implementation; `lfm2_*`
-below): gated short convolutions beside
-grouped-query attention with per-head QK-norm, a leading dense SwiGLU
-layer, and experts chosen by sigmoid score + a selection bias.  Its
-departures:
 
 - no auxiliary loss (the configuration has none);
 - the selection bias is an input that nothing updates (the
@@ -45,11 +26,13 @@ departures:
   published, the weights are the held experts', and what the experts
   held elsewhere would have added is LEFT OUT of the layer's result,
   here as in the program (size 1, rank 0: the whole layer).
-  `lfm2_experts` differentiates a share truly (the rank's own part of
-  every gradient); `lfm2_forward` makes the builder's decision, as
+  `experts` differentiates a share truly (the rank's own part of
+  every gradient); `forward` makes the builder's decision, as
   `models/decoder.py` does: under `expert_parallel_size` > 1 no
   exchange sums the ranks' parts, so the routing weights are constants
-  of the backward pass there (`router_gradient=False`).
+  of the backward pass there (`router_gradient=False`);
+- RoPE's frequencies are computed on the host (numpy float32), as a
+  checkpoint's `inv_freq` buffer is (PERF.md, PR 26).
 """
 
 from __future__ import annotations
@@ -57,25 +40,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-# parameters of one layer, in the order `models/decoder.py` creates them
-LAYER_KEYS = ("attn_norm", "wq", "q_norm", "wk", "k_norm", "wv", "wo",
-              "ffn_norm", "router", "w1", "w2", "w3")
-
-
-def params_from_list(arrays, num_hidden_layers):
-    """The reference's parameter tree from a flat list in the
-    builder's creation order: embedding, `LAYER_KEYS` per layer, final
-    norm, head."""
-    arrays = [jnp.asarray(a, jnp.float32) for a in arrays]
-    n = len(LAYER_KEYS)
-    if len(arrays) != 1 + n * num_hidden_layers + 2:
-        raise ValueError(f"{len(arrays)} arrays for "
-                         f"{num_hidden_layers} layers")
-    layers = [dict(zip(LAYER_KEYS, arrays[1 + i * n:1 + (i + 1) * n]))
-              for i in range(num_hidden_layers)]
-    return {"embed": arrays[0], "layers": layers,
-            "final_norm": arrays[-2], "head": arrays[-1]}
 
 
 def rms_norm(x, weight, eps):
@@ -99,112 +63,31 @@ def rope(x, theta):
     return x * jnp.cos(emb) + rotate_half(x) * jnp.sin(emb)
 
 
-def attention(x, layer, cfg):
-    n, t, _ = x.shape
-    heads = cfg["num_attention_heads"]
-    d = cfg["hidden_size"] // heads
-    eps, theta = cfg["rms_norm_eps"], float(cfg["rope_theta"])
-    q = rms_norm(x @ layer["wq"], layer["q_norm"], eps)
-    k = rms_norm(x @ layer["wk"], layer["k_norm"], eps)
-    v = x @ layer["wv"]
-    q = rope(q.reshape(n, t, heads, d), theta)
-    k = rope(k.reshape(n, t, heads, d), theta)
-    v = v.reshape(n, t, heads, d)
-    scores = jnp.einsum("nqhd,nkhd->nhqk", q, k) / jnp.sqrt(float(d))
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    out = jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(scores, axis=-1), v)
-    return out.reshape(n, t, heads * d) @ layer["wo"]
-
-
-def experts(x, layer, cfg):
-    """x (T, D) -> (y (T, D), load-balancing loss, z-loss, counts (E,),
-    chosen experts (T, k))."""
-    e, k = cfg["num_experts"], cfg["num_experts_per_tok"]
-    logits = x @ layer["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)
-    chosen = jnp.sum(jax.nn.one_hot(top_e, e, dtype=jnp.float32), axis=1)
-    gate = probs * chosen
-    if cfg["norm_topk_prob"]:
-        gate = gate / jnp.sum(top_p, axis=-1, keepdims=True)
-    y = jnp.zeros_like(x)
-    for i in range(e):
-        hidden = jax.nn.silu(x @ layer["w1"][i]) * (x @ layer["w3"][i])
-        y = y + gate[:, i:i + 1] * (hidden @ layer["w2"][i])
-    counts = jnp.sum(chosen, axis=0)
-    share = counts / (x.shape[0] * k)
-    aux = e * jnp.sum(share * jnp.mean(probs, axis=0))
-    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    return y, aux, z, counts, top_e
-
-
-def forward(params, tokens, cfg):
-    """tokens (N, T) int -> dict(logits (N, T, V), aux, z (means over
-    layers), counts [(E,) per layer], experts [(N*T, k) per layer])."""
-    with jax.default_matmul_precision("highest"):
-        x = params["embed"][tokens]
-        n, t, d = x.shape
-        aux, z, counts, chosen = [], [], [], []
-        for layer in params["layers"]:
-            x = x + attention(
-                rms_norm(x, layer["attn_norm"], cfg["rms_norm_eps"]),
-                layer, cfg)
-            h = rms_norm(x, layer["ffn_norm"], cfg["rms_norm_eps"])
-            y, a, zz, c, te = experts(h.reshape(n * t, d), layer, cfg)
-            x = x + y.reshape(n, t, d)
-            aux.append(a), z.append(zz), counts.append(c), chosen.append(te)
-        x = rms_norm(x, params["final_norm"], cfg["rms_norm_eps"])
-        head = (params["embed"].T if cfg["tie_word_embeddings"]
-                else params["head"])
-        return {"logits": x @ head, "aux": sum(aux) / len(aux),
-                "z": sum(z) / len(z), "counts": counts, "experts": chosen}
-
-
-def loss(params, tokens, labels, cfg, aux_loss_weight=0.01,
-         z_loss_weight=0.001):
-    """(total, parts): mean token cross-entropy + the weighted
-    auxiliary losses; `parts` is `forward`'s dict plus `ce`."""
-    out = forward(params, tokens, cfg)
-    logp = jax.nn.log_softmax(out["logits"], axis=-1)
-    ce = -jnp.mean(jnp.take_along_axis(logp, labels[..., None], axis=-1))
-    total = ce + aux_loss_weight * out["aux"] + z_loss_weight * out["z"]
-    return total, dict(out, ce=ce)
-
-
-def loss_and_grads(params, tokens, labels, cfg, **weights):
-    """((total, parts), gradient tree shaped like `params`)."""
-    return jax.value_and_grad(loss, has_aux=True)(params, tokens, labels,
-                                                  cfg, **weights)
-
-
-# -- LFM2 -------------------------------------------------------------------
-
-LFM2_OPERATOR_KEYS = {
+OPERATOR_KEYS = {
     "conv": ("op_norm", "w_in", "filter", "w_out"),
     "full_attention": ("op_norm", "wq", "q_norm", "wk", "k_norm", "wv",
                        "wo"),
 }
-LFM2_FFN_KEYS = {"dense": ("ffn_norm", "w1", "w3", "w2"),
+FFN_KEYS = {"dense": ("ffn_norm", "w1", "w3", "w2"),
                  "experts": ("ffn_norm", "router", "w1", "w2", "w3")}
 
 
-def lfm2_layer_keys(cfg, i):
+def layer_keys(cfg, i):
     """Parameters of layer i in the order `models/decoder.py` creates
     them."""
     ffn = "dense" if i < cfg["num_dense_layers"] else "experts"
-    return LFM2_OPERATOR_KEYS[cfg["layer_types"][i]] + LFM2_FFN_KEYS[ffn]
+    return OPERATOR_KEYS[cfg["layer_types"][i]] + FFN_KEYS[ffn]
 
 
-def lfm2_params_from_list(arrays, cfg, biases=None):
+def params_from_list(arrays, cfg, biases=None):
     """The parameter tree from a flat list in the builder's creation
-    order: embedding, `lfm2_layer_keys` per layer, final norm, head.
+    order: embedding, `layer_keys` per layer, final norm, head.
     `biases`: the selection bias (E,) of each routed layer, in order
     (not parameters: no gradient reaches them); None = zeros."""
     arrays = [jnp.asarray(a, jnp.float32) for a in arrays]
     layers, at, routed = [], 1, 0
     for i in range(cfg["num_hidden_layers"]):
-        keys = lfm2_layer_keys(cfg, i)
+        keys = layer_keys(cfg, i)
         layer = dict(zip(keys, arrays[at:at + len(keys)]))
         at += len(keys)
         if "router" in layer:
@@ -219,7 +102,7 @@ def lfm2_params_from_list(arrays, cfg, biases=None):
             "final_norm": arrays[-2], "head": arrays[-1]}
 
 
-def lfm2_short_conv(h, layer):
+def short_conv(h, layer):
     """h (N, T, D) -> (N, T, D): B, C, u = split3(h W_in); a causal
     depthwise convolution of B * u, one tap at a time; gated by C."""
     d = h.shape[-1]
@@ -237,7 +120,7 @@ def lfm2_short_conv(h, layer):
     return (c * conv) @ layer["w_out"]
 
 
-def lfm2_attention(h, layer, cfg, q_block=None, remat=False):
+def attention(h, layer, cfg, q_block=None, remat=False):
     """Grouped-query causal attention: query head j reads key/value
     head j // (heads / kv heads); q, k normalised per head.  `q_block`:
     rows of the (T, T) scores computed at a time (so that 8192
@@ -274,7 +157,7 @@ def lfm2_attention(h, layer, cfg, q_block=None, remat=False):
         @ layer["wo"]
 
 
-def lfm2_experts(x, layer, cfg, router_gradient=True):
+def experts(x, layer, cfg, router_gradient=True):
     """x (T, D) -> (y (T, D), counts of the held experts (G,), chosen
     experts (T, k)).  Sigmoid scores; the k experts with the largest
     score + bias; weights the unbiased scores over their sum + 1e-6,
@@ -304,27 +187,27 @@ def lfm2_experts(x, layer, cfg, router_gradient=True):
     return y, counts, top_e
 
 
-def lfm2_decoder_layer(x, layer, kind, cfg, q_block=None, remat=False):
+def decoder_layer(x, layer, kind, cfg, q_block=None, remat=False):
     """One layer: x (N, T, D) -> (x, counts (G,) or None, experts
     (N*T, k) or None)."""
     eps = cfg["norm_eps"]
     n, t, d = x.shape
     h = rms_norm(x, layer["op_norm"], eps)
-    x = x + (lfm2_short_conv(h, layer) if kind == "conv"
-             else lfm2_attention(h, layer, cfg, q_block, remat))
+    x = x + (short_conv(h, layer) if kind == "conv"
+             else attention(h, layer, cfg, q_block, remat))
     h = rms_norm(x, layer["ffn_norm"], eps)
     if "router" not in layer:
         return x + (jax.nn.silu(h @ layer["w1"]) * (h @ layer["w3"])
                     ) @ layer["w2"], None, None
     # no exchange sums the ranks' parts of a share's gradient: the
     # builder's decision (models/decoder.py), made here as there
-    y, counts, top_e = lfm2_experts(
+    y, counts, top_e = experts(
         h.reshape(n * t, d), layer, cfg,
         router_gradient=cfg.get("expert_parallel_size", 1) == 1)
     return x + y.reshape(n, t, d), counts, top_e
 
 
-def lfm2_forward(params, tokens, cfg, q_block=None, remat=False):
+def forward(params, tokens, cfg, q_block=None, remat=False):
     """tokens (N, T) int -> dict(logits (N, T, V), counts [(G,) per
     routed layer], experts [(N*T, k) per routed layer]).  `remat`: a
     layer's (and an attention block's) intermediates are computed again
@@ -335,7 +218,7 @@ def lfm2_forward(params, tokens, cfg, q_block=None, remat=False):
         counts, chosen = [], []
         for kind, layer in zip(cfg["layer_types"], params["layers"]):
             def run(x, layer, kind=kind):
-                return lfm2_decoder_layer(x, layer, kind, cfg, q_block, remat)
+                return decoder_layer(x, layer, kind, cfg, q_block, remat)
 
             x, c, te = (jax.checkpoint(run) if remat else run)(x, layer)
             if c is not None:
@@ -345,18 +228,18 @@ def lfm2_forward(params, tokens, cfg, q_block=None, remat=False):
                 "experts": chosen}
 
 
-def lfm2_loss(params, tokens, labels, cfg, q_block=None, remat=False):
-    """(mean token cross-entropy, `lfm2_forward`'s dict plus `ce`)."""
-    out = lfm2_forward(params, tokens, cfg, q_block, remat)
+def loss(params, tokens, labels, cfg, q_block=None, remat=False):
+    """(mean token cross-entropy, `forward`'s dict plus `ce`)."""
+    out = forward(params, tokens, cfg, q_block, remat)
     logp = jax.nn.log_softmax(out["logits"], axis=-1)
     ce = -jnp.mean(jnp.take_along_axis(logp, labels[..., None], axis=-1))
     return ce, dict(out, ce=ce)
 
 
-def lfm2_loss_and_grads(params, tokens, labels, cfg, q_block=None):
+def loss_and_grads(params, tokens, labels, cfg, q_block=None):
     """((loss, parts), gradient tree shaped like `params`; the
     selection biases' entries are zeros: nothing reaches them).  With
     `q_block` the scores go `q_block` rows at a time and every layer is
     recomputed in the backward pass (`remat`)."""
-    return jax.value_and_grad(lfm2_loss, has_aux=True)(
+    return jax.value_and_grad(loss, has_aux=True)(
         params, tokens, labels, cfg, q_block, q_block is not None)

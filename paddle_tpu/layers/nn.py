@@ -132,7 +132,10 @@ def switch_moe(input, num_experts, d_inner, top_k=1,
 
 
 def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
-                 param_attr=None, name=None):
+                 param_attr=None, name=None, experts_held=None,
+                 routing="softmax", use_expert_bias=False,
+                 routed_scaling_factor=1.0, expert_bias_update_rate=0.0,
+                 router_gradient=True):
     """Dropless routed SwiGLU experts (ops/moe_dropless.py): every
     token goes to its `top_k` of `num_experts` experts, none is
     dropped, shapes are static.  Returns (out, aux_loss, z_loss,
@@ -146,7 +149,39 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
     Parameter names keep the `moe_gate` / `moe_expert` prefixes (the ep
     sharding rules and the numerics groups key on them): `.w_0` the
     gate projection W1 (E, D, H), `.w_1` the down projection W2
-    (E, H, D), `.w_2` the up projection W3 (E, D, H)."""
+    (E, H, D), `.w_2` the up projection W3 (E, D, H).
+
+    `experts_held=(first, count)`: this layer is ONE expert-parallel
+    rank's share.  The gate stays (D, num_experts) and routes over all
+    of them; the expert weights are (count, ...), experts first ..
+    first+count-1; `out` is the part of the layer's result those
+    experts give (the rest is left out, not stood in for), `counts` and
+    `.token_count` are (count,), and `<w_0>.off_share_count`, int32
+    (1,), counts the rows that went to experts not held.  The backward
+    pass is the rank's own part of every gradient, the one through the
+    routing weights among them: over the ranks the parts add up to the
+    whole layer's (tests/test_expert_share.py).
+
+    `router_gradient=False`: the routing weights are constants of the
+    backward pass, so nothing reaches the gate, or the layer's input,
+    through them (the experts' inputs and weights get their gradients
+    as ever).  What a program asks for that runs a share WITHOUT the
+    exchange that sums the ranks' parts: one rank's part sees only its
+    own experts do any good, and applied alone it pulls the routing
+    onto them (12.5% -> 46% of the rows within 150 AdamW steps at
+    LFM2's widths; PERF.md, PR 30).  The caller's decision, tied to
+    no other argument.
+
+    `routing="sigmoid"`: sigmoid scores in place of the soft-max; with
+    `use_expert_bias` the k experts are chosen on score +
+    `<gate>.expert_bias` (E,), persistable float32 state that no
+    gradient reaches (zero at start-up), and weighted with the
+    unbiased score, over the chosen scores' sum + 1e-6 with
+    `norm_topk_prob`, times `routed_scaling_factor`.
+    `expert_bias_update_rate` u > 0: each step moves every expert's
+    bias by u against its load (`bias += u * sign(mean - load)`, all
+    E experts, this call's rows): load balancing without an auxiliary
+    loss, what the bias is for; 0 leaves it where it is."""
     if isinstance(param_attr, ParamAttr) and param_attr.name:
         raise ValueError(
             "dropless_moe: a NAMED ParamAttr cannot apply to its four "
@@ -157,19 +192,20 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
     gate_w = gate_h.create_parameter(param_attr, shape=[d, num_experts],
                                      dtype=dtype)
     eh = LayerHelper("moe_expert", name=name and f"moe_expert_{name}")
+    held = num_experts if experts_held is None else int(experts_held[1])
 
     def expert_weight(fan_in, fan_out):
         # per-expert fans, as switch_moe: the rank-3 default would
         # read (E, D, H) as a conv kernel
         return eh.create_parameter(
-            param_attr, shape=[num_experts, fan_in, fan_out], dtype=dtype,
+            param_attr, shape=[held, fan_in, fan_out], dtype=dtype,
             default_initializer=Xavier(fan_in=fan_in, fan_out=fan_out))
 
     w1 = expert_weight(d, d_inner)
     w2 = expert_weight(d_inner, d)
     w3 = expert_weight(d, d_inner)
     total = eh.create_or_get_global_variable(
-        f"{w1.name}.token_count", [num_experts], "int32")
+        f"{w1.name}.token_count", [held], "int32")
     out_v = eh.create_variable_for_type_inference(dtype)
     aux = eh.create_variable_for_type_inference("float32")
     z = eh.create_variable_for_type_inference("float32")
@@ -177,36 +213,86 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
     experts = eh.create_variable_for_type_inference("int32")
     for v in (counts, experts, total):
         v.desc.stop_gradient = True
-    eh.append_op(
-        type="moe_dropless",
-        inputs={"X": [input], "GateW": [gate_w], "W1": [w1], "W3": [w3],
-                "W2": [w2], "TokenCount": [total]},
-        outputs={"Out": [out_v], "AuxLoss": [aux], "ZLoss": [z],
-                 "Counts": [counts], "Experts": [experts],
-                 "TokenCountOut": [total]},
-        attrs={"top_k": int(top_k),
-               "norm_topk_prob": bool(norm_topk_prob)})
+    ins = {"X": [input], "GateW": [gate_w], "W1": [w1], "W3": [w3],
+           "W2": [w2], "TokenCount": [total]}
+    outs = {"Out": [out_v], "AuxLoss": [aux], "ZLoss": [z],
+            "Counts": [counts], "Experts": [experts],
+            "TokenCountOut": [total]}
+    attrs = {"top_k": int(top_k), "norm_topk_prob": bool(norm_topk_prob)}
+    if not router_gradient:
+        attrs["router_gradient"] = False
+    if experts_held is not None:
+        attrs["experts_held"] = [int(experts_held[0]), held]
+        off_share = eh.create_or_get_global_variable(
+            f"{w1.name}.off_share_count", [1], "int32")
+        off_share.desc.stop_gradient = True
+        ins["OffShareCount"] = outs["OffShareCountOut"] = [off_share]
+    if routing != "softmax":
+        attrs["routing"] = routing
+        attrs["routed_scaling_factor"] = float(routed_scaling_factor)
+        if use_expert_bias:
+            bias = gate_h.create_or_get_global_variable(
+                f"{gate_w.name}.expert_bias", [num_experts], "float32")
+            bias.desc.stop_gradient = True
+            ins["Bias"] = [bias]
+            if expert_bias_update_rate:
+                attrs["bias_update_rate"] = float(expert_bias_update_rate)
+                outs["BiasOut"] = [bias]
+        elif expert_bias_update_rate:
+            raise ValueError("dropless_moe: expert_bias_update_rate "
+                             "needs use_expert_bias")
+    elif use_expert_bias or routed_scaling_factor != 1.0:
+        raise ValueError("dropless_moe: the selection bias and the "
+                         "scaling factor belong to routing='sigmoid'")
+    eh.append_op(type="moe_dropless", inputs=ins, outputs=outs, attrs=attrs)
     out_v.desc.shape = tuple(input.shape)
     aux.desc.shape = z.desc.shape = (1,)
-    counts.desc.shape = (num_experts,)
+    counts.desc.shape = (held,)
     return out_v, aux, z, counts, experts
 
 
 def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
-             name=None):
+             name=None, group_size=None):
     """Root-mean-square norm over the axes from `begin_norm_axis`, with
-    a learned scale initialised to 1 (no shift, no mean subtraction)."""
+    a learned scale initialised to 1 (no shift, no mean subtraction).
+    `group_size` g: the minor dim is groups of g (the heads of a
+    head-grouped projection), each normalised alone under one shared
+    scale (g,)."""
     helper = LayerHelper("rms_norm", name=name)
     begin = begin_norm_axis % len(input.shape)
+    attrs = {"begin_norm_axis": begin, "epsilon": epsilon}
+    if group_size:
+        attrs["group_size"] = int(group_size)
     scale = helper.create_parameter(
-        param_attr, shape=[int(np.prod(input.shape[begin:]))],
+        param_attr,
+        shape=[int(group_size or np.prod(input.shape[begin:]))],
         dtype=input.dtype, default_initializer=Constant(1.0))
     y = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(type="rms_norm",
                      inputs={"X": [input], "Scale": [scale]},
-                     outputs={"Y": [y]},
-                     attrs={"begin_norm_axis": begin, "epsilon": epsilon})
+                     outputs={"Y": [y]}, attrs=attrs)
     return y
+
+
+def short_conv(input, filter_size, param_attr=None, name=None):
+    """The gated short convolution of a hybrid conv/attention decoder
+    (ops/decoder.py `short_conv`): `input` is `BCu` (N, T, 3D), what
+    the block's in-projection emits; returns `C * conv(B * u)`
+    (N, T, D), a causal depthwise convolution of `filter_size` taps
+    with one learned filter (D, filter_size)."""
+    helper = LayerHelper("short_conv", name=name)
+    d = int(input.shape[-1]) // 3
+    if 3 * d != int(input.shape[-1]):
+        raise ValueError(f"short_conv: minor dim {input.shape[-1]} is "
+                         f"not B, C and u side by side")
+    w = helper.create_parameter(param_attr, shape=[d, int(filter_size)],
+                                dtype=input.dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="short_conv",
+                     inputs={"X": [input], "Filter": [w]},
+                     outputs={"Out": [out]})
+    out.desc.shape = tuple(input.shape[:-1]) + (d,)
+    return out
 
 
 def rope(input, n_head, theta=10000.0, offset=None, name=None):
@@ -1067,13 +1153,18 @@ def grid_sampler(x, grid, name=None):
 
 def flash_attention(q, k, v, bias=None, scale=None, causal=False,
                     use_pallas=None, sequence_parallel=False,
-                    layout="nhtd", n_head=None, name=None):
+                    layout="nhtd", n_head=None, name=None,
+                    n_kv_head=None):
     """Fused multi-head attention over (N, H, T, D) tensors (see
     ops/attention.py).  The TPU-native replacement for composing
     matmul+softmax+matmul by hand.  layout="nthd" + n_head takes the
     head-major head-grouped (N, T, H*D) contract instead — what the
     attn_qkv projection emits directly, so NOTHING transposes at the
-    kernel boundary (the ISSUE 8 layout).  With sequence_parallel=True
+    kernel boundary (the ISSUE 8 layout).  `n_kv_head` < `n_head`
+    (head-major only) is grouped-query attention: k, v are
+    (N, T, n_kv_head*D), query head j reads key/value head
+    j // (n_head / n_kv_head), and the Pallas path (d_head 64,
+    ops/pallas/flash_gqa.py) never repeats them.  With sequence_parallel=True
     (or "ring" / "ulysses") and a CompiledProgram mesh that has an `sp`
     axis, the sequence dimension shards over sp and attention runs as
     ring attention (KV ppermute rotation) or Ulysses (head/sequence
@@ -1089,6 +1180,8 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
              "layout": layout}
     if n_head is not None:
         attrs["n_head"] = int(n_head)
+    if n_kv_head is not None and n_kv_head != n_head:
+        attrs["n_kv_head"] = int(n_kv_head)
     if scale is not None:
         attrs["scale"] = float(scale)
     helper.append_op(type="flash_attention", inputs=ins,

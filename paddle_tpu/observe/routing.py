@@ -9,6 +9,12 @@ copy of E numbers per layer, made when the reader chooses (after a
 benchmark window, every N steps of a trainer), never by the step.
 The sum wraps after 2^31 rows to one expert: a trainer that runs that
 long reads and resets.
+
+A layer that holds one expert-parallel rank's share
+(`experts_held=(first, count)`) keeps its counts (count,), the HELD
+experts' rows, and beside them `<moe_expert...>.off_share_count`, one
+int32 of the rows that went to experts it does not hold: the two add
+up to tokens x k.
 """
 
 from __future__ import annotations
@@ -18,25 +24,53 @@ from typing import Dict, Optional
 import numpy as np
 
 TOKEN_COUNT_SUFFIX = ".token_count"
+OFF_SHARE_COUNT_SUFFIX = ".off_share_count"
 
 
-def expert_token_counts(scope=None, reset: bool = False
-                        ) -> Dict[str, np.ndarray]:
-    """`{variable name: (E,) int64 rows routed to each expert so far}`
-    for every routed-expert layer whose state lives in `scope`; with no
-    scope, in any Scope alive in the process (a benchmark reader is
-    handed none).  `reset` zeroes what was read."""
+def _counters(suffix, scope, reset) -> Dict[str, np.ndarray]:
     from ..core.executor import Scope
 
     out: Dict[str, np.ndarray] = {}
     for s in ([scope] if scope is not None else list(Scope.live)):
         for name in s.local_var_names():
             value = s.vars[name]
-            if name.endswith(TOKEN_COUNT_SUFFIX) and value is not None:
+            if name.endswith(suffix) and value is not None:
                 out[name] = np.asarray(value).astype(np.int64)
                 if reset:
                     s.set_var(name, np.zeros(out[name].shape, np.int32))
     return out
+
+
+def expert_token_counts(scope=None, reset: bool = False
+                        ) -> Dict[str, np.ndarray]:
+    """`{variable name: (E,) int64 rows routed to each expert so far}`
+    for every routed-expert layer whose state lives in `scope` (the
+    held experts only, for a layer that holds a share); with no scope,
+    in any Scope alive in the process (a benchmark reader is handed
+    none).  `reset` zeroes what was read."""
+    return _counters(TOKEN_COUNT_SUFFIX, scope, reset)
+
+
+def off_share_counts(scope=None, reset: bool = False
+                     ) -> Dict[str, np.ndarray]:
+    """`{variable name: (1,) int64 rows routed to experts the layer
+    does not hold}`, one entry for each layer that holds a share."""
+    return _counters(OFF_SHARE_COUNT_SUFFIX, scope, reset)
+
+
+def held_row_share(scope=None) -> Optional[float]:
+    """Of the rows (token, expert) that share-holding layers routed,
+    the fraction that went to experts they hold, over all such layers:
+    count / E under uniform routing.  None where no layer holds a
+    share or nothing was routed yet."""
+    off = off_share_counts(scope)
+    if not off:
+        return None
+    counts = expert_token_counts(scope)
+    held = sum(int(counts[name[:-len(OFF_SHARE_COUNT_SUFFIX)]
+                          + TOKEN_COUNT_SUFFIX].sum()) for name in off)
+    gone = sum(int(c.sum()) for c in off.values())
+    return held / (held + gone) if held + gone else None
 
 
 def load_max_over_mean(counts) -> Optional[float]:

@@ -35,16 +35,23 @@ def _xla_attention(q, k, v, bias, scale, causal):
     return o
 
 
-def _xla_attention_nthd(q, k, v, bias, scale, causal, n_head):
+def _xla_attention_nthd(q, k, v, bias, scale, causal, n_head,
+                        n_kv_head=None):
     """XLA composition over head-grouped (N, T, H*D) operands.  The
     4D views are free reshapes (minor-dim split/merge) and the einsums
     carry the head dim as a dot batch dim — XLA folds the operand
-    orderings into the dot dimension numbers, no boundary transpose."""
+    orderings into the dot dimension numbers, no boundary transpose.
+    With fewer key/value heads than query heads this composition (the
+    CPU's and the tests', not the chip's) repeats them."""
     n, t_q, hd = q.shape
     d = hd // n_head
+    n_kv_head = n_kv_head or n_head
     q4 = q.reshape(n, t_q, n_head, d)
-    k4 = k.reshape(n, k.shape[1], n_head, d)
-    v4 = v.reshape(n, v.shape[1], n_head, d)
+    k4 = k.reshape(n, k.shape[1], n_kv_head, d)
+    v4 = v.reshape(n, v.shape[1], n_kv_head, d)
+    if n_kv_head != n_head:
+        k4 = jnp.repeat(k4, n_head // n_kv_head, axis=2)
+        v4 = jnp.repeat(v4, n_head // n_kv_head, axis=2)
     logits = jnp.einsum("nqhd,nkhd->nhqk", q4, k4) * scale
     if bias is not None:
         logits = logits + bias
@@ -76,9 +83,20 @@ def flash_attention(ctx, ins, attrs):
                 f"divisible by n_head {n_head}")
         head_dim = q.shape[-1] // int(n_head)
         t_axis, h_count = 1, int(n_head)
+        n_kv_head = int(attrs.get("n_kv_head") or n_head)
+        if (h_count % n_kv_head
+                or k.shape[-1] != n_kv_head * head_dim):
+            raise ValueError(
+                f"flash_attention nthd: K minor dim {k.shape[-1]} is not "
+                f"n_kv_head {n_kv_head} heads of {head_dim}, or n_head "
+                f"{h_count} is not a multiple of it")
     elif layout == "nhtd":
         head_dim = q.shape[-1]
         t_axis, h_count = 2, q.shape[1]
+        n_kv_head = h_count
+        if attrs.get("n_kv_head"):
+            raise ValueError("flash_attention: n_kv_head (grouped-query "
+                             "attention) needs layout='nthd'")
     else:
         raise ValueError(f"flash_attention: unknown layout {layout!r}")
     scale = attrs.get("scale", None)
@@ -106,6 +124,10 @@ def flash_attention(ctx, ins, attrs):
         # sharding inside the sp shard_map
         batch_axis = "dp" if ectx is None else ectx.batch_axis
         if mesh is not None and mesh.shape.get("sp", 1) > 1:
+            if n_kv_head != h_count:
+                raise NotImplementedError(
+                    "sequence_parallel flash_attention does not take "
+                    "grouped key/value heads (n_kv_head < n_head)")
             if bias is not None:
                 raise ValueError(
                     "sequence_parallel flash_attention does not take "
@@ -171,10 +193,12 @@ def flash_attention(ctx, ins, attrs):
                 f"leave use_pallas unset for the XLA composition")
         from .pallas.flash_attention import pallas_flash_attention
 
-        o = pallas_flash_attention(q, k, v, bias, scale, causal,
-                                   layout=layout, n_head=h_count)
+        o = pallas_flash_attention(
+            q, k, v, bias, scale, causal, layout=layout, n_head=h_count,
+            n_kv_head=None if n_kv_head == h_count else n_kv_head)
     elif layout == "nthd":
-        o = _xla_attention_nthd(q, k, v, bias, scale, causal, h_count)
+        o = _xla_attention_nthd(q, k, v, bias, scale, causal, h_count,
+                                n_kv_head)
     else:
         o = _xla_attention(q, k, v, bias, scale, causal)
     return out(Out=o)

@@ -1,8 +1,9 @@
 """The small ops of a modern decoder block: `rms_norm`, `rope`
-(rotate-half rotary positions) and `swiglu` (the gated FFN
-activation).
+(rotate-half rotary positions), `swiglu` (the gated FFN activation)
+and `short_conv` (the gated short convolution that stands where
+attention does in most layers of a hybrid conv/attention model).
 
-Not in the 1.2 reference (it predates all three); they are ops of
+Not in the 1.2 reference (it predates them all); they are ops of
 their own, not compositions of `square` / `reduce_mean` / `slice` /
 `concat`, so a block costs three named scopes instead of thirty and
 the statistics stay in float32 whatever dtype the activations arrive
@@ -23,9 +24,21 @@ from .common import first, opt_in, out
 @register_op("rms_norm")
 def rms_norm(ctx, ins, attrs):
     """Y = X * rsqrt(mean(X^2 over the axes from begin_norm_axis) + eps)
-    [* Scale].  Statistics and the scaling in float32, Y in X's dtype."""
+    [* Scale].  Statistics and the scaling in float32, Y in X's dtype.
+    With `group_size` g the minor dim is read as groups of g (the heads
+    of a head-grouped (N, T, H*g) projection), each normalised alone
+    and scaled by the one Scale (g,) they share."""
     x = first(ins, "X")
     scale = opt_in(ins, "Scale")
+    group = attrs.get("group_size")
+    if group:
+        if x.shape[-1] % int(group):
+            raise ValueError(f"rms_norm: minor dim {x.shape[-1]} is not "
+                             f"whole groups of {group}")
+        y = rms_norm(ctx, {"X": [x.reshape(x.shape[:-1] + (-1, int(group)))],
+                           "Scale": ins.get("Scale", [])},
+                     {"epsilon": attrs.get("epsilon", 1e-5)})["Y"][0]
+        return out(Y=y.reshape(x.shape))
     begin = attrs.get("begin_norm_axis", -1) % x.ndim
     eps = attrs.get("epsilon", 1e-5)
     axes = tuple(range(begin, x.ndim))
@@ -85,3 +98,36 @@ def silu_gate(a, b):
 @register_op("swiglu")
 def swiglu(ctx, ins, attrs):
     return out(Out=silu_gate(first(ins, "X"), first(ins, "Y")))
+
+
+def _short_conv(bcu, w):
+    d, taps = w.shape
+    f32 = jnp.float32
+    b, c, u = (bcu[..., i * d:(i + 1) * d].astype(f32) for i in range(3))
+    z = jnp.pad(b * u, ((0, 0), (taps - 1, 0), (0, 0)))
+    t = bcu.shape[1]
+    wf = w.astype(f32)
+    conv = sum(wf[:, j] * z[:, j:j + t] for j in range(taps))
+    return (c * conv).astype(bcu.dtype)
+
+
+@register_op("short_conv")
+def short_conv(ctx, ins, attrs):
+    """The gated short convolution between a block's two projections.
+    X is `BCu` (N, T, 3D), what the in-projection emits, split in that
+    order; Filter (D, L), one L-tap filter a channel.
+
+        z = B * u
+        conv[t] = sum_j Filter[:, j] * z[t - (L-1) + j]     (z[<0] = 0)
+        Out = C * conv                                       (N, T, D)
+
+    Causal (position t reads t-L+1..t) and depthwise (a channel reads
+    itself only).  One op, so that the three elementwise passes are
+    one scope and can be one fusion; float32 inside, X's dtype out.
+    The backward pass recomputes from X (`jax.checkpoint`): it reads
+    `BCu` and the output's gradient and keeps nothing in between."""
+    x, w = first(ins, "X"), first(ins, "Filter")
+    if x.ndim != 3 or x.shape[-1] != 3 * w.shape[0]:
+        raise ValueError(f"short_conv: X {x.shape} is not (N, T, 3D) for "
+                         f"a Filter {w.shape} of (D, L)")
+    return out(Out=jax.checkpoint(_short_conv)(x, w))

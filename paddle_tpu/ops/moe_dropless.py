@@ -34,6 +34,46 @@ rows per expert; `Experts` (T, k), each token's experts by falling
 weight; and `TokenCountOut` = `TokenCount` + `Counts`, int32
 state the step carries on the device (no fetch, no callback;
 `observe/routing.py` reads it).
+
+Two things are attributes of this ONE op, not ops of their own (and a
+third, `router_gradient`, is the caller's say over the backward pass):
+
+- `routing="sigmoid"`: the scores are `s = sigmoid_f32(X @ GateW)`; a
+  token's experts are the k largest of `s + Bias` (`Bias` (E,), state
+  that no gradient reaches: it steers the choice and never the
+  weight), its weights the unbiased `s` of the chosen, over their sum
+  + 1e-6 with `norm_topk_prob`, times `routed_scaling_factor`.  With
+  `bias_update_rate` u > 0 the step also balances the load the way the
+  bias exists for (Wang et al. 2024, arXiv:2408.15664, no auxiliary
+  loss): `BiasOut = Bias + u * sign(mean load - load)` over ALL E
+  experts' rows of this call, used from the next step on;
+- `experts_held=(first, count)`: the layer holds ONE expert-parallel
+  rank's share.  `GateW` stays (D, E) and routes over all E; W1, W3, W2
+  are (count, ...), experts first..first+count-1.  The pairs are sorted
+  with the held experts first, their rows go through the ragged dots
+  with the held experts' counts as group sizes, every other row is
+  zero, and `Out` is the PARTIAL sum: what the absent experts would
+  have added is left out (the shares of all ranks add up to the whole
+  layer: tests/test_expert_share.py).  The weights are normalised over
+  all k chosen experts, held or not.  Shapes stay static, T*k rows
+  whatever the routing, so a token routed to a held expert is never
+  dropped; there is no exchange and nothing stands in for one.
+  `Counts` and `TokenCount` are then (count,), the held experts' rows,
+  and `OffShareCountOut` = `OffShareCount` (1,) + the rows that went
+  to experts not held.  The backward pass is this rank's own part of
+  every gradient, the one that reaches `GateW` and `X` through the
+  routing weights included: summed over the ranks the parts are the
+  whole layer's gradient (tests/test_expert_share.py).  Absent: all
+  experts, the op as it was.
+
+`router_gradient=False` (its own attribute, tied to neither of the
+above) makes the routing weights constants of the backward pass:
+nothing reaches `GateW`, or `X`, through them; the experts' inputs and
+weights get their gradients as ever.  It is for the caller that runs a
+share with no exchange to sum the ranks' parts (`models/decoder.py`
+under `expert_parallel_size`): one rank's part knows only that ITS
+experts help, and applied alone it teaches the router to prefer them,
+which no deployment does.
 """
 
 from __future__ import annotations
@@ -82,6 +122,26 @@ def route(logits, top_k, norm_topk_prob=False):
     return probs, weights, experts.astype(jnp.int32)
 
 
+SIGMOID_NORM_EPS = 1e-6
+
+
+def route_sigmoid(logits, top_k, bias=None, norm_topk_prob=False,
+                  scaling=1.0):
+    """The sigmoid router: scores (T, E), weights (T, k), experts
+    (T, k) int32.  `bias` (E,) moves the choice only."""
+    scores = jax.nn.sigmoid(logits)
+    select = scores if bias is None else \
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, experts = jax.lax.top_k(select, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + SIGMOID_NORM_EPS)
+    if scaling != 1.0:
+        weights = weights * scaling
+    return scores, weights, experts.astype(jnp.int32)
+
+
 def router_losses(logits, probs, counts, top_k):
     """(load-balancing loss, z-loss) of one layer, float32 scalars."""
     t, e = probs.shape
@@ -93,41 +153,85 @@ def router_losses(logits, probs, counts, top_k):
 
 @register_op("moe_dropless")
 def moe_dropless(ctx, ins, attrs):
-    """X (..., D); GateW (D, E); W1 (E, D, H) gate, W3 (E, D, H) up,
-    W2 (E, H, D) down; optional TokenCount (E,) int32."""
+    """X (..., D); GateW (D, E); W1 (G, D, H) gate, W3 (G, D, H) up,
+    W2 (G, H, D) down, G = E or the `experts_held` count; optional
+    Bias (E,) (sigmoid routing), TokenCount (G,) int32, OffShareCount
+    (1,) int32."""
     x = first(ins, "X")
     gate_w = first(ins, "GateW")
     w1, w3, w2 = first(ins, "W1"), first(ins, "W3"), first(ins, "W2")
     total = opt_in(ins, "TokenCount")
+    off_share = opt_in(ins, "OffShareCount")
     k = int(attrs.get("top_k", 1))
     e = gate_w.shape[1]
     if not 1 <= k <= e:
         raise ValueError(f"moe_dropless: top_k {k} outside 1..E={e}")
+    held = attrs.get("experts_held")
+    first_held, groups = (0, e) if held is None else map(int, held)
+    if not (0 <= first_held and groups >= 1 and first_held + groups <= e):
+        raise ValueError(f"moe_dropless: experts_held {held} outside "
+                         f"the {e} experts")
+    if w1.shape[0] != groups:
+        raise ValueError(f"moe_dropless: weights of {w1.shape[0]} experts "
+                         f"for {groups} held")
 
     d = x.shape[-1]
     xf = x.reshape(-1, d)
     t = xf.shape[0]
     logits = jnp.dot(xf, gate_w, preferred_element_type=jnp.float32)
-    probs, weights, experts = route(
-        logits, k, bool(attrs.get("norm_topk_prob", False)))
+    norm = bool(attrs.get("norm_topk_prob", False))
+    routing = attrs.get("routing", "softmax")
+    if routing == "softmax":
+        probs, weights, experts = route(logits, k, norm)
+    elif routing == "sigmoid":
+        probs, weights, experts = route_sigmoid(
+            logits, k, opt_in(ins, "Bias"), norm,
+            float(attrs.get("routed_scaling_factor", 1.0)))
+    else:
+        raise ValueError(f"moe_dropless: routing {routing!r} is neither "
+                         f"'softmax' nor 'sigmoid'")
 
+    if not attrs.get("router_gradient", True):
+        weights = jax.lax.stop_gradient(weights)
     flat = experts.reshape(-1)                       # (T*k,) expert ids
-    order = jnp.argsort(flat, stable=True)           # sorted row -> pair
+    # held experts sort first, as groups 0..count-1; the rest follow
+    key = flat if held is None else (flat - first_held) % e
+    order = jnp.argsort(key, stable=True)            # sorted row -> pair
     back = jnp.argsort(order).astype(jnp.int32)      # pair -> sorted row
-    counts = jnp.sum(flat[:, None] == jnp.arange(e, dtype=jnp.int32),
+    counts = jnp.sum(key[:, None] == jnp.arange(groups, dtype=jnp.int32),
                      axis=0, dtype=jnp.int32)       # no scatter
 
     xs = _permute(xf, (order // k).astype(jnp.int32), back, k)
+    if held is not None:
+        # rows past the held experts' belong to no group: a ragged dot
+        # says nothing of them, forward or backward, so they are zero
+        # going in (which zeroes their gradient) and coming out
+        mine = (jnp.arange(t * k, dtype=jnp.int32)
+                < jnp.sum(counts))[:, None]
+        xs = jnp.where(mine, xs, 0)
     h = silu_gate(jax.lax.ragged_dot(xs, w1, counts),
                   jax.lax.ragged_dot(xs, w3, counts))
     ys = jax.lax.ragged_dot(h, w2, counts)           # (T*k, D) sorted
+    if held is not None:
+        ys = jnp.where(mine, ys, 0)
     yk = _permute(ys, back, order.astype(jnp.int32), 1).reshape(t, k, d)
     y = jnp.sum(yk.astype(jnp.float32) * weights[..., None], axis=1)
 
-    aux, z = router_losses(logits, probs, counts, k)
+    all_counts = counts if held is None else jnp.sum(
+        flat[:, None] == jnp.arange(e, dtype=jnp.int32), axis=0,
+        dtype=jnp.int32)
+    aux, z = router_losses(logits, probs, all_counts, k)
     outs = {"Out": [y.reshape(x.shape).astype(x.dtype)],
             "AuxLoss": [aux.reshape(1)], "ZLoss": [z.reshape(1)],
             "Counts": [counts], "Experts": [experts]}
     if total is not None:
         outs["TokenCountOut"] = [total + counts]
+    rate = float(attrs.get("bias_update_rate", 0.0))
+    if rate:
+        load = all_counts.astype(jnp.float32)
+        outs["BiasOut"] = [first(ins, "Bias")
+                           + rate * jnp.sign(jnp.mean(load) - load)]
+    if off_share is not None:
+        outs["OffShareCountOut"] = [
+            off_share + (t * k - jnp.sum(counts)).astype(jnp.int32)]
     return outs

@@ -691,13 +691,15 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
                            block_k=DEFAULT_BLOCK_K,
                            q_offset=None, k_offset=None,
                            return_lse=False, layout="nhtd",
-                           n_head=None):
+                           n_head=None, n_kv_head=None):
     """layout="nhtd" (default): q/k/v (N, H, T, D), output (N, H, T, D).
     layout="nthd": q/k/v (N, T, H*D) head-grouped — the head-major
     end-to-end contract; `n_head` is required and the batch*head fold
     happens in the kernel grid, so NO transpose/copy exists at the
     kernel boundary.  bias: None or broadcastable (N, 1, 1, Tk) in
-    either layout.
+    either layout.  `n_kv_head` < `n_head` (head-major only): k, v are
+    (N, T, n_kv_head*D) and query head j reads key/value head
+    j // (n_head / n_kv_head); they are never repeated.
 
     q_offset/k_offset: optional GLOBAL position offsets (python ints or
     traced scalars) applied in causal masking — ring attention passes the
@@ -714,13 +716,33 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
             raise ValueError(f"nthd minor dim {hd} not divisible by "
                              f"n_head {n_head}")
         h, d = n_head, hd // n_head
-        # NOTE for the TPU: one head's (block, d) tile is a lane slice
-        # of the grouped minor dim, which Mosaic takes only at whole
-        # 128-lane tiles — at d_head 64 the lowering refuses the block
-        # spec (tests/test_chip_compile.py pins both sides)
+        n_kv_head = n_head if n_kv_head is None else n_kv_head
+        # One head's (block, d) tile is a lane slice of the grouped
+        # minor dim, which Mosaic takes only at whole 128-lane tiles:
+        # at d_head 64 a causal self-attention call goes to the kernels
+        # that block heads in pairs (flash_gqa.py), which also read
+        # grouped key/value heads.  Anything else at 64 keeps the
+        # per-head blocks below (interpret mode; Mosaic refuses them).
+        plain = (bias is None and causal and not return_lse
+                 and q_offset is None and k_offset is None
+                 and k.shape[1] == t_q)
+        if d == 64 and plain:
+            from .flash_gqa import flash_gqa
+
+            return flash_gqa(q, k, v, h, n_kv_head, scale)
+        if n_kv_head != n_head:
+            raise NotImplementedError(
+                f"grouped-query flash attention (ops/pallas/flash_gqa.py) "
+                f"is causal self-attention at d_head 64 with no bias, "
+                f"offsets or returned logsumexp; got d_head {d}, "
+                f"causal={causal}")
         t_k = k.shape[1]
         qf, kf, vf = q, k, v
     elif layout == "nhtd":
+        if n_kv_head is not None:
+            raise NotImplementedError(
+                "grouped-query flash attention is head-major "
+                "(layout='nthd')")
         n, h, t_q, d = q.shape
         t_k = k.shape[2]
         qf = q.reshape(n * h, t_q, d)

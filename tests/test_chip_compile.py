@@ -124,21 +124,53 @@ def test_flash_attention_head_major_entry(one_chip):
     assert _kernels(text) >= 2
 
 
-def test_flash_attention_head_major_is_refused_at_d_head_64(one_chip):
-    """At d_head 64 (the Transformer's and the longctx bench's width)
-    a head is half a lane tile of the (N, T, H*D) operand and the
-    lowering refuses the block: the head-major Pallas path exists only
-    at d_head 128 until a kernel blocks head pairs (ROADMAP S4).  The
-    request is an error, never a silent fallback."""
+# (N, T, query heads, key/value heads) at d_head 64, head-major: the
+# lfm2-8k cell's attention layer, and the Transformer's heads
+@pytest.mark.parametrize("geometry", [(1, 8192, 32, 8), (64, 256, 8, 8)],
+                         ids=["lfm2_8k_gqa_32_over_8", "bs64_len256_mha"])
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
+        one_chip, geometry, dtype):
+    """At d_head 64 a head is half a lane tile of the (N, T, H*D)
+    operand, which Mosaic does not take as a block (the refusal this
+    test replaced): `ops/pallas/flash_gqa.py` blocks heads in pairs,
+    reads grouped key/value heads where they lie (K and V stay
+    (N, T, Hkv*64): nothing in the step is Hq heads wide but q, o and
+    their gradients), and its three kernels compile forward and
+    backward, in the cell's bfloat16 and in the parity script's
+    float32 at "highest"."""
+    from paddle_tpu.observe import cost
     from paddle_tpu.ops.pallas.flash_attention import \
         pallas_flash_attention
 
-    def fn(q, k, v):
-        return pallas_flash_attention(q, k, v, None, 0.125, True,
-                                      layout="nthd", n_head=8)
+    n, t, heads, kv = geometry
 
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        _compile(fn, one_chip, *[((2, 256, 8 * 64), BF16)] * 3)
+    def loss(q, k, v):
+        with jax.named_scope("flash_attention:9"):
+            o = pallas_flash_attention(q, k, v, None, 0.125, True,
+                                       layout="nthd", n_head=heads,
+                                       n_kv_head=kv)
+        return jnp.sum(o.astype(F32))
+
+    args = [jax.ShapeDtypeStruct((n, t, h * 64), dtype, sharding=one_chip)
+            for h in (heads, kv, kv)]
+    prec = "default" if dtype == BF16 else "highest"
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+            .lower(*args).compile()
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "flash_gqa_dkv", "flash_gqa_dq", "flash_gqa_fwd"]
+    assert {r["op_type"] for r in rows if r["kernel"]} == {
+        "flash_attention"}
+    totals = cost.total_costs(cost.compiled_hlo_proto(compiled))
+    assert totals["custom_calls"] == totals["pallas_matched"] == 3
+    # dense-equivalent: 4 matmuls' worth forward, 8 backward, a score
+    scores = n * heads * t * t
+    assert totals["pallas_flops"] >= 12 * 64 * scores
+    # dk, dv leave the kernel key/value heads wide, once
+    text = compiled.as_text()
+    assert f"bf16[{n},{t},{kv * 64}]" in text or dtype == F32
 
 
 def test_fused_vocab_ce_fwd_bwd(one_chip):
@@ -490,3 +522,62 @@ def test_olmoe_step_kernels_at_the_published_shapes(one_chip):
     assert totals["custom_calls"] == totals["pallas_matched"] >= 9
     # the routing never reaches a shape: no dynamic dimension anywhere
     assert "<=" not in compiled.as_text().split("ENTRY")[1].split("\n")[0]
+
+
+def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(one_chip):
+    """What `lfm2-8k`'s step hands the chip's compiler beside the
+    attention kernels, at LFM2-24B-A2B's widths (1 x 8192 tokens, a
+    router over 64 experts, 8 of them held at 2048 x 1536, 4 a token):
+    the expert op that holds a share still lowers to nine Mosaic
+    grouped matmuls over a static T*k-row buffer whatever the routing
+    (`observe.cost` counts the buffer's rows: what the held experts
+    really get is data), nothing is 64 experts wide but the router,
+    and the gated short convolution is XLA fusions with no kernel and
+    no dot."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+
+    t, hidden, e, held, h, k = 8192, 2048, 64, 8, 1536, 4
+    impl = get_op_impl("moe_dropless")
+    attrs = {"top_k": k, "routing": "sigmoid", "norm_topk_prob": True,
+             "experts_held": [0, held]}
+
+    def experts(x, gate, bias, w1, w3, w2):
+        with jax.named_scope("moe_dropless:12"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"X": [x], "GateW": [gate], "Bias": [bias],
+                      "W1": [w1], "W3": [w3], "W2": [w2]}, attrs)
+        # not linear in Out: a share's routing weights are constants of
+        # the backward pass, and a linear loss would need no forward
+        return jnp.sum(jnp.sin(o["Out"][0].astype(F32)))
+
+    shapes = [((1, t, hidden), BF16), ((hidden, e), BF16), ((e,), F32),
+              ((held, hidden, h), BF16), ((held, hidden, h), BF16),
+              ((held, h, hidden), BF16)]
+    compiled = _compile_args(
+        jax.jit(jax.grad(experts, argnums=(0, 1, 3, 4, 5))),
+        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes])
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    matmuls = [r for r in rows if r["kernel"] == "ragged_dot"]
+    assert len(matmuls) == 9            # 3 forward, 3 dX, 3 dW
+    assert {r["flops"] for r in matmuls} == {2.0 * t * k * hidden * h}
+    text = compiled.as_text()
+    assert f"[{e},{hidden},{h}]" not in text     # no absent expert's weight
+    assert "<=" not in text.split("ENTRY")[1].split("\n")[0]
+
+    conv = get_op_impl("short_conv")
+
+    def short_conv(bcu, w):
+        with jax.named_scope("short_conv:7"):
+            o = conv(OpContext(jax.random.PRNGKey(0), 0),
+                     {"X": [bcu], "Filter": [w]}, {})
+        return jnp.sum(o["Out"][0].astype(F32))
+
+    compiled = _compile_args(
+        jax.jit(jax.grad(short_conv, argnums=(0, 1))),
+        jax.ShapeDtypeStruct((1, t, 3 * hidden), BF16, sharding=one_chip),
+        jax.ShapeDtypeStruct((hidden, 3), F32, sharding=one_chip))
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    assert not any(r["kernel"] for r in rows)
+    assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
+    assert {r["op_type"] for r in rows if r["op_type"]} == {"short_conv"}

@@ -145,13 +145,6 @@ def test_tied_head_and_renormalised_top_k_follow_their_keys():
     close(got["logits"], parts["logits"], "logits")
 
 
-def test_grouped_query_attention_is_refused_not_guessed():
-    with fluid.program_guard(fluid.Program(), fluid.Program()):
-        with pytest.raises(NotImplementedError, match="grouped-query"):
-            decoder.decoder(max_length=8, **config(
-                num_key_value_heads=2, **SIZES["8-experts-top-2"]))
-
-
 def test_training_step_learns_and_counts_on_the_device():
     """The whole training Program (AdamW, clip, schedule, bf16 AMP):
     the loss falls, the device-side counters add up to steps x T x k,

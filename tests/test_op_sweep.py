@@ -987,6 +987,9 @@ S["rope"] = dict(ins={"X": f32((1, 3, 8), 270)}, attrs={"n_head": 2},
                  out="Out", shape=(1, 3, 8), grad=["X"])
 S["swiglu"] = dict(ins={"X": f32((2, 5), 271), "Y": f32((2, 5), 272)},
                    out="Out", shape=(2, 5), grad=["X", "Y"])
+S["short_conv"] = dict(
+    ins={"X": f32((2, 5, 12), 278), "Filter": f32((4, 3), 279)},
+    out="Out", shape=(2, 5, 4), grad=["X", "Filter"], tol=2e-2)
 S["moe_dropless"] = dict(
     ins={"X": f32((6, 8), 273), "GateW": f32((8, 4), 274),
          "W1": f32((4, 8, 6), 275), "W3": f32((4, 8, 6), 276),
