@@ -157,7 +157,12 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
     first+count-1; `out` is the part of the layer's result those
     experts give (the rest is left out, not stood in for), `counts` and
     `.token_count` are (count,), and `<w_0>.off_share_count`, int32
-    (1,), counts the rows that went to experts not held.  The backward
+    (1,), counts the rows that went to experts not held.  The op runs
+    its sorted rows on the smallest of up to three static buffer sizes
+    that holds the rows this share got, chosen on the device each call
+    (T*k is the last, so nothing is ever dropped);
+    `<w_0>.row_buffer_count`, int32 (3,), counts the calls that took
+    each.  The backward
     pass is the rank's own part of every gradient, the one through the
     routing weights among them: over the ranks the parts add up to the
     whole layer's (tests/test_expert_share.py).
@@ -227,6 +232,12 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
             f"{w1.name}.off_share_count", [1], "int32")
         off_share.desc.stop_gradient = True
         ins["OffShareCount"] = outs["OffShareCountOut"] = [off_share]
+        from ..ops.moe_dropless import ROW_BUFFER_SIZES
+
+        row_buffers = eh.create_or_get_global_variable(
+            f"{w1.name}.row_buffer_count", [ROW_BUFFER_SIZES], "int32")
+        row_buffers.desc.stop_gradient = True
+        ins["RowBufferCount"] = outs["RowBufferCountOut"] = [row_buffers]
     if routing != "softmax":
         attrs["routing"] = routing
         attrs["routed_scaling_factor"] = float(routed_scaling_factor)

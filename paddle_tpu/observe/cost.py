@@ -460,7 +460,12 @@ def _instr_flops(module: HloModule, comp: Computation, instr: Instr,
     if op == "scatter":
         upd = operands[-1].shape.elements if operands else 0
         return float(upd) * _reduce_ops(module, instr), 0.0
-    if op in ("fusion", "call", "while", "conditional", "async-start"):
+    if op == "conditional":
+        # one branch runs, not all: the heaviest, which no call exceeds
+        return max((_computation_flops(module, sub, seen)
+                    for sub in map(module.computations.get, instr.called_ids)
+                    if sub is not None), default=(0.0, 0.0))
+    if op in ("fusion", "call", "while", "async-start"):
         flops = transc = 0.0
         for cid in instr.called_ids:
             sub = module.computations.get(cid)
@@ -553,6 +558,9 @@ def ragged_dot_cost(operand_shapes, result_shapes):
 # per-instruction cost rows + bucketing
 # --------------------------------------------------------------------------
 
+BRANCH_BUCKET = "branch"      # a `conditional`: its branches' rows follow
+
+
 def _bucket(module: HloModule, comp: Computation, instr: Instr) -> str:
     op = instr.opcode
     if op == "custom-call":
@@ -564,6 +572,8 @@ def _bucket(module: HloModule, comp: Computation, instr: Instr) -> str:
         # — a roofline reader must never mistake that for real coverage
         trip = while_trip_count(module, comp, instr)
         return "loop" if trip is not None else "[loop?]"
+    if op == "conditional":
+        return BRANCH_BUCKET
     if op == "dot":
         return "matmul"
     if op == "convolution":
@@ -611,10 +621,15 @@ def _bucket(module: HloModule, comp: Computation, instr: Instr) -> str:
     return "elementwise"
 
 
-def instruction_costs(proto) -> List[Dict[str, Any]]:
+def instruction_costs(proto, every_branch: bool = False
+                      ) -> List[Dict[str, Any]]:
     """Analytic per-instruction cost rows for the entry computation of
     a serialized module, or of an `HloModule` already parsed (one row
-    per post-fusion kernel).
+    per post-fusion kernel).  A `conditional` is a row without cost
+    (bucket "branch") followed by the rows of its HEAVIEST branch, the
+    one with the most FLOPs, which no call exceeds: one branch runs,
+    so the rows still sum to a step.  `every_branch`: the rows of all
+    its branches instead (what a trace is joined to: any may run).
 
     Row keys: name, opcode, op_type (fluid attribution or None),
     bucket, flops, transcendentals, bytes, pallas_kernel (set when a
@@ -622,6 +637,8 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
     kernel (every `tpu_custom_call` row: the Pallas kernel's `name`
     from its `pallas_<name>` scope, registered or not, or
     `ragged_dot` for the compiler's own grouped matmul; else None),
+    branch_of (the `conditional` whose branch holds the instruction,
+    None in the entry computation),
     trip_count (while rows: the recovered loop trip count, already
     multiplied into flops; None = unrecoverable, body counted once and
     bucketed "[loop?]").
@@ -636,14 +653,22 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
     from ..ops.pallas import vocab_ce as _vc  # noqa: F401
 
     module = proto if isinstance(proto, HloModule) else HloModule(proto)
-    entry = module.entry
+    return _computation_costs(module, module.entry, every_branch, None)
+
+
+def _computation_costs(module: HloModule, comp: Computation,
+                       every_branch: bool, branch_of: Optional[str]
+                       ) -> List[Dict[str, Any]]:
     rows: List[Dict[str, Any]] = []
-    for instr in entry.instructions:
-        operands = [entry.by_id[i] for i in instr.operand_ids
-                    if i in entry.by_id]
-        flops, transc = _instr_flops(module, entry, instr)
-        bucket = _bucket(module, entry, instr)
-        if instr.opcode in _NO_BYTES:
+    for instr in comp.instructions:
+        operands = [comp.by_id[i] for i in instr.operand_ids
+                    if i in comp.by_id]
+        # a conditional's cost is its branches': their rows follow it
+        branching = instr.opcode == "conditional"
+        flops, transc = ((0.0, 0.0) if branching
+                         else _instr_flops(module, comp, instr))
+        bucket = _bucket(module, comp, instr)
+        if branching or instr.opcode in _NO_BYTES:
             nbytes = 0
         else:
             # materialized-buffers model: unique operands read once,
@@ -668,9 +693,10 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
             "bytes": float(nbytes),
             "pallas_kernel": None,
             "kernel": None,
+            "branch_of": branch_of,
         }
         if instr.opcode == "while":
-            row["trip_count"] = while_trip_count(module, entry, instr)
+            row["trip_count"] = while_trip_count(module, comp, instr)
         if instr.opcode == "custom-call":
             row["custom_call_target"] = instr.custom_call_target
             kernel = _pallas_kernel_of(instr.op_name)
@@ -692,6 +718,16 @@ def instruction_costs(proto) -> List[Dict[str, Any]]:
             if instr.custom_call_target == "tpu_custom_call":
                 row["kernel"] = kernel
         rows.append(row)
+        if branching:
+            branches = [
+                _computation_costs(module, sub, every_branch, instr.name)
+                for sub in map(module.computations.get, instr.called_ids)
+                if sub is not None]
+            if not every_branch and branches:
+                branches = [max(branches, key=lambda b: sum(
+                    r["flops"] for r in b))]
+            for branch in branches:
+                rows += branch
     return rows
 
 

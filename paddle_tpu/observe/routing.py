@@ -14,7 +14,11 @@ A layer that holds one expert-parallel rank's share
 (`experts_held=(first, count)`) keeps its counts (count,), the HELD
 experts' rows, and beside them `<moe_expert...>.off_share_count`, one
 int32 of the rows that went to experts it does not hold: the two add
-up to tokens x k.
+up to tokens x k.  Such a layer runs its sorted rows on the smallest
+of up to three static buffer sizes that holds the rows it got
+(`ops/moe_dropless.py row_buffer_sizes`), and
+`<moe_expert...>.row_buffer_count`, int32 (3,), counts the calls that
+took the smallest, the next and the last (tokens x k rows).
 """
 
 from __future__ import annotations
@@ -25,6 +29,7 @@ import numpy as np
 
 TOKEN_COUNT_SUFFIX = ".token_count"
 OFF_SHARE_COUNT_SUFFIX = ".off_share_count"
+ROW_BUFFER_COUNT_SUFFIX = ".row_buffer_count"
 
 
 def _counters(suffix, scope, reset) -> Dict[str, np.ndarray]:
@@ -56,6 +61,15 @@ def off_share_counts(scope=None, reset: bool = False
     """`{variable name: (1,) int64 rows routed to experts the layer
     does not hold}`, one entry for each layer that holds a share."""
     return _counters(OFF_SHARE_COUNT_SUFFIX, scope, reset)
+
+
+def row_buffer_counts(scope=None, reset: bool = False
+                      ) -> Dict[str, np.ndarray]:
+    """`{variable name: (3,) int64 calls that ran at each row-buffer
+    size, smallest first}`, one entry for each layer that holds a
+    share.  Where tokens x k is no more than a smaller size would be,
+    the layer has fewer sizes and the later slots stay zero."""
+    return _counters(ROW_BUFFER_COUNT_SUFFIX, scope, reset)
 
 
 def held_row_share(scope=None) -> Optional[float]:

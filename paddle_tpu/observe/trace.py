@@ -186,9 +186,10 @@ def instruction_name(event_name: str) -> str:
 def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
     """{instruction name: {op_name, bucket, flops, bytes, kernel}} of
     one serialized HloProto: every computation's instructions with
-    their `metadata.op_name`, and for the entry computation's the
-    bucket, FLOPs, bytes and Mosaic kernel name of
-    `cost.instruction_costs` (elsewhere None)."""
+    their `metadata.op_name`, and for the entry computation's and
+    those of every branch of its `conditional`s (any of which may be
+    the one that ran) the bucket, FLOPs, bytes and Mosaic kernel name
+    of `cost.instruction_costs` (elsewhere None)."""
     from . import cost
 
     module = cost.HloModule(proto)
@@ -198,7 +199,7 @@ def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
             out[instr.name] = {"op_name": instr.op_name, "bucket": None,
                                "flops": None, "bytes": None,
                                "kernel": None}
-    for row in cost.instruction_costs(module):
+    for row in cost.instruction_costs(module, every_branch=True):
         out[row["name"]].update(bucket=row["bucket"], flops=row["flops"],
                                 bytes=row["bytes"], kernel=row["kernel"])
     return out

@@ -66,6 +66,19 @@ third, `router_gradient`, is the caller's say over the backward pass):
   whole layer's gradient (tests/test_expert_share.py).  Absent: all
   experts, the op as it was.
 
+  A share's real rows are the HEAD of the sorted order, so its
+  sorted-row section (gather, masks, the ragged dots, gate, combine)
+  runs on a buffer of the rows it got, not of T*k: the section is
+  traced at up to three static sizes (`row_buffer_sizes`: 1.5 and 3
+  times the expected `T*k*count/E` rows, in whole 512s, and T*k) and
+  a `jax.lax.switch` on the device takes the smallest that holds this
+  call's held rows.  T*k is always among them: there is still no
+  capacity, and a routing that sends every row here costs what it did.
+  The backward pass recomputes the section at the size taken and
+  differentiates it there (`_switched`), so nothing of sorted-row size
+  is kept from the forward pass.  `RowBufferCountOut` =
+  `RowBufferCount` (3,) + the one-hot of the size taken.
+
 `router_gradient=False` (its own attribute, tied to neither of the
 above) makes the routing weights constants of the backward pass:
 nothing reaches `GateW`, or `X`, through them; the experts' inputs and
@@ -79,6 +92,7 @@ which no deployment does.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -109,6 +123,131 @@ def _permute_bwd(group, back, g):
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+_ROW_FACTORS = (1.5, 3.0)       # of the rows uniform routing would send
+_ROW_TILE = 512
+ROW_BUFFER_SIZES = len(_ROW_FACTORS) + 1        # and T*k
+
+
+def row_buffer_sizes(t, k, e, count):
+    """The static row counts a layer that holds `count` of `e` experts
+    may run its sorted-row section at, rising, T*k last: 1.5 and 3
+    times the rows uniform routing would send it, in whole 512s; a
+    size that reaches T*k is T*k."""
+    rows = t * k
+    return tuple(sorted({
+        min(rows, _ROW_TILE * math.ceil(f * rows * count / e / _ROW_TILE))
+        for f in _ROW_FACTORS} | {rows}))
+
+
+def _pairs_rows(ys, back, n):
+    """(T, k, D): each pair's row of the R-row `ys`, zero for a pair
+    whose row is not below `n`."""
+    return jnp.where((back < n)[..., None],
+                     ys[jnp.minimum(back, ys.shape[0] - 1)], 0)
+
+
+@jax.custom_vjp
+def _take_head(x, index, back, n):
+    """`x[index]` for `index` (R,) = the tokens of the first R sorted
+    rows.  `back` (T, k) is each pair's sorted row and only rows below
+    `n` <= R count (the caller zeroes the others), so the gradient is
+    a gather out of the R rows and a sum over k."""
+    return x[index]
+
+
+def _take_head_fwd(x, index, back, n):
+    return x[index], (back, n)
+
+
+def _take_head_bwd(res, g):
+    return _pairs_rows(g, *res).sum(axis=1), None, None, None
+
+
+_take_head.defvjp(_take_head_fwd, _take_head_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, weights, back, head, n):
+    """`y[t] = sum_j weights[t, j] * ys[back[t, j]]` in float32 over
+    the pairs whose sorted row is below `n`; `head` (R,) is the pair
+    of each row of `ys`, so the gradient of `ys` is a gather of R rows
+    of the output's."""
+    yk = _pairs_rows(ys, back, n)
+    return jnp.sum(yk.astype(jnp.float32) * weights[..., None], axis=1)
+
+
+def _combine_fwd(ys, weights, back, head, n):
+    return _combine(ys, weights, back, head, n), (ys, weights, back, head, n)
+
+
+def _combine_bwd(res, g):
+    ys, weights, back, head, n = res
+    k = weights.shape[1]
+    gys = (g[head // k] * weights.reshape(-1)[head][:, None]).astype(ys.dtype)
+    yk = _pairs_rows(ys, back, n)
+    gw = jnp.sum(g[:, None, :] * yk.astype(jnp.float32), axis=-1)
+    return gys, gw, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _held_rows(rows, xf, w1, w3, w2, weights, order, back, counts):
+    """The sorted-row section of a layer that holds a share, on a
+    buffer of `rows` rows (static; at least the held experts' rows):
+    (T, D) float32, the partial sum."""
+    k = weights.shape[1]
+    n = jnp.sum(counts)
+    head = order[:rows]
+    # rows past the held experts' belong to no group: a ragged dot
+    # says nothing of them, forward or backward, so they are zero
+    # going in (which zeroes their gradient) and coming out
+    mine = (jnp.arange(rows, dtype=jnp.int32) < n)[:, None]
+    xs = jnp.where(mine, _take_head(xf, head // k, back, n), 0)
+    h = silu_gate(jax.lax.ragged_dot(xs, w1, counts),
+                  jax.lax.ragged_dot(xs, w3, counts))
+    ys = jnp.where(mine, jax.lax.ragged_dot(h, w2, counts), 0)
+    return _combine(ys, weights, back, head, n)
+
+
+@functools.lru_cache(maxsize=None)
+def _branch(rows):
+    """`_held_rows` at `rows` rows and its gradient, as `switch`
+    branches.  The same objects for every layer: a program's layers
+    have one shape, and `switch` traces a branch it has seen at those
+    shapes once, not once a layer."""
+    section = functools.partial(_held_rows, rows)
+
+    def grads(g, diff, index):
+        return jax.vjp(lambda *d: section(*d, *index), *diff)[1](g)
+
+    return section, grads
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _switched(sizes, taken, diff, index):
+    """`_held_rows(sizes[taken], *diff, *index)`, the branch chosen on
+    the device.  Differentiating a `switch` would have every branch
+    write zeros for the other branches' residuals, T*k rows of them:
+    the backward pass keeps the inputs instead and recomputes the
+    section at the size taken."""
+    return jax.lax.switch(taken, [_branch(r)[0] for r in sizes],
+                          *diff, *index)
+
+
+def _switched_fwd(sizes, taken, diff, index):
+    return _switched(sizes, taken, diff, index), (taken, diff, index)
+
+
+def _switched_bwd(sizes, res, g):
+    taken, diff, index = res
+    return None, jax.lax.switch(taken, [_branch(r)[1] for r in sizes],
+                                g, diff, index), None
+
+
+_switched.defvjp(_switched_fwd, _switched_bwd)
 
 
 def route(logits, top_k, norm_topk_prob=False):
@@ -156,12 +295,13 @@ def moe_dropless(ctx, ins, attrs):
     """X (..., D); GateW (D, E); W1 (G, D, H) gate, W3 (G, D, H) up,
     W2 (G, H, D) down, G = E or the `experts_held` count; optional
     Bias (E,) (sigmoid routing), TokenCount (G,) int32, OffShareCount
-    (1,) int32."""
+    (1,) and RowBufferCount (3,) int32 (a share's)."""
     x = first(ins, "X")
     gate_w = first(ins, "GateW")
     w1, w3, w2 = first(ins, "W1"), first(ins, "W3"), first(ins, "W2")
     total = opt_in(ins, "TokenCount")
     off_share = opt_in(ins, "OffShareCount")
+    row_buffers = opt_in(ins, "RowBufferCount")
     k = int(attrs.get("top_k", 1))
     e = gate_w.shape[1]
     if not 1 <= k <= e:
@@ -174,6 +314,9 @@ def moe_dropless(ctx, ins, attrs):
     if w1.shape[0] != groups:
         raise ValueError(f"moe_dropless: weights of {w1.shape[0]} experts "
                          f"for {groups} held")
+    if row_buffers is not None and held is None:
+        raise ValueError("moe_dropless: RowBufferCount without "
+                         "experts_held: only a share chooses a row buffer")
 
     d = x.shape[-1]
     xf = x.reshape(-1, d)
@@ -201,21 +344,21 @@ def moe_dropless(ctx, ins, attrs):
     counts = jnp.sum(key[:, None] == jnp.arange(groups, dtype=jnp.int32),
                      axis=0, dtype=jnp.int32)       # no scatter
 
-    xs = _permute(xf, (order // k).astype(jnp.int32), back, k)
-    if held is not None:
-        # rows past the held experts' belong to no group: a ragged dot
-        # says nothing of them, forward or backward, so they are zero
-        # going in (which zeroes their gradient) and coming out
-        mine = (jnp.arange(t * k, dtype=jnp.int32)
-                < jnp.sum(counts))[:, None]
-        xs = jnp.where(mine, xs, 0)
-    h = silu_gate(jax.lax.ragged_dot(xs, w1, counts),
-                  jax.lax.ragged_dot(xs, w3, counts))
-    ys = jax.lax.ragged_dot(h, w2, counts)           # (T*k, D) sorted
-    if held is not None:
-        ys = jnp.where(mine, ys, 0)
-    yk = _permute(ys, back, order.astype(jnp.int32), 1).reshape(t, k, d)
-    y = jnp.sum(yk.astype(jnp.float32) * weights[..., None], axis=1)
+    if held is None:
+        xs = _permute(xf, (order // k).astype(jnp.int32), back, k)
+        h = silu_gate(jax.lax.ragged_dot(xs, w1, counts),
+                      jax.lax.ragged_dot(xs, w3, counts))
+        ys = jax.lax.ragged_dot(h, w2, counts)       # (T*k, D) sorted
+        yk = _permute(ys, back, order.astype(jnp.int32), 1).reshape(t, k, d)
+        y = jnp.sum(yk.astype(jnp.float32) * weights[..., None], axis=1)
+    else:
+        sizes = row_buffer_sizes(t, k, e, groups)
+        taken = jnp.sum(jnp.sum(counts) > jnp.asarray(sizes[:-1], jnp.int32),
+                        dtype=jnp.int32)
+        diff = (xf, w1, w3, w2, weights)
+        index = (order.astype(jnp.int32), back.reshape(t, k), counts)
+        y = (_held_rows(t * k, *diff, *index) if len(sizes) == 1
+             else _switched(sizes, taken, diff, index))
 
     all_counts = counts if held is None else jnp.sum(
         flat[:, None] == jnp.arange(e, dtype=jnp.int32), axis=0,
@@ -234,4 +377,7 @@ def moe_dropless(ctx, ins, attrs):
     if off_share is not None:
         outs["OffShareCountOut"] = [
             off_share + (t * k - jnp.sum(counts)).astype(jnp.int32)]
+    if row_buffers is not None:
+        outs["RowBufferCountOut"] = [row_buffers + jax.nn.one_hot(
+            taken, ROW_BUFFER_SIZES, dtype=jnp.int32)]
     return outs

@@ -524,23 +524,38 @@ def test_olmoe_step_kernels_at_the_published_shapes(one_chip):
     assert "<=" not in compiled.as_text().split("ENTRY")[1].split("\n")[0]
 
 
-def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(one_chip):
+def _computation(text, name):
+    """The lines of computation `name` in a compiled module's text."""
+    body = text.split(f"\n%{name} (", 1)[1]
+    return body[:body.index("\n}\n")].split("\n")[1:]
+
+
+def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
+        one_chip, monkeypatch):
     """What `lfm2-8k`'s step hands the chip's compiler beside the
     attention kernels, at LFM2-24B-A2B's widths (1 x 8192 tokens, a
-    router over 64 experts, 8 of them held at 2048 x 1536, 4 a token):
-    the expert op that holds a share still lowers to nine Mosaic
-    grouped matmuls over a static T*k-row buffer whatever the routing
-    (`observe.cost` counts the buffer's rows: what the held experts
-    really get is data), nothing is 64 experts wide but the router,
-    and the gated short convolution is XLA fusions with no kernel and
-    no dot."""
+    router over 64 experts, 8 of them held at 2048 x 1536, 4 a token).
+    The expert op that holds a share compiles to two `conditional`s,
+    forward and backward, of three branches: its sorted rows at 6144,
+    12288 and T*k = 32768 rows, eleven Mosaic grouped matmuls a size
+    (three forward; backward the two up-projections again and six
+    more: the down-projection's result would serve the router's
+    gradient alone, which a program that runs a share holds back);
+    static shapes whatever the routing, nothing 64 experts wide but
+    the router; the smallest branch writes one T*k-row buffer each
+    way, the gather back to token order; and the plan needs less
+    memory than the section differentiated on T*k rows.  The gated
+    short convolution is XLA fusions with no kernel and no dot."""
     from paddle_tpu.core.registry import OpContext, get_op_impl
     from paddle_tpu.observe import cost
+    from paddle_tpu.ops import moe_dropless
 
     t, hidden, e, held, h, k = 8192, 2048, 64, 8, 1536, 4
+    sizes = moe_dropless.row_buffer_sizes(t, k, e, held)
+    assert sizes == (6144, 12288, t * k)
     impl = get_op_impl("moe_dropless")
     attrs = {"top_k": k, "routing": "sigmoid", "norm_topk_prob": True,
-             "experts_held": [0, held]}
+             "experts_held": [0, held], "router_gradient": False}
 
     def experts(x, gate, bias, w1, w3, w2):
         with jax.named_scope("moe_dropless:12"):
@@ -554,16 +569,55 @@ def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(one_chip):
     shapes = [((1, t, hidden), BF16), ((hidden, e), BF16), ((e,), F32),
               ((held, hidden, h), BF16), ((held, hidden, h), BF16),
               ((held, h, hidden), BF16)]
-    compiled = _compile_args(
-        jax.jit(jax.grad(experts, argnums=(0, 1, 3, 4, 5))),
-        *[jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes])
-    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
-    matmuls = [r for r in rows if r["kernel"] == "ragged_dot"]
-    assert len(matmuls) == 9            # 3 forward, 3 dX, 3 dW
-    assert {r["flops"] for r in matmuls} == {2.0 * t * k * hidden * h}
+
+    def compile_layer():
+        return _compile_args(
+            jax.jit(jax.grad(experts, argnums=(0, 1, 3, 4, 5))),
+            *[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+              for s, d in shapes])
+
+    compiled = compile_layer()
     text = compiled.as_text()
     assert f"[{e},{hidden},{h}]" not in text     # no absent expert's weight
     assert "<=" not in text.split("ENTRY")[1].split("\n")[0]
+    branches = [line.split("branch_computations={")[1].split("}")[0]
+                .replace("%", "").split(", ")
+                for line in text.split("\n") if " conditional(" in line]
+    assert [len(b) for b in branches] == [3, 3]  # forward, backward
+    for smallest, _, _ in branches:
+        lines = _computation(text, smallest)
+        assert not any(f"[{t * k},{h}]" in line.split(" = ")[1].split("(")[0]
+                       for line in lines if " = " in line)
+        wide = [line for line in lines if " = " in line and
+                f"[{t * k},{hidden}]" in line.split(" = ")[1].split("(")[0]]
+        assert len(wide) == 1 and " fusion(" in wide[0], wide  # the gather
+
+    proto = cost.compiled_hlo_proto(compiled)
+    every = cost.instruction_costs(proto, every_branch=True)
+    matmuls = [r for r in every if r["kernel"] == "ragged_dot"]
+    assert {r["bucket"] for r in matmuls} == {"custom_call"}
+    assert all(r["branch_of"] for r in matmuls)
+    by_size = {}
+    for r in matmuls:
+        by_size[r["flops"]] = by_size.get(r["flops"], 0) + 1
+    assert by_size == {2.0 * rows * hidden * h: 11 for rows in sizes}
+    # a table that sums to a step lists the heaviest branch alone
+    rows = cost.instruction_costs(proto)
+    assert [r["flops"] for r in rows if r["kernel"] == "ragged_dot"] == [
+        2.0 * t * k * hidden * h] * 11
+    inside = [r for r in every if r["branch_of"] and r["bucket"] in (
+        "elementwise", "layout", "matmul")]
+    assert inside and {r["op_type"] for r in inside
+                       if r["op_type"]} == {"moe_dropless"}
+
+    # the section on T*k rows, differentiated as it stands (the parent
+    # of PR 31): what the switch is measured against
+    monkeypatch.setattr(moe_dropless, "row_buffer_sizes",
+                        lambda t, k, e, count: (t * k,))
+    full = compile_layer()
+    assert " conditional(" not in full.as_text()
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 0.75 * full.memory_analysis().temp_size_in_bytes)
 
     conv = get_op_impl("short_conv")
 

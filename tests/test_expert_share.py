@@ -11,6 +11,8 @@ of 64 experts, add up to what the uncut float32 reference gives for
 the whole layer.
 """
 
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.registry import OpContext, get_op_impl
 from paddle_tpu.models import decoder_reference as ref
+from paddle_tpu.ops import moe_dropless
 
 from op_test import run_op
 
@@ -307,6 +310,228 @@ def test_a_share_that_does_not_fit_is_an_error(held, message):
     with pytest.raises(ValueError, match=message):
         run(share_of(whole_layer(), 0, 8),
             dict(ROUTING, experts_held=held))
+
+
+# --------------------------------------------------------------------------
+# the row buffer a share runs on
+# --------------------------------------------------------------------------
+
+TS = 640                  # tokens at which a share has three buffer sizes
+R1, R2, R3 = SIZES = moe_dropless.row_buffer_sizes(TS, K, E, 8)
+NAMES = ("X", "GateW", "W1", "W3", "W2")
+
+
+def test_the_row_buffer_sizes_follow_the_expected_rows_in_whole_512s():
+    assert SIZES == (512, 1024, TS * K)         # 1.5 x 320 and 3 x 320, up
+    assert moe_dropless.row_buffer_sizes(8192, 4, 64, 8) == (
+        6144, 12288, 32768)                     # lfm2-8k
+    assert moe_dropless.row_buffer_sizes(T, K, E, 8) == (T * K,)
+    assert moe_dropless.row_buffer_sizes(512, 4, 64, 24) == (1536, 2048)
+    assert moe_dropless.row_buffer_sizes(4096, 8, 64, 64) == (4096 * 8,)
+
+
+def steered(rows, seed=0, rank=2):
+    """A whole layer of TS tokens whose router sends exactly `rows` of
+    the TS*K (token, expert) rows to experts rank `rank` of 8 holds:
+    feature j of a token says whether it picks held expert first + 2j
+    (score 0.95 + a bias of 5 against 0.05 + 5); the places left go to
+    experts 60..63 (0.5 + 5), which rank 7 holds.  Every chosen score
+    is far from 0 and 1, so the router has a gradient."""
+    r = R(seed)
+    f32 = np.float32
+    first = 8 * rank
+    x = r.normal(size=(TS, D)).astype(f32)
+    gate = r.normal(size=(D, E)).astype(f32) * 0.02
+    picks = rows // TS + (np.arange(TS) < rows % TS)    # held picks a token
+    x[:, :K] = np.where(np.arange(K) < picks[:, None], 1.0, -1.0)
+    gate[:K] = 0
+    gate[np.arange(K), first + 2 * np.arange(K)] = 3.0
+    bias = np.zeros(E, f32)
+    bias[first:first + 8:2] = bias[60:] = 5.0
+    return {"X": x, "GateW": gate, "Bias": bias,
+            "W1": r.normal(size=(E, D, H)).astype(f32) * 0.3,
+            "W3": r.normal(size=(E, D, H)).astype(f32) * 0.3,
+            "W2": r.normal(size=(E, H, D)).astype(f32) * 0.3}
+
+
+def share_and_gradients(ins, attrs):
+    """(out, counts, row-buffer slots, gradients of NAMES) of one jitted
+    call of the op on a share's inputs."""
+    fixed = {k: jnp.asarray(v) for k, v in ins.items()}
+
+    def f(*vals):
+        o = run(dict(fixed, **dict(zip(NAMES, vals)),
+                     RowBufferCount=jnp.zeros((3,), jnp.int32)), attrs)
+        out = o["Out"][0]
+        return jnp.sum(jnp.sin(out)), (out, o["Counts"][0],
+                                       o["RowBufferCountOut"][0])
+
+    (_, aux), grads = jax.jit(jax.value_and_grad(
+        f, argnums=range(5), has_aux=True))(*[fixed[k] for k in NAMES])
+    return aux + (grads,)
+
+
+@pytest.mark.parametrize("rows, slot", [
+    (0, 0), (R1, 0), (R1 + 1, 1), (R2, 1), (R2 + 1, 2), (R3, 2)],
+    ids=["no-row", "R1", "R1+1", "R2", "R2+1", "every-row"])
+def test_a_share_on_the_smallest_buffer_that_fits_is_the_share(
+        rows, slot, monkeypatch):
+    """Whatever the routing sends a share, the op takes the smallest
+    of its buffer sizes that holds the rows, and output and every
+    gradient (the router's too) are those of the section on T*k rows
+    and of the float32 reference's share; with every row held (the
+    worst case) nothing is dropped."""
+    whole = steered(rows, seed=rows)
+    ins = share_of(whole, 16, 8)
+    attrs = dict(ROUTING, experts_held=[16, 8])
+    out, counts, slots, grads = share_and_gradients(ins, attrs)
+    assert int(counts.sum()) == rows            # the routing is as forced
+    assert np.asarray(slots).tolist() == np.eye(3, dtype=int)[slot].tolist()
+
+    monkeypatch.setattr(moe_dropless, "row_buffer_sizes",
+                        lambda t, k, e, count: (t * k,))
+    full, _, full_slots, full_grads = share_and_gradients(ins, attrs)
+    assert np.asarray(full_slots).tolist() == [1, 0, 0]     # its one size
+    np.testing.assert_array_equal(out, full)
+    for name, g, w in zip(NAMES, grads, full_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6, err_msg=name)
+
+    cfg = dict(CFG, expert_parallel_rank=2)
+
+    def dense(x, gate, w1, w3, w2):
+        layer = {"router": gate, "bias": jnp.asarray(ins["Bias"]),
+                 "w1": w1, "w3": w3, "w2": w2}
+        with jax.default_matmul_precision("highest"):
+            y = ref.lfm2_experts(x, layer, cfg)[0]
+        return jnp.sum(jnp.sin(y)), y
+
+    (_, want), want_grads = jax.value_and_grad(
+        dense, argnums=range(5), has_aux=True)(
+            *[jnp.asarray(ins[k]) for k in NAMES])
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    for name, g, w in zip(NAMES, grads, want_grads):
+        assert (np.abs(np.asarray(w)).max() > 0) == (rows > 0), name
+        np.testing.assert_allclose(g, w, rtol=5e-5, atol=5e-5, err_msg=name)
+    if rows == R3:                              # no token went elsewhere
+        assert np.abs(np.asarray(out)).min(axis=-1).min() > 0
+
+
+def test_eight_shares_on_small_buffers_add_up_to_the_uncut_layer():
+    """At TS tokens every rank has three sizes; a rank that gets a
+    usual load runs on the first, the rank the routing is steered to
+    on the last, and the parts still add up to the whole."""
+    ins = steered(1600, seed=11)
+    want, counts, _ = reference_layer(ins)
+    total, taken = np.zeros((TS, D), np.float64), []
+    for rank in range(8):
+        o = run(dict(share_of(ins, 8 * rank, 8),
+                     RowBufferCount=np.zeros(3, np.int32)),
+                dict(ROUTING, experts_held=[8 * rank, 8]))
+        np.testing.assert_array_equal(
+            o["Counts"][0], np.asarray(counts)[8 * rank:8 * rank + 8])
+        total += np.asarray(o["Out"][0])
+        taken.append(int(np.argmax(o["RowBufferCountOut"][0])))
+    assert taken == [0, 0, 2, 0, 0, 0, 0, 1]    # rank 7: the other 960 rows
+    np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-5)
+
+
+def test_the_switch_keeps_no_sorted_rows_for_the_backward_pass():
+    """What the forward pass of a switched share hands the backward
+    pass is its inputs and the size taken: nothing with a sorted-row
+    dimension (a differentiated `switch` would keep every branch's
+    residuals, zero-filled where the branch did not run)."""
+    ins = {k: jnp.asarray(v)
+           for k, v in share_of(steered(R1), 16, 8).items()}
+    attrs = dict(ROUTING, experts_held=[16, 8])
+
+    def f(*vals):
+        return run(dict(ins, **dict(zip(NAMES, vals))), attrs)["Out"][0]
+
+    _, pull = jax.vjp(f, *[ins[k] for k in NAMES])
+    kept = {tuple(leaf.shape) for leaf in jax.tree_util.tree_leaves(pull)
+            if hasattr(leaf, "shape")}
+    assert (TS, D) in kept and (8, D, H) in kept            # its inputs
+    assert not any(len(shape) > 1 and shape[0] in SIZES
+                   for shape in kept), kept
+    # (the router's own gradient scatters over (T, E); it is held back
+    # here, as a program that runs a share alone holds it back)
+    attrs["router_gradient"] = False
+    text = str(jax.make_jaxpr(jax.grad(lambda *v: jnp.sum(f(*v)),
+                                       argnums=range(5)))(
+        *[ins[k] for k in NAMES]))
+    assert text.count("cond[") == 2             # forward, and backward
+    assert "scatter" not in text                # gathers and k-sums only
+
+
+# the lowered text of the op with every expert held, on the parent of
+# the PR that brought the row buffers (PR 31): sha256, jax 0.9.0, CPU
+WHOLE_LAYER_TEXT = (
+    "b9c43ec9154acb58a3f47200dfe97464096eeafa3c2708c2d8a8a3d03442770a")
+
+
+def test_without_a_share_the_op_is_the_parents_text_for_text():
+    ins = {k: jnp.asarray(v) for k, v in whole_layer().items()}
+
+    def f(*vals):
+        o = run(dict(ins, **dict(zip(NAMES, vals))), ROUTING)
+        return jnp.sum(jnp.sin(o["Out"][0])) + o["AuxLoss"][0][0]
+
+    step = jax.jit(jax.value_and_grad(f, argnums=range(5)))
+    vals = [ins[k] for k in NAMES]
+    assert "cond[" not in str(jax.make_jaxpr(step)(*vals))
+    text = step.lower(*vals).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == WHOLE_LAYER_TEXT
+    # and a share too small for a second size has no switch either
+    small = {k: jnp.asarray(v) for k, v in
+             share_of(whole_layer(), 0, 8).items()}
+    jaxpr = jax.make_jaxpr(lambda x: run(
+        dict(small, X=x), dict(ROUTING, experts_held=[0, 8]))["Out"][0])(
+            small["X"])
+    assert "cond[" not in str(jaxpr)
+
+
+def test_the_row_buffer_counter_is_state_the_step_carries_on_the_device():
+    """`<w_0>.row_buffer_count` beside `off_share_count`: three forced
+    routings land in three slots, the step fetches only its loss, and
+    `observe.routing.row_buffer_counts` reads the scope afterwards."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.observe import routing
+
+    main, startup, scope = fluid.Program(), fluid.Program(), fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        x = layers.data(name="x", shape=[TS, D], dtype="float32")
+        out, *_ = layers.dropless_moe(
+            x, E, H, K, norm_topk_prob=True, experts_held=(16, 8),
+            routing="sigmoid", use_expert_bias=True)
+        loss = layers.mean(out)
+        fluid.optimizer.SGDOptimizer(learning_rate=0.0).minimize(loss)
+        exe = fluid.Executor()
+        exe.run(startup)
+        (name,) = [n for n in scope.local_var_names()
+                   if n.endswith(routing.ROW_BUFFER_COUNT_SUFFIX)]
+        assert name[:-len(routing.ROW_BUFFER_COUNT_SUFFIX)] + \
+            routing.OFF_SHARE_COUNT_SUFFIX in scope.local_var_names()
+        gate = [n for n in scope.local_var_names()
+                if n.startswith("moe_gate") and n.endswith(".w_0")][0]
+        seen = []
+        for rows in (R1, R1, R3, R2, R1 - 1, R2 + 1):
+            forced = steered(rows, seed=rows)
+            scope.set_var(gate, forced["GateW"])
+            scope.set_var(gate + ".expert_bias", forced["Bias"])
+            exe.run(main, feed={"x": forced["X"][None]}, fetch_list=[loss])
+            assert isinstance(scope.vars[name], jax.Array)   # never fetched
+            seen.append(routing.row_buffer_counts(scope)[name].tolist())
+        assert seen[-1] == [3, 1, 2] and seen[0] == [1, 0, 0]
+        assert routing.row_buffer_counts(scope, reset=True)[name].sum() == 6
+        assert not routing.row_buffer_counts(scope)[name].any()
+
+
+def test_a_row_buffer_counter_without_a_share_is_an_error():
+    with pytest.raises(ValueError, match="only a share chooses"):
+        run(dict(whole_layer(), RowBufferCount=np.zeros(3, np.int32)),
+            ROUTING)
 
 
 # --------------------------------------------------------------------------

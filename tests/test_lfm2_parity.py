@@ -255,5 +255,5 @@ def test_training_step_learns_and_counts_both_sides_of_the_share():
             assert b.shape == (8,) and np.abs(b).max() > 0
             np.testing.assert_allclose(b / 0.001, np.round(b / 0.001),
                                        atol=0.02)
-            assert np.abs(b).max() <= 0.006 + 1e-6
+            assert np.abs(b).max() <= 0.006 + 2e-5   # as rounded above
     assert routing.held_row_share(fluid.Scope()) is None

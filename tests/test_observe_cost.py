@@ -260,6 +260,54 @@ def test_op_cost_table_against_xla_aggregate():
     assert abs(once - xla) / xla < 0.05, (totals["flops"], once, xla)
 
 
+def test_a_conditional_costs_its_heaviest_branch_not_the_sum():
+    """One branch of a `conditional` runs.  Its row carries no cost
+    and the rows of its heaviest branch follow it (`branch_of`), so
+    the table still sums to one call; `every_branch` lists them all,
+    each instruction under its own name and bucket, which is what a
+    trace is joined to; nested in a loop, the same rule by FLOPs."""
+    w = {n: jnp.ones((n, n), jnp.float32) for n in (32, 64, 128)}
+
+    def at(n):
+        return lambda x: jnp.sum(jnp.tanh(x[:n, :n] @ w[n]))
+
+    def f(i, x):
+        return jax.lax.switch(i, [at(32), at(64), at(128)], x)
+
+    compiled = jax.jit(f).lower(jnp.int32(0),
+                                jnp.ones((128, 128), jnp.float32)).compile()
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    (cond,) = [r for r in rows if r["opcode"] == "conditional"]
+    assert cond["bucket"] == "branch" and cond["flops"] == cond["bytes"] == 0
+    inside = [r for r in rows if r["branch_of"] == cond["name"]]
+    dots = [r["flops"] for r in inside if r["bucket"] == "matmul"]
+    assert dots == [2.0 * 128 ** 3]              # the 128-row branch only
+    assert not [r for r in rows if r["branch_of"] is None
+                and r["bucket"] == "matmul"]
+    every = cost.instruction_costs(proto, every_branch=True)
+    assert sorted(r["flops"] for r in every if r["bucket"] == "matmul") == [
+        2.0 * n ** 3 for n in (32, 64, 128)]
+    assert len({r["name"] for r in every}) == len(every)
+    # a trace is joined to every branch: whichever ran, its ops are
+    # rows with a bucket of their own, not a loop body's
+    pmap = observe.trace.program_map(proto)
+    assert all(pmap[r["name"]]["bucket"] == r["bucket"] for r in every)
+    total = cost.total_costs(proto)["flops"]
+    assert 2.0 * 128 ** 3 <= total < 2.0 * 128 ** 3 + 2.0 * 64 ** 3
+
+    def looped(i, x):
+        return jax.lax.fori_loop(
+            0, 16, lambda _, c: 0.5 * c + f(i, x + c), 0.0)
+
+    compiled = jax.jit(looped).lower(
+        jnp.int32(0), jnp.ones((128, 128), jnp.float32)).compile()
+    (loop,) = [r for r in cost.instruction_costs(
+        cost.compiled_hlo_proto(compiled)) if r["opcode"] == "while"]
+    assert 16 * 2.0 * 128 ** 3 <= loop["flops"] < 16 * (
+        2.0 * 128 ** 3 + 2.0 * 64 ** 3)
+
+
 def test_op_cost_table_joins_profile_time(tmp_path):
     # end-to-end: cost rows join measured per-instruction device time
     # from a jax.profiler trace (XLA:CPU emits per-instruction events)
