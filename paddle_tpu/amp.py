@@ -28,7 +28,7 @@ from typing import Optional, Set
 DEFAULT_WHITE: Set[str] = {
     "mul", "matmul", "conv2d", "conv3d", "depthwise_conv2d",
     "conv2d_transpose", "conv3d_transpose", "flash_attention",
-    "sequence_conv", "moe_dropless",
+    "sequence_conv", "moe_dropless", "latent_attention",
 }
 
 # Numerically sensitive ops: force f32 inputs.

@@ -302,9 +302,13 @@ def _run_one_op(op, env, rng_key, op_index, amp_lists=None,
     # every emitted HLO instruction's metadata.op_name, so device
     # profiles and compiled-HLO dumps carry "<op_type>:<op_index>" —
     # trace-time only, zero runtime cost (observe/trace.py parses it
-    # back out of captured profiles)
+    # back out of captured profiles).  An op built under a
+    # fluid.name_scope() lowers as "<path>/<op_type>:<op_index>".
+    scope = f"{desc.type}:{op_index}"
+    if "__name_scope__" in desc.attrs:
+        scope = f"{desc.attrs['__name_scope__']}/{scope}"
     try:
-        with jax.named_scope(f"{desc.type}:{op_index}"):
+        with jax.named_scope(scope):
             if is_macro_op(desc.type):
                 ctx = OpContext(rng_key, op_index=op_index,
                                 program=program, amp_lists=amp_lists)

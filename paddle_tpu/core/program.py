@@ -315,6 +315,12 @@ class Block:
         if getattr(self.program, "_pp_seg_active", False):
             desc.attrs["__pp_group__"] = self.program._pp_group_tag
             desc.attrs["__pp_seg__"] = self.program._pp_seg_counter
+        # ops built inside fluid.name_scope() carry the scope path: the
+        # executor lowers them under "<path>/<op_type>:<op_index>", so a
+        # trace can tell one module's ops from another's of the same
+        # type (observe/trace.py name_scope_of)
+        if unique_name._scope_stack:
+            desc.attrs["__name_scope__"] = "/".join(unique_name._scope_stack)
         op = Operator(self, desc)
         self.ops.append(op)
         self.program._bump()
@@ -648,7 +654,8 @@ def pipeline_segment(main_program: Optional[Program] = None):
 @contextlib.contextmanager
 def name_scope(prefix: str):
     """Name scoping (fluid framework.py:106): generated var/param names are
-    prefixed with the scope path while the context is active."""
+    prefixed with the scope path while the context is active, and the ops
+    appended meanwhile lower under it (`Block.append_op`)."""
     unique_name._scope_stack.append(prefix)
     try:
         yield

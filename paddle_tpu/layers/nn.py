@@ -135,7 +135,7 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
                  param_attr=None, name=None, experts_held=None,
                  routing="softmax", use_expert_bias=False,
                  routed_scaling_factor=1.0, expert_bias_update_rate=0.0,
-                 router_gradient=True):
+                 router_gradient=True, norm_topk_eps=None):
     """Dropless routed SwiGLU experts (ops/moe_dropless.py): every
     token goes to its `top_k` of `num_experts` experts, none is
     dropped, shapes are static.  Returns (out, aux_loss, z_loss,
@@ -181,8 +181,8 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
     `use_expert_bias` the k experts are chosen on score +
     `<gate>.expert_bias` (E,), persistable float32 state that no
     gradient reaches (zero at start-up), and weighted with the
-    unbiased score, over the chosen scores' sum + 1e-6 with
-    `norm_topk_prob`, times `routed_scaling_factor`.
+    unbiased score, over the chosen scores' sum + `norm_topk_eps`
+    (1e-6 if None) with `norm_topk_prob`, times `routed_scaling_factor`.
     `expert_bias_update_rate` u > 0: each step moves every expert's
     bias by u against its load (`bias += u * sign(mean - load)`, all
     E experts, this call's rows): load balancing without an auxiliary
@@ -241,6 +241,8 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
     if routing != "softmax":
         attrs["routing"] = routing
         attrs["routed_scaling_factor"] = float(routed_scaling_factor)
+        if norm_topk_eps is not None:
+            attrs["norm_topk_eps"] = float(norm_topk_eps)
         if use_expert_bias:
             bias = gate_h.create_or_get_global_variable(
                 f"{gate_w.name}.expert_bias", [num_experts], "float32")
@@ -252,9 +254,11 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
         elif expert_bias_update_rate:
             raise ValueError("dropless_moe: expert_bias_update_rate "
                              "needs use_expert_bias")
-    elif use_expert_bias or routed_scaling_factor != 1.0:
-        raise ValueError("dropless_moe: the selection bias and the "
-                         "scaling factor belong to routing='sigmoid'")
+    elif (use_expert_bias or routed_scaling_factor != 1.0
+          or norm_topk_eps is not None):
+        raise ValueError("dropless_moe: the selection bias, the scaling "
+                         "factor and norm_topk_eps belong to "
+                         "routing='sigmoid'")
     eh.append_op(type="moe_dropless", inputs=ins, outputs=outs, attrs=attrs)
     out_v.desc.shape = tuple(input.shape)
     aux.desc.shape = z.desc.shape = (1,)
@@ -306,17 +310,44 @@ def short_conv(input, filter_size, param_attr=None, name=None):
     return out
 
 
-def rope(input, n_head, theta=10000.0, offset=None, name=None):
-    """Rotate-half rotary position embedding of a head-grouped
-    (N, T, n_head * D) projection (ops/decoder.py).  `offset`: a (1,)
-    integer variable, the position of the first row (0 if None)."""
+def rope(input, n_head, theta=10000.0, offset=None, name=None,
+         interleave=False):
+    """Rotary position embedding of a head-grouped (N, T, n_head * D)
+    projection (ops/decoder.py): rotate-half, or with `interleave` the
+    pairs (2i, 2i + 1) of every head.  `offset`: a (1,) integer
+    variable, the position of the first row (0 if None)."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     ins = {"X": [input]}
     if offset is not None:
         ins["Offset"] = [offset]
+    attrs = {"n_head": int(n_head), "theta": float(theta)}
+    if interleave:
+        attrs["interleave"] = True
     helper.append_op(type="rope", inputs=ins, outputs={"Out": [out]},
-                     attrs={"n_head": int(n_head), "theta": float(theta)})
+                     attrs=attrs)
+    return out
+
+
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head,
+                     use_pallas=False, name=None):
+    """The causal attention core of a latent-attention layer
+    (ops/decoder.py `latent_attention`): head-major `q_nope`, `k_nope`
+    (N, T, n_head*Dn), `q_rope` (N, T, n_head*Dr) and `v`
+    (N, T, n_head*Dv), and ONE rotary key head `k_rope` (N, T, Dr) that
+    every query head reads; a score is the unrotated and the rotary
+    dot product together, times (Dn + Dr)^-1/2.  Returns
+    (N, T, n_head*Dv).  `use_pallas`: the flash kernels of
+    ops/pallas/flash_mla.py (Dn 128, Dr 64, Dv 128)."""
+    helper = LayerHelper("latent_attention", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op(
+        type="latent_attention",
+        inputs={"QNope": [q_nope], "QRope": [q_rope], "KNope": [k_nope],
+                "KRope": [k_rope], "V": [v]},
+        outputs={"Out": [out]},
+        attrs={"n_head": int(n_head), "use_pallas": bool(use_pallas)})
+    out.desc.shape = tuple(v.shape)
     return out
 
 

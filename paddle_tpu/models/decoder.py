@@ -4,10 +4,26 @@ own keys.
 One builder for the decoder family of queue R: its arguments ARE the
 catalog's keys (`hidden_size`, `num_hidden_layers`, ...), so a
 configuration file is passed through unrenamed and the next decoder
-configuration extends this builder instead of forking it.  What is
-here is what OLMoE-1B-7B (Muennighoff et al. 2024, arXiv:2409.02060)
-and LFM2-24B-A2B need; a key whose other values are not built yet
-raises.
+configuration extends this builder instead of forking it.  What it
+builds, each chosen by the configuration's own keys (a key whose other
+values are not built yet raises):
+
+- operators: causal attention with grouped-query heads and QK-norm
+  (flash kernels at d_head 128 and 64); the gated short convolution;
+  latent attention (`kv_lora_rank` ...: queries, keys and values out
+  of low-rank latents, a rotary part beside the unrotated one, its own
+  flash kernels);
+- feed-forward layers: dense SwiGLU; dropless routed SwiGLU experts
+  under a soft-max or a sigmoid router with a selection bias, whole or
+  as one expert-parallel rank's share; shared experts beside the
+  routed ones (`n_shared_experts`);
+- heads: the vocabulary head and token cross-entropy, tied or not; a
+  multi-token-prediction module (`num_nextn_predict_layers`) that
+  re-enters the embedding table and the head.
+
+The first two configurations it was written for are OLMoE-1B-7B
+(Muennighoff et al. 2024, arXiv:2409.02060) and a hybrid
+conv/attention expert model; their blocks are spelt out below.
 
 OLMoE's block, pre-norm:
 
@@ -48,6 +64,43 @@ passes them): `qk_norm` ("projection": over the whole projection, one
             each expert's load every step (balancing without an
             auxiliary loss); 0, the default, leaves it alone
 
+Latent attention (`kv_lora_rank`, `q_lora_rank`, `qk_nope_head_dim`,
+`qk_rope_head_dim`, `v_head_dim`; DeepSeek-V2, arXiv:2405.04434) takes
+the place of `full_attention`'s operator:
+
+    c_q = rms_norm(h W_qa);  q_nope, q_rope = c_q W_qb      (H heads)
+    c_kv = rms_norm(h W_kva[:, :kv_lora_rank])
+    k_rope = rope(h W_kva[:, kv_lora_rank:])               (ONE head)
+    k_nope, v = c_kv W_kvb                                  (H heads)
+    s = (q_nope . k_nope + rope(q_rope) . k_rope) / sqrt(Dn + Dr)
+    out = causal_softmax(s) v W_o
+
+`rope` is over the rotary lanes only, over pairs (2i, 2i+1) with
+`rope_interleave`.  Every piece of W_qb, W_kva and W_kvb is a
+projection of its own: all heads' unrotated parts side by side, all
+heads' rotary parts side by side, all heads' keys, all heads' values
+(a checkpoint's per-head column order is a loader's one-off
+permutation), so nothing is sliced at a stride and the kernels
+(`ops/pallas/flash_mla.py`) read each where the projection wrote it.
+Its ops lower under the `latent_attention` name scope.
+
+`n_shared_experts` s > 0: beside the routed experts every token also
+goes through ONE dense SwiGLU of width s x `moe_intermediate_size`
+(name scope `shared_expert`); under `expert_parallel_size` it is whole
+on every rank, so over the ranks it counts ONCE and its gradient flows
+as ever.
+
+`num_nextn_predict_layers` 1 (DeepSeek-V3, arXiv:2412.19437, 2.2): one
+module predicts token i+2 from the main model's final normed state at
+i and the embedding of token i+1,
+
+    g = [rms_norm(Emb(t_{i+1})) ; rms_norm(h_i)] W_eh
+    logits' = Head(rms_norm(block(g)))      a block of the routed kind
+
+with the main model's own table and head, fed `next_labels` beside
+`labels`; its cross-entropy joins the objective x `mtp_loss_weight`.
+Its ops lower under the `mtp` name scope.
+
 `expert_parallel_size` chips share each layer's experts: `num_experts`
 is then what THIS chip (`expert_parallel_rank`) holds, the router is
 `num_experts * expert_parallel_size` wide, and the expert layer gives
@@ -69,6 +122,7 @@ warm-up into a cosine decay to `lr_floor` of the peak.
 from __future__ import annotations
 
 from .. import layers, optimizer
+from ..core.program import name_scope
 from ..clip import GradientClipByGlobalNorm, set_gradient_clip
 from ..initializer import Normal
 from ..param_attr import ParamAttr
@@ -84,14 +138,20 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             conv_L_cache=None, conv_bias=False, use_expert_bias=False,
             routed_scaling_factor=1.0, norm_eps=None, rope_parameters=None,
             expert_parallel_size=1, expert_parallel_rank=0,
-            expert_bias_update_rate=0.0):
+            expert_bias_update_rate=0.0, norm_topk_eps=None,
+            kv_lora_rank=None, q_lora_rank=None, qk_nope_head_dim=None,
+            qk_rope_head_dim=None, v_head_dim=None, rope_interleave=False,
+            rope_scaling=None, n_shared_experts=0,
+            num_nextn_predict_layers=0):
     """Append the forward pass to the default program.  Feeds `tokens`
-    and `labels`, both (N, max_length) int64.  Returns a dict: `logits`
-    (N, T, vocab); `ce`, `aux`, `z`, each (1,): the mean token
+    and `labels`, both (N, max_length) int64 (and `next_labels`, the
+    labels' own successors, with a prediction module).  Returns a dict:
+    `logits` (N, T, vocab); `ce`, `aux`, `z`, each (1,): the mean token
     cross-entropy, the load-balancing loss and the router z-loss, the
     last two averaged over the layers that route (None where none
     does); `counts` and `experts`, per routed layer the rows per expert
-    held and each token's experts (N*T, k)."""
+    held and each token's experts (N*T, k), the module's layer last;
+    `mtp_logits` and `mtp_ce`, the module's (None without one)."""
     if qk_norm not in ("projection", "head"):
         raise NotImplementedError(f"qk_norm {qk_norm!r} is not built")
     if router not in ("softmax", "sigmoid"):
@@ -103,6 +163,24 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                          "num_key_value_heads")
     if conv_bias:
         raise NotImplementedError("conv_bias is not built")
+    if rope_scaling:
+        raise NotImplementedError(f"rope_scaling {rope_scaling!r} is not "
+                                  f"built")
+    if num_nextn_predict_layers not in (0, 1):
+        raise NotImplementedError(
+            f"{num_nextn_predict_layers} chained prediction modules are "
+            f"not built")
+    latent = (kv_lora_rank, q_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+              v_head_dim)
+    if kv_lora_rank is None:
+        if any(v is not None for v in latent):
+            raise ValueError("latent attention's sizes without kv_lora_rank")
+    elif None in latent:
+        raise ValueError("latent attention needs kv_lora_rank, q_lora_rank, "
+                         "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+    elif num_key_value_heads != num_attention_heads:
+        raise ValueError("latent attention has one key and value a query "
+                         "head: num_key_value_heads is num_attention_heads")
     eps = norm_eps if rms_norm_eps is None else rms_norm_eps
     theta = (rope_parameters or {}).get("rope_theta", rope_theta)
     if rope_parameters and rope_parameters.get("rope_type",
@@ -151,54 +229,118 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                                      n_kv_head=num_key_value_heads)
         return proj(ctx, hidden_size, "attn_out")
 
+    def latent_attention(h):
+        heads = num_attention_heads
+
+        def rotary(x, n_head):
+            return layers.rope(x, n_head, theta, interleave=rope_interleave)
+
+        with name_scope("latent_attention"):
+            c_q = norm(proj(h, q_lora_rank, "attn_q_a"))
+            q_nope = proj(c_q, heads * qk_nope_head_dim, "attn_q_b")
+            q_rope = rotary(proj(c_q, heads * qk_rope_head_dim, "attn_q_b"),
+                            heads)
+            c_kv = norm(proj(h, kv_lora_rank, "attn_kv_a"))
+            k_rope = rotary(proj(h, qk_rope_head_dim, "attn_kv_a"), 1)
+            k_nope = proj(c_kv, heads * qk_nope_head_dim, "attn_kv_b")
+            v = proj(c_kv, heads * v_head_dim, "attn_kv_b")
+            from ..ops.pallas import flash_mla
+
+            ctx = layers.latent_attention(
+                q_nope, q_rope, k_nope, k_rope, v, heads,
+                use_pallas=(qk_nope_head_dim, qk_rope_head_dim, v_head_dim)
+                == (flash_mla.NOPE_DIM, flash_mla.ROPE_DIM,
+                    flash_mla.NOPE_DIM))
+            return proj(ctx, hidden_size, "attn_out")
+
     def conv(h):
         y = layers.short_conv(proj(h, 3 * hidden_size, "conv_in"),
                               conv_L_cache, param_attr=weight())
         return proj(y, hidden_size, "conv_out")
 
-    def dense_ffn(h):
-        gate = layers.swiglu(proj(h, intermediate_size, "ffn_in"),
-                             proj(h, intermediate_size, "ffn_in"))
+    def dense_ffn(h, width=intermediate_size):
+        gate = layers.swiglu(proj(h, width, "ffn_in"),
+                             proj(h, width, "ffn_in"))
         return proj(gate, hidden_size, "ffn_out")
 
-    tokens = layers.data(name="tokens", shape=[max_length], dtype="int64")
-    labels = layers.data(name="labels", shape=[max_length], dtype="int64")
-    embed = ParamAttr(name="tok_embedding.w",
-                      initializer=Normal(0.0, initializer_range))
-    x = layers.embedding(tokens, size=[vocab_size, hidden_size],
-                         param_attr=embed)
     aux_losses, z_losses, counts, experts = [], [], [], []
-    for i, kind in enumerate(layer_types):
-        if kind == "full_attention":
-            op = attention
-        elif kind == "conv":
-            op = conv
-        else:
-            raise NotImplementedError(f"layer type {kind!r} is not built")
-        x = layers.elementwise_add(x, op(norm(x)))
-        if i < num_dense_layers:
-            x = layers.elementwise_add(x, dense_ffn(norm(x)))
-            continue
+
+    def routed_ffn(h):
         y, aux, z, count, chosen = layers.dropless_moe(
-            norm(x), num_experts * expert_parallel_size, expert_width,
+            h, num_experts * expert_parallel_size, expert_width,
             num_experts_per_tok, norm_topk_prob=norm_topk_prob,
             param_attr=weight(), experts_held=held,
             # a share without the exchange that sums the ranks' parts
             router_gradient=held is None, routing=router,
             use_expert_bias=use_expert_bias,
             routed_scaling_factor=routed_scaling_factor,
-            expert_bias_update_rate=expert_bias_update_rate)
-        x = layers.elementwise_add(x, y)
+            expert_bias_update_rate=expert_bias_update_rate,
+            norm_topk_eps=norm_topk_eps)
         aux_losses.append(aux), z_losses.append(z)
         counts.append(count), experts.append(chosen)
+        if n_shared_experts:
+            # whole on every rank: over the ranks it counts once
+            with name_scope("shared_expert"):
+                y = layers.elementwise_add(
+                    y, dense_ffn(h, n_shared_experts * expert_width))
+        return y
+
+    def block(x, kind, dense):
+        if kind == "full_attention":
+            op = attention if kv_lora_rank is None else latent_attention
+        elif kind == "conv":
+            op = conv
+        else:
+            raise NotImplementedError(f"layer type {kind!r} is not built")
+        x = layers.elementwise_add(x, op(norm(x)))
+        ffn = dense_ffn if dense else routed_ffn
+        return layers.elementwise_add(x, ffn(norm(x)))
+
+    def head(x):
+        if tie_word_embeddings:
+            table = x.block.program.global_block().var(embed_name)
+            return layers.matmul(x, table, transpose_y=True)
+        if not num_nextn_predict_layers:
+            return proj(x, vocab_size, "lm_head")
+        # the module re-enters the head: declared once, shared by name
+        shared = x.block.program.global_block().has_var("lm_head.w")
+        return layers.fc(
+            x, size=vocab_size, num_flatten_dims=2, bias_attr=False,
+            param_attr=ParamAttr(
+                name="lm_head.w", initializer=None if shared
+                else Normal(0.0, initializer_range)), name="lm_head")
+
+    def token_ce(logits, targets):
+        return layers.mean(layers.softmax_with_cross_entropy(
+            logits, layers.unsqueeze(targets, axes=[2])))
+
+    tokens = layers.data(name="tokens", shape=[max_length], dtype="int64")
+    labels = layers.data(name="labels", shape=[max_length], dtype="int64")
+    embed_name = "tok_embedding.w"
+    x = layers.embedding(
+        tokens, size=[vocab_size, hidden_size],
+        param_attr=ParamAttr(name=embed_name,
+                             initializer=Normal(0.0, initializer_range)))
+    for i, kind in enumerate(layer_types):
+        x = block(x, kind, dense=i < num_dense_layers)
     x = norm(x)
-    if tie_word_embeddings:
-        table = x.block.program.global_block().var(embed.name)
-        logits = layers.matmul(x, table, transpose_y=True)
-    else:
-        logits = proj(x, vocab_size, "lm_head")
-    ce = layers.mean(layers.softmax_with_cross_entropy(
-        logits, layers.unsqueeze(labels, axes=[2])))
+    logits = head(x)
+    ce = token_ce(logits, labels)
+    feeds = ["tokens", "labels"]
+    mtp_logits = mtp_ce = None
+    if num_nextn_predict_layers:
+        next_labels = layers.data(name="next_labels", shape=[max_length],
+                                  dtype="int64")
+        feeds.append("next_labels")
+        with name_scope("mtp"):
+            # token i+1's embedding beside the main model's state at i
+            e = layers.embedding(labels, size=[vocab_size, hidden_size],
+                                 param_attr=ParamAttr(name=embed_name))
+            g = proj(layers.concat([norm(e), norm(x)], axis=2), hidden_size,
+                     "mtp_eh")
+            g = norm(block(g, layer_types[-1], dense=False))
+            mtp_logits = head(g)
+            mtp_ce = token_ce(mtp_logits, next_labels)
 
     def layer_mean(losses):
         if not losses:
@@ -207,24 +349,28 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
 
     return {"logits": logits, "ce": ce, "aux": layer_mean(aux_losses),
             "z": layer_mean(z_losses), "counts": counts,
-            "experts": experts, "feeds": ["tokens", "labels"]}
+            "experts": experts, "feeds": feeds, "mtp_logits": mtp_logits,
+            "mtp_ce": mtp_ce}
 
 
 def build_model(max_length, learning_rate=4e-4, beta1=0.9, beta2=0.95,
                 epsilon=1e-8, weight_decay=0.1, warmup_steps=2000,
                 decay_steps=1_000_000, lr_floor=0.1, clip_norm=1.0,
-                aux_loss_weight=0.01, z_loss_weight=0.001, use_amp=True,
-                with_optimizer=True, **architecture):
+                aux_loss_weight=0.01, z_loss_weight=0.001,
+                mtp_loss_weight=0.3, use_amp=True, with_optimizer=True,
+                **architecture):
     """The training Program of `decoder(**architecture)`: loss, AdamW
     under bf16 AMP, clipping and the schedule.  The defaults are the
-    OLMoE paper's settings; `architecture` holds the configuration's
-    own keys and, where its equations need them, `qk_norm` / `router`."""
+    OLMoE paper's settings (`mtp_loss_weight` DeepSeek-V3's first);
+    `architecture` holds the configuration's own keys and, where its
+    equations need them, `qk_norm` / `router` / `norm_topk_eps`."""
     model = decoder(max_length=max_length, **architecture)
     ce, aux, z = model["ce"], model["aux"], model["z"]
-    # an auxiliary loss with no weight (or no routed layer) is no term
-    # of the objective: LFM2's configuration has none
+    # an auxiliary loss with no weight (or no routed layer, or no
+    # module) is no term of the objective
     terms = {"moe_aux_loss": (aux, aux_loss_weight),
-             "moe_z_loss": (z, z_loss_weight)}
+             "moe_z_loss": (z, z_loss_weight),
+             "mtp_loss": (model["mtp_ce"], mtp_loss_weight)}
     terms = {name: (var, w) for name, (var, w) in terms.items()
              if var is not None and w}
     loss = layers.sums([ce] + [layers.scale(var, scale=w)

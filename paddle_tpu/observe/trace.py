@@ -158,6 +158,9 @@ _FLUID_SCOPE_RE = re.compile(
     r"(?:^|[/(])([A-Za-z0-9_.\-]+):(\d+)(?=[/)]|$)")
 
 
+_SCOPE_PATH_RE = re.compile(r"((?:[A-Za-z0-9_.\-]+/)*)$")
+
+
 def fluid_op_of(op_name: str) -> Optional[str]:
     """Innermost `<op_type>:<index>` scope segment of an HLO op_name
     (including transform-wrapped `jvp(...)` / `transpose(jvp(...))`
@@ -165,6 +168,21 @@ def fluid_op_of(op_name: str) -> Optional[str]:
     attribution."""
     hits = _FLUID_SCOPE_RE.findall(op_name)
     return hits[-1][0] if hits else None
+
+
+def name_scope_of(op_name: str) -> str:
+    """The `fluid.name_scope()` path an instruction's op was built
+    under ("" for none): the executor lowers such an op as
+    "<path>/<op_type>:<op_index>", so the path is the run of plain
+    segments that ends at the innermost fluid scope (a `jit(...)` or a
+    transform's `jvp(` ends it; a `while/body` of jax's own between
+    the two would read as part of it, so ask for a segment, not for
+    equality)."""
+    hits = list(_FLUID_SCOPE_RE.finditer(op_name))
+    if not hits:
+        return ""
+    return _SCOPE_PATH_RE.search(
+        op_name[:hits[-1].start(1)]).group(1).rstrip("/")
 
 
 def phase_of(op_name: str) -> str:
@@ -239,7 +257,8 @@ def join_events(ops, modules, programs, window=None, chip=0
     child covers, so the rows of a window sum to its busy union.
 
     One row per (module, instruction): chip, module, instruction,
-    op_name, op_type (fluid), phase, bucket, flops and bytes (per
+    op_name, op_type (fluid), name_scope (the `fluid.name_scope()` path
+    the op was built under, "" for none), phase, bucket, flops and bytes (per
     call), kernel (a Mosaic kernel's name, else None), joined (found
     in its program's map), calls, self_s, total_s, max_s, min_s (of
     one call's self time).
@@ -272,6 +291,7 @@ def join_events(ops, modules, programs, window=None, chip=0
                 "chip": chip, "module": module, "instruction": name,
                 "op_name": op_name,
                 "op_type": fluid_op_of(op_name) if op_name else None,
+                "name_scope": name_scope_of(op_name or ""),
                 "phase": phase_of(op_name or ""),
                 "bucket": (UNJOINED_BUCKET if info is None
                            else info["bucket"] or BODY_BUCKET),

@@ -1,7 +1,9 @@
 """The small ops of a modern decoder block: `rms_norm`, `rope`
-(rotate-half rotary positions), `swiglu` (the gated FFN activation)
-and `short_conv` (the gated short convolution that stands where
-attention does in most layers of a hybrid conv/attention model).
+(rotary positions, over halves or over pairs), `swiglu` (the gated FFN
+activation), `short_conv` (the gated short convolution that stands
+where attention does in most layers of a hybrid conv/attention model)
+and `latent_attention` (the attention core of a layer whose keys and
+values come out of a low-rank latent, with a rotary part beside it).
 
 Not in the 1.2 reference (it predates them all); they are ops of
 their own, not compositions of `square` / `reduce_mean` / `slice` /
@@ -64,9 +66,10 @@ def rope_angles(positions, head_dim, theta):
 
 @register_op("rope")
 def rope(ctx, ins, attrs):
-    """Rotate-half rotary embedding over the whole head.  X is
-    head-grouped (N, T, H*D), what a q or k projection emits; the pair
-    (x[i], x[i + D/2]) of every head turns by pos * theta^(-2i/D).
+    """Rotary embedding over the whole head.  X is head-grouped
+    (N, T, H*D), what a q or k projection emits; the pair
+    (x[i], x[i + D/2]) of every head turns by pos * theta^(-2i/D)
+    (rotate-half), or with `interleave` the pair (x[2i], x[2i + 1]).
     Offset (1,) is the position of X's first row (a decode step passes
     the cache length); absent = 0."""
     x = first(ins, "X")
@@ -84,9 +87,14 @@ def rope(ctx, ins, attrs):
     ang = rope_angles(pos, d, theta)                  # (T, D/2)
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
-    xf = x.astype(jnp.float32).reshape(n, t, n_head, 2, d // 2)
-    x1, x2 = xf[..., 0, :], xf[..., 1, :]
-    y = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)
+    # a head as (2, D/2) halves, or as (D/2, 2) pairs
+    pairs = attrs.get("interleave", False)
+    xf = x.astype(jnp.float32).reshape(
+        (n, t, n_head) + ((d // 2, 2) if pairs else (2, d // 2)))
+    x1, x2 = (xf[..., 0], xf[..., 1]) if pairs else \
+        (xf[..., 0, :], xf[..., 1, :])
+    y = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                  axis=-1 if pairs else -2)
     return out(Out=y.reshape(n, t, hd).astype(x.dtype))
 
 
@@ -131,3 +139,57 @@ def short_conv(ctx, ins, attrs):
         raise ValueError(f"short_conv: X {x.shape} is not (N, T, 3D) for "
                          f"a Filter {w.shape} of (D, L)")
     return out(Out=jax.checkpoint(_short_conv)(x, w))
+
+
+def plain_latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head, scale):
+    """`latent_attention` as it is written: every head's score over its
+    unrotated and its rotary lanes, a causal soft-max in float32."""
+    n, t, _ = q_nope.shape
+    f32 = jnp.float32
+
+    def heads(x):
+        return x.astype(f32).reshape(n, t, n_head, -1)
+
+    s = (jnp.einsum("nqhd,nkhd->nhqk", heads(q_nope), heads(k_nope))
+         + jnp.einsum("nqhd,nkd->nhqk", heads(q_rope), k_rope.astype(f32)))
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    p = jax.nn.softmax(jnp.where(causal, s * scale, -jnp.inf), axis=-1)
+    o = jnp.einsum("nhqk,nkhd->nqhd", p, heads(v))
+    return o.reshape(n, t, -1).astype(v.dtype)
+
+
+@register_op("latent_attention")
+def latent_attention(ctx, ins, attrs):
+    """The attention core of a latent-attention layer, causal, over
+    one sequence a row.  Head-major operands as the up-projections emit
+    them: QNope, KNope (N, T, H*Dn), QRope (N, T, H*Dr) and V
+    (N, T, H*Dv) hold H heads side by side; KRope (N, T, Dr) is ONE
+    rotary key head that every query head reads (rotated already, as
+    QRope is).
+
+        s_ij = (q_nope_i . k_nope_j + q_rope_i . k_rope_j) / sqrt(Dn + Dr)
+        Out  = causal_softmax(s) V                       (N, T, H*Dv)
+
+    `use_pallas` sends it to the kernels of `ops/pallas/flash_mla.py`
+    (Dn 128, Dr 64, Dv 128), which read the operands where they lie:
+    the rotary key is never repeated over the heads and V is never
+    padded to the score's width."""
+    q_nope, q_rope = first(ins, "QNope"), first(ins, "QRope")
+    k_nope, k_rope = first(ins, "KNope"), first(ins, "KRope")
+    v = first(ins, "V")
+    n_head = int(attrs["n_head"])
+    nope, rope_dim = q_nope.shape[-1] // n_head, k_rope.shape[-1]
+    if (q_nope.shape[-1] != n_head * nope or k_nope.shape != q_nope.shape
+            or q_rope.shape[-1] != n_head * rope_dim
+            or v.shape[-1] % n_head):
+        raise ValueError(
+            f"latent_attention: QNope {q_nope.shape}, QRope {q_rope.shape}, "
+            f"KNope {k_nope.shape}, KRope {k_rope.shape}, V {v.shape} are "
+            f"not {n_head} heads and one rotary key head")
+    scale = (nope + rope_dim) ** -0.5
+    if attrs.get("use_pallas", False):
+        from .pallas.flash_mla import flash_mla
+
+        return out(Out=flash_mla(q_nope, q_rope, k_nope, k_rope, v, scale))
+    return out(Out=plain_latent_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                          n_head, scale))

@@ -42,7 +42,8 @@ third, `router_gradient`, is the caller's say over the backward pass):
   token's experts are the k largest of `s + Bias` (`Bias` (E,), state
   that no gradient reaches: it steers the choice and never the
   weight), its weights the unbiased `s` of the chosen, over their sum
-  + 1e-6 with `norm_topk_prob`, times `routed_scaling_factor`.  With
+  + `norm_topk_eps` (1e-6 absent) with `norm_topk_prob`, times
+  `routed_scaling_factor`.  With
   `bias_update_rate` u > 0 the step also balances the load the way the
   bias exists for (Wang et al. 2024, arXiv:2408.15664, no auxiliary
   loss): `BiasOut = Bias + u * sign(mean load - load)` over ALL E
@@ -265,9 +266,10 @@ SIGMOID_NORM_EPS = 1e-6
 
 
 def route_sigmoid(logits, top_k, bias=None, norm_topk_prob=False,
-                  scaling=1.0):
+                  scaling=1.0, norm_eps=SIGMOID_NORM_EPS):
     """The sigmoid router: scores (T, E), weights (T, k), experts
-    (T, k) int32.  `bias` (E,) moves the choice only."""
+    (T, k) int32.  `bias` (E,) moves the choice only; `norm_eps` is
+    what a family adds to the chosen scores' sum before it divides."""
     scores = jax.nn.sigmoid(logits)
     select = scores if bias is None else \
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
@@ -275,7 +277,7 @@ def route_sigmoid(logits, top_k, bias=None, norm_topk_prob=False,
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + SIGMOID_NORM_EPS)
+                             + norm_eps)
     if scaling != 1.0:
         weights = weights * scaling
     return scores, weights, experts.astype(jnp.int32)
@@ -329,7 +331,8 @@ def moe_dropless(ctx, ins, attrs):
     elif routing == "sigmoid":
         probs, weights, experts = route_sigmoid(
             logits, k, opt_in(ins, "Bias"), norm,
-            float(attrs.get("routed_scaling_factor", 1.0)))
+            float(attrs.get("routed_scaling_factor", 1.0)),
+            float(attrs.get("norm_topk_eps", SIGMOID_NORM_EPS)))
     else:
         raise ValueError(f"moe_dropless: routing {routing!r} is neither "
                          f"'softmax' nor 'sigmoid'")

@@ -173,6 +173,57 @@ def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
     assert f"bf16[{n},{t},{kv * 64}]" in text or dtype == F32
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
+    """What `joyai-8k`'s step hands the chip's compiler that no other
+    cell does (1 x 8192 tokens, 32 heads of 128 unrotated + 64 rotary
+    lanes, values of 128, ONE rotary key head): the three kernels of
+    `ops/pallas/flash_mla.py` through the `latent_attention` op, in the
+    cell's bfloat16 and in the parity script's float32 at "highest".  A
+    head's 64 rotary lanes are half a tile: the kernels block heads in
+    pairs, take the rotary key as a (rows, 64) block of the whole minor
+    dim, copy it across a tile's halves and fold its gradient's halves
+    in VMEM.  The rotary key and its gradient stay (N, T, 64) and v
+    stays 128 a head: nothing 32 x 192 wide exists."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+
+    n, t, heads = 1, 8192, 32
+    impl = get_op_impl("latent_attention")
+
+    def loss(q_nope, q_rope, k_nope, k_rope, v):
+        with jax.named_scope("latent_attention/latent_attention:9"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"QNope": [q_nope], "QRope": [q_rope],
+                      "KNope": [k_nope], "KRope": [k_rope], "V": [v]},
+                     {"n_head": heads, "use_pallas": True})["Out"][0]
+        return jnp.sum(o.astype(F32))
+
+    widths = (heads * 128, heads * 64, heads * 128, 64, heads * 128)
+    args = [jax.ShapeDtypeStruct((n, t, w), dtype, sharding=one_chip)
+            for w in widths]
+    prec = "default" if dtype == BF16 else "highest"
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
+            .lower(*args).compile()
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "flash_mla_dkv", "flash_mla_dq", "flash_mla_fwd"]
+    assert {r["op_type"] for r in rows if r["kernel"]} == {
+        "latent_attention"}
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 3
+    # dense-equivalent: scores 192 and values 128 forward; dv, dp 128
+    # and dk, dq 192 backward, 2 FLOP a lane
+    scores = n * heads * t * t
+    assert totals["pallas_flops"] >= 2 * (320 + 640) * scores
+    text = compiled.as_text()
+    assert f"[{n},{t},{heads * 192}]" not in text
+    if dtype == BF16:
+        assert f"bf16[{n},{t},64]" in text      # the rotary key's gradient
+
+
 def test_fused_vocab_ce_fwd_bwd(one_chip):
     from paddle_tpu.ops.pallas.vocab_ce import fused_vocab_ce
 

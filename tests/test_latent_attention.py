@@ -1,0 +1,167 @@
+"""Latent attention's ops on the CPU: the Pallas kernels of
+`ops/pallas/flash_mla.py` in interpret mode against plain attention on
+the concatenated 192-wide queries and keys (forward and all five
+gradients, the ONE rotary key's summed over the heads), the
+`latent_attention` op on both paths, and `rope` over pairs against
+`rope` over halves under the column permutation that maps one onto the
+other.  Mosaic's own checks are tests/test_chip_compile.py's.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.registry import OpContext, get_op_impl
+from paddle_tpu.ops.pallas import KERNEL_COSTS, flash_mla
+
+NOPE, ROPE = flash_mla.NOPE_DIM, flash_mla.ROPE_DIM
+
+
+def operands(n, t, h, seed=0, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shapes = [(n, t, h * NOPE), (n, t, h * ROPE), (n, t, h * NOPE),
+              (n, t, ROPE), (n, t, h * NOPE), (n, t, h * NOPE)]
+    return [jax.random.normal(k, s, dtype) for k, s in zip(ks, shapes)]
+
+
+def dense(q_nope, q_rope, k_nope, k_rope, v):
+    """Plain causal attention on q, k of 192 lanes a head: the rotary
+    key REPEATED over the heads, which is what the kernels never do."""
+    n, t, _ = q_nope.shape
+    h = q_nope.shape[-1] // NOPE
+    q = jnp.concatenate([q_nope.reshape(n, t, h, NOPE),
+                         q_rope.reshape(n, t, h, ROPE)], axis=-1)
+    k = jnp.concatenate([k_nope.reshape(n, t, h, NOPE),
+                         jnp.repeat(k_rope[:, :, None], h, axis=2)], axis=-1)
+    s = jnp.einsum("nqhd,nkhd->nhqk", q, k) * (NOPE + ROPE) ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    o = jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, axis=-1),
+                   v.reshape(n, t, h, NOPE))
+    return o.reshape(n, t, h * NOPE)
+
+
+# (N, T, H, block_q, block_k): square blocks, q blocks of two k blocks
+# (the diagonal crosses a block off its corner), k blocks of two q
+# blocks, and one block that is the whole sequence
+@pytest.mark.parametrize("geometry", [
+    (2, 256, 2, 128, 128), (1, 256, 2, 128, 64), (1, 256, 2, 64, 128),
+    (1, 128, 4, 512, 512)], ids=["square", "wide_q", "wide_k", "one_block"])
+def test_flash_mla_matches_dense_attention_forward_and_backward(geometry):
+    n, t, h, bq, bk = geometry
+    *args, w = operands(n, t, h)
+
+    def via(fn):
+        return lambda *a: jnp.sum(fn(*a) * w)
+
+    def kernel(*a):
+        return flash_mla.flash_mla(*a, block_q=bq, block_k=bk)
+
+    np.testing.assert_allclose(kernel(*args), dense(*args), atol=2e-5)
+    got = jax.grad(via(kernel), argnums=range(5))(*args)
+    want = jax.grad(via(dense), argnums=range(5))(*args)
+    for name, g, r in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"),
+                          got, want):
+        assert g.shape == r.shape, name
+        np.testing.assert_allclose(g, r, atol=5e-5 * float(jnp.abs(r).max()),
+                                   err_msg=name)
+    # the one rotary key's gradient is the sum over the heads'
+    assert got[3].shape == (n, t, ROPE)
+
+
+def test_the_rotary_key_is_one_head_at_the_kernel_boundary():
+    """The three kernels take k_rope (N, T, 64) and v (N, T, H*128) as
+    they lie: no operand of any of them is H x 192 wide."""
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+            for a in operands(1, 256, 4)[:5]]
+    text = jax.jit(jax.grad(
+        lambda *a: jnp.sum(flash_mla.flash_mla(*a, block_q=128, block_k=128)),
+        argnums=range(5))).lower(*args).as_text()
+    assert "x768x" not in text and "768xf32" not in text      # 4 x 192
+    assert "tensor<1x256x64xf32>" in text
+
+
+@pytest.mark.parametrize("what, shapes", [
+    ("odd", [(1, 128, 3 * NOPE), (1, 128, 3 * ROPE), (1, 128, 3 * NOPE),
+             (1, 128, ROPE), (1, 128, 3 * NOPE)]),
+    ("one rotary key head", [(1, 128, 2 * NOPE), (1, 128, 2 * ROPE),
+                             (1, 128, 2 * NOPE), (1, 128, 2 * ROPE),
+                             (1, 128, 2 * NOPE)]),
+    ("whole number", [(1, 192, 2 * NOPE), (1, 192, 2 * ROPE),
+                      (1, 192, 2 * NOPE), (1, 192, ROPE),
+                      (1, 192, 2 * NOPE)])])
+def test_a_geometry_the_kernels_do_not_block_is_refused(what, shapes):
+    args = [jnp.zeros(s, jnp.float32) for s in shapes]
+    with pytest.raises((ValueError, NotImplementedError), match=what):
+        flash_mla.flash_mla(*args, block_q=128, block_k=128)
+
+
+def test_kernel_costs_are_registered_under_the_kernels_names():
+    shapes = [((1, 8192, 32 * NOPE), 2), ((1, 8192, 32 * ROPE), 2),
+              ((1, 8192, 32 * NOPE), 2), ((1, 8192, ROPE), 2),
+              ((1, 8192, 32 * NOPE), 2)]
+    scores = 32 * 8192 * 8192
+    for name, lanes in (("flash_mla_fwd", 192 + 128),
+                        ("flash_mla_dkv", 192 + 128 + 128),
+                        ("flash_mla_dq", 192)):
+        flops, nbytes = KERNEL_COSTS[name](shapes, [((1, 8192, 4096), 2)])
+        # dense-equivalent: 2 x lanes a score, plus the soft-max's few
+        assert 2 * lanes * scores <= flops <= (2 * lanes + 8) * scores
+        assert nbytes == 2 * 8192 * (3 * 4096 + 2048 + 64 + 4096)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_the_op_gives_dense_attention_on_both_paths(use_pallas):
+    *args, _ = operands(1, 128, 2, seed=3)
+    impl = get_op_impl("latent_attention")
+    out = impl(OpContext(jax.random.PRNGKey(0)),
+               dict(zip(("QNope", "QRope", "KNope", "KRope", "V"),
+                        ([a] for a in args))),
+               {"n_head": 2, "use_pallas": use_pallas})["Out"][0]
+    np.testing.assert_allclose(out, dense(*args), atol=2e-5)
+
+
+def test_the_plain_path_takes_any_head_sizes():
+    """Dn 16, Dr 8, Dv 24: what the small-size parity tests run."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    n, t, h = 2, 12, 3
+    shapes = [(n, t, h * 16), (n, t, h * 8), (n, t, h * 16), (n, t, 8),
+              (n, t, h * 24)]
+    qn, qr, kn, kr, v = (jax.random.normal(k, s) for k, s in zip(ks, shapes))
+    impl = get_op_impl("latent_attention")
+    out = impl(OpContext(jax.random.PRNGKey(0)),
+               {"QNope": [qn], "QRope": [qr], "KNope": [kn], "KRope": [kr],
+                "V": [v]}, {"n_head": h})["Out"][0]
+    assert out.shape == (n, t, h * 24)
+    q = jnp.concatenate([qn.reshape(n, t, h, 16), qr.reshape(n, t, h, 8)], -1)
+    k = jnp.concatenate([kn.reshape(n, t, h, 16),
+                         jnp.repeat(kr[:, :, None], h, axis=2)], -1)
+    s = jnp.einsum("nqhd,nkhd->nhqk", q, k) / np.sqrt(24.0)
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    want = jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, -1),
+                      v.reshape(n, t, h, 24)).reshape(n, t, h * 24)
+    np.testing.assert_allclose(out, want, atol=2e-6)
+
+
+def test_rope_over_pairs_is_rope_over_halves_under_the_column_permutation():
+    """Rotating the pairs (2i, 2i+1) in place is rotating the halves
+    (i, i + D/2) of the head whose columns were gathered evens first:
+    y_pairs[:, perm] == rope_halves(x[:, perm])."""
+    impl = get_op_impl("rope")
+    ctx = OpContext(jax.random.PRNGKey(0))
+    n, t, h, d = 2, 9, 3, 8
+    x = jax.random.normal(jax.random.PRNGKey(2), (n, t, h * d))
+    perm = np.concatenate([np.arange(0, d, 2), np.arange(1, d, 2)])
+    cols = (np.arange(h)[:, None] * d + perm[None, :]).reshape(-1)
+    attrs = {"n_head": h, "theta": 32e6}
+    pairs = impl(ctx, {"X": [x]}, dict(attrs, interleave=True))["Out"][0]
+    halves = impl(ctx, {"X": [x[..., cols]]}, attrs)["Out"][0]
+    np.testing.assert_allclose(pairs[..., cols], halves, atol=1e-6)
+    assert float(jnp.abs(pairs - impl(ctx, {"X": [x]}, attrs)["Out"][0]
+                         ).max()) > 0.1
+    # position 0 is not rotated; a rotation keeps each pair's norm
+    np.testing.assert_allclose(pairs[:, 0], x[:, 0], atol=1e-6)
+    np.testing.assert_allclose(
+        jnp.sum(pairs.reshape(n, t, h, d // 2, 2) ** 2, -1),
+        jnp.sum(x.reshape(n, t, h, d // 2, 2) ** 2, -1), rtol=1e-5)

@@ -985,6 +985,12 @@ S["rms_norm"] = dict(
     grad=["X", "Scale"], tol=2e-2)
 S["rope"] = dict(ins={"X": f32((1, 3, 8), 270)}, attrs={"n_head": 2},
                  out="Out", shape=(1, 3, 8), grad=["X"])
+S["latent_attention"] = dict(
+    ins={"QNope": f32((1, 4, 8), 280), "QRope": f32((1, 4, 4), 281),
+         "KNope": f32((1, 4, 8), 282), "KRope": f32((1, 4, 2), 283),
+         "V": f32((1, 4, 6), 284)},
+    attrs={"n_head": 2}, out="Out", shape=(1, 4, 6),
+    grad=["QNope", "QRope", "KNope", "KRope", "V"], tol=2e-2)
 S["swiglu"] = dict(ins={"X": f32((2, 5), 271), "Y": f32((2, 5), 272)},
                    out="Out", shape=(2, 5), grad=["X", "Y"])
 S["short_conv"] = dict(
