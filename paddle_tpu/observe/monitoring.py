@@ -38,7 +38,8 @@ _TRACE_EVENT_PREFIXES = ("/jax/core/compile/jaxpr_trace_duration",
 _FIELDS = ("compiles", "compile_time_s", "trace_time_s", "builds",
            "retraces", "dispatches", "dispatch_time_s",
            "place_puts", "place_skips",
-           "dropout_masks_kernel", "dropout_masks_xla")
+           "dropout_masks_kernel", "dropout_masks_xla",
+           "flash_mla_backward_fused", "flash_mla_backward_split")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
 SPAN_PREFIX = "paddle_tpu.step."
@@ -93,6 +94,11 @@ class RuntimeStats:
         # (a step that fell back says so; delta() around a build)
         self.dropout_masks_kernel = 0
         self.dropout_masks_xla = 0
+        # backward passes of `ops/pallas/flash_mla.py` traced, by what
+        # the operands' shape chose: the single kernel, or dk/dv and dq
+        # by a kernel each (delta() around a build; `joyai-8k` 6 / 0)
+        self.flash_mla_backward_fused = 0
+        self.flash_mla_backward_split = 0
         # per-phase totals and the most recent durations
         self._phase_time_s: Dict[str, float] = {}
         self._phase_count: Dict[str, int] = {}
@@ -126,6 +132,13 @@ class RuntimeStats:
                 self.dropout_masks_kernel += 1
             else:
                 self.dropout_masks_xla += 1
+
+    def record_flash_mla_backward(self, fused: bool):
+        with self._lock:
+            if fused:
+                self.flash_mla_backward_fused += 1
+            else:
+                self.flash_mla_backward_split += 1
 
     def phase(self, name: str) -> _Phase:
         """Context manager around one host phase of a step: a

@@ -177,16 +177,22 @@ def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
 def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
     """What `joyai-8k`'s step hands the chip's compiler that no other
     cell does (1 x 8192 tokens, 32 heads of 128 unrotated + 64 rotary
-    lanes, values of 128, ONE rotary key head): the three kernels of
+    lanes, values of 128, ONE rotary key head): the kernels of
     `ops/pallas/flash_mla.py` through the `latent_attention` op, in the
     cell's bfloat16 and in the parity script's float32 at "highest".  A
     head's 64 rotary lanes are half a tile: the kernels block heads in
     pairs, take the rotary key as a (rows, 64) block of the whole minor
     dim, copy it across a tile's halves and fold its gradient's halves
     in VMEM.  The rotary key and its gradient stay (N, T, 64) and v
-    stays 128 a head: nothing 32 x 192 wide exists."""
+    stays 128 a head: nothing 32 x 192 wide exists.  At this length
+    the backward pass is ONE kernel, `flash_mla_dkv` grown by dq's two
+    dots, whose 17 MB of float32 accumulators (dq of a pair's whole
+    sequence, the rotary key's gradient) Mosaic must take in VMEM in
+    both dtypes; no partial of dq (32 heads x 192 = 6144 wide) reaches
+    HBM."""
     from paddle_tpu.core.registry import OpContext, get_op_impl
     from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
 
     n, t, heads = 1, 8192, 32
     impl = get_op_impl("latent_attention")
@@ -203,17 +209,21 @@ def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
     args = [jax.ShapeDtypeStruct((n, t, w), dtype, sharding=one_chip)
             for w in widths]
     prec = "default" if dtype == BF16 else "highest"
+    before = runtime_stats.snapshot()
     with force_mosaic_lowering(), jax.default_matmul_precision(prec):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
             .lower(*args).compile()
+    took = runtime_stats.delta(before)
+    assert (took["flash_mla_backward_fused"],
+            took["flash_mla_backward_split"]) == (1, 0)
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
-        "flash_mla_dkv", "flash_mla_dq", "flash_mla_fwd"]
+        "flash_mla_dkv", "flash_mla_fwd"]
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "latent_attention"}
     totals = cost.total_costs(proto)
-    assert totals["custom_calls"] == totals["pallas_matched"] == 3
+    assert totals["custom_calls"] == totals["pallas_matched"] == 2
     # dense-equivalent: scores 192 and values 128 forward; dv, dp 128
     # and dk, dq 192 backward, 2 FLOP a lane
     scores = n * heads * t * t
@@ -222,6 +232,41 @@ def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
     assert f"[{n},{t},{heads * 192}]" not in text
     if dtype == BF16:
         assert f"bf16[{n},{t},64]" in text      # the rotary key's gradient
+
+
+@pytest.mark.parametrize("t, dtype, fused", [
+    (16384, F32, True), (32768, BF16, False)], ids=["edge", "beyond"])
+def test_latent_attention_backward_follows_the_budget(one_chip, t, dtype,
+                                                       fused):
+    """The shape rule's two sides.  At the accumulators' budget (2 KiB
+    a position: 16384 positions are its 32 MiB) Mosaic still takes the
+    single backward kernel, with float32 operands, the larger blocks;
+    past it the two backward kernels stay, which hold blocks only.  The
+    counter says which path the trace took."""
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas import flash_mla
+
+    assert flash_mla.fused_backward_fits(t) == fused
+    assert not flash_mla.fused_backward_fits(t + 1024) or not fused
+    n, heads = 1, 8
+    widths = (heads * 128, heads * 64, heads * 128, 64, heads * 128)
+    args = [jax.ShapeDtypeStruct((n, t, w), dtype, sharding=one_chip)
+            for w in widths]
+    prec = "default" if dtype == BF16 else "highest"
+    before = runtime_stats.snapshot()
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        compiled = jax.jit(jax.grad(
+            lambda *a: jnp.sum(flash_mla.flash_mla(*a).astype(F32)),
+            argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    took = runtime_stats.delta(before)
+    assert (took["flash_mla_backward_fused"],
+            took["flash_mla_backward_split"]) == (
+                (1, 0) if fused else (0, 1))
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == (
+        ["flash_mla_dkv", "flash_mla_fwd"] if fused else
+        ["flash_mla_dkv", "flash_mla_dq", "flash_mla_fwd"])
 
 
 def test_fused_vocab_ce_fwd_bwd(one_chip):

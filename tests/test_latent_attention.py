@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.registry import OpContext, get_op_impl
+from paddle_tpu.observe.monitoring import runtime_stats
 from paddle_tpu.ops.pallas import KERNEL_COSTS, flash_mla
 
 NOPE, ROPE = flash_mla.NOPE_DIM, flash_mla.ROPE_DIM
@@ -70,9 +71,107 @@ def test_flash_mla_matches_dense_attention_forward_and_backward(geometry):
     assert got[3].shape == (n, t, ROPE)
 
 
-def test_the_rotary_key_is_one_head_at_the_kernel_boundary():
-    """The three kernels take k_rope (N, T, 64) and v (N, T, H*128) as
-    they lie: no operand of any of them is H x 192 wide."""
+def _backward_path(monkeypatch, path):
+    """Send the backward pass down `path` the only way there is: the
+    shape rule's budget (no option chooses)."""
+    monkeypatch.setattr(flash_mla, "FUSED_ACCUMULATOR_BUDGET",
+                        {"one_kernel": 1 << 40, "two_kernels": 0}[path])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [2, 4])
+@pytest.mark.parametrize("blocks, block_q, block_k", [
+    (1, 128, 128), (2, 128, 128), (4, 128, 128), (2, 128, 64), (2, 64, 128),
+    (4, 64, 128)], ids=["1_block", "2_blocks", "4_blocks", "wide_q",
+                        "wide_k", "4_blocks_wide_k"])
+@pytest.mark.parametrize("path", ["one_kernel", "two_kernels"])
+def test_both_backward_paths_give_the_dense_gradients(
+        monkeypatch, path, blocks, block_q, block_k, heads, dtype):
+    """All five gradients, the single backward kernel and the two, over
+    T of 1, 2 and 4 blocks and block_q != block_k (a dq block then
+    completes off the diagonal's corner, and a pass may complete two or
+    none), 2 and 4 heads (the rotary key's gradient sums over the
+    pairs, the outer axis of the single kernel)."""
+    _backward_path(monkeypatch, path)
+    t = blocks * max(block_q, block_k)
+    *args, w = operands(1, t, heads, seed=blocks + heads)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=range(5))(
+        *args)
+    args = [a.astype(dtype) for a in args]
+    before = runtime_stats.snapshot()
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(
+            lambda *a: jnp.sum(flash_mla.flash_mla(
+                *a, block_q=block_q, block_k=block_k).astype(jnp.float32) * w),
+            argnums=range(5))(*args)
+    took = runtime_stats.delta(before)
+    assert (took["flash_mla_backward_fused"],
+            took["flash_mla_backward_split"]) == (
+                (1, 0) if path == "one_kernel" else (0, 1))
+    # float32: today's limit; bfloat16 operands: p and ds are cast to
+    # 8 bits of mantissa before their dots
+    limit = 5e-5 if dtype == jnp.float32 else 4e-2
+    for name, g, r in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"),
+                          got, want):
+        assert g.shape == r.shape and g.dtype == dtype, name
+        np.testing.assert_allclose(g.astype(jnp.float32), r,
+                                   atol=limit * float(jnp.abs(r).max()),
+                                   err_msg=name)
+
+
+def test_the_two_backward_paths_agree_to_the_bit(monkeypatch):
+    """Same arithmetic in the same order: the single kernel sums dq
+    over the key blocks and dk over the query blocks as the two do."""
+    *args, w = operands(2, 256, 4, seed=11)
+    grads = {}
+    for path in ("one_kernel", "two_kernels"):
+        _backward_path(monkeypatch, path)
+        grads[path] = jax.grad(
+            lambda *a: jnp.sum(flash_mla.flash_mla(
+                *a, block_q=64, block_k=128) * w), argnums=range(5))(*args)
+    for a, b in zip(grads["one_kernel"], grads["two_kernels"]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_shape_alone_chooses_the_backward_path():
+    """The accumulators of the single kernel are 2 KiB a position: the
+    cell's 8192 positions fit the budget, 32768 and the model's 131072
+    do not, whatever the operands' dtype; a traced backward says which
+    it took."""
+    assert flash_mla.fused_backward_fits(8192)
+    assert flash_mla.fused_backward_fits(
+        flash_mla.FUSED_ACCUMULATOR_BUDGET // 2048)
+    assert not flash_mla.fused_backward_fits(
+        flash_mla.FUSED_ACCUMULATOR_BUDGET // 2048 + 1024)
+    assert not flash_mla.fused_backward_fits(32768)
+    assert not flash_mla.fused_backward_fits(131072)
+
+    def kernels(t):
+        args = [jax.ShapeDtypeStruct((1, t, w), jnp.bfloat16)
+                for w in (2 * NOPE, 2 * ROPE, 2 * NOPE, ROPE, 2 * NOPE)]
+        before = runtime_stats.snapshot()
+        text = jax.jit(jax.grad(
+            lambda *a: jnp.sum(flash_mla.flash_mla(*a).astype(jnp.float32)),
+            argnums=range(5))).lower(*args).as_text(debug_info=True)
+        took = runtime_stats.delta(before)
+        return (sorted(n for n in ("flash_mla_fwd", "flash_mla_dkv",
+                                   "flash_mla_dq") if f"pallas_{n}" in text),
+                took["flash_mla_backward_fused"],
+                took["flash_mla_backward_split"])
+
+    assert kernels(8192) == (["flash_mla_dkv", "flash_mla_fwd"], 1, 0)
+    assert kernels(32768) == (
+        ["flash_mla_dkv", "flash_mla_dq", "flash_mla_fwd"], 0, 1)
+
+
+@pytest.mark.parametrize("path", ["one_kernel", "two_kernels"])
+def test_the_rotary_key_is_one_head_at_the_kernel_boundary(monkeypatch, path):
+    """The kernels take k_rope (N, T, 64) and v (N, T, H*128) as they
+    lie: no operand of any of them is H x 192 wide, and the single
+    backward kernel's dq accumulators never leave VMEM (no (T, H*192)
+    or per-key-block partial of dq in the program)."""
+    _backward_path(monkeypatch, path)
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype)
             for a in operands(1, 256, 4)[:5]]
     text = jax.jit(jax.grad(
@@ -80,6 +179,7 @@ def test_the_rotary_key_is_one_head_at_the_kernel_boundary():
         argnums=range(5))).lower(*args).as_text()
     assert "x768x" not in text and "768xf32" not in text      # 4 x 192
     assert "tensor<1x256x64xf32>" in text
+    assert "tensor<2x256x" not in text          # nk = 2 partials of dq
 
 
 @pytest.mark.parametrize("what, shapes", [
@@ -98,17 +198,32 @@ def test_a_geometry_the_kernels_do_not_block_is_refused(what, shapes):
 
 
 def test_kernel_costs_are_registered_under_the_kernels_names():
+    """`flash_mla_dkv` names the two-kernel path's dk / dv kernel (three
+    gradients out) and the single backward kernel (five): the second
+    carries dq's dense-equivalent work too, so a step's total is the
+    same on both paths."""
     shapes = [((1, 8192, 32 * NOPE), 2), ((1, 8192, 32 * ROPE), 2),
               ((1, 8192, 32 * NOPE), 2), ((1, 8192, ROPE), 2),
               ((1, 8192, 32 * NOPE), 2)]
     scores = 32 * 8192 * 8192
-    for name, lanes in (("flash_mla_fwd", 192 + 128),
-                        ("flash_mla_dkv", 192 + 128 + 128),
-                        ("flash_mla_dq", 192)):
-        flops, nbytes = KERNEL_COSTS[name](shapes, [((1, 8192, 4096), 2)])
+    wide, rotary, key = ((1, 8192, 4096), 2), ((1, 8192, 2048), 2), \
+        ((1, 8192, 64), 2)
+    for name, results, lanes in (
+            ("flash_mla_fwd", [wide], 192 + 128),
+            ("flash_mla_dkv", [wide, key, wide], 192 + 128 + 128),
+            ("flash_mla_dq", [wide, rotary], 192),
+            ("flash_mla_dkv", [wide, rotary, wide, key, wide],
+             192 + 128 + 128 + 192)):
+        flops, nbytes = KERNEL_COSTS[name](shapes, results)
         # dense-equivalent: 2 x lanes a score, plus the soft-max's few
         assert 2 * lanes * scores <= flops <= (2 * lanes + 8) * scores
-        assert nbytes == 2 * 8192 * (3 * 4096 + 2048 + 64 + 4096)
+        assert nbytes == 2 * 8192 * (3 * 4096 + 2048 + 64) + sum(
+            2 * dims[1] * dims[2] for dims, _ in results)
+    two = (KERNEL_COSTS["flash_mla_dkv"](shapes, [wide, key, wide])[0]
+           + KERNEL_COSTS["flash_mla_dq"](shapes, [wide, rotary])[0])
+    one, _ = KERNEL_COSTS["flash_mla_dkv"](
+        shapes, [wide, rotary, wide, key, wide])
+    assert one == two
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
