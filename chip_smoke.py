@@ -123,11 +123,13 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None,
     its dict joins the phase line.  Returns (per-step losses, that
     dict)."""
     import paddle_tpu as fluid
-    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.observe.monitoring import (format_cold_run,
+                                               runtime_stats)
 
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 7
     scope = fluid.Scope()
+    cold_before = runtime_stats.snapshot()["cold_runs"]
     with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
             fluid.unique_name.guard():
         loss = build()["loss"]
@@ -149,6 +151,10 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None,
         losses = [step()]
         first_s = time.perf_counter() - t0
         cold = runtime_stats.delta(snap)
+        # the start-up run and the first step, as the program saw them
+        emit(phase + ".cold_runs", runs=[
+            format_cold_run(r) for r in runtime_stats.cold_runs()[
+                cold_before - runtime_stats.snapshot()["cold_runs"]:]])
         snap = runtime_stats.snapshot()
         t0 = time.perf_counter()
         losses += [step() for _ in range(STEPS - 1)]

@@ -1170,11 +1170,16 @@ class Executor:
         feed = dict(feed or {})
         fetch_names = _fetch_names(fetch_list)
 
+        # replaced whenever a step fn is built, a feed signature is new
+        # or jax traces, lowers, compiles or reads its cache: another
+        # object after the call means this run was cold
+        heard = runtime_stats.heard
         fn, state, feed_arrays = self._prepare(
             program, feed, fetch_names, scope, iterations,
             use_program_cache, accumulation_steps, placement)
         with runtime_stats.phase("call"):
             new_state, fetches = fn(state, feed_arrays)
+        cold = runtime_stats.heard is not heard
         with runtime_stats.phase("writeback"):
             for name, val in new_state.items():
                 scope.set_var(name, val)
@@ -1184,6 +1189,13 @@ class Executor:
             _debug_checks(fetch_names, fetches, new_state)
             if return_numpy:
                 fetches = [np.asarray(f) for f in fetches]
+        if cold:
+            runtime_stats.record_cold_run(
+                heard, program=program._uid,
+                ops=len(program.global_block().ops),
+                state_arrays=len(new_state), feed_arrays=len(feed),
+                fetches=len(fetch_names),
+                placement=placement is not None)
         return fetches
 
     def close(self):

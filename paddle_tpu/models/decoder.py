@@ -123,6 +123,7 @@ from __future__ import annotations
 
 from .. import layers, optimizer
 from ..core.program import name_scope
+from ..observe.monitoring import runtime_stats
 from ..clip import GradientClipByGlobalNorm, set_gradient_clip
 from ..initializer import Normal
 from ..param_attr import ParamAttr
@@ -353,6 +354,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             "mtp_ce": mtp_ce}
 
 
+@runtime_stats.stage("build_program")
 def build_model(max_length, learning_rate=4e-4, beta1=0.9, beta2=0.95,
                 epsilon=1e-8, weight_decay=0.1, warmup_steps=2000,
                 decay_steps=1_000_000, lr_floor=0.1, clip_norm=1.0,

@@ -13,6 +13,7 @@ transposed once at the front of the graph.
 from __future__ import annotations
 
 from .. import layers, optimizer
+from ..observe.monitoring import runtime_stats
 
 
 def conv_bn_layer(input, ch_out, filter_size, stride, padding, act="relu",
@@ -111,6 +112,7 @@ def resnet_cifar10(input, class_dim, depth=32, is_train=True,
     return out
 
 
+@runtime_stats.stage("build_program")
 def build_model(dataset="flowers", depth=50, class_dim=1000,
                 learning_rate=0.01, with_optimizer=True, is_train=True,
                 use_amp=False, data_format="NCHW"):

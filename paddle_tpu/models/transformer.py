@@ -17,6 +17,7 @@ import numpy as np
 from .. import layers, optimizer
 from ..param_attr import ParamAttr
 from ..initializer import Normal
+from ..observe.monitoring import runtime_stats
 
 
 def multi_head_attention(queries, keys, values, attn_bias, d_key, d_value,
@@ -435,6 +436,7 @@ def transformer(src_vocab_size=10000, trg_vocab_size=10000, max_length=64,
     return avg_cost, logits, feeds
 
 
+@runtime_stats.stage("build_program")
 def build_model(src_vocab_size=10000, trg_vocab_size=10000, max_length=64,
                 n_layer=6, n_head=8, d_model=512, d_inner_hid=2048,
                 dropout=0.1, learning_rate=2.0, warmup_steps=4000,

@@ -13,8 +13,12 @@ Three pillars (docs/OBSERVE.md):
    state, no host round-trips, no callbacks) and is fetched every N
    steps in one sync; host-side
    `runtime_stats` counts XLA compiles (+wall time, via
-   jax.monitoring), executor retraces, and the four host phases of a
-   step (prepare / place / call / writeback; `call` is the dispatch).
+   jax.monitoring), persistent-cache hits and misses, executor
+   retraces, and the four host phases of a step (prepare / place /
+   call / writeback; `call` is the dispatch); every COLD run (jax
+   traced, lowered, compiled or read its cache) leaves a record in
+   `runtime_stats.cold_runs()`, and building a Program is
+   `runtime_stats.stage("build_program")`.
 
 3. STRUCTURED RUN EVENTS — `RunEventLog` writes JSONL records with
    run-id/git-sha/backend/mesh provenance, consumed by

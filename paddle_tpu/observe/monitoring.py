@@ -6,44 +6,75 @@ platform/profiler RecordEvent; on TPU the expensive host-side events
 are XLA COMPILES (seconds each) and jit RETRACES (a shape change
 silently recompiling the step), which are invisible without hooks.
 Compile events come from `jax.monitoring` (the jit/pjit internals emit
-`/jax/core/compile/backend_compile_duration` per backend compile);
-retraces are detected in `Executor._prepare` by input-signature change
-on an already-built step fn (jax re-traces per new shape/dtype
-signature).  The host side of one step is split into four phases,
+`/jax/core/compile/backend_compile_duration` per backend compile, a
+duration each for jaxpr tracing and MLIR lowering, and the persistent
+compilation cache a hit or a miss per request); retraces are detected
+in `Executor._prepare` by input-signature change on an already-built
+step fn (jax re-traces per new shape/dtype signature).  The host side of one step is split into four phases,
 `prepare`, `place`, `call` and `writeback`, entered through
 `runtime_stats.phase(name)` in `Executor.run` and
 `CompiledProgram.run`: each is a `paddle_tpu.step.<name>` span in a
 profiler trace and a counter here.  `call` is the jitted call alone
 (async: device completion is NOT included) and also feeds
 `dispatches` / `dispatch_time_s`.
+
+Set-up is seen through the same counters.  Everything that makes a run
+of `Executor.run` slow the first time (a step fn built, a feed
+signature met, and whatever jax then says it traced, lowered, compiled
+or read from its cache) is kept in ONE immutable tuple,
+`runtime_stats.heard`, which is replaced on every such event.
+`Executor.run` reads it on entry and after the call: the same object
+means a warm run and costs nothing more; another means the run was
+COLD, and one record of it (`runtime_stats.cold_runs()`) says which
+program, how long each phase took and what jax did meanwhile.  Building
+a Program is `runtime_stats.stage("build_program")`.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
-# wraps compile_or_get_cached: a persistent-cache hit is counted too,
-# at its (short) retrieval time
+# What makes a run cold, as `runtime_stats.heard` holds it.  `events`
+# counts the replacements; the other fields are counters of their own
+# in `snapshot()`.  jaxpr tracing and MLIR lowering are the host-side
+# work a cold dispatch pays BEFORE the backend compile (their sum is
+# `trace_time_s`, which the goodput ledger folds into "compile");
+# both count outermost spans only, so they are wall time.
+Heard = collections.namedtuple("Heard", (
+    "events", "builds", "retraces", "compiles", "compile_time_s",
+    "jaxpr_trace_time_s", "lower_time_s",
+    "cache_hits", "cache_misses", "cache_read_time_s"))
+# jax.monitoring's names.  backend_compile_duration wraps
+# compile_or_get_cached: a persistent-cache hit is counted too, at its
+# (short) retrieval time, which the cache's own events tell apart
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# jaxpr tracing + mlir lowering: the host-side compilation work a cold
-# dispatch pays BEFORE the backend compile — the goodput ledger folds
-# it into the "compile" category so a first/replayed step's own time
-# stays dispatch-sized
-_TRACE_EVENT_PREFIXES = ("/jax/core/compile/jaxpr_trace_duration",
-                         "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SPAN_EVENTS = {        # these nest: see install()
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace_time_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_time_s"}
+_COUNT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses"}
 
-_FIELDS = ("compiles", "compile_time_s", "trace_time_s", "builds",
-           "retraces", "dispatches", "dispatch_time_s",
-           "place_puts", "place_skips",
-           "dropout_masks_kernel", "dropout_masks_xla",
-           "flash_mla_backward_fused", "flash_mla_backward_split")
+# `cold_runs` (the count) is in the snapshot too: the name is the
+# records' method here
+_FIELDS = Heard._fields[1:] + (
+    "trace_time_s", "dispatches", "dispatch_time_s",
+    "place_puts", "place_skips",
+    "dropout_masks_kernel", "dropout_masks_xla",
+    "flash_mla_backward_fused", "flash_mla_backward_split")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
 SPAN_PREFIX = "paddle_tpu.step."
+# what `stage(name)` takes: a model builder appending its ops
+SETUP_STAGES = ("build_program",)
+SETUP_SPAN_PREFIX = "paddle_tpu.setup."
 _RECENT = 4096          # durations kept per phase
+_COLD_RUNS = 256        # records kept
 
 
 class _Phase:
@@ -75,12 +106,11 @@ class RuntimeStats:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self.compiles = 0           # XLA backend compiles (jax.monitoring)
-        self.compile_time_s = 0.0   # total backend-compile wall time
-        self.trace_time_s = 0.0     # jaxpr trace + mlir lowering wall
-        self.builds = 0             # Executor step fns traced (cache miss)
-        self.retraces = 0           # re-compiles of an existing step fn
-        #                             caused by a feed signature change
+        # builds: Executor step fns traced (program-cache miss);
+        # retraces: a NEW feed signature on an existing step fn;
+        # compiles / compile_time_s: XLA backend compiles and their
+        # wall time (jax.monitoring); the rest as `Heard` says
+        self.heard = Heard(*(0,) * len(Heard._fields))
         self.dispatches = 0         # step dispatches: the `call` phase
         self.dispatch_time_s = 0.0  # host enqueue time (async; excludes
         #                             device execution)
@@ -99,27 +129,43 @@ class RuntimeStats:
         # by a kernel each (delta() around a build; `joyai-8k` 6 / 0)
         self.flash_mla_backward_fused = 0
         self.flash_mla_backward_split = 0
-        # per-phase totals and the most recent durations
-        self._phase_time_s: Dict[str, float] = {}
-        self._phase_count: Dict[str, int] = {}
+        # per-phase totals and the most recent durations; the stages of
+        # set-up beside them (outermost entries only), there from the
+        # start so that a snapshot always carries them
+        self._phase_time_s: Dict[str, float] = dict.fromkeys(
+            SETUP_STAGES, 0.0)
+        self._phase_count: Dict[str, int] = dict.fromkeys(SETUP_STAGES, 0)
+        self._stage_depth: Dict[str, int] = dict.fromkeys(SETUP_STAGES, 0)
         self._recent: Dict[str, collections.deque] = {}
+        self._cold_runs = collections.deque(maxlen=_COLD_RUNS)
+        self._cold_run_count = 0
 
-    def record_compile(self, duration_s: float):
+    def hear(self, **added):
+        """One event that makes the run it falls in cold: `heard` is
+        replaced by a tuple with `added` added and `events` one up."""
         with self._lock:
-            self.compiles += 1
-            self.compile_time_s += float(duration_s)
-
-    def record_trace(self, duration_s: float):
-        with self._lock:
-            self.trace_time_s += float(duration_s)
+            h = self.heard
+            self.heard = h._replace(
+                events=h.events + 1,
+                **{f: getattr(h, f) + v for f, v in added.items()})
 
     def record_build(self):
-        with self._lock:
-            self.builds += 1
+        self.hear(builds=1)
 
     def record_retrace(self):
-        with self._lock:
-            self.retraces += 1
+        self.hear(retraces=1)
+
+    def __getattr__(self, name):
+        # the counters `heard` holds read as attributes of their own
+        if name in Heard._fields:
+            return getattr(self.__dict__["heard"], name)
+        raise AttributeError(name)
+
+    @property
+    def trace_time_s(self) -> float:
+        """jaxpr tracing + MLIR lowering wall time."""
+        h = self.heard
+        return h.jaxpr_trace_time_s + h.lower_time_s
 
     def record_place(self, puts: int, skips: int):
         with self._lock:
@@ -162,6 +208,71 @@ class RuntimeStats:
                 self.dispatches += 1
                 self.dispatch_time_s += duration_s
 
+    @contextlib.contextmanager
+    def stage(self, name: str):
+        """Context manager and decorator around one stage of set-up
+        (`build_program`: a model builder appending forward, backward
+        and optimizer ops): a `paddle_tpu.setup.<name>` span and
+        `<name>_time_s` / `<name>_count` in `snapshot()`.  Re-entrant:
+        an entry inside another of the same name adds nothing."""
+        import jax
+
+        with jax.profiler.TraceAnnotation(SETUP_SPAN_PREFIX + name):
+            with self._lock:
+                self._stage_depth[name] += 1
+                outermost = self._stage_depth[name] == 1
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                duration_s = time.perf_counter() - t0
+                with self._lock:
+                    self._stage_depth[name] -= 1
+                    if outermost:
+                        self._phase_time_s[name] += duration_s
+                        self._phase_count[name] += 1
+
+    def record_cold_run(self, before: Heard, **what):
+        """Append the record of one cold `Executor.run`: `what` (the
+        program and its arrays), the run's own phase durations, and
+        what was heard since `before`, the `heard` of its entry."""
+        now = time.perf_counter()
+        with self._lock:
+            h = self.heard
+            phases = {p: self._recent[p][-1] for p in STEP_PHASES}
+            self._cold_run_count += 1
+            self._cold_runs.append(dict(
+                what,
+                # to the microseconds between the phases
+                t_entry=now - sum(phases.values()),
+                new_signature=(h.builds + h.retraces
+                               > before.builds + before.retraces),
+                **{p + "_s": phases[p] for p in STEP_PHASES},
+                trace_s=h.jaxpr_trace_time_s - before.jaxpr_trace_time_s,
+                lower_s=h.lower_time_s - before.lower_time_s,
+                backend_compile_s=h.compile_time_s - before.compile_time_s,
+                compiles=h.compiles - before.compiles,
+                cache_hits=h.cache_hits - before.cache_hits,
+                cache_misses=h.cache_misses - before.cache_misses,
+                cache_read_s=(h.cache_read_time_s
+                              - before.cache_read_time_s)))
+
+    def cold_runs(self) -> List[Dict[str, Any]]:
+        """The records of the newest (at most 256) cold runs of
+        `Executor.run`, oldest first: every run during which a step fn
+        was built, a feed signature was new, or jax traced, lowered,
+        compiled or read its cache.  Keys: `program` (its `_uid`),
+        `ops`, `state_arrays`, `feed_arrays`, `fetches`, `placement`,
+        `new_signature`, `t_entry` (`time.perf_counter()`), the four
+        `<phase>_s`, and the deltas heard during the run: `trace_s`,
+        `lower_s`, `backend_compile_s` (on a cache hit the read),
+        `compiles`, `cache_hits`, `cache_misses`, `cache_read_s`.  A
+        cold run whose signature was not new is jax re-lowering a step
+        it has seen (arguments committed or laid out otherwise).  An
+        event on another thread during a run marks that run too."""
+        with self._lock:
+            return [dict(r) for r in self._cold_runs]
+
     def recent(self, name: str) -> List[float]:
         """The last (at most 4096) durations of phase `name`, seconds,
         oldest first."""
@@ -171,6 +282,7 @@ class RuntimeStats:
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             out = {f: getattr(self, f) for f in _FIELDS}
+            out["cold_runs"] = self._cold_run_count
             for name, total in self._phase_time_s.items():
                 out[name + "_time_s"] = total
                 out[name + "_count"] = self._phase_count[name]
@@ -183,25 +295,65 @@ class RuntimeStats:
 
 runtime_stats = RuntimeStats()
 
+
+def format_cold_run(r: Dict[str, Any]) -> str:
+    """One record of `cold_runs()` on one line, for a log."""
+    total = sum(r[p + "_s"] for p in STEP_PHASES)
+    phases = " + ".join(f"{p} {r[p + '_s']:.2f}" for p in STEP_PHASES)
+    return (
+        f"program {r['program']} ({r['ops']} ops, {r['state_arrays']} "
+        f"state / {r['feed_arrays']} feed / {r['fetches']} fetch"
+        f"{', placed' if r['placement'] else ''}"
+        f"{'' if r['new_signature'] else ', signature seen before'}): "
+        f"{total:.2f} s = {phases}; jax: trace {r['trace_s']:.2f}, "
+        f"lower {r['lower_s']:.2f}, compile or read "
+        f"{r['backend_compile_s']:.2f} ({r['compiles']}), cache "
+        f"{r['cache_hits']} hit / {r['cache_misses']} miss, read "
+        f"{r['cache_read_s']:.2f}")
+
+
 _installed = [False]
 
 
 def install():
-    """Register the jax.monitoring compile listener (idempotent).
-    Called on first Executor use; listeners cannot be removed
-    individually in jax, so this stays for the process lifetime —
-    the callback is a counter bump, nanoseconds per compile."""
+    """Register the jax.monitoring listeners (idempotent).  Called on
+    first Executor use; they stay for the process lifetime.  A
+    callback is one tuple replaced, a microsecond or two an event, and
+    jax emits events only where it traces, lowers, compiles or reads
+    its cache."""
     if _installed[0]:
         return
     import jax.monitoring
 
-    def _on_duration(event, duration, **_kw):
-        if event == _COMPILE_EVENT:
-            runtime_stats.record_compile(duration)
-        elif event.startswith(_TRACE_EVENT_PREFIXES):
-            runtime_stats.record_trace(duration)
+    # jax times a jitted function traced inside another, and one traced
+    # while a module is lowered, each on its own: only the outermost
+    # span of a thread is wall time (`joyai-8k`'s step: 12.2 s of
+    # events in an 11.8 s call).  A span's start is a scalar event.
+    open_spans = threading.local()
 
+    def _on_scalar(event, _value, **_kw):
+        if event in _SPAN_EVENTS:
+            open_spans.n = getattr(open_spans, "n", 0) + 1
+
+    def _on_duration(event, duration, **_kw):
+        field = _SPAN_EVENTS.get(event)
+        if field:
+            open_spans.n = max(getattr(open_spans, "n", 0) - 1, 0)
+            if not open_spans.n:
+                runtime_stats.hear(**{field: float(duration)})
+        elif event == _COMPILE_EVENT:
+            runtime_stats.hear(compiles=1, compile_time_s=float(duration))
+        elif event == _CACHE_READ_EVENT:
+            runtime_stats.hear(cache_read_time_s=float(duration))
+
+    def _on_event(event, **_kw):
+        field = _COUNT_EVENTS.get(event)
+        if field:
+            runtime_stats.hear(**{field: 1})
+
+    jax.monitoring.register_scalar_listener(_on_scalar)
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
     _installed[0] = True
 
 
