@@ -66,7 +66,8 @@ _FIELDS = Heard._fields[1:] + (
     "trace_time_s", "dispatches", "dispatch_time_s",
     "place_puts", "place_skips",
     "dropout_masks_kernel", "dropout_masks_xla",
-    "flash_mla_backward_fused", "flash_mla_backward_split")
+    "flash_mla_backward_fused", "flash_mla_backward_split",
+    "flash_gqa_backward_fused", "flash_gqa_backward_split")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
 SPAN_PREFIX = "paddle_tpu.step."
@@ -124,11 +125,14 @@ class RuntimeStats:
         # (a step that fell back says so; delta() around a build)
         self.dropout_masks_kernel = 0
         self.dropout_masks_xla = 0
-        # backward passes of `ops/pallas/flash_mla.py` traced, by what
-        # the operands' shape chose: the single kernel, or dk/dv and dq
-        # by a kernel each (delta() around a build; `joyai-8k` 6 / 0)
+        # backward passes of `ops/pallas/flash_mla.py` and of
+        # `flash_gqa.py` traced, by what the operands' shape chose: the
+        # single kernel, or dk/dv and dq by a kernel each (delta()
+        # around a build; `joyai-8k` 6 / 0, `lfm2-8k` 1 / 0)
         self.flash_mla_backward_fused = 0
         self.flash_mla_backward_split = 0
+        self.flash_gqa_backward_fused = 0
+        self.flash_gqa_backward_split = 0
         # per-phase totals and the most recent durations; the stages of
         # set-up beside them (outermost entries only), there from the
         # start so that a snapshot always carries them
@@ -179,12 +183,12 @@ class RuntimeStats:
             else:
                 self.dropout_masks_xla += 1
 
-    def record_flash_mla_backward(self, fused: bool):
+    def record_flash_backward(self, family: str, fused: bool):
+        """One traced backward pass of kernel family `family`
+        ("flash_mla", "flash_gqa")."""
+        field = f"{family}_backward_{'fused' if fused else 'split'}"
         with self._lock:
-            if fused:
-                self.flash_mla_backward_fused += 1
-            else:
-                self.flash_mla_backward_split += 1
+            setattr(self, field, getattr(self, field) + 1)
 
     def phase(self, name: str) -> _Phase:
         """Context manager around one host phase of a step: a

@@ -607,7 +607,7 @@ def _flash_bwd(qn, qr, kn, kr, v, o, lse8, do, scale, geo):
     from ...observe.monitoring import runtime_stats
 
     fused = fused_backward_fits(geo.t)
-    runtime_stats.record_flash_mla_backward(fused)
+    runtime_stats.record_flash_backward("flash_mla", fused)
     bwd = _flash_bwd_fused if fused else _flash_bwd_split
     return bwd(qn, qr, kn, kr, v, o, lse8, do, scale, geo)
 

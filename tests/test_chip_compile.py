@@ -125,10 +125,15 @@ def test_flash_attention_head_major_entry(one_chip):
 
 
 # (N, T, query heads, key/value heads) at d_head 64, head-major: the
-# lfm2-8k cell's attention layer, and the Transformer's heads
-@pytest.mark.parametrize("geometry", [(1, 8192, 32, 8), (64, 256, 8, 8)],
-                         ids=["lfm2_8k_gqa_32_over_8", "bs64_len256_mha"])
-@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+# lfm2-8k cell's attention layer, the Transformer's heads, and a
+# sequence past the single backward kernel's budget
+@pytest.mark.parametrize("geometry, dtype", [
+    ((1, 8192, 32, 8), BF16), ((1, 8192, 32, 8), F32),
+    ((64, 256, 8, 8), BF16), ((64, 256, 8, 8), F32),
+    ((1, 32768, 8, 2), BF16)],
+    ids=["lfm2_8k_gqa_32_over_8-bf16", "lfm2_8k_gqa_32_over_8-f32",
+         "bs64_len256_mha-bf16", "bs64_len256_mha-f32",
+         "beyond_the_budget_32k-bf16"])
 def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
         one_chip, geometry, dtype):
     """At d_head 64 a head is half a lane tile of the (N, T, H*D)
@@ -136,14 +141,23 @@ def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
     test replaced): `ops/pallas/flash_gqa.py` blocks heads in pairs,
     reads grouped key/value heads where they lie (K and V stay
     (N, T, Hkv*64): nothing in the step is Hq heads wide but q, o and
-    their gradients), and its three kernels compile forward and
-    backward, in the cell's bfloat16 and in the parity script's
-    float32 at "highest"."""
+    their gradients), and its kernels compile forward and backward, in
+    the cell's bfloat16 and in the parity script's float32 at
+    "highest".  The backward pass is ONE kernel, `flash_gqa_dkv` grown
+    by dq's dot, whose 1.5 KiB a position of float32 accumulators (dq
+    of a query tile's whole sequence, dk and dv of its key/value
+    tile: 12 MiB at 8192) Mosaic must take in VMEM in both dtypes; at
+    32768 positions they pass the budget and the two kernels that hold
+    blocks only stay.  The counter says which path the trace took."""
     from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas import flash_gqa
     from paddle_tpu.ops.pallas.flash_attention import \
         pallas_flash_attention
 
     n, t, heads, kv = geometry
+    fused = flash_gqa.fused_backward_fits(t)
+    assert fused == (t <= 8192)
 
     def loss(q, k, v):
         with jax.named_scope("flash_attention:9"):
@@ -155,16 +169,23 @@ def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
     args = [jax.ShapeDtypeStruct((n, t, h * 64), dtype, sharding=one_chip)
             for h in (heads, kv, kv)]
     prec = "default" if dtype == BF16 else "highest"
+    before = runtime_stats.snapshot()
     with force_mosaic_lowering(), jax.default_matmul_precision(prec):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
             .lower(*args).compile()
+    took = runtime_stats.delta(before)
+    assert (took["flash_gqa_backward_fused"],
+            took["flash_gqa_backward_split"]) == (
+                (1, 0) if fused else (0, 1))
     rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
-    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
-        "flash_gqa_dkv", "flash_gqa_dq", "flash_gqa_fwd"]
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == (
+        ["flash_gqa_dkv", "flash_gqa_fwd"] if fused else
+        ["flash_gqa_dkv", "flash_gqa_dq", "flash_gqa_fwd"])
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "flash_attention"}
     totals = cost.total_costs(cost.compiled_hlo_proto(compiled))
-    assert totals["custom_calls"] == totals["pallas_matched"] == 3
+    assert totals["custom_calls"] == totals["pallas_matched"] == (
+        2 if fused else 3)
     # dense-equivalent: 4 matmuls' worth forward, 8 backward, a score
     scores = n * heads * t * t
     assert totals["pallas_flops"] >= 12 * 64 * scores
