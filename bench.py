@@ -397,8 +397,10 @@ def _comm_fields(program, feed, loss, scope):
         compiled = wrapper.compiled_step(feed, [loss.name], scope)
         rows = obs_cost.instruction_costs(
             obs_cost.compiled_hlo_proto(compiled))
-        comm = sum(r["bytes"] for r in rows if r["bucket"] == "comm")
-        total = sum(r["bytes"] for r in rows if r["bucket"] != "noop")
+        comm = sum(obs_cost.per_step(r, "bytes") for r in rows
+                   if r["bucket"] == "comm")
+        total = sum(obs_cost.per_step(r, "bytes") for r in rows
+                    if r["bucket"] != "noop")
         return {"comm_bytes": comm,
                 "comm_share": round(comm / total, 4) if total else 0.0,
                 "comm_instructions": sum(

@@ -109,10 +109,13 @@ class OpContext:
             )
         return jax.random.fold_in(self._rng_key, self.op_index)
 
-    def run_block(self, block_idx: int, env):
+    def run_block(self, block_idx: int, env, keep_names=None):
         """Trace a sub-block's ops over `env` (mutated in place).  Used by
         control-flow macro ops; the sub-block gets a distinct RNG stream so
-        per-op keys don't collide with the parent block's."""
+        per-op keys don't collide with the parent block's.  `keep_names`:
+        the names the caller reads from `env` afterwards; a recompute
+        segment of the block then hands out only those and what later
+        ops of the block read (None: everything it writes)."""
         import jax
 
         from .executor import run_ops
@@ -128,5 +131,5 @@ class OpContext:
         sub_key = (None if self._rng_key is None
                    else jax.random.fold_in(self._rng_key, 7919 + block_idx))
         run_ops(block.ops, env, sub_key, amp_lists=self.amp_lists,
-                program=self.program)
+                program=self.program, keep_names=keep_names)
         return env

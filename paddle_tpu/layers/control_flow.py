@@ -497,18 +497,36 @@ class StaticRNN:
     by jax AD.  `unroll` unrolls the scan body by that factor (the
     scan-bound perf lever, docs/RNN.md); results are bit-identical to
     unroll=1.
+
+    `trip_count=R` is the counted form: no step input, the body runs R
+    times over its memories alone (a stack of layers applied R times
+    over shared weights), and a step output is stacked (R, ...).  A
+    parameter created in the body exists once; its gradient is the sum
+    over the trips (the scan's backward pass accumulates it).
+
+        loop = layers.StaticRNN(trip_count=4)
+        with loop.step():
+            h = loop.memory(init=x)
+            y = layers.fc(h, size=D)           # ONE weight, read 4 times
+            loop.update_memory(h, y)
+            loop.step_output(layers.reduce_mean(y))
+        per_trip = loop()                      # (4, ...)
     """
 
-    def __init__(self, name: Optional[str] = None, unroll: int = 1):
+    def __init__(self, name: Optional[str] = None, unroll: int = 1,
+                 trip_count: Optional[int] = None):
         self.helper = LayerHelper("static_rnn", name=name)
         self._unroll = int(unroll)
+        if trip_count is not None and int(trip_count) < 1:
+            raise ValueError(f"trip_count {trip_count} is not positive")
+        self._trip_count = None if trip_count is None else int(trip_count)
         self._program = default_main_program()
         self._sub = None
         self._step_inputs = []   # [outer_name, inner_name]
         self._memories = []      # [pre_name, post_name, init_name]
         self._step_outputs = []  # [inner_name, outer_name]
         self._outputs: List[Variable] = []
-        self._seq_len_static: Optional[int] = None
+        self._seq_len_static: Optional[int] = self._trip_count
 
     @contextlib.contextmanager
     def step(self):
@@ -525,23 +543,29 @@ class StaticRNN:
             raise RuntimeError("StaticRNN memory never updated via "
                                "update_memory")
         reads, _writes = _analyze_block_io(self._sub)
+        attrs = {"sub_block": self._sub.idx,
+                 "step_inputs": self._step_inputs,
+                 "memories": self._memories,
+                 "step_outputs": self._step_outputs,
+                 "final_states": [],
+                 "unroll": self._unroll}
+        if self._trip_count is not None:
+            attrs["trip_count"] = self._trip_count
         parent_block.append_op(
             type="static_rnn",
             inputs={"X": sorted(set(o for o, _i in self._step_inputs)
                     | set(init for _p, _q, init in self._memories)
                     | reads)},
             outputs={"Out": [o for _i, o in self._step_outputs]},
-            attrs={"sub_block": self._sub.idx,
-                   "step_inputs": self._step_inputs,
-                   "memories": self._memories,
-                   "step_outputs": self._step_outputs,
-                   "final_states": [],
-                   "unroll": self._unroll},
+            attrs=attrs,
         )
 
     def step_input(self, x: Variable) -> Variable:
         if self._sub is None:
             raise RuntimeError("step_input outside rnn.step()")
+        if self._trip_count is not None:
+            raise RuntimeError("a counted StaticRNN (trip_count=) takes "
+                               "no step input")
         if self._seq_len_static is None:
             self._seq_len_static = x.shape[0]
         inner = self._sub.create_var(
@@ -583,7 +607,8 @@ class StaticRNN:
         if self._sub is None:
             raise RuntimeError("step_output outside rnn.step()")
         if self._seq_len_static is None:
-            raise RuntimeError("step_output before any step_input")
+            raise RuntimeError("step_output before any step_input "
+                               "(or give StaticRNN a trip_count)")
         outer = self._program.current_block().parent.create_var(
             name=unique_name.generate(f"{o.name}@stacked"),
             shape=(self._seq_len_static,) + tuple(o.shape), dtype=o.dtype)

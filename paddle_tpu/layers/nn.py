@@ -788,21 +788,28 @@ def cross_entropy(input, label, soft_label=False, ignore_index=-100):
 
 def softmax_with_cross_entropy(logits, label, soft_label=False,
                                ignore_index=-100, numeric_stable_mode=True,
-                               return_softmax=False, label_smooth_eps=0.0):
+                               return_softmax=False, label_smooth_eps=0.0,
+                               one_hot_pick=False):
     """label_smooth_eps > 0 folds label smoothing into the hard-label CE,
     mathematically identical to one_hot → label_smooth → soft-label CE.
     Convenience/API form; on TPU the one_hot composition benchmarks
     slightly faster (XLA fuses it onto the MXU), so prefer that on hot
-    paths — see models/transformer.py."""
+    paths — see models/transformer.py.  `one_hot_pick`: the label's
+    log-probability is read by a one-hot product and not a gather (the
+    same number): its backward pass is elementwise and fuses with the
+    soft-max's, where a gather's is a (tokens x vocab) scatter kept in
+    memory — for a large vocabulary inside a loop."""
     helper = LayerHelper("softmax_with_cross_entropy")
     loss = helper.create_variable_for_type_inference(logits.dtype)
     sm = helper.create_variable_for_type_inference(logits.dtype)
+    attrs = {"soft_label": soft_label, "ignore_index": ignore_index,
+             "label_smooth_eps": float(label_smooth_eps)}
+    if one_hot_pick:        # absent = the op as every program has it
+        attrs["one_hot_pick"] = True
     helper.append_op(type="softmax_with_cross_entropy",
                      inputs={"Logits": [logits], "Label": [label]},
                      outputs={"Loss": [loss], "Softmax": [sm]},
-                     attrs={"soft_label": soft_label,
-                            "ignore_index": ignore_index,
-                            "label_smooth_eps": float(label_smooth_eps)})
+                     attrs=attrs)
     if return_softmax:
         return loss, sm
     return loss

@@ -101,6 +101,38 @@ with the main model's own table and head, fed `next_labels` beside
 `labels`; its cross-entropy joins the objective x `mtp_loss_weight`.
 Its ops lower under the `mtp` name scope.
 
+`sandwich_norm`: a second norm AFTER each sub-layer, before the
+residual add, each with a scale of its own:
+
+    x = x + rms_norm(op_i(rms_norm(x)));  x = x + rms_norm(ffn_i(rms_norm(x)))
+
+`total_ut_steps` R > 1 (a looped language model, Zhu et al. 2025,
+arXiv:2510.25741): the SAME stack of layers runs R times over shared
+weights, and every trip ends in the final norm, the vocabulary head, a
+token cross-entropy and a 1-wide exit gate (`exit_gate="sigmoid"`):
+
+    x_0 = Emb(tokens);   trip r = 1..R:   x_r = stack(x_{r-1})
+      s_r = rms_norm(x_r);  z_r = s_r W_head;  ce_r = token_ce(z_r)
+      lam_r = sigmoid(s_r w_gate + b_gate)
+    p_r = lam_r prod_{j<r}(1 - lam_j)  (r < R);   p_R = prod_{j<R}(1 - lam_j)
+    loss = mean_tokens[ sum_r p_r ce_r - exit_entropy_weight * H(p) ]
+
+The stack is built ONCE, in one sub-block that a counted
+`layers.StaticRNN(trip_count=R)` runs R times (`lax.scan`): the hidden
+state is its memory (beside the exit mass that has not left yet),
+`ce_r`, the mass leaving at trip r and the logits are its step outputs,
+stacked (R, N, T[, V]); the Program is no longer for a larger R.
+The weights are created once in the global block and read at every
+trip, so their gradients are the sums over the trips.  Head, gate and
+cross-entropy run INSIDE the trip, so one head's logits are alive at a
+time (fetch `logits` and all R are kept; the training step keeps none).
+`recompute="layer"` wraps each layer pass and each trip's head in a
+`recompute_scope`: the backward pass keeps their inputs alone.  The
+loop lowers under the name scope `ut_loop`, a trip's head under
+`ut_loop/exit_head`.  With `total_ut_steps` 1 and no gate the stack is
+appended to the main block as ever.  (A serving-time key such as an
+early-exit threshold is not the training path's to read.)
+
 `expert_parallel_size` chips share each layer's experts: `num_experts`
 is then what THIS chip (`expert_parallel_rank`) holds, the router is
 `num_experts * expert_parallel_size` wide, and the expert layer gives
@@ -115,14 +147,19 @@ all-reduces the parts asks for it.
 
 The training objective is the paper's: token cross-entropy + `aux_loss_weight`
 x the load-balancing loss + `z_loss_weight` x the router z-loss (both
-averaged over layers), AdamW, global-norm gradient clipping, linear
+averaged over layers), or the exit-weighted loss above, AdamW,
+global-norm gradient clipping, linear
 warm-up into a cosine decay to `lr_floor` of the peak.
 """
 
 from __future__ import annotations
 
+import contextlib
+
+import numpy as np
+
 from .. import layers, optimizer
-from ..core.program import name_scope
+from ..core.program import name_scope, recompute_scope
 from ..observe.monitoring import runtime_stats
 from ..clip import GradientClipByGlobalNorm, set_gradient_clip
 from ..initializer import Normal
@@ -143,7 +180,9 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             kv_lora_rank=None, q_lora_rank=None, qk_nope_head_dim=None,
             qk_rope_head_dim=None, v_head_dim=None, rope_interleave=False,
             rope_scaling=None, n_shared_experts=0,
-            num_nextn_predict_layers=0):
+            num_nextn_predict_layers=0, total_ut_steps=1,
+            sandwich_norm=False, exit_gate=None, exit_entropy_weight=0.0,
+            recompute=None):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
     labels' own successors, with a prediction module).  Returns a dict:
@@ -152,8 +191,12 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     last two averaged over the layers that route (None where none
     does); `counts` and `experts`, per routed layer the rows per expert
     held and each token's experts (N*T, k), the module's layer last;
-    `mtp_logits` and `mtp_ce`, the module's (None without one)."""
-    if qk_norm not in ("projection", "head"):
+    `mtp_logits` and `mtp_ce`, the module's (None without one).  A
+    looped model (`total_ut_steps` > 1 or an `exit_gate`) gives `logits`
+    (R, N, T, vocab), `ut_ce` and `ut_exit_p`, each (R,): the mean
+    cross-entropy and the mean exit mass of each trip, `ut_exit_entropy`
+    (1,), and `ce`, the exit-weighted objective."""
+    if qk_norm not in (None, "projection", "head"):
         raise NotImplementedError(f"qk_norm {qk_norm!r} is not built")
     if router not in ("softmax", "sigmoid"):
         raise NotImplementedError(f"router {router!r} is not built")
@@ -171,6 +214,21 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise NotImplementedError(
             f"{num_nextn_predict_layers} chained prediction modules are "
             f"not built")
+    if exit_gate not in (None, "sigmoid"):
+        raise NotImplementedError(f"exit_gate {exit_gate!r} is not built")
+    if recompute not in (None, "layer"):
+        raise NotImplementedError(f"recompute {recompute!r} is not built")
+    if total_ut_steps < 1:
+        raise ValueError(f"total_ut_steps {total_ut_steps} is not positive")
+    loops = total_ut_steps > 1 or exit_gate is not None
+    if loops and exit_gate is None:
+        raise ValueError("a stack run several times needs an exit_gate: "
+                         "its objective weighs the trips by it")
+    if loops and (num_dense_layers < num_hidden_layers
+                   or num_nextn_predict_layers or tie_word_embeddings):
+        raise NotImplementedError(
+            "routed experts, a prediction module or a tied head inside "
+            "the loop are not built")
     latent = (kv_lora_rank, q_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
               v_head_dim)
     if kv_lora_rank is None:
@@ -214,6 +272,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         return layers.rms_norm(x, epsilon=eps)
 
     def norm_qk(x):
+        if qk_norm is None:
+            return x
         if qk_norm == "head":
             return layers.rms_norm(x, epsilon=eps, group_size=head_dim)
         return norm(x)
@@ -293,9 +353,20 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             op = conv
         else:
             raise NotImplementedError(f"layer type {kind!r} is not built")
-        x = layers.elementwise_add(x, op(norm(x)))
+        post = norm if sandwich_norm else (lambda y: y)
+        x = layers.elementwise_add(x, post(op(norm(x))))
         ffn = dense_ffn if dense else routed_ffn
-        return layers.elementwise_add(x, ffn(norm(x)))
+        return layers.elementwise_add(x, post(ffn(norm(x))))
+
+    def segment():
+        return (recompute_scope() if recompute == "layer"
+                else contextlib.nullcontext())
+
+    def stack(x):
+        for i, kind in enumerate(layer_types):
+            with segment():
+                x = block(x, kind, dense=i < num_dense_layers)
+        return x
 
     def head(x):
         if tie_word_embeddings:
@@ -315,6 +386,57 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         return layers.mean(layers.softmax_with_cross_entropy(
             logits, layers.unsqueeze(targets, axes=[2])))
 
+    def looped(x, trips=total_ut_steps):
+        """`stack` run `trips` times over the memory `x` as ONE sub-block,
+        each trip ending in the final norm, the head, the token
+        cross-entropy and the exit gate; then the exit distribution and the
+        exit-weighted objective over the stacked (trips, N, T) outputs.
+        The op count does not depend on `trips`."""
+        targets = layers.unsqueeze(labels, axes=[2])
+        with name_scope("ut_loop"):
+            loop = layers.StaticRNN(trip_count=trips)
+            with loop.step():
+                h = loop.memory(init=x)
+                # the exit mass that has not left before this trip
+                left = loop.memory(shape=[-1, x.shape[1], 1], batch_ref=x,
+                                   init_value=1.0)
+                y = stack(h)
+                loop.update_memory(h, y)
+                with name_scope("exit_head"), segment():
+                    s = norm(y)
+                    logits = head(s)
+                    ce = layers.softmax_with_cross_entropy(logits, targets,
+                                                           one_hot_pick=True)
+                    gate = layers.fc(s, size=1, num_flatten_dims=2,
+                                     param_attr=weight(), name="exit_gate")
+                    lam = layers.sigmoid(layers.cast(gate, "float32"))
+                    leaving = layers.elementwise_mul(left, lam)
+                loop.update_memory(left, layers.elementwise_sub(left, leaving))
+                loop.output(ce, leaving, left, logits)
+            ce, leaving, left, logits = loop()
+        with name_scope("exit_loss"):
+            ce = layers.squeeze(ce, axes=[3])               # (R, N, T)
+            # p_r = lam_r prod_{j<r}(1 - lam_j), plain products (the chip's
+            # float32 log1p is good to 1e-4 only: no log space); the last
+            # trip takes all the mass that is left
+            last = np.array([0.0] * (trips - 1) + [1.0],
+                            np.float32).reshape(trips, 1, 1, 1)
+            p = layers.squeeze(layers.elementwise_add(
+                layers.elementwise_mul(leaving, layers.assign(1.0 - last)),
+                layers.elementwise_mul(left, layers.assign(last))), axes=[3])
+            task = layers.reduce_sum(layers.elementwise_mul(p, ce), dim=0)
+            # p log p -> 0 as p -> 0
+            neg_entropy = layers.reduce_sum(layers.elementwise_mul(
+                p, layers.log(layers.clip(p, 1e-30, 1.0))), dim=0)
+            objective = layers.mean(layers.elementwise_add(task, layers.scale(
+                neg_entropy, scale=float(exit_entropy_weight))))
+            return {"logits": logits, "ce": objective, "exit_p": p,
+                    "ut_ce": layers.reduce_mean(ce, dim=[1, 2]),
+                    "ut_exit_p": layers.reduce_mean(p, dim=[1, 2]),
+                    "ut_exit_entropy": layers.scale(layers.mean(neg_entropy),
+                                                    scale=-1.0),
+                    "total_ut_steps": trips}
+
     tokens = layers.data(name="tokens", shape=[max_length], dtype="int64")
     labels = layers.data(name="labels", shape=[max_length], dtype="int64")
     embed_name = "tok_embedding.w"
@@ -322,12 +444,13 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         tokens, size=[vocab_size, hidden_size],
         param_attr=ParamAttr(name=embed_name,
                              initializer=Normal(0.0, initializer_range)))
-    for i, kind in enumerate(layer_types):
-        x = block(x, kind, dense=i < num_dense_layers)
-    x = norm(x)
+    feeds = ["tokens", "labels"]
+    if loops:
+        return dict(looped(x), aux=None, z=None, counts=[], experts=[],
+                    feeds=feeds, mtp_logits=None, mtp_ce=None)
+    x = norm(stack(x))
     logits = head(x)
     ce = token_ce(logits, labels)
-    feeds = ["tokens", "labels"]
     mtp_logits = mtp_ce = None
     if num_nextn_predict_layers:
         next_labels = layers.data(name="next_labels", shape=[max_length],
@@ -365,7 +488,9 @@ def build_model(max_length, learning_rate=4e-4, beta1=0.9, beta2=0.95,
     under bf16 AMP, clipping and the schedule.  The defaults are the
     OLMoE paper's settings (`mtp_loss_weight` DeepSeek-V3's first);
     `architecture` holds the configuration's own keys and, where its
-    equations need them, `qk_norm` / `router` / `norm_topk_eps`."""
+    equations need them, `qk_norm` / `router` / `norm_topk_eps` /
+    `sandwich_norm` / `exit_gate`; a recipe's `exit_entropy_weight` and
+    `recompute` travel with them."""
     model = decoder(max_length=max_length, **architecture)
     ce, aux, z = model["ce"], model["aux"], model["z"]
     # an auxiliary loss with no weight (or no routed layer, or no
@@ -401,4 +526,17 @@ def build_model(max_length, learning_rate=4e-4, beta1=0.9, beta2=0.95,
 
         track_scalars(program, ce_loss=ce,
                       **{name: var for name, (var, _) in terms.items()})
+        trips = model.get("total_ut_steps")
+        if trips:
+            # whether a further trip buys anything, and where the exit
+            # mass goes
+            def trip(var, r):
+                return layers.slice(var, axes=[0], starts=[r], ends=[r + 1])
+
+            track_scalars(
+                program, ut_exit_entropy=model["ut_exit_entropy"],
+                **{f"ut_ce_{r + 1}": trip(model["ut_ce"], r)
+                   for r in range(trips)},
+                **{f"ut_exit_p_{r + 1}": trip(model["ut_exit_p"], r)
+                   for r in range(trips)})
     return dict(model, loss=loss)

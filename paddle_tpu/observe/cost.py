@@ -99,7 +99,9 @@ def device_peaks(kind: Optional[str] = None):
 #                      convolution_dimension_numbers=16
 #                      custom_call_target=28 dot_dimension_numbers=30
 #                      id=35 operand_ids=36 called_computation_ids=38
-#                      feature_group_count=50
+#                      feature_group_count=50 literal=8 tuple_index=13
+#                      comparison_direction=63
+# LiteralProto:        s32s=4 s64s=5 u32s=6 u64s=7
 # ShapeProto:          element_type=2 dimensions=3 tuple_shapes=4
 # OpMetadata:          op_type=1 op_name=2
 # DotDimensionNumbers: lhs_contracting=1 rhs_contracting=2 lhs_batch=3
@@ -179,7 +181,8 @@ class Instr:
     __slots__ = ("name", "opcode", "shape", "op_name", "id",
                  "operand_ids", "called_ids", "dot_dnums_buf",
                  "window_buf", "conv_dnums_buf", "feature_group_count",
-                 "custom_call_target", "backend_config")
+                 "custom_call_target", "backend_config", "literal_buf",
+                 "tuple_index", "comparison_direction")
 
     def __init__(self, buf: bytes):
         self.name = ""
@@ -195,9 +198,18 @@ class Instr:
         self.feature_group_count = 1
         self.custom_call_target = ""
         self.backend_config = b""
+        self.literal_buf = b""
+        self.tuple_index = 0
+        self.comparison_direction = ""
         for f, _wt, v in _fields(buf):
             if f == 1:
                 self.name = _utf8(v)
+            elif f == 8:
+                self.literal_buf = v
+            elif f == 13:
+                self.tuple_index = int(v)
+            elif f == 63:
+                self.comparison_direction = _utf8(v)
             elif f == 2:
                 self.opcode = _utf8(v)
             elif f == 3:
@@ -282,22 +294,110 @@ class HloModule:
 def while_trip_count(module: HloModule, comp: Computation,
                      instr: Instr) -> Optional[int]:
     """Known trip count of a counted `while` (the lax.scan / fori_loop
-    pattern), or None when XLA found none.
+    pattern), or None when none can be recovered.
 
-    XLA's own loop analysis annotates every counted while with
+    XLA:CPU's loop analysis annotates every counted while with
     `backend_config={"known_trip_count":{"n":"T"}, ...}` after
-    optimization; that annotation is read here rather than re-derived
-    from the condition's compare (which this XLA wraps in a fusion).
-    A genuine data-dependent `while` (a decode loop) carries no such
-    key and returns None — callers fall back to ×1 with the loud
-    `[loop?]` bucket, never a silent guess.
+    optimization; that annotation is read where it is there.  The TPU
+    compiler leaves none, and keeps the loop in its plain form, which
+    is read instead (`_induction_trip_count`): the condition compares
+    one element of the carry with a constant, the body adds a constant
+    to that element, and it starts from a constant.  A genuine
+    data-dependent `while` (a decode loop) matches neither and returns
+    None — callers fall back to x1 with the loud `[loop?]` bucket,
+    never a silent guess.
     """
-    if instr.opcode != "while" or not instr.backend_config:
+    if instr.opcode != "while":
         return None
-    import json
+    if instr.backend_config:
+        import json
 
-    known = json.loads(instr.backend_config).get("known_trip_count")
-    return int(known["n"]) if known else None
+        try:
+            known = json.loads(instr.backend_config).get("known_trip_count")
+        except ValueError:
+            known = None
+        if known:
+            return int(known["n"])
+    return _induction_trip_count(module, comp, instr)
+
+
+def _scalar_int(instr: Optional[Instr]) -> Optional[int]:
+    """The value of an integer scalar `constant`, else None."""
+    if instr is None or instr.opcode != "constant" or instr.shape.dims:
+        return None
+    for f, _wt, v in _fields(instr.literal_buf):
+        if f in (4, 5, 6, 7):
+            values = _varints(v)
+            if len(values) == 1:
+                value = values[0]
+                # int32 / int64 are plain varints: a negative one
+                # arrives as its 64-bit two's complement
+                return value - (1 << 64) if value >> 63 else value
+    return 0 if instr.literal_buf else None     # an all-default literal
+
+
+def _behind(comp: Computation, instr: Optional[Instr]) -> Optional[Instr]:
+    """`instr` behind the copies, bitcasts and converts that wrap it."""
+    while (instr is not None and len(instr.operand_ids) == 1
+           and instr.opcode in ("copy", "bitcast", "convert")):
+        instr = comp.by_id.get(instr.operand_ids[0])
+    return instr
+
+
+def _carried_index(comp: Computation, instr: Optional[Instr]
+                   ) -> Optional[int]:
+    """i where `instr` is `get-tuple-element(parameter), index=i`."""
+    instr = _behind(comp, instr)
+    if instr is None or instr.opcode != "get-tuple-element" \
+            or len(instr.operand_ids) != 1:
+        return None
+    source = comp.by_id.get(instr.operand_ids[0])
+    if source is None or source.opcode != "parameter":
+        return None
+    return instr.tuple_index
+
+
+def _induction_trip_count(module: HloModule, comp: Computation,
+                          instr: Instr) -> Optional[int]:
+    """Trip count of `while (carry[i] < N) carry[i] += step`, carry[i]
+    starting from a constant, every piece read off the instructions
+    themselves; None for anything else."""
+    if len(instr.called_ids) != 2 or len(instr.operand_ids) != 1:
+        return None
+    body, cond = (module.computations.get(c) for c in instr.called_ids)
+    if body is None or cond is None or body.root is None \
+            or cond.root is None:
+        return None
+    if cond.root.opcode != "compare":
+        body, cond = cond, body
+    test = cond.root
+    if test.opcode != "compare" or test.comparison_direction != "LT" \
+            or len(test.operand_ids) != 2:
+        return None
+    index = _carried_index(cond, cond.by_id.get(test.operand_ids[0]))
+    limit = _scalar_int(_behind(cond, cond.by_id.get(test.operand_ids[1])))
+    if index is None or limit is None:
+        return None
+    init = _behind(comp, comp.by_id.get(instr.operand_ids[0]))
+    if init is None or init.opcode != "tuple" \
+            or index >= len(init.operand_ids):
+        return None
+    start = _scalar_int(_behind(comp,
+                                comp.by_id.get(init.operand_ids[index])))
+    root = body.root
+    if start is None or root.opcode != "tuple" \
+            or index >= len(root.operand_ids):
+        return None
+    nxt = _behind(body, body.by_id.get(root.operand_ids[index]))
+    if nxt is None or nxt.opcode != "add" or len(nxt.operand_ids) != 2:
+        return None
+    a, b = (body.by_id.get(i) for i in nxt.operand_ids)
+    if _carried_index(body, a) != index:
+        a, b = b, a
+    step = _scalar_int(_behind(body, b))
+    if _carried_index(body, a) != index or not step or step < 0:
+        return None
+    return max(0, -(-(limit - start) // step))
 
 
 # --------------------------------------------------------------------------
@@ -639,9 +739,19 @@ def instruction_costs(proto, every_branch: bool = False
     `ragged_dot` for the compiler's own grouped matmul; else None),
     branch_of (the `conditional` whose branch holds the instruction,
     None in the entry computation),
-    trip_count (while rows: the recovered loop trip count, already
-    multiplied into flops; None = unrecoverable, body counted once and
-    bucketed "[loop?]").
+    trip_count (while rows: the recovered loop trip count; None =
+    unrecoverable, body counted once IN the row and bucketed
+    "[loop?]"),
+    loop_of / trips (every row: the innermost counted `while` whose
+    body or condition holds the instruction, None outside one, and how
+    often a step runs it: the product of the enclosing trip counts, 1
+    outside).
+    A counted `while` is a row WITHOUT cost (bucket "loop") followed by
+    the rows of its body and condition, each PER CALL: a step's cost is
+    `flops * trips`, which `total_costs` and `op_cost_table` sum, and a
+    trace's events join the body's instructions under their own names
+    (`calls` there counts the trips).  The row keeps `body_flops`, its
+    body's FLOPs over all trips, for reading alone.
     `flops` already includes the injected registry flops; `xla_flops`
     carries the pre-injection analytic count.
     """
@@ -658,18 +768,24 @@ def instruction_costs(proto, every_branch: bool = False
 
 
 def _computation_costs(module: HloModule, comp: Computation,
-                       every_branch: bool, branch_of: Optional[str]
+                       every_branch: bool, branch_of: Optional[str],
+                       loop_of: Optional[str] = None, trips: int = 1
                        ) -> List[Dict[str, Any]]:
     rows: List[Dict[str, Any]] = []
     for instr in comp.instructions:
         operands = [comp.by_id[i] for i in instr.operand_ids
                     if i in comp.by_id]
-        # a conditional's cost is its branches': their rows follow it
+        # a conditional's cost is its branches', a counted loop's its
+        # body's: their rows follow it
         branching = instr.opcode == "conditional"
+        trip = while_trip_count(module, comp, instr)
         flops, transc = ((0.0, 0.0) if branching
                          else _instr_flops(module, comp, instr))
+        body_flops = flops if trip is not None else None
+        if trip is not None:
+            flops = transc = 0.0
         bucket = _bucket(module, comp, instr)
-        if branching or instr.opcode in _NO_BYTES:
+        if branching or trip is not None or instr.opcode in _NO_BYTES:
             nbytes = 0
         else:
             # materialized-buffers model: unique operands read once,
@@ -695,9 +811,12 @@ def _computation_costs(module: HloModule, comp: Computation,
             "pallas_kernel": None,
             "kernel": None,
             "branch_of": branch_of,
+            "loop_of": loop_of,
+            "trips": trips,
         }
         if instr.opcode == "while":
-            row["trip_count"] = while_trip_count(module, comp, instr)
+            row["trip_count"] = trip
+            row["body_flops"] = body_flops
         if instr.opcode == "custom-call":
             row["custom_call_target"] = instr.custom_call_target
             kernel = _pallas_kernel_of(instr.op_name)
@@ -721,15 +840,28 @@ def _computation_costs(module: HloModule, comp: Computation,
         rows.append(row)
         if branching:
             branches = [
-                _computation_costs(module, sub, every_branch, instr.name)
+                _computation_costs(module, sub, every_branch, instr.name,
+                                   loop_of, trips)
                 for sub in map(module.computations.get, instr.called_ids)
                 if sub is not None]
             if not every_branch and branches:
                 branches = [max(branches, key=lambda b: sum(
-                    r["flops"] for r in b))]
+                    r["flops"] * r["trips"] for r in b))]
             for branch in branches:
                 rows += branch
+        elif trip is not None:
+            for sub in map(module.computations.get, instr.called_ids):
+                if sub is not None:
+                    rows += _computation_costs(
+                        module, sub, every_branch, branch_of, instr.name,
+                        trips * trip)
     return rows
+
+
+def per_step(row: Dict[str, Any], key: str) -> float:
+    """A cost row's `key` (flops, transcendentals, bytes) over one
+    step: per call x the trips of the loops that hold it."""
+    return row[key] * row.get("trips", 1)
 
 
 def total_costs(proto: bytes) -> Dict[str, Any]:
@@ -750,10 +882,11 @@ def total_costs(proto: bytes) -> Dict[str, Any]:
     matched = [r for r in custom if r["pallas_kernel"]
                or (r["kernel"] or "").startswith(RAGGED_DOT_KERNEL)]
     return {
-        "flops": sum(r["flops"] for r in rows),
-        "transcendentals": sum(r["transcendentals"] for r in rows),
-        "bytes": sum(r["bytes"] for r in rows),
-        "pallas_flops": sum(r["flops"] for r in matched),
+        "flops": sum(per_step(r, "flops") for r in rows),
+        "transcendentals": sum(per_step(r, "transcendentals")
+                               for r in rows),
+        "bytes": sum(per_step(r, "bytes") for r in rows),
+        "pallas_flops": sum(per_step(r, "flops") for r in matched),
         "custom_calls": len(custom),
         "pallas_matched": len(matched),
         "bucket_bytes": _sum_by(rows, "bytes"),
@@ -764,7 +897,7 @@ def total_costs(proto: bytes) -> Dict[str, Any]:
 def _sum_by(rows: Iterable[Dict[str, Any]], key: str) -> Dict[str, float]:
     out: Dict[str, float] = {}
     for r in rows:
-        out[r["bucket"]] = out.get(r["bucket"], 0.0) + r[key]
+        out[r["bucket"]] = out.get(r["bucket"], 0.0) + per_step(r, key)
     return out
 
 
@@ -876,9 +1009,9 @@ def op_cost_table(program=None, feed=None, fetch_list=None, scope=None,
             "time_ms": None,
         })
         g["instructions"] += 1
-        g["flops"] += r["flops"]
-        g["transcendentals"] += r["transcendentals"]
-        g["bytes"] += r["bytes"]
+        g["flops"] += per_step(r, "flops")
+        g["transcendentals"] += per_step(r, "transcendentals")
+        g["bytes"] += per_step(r, "bytes")
         t = times.get(r["name"])
         if t is not None:
             g["time_ms"] = (g["time_ms"] or 0.0) + t

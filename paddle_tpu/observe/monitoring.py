@@ -67,7 +67,8 @@ _FIELDS = Heard._fields[1:] + (
     "place_puts", "place_skips",
     "dropout_masks_kernel", "dropout_masks_xla",
     "flash_mla_backward_fused", "flash_mla_backward_split",
-    "flash_gqa_backward_fused", "flash_gqa_backward_split")
+    "flash_gqa_backward_fused", "flash_gqa_backward_split",
+    "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
 SPAN_PREFIX = "paddle_tpu.step."
@@ -133,6 +134,11 @@ class RuntimeStats:
         self.flash_mla_backward_split = 0
         self.flash_gqa_backward_fused = 0
         self.flash_gqa_backward_split = 0
+        # trips of the counted loops traced (`static_rnn` with a
+        # `trip_count`): what a step runs of them (delta() around a
+        # build: 4 where one stack runs 4 times), and what an early exit
+        # would lower
+        self.loop_trips = 0
         # per-phase totals and the most recent durations; the stages of
         # set-up beside them (outermost entries only), there from the
         # start so that a snapshot always carries them
@@ -189,6 +195,10 @@ class RuntimeStats:
         field = f"{family}_backward_{'fused' if fused else 'split'}"
         with self._lock:
             setattr(self, field, getattr(self, field) + 1)
+
+    def record_loop_trips(self, trips: int):
+        with self._lock:
+            self.loop_trips += trips
 
     def phase(self, name: str) -> _Phase:
         """Context manager around one host phase of a step: a

@@ -204,10 +204,12 @@ def instruction_name(event_name: str) -> str:
 def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
     """{instruction name: {op_name, bucket, flops, bytes, kernel}} of
     one serialized HloProto: every computation's instructions with
-    their `metadata.op_name`, and for the entry computation's and
-    those of every branch of its `conditional`s (any of which may be
-    the one that ran) the bucket, FLOPs, bytes and Mosaic kernel name
-    of `cost.instruction_costs` (elsewhere None)."""
+    their `metadata.op_name`, and for the entry computation's, those
+    of every branch of its `conditional`s (any of which may be the one
+    that ran) and those of the body of every counted `while` the
+    bucket, FLOPs and bytes (per call) and Mosaic kernel name of
+    `cost.instruction_costs` (elsewhere None: the body of a `while`
+    whose trip count is not known)."""
     from . import cost
 
     module = cost.HloModule(proto)
@@ -228,7 +230,9 @@ def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
 # --------------------------------------------------------------------------
 
 UNJOINED_BUCKET = "unknown"     # the instruction is in no map
-BODY_BUCKET = "loop"            # in the map, no cost row: a while body
+# in the map, no cost row: the body of a `while` whose trip count XLA
+# did not recover (a counted loop's body has rows of its own)
+BODY_BUCKET = "loop"
 
 
 def _enclosing(modules, starts, t) -> Optional[str]:
@@ -254,7 +258,11 @@ def join_events(ops, modules, programs, window=None, chip=0
 
     An event nested inside another on the line (a `while`'s body)
     keeps its time and takes it from its parent: `self_s` is time no
-    child covers, so the rows of a window sum to its busy union.
+    child covers, so the rows of a window sum to its busy union.  The
+    body of a counted loop joins like the entry computation: its
+    instructions have buckets, FLOPs and bytes of their own (per
+    call; `calls` counts the trips), and the `while` row keeps the
+    time no body event covers.
 
     One row per (module, instruction): chip, module, instruction,
     op_name, op_type (fluid), name_scope (the `fluid.name_scope()` path

@@ -140,6 +140,10 @@ def static_rnn_op(ctx, env, desc):
       unroll:       lax.scan unroll factor (default 1) — the cheap
                     XLA-side scan-bound lever (fewer while iterations,
                     more work per iteration for the scheduler)
+      trip_count:   (optional) the counted form: no step input, the
+                    body runs this many times over its memories; what
+                    the body reads from outside (weights) is the scan's
+                    closure, so its gradient sums over the trips
 
     reference: paddle/fluid/operators/recurrent_op.cc:222 (step-scope
     iteration) — here one lax.scan, reverse-differentiable by jax AD, so
@@ -151,9 +155,20 @@ def static_rnn_op(ctx, env, desc):
     step_outputs = desc.attrs.get("step_outputs", [])
     final_states = desc.attrs.get("final_states", [])
     unroll = int(desc.attrs.get("unroll", 1))
+    trip_count = desc.attrs.get("trip_count")
+    if trip_count is not None:
+        if step_inputs:
+            raise ValueError("static_rnn: a trip_count and step inputs")
+        from ..observe.monitoring import runtime_stats
+
+        runtime_stats.record_loop_trips(int(trip_count))
 
     init_carry = tuple(env[init] for _pre, _post, init in memories)
     xs = tuple(env[outer] for outer, _inner in step_inputs)
+    # what leaves the body: a recompute segment inside it hands out
+    # these names and keeps the rest to itself
+    leaving = ({post for _pre, post, _init in memories}
+               | {inner for inner, _outer in step_outputs})
 
     def body(carry, x_slices):
         e = dict(env)
@@ -161,14 +176,15 @@ def static_rnn_op(ctx, env, desc):
             e[pre] = c
         for (_outer, inner), x in zip(step_inputs, x_slices):
             e[inner] = x
-        ctx.run_block(sub_block, e)
+        ctx.run_block(sub_block, e, keep_names=leaving)
         new_carry = tuple(
             e[post].astype(c.dtype) if hasattr(c, "dtype") else e[post]
             for (_pre, post, _init), c in zip(memories, carry))
         ys = tuple(e[inner] for inner, _outer in step_outputs)
         return new_carry, ys
 
-    final, ys = lax.scan(body, init_carry, xs, unroll=unroll)
+    final, ys = lax.scan(body, init_carry, xs, length=trip_count,
+                         unroll=unroll)
     for (_inner, outer), y in zip(step_outputs, ys):
         env[outer] = y
     # final is ordered by memories; final_states maps post->outer

@@ -496,8 +496,16 @@ def softmax_with_cross_entropy(ctx, ins, attrs):
         ignore = attrs.get("ignore_index", -100)
         valid = lbl != ignore
         safe_lbl = jnp.where(valid, lbl, 0)
-        picked = jnp.take_along_axis(
-            log_sm, safe_lbl[..., None].astype(jnp.int32), axis=-1)
+        if attrs.get("one_hot_pick", False):
+            # the same number, read elementwise: the backward pass is
+            # a select that fuses, not a scatter
+            hot = (jnp.arange(logits.shape[-1], dtype=jnp.int32)
+                   == safe_lbl[..., None].astype(jnp.int32))
+            picked = jnp.sum(jnp.where(hot, log_sm, 0.0), axis=-1,
+                             keepdims=True)
+        else:
+            picked = jnp.take_along_axis(
+                log_sm, safe_lbl[..., None].astype(jnp.int32), axis=-1)
         picked = jnp.where(valid[..., None], picked, 0.0)
         loss = -picked
         eps = float(attrs.get("label_smooth_eps", 0.0) or 0.0)

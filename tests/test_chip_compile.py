@@ -752,3 +752,105 @@ def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
     assert not any(r["kernel"] for r in rows)
     assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
     assert {r["op_type"] for r in rows if r["op_type"]} == {"short_conv"}
+
+
+def test_a_looped_step_with_flash_kernels_in_the_scans_body(one_chip):
+    """What a looped decoder's step hands the chip's compiler that no
+    other cell does, at the published widths (1 x 4096 tokens, 16 heads
+    of 128, FFN 5632, the whole 49152-row head; depth cut to ONE layer
+    for the test's time, 4 trips as published): the Mosaic flash
+    kernels inside a `lax.scan`'s body and inside its transpose, each
+    layer pass and each trip's head a recompute segment in the body,
+    bf16 AMP.  The loops are counted (trip count 4), the body's
+    instructions are cost rows of their own under their kernels' names
+    and the `ut_loop` scope, the weights' bf16 copies are made outside
+    the loops, and the forward loop hands its transpose the segments'
+    inputs alone."""
+    import numpy as np
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import decoder
+    from paddle_tpu.observe import cost, trace
+
+    t, d, dff, vocab, trips = 4096, 2048, 5632, 49152, 4
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        model = decoder.build_model(
+            max_length=t, hidden_size=d, num_hidden_layers=1,
+            num_attention_heads=16, num_key_value_heads=16,
+            intermediate_size=dff, num_experts=0, num_experts_per_tok=0,
+            norm_topk_prob=False, num_dense_layers=1, vocab_size=vocab,
+            rope_theta=1e6, rms_norm_eps=1e-6, total_ut_steps=trips,
+            sandwich_norm=True, qk_norm=None, exit_gate="sigmoid",
+            exit_entropy_weight=0.1, recompute="layer")
+        # the state by its shapes: no start-up run at this size
+        for var in main.global_block().vars.values():
+            if var.persistable and all(int(s) > 0 for s in var.shape):
+                scope.set_var(var.name, jax.ShapeDtypeStruct(
+                    tuple(int(s) for s in var.shape),
+                    np.dtype(str(var.dtype))))
+        feed = {k: jnp.zeros((1, t), jnp.int64)
+                for k in ("tokens", "labels")}
+        exe = fluid.Executor()
+        step, state, feeds = exe._prepare(
+            main, feed, [model["loss"].name], scope, 1, True)
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        compiled = _compile_args(step, jax.tree.map(described, state),
+                                 jax.tree.map(described, feeds))
+    assert [len(b.ops) for b in main.blocks][1] > 20     # ONE sub-block
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    loops = [r for r in rows if r["opcode"] == "while"]
+    assert [r["trip_count"] for r in loops] == [trips, trips]
+    assert all(r["bucket"] == "loop" and r["flops"] == 0 for r in loops)
+    inside = [r for r in rows if r["loop_of"]]
+    assert all(r["trips"] == trips for r in inside)
+    # the forward kernel in the forward loop AND again in the backward
+    # loop's recomputed layer pass; the two backward kernels once
+    assert sorted(r["kernel"] for r in inside if r["kernel"]) == [
+        "flash_dkv", "flash_dq", "flash_fwd", "flash_fwd"]
+    assert not [r for r in rows if r["kernel"] and not r["loop_of"]]
+    pmap = trace.program_map(proto)
+    for r in inside:
+        if r["kernel"]:
+            assert r["pallas_kernel"] and r["flops"] > 0
+            assert "ut_loop" in trace.name_scope_of(
+                pmap[r["name"]]["op_name"]).split("/")
+    # the loop's matmuls carry their FLOPs per call: a layer pass's
+    # seven products forward, again recomputed, twice that backward,
+    # and the head's three; nothing of them outside the loops
+    tokens = float(t)
+    layer = 2 * tokens * (4 * d * d + 3 * d * dff)
+    head = 2 * tokens * d * vocab
+    matmul = sum(r["flops"] for r in inside if r["bucket"] == "matmul")
+    assert matmul == pytest.approx(4 * layer + 4 * head, rel=0.02)
+    assert sum(cost.per_step(r, "flops") for r in rows
+               if r["bucket"] == "matmul") == pytest.approx(
+        trips * (4 * layer + 4 * head), rel=0.02)
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 4
+    # the weights' bf16 copies are loop-invariant: no float32 weight
+    # enters a loop's body to be cast there once a trip
+    module = cost.HloModule(proto)
+    bodies = [module.computations[c] for loop in module.entry.instructions
+              if loop.opcode == "while" for c in loop.called_ids]
+    weights = {(d, dff), (dff, d), (d, vocab)}
+    for body in bodies:
+        for instr in body.instructions:
+            if instr.opcode == "parameter":
+                continue
+            assert not (instr.opcode == "convert"
+                        and tuple(instr.shape.dims) in weights), instr.name
+    text = compiled.as_text()
+    forward = [ln for ln in text.splitlines() if " while(" in ln][0]
+    # what the forward loop saves for its transpose: the float32 input
+    # of the layer's segment and of the head's, stacked over the trips
+    # (2 x 134 MB), not the layer's activations
+    assert forward.count(f"f32[{trips},1,{t},{d}]") == 2
+    assert f"[{trips},1,{t},{dff}]" not in forward
+    assert f"{t},{vocab}]" not in forward

@@ -255,8 +255,11 @@ def test_op_cost_table_against_xla_aggregate():
     loops = [r for r in cost.instruction_costs(
         cost.compiled_hlo_proto(compiled)) if r["opcode"] == "while"]
     assert all(r["trip_count"] for r in loops), loops
+    # (a counted loop's own row carries no cost: its body's rows do,
+    # per call; `body_flops` is the body over all trips)
+    assert all(r["flops"] == 0 for r in loops)
     once = totals["flops"] - sum(
-        r["flops"] * (1.0 - 1.0 / r["trip_count"]) for r in loops)
+        r["body_flops"] * (1.0 - 1.0 / r["trip_count"]) for r in loops)
     assert abs(once - xla) / xla < 0.05, (totals["flops"], once, xla)
 
 
@@ -302,10 +305,18 @@ def test_a_conditional_costs_its_heaviest_branch_not_the_sum():
 
     compiled = jax.jit(looped).lower(
         jnp.int32(0), jnp.ones((128, 128), jnp.float32)).compile()
-    (loop,) = [r for r in cost.instruction_costs(
-        cost.compiled_hlo_proto(compiled)) if r["opcode"] == "while"]
-    assert 16 * 2.0 * 128 ** 3 <= loop["flops"] < 16 * (
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    (loop,) = [r for r in rows if r["opcode"] == "while"]
+    assert 16 * 2.0 * 128 ** 3 <= loop["body_flops"] < 16 * (
         2.0 * 128 ** 3 + 2.0 * 64 ** 3)
+    # ... and the rows of the body say the same, each FLOP once: the
+    # heaviest branch's dot is a row of the loop's, 16 trips a step
+    assert loop["flops"] == 0
+    inside = [r for r in rows if r["loop_of"] == loop["name"]]
+    assert [(r["flops"], r["trips"]) for r in inside
+            if r["bucket"] == "matmul"] == [(2.0 * 128 ** 3, 16)]
+    assert sum(cost.per_step(r, "flops") for r in inside) \
+        == loop["body_flops"]
 
 
 def test_op_cost_table_joins_profile_time(tmp_path):
