@@ -68,6 +68,7 @@ _FIELDS = Heard._fields[1:] + (
     "dropout_masks_kernel", "dropout_masks_xla",
     "flash_mla_backward_fused", "flash_mla_backward_split",
     "flash_gqa_backward_fused", "flash_gqa_backward_split",
+    "flash_attention_backward_fused", "flash_attention_backward_split",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -126,14 +127,17 @@ class RuntimeStats:
         # (a step that fell back says so; delta() around a build)
         self.dropout_masks_kernel = 0
         self.dropout_masks_xla = 0
-        # backward passes of `ops/pallas/flash_mla.py` and of
-        # `flash_gqa.py` traced, by what the operands' shape chose: the
-        # single kernel, or dk/dv and dq by a kernel each (delta()
-        # around a build; `joyai-8k` 6 / 0, `lfm2-8k` 1 / 0)
+        # backward passes of `ops/pallas/flash_mla.py`, `flash_gqa.py`
+        # and `flash_attention.py` traced, by what the call's shape
+        # chose: the single kernel, or dk/dv and dq by a kernel each
+        # (delta() around a build; `joyai-8k` 6 / 0, `lfm2-8k` 1 / 0,
+        # `olmoe-4k` 1 / 0; a loop's body counts once, as traced)
         self.flash_mla_backward_fused = 0
         self.flash_mla_backward_split = 0
         self.flash_gqa_backward_fused = 0
         self.flash_gqa_backward_split = 0
+        self.flash_attention_backward_fused = 0
+        self.flash_attention_backward_split = 0
         # trips of the counted loops traced (`static_rnn` with a
         # `trip_count`): what a step runs of them (delta() around a
         # build: 4 where one stack runs 4 times), and what an early exit
@@ -191,7 +195,7 @@ class RuntimeStats:
 
     def record_flash_backward(self, family: str, fused: bool):
         """One traced backward pass of kernel family `family`
-        ("flash_mla", "flash_gqa")."""
+        ("flash_mla", "flash_gqa", "flash_attention")."""
         field = f"{family}_backward_{'fused' if fused else 'split'}"
         with self._lock:
             setattr(self, field, getattr(self, field) + 1)

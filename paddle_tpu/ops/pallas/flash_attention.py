@@ -6,15 +6,67 @@ normalizer / accumulator (the standard flash algorithm).  This is the
 modern TPU equivalent of the LoD no-padding efficiency story
 (SURVEY.md §5.7): padding positions are masked via an additive key bias.
 
-The backward is also tiled (two kernels): dk/dv accumulates over q-blocks
-and dq over k-blocks, both recomputing p = exp(s - lse) from the saved
-logsumexp — end-to-end O(T) memory so long-context training never
+The backward is tiled too, recomputing p = exp(s - lse) from the saved
+logsumexp: end-to-end O(T) memory, so long-context training never
 materializes the score matrix.  Score blocks are kept in (k, q)
 orientation in the backward so the per-q lse/delta vectors broadcast
 along the TPU lane dimension (no transposes in-kernel).  delta =
 rowsum(do*o) is recomputed in-kernel from the o/do tiles (cheap
 elementwise per block) instead of a separate XLA reduction, so NOTHING
-but the q/k/v/o/do/lse buffers crosses the kernel boundary.
+but the q/k/v/o/do/lse buffers crosses the kernel boundary.  Every dot
+takes q, k, v, do in the dtype they arrive in (p and ds cast to it) and
+accumulates in float32, as flash_gqa.py and flash_mla.py do; scores,
+soft-max and delta are float32.
+
+It is ONE kernel where the call allows it (`_bwd_kernel`, PR 37): p and
+ds once a block pair, dq, dk and dv from them: five score-sized matmuls
+where two kernels run seven, the soft-max's vector work once.  dk / dv
+sum over the query blocks and dq over the key blocks, which no grid
+order visits consecutively for both, so one side is held full-length in
+float32 VMEM.  Layout (A) was taken: the dk / dv kernel's grid (N*H, k
+blocks, q blocks), dk and dv block accumulators as before, and dq of
+the head's whole sequence in scratch (nq, block_q, d), 4 * d bytes a
+position (2 MiB at 4096 x 128).  Each dq block leaves for HBM on the
+step that completes it (its last key block: the diagonal's when
+causal, the last pass otherwise); the output's index map moves on only
+then, so Pallas never writes a half-summed block back and no partial of
+dq reaches HBM.  Layout (B), query blocks outside with dk and dv of the
+head full-length (twice the scratch, whole-head output blocks written
+in one burst at the head's last step), compiled too and was 6-11%
+slower alone at every block size tried on the chip (PERF.md, PR 37).
+
+What goes where is decided by the CALL ALONE (`fused_backward_fits`; no
+option, attribute, environment variable or config key):
+
+- the single kernel: self-attention (T_q == T_k) over whole blocks,
+  causal or not, no bias, no position offsets, no returned logsumexp,
+  the dq accumulator within `FUSED_ACCUMULATOR_BUDGET` (65536 positions
+  at d_head 128).  Both layouts; any d_head the forward takes.  What a
+  decoder layer asks, and Ulysses attention's local call;
+- the two kernels (`flash_dkv` + `flash_dq`, each recomputing s and
+  dp): a key-padding bias (its db is a third result of the dk / dv
+  kernel), ring attention's calls (dynamic offsets: the step that
+  completes a dq block is not known when the grid is laid out; and a
+  logsumexp cotangent), cross-attention, a ragged last block, a longer
+  sequence.
+
+Both paths take every term from the same `_bwd_p_ds` and add it in
+the same order: the same bits out (tests/test_flash_attention.py).  The
+single kernel is passed to `pallas_call` under the name `flash_dkv`: it
+is that kernel grown by dq's dot, its registered cost grows by dq's
+work when the call has three gradient results, and the benchmark's
+closed list of kernels (`benchmarks/kernel_counts.py FLASH_KERNELS`)
+knows that name; `flash_dq` exists on the two-kernel path only.
+`observe.monitoring` counts the backward passes traced as
+`flash_attention_backward_fused` / `_split`.
+
+The backward's blocks are its own, 1024 x 1024 (`DEFAULT_BWD_BLOCK_*`;
+the forward keeps 256 x 1024): alone on the chip the single kernel took
+1.34 ms a call at 4096 x 16 heads against 1.40 at 512 x 1024, 1.53 at
+256 x 1024 and 2.69 for the parent's two kernels.  Their float32 score
+blocks pass Mosaic's default 16 MiB of scoped VMEM (17.35 MiB), so such
+a call names a limit (`_vmem_params`: only where blocks and accumulator
+need it; a call that fits names none).
 
 Two operand layouts, selected by `layout=`:
 
@@ -54,7 +106,24 @@ import numpy as np
 # attention; both dims are clamped to the actual sequence length.
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 1024
+# the backward pass's own blocks (the statistics are block-free, so it
+# need not take the forward's)
+DEFAULT_BWD_BLOCK_Q = 1024
+DEFAULT_BWD_BLOCK_K = 1024
 NEG_INF = -1e30
+# The single backward kernel holds one head's dq, a whole sequence of
+# float32 (4 * d bytes a position).  It may take this much of v5e's
+# 128 MiB of VMEM; a longer sequence goes to the two kernels, which
+# hold blocks only
+FUSED_ACCUMULATOR_BUDGET = 32 << 20
+# A backward kernel whose float32 score blocks and accumulators pass
+# Mosaic's default 16 MiB of scoped VMEM claims this much instead (the
+# verdict is Mosaic's: tests/test_chip_compile_flash.py); one that
+# fits asks for nothing, because XLA plans and schedules a step
+# differently around a call that names a limit (PERF.md, PRs 35, 37).
+# "Fits": accumulator + four score blocks within _VMEM_FITS
+_VMEM_LIMIT = 100 << 20
+_VMEM_FITS = 10 << 20
 
 # -- kernel cost registry (observe/cost.py injects these at the custom
 # -- call instructions; tests/test_observe_cost.py holds them to the
@@ -105,19 +174,27 @@ def flash_fwd_cost(operand_shapes, result_shapes):
     return flops, _io_bytes(operand_shapes, result_shapes)
 
 
+def _dq_flops(operand_shapes):
+    nh, t_q, t_k, d = _attn_dims(operand_shapes, operand_shapes[5][0])
+    return nh * t_q * t_k * (2.0 * d + 0.375 * _SOFTMAX_BWD_PER_SCORE)
+
+
 def flash_dkv_cost(operand_shapes, result_shapes):
     # carries dk + dv + the shared dp dot (dense-equivalent split with
     # flash_dq_cost: together they sum to the dense backward's 4 dots).
-    # operand_shapes[5] is the (nh, 8, t_q) lse input.
+    # operand_shapes[5] is the (nh, 8, t_q) lse input.  The kernel of
+    # this name that emits dq too (the single backward kernel: three
+    # gradients out, where a bias's third result is a (nh, t_k, 1)
+    # column) does dq's work as well.
     nh, t_q, t_k, d = _attn_dims(operand_shapes, operand_shapes[5][0])
     flops = nh * t_q * t_k * (6.0 * d + 0.625 * _SOFTMAX_BWD_PER_SCORE)
+    if len(result_shapes) == 3 and result_shapes[2][0] == result_shapes[1][0]:
+        flops += _dq_flops(operand_shapes)
     return flops, _io_bytes(operand_shapes, result_shapes)
 
 
 def flash_dq_cost(operand_shapes, result_shapes):
-    nh, t_q, t_k, d = _attn_dims(operand_shapes, operand_shapes[5][0])
-    flops = nh * t_q * t_k * (2.0 * d + 0.375 * _SOFTMAX_BWD_PER_SCORE)
-    return flops, _io_bytes(operand_shapes, result_shapes)
+    return _dq_flops(operand_shapes), _io_bytes(operand_shapes, result_shapes)
 
 
 def attention_cost(nh, t_q, t_k, d, dtype_bytes=4):
@@ -148,6 +225,19 @@ def _pallas_call(*args, **kw):
     from . import pallas_call  # shared interpret gate (package init)
 
     return pallas_call(*args, **kw)
+
+
+def _vmem_params(accumulator_bytes, block_q, block_k):
+    """`pallas_call` keywords of a backward kernel: a VMEM limit where
+    its float32 score blocks (Mosaic holds some four of them: 17.35 MiB
+    scoped at 1024 x 1024 with a 2 MiB accumulator) and its
+    accumulators come near the default, nothing where they fit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if accumulator_bytes + 4 * 4 * block_q * block_k <= _VMEM_FITS:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_VMEM_LIMIT)}
 
 
 def _offs(offs_ref):
@@ -348,61 +438,100 @@ def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
 # Score blocks are held transposed, sT: (block_k, block_q), so the per-q
 # vectors (lse, delta) broadcast along lanes.  delta is recomputed from
 # the o/do tiles in-kernel (elementwise, cheap) so no (NH, T) statistic
-# has to be produced by XLA between the kernels.
+# has to be produced by XLA between the kernels.  Every dot takes its
+# operands in the dtype they arrive in (p and ds cast to it) and
+# accumulates in float32, as flash_gqa.py and flash_mla.py do; scores,
+# soft-max and delta are float32.
 
-def _bwd_p_ds(q, k, v, do, lse_row, delta_row, bias_col, q_off, k_off, *,
-              scale, causal, kb, qb, block_q, block_k, t_q, t_k):
-    """Shared (block_k, block_q)-oriented recompute of p and ds.
-
-    q/do must already have invalid rows zeroed by the caller; invalid
-    (padded) score positions are masked here via `valid`, never letting
-    undefined block padding reach an accumulator (0 * NaN poisons).
-    ds is d(loss)/d(s_with_bias): unscaled — the q/k grads multiply by
-    `scale` at their accumulation (chain rule through s = scale*qk^T),
-    while the bias grad uses ds directly."""
-    sT = jax.lax.dot_general(
-        k, q, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    if bias_col is not None:
-        sT = sT + bias_col                  # (block_k, 1) over lanes
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 0)
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 1)
-    valid = (k_pos < t_k) & (q_pos < t_q)
-    if causal:
-        valid = valid & (q_off + q_pos >= k_off + k_pos)
-    p = jnp.where(valid, jnp.exp(sT - lse_row), 0.0)
-    dp = jax.lax.dot_general(
-        v, do, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = jnp.where(valid, p * (dp - delta_row), 0.0)
-    return p, ds
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 def _row_clean(ref, base, limit, block):
     """Load a (block, d) tile zeroing rows at absolute position >= limit
     (undefined padding of the final block)."""
     x = _tile(ref)
+    if limit % block == 0:      # whole blocks: nothing to zero
+        return x
     rows = base + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     return jnp.where(rows < limit, x, 0)
 
 
 def _delta_row(do, o, dlse_ref):
-    """(1, block_q) delta = rowsum(do * o) [- dlse], recomputed from the
-    already-cleaned f32 tiles.  dlse arrives 8-sublane-stored with only
-    row 0 populated (the public wrapper slices lse8[:, 0, :]), so the
-    sublane SUM recovers it."""
-    delta = jnp.sum(do * o, axis=1)[None, :]
+    """(1, block_q) float32 delta = rowsum(do * o) [- dlse], recomputed
+    from the already-cleaned tiles.  dlse arrives 8-sublane-stored with
+    only row 0 populated (the public wrapper slices lse8[:, 0, :]), so
+    the sublane SUM recovers it."""
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=1)[None, :]
     if dlse_ref is not None:
         delta = delta - jnp.sum(dlse_ref[0], axis=0)[None, :]
     return delta
 
 
+def _runs(offs_ref, kb, qb, *, causal, block_q, block_k, **_):
+    """Whether block pair (kb, qb) holds a score: a causal key block
+    sees no query block wholly above the (offset) diagonal."""
+    if not causal:
+        return True
+    q_off, k_off = _offs(offs_ref)
+    return q_off + (qb + 1) * block_q > k_off + kb * block_k
+
+
+def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+              bias_ref, offs_ref, kb, qb, *, scale, causal, block_q,
+              block_k, t_q, t_k):
+    """(q, k, do, p, ds) of block pair (kb, qb): the tiles with their
+    padding zeroed, and the float32 (block_k, block_q) p and ds every
+    gradient is a dot of.  All three backward kernels take their terms
+    from here.
+
+    Invalid (padded) score positions are masked via `valid`, never
+    letting undefined block padding reach an accumulator (0 * NaN
+    poisons).  ds is d(loss)/d(s_with_bias): unscaled — the q/k grads
+    multiply by `scale` at their accumulation (chain rule through
+    s = scale*qk^T), while the bias grad uses ds directly."""
+    q = _row_clean(q_ref, qb * block_q, t_q, block_q)
+    do = _row_clean(do_ref, qb * block_q, t_q, block_q)
+    o = _row_clean(o_ref, qb * block_q, t_q, block_q)
+    k = _row_clean(k_ref, kb * block_k, t_k, block_k)
+    sT = _dot(k, q, ((1,), (1,))) * scale
+    if bias_ref is not None:    # a (block_k, 1) column over the lanes
+        sT = sT + bias_ref[0].astype(jnp.float32)
+    k_pos = kb * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 0)
+    q_pos = qb * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 1)
+    q_off, k_off = _offs(offs_ref)
+    # a sequence of whole blocks has no padding to mask (static)
+    valid = [k_pos < t_k] * bool(t_k % block_k) \
+        + [q_pos < t_q] * bool(t_q % block_q) \
+        + [q_off + q_pos >= k_off + k_pos] * bool(causal)
+    p = jnp.exp(sT - lse_ref[0, 0][None, :])
+    ds = p * (_dot(_tile(v_ref), do, ((1,), (1,)))
+              - _delta_row(do, o, dlse_ref))
+    if valid:
+        valid = functools.reduce(jnp.logical_and, valid)
+        p, ds = jnp.where(valid, p, 0.0), jnp.where(valid, ds, 0.0)
+    return q, k, do, p, ds
+
+
+def _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, scale):
+    """The block pair's part of dk and dv into their float32 sums."""
+    dv_scr[:] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+    dk_scr[:] += scale * _dot(ds.astype(q.dtype), q, ((1,), (0,)))
+
+
+def _add_dq(ds, k, dq_scr, scale, at=slice(None)):
+    """The block pair's part of dq into its float32 sum `dq_scr[at]`:
+    dq[q, d] = scale * sum_k ds[k, q] * k[k, d]."""
+    dq_scr[at] += scale * _dot(ds.astype(k.dtype), k, ((0,), (0,)))
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                     dlse_ref, bias_ref, offs_ref, dk_ref, dv_ref, db_ref,
-                    dk_scr, dv_scr, db_scr, *, scale, causal, block_q,
-                    block_k, t_q, t_k):
+                    dk_scr, dv_scr, db_scr, **dims):
     from jax.experimental import pallas as pl
 
     kb = pl.program_id(1)
@@ -416,34 +545,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         if db_scr is not None:
             db_scr[:] = jnp.zeros_like(db_scr)
 
-    q_off, k_off = _offs(offs_ref)
-    # causal: this k-block sees no q-block strictly below the diagonal
-    run = (q_off + (qb + 1) * block_q > k_off + kb * block_k) \
-        if causal else True
-
-    @pl.when(run)
+    @pl.when(_runs(offs_ref, kb, qb, **dims))
     def _compute():
-        q = _row_clean(q_ref, qb * block_q, t_q, block_q)
-        do = _row_clean(do_ref, qb * block_q, t_q, block_q)
-        o = _row_clean(o_ref, qb * block_q, t_q, block_q)
-        k = _tile(k_ref)
-        v = _tile(v_ref)
-        bias_col = None if bias_ref is None else \
-            bias_ref[0].astype(jnp.float32)
-        do32 = do.astype(jnp.float32)
-        delta = _delta_row(do32, o.astype(jnp.float32), dlse_ref)
-        p, ds = _bwd_p_ds(
-            q.astype(jnp.float32), k.astype(jnp.float32),
-            v.astype(jnp.float32), do32,
-            lse_ref[0, 0][None, :], delta, bias_col,
-            q_off, k_off, scale=scale, causal=causal, kb=kb, qb=qb,
-            block_q=block_q, block_k=block_k, t_q=t_q, t_k=t_k)
-        dv_scr[:] += jax.lax.dot_general(
-            p, do32, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[:] += scale * jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        q, _, do, p, ds = _bwd_p_ds(
+            q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+            bias_ref, offs_ref, kb, qb, **dims)
+        _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, dims["scale"])
         if db_scr is not None:
             db_scr[:] += jnp.sum(ds, axis=1, keepdims=True)
 
@@ -456,8 +563,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                   dlse_ref, bias_ref, offs_ref, dq_ref, dq_scr, *,
-                   scale, causal, block_q, block_k, t_q, t_k):
+                   dlse_ref, bias_ref, offs_ref, dq_ref, dq_scr, **dims):
     from jax.experimental import pallas as pl
 
     qb = pl.program_id(1)
@@ -468,39 +574,162 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q_off, k_off = _offs(offs_ref)
-    run = (q_off + (qb + 1) * block_q > k_off + kb * block_k) \
-        if causal else True
-
-    @pl.when(run)
+    @pl.when(_runs(offs_ref, kb, qb, **dims))
     def _compute():
-        q = _row_clean(q_ref, qb * block_q, t_q, block_q)
-        do = _row_clean(do_ref, qb * block_q, t_q, block_q)
-        o = _row_clean(o_ref, qb * block_q, t_q, block_q)
-        k = _row_clean(k_ref, kb * block_k, t_k, block_k)
-        v = _tile(v_ref)
-        bias_col = None if bias_ref is None else \
-            bias_ref[0].astype(jnp.float32)
-        do32 = do.astype(jnp.float32)
-        delta = _delta_row(do32, o.astype(jnp.float32), dlse_ref)
-        _, ds = _bwd_p_ds(
-            q.astype(jnp.float32), k.astype(jnp.float32),
-            v.astype(jnp.float32), do32,
-            lse_ref[0, 0][None, :], delta, bias_col,
-            q_off, k_off, scale=scale, causal=causal, kb=kb, qb=qb,
-            block_q=block_q, block_k=block_k, t_q=t_q, t_k=t_k)
-        # dq[q,d] = scale * sum_k ds[k,q] * k[k,d]
-        dq_scr[:] += scale * jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _, k, _, _, ds = _bwd_p_ds(
+            q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+            bias_ref, offs_ref, kb, qb, **dims)
+        _add_dq(ds, k, dq_scr, dims["scale"])
 
     @pl.when(kb == nk - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+                dk_ref, dv_ref, dq_acc, dk_scr, dv_scr, *, last_k, **dims):
+    """The whole backward pass, grid (NH, kb, qb): p and ds once a block
+    pair, dq, dk and dv from them, each added as the kernel that holds
+    blocks only adds it.  dk and dv sum over the query blocks, the
+    inner axis, in block scratch; dq sums over the key blocks in
+    `dq_acc` (nq, block_q, d), the head's whole sequence, each block
+    leaving for HBM on the step that completes it (`last_k`)."""
+    from jax.experimental import pallas as pl
+
+    kb = pl.program_id(1)
+    qb = pl.program_id(2)
+    nq = pl.num_programs(2)
+
+    @pl.when(qb == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(kb == 0)       # every query block meets key block 0, first
+    def _init_dq():
+        dq_acc[qb] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+    @pl.when(_runs(None, kb, qb, **dims))
+    def _compute():
+        q, k, do, p, ds = _bwd_p_ds(
+            q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
+            kb, qb, **dims)
+        _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, dims["scale"])
+        _add_dq(ds, k, dq_acc, dims["scale"], at=qb)
+
+    @pl.when(qb == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(kb == last_k(qb))
+    def _finalize_dq():
+        dq_ref[0] = dq_acc[qb].astype(dq_ref.dtype)
+
+
+def fused_backward_fits(t_q, t_k, d, block_q, block_k, *, bias=False,
+                        offsets=False, lse_cotangent=False):
+    """Whether the backward pass of a call is the single kernel: from
+    the call alone, never from an option.  It takes self-attention over
+    whole blocks with nothing beside q, k, v (causal or not): the step
+    that completes a dq block must be known when the grid is laid out
+    (no dynamic offsets), and a bias's gradient, a logsumexp cotangent
+    and the masks of a ragged last block stay with the two kernels.
+    Its float32 dq of one head's whole sequence, 4 * d bytes a
+    position, must fit the budget."""
+    plain = not (bias or offsets or lse_cotangent)
+    whole = (t_q == t_k and t_q % min(block_q, t_q) == 0
+             and t_k % min(block_k, t_k) == 0)
+    return plain and whole and t_q * d * 4 <= FUSED_ACCUMULATOR_BUDGET
+
+
 def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
                block_q, block_k, layout, n_head):
+    from ...observe.monitoring import runtime_stats
+
+    nh, t_q, t_k, d = _fwd_dims(q, k, layout, n_head)
+    fused = fused_backward_fits(
+        t_q, t_k, d, block_q, block_k, bias=bias is not None,
+        offsets=offsets is not None, lse_cotangent=dlse8 is not None)
+    runtime_stats.record_flash_backward("flash_attention", fused)
+    if fused:
+        return _flash_bwd_fused(q, k, v, o, lse8, do, scale, causal,
+                                block_q, block_k, layout, n_head) + (None,)
+    return _flash_bwd_split(q, k, v, bias, offsets, o, lse8, do, dlse8,
+                            scale, causal, block_q, block_k, layout, n_head)
+
+
+def _grad_shapes(q, k, layout, nh, t_q, t_k, d):
+    """(dq, dk) shapes; dv is dk's."""
+    if layout == "nthd":
+        return (jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(k.shape, q.dtype))
+    return (jax.ShapeDtypeStruct((nh, t_q, d), q.dtype),
+            jax.ShapeDtypeStruct((nh, t_k, d), q.dtype))
+
+
+def _flash_bwd_fused(q, k, v, o, lse8, do, scale, causal, block_q, block_k,
+                     layout, n_head):
+    """dq, dk and dv by ONE kernel, named `flash_dkv`: it is that kernel
+    grown by dq's dot, and the name is the one the benchmark's closed
+    list of kernels knows.  Grid (NH, kb, qb), the dk / dv kernel's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    nh, t, _, d = _fwd_dims(q, k, layout, n_head)
+    h = n_head
+    block_q, block_k = min(block_q, t), min(block_k, t)
+    nq, nk = t // block_q, t // block_k
+
+    # The key block that completes query block qb, and the first query
+    # block key block kb (or a later one) completes: the diagonal's
+    # when causal, the last pass otherwise
+    def last_k(qb):
+        return ((qb + 1) * block_q - 1) // block_k if causal else nk - 1
+
+    def first_q(kb):
+        if causal:
+            return (kb * block_k) // block_q
+        return jnp.where(kb >= nk, nq, 0)
+
+    def q_time(kb, qb):     # a skipped block pair fetches nothing new
+        return jnp.maximum(qb, first_q(kb))
+
+    def dq_time(kb, qb):
+        # the query block last completed, or being completed: the
+        # blocks before first_q(kb) met their last key block in an
+        # earlier pass, those before first_q(kb + 1) meet it in this
+        # one.  The index moves on only on the step that writes the
+        # next block, so no half-summed block is ever what Pallas
+        # writes back
+        done = jnp.minimum(jnp.maximum(qb, first_q(kb) - 1),
+                           first_q(kb + 1) - 1)
+        return jnp.maximum(done, 0)
+
+    q_spec = _tile_spec(block_q, d, layout, h, q_time)
+    kv_spec = _tile_spec(block_k, d, layout, h, lambda kb, qb: kb)
+    dq_shape, dk_shape = _grad_shapes(q, k, layout, nh, t, t, d)
+    kern = functools.partial(
+        _bwd_kernel, last_k=last_k, scale=scale, causal=causal,
+        block_q=block_q, block_k=block_k, t_q=t, t_k=t)
+    return tuple(_pallas_call(
+        kern,
+        name="flash_dkv",
+        grid=(nh, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec,
+                  _stat_spec(block_q, q_time)],
+        out_specs=[_tile_spec(block_q, d, layout, h, dq_time), kv_spec,
+                   kv_spec],
+        out_shape=[dq_shape, dk_shape, dk_shape],
+        scratch_shapes=[pltpu.VMEM((nq, block_q, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        **_vmem_params(nq * block_q * d * 4, block_q, block_k),
+    )(q, k, v, do, o, lse8))
+
+
+def _flash_bwd_split(q, k, v, bias, offsets, o, lse8, do, dlse8, scale,
+                     causal, block_q, block_k, layout, n_head):
+    """dk / dv (and db) and dq by a kernel each: every score twice."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -510,6 +739,9 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
     block_k = min(block_k, t_k)
     nq = pl.cdiv(t_q, block_q)
     nk = pl.cdiv(t_k, block_k)
+    dims = dict(scale=scale, causal=causal, block_q=block_q,
+                block_k=block_k, t_q=t_q, t_k=t_k)
+    vmem = _vmem_params(0, block_q, block_k)
 
     # bias arrives (N, 1, 1, t_k); kernels want it as a (block_k, 1)
     # column so it broadcasts over the lane (q) dimension
@@ -570,12 +802,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
     def grad_spec(block, tsel):
         return _tile_spec(block, d, layout, h, tsel)
 
-    if layout == "nthd":
-        dk_shape = jax.ShapeDtypeStruct(k.shape, q.dtype)
-        dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
-    else:
-        dk_shape = jax.ShapeDtypeStruct((nh, t_k, d), q.dtype)
-        dq_shape = jax.ShapeDtypeStruct((nh, t_q, d), q.dtype)
+    dq_shape, dk_shape = _grad_shapes(q, k, layout, nh, t_q, t_k, d)
 
     # dk/dv (+db): grid (g, kb, qb), accumulate over q-blocks
     def dkv_kern(*refs):
@@ -587,9 +814,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
             dk_r, dv_r, dk_s, dv_s = rest
             db_r = db_s = None
         _bwd_dkv_kernel(q_r, k_r, v_r, do_r, o_r, lse_r, dl_r, b_r, of_r,
-                        dk_r, dv_r, db_r, dk_s, dv_s, db_s, scale=scale,
-                        causal=causal, block_q=block_q, block_k=block_k,
-                        t_q=t_q, t_k=t_k)
+                        dk_r, dv_r, db_r, dk_s, dv_s, db_s, **dims)
 
     kq_out_specs = [grad_spec(block_k, lambda a, b: a),
                     grad_spec(block_k, lambda a, b: a)]
@@ -616,6 +841,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
         out_specs=kq_out_specs,
         out_shape=kq_out_shape,
         scratch_shapes=kq_scratch,
+        **vmem,
     )(*args)
     if has_bias:
         dk, dv, db = dkv_out
@@ -632,9 +858,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
             unpack(refs)
         dq_r, dq_s = rest
         _bwd_dq_kernel(q_r, k_r, v_r, do_r, o_r, lse_r, dl_r, b_r, of_r,
-                       dq_r, dq_s, scale=scale, causal=causal,
-                       block_q=block_q, block_k=block_k, t_q=t_q,
-                       t_k=t_k)
+                       dq_r, dq_s, **dims)
 
     dq = _pallas_call(
         dq_kern,
@@ -644,6 +868,7 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
         out_specs=grad_spec(block_q, lambda a, b: a),
         out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        **vmem,
     )(*args)
 
     return dq, dk, dv, dbias
@@ -652,22 +877,22 @@ def _flash_bwd(q, k, v, bias, offsets, o, lse8, do, dlse8, scale, causal,
 # -- custom VJP -------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
-def _flash(q, k, v, bias, offsets, scale, causal, block_q, block_k,
+def _flash(q, k, v, bias, offsets, scale, causal, blocks, bwd_blocks,
            layout, n_head, with_lse):
-    o, lse8 = _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q,
-                         block_k, layout, n_head)
+    o, lse8 = _flash_fwd(q, k, v, bias, offsets, scale, causal, *blocks,
+                         layout, n_head)
     return (o, lse8) if with_lse else o
 
 
-def _flash_vjp_fwd(q, k, v, bias, offsets, scale, causal, block_q,
-                   block_k, layout, n_head, with_lse):
-    o, lse8 = _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q,
-                         block_k, layout, n_head)
+def _flash_vjp_fwd(q, k, v, bias, offsets, scale, causal, blocks,
+                   bwd_blocks, layout, n_head, with_lse):
+    o, lse8 = _flash_fwd(q, k, v, bias, offsets, scale, causal, *blocks,
+                         layout, n_head)
     out = (o, lse8) if with_lse else o
     return out, (q, k, v, bias, offsets, o, lse8)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, layout, n_head,
+def _flash_vjp_bwd(scale, causal, blocks, bwd_blocks, layout, n_head,
                    with_lse, res, cts):
     q, k, v, bias, offsets, o, lse8 = res
     if with_lse:
@@ -675,8 +900,8 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, layout, n_head,
     else:
         do, dlse8 = cts, None
     dq, dk, dv, dbias = _flash_bwd(q, k, v, bias, offsets, o, lse8, do,
-                                   dlse8, scale, causal, block_q,
-                                   block_k, layout, n_head)
+                                   dlse8, scale, causal, *bwd_blocks,
+                                   layout, n_head)
     doffs = None if offsets is None else \
         np.zeros(offsets.shape, dtype=jax.dtypes.float0)
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
@@ -687,8 +912,7 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
-                           block_q=DEFAULT_BLOCK_Q,
-                           block_k=DEFAULT_BLOCK_K,
+                           block_q=None, block_k=None,
                            q_offset=None, k_offset=None,
                            return_lse=False, layout="nhtd",
                            n_head=None, n_kv_head=None):
@@ -706,7 +930,12 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
     rotated chunk's origin so the causal structure survives sharding.
     With return_lse=True also returns the per-row logsumexp —
     (N, H, T) for nhtd, (N, T, H) for nthd — differentiable (the dlse
-    cotangent folds into the backward)."""
+    cotangent folds into the backward).
+
+    A block size given holds for both passes; left out, each pass takes
+    its own (`DEFAULT_BLOCK_*`, `DEFAULT_BWD_BLOCK_*`), the backward
+    pass the forward's where a sequence is not a whole number of its
+    own."""
     if layout == "nthd":
         if n_head is None:
             raise ValueError("layout='nthd' needs n_head (operands are "
@@ -763,9 +992,14 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
                         jnp.int32),
         ]).reshape(1, 2)
 
+    blocks = (int(block_q or DEFAULT_BLOCK_Q), int(block_k or DEFAULT_BLOCK_K))
+    bwd_blocks = tuple(
+        int(given or (own if t % min(own, t) == 0 else fwd))
+        for given, own, fwd, t in zip(
+            (block_q, block_k), (DEFAULT_BWD_BLOCK_Q, DEFAULT_BWD_BLOCK_K),
+            blocks, (t_q, t_k)))
     out = _flash(qf, kf, vf, bias, offsets, float(scale), bool(causal),
-                 int(block_q), int(block_k), layout, int(h),
-                 bool(return_lse))
+                 blocks, bwd_blocks, layout, int(h), bool(return_lse))
     if return_lse:
         o, lse8 = out
         lse = lse8[:, 0, :].reshape(n, h, t_q)

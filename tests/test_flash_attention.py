@@ -154,6 +154,258 @@ def test_flash_bwd_bias_grad():
                                rtol=5e-3, atol=5e-3)
 
 
+# -- the backward pass: one kernel where the call allows it -----------------
+#
+# `flash_attention.py _flash_bwd`: the single kernel (p and ds once a
+# block pair, dq of the head's whole sequence in float32 VMEM) against
+# the two kernels that hold blocks only, which recompute the scores; the
+# rule that chooses between them from the call alone; the counters.
+# Mosaic's own checks are tests/test_chip_compile_flash.py's.
+
+def _backward_path(monkeypatch, path):
+    """Send the backward pass down `path` the only way there is: the
+    shape rule's budget (no option chooses)."""
+    import paddle_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "FUSED_ACCUMULATOR_BUDGET",
+                        {"one_kernel": 1 << 40, "two_kernels": 0}[path])
+
+
+def _took(before):
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    took = runtime_stats.delta(before)
+    return (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"])
+
+
+def _snapshot():
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    return runtime_stats.snapshot()
+
+
+def _qkvw(n, h, t, d, seed, dtype=jnp.float32):
+    """q, k, v and a cotangent weight, (N, H, T, D)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return [(jax.random.normal(k, (n, h, t, d)) * 0.5).astype(dtype)
+            for k in ks]
+
+
+def _head_major(x):
+    n, h, t, d = x.shape
+    return jnp.moveaxis(x, 1, 2).reshape(n, t, h * d)
+
+
+def _flash_grads(q, k, v, w, layout, causal, block_q, block_k):
+    """dq, dk, dv of sum(o * w) through the kernels, as (N, H, T, D)
+    whatever the layout the kernels saw."""
+    import paddle_tpu.ops.pallas.flash_attention as fa
+
+    n, h, t, d = q.shape
+    if layout == "nthd":
+        q, k, v, w = (_head_major(x) for x in (q, k, v, w))
+
+    def loss(q, k, v):
+        o = fa.pallas_flash_attention(
+            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+            layout=layout, n_head=h if layout == "nthd" else None)
+        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    if layout == "nthd":
+        grads = [jnp.moveaxis(g.reshape(n, t, h, d), 2, 1) for g in grads]
+    return grads
+
+
+BLOCKS = [(1, 128, 128), (2, 128, 128), (4, 128, 128), (2, 128, 256),
+          (2, 256, 128), (4, 128, 256)]
+BLOCK_IDS = ["1_block", "2_blocks", "4_blocks", "wide_k", "wide_q",
+             "4_blocks_wide_k"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("layout", ["nhtd", "nthd"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("blocks, block_q, block_k", BLOCKS, ids=BLOCK_IDS)
+@pytest.mark.parametrize("path", ["one_kernel", "two_kernels"])
+def test_both_backward_paths_give_the_reference_gradients(
+        monkeypatch, path, blocks, block_q, block_k, causal, layout, dtype):
+    """dq, dk and dv, the single backward kernel and the two, over T of
+    1, 2 and 4 blocks (diagonal, below-diagonal and skipped block pairs)
+    and block_q != block_k (a dq block then completes off the
+    diagonal's corner, and a pass over the query blocks may complete
+    two or none), causal and not, in both operand layouts; the counters
+    say which path a traced backward took."""
+    _backward_path(monkeypatch, path)
+    t = blocks * max(block_q, block_k)
+    q, k, v, w = _qkvw(1, 2, t, 128, seed=blocks + block_k)
+    want = jax.grad(
+        lambda *a: jnp.sum(_ref_attention(*a, causal=causal) * w),
+        argnums=(0, 1, 2))(q, k, v)
+    before = _snapshot()
+    got = _flash_grads(*(x.astype(dtype) for x in (q, k, v, w)), layout,
+                       causal, block_q, block_k)
+    assert _took(before) == ((1, 0) if path == "one_kernel" else (0, 1))
+    for name, g, r in zip("qkv", got, want):
+        assert g.shape == r.shape and g.dtype == dtype, name
+        if dtype == jnp.float32:    # this file's limits for a gradient
+            np.testing.assert_allclose(g, r, rtol=5e-3, atol=5e-3,
+                                       err_msg="d" + name)
+        else:       # p and ds are cast to 8 bits of mantissa before a dot
+            np.testing.assert_allclose(
+                g.astype(jnp.float32), r, err_msg="d" + name,
+                atol=4e-2 * float(jnp.abs(r).max()))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("layout", ["nhtd", "nthd"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("block_q, block_k", [(128, 128), (128, 256),
+                                              (256, 128), (128, 512)],
+                         ids=["square", "wide_k", "wide_q", "4_q_a_k"])
+def test_the_two_backward_paths_agree_to_the_bit(monkeypatch, block_q,
+                                                  block_k, causal, layout,
+                                                  dtype):
+    """Same terms in the same order: the single kernel sums dk / dv
+    over the query blocks and dq over the key blocks as the two do, from
+    the same p and ds."""
+    args = _qkvw(2, 2, 1024, 128, seed=11, dtype=dtype)
+    grads = {}
+    for path in ("one_kernel", "two_kernels"):
+        _backward_path(monkeypatch, path)
+        grads[path] = _flash_grads(*args, layout, causal, block_q, block_k)
+    for a, b in zip(grads["one_kernel"], grads["two_kernels"]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_call_alone_chooses_the_backward_path():
+    """The single kernel holds one head's dq, 4 * d bytes a position:
+    both cells' 4096 positions at d_head 128 fit the budget, a sequence
+    past it does not; a bias, position offsets, a returned logsumexp,
+    cross-attention and a ragged last block keep the two kernels.  A
+    traced backward says which it took, and `flash_dq` exists on the
+    two-kernel path only."""
+    import paddle_tpu.ops.pallas.flash_attention as fa
+
+    d = 128
+    edge = fa.FUSED_ACCUMULATOR_BUDGET // (4 * d)
+    fits = fa.fused_backward_fits
+    assert fits(4096, 4096, d, 256, 1024)
+    assert fits(4096, 4096, d, fa.DEFAULT_BWD_BLOCK_Q, fa.DEFAULT_BWD_BLOCK_K)
+    assert fits(edge, edge, d, 1024, 1024)
+    assert not fits(edge + 1024, edge + 1024, d, 1024, 1024)
+    assert fits(2 * edge, 2 * edge, d // 2, 1024, 1024)     # bytes, not T
+    assert not fits(4096, 4096, d, 256, 1024, bias=True)
+    assert not fits(4096, 4096, d, 256, 1024, offsets=True)
+    assert not fits(4096, 4096, d, 256, 1024, lse_cotangent=True)
+    assert not fits(4096, 2048, d, 256, 1024)               # cross
+    assert not fits(4000, 4000, d, 256, 1024)               # ragged
+    assert fits(320, 320, d, 512, 1024)         # one block, clamped to T
+
+    def kernels(t, n=1, h=2, **kw):
+        shape = jax.ShapeDtypeStruct((n, t, h * d), jnp.bfloat16)
+        bias = kw.pop("bias", None)
+
+        def loss(q, k, v):
+            out = fa.pallas_flash_attention(
+                q, k, v, bias, causal=True, layout="nthd", n_head=h, **kw)
+            return sum(jnp.sum(o.astype(jnp.float32))
+                       for o in jax.tree.leaves(out))
+
+        before = _snapshot()
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape, shape, shape).as_text(debug_info=True)
+        return (sorted(name for name in ("flash_fwd", "flash_dkv",
+                                         "flash_dq")
+                       if f"pallas_{name}" in text), *_took(before))
+
+    one = (["flash_dkv", "flash_fwd"], 1, 0)
+    two = (["flash_dkv", "flash_dq", "flash_fwd"], 0, 1)
+    assert kernels(4096) == one                         # ouro-4k's call
+    assert kernels(4096, n=4, h=16) == one              # olmoe-4k's
+    assert kernels(edge + 1024) == two
+    assert kernels(4096, bias=jnp.zeros((1, 1, 1, 4096))) == two
+    assert kernels(4096, q_offset=0, k_offset=0) == two
+    assert kernels(4096, return_lse=True) == two
+    assert kernels(320, block_q=128, block_k=256) == two
+
+
+def test_the_backward_pass_takes_its_own_blocks_where_they_divide_t():
+    """The statistics are block-free, so the backward pass has blocks
+    of its own; a sequence that is not a whole number of them keeps the
+    forward's, and a block size the caller gives holds for both
+    passes."""
+    import paddle_tpu.ops.pallas.flash_attention as fa
+
+    fq, fk = fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K
+    bq, bk = fa.DEFAULT_BWD_BLOCK_Q, fa.DEFAULT_BWD_BLOCK_K
+
+    def grids(t, **blocks):
+        shape = jax.ShapeDtypeStruct((1, t, 2 * 128), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(fa.pallas_flash_attention(
+                *a, causal=True, layout="nthd", n_head=2, **blocks)
+                .astype(jnp.float32)), argnums=(0, 1, 2)))(*[shape] * 3)
+        return [e.params["grid_mapping"].grid for e in jaxpr.eqns
+                if e.primitive.name == "pallas_call"]
+
+    def cdiv(a, b):
+        return -(-a // min(b, a))
+
+    t = 4096
+    assert grids(t) == [(2, cdiv(t, fq), cdiv(t, fk)),
+                        (2, cdiv(t, bk), cdiv(t, bq))]
+    assert grids(t, block_q=128, block_k=256) == [(2, 32, 16), (2, 16, 32)]
+    # 1280 = 5 x 256: ragged for a 1024-wide block, so the forward's
+    # blocks and the two kernels
+    assert grids(1280, block_q=None, block_k=1024) == [
+        (2, 5, 2), (2, 2, 5), (2, 5, 2)]
+
+
+def test_gradient_through_a_checkpointed_scan_body_equals_the_unrolled():
+    """`ouro-4k`'s form: the call inside a `jax.checkpoint` segment
+    inside a `lax.scan` body (its backward runs in the scan's transpose,
+    the forward kernel a second time).  The gradient equals that of the
+    Python loop without checkpoint, and ONE body is traced: one single
+    backward kernel whatever the trip count."""
+    import paddle_tpu.ops.pallas.flash_attention as fa
+
+    n, h, t, d, trips = 1, 2, 512, 128, 3
+    x0 = jax.random.normal(jax.random.PRNGKey(3), (n, t, h * d)) * 0.5
+    w = jax.random.normal(jax.random.PRNGKey(4), (3, h * d, h * d)) \
+        * (h * d) ** -0.5
+
+    def layer(x, w):
+        q, k, v = (x @ w[i] for i in range(3))
+        return x + fa.pallas_flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=256, layout="nthd",
+            n_head=h)
+
+    def scanned(x, w):
+        body = jax.checkpoint(layer)
+        x, _ = jax.lax.scan(lambda c, _: (body(c, w), None), x, None,
+                            length=trips)
+        return jnp.sum(x ** 2)
+
+    def unrolled(x, w):
+        for _ in range(trips):
+            x = layer(x, w)
+        return jnp.sum(x ** 2)
+
+    before = _snapshot()
+    got = jax.grad(scanned, argnums=(0, 1))(x0, w)
+    assert _took(before) == (1, 0)
+    before = _snapshot()
+    want = jax.grad(unrolled, argnums=(0, 1))(x0, w)
+    assert _took(before) == (trips, 0)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(
+            g, r, rtol=1e-5, atol=1e-5 * float(jnp.abs(r).max()))
+
+
 # -- helpers ---------------------------------------------------------------
 
 

@@ -137,6 +137,35 @@ def test_flash_registry_matches_dense_twin():
     assert rel < 0.05, (registry, dense, rel)
 
 
+@pytest.mark.parametrize("layout", ["nhtd", "nthd"])
+def test_single_backward_kernel_carries_dqs_count(layout):
+    """`flash_dkv` names the two-kernel path's dk / dv kernel (two
+    gradients out, or three with a bias's (nh, t_k, 1) column) and the
+    single backward kernel (dq, dk, dv): the second carries dq's
+    dense-equivalent work too, so with `flash_fwd` a step's count is
+    the dense twin's on both paths: six dots' worth (the recomputed
+    scores are never credited)."""
+    from paddle_tpu.ops.pallas import KERNEL_COSTS
+    from paddle_tpu.ops.pallas.flash_attention import attention_cost
+
+    n, h, t, d = 2, 4, 256, 128
+    x = ((n * h, t, d), 2) if layout == "nhtd" else ((n, t, h * d), 2)
+    stat, db = ((n * h, 8, t), 4), ((n * h, t, 1), 4)
+    bwd_in = [x, x, x, x, x, stat]
+    fwd = KERNEL_COSTS["flash_fwd"]([x, x, x], [x, stat])
+    one = KERNEL_COSTS["flash_dkv"](bwd_in, [x, x, x])
+    dkv = KERNEL_COSTS["flash_dkv"](bwd_in, [x, x])
+    dq = KERNEL_COSTS["flash_dq"](bwd_in, [x])
+    assert one[0] == dkv[0] + dq[0]
+    assert KERNEL_COSTS["flash_dkv"](bwd_in + [db], [x, x, db])[0] == dkv[0]
+    registry, _bytes = attention_cost(n * h, t, t, d)   # the dense twin's,
+    assert fwd[0] + one[0] == registry      # test_flash_registry_matches...
+    scores = n * h * t * t
+    assert 2 * d * 6 * scores <= fwd[0] + one[0] <= (2 * d * 6 + 16) * scores
+    # bytes: every operand and result once
+    assert one[1] == 2 * 8 * n * h * t * d + 4 * n * h * 8 * t
+
+
 def test_vocab_ce_registry_matches_dense_twin():
     from paddle_tpu.ops.pallas.vocab_ce import vocab_ce_cost
 
