@@ -311,11 +311,15 @@ def short_conv(input, filter_size, param_attr=None, name=None):
 
 
 def rope(input, n_head, theta=10000.0, offset=None, name=None,
-         interleave=False):
+         interleave=False, inv_freq=None, attention_factor=None):
     """Rotary position embedding of a head-grouped (N, T, n_head * D)
     projection (ops/decoder.py): rotate-half, or with `interleave` the
     pairs (2i, 2i + 1) of every head.  `offset`: a (1,) integer
-    variable, the position of the first row (0 if None)."""
+    variable, the position of the first row (0 if None).  Scaled RoPE:
+    `inv_freq`, D/2 frequencies in place of theta^(-2i/D), and
+    `attention_factor` on cos and sin, both host constants
+    (`ops.decoder.rope_frequencies` makes them of a config's
+    `rope_parameters`)."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     ins = {"X": [input]}
@@ -324,6 +328,10 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
     attrs = {"n_head": int(n_head), "theta": float(theta)}
     if interleave:
         attrs["interleave"] = True
+    if inv_freq is not None:
+        attrs["inv_freq"] = [float(f) for f in inv_freq]
+    if attention_factor is not None and float(attention_factor) != 1.0:
+        attrs["attention_factor"] = float(attention_factor)
     helper.append_op(type="rope", inputs=ins, outputs={"Out": [out]},
                      attrs=attrs)
     return out
@@ -1203,7 +1211,7 @@ def grid_sampler(x, grid, name=None):
 def flash_attention(q, k, v, bias=None, scale=None, causal=False,
                     use_pallas=None, sequence_parallel=False,
                     layout="nhtd", n_head=None, name=None,
-                    n_kv_head=None):
+                    n_kv_head=None, window=None):
     """Fused multi-head attention over (N, H, T, D) tensors (see
     ops/attention.py).  The TPU-native replacement for composing
     matmul+softmax+matmul by hand.  layout="nthd" + n_head takes the
@@ -1212,8 +1220,11 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
     kernel boundary (the ISSUE 8 layout).  `n_kv_head` < `n_head`
     (head-major only) is grouped-query attention: k, v are
     (N, T, n_kv_head*D), query head j reads key/value head
-    j // (n_head / n_kv_head), and the Pallas path (d_head 64,
-    ops/pallas/flash_gqa.py) never repeats them.  With sequence_parallel=True
+    j // (n_head / n_kv_head), and the Pallas paths (d_head 64,
+    ops/pallas/flash_gqa.py; 128, flash_attention.py) never repeat
+    them.  `window` W (head-major, causal, no bias): query i reads the
+    W newest keys of its prefix, i - W < j <= i; the Pallas kernels
+    skip the key blocks behind the window.  With sequence_parallel=True
     (or "ring" / "ulysses") and a CompiledProgram mesh that has an `sp`
     axis, the sequence dimension shards over sp and attention runs as
     ring attention (KV ppermute rotation) or Ulysses (head/sequence
@@ -1233,6 +1244,8 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
         attrs["n_kv_head"] = int(n_kv_head)
     if scale is not None:
         attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
     helper.append_op(type="flash_attention", inputs=ins,
                      outputs={"Out": [out]}, attrs=attrs)
     return out

@@ -9,7 +9,9 @@ builds, each chosen by the configuration's own keys (a key whose other
 values are not built yet raises):
 
 - operators: causal attention with grouped-query heads and QK-norm
-  (flash kernels at d_head 128 and 64); the gated short convolution;
+  (flash kernels at d_head 128 and 64), over the whole prefix or under
+  a sliding window, its RoPE unscaled or YaRN's, by layer type; the
+  gated short convolution;
   latent attention (`kv_lora_rank` ...: queries, keys and values out
   of low-rank latents, a rotary part beside the unrotated one, its own
   flash kernels);
@@ -63,6 +65,28 @@ passes them): `qk_norm` ("projection": over the whole projection, one
             recipe's `expert_bias_update_rate` moves that bias against
             each expert's load every step (balancing without an
             auxiliary loss); 0, the default, leaves it alone
+
+`head_dim` is a key of its own where a configuration has it: the q
+and o projections are `num_attention_heads * head_dim` wide and k, v
+`num_key_value_heads * head_dim`, whatever `hidden_size` is (absent:
+`hidden_size // num_attention_heads`).  `layer_types[i]` =
+"sliding_attention" is "full_attention" in which query i reads the
+`sliding_window` newest keys of its prefix, i - W < j <= i, its own
+included (the flash kernels skip the key blocks behind the window).
+`rope_parameters` is one flat group (every layer's) or one group a
+layer type; a group's `rope_type` "default" is theta^(-2i/D) and
+"yarn" the blend of kept and interpolated frequencies with
+`attention_factor` on cos and sin (`ops/decoder.py rope_frequencies`,
+on the host; the op takes them as attributes); any other raises.  In
+a program that has a window layer the two kinds' attention operators
+lower under the name scopes `sliding_attention` / `full_attention`.
+
+`embedding_init_range` (a recipe's): the std of the embedding table
+where it is not `initializer_range`, every matrix's.  A table of unit
+variance under small matrices keeps the residual stream what the token
+is; under one std for all, attention's averaging makes it ONE direction
+by the second layer and an untrained router sends every token to the
+same experts (PERF.md, PR 38).
 
 Latent attention (`kv_lora_rank`, `q_lora_rank`, `qk_nope_head_dim`,
 `qk_rope_head_dim`, `v_head_dim`; DeepSeek-V2, arXiv:2405.04434) takes
@@ -155,12 +179,14 @@ warm-up into a cosine decay to `lr_floor` of the peak.
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import numpy as np
 
 from .. import layers, optimizer
 from ..core.program import name_scope, recompute_scope
 from ..observe.monitoring import runtime_stats
+from ..ops.decoder import rope_frequencies
 from ..clip import GradientClipByGlobalNorm, set_gradient_clip
 from ..initializer import Normal
 from ..param_attr import ParamAttr
@@ -182,7 +208,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             rope_scaling=None, n_shared_experts=0,
             num_nextn_predict_layers=0, total_ut_steps=1,
             sandwich_norm=False, exit_gate=None, exit_entropy_weight=0.0,
-            recompute=None):
+            recompute=None, head_dim=None, sliding_window=None,
+            embedding_init_range=None):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
     labels' own successors, with a prediction module).  Returns a dict:
@@ -200,7 +227,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise NotImplementedError(f"qk_norm {qk_norm!r} is not built")
     if router not in ("softmax", "sigmoid"):
         raise NotImplementedError(f"router {router!r} is not built")
-    if hidden_size % num_attention_heads:
+    if head_dim is None and hidden_size % num_attention_heads:
         raise ValueError("hidden_size is not a whole number of heads")
     if num_attention_heads % num_key_value_heads:
         raise ValueError("num_attention_heads is not a multiple of "
@@ -241,21 +268,42 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise ValueError("latent attention has one key and value a query "
                          "head: num_key_value_heads is num_attention_heads")
     eps = norm_eps if rms_norm_eps is None else rms_norm_eps
-    theta = (rope_parameters or {}).get("rope_theta", rope_theta)
-    if rope_parameters and rope_parameters.get("rope_type",
-                                               "default") != "default":
-        raise NotImplementedError(
-            f"rope_type {rope_parameters['rope_type']!r} is not built")
-    if eps is None or theta is None:
-        raise ValueError("decoder needs rms_norm_eps or norm_eps, and "
-                         "rope_theta or rope_parameters")
     layer_types = list(layer_types or
                        ["full_attention"] * num_hidden_layers)
     if len(layer_types) != num_hidden_layers:
         raise ValueError(f"{len(layer_types)} layer_types for "
                          f"{num_hidden_layers} layers")
-    head_dim = hidden_size // num_attention_heads
+    windowed = "sliding_attention" in layer_types
+    if windowed and not sliding_window:
+        raise ValueError("a sliding_attention layer needs sliding_window")
+    if head_dim is None:
+        head_dim = hidden_size // num_attention_heads
+    q_size = num_attention_heads * head_dim
     kv_size = num_key_value_heads * head_dim
+    # `rope_parameters`: one flat group, or one a layer type
+    by_kind = rope_parameters and all(
+        isinstance(v, dict) for v in rope_parameters.values())
+    rotary = {}
+    for kind in ("full_attention", "sliding_attention"):
+        group = dict((rope_parameters.get(kind) if by_kind
+                      else rope_parameters) or {})
+        group.setdefault("rope_theta", rope_theta)
+        if group["rope_theta"] is None:
+            if kind in layer_types:
+                raise ValueError("decoder needs rope_theta or "
+                                 "rope_parameters")
+            continue
+        if group.get("rope_type", "default") == "default":
+            rotary[kind] = {"theta": group["rope_theta"]}
+        else:
+            inv_freq, factor = rope_frequencies(head_dim, **group)
+            rotary[kind] = {"theta": group["rope_theta"],
+                            "inv_freq": inv_freq,
+                            "attention_factor": factor}
+    if eps is None or not rotary:
+        raise ValueError("decoder needs rms_norm_eps or norm_eps, and "
+                         "rope_theta or rope_parameters")
+    theta = rotary.get("full_attention", {}).get("theta")
     expert_width = moe_intermediate_size or intermediate_size
     held = None
     if expert_parallel_size != 1:
@@ -278,17 +326,21 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             return layers.rms_norm(x, epsilon=eps, group_size=head_dim)
         return norm(x)
 
-    def attention(h):
-        q = layers.rope(norm_qk(proj(h, hidden_size, "attn_qkv")),
-                        num_attention_heads, theta)
-        k = layers.rope(norm_qk(proj(h, kv_size, "attn_qkv")),
-                        num_key_value_heads, theta)
-        v = proj(h, kv_size, "attn_qkv")
-        ctx = layers.flash_attention(q, k, v, causal=True, use_pallas=True,
-                                     layout="nthd",
-                                     n_head=num_attention_heads,
-                                     n_kv_head=num_key_value_heads)
-        return proj(ctx, hidden_size, "attn_out")
+    def attention(h, kind):
+        turn = rotary[kind]
+        # a program that mixes the two kinds tells their rows apart
+        with name_scope(kind) if windowed else contextlib.nullcontext():
+            q = layers.rope(norm_qk(proj(h, q_size, "attn_qkv")),
+                            num_attention_heads, **turn)
+            k = layers.rope(norm_qk(proj(h, kv_size, "attn_qkv")),
+                            num_key_value_heads, **turn)
+            v = proj(h, kv_size, "attn_qkv")
+            ctx = layers.flash_attention(
+                q, k, v, causal=True, use_pallas=True, layout="nthd",
+                n_head=num_attention_heads, n_kv_head=num_key_value_heads,
+                window=sliding_window if kind == "sliding_attention"
+                else None)
+            return proj(ctx, hidden_size, "attn_out")
 
     def latent_attention(h):
         heads = num_attention_heads
@@ -347,12 +399,17 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         return y
 
     def block(x, kind, dense):
-        if kind == "full_attention":
-            op = attention if kv_lora_rank is None else latent_attention
-        elif kind == "conv":
+        if kind == "conv":
             op = conv
-        else:
+        elif kind not in ("full_attention", "sliding_attention"):
             raise NotImplementedError(f"layer type {kind!r} is not built")
+        elif kv_lora_rank is None:
+            op = functools.partial(attention, kind=kind)
+        elif kind == "full_attention":
+            op = latent_attention
+        else:
+            raise NotImplementedError("latent attention under a window is "
+                                      "not built")
         post = norm if sandwich_norm else (lambda y: y)
         x = layers.elementwise_add(x, post(op(norm(x))))
         ffn = dense_ffn if dense else routed_ffn
@@ -442,8 +499,9 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     embed_name = "tok_embedding.w"
     x = layers.embedding(
         tokens, size=[vocab_size, hidden_size],
-        param_attr=ParamAttr(name=embed_name,
-                             initializer=Normal(0.0, initializer_range)))
+        param_attr=ParamAttr(name=embed_name, initializer=Normal(
+            0.0, initializer_range if embedding_init_range is None
+            else embedding_init_range)))
     feeds = ["tokens", "labels"]
     if loops:
         return dict(looped(x), aux=None, z=None, counts=[], experts=[],

@@ -592,6 +592,11 @@ def _instr_flops(module: HloModule, comp: Computation, instr: Instr,
 # --------------------------------------------------------------------------
 
 _PALLAS_SCOPE_RE = re.compile(r"pallas_([A-Za-z0-9_]+)")
+# the `cost_estimate` a `pallas_call` declared, as Mosaic's custom call
+# carries it in its backend_config
+_COST_ESTIMATE_RE = re.compile(
+    rb'"cost_estimate":\{"flops":"?(\d+)"?,"transcendentals":"?\d+"?,'
+    rb'"bytes_accessed":"?(\d+)"?')
 
 
 def _pallas_kernel_of(op_name: str) -> Optional[str]:
@@ -609,6 +614,9 @@ def _registry_cost(kernel: str, instr: Instr, operands: List[Instr]):
     fn = pallas_pkg.KERNEL_COSTS.get(kernel)
     if fn is None:
         return None
+    if fn == pallas_pkg.DECLARED_AT_CALL:
+        m = _COST_ESTIMATE_RE.search(instr.backend_config or b"")
+        return (float(m.group(1)), float(m.group(2))) if m else None
     op_shapes = [(tuple(o.shape.dims), o.shape.elem_bytes)
                  for o in operands]
     res = instr.shape

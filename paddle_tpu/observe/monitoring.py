@@ -69,6 +69,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_mla_backward_fused", "flash_mla_backward_split",
     "flash_gqa_backward_fused", "flash_gqa_backward_split",
     "flash_attention_backward_fused", "flash_attention_backward_split",
+    "flash_window_blocks_visited", "flash_window_blocks_allowed",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -138,6 +139,14 @@ class RuntimeStats:
         self.flash_gqa_backward_split = 0
         self.flash_attention_backward_fused = 0
         self.flash_attention_backward_split = 0
+        # key blocks the forward grid of the window kernel
+        # (`flash_attention.py`, a call with a `window`) visits for
+        # one head, over its query blocks, and those of them that hold
+        # a score the mask allows, summed over the calls traced: equal
+        # where the grid skips every block outside the band, T / window
+        # times apart where skipping is lost (delta() around a build)
+        self.flash_window_blocks_visited = 0
+        self.flash_window_blocks_allowed = 0
         # trips of the counted loops traced (`static_rnn` with a
         # `trip_count`): what a step runs of them (delta() around a
         # build: 4 where one stack runs 4 times), and what an early exit
@@ -199,6 +208,11 @@ class RuntimeStats:
         field = f"{family}_backward_{'fused' if fused else 'split'}"
         with self._lock:
             setattr(self, field, getattr(self, field) + 1)
+
+    def record_flash_window_blocks(self, visited: int, allowed: int):
+        with self._lock:
+            self.flash_window_blocks_visited += visited
+            self.flash_window_blocks_allowed += allowed
 
     def record_loop_trips(self, trips: int):
         with self._lock:

@@ -22,13 +22,22 @@ from ..core.registry import register_op
 from .common import first, opt_in, out
 
 
-def _xla_attention(q, k, v, bias, scale, causal):
+def _causal_mask(t_q, t_k, window=None):
+    """Query i reads keys j <= i, and with a `window` W only the W
+    newest of them, i - W < j <= i."""
+    mask = jnp.tril(jnp.ones((t_q, t_k), jnp.bool_))
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((t_q, t_k), jnp.bool_), -window)
+    return mask
+
+
+def _xla_attention(q, k, v, bias, scale, causal, window=None):
     logits = jnp.einsum("nhqd,nhkd->nhqk", q, k) * scale
     if bias is not None:
         logits = logits + bias
     if causal:
         t_q, t_k = logits.shape[-2], logits.shape[-1]
-        mask = jnp.tril(jnp.ones((t_q, t_k), jnp.bool_))
+        mask = _causal_mask(t_q, t_k, window)
         logits = jnp.where(mask, logits, -1e9)
     weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     o = jnp.einsum("nhqk,nhkd->nhqd", weights.astype(q.dtype), v)
@@ -36,7 +45,7 @@ def _xla_attention(q, k, v, bias, scale, causal):
 
 
 def _xla_attention_nthd(q, k, v, bias, scale, causal, n_head,
-                        n_kv_head=None):
+                        n_kv_head=None, window=None):
     """XLA composition over head-grouped (N, T, H*D) operands.  The
     4D views are free reshapes (minor-dim split/merge) and the einsums
     carry the head dim as a dot batch dim — XLA folds the operand
@@ -57,7 +66,7 @@ def _xla_attention_nthd(q, k, v, bias, scale, causal, n_head,
         logits = logits + bias
     if causal:
         t_kk = logits.shape[-1]
-        mask = jnp.tril(jnp.ones((t_q, t_kk), jnp.bool_))
+        mask = _causal_mask(t_q, t_kk, window)
         logits = jnp.where(mask, logits, -1e9)
     weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     o = jnp.einsum("nhqk,nkhd->nqhd", weights.astype(q.dtype), v4)
@@ -103,6 +112,17 @@ def flash_attention(ctx, ins, attrs):
     if scale is None:
         scale = head_dim ** -0.5
     causal = attrs.get("causal", False)
+    window = attrs.get("window", None)
+    if window is not None:
+        # the newest `window` keys of the causal prefix, the query's
+        # own included: self-attention with nothing beside q, k, v
+        if (not causal or bias is not None or layout != "nthd"
+                or attrs.get("sequence_parallel", False)
+                or k.shape[t_axis] != q.shape[t_axis]):
+            raise NotImplementedError(
+                "flash_attention: a window is causal head-major "
+                "self-attention with no Bias and no sequence_parallel")
+        window = int(window)
     if attrs.get("sequence_parallel", False):
         # long-context path: shard the sequence axis over the mesh's
         # sp axis and run ring attention (KV rotation via ppermute) or
@@ -195,10 +215,11 @@ def flash_attention(ctx, ins, attrs):
 
         o = pallas_flash_attention(
             q, k, v, bias, scale, causal, layout=layout, n_head=h_count,
-            n_kv_head=None if n_kv_head == h_count else n_kv_head)
+            n_kv_head=None if n_kv_head == h_count else n_kv_head,
+            **({} if window is None else {"window": window}))
     elif layout == "nthd":
         o = _xla_attention_nthd(q, k, v, bias, scale, causal, h_count,
-                                n_kv_head)
+                                n_kv_head, window)
     else:
         o = _xla_attention(q, k, v, bias, scale, causal)
     return out(Out=o)

@@ -64,6 +64,46 @@ def rope_angles(positions, head_dim, theta):
             * inv_freq.astype(np.float32)[None, :])
 
 
+def rope_frequencies(head_dim, rope_type="default", rope_theta=10000.0,
+                     factor=1.0, original_max_position_embeddings=None,
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=None,
+                     truncate=True, **_):
+    """(inv_freq (head_dim/2,) float64, scale on cos and sin) of a
+    config's `rope_parameters`, on the host.  "default":
+    theta^(-2i/D), scale 1.  "yarn" (Peng et al. 2023,
+    arXiv:2309.00071, as transformers' `_compute_yarn_parameters`
+    writes it): the frequencies that turn more than `beta_fast` times
+    within the original context are kept, those that turn fewer than
+    `beta_slow` times are divided by `factor`, and a linear ramp over
+    the dimensions between blends the two; cos and sin carry
+    `attention_factor` (0.1 ln factor + 1 where the config gives
+    none), so a score carries its square."""
+    extra = float(rope_theta) ** (-np.arange(0, head_dim, 2, dtype=np.float64)
+                                  / head_dim)
+    if rope_type == "default":
+        return extra, 1.0
+    if rope_type != "yarn":
+        raise NotImplementedError(f"rope_type {rope_type!r} is not built")
+
+    def turns_at(rotations):     # the dimension that turns so often
+        return (head_dim * np.log(original_max_position_embeddings
+                                  / (rotations * 2 * np.pi))
+                / (2 * np.log(float(rope_theta))))
+
+    low, high = turns_at(beta_fast), turns_at(beta_slow)
+    if truncate:
+        low, high = np.floor(low), np.ceil(high)
+    low, high = max(low, 0), min(high, head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    if attention_factor is None:
+        attention_factor = 0.1 * np.log(factor) + 1.0 if factor > 1 else 1.0
+    return (extra / factor * ramp + extra * (1.0 - ramp),
+            float(attention_factor))
+
+
 @register_op("rope")
 def rope(ctx, ins, attrs):
     """Rotary embedding over the whole head.  X is head-grouped
@@ -71,7 +111,10 @@ def rope(ctx, ins, attrs):
     (x[i], x[i + D/2]) of every head turns by pos * theta^(-2i/D)
     (rotate-half), or with `interleave` the pair (x[2i], x[2i + 1]).
     Offset (1,) is the position of X's first row (a decode step passes
-    the cache length); absent = 0."""
+    the cache length); absent = 0.  `inv_freq` (D/2 numbers, a host
+    constant as a checkpoint's buffer is) stands in for theta^(-2i/D),
+    and cos and sin are multiplied by `attention_factor`: scaled RoPE
+    (`rope_frequencies`)."""
     x = first(ins, "X")
     offset = opt_in(ins, "Offset")
     n_head = int(attrs["n_head"])
@@ -84,9 +127,20 @@ def rope(ctx, ins, attrs):
     pos = jnp.arange(t, dtype=jnp.int32)
     if offset is not None:
         pos = pos + offset.reshape(()).astype(jnp.int32)
-    ang = rope_angles(pos, d, theta)                  # (T, D/2)
+    inv_freq = attrs.get("inv_freq")
+    if inv_freq is None:
+        ang = rope_angles(pos, d, theta)              # (T, D/2)
+    else:
+        if len(inv_freq) != d // 2:
+            raise ValueError(f"rope: {len(inv_freq)} frequencies for a "
+                             f"head of {d}")
+        ang = (pos.astype(jnp.float32)[:, None]
+               * np.asarray(inv_freq, np.float32)[None, :])
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
+    factor = float(attrs.get("attention_factor") or 1.0)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     # a head as (2, D/2) halves, or as (D/2, 2) pairs
     pairs = attrs.get("interleave", False)
     xf = x.astype(jnp.float32).reshape(

@@ -52,6 +52,11 @@ def interpret() -> bool:
 # matches how these kernels stream HBM.  Each kernel module registers
 # its entries next to its DEFAULT_BLOCK_* tuning constants.
 KERNEL_COSTS = {}
+# Registered in a cost function's place by a kernel whose work no
+# operand's shape tells (a window's width): the call itself declares
+# it, `pallas_call(cost_estimate=pl.CostEstimate(...))` in the same
+# convention, and observe/cost.py reads it off the custom call.
+DECLARED_AT_CALL = "cost_estimate"
 
 
 def register_kernel_cost(name: str, fn):

@@ -110,6 +110,18 @@ DEFAULT_BLOCK_K = 1024
 # need not take the forward's)
 DEFAULT_BWD_BLOCK_Q = 1024
 DEFAULT_BWD_BLOCK_K = 1024
+# The band kernels' own (a window, grouped key/value heads), tuned on
+# v5e at 16384 positions, 32 / 4 heads of 128, alone (PERF.md, PR 38).
+# Their forward takes 1024 x 1024: 18.9 ms a call over the whole prefix
+# against 28.6 at the 256 x 1024 above and 21.9 at 512 x 1024; under a
+# window of 1024 keys 4.78 ms against 6.50, 5.26 and 6.20 at 512 x 512,
+# though half of what it then visits is masked.  The window's backward
+# takes 512 x 512 (7.19 ms; 8.52 at 1024 x 1024, 8.26 at 256 x 512),
+# the backward over the whole prefix the 1024 x 1024 above (36.7).
+DEFAULT_BAND_BLOCK_Q = 1024
+DEFAULT_BAND_BLOCK_K = 1024
+DEFAULT_WINDOW_BWD_BLOCK_Q = 512
+DEFAULT_WINDOW_BWD_BLOCK_K = 512
 NEG_INF = -1e30
 # The single backward kernel holds one head's dq, a whole sequence of
 # float32 (4 * d bytes a position).  It may take this much of v5e's
@@ -211,11 +223,14 @@ def attention_cost(nh, t_q, t_k, d, dtype_bytes=4):
 
 
 def _register_costs():
-    from . import register_kernel_cost
+    from . import DECLARED_AT_CALL, register_kernel_cost
 
     register_kernel_cost("flash_fwd", flash_fwd_cost)
     register_kernel_cost("flash_dkv", flash_dkv_cost)
     register_kernel_cost("flash_dq", flash_dq_cost)
+    # a call with a window: the band's pairs (`_Band.cost_estimate`)
+    for kernel in ("fwd", "dkv", "dq"):
+        register_kernel_cost("flash_window_" + kernel, DECLARED_AT_CALL)
 
 
 _register_costs()
@@ -264,11 +279,17 @@ def _tile(ref):
 # the folded (NH, 8, T) form in both layouts (kernel-internal
 # statistics, never touching the model's activation layout).
 
-def _tile_spec(block, d, layout, h, tsel):
+def _tile_spec(block, d, layout, h, tsel, group=1):
     """BlockSpec for a (1, block, d) q/k/v/o/do tile; `tsel` maps the
-    non-head grid axes (a, b) to the time block index."""
+    non-head grid axes (a, b) to the time block index.  `group` > 1
+    (head-major): the tile of the key/value head that query head g % h
+    reads, (g % h) // group."""
     from jax.experimental import pallas as pl
 
+    if group != 1:
+        return pl.BlockSpec(
+            (1, block, d),
+            lambda g, a, b: (g // h, tsel(a, b), (g % h) // group))
     if layout == "nthd":
         return pl.BlockSpec((1, block, d),
                             lambda g, a, b: (g // h, tsel(a, b), g % h))
@@ -287,7 +308,7 @@ def _stat_spec(block_q, tsel):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                t_k):
+                t_k, band=None):
     from jax.experimental import pallas as pl
 
     kb = pl.program_id(2)
@@ -301,9 +322,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
 
     qb = pl.program_id(1)
     q_off, k_off = _offs(offs_ref)
-    # causal: skip k-blocks strictly above the (offset) diagonal
-    run = (q_off + (qb + 1) * block_q > k_off + kb * block_k) \
-        if causal else True
+    step = kb
+    if band is None:
+        # causal: skip k-blocks strictly above the (offset) diagonal
+        run = (q_off + (qb + 1) * block_q > k_off + kb * block_k) \
+            if causal else True
+    else:
+        # the grid's last axis counts from the band's first key block
+        kb = band.first_k(qb) + kb
+        run = kb <= band.last_k(qb)
 
     @pl.when(run)
     def _compute():
@@ -326,6 +353,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
             q_pos = qb * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             valid = valid & (q_off + q_pos >= k_off + k_pos)
+            if band is not None and band.window:
+                valid = valid & (q_pos - k_pos < band.window)
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_scr[:]                 # (block_q, 1)
@@ -345,7 +374,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    @pl.when(kb == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -364,7 +393,12 @@ def _fwd_dims(q, k, layout, n_head):
 
 
 def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
-               layout, n_head):
+               layout, n_head, band=None, group=1):
+    """`band` (a causal self-attention call over whole blocks with a
+    window, or grouped key/value heads): the grid's last axis runs over
+    the key blocks of a query block's band alone, and the k / v index
+    maps stop at the diagonal's block, so a block outside the band
+    costs no compute and no DMA."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -372,12 +406,17 @@ def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
     h = n_head
     block_q = min(block_q, t_q)
     block_k = min(block_k, t_k)
-    grid = (nh, pl.cdiv(t_q, block_q), pl.cdiv(t_k, block_k))
+    if band is None:
+        grid = (nh, pl.cdiv(t_q, block_q), pl.cdiv(t_k, block_k))
+        k_time = lambda a, b: b     # noqa: E731
+    else:
+        grid = (nh, band.nq, band.k_steps)
+        k_time = band.k_time
 
     in_specs = [
         _tile_spec(block_q, d, layout, h, lambda a, b: a),
-        _tile_spec(block_k, d, layout, h, lambda a, b: b),
-        _tile_spec(block_k, d, layout, h, lambda a, b: b),
+        _tile_spec(block_k, d, layout, h, k_time, group),
+        _tile_spec(block_k, d, layout, h, k_time, group),
     ]
     args = [q, k, v]
     has_bias = bias is not None
@@ -401,15 +440,25 @@ def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
         of_r = ins[3 + has_bias] if has_offs else None
         _fwd_kernel(q_r, k_r, v_r, b_r, of_r, *outs, scale=scale,
                     causal=causal, block_q=block_q, block_k=block_k,
-                    t_k=t_k)
+                    t_k=t_k, band=band)
 
     if layout == "nthd":
         o_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     else:
         o_shape = jax.ShapeDtypeStruct((nh, t_q, d), q.dtype)
+    windowed = {}
+    if band is not None and band.window:
+        from ...observe.monitoring import runtime_stats
+
+        # a head's: the batch of a build-time shape inference is a
+        # placeholder
+        runtime_stats.record_flash_window_blocks(
+            band.nq * band.k_steps, band.blocks_allowed)
+        windowed = band.cost_estimate("fwd", nh, d, q.dtype.itemsize, group)
     o, lse8 = _pallas_call(
         kern,
-        name="flash_fwd",
+        name="flash_window_fwd" if windowed else "flash_fwd",
+        **windowed,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -481,7 +530,7 @@ def _runs(offs_ref, kb, qb, *, causal, block_q, block_k, **_):
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
               bias_ref, offs_ref, kb, qb, *, scale, causal, block_q,
-              block_k, t_q, t_k):
+              block_k, t_q, t_k, window=None):
     """(q, k, do, p, ds) of block pair (kb, qb): the tiles with their
     padding zeroed, and the float32 (block_k, block_q) p and ds every
     gradient is a dot of.  All three backward kernels take their terms
@@ -508,6 +557,8 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     valid = [k_pos < t_k] * bool(t_k % block_k) \
         + [q_pos < t_q] * bool(t_q % block_q) \
         + [q_off + q_pos >= k_off + k_pos] * bool(causal)
+    if window:
+        valid.append(q_pos - k_pos < window)
     p = jnp.exp(sT - lse_ref[0, 0][None, :])
     ds = p * (_dot(_tile(v_ref), do, ((1,), (1,)))
               - _delta_row(do, o, dlse_ref))
@@ -517,10 +568,10 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     return q, k, do, p, ds
 
 
-def _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, scale):
+def _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, scale, at=slice(None)):
     """The block pair's part of dk and dv into their float32 sums."""
-    dv_scr[:] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
-    dk_scr[:] += scale * _dot(ds.astype(q.dtype), q, ((1,), (0,)))
+    dv_scr[at] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+    dk_scr[at] += scale * _dot(ds.astype(q.dtype), q, ((1,), (0,)))
 
 
 def _add_dq(ds, k, dq_scr, scale, at=slice(None)):
@@ -874,6 +925,349 @@ def _flash_bwd_split(q, k, v, bias, offsets, o, lse8, do, dlse8, scale,
     return dq, dk, dv, dbias
 
 
+# -- the band: a window, grouped key/value heads ----------------------------
+#
+# A causal self-attention call, head-major, over whole blocks, in which
+# query i reads keys i - window < j <= i (window None: every j <= i)
+# and `group` query heads read one key/value head.  The grids below run
+# over the block pairs of the band alone: an axis counts from the
+# band's first block, and the index maps stop at its last, so a block
+# pair outside the band costs no compute and no DMA.  k, v, dk and dv
+# stay n_kv_head heads wide in HBM: query head a reads head a // group
+# through the index maps, and dk / dv sum over the group in VMEM.
+
+def _hi(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+def _lo(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+class _Band:
+    """The block geometry of such a call of `t` positions: which key
+    blocks a query block meets (`first_k` .. `last_k`, the diagonal's)
+    and which query blocks a key block (`first_q`, the diagonal's, ..
+    `last_q`), on Python ints or on grid indices alike."""
+
+    def __init__(self, t, block_q, block_k, window):
+        self.t, self.window = t, window
+        self.block_q, self.block_k = block_q, block_k
+        self.nq, self.nk = t // block_q, t // block_k
+        spans_k = [self.last_k(qb) - self.first_k(qb) + 1
+                   for qb in range(self.nq)]
+        # grid steps a query block takes over its key blocks, a key
+        # block over its query blocks; and the block pairs that hold
+        # an allowed score
+        self.k_steps = max(spans_k)
+        self.q_steps = max(self.last_q(kb) - self.first_q(kb) + 1
+                           for kb in range(self.nk))
+        self.blocks_allowed = sum(spans_k)
+
+    def first_k(self, qb):
+        if not self.window:
+            return 0
+        return _hi(qb * self.block_q - (self.window - 1), 0) // self.block_k
+
+    def last_k(self, qb):
+        return ((qb + 1) * self.block_q - 1) // self.block_k
+
+    def first_q(self, kb):
+        return (kb * self.block_k) // self.block_q
+
+    def last_q(self, kb):
+        if not self.window:
+            return self.nq - 1
+        return _lo(((kb + 1) * self.block_k + self.window - 2)
+                   // self.block_q, self.nq - 1)
+
+    def k_time(self, qb, step):
+        """The key block of a query block's `step`: past the diagonal
+        it stays there, and nothing new is fetched."""
+        return _lo(self.first_k(qb) + step, self.last_k(qb))
+
+    def q_time(self, kb, step):
+        return _lo(self.first_q(kb) + step, self.last_q(kb))
+
+    def dq_time(self, kb, step):
+        """The dq block last completed, or being completed, when key
+        block `kb` meets its `step`-th query block: a query block is
+        complete after the diagonal's key block, so ((kb + 1) *
+        block_k) // block_q of them are once pass kb ends.  The index
+        moves on only on the step that writes the next block, so no
+        half-summed block is ever what Pallas writes back."""
+        done = ((kb + 1) * self.block_k) // self.block_q
+        return _hi(_lo(self.first_q(kb) + step, done - 1), 0)
+
+    def pairs(self):
+        """Score pairs the mask allows, a head."""
+        w = min(self.window or self.t, self.t)
+        return w * self.t - w * (w - 1) // 2
+
+    def cost_estimate(self, kernel, nh, d, itemsize, group):
+        """`pallas_call`'s cost_estimate of a window kernel: the
+        registry's convention (dense-equivalent, recomputation not
+        credited) over the pairs the BAND allows, which no operand's
+        shape says; `observe/cost.py` reads it off the custom call."""
+        from jax.experimental import pallas as pl
+
+        dots, soft = {"fwd": (4.0, _SOFTMAX_FWD_PER_SCORE),
+                      "dq": (2.0, 0.375 * _SOFTMAX_BWD_PER_SCORE),
+                      "dkv": (6.0, 0.625 * _SOFTMAX_BWD_PER_SCORE),
+                      "bwd": (8.0, _SOFTMAX_BWD_PER_SCORE)}[kernel]
+        tiles = {"fwd": (2, 2), "dq": (4, 2), "dkv": (3, 4),
+                 "bwd": (4, 4)}[kernel]          # q-wide, k/v-wide
+        pairs = nh * self.pairs()
+        return {"cost_estimate": pl.CostEstimate(
+            flops=int(pairs * (dots * d + soft)), transcendentals=int(pairs),
+            bytes_accessed=int(nh * self.t * d * itemsize
+                               * (tiles[0] + tiles[1] / group)))}
+
+
+def band_backward_fits(t, d):
+    """Whether the backward pass of a band call is the single kernel:
+    from the shape alone.  It holds dq of one query head and dk, dv of
+    the key/value head it reads, whole sequences of float32 (12 * d
+    bytes a position: 24 MiB at 16384 x 128)."""
+    return t * d * 4 * 3 <= FUSED_ACCUMULATOR_BUDGET
+
+
+def _bwd_band_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+                     dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, band, group,
+                     **dims):
+    """The whole backward pass of a band call, grid (N*Hkv, group, k
+    blocks, query blocks of the key block's band): p and ds once a
+    block pair, dq, dk and dv from them.  dq of the query head sums
+    over the key blocks and dk, dv of the key/value head over the query
+    blocks AND the group's query heads, which lie outside the key
+    blocks (as `flash_gqa.py` sets out), so all three are held
+    full-length: `dq_acc` (nq, block_q, d), each block leaving on the
+    step that completes it, and `dk_acc`, `dv_acc` (nk, block_k, d),
+    leaving during the group's last head."""
+    from jax.experimental import pallas as pl
+
+    gi, kb, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    qb = band.first_q(kb) + step
+    run = qb <= band.last_q(kb)
+
+    @pl.when((gi == 0) & (step == 0))
+    def _init():
+        dk_acc[kb] = jnp.zeros(dk_acc.shape[1:], dk_acc.dtype)
+        dv_acc[kb] = jnp.zeros(dv_acc.shape[1:], dv_acc.dtype)
+
+    @pl.when(run & (kb == band.first_k(qb)))
+    def _init_dq():
+        dq_acc[qb] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+    @pl.when(run)
+    def _compute():
+        q, k, do, p, ds = _bwd_p_ds(
+            q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
+            kb, qb, **dims)
+        _add_dk_dv(p, ds, q, do, dk_acc, dv_acc, dims["scale"], at=kb)
+        _add_dq(ds, k, dq_acc, dims["scale"], at=qb)
+
+    @pl.when((gi == group - 1) & (step == band.q_steps - 1))
+    def _finalize():
+        dk_ref[0] = dk_acc[kb].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[kb].astype(dv_ref.dtype)
+
+    @pl.when(run & (kb == band.last_k(qb)))
+    def _finalize_dq():
+        dq_ref[0] = dq_acc[qb].astype(dq_ref.dtype)
+
+
+def _bwd_band_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         dk_ref, dv_ref, dk_scr, dv_scr, *, band, group,
+                         **dims):
+    """dk, dv of a band call past the single kernel's budget, grid
+    (N*Hkv, k blocks, group, query blocks of the band): blocks only."""
+    from jax.experimental import pallas as pl
+
+    kb, gi, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    qb = band.first_q(kb) + step
+
+    @pl.when((gi == 0) & (step == 0))
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(qb <= band.last_q(kb))
+    def _compute():
+        q, _, do, p, ds = _bwd_p_ds(
+            q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
+            kb, qb, **dims)
+        _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, dims["scale"])
+
+    @pl.when((gi == group - 1) & (step == band.q_steps - 1))
+    def _finalize():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_band_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+                        dq_scr, *, band, **dims):
+    """dq of a band call past the budget, on the forward's grid."""
+    from jax.experimental import pallas as pl
+
+    qb, step = pl.program_id(1), pl.program_id(2)
+    kb = band.first_k(qb) + step
+
+    @pl.when(step == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(kb <= band.last_k(qb))
+    def _compute():
+        _, k, _, _, ds = _bwd_p_ds(
+            q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
+            kb, qb, **dims)
+        _add_dq(ds, k, dq_scr, dims["scale"])
+
+    @pl.when(step == band.k_steps - 1)
+    def _finalize():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
+    """(dq, dk, dv) of a band call: one kernel where `band_backward_fits`,
+    the two that hold blocks only beyond; both take every term from
+    `_bwd_p_ds` and add it in the same order.  The kernels of a call
+    with a window run under names of their own (`flash_window_*`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ...observe.monitoring import runtime_stats
+
+    n, t, hd = q.shape
+    h, hkv, d = n_head, n_head // group, hd // n_head
+    bq, bk = band.block_q, band.block_k
+    fused = band_backward_fits(t, d)
+    runtime_stats.record_flash_backward("flash_attention", fused)
+    dims = dict(scale=scale, causal=True, block_q=bq, block_k=bk, t_q=t,
+                t_k=t, window=band.window)
+    item = q.dtype.itemsize
+
+    def name(kernel):
+        return ("flash_window_" if band.window else "flash_") + kernel
+
+    def cost(kernel):
+        if not band.window:
+            return {}
+        return band.cost_estimate(kernel, n * h, d, item, group)
+
+    dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dk_shape = jax.ShapeDtypeStruct(k.shape, q.dtype)
+
+    # grid (g = batch x key/value head, .., ..): which of the last
+    # three axes is the group's head, the key block and the step over
+    # its query blocks differs by kernel
+    def specs(order):
+        def at(i):
+            return lambda *ids: ids[1 + order.index(i)]
+
+        gi, kb, step = at("g"), at("k"), at("s")
+
+        def head(*ids):
+            return (ids[0] % hkv) * group + gi(*ids)
+
+        def q_tile(time):
+            return pl.BlockSpec(
+                (1, bq, d), lambda *ids: (ids[0] // hkv,
+                                          time(kb(*ids), step(*ids)),
+                                          head(*ids)))
+
+        def kv_tile(time):
+            return pl.BlockSpec(
+                (1, bk, d), lambda *ids: (ids[0] // hkv, time(*ids),
+                                          ids[0] % hkv))
+
+        stat = pl.BlockSpec(
+            (1, 8, bq), lambda *ids: (ids[0] * group + gi(*ids), 0,
+                                      band.q_time(kb(*ids), step(*ids))))
+        q_in, kv_in = q_tile(band.q_time), kv_tile(kb)
+        return ([q_in, kv_in, kv_in, q_in, q_in, stat], q_tile, kv_tile,
+                gi, kb)
+
+    args = (q, k, v, do, o, lse8)
+    if fused:
+        in_specs, q_tile, kv_tile, gi, kb = specs("gks")
+        kern = functools.partial(_bwd_band_kernel, band=band, group=group,
+                                 **dims)
+        # dk / dv leave during the group's last head, block by block
+        kv_out = kv_tile(lambda *ids: jnp.where(gi(*ids) == group - 1,
+                                                kb(*ids), 0))
+        return tuple(_pallas_call(
+            kern,
+            name=name("dkv"),
+            grid=(n * hkv, group, band.nk, band.q_steps),
+            in_specs=in_specs,
+            out_specs=[q_tile(band.dq_time), kv_out, kv_out],
+            out_shape=[dq_shape, dk_shape, dk_shape],
+            scratch_shapes=[pltpu.VMEM((band.nq, bq, d), jnp.float32),
+                            pltpu.VMEM((band.nk, bk, d), jnp.float32),
+                            pltpu.VMEM((band.nk, bk, d), jnp.float32)],
+            **_vmem_params(3 * t * d * 4, bq, bk), **cost("bwd"),
+        )(*args))
+
+    vmem = _vmem_params(0, bq, bk)
+    in_specs, _, kv_tile, _, kb = specs("kgs")
+    dk, dv = _pallas_call(
+        functools.partial(_bwd_band_dkv_kernel, band=band, group=group,
+                          **dims),
+        name=name("dkv"),
+        grid=(n * hkv, band.nk, group, band.q_steps),
+        in_specs=in_specs,
+        out_specs=[kv_tile(kb), kv_tile(kb)],
+        out_shape=[dk_shape, dk_shape],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        **vmem, **cost("dkv"),
+    )(*args)
+    # dq on the forward's grid (N*H, q blocks, key blocks of the band)
+    q_spec = _tile_spec(bq, d, "nthd", h, lambda a, b: a)
+    kv_spec = _tile_spec(bk, d, "nthd", h, band.k_time, group)
+    dq = _pallas_call(
+        functools.partial(_bwd_band_dq_kernel, band=band, **dims),
+        name=name("dq"),
+        grid=(n * h, band.nq, band.k_steps),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec,
+                  _stat_spec(bq, lambda a, b: a)],
+        out_specs=q_spec,
+        out_shape=dq_shape,
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        **vmem, **cost("dq"),
+    )(*args)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window):
+    return _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
+                           window)[0]
+
+
+def _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
+                    window):
+    o, lse8 = _flash_fwd(q, k, v, None, None, scale, True, *blocks, "nthd",
+                         n_head, _Band(q.shape[1], *blocks, window), group)
+    return o, (q, k, v, o, lse8)
+
+
+def _flash_band_bwd(scale, blocks, bwd_blocks, n_head, group, window, res,
+                    do):
+    q, k, v, o, lse8 = res
+    band = _Band(q.shape[1], *bwd_blocks, window)
+    dq, dk, dv = _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head,
+                                 group)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_flash_band.defvjp(_flash_band_fwd, _flash_band_bwd)
+
+
 # -- custom VJP -------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
@@ -911,11 +1305,24 @@ def _flash_vjp_bwd(scale, causal, blocks, bwd_blocks, layout, n_head,
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _band_blocks(t, block_q, block_k, window):
+    """(forward blocks, backward blocks) of a band call: a block size
+    given holds for both passes; left out, the band kernels take their
+    own (`DEFAULT_BAND_BLOCK_*`, `DEFAULT_WINDOW_BWD_BLOCK_*`)."""
+    own = ((DEFAULT_BAND_BLOCK_Q, DEFAULT_BAND_BLOCK_K),
+           (DEFAULT_WINDOW_BWD_BLOCK_Q, DEFAULT_WINDOW_BWD_BLOCK_K)
+           if window else (DEFAULT_BWD_BLOCK_Q, DEFAULT_BWD_BLOCK_K))
+    return tuple(
+        tuple(min(int(given or default), t)
+              for given, default in zip((block_q, block_k), pair))
+        for pair in own)
+
+
 def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
                            block_q=None, block_k=None,
                            q_offset=None, k_offset=None,
                            return_lse=False, layout="nhtd",
-                           n_head=None, n_kv_head=None):
+                           n_head=None, n_kv_head=None, window=None):
     """layout="nhtd" (default): q/k/v (N, H, T, D), output (N, H, T, D).
     layout="nthd": q/k/v (N, T, H*D) head-grouped — the head-major
     end-to-end contract; `n_head` is required and the batch*head fold
@@ -923,7 +1330,11 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
     kernel boundary.  bias: None or broadcastable (N, 1, 1, Tk) in
     either layout.  `n_kv_head` < `n_head` (head-major only): k, v are
     (N, T, n_kv_head*D) and query head j reads key/value head
-    j // (n_head / n_kv_head); they are never repeated.
+    j // (n_head / n_kv_head); they are never repeated.  `window` W
+    (head-major, causal): query i reads keys i - W < j <= i, its own
+    included; a window that holds every key is no window.  Both are
+    causal self-attention over whole blocks with nothing beside q, k,
+    v (the band kernels above); anything else with them raises.
 
     q_offset/k_offset: optional GLOBAL position offsets (python ints or
     traced scalars) applied in causal masking — ring attention passes the
@@ -955,23 +1366,34 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
         plain = (bias is None and causal and not return_lse
                  and q_offset is None and k_offset is None
                  and k.shape[1] == t_q)
-        if d == 64 and plain:
+        if window is not None and plain and window >= t_q:
+            window = None
+        if d == 64 and plain and window is None:
             from .flash_gqa import flash_gqa
 
             return flash_gqa(q, k, v, h, n_kv_head, scale)
-        if n_kv_head != n_head:
-            raise NotImplementedError(
-                f"grouped-query flash attention (ops/pallas/flash_gqa.py) "
-                f"is causal self-attention at d_head 64 with no bias, "
-                f"offsets or returned logsumexp; got d_head {d}, "
-                f"causal={causal}")
+        if n_kv_head != n_head or window is not None:
+            if window is not None and window < 1:
+                raise ValueError(f"window {window} holds no key")
+            blocks, bwd_blocks = _band_blocks(t_q, block_q, block_k, window)
+            if not plain or any(t_q % b for b in blocks + bwd_blocks):
+                raise NotImplementedError(
+                    f"flash attention with a window or grouped key/value "
+                    f"heads is causal self-attention over whole blocks "
+                    f"with no bias, offsets or returned logsumexp; got "
+                    f"causal={causal}, T_q {t_q}, T_k {k.shape[1]}, "
+                    f"blocks {blocks} / {bwd_blocks}")
+            return _flash_band(
+                q, k, v, float(d ** -0.5 if scale is None else scale),
+                blocks, bwd_blocks, int(h), int(h // n_kv_head),
+                None if window is None else int(window))
         t_k = k.shape[1]
         qf, kf, vf = q, k, v
     elif layout == "nhtd":
-        if n_kv_head is not None:
+        if n_kv_head is not None or window is not None:
             raise NotImplementedError(
-                "grouped-query flash attention is head-major "
-                "(layout='nthd')")
+                "flash attention with grouped key/value heads or a "
+                "window is head-major (layout='nthd')")
         n, h, t_q, d = q.shape
         t_k = k.shape[2]
         qf = q.reshape(n * h, t_q, d)

@@ -26,24 +26,27 @@ D = 128
 
 
 def _backward(one_chip, n, t, heads, dtype, d=D, layout="nthd", causal=True,
-              **blocks):
+              kv_heads=None, **blocks):
     """Compile forward + backward of one call for the described chip:
     ([kernel name, number of results] sorted by name, (fused, split))."""
     from paddle_tpu.observe import cost
 
     shape = (n, t, heads * d) if layout == "nthd" else (n, heads, t, d)
+    kv_shape = shape if kv_heads is None else (n, t, kv_heads * d)
 
     def loss(q, k, v):
         with jax.named_scope("flash_attention:9"):
             o = fa.pallas_flash_attention(
                 q, k, v, None, d ** -0.5, causal, layout=layout,
-                n_head=heads if layout == "nthd" else None, **blocks)
+                n_head=heads if layout == "nthd" else None,
+                n_kv_head=kv_heads, **blocks)
         return jnp.sum(o.astype(F32))
 
     before = runtime_stats.snapshot()
     compiled = _compile_args(
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-        *[jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)] * 3)
+        *[jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+          for s in (shape, kv_shape, kv_shape)])
     took = runtime_stats.delta(before)
     rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
     assert {r["op_type"] for r in rows if r["kernel"]} == {"flash_attention"}
@@ -113,3 +116,141 @@ def test_a_sequence_past_the_budget_compiles_the_two_kernels(one_chip):
     kernels, took = _backward(one_chip, 1, t, 1, BF16)
     assert kernels == [("flash_dkv", 2), ("flash_dq", 1), ("flash_fwd", 2)]
     assert took == (0, 1)
+
+
+# -- the band kernels: a window, grouped key/value heads --------------------
+#
+# (1, 16384) positions, 32 query heads over 4 key/value heads of 128:
+# what a `sliding_attention` (window 1024) and a `full_attention` layer
+# of `mellum2-16k` ask, 6 + 2 calls a step
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_the_window_cells_backward_pass_is_one_kernel(one_chip, window,
+                                                      dtype):
+    """Two custom calls a layer: the forward kernel and ONE backward
+    kernel (dq, dk, dv), which holds dq of a query head and dk, dv of
+    its key/value head full-length: 24 MiB at 16384 x 128, inside the
+    budget.  The window's kernels run under names of their own and
+    carry the band's cost (`cost_estimate`), not the causal half's;
+    dk and dv come out 4 heads wide."""
+    assert fa.band_backward_fits(16384, D)
+    kernels, took = _backward(one_chip, 1, 16384, 32, dtype, kv_heads=4,
+                              **({"window": window} if window else {}))
+    prefix = "flash_window_" if window else "flash_"
+    assert kernels == [(prefix + "dkv", 3), (prefix + "fwd", 2)]
+    assert took == (1, 0)
+
+
+def test_a_window_kernels_registered_cost_is_the_bands(one_chip):
+    from paddle_tpu.observe import cost
+
+    def loss(q, k, v):
+        with jax.named_scope("flash_attention:9"):
+            o = fa.pallas_flash_attention(
+                q, k, v, None, D ** -0.5, True, layout="nthd", n_head=32,
+                n_kv_head=4, window=1024)
+        return jnp.sum(o.astype(F32))
+
+    compiled = _compile_args(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        *[jax.ShapeDtypeStruct((1, 16384, h * D), BF16, sharding=one_chip)
+          for h in (32, 4, 4)])
+    rows = {r["kernel"]: r for r in cost.instruction_costs(
+        cost.compiled_hlo_proto(compiled)) if r["kernel"]}
+    pairs = 32 * (1024 * 16384 - 1024 * 1023 // 2)
+    assert rows["flash_window_fwd"]["flops"] == pairs * (4 * D + 8)
+    assert rows["flash_window_dkv"]["flops"] == pairs * (8 * D + 8)
+    # q, o and k, v a forward; q, do, o, dq and k, v, dk, dv a backward
+    assert rows["flash_window_fwd"]["bytes"] == 16384 * D * 2 * (
+        2 * 32 + 2 * 4)
+    assert rows["flash_window_dkv"]["bytes"] == 16384 * D * 2 * (
+        4 * 32 + 4 * 4)
+    # the causal half would be 8.3 x that
+    full = 32 * (16384 * 16385 // 2)
+    assert 8.2 < full / pairs < 8.3
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_a_band_call_past_the_budget_compiles_the_two_kernels(one_chip,
+                                                              window):
+    """32768 positions: 48 MiB of dq, dk and dv: a kernel for dk / dv
+    over the group's heads and one for dq, blocks only in VMEM."""
+    assert not fa.band_backward_fits(32768, D)
+    kernels, took = _backward(one_chip, 1, 32768, 32, BF16, kv_heads=4,
+                              **({"window": window} if window else {}))
+    prefix = "flash_window_" if window else "flash_"
+    assert kernels == [(prefix + "dkv", 2), (prefix + "dq", 1),
+                       (prefix + "fwd", 2)]
+    assert took == (0, 1)
+
+
+# -- the cells this file's kernels serve keep their steps -------------------
+#
+# sha256 of every existing cell's lowered step (`fn.lower(state,
+# feeds).as_text()`, the state by its shapes) on the CPU under this
+# suite's conftest (8 virtual devices, "highest" matmuls), jax 0.9.0, as
+# the commit before the band kernels gives it (PR 38: the kernels
+# `olmoe-4k` and `ouro-4k` call grew two arguments, the decoder
+# builder four; with `n_kv_head == n_head`, `window=None`, no
+# `head_dim` and one flat `rope_parameters` every step is the
+# parent's text).  A PR that means to change a step updates its line.
+STEP_TEXT = {
+    "tbase-256":
+    "59a1ce76e7b7759970f178a35b4cde1478f17086a0768c5142a1e93f2c1efe07",
+    "resnet50-b128":
+    "2c02c843a21d4dc13cc7419ed42fac09fbe5417671d362434b8837957e9e9d4b",
+    "olmoe-4k":
+    "473330796808045c71562781385f331caaacdaaa3611bf06d631209d901e5148",
+    "lfm2-8k":
+    "07601de3300a1e3f526b5e32368123b01ae99e2bd07453ba56341b5191abc75b",
+    "joyai-8k":
+    "763a665ebaff790b785b98be78745a908131bfb1dc5e2ffa745091d66f02a53d",
+    "ouro-4k":
+    "3681e31fdf4a35fde50a5163cece591bc623b0aa78be55d43077619c84122e4c",
+}
+
+
+def step_text(cell_name):
+    """The lowered text of a cell's one jitted step at the cell's own
+    sizes, built as `benchmarks/run.py` builds it, nothing run."""
+    import os
+    import sys
+
+    import numpy as np
+
+    import paddle_tpu as fluid
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run as bench_run
+
+    cell, config, family = bench_run.load_cell(cell_name, (bench,))
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        loss = family.build(config)
+        for var in main.global_block().vars.values():
+            if var.persistable and all(int(s) > 0 for s in var.shape):
+                scope.set_var(var.name, jax.ShapeDtypeStruct(
+                    tuple(int(s) for s in var.shape),
+                    np.dtype(str(var.dtype))))
+        batch = family.make_batch(config, dict(cell, chips=1),
+                                  np.random.default_rng(0))
+        step, state, feeds = fluid.Executor()._prepare(
+            main, batch, [loss.name], scope, 1, True)
+        return step.lower(state, feeds).as_text()
+
+
+@pytest.mark.parametrize("cell", sorted(STEP_TEXT))
+def test_every_existing_cells_step_is_the_parents_text(cell):
+    """`tbase-256-dp4` runs `tbase-256`'s Program under a mesh, which is
+    a placement of the same step (PR 28)."""
+    import hashlib
+
+    assert hashlib.sha256(step_text(cell).encode()).hexdigest() \
+        == STEP_TEXT[cell]

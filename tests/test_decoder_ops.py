@@ -78,6 +78,135 @@ def test_rope_scores_depend_on_the_distance_only():
         np.testing.assert_allclose(diag, diag[0], rtol=1e-4, atol=1e-4)
 
 
+PUBLISHED_YARN = dict(rope_type="yarn", rope_theta=500000, factor=16,
+                      original_max_position_embeddings=8192, beta_fast=32,
+                      beta_slow=1, attention_factor=1.2772588722239782)
+
+
+def test_rope_with_yarn_attributes_is_the_float64_rotation_at_16383():
+    """`inv_freq` and `attention_factor` arrive as the op's attributes
+    (host constants).  At the last of 16384 positions the rotation is
+    numpy float64's of the angle the op holds, float32(pos * inv_freq),
+    to 1e-6 of the scale: the frequencies are exact, which the chip's
+    own float32 `pow` would not give (PERF.md, PR 26).  Against the
+    angle in float64 it is good to 2e-3: a float32 angle of 16383 rad
+    is rounded to 1e-3, under the default frequencies alike (the
+    reference holds the same float32 angle)."""
+    from paddle_tpu.ops.decoder import rope_frequencies
+
+    heads, d, last = 2, 128, 16383
+    inv_freq, factor = rope_frequencies(d, **PUBLISHED_YARN)
+    assert inv_freq.dtype == np.float64 and inv_freq.shape == (64,)
+    x = R(4).normal(size=(1, 1, heads * d)).astype(np.float32)
+    got = run_op("rope", {"X": x, "Offset": np.array([last], np.int32)},
+                 {"n_head": heads, "theta": 5e5, "inv_freq": list(inv_freq),
+                  "attention_factor": factor})
+    # the frequencies as float32 holds them: a checkpoint's buffer
+    held = inv_freq.astype(np.float32).astype(np.float64)
+    x4 = x.reshape(1, 1, heads, d).astype(np.float64)
+    def rotated(angle):
+        z = (x4[..., :d // 2] + 1j * x4[..., d // 2:]) * factor \
+            * np.exp(1j * angle)
+        return np.concatenate([z.real, z.imag], -1).reshape(1, 1, heads * d)
+
+    angle32 = (np.float32(last) * inv_freq.astype(np.float32)).astype(
+        np.float64)
+    scale = np.abs(x).max() * factor
+    np.testing.assert_allclose(got, rotated(angle32), rtol=0,
+                               atol=1e-6 * scale)
+    np.testing.assert_allclose(got, rotated(last * held), rtol=0,
+                               atol=2e-3 * scale)
+    # ... and it is YaRN's: the slow frequencies turn 16 x less
+    plain = run_op("rope", {"X": x, "Offset": np.array([last], np.int32)},
+                   {"n_head": heads, "theta": 5e5})
+    assert np.abs(got / factor - plain)[..., 40:64].max() > 1e-2
+    np.testing.assert_allclose((got / factor)[..., :18], plain[..., :18],
+                               atol=3e-3)
+
+
+def test_rope_parameters_flat_or_a_layer_type_and_what_still_raises():
+    """One flat group (every layer's), or one a layer type; `yarn` is
+    built, any other type still raises; a `sliding_attention` layer
+    without `sliding_window` raises; `head_dim` sizes the projections."""
+    from paddle_tpu.models import decoder
+
+    base = dict(hidden_size=48, num_hidden_layers=2, num_attention_heads=4,
+                num_key_value_heads=2, intermediate_size=64, num_experts=0,
+                num_experts_per_tok=0, norm_topk_prob=False,
+                num_dense_layers=2, vocab_size=32, max_length=16,
+                rms_norm_eps=1e-6, qk_norm="head")
+    small_yarn = dict(PUBLISHED_YARN, original_max_position_embeddings=8)
+
+    def build(**over):
+        main = fluid.Program()
+        with fluid.program_guard(main, fluid.Program()), \
+                fluid.unique_name.guard():
+            decoder.decoder(**dict(base, **over))
+        return main
+
+    def ropes(main):
+        return [op.attrs for op in main.global_block().ops
+                if op.type == "rope"]
+
+    flat = ropes(build(rope_parameters={"rope_theta": 1e4,
+                                        "rope_type": "default"}))
+    assert len(flat) == 4 and all("inv_freq" not in a for a in flat)
+    scaled = ropes(build(rope_parameters=small_yarn, head_dim=16))
+    assert all(len(a["inv_freq"]) == 8 for a in scaled)
+    assert all(a["attention_factor"] == PUBLISHED_YARN["attention_factor"]
+               for a in scaled)
+    mixed = build(
+        layer_types=["sliding_attention", "full_attention"],
+        sliding_window=4, head_dim=16,
+        rope_parameters={"full_attention": small_yarn,
+                         "sliding_attention": {"rope_type": "default",
+                                               "rope_theta": 1e4}})
+    attrs = ropes(mixed)
+    assert ["inv_freq" in a for a in attrs] == [False, False, True, True]
+    windows = [op.attrs.get("window") for op in mixed.global_block().ops
+               if op.type == "flash_attention"]
+    assert windows == [4, None]
+    # head_dim 16 x 4 heads = 64 beside hidden_size 48
+    shapes = sorted(tuple(p.shape) for p in mixed.all_parameters()
+                    if "attn" in p.name)
+    assert shapes == [(48, 32)] * 4 + [(48, 64)] * 2 + [(64, 48)] * 2
+    with pytest.raises(NotImplementedError, match="rope_type 'llama3'"):
+        build(rope_parameters={"rope_theta": 1e4, "rope_type": "llama3"})
+    with pytest.raises(NotImplementedError, match="rope_type 'linear'"):
+        build(rope_parameters={
+            "full_attention": {"rope_theta": 1e4, "rope_type": "linear"}})
+    with pytest.raises(ValueError, match="needs sliding_window"):
+        build(layer_types=["sliding_attention", "full_attention"],
+              rope_theta=1e4)
+    with pytest.raises(ValueError, match="rope_theta or rope_parameters"):
+        build()
+
+
+def test_the_embedding_table_takes_a_range_of_its_own():
+    """`embedding_init_range` is the table's std alone; every matrix
+    keeps `initializer_range`; absent, the table has it too."""
+    from paddle_tpu.models import decoder
+
+    def stds(**over):
+        startup = fluid.Program()
+        with fluid.program_guard(fluid.Program(), startup), \
+                fluid.unique_name.guard():
+            decoder.decoder(
+                hidden_size=32, num_hidden_layers=1, num_attention_heads=2,
+                num_key_value_heads=2, intermediate_size=64, num_experts=0,
+                num_experts_per_tok=0, norm_topk_prob=False,
+                num_dense_layers=1, vocab_size=48, max_length=8,
+                rms_norm_eps=1e-6, rope_theta=1e4, **over)
+        return {op.output("Out")[0]: op.attrs["std"]
+                for op in startup.global_block().ops
+                if op.type == "gaussian_random"}
+
+    both = stds(initializer_range=0.002, embedding_init_range=1.0)
+    assert both.pop("tok_embedding.w") == 1.0
+    assert set(both.values()) == {0.002} and len(both) == 8
+    assert set(stds(initializer_range=0.002).values()) == {0.002}
+
+
 def test_swiglu_is_silu_times_gate():
     a = R(5).normal(size=(3, 7)).astype(np.float32)
     b = R(6).normal(size=(3, 7)).astype(np.float32)

@@ -190,12 +190,12 @@ def test_bf16_compute_fails_the_tolerance():
 
 
 @pytest.mark.parametrize("what, over", [
-    ("layer type", dict(layer_types=["conv", "sliding_attention", "conv"])),
+    ("layer type", dict(layer_types=["conv", "chunked_attention", "conv"])),
     ("qk_norm", dict(qk_norm="layer")),
     ("router", dict(router="tanh")),
     ("conv_bias", dict(conv_bias=True)),
     ("rope_type", dict(rope_parameters={"rope_theta": 1e6,
-                                        "rope_type": "yarn"})),
+                                        "rope_type": "llama3"})),
 ])
 def test_a_value_that_is_not_built_is_refused_not_guessed(what, over):
     with fluid.program_guard(fluid.Program(), fluid.Program()):
