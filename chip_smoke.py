@@ -8,7 +8,8 @@ width of the models bench.py measures, with random weights from a seed:
 
     python chip_smoke.py             one chip: dropout_mask,
                                      train_transformer,
-                                     train_resnet50, serve_decode
+                                     train_resnet50,
+                                     train_recompute, serve_decode
     python chip_smoke.py --chips 4   the four-chip host: dropout_mask
                                      over dp=4, then the same
                                      Transformer on one device and on
@@ -52,6 +53,15 @@ TRANSFORMER_WARMUP = 40
 STEPS = 8                # training steps per phase
 REQUESTS = 32            # decode requests in serve_decode
 RESNET_BATCH = 128
+# a decoder small enough to compile in seconds whose two layers are
+# recompute segments holding a flash call (4 heads of 128, the plain
+# kernel of ops/pallas/flash_attention.py); one packed sequence a step
+RECOMPUTE_DECODER = dict(
+    max_length=2048, hidden_size=512, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=4, intermediate_size=1024,
+    num_experts=0, num_experts_per_tok=0, norm_topk_prob=False,
+    num_dense_layers=2, vocab_size=4096, rope_theta=1e4, rms_norm_eps=1e-6,
+    recompute="layer", learning_rate=1e-3, warmup_steps=4)
 DECODER = dict(vocab_size=8192, n_layer=4, n_head=8, d_model=512,
                d_inner=1024, kv_dtype="bfloat16", seed=0)
 DECODE = dict(num_slots=16, page_size=16, max_len=512, num_pages=384,
@@ -112,11 +122,13 @@ def require_tpu(count):
 # --------------------------------------------------------------------------
 
 def _train(phase, build, feed, mesh_axes=None, inspect=None,
-           masks=(0, 0)):
+           masks=(0, 0), kept=(0, 0)):
     """Build under a fresh Program pair and run STEPS Executor steps
     on one fixed batch; the loss must be finite and fall, and no step
     after the first may compile.  `masks`: the `dropout` ops the step's
     build must count as drawn by (the Pallas kernel, jax.random).
+    `kept`: the attention calls whose residuals its recompute segments
+    keep, and their bytes.
     Over a mesh every step after the first must put its feeds and
     nothing else: the state lies where the step left it.
     `inspect(main, scope, loss, feed)` runs last, inside the guards;
@@ -176,6 +188,11 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None,
     assert drawn == masks, \
         f"{phase}: dropout masks by (kernel, jax.random) {drawn}, " \
         f"expected {masks}"
+    residuals = (cold["recompute_kept_residuals"],
+                 cold["recompute_kept_bytes"])
+    assert residuals == kept, \
+        f"{phase}: recompute segments keep (calls, bytes) {residuals}, " \
+        f"expected {kept}"
     assert losses[-1] < losses[0], \
         f"{phase}: loss did not fall over {STEPS} steps: {losses}"
     emit(phase, steps=STEPS, compiles=cold["compiles"],
@@ -185,6 +202,8 @@ def _train(phase, build, feed, mesh_axes=None, inspect=None,
          first_loss=losses[0], last_loss=losses[-1],
          dropout_masks_kernel=drawn[0], dropout_masks_xla=drawn[1],
          place_puts_per_step=placed[0], place_skips_per_step=placed[1],
+         recompute_kept_residuals=residuals[0],
+         recompute_kept_bytes=residuals[1],
          peak_bytes=peak_bytes(), **extra)
     del exe, scope, main, startup
     gc.collect()
@@ -228,6 +247,39 @@ def train_resnet50():
                                       class_dim=1000, learning_rate=0.01,
                                       use_amp=True),
            feed)
+
+
+def train_recompute():
+    """A decoder whose layers are recompute segments: each keeps its
+    flash kernel's output and logsumexp (`runtime_stats.
+    recompute_kept_*` around the step's build), and the compiled step
+    holds each layer's forward kernel once."""
+    from paddle_tpu.models import decoder
+
+    arch = RECOMPUTE_DECODER
+    t, width = arch["max_length"], arch["hidden_size"]
+    heads, depth = arch["num_attention_heads"], arch["num_hidden_layers"]
+    tokens = np.random.RandomState(0).randint(
+        0, arch["vocab_size"], (1, t + 1)).astype(np.int64)
+
+    def kernels(main, scope, loss, feed):
+        import paddle_tpu as fluid
+
+        text = fluid.Executor(fluid.TPUPlace(0)).compiled_step(
+            main, feed, [loss], scope=scope).as_text()
+        calls = [ln for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and " custom-call(" in ln]
+        found = {k: sum(f"pallas_{k}/" in ln for ln in calls)
+                 for k in ("flash_fwd", "flash_dkv")}
+        assert found == {"flash_fwd": depth, "flash_dkv": depth}, \
+            f"train_recompute: the step's flash kernels are {found}"
+        return {"flash_kernels": found}
+
+    # o in bf16 + 8 float32 sublanes of logsumexp a head, a layer
+    _train("train_recompute", lambda: decoder.build_model(**arch),
+           {"tokens": tokens[:, :-1], "labels": tokens[:, 1:]},
+           inspect=kernels,
+           kept=(depth, depth * (t * width * 2 + heads * 8 * t * 4)))
 
 
 # --------------------------------------------------------------------------
@@ -572,6 +624,7 @@ def main():
         dropout_mask()
         train_transformer()
         train_resnet50()
+        train_recompute()
         serve_decode()
     emit("done", total_s=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": device}), flush=True)

@@ -244,8 +244,12 @@ def _run_checkpointed_segment(seg_ops, env, rng_key, start_index,
     the segment reads enter as EXPLICIT arguments (closed-over tracers
     would be saved as residuals, defeating the remat); names it writes
     that someone downstream reads (`keep`; None = all) merge back into
-    env."""
+    env.  The backward pass keeps the segment's inputs and what an
+    attention kernel names (ops/pallas `keep_residuals`: its output and
+    logsumexp), and recomputes everything else."""
     import jax
+
+    from ..ops.pallas import segment_policy, tracing_segment
 
     read, written = [], set()
     read_set = set()
@@ -276,14 +280,15 @@ def _run_checkpointed_segment(seg_ops, env, rng_key, start_index,
     arr_set = set(arr_in)
     other_in = {n: env[n] for n in read if n not in arr_set}
 
-    @jax.checkpoint
+    @functools.partial(jax.checkpoint, policy=segment_policy())
     def seg_fn(rk, *vals):
         local = dict(other_in)
         local.update(zip(arr_in, vals))
-        for k, op in enumerate(seg_ops):
-            _run_one_op(op, local, rk, start_index + k,
-                        amp_lists=amp_lists, program=program,
-                        sparse_rows=sparse_rows)
+        with tracing_segment():
+            for k, op in enumerate(seg_ops):
+                _run_one_op(op, local, rk, start_index + k,
+                            amp_lists=amp_lists, program=program,
+                            sparse_rows=sparse_rows)
         return tuple(local[n] for n in out_names)
 
     results = seg_fn(rng_key, *(env[n] for n in arr_in))

@@ -151,7 +151,9 @@ trip, so their gradients are the sums over the trips.  Head, gate and
 cross-entropy run INSIDE the trip, so one head's logits are alive at a
 time (fetch `logits` and all R are kept; the training step keeps none).
 `recompute="layer"` wraps each layer pass and each trip's head in a
-`recompute_scope`: the backward pass keeps their inputs alone.  The
+`recompute_scope`: the backward pass keeps their inputs and the
+attention kernel's output and logsumexp, and recomputes the rest (the
+projections, norms, RoPE and the feed-forward or expert layer).  The
 loop lowers under the name scope `ut_loop`, a trip's head under
 `ut_loop/exit_head`.  With `total_ut_steps` 1 and no gate the stack is
 appended to the main block as ever.  (A serving-time key such as an

@@ -290,7 +290,9 @@ def transformer(src_vocab_size=10000, trg_vocab_size=10000, max_length=64,
     (experts sharded over mp/ep) and folds the load-balance aux losses
     into the objective with weight moe_aux_weight.  recompute=True
     wraps every encoder/decoder layer in fluid.recompute_scope
-    (activations rematerialized in the backward — HBM for FLOPs).
+    (activations rematerialized in the backward — HBM for FLOPs; what a
+    layer keeps is its input and, with flash_pallas, each attention
+    kernel's output and logsumexp).
     pipeline=True tags the encoder and decoder stacks as two
     fluid.pipeline_scope groups: on a mesh with a "pp" axis each stack
     runs as a GPipe schedule over the pp stages

@@ -70,6 +70,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_gqa_backward_fused", "flash_gqa_backward_split",
     "flash_attention_backward_fused", "flash_attention_backward_split",
     "flash_window_blocks_visited", "flash_window_blocks_allowed",
+    "recompute_kept_residuals", "recompute_kept_bytes",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -147,6 +148,15 @@ class RuntimeStats:
         # times apart where skipping is lost (delta() around a build)
         self.flash_window_blocks_visited = 0
         self.flash_window_blocks_allowed = 0
+        # attention calls traced inside a recompute segment, whose
+        # backward pass therefore keeps the kernel's two residuals and
+        # does not run its forward kernel again, and the bytes of those
+        # residuals (the output in the operands' dtype + 8 float32
+        # sublanes of logsumexp a head) from their shapes (delta()
+        # around a build; a loop's body counts once, as traced; 0
+        # where no segment holds a flash call)
+        self.recompute_kept_residuals = 0
+        self.recompute_kept_bytes = 0
         # trips of the counted loops traced (`static_rnn` with a
         # `trip_count`): what a step runs of them (delta() around a
         # build: 4 where one stack runs 4 times), and what an early exit
@@ -213,6 +223,11 @@ class RuntimeStats:
         with self._lock:
             self.flash_window_blocks_visited += visited
             self.flash_window_blocks_allowed += allowed
+
+    def record_kept_residuals(self, nbytes: int):
+        with self._lock:
+            self.recompute_kept_residuals += 1
+            self.recompute_kept_bytes += nbytes
 
     def record_loop_trips(self, trips: int):
         with self._lock:

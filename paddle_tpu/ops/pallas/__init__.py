@@ -39,6 +39,60 @@ def interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# What a recompute segment keeps besides its inputs: the two residuals
+# an attention kernel's forward rule hands its backward kernel, the
+# output and the logsumexp.  Attention is the one op of a layer whose
+# cost to recompute grows with the square of the length (or length x
+# window) while what it must keep grows with the length, so a segment
+# never runs a flash forward kernel a second time only to rebuild them.
+# ONE mechanism in two places: the forward rule of every attention
+# family names the pair through `keep_residuals`, and the executor's
+# `jax.checkpoint` saves exactly these names (`segment_policy`).  Names
+# without that policy are inert (a `name` equation lowers to nothing);
+# a family that does not name its residuals is recomputed.
+KEPT_RESIDUALS = ("attention_out", "attention_logsumexp")
+_open_segments = [0]
+
+
+def segment_policy():
+    """The `jax.checkpoint` policy of a recompute segment."""
+    import jax
+
+    return jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS)
+
+
+@contextlib.contextmanager
+def tracing_segment():
+    """Around the ops of a recompute segment WHILE `jax.checkpoint`
+    traces them.  That trace runs a `custom_vjp`'s primal function; its
+    forward rule is traced later, when the segment is differentiated
+    (for a loop's body after the executor has left the loop), which is
+    why the attention families' primal functions call their forward
+    rules: the call below sees the segment it is in."""
+    _open_segments[0] += 1
+    try:
+        yield
+    finally:
+        _open_segments[0] -= 1
+
+
+def keep_residuals(o, lse):
+    """Name an attention kernel's output and logsumexp where its
+    forward rule returns them as residuals; gives both back.  Inside a
+    segment's trace the call counts (`runtime_stats.
+    recompute_kept_residuals` / `_bytes`: a loop's body once, as
+    traced)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    if _open_segments[0]:
+        from ...observe.monitoring import runtime_stats
+
+        runtime_stats.record_kept_residuals(
+            sum(x.size * x.dtype.itemsize for x in (o, lse)))
+    return tuple(checkpoint_name(x, name)
+                 for x, name in zip((o, lse), KEPT_RESIDUALS))
+
+
 # kernel-name -> cost function registry (observe/cost.py injection
 # point).  A cost fn maps the custom call's actual operand/result
 # shapes to the kernel's DENSE-EQUIVALENT work:

@@ -102,6 +102,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import keep_residuals
+
 # Tuned on v5e (seq 2048, d 128): q=256/k=1024 beats the XLA-composed
 # attention; both dims are clamped to the actual sequence length.
 DEFAULT_BLOCK_Q = 256
@@ -1251,8 +1253,9 @@ def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window):
 
 def _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
                     window):
-    o, lse8 = _flash_fwd(q, k, v, None, None, scale, True, *blocks, "nthd",
-                         n_head, _Band(q.shape[1], *blocks, window), group)
+    o, lse8 = keep_residuals(*_flash_fwd(
+        q, k, v, None, None, scale, True, *blocks, "nthd", n_head,
+        _Band(q.shape[1], *blocks, window), group))
     return o, (q, k, v, o, lse8)
 
 
@@ -1273,15 +1276,14 @@ _flash_band.defvjp(_flash_band_fwd, _flash_band_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, bias, offsets, scale, causal, blocks, bwd_blocks,
            layout, n_head, with_lse):
-    o, lse8 = _flash_fwd(q, k, v, bias, offsets, scale, causal, *blocks,
-                         layout, n_head)
-    return (o, lse8) if with_lse else o
+    return _flash_vjp_fwd(q, k, v, bias, offsets, scale, causal, blocks,
+                          bwd_blocks, layout, n_head, with_lse)[0]
 
 
 def _flash_vjp_fwd(q, k, v, bias, offsets, scale, causal, blocks,
                    bwd_blocks, layout, n_head, with_lse):
-    o, lse8 = _flash_fwd(q, k, v, bias, offsets, scale, causal, *blocks,
-                         layout, n_head)
+    o, lse8 = keep_residuals(*_flash_fwd(
+        q, k, v, bias, offsets, scale, causal, *blocks, layout, n_head))
     out = (o, lse8) if with_lse else o
     return out, (q, k, v, bias, offsets, o, lse8)
 

@@ -75,6 +75,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import keep_residuals
 from .flash_attention import (_SOFTMAX_BWD_PER_SCORE, _SOFTMAX_FWD_PER_SCORE,
                               _io_bytes)
 
@@ -610,13 +611,13 @@ def _flash_bwd(q, k, v, o, lse8, do, scale, geo):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, scale, n_head, n_kv_head, blocks, bwd_blocks):
-    geo = _Geometry(q, k, n_head, n_kv_head, *blocks)
-    return _flash_fwd(q, k, v, scale, geo)[0]
+    return _flash_vjp_fwd(q, k, v, scale, n_head, n_kv_head, blocks,
+                          bwd_blocks)[0]
 
 
 def _flash_vjp_fwd(q, k, v, scale, n_head, n_kv_head, blocks, bwd_blocks):
     geo = _Geometry(q, k, n_head, n_kv_head, *blocks)
-    o, lse8 = _flash_fwd(q, k, v, scale, geo)
+    o, lse8 = keep_residuals(*_flash_fwd(q, k, v, scale, geo))
     return o, (q, k, v, o, lse8)
 
 

@@ -69,6 +69,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import keep_residuals
 from .flash_attention import (_SOFTMAX_BWD_PER_SCORE, _SOFTMAX_FWD_PER_SCORE,
                               _io_bytes)
 from .flash_gqa import NEG_INF, _causal, _dot, _of_head
@@ -614,13 +615,12 @@ def _flash_bwd(qn, qr, kn, kr, v, o, lse8, do, scale, geo):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash(qn, qr, kn, kr, v, scale, block_q, block_k):
-    geo = _Geometry(qn, qr, kn, kr, v, block_q, block_k)
-    return _flash_fwd(qn, qr, kn, kr, v, scale, geo)[0]
+    return _flash_vjp_fwd(qn, qr, kn, kr, v, scale, block_q, block_k)[0]
 
 
 def _flash_vjp_fwd(qn, qr, kn, kr, v, scale, block_q, block_k):
     geo = _Geometry(qn, qr, kn, kr, v, block_q, block_k)
-    o, lse8 = _flash_fwd(qn, qr, kn, kr, v, scale, geo)
+    o, lse8 = keep_residuals(*_flash_fwd(qn, qr, kn, kr, v, scale, geo))
     return o, (qn, qr, kn, kr, v, o, lse8)
 
 

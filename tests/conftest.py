@@ -29,3 +29,17 @@ jax.config.update("jax_default_matmul_precision", "highest")
 from paddle_tpu.observe import events as _observe_events  # noqa: E402
 
 _observe_events.set_strict_kinds(True)
+
+import pytest  # noqa: E402
+
+from paddle_tpu.observe.monitoring import runtime_stats  # noqa: E402
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _cold_runs_of_this_module_alone():
+    """`runtime_stats.cold_runs()` is the PROCESS's record, and a test
+    that picks "the start-up runs before my step" out of it
+    (`benchmarks/setup_anatomy.pick`) takes a start-up run that the
+    module before it left last for its own.  Which module that is
+    depends on which xdist worker was free: a module starts with none."""
+    runtime_stats._cold_runs.clear()

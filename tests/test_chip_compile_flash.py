@@ -185,29 +185,125 @@ def test_a_band_call_past_the_budget_compiles_the_two_kernels(one_chip,
     assert took == (0, 1)
 
 
+# -- a recompute segment keeps the forward kernel's residuals ---------------
+#
+# What `mellum2-16k` (a layer a segment, straight stack) and `ouro-4k`
+# (the segments inside the loop's body) hand the chip's compiler since
+# PR 39: a segment's backward pass rebuilds q, k, v from the
+# projections and reads the kernel's (o, logsumexp) where the forward
+# pass left them; no second forward kernel.
+
+def _state_by_shape(main, scope):
+    """Every persistable of `main` into `scope` as its shape and dtype:
+    a step can be prepared, lowered and compiled, nothing run."""
+    import numpy as np
+
+    for var in main.global_block().vars.values():
+        if var.persistable and all(int(s) > 0 for s in var.shape):
+            scope.set_var(var.name, jax.ShapeDtypeStruct(
+                tuple(int(s) for s in var.shape), np.dtype(str(var.dtype))))
+
+
+def _segment_kernels(one_chip, geometry, t, trips=0):
+    """Compile the step of ONE attention layer in a recompute segment
+    (`tests/test_recompute.py attention_stack`, bf16 AMP, hidden 256)
+    for the described chip: the compiled step's Pallas kernels by name,
+    the counters around the build, the compiled text."""
+    import paddle_tpu as fluid
+    from test_recompute import attention_stack
+
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        loss = attention_stack(geometry, t, 256, 1, trips)
+        main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
+        fetch = [loss.name] + [g.name for _, g in fluid.append_backward(loss)]
+        _state_by_shape(main, scope)
+        step, state, feeds = fluid.Executor()._prepare(
+            main, {"x": jnp.zeros((1, t, 256), F32)}, fetch, scope, 1, True)
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        before = runtime_stats.snapshot()
+        compiled = _compile_args(step, jax.tree.map(described, state),
+                                 jax.tree.map(described, feeds))
+        took = runtime_stats.delta(before)
+    text = compiled.as_text()
+    kernels = sorted(
+        re.search(r'op_name="[^"]*pallas_(\w+?)/', line).group(1)
+        for line in text.splitlines() if "tpu_custom_call" in line
+        and " custom-call(" in line and "pallas_" in line)
+    return kernels, took, text
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_a_layer_segments_gradient_is_one_forward_and_one_backward_kernel(
+        one_chip, window):
+    """A `sliding_attention` / `full_attention` layer of `mellum2-16k`
+    as a segment: 32 / 4 heads of 128, 1 x 16384."""
+    prefix = "flash_window_" if window else "flash_"
+    kernels, took, _ = _segment_kernels(
+        one_chip, (prefix + "fwd", D, 32, 4, window), 16384)
+    assert kernels == [prefix + "dkv", prefix + "fwd"]
+    assert took["recompute_kept_residuals"] == 1
+    # o in bf16 + 8 float32 sublanes of logsumexp a head: 151.0 MB
+    assert took["recompute_kept_bytes"] \
+        == 16384 * 32 * D * 2 + 32 * 8 * 16384 * 4 == 150994944
+
+
+def test_a_layer_segment_in_a_scans_body_is_one_forward_and_one_backward_kernel(
+        one_chip):
+    """A layer pass of `ouro-4k`: the plain kernel, 16 heads of 128,
+    1 x 4096, the segment inside a 4-trip scan.  The forward loop
+    stacks the kernel's residuals over the trips beside the segment's
+    input; the backward loop's body holds the backward kernel alone."""
+    kernels, took, text = _segment_kernels(
+        one_chip, ("flash_fwd", D, 16, 16, None), 4096, trips=4)
+    assert kernels == ["flash_dkv", "flash_fwd"]
+    assert took["recompute_kept_residuals"] == 1      # the body, once
+    assert took["recompute_kept_bytes"] \
+        == 4096 * 16 * D * 2 + 16 * 8 * 4096 * 4 == 18874368     # 18.9 MB
+    forward = [ln for ln in text.splitlines() if " while(" in ln][0]
+    assert "bf16[4,1,4096,2048]" in forward         # o, a trip each
+    assert "f32[4,16,8,4096]" in forward            # logsumexp
+
+
 # -- the cells this file's kernels serve keep their steps -------------------
 #
-# sha256 of every existing cell's lowered step (`fn.lower(state,
-# feeds).as_text()`, the state by its shapes) on the CPU under this
-# suite's conftest (8 virtual devices, "highest" matmuls), jax 0.9.0, as
-# the commit before the band kernels gives it (PR 38: the kernels
-# `olmoe-4k` and `ouro-4k` call grew two arguments, the decoder
-# builder four; with `n_kv_head == n_head`, `window=None`, no
-# `head_dim` and one flat `rope_parameters` every step is the
-# parent's text).  A PR that means to change a step updates its line.
+# sha256 of every cell's lowered step (`fn.lower(state, feeds).as_text()`,
+# the state by its shapes) on the CPU under this suite's conftest (8
+# virtual devices, "highest" matmuls), jax 0.9.0, with the running
+# number jax appends to private functions taken off (`_NUMBERED`:
+# `@argsort_115` -> `@argsort`; one more private function anywhere in
+# the process shifts every later number, which is all that naming the
+# flash residuals does to a step with no recompute segment).  The five
+# control cells' hashes are the PARENT's (the commit before PR 39),
+# computed on its checkout with the same regex: their Programs open no
+# segment, so their steps are the parent's text.  A PR that means to
+# change a step updates its line.
+_NUMBERED = re.compile(r"(@[A-Za-z_][\w.]*?)_\d+\b")
 STEP_TEXT = {
     "tbase-256":
-    "59a1ce76e7b7759970f178a35b4cde1478f17086a0768c5142a1e93f2c1efe07",
+    "87120efa12b45c7d69f7684350139b982024676c0a63adb4374351fd02c5a473",
     "resnet50-b128":
-    "2c02c843a21d4dc13cc7419ed42fac09fbe5417671d362434b8837957e9e9d4b",
+    "0f48812e8db4cbbab451ea81efcf5d14ebe463e612fac1d4887697bd27719340",
     "olmoe-4k":
-    "473330796808045c71562781385f331caaacdaaa3611bf06d631209d901e5148",
+    "90e5dcc6b4a0f75d65c8b5fbec2084a581c4f57c18d8169016d9741c12c210ae",
     "lfm2-8k":
-    "07601de3300a1e3f526b5e32368123b01ae99e2bd07453ba56341b5191abc75b",
+    "bef93476b1a65ba5540e2bc100b87d609bd0205a983c544fb63ee38b4e70d366",
     "joyai-8k":
-    "763a665ebaff790b785b98be78745a908131bfb1dc5e2ffa745091d66f02a53d",
+    "5e45cfedf796402c31d74a2ff5d4c5ed292a306fbc9f7dad1e666a147554e353",
+    # re-pinned, PR 39: the loop's segments keep (o, logsumexp), the
+    # backward body holds no forward kernel (parent: 83ada587..)
     "ouro-4k":
-    "3681e31fdf4a35fde50a5163cece591bc623b0aa78be55d43077619c84122e4c",
+    "feb20e0f77cac9b68fcfcbc630aa0a2d04a50249bddebc892a00584d8a915084",
+    # pinned for the first time, PR 39 (PR 38 left it out): the step
+    # with the eight layer segments keeping their kernels' residuals
+    # (parent: 9e3dc21c..)
+    "mellum2-16k":
+    "e1f3219f7419bb7acb5d13527df6d988e3a5bdc3119428cdb5229a18bd86f105",
 }
 
 
@@ -234,11 +330,7 @@ def step_text(cell_name):
     with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
             fluid.unique_name.guard():
         loss = family.build(config)
-        for var in main.global_block().vars.values():
-            if var.persistable and all(int(s) > 0 for s in var.shape):
-                scope.set_var(var.name, jax.ShapeDtypeStruct(
-                    tuple(int(s) for s in var.shape),
-                    np.dtype(str(var.dtype))))
+        _state_by_shape(main, scope)
         batch = family.make_batch(config, dict(cell, chips=1),
                                   np.random.default_rng(0))
         step, state, feeds = fluid.Executor()._prepare(
@@ -252,5 +344,5 @@ def test_every_existing_cells_step_is_the_parents_text(cell):
     a placement of the same step (PR 28)."""
     import hashlib
 
-    assert hashlib.sha256(step_text(cell).encode()).hexdigest() \
-        == STEP_TEXT[cell]
+    text = _NUMBERED.sub(r"\1", step_text(cell))
+    assert hashlib.sha256(text.encode()).hexdigest() == STEP_TEXT[cell]
