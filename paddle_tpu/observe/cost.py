@@ -767,6 +767,7 @@ def instruction_costs(proto, every_branch: bool = False
     from ..ops.pallas import dropout_mask as _dm  # noqa: F401
     from ..ops.pallas import flash_attention as _fa  # noqa: F401
     from ..ops.pallas import flash_mla as _fm  # noqa: F401 (and flash_gqa)
+    from ..ops.pallas import grouped_matmul as _gm  # noqa: F401
     from ..ops.pallas import paged_attention as _pa  # noqa: F401
     from ..ops.pallas import recurrence as _rc  # noqa: F401
     from ..ops.pallas import vocab_ce as _vc  # noqa: F401

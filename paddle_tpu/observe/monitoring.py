@@ -71,6 +71,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_attention_backward_fused", "flash_attention_backward_split",
     "flash_window_blocks_visited", "flash_window_blocks_allowed",
     "recompute_kept_residuals", "recompute_kept_bytes",
+    "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -157,6 +158,14 @@ class RuntimeStats:
         # where no segment holds a flash call)
         self.recompute_kept_residuals = 0
         self.recompute_kept_bytes = 0
+        # grouped matmuls of the dropless expert op traced
+        # (`ops/pallas/grouped_matmul.py`), by what the product's shape
+        # chose: the Pallas kernels, or `jax.lax.ragged_dot` where no
+        # tile divides a width (delta() around a build; a branch of a
+        # share's `switch` counts once, as traced, forward and again
+        # where the backward pass recomputes it)
+        self.grouped_matmuls_kernel = 0
+        self.grouped_matmuls_xla = 0
         # trips of the counted loops traced (`static_rnn` with a
         # `trip_count`): what a step runs of them (delta() around a
         # build: 4 where one stack runs 4 times), and what an early exit
@@ -228,6 +237,13 @@ class RuntimeStats:
         with self._lock:
             self.recompute_kept_residuals += 1
             self.recompute_kept_bytes += nbytes
+
+    def record_grouped_matmul(self, kernel: bool):
+        with self._lock:
+            if kernel:
+                self.grouped_matmuls_kernel += 1
+            else:
+                self.grouped_matmuls_xla += 1
 
     def record_loop_trips(self, trips: int):
         with self._lock:

@@ -14,10 +14,13 @@ capacity.  This op routes by sorting instead:
   each expert's rows are contiguous and in token order; rows are
   gathered into that order: `(T*k, D)`, a STATIC shape whatever the
   routing is, only the group sizes are data;
-- the three expert matmuls run over the sorted rows as
-  `jax.lax.ragged_dot` with the per-expert row counts as group sizes
-  (the TPU compiler lowers each to a grouped-matmul kernel of its own:
-  `T*k` rows of work, never `E x` dense);
+- the three expert matmuls run over the sorted rows as grouped matmuls
+  with the per-expert row counts as group sizes
+  (`ops/pallas/grouped_matmul.py`: Pallas kernels whose grid walks the
+  row tiles group by group, the rows' count being data, so the work is
+  the real rows', never `E x` dense and never the buffer's; a width
+  that is no multiple of 128 keeps `jax.lax.ragged_dot`, which the TPU
+  compiler lowers to a grouped-matmul kernel of its own);
 - rows are gathered back to token order and combined with the router
   weights.
 
@@ -51,9 +54,10 @@ third, `router_gradient`, is the caller's say over the backward pass):
 - `experts_held=(first, count)`: the layer holds ONE expert-parallel
   rank's share.  `GateW` stays (D, E) and routes over all E; W1, W3, W2
   are (count, ...), experts first..first+count-1.  The pairs are sorted
-  with the held experts first, their rows go through the ragged dots
-  with the held experts' counts as group sizes, every other row is
-  zero, and `Out` is the PARTIAL sum: what the absent experts would
+  with the held experts first, their rows go through the grouped
+  matmuls with the held experts' counts as group sizes, every other
+  row comes out of them as zero, and `Out` is the PARTIAL sum: what
+  the absent experts would
   have added is left out (the shares of all ranks add up to the whole
   layer: tests/test_expert_share.py).  The weights are normalised over
   all k chosen experts, held or not.  Shapes stay static, T*k rows
@@ -68,7 +72,7 @@ third, `router_gradient`, is the caller's say over the backward pass):
   experts, the op as it was.
 
   A share's real rows are the HEAD of the sorted order, so its
-  sorted-row section (gather, masks, the ragged dots, gate, combine)
+  sorted-row section (gather, the grouped matmuls, gate, combine)
   runs on a buffer of the rows it got, not of T*k: the section is
   traced at up to three static sizes (`row_buffer_sizes`: 1.5 and 3
   times the expected `T*k*count/E` rows, in whole 512s, and T*k) and
@@ -77,7 +81,8 @@ third, `router_gradient`, is the caller's say over the backward pass):
   capacity, and a routing that sends every row here costs what it did.
   The backward pass recomputes the section at the size taken and
   differentiates it there (`_switched`), so nothing of sorted-row size
-  is kept from the forward pass.  `RowBufferCountOut` =
+  is kept from the forward pass.  The grouped matmuls are the same
+  kernels in every branch, T*k included.  `RowBufferCountOut` =
   `RowBufferCount` (3,) + the one-hot of the size taken.
 
 `router_gradient=False` (its own attribute, tied to neither of the
@@ -101,6 +106,7 @@ import jax.numpy as jnp
 from ..core.registry import register_op
 from .common import first, opt_in
 from .decoder import silu_gate
+from .pallas.grouped_matmul import grouped_matmul, row_visits
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -195,21 +201,21 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _held_rows(rows, xf, w1, w3, w2, weights, order, back, counts):
+def _held_rows(rows, xf, w1, w3, w2, weights, order, back, counts, visits):
     """The sorted-row section of a layer that holds a share, on a
     buffer of `rows` rows (static; at least the held experts' rows):
-    (T, D) float32, the partial sum."""
+    (T, D) float32, the partial sum.  `visits`: the grouped matmuls'
+    tables over T*k rows, the same for every buffer."""
     k = weights.shape[1]
     n = jnp.sum(counts)
     head = order[:rows]
-    # rows past the held experts' belong to no group: a ragged dot
-    # says nothing of them, forward or backward, so they are zero
-    # going in (which zeroes their gradient) and coming out
-    mine = (jnp.arange(rows, dtype=jnp.int32) < n)[:, None]
-    xs = jnp.where(mine, _take_head(xf, head // k, back, n), 0)
-    h = silu_gate(jax.lax.ragged_dot(xs, w1, counts),
-                  jax.lax.ragged_dot(xs, w3, counts))
-    ys = jnp.where(mine, jax.lax.ragged_dot(h, w2, counts), 0)
+    # rows past the held experts' belong to no group: a grouped matmul
+    # writes them as zeros, forward and backward (the kernels as they
+    # store, no pass over (rows, D))
+    xs = _take_head(xf, head // k, back, n)
+    h = silu_gate(grouped_matmul(xs, w1, counts, visits),
+                  grouped_matmul(xs, w3, counts, visits))
+    ys = grouped_matmul(h, w2, counts, visits)
     return _combine(ys, weights, back, head, n)
 
 
@@ -347,11 +353,14 @@ def moe_dropless(ctx, ins, attrs):
     counts = jnp.sum(key[:, None] == jnp.arange(groups, dtype=jnp.int32),
                      axis=0, dtype=jnp.int32)       # no scatter
 
+    # what the grouped matmuls' grids walk: once a layer, for the
+    # three products, forward and backward, whatever the row buffer
+    visits = row_visits(counts, t * k)
     if held is None:
         xs = _permute(xf, (order // k).astype(jnp.int32), back, k)
-        h = silu_gate(jax.lax.ragged_dot(xs, w1, counts),
-                      jax.lax.ragged_dot(xs, w3, counts))
-        ys = jax.lax.ragged_dot(h, w2, counts)       # (T*k, D) sorted
+        h = silu_gate(grouped_matmul(xs, w1, counts, visits),
+                      grouped_matmul(xs, w3, counts, visits))
+        ys = grouped_matmul(h, w2, counts, visits)   # (T*k, D) sorted
         yk = _permute(ys, back, order.astype(jnp.int32), 1).reshape(t, k, d)
         y = jnp.sum(yk.astype(jnp.float32) * weights[..., None], axis=1)
     else:
@@ -359,7 +368,8 @@ def moe_dropless(ctx, ins, attrs):
         taken = jnp.sum(jnp.sum(counts) > jnp.asarray(sizes[:-1], jnp.int32),
                         dtype=jnp.int32)
         diff = (xf, w1, w3, w2, weights)
-        index = (order.astype(jnp.int32), back.reshape(t, k), counts)
+        index = (order.astype(jnp.int32), back.reshape(t, k), counts,
+                 visits)
         y = (_held_rows(t * k, *diff, *index) if len(sizes) == 1
              else _switched(sizes, taken, diff, index))
 

@@ -278,32 +278,33 @@ def test_a_layer_segment_in_a_scans_body_is_one_forward_and_one_backward_kernel(
 # number jax appends to private functions taken off (`_NUMBERED`:
 # `@argsort_115` -> `@argsort`; one more private function anywhere in
 # the process shifts every later number, which is all that naming the
-# flash residuals does to a step with no recompute segment).  The five
-# control cells' hashes are the PARENT's (the commit before PR 39),
-# computed on its checkout with the same regex: their Programs open no
-# segment, so their steps are the parent's text.  A PR that means to
-# change a step updates its line.
+# flash residuals does to a step with no recompute segment).  The
+# control cells' hashes are the PARENT's (PR 40: `tbase-256`,
+# `resnet50-b128` and `ouro-4k`, which run no expert op), computed on
+# its checkout with the same regex.  A PR that means to change a step
+# updates its line.
 _NUMBERED = re.compile(r"(@[A-Za-z_][\w.]*?)_\d+\b")
 STEP_TEXT = {
     "tbase-256":
     "87120efa12b45c7d69f7684350139b982024676c0a63adb4374351fd02c5a473",
     "resnet50-b128":
     "0f48812e8db4cbbab451ea81efcf5d14ebe463e612fac1d4887697bd27719340",
+    # the four cells with routed experts re-pinned, PR 40: their grouped
+    # matmuls are the kernels of `ops/pallas/grouped_matmul.py`, here
+    # through the interpreter (parents: 90e5dcc6.., bef93476..,
+    # 5e45cfed.., e1f3219f..)
     "olmoe-4k":
-    "90e5dcc6b4a0f75d65c8b5fbec2084a581c4f57c18d8169016d9741c12c210ae",
+    "edde7176fe33bb4d5429b1ced73b68db315c639ed19922b19640112a2b71fd8b",
     "lfm2-8k":
-    "bef93476b1a65ba5540e2bc100b87d609bd0205a983c544fb63ee38b4e70d366",
+    "f2f9d30e34e0e63d920f1c26bd672caef81a41e69fdbcfebdf582c6bafa6576a",
     "joyai-8k":
-    "5e45cfedf796402c31d74a2ff5d4c5ed292a306fbc9f7dad1e666a147554e353",
+    "46a623eb6f4691120a58f68448449341b589b0b1f23fce5dd2eaca924a43b904",
     # re-pinned, PR 39: the loop's segments keep (o, logsumexp), the
     # backward body holds no forward kernel (parent: 83ada587..)
     "ouro-4k":
     "feb20e0f77cac9b68fcfcbc630aa0a2d04a50249bddebc892a00584d8a915084",
-    # pinned for the first time, PR 39 (PR 38 left it out): the step
-    # with the eight layer segments keeping their kernels' residuals
-    # (parent: 9e3dc21c..)
     "mellum2-16k":
-    "e1f3219f7419bb7acb5d13527df6d988e3a5bdc3119428cdb5229a18bd86f105",
+    "692f610496a65734e5d2e1dccfa470e6af344cd1f6baea8c2723dcd8a95d0320",
 }
 
 
