@@ -3,8 +3,10 @@
 
 Drives the main path once on a TPU through the entry points a user
 calls (`import paddle_tpu as fluid`; Program/program_guard -> layers ->
-optimizer.minimize -> Executor.run; DecodeEngine.submit), at the full
-width of the models bench.py measures, with random weights from a seed:
+optimizer.minimize -> Executor.run; DecodeEngine.submit), at full model
+widths (the Transformer and ResNet-50 of `BENCHMARK.json`'s cells; a
+4-layer decoder of 8 heads x 64 behind the engine), with random weights
+from a seed:
 
     python chip_smoke.py             one chip: dropout_mask,
                                      train_transformer,
@@ -39,14 +41,15 @@ import time
 
 import numpy as np
 
-# the bench's full widths (bench.py bench_transformer / bench_resnet50 /
-# bench_serving_decode)
+# Transformer-base (Vaswani et al. 2017, Table 3: 6 layers, 8 heads,
+# d_model 512, FFN 2048, dropout 0.1; vocabulary 32000 a side) at batch
+# 64 x 256 positions, as benchmarks/configs/transformer-base.json has it
 TRANSFORMER = dict(src_vocab_size=32000, trg_vocab_size=32000,
                    max_length=256, n_layer=6, n_head=8, d_model=512,
                    d_inner_hid=2048, dropout=0.1, use_flash=True,
                    use_amp=True)
 TRANSFORMER_BATCH = 64
-# the lr schedule is not a width: the bench's 4000-step Noam warm-up
+# the lr schedule is not a width: the recipe's 4000-step Noam warm-up
 # moves nothing in a handful of steps, so every Transformer run here
 # warms up over 40 and must see its loss fall
 TRANSFORMER_WARMUP = 40

@@ -1,8 +1,8 @@
 """Where XLA's persistent compilation cache lives.
 
 One function, called by every entry point that compiles at real size
-(`chip_smoke.py`, `bench.py`, `benchmarks/run.py`) before its first
-compile.  A later run finds the cache only where the
+(`chip_smoke.py`, `benchmarks/run.py`) before its first compile.  A
+later run finds the cache only where the
 earlier one left it, so it is either where the environment says or at
 one fixed place in the checkout — never a temp name, a pid or a time.
 (The checkout's own path is part of every entry's key: a moved

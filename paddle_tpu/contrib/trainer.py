@@ -227,8 +227,8 @@ class Trainer:
         self.goodput_ledger = GoodputLedger()
         # blocking_ms/write_ms are READS of the goodput ledger's
         # checkpoint category / ckpt_write background channel — one
-        # source for the same milliseconds across train_end, bench and
-        # /metrics (the keys survive as aliases for perf_gate baselines)
+        # source for the same milliseconds across train_end and
+        # /metrics (the keys survive as aliases for their old readers)
         self.ckpt_stats = {"saves": 0, "blocking_ms": 0.0,
                            "write_ms": 0.0, "bytes": 0}
         self.validate_feed = bool(validate_feed)

@@ -280,8 +280,8 @@ def multiprocess_reader(readers, use_pipe=True, queue_size=1000):
 
 class Fake:
     """Cache the FIRST sample of a reader and replay it `data_num`
-    times (reference decorator.py:509 — frozen-feed speed testing;
-    bench.py's data_mode="frozen" is the device-side analog)."""
+    times (reference decorator.py:509 — frozen-feed speed testing: a
+    consumer timed over it pays no reader)."""
 
     def __init__(self):
         self.data = None

@@ -22,14 +22,14 @@ Three pillars (docs/OBSERVE.md):
 
 3. STRUCTURED RUN EVENTS — `RunEventLog` writes JSONL records with
    run-id/git-sha/backend/mesh provenance, consumed by
-   contrib.Trainer(telemetry=...) and bench.py.
+   contrib.Trainer(telemetry=...).
 
 4. COST ATTRIBUTION — `cost.py` walks the *optimized* HLO module with
    the same wire scanner, computing analytic per-instruction flops and
    materialized-buffer bytes, injecting the Pallas kernel cost
    registry at custom calls, and joining to fluid ops + measured
-   device time (`op_cost_table`); bench.py's Pallas MFU numerators
-   are built on it.
+   device time (`op_cost_table`); a Pallas-active step's FLOP count
+   is `total_costs` of it.
 
 5. MEMORY — `memory.py` parses the optimized module's buffer
    assignment (compiled.memory_analysis()), attributing every HBM
@@ -40,7 +40,7 @@ Three pillars (docs/OBSERVE.md):
    `plan_fit` — peak-HBM prediction for a candidate (batch, seq,
    dtype, remat) config from two small probe compiles, without ever
    compiling the candidate.  serving.ServingEngine validates its
-   bucket ladder with it; bench.py entries carry `mem_breakdown`.
+   bucket ladder with it; `step_mem_breakdown` is the one-dict form.
 
 7. PER-REQUEST TRACING + METRICS EXPORT — `reqtrace.py` threads a
    host-side `RequestTrace` (monotonic spans at queue boundaries

@@ -1,17 +1,17 @@
 """Per-op cost attribution: analytic flop/byte accounting over the
 *optimized* HLO module, joined to fluid ops and measured device time.
 
-Why XLA's own aggregates are not enough (the r05 roofline lesson):
+Why XLA's own aggregates are not enough:
 
 - `cost_analysis()["bytes accessed"]` OVERCOUNTS real HBM traffic —
   per-instruction estimates inside fusions are summed with utilization
-  heuristics, which produced the impossible r05 result of an MFU
-  "ceiling" (0.269) below an actually measured MFU (0.309).
-- Pallas custom calls report ZERO flops, forcing bench.py's
-  dense-twin workaround for every Pallas-active config.
-- The aggregate has no attribution: r05's longctx device profile found
-  ~15.9 s of copy/transpose against ~5.0 s of flash-kernel time only
-  by manual trace reading.
+  heuristics, so a roofline "ceiling" built on it can come out BELOW
+  a measured rate.
+- Pallas custom calls report ZERO flops, so a Pallas-active step's
+  count would otherwise need a dense twin program compiled beside it.
+- The aggregate has no attribution: copy/transpose time several times
+  the flash kernels' own is found in a device profile only by reading
+  the trace by hand.
 
 This module recomputes both sides analytically from the optimized
 HloModuleProto (read with trace.py's dependency-free wire scanner):
@@ -1048,10 +1048,10 @@ def op_cost_table(program=None, feed=None, fetch_list=None, scope=None,
 def layout_byte_share(proto: bytes) -> float:
     """Fraction of the step's modeled HBM traffic spent in the LAYOUT
     bucket (copy/transpose/bitcast-convert + layout-rooted fusions) —
-    the r05 longctx diagnostic as one number.  bench.py records it as
-    `layout_share` on every transformer/longctx entry and
-    tools/perf_gate.py gates its regression (--tol-layout-share), so
-    transpose traffic can never silently creep back."""
+    transpose traffic at kernel boundaries as one number, from a
+    compile alone.  The benchmark's reading of the same bucket is
+    MEASURED: `device_ms_per_step.layout`, the self time of those
+    instructions in a traced run (PERF.md section 3)."""
     rows = instruction_costs(proto)
     total = sum(r["bytes"] for r in rows if r["bucket"] != "noop")
     if not total:

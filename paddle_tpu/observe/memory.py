@@ -654,7 +654,7 @@ def resident_state_bytes(report: Dict[str, Any],
 
 def step_mem_breakdown(program=None, feed=None, fetch_list=None,
                        scope=None, exe=None) -> Dict[str, Any]:
-    """The one-dict summary bench.py entries carry: per-bucket byte
+    """`memory_report` as one flat dict: per-bucket byte
     sums + peak_bytes + source.  A program compiled over a REAL
     (multi-device) mesh reports its SHARDED step's per-device buffer
     assignment — the number that must fit each chip — instead of the

@@ -24,7 +24,7 @@ the serving stack threads a `RequestTrace` through:
   per trace are capped (`max_spans`, drops counted, never unbounded).
 - **exact phase aggregation regardless of sampling** — every finished
   trace folds its span durations into per-phase `LatencyHistogram`s
-  (`phase_summary()`), so bench.py's queue_wait/batch_form/dispatch/
+  (`phase_summary()`), so the queue_wait/batch_form/dispatch/
   join_wait percentiles are computed over ALL requests even at
   sample_rate=0.
 - **one timeline under chaos** — `export_chrome_trace()` renders the

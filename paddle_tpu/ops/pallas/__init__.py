@@ -98,7 +98,7 @@ def keep_residuals(o, lse):
 # shapes to the kernel's DENSE-EQUIVALENT work:
 #     fn(operand_shapes, result_shapes) -> (flops, bytes_or_None)
 # where each shapes list holds (dims_tuple, element_bytes) pairs.
-# "Dense-equivalent" is bench.py's standing MFU convention: the flop
+# "Dense-equivalent" is the standing MFU convention here: the flop
 # count of the logical math (what the non-Pallas composition would
 # compute ONCE) — skipped masked blocks are not credited and backward
 # recompute is not double-counted.  bytes None = use the default

@@ -90,7 +90,7 @@ DEFAULT_BWD_BLOCK_K = 1024
 NEG_INF = -1e30
 # what a backward kernel may claim of v5e's 128 MiB of VMEM (its blocks
 # of float32 scores and the single kernel's accumulators pass Mosaic's
-# default 16 MiB); the verdict is Mosaic's (tests/test_chip_compile.py)
+# default 16 MiB); Mosaic's verdict: tests/test_chip_compile_flash_attention.py
 _VMEM_LIMIT = 100 << 20
 # The single backward kernel holds float32 sums of a whole sequence: dq
 # of one query tile, dk and dv of its key/value tile, 3 x 128 lanes =

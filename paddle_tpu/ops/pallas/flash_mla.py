@@ -80,7 +80,7 @@ QK_DIM = NOPE_DIM + ROPE_DIM
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 # what a kernel may claim of v5e's 128 MiB of VMEM; the verdict is
-# Mosaic's (tests/test_chip_compile.py)
+# Mosaic's (tests/test_chip_compile_kernels.py)
 _VMEM_LIMIT = 100 << 20
 # The single backward kernel holds float32 accumulators of a pair's
 # whole sequence: dq_nope 256 + dq_rope 128 + dk_rope 128 lanes, 2 KiB a

@@ -67,7 +67,7 @@ MIN_BLOCK_ROWS = 16
 # `_kept_bytes` / `_contracted_bytes`, which count the pipeline's blocks
 # as Mosaic does and its temporaries from above (calibrated against the
 # compiler's own refusals); the verdict is Mosaic's
-# (tests/test_chip_compile.py)
+# (tests/test_chip_compile_cells.py)
 VMEM_BUDGET = (16 << 20) - (1 << 19)
 
 

@@ -1,7 +1,7 @@
 """Blocked fused LSTM recurrence kernel (Pallas, TPU).
 
-The scan-bound story (BENCH_r05: LSTM 0.078 MFU, nowhere near any
-roofline): `lax.scan` lowers one XLA while-iteration per timestep, so
+The scan-bound story (a stacked LSTM through `lax.scan` runs nowhere
+near any roofline): it lowers one XLA while-iteration per timestep, so
 every step pays loop bookkeeping, an HBM round-trip for the (N, H)
 carry, and a dynamic-slice/dynamic-update-slice pair on the stacked
 (T, ...) tensors — the per-step recurrent GEMM (N×H @ H×4H) is far too
@@ -46,7 +46,7 @@ import jax.numpy as jnp
 DEFAULT_BLOCK_T = 4
 
 # Mosaic gives a kernel 16 MiB of scoped VMEM unless told otherwise.
-# The backward at the bench shape (N=128, H=512, f32) takes 27 MiB at
+# The backward at the model width (N=128, H=512, f32) takes 27 MiB at
 # block_t=4 by the compiler's count (x and dx slabs are N*4H*4B = 1 MiB
 # per timestep each, W / dW / the dW accumulator 4 MiB each), so both
 # kernels raise the limit — a limit reserves nothing — to half of a

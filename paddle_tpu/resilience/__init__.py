@@ -12,7 +12,7 @@ failures): this subsystem survives them (docs/RESILIENCE.md):
   contrib.Trainer falling back to the newest *valid* serial,
 - `watchdog`: `Deadline` (SIGALRM guard for hung compiles/dispatches),
   `probe_backend` (subprocess init probe), `retry_call` (bounded
-  exponential backoff) — shared by bench.py, Trainer, ServingEngine,
+  exponential backoff) — shared by Trainer and ServingEngine,
 - the serving circuit breaker lives with its state machine in
   `paddle_tpu.serving.admission` (DEGRADED state, `CircuitBreaker`),
 - `preempt`: preemption tolerance — `SnapshotWriter` (async checkpoint

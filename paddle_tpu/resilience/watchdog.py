@@ -1,7 +1,7 @@
 """Watchdog + retry: deadline-guarded compile/dispatch and bounded
 exponential-backoff retries.
 
-Two lessons from bench.py as reusable machinery:
+Two lessons from measuring on a real chip as reusable machinery:
 
 - backend init can HANG, not just error — so `probe_backend` runs the
   init + one tiny matmul in a SUBPROCESS with a hard timeout; an
@@ -9,7 +9,7 @@ Two lessons from bench.py as reusable machinery:
   chip: only for callers that have not touched JAX),
 - a hung XLA compile/dispatch must become a recorded error, not eat
   the caller's whole budget — `Deadline` is the SIGALRM watchdog
-  bench.py wrapped each model in, now shared by bench, contrib.Trainer
+  around a guarded region, shared by contrib.Trainer
   (`step_deadline_s`) and `ServingEngine.start()` (warmup deadline).
 
 `Deadline` uses SIGALRM on the main thread and a TIMER-THREAD

@@ -1,0 +1,64 @@
+"""What the chip-compile tests share: dtypes, the compile helpers and
+the expert cells' shape table.  Not a test file.
+
+Interpret mode and `jax.export` (tests/test_pallas_lowering.py) both
+stop before Mosaic compiles: kernels that passed every such test were
+refused by the TPU's compiler for a lane slice not aligned to the
+tiling (paged attention) and for more scoped VMEM than a kernel may
+claim (fused LSTM).  The compiler is installed here and compiles for a
+chip that is DESCRIBED, not attached, so each kernel of the main path
+is compiled once for one v5e device at the width chip_smoke.py and the
+cells run it, and the compiled text must hold the Mosaic custom call.
+Nothing runs: this says nothing about results or times.
+
+The fixtures (`topology`, `one_chip`, `dp4_mesh`) are in
+tests/conftest.py.  The tests are tests/test_chip_compile_*.py: four
+files, not one, because `--dist loadfile` gives a file to ONE worker;
+and four, not one a kernel family, because that scheduler starts the
+files in the order of their NUMBER OF TESTS, largest first, so a file
+of two compiles that take two minutes each starts last and the run
+waits for it alone (PR 41 measured it: one file 892 s, nine files
+963 s, these four 821 s, on one machine).  A heavy family rides with a
+many-test light one: keep every file here at ten tests or so, or under
+half a minute.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import force_mosaic_lowering
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+# the sorted-row buffers of the four cells with routed experts: tokens,
+# experts a token, experts, experts held (None: all), D, H
+EXPERT_CELLS = {
+    "mellum2-16k": (16384, 8, 64, 8, 2304, 896),
+    "lfm2-8k": (8192, 4, 64, 8, 2048, 1536),
+    "joyai-8k": (8192, 8, 256, 8, 2048, 768),
+    "olmoe-4k": (4 * 4096, 8, 64, None, 2048, 1024),
+}
+
+
+def _compile_args(fn, *args):
+    """Compile an already-jittable `fn` for the described chip from
+    ShapeDtypeStruct arguments that carry its sharding."""
+    # conftest asks for "highest" matmul precision (f64 references);
+    # the program runs at the default, and Mosaic refuses an fp32
+    # contraction of bf16 operands
+    with force_mosaic_lowering(), jax.default_matmul_precision("default"):
+        return fn.lower(*args).compile()
+
+
+def _compile(fn, sharding, *specs):
+    """Compile `fn` for the described chip from (shape, dtype) specs
+    and return the compiled text."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in specs]
+    return _compile_args(jax.jit(fn), *args).as_text()
+
+
+def _kernels(text):
+    return text.count("tpu_custom_call")

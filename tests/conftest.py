@@ -43,3 +43,45 @@ def _cold_runs_of_this_module_alone():
     module before it left last for its own.  Which module that is
     depends on which xdist worker was free: a module starts with none."""
     runtime_stats._cold_runs.clear()
+
+
+# -- the chip's compiler for a DESCRIBED v5e (tests/chip_compile.py) --------
+# Described inside a module-scoped fixture (never at import: only one
+# process may hold the TPU library, and every xdist worker imports
+# every test file), in the test's own process, with the persistent
+# compilation cache off.
+
+@pytest.fixture(scope="module")
+def topology():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever the library raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def dp4_mesh(topology):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topology.devices).reshape(4), ("dp",))

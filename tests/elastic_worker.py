@@ -1,6 +1,6 @@
 """Elastic gang worker for the reshard-resume chaos harness
-(tests/test_gang.py::test_elastic_gang_shrinks_and_reshards and the
-run_ci.sh gang-chaos smoke; ISSUE 13 gang elasticity).
+(tests/test_gang.py::test_elastic_gang_shrinks_and_reshards; ISSUE 13
+gang elasticity).
 
 One rank of a supervised gang whose WORLD SIZE can shrink between
 attempts (Supervisor(elastic=True)): the worker sizes its VIRTUAL

@@ -1,6 +1,5 @@
 """Gang worker for the multi-process fault-tolerance chaos harness
-(tests/test_gang.py and the run_ci.sh gang-chaos smoke): one rank of a
-REAL supervised training gang.
+(tests/test_gang.py): one rank of a REAL supervised training gang.
 
 Launched by `resilience.Supervisor` (or tools/launch_gang.py), so it
 reads its identity from the PADDLE_TRAINER_ID / PADDLE_TRAINERS /

@@ -1,6 +1,6 @@
-"""Training worker for the crash-chaos harness (tests/test_preempt.py
-and the run_ci.sh crash-resume smoke): a REAL training subprocess the
-parent SIGKILLs/SIGTERMs at an arbitrary step and relaunches.
+"""Training worker for the crash-chaos harness (tests/test_preempt.py):
+a REAL training subprocess the parent SIGKILLs/SIGTERMs at an arbitrary
+step and relaunches.
 
 The job is deliberately loaded with every piece of state bit-exact
 resume must carry (docs/RESILIENCE.md):

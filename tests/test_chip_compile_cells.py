@@ -1,0 +1,352 @@
+"""The chip's compiler on what a cell's step hands it beside the attention
+kernels, at the published widths: `olmoe-4k`, `lfm2-8k`, the looped step
+of `ouro-4k`, and `ops/pallas/grouped_matmul.py` at the four expert
+cells' shapes (tests/chip_compile.py says why and how).
+"""
+
+from __future__ import annotations
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from chip_compile import BF16, EXPERT_CELLS, F32, I32, _compile_args
+
+
+def test_olmoe_step_kernels_at_the_published_shapes(one_chip):
+    """What `olmoe-4k`'s step hands the chip's compiler that no other
+    cell does, at OLMoE-1B-7B's widths (4 x 4096 tokens, 16 heads of
+    128, 64 experts of 2048 x 1024, 8 a token): the causal head-major
+    flash call with no bias, forward and backward, and the dropless
+    expert op, whose nine grouped matmuls are the Pallas kernels of
+    `ops/pallas/grouped_matmul.py` under the name `ragged_dot` (PR 40;
+    the TPU compiler's own lowering of `jax.lax.ragged_dot` before),
+    static shapes whatever the routing.  `observe.cost` must name every kernel and count T*k rows
+    of work for a grouped matmul, never E x dense."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.ops.pallas.flash_attention import \
+        pallas_flash_attention
+
+    n, t, heads, d, e, h, k = 4, 4096, 16, 128, 64, 1024, 8
+    hidden = heads * d
+
+    def attention(q, k_, v):
+        with jax.named_scope("flash_attention:9"):
+            o = pallas_flash_attention(q, k_, v, None, d ** -0.5, True,
+                                       layout="nthd", n_head=heads)
+        return jnp.sum(o.astype(F32))
+
+    compiled = _compile_args(
+        jax.jit(jax.grad(attention, argnums=(0, 1, 2))),
+        *[jax.ShapeDtypeStruct((n, t, hidden), BF16, sharding=one_chip)] * 3)
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    # the backward pass is ONE kernel at this shape (PR 37;
+    # tests/test_chip_compile_flash.py has its cases)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "flash_dkv", "flash_fwd"]
+    assert {r["op_type"] for r in rows if r["kernel"]} == {
+        "flash_attention"}
+
+    impl = get_op_impl("moe_dropless")
+
+    def experts(x, gate, w1, w3, w2):
+        with jax.named_scope("moe_dropless:12"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"X": [x], "GateW": [gate], "W1": [w1], "W3": [w3],
+                      "W2": [w2]}, {"top_k": k})
+        return (jnp.sum(o["Out"][0].astype(F32)) + o["AuxLoss"][0][0]
+                + o["ZLoss"][0][0])
+
+    shapes = [(n, t, hidden), (hidden, e), (e, hidden, h), (e, hidden, h),
+              (e, h, hidden)]
+    compiled = _compile_args(
+        jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3, 4))),
+        *[jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+          for s in shapes])
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    matmuls = [r for r in rows if r["kernel"] == "ragged_dot"]
+    assert len(matmuls) == 9            # 3 forward, 3 dX, 3 dW
+    per_matmul = 2.0 * n * t * k * hidden * h
+    assert {r["flops"] for r in matmuls} == {per_matmul}
+    assert {r["bucket"] for r in matmuls} == {"custom_call"}
+    assert not any(r["bucket"] == "matmul" and r["flops"] > per_matmul
+                   for r in rows)       # nothing E x dense beside them
+    totals = cost.total_costs(cost.compiled_hlo_proto(compiled))
+    assert totals["custom_calls"] == totals["pallas_matched"] >= 9
+    # the routing never reaches a shape: no dynamic dimension anywhere
+    assert "<=" not in compiled.as_text().split("ENTRY")[1].split("\n")[0]
+
+
+def _computation(text, name):
+    """The lines of computation `name` in a compiled module's text."""
+    body = text.split(f"\n%{name} (", 1)[1]
+    return body[:body.index("\n}\n")].split("\n")[1:]
+
+
+def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
+        one_chip, monkeypatch):
+    """What `lfm2-8k`'s step hands the chip's compiler beside the
+    attention kernels, at LFM2-24B-A2B's widths (1 x 8192 tokens, a
+    router over 64 experts, 8 of them held at 2048 x 1536, 4 a token).
+    The expert op that holds a share compiles to two `conditional`s,
+    forward and backward, of three branches: its sorted rows at 6144,
+    12288 and T*k = 32768 rows, eleven Mosaic grouped matmuls a size,
+    the kernels of `ops/pallas/grouped_matmul.py` under the
+    `moe_dropless` scope in every branch (PR 40; the compiler's own
+    lowering of `jax.lax.ragged_dot` before)
+    (three forward; backward the two up-projections again and six
+    more: the down-projection's result would serve the router's
+    gradient alone, which a program that runs a share holds back);
+    static shapes whatever the routing, nothing 64 experts wide but
+    the router; the smallest branch writes one T*k-row buffer each
+    way, the gather back to token order; and the plan needs less
+    memory than the section differentiated on T*k rows (a quarter less
+    before PR 40; an eighth since, the kernels having taken the masks'
+    buffers out of the section differentiated as it stands).  The gated
+    short convolution is XLA fusions with no kernel and no dot."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.ops import moe_dropless
+
+    t, hidden, e, held, h, k = 8192, 2048, 64, 8, 1536, 4
+    sizes = moe_dropless.row_buffer_sizes(t, k, e, held)
+    assert sizes == (6144, 12288, t * k)
+    impl = get_op_impl("moe_dropless")
+    attrs = {"top_k": k, "routing": "sigmoid", "norm_topk_prob": True,
+             "experts_held": [0, held], "router_gradient": False}
+
+    def experts(x, gate, bias, w1, w3, w2):
+        with jax.named_scope("moe_dropless:12"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"X": [x], "GateW": [gate], "Bias": [bias],
+                      "W1": [w1], "W3": [w3], "W2": [w2]}, attrs)
+        # not linear in Out: a share's routing weights are constants of
+        # the backward pass, and a linear loss would need no forward
+        return jnp.sum(jnp.sin(o["Out"][0].astype(F32)))
+
+    shapes = [((1, t, hidden), BF16), ((hidden, e), BF16), ((e,), F32),
+              ((held, hidden, h), BF16), ((held, hidden, h), BF16),
+              ((held, h, hidden), BF16)]
+
+    def compile_layer():
+        return _compile_args(
+            jax.jit(jax.grad(experts, argnums=(0, 1, 3, 4, 5))),
+            *[jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+              for s, d in shapes])
+
+    compiled = compile_layer()
+    text = compiled.as_text()
+    assert f"[{e},{hidden},{h}]" not in text     # no absent expert's weight
+    assert "<=" not in text.split("ENTRY")[1].split("\n")[0]
+    branches = [line.split("branch_computations={")[1].split("}")[0]
+                .replace("%", "").split(", ")
+                for line in text.split("\n") if " conditional(" in line]
+    assert [len(b) for b in branches] == [3, 3]  # forward, backward
+    for smallest, _, _ in branches:
+        lines = _computation(text, smallest)
+        assert not any(f"[{t * k},{h}]" in line.split(" = ")[1].split("(")[0]
+                       for line in lines if " = " in line)
+        wide = [line for line in lines if " = " in line and
+                f"[{t * k},{hidden}]" in line.split(" = ")[1].split("(")[0]]
+        assert len(wide) == 1 and " fusion(" in wide[0], wide  # the gather
+
+    proto = cost.compiled_hlo_proto(compiled)
+    every = cost.instruction_costs(proto, every_branch=True)
+    matmuls = [r for r in every if r["kernel"] == "ragged_dot"]
+    assert {r["bucket"] for r in matmuls} == {"custom_call"}
+    assert all(r["branch_of"] for r in matmuls)
+    # every branch's are the Pallas kernels, under the op's scope: the
+    # step holds no `ragged-dot` of the compiler's
+    assert {r["pallas_kernel"] for r in matmuls} == {"ragged_dot"}
+    assert {r["op_type"] for r in matmuls} == {"moe_dropless"}
+    assert "ragged-dot" not in text
+    by_size = {}
+    for r in matmuls:
+        by_size[r["flops"]] = by_size.get(r["flops"], 0) + 1
+    assert by_size == {2.0 * rows * hidden * h: 11 for rows in sizes}
+    # a table that sums to a step lists the heaviest branch alone
+    rows = cost.instruction_costs(proto)
+    assert [r["flops"] for r in rows if r["kernel"] == "ragged_dot"] == [
+        2.0 * t * k * hidden * h] * 11
+    inside = [r for r in every if r["branch_of"] and r["bucket"] in (
+        "elementwise", "layout", "matmul")]
+    assert inside and {r["op_type"] for r in inside
+                       if r["op_type"]} == {"moe_dropless"}
+
+    # the section on T*k rows, differentiated as it stands (the parent
+    # of PR 31): what the switch is measured against
+    monkeypatch.setattr(moe_dropless, "row_buffer_sizes",
+                        lambda t, k, e, count: (t * k,))
+    full = compile_layer()
+    assert " conditional(" not in full.as_text()
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < 0.9 * full.memory_analysis().temp_size_in_bytes
+    # and in bytes.  What plans the most is the catch-all's backward:
+    # the T*k rows, the two up-projections and three gradients as wide
+    # at once, which the compiler's own ragged dots took as fused
+    # operands and a kernel takes from HBM (713 MiB; 556 at the parent,
+    # whose section as it stands planned 994 to this one's 803).  No
+    # step's peak is there: `lfm2-8k` reads `hbm_peak_gb` 4.07 for the
+    # parent's 4.12 (PERF.md, PR 40)
+    assert temporaries <= 720 << 20
+
+    conv = get_op_impl("short_conv")
+
+    def short_conv(bcu, w):
+        with jax.named_scope("short_conv:7"):
+            o = conv(OpContext(jax.random.PRNGKey(0), 0),
+                     {"X": [bcu], "Filter": [w]}, {})
+        return jnp.sum(o["Out"][0].astype(F32))
+
+    compiled = _compile_args(
+        jax.jit(jax.grad(short_conv, argnums=(0, 1))),
+        jax.ShapeDtypeStruct((1, t, 3 * hidden), BF16, sharding=one_chip),
+        jax.ShapeDtypeStruct((hidden, 3), F32, sharding=one_chip))
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    assert not any(r["kernel"] for r in rows)
+    assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
+    assert {r["op_type"] for r in rows if r["op_type"]} == {"short_conv"}
+
+
+def test_a_looped_step_with_flash_kernels_in_the_scans_body(one_chip):
+    """What a looped decoder's step hands the chip's compiler that no
+    other cell does, at the published widths (1 x 4096 tokens, 16 heads
+    of 128, FFN 5632, the whole 49152-row head; depth cut to ONE layer
+    for the test's time, 4 trips as published): the Mosaic flash
+    kernels inside a `lax.scan`'s body and inside its transpose, each
+    layer pass and each trip's head a recompute segment in the body,
+    bf16 AMP.  The loops are counted (trip count 4), the body's
+    instructions are cost rows of their own under their kernels' names
+    and the `ut_loop` scope, the weights' bf16 copies are made outside
+    the loops, and the forward loop hands its transpose the segments'
+    inputs and the flash kernel's two residuals (PR 39), nothing else
+    of a layer."""
+    import numpy as np
+
+    import paddle_tpu as fluid
+    from paddle_tpu.models import decoder
+    from paddle_tpu.observe import cost, trace
+
+    t, d, dff, vocab, trips = 4096, 2048, 5632, 49152, 4
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        model = decoder.build_model(
+            max_length=t, hidden_size=d, num_hidden_layers=1,
+            num_attention_heads=16, num_key_value_heads=16,
+            intermediate_size=dff, num_experts=0, num_experts_per_tok=0,
+            norm_topk_prob=False, num_dense_layers=1, vocab_size=vocab,
+            rope_theta=1e6, rms_norm_eps=1e-6, total_ut_steps=trips,
+            sandwich_norm=True, qk_norm=None, exit_gate="sigmoid",
+            exit_entropy_weight=0.1, recompute="layer")
+        # the state by its shapes: no start-up run at this size
+        for var in main.global_block().vars.values():
+            if var.persistable and all(int(s) > 0 for s in var.shape):
+                scope.set_var(var.name, jax.ShapeDtypeStruct(
+                    tuple(int(s) for s in var.shape),
+                    np.dtype(str(var.dtype))))
+        feed = {k: jnp.zeros((1, t), jnp.int64)
+                for k in ("tokens", "labels")}
+        exe = fluid.Executor()
+        step, state, feeds = exe._prepare(
+            main, feed, [model["loss"].name], scope, 1, True)
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        compiled = _compile_args(step, jax.tree.map(described, state),
+                                 jax.tree.map(described, feeds))
+    assert [len(b.ops) for b in main.blocks][1] > 20     # ONE sub-block
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    loops = [r for r in rows if r["opcode"] == "while"]
+    assert [r["trip_count"] for r in loops] == [trips, trips]
+    assert all(r["bucket"] == "loop" and r["flops"] == 0 for r in loops)
+    inside = [r for r in rows if r["loop_of"]]
+    assert all(r["trips"] == trips for r in inside)
+    # the forward kernel in the forward loop and NOT again in the
+    # backward loop's recomputed layer pass (the segment keeps its
+    # output and logsumexp); the single backward kernel once
+    assert sorted(r["kernel"] for r in inside if r["kernel"]) == [
+        "flash_dkv", "flash_fwd"]
+    assert not [r for r in rows if r["kernel"] and not r["loop_of"]]
+    pmap = trace.program_map(proto)
+    for r in inside:
+        if r["kernel"]:
+            assert r["pallas_kernel"] and r["flops"] > 0
+            assert "ut_loop" in trace.name_scope_of(
+                pmap[r["name"]]["op_name"]).split("/")
+    # the loop's matmuls carry their FLOPs per call: a layer pass's
+    # seven products forward, again recomputed, twice that backward,
+    # and the head's three; nothing of them outside the loops
+    tokens = float(t)
+    layer = 2 * tokens * (4 * d * d + 3 * d * dff)
+    head = 2 * tokens * d * vocab
+    matmul = sum(r["flops"] for r in inside if r["bucket"] == "matmul")
+    assert matmul == pytest.approx(4 * layer + 4 * head, rel=0.02)
+    assert sum(cost.per_step(r, "flops") for r in rows
+               if r["bucket"] == "matmul") == pytest.approx(
+        trips * (4 * layer + 4 * head), rel=0.02)
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 2
+    # the weights' bf16 copies are loop-invariant: no float32 weight
+    # enters a loop's body to be cast there once a trip
+    module = cost.HloModule(proto)
+    bodies = [module.computations[c] for loop in module.entry.instructions
+              if loop.opcode == "while" for c in loop.called_ids]
+    weights = {(d, dff), (dff, d), (d, vocab)}
+    for body in bodies:
+        for instr in body.instructions:
+            if instr.opcode == "parameter":
+                continue
+            assert not (instr.opcode == "convert"
+                        and tuple(instr.shape.dims) in weights), instr.name
+    text = compiled.as_text()
+    forward = [ln for ln in text.splitlines() if " while(" in ln][0]
+    # what the forward loop saves for its transpose: the float32 input
+    # of the layer's segment and of the head's, stacked over the trips
+    # (2 x 134 MB) and the flash kernel's output and logsumexp (67 +
+    # 8.4 MB), not the layer's activations
+    assert forward.count(f"f32[{trips},1,{t},{d}]") == 2
+    assert forward.count(f"bf16[{trips},1,{t},{d}]") == 1
+    assert forward.count(f"f32[{trips},16,8,{t}]") == 1
+    assert f"[{trips},1,{t},{dff}]" not in forward
+    assert f"{t},{vocab}]" not in forward
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_grouped_matmul_kernels_at_the_cells_shapes(one_chip, cell):
+    """`ops/pallas/grouped_matmul.py`: forward, dX and dW at the tiles
+    its rule takes for a cell's up- and down-projection, within
+    Mosaic's default scoped VMEM (no `vmem_limit_bytes`): bf16 at the
+    smallest and the largest row buffer, float32 at the smallest.  The cost table
+    counts 2 x rows x K x N for each, dW by its result's rank."""
+    from paddle_tpu.observe import cost
+    from paddle_tpu.ops import moe_dropless
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    t, k, e, held, d, h = EXPERT_CELLS[cell]
+    groups = e if held is None else held
+    sizes = ((t * k,) if held is None
+             else moe_dropless.row_buffer_sizes(t, k, e, held))
+
+    def vjp(lhs, rhs, counts, ct):
+        out, pull = jax.vjp(lambda l, r: grouped_matmul(l, r, counts),
+                            lhs, rhs)
+        return (out,) + pull(ct)
+
+    cases = [(sizes[0], BF16), (sizes[-1], BF16), (sizes[0], F32)]
+    for rows, dtype in dict.fromkeys(cases):
+        for kk, nn in ((d, h), (h, d)):
+            compiled = _compile_args(jax.jit(vjp), *[
+                jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                for s, dt in (((rows, kk), dtype), ((groups, kk, nn), dtype),
+                              ((groups,), I32), ((rows, nn), dtype))])
+            assert "vmem_limit_bytes" not in compiled.as_text()
+            table = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+            kernels = [r for r in table if r["kernel"] == "ragged_dot"]
+            assert [r["pallas_kernel"] for r in kernels] == ["ragged_dot"] * 3
+            assert {r["flops"] for r in kernels} == {2.0 * rows * kk * nn}

@@ -1,11 +1,9 @@
 """The chip's compiler on `ops/pallas/flash_attention.py`'s backward
 pass, at the two cells' shapes and at the shape rule's edges.
 
-`tests/test_chip_compile.py` says why such tests exist and how they
-work (a v5e that is DESCRIBED, not attached; nothing runs), and lends
-its fixtures and helpers.  These cases are a file of their own because
-`--dist loadfile` gives a file to ONE worker, and that file is already
-the suite's longest.
+`tests/chip_compile.py` says why such tests exist and how they work (a
+v5e that is DESCRIBED, not attached; nothing runs) and holds the
+helpers; the fixtures are `tests/conftest.py`'s.
 """
 
 from __future__ import annotations
@@ -19,8 +17,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.observe.monitoring import runtime_stats
 from paddle_tpu.ops.pallas import flash_attention as fa
-from test_chip_compile import (BF16, F32, _compile_args,  # noqa: F401
-                               one_chip, topology)
+from chip_compile import BF16, F32, _compile_args
 
 D = 128
 

@@ -1,0 +1,233 @@
+"""The chip's compiler on the other kernel families, each alone: latent
+attention (`ops/pallas/flash_mla.py`) at `joyai-8k`'s shape, the fused
+vocabulary cross-entropy, paged attention, the fused LSTM recurrence;
+and the cost table over whole steps it compiled (every Mosaic kernel has
+a registered cost, the TPU's dots are matmul rows).  tests/chip_compile.py
+says why and how, and why these share a file.
+"""
+
+from __future__ import annotations
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import force_mosaic_lowering
+from chip_compile import BF16, F32, I32, I8, _compile, _compile_args, _kernels
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
+    """What `joyai-8k`'s step hands the chip's compiler that no other
+    cell does (1 x 8192 tokens, 32 heads of 128 unrotated + 64 rotary
+    lanes, values of 128, ONE rotary key head): the kernels of
+    `ops/pallas/flash_mla.py` through the `latent_attention` op, in the
+    cell's bfloat16 and in the parity script's float32 at "highest".  A
+    head's 64 rotary lanes are half a tile: the kernels block heads in
+    pairs, take the rotary key as a (rows, 64) block of the whole minor
+    dim, copy it across a tile's halves and fold its gradient's halves
+    in VMEM.  The rotary key and its gradient stay (N, T, 64) and v
+    stays 128 a head: nothing 32 x 192 wide exists.  At this length
+    the backward pass is ONE kernel, `flash_mla_dkv` grown by dq's two
+    dots, whose 17 MB of float32 accumulators (dq of a pair's whole
+    sequence, the rotary key's gradient) Mosaic must take in VMEM in
+    both dtypes; no partial of dq (32 heads x 192 = 6144 wide) reaches
+    HBM."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    n, t, heads = 1, 8192, 32
+    impl = get_op_impl("latent_attention")
+
+    def loss(q_nope, q_rope, k_nope, k_rope, v):
+        with jax.named_scope("latent_attention/latent_attention:9"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"QNope": [q_nope], "QRope": [q_rope],
+                      "KNope": [k_nope], "KRope": [k_rope], "V": [v]},
+                     {"n_head": heads, "use_pallas": True})["Out"][0]
+        return jnp.sum(o.astype(F32))
+
+    widths = (heads * 128, heads * 64, heads * 128, 64, heads * 128)
+    args = [jax.ShapeDtypeStruct((n, t, w), dtype, sharding=one_chip)
+            for w in widths]
+    prec = "default" if dtype == BF16 else "highest"
+    before = runtime_stats.snapshot()
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
+            .lower(*args).compile()
+    took = runtime_stats.delta(before)
+    assert (took["flash_mla_backward_fused"],
+            took["flash_mla_backward_split"]) == (1, 0)
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "flash_mla_dkv", "flash_mla_fwd"]
+    assert {r["op_type"] for r in rows if r["kernel"]} == {
+        "latent_attention"}
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 2
+    # dense-equivalent: scores 192 and values 128 forward; dv, dp 128
+    # and dk, dq 192 backward, 2 FLOP a lane
+    scores = n * heads * t * t
+    assert totals["pallas_flops"] >= 2 * (320 + 640) * scores
+    text = compiled.as_text()
+    assert f"[{n},{t},{heads * 192}]" not in text
+    if dtype == BF16:
+        assert f"bf16[{n},{t},64]" in text      # the rotary key's gradient
+
+
+def test_fused_vocab_ce_fwd_bwd(one_chip):
+    from paddle_tpu.ops.pallas.vocab_ce import fused_vocab_ce
+
+    tokens, d, vocab = 64 * 256, 512, 32000
+
+    def loss(hidden, w, labels):
+        return jnp.sum(fused_vocab_ce(hidden, w, labels, 0.1))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+                    ((tokens, d), BF16), ((d, vocab), BF16),
+                    ((tokens,), I32))
+    assert _kernels(text) >= 2
+
+
+# (S, H, d, P, page, maxp): chip_smoke's serve_decode geometry (16
+# slots, 8 heads x 64, 384 pages of 16, 512-token slots) and d_head 128
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("geom", [(16, 8, 64, 384, 16, 32),
+                                  (16, 4, 128, 384, 16, 32)],
+                         ids=["serve_decode", "d_head128"])
+def test_paged_attention(one_chip, geom, int8):
+    from paddle_tpu.ops.pallas.paged_attention import \
+        ragged_paged_attention
+
+    s, h, d, p, page, maxp = geom
+    pool = ((p, page, h * d), I8 if int8 else BF16)
+    specs = [((s, h * d), BF16), pool, pool, ((s, maxp), I32),
+             ((s,), I32)]
+    if int8:
+        specs += [((p, page, 1), F32)] * 2
+
+    def fn(q, k, v, pt, ln, ks=None, vs=None):
+        return ragged_paged_attention(q, k, v, pt, ln, n_head=h,
+                                      k_scales=ks, v_scales=vs)
+
+    assert _kernels(_compile(fn, one_chip, *specs)) == 1
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+def test_fused_lstm_fwd_bwd(one_chip, dtype):
+    """The stacked LSTM's width (N=128, H=512; it builds f32): the
+    backward takes 27 MiB of VMEM at the default time block, over
+    Mosaic's 16 MiB default; the kernel raises the limit."""
+    from paddle_tpu.ops.pallas.recurrence import fused_lstm
+
+    n, t, hid = 128, 128, 512
+
+    def loss(x, w):
+        hs, _cs, _h, c_last = fused_lstm(x, w)
+        return jnp.sum(hs.astype(F32)) + jnp.sum(c_last.astype(F32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+                    ((n, t, 4 * hid), dtype), ((hid, 4 * hid), dtype))
+    assert _kernels(text) == 2
+
+
+def test_fused_lstm_never_blocks_shape_inference():
+    """Build-time shape inference traces the kernel with a huge
+    stand-in batch and must get its shapes: the kernel leaves the VMEM
+    verdict to Mosaic at compile time and raises nothing before (an
+    early raise left the LSTM layer's output shapeless and the next
+    fc's weight (1, 4H) — found on the chip, PR 21)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import stacked_dynamic_lstm as lstm
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        lstm.build_model(max_len=16, use_amp=False, pallas_rnn=True)
+    assert main.global_block().var("lstm_0.tmp_0").shape == (-1, 16, 512)
+
+
+def test_kernel_cost_registry_covers_a_whole_step_on_the_tpu(one_chip):
+    """The stacked-LSTM train step with the fused kernel, compiled for
+    the chip: every Mosaic kernel in it has a registered cost, and the
+    TPU compiler's own bookkeeping custom calls (ConcatBitcast, ...)
+    are not mistaken for kernels (a miscount that once made the cost
+    table refuse the step on the chip, PR 21)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models import stacked_dynamic_lstm as lstm
+    from paddle_tpu.observe import cost
+
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        model = lstm.build_model(max_len=16, use_amp=False,
+                                 pallas_rnn=True)
+        exe = fluid.Executor()
+        exe.run(startup)
+        feed = {k: jnp.asarray(v)
+                for k, v in lstm.make_fake_batch(8, 16).items()}
+        step, state, feeds = exe._prepare(
+            main, feed, [model["loss"].name], scope, 1, True)
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=one_chip)
+
+        compiled = _compile_args(step, jax.tree.map(described, state),
+                                 jax.tree.map(described, feeds))
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    targets = {r["custom_call_target"] for r in rows
+               if r["opcode"] == "custom-call"}
+    assert "tpu_custom_call" in targets
+    totals = cost.total_costs(cost.compiled_hlo_proto(compiled))
+    assert totals["custom_calls"] == totals["pallas_matched"] > 0, totals
+    assert totals["pallas_flops"] > 0
+
+
+def test_tpu_dots_are_matmul_rows_with_xlas_flops(one_chip):
+    """The TPU compiler writes every dot as a `convolution` (a batched
+    one over its batch dimensions, with a window as large as the batch
+    of which a dilation leaves one position valid): observe.cost must
+    still bucket it `matmul`, a real convolution `conv`, and count the
+    FLOPs XLA's own cost analysis counts."""
+    from paddle_tpu.observe import cost
+
+    def forward(q, k, v):
+        with jax.named_scope("flash_attention:3"):
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+            p = jax.nn.softmax(s.astype(F32), -1).astype(BF16)
+            return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(F32).sum()
+
+    # forward and backward: five batched dots (the forward alone is
+    # matched to a fused attention of the compiler's own)
+    attention = jax.grad(forward, argnums=(0, 1, 2))
+
+    def stem(x, w):
+        with jax.named_scope("conv2d:0"):
+            return jax.lax.conv_general_dilated(x, w, (2, 2), "SAME")
+
+    qkv = [jax.ShapeDtypeStruct((8, 8, 256, 64), BF16, sharding=one_chip)] * 3
+    img = [jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+           for s in ((8, 3, 224, 224), (64, 3, 7, 7))]
+    for fn, args, bucket, op, dots in ((attention, qkv, "matmul",
+                                        "flash_attention", 5),
+                                       (stem, img, "conv", "conv2d", 1)):
+        compiled = _compile_args(jax.jit(fn), *args)
+        assert " convolution(" in compiled.as_text()
+        assert " dot(" not in compiled.as_text()
+        rows = [r for r in cost.instruction_costs(
+            cost.compiled_hlo_proto(compiled))
+            if r["bucket"] in ("matmul", "conv")]
+        assert len(rows) == dots
+        assert {r["bucket"] for r in rows} == {bucket}
+        assert {r["op_type"] for r in rows} == {op}
+        total = sum(r["flops"] for r in cost.instruction_costs(
+            cost.compiled_hlo_proto(compiled)))
+        assert total == pytest.approx(cost.compiled_xla_flops(compiled),
+                                      rel=0.02)
+    # the stem by hand: SAME padding clips 3 of 7 window positions at
+    # each edge, which a count of window sizes would miss
+    assert sum(r["flops"] for r in rows) < 2 * 8 * 64 * 112 * 112 * 3 * 49

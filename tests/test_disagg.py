@@ -77,6 +77,16 @@ def _clear_chaos():
     chaos.clear()
 
 
+def _kill_mid_generation(engine):
+    """Kill a decode worker once it has COMMITTED tokens, so that its
+    sessions carry a prefix to verify."""
+    t0 = time.monotonic()
+    while engine.stats.tokens_generated < 2 \
+            and time.monotonic() - t0 < 60:
+        time.sleep(0.002)
+    chaos.kill_replica(engine)
+
+
 def _assert_parity(outs, control):
     for i, (r, c) in enumerate(zip(outs, control)):
         assert list(r.tokens) == list(c), \
@@ -127,11 +137,7 @@ def test_decode_worker_kill_token_parity(control_tokens):
     victim = fleet.decode[0].engine
     futs = [fleet.submit(p, max_new_tokens=b)
             for p, b in zip(PROMPTS, BUDGETS)]
-    t0 = time.monotonic()
-    while victim.stats.tokens_generated < 2 \
-            and time.monotonic() - t0 < 60:
-        time.sleep(0.002)
-    chaos.kill_replica(victim)
+    _kill_mid_generation(victim)
     outs = [f.result(300) for f in futs]
     snap = fleet.snapshot()
     _assert_parity(outs, control_tokens)
@@ -160,6 +166,62 @@ def test_prefill_worker_kill_zero_client_failures(control_tokens):
     assert snap["failed"] == 0, snap
     assert snap["prefill_failovers"] >= 1, snap
     assert snap["post_warmup_compiles"] == 0, snap
+    fleet.close()
+
+
+def test_one_worker_of_each_kind_killed_and_one_trace_draws_the_handoff(
+        control_tokens):
+    """Two prefill + two decode workers, ONE of each kind killed in the
+    same stream: zero client-visible failures, every output the unified
+    control's, zero recompiles (the two tests above kill one kind each).
+    And the chrome export of a LIVE fleet's trace: one trace_id draws
+    the router's row, a prefill worker's row, a paired kv_transfer
+    arrow and the decode worker's row (the exporter's arrows alone, on
+    a hand-made trace, are tests/test_observe_reqtrace.py's)."""
+    tracer = ReqTracer(sample_rate=1.0)
+    fleet = DisaggFleet([_engine("prefill"), _engine("prefill")],
+                        [_engine("decode"), _engine("decode")],
+                        tracer=tracer).start()
+    pf_victim = fleet.prefill[0].engine
+    dec_victim = fleet.decode[0].engine
+    chaos.arm(f"replica:{pf_victim.replica_id}:kill", times=1)
+    futs = [fleet.submit(p, max_new_tokens=b)
+            for p, b in zip(PROMPTS, BUDGETS)]
+    _kill_mid_generation(dec_victim)
+    outs = [f.result(300) for f in futs]
+    snap = fleet.snapshot()
+    _assert_parity(outs, control_tokens)
+    assert snap["failed"] == 0, snap
+    assert snap["prefill_failovers"] >= 1, snap
+    assert snap["decode_failovers"] >= 1, snap
+    assert snap["parity_failed"] == 0, snap
+    assert snap["post_warmup_compiles"] == 0, snap
+    assert snap["handoffs"] >= len(PROMPTS), snap
+
+    r0 = outs[0]
+    pf_ids = {h.replica_id for h in fleet.prefill}
+    dec_ids = {h.replica_id for h in fleet.decode}
+    assert r0.hops[0] in pf_ids and r0.hops[-1] in dec_ids, r0.hops
+    events = tracer.export_chrome_trace()["traceEvents"]
+    mine = [e for e in events
+            if e.get("args", {}).get("trace_id") == r0.trace_id]
+    rows = {e["pid"] for e in mine if e.get("ph") == "X"}
+    # a replica's row is its id + 1; row 0 is the router's
+    assert rows >= {0, r0.hops[0] + 1, r0.hops[-1] + 1}, rows
+    arrows = {}
+    for e in mine:
+        if e["name"] == "kv_transfer" and e.get("ph") in ("s", "f"):
+            arrows.setdefault(e["id"], []).append(e)
+    assert arrows, mine
+    assert all(sorted(x["ph"] for x in pair) == ["f", "s"]
+               for pair in arrows.values()), arrows
+    # the LAST arrow leaves a prefill worker's row and lands on the row
+    # of the decode worker that served the request
+    last = max(arrows.values(), key=lambda pair: min(x["ts"] for x in pair))
+    src = next(e for e in last if e["ph"] == "s")
+    dst = next(e for e in last if e["ph"] == "f")
+    assert src["pid"] - 1 in pf_ids and dst["pid"] == r0.hops[-1] + 1, \
+        (src["pid"], dst["pid"], r0.hops)
     fleet.close()
 
 

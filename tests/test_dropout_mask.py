@@ -4,7 +4,7 @@ The kernel uses the TPU's hardware generator, which has no CPU rule, so
 nothing here RUNS it: these tests trace the op under the Mosaic gate
 and read, from the two counters the op keeps, which path it took; the
 kernel's results are checked on the chip (`chip_smoke.py dropout_mask`)
-and its compile in tests/test_chip_compile.py.
+and its compile in tests/test_chip_compile_flash_attention.py.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ each other and against plain attention with the key/value heads
 REPEATED over their groups (which the kernels never do); the shape rule
 that chooses between them; the counters; the registered costs.  The
 forward pass and the op are tests/test_expert_share.py's, Mosaic's own
-checks tests/test_chip_compile.py's.
+checks tests/test_chip_compile_flash_attention.py's.
 """
 
 import numpy as np

@@ -252,6 +252,54 @@ def test_hot_reload_under_load(control_tokens):
     fleet.close()
 
 
+def test_live_fleet_exporter_scrape_and_dump_cli():
+    """The fleet's own /metrics endpoint over HTTP while it serves:
+    families of at least four subsystems, the zero-recompile contract
+    readable as a gauge, /healthz beside it, and `tools/metrics_dump.py`
+    scraping the same URL.  (The registry's text form and the parser
+    are tests/test_observe_reqtrace.py's; this is the server the fleet
+    starts itself.)"""
+    import json
+    import re
+    import subprocess
+    import sys
+    import urllib.request
+
+    from paddle_tpu.observe import ReqTracer
+
+    fleet = Fleet([_engine(), _engine()], FleetConfig(),
+                  tracer=ReqTracer(sample_rate=1.0)).start()
+    try:
+        for p in PROMPTS[:2]:
+            fleet.generate(p, max_new_tokens=4, timeout_s=300)
+        srv = fleet.start_metrics_server()      # 127.0.0.1, any free port
+        assert fleet.start_metrics_server() is srv
+        body = urllib.request.urlopen(srv.url + "/metrics",
+                                      timeout=10).read().decode()
+        health = json.loads(urllib.request.urlopen(
+            srv.url + "/healthz", timeout=10).read())
+        assert health["healthy_replicas"] == 2 and not health["closed"]
+        m = re.search(r"^serving_post_warmup_compiles\{[^}]*\} (\d+)$",
+                      body, re.M)
+        assert m and m.group(1) == "0", body[:400]
+        subsystems = {ln.split("_")[0] for ln in body.splitlines()
+                      if ln and not ln.startswith("#")}
+        present = subsystems & {"serving", "fleet", "runtime", "reqtrace",
+                                "process", "memory"}
+        assert len(present) >= 4, subsystems
+        tool = os.path.join(os.path.dirname(__file__), "..", "tools",
+                            "metrics_dump.py")
+        dump = subprocess.run(
+            [sys.executable, tool, "--url", srv.url + "/metrics",
+             "--grep", "fleet_"],
+            capture_output=True, text=True, timeout=60)
+        assert dump.returncode == 0, dump.stderr
+        assert "fleet_failovers_total" in dump.stdout, dump.stdout[:500]
+        assert "serving_post_warmup_compiles" not in dump.stdout
+    finally:
+        fleet.close()
+
+
 # -- structured evacuation / failure surface --------------------------------
 
 @pytest.mark.slow

@@ -34,9 +34,6 @@ Slow (real-subprocess) chaos — the acceptance proof:
   params BIT-IDENTICAL to an uninterrupted control run, no orphans,
 - a checkpoint barrier with a poisoned peer aborts in seconds (vs
   its 120 s timeout) with the poison reason attached.
-
-`python tests/test_gang.py --ci-smoke` runs the two subprocess
-scenarios standalone (tools/run_ci.sh gang-chaos smoke).
 """
 
 import glob
@@ -49,11 +46,6 @@ import time
 
 import numpy as np
 import pytest
-
-if __name__ == "__main__":
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observe
@@ -884,19 +876,3 @@ def test_supervisor_elastic_shrinks_to_survivors(tmp_path):
     assert result.attempts[0]["shrunk_to"] == 1, result.attempts
     assert list(result.attempts[1]["exit_codes"]) == [0]
     assert open(marker).read() == "1"  # relaunched at world size 1
-
-
-if __name__ == "__main__":
-    # run_ci.sh gang-chaos smoke: the subprocess scenarios, no pytest
-    import argparse
-    import tempfile
-
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--ci-smoke", action="store_true")
-    if not ap.parse_args().ci_smoke:
-        sys.exit("usage: python tests/test_gang.py --ci-smoke")
-    d = tempfile.mkdtemp(prefix="gang_smoke_")
-    info = run_gang_sigkill_chaos(d)
-    info2 = run_barrier_poison_chaos(d)
-    info3 = run_elastic_reshard_chaos(d)
-    print("gang-chaos smoke OK:", {**info, **info2, **info3})

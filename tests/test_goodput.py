@@ -402,7 +402,7 @@ def _trainer(ckpt_dir, log=None, step_interval=3):
 
 
 def test_trainer_ledger_sums_to_wall_with_data_stall(tmp_path):
-    """The run_ci goodput smoke, pinned: a slow reader's sleeps land
+    """A short Trainer run, pinned: a slow reader's sleeps land
     in data_stall, checkpoint blocking in checkpoint, Σ == wall, and
     ckpt_stats keeps the old keys as ledger reads."""
     log = str(tmp_path / "ev.jsonl")

@@ -3,8 +3,7 @@
 
 Acceptance pins:
 - dp=8 loss trajectory matches single-device at a FIXED global batch
-  within a pinned tolerance (the GSPMD implicit path — the bench
-  --mesh contract);
+  within a pinned tolerance (the GSPMD implicit path);
 - the explicit bf16 exchange matches the implicit path (control arm);
 - int8 quantized grad sync trains to a trajectory within the
   documented tolerance of bf16 dp (the EQuARX correctness A/B the
@@ -319,105 +318,3 @@ def test_trainer_trains_on_dp_mesh_with_int8_sync():
             if hasattr(e, "metrics") else None)
     t.stop()
     assert len(losses) == 4 and np.isfinite(losses).all()
-
-
-# -- bench helpers ---------------------------------------------------------
-
-def test_bench_parse_mesh():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench._parse_mesh("dp=8") == {"dp": 8}
-    assert bench._parse_mesh("dp=4,mp=2") == {"dp": 4, "mp": 2}
-    for bad in ("dp", "dp=0", "=8", "dp=x"):
-        with pytest.raises(ValueError):
-            bench._parse_mesh(bad)
-
-
-# -- perf_gate dp schema + regression keys ---------------------------------
-
-def _perf_gate():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools", "perf_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _dp_entry(**over):
-    e = {"mfu": 0.3, "tokens_per_sec": 1000.0,
-         "per_device_tokens_per_sec": 125.0, "mesh": {"dp": 8},
-         "n_devices": 8, "grad_sync": None, "comm_bytes": 5.0e8,
-         # hybrid-parallel contract (ISSUE 13): every mesh entry
-         # carries the sharded step's per-device opt-state bytes
-         "opt_state_bytes_per_device": 2.0e8,
-         "last_loss": 1.0, "ckpt_blocking_ms": 1.0,
-         # numerics observability contract (ISSUE 11): training
-         # entries carry the window's grad norm + worst update ratio
-         "grad_norm_last": 0.5, "update_ratio_worst": 1e-3,
-         # goodput-ledger contract (observe pillar 8): training
-         # entries decompose their harness wall next to the headline
-         "goodput": 0.9, "effective_mfu": 0.27,
-         "badput_breakdown": {"compile": 0.08, "idle": 0.02}}
-    e.update(over)
-    return e
-
-
-def test_perf_gate_schema_requires_dp_keys():
-    pg = _perf_gate()
-    line = {k: 0 for k in pg._SCHEMA_FIELDS}
-    line["detail"] = {"transformer_dp8": _dp_entry()}
-    assert pg.check_schema(line) == []
-    broken = _dp_entry()
-    del broken["comm_bytes"], broken["per_device_tokens_per_sec"]
-    del broken["opt_state_bytes_per_device"]
-    broken["mesh"] = {}
-    line["detail"] = {"transformer_dp8": broken}
-    errs = pg.check_schema(line)
-    assert any("comm_bytes" in e for e in errs)
-    assert any("per_device_" in e for e in errs)
-    assert any("opt_state_bytes_per_device" in e for e in errs)
-    assert any("non-empty axis->size dict" in e for e in errs)
-
-
-def test_perf_gate_catches_per_device_and_comm_regressions():
-    pg = _perf_gate()
-    base = {"detail": {"transformer_dp8": _dp_entry()}}
-    # 10% per-device throughput drop with aggregate held (mesh grew
-    # elsewhere / entry mislabeled) -> caught by the per_device key
-    cand = {"detail": {"transformer_dp8": _dp_entry(
-        per_device_tokens_per_sec=112.0)}}
-    regs, _, compared = pg.gate(base, cand)
-    assert compared == 1
-    assert any("per_device_tokens_per_sec" in r for r in regs)
-    # comm bytes creeping +20% -> regression even at flat throughput
-    cand = {"detail": {"transformer_dp8": _dp_entry(
-        comm_bytes=6.1e8)}}
-    regs, _, _ = pg.gate(base, cand)
-    assert any("comm_bytes" in r for r in regs)
-    # within tolerance -> clean
-    cand = {"detail": {"transformer_dp8": _dp_entry(
-        comm_bytes=5.2e8, per_device_tokens_per_sec=120.0)}}
-    regs, _, _ = pg.gate(base, cand)
-    assert regs == []
-
-
-def test_perf_gate_never_compares_across_mesh_or_sync_mode():
-    pg = _perf_gate()
-    base = {"detail": {"transformer_dp8": _dp_entry()}}
-    # same entry name, different grad_sync -> reported, not gated
-    cand = {"detail": {"transformer_dp8": _dp_entry(
-        grad_sync="int8", tokens_per_sec=500.0,
-        per_device_tokens_per_sec=62.5)}}
-    regs, report, _ = pg.gate(base, cand)
-    assert regs == []
-    assert any("mesh/grad_sync mismatch" in ln for ln in report)

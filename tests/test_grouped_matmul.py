@@ -2,7 +2,7 @@
 vjp, in interpret mode on the CPU: forward, dX and dW over group
 patterns, widths and dtypes; the rows past the groups' sum; the tile
 rule; the Mosaic lowering of each kernel at the four cells' shapes
-(the chip's compiler has its say in tests/test_chip_compile.py)."""
+(the chip's compiler has its say in tests/test_chip_compile_cells.py)."""
 
 from __future__ import annotations
 

@@ -355,29 +355,41 @@ def test_nthd_tpu_export_has_zero_transposes():
         "head-major lowering emitted a transpose at a kernel boundary"
 
 
-def test_flash_boundary_layout_audit():
-    """The observe.cost boundary audit runs over a compiled head-major
-    step and reports zero copy/transpose neighbors at flash custom
-    calls (vacuously on CPU where Pallas interprets — the audit is the
-    on-chip CI check — but the plumbing is exercised end-to-end), and
-    layout_byte_share yields a sane fraction."""
+# the smallest head-major program, and the long-context stack at a
+# CPU's size: Pallas flash for self AND cross attention, the fused
+# vocabulary cross-entropy, dropout on
+@pytest.mark.parametrize("stack", [
+    dict(max_length=8, n_layer=1, n_head=2, d_model=16, d_inner_hid=32,
+         dropout=0.0),
+    dict(max_length=128, n_layer=1, n_head=4, d_model=64, d_inner_hid=128,
+         dropout=0.1, flash_cross=True, use_fused_ce=True)],
+    ids=["self_attention", "longctx_stack"])
+def test_flash_boundary_layout_audit(stack):
+    """The program built head-major holds NO `transpose` fluid op (the
+    baseline layout has one at every kernel boundary), and the
+    observe.cost boundary audit over its compiled step reports zero
+    copy/transpose neighbors at flash custom calls (vacuously on CPU
+    where Pallas interprets — the audit is the on-chip CI check — but
+    the plumbing is exercised end-to-end), and layout_byte_share
+    yields a sane fraction."""
     from paddle_tpu.models import transformer
     from paddle_tpu.observe import cost as obs_cost
 
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = 7
     scope = fluid.Scope()
+    t = stack["max_length"]
     with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
             fluid.unique_name.guard():
         m = transformer.build_model(
-            src_vocab_size=64, trg_vocab_size=64, max_length=8,
-            n_layer=1, n_head=2, d_model=16, d_inner_hid=32,
-            dropout=0.0, use_flash=True, flash_pallas=True,
-            head_major=True)
+            src_vocab_size=64, trg_vocab_size=64, use_flash=True,
+            flash_pallas=True, head_major=True, **stack)
+        assert not [op for op in main.global_block().ops
+                    if op.type == "transpose"]
         exe = fluid.Executor()
         exe.run(startup)
         feed = {k: jnp.asarray(v) for k, v in
-                transformer.make_fake_batch(2, 8, 60, 60).items()}
+                transformer.make_fake_batch(2, t, 60, 60).items()}
         compiled = exe.compiled_step(main, feed=feed,
                                      fetch_list=[m["loss"]])
         proto = obs_cost.compiled_hlo_proto(compiled)
@@ -388,22 +400,3 @@ def test_flash_boundary_layout_audit():
     # `transpose` fluid op — the op type does not exist in the program
     assert obs_cost.copyish_instructions(proto,
                                          op_types={"transpose"}) == []
-
-
-def test_perf_gate_layout_share_regression():
-    """tools/perf_gate.py catches layout_share creeping back."""
-    import sys
-
-    sys.path.insert(0, "tools")
-    from perf_gate import gate
-
-    base = {"detail": {"transformer": {"tokens_per_sec": 100.0,
-                                       "layout_share": 0.05}}}
-    good = {"detail": {"transformer": {"tokens_per_sec": 100.0,
-                                       "layout_share": 0.055}}}
-    bad = {"detail": {"transformer": {"tokens_per_sec": 100.0,
-                                      "layout_share": 0.12}}}
-    regressions, _, compared = gate(base, good)
-    assert compared == 1 and not regressions
-    regressions, _, _ = gate(base, bad)
-    assert any("layout_share" in r for r in regressions), regressions

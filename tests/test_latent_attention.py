@@ -4,7 +4,7 @@ the concatenated 192-wide queries and keys (forward and all five
 gradients, the ONE rotary key's summed over the heads), the
 `latent_attention` op on both paths, and `rope` over pairs against
 `rope` over halves under the column permutation that maps one onto the
-other.  Mosaic's own checks are tests/test_chip_compile.py's.
+other.  Mosaic's own checks are tests/test_chip_compile_kernels.py's.
 """
 
 import numpy as np

@@ -264,10 +264,13 @@ def test_op_cost_table_transformer_train_step():
     assert "layout" in text and "matmul" in text
 
 
-def test_op_cost_table_against_xla_aggregate():
+@pytest.mark.parametrize("xla_from", ["program_costs",
+                                      "executor_cost_analysis"])
+def test_op_cost_table_against_xla_aggregate(xla_from):
     # whole-program analytic flops track XLA's aggregate on the real
     # train step too (CPU backend: no custom calls, so the counts are
-    # directly comparable)
+    # directly comparable); the aggregate as `program_costs` carries it
+    # and as `Executor.cost_analysis` returns it, from the one compile
     main, scope, exe, feed, model = _transformer_step()
     with fluid.scope_guard(scope):
         totals = observe.program_costs(main, feed=feed,
@@ -276,7 +279,13 @@ def test_op_cost_table_against_xla_aggregate():
         compiled = exe.compiled_step(main, feed=feed,
                                      fetch_list=[model["loss"]],
                                      scope=scope)
-    xla = totals["xla_aggregate_flops"]
+        if xla_from == "program_costs":
+            xla = totals["xla_aggregate_flops"]
+        else:
+            xla = exe.cost_analysis(main, feed=feed,
+                                    fetch_list=[model["loss"]],
+                                    scope=scope)["flops"]
+            assert xla == totals["xla_aggregate_flops"]
     assert xla > 0
     # XLA's aggregate counts a while body ONCE; the analytic total
     # carries the trip count (the dropout RNG's 5-round threefry loops

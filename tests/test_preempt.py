@@ -23,9 +23,6 @@ Slow (real-subprocess) chaos — the acceptance proof:
   an uninterrupted control, zero loadable torn checkpoints,
 - SIGTERM → drain → exit code PREEMPT_EXIT_CODE + ckpt_emergency event
   → relaunch → bit-identical.
-
-`python tests/test_preempt.py --ci-smoke` runs the two subprocess
-scenarios standalone (tools/run_ci.sh crash-resume smoke).
 """
 
 import json
@@ -37,13 +34,6 @@ import time
 
 import numpy as np
 import pytest
-
-# script mode (run_ci.sh crash-resume smoke runs this file directly):
-# repo root on sys.path + CPU pin, neither needed under pytest/conftest
-if __name__ == "__main__":
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import paddle_tpu as fluid
 from paddle_tpu import layers, observe
@@ -565,18 +555,3 @@ def test_sigkill_chaos_bit_exact_resume(tmp_path):
 def test_sigterm_drain_distinct_exit_and_bit_exact(tmp_path):
     info = run_sigterm_drain_chaos(str(tmp_path))
     print("sigterm drain chaos:", info)
-
-
-if __name__ == "__main__":
-    # run_ci.sh crash-resume smoke: both chaos scenarios, no pytest
-    import argparse
-    import tempfile
-
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--ci-smoke", action="store_true")
-    if not ap.parse_args().ci_smoke:
-        sys.exit("usage: python tests/test_preempt.py --ci-smoke")
-    d = tempfile.mkdtemp(prefix="preempt_smoke_")
-    info = run_sigkill_chaos(d)
-    info2 = run_sigterm_drain_chaos(d)
-    print("crash-resume smoke OK:", {**info, **info2})

@@ -22,6 +22,7 @@ injecting its fault (resilience/chaos.py) —
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -533,6 +534,55 @@ def test_admission_degraded_rejections_are_structured():
     assert adm.state == "degraded"
     adm.begin_drain()
     assert adm.state == "draining"
+
+
+def test_engine_breaker_degrades_and_recovers_end_to_end(tmp_path):
+    """The breaker behind a LIVE ServingEngine, its executor failing by
+    injection (chaos FlakyPredictor): two failed dispatches reach their
+    callers as structured executor failures and open the circuit, the
+    next request is rejected without a dispatch, and after the
+    cool-down one half-open probe that succeeds puts the engine back to
+    RUNNING.  (The tests above drive the breaker with an injected
+    clock and no engine.)"""
+    from paddle_tpu.resilience import FlakyPredictor
+    from paddle_tpu.serving import (BucketConfig, ExecutorFailureError,
+                                    ServingEngine)
+
+    model_dir = str(tmp_path / "model")
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), \
+            fluid.scope_guard(fluid.Scope()):
+        x = layers.data("x", shape=[8], append_batch_size=True)
+        pred = layers.fc(x, size=4)
+        exe = fluid.Executor()
+        exe.run(startup)
+        fluid.io.save_inference_model(model_dir, ["x"], [pred], exe,
+                                      main_program=main)
+    flaky = FlakyPredictor(fluid.Predictor(model_dir), fail_first=2)
+    engine = ServingEngine(
+        flaky, {"x": np.zeros(8, np.float32)}, buckets=BucketConfig((1, 2)),
+        max_wait_ms=0, queue_capacity=8,
+        breaker=CircuitBreaker(failure_threshold=2, cooldown_s=0.2)).start()
+    x0 = np.ones(8, np.float32)
+    try:
+        for _ in range(2):
+            with pytest.raises(ExecutorFailureError) as ei:
+                engine.infer({"x": x0}, timeout_s=60)
+            assert ei.value.as_dict()["error"] == "executor_failure"
+        assert engine.health()["state"] == "degraded", engine.health()
+        calls = flaky.calls
+        with pytest.raises(CircuitOpenError) as ei:
+            engine.infer({"x": x0}, timeout_s=60)
+        assert ei.value.as_dict()["error"] == "circuit_open"
+        assert flaky.calls == calls     # rejected before any dispatch
+        time.sleep(0.25)
+        out = engine.infer({"x": x0}, timeout_s=60)  # the half-open probe
+        assert np.isfinite(out[0]).all()
+        assert engine.health()["state"] == "running", engine.health()
+        assert engine.health()["breaker"]["state"] == "closed"
+    finally:
+        engine.close()
+    assert flaky.failures_injected == 2
 
 
 # ---------------------------------------------------------------------------
