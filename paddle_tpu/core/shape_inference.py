@@ -38,10 +38,14 @@ def _decode_shape(shape):
 # Op types that the executor handles specially or whose impls can't be
 # abstractly evaluated; their outputs keep declared shapes.  Tensor-array
 # ops carry (buffer, length) tuples that ShapeDtypeStructs can't model.
+# `gated_delta_rule`'s layer declares its one output itself: tracing a
+# 256-chunk scan at the stand-in batch would only add three kernel
+# calls of a million sequences to `runtime_stats.gated_delta_*`, which
+# a benchmark reader takes for the step's.
 _SKIP_INFERENCE = {
     "backward_marker", "py_func", "print",
     "create_array", "array_write", "array_read", "array_length",
-    "array_to_tensor",
+    "array_to_tensor", "gated_delta_rule",
 }
 
 

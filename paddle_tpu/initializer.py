@@ -47,6 +47,21 @@ class Uniform(Initializer):
 UniformInitializer = Uniform
 
 
+class LogUniform(Initializer):
+    """log of a draw from U(low, high), 0 < low: a rate kept as its
+    logarithm (a decay's `A_log`)."""
+
+    def __init__(self, low: float, high: float, seed: int = 0):
+        if not 0.0 < low < high:
+            raise ValueError(f"LogUniform: not 0 < {low} < {high}")
+        self.low, self.high, self.seed = low, high, seed
+
+    def __call__(self, var, block):
+        Uniform(self.low, self.high, self.seed)(var, block)
+        return block.append_op(type="log", inputs={"X": [var]},
+                               outputs={"Out": [var]})
+
+
 class Normal(Initializer):
     def __init__(self, loc: float = 0.0, scale: float = 1.0, seed: int = 0):
         self.mean, self.std, self.seed = loc, scale, seed

@@ -267,37 +267,47 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
 
 
 def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
-             name=None, group_size=None):
+             name=None, group_size=None, zero_centered=False, gate=None):
     """Root-mean-square norm over the axes from `begin_norm_axis`, with
     a learned scale initialised to 1 (no shift, no mean subtraction).
     `group_size` g: the minor dim is groups of g (the heads of a
     head-grouped projection), each normalised alone under one shared
-    scale (g,)."""
+    scale (g,).  `zero_centered`: the learned parameter w starts at 0
+    and the scale is 1 + w, so weight decay pulls the scale to 1 and
+    not to 0.  `gate` (input's shape): the result times silu(gate)."""
     helper = LayerHelper("rms_norm", name=name)
     begin = begin_norm_axis % len(input.shape)
     attrs = {"begin_norm_axis": begin, "epsilon": epsilon}
     if group_size:
         attrs["group_size"] = int(group_size)
+    if zero_centered:
+        attrs["zero_centered"] = True
     scale = helper.create_parameter(
         param_attr,
         shape=[int(group_size or np.prod(input.shape[begin:]))],
-        dtype=input.dtype, default_initializer=Constant(1.0))
+        dtype=input.dtype,
+        default_initializer=Constant(0.0 if zero_centered else 1.0))
     y = helper.create_variable_for_type_inference(input.dtype)
-    helper.append_op(type="rms_norm",
-                     inputs={"X": [input], "Scale": [scale]},
-                     outputs={"Y": [y]}, attrs=attrs)
+    ins = {"X": [input], "Scale": [scale]}
+    if gate is not None:
+        ins["Gate"] = [gate]
+    helper.append_op(type="rms_norm", inputs=ins, outputs={"Y": [y]},
+                     attrs=attrs)
     return y
 
 
-def short_conv(input, filter_size, param_attr=None, name=None):
+def short_conv(input, filter_size, param_attr=None, name=None,
+               activation=None):
     """The gated short convolution of a hybrid conv/attention decoder
     (ops/decoder.py `short_conv`): `input` is `BCu` (N, T, 3D), what
     the block's in-projection emits; returns `C * conv(B * u)`
     (N, T, D), a causal depthwise convolution of `filter_size` taps
-    with one learned filter (D, filter_size)."""
+    with one learned filter (D, filter_size).  `activation` "silu":
+    `input` is (N, T, D), ungated, and the result silu(conv(input))."""
     helper = LayerHelper("short_conv", name=name)
-    d = int(input.shape[-1]) // 3
-    if 3 * d != int(input.shape[-1]):
+    wide = 1 if activation else 3
+    d = int(input.shape[-1]) // wide
+    if wide * d != int(input.shape[-1]):
         raise ValueError(f"short_conv: minor dim {input.shape[-1]} is "
                          f"not B, C and u side by side")
     w = helper.create_parameter(param_attr, shape=[d, int(filter_size)],
@@ -305,13 +315,15 @@ def short_conv(input, filter_size, param_attr=None, name=None):
     out = helper.create_variable_for_type_inference(input.dtype)
     helper.append_op(type="short_conv",
                      inputs={"X": [input], "Filter": [w]},
-                     outputs={"Out": [out]})
+                     outputs={"Out": [out]},
+                     attrs={"activation": activation} if activation else {})
     out.desc.shape = tuple(input.shape[:-1]) + (d,)
     return out
 
 
 def rope(input, n_head, theta=10000.0, offset=None, name=None,
-         interleave=False, inv_freq=None, attention_factor=None):
+         interleave=False, inv_freq=None, attention_factor=None,
+         rotary_dim=None):
     """Rotary position embedding of a head-grouped (N, T, n_head * D)
     projection (ops/decoder.py): rotate-half, or with `interleave` the
     pairs (2i, 2i + 1) of every head.  `offset`: a (1,) integer
@@ -319,7 +331,8 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
     `inv_freq`, D/2 frequencies in place of theta^(-2i/D), and
     `attention_factor` on cos and sin, both host constants
     (`ops.decoder.rope_frequencies` makes them of a config's
-    `rope_parameters`)."""
+    `rope_parameters`).  `rotary_dim`: only the first so many lanes of
+    each head turn (a config's `partial_rotary_factor` x the head)."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     ins = {"X": [input]}
@@ -332,8 +345,42 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
         attrs["inv_freq"] = [float(f) for f in inv_freq]
     if attention_factor is not None and float(attention_factor) != 1.0:
         attrs["attention_factor"] = float(attention_factor)
+    if rotary_dim is not None:
+        attrs["rotary_dim"] = int(rotary_dim)
     helper.append_op(type="rope", inputs=ins, outputs={"Out": [out]},
                      attrs=attrs)
+    return out
+
+
+def gated_delta_rule(qkv, ba, n_key_head, n_value_head, key_dim, value_dim,
+                     use_pallas=False, name=None):
+    """The scan of a gated-delta-rule linear-attention layer
+    (ops/decoder.py `gated_delta_rule`): `qkv` (N, T, 2 Hk Dk + Hv Dv)
+    is the convolved projection, q, k and v side by side; `ba`
+    (N, T, 2 Hv) the write strength's and the decay's pre-activations,
+    one of each a value head.  Two learned (Hv,) vectors: `A_log`, the
+    log of the decay's rate, from log U(2^-10, 16), and `dt_bias`, from
+    1.  Returns (N, T, Hv Dv).  `use_pallas`: the kernels of
+    ops/pallas/gated_delta.py (Dk = Dv = 128)."""
+    from ..initializer import LogUniform
+
+    helper = LayerHelper("gated_delta_rule", name=name)
+    a_log = helper.create_parameter(
+        None, shape=[int(n_value_head)], dtype="float32",
+        default_initializer=LogUniform(2.0 ** -10, 16.0))
+    dt_bias = helper.create_parameter(
+        None, shape=[int(n_value_head)], dtype="float32",
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(qkv.dtype)
+    helper.append_op(
+        type="gated_delta_rule",
+        inputs={"QKV": [qkv], "BA": [ba], "ALog": [a_log],
+                "DtBias": [dt_bias]},
+        outputs={"Out": [out]},
+        attrs={"n_key_head": int(n_key_head),
+               "n_value_head": int(n_value_head), "key_dim": int(key_dim),
+               "value_dim": int(value_dim), "use_pallas": bool(use_pallas)})
+    out.desc.shape = tuple(qkv.shape[:-1]) + (int(n_value_head * value_dim),)
     return out
 
 

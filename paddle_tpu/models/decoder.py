@@ -9,9 +9,11 @@ builds, each chosen by the configuration's own keys (a key whose other
 values are not built yet raises):
 
 - operators: causal attention with grouped-query heads and QK-norm
-  (flash kernels at d_head 128 and 64), over the whole prefix or under
-  a sliding window, its RoPE unscaled or YaRN's, by layer type; the
-  gated short convolution;
+  (flash kernels at d_head 256, 128 and 64), over the whole prefix or
+  under a sliding window, its RoPE unscaled or YaRN's, over the whole
+  head or its first lanes, by layer type, its context gated or not;
+  the gated short convolution; the gated-delta-rule linear-attention
+  mixer (a chunked scan, `ops/pallas/gated_delta.py`);
   latent attention (`kv_lora_rank` ...: queries, keys and values out
   of low-rank latents, a rotary part beside the unrotated one, its own
   flash kernels);
@@ -80,6 +82,38 @@ layer type; a group's `rope_type` "default" is theta^(-2i/D) and
 on the host; the op takes them as attributes); any other raises.  In
 a program that has a window layer the two kinds' attention operators
 lower under the name scopes `sliding_attention` / `full_attention`.
+
+`layer_types[i]` = "linear_attention" (the `linear_*` keys; Gated
+DeltaNet, Yang, Kautz, Hatamizadeh, arXiv:2412.06464) is a mixer with a
+matrix-valued state in place of a cache:
+
+    [q | k | v] = silu(conv(h W_qkv));  z = h W_z;  [b | a] = h W_ba
+    q, k = l2norm a head (q x Dk^-1/2);  beta = sigmoid(b)
+    g = -exp(A_log) softplus(a + dt_bias)
+    S_t = exp(g_t) S_{t-1} + k_t (beta_t (v_t - exp(g_t) S_{t-1}^T k_t))^T
+    out = (rms_norm(S_t^T q_t) * w * silu(z)) W_out        (a head)
+
+`linear_num_key_heads` key heads of `linear_key_head_dim` serve
+`linear_num_value_heads` value heads of `linear_value_head_dim` (value
+head h reads key head h // ratio); the convolution is causal,
+depthwise, `linear_conv_kernel_dim` taps (`layers.short_conv(
+activation="silu")`); the recurrence is ONE op, `gated_delta_rule`,
+whose sequential part is a Pallas kernel at heads of 128 x 128.  Its
+ops lower under the `linear_attention` name scope.
+
+`partial_rotary_factor` f < 1: RoPE turns the first f x head_dim lanes
+of each head as a head of that size would, the rest pass through.
+Three equations more that no key spells, arguments named for the
+mechanism: `zero_centered_norm` (every norm of the decoder scales by
+1 + w, w from 0, so that decay pulls the scale to 1; the linear mixer's
+gated output norm keeps a plain scale from 1), `attention_gate`
+("sigmoid": a second projection as wide as q, the context times its
+sigmoid before the out projection; the operator then lowers under the
+`gated_attention` name scope) and `shared_expert_gate` ("sigmoid": the
+shared expert's result times sigmoid(h w), w (D, 1)).
+`shared_expert_intermediate_size` gives the shared expert's width where
+a configuration names it so (`n_shared_experts` x the routed width
+where it counts experts).
 
 `embedding_init_range` (a recipe's): the std of the embedding table
 where it is not `initializer_range`, every matrix's.  A table of unit
@@ -211,7 +245,12 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             num_nextn_predict_layers=0, total_ut_steps=1,
             sandwich_norm=False, exit_gate=None, exit_entropy_weight=0.0,
             recompute=None, head_dim=None, sliding_window=None,
-            embedding_init_range=None):
+            embedding_init_range=None, partial_rotary_factor=1.0,
+            linear_num_key_heads=None, linear_num_value_heads=None,
+            linear_key_head_dim=None, linear_value_head_dim=None,
+            linear_conv_kernel_dim=None,
+            shared_expert_intermediate_size=None, zero_centered_norm=False,
+            attention_gate=None, shared_expert_gate=None):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
     labels' own successors, with a prediction module).  Returns a dict:
@@ -247,6 +286,21 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise NotImplementedError(f"exit_gate {exit_gate!r} is not built")
     if recompute not in (None, "layer"):
         raise NotImplementedError(f"recompute {recompute!r} is not built")
+    if attention_gate not in (None, "sigmoid"):
+        raise NotImplementedError(f"attention_gate {attention_gate!r} is "
+                                  f"not built")
+    if shared_expert_gate not in (None, "sigmoid"):
+        raise NotImplementedError(f"shared_expert_gate "
+                                  f"{shared_expert_gate!r} is not built")
+    if shared_expert_intermediate_size and n_shared_experts:
+        raise ValueError("the shared expert's width is given twice: "
+                         "shared_expert_intermediate_size and "
+                         "n_shared_experts")
+    if not 0.0 < partial_rotary_factor <= 1.0:
+        raise ValueError(f"partial_rotary_factor {partial_rotary_factor} is "
+                         f"no share of a head")
+    if attention_gate and kv_lora_rank is not None:
+        raise NotImplementedError("a gate on latent attention is not built")
     if total_ut_steps < 1:
         raise ValueError(f"total_ut_steps {total_ut_steps} is not positive")
     loops = total_ut_steps > 1 or exit_gate is not None
@@ -278,8 +332,24 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     windowed = "sliding_attention" in layer_types
     if windowed and not sliding_window:
         raise ValueError("a sliding_attention layer needs sliding_window")
+    linear = (linear_num_key_heads, linear_num_value_heads,
+              linear_key_head_dim, linear_value_head_dim,
+              linear_conv_kernel_dim)
+    if "linear_attention" in layer_types:
+        if None in linear:
+            raise ValueError(
+                "a linear_attention layer needs linear_num_key_heads, "
+                "linear_num_value_heads, linear_key_head_dim, "
+                "linear_value_head_dim and linear_conv_kernel_dim")
+        if linear_num_value_heads % linear_num_key_heads:
+            raise ValueError("linear_num_value_heads is not a multiple of "
+                             "linear_num_key_heads")
     if head_dim is None:
         head_dim = hidden_size // num_attention_heads
+    rotary_dim = int(head_dim * partial_rotary_factor)
+    if rotary_dim % 2:
+        raise ValueError(f"partial_rotary_factor {partial_rotary_factor} of "
+                         f"a head of {head_dim} is no whole number of pairs")
     q_size = num_attention_heads * head_dim
     kv_size = num_key_value_heads * head_dim
     # `rope_parameters`: one flat group, or one a layer type
@@ -297,6 +367,12 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             continue
         if group.get("rope_type", "default") == "default":
             rotary[kind] = {"theta": group["rope_theta"]}
+            if rotary_dim != head_dim:
+                rotary[kind]["rotary_dim"] = rotary_dim
+        elif rotary_dim != head_dim:
+            raise NotImplementedError(
+                f"rope_type {group['rope_type']!r} over a part of the head "
+                f"is not built")
         else:
             inv_freq, factor = rope_frequencies(head_dim, **group)
             rotary[kind] = {"theta": group["rope_theta"],
@@ -319,19 +395,23 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                          param_attr=weight(), name=name)
 
     def norm(x):
-        return layers.rms_norm(x, epsilon=eps)
+        return layers.rms_norm(x, epsilon=eps,
+                               zero_centered=zero_centered_norm)
 
     def norm_qk(x):
         if qk_norm is None:
             return x
         if qk_norm == "head":
-            return layers.rms_norm(x, epsilon=eps, group_size=head_dim)
+            return layers.rms_norm(x, epsilon=eps, group_size=head_dim,
+                                   zero_centered=zero_centered_norm)
         return norm(x)
 
     def attention(h, kind):
         turn = rotary[kind]
-        # a program that mixes the two kinds tells their rows apart
-        with name_scope(kind) if windowed else contextlib.nullcontext():
+        # a program that mixes kinds of layer tells their rows apart
+        scope = "gated_attention" if attention_gate else kind
+        with name_scope(scope) if windowed or attention_gate \
+                else contextlib.nullcontext():
             q = layers.rope(norm_qk(proj(h, q_size, "attn_qkv")),
                             num_attention_heads, **turn)
             k = layers.rope(norm_qk(proj(h, kv_size, "attn_qkv")),
@@ -342,7 +422,32 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                 n_head=num_attention_heads, n_kv_head=num_key_value_heads,
                 window=sliding_window if kind == "sliding_attention"
                 else None)
+            if attention_gate:
+                # a gate a lane of the context, from the layer's input
+                ctx = layers.elementwise_mul(ctx, layers.sigmoid(
+                    proj(h, q_size, "attn_gate")))
             return proj(ctx, hidden_size, "attn_out")
+
+    def linear_attention(h):
+        """The gated-delta-rule mixer: q, k, v out of ONE projection
+        through a short causal convolution and a SiLU, the scan, and an
+        output norm a head gated by a fourth projection z."""
+        from ..ops.pallas import gated_delta
+
+        hk, hv = linear_num_key_heads, linear_num_value_heads
+        dk, dv = linear_key_head_dim, linear_value_head_dim
+        with name_scope("linear_attention"):
+            qkv = layers.short_conv(
+                proj(h, 2 * hk * dk + hv * dv, "linear_qkvz"),
+                linear_conv_kernel_dim, param_attr=weight(),
+                activation="silu")
+            z = proj(h, hv * dv, "linear_qkvz")
+            o = layers.gated_delta_rule(
+                qkv, proj(h, 2 * hv, "linear_ba"), hk, hv, dk, dv,
+                use_pallas=gated_delta.kernel_takes(dk, dv))
+            # this norm's scale starts at 1 whatever the others do
+            return proj(layers.rms_norm(o, epsilon=eps, group_size=dv,
+                                        gate=z), hidden_size, "linear_out")
 
     def latent_attention(h):
         heads = num_attention_heads
@@ -393,25 +498,36 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             norm_topk_eps=norm_topk_eps)
         aux_losses.append(aux), z_losses.append(z)
         counts.append(count), experts.append(chosen)
-        if n_shared_experts:
+        shared_width = (shared_expert_intermediate_size
+                        or n_shared_experts * expert_width)
+        if shared_width:
             # whole on every rank: over the ranks it counts once
             with name_scope("shared_expert"):
-                y = layers.elementwise_add(
-                    y, dense_ffn(h, n_shared_experts * expert_width))
+                shared = dense_ffn(h, shared_width)
+                if shared_expert_gate:
+                    shared = layers.elementwise_mul(shared, layers.sigmoid(
+                        proj(h, 1, "shared_expert_gate")))
+                y = layers.elementwise_add(y, shared)
         return y
 
+    # layer type -> mixer; latent attention stands where full attention
+    # does and is not built under a window
+    mixers = {"conv": conv, "linear_attention": linear_attention,
+              "full_attention": functools.partial(attention,
+                                                  kind="full_attention"),
+              "sliding_attention": functools.partial(
+                  attention, kind="sliding_attention")}
+    if kv_lora_rank is not None:
+        mixers["full_attention"] = latent_attention
+        del mixers["sliding_attention"]
+
     def block(x, kind, dense):
-        if kind == "conv":
-            op = conv
-        elif kind not in ("full_attention", "sliding_attention"):
-            raise NotImplementedError(f"layer type {kind!r} is not built")
-        elif kv_lora_rank is None:
-            op = functools.partial(attention, kind=kind)
-        elif kind == "full_attention":
-            op = latent_attention
-        else:
-            raise NotImplementedError("latent attention under a window is "
-                                      "not built")
+        op = mixers.get(kind)
+        if op is None:
+            raise NotImplementedError(
+                f"layer type {kind!r} is not built"
+                + (" beside latent attention" if kind == "sliding_attention"
+                   else ""))
         post = norm if sandwich_norm else (lambda y: y)
         x = layers.elementwise_add(x, post(op(norm(x))))
         ffn = dense_ffn if dense else routed_ffn

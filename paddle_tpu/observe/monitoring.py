@@ -70,6 +70,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_gqa_backward_fused", "flash_gqa_backward_split",
     "flash_attention_backward_fused", "flash_attention_backward_split",
     "flash_window_blocks_visited", "flash_window_blocks_allowed",
+    "gated_delta_calls", "gated_delta_chunks",
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "loop_trips")
@@ -149,6 +150,13 @@ class RuntimeStats:
         # times apart where skipping is lost (delta() around a build)
         self.flash_window_blocks_visited = 0
         self.flash_window_blocks_allowed = 0
+        # calls of the chunked delta-rule scan's Pallas kernels traced
+        # (`ops/pallas/gated_delta.py`: a layer's forward, its
+        # recomputed forward and its backward are a call each) and their
+        # chunks x heads; a step that fell back to the XLA lowering of
+        # the scan reads 0 (delta() around a build)
+        self.gated_delta_calls = 0
+        self.gated_delta_chunks = 0
         # attention calls traced inside a recompute segment, whose
         # backward pass therefore keeps the kernel's two residuals and
         # does not run its forward kernel again, and the bytes of those
@@ -232,6 +240,11 @@ class RuntimeStats:
         with self._lock:
             self.flash_window_blocks_visited += visited
             self.flash_window_blocks_allowed += allowed
+
+    def record_gated_delta(self, chunks: int):
+        with self._lock:
+            self.gated_delta_calls += 1
+            self.gated_delta_chunks += chunks
 
     def record_kept_residuals(self, nbytes: int):
         with self._lock:

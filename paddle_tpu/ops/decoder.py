@@ -1,9 +1,12 @@
-"""The small ops of a modern decoder block: `rms_norm`, `rope`
-(rotary positions, over halves or over pairs), `swiglu` (the gated FFN
-activation), `short_conv` (the gated short convolution that stands
-where attention does in most layers of a hybrid conv/attention model)
-and `latent_attention` (the attention core of a layer whose keys and
-values come out of a low-rank latent, with a rotary part beside it).
+"""The small ops of a modern decoder block: `rms_norm` (its scale from
+1 or zero-centred, alone or gated), `rope` (rotary positions, over
+halves or over pairs, over a whole head or its first lanes), `swiglu`
+(the gated FFN activation), `short_conv` (the short causal convolution
+that stands where attention does in most layers of a hybrid
+conv/attention model, gated or under a SiLU), `latent_attention` (the
+attention core of a layer whose keys and values come out of a low-rank
+latent, with a rotary part beside it) and `gated_delta_rule` (the scan
+of a linear-attention layer).
 
 Not in the 1.2 reference (it predates them all); they are ops of
 their own, not compositions of `square` / `reduce_mean` / `slice` /
@@ -29,17 +32,25 @@ def rms_norm(ctx, ins, attrs):
     [* Scale].  Statistics and the scaling in float32, Y in X's dtype.
     With `group_size` g the minor dim is read as groups of g (the heads
     of a head-grouped (N, T, H*g) projection), each normalised alone
-    and scaled by the one Scale (g,) they share."""
+    and scaled by the one Scale (g,) they share.  `zero_centered`: the
+    scale is 1 + Scale (a Scale that starts at 0 and that weight decay
+    pulls to 0 leaves the norm ON).  Gate (X's shape): Y is multiplied
+    by silu(Gate), in float32 (the output norm of a gated mixer)."""
     x = first(ins, "X")
     scale = opt_in(ins, "Scale")
+    gate = opt_in(ins, "Gate")
     group = attrs.get("group_size")
     if group:
         if x.shape[-1] % int(group):
             raise ValueError(f"rms_norm: minor dim {x.shape[-1]} is not "
                              f"whole groups of {group}")
-        y = rms_norm(ctx, {"X": [x.reshape(x.shape[:-1] + (-1, int(group)))],
-                           "Scale": ins.get("Scale", [])},
-                     {"epsilon": attrs.get("epsilon", 1e-5)})["Y"][0]
+        split = x.shape[:-1] + (-1, int(group))
+        y = rms_norm(ctx, {"X": [x.reshape(split)],
+                           "Scale": ins.get("Scale", []),
+                           "Gate": [g.reshape(split)
+                                    for g in ins.get("Gate", [])]},
+                     {k: v for k, v in attrs.items()
+                      if k in ("epsilon", "zero_centered")})["Y"][0]
         return out(Y=y.reshape(x.shape))
     begin = attrs.get("begin_norm_axis", -1) % x.ndim
     eps = attrs.get("epsilon", 1e-5)
@@ -48,7 +59,10 @@ def rms_norm(ctx, ins, attrs):
     y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
                        + eps)
     if scale is not None:
-        y = y * scale.reshape(x.shape[begin:]).astype(jnp.float32)
+        scale = scale.reshape(x.shape[begin:]).astype(jnp.float32)
+        y = y * (1.0 + scale if attrs.get("zero_centered") else scale)
+    if gate is not None:
+        y = y * jax.nn.silu(gate.astype(jnp.float32))
     return out(Y=y.astype(x.dtype))
 
 
@@ -114,7 +128,9 @@ def rope(ctx, ins, attrs):
     the cache length); absent = 0.  `inv_freq` (D/2 numbers, a host
     constant as a checkpoint's buffer is) stands in for theta^(-2i/D),
     and cos and sin are multiplied by `attention_factor`: scaled RoPE
-    (`rope_frequencies`)."""
+    (`rope_frequencies`).  `rotary_dim` R < D: only the first R lanes
+    of every head turn, as a head of R would (pairs (i, i + R/2),
+    theta^(-2i/R)); lanes R.. pass through."""
     x = first(ins, "X")
     offset = opt_in(ins, "Offset")
     n_head = int(attrs["n_head"])
@@ -124,6 +140,19 @@ def rope(ctx, ins, attrs):
     if d * n_head != hd or d % 2:
         raise ValueError(f"rope: minor dim {hd} is not n_head {n_head} "
                          f"heads of an even size")
+    rotary = int(attrs.get("rotary_dim") or d)
+    if rotary != d:
+        if not 0 < rotary < d or rotary % 2:
+            raise ValueError(f"rope: rotary_dim {rotary} is not an even "
+                             f"part of a head of {d}")
+        x4 = x.reshape(n, t, n_head, d)
+        turned = rope(ctx, {"X": [x4[..., :rotary].reshape(n, t, -1)],
+                            "Offset": ins.get("Offset", [])},
+                      {k: v for k, v in attrs.items()
+                       if k != "rotary_dim"})["Out"][0]
+        return out(Out=jnp.concatenate(
+            [turned.reshape(n, t, n_head, rotary), x4[..., rotary:]],
+            axis=-1).reshape(n, t, hd))
     pos = jnp.arange(t, dtype=jnp.int32)
     if offset is not None:
         pos = pos + offset.reshape(()).astype(jnp.int32)
@@ -162,15 +191,23 @@ def swiglu(ctx, ins, attrs):
     return out(Out=silu_gate(first(ins, "X"), first(ins, "Y")))
 
 
+def _causal_conv(z, w):
+    """conv[t] = sum_j w[:, j] * z[t - (L-1) + j], z[<0] = 0; float32."""
+    taps, t = w.shape[1], z.shape[1]
+    z = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    wf = w.astype(jnp.float32)
+    return sum(wf[:, j] * z[:, j:j + t] for j in range(taps))
+
+
 def _short_conv(bcu, w):
-    d, taps = w.shape
-    f32 = jnp.float32
-    b, c, u = (bcu[..., i * d:(i + 1) * d].astype(f32) for i in range(3))
-    z = jnp.pad(b * u, ((0, 0), (taps - 1, 0), (0, 0)))
-    t = bcu.shape[1]
-    wf = w.astype(f32)
-    conv = sum(wf[:, j] * z[:, j:j + t] for j in range(taps))
-    return (c * conv).astype(bcu.dtype)
+    d = w.shape[0]
+    b, c, u = (bcu[..., i * d:(i + 1) * d].astype(jnp.float32)
+               for i in range(3))
+    return (c * _causal_conv(b * u, w)).astype(bcu.dtype)
+
+
+def _silu_conv(x, w):
+    return jax.nn.silu(_causal_conv(x.astype(jnp.float32), w)).astype(x.dtype)
 
 
 @register_op("short_conv")
@@ -187,12 +224,23 @@ def short_conv(ctx, ins, attrs):
     itself only).  One op, so that the three elementwise passes are
     one scope and can be one fusion; float32 inside, X's dtype out.
     The backward pass recomputes from X (`jax.checkpoint`): it reads
-    `BCu` and the output's gradient and keeps nothing in between."""
+    `BCu` and the output's gradient and keeps nothing in between.
+
+    `activation` "silu": no gates; X is (N, T, D) and
+    Out = silu(conv(X)), the same convolution (what stands before the
+    scan of a linear-attention layer)."""
     x, w = first(ins, "X"), first(ins, "Filter")
-    if x.ndim != 3 or x.shape[-1] != 3 * w.shape[0]:
-        raise ValueError(f"short_conv: X {x.shape} is not (N, T, 3D) for "
-                         f"a Filter {w.shape} of (D, L)")
-    return out(Out=jax.checkpoint(_short_conv)(x, w))
+    activation = attrs.get("activation")
+    if activation not in (None, "silu"):
+        raise NotImplementedError(f"short_conv: activation {activation!r} "
+                                  f"is not built")
+    wide = 1 if activation else 3
+    if x.ndim != 3 or x.shape[-1] != wide * w.shape[0]:
+        raise ValueError(f"short_conv: X {x.shape} is not (N, T, "
+                         f"{wide if wide > 1 else ''}D) for a Filter "
+                         f"{w.shape} of (D, L)")
+    return out(Out=jax.checkpoint(_silu_conv if activation
+                                  else _short_conv)(x, w))
 
 
 def plain_latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head, scale):
@@ -247,3 +295,52 @@ def latent_attention(ctx, ins, attrs):
         return out(Out=flash_mla(q_nope, q_rope, k_nope, k_rope, v, scale))
     return out(Out=plain_latent_attention(q_nope, q_rope, k_nope, k_rope, v,
                                           n_head, scale))
+
+
+@register_op("gated_delta_rule")
+def gated_delta_rule(ctx, ins, attrs):
+    """The mixer core of a gated-delta-rule linear-attention layer
+    (arXiv:2412.06464), over one sequence a row.  QKV (N, T, 2 Hk Dk +
+    Hv Dv): the convolved projection, q, k (Hk heads of Dk) and v (Hv
+    heads of Dv) side by side; BA (N, T, 2 Hv): b then a, one of each a
+    value head; ALog, DtBias (Hv,).  In float32:
+
+        q = l2norm(q) * Dk^-1/2;  k = l2norm(k)         (eps 1e-6, a head)
+        beta = sigmoid(b);  g = -exp(ALog) * softplus(a + DtBias)
+        S'_t = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S'_t^T k_t)
+        S_t = S'_t + k_t u_t^T;   Out_t = S_t^T q_t      (N, T, Hv Dv)
+
+    with S (Dk, Dv) a value head from 0, value head h reading key head
+    h // (Hv / Hk).  The scan runs in chunks of 64 positions
+    (`ops/pallas/gated_delta.py`): its dots in QKV's dtype, state and
+    decay in float32.  `use_pallas` sends the sequential part to the
+    Pallas kernels there (Dk = Dv = 128); without it, it is a
+    `lax.scan` over the chunks."""
+    from .pallas import gated_delta
+
+    qkv, ba = first(ins, "QKV"), first(ins, "BA")
+    a_log, dt_bias = first(ins, "ALog"), first(ins, "DtBias")
+    hk, hv = int(attrs["n_key_head"]), int(attrs["n_value_head"])
+    dk, dv = int(attrs["key_dim"]), int(attrs["value_dim"])
+    n, t, width = qkv.shape
+    if width != 2 * hk * dk + hv * dv or ba.shape != (n, t, 2 * hv) \
+            or hv % hk:
+        raise ValueError(
+            f"gated_delta_rule: QKV {qkv.shape} and BA {ba.shape} are not "
+            f"{hk} key heads of {dk}, {hv} value heads of {dv} and two "
+            f"gates a value head")
+    f32 = jnp.float32
+
+    def l2norm(x):
+        x = x.astype(f32).reshape(n, t, hk, dk)
+        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+    q = (l2norm(qkv[..., :hk * dk]) * dk ** -0.5).astype(qkv.dtype)
+    k = l2norm(qkv[..., hk * dk:2 * hk * dk]).astype(qkv.dtype)
+    v = qkv[..., 2 * hk * dk:].reshape(n, t, hv, dv)
+    beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
+    g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+        ba[..., hv:].astype(f32) + dt_bias.astype(f32))
+    o = gated_delta.gated_delta_rule(
+        q, k, v, g, beta, use_kernel=bool(attrs.get("use_pallas", False)))
+    return out(Out=o.reshape(n, t, hv * dv))

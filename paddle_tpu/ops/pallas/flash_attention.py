@@ -257,6 +257,25 @@ def _vmem_params(accumulator_bytes, block_q, block_k):
         vmem_limit_bytes=_VMEM_LIMIT)}
 
 
+def _fwd_vmem_params(block_q, block_k, d, itemsize):
+    """`pallas_call` keywords of a forward kernel, by the same rule of
+    thumb: its q, o, k, v tiles twice over (the pipeline's two
+    buffers), its float32 accumulator and two score blocks.  Past
+    Mosaic's default 16 MiB it claims the limit: float32 operands at
+    d_head 256 in 1024 x 1024 blocks (17 MiB by this count, 27.5 by
+    Mosaic's: the parity scripts' "highest" run of a grouped call; the
+    chip refused it unasked, PR 44).  bfloat16 at d_head 256 (13 MiB)
+    and every call at d_head 128 fit and ask for nothing, so their
+    steps are scheduled as they were."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    tiles = 2 * 2 * (block_q + block_k) * d * itemsize
+    if tiles + 4 * block_q * d + 2 * 4 * block_q * block_k <= 16 << 20:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_VMEM_LIMIT)}
+
+
 def _offs(offs_ref):
     """(q_off, k_off) global position offsets from the SMEM scalar input
     (zero when no offsets were passed)."""
@@ -476,6 +495,7 @@ def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        **_fwd_vmem_params(block_q, block_k, d, q.dtype.itemsize),
     )(*args)
     return o, lse8
 

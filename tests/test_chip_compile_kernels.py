@@ -1,6 +1,8 @@
 """The chip's compiler on the other kernel families, each alone: latent
-attention (`ops/pallas/flash_mla.py`) at `joyai-8k`'s shape, the fused
-vocabulary cross-entropy, paged attention, the fused LSTM recurrence;
+attention (`ops/pallas/flash_mla.py`) at `joyai-8k`'s shape, the chunked
+delta-rule scan (`ops/pallas/gated_delta.py`) and grouped flash
+attention at d_head 256 at `qwen3next-16k`'s, the fused vocabulary
+cross-entropy, paged attention, the fused LSTM recurrence;
 and the cost table over whole steps it compiled (every Mosaic kernel has
 a registered cost, the TPU's dots are matmul rows).  tests/chip_compile.py
 says why and how, and why these share a file.
@@ -76,6 +78,105 @@ def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
     assert f"[{n},{t},{heads * 192}]" not in text
     if dtype == BF16:
         assert f"bf16[{n},{t},64]" in text      # the rotary key's gradient
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
+    """What `qwen3next-16k`'s step hands the chip's compiler that no
+    other cell does, first half: the `gated_delta_rule` op at 1 x 16384
+    positions, 16 key and 32 value heads of 128, in the cell's bfloat16
+    and in the parity script's float32 at "highest".  Three Mosaic
+    kernels under a gradient: the forward rule's `gated_delta_fwd`
+    (which also writes the 256 chunk-entry states a head) and
+    `gated_delta_bwd`; each a grid of 32 heads x 32 blocks of 8 chunks
+    carrying a (128, 128) float32 state in VMEM scratch."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    n, t, hk, hv, d = 1, 16384, 16, 32, 128
+    impl = get_op_impl("gated_delta_rule")
+
+    def loss(qkv, ba, a_log, dt_bias):
+        with jax.named_scope("linear_attention/gated_delta_rule:9"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"QKV": [qkv], "BA": [ba], "ALog": [a_log],
+                      "DtBias": [dt_bias]},
+                     {"n_key_head": hk, "n_value_head": hv, "key_dim": d,
+                      "value_dim": d, "use_pallas": True})["Out"][0]
+        return jnp.sum(o.astype(F32))
+
+    args = [jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
+            for shape, kind in (((n, t, (2 * hk + hv) * d), dtype),
+                                ((n, t, 2 * hv), dtype), ((hv,), F32),
+                                ((hv,), F32))]
+    prec = "default" if dtype == BF16 else "highest"
+    before = runtime_stats.snapshot()
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))) \
+            .lower(*args).compile()
+    took = runtime_stats.delta(before)
+    # the forward rule's call and the backward's: 256 chunks x 32 heads
+    assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (
+        2, 2 * 256 * 32)
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "gated_delta_bwd", "gated_delta_fwd"]
+    assert {r["op_type"] for r in rows if r["kernel"]} == {
+        "gated_delta_rule"}
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 2
+    chunk = (3 + 6) * 2 * 64 * d * d + (1 + 2) * 2 * 64 * 64 * d
+    assert totals["pallas_flops"] == 256 * 32 * chunk
+    # the states that enter the chunks, in the operands' dtype
+    kind = "bf16" if dtype == BF16 else "f32"
+    assert f"{kind}[{hv},{256 * d},{d}]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_grouped_flash_at_head_dim_256_takes_the_two_backward_kernels(
+        one_chip, dtype):
+    """Second half: causal flash attention at 16 query heads of 256
+    over 2 key/value heads, 1 x 16384.  One head's dq with its key/value
+    head's dk and dv, whole sequences of float32, is 48 MiB at this head
+    size, past the single backward kernel's 32 MiB: the shape rule
+    (`band_backward_fits`) takes `flash_dkv` and `flash_dq`, which hold
+    blocks only, and Mosaic takes their 1024 x 1024 score blocks at a
+    256-deep contraction; in the parity script's float32 the FORWARD
+    kernel's tiles pass Mosaic's default 16 MiB of scoped VMEM too
+    (27.5 MiB: the chip refused the call, PR 44) and it claims the limit
+    (`_fwd_vmem_params`), which the bfloat16 call does not."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas.flash_attention import (
+        band_backward_fits, pallas_flash_attention)
+
+    n, t, h, hkv, d = 1, 16384, 16, 2, 256
+    assert not band_backward_fits(t, d) and band_backward_fits(t, 128)
+
+    def loss(q, k, v):
+        return jnp.sum(pallas_flash_attention(
+            q, k, v, causal=True, layout="nthd", n_head=h,
+            n_kv_head=hkv).astype(F32))
+
+    before = runtime_stats.snapshot()
+    args = [jax.ShapeDtypeStruct((n, t, heads * d), dtype, sharding=one_chip)
+            for heads in (h, hkv, hkv)]
+    prec = "default" if dtype == BF16 else "highest"
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile().as_text()
+    took = runtime_stats.delta(before)
+    from paddle_tpu.ops.pallas.flash_attention import _fwd_vmem_params
+
+    assert bool(_fwd_vmem_params(1024, 1024, d, 4)) and not any(
+        _fwd_vmem_params(1024, 1024, width, size)
+        for width, size in ((256, 2), (128, 4), (128, 2)))
+    assert (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"]) == (0, 1)
+    assert _kernels(text) == 3
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert f"pallas_{kernel}" in text
 
 
 def test_fused_vocab_ce_fwd_bwd(one_chip):

@@ -996,6 +996,12 @@ S["swiglu"] = dict(ins={"X": f32((2, 5), 271), "Y": f32((2, 5), 272)},
 S["short_conv"] = dict(
     ins={"X": f32((2, 5, 12), 278), "Filter": f32((4, 3), 279)},
     out="Out", shape=(2, 5, 4), grad=["X", "Filter"], tol=2e-2)
+S["gated_delta_rule"] = dict(
+    ins={"QKV": f32((1, 5, 16), 285), "BA": f32((1, 5, 4), 286),
+         "ALog": f32((2,), 287), "DtBias": f32((2,), 288)},
+    attrs={"n_key_head": 1, "n_value_head": 2, "key_dim": 4,
+           "value_dim": 4}, out="Out", shape=(1, 5, 8),
+    grad=["QKV", "BA", "ALog", "DtBias"], tol=2e-2)
 S["moe_dropless"] = dict(
     ins={"X": f32((6, 8), 273), "GateW": f32((8, 4), 274),
          "W1": f32((4, 8, 6), 275), "W3": f32((4, 8, 6), 276),

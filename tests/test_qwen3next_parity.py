@@ -1,0 +1,555 @@
+"""The hybrid linear-attention MoE decoder on the normal path
+(`models/decoder.py` with `layer_types` holding `linear_attention`, the
+`linear_*` keys, `partial_rotary_factor`, `zero_centered_norm`,
+`attention_gate`, `shared_expert_intermediate_size` and
+`shared_expert_gate`) against its plain float32 reference
+(`benchmarks/reference_qwen3next.py`, whose recurrence is a scan over
+POSITIONS) on the CPU at a small size, seeded random weights; the
+chunked scan (`ops/pallas/gated_delta.py`), kernels in interpret mode
+and XLA lowering alike, against the sequential recurrence; the share
+test of the `model-configs` guide; the norm's zero-centred scale; and
+every new key's unbuilt values.
+
+Sizes of the preset: d 64, one period (linear x 3, full), 2 key / 4
+value linear heads of 16, 2 query heads over 1 key/value head of 32
+with 8 rotated lanes, 16 experts of which 4 are held (rank 1 of 4), 3 a
+token, a shared expert of 24 with its gate, T 80 (a chunk and a quarter:
+the padded tail).
+
+Tolerance.  Float32: both sides are float32 with matmuls at "highest"
+and differ in summation order only (chunks against positions, the
+flash kernel's online soft-max, the sorted expert rows): 5e-6
+absolute-or-relative, as tests/test_mellum_parity.py (largest seen
+here 4e-8 on a gradient, 2e-6 on the logits).
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu.models import decoder
+from paddle_tpu.ops.pallas import gated_delta
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                "benchmarks"))
+sys.path.insert(0, os.path.dirname(__file__))
+import reference_qwen3next as ref  # noqa: E402
+
+TOL = 5e-6
+NO_AUX = dict(aux_loss_weight=0.0, z_loss_weight=0.0)
+EQUATIONS = dict(qk_norm="head", router="softmax", zero_centered_norm=True,
+                 attention_gate="sigmoid", shared_expert_gate="sigmoid")
+SHARES = {"whole-layer": dict(num_experts=16),
+          "rank-1-of-4": dict(num_experts=4, expert_parallel_size=4,
+                              expert_parallel_rank=1)}
+PERIOD = ["linear_attention"] * 3 + ["full_attention"]
+
+
+def config(**over):
+    cfg = dict(hidden_size=64, num_hidden_layers=4, num_attention_heads=2,
+               num_key_value_heads=1, head_dim=32,
+               partial_rotary_factor=0.25, rope_theta=100.0,
+               intermediate_size=96, moe_intermediate_size=32,
+               shared_expert_intermediate_size=24, num_experts=16,
+               num_experts_per_tok=3, norm_topk_prob=True,
+               rms_norm_eps=1e-6, vocab_size=96, linear_num_key_heads=2,
+               linear_num_value_heads=4, linear_key_head_dim=16,
+               linear_value_head_dim=16, linear_conv_kernel_dim=4,
+               layer_types=list(PERIOD), **EQUATIONS)
+    cfg.update(over)
+    return cfg
+
+
+def reference_config(cfg):
+    return dict(cfg, full_attention_interval=4)
+
+
+def batch(cfg, n=2, length=80, seed=0):
+    ids = np.random.default_rng(seed).integers(
+        1, cfg["vocab_size"], size=(n, length + 1))
+    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+
+
+def system(cfg, feed, seed=7, **build):
+    """One forward and backward of the Program: what was fetched and
+    the parameters in creation order.  Parameters that start at a
+    constant (the norms' scales, `dt_bias`) are moved off it first, so
+    that a scale of 1 + w with w = 0 is not all that is compared."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = seed
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        m = decoder.build_model(max_length=feed["tokens"].shape[1],
+                                with_optimizer=False, **NO_AUX, **build,
+                                **cfg)
+        grads = [g for _, g in fluid.append_backward(m["loss"])]
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        rng = np.random.default_rng(seed + 1)
+        for p in main.all_parameters():
+            value = np.asarray(scope.find_var(p.name))
+            if value.std() == 0:
+                scope.set_var(p.name, jnp.asarray(
+                    value + 0.1 * rng.normal(size=value.shape)
+                    .astype(np.float32)))
+        params = [np.asarray(scope.find_var(p.name))
+                  for p in main.all_parameters()]
+        routed = len(m["counts"])
+        fetched = exe.run(
+            main, feed=feed, scope=scope,
+            fetch_list=[m["loss"], m["logits"]] + m["counts"]
+            + m["experts"] + grads)
+    out = {"loss": fetched[0], "logits": fetched[1],
+           "counts": fetched[2:2 + routed],
+           "experts": fetched[2 + routed:2 + 2 * routed],
+           "grads": fetched[2 + 2 * routed:], "main": main}
+    return out, params
+
+
+def reference(cfg, feed, params, q_block=None):
+    cfg = reference_config(cfg)
+    tree = ref.params_from_list(params, cfg)
+    (total, parts), grads = ref.loss_and_grads(
+        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]), cfg,
+        q_block)
+    return total, parts, ref.flat_leaves(grads)
+
+
+def close(got, want, what, tol=TOL):
+    np.testing.assert_allclose(np.asarray(got).reshape(-1),
+                               np.asarray(want).reshape(-1),
+                               rtol=tol, atol=tol, err_msg=what)
+
+
+# -- (a) the builder's program against the reference ------------------------
+
+@pytest.mark.parametrize("recompute", [None, "layer"])
+@pytest.mark.parametrize("share", sorted(SHARES))
+def test_program_matches_the_float32_reference(share, recompute):
+    cfg = config(**SHARES[share])
+    feed = batch(cfg)
+    got, params = system(cfg, feed, recompute=recompute)
+    total, parts, grads = reference(cfg, feed, params)
+    close(got["logits"], parts["logits"], "logits")
+    close(got["loss"], total, "loss")
+    assert len(got["counts"]) == 4
+    for i in range(4):
+        np.testing.assert_array_equal(got["counts"][i],
+                                      np.asarray(parts["counts"][i]))
+        np.testing.assert_array_equal(
+            np.sort(got["experts"][i], axis=-1),
+            np.sort(np.asarray(parts["experts"][i]), axis=-1))
+    names = ref.leaf_names(reference_config(cfg))
+    assert len(got["grads"]) == len(grads) == len(params) == len(names)
+    for name, g, w in zip(names, got["grads"], grads):
+        # no vacuous match, but for a share's router (held constant
+        # by the builder on both sides: no exchange sums the ranks')
+        routerless = share != "whole-layer" and name.endswith(".router")
+        assert (np.abs(np.asarray(w)).max() > 0) != routerless, name
+        close(g, w, f"gradient of {name}")
+    # the mixers' parameters, in creation order, by shape
+    held = SHARES[share]["num_experts"]
+    linear = [p.shape for p in params[1:10]]
+    assert linear == [(64,), (64, 128), (128, 4), (64, 64), (64, 8), (4,),
+                      (4,), (16,), (64, 64)]
+    full = [p.shape for p in params[-11 - 8:-11]]
+    assert full == [(64,), (64, 64), (32,), (64, 32), (32,), (64, 32),
+                    (64, 64), (64, 64)]
+    sparse = [p.shape for p in params[-11:-2]]
+    assert sparse[:2] == [(64,), (64, 16)]
+    assert sparse[2] == (held, 64, 32)
+    assert sparse[5:] == [(64, 24), (64, 24), (24, 64), (64, 1)]
+
+
+def test_the_reference_in_runs_and_recomputed_gives_the_same_gradients():
+    """What `benchmarks/qwen3next_parity.py` runs on the chip so that
+    16384 positions fit: scores `q_block` rows at a time, the recurrence
+    in recomputed runs of `q_block` positions (one state kept a run),
+    every layer recomputed in its backward pass.  Same numbers."""
+    cfg = config(**SHARES["rank-1-of-4"])
+    feed = batch(cfg)
+    _, params = system(cfg, feed)
+    plain, _, want = reference(cfg, feed, params)
+    blocked, _, got = reference(cfg, feed, params, q_block=16)
+    close(blocked, plain, "loss")
+    for w, g in zip(want, got):
+        close(g, w, "gradient")
+
+
+def test_the_two_kinds_of_layer_lower_under_scopes_of_their_own():
+    """`linear_attention` / `gated_attention` / `shared_expert` name
+    scopes around the mixers and the shared expert's ops; at heads of
+    16 the scan is the XLA lowering and counts no kernel call."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    cfg = config(**SHARES["rank-1-of-4"])
+    before = runtime_stats.snapshot()
+    got, _ = system(cfg, batch(cfg, n=1))
+    took = runtime_stats.delta(before)
+    assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (0, 0)
+    found = [op.attrs.get("__name_scope__", "") for b in got["main"].blocks
+             for op in b.ops]
+    by_type = {s: [op.type for b in got["main"].blocks for op in b.ops
+                   if op.attrs.get("__name_scope__", "") == s]
+               for s in set(found)}
+    assert by_type["linear_attention"].count("gated_delta_rule") == 3
+    assert by_type["linear_attention"].count("short_conv") == 3
+    assert by_type["gated_attention"].count("flash_attention") == 1
+    assert by_type["gated_attention"].count("sigmoid") == 1
+    assert by_type["shared_expert"].count("sigmoid") == 4
+    assert "full_attention" not in by_type
+
+
+def test_a_step_counts_three_kernel_calls_a_linear_layer_and_a_build_none():
+    """At heads of 128 x 128 the scan is the Pallas kernels' (interpret
+    mode here): building the Program traces no call (the op is not
+    shape-inferred at the stand-in batch), the first step's build a
+    layer's forward, recomputed forward and backward, a second step
+    none.  What `gated_delta_chunks_per_step` reads off a process."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    cfg = config(num_hidden_layers=2, linear_num_key_heads=1,
+                 linear_num_value_heads=2, linear_key_head_dim=128,
+                 linear_value_head_dim=128,
+                 layer_types=["linear_attention", "full_attention"],
+                 **SHARES["rank-1-of-4"])
+    feed = batch(cfg, n=1, length=128)
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    marks = [runtime_stats.snapshot()]
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        m = decoder.build_model(max_length=128, warmup_steps=2,
+                                recompute="layer", **NO_AUX, **cfg)
+        marks.append(runtime_stats.snapshot())
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        for _ in range(2):
+            exe.run(main, feed=feed, scope=scope, fetch_list=[m["loss"]])
+            marks.append(runtime_stats.snapshot())
+    took = [(b["gated_delta_calls"] - a["gated_delta_calls"],
+             b["gated_delta_chunks"] - a["gated_delta_chunks"])
+            for a, b in zip(marks, marks[1:])]
+    assert took == [(0, 0), (3, 3 * 2 * 2), (0, 0)]
+
+
+# -- (b) the chunked scan against the sequential recurrence -----------------
+
+def sequential(q, k, v, g, beta):
+    """The recurrence as it is written, a position at a time."""
+    r = v.shape[2] // k.shape[2]
+    return ref.delta_rule(jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2),
+                          v, g, beta)
+
+
+def scan_case(t, decay, seed=0, hk=1, hv=2, d=gated_delta.HEAD_DIM):
+    """q, k unit vectors a head (q over sqrt(d)), v N(0, 1), beta in
+    (0, 1); `decay` "far": g drawn so that a chunk's exp(gamma_C) passes
+    1e-6; "none": g = 0, the plain delta rule; else mild."""
+    r = np.random.default_rng(seed)
+    q, k = r.normal(size=(2, 1, t, hk, d))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(d)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = r.normal(size=(1, t, hv, d))
+    g = -np.abs(r.normal(size=(1, t, hv))) \
+        * {"far": 0.44, "none": 0.0, "mild": 0.05}[decay]
+    beta = 1 / (1 + np.exp(-r.normal(size=(1, t, hv))))
+    return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
+
+
+@pytest.mark.parametrize("lowering", ["xla", "kernel"])
+@pytest.mark.parametrize("t,decay", [(64, "mild"), (256, "far"),
+                                     (256, "none"), (200, "mild")],
+                         ids=["T=C", "T=4C-far-decay", "T=4C-no-decay",
+                              "T=200-padded-tail"])
+def test_the_chunked_scan_is_the_sequential_recurrence(lowering, t, decay):
+    """Forward and the gradients of q, k, v, g and beta, the Pallas
+    kernels (interpret mode) and the XLA lowering of the same chunks."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    args = scan_case(t, decay)
+    if decay == "far":
+        per_chunk = jnp.exp(args[3].reshape(1, -1, 64, 2).sum(axis=2))
+        assert float(per_chunk.min()) < 1e-6
+    weight = jnp.asarray(np.random.default_rng(9).normal(
+        size=(1, t, 2, gated_delta.HEAD_DIM)), jnp.float32)
+
+    def chunked(*a):
+        return gated_delta.gated_delta_rule(*a,
+                                            use_kernel=lowering == "kernel")
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a) * weight)
+
+    before = runtime_stats.snapshot()
+    got = chunked(*args)
+    got_grads = jax.grad(scalar(chunked), argnums=range(5))(*args)
+    took = runtime_stats.delta(before)
+    want = sequential(*args)
+    want_grads = jax.grad(scalar(sequential), argnums=range(5))(*args)
+    for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"),
+                          (got,) + got_grads, (want,) + want_grads):
+        scale = float(jnp.abs(b).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=0, atol=TOL * scale, err_msg=name)
+    chunks = 2 * -(-t // 64)            # heads x chunks, the tail padded
+    # a forward call, then the forward rule's and the backward kernel
+    assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (
+        (3, 3 * chunks) if lowering == "kernel" else (0, 0))
+
+
+def test_the_scan_in_bfloat16_misses_the_float32_tolerance():
+    """The operands' dtype is the dots': bfloat16 operands (AMP) stay
+    within 2% of the sequential recurrence and miss TOL by far."""
+    args = scan_case(256, "mild")
+    want = sequential(*args)
+    low = [x.astype(jnp.bfloat16) for x in args[:3]] + args[3:]
+    for use_kernel in (False, True):
+        got = gated_delta.gated_delta_rule(*low, use_kernel=use_kernel)
+        err = float(jnp.abs(got.astype(jnp.float32) - want).max()
+                    / jnp.abs(want).max())
+        assert 100 * TOL < err < 0.02, err
+
+
+def test_the_inverse_and_its_own_gradient():
+    """(I + A)^-1 against numpy's, and its VJP (-M^T dM M^T) against
+    the gradient of the series sum_k (-A)^k it stands for."""
+    r = np.random.default_rng(2)
+    a = np.tril(r.normal(size=(3, 64, 64)) * 0.2, -1).astype(np.float32)
+    want = np.linalg.inv(np.eye(64) + a.astype(np.float64))
+    got = gated_delta.unit_lower_inverse(jnp.asarray(a))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    ct = jnp.asarray(r.normal(size=a.shape), jnp.float32)
+
+    def series(x):              # A is nilpotent: 64 terms are all
+        term = total = jnp.broadcast_to(jnp.eye(64, dtype=x.dtype), x.shape)
+        for _ in range(63):
+            term = -term @ x
+            total = total + term
+        return total
+
+    own = jax.grad(lambda x: jnp.sum(
+        gated_delta.unit_lower_inverse(x) * ct))(jnp.asarray(a))
+    plain = jax.grad(lambda x: jnp.sum(series(x) * ct))(jnp.asarray(a))
+    np.testing.assert_allclose(own, plain, rtol=1e-4, atol=1e-4)
+
+
+def test_the_kernels_take_heads_of_128_only():
+    assert gated_delta.kernel_takes(128, 128)
+    assert not gated_delta.kernel_takes(16, 16)
+    with pytest.raises(NotImplementedError, match="heads of 128"):
+        gated_delta.gated_delta_rule(*scan_case(64, "mild", d=16),
+                                     use_kernel=True)
+    with pytest.raises(ValueError, match="key heads"):
+        gated_delta.gated_delta_rule(*scan_case(64, "mild", hk=2, hv=3))
+
+
+# -- (c) the share test -----------------------------------------------------
+
+E, RANKS, K, D, H, HS, T = 16, 4, 3, 64, 32, 24, 40
+HELD = E // RANKS
+
+
+def whole_block(seed=0):
+    r = np.random.default_rng(seed)
+
+    def draw(*shape, scale=0.3):
+        return jnp.asarray(r.normal(size=shape).astype(np.float32) * scale)
+
+    return {"x": draw(T, D, scale=1.0), "router": draw(D, E, scale=0.25),
+            "w1": draw(E, D, H), "w3": draw(E, D, H), "w2": draw(E, H, D),
+            "shared_w1": draw(D, HS), "shared_w3": draw(D, HS),
+            "shared_w2": draw(HS, D), "shared_gate": draw(D, 1)}
+
+
+def routed_part(p, rank):
+    """One rank's routed part, through the op the builder appends."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+
+    lo = rank * HELD
+    o = get_op_impl("moe_dropless")(
+        OpContext(jax.random.PRNGKey(0), 0),
+        {"X": [p["x"]], "GateW": [p["router"]],
+         **{k.upper(): [p[k][lo:lo + HELD]] for k in ("w1", "w3", "w2")}},
+        {"routing": "softmax", "norm_topk_prob": True, "top_k": K,
+         "experts_held": [lo, HELD]})
+    return o["Out"][0], o["Counts"][0]
+
+
+def gated_shared_expert(p):
+    """The shared expert as the builder composes it: `mul`, `mul`,
+    `swiglu`, `mul`, times `sigmoid` of a 1-wide `mul`."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+
+    ctx = OpContext(jax.random.PRNGKey(0), 0)
+
+    def op(kind, **ins):
+        return get_op_impl(kind)(ctx, {k: [v] for k, v in ins.items()},
+                                 {"axis": -1})["Out"][0]
+
+    hidden = op("swiglu", X=op("mul", X=p["x"], Y=p["shared_w1"]),
+                Y=op("mul", X=p["x"], Y=p["shared_w3"]))
+    gate = op("sigmoid", X=op("mul", X=p["x"], Y=p["shared_gate"]))
+    return op("elementwise_mul", X=op("mul", X=hidden, Y=p["shared_w2"]),
+              Y=gate)
+
+
+def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_block():
+    """The `model-configs` guide's tie of the share to the model: the
+    routed parts of all 16 / 4 = 4 shares of the preset plus the gated
+    shared expert COUNTED ONCE are the uncut reference's sparse block;
+    summed as each rank adds it, the shared expert counts four times."""
+    p = whole_block()
+    cfg = {"num_experts_per_tok": K, "norm_topk_prob": True}
+    with jax.default_matmul_precision("highest"):
+        want, counts, _ = ref.experts(p["x"], p, cfg)
+    parts = [routed_part(p, r) for r in range(RANKS)]
+    shared = gated_shared_expert(p)
+    total = sum(np.asarray(y, np.float64) for y, _ in parts) \
+        + np.asarray(shared, np.float64)
+    np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(c) for _, c in parts]),
+        np.asarray(counts))
+    assert sum(int(c.sum()) for _, c in parts) == T * K
+    streams = sum(np.asarray(y + shared, np.float64) for y, _ in parts)
+    np.testing.assert_allclose(
+        streams, np.asarray(want) + (RANKS - 1) * np.asarray(shared),
+        rtol=2e-5, atol=2e-5)
+    assert np.abs(np.asarray(shared)).max() > 0.1
+    # one rank's share of the reference is that rank's part and the
+    # shared expert whole
+    held = dict(p, **{k: p[k][HELD:2 * HELD] for k in ("w1", "w3", "w2")})
+    with jax.default_matmul_precision("highest"):
+        one, _, _ = ref.experts(p["x"], held,
+                                dict(cfg, expert_parallel_rank=1))
+    np.testing.assert_allclose(np.asarray(parts[1][0] + shared), one,
+                               rtol=2e-5, atol=2e-5)
+
+
+# -- (d) the zero-centred norm ----------------------------------------------
+
+def test_a_zero_centred_scale_is_rms_norm_with_one_plus_w():
+    from op_test import run_op
+
+    r = np.random.default_rng(5)
+    x = r.normal(size=(2, 6, 32)).astype(np.float32)
+    w = (0.2 * r.normal(size=(32,))).astype(np.float32)
+    gate = r.normal(size=(2, 6, 32)).astype(np.float32)
+    plain = run_op("rms_norm", {"X": x, "Scale": 1 + w}, {"epsilon": 1e-6},
+                   out_slot="Y")
+    zero = run_op("rms_norm", {"X": x, "Scale": w},
+                  {"epsilon": 1e-6, "zero_centered": True}, out_slot="Y")
+    np.testing.assert_allclose(zero, plain, rtol=1e-6, atol=1e-6)
+    # a head at a time (16 lanes under one (16,) scale), gated
+    heads = run_op("rms_norm", {"X": x, "Scale": w[:16], "Gate": gate},
+                   {"epsilon": 1e-6, "group_size": 16,
+                    "zero_centered": True}, out_slot="Y")
+    x4 = x.reshape(2, 6, 2, 16)
+    want = (x4 / np.sqrt((x4 ** 2).mean(-1, keepdims=True) + 1e-6)
+            * (1 + w[:16])).reshape(x.shape) * gate / (1 + np.exp(-gate))
+    np.testing.assert_allclose(heads, want, rtol=2e-6, atol=2e-6)
+
+
+def test_weight_decay_pulls_a_zero_centred_scale_to_zero_and_the_norm_to_one():
+    """The layer's parameter starts at 0, and a step of pure decay
+    (no gradient reaches it: its output is not in the loss) moves it
+    TOWARD 0: the scale it stands for goes to 1, where a plain scale
+    would go to 0."""
+    main, startup = fluid.Program(), fluid.Program()
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        y = fluid.layers.rms_norm(x, epsilon=1e-6, zero_centered=True)
+        loss = fluid.layers.mean(fluid.layers.scale(y, scale=0.0))
+        fluid.optimizer.AdamOptimizer(learning_rate=0.1,
+                                      weight_decay=0.5).minimize(loss)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        (name,) = [p.name for p in main.all_parameters()]
+        assert not np.asarray(scope.find_var(name)).any()     # from 0
+        start = np.linspace(-0.4, 0.4, 8).astype(np.float32)
+        scope.set_var(name, jnp.asarray(start))
+        exe.run(main, feed={"x": np.ones((2, 8), np.float32)}, scope=scope,
+                fetch_list=[loss])
+        after = np.asarray(scope.find_var(name))
+    np.testing.assert_allclose(after, start * (1 - 0.1 * 0.5), rtol=1e-5,
+                               atol=1e-7)
+    assert (np.abs(after) <= np.abs(start)).all()
+
+
+def test_rope_over_a_part_of_the_head_leaves_the_rest():
+    from op_test import run_op
+
+    x = np.random.default_rng(3).normal(size=(1, 6, 64)).astype(np.float32)
+    part = run_op("rope", {"X": x}, {"n_head": 2, "theta": 100.0,
+                                     "rotary_dim": 8})
+    x4, p4 = x.reshape(1, 6, 2, 32), np.asarray(part).reshape(1, 6, 2, 32)
+    np.testing.assert_array_equal(p4[..., 8:], x4[..., 8:])
+    small = run_op("rope", {"X": x4[..., :8].reshape(1, 6, 16)},
+                   {"n_head": 2, "theta": 100.0})
+    np.testing.assert_allclose(p4[..., :8].reshape(1, 6, 16), small,
+                               rtol=1e-6, atol=1e-6)
+    with pytest.raises(ValueError, match="rotary_dim"):
+        run_op("rope", {"X": x}, {"n_head": 2, "rotary_dim": 7})
+
+
+def test_the_ungated_convolution_is_the_gated_ones_convolution():
+    from op_test import run_op
+
+    r = np.random.default_rng(4)
+    x = r.normal(size=(2, 9, 6)).astype(np.float32)
+    w = r.normal(size=(6, 4)).astype(np.float32)
+    got = run_op("short_conv", {"X": x, "Filter": w}, {"activation": "silu"})
+    want = np.asarray(ref.causal_conv(jnp.asarray(x), jnp.asarray(w)))
+    np.testing.assert_allclose(got, want / (1 + np.exp(-want)), rtol=1e-5,
+                               atol=1e-6)
+    ones = np.ones_like(x)
+    gated = run_op("short_conv",
+                   {"X": np.concatenate([ones, ones, x], -1), "Filter": w}, {})
+    np.testing.assert_allclose(gated, want, rtol=1e-5, atol=1e-6)
+    with pytest.raises(NotImplementedError, match="activation"):
+        run_op("short_conv", {"X": x, "Filter": w}, {"activation": "gelu"})
+
+
+# -- (e) every new key's unbuilt values raise -------------------------------
+
+@pytest.mark.parametrize("over,error,match", [
+    (dict(attention_gate="tanh"), NotImplementedError, "attention_gate"),
+    (dict(shared_expert_gate="softmax"), NotImplementedError,
+     "shared_expert_gate"),
+    (dict(n_shared_experts=1), ValueError, "given twice"),
+    (dict(partial_rotary_factor=0.0), ValueError, "partial_rotary_factor"),
+    (dict(partial_rotary_factor=1.5), ValueError, "partial_rotary_factor"),
+    (dict(partial_rotary_factor=0.25, head_dim=20), ValueError,
+     "whole number of pairs"),
+    (dict(rope_parameters={"rope_type": "yarn", "rope_theta": 100.0,
+                           "factor": 4.0,
+                           "original_max_position_embeddings": 16}),
+     NotImplementedError, "part of the head"),
+    (dict(linear_conv_kernel_dim=None), ValueError, "linear_conv_kernel_dim"),
+    (dict(linear_num_value_heads=3), ValueError, "multiple"),
+    (dict(layer_types=["linear_attention"] * 3 + ["state_space"]),
+     NotImplementedError, "state_space"),
+    (dict(attention_gate="sigmoid", kv_lora_rank=8, q_lora_rank=8,
+          qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+          num_key_value_heads=2), NotImplementedError, "latent"),
+], ids=["attention_gate", "shared_expert_gate", "shared-width-twice",
+        "rotary-0", "rotary-over-1", "rotary-odd", "rotary-part-yarn",
+        "linear-keys-missing", "linear-heads", "layer-type", "gate-on-latent"])
+def test_unbuilt_values_of_the_new_keys_raise(over, error, match):
+    cfg = config(**over)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        with pytest.raises(error, match=match):
+            decoder.build_model(max_length=16, with_optimizer=False, **NO_AUX,
+                                **cfg)
