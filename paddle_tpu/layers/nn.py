@@ -361,7 +361,8 @@ def gated_delta_rule(qkv, ba, n_key_head, n_value_head, key_dim, value_dim,
     one of each a value head.  Two learned (Hv,) vectors: `A_log`, the
     log of the decay's rate, from log U(2^-10, 16), and `dt_bias`, from
     1.  Returns (N, T, Hv Dv).  `use_pallas`: the kernels of
-    ops/pallas/gated_delta.py (Dk = Dv = 128)."""
+    ops/pallas/gated_delta.py (Dk = Dv = 128; the chunk-local part's
+    too where Hv = 2 Hk)."""
     from ..initializer import LogUniform
 
     helper = LayerHelper("gated_delta_rule", name=name)

@@ -71,6 +71,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_attention_backward_fused", "flash_attention_backward_split",
     "flash_window_blocks_visited", "flash_window_blocks_allowed",
     "gated_delta_calls", "gated_delta_chunks",
+    "gated_delta_operand_calls", "gated_delta_operand_chunks",
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "loop_trips")
@@ -157,6 +158,11 @@ class RuntimeStats:
         # the scan reads 0 (delta() around a build)
         self.gated_delta_calls = 0
         self.gated_delta_chunks = 0
+        # the same for the kernels of the scan's chunk-local part
+        # (`gated_delta_operands_fwd` / `_bwd`); 0 where XLA's
+        # `chunk_operands` ran
+        self.gated_delta_operand_calls = 0
+        self.gated_delta_operand_chunks = 0
         # attention calls traced inside a recompute segment, whose
         # backward pass therefore keeps the kernel's two residuals and
         # does not run its forward kernel again, and the bytes of those
@@ -245,6 +251,11 @@ class RuntimeStats:
         with self._lock:
             self.gated_delta_calls += 1
             self.gated_delta_chunks += chunks
+
+    def record_gated_delta_operands(self, chunks: int):
+        with self._lock:
+            self.gated_delta_operand_calls += 1
+            self.gated_delta_operand_chunks += chunks
 
     def record_kept_residuals(self, nbytes: int):
         with self._lock:

@@ -312,10 +312,13 @@ def gated_delta_rule(ctx, ins, attrs):
 
     with S (Dk, Dv) a value head from 0, value head h reading key head
     h // (Hv / Hk).  The scan runs in chunks of 64 positions
-    (`ops/pallas/gated_delta.py`): its dots in QKV's dtype, state and
-    decay in float32.  `use_pallas` sends the sequential part to the
-    Pallas kernels there (Dk = Dv = 128); without it, it is a
-    `lax.scan` over the chunks."""
+    (`ops/pallas/gated_delta.py`): its dots in QKV's dtype, state,
+    decay and the chunk's inverse in float32.  `use_pallas` sends the
+    sequential part to the Pallas kernels there (Dk = Dv = 128) and,
+    where two value heads read a key head, the chunk-local part to its
+    own two (a chunk's matrices then stay in VMEM); without it the
+    first is a `lax.scan` over the chunks and the second XLA's batch
+    over all of them."""
     from .pallas import gated_delta
 
     qkv, ba = first(ins, "QKV"), first(ins, "BA")

@@ -23,31 +23,71 @@ for i >= j (no exponent is ever positive):
     O  = (Q * exp(gamma)) S + lower_incl(Q K^T * G) V'
     S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
-Two parts.  Everything that does not read S is a batch over all the
-chunks and stays with XLA (`chunk_operands`): the cumulative decay, the
-two (C, C) products a key head, the inverse, W, U and the two scaled
-copies of Q and K.  (I + A)^-1 has no Mosaic primitive: it is XLA's
-batched triangular solve against the identity (`unit_lower_inverse`,
-with a VJP of its own, dA = -M^T dM M^T).  What
-reads S is sequential over the chunks and is the kernel's: grid (batch x
-value head, blocks of `DEFAULT_BLOCK_CHUNKS` chunks), the state in a
-(Dk, Dv) float32 VMEM scratch across the grid as `recurrence.py` carries
-(h, c), four MXU dots a chunk.  Operands of the dots are the operands'
-dtype (bfloat16 under AMP), accumulation, S and gamma float32.
+Two parts, four kernels and one reference lowering of each part.
 
-Backward: a custom VJP around the sequential part alone (XLA
-differentiates the batch part).  The forward rule's kernel also writes
-the state that ENTERS each chunk (in the operands' dtype, which is what
-the dots read: 268 MB a layer at 16384 positions x 32 heads in
-bfloat16); the backward kernel walks the blocks in reverse carrying dS
-in scratch, rebuilds V' = U - W S from the saved state (one dot), and
-emits dW, dU, d(Q exp gamma), d(K exp ..), dP and d exp(gamma_C).
+**The chunk-local part**: everything that does not read S is a batch
+over all the chunks: the cumulative decay, the two (C, C) products a
+key head, the inverse, W, U, the two scaled copies of Q and K and
+P = lower_incl(Q K^T * G).  `chunk_operands` is its XLA lowering, which
+XLA differentiates: the reference the kernels are tested against, and
+what runs where `operand_kernels_take` is false.  There every (C, C)
+float32 matrix of a chunk is a tensor in HBM that the TPU's tiling pads
+to 128 lanes, (I + A)^-1 is XLA's batched triangular solve
+(`unit_lower_inverse`, with a VJP of its own, dA = -M^T dM M^T), and
+`chunk_decay` has a VJP of its own too.  `gated_delta_operands_fwd` /
+`gated_delta_operands_bwd` (since PR 45) are the same arithmetic with a
+chunk's matrices in VMEM: grid (batch x KEY head, blocks of chunks); a
+step holds a key head's q and k rows and the TWO value heads that read
+it, their two (C, C) matrices side by side in the 128 lanes of one
+float32 tile; q, k, v are read from the op's (N, T, H x 128) layout by
+lane block, K K^T and Q K^T are one MXU product a key head (the second
+head's copy is the MXU's, a product against [k; k]), and what leaves is
+what the scan kernels read: W, U, Qg, Kd, P in the operands' dtype.  The
+(C,)-vectors of a chunk (gamma, what is left of the chunk after a
+position, beta, g) come as one (8, 128) float32 tile a chunk and key
+head, which XLA makes (`_row_tiles`: a cumulative sum and a suffix sum
+over 2 MB, whose gradients XLA has), and exp(gamma_C) stays XLA's too.
+(I + A)^-1 is float32 forward substitution in blocks
+(`_inverse_side_by_side`): the diagonal blocks of `DIAGONAL_BLOCK` rows
+row by row on the VPU, all of them and both heads at once, then the
+blocks doubled by two MXU products a doubling at "highest".  The
+backward kernel is a `custom_vjp` around the part: the forward kernel
+also writes (I + A)^-1 (float32, two heads a tile: 134 MB a layer at
+16384 positions x 32 heads; the forward rule keeps it, alive inside a
+recompute segment's backward alone, and a call that is not
+differentiated drops it), the backward kernel rebuilds G, K K^T and
+Q K^T from q, k and the row tile, and returns dq and dk summed over
+the two value heads in VMEM, dv, and the row tile's gradient.  It keeps the two rules the XLA
+lowering has: dA = -M^T dM M^T, and the decay matrix's gradient summed
+over the pairs that straddle a position (it lands on the tile's `g`
+row, which the forward does not read; differentiating gamma_i - gamma_j
+term by term leaves their float32 cancellation in a decay parameter's
+gradient).
 
-The kernels take Dk = Dv = 128 (`kernel_takes`); any other head size
-and the CPU run `scan_xla`, the same chunk steps as a `lax.scan` that
-XLA differentiates.  `runtime_stats.gated_delta_calls` / `_chunks`
-count the kernel calls traced and their chunks x heads: a step that
-fell back reads 0.
+**The sequential part**: what reads S is sequential over the chunks:
+grid (batch x value head, blocks of `DEFAULT_BLOCK_CHUNKS` chunks), the
+state in a (Dk, Dv) float32 VMEM scratch across the grid as
+`recurrence.py` carries (h, c), four MXU dots a chunk
+(`gated_delta_fwd`).  Operands of the dots are the operands' dtype
+(bfloat16 under AMP), accumulation, S and gamma float32.  Backward: a
+custom VJP around the sequential part alone.  The forward rule's kernel
+also writes the state that ENTERS each chunk (in the operands' dtype,
+which is what the dots read: 268 MB a layer at 16384 positions x 32
+heads in bfloat16); the backward kernel (`gated_delta_bwd`) walks the
+blocks in reverse carrying dS in scratch, rebuilds V' = U - W S from
+the saved state (one dot), and emits dW, dU, d(Q exp gamma),
+d(K exp ..), dP and d exp(gamma_C).  `scan_xla` is its XLA lowering,
+the same chunk steps as a `lax.scan` that XLA differentiates.
+
+Which runs is the shape's alone: the scan kernels take Dk = Dv = 128
+(`kernel_takes`), the chunk-operand kernels besides that two value
+heads a key head (`operand_kernels_take`); any other head size and the
+CPU presets run the XLA lowerings.  `runtime_stats.gated_delta_calls`
+/ `_chunks` count the scan kernels' calls traced and their chunks x
+heads, `gated_delta_operand_calls` / `_operand_chunks` the
+chunk-operand kernels': a part that fell back reads 0.  The benchmark
+finds the scan kernels by the PREFIXES `gated_delta_fwd` /
+`gated_delta_bwd`: no other kernel's name may start with either.
 """
 
 from __future__ import annotations
@@ -72,7 +112,8 @@ def kernel_takes(dk, dv):
 
 # -- kernel cost registry (observe/cost.py) ----------------------------
 #
-# What the sequential part computes once, per chunk and head: forward
+# What the sequential part computes once, per chunk and head (the
+# chunk-local part's kernels say theirs at `operands_*_cost`): forward
 # W S, Q S, P V', K^T V' (three of 2 C Dk Dv and one of 2 C C Dv);
 # backward the forward's V' again is NOT credited, its eight products
 # are (dV' two, dP, dQ, dK, dW, dS two: six of 2 C Dk Dv, two of
@@ -94,11 +135,38 @@ def scan_bwd_cost(operand_shapes, result_shapes):
     return bh * t * (6 * 2.0 * dk * dv + 2 * 2.0 * CHUNK * dv), None
 
 
+def _operand_dims(operand_shapes):
+    (n, t, qw), _ = operand_shapes[0]
+    vw = operand_shapes[2][0][2]
+    return n * (qw // HEAD_DIM), n * (vw // HEAD_DIM), t
+
+
+def operands_fwd_cost(operand_shapes, result_shapes):
+    """K K^T and Q K^T a key head, W and U a value head, and the
+    substitution's C^3 / 3 multiply-adds a value head."""
+    bk, bh, t = _operand_dims(operand_shapes)
+    return t * (bk * 2 * 2.0 * CHUNK * HEAD_DIM
+                + bh * (2 * 2.0 * CHUNK * HEAD_DIM
+                        + 2.0 * CHUNK * CHUNK / 3)), None
+
+
+def operands_bwd_cost(operand_shapes, result_shapes):
+    """A value head: dT (two), dk', dv, and its share of the four
+    products that turn d(K K^T) and d(Q K^T) into dq and dk, of
+    2 C C D; the inverse's gradient (two) and the decay's pair sums, of
+    2 C^3.  The rebuilt K K^T and Q K^T are not credited."""
+    _, bh, t = _operand_dims(operand_shapes)
+    return bh * t * (8 * 2.0 * CHUNK * HEAD_DIM
+                     + 3 * 2.0 * CHUNK * CHUNK), None
+
+
 def _register_costs():
     from . import register_kernel_cost
 
     register_kernel_cost("gated_delta_fwd", scan_fwd_cost)
     register_kernel_cost("gated_delta_bwd", scan_bwd_cost)
+    register_kernel_cost("gated_delta_operands_fwd", operands_fwd_cost)
+    register_kernel_cost("gated_delta_operands_bwd", operands_bwd_cost)
 
 
 _register_costs()
@@ -230,6 +298,406 @@ def chunk_operands(q, k, v, g, beta):
     flat = lambda x, d: x.reshape(n * hv, t, d)  # noqa: E731
     return (flat(w, dk), flat(u, dv), flat(qg, dk), flat(kd, dk),
             flat(p, c), jnp.exp(last).reshape(n * hv, nc))
+
+
+# -- the batch part, as the kernels run it -----------------------------
+#
+# Two value heads read a key head (`operand_kernels_take`), and their
+# two (C, C) matrices lie side by side in one (C, 2C) float32 tile, 128
+# lanes: head 0 in lanes 0-63, head 1 in lanes 64-127 ("side by side"
+# below).  A product a head is one MXU product of such a tile with the
+# (2C, 2C) block-diagonal form of the other operand (`_block_diagonal`).
+
+def operand_kernels_take(hk, hv, dk, dv):
+    """Whether the chunk-operand kernels run a call: from the shape
+    alone (heads of 128, two value heads a key head)."""
+    return kernel_takes(dk, dv) and hv == 2 * hk
+
+
+# rows of the (8, 2C) float32 tile a chunk and key head carries into
+# the kernels, each side by side: the cumulative decay, what is left of
+# the chunk after a position, beta, and g itself (which no forward
+# reads: the decay matrix's gradient is summed over the pairs that
+# straddle a position and lands THERE, `chunk_decay`'s rule)
+ROW_GAMMA, ROW_REST, ROW_BETA, ROW_G = range(4)
+# rows a diagonal block of the substitution holds: the blocks' inverses
+# row by row on the VPU, the rest by block products on the MXU
+DIAGONAL_BLOCK = 16
+
+
+def _dot_hi(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _tile_iotas():
+    shape = (CHUNK, 2 * CHUNK)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return row, lane & (CHUNK - 1), lane < CHUNK
+
+
+def _block_diagonal(x, left):
+    """Side by side (C, 2C) -> [[x0, 0], [0, x1]] (2C, 2C)."""
+    zero = jnp.zeros_like(x)
+    return jnp.concatenate([jnp.where(left, x, zero),
+                            jnp.where(left, zero, x)], axis=0)
+
+
+def _side_by_side(x, left):
+    """The diagonal blocks of a (2C, 2C) product, side by side."""
+    return jnp.where(left, x[:CHUNK], x[CHUNK:])
+
+
+def _heads_product(a, b, left):
+    """a_h @ b_h a head, all three side by side."""
+    return _dot_hi(a, _block_diagonal(b, left), ((1,), (0,)))
+
+
+def _inverse_side_by_side(a, iotas, block=None):
+    """(I + A_h)^-1 a head of side-by-side strictly lower A: forward
+    substitution.  The diagonal blocks of `block` rows row by row, all
+    at once: B starts as I, and step j takes column j of every block
+    times the block's row j (final by then) off the rows below.  Then
+    the blocks double: with M the inverse so far and L the lower-left
+    quarter of each doubled block, the inverse is M - M L M.
+
+    (`lax` and not `jnp` in the steps: a `jnp.where` or a `jnp` index
+    is a jitted function of its own, and these sixty-odd steps are
+    traced and lowered in every warm start, `setup_s`.)"""
+    lax = jax.lax
+    block = block or DIAGONAL_BLOCK
+    row, col, left = iotas
+    lanes = 2 * CHUNK
+    one, zero = (jnp.full((CHUNK, lanes), x, jnp.float32) for x in (1., 0.))
+    m = lax.select(row == col, one, zero)
+
+    def wide(x, rows):          # a row or a column over (rows, lanes)
+        return lax.broadcast_in_dim(x, (rows, lanes), (0, 1))
+
+    for j in range(block - 1):
+        # rows up to j are final: leave the sublane tiles that hold
+        # nothing else alone
+        skip = (j + 1) // 8 * 8
+        rows = block - skip
+        first_head = lax.broadcasted_iota(jnp.int32, (rows, lanes), 1) < CHUNK
+        parts = []
+        for first in range(0, CHUNK, block):
+            at, top = first + j, first + skip
+            column = lax.select(
+                first_head,
+                wide(lax.slice(a, (top, at), (top + rows, at + 1)), rows),
+                wide(lax.slice(a, (top, CHUNK + at),
+                               (top + rows, CHUNK + at + 1)), rows))
+            pivot = wide(lax.slice(m, (at, 0), (at + 1, lanes)), rows)
+            if skip:
+                parts.append(lax.slice(m, (first, 0), (top, lanes)))
+            parts.append(lax.slice(m, (top, 0), (top + rows, lanes))
+                         - column * pivot)
+        m = lax.concatenate(parts, 0)
+    size = block
+    while size < CHUNK:
+        quarter = ((row // (2 * size)) == (col // (2 * size))) \
+            & ((row // size) != (col // size))
+        m = m - _heads_product(
+            m, _heads_product(lax.select(quarter, a, zero), m, left), left)
+        size *= 2
+    return m
+
+
+def _chunk_matrices(q, k, x8, iotas):
+    """What forward and backward both build of a chunk: the columns of
+    the row tile, the decay matrix, K K^T and Q K^T (each product twice
+    side by side: the MXU repeats it for the second head), [k; k]."""
+    row, col, left = iotas
+    xt = x8.T                                       # (2C, 8)
+    columns = [(xt[:CHUNK, i:i + 1], xt[CHUNK:, i:i + 1])
+               for i in (ROW_GAMMA, ROW_REST, ROW_BETA)]
+    gamma_i = jnp.where(left, *columns[0])
+    decay = jnp.where(row >= col,
+                      jnp.exp(gamma_i - x8[ROW_GAMMA:ROW_GAMMA + 1]), 0.0)
+    kk2 = jnp.concatenate([k, k], axis=0)
+    return columns, decay, _dot(k, kk2, ((1,), (1,))), \
+        _dot(q, kk2, ((1,), (1,))), kk2
+
+
+def _for_each_chunk(block_chunks, chunk):
+    """`chunk(c)` for the chunks of a grid step: a loop, one chunk a
+    body.  (A chunk is one chain of dependent steps, and two a body
+    took 0.2 ms off a forward call and 1.3 off a backward one at 16384
+    positions x 16 / 32 heads, of 9.8 and 5.5; but the bodies are
+    traced and lowered in every warm start, and `setup_s` paid 2 s for
+    them: my chip runs, PR 45.)"""
+    def body(c, carry):
+        chunk(c)
+        return carry
+
+    jax.lax.fori_loop(0, block_chunks, body, 0)
+
+
+def _chunk_rows(c):
+    from jax.experimental import pallas as pl
+
+    return pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
+
+
+def _operands_fwd_kernel(q_ref, k_ref, v_ref, x_ref, w_ref, u_ref, qg_ref,
+                         kd_ref, p_ref, m_ref, *, block_chunks):
+    f32 = jnp.float32
+    iotas = _tile_iotas()
+    row, col, left = iotas
+
+    def chunk(c):
+        r = _chunk_rows(c)
+        q, k, x8 = q_ref[0, r, :], k_ref[0, r, :], x_ref[0, c]
+        dt = k.dtype
+        (gamma, rest, beta), decay, kk, qk, _ = _chunk_matrices(
+            q, k, x8, iotas)
+        a = jnp.where(row > col, jnp.where(left, *beta) * kk * decay, 0.0)
+        m = _inverse_side_by_side(a, iotas)
+        m_ref[0, r, :] = m
+        solve = (m * x8[ROW_BETA:ROW_BETA + 1]).astype(dt)
+        p = (qk * decay).astype(dt)
+        kf, qf = k.astype(f32), q.astype(f32)
+        for h in range(2):
+            lanes = slice(h * CHUNK, (h + 1) * CHUNK)
+            e = jnp.exp(gamma[h])
+            p_ref[h, r, :] = p[:, lanes]
+            w_ref[h, r, :] = _dot(solve[:, lanes], (kf * e).astype(dt),
+                                  ((1,), (0,))).astype(dt)
+            u_ref[h, r, :] = _dot(
+                solve[:, lanes],
+                v_ref[0, r, h * HEAD_DIM:(h + 1) * HEAD_DIM],
+                ((1,), (0,))).astype(dt)
+            qg_ref[h, r, :] = (qf * e).astype(dt)
+            kd_ref[h, r, :] = (kf * jnp.exp(rest[h])).astype(dt)
+
+    _for_each_chunk(block_chunks, chunk)
+
+
+def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
+                         dqg_ref, dkd_ref, dp_ref, dq_ref, dk_ref, dv_ref,
+                         dx_ref, *, block_chunks):
+    f32 = jnp.float32
+    iotas = _tile_iotas()
+    row, col, left = iotas
+    shape = (2 * CHUNK, 2 * CHUNK)
+    above = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    # [j, t] = j < t inside a head's block: X @ this sums a row's
+    # entries left of each position
+    left_of = jnp.where(
+        ((above < CHUNK) == (lane < CHUNK))
+        & ((above & (CHUNK - 1)) < (lane & (CHUNK - 1))), 1.0, 0.0
+    ).astype(f32)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (8, 2 * CHUNK), 0)
+
+    def to_row(columns):        # two (C, 1) columns -> a (1, 2C) row
+        return jnp.sum(jnp.where(row == col, jnp.where(left, *columns), 0.0),
+                       axis=0, keepdims=True)
+
+    def by_head(x):             # a side-by-side tile's sums over a head
+        return (jnp.sum(jnp.where(left, x, 0.0), axis=1, keepdims=True),
+                jnp.sum(jnp.where(left, 0.0, x), axis=1, keepdims=True))
+
+    def chunk(c):
+        r = _chunk_rows(c)
+        q, k, x8, m = (q_ref[0, r, :], k_ref[0, r, :], x_ref[0, c],
+                       m_ref[0, r, :])
+        dt = k.dtype
+        (gamma, rest, beta), decay, kk, qk, kk2 = _chunk_matrices(
+            q, k, x8, iotas)
+        beta_j = x8[ROW_BETA:ROW_BETA + 1]
+        solve = (m * beta_j).astype(dt)
+        kf, qf = k.astype(f32), q.astype(f32)
+        dq = jnp.zeros((CHUNK, HEAD_DIM), f32)
+        dk = jnp.zeros((CHUNK, HEAD_DIM), f32)
+        dsolve, dgamma, drest = [], [], []
+        for h in range(2):
+            lanes = slice(h * CHUNK, (h + 1) * CHUNK)
+            wide = slice(h * HEAD_DIM, (h + 1) * HEAD_DIM)
+            e, left_after = jnp.exp(gamma[h]), jnp.exp(rest[h])
+            dw, du = dw_ref[h, r, :], du_ref[h, r, :]
+            dqg, dkd = dqg_ref[h, r, :].astype(f32), \
+                dkd_ref[h, r, :].astype(f32)
+            dsolve.append(
+                _dot(dw, (kf * e).astype(dt), ((1,), (1,)))
+                + _dot(du, v_ref[0, r, wide], ((1,), (1,))))
+            dkg = _dot(solve[:, lanes], dw, ((0,), (0,)))
+            dv_ref[0, r, wide] = _dot(solve[:, lanes], du,
+                                      ((0,), (0,))).astype(dt)
+            dk = dk + dkg * e + dkd * left_after
+            dq = dq + dqg * e
+            dgamma.append(e * jnp.sum(dkg * kf + dqg * qf, axis=1,
+                                      keepdims=True))
+            drest.append(left_after * jnp.sum(dkd * kf, axis=1,
+                                              keepdims=True))
+        dsolve = jnp.concatenate(dsolve, axis=1)
+        dbeta = jnp.sum(dsolve * m, axis=0, keepdims=True)
+        # the inverse's gradient, dA = -M^T dM M^T a head
+        da = _dot_hi(dsolve * beta_j, _block_diagonal(m, left), ((1,), (1,)))
+        da = -_side_by_side(_dot_hi(m, da, ((0,), (0,))), left)
+        da = jnp.where(row > col, da, 0.0) * decay
+        beta_i = jnp.where(left, *beta)
+        dbeta = dbeta + to_row(by_head(da * kk))
+        dp = jnp.concatenate([dp_ref[0, r, :], dp_ref[1, r, :]],
+                             axis=1).astype(f32)
+        # the decay matrix's gradient, summed over the pairs j < t <= i
+        pairs = da * beta_i * kk + dp * decay * qk
+        pairs = jnp.sum(jnp.where(
+            row >= col, _dot_hi(pairs, left_of, ((1,), (0,))), 0.0),
+            axis=0, keepdims=True)
+        dkk, dqk = (da * beta_i).astype(dt), (dp * decay).astype(dt)
+        folded = _dot(dqk, q, ((0,), (0,))) + _dot(dkk, k, ((0,), (0,)))
+        dq_ref[0, r, :] = (dq + _dot(dqk, kk2, ((1,), (0,)))).astype(dt)
+        dk_ref[0, r, :] = (dk + _dot(dkk, kk2, ((1,), (0,)))
+                           + folded[:CHUNK] + folded[CHUNK:]).astype(dt)
+        rows = {ROW_GAMMA: to_row(dgamma), ROW_REST: to_row(drest),
+                ROW_BETA: dbeta, ROW_G: pairs}
+        tile = jnp.zeros((8, 2 * CHUNK), f32)
+        for at, value in rows.items():
+            tile = jnp.where(sublane == at, value, tile)
+        dx_ref[0, c] = tile
+
+    _for_each_chunk(block_chunks, chunk)
+
+
+def _operand_specs(hk, bc):
+    from jax.experimental import pallas as pl
+
+    rows = bc * CHUNK
+
+    def key_head(width):        # a key head's lanes of (N, T, H x 128)
+        return pl.BlockSpec((1, rows, width),
+                            lambda b, i: (b // hk, i, b % hk))
+
+    def value_heads(width):     # its two value heads of (N Hv, T, width)
+        return pl.BlockSpec((2, rows, width), lambda b, i: (b, i, 0))
+
+    return (key_head(HEAD_DIM), key_head(2 * HEAD_DIM),
+            pl.BlockSpec((1, bc, 8, 2 * CHUNK), lambda b, i: (b, i, 0, 0)),
+            pl.BlockSpec((1, rows, 2 * CHUNK), lambda b, i: (b, i, 0)),
+            value_heads(HEAD_DIM), value_heads(CHUNK))
+
+
+@functools.partial(jax.jit, static_argnames=("interpreted",))
+def _operands_fwd_call(q, k, v, x, interpreted=False):
+    """W, U, Qg, Kd, P and (I + A)^-1 (float32, two heads a tile: what
+    the backward kernel is handed; a call that is not differentiated
+    drops it: 134 MB written at the cell's shape, 0.16 ms, for one
+    kernel to trace, lower and compile, not two)."""
+    n, t, width = k.shape
+    hk, nc = width // HEAD_DIM, t // CHUNK
+    bc = _block_chunks(nc)
+    narrow, pair, tile, inverse, wide, square = _operand_specs(hk, bc)
+    flat = lambda d: jax.ShapeDtypeStruct((2 * n * hk, t, d),  # noqa: E731
+                                          v.dtype)
+    return _pallas_call(
+        functools.partial(_operands_fwd_kernel, block_chunks=bc),
+        name="gated_delta_operands_fwd", grid=(n * hk, nc // bc),
+        in_specs=[narrow, narrow, pair, tile],
+        out_specs=[wide, wide, wide, wide, square, inverse],
+        out_shape=[flat(HEAD_DIM)] * 4 + [flat(CHUNK), jax.ShapeDtypeStruct(
+            (n * hk, t, 2 * CHUNK), jnp.float32)],
+        compiler_params=_params(),
+    )(q, k, v, x)
+
+
+@functools.partial(jax.jit, static_argnames=("interpreted",))
+def _operands_bwd_call(q, k, v, x, m, dw, du, dqg, dkd, dp,
+                       interpreted=False):
+    n, t, width = k.shape
+    hk, nc = width // HEAD_DIM, t // CHUNK
+    bc = _block_chunks(nc)
+    narrow, pair, tile, inverse, wide, square = _operand_specs(hk, bc)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    return _pallas_call(
+        functools.partial(_operands_bwd_kernel, block_chunks=bc),
+        name="gated_delta_operands_bwd", grid=(n * hk, nc // bc),
+        in_specs=[narrow, narrow, pair, tile, inverse, wide, wide, wide,
+                  wide, square],
+        out_specs=[narrow, narrow, pair, tile],
+        out_shape=[like(q), like(k), like(v), like(x)],
+        compiler_params=_params(),
+    )(q, k, v, x, m, dw, du, dqg, dkd, dp)
+
+
+def _record_operands(k):
+    """Count a call of a chunk-operand kernel where it is traced
+    (outside the jitted call, which is traced once a shape); gives the
+    interpret gate, which keys that call's cache: the same shapes are
+    lowered through the interpreter and through Mosaic in one test
+    process."""
+    from ...observe.monitoring import runtime_stats
+    from . import interpret
+
+    n, t, width = k.shape
+    runtime_stats.record_gated_delta_operands(
+        2 * n * (width // HEAD_DIM) * (t // CHUNK))
+    return interpret()
+
+
+@jax.custom_vjp
+def operands_kernel(q, k, v, x):
+    """`chunk_operands` less exp(gamma_C) by the Pallas kernels.  q, k
+    (N, T, Hk x 128), v (N, T, 2 Hk x 128), x the row tiles
+    (`_row_tiles`)."""
+    return _operands_vjp_fwd(q, k, v, x)[0]
+
+
+def _operands_vjp_fwd(q, k, v, x):
+    *operands, m = _operands_fwd_call(q, k, v, x,
+                                      interpreted=_record_operands(k))
+    return tuple(operands), (q, k, v, x, m)
+
+
+def _operands_vjp_bwd(res, cts):
+    q, k, v, x, m = res
+    return tuple(_operands_bwd_call(
+        q, k, v, x, m, *(c.astype(v.dtype) for c in cts),
+        interpreted=_record_operands(k)))
+
+
+operands_kernel.defvjp(_operands_vjp_fwd, _operands_vjp_bwd)
+
+
+def _row_tiles(g, beta, hk):
+    """g, beta (N, T, Hv) float32 -> (N Hk, T / C, 8, 2C): a chunk's
+    rows `ROW_GAMMA` .. `ROW_G`, a key head's two value heads side by
+    side, and exp(gamma_C) (N Hv, T / C).  The sums are XLA's (and
+    their gradients: sums again), as products with 0 / 1 triangles at
+    "highest" (a cumulative sum along 64 of 2 MB is 1 ms of
+    reduce-windows on the chip); `rest` is a suffix sum, not
+    `last - gamma`."""
+    n, t, hv = g.shape
+    nc = t // CHUNK
+
+    def heads_first(x):         # (N, T, Hv) -> (N, Hk, nc, 2, C)
+        x = jnp.swapaxes(x, 1, 2).reshape(n, hk, 2, nc, CHUNK)
+        return jnp.swapaxes(x, 2, 3)
+
+    gc = heads_first(g)
+    upto = jnp.where(_lower(CHUNK), 1.0, 0.0).astype(g.dtype)   # [i >= t]
+    after = jnp.where(_lower(CHUNK), 0.0, 1.0).astype(g.dtype)  # [t > i]
+    gamma = jnp.einsum("it,nhcvt->nhcvi", upto, gc, precision=_HI)
+    rest = jnp.einsum("it,nhcvt->nhcvi", after, gc, precision=_HI)
+    rows = jnp.stack([gamma, rest, heads_first(beta), gc], axis=3)
+    rows = jnp.pad(rows.reshape(n * hk, nc, 4, 2 * CHUNK),
+                   ((0, 0), (0, 0), (0, 4), (0, 0)))
+    last = jnp.exp(jnp.swapaxes(gamma[..., -1], 2, 3))  # (N,Hk,2,nc)
+    return rows, last.reshape(n * hv, nc)
+
+
+def chunk_operands_kernel(q, k, v, g, beta):
+    """`chunk_operands` where `operand_kernels_take` the heads: the
+    same six results, the chunk's matrices never in HBM."""
+    n, t, hk, dk = k.shape
+    hv = v.shape[2]
+    x, last = _row_tiles(g.astype(jnp.float32), beta.astype(jnp.float32),
+                         hk)
+    return operands_kernel(q.reshape(n, t, hk * dk), k.reshape(n, t, hk * dk),
+                           v.reshape(n, t, hv * v.shape[3]), x) + (last,)
 
 
 # -- the sequential part, as XLA runs it -------------------------------
@@ -460,7 +928,8 @@ def gated_delta_rule(q, k, v, g, beta, use_kernel=False):
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, tail)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    operands = chunk_operands(q.astype(v.dtype), k.astype(v.dtype), v, g,
-                              beta)
+    batch = chunk_operands_kernel if use_kernel and operand_kernels_take(
+        hk, hv, dk, dv) else chunk_operands
+    operands = batch(q.astype(v.dtype), k.astype(v.dtype), v, g, beta)
     o = (scan_kernel if use_kernel else scan_xla)(*operands)
     return jnp.moveaxis(o.reshape(n, hv, t + tail, dv), 1, 2)[:, :t]

@@ -302,9 +302,11 @@ STEP_TEXT = {
     "feb20e0f77cac9b68fcfcbc630aa0a2d04a50249bddebc892a00584d8a915084",
     "mellum2-16k":
     "692f610496a65734e5d2e1dccfa470e6af344cd1f6baea8c2723dcd8a95d0320",
-    # new in PR 44 (no parent): pinned as PR 44 left it, for the next PR
+    # re-pinned, PR 45: the chunk-local part of the scan is the two
+    # chunk-operand kernels, here through the interpreter (parent:
+    # e13e01c2..)
     "qwen3next-16k":
-    "e13e01c275e1567d0b909d9e239cb47da51a311ce94df07f5bc57a155813fab5",
+    "83bb4b9637fd59e0fc62c8d7320dddb577a1d3ac3a5fc5478a151c159150ee11",
 }
 
 

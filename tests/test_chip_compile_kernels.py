@@ -85,10 +85,13 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     """What `qwen3next-16k`'s step hands the chip's compiler that no
     other cell does, first half: the `gated_delta_rule` op at 1 x 16384
     positions, 16 key and 32 value heads of 128, in the cell's bfloat16
-    and in the parity script's float32 at "highest".  Three Mosaic
-    kernels under a gradient: the forward rule's `gated_delta_fwd`
-    (which also writes the 256 chunk-entry states a head) and
-    `gated_delta_bwd`; each a grid of 32 heads x 32 blocks of 8 chunks
+    and in the parity script's float32 at "highest".  Four Mosaic
+    kernels under a gradient: the chunk-local part's
+    `gated_delta_operands_fwd` (which also writes (I + A)^-1, two heads
+    a float32 tile) and `gated_delta_operands_bwd`, each a grid of 16
+    key heads x 32 blocks of 8 chunks; the forward rule's
+    `gated_delta_fwd` (which also writes the 256 chunk-entry states a
+    head) and `gated_delta_bwd`, each a grid of 32 heads x 32 blocks
     carrying a (128, 128) float32 state in VMEM scratch."""
     from paddle_tpu.core.registry import OpContext, get_op_impl
     from paddle_tpu.observe import cost
@@ -117,18 +120,31 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
             .lower(*args).compile()
     took = runtime_stats.delta(before)
     # the forward rule's call and the backward's: 256 chunks x 32 heads
-    assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (
-        2, 2 * 256 * 32)
+    for kind in ("gated_delta", "gated_delta_operand"):
+        assert (took[f"{kind}_calls"], took[f"{kind}_chunks"]) == (
+            2, 2 * 256 * 32), kind
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
-        "gated_delta_bwd", "gated_delta_fwd"]
+        "gated_delta_bwd", "gated_delta_fwd", "gated_delta_operands_bwd",
+        "gated_delta_operands_fwd"]
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "gated_delta_rule"}
     totals = cost.total_costs(proto)
-    assert totals["custom_calls"] == totals["pallas_matched"] == 2
+    assert totals["custom_calls"] == totals["pallas_matched"] == 4
+    # no scan reader may take the new kernels for scan kernels: they
+    # match by prefix (`benchmarks/kernel_counts.py kernel_ms_per_step`)
+    scan = [r for r in rows if (r["kernel"] or "").startswith(
+        ("gated_delta_fwd", "gated_delta_bwd"))]
     chunk = (3 + 6) * 2 * 64 * d * d + (1 + 2) * 2 * 64 * 64 * d
-    assert totals["pallas_flops"] == 256 * 32 * chunk
+    assert sum(r["flops"] for r in scan) == 256 * 32 * chunk
+    # the chunk-local part: K K^T, Q K^T a key head; W, U, the
+    # substitution, and the backward's eight products and three (C, C)
+    # ones a value head
+    local = 256 * (16 * 2 * 2 * 64 * 64 * d + 32 * (
+        (2 + 8) * 2 * 64 * 64 * d + 2 * 64 ** 3 / 3 + 3 * 2 * 64 ** 3))
+    assert totals["pallas_flops"] == pytest.approx(
+        256 * 32 * chunk + local, rel=1e-9)
     # the states that enter the chunks, in the operands' dtype
     kind = "bf16" if dtype == BF16 else "f32"
     assert f"{kind}[{hv},{256 * d},{d}]" in compiled.as_text()

@@ -194,6 +194,8 @@ def test_the_two_kinds_of_layer_lower_under_scopes_of_their_own():
     got, _ = system(cfg, batch(cfg, n=1))
     took = runtime_stats.delta(before)
     assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (0, 0)
+    assert (took["gated_delta_operand_calls"],
+            took["gated_delta_operand_chunks"]) == (0, 0)
     found = [op.attrs.get("__name_scope__", "") for b in got["main"].blocks
              for op in b.ops]
     by_type = {s: [op.type for b in got["main"].blocks for op in b.ops
@@ -234,10 +236,12 @@ def test_a_step_counts_three_kernel_calls_a_linear_layer_and_a_build_none():
         for _ in range(2):
             exe.run(main, feed=feed, scope=scope, fetch_list=[m["loss"]])
             marks.append(runtime_stats.snapshot())
-    took = [(b["gated_delta_calls"] - a["gated_delta_calls"],
-             b["gated_delta_chunks"] - a["gated_delta_chunks"])
-            for a, b in zip(marks, marks[1:])]
-    assert took == [(0, 0), (3, 3 * 2 * 2), (0, 0)]
+    for kind in ("gated_delta", "gated_delta_operand"):
+        # the scan's kernels, and the chunk-operand kernels before them
+        took = [(b[f"{kind}_calls"] - a[f"{kind}_calls"],
+                 b[f"{kind}_chunks"] - a[f"{kind}_chunks"])
+                for a, b in zip(marks, marks[1:])]
+        assert took == [(0, 0), (3, 3 * 2 * 2), (0, 0)], kind
 
 
 # -- (b) the chunked scan against the sequential recurrence -----------------
@@ -249,18 +253,24 @@ def sequential(q, k, v, g, beta):
                           v, g, beta)
 
 
-def scan_case(t, decay, seed=0, hk=1, hv=2, d=gated_delta.HEAD_DIM):
+def scan_case(t, decay, seed=0, hk=1, hv=2, d=gated_delta.HEAD_DIM,
+              repeat=False, beta_scale=1.0):
     """q, k unit vectors a head (q over sqrt(d)), v N(0, 1), beta in
     (0, 1); `decay` "far": g drawn so that a chunk's exp(gamma_C) passes
-    1e-6; "none": g = 0, the plain delta rule; else mild."""
+    1e-6; "none": g = 0, the plain delta rule; else mild.  `repeat`:
+    positions 10-70 hold ONE key (a chunk's A is then all of one sign
+    and size, where the product form of the inverse lost its digits);
+    `beta_scale` 12 puts beta within 1e-5 of 0 or 1."""
     r = np.random.default_rng(seed)
     q, k = r.normal(size=(2, 1, t, hk, d))
+    if repeat:
+        k[:, 10:70] = k[:, 10:11]
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(d)
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
     v = r.normal(size=(1, t, hv, d))
     g = -np.abs(r.normal(size=(1, t, hv))) \
         * {"far": 0.44, "none": 0.0, "mild": 0.05}[decay]
-    beta = 1 / (1 + np.exp(-r.normal(size=(1, t, hv))))
+    beta = 1 / (1 + np.exp(-beta_scale * r.normal(size=(1, t, hv))))
     return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
 
 
@@ -302,8 +312,9 @@ def test_the_chunked_scan_is_the_sequential_recurrence(lowering, t, decay):
                                    rtol=0, atol=TOL * scale, err_msg=name)
     chunks = 2 * -(-t // 64)            # heads x chunks, the tail padded
     # a forward call, then the forward rule's and the backward kernel
-    assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (
-        (3, 3 * chunks) if lowering == "kernel" else (0, 0))
+    for kind in ("gated_delta", "gated_delta_operand"):
+        assert (took[f"{kind}_calls"], took[f"{kind}_chunks"]) == (
+            (3, 3 * chunks) if lowering == "kernel" else (0, 0)), kind
 
 
 def test_the_scan_in_bfloat16_misses_the_float32_tolerance():
@@ -317,6 +328,133 @@ def test_the_scan_in_bfloat16_misses_the_float32_tolerance():
         err = float(jnp.abs(got.astype(jnp.float32) - want).max()
                     / jnp.abs(want).max())
         assert 100 * TOL < err < 0.02, err
+
+
+OPERAND_CASES = {
+    # T and what `scan_case` makes of it, two value heads a key head
+    "weak-decay": (128, dict(decay="mild")),
+    "strong-decay": (128, dict(decay="far")),
+    "repeated-keys": (128, dict(decay="mild", repeat=True)),
+    "beta-near-0-and-1": (128, dict(decay="mild", beta_scale=12.0)),
+    "ten-chunks-two-blocks-two-key-heads": (
+        640, dict(decay="mild", hk=2, hv=4)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OPERAND_CASES))
+def test_the_operand_kernels_are_chunk_operands(case):
+    """The two chunk-operand kernels (interpret mode) against the XLA
+    lowering of the same chunks and ITS gradient: W, U, Qg, Kd, P and
+    exp(gamma_C), and dq, dk, dv, dg, dbeta under a random cotangent
+    of each."""
+    t, kind = OPERAND_CASES[case]
+    args = scan_case(t, seed=3, **kind)
+    want = gated_delta.chunk_operands(*args)
+    weights = [jnp.asarray(np.random.default_rng(5 + i).normal(size=w.shape),
+                           jnp.float32) for i, w in enumerate(want)]
+
+    def scalar(fn):
+        return lambda *a: sum(jnp.sum(o * w)
+                              for o, w in zip(fn(*a), weights))
+
+    got = gated_delta.chunk_operands_kernel(*args)
+    got_grads = jax.grad(scalar(gated_delta.chunk_operands_kernel),
+                         argnums=range(5))(*args)
+    want_grads = jax.grad(scalar(gated_delta.chunk_operands),
+                          argnums=range(5))(*args)
+    names = ("w", "u", "qg", "kd", "p", "dec", "dq", "dk", "dv", "dg",
+             "dbeta")
+    for name, a, b in zip(names, got + got_grads, want + want_grads):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        scale = float(jnp.abs(b).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=TOL * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("diagonal", [8, 32, 64])
+def test_the_substitution_at_every_size_of_its_diagonal_blocks(diagonal):
+    """`_inverse_side_by_side` against numpy's inverse, two heads side
+    by side: rows alone (64), and rows then one to three doublings."""
+    r = np.random.default_rng(diagonal)
+    a = np.tril(r.normal(size=(2, 64, 64)) * 0.3, -1).astype(np.float32)
+    a[1, 20:50, :20] = 0.9          # repeated keys: a block of one value
+    want = np.linalg.inv(np.eye(64) + a.astype(np.float64))
+    got = gated_delta._inverse_side_by_side(
+        jnp.asarray(np.concatenate(list(a), axis=1)),
+        gated_delta._tile_iotas(), diagonal)
+    np.testing.assert_allclose(got, np.concatenate(list(want), axis=1),
+                               rtol=0, atol=2e-5 * np.abs(want).max())
+
+
+def test_the_op_with_its_gates_is_the_sequential_recurrence():
+    """The `gated_delta_rule` op as the cell runs it (heads of 128, two
+    value heads a key head: all four kernels, interpret mode) against
+    the recurrence a position at a time: the output and the gradient
+    of every input, `ALog` and `DtBias` among them; T = 200 is three
+    chunks and a padded tail."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+
+    t, hk, hv, d = 200, 1, 2, gated_delta.HEAD_DIM
+    r = np.random.default_rng(11)
+    args = [jnp.asarray(x, jnp.float32) for x in (
+        r.normal(size=(1, t, (2 * hk + hv) * d)),
+        r.normal(size=(1, t, 2 * hv)), np.log(r.uniform(0.05, 2.0, size=hv)),
+        r.normal(size=hv))]
+    weight = jnp.asarray(r.normal(size=(1, t, hv * d)), jnp.float32)
+    impl = get_op_impl("gated_delta_rule")
+
+    def op(qkv, ba, a_log, dt_bias):
+        return impl(OpContext(jax.random.PRNGKey(0), 0),
+                    {"QKV": [qkv], "BA": [ba], "ALog": [a_log],
+                     "DtBias": [dt_bias]},
+                    {"n_key_head": hk, "n_value_head": hv, "key_dim": d,
+                     "value_dim": d, "use_pallas": True})["Out"][0]
+
+    def recurrence(qkv, ba, a_log, dt_bias):
+        def l2norm(x):
+            x = x.reshape(1, t, hk, d)
+            return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+        q = l2norm(qkv[..., :hk * d]) * d ** -0.5
+        k = l2norm(qkv[..., hk * d:2 * hk * d])
+        g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+        return sequential(q, k, qkv[..., 2 * hk * d:].reshape(1, t, hv, d),
+                          g, jax.nn.sigmoid(ba[..., :hv])).reshape(weight.shape)
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a) * weight)
+
+    got = (op(*args),) + jax.grad(scalar(op), argnums=range(4))(*args)
+    want = (recurrence(*args),) + jax.grad(scalar(recurrence),
+                                           argnums=range(4))(*args)
+    for name, a, b in zip(("o", "dqkv", "dba", "dA_log", "ddt_bias"),
+                          got, want):
+        scale = float(jnp.abs(b).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=TOL * scale, err_msg=name)
+
+
+def test_other_heads_keep_chunk_operands():
+    """Heads of 128 with ONE or THREE value heads a key head run the
+    scan's kernels on XLA's `chunk_operands`: the choice is the shape's
+    (`operand_kernels_take`), and the counters say which ran."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    assert gated_delta.operand_kernels_take(16, 32, 128, 128)
+    assert not gated_delta.operand_kernels_take(16, 16, 128, 128)
+    assert not gated_delta.operand_kernels_take(16, 32, 64, 64)
+    args = scan_case(128, "mild", hk=1, hv=1)
+    before = runtime_stats.snapshot()
+    got = gated_delta.gated_delta_rule(*args, use_kernel=True)
+    took = runtime_stats.delta(before)
+    assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (1, 2)
+    assert (took["gated_delta_operand_calls"],
+            took["gated_delta_operand_chunks"]) == (0, 0)
+    want = sequential(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=TOL * float(jnp.abs(want).max()))
 
 
 def test_the_inverse_and_its_own_gradient():
