@@ -41,11 +41,13 @@ def _decode_shape(shape):
 # `gated_delta_rule`'s layer declares its one output itself: tracing a
 # 256-chunk scan at the stand-in batch would only add three kernel
 # calls of a million sequences to `runtime_stats.gated_delta_*`, which
-# a benchmark reader takes for the step's.
+# a benchmark reader takes for the step's.  `short_conv`'s layer
+# declares its output likewise, and its kernels are neither traced nor
+# counted (`runtime_stats.short_convs_*`) by a Program build.
 _SKIP_INFERENCE = {
     "backward_marker", "py_func", "print",
     "create_array", "array_write", "array_read", "array_length",
-    "array_to_tensor", "gated_delta_rule",
+    "array_to_tensor", "gated_delta_rule", "short_conv",
 }
 
 

@@ -74,6 +74,7 @@ _FIELDS = Heard._fields[1:] + (
     "gated_delta_operand_calls", "gated_delta_operand_chunks",
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
+    "short_convs_kernel", "short_convs_xla",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -180,6 +181,11 @@ class RuntimeStats:
         # where the backward pass recomputes it)
         self.grouped_matmuls_kernel = 0
         self.grouped_matmuls_xla = 0
+        # `short_conv` ops traced, by what the shape chose: the Pallas
+        # kernels (`ops/pallas/short_conv.py`) or the composition
+        # (delta() around a build; a Program build counts nothing)
+        self.short_convs_kernel = 0
+        self.short_convs_xla = 0
         # trips of the counted loops traced (`static_rnn` with a
         # `trip_count`): what a step runs of them (delta() around a
         # build: 4 where one stack runs 4 times), and what an early exit
@@ -268,6 +274,13 @@ class RuntimeStats:
                 self.grouped_matmuls_kernel += 1
             else:
                 self.grouped_matmuls_xla += 1
+
+    def record_short_conv(self, kernel: bool):
+        with self._lock:
+            if kernel:
+                self.short_convs_kernel += 1
+            else:
+                self.short_convs_xla += 1
 
     def record_loop_trips(self, trips: int):
         with self._lock:

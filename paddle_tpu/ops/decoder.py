@@ -221,14 +221,25 @@ def short_conv(ctx, ins, attrs):
         Out = C * conv                                       (N, T, D)
 
     Causal (position t reads t-L+1..t) and depthwise (a channel reads
-    itself only).  One op, so that the three elementwise passes are
-    one scope and can be one fusion; float32 inside, X's dtype out.
-    The backward pass recomputes from X (`jax.checkpoint`): it reads
-    `BCu` and the output's gradient and keeps nothing in between.
+    itself only).  One op and one scope; float32 inside, X's dtype out.
+    The backward pass recomputes from X: it reads `BCu` and the
+    output's gradient and keeps nothing in between.
 
     `activation` "silu": no gates; X is (N, T, D) and
     Out = silu(conv(X)), the same convolution (what stands before the
-    scan of a linear-attention layer)."""
+    scan of a linear-attention layer).
+
+    Two lowerings of the one algorithm, chosen by the shape alone
+    (`ops/pallas/short_conv.py short_conv_kernel_takes`: D a multiple
+    of 128, T of the row tile, the taps within a halo, a gated tile
+    that fits VMEM): the two Pallas kernels there, which read X and
+    write Out once; everything else (odd widths, short rows) the
+    composition below under `jax.checkpoint`, whose float32
+    intermediates XLA writes to HBM.  `runtime_stats.
+    short_convs_kernel` / `_xla` count the calls traced each way."""
+    from ..observe.monitoring import runtime_stats
+    from .pallas.short_conv import short_conv_kernel, short_conv_kernel_takes
+
     x, w = first(ins, "X"), first(ins, "Filter")
     activation = attrs.get("activation")
     if activation not in (None, "silu"):
@@ -239,8 +250,14 @@ def short_conv(ctx, ins, attrs):
         raise ValueError(f"short_conv: X {x.shape} is not (N, T, "
                          f"{wide if wide > 1 else ''}D) for a Filter "
                          f"{w.shape} of (D, L)")
-    return out(Out=jax.checkpoint(_silu_conv if activation
-                                  else _short_conv)(x, w))
+    gated = not activation
+    kernel = short_conv_kernel_takes(x.shape[1], w.shape[0], w.shape[1],
+                                     gated, x.dtype.itemsize)
+    runtime_stats.record_short_conv(kernel)
+    if kernel:
+        return out(Out=short_conv_kernel(x, w, gated))
+    return out(Out=jax.checkpoint(_short_conv if gated
+                                  else _silu_conv)(x, w))
 
 
 def plain_latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head, scale):

@@ -105,7 +105,9 @@ def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
     memory than the section differentiated on T*k rows (a quarter less
     before PR 40; an eighth since, the kernels having taken the masks'
     buffers out of the section differentiated as it stands).  The gated
-    short convolution is XLA fusions with no kernel and no dot."""
+    short convolution's gradient is one Mosaic kernel of
+    `ops/pallas/short_conv.py` under the op's scope, and no dot (XLA
+    fusions with no kernel before PR 46)."""
     from paddle_tpu.core.registry import OpContext, get_op_impl
     from paddle_tpu.observe import cost
     from paddle_tpu.ops import moe_dropless
@@ -205,7 +207,11 @@ def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
         jax.ShapeDtypeStruct((1, t, 3 * hidden), BF16, sharding=one_chip),
         jax.ShapeDtypeStruct((hidden, 3), F32, sharding=one_chip))
     rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
-    assert not any(r["kernel"] for r in rows)
+    # the shape rule takes the gated form at this width (PR 46): the
+    # gradient is ONE kernel, which recomputes the convolution from
+    # `BCu` and writes d(BCu) whole, under the op's scope; beside it
+    # only the sums of the filter's gradient and of the loss
+    assert [r["kernel"] for r in rows if r["kernel"]] == ["short_conv_bwd"]
     assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
     assert {r["op_type"] for r in rows if r["op_type"]} == {"short_conv"}
 

@@ -292,8 +292,11 @@ STEP_TEXT = {
     # 5e45cfed.., e1f3219f..)
     "olmoe-4k":
     "edde7176fe33bb4d5429b1ced73b68db315c639ed19922b19640112a2b71fd8b",
+    # re-pinned, PR 46: the gated short convolution is the two kernels
+    # of `ops/pallas/short_conv.py`, here through the interpreter
+    # (parent: f2f9d30e..)
     "lfm2-8k":
-    "f2f9d30e34e0e63d920f1c26bd672caef81a41e69fdbcfebdf582c6bafa6576a",
+    "8265f55d050161e51092c15b9d763f437cb7a66acfa597ebb78e6d434bc1d69e",
     "joyai-8k":
     "46a623eb6f4691120a58f68448449341b589b0b1f23fce5dd2eaca924a43b904",
     # re-pinned, PR 39: the loop's segments keep (o, logsumexp), the
@@ -302,11 +305,11 @@ STEP_TEXT = {
     "feb20e0f77cac9b68fcfcbc630aa0a2d04a50249bddebc892a00584d8a915084",
     "mellum2-16k":
     "692f610496a65734e5d2e1dccfa470e6af344cd1f6baea8c2723dcd8a95d0320",
-    # re-pinned, PR 45: the chunk-local part of the scan is the two
-    # chunk-operand kernels, here through the interpreter (parent:
-    # e13e01c2..)
+    # re-pinned, PR 46: the SiLU short convolution is the same two
+    # kernels (parent: 83bb4b96.., PR 45's chunk-operand kernels;
+    # before them e13e01c2..)
     "qwen3next-16k":
-    "83bb4b9637fd59e0fc62c8d7320dddb577a1d3ac3a5fc5478a151c159150ee11",
+    "acbeb427a7e7fb7ac77fb76405d6dc1535f193eed451787819a8089933ace118",
 }
 
 

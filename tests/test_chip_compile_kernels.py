@@ -1,8 +1,9 @@
 """The chip's compiler on the other kernel families, each alone: latent
 attention (`ops/pallas/flash_mla.py`) at `joyai-8k`'s shape, the chunked
 delta-rule scan (`ops/pallas/gated_delta.py`) and grouped flash
-attention at d_head 256 at `qwen3next-16k`'s, the fused vocabulary
-cross-entropy, paged attention, the fused LSTM recurrence;
+attention at d_head 256 at `qwen3next-16k`'s, the short convolution
+(`ops/pallas/short_conv.py`) at that cell's and `lfm2-8k`'s, the fused
+vocabulary cross-entropy, paged attention, the fused LSTM recurrence;
 and the cost table over whole steps it compiled (every Mosaic kernel has
 a registered cost, the TPU's dots are matmul rows).  tests/chip_compile.py
 says why and how, and why these share a file.
@@ -148,6 +149,66 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     # the states that enter the chunks, in the operands' dtype
     kind = "bf16" if dtype == BF16 else "f32"
     assert f"{kind}[{hv},{256 * d},{d}]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("form", ["silu", "gated"])
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_short_conv_kernels_at_the_published_shapes(one_chip, dtype, form):
+    """The `short_conv` op and its gradient at the two cells' shapes:
+    `qwen3next-16k`'s (1, 16384, 8192) x 4 taps under a SiLU and
+    `lfm2-8k`'s gated (1, 8192, 3 x 2048) x 3 taps, in the cells'
+    bfloat16 and the parity scripts' float32.  The shape rule takes
+    both, so the output with its gradient is TWO Mosaic kernels,
+    `short_conv_fwd` and `short_conv_bwd` (which recomputes the
+    convolution from X); each has a registered cost in bytes and no
+    FLOP, and sits under the op's scope.  The gated
+    form's full-width tiles (256 rows x 6144 lanes in, as many out)
+    are what claims VMEM past Mosaic's default 16 MiB."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    t, d, taps = (16384, 8192, 4) if form == "silu" else (8192, 2048, 3)
+    wide = 1 if form == "silu" else 3
+    impl = get_op_impl("short_conv")
+
+    def both(x, w, ct):
+        def fn(x, w):
+            with jax.named_scope("linear_attention/short_conv:7"):
+                return impl(OpContext(jax.random.PRNGKey(0), 0),
+                            {"X": [x], "Filter": [w]},
+                            {"activation": "silu"} if form == "silu"
+                            else {})["Out"][0]
+
+        o, vjp = jax.vjp(fn, x, w)
+        return o, vjp(ct)
+
+    before = runtime_stats.snapshot()
+    compiled = _compile_args(
+        jax.jit(both),
+        jax.ShapeDtypeStruct((1, t, wide * d), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((d, taps), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, t, d), dtype, sharding=one_chip))
+    took = runtime_stats.delta(before)
+    assert (took["short_convs_kernel"], took["short_convs_xla"]) == (1, 0)
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "short_conv_bwd", "short_conv_fwd"]
+    assert {r["op_type"] for r in rows if r["op_type"]} == {"short_conv"}
+    assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 2
+    assert totals["pallas_flops"] == 0
+    # X and Out forward; X, dOut and dX backward; once each
+    item = 2 if dtype == BF16 else 4
+    tile = t * d * item
+    by = {r["kernel"]: r["bytes"] for r in rows if r["kernel"]}
+    assert by["short_conv_fwd"] == (wide + 1) * tile + d * taps * 4
+    assert by["short_conv_bwd"] == (2 * wide + 1) * tile + (
+        1 + 8) * d * taps * 4
+    # nothing float32 as large as X is written between the kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < tile // 4
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
