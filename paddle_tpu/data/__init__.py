@@ -10,4 +10,5 @@ from .decorator import (Fake, batch, buffered, chain, compose, firstn,  # noqa: 
                         map_readers, multiprocess_reader, shuffle,
                         xmap_readers)
 from . import dataset  # noqa: F401
+from . import diffusion  # noqa: F401
 from . import image  # noqa: F401

@@ -323,7 +323,7 @@ def short_conv(input, filter_size, param_attr=None, name=None,
 
 def rope(input, n_head, theta=10000.0, offset=None, name=None,
          interleave=False, inv_freq=None, attention_factor=None,
-         rotary_dim=None):
+         rotary_dim=None, period=None):
     """Rotary position embedding of a head-grouped (N, T, n_head * D)
     projection (ops/decoder.py): rotate-half, or with `interleave` the
     pairs (2i, 2i + 1) of every head.  `offset`: a (1,) integer
@@ -332,7 +332,9 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
     `attention_factor` on cos and sin, both host constants
     (`ops.decoder.rope_frequencies` makes them of a config's
     `rope_parameters`).  `rotary_dim`: only the first so many lanes of
-    each head turn (a config's `partial_rotary_factor` x the head)."""
+    each head turn (a config's `partial_rotary_factor` x the head).
+    `period` P: positions restart every P rows (row r stands at
+    r mod P)."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     ins = {"X": [input]}
@@ -347,6 +349,10 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
         attrs["attention_factor"] = float(attention_factor)
     if rotary_dim is not None:
         attrs["rotary_dim"] = int(rotary_dim)
+    if period is not None:
+        if int(period) < 1:
+            raise ValueError(f"rope: period {period} is no row count")
+        attrs["period"] = int(period)
     helper.append_op(type="rope", inputs=ins, outputs={"Out": [out]},
                      attrs=attrs)
     return out
@@ -1259,7 +1265,7 @@ def grid_sampler(x, grid, name=None):
 def flash_attention(q, k, v, bias=None, scale=None, causal=False,
                     use_pallas=None, sequence_parallel=False,
                     layout="nhtd", n_head=None, name=None,
-                    n_kv_head=None, window=None):
+                    n_kv_head=None, window=None, block_diffusion=None):
     """Fused multi-head attention over (N, H, T, D) tensors (see
     ops/attention.py).  The TPU-native replacement for composing
     matmul+softmax+matmul by hand.  layout="nthd" + n_head takes the
@@ -1272,7 +1278,14 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
     ops/pallas/flash_gqa.py; 128, flash_attention.py) never repeat
     them.  `window` W (head-major, causal, no bias): query i reads the
     W newest keys of its prefix, i - W < j <= i; the Pallas kernels
-    skip the key blocks behind the window.  With sequence_parallel=True
+    skip the key blocks behind the window.  `block_diffusion` B
+    (head-major, NOT causal, no bias or window): the T rows are a clean
+    half x_0 and a noised half x_t of T / 2 positions each, cut into
+    blocks of B, under the block-diffusion training mask (a clean row
+    reads the clean rows of its own and earlier blocks, a noised row
+    the clean rows of strictly earlier blocks and the noised rows of its
+    own block; ops/attention.py `_block_diffusion_mask`); the Pallas
+    kernels compute only tiles that hold an allowed pair.  With sequence_parallel=True
     (or "ring" / "ulysses") and a CompiledProgram mesh that has an `sp`
     axis, the sequence dimension shards over sp and attention runs as
     ring attention (KV ppermute rotation) or Ulysses (head/sequence
@@ -1294,6 +1307,8 @@ def flash_attention(q, k, v, bias=None, scale=None, causal=False,
         attrs["scale"] = float(scale)
     if window is not None:
         attrs["window"] = int(window)
+    if block_diffusion is not None:
+        attrs["block_diffusion"] = int(block_diffusion)
     helper.append_op(type="flash_attention", inputs=ins,
                      outputs={"Out": [out]}, attrs=attrs)
     return out

@@ -205,6 +205,26 @@ backward pass: the router of such a program is NOT trained, it only
 decays).  The op's own backward is the true partial; a lowering that
 all-reduces the parts asks for it.
 
+`objective` = "block_diffusion" (block-diffusion training, Arriola et
+al., arXiv:2503.09573; `block_length` B): the program reads ONE
+sequence of 2 x `max_length` rows, a document x_0 and after it its
+noised copy x_t (`paddle_tpu.data.diffusion.block_diffusion_feeds`
+makes both on the host, with the labels x_0 and the weights), row L + p
+turning as position p in RoPE (`layers.rope(period=)`).  Every layer
+runs all 2 L rows under the block-diffusion mask
+(`layers.flash_attention(block_diffusion=B)`: a clean row reads the
+clean rows of its own and earlier blocks; a noised row the clean rows
+of strictly earlier blocks and the noised rows of its own block), under
+the `block_diffusion_attention` name scope.  The final norm and the
+head read the noised half only; position i predicts x_0[i], no shift:
+
+    loss = (1 / (N L)) sum_i w_i CE(logits_i, x_0[i])
+
+with w_i = 1 / t_b on a masked position and 0 elsewhere (feed
+`loss_weights`).  Only `full_attention` layers are built under it: a
+window, a convolution, latent or linear attention, a prediction module
+and a loop raise.
+
 The training objective is the paper's: token cross-entropy + `aux_loss_weight`
 x the load-balancing loss + `z_loss_weight` x the router z-loss (both
 averaged over layers), or the exit-weighted loss above, AdamW,
@@ -250,10 +270,15 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             linear_key_head_dim=None, linear_value_head_dim=None,
             linear_conv_kernel_dim=None,
             shared_expert_intermediate_size=None, zero_centered_norm=False,
-            attention_gate=None, shared_expert_gate=None):
+            attention_gate=None, shared_expert_gate=None,
+            objective="next_token", block_length=None):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
-    labels' own successors, with a prediction module).  Returns a dict:
+    labels' own successors, with a prediction module); under
+    `objective="block_diffusion"` `tokens` (N, 2 * max_length), `labels`
+    and float32 `loss_weights` (N, max_length), `logits` over the noised
+    half, `ce` the weighted loss and `masked_share` (1,), the share of
+    positions with a weight.  Returns a dict:
     `logits` (N, T, vocab); `ce`, `aux`, `z`, each (1,): the mean token
     cross-entropy, the load-balancing loss and the router z-loss, the
     last two averaged over the layers that route (None where none
@@ -303,6 +328,30 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise NotImplementedError("a gate on latent attention is not built")
     if total_ut_steps < 1:
         raise ValueError(f"total_ut_steps {total_ut_steps} is not positive")
+    if objective not in ("next_token", "block_diffusion"):
+        raise NotImplementedError(f"objective {objective!r} is not built")
+    diffusion = objective == "block_diffusion"
+    if not diffusion and block_length is not None:
+        raise ValueError("block_length without objective='block_diffusion'")
+    if diffusion:
+        if not block_length or block_length < 1 \
+                or max_length % block_length:
+            raise ValueError(
+                f"block_length {block_length!r} does not cut max_length "
+                f"{max_length} into whole blocks")
+        kinds = set(layer_types or ["full_attention"])
+        unbuilt = [what for what, asked in [
+            (f"layer types {sorted(kinds - {'full_attention'})}",
+             kinds != {"full_attention"}),
+            ("latent attention", kv_lora_rank is not None),
+            ("a prediction module", num_nextn_predict_layers),
+            ("a looped stack", total_ut_steps > 1 or exit_gate),
+            ("a tied head", tie_word_embeddings)] if asked]
+        if unbuilt:
+            raise NotImplementedError(
+                "under objective='block_diffusion' only full_attention "
+                "layers, straight, with an untied head are built; got "
+                + ", ".join(unbuilt))
     loops = total_ut_steps > 1 or exit_gate is not None
     if loops and exit_gate is None:
         raise ValueError("a stack run several times needs an exit_gate: "
@@ -410,7 +459,11 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         turn = rotary[kind]
         # a program that mixes kinds of layer tells their rows apart
         scope = "gated_attention" if attention_gate else kind
-        with name_scope(scope) if windowed or attention_gate \
+        if diffusion:
+            # both halves stand at positions 0 .. max_length - 1
+            scope = "block_diffusion_attention"
+            turn = dict(turn, period=max_length)
+        with name_scope(scope) if windowed or attention_gate or diffusion \
                 else contextlib.nullcontext():
             q = layers.rope(norm_qk(proj(h, q_size, "attn_qkv")),
                             num_attention_heads, **turn)
@@ -418,10 +471,11 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                             num_key_value_heads, **turn)
             v = proj(h, kv_size, "attn_qkv")
             ctx = layers.flash_attention(
-                q, k, v, causal=True, use_pallas=True, layout="nthd",
-                n_head=num_attention_heads, n_kv_head=num_key_value_heads,
+                q, k, v, causal=not diffusion, use_pallas=True,
+                layout="nthd", n_head=num_attention_heads,
+                n_kv_head=num_key_value_heads,
                 window=sliding_window if kind == "sliding_attention"
-                else None)
+                else None, block_diffusion=block_length)
             if attention_gate:
                 # a gate a lane of the context, from the layer's input
                 ctx = layers.elementwise_mul(ctx, layers.sigmoid(
@@ -612,7 +666,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                                                     scale=-1.0),
                     "total_ut_steps": trips}
 
-    tokens = layers.data(name="tokens", shape=[max_length], dtype="int64")
+    tokens = layers.data(name="tokens", dtype="int64",
+                         shape=[2 * max_length if diffusion else max_length])
     labels = layers.data(name="labels", shape=[max_length], dtype="int64")
     embed_name = "tok_embedding.w"
     x = layers.embedding(
@@ -624,9 +679,25 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     if loops:
         return dict(looped(x), aux=None, z=None, counts=[], experts=[],
                     feeds=feeds, mtp_logits=None, mtp_ce=None)
-    x = norm(stack(x))
+    x = stack(x)
+    masked_share = None
+    if diffusion:
+        # the head reads the noised half; position i predicts x_0[i]
+        weights = layers.data(name="loss_weights", shape=[max_length],
+                              dtype="float32")
+        feeds.append("loss_weights")
+        x = layers.slice(x, axes=[1], starts=[max_length],
+                         ends=[2 * max_length])
+    x = norm(x)
     logits = head(x)
-    ce = token_ce(logits, labels)
+    if diffusion:
+        ce = layers.mean(layers.elementwise_mul(
+            layers.softmax_with_cross_entropy(
+                logits, layers.unsqueeze(labels, axes=[2])),
+            layers.unsqueeze(weights, axes=[2])))
+        masked_share = layers.mean(layers.sign(weights))
+    else:
+        ce = token_ce(logits, labels)
     mtp_logits = mtp_ce = None
     if num_nextn_predict_layers:
         next_labels = layers.data(name="next_labels", shape=[max_length],
@@ -650,7 +721,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     return {"logits": logits, "ce": ce, "aux": layer_mean(aux_losses),
             "z": layer_mean(z_losses), "counts": counts,
             "experts": experts, "feeds": feeds, "mtp_logits": mtp_logits,
-            "mtp_ce": mtp_ce}
+            "mtp_ce": mtp_ce, "masked_share": masked_share}
 
 
 @runtime_stats.stage("build_program")
@@ -702,6 +773,9 @@ def build_model(max_length, learning_rate=4e-4, beta1=0.9, beta2=0.95,
 
         track_scalars(program, ce_loss=ce,
                       **{name: var for name, (var, _) in terms.items()})
+        if model.get("masked_share") is not None:
+            track_scalars(program, diffusion_loss=ce,
+                          masked_share=model["masked_share"])
         trips = model.get("total_ut_steps")
         if trips:
             # whether a further trip buys anything, and where the exit

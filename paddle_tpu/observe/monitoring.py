@@ -70,6 +70,8 @@ _FIELDS = Heard._fields[1:] + (
     "flash_gqa_backward_fused", "flash_gqa_backward_split",
     "flash_attention_backward_fused", "flash_attention_backward_split",
     "flash_window_blocks_visited", "flash_window_blocks_allowed",
+    "flash_block_diffusion_calls", "flash_block_diffusion_blocks_visited",
+    "flash_block_diffusion_blocks_allowed",
     "gated_delta_calls", "gated_delta_chunks",
     "gated_delta_operand_calls", "gated_delta_operand_chunks",
     "recompute_kept_residuals", "recompute_kept_bytes",
@@ -152,6 +154,14 @@ class RuntimeStats:
         # times apart where skipping is lost (delta() around a build)
         self.flash_window_blocks_visited = 0
         self.flash_window_blocks_allowed = 0
+        # the same pair for the forward kernel under the block-diffusion
+        # mask (`flash_attention.py _DiffusionBand`: tiles a head's grid
+        # computes, and tiles that hold an allowed pair), and the calls
+        # of it traced, forward and recomputed; 0 calls = a step that
+        # fell back to the XLA lowering under an explicit mask
+        self.flash_block_diffusion_calls = 0
+        self.flash_block_diffusion_blocks_visited = 0
+        self.flash_block_diffusion_blocks_allowed = 0
         # calls of the chunked delta-rule scan's Pallas kernels traced
         # (`ops/pallas/gated_delta.py`: a layer's forward, its
         # recomputed forward and its backward are a call each) and their
@@ -252,6 +262,12 @@ class RuntimeStats:
         with self._lock:
             self.flash_window_blocks_visited += visited
             self.flash_window_blocks_allowed += allowed
+
+    def record_flash_block_diffusion(self, visited: int, allowed: int):
+        with self._lock:
+            self.flash_block_diffusion_calls += 1
+            self.flash_block_diffusion_blocks_visited += visited
+            self.flash_block_diffusion_blocks_allowed += allowed
 
     def record_gated_delta(self, chunks: int):
         with self._lock:

@@ -31,6 +31,24 @@ def _causal_mask(t_q, t_k, window=None):
     return mask
 
 
+def _block_diffusion_mask(t, length):
+    """The block-diffusion training mask (Arriola et al.,
+    arXiv:2503.09573) over `t` = 2 L rows, the clean half first and the
+    noised half after it, row L + p standing at position p, blocks of
+    `length` positions: a clean row reads the clean rows of its own and
+    earlier blocks; a noised row the clean rows of strictly earlier
+    blocks and the noised rows of its own block, later ones too."""
+    half = t // 2
+    row = jnp.arange(t)
+    noised = row >= half
+    blk = (row - half * noised) // length
+    r_blk, s_blk = blk[:, None], blk[None, :]
+    r_noised, s_noised = noised[:, None], noised[None, :]
+    return jnp.where(
+        s_noised, r_noised & (s_blk == r_blk),
+        jnp.where(r_noised, s_blk < r_blk, s_blk <= r_blk))
+
+
 def _xla_attention(q, k, v, bias, scale, causal, window=None):
     logits = jnp.einsum("nhqd,nhkd->nhqk", q, k) * scale
     if bias is not None:
@@ -45,7 +63,7 @@ def _xla_attention(q, k, v, bias, scale, causal, window=None):
 
 
 def _xla_attention_nthd(q, k, v, bias, scale, causal, n_head,
-                        n_kv_head=None, window=None):
+                        n_kv_head=None, window=None, block_diffusion=None):
     """XLA composition over head-grouped (N, T, H*D) operands.  The
     4D views are free reshapes (minor-dim split/merge) and the einsums
     carry the head dim as a dot batch dim — XLA folds the operand
@@ -68,6 +86,9 @@ def _xla_attention_nthd(q, k, v, bias, scale, causal, n_head,
         t_kk = logits.shape[-1]
         mask = _causal_mask(t_q, t_kk, window)
         logits = jnp.where(mask, logits, -1e9)
+    if block_diffusion is not None:
+        logits = jnp.where(_block_diffusion_mask(t_q, block_diffusion),
+                           logits, -1e9)
     weights = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     o = jnp.einsum("nhqk,nkhd->nqhd", weights.astype(q.dtype), v4)
     return o.reshape(n, t_q, hd)
@@ -123,6 +144,24 @@ def flash_attention(ctx, ins, attrs):
                 "flash_attention: a window is causal head-major "
                 "self-attention with no Bias and no sequence_parallel")
         window = int(window)
+    block_diffusion = attrs.get("block_diffusion", None)
+    if block_diffusion is not None:
+        # the mask is the call's own: a clean and a noised half of
+        # whole blocks, nothing beside q, k, v
+        t = q.shape[t_axis]
+        block_diffusion = int(block_diffusion)
+        if (causal or window is not None or bias is not None
+                or layout != "nthd"
+                or attrs.get("sequence_parallel", False)
+                or k.shape[t_axis] != t):
+            raise NotImplementedError(
+                "flash_attention: the block-diffusion mask is head-major "
+                "self-attention with no causal mask or window beside it, "
+                "no Bias and no sequence_parallel")
+        if block_diffusion < 1 or t % (2 * block_diffusion):
+            raise ValueError(
+                f"flash_attention: {t} rows are not a clean and a noised "
+                f"half of whole blocks of {block_diffusion}")
     if attrs.get("sequence_parallel", False):
         # long-context path: shard the sequence axis over the mesh's
         # sp axis and run ring attention (KV rotation via ppermute) or
@@ -189,7 +228,13 @@ def flash_attention(ctx, ins, attrs):
                                n_head=h_count)
             return out(Out=o)
         # no sp axis in this compile: fall through to the local kernel
-    if attrs.get("use_pallas", False):
+    use_pallas = attrs.get("use_pallas", False)
+    if use_pallas and block_diffusion is not None:
+        from .pallas.flash_attention import block_diffusion_takes
+
+        # a half the kernels' tiles do not divide runs the explicit mask
+        use_pallas = block_diffusion_takes(t, block_diffusion)
+    if use_pallas:
         def _kernel_bias_ok(b):
             # the tiled kernel takes a KEY-padding bias broadcastable
             # TO (N, 1, 1, Tk): every (right-aligned) dim must be 1 or
@@ -213,13 +258,15 @@ def flash_attention(ctx, ins, attrs):
                 f"leave use_pallas unset for the XLA composition")
         from .pallas.flash_attention import pallas_flash_attention
 
+        extra = {} if window is None else {"window": window}
+        if block_diffusion is not None:
+            extra["block_diffusion"] = block_diffusion
         o = pallas_flash_attention(
             q, k, v, bias, scale, causal, layout=layout, n_head=h_count,
-            n_kv_head=None if n_kv_head == h_count else n_kv_head,
-            **({} if window is None else {"window": window}))
+            n_kv_head=None if n_kv_head == h_count else n_kv_head, **extra)
     elif layout == "nthd":
         o = _xla_attention_nthd(q, k, v, bias, scale, causal, h_count,
-                                n_kv_head, window)
+                                n_kv_head, window, block_diffusion)
     else:
         o = _xla_attention(q, k, v, bias, scale, causal)
     return out(Out=o)

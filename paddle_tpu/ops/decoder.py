@@ -130,7 +130,10 @@ def rope(ctx, ins, attrs):
     and cos and sin are multiplied by `attention_factor`: scaled RoPE
     (`rope_frequencies`).  `rotary_dim` R < D: only the first R lanes
     of every head turn, as a head of R would (pairs (i, i + R/2),
-    theta^(-2i/R)); lanes R.. pass through."""
+    theta^(-2i/R)); lanes R.. pass through.  `period` P: positions
+    restart every P rows (row r stands at r mod P; block-diffusion
+    training feeds a clean and a noised copy of a sequence, P = T / 2),
+    before the Offset."""
     x = first(ins, "X")
     offset = opt_in(ins, "Offset")
     n_head = int(attrs["n_head"])
@@ -154,6 +157,8 @@ def rope(ctx, ins, attrs):
             [turned.reshape(n, t, n_head, rotary), x4[..., rotary:]],
             axis=-1).reshape(n, t, hd))
     pos = jnp.arange(t, dtype=jnp.int32)
+    if attrs.get("period"):
+        pos = pos % int(attrs["period"])
     if offset is not None:
         pos = pos + offset.reshape(()).astype(jnp.int32)
     inv_freq = attrs.get("inv_freq")

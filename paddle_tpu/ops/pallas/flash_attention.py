@@ -124,6 +124,16 @@ DEFAULT_BAND_BLOCK_Q = 1024
 DEFAULT_BAND_BLOCK_K = 1024
 DEFAULT_WINDOW_BWD_BLOCK_Q = 512
 DEFAULT_WINDOW_BWD_BLOCK_K = 512
+# The block-diffusion mask's square tiles, forward and backward, tuned
+# on v5e at 2 x 8192 rows, 32 / 4 heads of 128, blocks of 4, alone
+# (PERF.md, PR 47): 1024 x 1024 takes 11.2 ms forward and 33.7 forward +
+# backward, 512 x 512 19.4 and 47.7, 256 x 256 45.7 and 109.8 (the
+# causal band kernels over the same 2 L rows 19.0 and 55.2).  Of a
+# head's 256 tiles of 1024 x 1024 80 hold an allowed pair and 24 of
+# those a mask (of 1024 tiles of 512 x 512: 288 and 48: fewer pairs
+# computed, and three times the grid steps)
+DEFAULT_DIFFUSION_BLOCK = 1024
+DEFAULT_DIFFUSION_BWD_BLOCK = 1024
 NEG_INF = -1e30
 # The single backward kernel holds one head's dq, a whole sequence of
 # float32 (4 * d bytes a position).  It may take this much of v5e's
@@ -230,9 +240,12 @@ def _register_costs():
     register_kernel_cost("flash_fwd", flash_fwd_cost)
     register_kernel_cost("flash_dkv", flash_dkv_cost)
     register_kernel_cost("flash_dq", flash_dq_cost)
-    # a call with a window: the band's pairs (`_Band.cost_estimate`)
+    # a call with a window or under the block-diffusion mask: the
+    # band's pairs (`_Band.cost_estimate`)
     for kernel in ("fwd", "dkv", "dq"):
         register_kernel_cost("flash_window_" + kernel, DECLARED_AT_CALL)
+        register_kernel_cost("flash_block_diffusion_" + kernel,
+                             DECLARED_AT_CALL)
 
 
 _register_costs()
@@ -349,12 +362,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
         run = (q_off + (qb + 1) * block_q > k_off + kb * block_k) \
             if causal else True
     else:
-        # the grid's last axis counts from the band's first key block
-        kb = band.first_k(qb) + kb
-        run = kb <= band.last_k(qb)
+        # the grid's last axis counts the key blocks of the band
+        kb = band.key_at(qb, step)
+        run = band.key_runs(qb, step, kb)
+    diffusion = band is not None and band.block_length
 
-    @pl.when(run)
-    def _compute():
+    def _compute(masked=True):
         q = _tile(q_ref)                  # (block_q, d)
         k = _tile(k_ref)                  # (block_k, d)
         s = jax.lax.dot_general(
@@ -364,19 +377,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)
 
-        # Always mask k-positions past the true sequence length: when
-        # t_k % block_k != 0 the last k-block is padded and its garbage
-        # columns would otherwise corrupt the online softmax and lse.
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = k_pos < t_k
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = valid & (q_off + q_pos >= k_off + k_pos)
-            if band is not None and band.window:
-                valid = valid & (q_pos - k_pos < band.window)
-        s = jnp.where(valid, s, NEG_INF)
+        if diffusion:
+            # whole blocks: no padding; only a diagonal tile is masked
+            if masked:
+                s = jnp.where(band.allowed(qb, kb, 0), s, NEG_INF)
+        else:
+            # Always mask k-positions past the true sequence length:
+            # when t_k % block_k != 0 the last k-block is padded and its
+            # garbage columns would otherwise corrupt the online softmax
+            # and lse.
+            k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            valid = k_pos < t_k
+            if causal:
+                q_pos = qb * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                valid = valid & (q_off + q_pos >= k_off + k_pos)
+                if band is not None and band.window:
+                    valid = valid & (q_pos - k_pos < band.window)
+            s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_scr[:]                 # (block_q, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -386,14 +405,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
         l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
         # Zero padded v-rows: block padding is undefined memory and
         # 0 * NaN would poison the accumulator even though p==0 there.
-        v_rows = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)
-        vv = jnp.where(v_rows < t_k, _tile(v_ref), 0)
+        if diffusion:
+            vv = _tile(v_ref)
+        else:
+            v_rows = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            vv = jnp.where(v_rows < t_k, _tile(v_ref), 0)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
             p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
         l_scr[:] = l_new
+
+    if band is None:
+        pl.when(run)(_compute)
+    else:
+        band.when_runs(run, qb, kb, _compute)
 
     @pl.when(step == nk - 1)
     def _finalize():
@@ -467,19 +494,16 @@ def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
         o_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     else:
         o_shape = jax.ShapeDtypeStruct((nh, t_q, d), q.dtype)
-    windowed = {}
-    if band is not None and band.window:
-        from ...observe.monitoring import runtime_stats
-
+    declared = {}
+    if band is not None and band.prefix != "flash_":
         # a head's: the batch of a build-time shape inference is a
         # placeholder
-        runtime_stats.record_flash_window_blocks(
-            band.nq * band.k_steps, band.blocks_allowed)
-        windowed = band.cost_estimate("fwd", nh, d, q.dtype.itemsize, group)
+        band.record_blocks()
+        declared = band.cost_estimate("fwd", nh, d, q.dtype.itemsize, group)
     o, lse8 = _pallas_call(
         kern,
-        name="flash_window_fwd" if windowed else "flash_fwd",
-        **windowed,
+        name=(band.prefix if band is not None else "flash_") + "fwd",
+        **declared,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -552,7 +576,7 @@ def _runs(offs_ref, kb, qb, *, causal, block_q, block_k, **_):
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
               bias_ref, offs_ref, kb, qb, *, scale, causal, block_q,
-              block_k, t_q, t_k, window=None):
+              block_k, t_q, t_k, window=None, diffusion=None, masked=True):
     """(q, k, do, p, ds) of block pair (kb, qb): the tiles with their
     padding zeroed, and the float32 (block_k, block_q) p and ds every
     gradient is a dot of.  All three backward kernels take their terms
@@ -581,6 +605,10 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
         + [q_off + q_pos >= k_off + k_pos] * bool(causal)
     if window:
         valid.append(q_pos - k_pos < window)
+    if diffusion is not None:
+        # block diffusion (`_DiffusionBand`): whole blocks, and only a
+        # diagonal tile is `masked`
+        valid = [diffusion.allowed(qb, kb, 1)] * bool(masked)
     p = jnp.exp(sT - lse_ref[0, 0][None, :])
     ds = p * (_dot(_tile(v_ref), do, ((1,), (1,)))
               - _delta_row(do, o, dlse_ref))
@@ -947,11 +975,14 @@ def _flash_bwd_split(q, k, v, bias, offsets, o, lse8, do, dlse8, scale,
     return dq, dk, dv, dbias
 
 
-# -- the band: a window, grouped key/value heads ----------------------------
+# -- the band: a window, grouped key/value heads, the block-diffusion mask ----
 #
 # A causal self-attention call, head-major, over whole blocks, in which
 # query i reads keys i - window < j <= i (window None: every j <= i)
-# and `group` query heads read one key/value head.  The grids below run
+# and `group` query heads read one key/value head; or, in place of the
+# causal prefix, the block-diffusion training mask over a clean and a
+# noised half (`_DiffusionBand`, a third geometry of the same kernels).
+# The grids below run
 # over the block pairs of the band alone: an axis counts from the
 # band's first block, and the index maps stop at its last, so a block
 # pair outside the band costs no compute and no DMA.  k, v, dk and dv
@@ -1005,6 +1036,53 @@ class _Band:
         return _lo(((kb + 1) * self.block_k + self.window - 2)
                    // self.block_q, self.nq - 1)
 
+    block_length = None     # the block-diffusion geometry's own
+
+    @property
+    def prefix(self):
+        """What the kernels' names begin with.  A call with a window
+        runs under names of its own and declares its cost at the call:
+        no operand's shape says what a band allows."""
+        return "flash_window_" if self.window else "flash_"
+
+    def record_blocks(self):
+        """The forward grid of a call with a window, a head's."""
+        from ...observe.monitoring import runtime_stats
+
+        runtime_stats.record_flash_window_blocks(
+            self.nq * self.k_steps, self.blocks_allowed)
+
+    # A grid's last axis counts the blocks of the band: the key block
+    # of a query block's `step` (`key_at`) and whether the pair holds a
+    # score (`key_runs`); the same from a key block's side.  Two calls,
+    # because a kernel asks the second where it branches on it.
+    def key_at(self, qb, step):
+        return self.first_k(qb) + step
+
+    def key_runs(self, qb, step, kb):
+        return kb <= self.last_k(qb)
+
+    def query_at(self, kb, step):
+        return self.first_q(kb) + step
+
+    def query_runs(self, kb, step, qb):
+        return qb <= self.last_q(kb)
+
+    def key_block(self, qb, step):
+        kb = self.key_at(qb, step)
+        return kb, self.key_runs(qb, step, kb)
+
+    def query_block(self, kb, step):
+        qb = self.query_at(kb, step)
+        return qb, self.query_runs(kb, step, qb)
+
+    def when_runs(self, run, qb, kb, compute):
+        """Run `compute(masked)` where the block pair holds a score;
+        every pair of this band goes through its position masks."""
+        from jax.experimental import pallas as pl
+
+        pl.when(run)(functools.partial(compute, True))
+
     def k_time(self, qb, step):
         """The key block of a query block's `step`: past the diagonal
         it stays there, and nothing new is fetched."""
@@ -1048,6 +1126,150 @@ class _Band:
                                * (tiles[0] + tiles[1] / group)))}
 
 
+def _pick(cond, a, b):
+    if isinstance(cond, (bool, np.bool_)):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+class _DiffusionBand(_Band):
+    """The block geometry of block-diffusion training (Arriola et al.,
+    arXiv:2503.09573) over `t` = 2 L rows, the clean half x_0 FIRST and
+    the noised half x_t after it, row L + p standing at position p.
+    With blk(p) = p // block_length, a row reads
+
+        clean  -> clean :  blk(s) <= blk(r)   (block-causal, a block whole)
+        noised -> clean :  blk(s) <  blk(r)   (the clean prefix)
+        noised -> noised:  blk(s) == blk(r)   (its own block, both ways)
+        clean  -> noised:  never
+
+    so every allowed key lies at or before its query's tile and
+    `last_k` stays the diagonal's.  Tiles are square, a whole number of
+    blocks, `n` a half.  A clean query tile meets clean tiles 0 .. its
+    own; a noised one (position tile qp) meets clean tiles 0 .. qp (the
+    last holds its strictly earlier blocks: none where a tile is ONE
+    block, and it is then not visited) and then its own noised tile: TWO
+    runs of key tiles, joined in `key_at`.  A clean key tile kc meets
+    clean query tiles kc .. n - 1 and then the noised ones from n + kc
+    (n + kc + 1 where a tile is one block); a noised key tile its own
+    query tile alone.  Only tiles at the query tile's own position are
+    masked (by block id: two iotas and a division, `allowed`)."""
+
+    window = None
+    prefix = "flash_block_diffusion_"
+
+    def __init__(self, t, block, block_length):
+        self.t, self.block_length = t, block_length
+        self.block_q = self.block_k = block
+        self.n = n = t // 2 // block
+        self.nq = self.nk = 2 * n
+        # whether a noised query tile reads the clean tile at its own
+        # position: it holds strictly earlier blocks
+        self.own = int(block_length < block)
+        self.k_steps = n + self.own
+        self.q_steps = 2 * n - 1 + self.own
+        self.blocks_allowed = n * (n + 1) // 2 + n * (n - 1) // 2 \
+            + n * self.own + n
+
+    def record_blocks(self, backward=False):
+        """One traced call: the tiles its grid computes for a head (the
+        forward's over query tiles, the backward's over key tiles) and
+        those that hold an allowed pair."""
+        from ...observe.monitoring import runtime_stats
+
+        if backward:
+            visited = sum(bool(self.query_block(kb, step)[1])
+                          for kb in range(self.nk)
+                          for step in range(self.q_steps))
+        else:
+            visited = sum(bool(self.key_block(qb, step)[1])
+                          for qb in range(self.nq)
+                          for step in range(self.k_steps))
+        runtime_stats.record_flash_block_diffusion(visited,
+                                                   self.blocks_allowed)
+
+    def _clean_keys(self, qb):
+        """Clean key tiles a query tile meets: 0 .. this many - 1."""
+        return _pick(qb >= self.n, qb - self.n + self.own, qb + 1)
+
+    def key_at(self, qb, step):
+        # past its last step a query tile stays on its last key tile
+        return _pick(step < self._clean_keys(qb), step, qb)
+
+    def key_runs(self, qb, step, kb):
+        return step < self._clean_keys(qb) + _pick(qb >= self.n, 1, 0)
+
+    k_time = key_at
+
+    def first_k(self, qb):
+        if self.own:
+            return 0
+        return _pick(qb == self.n, self.n, 0)
+
+    def last_k(self, qb):
+        return qb
+
+    def _queries(self, kb):
+        """Query tiles a key tile meets: a noised one its own alone."""
+        return _pick(kb >= self.n, 1, 2 * (self.n - kb) - 1 + self.own)
+
+    def query_at(self, kb, step):
+        n = self.n
+        clean = n - kb          # clean query tiles of a clean key tile
+        at = _lo(step, self._queries(kb) - 1)
+        qb = _pick(at < clean, kb + at, 2 * kb + 1 - self.own + at)
+        return _pick(kb >= n, kb, qb)
+
+    def query_runs(self, kb, step, qb):
+        return step < self._queries(kb)
+
+    q_time = query_at
+
+    def dq_time(self, kb, step):
+        """Query tile kb is complete at the FIRST step of key tile kb's
+        pass (its diagonal, a clean tile's and a noised tile's alike)
+        and none other is completed in that pass: the index stays there,
+        and the tile leaves when the next pass begins."""
+        return kb
+
+    def interior(self, qb, kb):
+        """Whether every pair of tile (qb, kb) is allowed: a key tile
+        before the query tile's own position."""
+        n = self.n
+        return _pick(kb >= n, kb - n, kb) < _pick(qb >= n, qb - n, qb)
+
+    def allowed(self, qb, kb, q_axis):
+        """The mask of a tile at the query tile's own position, from
+        local block ids; queries along `q_axis` of the square tile."""
+        block, n = self.block_q, self.n
+        shape = (block, block)
+
+        def blk(axis):
+            i = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            b = self.block_length
+            if b & (b - 1) == 0:
+                return jax.lax.shift_right_logical(i, b.bit_length() - 1)
+            return jax.lax.div(i, b)
+
+        ahead = blk(q_axis) - blk(1 - q_axis)   # blk(r) - blk(s)
+        # clean -> clean: >= 0; noised -> clean: >= 1; noised -> noised: 0
+        least = jnp.where((qb >= n) & (kb < n), 1, 0)
+        return (ahead >= least) & ((kb < n) | (ahead == 0))
+
+    def when_runs(self, run, qb, kb, compute):
+        from jax.experimental import pallas as pl
+
+        inner = self.interior(qb, kb)
+        pl.when(run & inner)(functools.partial(compute, False))
+        pl.when(run & jnp.logical_not(inner))(functools.partial(compute, True))
+
+    def pairs(self):
+        half, b = self.t // 2, self.block_length
+        blocks = half // b
+        return half * b + b * b * (blocks * (blocks - 1) // 2
+                                   + blocks * (blocks + 1) // 2)
+
+
 def band_backward_fits(t, d):
     """Whether the backward pass of a band call is the single kernel:
     from the shape alone.  It holds dq of one query head and dk, dv of
@@ -1071,8 +1293,8 @@ def _bwd_band_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     from jax.experimental import pallas as pl
 
     gi, kb, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    qb = band.first_q(kb) + step
-    run = qb <= band.last_q(kb)
+    qb = band.query_at(kb, step)
+    run = band.query_runs(kb, step, qb)
 
     @pl.when((gi == 0) & (step == 0))
     def _init():
@@ -1083,13 +1305,14 @@ def _bwd_band_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     def _init_dq():
         dq_acc[qb] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
-    @pl.when(run)
-    def _compute():
+    def _compute(masked):
         q, k, do, p, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
-            kb, qb, **dims)
+            kb, qb, masked=masked, **dims)
         _add_dk_dv(p, ds, q, do, dk_acc, dv_acc, dims["scale"], at=kb)
         _add_dq(ds, k, dq_acc, dims["scale"], at=qb)
+
+    band.when_runs(run, qb, kb, _compute)
 
     @pl.when((gi == group - 1) & (step == band.q_steps - 1))
     def _finalize():
@@ -1109,19 +1332,20 @@ def _bwd_band_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     from jax.experimental import pallas as pl
 
     kb, gi, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    qb = band.first_q(kb) + step
+    qb = band.query_at(kb, step)
 
     @pl.when((gi == 0) & (step == 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(qb <= band.last_q(kb))
-    def _compute():
+    def _compute(masked):
         q, _, do, p, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
-            kb, qb, **dims)
+            kb, qb, masked=masked, **dims)
         _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, dims["scale"])
+
+    band.when_runs(band.query_runs(kb, step, qb), qb, kb, _compute)
 
     @pl.when((gi == group - 1) & (step == band.q_steps - 1))
     def _finalize():
@@ -1135,18 +1359,19 @@ def _bwd_band_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     from jax.experimental import pallas as pl
 
     qb, step = pl.program_id(1), pl.program_id(2)
-    kb = band.first_k(qb) + step
+    kb = band.key_at(qb, step)
 
     @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(kb <= band.last_k(qb))
-    def _compute():
+    def _compute(masked):
         _, k, _, _, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
-            kb, qb, **dims)
+            kb, qb, masked=masked, **dims)
         _add_dq(ds, k, dq_scr, dims["scale"])
+
+    band.when_runs(band.key_runs(qb, step, kb), qb, kb, _compute)
 
     @pl.when(step == band.k_steps - 1)
     def _finalize():
@@ -1157,7 +1382,8 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
     """(dq, dk, dv) of a band call: one kernel where `band_backward_fits`,
     the two that hold blocks only beyond; both take every term from
     `_bwd_p_ds` and add it in the same order.  The kernels of a call
-    with a window run under names of their own (`flash_window_*`)."""
+    with a window run under names of their own (`flash_window_*`), as
+    do those of the block-diffusion mask (`flash_block_diffusion_*`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1170,13 +1396,16 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
     runtime_stats.record_flash_backward("flash_attention", fused)
     dims = dict(scale=scale, causal=True, block_q=bq, block_k=bk, t_q=t,
                 t_k=t, window=band.window)
+    if band.block_length:
+        dims.update(causal=False, diffusion=band)
+        band.record_blocks(backward=True)
     item = q.dtype.itemsize
 
     def name(kernel):
-        return ("flash_window_" if band.window else "flash_") + kernel
+        return band.prefix + kernel
 
     def cost(kernel):
-        if not band.window:
+        if band.prefix == "flash_":
             return {}
         return band.cost_estimate(kernel, n * h, d, item, group)
 
@@ -1265,24 +1494,32 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window):
+def _band_of(t, blocks, window, block_diffusion):
+    if block_diffusion:
+        return _DiffusionBand(t, blocks[0], block_diffusion)
+    return _Band(t, *blocks, window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window,
+                block_diffusion=None):
     return _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
-                           window)[0]
+                           window, block_diffusion)[0]
 
 
 def _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
-                    window):
+                    window, block_diffusion):
+    band = _band_of(q.shape[1], blocks, window, block_diffusion)
     o, lse8 = keep_residuals(*_flash_fwd(
-        q, k, v, None, None, scale, True, *blocks, "nthd", n_head,
-        _Band(q.shape[1], *blocks, window), group))
+        q, k, v, None, None, scale, not block_diffusion, *blocks, "nthd",
+        n_head, band, group))
     return o, (q, k, v, o, lse8)
 
 
-def _flash_band_bwd(scale, blocks, bwd_blocks, n_head, group, window, res,
-                    do):
+def _flash_band_bwd(scale, blocks, bwd_blocks, n_head, group, window,
+                    block_diffusion, res, do):
     q, k, v, o, lse8 = res
-    band = _Band(q.shape[1], *bwd_blocks, window)
+    band = _band_of(q.shape[1], bwd_blocks, window, block_diffusion)
     dq, dk, dv = _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head,
                                  group)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -1340,11 +1577,68 @@ def _band_blocks(t, block_q, block_k, window):
         for pair in own)
 
 
+def _diffusion_blocks(t, length, block=None):
+    """(forward tile, backward tile) of a call under the block-diffusion
+    mask over `t` rows in blocks of `length`: a size given holds for
+    both passes; else each pass's own, or its half or its quarter where
+    that is the largest that cuts a half into whole tiles of whole
+    blocks (a half under a tile is one tile)."""
+    half = t // 2
+
+    def tile(own):
+        sizes = [min(own >> halvings, half) for halvings in range(3)]
+        return next((b for b in sizes
+                     if b and half % b == 0 and b % length == 0), sizes[0])
+
+    return tuple(min(int(block), half) if block else tile(own) for own in
+                 (DEFAULT_DIFFUSION_BLOCK, DEFAULT_DIFFUSION_BWD_BLOCK))
+
+
+def block_diffusion_takes(t, length):
+    """Whether the kernels run `t` rows under the block-diffusion mask
+    at one of their own tiles: each half a whole number of tiles, a
+    tile a whole number of blocks of `length`.  From the shape alone;
+    another shape runs the XLA lowering under the explicit mask."""
+    return t % 2 == 0 and length >= 1 and all(
+        b and (t // 2) % b == 0 and b % length == 0
+        for b in _diffusion_blocks(t, length))
+
+
+def _flash_block_diffusion(q, k, v, scale, h, hkv, length, block_q, block_k,
+                           bare):
+    """The head-major call under the block-diffusion mask, or the
+    reason it is not one.  `bare`: nothing beside q, k, v came with it
+    (a bias, offsets, a returned logsumexp, a causal mask or a window)."""
+    n, t, hd = q.shape
+    d = hd // h
+    if length < 1:
+        raise ValueError(f"block_diffusion {length} is no block length")
+    if block_q != block_k:
+        raise NotImplementedError(
+            f"the block-diffusion mask's tiles are square; got blocks "
+            f"{block_q} x {block_k}")
+    blocks = _diffusion_blocks(t, length, block_q)
+    if (not bare or k.shape[1] != t or t % 2
+            or any(b < 1 or (t // 2) % b or b % length for b in blocks)):
+        raise NotImplementedError(
+            f"flash attention under the block-diffusion mask is "
+            f"self-attention over a clean and a noised half of whole "
+            f"blocks each, a tile a whole number of blocks of "
+            f"{length}, with no bias, offsets, returned logsumexp, "
+            f"window or causal mask beside its own; got T_q {t}, T_k "
+            f"{k.shape[1]}, tiles {blocks}")
+    return _flash_band(
+        q, k, v, float(d ** -0.5 if scale is None else scale),
+        (blocks[0],) * 2, (blocks[1],) * 2, int(h), int(h // hkv), None,
+        length)
+
+
 def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
                            block_q=None, block_k=None,
                            q_offset=None, k_offset=None,
                            return_lse=False, layout="nhtd",
-                           n_head=None, n_kv_head=None, window=None):
+                           n_head=None, n_kv_head=None, window=None,
+                           block_diffusion=None):
     """layout="nhtd" (default): q/k/v (N, H, T, D), output (N, H, T, D).
     layout="nthd": q/k/v (N, T, H*D) head-grouped — the head-major
     end-to-end contract; `n_head` is required and the batch*head fold
@@ -1357,6 +1651,14 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
     included; a window that holds every key is no window.  Both are
     causal self-attention over whole blocks with nothing beside q, k,
     v (the band kernels above); anything else with them raises.
+    `block_diffusion` B (head-major, not causal, no window): the rows
+    are a clean half and a noised half of T / 2 positions each, cut
+    into blocks of B, under the block-diffusion training mask
+    (`_DiffusionBand`), with or without grouped key/value heads, at
+    d_head 128 or more on the chip.  The band kernels thus take THREE
+    geometries: the causal prefix (grouped heads), the causal prefix
+    under a window, and the block-diffusion mask; each refuses a bias,
+    offsets, a returned logsumexp, cross lengths and a ragged block.
 
     q_offset/k_offset: optional GLOBAL position offsets (python ints or
     traced scalars) applied in causal masking — ring attention passes the
@@ -1390,6 +1692,12 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
                  and k.shape[1] == t_q)
         if window is not None and plain and window >= t_q:
             window = None
+        if block_diffusion is not None:
+            return _flash_block_diffusion(
+                q, k, v, scale, h, n_kv_head, int(block_diffusion), block_q,
+                block_k, bare=bias is None and not (
+                    causal or return_lse or window is not None
+                    or q_offset is not None or k_offset is not None))
         if d == 64 and plain and window is None:
             from .flash_gqa import flash_gqa
 
@@ -1412,10 +1720,12 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
         t_k = k.shape[1]
         qf, kf, vf = q, k, v
     elif layout == "nhtd":
-        if n_kv_head is not None or window is not None:
+        if (n_kv_head is not None or window is not None
+                or block_diffusion is not None):
             raise NotImplementedError(
-                "flash attention with grouped key/value heads or a "
-                "window is head-major (layout='nthd')")
+                "flash attention with grouped key/value heads, a "
+                "window or the block-diffusion mask is head-major "
+                "(layout='nthd')")
         n, h, t_q, d = q.shape
         t_k = k.shape[2]
         qf = q.reshape(n * h, t_q, d)

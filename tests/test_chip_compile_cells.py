@@ -1,7 +1,8 @@
 """The chip's compiler on what a cell's step hands it beside the attention
 kernels, at the published widths: `olmoe-4k`, `lfm2-8k`, the looped step
-of `ouro-4k`, and `ops/pallas/grouped_matmul.py` at the four expert
-cells' shapes (tests/chip_compile.py says why and how).
+of `ouro-4k`, `ops/pallas/grouped_matmul.py` at the four expert cells'
+shapes, and the whole step of `sdar-8k` with the plan its depth rule
+read (tests/chip_compile.py says why and how).
 """
 
 from __future__ import annotations
@@ -356,3 +357,65 @@ def test_grouped_matmul_kernels_at_the_cells_shapes(one_chip, cell):
             kernels = [r for r in table if r["kernel"] == "ragged_dot"]
             assert [r["pallas_kernel"] for r in kernels] == ["ragged_dot"] * 3
             assert {r["flops"] for r in kernels} == {2.0 * rows * kk * nn}
+
+
+def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
+        one_chip):
+    """The whole training step of `sdar-8k` as `benchmarks/run.py`
+    builds it (6 layers at the published widths, 16384 rows, bf16 AMP,
+    every layer a recompute segment), compiled for the described chip,
+    nothing run.  The depth rule of the configuration (ISSUE 47: 6
+    layers if the step's plan is 15.0 GB or less, else 4) read THIS
+    plan: arguments (aliased to the outputs) 7.75 GB + temporaries 6.06
+    GB = 13.81 GB; at 4 layers 5.48 + 5.41 = 10.89 GB (PERF.md, PR 47).
+    A layer is ONE forward and one backward flash kernel under the
+    block-diffusion mask: the segment keeps the forward's residuals."""
+    import collections
+    import os
+    import re
+    import sys
+
+    import numpy as np
+
+    import paddle_tpu as fluid
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run as bench_run
+
+    cell, config, family = bench_run.load_cell("sdar-8k", (bench,))
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        loss = family.build(config)
+        for var in main.global_block().vars.values():
+            if var.persistable and all(int(s) > 0 for s in var.shape):
+                scope.set_var(var.name, jax.ShapeDtypeStruct(
+                    tuple(int(s) for s in var.shape),
+                    np.dtype(str(var.dtype))))
+        batch = family.make_batch(config, cell, np.random.default_rng(0))
+        step, state, feeds = fluid.Executor()._prepare(
+            main, batch, [loss.name], scope, 1, True)
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        compiled = _compile_args(step, jax.tree.map(described, state),
+                                 jax.tree.map(described, feeds))
+    assert sum(int(np.prod(p.shape)) for p in main.all_parameters()) \
+        == 645623296
+    plan = compiled.memory_analysis()
+    total = (plan.argument_size_in_bytes + plan.temp_size_in_bytes) / 1e9
+    assert plan.argument_size_in_bytes / 1e9 == pytest.approx(7.75, abs=0.01)
+    assert 12.5 < total <= 15.0, total              # the rule's side: 6
+    calls = " ".join(ln for ln in compiled.as_text().splitlines()
+                     if "tpu_custom_call" in ln)
+    kernels = collections.Counter(re.findall(r"pallas_(\w+?)/", calls))
+    assert kernels["flash_block_diffusion_fwd"] == 6
+    assert kernels["flash_block_diffusion_dkv"] == 6
+    assert set(kernels) == {"flash_block_diffusion_fwd",
+                            "flash_block_diffusion_dkv", "ragged_dot"}

@@ -310,6 +310,10 @@ STEP_TEXT = {
     # before them e13e01c2..)
     "qwen3next-16k":
     "acbeb427a7e7fb7ac77fb76405d6dc1535f193eed451787819a8089933ace118",
+    # new in PR 47 (no parent): block-diffusion training, the band
+    # kernels' third geometry at 1024 x 1024 tiles
+    "sdar-8k":
+    "eeed81fbed9af9280a848c0e47030f2fdbdb391be4b5e5c47c3d537d9eff112d",
 }
 
 

@@ -256,6 +256,72 @@ def test_grouped_flash_at_head_dim_256_takes_the_two_backward_kernels(
         assert f"pallas_{kernel}" in text
 
 
+@pytest.mark.parametrize("rows, dtype", [(16384, BF16), (16384, F32),
+                                         (32768, BF16)],
+                         ids=["sdar_8k-bf16", "sdar_8k-f32", "past_budget"])
+def test_flash_under_the_block_diffusion_mask_at_the_cells_shape(
+        one_chip, rows, dtype):
+    """The band kernels' third geometry as `sdar-8k` asks it, 6 calls a
+    step: one document of 8192 positions as 16384 rows (clean, then
+    noised), blocks of 4, 32 query heads of 128 over 4 key/value heads,
+    1024 x 1024 tiles of which only the 24 at a query tile's own
+    position carry the mask (two branches of a kernel).  Two custom calls: the
+    forward kernel and ONE backward kernel that holds dq of a query
+    head and dk, dv of its key/value head full-length (24 MiB); in the
+    parity script's float32 at "highest" too.  A document of 16384 (48
+    MiB) takes the two kernels that hold tiles only.  Each declares the
+    cost of the pairs the MASK allows, 67,141,632 a head at 8192."""
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    h, hkv, d = 32, 4, 128
+    fused = fa.band_backward_fits(rows, d)
+    assert fused == (rows == 16384)
+    assert fa.block_diffusion_takes(rows, 4)
+
+    def loss(q, k, v):
+        with jax.named_scope("flash_attention:9"):
+            o = fa.pallas_flash_attention(
+                q, k, v, None, d ** -0.5, False, layout="nthd", n_head=h,
+                n_kv_head=hkv, block_diffusion=4)
+        return jnp.sum(o.astype(F32))
+
+    before = runtime_stats.snapshot()
+    args = [jax.ShapeDtypeStruct((1, rows, heads * d), dtype,
+                                 sharding=one_chip)
+            for heads in (h, hkv, hkv)]
+    prec = "default" if dtype == BF16 else "highest"
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile()
+    took = runtime_stats.delta(before)
+    assert (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"]) == (int(fused),
+                                                        int(not fused))
+    assert took["flash_block_diffusion_calls"] == 2
+    assert took["flash_block_diffusion_blocks_visited"] \
+        == took["flash_block_diffusion_blocks_allowed"] \
+        == 2 * fa._DiffusionBand(rows, 1024, 4).blocks_allowed
+    assert _kernels(compiled.as_text()) == (2 if fused else 3)
+    rows_ = {r["kernel"]: r for r in cost.instruction_costs(
+        cost.compiled_hlo_proto(compiled)) if r["kernel"]}
+    prefix = "flash_block_diffusion_"
+    assert sorted(rows_) == [prefix + k for k in
+                             (("dkv", "fwd") if fused
+                              else ("dkv", "dq", "fwd"))]
+    assert {r["op_type"] for r in rows_.values()} == {"flash_attention"}
+    if fused:
+        pairs = h * 67141632
+        item = jnp.dtype(dtype).itemsize
+        assert rows_[prefix + "fwd"]["flops"] == pairs * (4 * d + 8)
+        assert rows_[prefix + "dkv"]["flops"] == pairs * (8 * d + 8)
+        assert rows_[prefix + "fwd"]["bytes"] == rows * d * item * (
+            2 * h + 2 * hkv)
+        assert rows_[prefix + "dkv"]["bytes"] == rows * d * item * (
+            4 * h + 4 * hkv)
+
+
 def test_fused_vocab_ce_fwd_bwd(one_chip):
     from paddle_tpu.ops.pallas.vocab_ce import fused_vocab_ce
 
