@@ -43,11 +43,12 @@ def _decode_shape(shape):
 # calls of a million sequences to `runtime_stats.gated_delta_*`, which
 # a benchmark reader takes for the step's.  `short_conv`'s layer
 # declares its output likewise, and its kernels are neither traced nor
-# counted (`runtime_stats.short_convs_*`) by a Program build.
+# counted (`runtime_stats.short_convs_*`) by a Program build; `rope`'s
+# likewise (`runtime_stats.ropes_*`).
 _SKIP_INFERENCE = {
     "backward_marker", "py_func", "print",
     "create_array", "array_write", "array_read", "array_length",
-    "array_to_tensor", "gated_delta_rule", "short_conv",
+    "array_to_tensor", "gated_delta_rule", "short_conv", "rope",
 }
 
 

@@ -323,7 +323,8 @@ def short_conv(input, filter_size, param_attr=None, name=None,
 
 def rope(input, n_head, theta=10000.0, offset=None, name=None,
          interleave=False, inv_freq=None, attention_factor=None,
-         rotary_dim=None, period=None):
+         rotary_dim=None, period=None, norm=False, epsilon=1e-5,
+         zero_centered=False, param_attr=None):
     """Rotary position embedding of a head-grouped (N, T, n_head * D)
     projection (ops/decoder.py): rotate-half, or with `interleave` the
     pairs (2i, 2i + 1) of every head.  `offset`: a (1,) integer
@@ -334,13 +335,35 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
     `rope_parameters`).  `rotary_dim`: only the first so many lanes of
     each head turn (a config's `partial_rotary_factor` x the head).
     `period` P: positions restart every P rows (row r stands at
-    r mod P)."""
+    r mod P).
+
+    `norm`: each head is RMS-normed before it turns (QK-norm a head),
+    under one learned scale (D,) with `epsilon` and `zero_centered` as
+    `rms_norm(group_size=D)` has them: the same parameter under the
+    same name, created where that layer would have been called, and
+    the same arithmetic in ONE op, float32 from the projection to the
+    turned head.  Where the shape allows (rotate-half, D a multiple of
+    128, whole row tiles: `ops/pallas/rope.py rope_kernel_takes`) the
+    normed op is one Pallas kernel forward and one backward; a bare
+    turn stays an XLA composition, which fuses into its neighbours.
+    `runtime_stats.ropes_kernel` / `ropes_xla` count the calls traced
+    each way."""
     helper = LayerHelper("rope", name=name)
     out = helper.create_variable_for_type_inference(input.dtype)
     ins = {"X": [input]}
     if offset is not None:
         ins["Offset"] = [offset]
     attrs = {"n_head": int(n_head), "theta": float(theta)}
+    if norm:
+        head_dim = int(input.shape[-1]) // int(n_head)
+        # named as `rms_norm`'s scale: a checkpoint from before the
+        # two ops were one still loads
+        ins["Scale"] = [LayerHelper("rms_norm").create_parameter(
+            param_attr, shape=[head_dim], dtype=input.dtype,
+            default_initializer=Constant(0.0 if zero_centered else 1.0))]
+        attrs["epsilon"] = epsilon
+        if zero_centered:
+            attrs["zero_centered"] = True
     if interleave:
         attrs["interleave"] = True
     if inv_freq is not None:
@@ -355,6 +378,7 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
         attrs["period"] = int(period)
     helper.append_op(type="rope", inputs=ins, outputs={"Out": [out]},
                      attrs=attrs)
+    out.desc.shape = tuple(input.shape)
     return out
 
 

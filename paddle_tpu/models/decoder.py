@@ -447,13 +447,12 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         return layers.rms_norm(x, epsilon=eps,
                                zero_centered=zero_centered_norm)
 
-    def norm_qk(x):
-        if qk_norm is None:
-            return x
+    def turn_qk(x, n_head, turn):
+        """QK-norm, then RoPE; a norm a head rides in the `rope` op."""
         if qk_norm == "head":
-            return layers.rms_norm(x, epsilon=eps, group_size=head_dim,
-                                   zero_centered=zero_centered_norm)
-        return norm(x)
+            return layers.rope(x, n_head, norm=True, epsilon=eps,
+                               zero_centered=zero_centered_norm, **turn)
+        return layers.rope(x if qk_norm is None else norm(x), n_head, **turn)
 
     def attention(h, kind):
         turn = rotary[kind]
@@ -465,10 +464,10 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             turn = dict(turn, period=max_length)
         with name_scope(scope) if windowed or attention_gate or diffusion \
                 else contextlib.nullcontext():
-            q = layers.rope(norm_qk(proj(h, q_size, "attn_qkv")),
-                            num_attention_heads, **turn)
-            k = layers.rope(norm_qk(proj(h, kv_size, "attn_qkv")),
-                            num_key_value_heads, **turn)
+            q = turn_qk(proj(h, q_size, "attn_qkv"), num_attention_heads,
+                        turn)
+            k = turn_qk(proj(h, kv_size, "attn_qkv"), num_key_value_heads,
+                        turn)
             v = proj(h, kv_size, "attn_qkv")
             ctx = layers.flash_attention(
                 q, k, v, causal=not diffusion, use_pallas=True,

@@ -77,6 +77,7 @@ _FIELDS = Heard._fields[1:] + (
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "short_convs_kernel", "short_convs_xla",
+    "ropes_kernel", "ropes_xla",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -196,6 +197,11 @@ class RuntimeStats:
         # (delta() around a build; a Program build counts nothing)
         self.short_convs_kernel = 0
         self.short_convs_xla = 0
+        # `rope` ops traced, by what the shape and attrs chose: the
+        # Pallas kernels (`ops/pallas/rope.py`) or the composition
+        # (delta() around a build; a Program build counts nothing)
+        self.ropes_kernel = 0
+        self.ropes_xla = 0
         # trips of the counted loops traced (`static_rnn` with a
         # `trip_count`): what a step runs of them (delta() around a
         # build: 4 where one stack runs 4 times), and what an early exit
@@ -297,6 +303,13 @@ class RuntimeStats:
                 self.short_convs_kernel += 1
             else:
                 self.short_convs_xla += 1
+
+    def record_rope(self, kernel: bool):
+        with self._lock:
+            if kernel:
+                self.ropes_kernel += 1
+            else:
+                self.ropes_xla += 1
 
     def record_loop_trips(self, trips: int):
         with self._lock:

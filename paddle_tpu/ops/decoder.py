@@ -26,6 +26,12 @@ from ..core.registry import register_op
 from .common import first, opt_in, out
 
 
+def _over_rms(xf, axes, eps):
+    """float32 xf over the root of its mean square along `axes`."""
+    return xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
+                          + eps)
+
+
 @register_op("rms_norm")
 def rms_norm(ctx, ins, attrs):
     """Y = X * rsqrt(mean(X^2 over the axes from begin_norm_axis) + eps)
@@ -55,9 +61,7 @@ def rms_norm(ctx, ins, attrs):
     begin = attrs.get("begin_norm_axis", -1) % x.ndim
     eps = attrs.get("epsilon", 1e-5)
     axes = tuple(range(begin, x.ndim))
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
-                       + eps)
+    y = _over_rms(x.astype(jnp.float32), axes, eps)
     if scale is not None:
         scale = scale.reshape(x.shape[begin:]).astype(jnp.float32)
         y = y * (1.0 + scale if attrs.get("zero_centered") else scale)
@@ -118,6 +122,63 @@ def rope_frequencies(head_dim, rope_type="default", rope_theta=10000.0,
             float(attention_factor))
 
 
+def _cos_sin(t, rotary, attrs, offset):
+    """cos, sin (1, T, 1, rotary/2) float32 of the rows' positions, the
+    `attention_factor` on them."""
+    pos = jnp.arange(t, dtype=jnp.int32)
+    if attrs.get("period"):
+        pos = pos % int(attrs["period"])
+    if offset is not None:
+        pos = pos + offset.reshape(()).astype(jnp.int32)
+    inv_freq = attrs.get("inv_freq")
+    if inv_freq is None:
+        ang = rope_angles(pos, rotary, float(attrs.get("theta", 10000.0)))
+    else:
+        if len(inv_freq) != rotary // 2:
+            raise ValueError(f"rope: {len(inv_freq)} frequencies for a "
+                             f"head of {rotary}")
+        ang = (pos.astype(jnp.float32)[:, None]
+               * np.asarray(inv_freq, np.float32)[None, :])
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    factor = float(attrs.get("attention_factor") or 1.0)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    return cos, sin
+
+
+def _turn(xf, cos, sin, n_head, pairs):
+    """float32 heads of R lanes, (N, T, H*R) or (N, T, H, R), turned by
+    cos, sin: a head as (2, R/2) halves, or as (R/2, 2) pairs; as
+    stacked."""
+    half = cos.shape[-1]
+    xf = xf.reshape(xf.shape[:2] + (n_head,)
+                    + ((half, 2) if pairs else (2, half)))
+    x1, x2 = (xf[..., 0], xf[..., 1]) if pairs else \
+        (xf[..., 0, :], xf[..., 1, :])
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1 if pairs else -2)
+
+
+def _rope(x, scale, cos, sin, n_head, eps=1e-5, pairs=False):
+    """The op as it is written, the reference of its kernels: float32
+    from X to Out, no rounding between the norm and the turn."""
+    n, t, hd = x.shape
+    d, rotary = hd // n_head, 2 * cos.shape[-1]
+    xf = x.astype(jnp.float32)
+    if scale is not None:
+        xf = _over_rms(xf.reshape(n, t, n_head, d), -1, eps) \
+            * scale.astype(jnp.float32)
+    if rotary == d:
+        y = _turn(xf, cos, sin, n_head, pairs)
+    else:
+        xf = xf.reshape(n, t, n_head, d)
+        y = jnp.concatenate(
+            [_turn(xf[..., :rotary], cos, sin, n_head, pairs)
+             .reshape(n, t, n_head, rotary), xf[..., rotary:]], axis=-1)
+    return y.reshape(n, t, hd).astype(x.dtype)
+
+
 @register_op("rope")
 def rope(ctx, ins, attrs):
     """Rotary embedding over the whole head.  X is head-grouped
@@ -133,57 +194,61 @@ def rope(ctx, ins, attrs):
     theta^(-2i/R)); lanes R.. pass through.  `period` P: positions
     restart every P rows (row r stands at r mod P; block-diffusion
     training feeds a clean and a noised copy of a sequence, P = T / 2),
-    before the Offset."""
+    before the Offset.
+
+    Scale (D,): each head is RMS-normed before it turns (QK-norm a
+    head), xhat = x * rsqrt(mean_head(x^2) + `epsilon`) * Scale, or
+    * (1 + Scale) with `zero_centered`: what `rms_norm(group_size=D)`
+    before this op computes, without the rounding to X's dtype in
+    between and without the round trip through HBM.  float32 from X to
+    Out, Out in X's dtype.
+
+    Two lowerings of the one algorithm, chosen by the shape and attrs
+    alone (`ops/pallas/rope.py rope_kernel_takes`: a Scale, rotate-half,
+    D a multiple of 128, R even, T whole row tiles that fit VMEM): the
+    two Pallas kernels there, which read X once and write Out once (the
+    backward pass X and dOut, recomputing the norm); everything else
+    (a bare turn, which XLA fuses into its neighbours; a head of 64,
+    `interleave`, a decode step's single row) the composition `_rope`,
+    under `jax.checkpoint` where it norms (X is the residual there
+    too).  `runtime_stats.ropes_kernel` / `ropes_xla` count the calls
+    traced each way."""
+    from ..observe.monitoring import runtime_stats
+    from .pallas.rope import rope_kernel, rope_kernel_takes
+
     x = first(ins, "X")
     offset = opt_in(ins, "Offset")
+    scale = opt_in(ins, "Scale")
     n_head = int(attrs["n_head"])
-    theta = float(attrs.get("theta", 10000.0))
     n, t, hd = x.shape
     d = hd // n_head
     if d * n_head != hd or d % 2:
         raise ValueError(f"rope: minor dim {hd} is not n_head {n_head} "
                          f"heads of an even size")
     rotary = int(attrs.get("rotary_dim") or d)
-    if rotary != d:
-        if not 0 < rotary < d or rotary % 2:
-            raise ValueError(f"rope: rotary_dim {rotary} is not an even "
-                             f"part of a head of {d}")
-        x4 = x.reshape(n, t, n_head, d)
-        turned = rope(ctx, {"X": [x4[..., :rotary].reshape(n, t, -1)],
-                            "Offset": ins.get("Offset", [])},
-                      {k: v for k, v in attrs.items()
-                       if k != "rotary_dim"})["Out"][0]
-        return out(Out=jnp.concatenate(
-            [turned.reshape(n, t, n_head, rotary), x4[..., rotary:]],
-            axis=-1).reshape(n, t, hd))
-    pos = jnp.arange(t, dtype=jnp.int32)
-    if attrs.get("period"):
-        pos = pos % int(attrs["period"])
-    if offset is not None:
-        pos = pos + offset.reshape(()).astype(jnp.int32)
-    inv_freq = attrs.get("inv_freq")
-    if inv_freq is None:
-        ang = rope_angles(pos, d, theta)              # (T, D/2)
-    else:
-        if len(inv_freq) != d // 2:
-            raise ValueError(f"rope: {len(inv_freq)} frequencies for a "
-                             f"head of {d}")
-        ang = (pos.astype(jnp.float32)[:, None]
-               * np.asarray(inv_freq, np.float32)[None, :])
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    factor = float(attrs.get("attention_factor") or 1.0)
-    if factor != 1.0:
-        cos, sin = cos * factor, sin * factor
-    # a head as (2, D/2) halves, or as (D/2, 2) pairs
-    pairs = attrs.get("interleave", False)
-    xf = x.astype(jnp.float32).reshape(
-        (n, t, n_head) + ((d // 2, 2) if pairs else (2, d // 2)))
-    x1, x2 = (xf[..., 0], xf[..., 1]) if pairs else \
-        (xf[..., 0, :], xf[..., 1, :])
-    y = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                  axis=-1 if pairs else -2)
-    return out(Out=y.reshape(n, t, hd).astype(x.dtype))
+    if not 0 < rotary <= d or rotary % 2:
+        raise ValueError(f"rope: rotary_dim {rotary} is not an even "
+                         f"part of a head of {d}")
+    pairs = bool(attrs.get("interleave", False))
+    eps = float(attrs.get("epsilon", 1e-5))
+    if scale is not None:
+        if scale.shape != (d,):
+            raise ValueError(f"rope: Scale {scale.shape} for a head of {d}")
+        scale = scale.astype(jnp.float32)
+        if attrs.get("zero_centered"):
+            scale = 1.0 + scale
+    cos, sin = _cos_sin(t, rotary, attrs, offset)
+    kernel = rope_kernel_takes(t, n_head, d, rotary, pairs, scale is not None,
+                               x.dtype.itemsize)
+    runtime_stats.record_rope(kernel)
+    if kernel:
+        return out(Out=rope_kernel(x, scale, cos.reshape(t, -1),
+                                   sin.reshape(t, -1), n_head, eps))
+    if scale is None:
+        return out(Out=_rope(x, None, cos, sin, n_head, pairs=pairs))
+    return out(Out=jax.checkpoint(
+        lambda x, scale: _rope(x, scale, cos, sin, n_head, eps, pairs))(
+            x, scale))
 
 
 def silu_gate(a, b):

@@ -417,5 +417,9 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
     kernels = collections.Counter(re.findall(r"pallas_(\w+?)/", calls))
     assert kernels["flash_block_diffusion_fwd"] == 6
     assert kernels["flash_block_diffusion_dkv"] == 6
+    # q and k of six layers: normed and turned forward and recomputed,
+    # one backward kernel each (PR 48)
+    assert (kernels["rope_fwd"], kernels["rope_bwd"]) == (24, 12)
     assert set(kernels) == {"flash_block_diffusion_fwd",
-                            "flash_block_diffusion_dkv", "ragged_dot"}
+                            "flash_block_diffusion_dkv", "ragged_dot",
+                            "rope_fwd", "rope_bwd"}

@@ -292,28 +292,28 @@ STEP_TEXT = {
     # 5e45cfed.., e1f3219f..)
     "olmoe-4k":
     "edde7176fe33bb4d5429b1ced73b68db315c639ed19922b19640112a2b71fd8b",
-    # re-pinned, PR 46: the gated short convolution is the two kernels
-    # of `ops/pallas/short_conv.py`, here through the interpreter
-    # (parent: f2f9d30e..)
+    # re-pinned, PR 48: its attention layer's QK-norm a head rides in
+    # the `rope` op (d_head 64: the op's composition, one op where two
+    # stood; parent: 8265f55d.., PR 46's short-convolution kernels)
     "lfm2-8k":
-    "8265f55d050161e51092c15b9d763f437cb7a66acfa597ebb78e6d434bc1d69e",
+    "acccef44be8bba8ada2cc1f04a56c7ccf272eed2c3c02106062aa3ad6924d41a",
     "joyai-8k":
     "46a623eb6f4691120a58f68448449341b589b0b1f23fce5dd2eaca924a43b904",
     # re-pinned, PR 39: the loop's segments keep (o, logsumexp), the
     # backward body holds no forward kernel (parent: 83ada587..)
     "ouro-4k":
     "feb20e0f77cac9b68fcfcbc630aa0a2d04a50249bddebc892a00584d8a915084",
+    # re-pinned, PR 48, with `qwen3next-16k` and `sdar-8k` below: q and
+    # k are normed and turned in the kernels of `ops/pallas/rope.py`,
+    # here through the interpreter (parents: 692f6104.., acbeb427..,
+    # PR 46's SiLU short convolution, eeed81fb.., new in PR 47); every
+    # other cell turns bare or over pairs and keeps its parent's text
     "mellum2-16k":
-    "692f610496a65734e5d2e1dccfa470e6af344cd1f6baea8c2723dcd8a95d0320",
-    # re-pinned, PR 46: the SiLU short convolution is the same two
-    # kernels (parent: 83bb4b96.., PR 45's chunk-operand kernels;
-    # before them e13e01c2..)
+    "7914857b0ab411c6d9c1d7dbfa7f4a7f88a103c8179cd76df5ed2d25e1622cfc",
     "qwen3next-16k":
-    "acbeb427a7e7fb7ac77fb76405d6dc1535f193eed451787819a8089933ace118",
-    # new in PR 47 (no parent): block-diffusion training, the band
-    # kernels' third geometry at 1024 x 1024 tiles
+    "5a9ae15aab5f7e765ce566b771900c50ef10f13d46733ff7c61373eb55ea5d9b",
     "sdar-8k":
-    "eeed81fbed9af9280a848c0e47030f2fdbdb391be4b5e5c47c3d537d9eff112d",
+    "44ab655f2aab54ebd3b73538a551e2ee79c62d560777bf61bfbbb5cdc8a4c57a",
 }
 
 

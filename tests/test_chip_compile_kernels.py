@@ -211,6 +211,81 @@ def test_short_conv_kernels_at_the_published_shapes(one_chip, dtype, form):
     assert compiled.memory_analysis().temp_size_in_bytes < tile // 4
 
 
+# heads, d_head, lanes that turn
+ROPE_SHAPES = {
+    "q_128": (32, 128, None), "k_128": (4, 128, None),
+    "q_256_quarter": (16, 256, 64), "k_256_quarter": (2, 256, 64),
+}
+
+
+@pytest.mark.parametrize("shape", list(ROPE_SHAPES))
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_rope_kernels_at_the_published_shapes(one_chip, dtype, shape):
+    """The `rope` op and its gradient at the cells' shapes, 1 x 16384
+    rows: `mellum2-16k`'s and `sdar-8k`'s q and k (32 and 4 heads of
+    128, each normed), `qwen3next-16k`'s (16 and 2 heads of 256 of
+    which 64 lanes turn, zero-centred norm, no slice and no concatenate
+    of the projection), in the cells' bfloat16 and the parity scripts'
+    float32.  The rule takes all (and leaves `ouro-4k`'s bare turn to
+    XLA), so the output with its gradient is TWO Mosaic kernels,
+    `rope_fwd` and `rope_bwd` (which recomputes the norm from X); each
+    has a registered cost in bytes and no FLOP and sits under the op's
+    scope."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    from paddle_tpu.ops.pallas.rope import rope_kernel_takes
+
+    heads, d, rotary = ROPE_SHAPES[shape]
+    t = 16384
+    assert not rope_kernel_takes(4096, 16, 128, normed=False)
+    impl = get_op_impl("rope")
+    attrs = {"n_head": heads, "theta": 1e6, "epsilon": 1e-6,
+             "zero_centered": d == 256}
+    if rotary:
+        attrs["rotary_dim"] = rotary
+
+    def both(x, w, ct):
+        def fn(x, w):
+            with jax.named_scope("full_attention/rope:7"):
+                return impl(OpContext(jax.random.PRNGKey(0), 0),
+                            {"X": [x], "Scale": [w]},
+                            attrs)["Out"][0]
+
+        o, vjp = jax.vjp(fn, x, w)
+        return o, vjp(ct)
+
+    before = runtime_stats.snapshot()
+    compiled = _compile_args(
+        jax.jit(both),
+        jax.ShapeDtypeStruct((1, t, heads * d), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((d,), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, t, heads * d), dtype, sharding=one_chip))
+    took = runtime_stats.delta(before)
+    assert (took["ropes_kernel"], took["ropes_xla"]) == (1, 0)
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "rope_bwd", "rope_fwd"]
+    assert {r["op_type"] for r in rows if r["op_type"]} == {"rope"}
+    assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 2
+    assert totals["pallas_flops"] == 0
+    # X and Out forward, X, dOut and dX backward, once each; the
+    # (T, D) float32 tables (two, or three where a part turns), the
+    # scale, and backward its 8 sublanes of partial sums
+    tile = t * heads * d * (2 if dtype == BF16 else 4)
+    small = (3 if rotary else 2) * t * d * 4 + d * 4
+    by = {r["kernel"]: r["bytes"] for r in rows if r["kernel"]}
+    assert by["rope_fwd"] == 2 * tile + small
+    assert by["rope_bwd"] == 3 * tile + small + 8 * d * 4
+    # nothing as large as X is written between the kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < max(
+        tile // 4, 4 * t * d * 4)
+
+
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
 def test_grouped_flash_at_head_dim_256_takes_the_two_backward_kernels(
         one_chip, dtype):

@@ -182,6 +182,48 @@ def test_rope_parameters_flat_or_a_layer_type_and_what_still_raises():
         build()
 
 
+@pytest.mark.parametrize("qk_norm", ["head", "projection", None])
+def test_a_norm_a_head_rides_in_the_rope_op(qk_norm):
+    """`qk_norm` "head": q and k are ONE `rope` op each, with the norm's
+    Scale (d_head,), epsilon and zero-centring in it, and the program
+    holds no `rms_norm` with a `group_size`; "projection" and None keep
+    `rope` bare.  Parameter names, order and shapes are what the two
+    ops gave."""
+    from paddle_tpu.models import decoder
+
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()), \
+            fluid.unique_name.guard():
+        decoder.decoder(
+            hidden_size=48, num_hidden_layers=1, num_attention_heads=4,
+            num_key_value_heads=2, intermediate_size=64, num_experts=0,
+            num_experts_per_tok=0, norm_topk_prob=False, num_dense_layers=1,
+            vocab_size=32, max_length=16, rms_norm_eps=1e-6, rope_theta=1e4,
+            qk_norm=qk_norm, zero_centered_norm=qk_norm == "head")
+    ops = main.global_block().ops
+    ropes = [op for op in ops if op.type == "rope"]
+    norms = [op for op in ops if op.type == "rms_norm"]
+    assert len(ropes) == 2
+    assert not any(op.attrs.get("group_size") for op in norms)
+    names = [p.name for p in main.all_parameters()]
+    if qk_norm == "head":
+        assert len(norms) == 3              # two hidden norms, the final
+        scales = [op.input("Scale")[0] for op in ropes]
+        assert scales == ["rms_norm_1.w_0", "rms_norm_2.w_0"]
+        for op in ropes:
+            assert op.attrs["epsilon"] == 1e-6 and op.attrs["zero_centered"]
+        assert [tuple(main.global_block().var(n).shape) for n in scales] \
+            == [(12,), (12,)]
+    else:
+        assert len(norms) == (5 if qk_norm else 3)
+        assert not any(op.input("Scale") for op in ropes)
+    # x-norm, q projection, its norm, k projection, its norm, v
+    start = names.index("rms_norm_0.w_0")
+    kinds = [n.split("_")[0] for n in names[start:start + 6]]
+    assert kinds == (["rms", "attn", "attn", "attn", "attn", "rms"] if qk_norm is None
+                     else ["rms", "attn", "rms", "attn", "rms", "attn"])
+
+
 def test_the_embedding_table_takes_a_range_of_its_own():
     """`embedding_init_range` is the table's std alone; every matrix
     keeps `initializer_range`; absent, the table has it too."""
