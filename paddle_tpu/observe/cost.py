@@ -52,11 +52,12 @@ HloModuleProto (read with trace.py's dependency-free wire scanner):
 
 from __future__ import annotations
 
+import functools
 import math
 import re
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-from .trace import _fields, _first, _utf8, fluid_op_of
+from .trace import _fields, _first, _utf8, fluid_scope_of, phase_of
 
 # --------------------------------------------------------------------------
 # device peaks (op_cost_table)
@@ -100,7 +101,9 @@ def device_peaks(kind: Optional[str] = None):
 #                      custom_call_target=28 dot_dimension_numbers=30
 #                      id=35 operand_ids=36 called_computation_ids=38
 #                      feature_group_count=50 literal=8 tuple_index=13
-#                      comparison_direction=63
+#                      comparison_direction=63 parameter_number=9
+# HloModuleProto.schedule=7: sequences=1 (map: computation id ->
+#                      InstructionSequence{instruction_ids=1})
 # LiteralProto:        s32s=4 s64s=5 u32s=6 u64s=7
 # ShapeProto:          element_type=2 dimensions=3 tuple_shapes=4
 # OpMetadata:          op_type=1 op_name=2
@@ -140,6 +143,12 @@ def _repeated_ints(buf: bytes, fno: int) -> List[int]:
     return out
 
 
+_ELEM_NAMES = {1: "pred", 2: "s8", 3: "s16", 4: "s32", 5: "s64", 6: "u8",
+               7: "u16", 8: "u32", 9: "u64", 10: "f16", 11: "f32",
+               12: "f64", 15: "c64", 16: "bf16", 17: "token", 18: "c128",
+               19: "f8e5m2", 20: "f8e4m3fn", 21: "s4", 22: "u4"}
+
+
 class Shape:
     __slots__ = ("element_type", "dims", "tuple_shapes")
 
@@ -176,13 +185,23 @@ class Shape:
     def elem_bytes(self) -> int:
         return _ELEM_BYTES.get(self.element_type, 0)
 
+    @property
+    def text(self) -> str:
+        """`bf16[8,2048,1536]`, as the compiled text writes it without
+        its layout."""
+        if self.tuple_shapes:
+            return "(" + ", ".join(s.text for s in self.tuple_shapes) + ")"
+        return (f"{_ELEM_NAMES.get(self.element_type, '?')}"
+                f"[{','.join(map(str, self.dims))}]")
+
 
 class Instr:
     __slots__ = ("name", "opcode", "shape", "op_name", "id",
                  "operand_ids", "called_ids", "dot_dnums_buf",
                  "window_buf", "conv_dnums_buf", "feature_group_count",
                  "custom_call_target", "backend_config", "literal_buf",
-                 "tuple_index", "comparison_direction")
+                 "tuple_index", "comparison_direction",
+                 "parameter_number")
 
     def __init__(self, buf: bytes):
         self.name = ""
@@ -201,11 +220,14 @@ class Instr:
         self.literal_buf = b""
         self.tuple_index = 0
         self.comparison_direction = ""
+        self.parameter_number = 0
         for f, _wt, v in _fields(buf):
             if f == 1:
                 self.name = _utf8(v)
             elif f == 8:
                 self.literal_buf = v
+            elif f == 9:
+                self.parameter_number = int(v)
             elif f == 13:
                 self.tuple_index = int(v)
             elif f == 63:
@@ -273,10 +295,19 @@ class HloModule:
         self.name = _utf8(_first(proto, 1, b""))
         self.entry_id = _first(proto, 6, 0)
         self.computations: Dict[int, Computation] = {}
+        # {computation id: instruction ids in the order they run}: a
+        # compiled module is scheduled, and a computation's own list
+        # is a post-order, not that order
+        self.schedule: Dict[int, List[int]] = {}
         for f, _wt, v in _fields(proto):
             if f == 3:
                 comp = Computation(v)
                 self.computations[comp.id] = comp
+            elif f == 7:
+                for sf, _swt, entry in _fields(v):
+                    if sf == 1:
+                        self.schedule[_first(entry, 1, 0)] = _repeated_ints(
+                            _first(entry, 2, b""), 1)
 
     @property
     def entry(self) -> Computation:
@@ -450,6 +481,7 @@ def _dot_flops(instr: Instr, operands: List[Instr]) -> float:
     return 2.0 * instr.shape.elements * k
 
 
+@functools.lru_cache(maxsize=None)
 def _valid_positions(size: int, kernel: int, out: int,
                      dim: bytes) -> int:
     """(output position, kernel position) pairs of one spatial
@@ -729,6 +761,145 @@ def _bucket(module: HloModule, comp: Computation, instr: Instr) -> str:
     return "elementwise"
 
 
+# --------------------------------------------------------------------------
+# one def-use map a computation: whose work a scopeless instruction is
+# --------------------------------------------------------------------------
+
+# no work of their own: an owner walk passes through them whatever
+# scope they carry (an asynchronous pair from its start to its done)
+_TRANSPARENT = {"bitcast", "get-tuple-element", "tuple", "opt-barrier",
+                "optimization-barrier", "copy-start", "copy-done",
+                "async-start", "async-update", "async-done"}
+
+OWNER_VIAS = ("scope", "consumer", "producer")     # else "none"
+
+
+class DefUse:
+    """The users of every instruction of ONE computation, and the
+    owner of each: the fluid op an instruction works for.
+
+    The compiler's own copies, slices and prefetches carry no
+    `metadata.op_name`.  An instruction under a fluid
+    `<op_type>:<index>` scope owns itself (`via` "scope").  One without
+    is handed to (a) "consumer": the first instruction in the order
+    the module runs (its schedule: the first user is what the copy had
+    to be ready for) that carries a fluid scope and is reached forward
+    through scopeless and `_TRANSPARENT` instructions; `consumers`
+    counts all that the walk met, so a copy shared by several ops shows
+    as shared and is not split; (b) "producer": where no consumer is
+    met (the walk ends at the root, a state array written back, or at
+    a loop's carry, a stacked residual), the nearest scoped
+    instruction backward, the latest to run among equally near ones;
+    (c) nobody (`via` "none").  A walk stays inside its computation.
+    """
+
+    def __init__(self, module: HloModule, comp: Computation):
+        self.comp = comp
+        self.users: Dict[int, List[Instr]] = {}
+        for instr in comp.instructions:
+            for oid in dict.fromkeys(instr.operand_ids):
+                self.users.setdefault(oid, []).append(instr)
+        order = module.schedule.get(comp.id) or ()
+        self.position = {iid: n for n, iid in enumerate(order)}
+        for instr in comp.instructions:
+            self.position.setdefault(instr.id, len(self.position))
+        # (fluid op type or None, name scope) of every instruction
+        self.scopes = {i.id: fluid_scope_of(i.op_name)
+                       for i in comp.instructions}
+        # (owning instruction or None, via, scoped consumers met)
+        self.owners: Dict[int, Tuple[Optional[Instr], str, int]] = \
+            self._walk()
+
+    def _walk(self):
+        comp, position = self.comp, self.position
+        scoped = {iid: scope[0] is not None
+                  for iid, scope in self.scopes.items()}
+        passed = {i.id: not scoped[i.id] or i.opcode in _TRANSPARENT
+                  for i in comp.instructions}
+        ordered = sorted(comp.instructions, key=lambda i: position[i.id])
+        # operands run before their users: one pass each way
+        forward: Dict[int, frozenset] = {}
+        for instr in reversed(ordered):
+            if not passed[instr.id]:
+                continue
+            met = set()
+            for user in self.users.get(instr.id, ()):
+                if passed[user.id]:
+                    met |= forward.get(user.id, frozenset())
+                else:
+                    met.add(user.id)
+            forward[instr.id] = frozenset(met)
+        backward: Dict[int, Tuple[int, int, int]] = {}
+        for instr in ordered:
+            if not passed[instr.id]:
+                continue
+            near = None                 # (distance, -position, id)
+            for oid in instr.operand_ids:
+                if oid not in comp.by_id:
+                    continue
+                if not passed[oid]:
+                    found = (1, -position[oid], oid)
+                elif oid in backward:
+                    d, p, producer = backward[oid]
+                    found = (d + 1, p, producer)
+                else:
+                    continue
+                near = found if near is None else min(near, found)
+            if near is not None:
+                backward[instr.id] = near
+        owners = {}
+        for instr in comp.instructions:
+            if scoped[instr.id]:
+                owners[instr.id] = (instr, "scope", 0)
+            elif forward[instr.id]:
+                first = min(forward[instr.id], key=position.get)
+                owners[instr.id] = (comp.by_id[first], "consumer",
+                                    len(forward[instr.id]))
+            elif instr.id in backward:
+                owners[instr.id] = (comp.by_id[backward[instr.id][2]],
+                                    "producer", 0)
+            else:
+                owners[instr.id] = (None, "none", 0)
+        return owners
+
+    def source(self, instr: Instr, buckets: Dict[int, str],
+               parameters: str
+               ) -> Tuple[str, Optional[Instr], Optional[Shape]]:
+        """Where the array a `layout` instruction moves comes from:
+        `parameters` (what a parameter of this computation is: "state"
+        in the entry computation, a weight, a moment or a feed that is
+        re-laid every step; "carry" in a counted loop's body) with the
+        parameter and its shape, where the chain of operands backward
+        through `_TRANSPARENT` and `layout` instructions reaches one
+        (of an instruction with several operands the largest; of a
+        `tuple` the element a `get-tuple-element` on the way asked
+        for, and no other); else "activation"."""
+        comp = self.comp
+        indices: List[int] = []
+        while True:
+            if instr.opcode == "parameter":
+                shape = instr.shape
+                if shape.tuple_shapes and indices \
+                        and indices[-1] < len(shape.tuple_shapes):
+                    shape = shape.tuple_shapes[indices[-1]]
+                return parameters, instr, shape
+            operands = [comp.by_id[i] for i in instr.operand_ids
+                        if i in comp.by_id]
+            if not operands or (instr.opcode not in _TRANSPARENT
+                                and buckets.get(instr.id) != "layout"):
+                return "activation", None, None
+            if instr.opcode == "get-tuple-element":
+                indices.append(instr.tuple_index)
+            if instr.opcode == "tuple":
+                if not indices or indices[-1] >= len(operands):
+                    return "activation", None, None
+                instr = operands[indices.pop()]
+            elif len(operands) == 1:
+                instr = operands[0]
+            else:
+                instr = max(operands, key=lambda o: o.shape.bytes)
+
+
 def instruction_costs(proto, every_branch: bool = False
                       ) -> List[Dict[str, Any]]:
     """Analytic per-instruction cost rows for the entry computation of
@@ -762,6 +933,27 @@ def instruction_costs(proto, every_branch: bool = False
     body's FLOPs over all trips, for reading alone.
     `flops` already includes the injected registry flops; `xla_flops`
     carries the pre-injection analytic count.
+    Every row also says whose work it is, from ONE def-use map a
+    computation (`DefUse`: the entry, every branch, every counted
+    loop's body and condition): op_name, shape / shape_bytes (the
+    result as text, `bf16[8,2048,1536]`, and its bytes: what `_moved`
+    gives of a tuple), operands / users (instruction names), copyish
+    (a copy or transpose, or a fusion rooted at one),
+    owner (the owning instruction's name, None for nobody),
+    owner_op_type / owner_name_scope / owner_phase (that instruction's
+    fluid op, `fluid.name_scope()` path and forward / backward /
+    other), owner_via ("scope": the row's own `<op_type>:<index>`
+    scope, for which nothing changes; "consumer" / "producer": a
+    scopeless row handed to the first scoped instruction it feeds, or
+    failing that the nearest behind it; "none"), owner_consumers (the
+    scoped consumers the walk met: a copy shared by several ops says
+    so and is not split).  Rows of the `layout` bucket carry source:
+    "state" where what they move is a parameter of the ENTRY
+    computation (a weight, an optimizer moment, a feed: re-laid or
+    fetched EVERY step), with source_parameter (its number) and
+    source_shape; "carry" where it is a counted loop's body parameter
+    (source_shape: the carried element's); "activation" otherwise
+    (None off the bucket).
     """
     # force kernel-cost registration before walking custom calls
     from ..ops.pallas import dropout_mask as _dm  # noqa: F401
@@ -776,11 +968,28 @@ def instruction_costs(proto, every_branch: bool = False
     return _computation_costs(module, module.entry, every_branch, None)
 
 
+def _moved(instr: Instr) -> Shape:
+    """The array an instruction gives: its result, of an asynchronous
+    start `((operands), result, context)` the result, of any other
+    tuple (a `copy-start`'s `(destination, source, context)`) the
+    largest array."""
+    shape = instr.shape
+    if instr.opcode in ("async-start", "async-update") \
+            and len(shape.tuple_shapes) > 1:
+        shape = shape.tuple_shapes[1]
+    while shape.tuple_shapes:
+        shape = max(shape.tuple_shapes, key=lambda s: s.bytes)
+    return shape
+
+
 def _computation_costs(module: HloModule, comp: Computation,
                        every_branch: bool, branch_of: Optional[str],
-                       loop_of: Optional[str] = None, trips: int = 1
+                       loop_of: Optional[str] = None, trips: int = 1,
+                       parameters: str = "state"
                        ) -> List[Dict[str, Any]]:
     rows: List[Dict[str, Any]] = []
+    def_use = DefUse(module, comp)
+    buckets: Dict[int, str] = {}
     for instr in comp.instructions:
         operands = [comp.by_id[i] for i in instr.operand_ids
                     if i in comp.by_id]
@@ -793,7 +1002,7 @@ def _computation_costs(module: HloModule, comp: Computation,
         body_flops = flops if trip is not None else None
         if trip is not None:
             flops = transc = 0.0
-        bucket = _bucket(module, comp, instr)
+        bucket = buckets[instr.id] = _bucket(module, comp, instr)
         if branching or trip is not None or instr.opcode in _NO_BYTES:
             nbytes = 0
         else:
@@ -808,21 +1017,49 @@ def _computation_costs(module: HloModule, comp: Computation,
                     continue
                 seen_ids.add(o.id)
                 nbytes += o.shape.bytes
+        owner, via, consumers = def_use.owners[instr.id]
+        owner_type, owner_scope = (def_use.scopes[owner.id]
+                                   if owner is not None else (None, ""))
+        moved = _moved(instr)
         row = {
             "name": instr.name,
             "opcode": instr.opcode,
-            "op_type": fluid_op_of(instr.op_name),
+            "op_name": instr.op_name,
+            "op_type": def_use.scopes[instr.id][0],
             "bucket": bucket,
             "flops": flops,
             "xla_flops": flops,
             "transcendentals": transc,
             "bytes": float(nbytes),
+            "shape": moved.text,
+            "shape_bytes": float(moved.bytes),
             "pallas_kernel": None,
             "kernel": None,
             "branch_of": branch_of,
             "loop_of": loop_of,
             "trips": trips,
+            "operands": tuple(o.name for o in operands),
+            "users": tuple(u.name for u in def_use.users.get(instr.id, ())),
+            "copyish": _is_copyish(module, instr),
+            "owner": owner.name if owner is not None else None,
+            "owner_op_type": owner_type,
+            "owner_name_scope": owner_scope,
+            "owner_phase": phase_of(owner.op_name if owner is not None
+                                    else ""),
+            "owner_via": via,
+            "owner_consumers": consumers,
+            "source": None,
+            "source_parameter": None,
+            "source_shape": None,
         }
+        if bucket == "layout":
+            source, parameter, shape = def_use.source(instr, buckets,
+                                                      parameters)
+            row["source"] = source
+            if source != "activation":
+                row["source_shape"] = shape.text
+            if source == "state":
+                row["source_parameter"] = parameter.parameter_number
         if instr.opcode == "while":
             row["trip_count"] = trip
             row["body_flops"] = body_flops
@@ -850,7 +1087,7 @@ def _computation_costs(module: HloModule, comp: Computation,
         if branching:
             branches = [
                 _computation_costs(module, sub, every_branch, instr.name,
-                                   loop_of, trips)
+                                   loop_of, trips, "activation")
                 for sub in map(module.computations.get, instr.called_ids)
                 if sub is not None]
             if not every_branch and branches:
@@ -863,7 +1100,7 @@ def _computation_costs(module: HloModule, comp: Computation,
                 if sub is not None:
                     rows += _computation_costs(
                         module, sub, every_branch, branch_of, instr.name,
-                        trips * trip)
+                        trips * trip, "carry")
     return rows
 
 
@@ -1078,62 +1315,53 @@ def _is_copyish(module: HloModule, instr: Instr) -> bool:
     return False
 
 
+def _entry_rows(proto) -> List[Dict[str, Any]]:
+    """`instruction_costs` of the entry computation alone."""
+    return [r for r in instruction_costs(proto)
+            if r["branch_of"] is None and r["loop_of"] is None]
+
+
 def flash_boundary_layout(proto: bytes,
                           kernel_prefix: str = "flash") -> List[Dict[str, str]]:
-    """Copy/transpose instructions ADJACENT (operand or user) to Pallas
-    flash custom calls in the entry computation — the ISSUE 8 "zero
+    """Copy/transpose instructions ADJACENT (operand or user, by the
+    rows' `operands` and `users`) to Pallas flash custom calls in the
+    entry computation — the ISSUE 8 "zero
     transpose traffic at the kernel boundary" proof, asserted empty by
     tests/test_head_major.py and the run_ci.sh layout smoke.  On a
     backend where Pallas runs in interpret mode (CPU) there are no
     custom calls and the list is trivially empty — pair this with
     `copyish_instructions` / the program-level zero-`transpose`-ops
     check for a chip-free proof."""
-    module = HloModule(proto)
-    entry = module.entry
-    users: Dict[int, List[Instr]] = {}
-    for instr in entry.instructions:
-        for oid in instr.operand_ids:
-            users.setdefault(oid, []).append(instr)
+    rows = {r["name"]: r for r in _entry_rows(proto)}
     offenders = []
-    for instr in entry.instructions:
-        if instr.opcode != "custom-call":
+    for row in rows.values():
+        if row["opcode"] != "custom-call":
             continue
-        kern = _pallas_kernel_of(instr.op_name)
+        kern = _pallas_kernel_of(row["op_name"])
         if not kern or not kern.startswith(kernel_prefix):
             continue
-        neighbors = [entry.by_id[i] for i in instr.operand_ids
-                     if i in entry.by_id]
-        neighbors += users.get(instr.id, [])
-        for nb in neighbors:
-            if _is_copyish(module, nb):
-                offenders.append({"custom_call": instr.name,
+        for name in row["operands"] + row["users"]:
+            if rows[name]["copyish"]:
+                offenders.append({"custom_call": row["name"],
                                   "kernel": kern,
-                                  "neighbor": nb.name,
-                                  "opcode": nb.opcode})
+                                  "neighbor": name,
+                                  "opcode": rows[name]["opcode"]})
     return offenders
 
 
 def copyish_instructions(proto: bytes,
                          op_types: Optional[set] = None) -> List[Dict[str, Any]]:
     """Entry-computation copy/transpose instructions (incl. fusions
-    rooted at one), optionally restricted to rows attributed to the
+    rooted at one: the rows' `copyish`), optionally restricted to rows
+    attributed to the
     given fluid op types.  The chip-free half of the boundary proof:
     with Pallas in interpret mode the flash custom calls don't exist,
     but a head-major program still must not contain transpose kernels
     attributed to its attention ops."""
-    module = HloModule(proto)
-    entry = module.entry
-    out = []
-    for instr in entry.instructions:
-        if not _is_copyish(module, instr):
-            continue
-        op_type = fluid_op_of(instr.op_name)
-        if op_types is not None and op_type not in op_types:
-            continue
-        out.append({"name": instr.name, "opcode": instr.opcode,
-                    "op_type": op_type,
-                    "bytes": float(instr.shape.bytes)})
-    return out
+    return [{"name": r["name"], "opcode": r["opcode"],
+             "op_type": r["op_type"], "bytes": r["shape_bytes"]}
+            for r in _entry_rows(proto) if r["copyish"]
+            and (op_types is None or r["op_type"] in op_types)]
 
 
 def bucket_summary(rows: List[Dict[str, Any]]) -> Dict[str, Dict[str, float]]:

@@ -158,6 +158,8 @@ _FLUID_SCOPE_RE = re.compile(
     r"(?:^|[/(])([A-Za-z0-9_.\-]+):(\d+)(?=[/)]|$)")
 
 
+# the same, the LAST one of an op_name (the innermost scope)
+_LAST_FLUID_SCOPE_RE = re.compile(r"(?s:.*)" + _FLUID_SCOPE_RE.pattern)
 _SCOPE_PATH_RE = re.compile(r"((?:[A-Za-z0-9_.\-]+/)*)$")
 
 
@@ -178,11 +180,16 @@ def name_scope_of(op_name: str) -> str:
     transform's `jvp(` ends it; a `while/body` of jax's own between
     the two would read as part of it, so ask for a segment, not for
     equality)."""
-    hits = list(_FLUID_SCOPE_RE.finditer(op_name))
-    if not hits:
-        return ""
-    return _SCOPE_PATH_RE.search(
-        op_name[:hits[-1].start(1)]).group(1).rstrip("/")
+    return fluid_scope_of(op_name)[1]
+
+
+def fluid_scope_of(op_name: str) -> Tuple[Optional[str], str]:
+    """(`fluid_op_of`, `name_scope_of`) in one pass over the op_name."""
+    hit = _LAST_FLUID_SCOPE_RE.match(op_name)
+    if hit is None:
+        return None, ""
+    return hit.group(1), _SCOPE_PATH_RE.search(
+        op_name[:hit.start(1)]).group(1).rstrip("/")
 
 
 def phase_of(op_name: str) -> str:
@@ -201,27 +208,51 @@ def instruction_name(event_name: str) -> str:
     return event_name.partition(" = ")[0].lstrip("%")
 
 
+# what `cost.instruction_costs` says of an instruction beyond its
+# bucket, FLOPs, bytes and kernel: its result, the op it works for and,
+# in the `layout` bucket, where what it moves comes from
+OWNER_KEYS = ("shape", "shape_bytes", "owner", "owner_op_type",
+              "owner_name_scope", "owner_phase", "owner_via",
+              "owner_consumers", "source", "source_parameter",
+              "source_shape")
+
+
+def _own_scope(op_name: str) -> Dict[str, Any]:
+    """The owner keys of an instruction without a cost row: itself
+    where it carries a fluid scope, else nobody."""
+    op_type, name_scope = fluid_scope_of(op_name)
+    out = dict.fromkeys(OWNER_KEYS)
+    out.update(owner_op_type=op_type, owner_consumers=0,
+               owner_name_scope=name_scope,
+               owner_phase=phase_of(op_name),
+               owner_via="scope" if op_type else "none")
+    return out
+
+
 def program_map(proto: bytes) -> Dict[str, Dict[str, Any]]:
-    """{instruction name: {op_name, bucket, flops, bytes, kernel}} of
-    one serialized HloProto: every computation's instructions with
-    their `metadata.op_name`, and for the entry computation's, those
-    of every branch of its `conditional`s (any of which may be the one
-    that ran) and those of the body of every counted `while` the
-    bucket, FLOPs and bytes (per call) and Mosaic kernel name of
-    `cost.instruction_costs` (elsewhere None: the body of a `while`
-    whose trip count is not known)."""
+    """{instruction name: {op_name, bucket, flops, bytes, kernel,
+    *OWNER_KEYS}} of one serialized HloProto: every computation's
+    instructions with their `metadata.op_name`, and for the entry
+    computation's, those of every branch of its `conditional`s (any of
+    which may be the one that ran) and those of the body of every
+    counted `while` the bucket, FLOPs and bytes (per call), Mosaic
+    kernel name and owner keys of `cost.instruction_costs` (elsewhere
+    None and no owner keys: the body of a `while` whose trip count is
+    not known, whose instructions own themselves by their scope or
+    not at all)."""
     from . import cost
 
     module = cost.HloModule(proto)
     out: Dict[str, Dict[str, Any]] = {}
+    keys = ("op_name", "bucket", "flops", "bytes", "kernel") + OWNER_KEYS
+    for row in cost.instruction_costs(module, every_branch=True):
+        out[row["name"]] = {k: row[k] for k in keys}
     for comp in module.computations.values():
         for instr in comp.instructions:
-            out[instr.name] = {"op_name": instr.op_name, "bucket": None,
-                               "flops": None, "bytes": None,
-                               "kernel": None}
-    for row in cost.instruction_costs(module, every_branch=True):
-        out[row["name"]].update(bucket=row["bucket"], flops=row["flops"],
-                                bytes=row["bytes"], kernel=row["kernel"])
+            if instr.name not in out:
+                out[instr.name] = {"op_name": instr.op_name,
+                                   "bucket": None, "flops": None,
+                                   "bytes": None, "kernel": None}
     return out
 
 
@@ -269,7 +300,15 @@ def join_events(ops, modules, programs, window=None, chip=0
     the op was built under, "" for none), phase, bucket, flops and bytes (per
     call), kernel (a Mosaic kernel's name, else None), joined (found
     in its program's map), calls, self_s, total_s, max_s, min_s (of
-    one call's self time).
+    one call's self time), and `OWNER_KEYS` as
+    `cost.instruction_costs` gives them: `owner_op_type`,
+    `owner_name_scope`, `owner_phase` of the fluid op the instruction
+    works for and `owner_via`, how it was found ("scope": its own;
+    "consumer" / "producer": a scopeless copy handed to the op it
+    feeds / comes from; "none": nobody, as for every instruction that
+    is in no map), `source` of a `layout` row ("state": it re-lays a
+    step input, `source_parameter` of shape `source_shape`; "carry";
+    "activation").
     """
     modules = sorted(modules, key=lambda e: e[1])
     starts = [m[1] for m in modules]
@@ -295,11 +334,14 @@ def join_events(ops, modules, programs, window=None, chip=0
         if r is None:
             info = programs.get(module, {}).get(name)
             op_name = info["op_name"] if info else None
+            op_type, name_scope = fluid_scope_of(op_name or "")
+            owned = (info if info and "owner_via" in info
+                     else _own_scope(op_name or ""))
             r = rows[(module, name)] = {
                 "chip": chip, "module": module, "instruction": name,
                 "op_name": op_name,
-                "op_type": fluid_op_of(op_name) if op_name else None,
-                "name_scope": name_scope_of(op_name or ""),
+                "op_type": op_type,
+                "name_scope": name_scope,
                 "phase": phase_of(op_name or ""),
                 "bucket": (UNJOINED_BUCKET if info is None
                            else info["bucket"] or BODY_BUCKET),
@@ -308,7 +350,8 @@ def join_events(ops, modules, programs, window=None, chip=0
                 "kernel": info.get("kernel") if info else None,
                 "joined": info is not None,
                 "calls": 0, "self_s": 0.0, "total_s": 0.0,
-                "max_s": 0.0, "min_s": float("inf")}
+                "max_s": 0.0, "min_s": float("inf"),
+                **{k: owned[k] for k in OWNER_KEYS}}
         own = max(own, 0.0)
         r["calls"] += 1
         r["self_s"] += own
@@ -447,15 +490,19 @@ def op_time_table(profile_dir: str, windows=None) -> List[Dict[str, Any]]:
 
     Returns [{op_type, calls, total_ms, avg_ms, max_ms, min_ms, ratio}]
     sorted by total time; times are self times, so the rows sum to the
-    device's busy time.  Instructions that carry no `<op>:<idx>` scope
-    (infra, un-annotated programs, an instruction its program's map
-    does not hold) aggregate under "[unattributed]"; host python
-    events, `Steps`, `XLA Modules` and `Async XLA Ops` are not op time.
+    device's busy time.  `op_type` is the OWNER's (`owner_op_type`): an
+    instruction under an `<op>:<idx>` scope counts for that op, and a
+    scopeless one (the compiler's copies, slices and prefetches) for
+    the op it feeds or, failing that, comes from.  "[unattributed]" is
+    what truly has no owner: un-annotated programs, an instruction its
+    program's map does not hold, a scopeless one with no scoped
+    instruction either way; host python events, `Steps`, `XLA Modules`
+    and `Async XLA Ops` are not op time.
     `windows`: `{chip: (lo, hi)}` seconds on the trace's clock.
     """
     rows: Dict[str, Dict[str, Any]] = {}
     for r in op_rows(profile_dir, windows):
-        op = r["op_type"] or "[unattributed]"
+        op = r["owner_op_type"] or "[unattributed]"
         t = rows.setdefault(op, {"op_type": op, "calls": 0,
                                  "total_ms": 0.0, "max_ms": 0.0,
                                  "min_ms": float("inf")})
@@ -477,8 +524,9 @@ _SORT_KEYS = {"total": "total_ms", "calls": "calls", "max": "max_ms",
 
 def format_op_table(profile_dir: str,
                     sorted_key: Optional[str] = "total") -> str:
-    """The fluid profiler report: one row per fluid op type, sorted by
-    `sorted_key` (total/calls/max/min/ave — fluid's vocabulary)."""
+    """The fluid profiler report: one row per fluid op type (the
+    owner's, `op_time_table`), sorted by `sorted_key`
+    (total/calls/max/min/ave — fluid's vocabulary)."""
     rows = op_time_table(profile_dir)
     key = _SORT_KEYS.get(str(sorted_key).lower(), "total_ms")
     rows = sorted(rows, key=lambda r: -r[key])

@@ -311,6 +311,26 @@ def test_a_looped_step_with_flash_kernels_in_the_scans_body(one_chip):
                 continue
             assert not (instr.opcode == "convert"
                         and tuple(instr.shape.dims) in weights), instr.name
+    # the compiler's own copies inside the loops (no scope of theirs)
+    # are handed to the op they work for, and what they move of the
+    # body's parameter reads `carry`: a weight that rides the carry is
+    # no `state` in there, and nothing in a body is
+    moved = [r for r in inside if r["bucket"] == "layout"]
+    carried = [r for r in moved if r["source"] == "carry"]
+    assert carried and not [r for r in moved if r["source"] == "state"]
+    assert all(r["source_shape"] and r["source_parameter"] is None
+               for r in carried)
+    pairs = [r for r in carried if r["opcode"] in ("copy-start",
+                                                   "copy-done")]
+    assert pairs and all(r["op_type"] is None for r in pairs)
+    # most feed a scoped instruction of the body; one that moves an
+    # element of the carry and hands it straight back has nobody IN
+    # the body (a walk stays inside its computation)
+    fed = [r for r in pairs if r["owner_via"] == "consumer"]
+    assert len(fed) > len(pairs) / 2
+    assert all(r["owner_op_type"] and r["owner_consumers"] for r in fed)
+    assert {r["source"] for r in rows if r["bucket"] == "layout"
+            and not r["loop_of"]} == {"state", "activation"}
     text = compiled.as_text()
     forward = [ln for ln in text.splitlines() if " while(" in ln][0]
     # what the forward loop saves for its transpose: the float32 input
@@ -423,3 +443,37 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
     assert set(kernels) == {"flash_block_diffusion_fwd",
                             "flash_block_diffusion_dkv", "ragged_dot",
                             "rope_fwd", "rope_bwd"}
+    # the step was built through `Executor._prepare`, so under the
+    # fluid scopes: the TPU compiler's own `copy-start` / `copy-done`
+    # and `slice-start` pairs and relayout fusions carry none, and the
+    # def-use map hands them to the op they work for (PR 49)
+    from paddle_tpu.observe import cost
+
+    module = cost.HloModule(cost.compiled_hlo_proto(compiled))
+    rows = cost.instruction_costs(module, every_branch=True)
+    moved = [r for r in rows if r["bucket"] == "layout"]
+    opcodes = {r["opcode"] for r in moved if r["op_type"] is None}
+    assert {"copy-start", "copy-done", "async-start", "async-done"} \
+        <= opcodes
+    owned = sum(r["shape_bytes"] * r["trips"] for r in moved
+                if r["owner_via"] in cost.OWNER_VIAS)
+    assert owned >= 0.99 * sum(r["shape_bytes"] * r["trips"]
+                               for r in moved)
+    assert all(r["owner_via"] == "scope" for r in rows if r["op_type"])
+    # what re-lays or prefetches a step input says so, with the
+    # parameter's number and shape: the placed experts' float32
+    # weights, fetched four experts at a time
+    parameters = {i.parameter_number: i for i in module.entry.instructions
+                  if i.opcode == "parameter"}
+    state = [r for r in moved if r["source"] == "state"]
+    assert state and all(
+        parameters[r["source_parameter"]].shape.text == r["source_shape"]
+        for r in state)
+    assert not [r for r in state if r["branch_of"] or r["loop_of"]]
+    experts = [r for r in state if r["source_shape"] == "f32[16,768,2048]"
+               and r["opcode"] == "async-start"]
+    assert experts and {(r["owner_op_type"], r["shape"])
+                        for r in experts} <= {
+        ("moe_dropless", "f32[4,768,2048]"), ("adam", "f32[4,768,2048]"),
+        ("moe_dropless", "f32[16,768,2048]"),
+        ("adam", "f32[16,768,2048]")}

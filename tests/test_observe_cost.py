@@ -578,3 +578,366 @@ def test_async_wrappers_are_layout_unless_they_wrap_a_collective():
                        "slice-done.1": "layout", "ar-start.1": "comm",
                        "ar-update.1": "comm", "ar-done.1": "comm"}
     assert cost.HloModule(module).name == "jit_step"
+
+
+# --------------------------------------------------------------------------
+# whose work a scopeless instruction is (PR 49): `cost.DefUse`, the
+# owner keys of every row, `source` of the `layout` rows
+# --------------------------------------------------------------------------
+
+F32, TUPLE = 11, 13
+MUL, RELU = "jit(step)/jvp(mul:3)/dot_general", "jit(step)/jvp(relu:4)/max"
+MUL_BWD = "jit(step)/transpose(jvp(mul:3))/dot_general"
+
+
+def _array(*dims):
+    """A serialized f32 ShapeProto (element_type=2 dimensions=3)."""
+    return _vi(2, F32) + b"".join(_vi(3, d) for d in dims)
+
+
+def _tuple_of(*shapes):
+    return _vi(2, TUPLE) + b"".join(_ld(4, s) for s in shapes)
+
+
+A, B = _array(8, 4), _array(4, 8)
+
+
+def _op(name, opcode, iid, operands=(), called=(), scope="", shape=A,
+        index=None, number=None, config=b""):
+    """`_instr` with metadata.op_name (7: 2), shape (3), tuple_index
+    (13), parameter_number (9) and backend_config (43)."""
+    buf = _instr(name, opcode, iid, operands, called) + _ld(3, shape)
+    if scope:
+        buf += _ld(7, _ld(2, scope.encode()))
+    if index is not None:
+        buf += _vi(13, index)
+    if number is not None:
+        buf += _vi(9, number)
+    if config:
+        buf += _ld(43, config)
+    return buf
+
+
+def _module(*comps, entry, schedule=None):
+    """A serialized HloModuleProto; `schedule`: {computation id:
+    instruction ids in the order they run} (7: sequences=1, a map)."""
+    buf = _ld(1, b"jit_step") + b"".join(_ld(3, c) for c in comps) \
+        + _vi(6, entry)
+    for cid, ids in (schedule or {}).items():
+        packed = b"".join(_varint(i) for i in ids)
+        buf += _ld(7, _ld(1, _vi(1, cid) + _ld(2, _ld(1, packed))))
+    return buf
+
+
+def _one_consumer():
+    return _module(_comp("main", 1, [
+        _op("w", "parameter", 1, number=2),
+        _op("copy.1", "copy", 2, [1]),
+        _op("fusion.2", "fusion", 3, [2],
+            scope="jit(step)/jvp(attention/mul:3)/dot_general"),
+        _op("tuple.3", "tuple", 4, [3])], 4), entry=1)
+
+
+def _two_consumers(schedule):
+    # the computation lists relu's fusion first; the schedule runs
+    # mul's first
+    return _module(_comp("main", 1, [
+        _op("x", "parameter", 1, number=0),
+        _op("copy.1", "copy", 2, [1]),
+        _op("fusion.relu", "fusion", 3, [2], scope=RELU),
+        _op("fusion.mul", "fusion", 4, [2], scope=MUL_BWD),
+        _op("tuple.5", "tuple", 5, [3, 4])], 5), entry=1,
+        schedule={1: [1, 2, 4, 3, 5]} if schedule else None)
+
+
+def _ends_at_root():
+    return _module(_comp("main", 1, [
+        _op("x", "parameter", 1, number=0),
+        _op("fusion.1", "fusion", 2, [1], scope=MUL),
+        _op("bitcast.2", "bitcast", 3, [2]),
+        _op("copy.3", "copy", 4, [3]),
+        _op("tuple.4", "tuple", 5, [4])], 5), entry=1)
+
+
+def _through_transparent():
+    pair = _tuple_of(B, A, _array())
+    return _module(_comp("main", 1, [
+        _op("x", "parameter", 1, number=0),
+        _op("w", "parameter", 2, number=1, shape=B),
+        _op("tuple.1", "tuple", 3, [1, 2], shape=_tuple_of(A, B)),
+        _op("opt-barrier.2", "opt-barrier", 4, [3],
+            shape=_tuple_of(A, B)),
+        _op("get-tuple-element.3", "get-tuple-element", 5, [4], index=1,
+            shape=B),
+        _op("bitcast.4", "bitcast", 6, [5], shape=B, scope=RELU),
+        _op("copy-start.5", "copy-start", 7, [6], shape=pair),
+        _op("copy-done.5", "copy-done", 8, [7], shape=B),
+        _op("slice-start.6", "async-start", 9, [8],
+            shape=_tuple_of(_tuple_of(B), _array(2, 8), _array())),
+        _op("slice-done.6", "async-done", 10, [9], shape=_array(2, 8)),
+        _op("fusion.7", "fusion", 11, [10], scope=MUL),
+        _op("tuple.8", "tuple", 12, [11])], 12), entry=1)
+
+
+def _loop():
+    carry = _tuple_of(A, B)
+    body = _comp("body", 2, [
+        _op("p", "parameter", 1, number=0, shape=carry),
+        _op("get-tuple-element.1", "get-tuple-element", 2, [1], index=0),
+        _op("get-tuple-element.2", "get-tuple-element", 3, [1], index=1,
+            shape=B),
+        _op("copy.body", "copy", 4, [2]),
+        _op("fusion.body", "fusion", 5, [4],
+            scope="jit(step)/scan:5/while/body/mul:2/dot_general"),
+        _op("copy.carried", "copy", 6, [3], shape=B),
+        _op("tuple.3", "tuple", 7, [5, 6], shape=carry)], 7)
+    cond = _comp("cond", 3, [_op("p", "parameter", 1, number=0,
+                                 shape=carry)], 1)
+    main = _comp("main", 1, [
+        _op("x", "parameter", 1, number=0, shape=carry),
+        _op("while.1", "while", 2, [1], [2, 3], shape=carry,
+            scope="jit(step)/scan:5/while",
+            config=b'{"known_trip_count":{"n":"4"}}')], 2)
+    return _module(body, cond, main, entry=1)
+
+
+def _branches():
+    def branch(cid, scope):
+        return _comp(f"branch{cid}", cid, [
+            _op(f"p{cid}", "parameter", 1, number=0),
+            _op(f"copy.b{cid}", "copy", 2, [1]),
+            _op(f"fusion.b{cid}", "fusion", 3, [2], scope=scope)], 3)
+
+    main = _comp("main", 1, [
+        _op("pred", "parameter", 1, number=0, shape=_array()),
+        _op("x", "parameter", 2, number=1),
+        _op("conditional.1", "conditional", 3, [1, 2, 2], [2, 3],
+            scope="jit(step)/moe:1/cond")], 3)
+    return _module(branch(2, MUL), branch(3, RELU), main, entry=1)
+
+
+def _nobody():
+    return _module(_comp("main", 1, [
+        _op("x", "parameter", 1, number=0),
+        _op("copy.1", "copy", 2, [1]),
+        _op("tuple.2", "tuple", 3, [2])], 3), entry=1)
+
+
+def _owner(**want):
+    return dict({"owner_via": "consumer", "owner_consumers": 1}, **want)
+
+
+OWNER_CASES = {
+    # a copy of a step input that one scoped instruction reads: its
+    # op's, forward, and `state` with the parameter's number and shape
+    "one_scoped_consumer": (_one_consumer, {"copy.1": _owner(
+        owner="fusion.2", owner_op_type="mul", owner_phase="forward",
+        owner_name_scope="attention", source="state", source_parameter=2, source_shape="f32[8,4]")}),
+    # two: the first to RUN, by the schedule where there is one, by
+    # the computation's own list where not; both are counted
+    "two_consumers_by_the_schedule": (lambda: _two_consumers(True), {
+        "copy.1": _owner(owner="fusion.mul", owner_op_type="mul",
+                         owner_phase="backward", owner_consumers=2)}),
+    "two_consumers_by_the_list": (lambda: _two_consumers(False), {
+        "copy.1": _owner(owner="fusion.relu", owner_op_type="relu",
+                         owner_consumers=2)}),
+    # no consumer before the root: the nearest scoped one behind it
+    "ends_at_the_root": (_ends_at_root, {
+        "copy.3": dict(owner="fusion.1", owner_via="producer",
+                       owner_op_type="mul", owner_consumers=0,
+                       source="activation", source_parameter=None),
+        "fusion.1": dict(owner="fusion.1", owner_via="scope"),
+        "x": _owner(owner="fusion.1")}),
+    # through tuple, opt-barrier, get-tuple-element, a SCOPED bitcast
+    # and both asynchronous pairs; the chain back picks the tuple's
+    # element that was asked for
+    "through_the_transparent_ones": (_through_transparent, {
+        "copy-done.5": _owner(owner="fusion.7", source="state",
+                              source_parameter=1,
+                              source_shape="f32[4,8]", shape="f32[4,8]"),
+        "copy-start.5": _owner(owner="fusion.7", source="state",
+                               shape="f32[4,8]"),
+        "slice-start.6": _owner(owner="fusion.7", source="state",
+                                shape="f32[2,8]", shape_bytes=64.0),
+        "slice-done.6": _owner(owner="fusion.7", source="state",
+                               source_parameter=1),
+        "bitcast.4": dict(owner_via="scope", owner_op_type="relu"),
+        "w": _owner(owner="fusion.7")}),
+    # a counted loop's body is a computation of its own: its carry is
+    # `carry`, and what is only written back has nobody in there
+    "inside_a_counted_loop": (_loop, {
+        "copy.body": _owner(owner="fusion.body", owner_op_type="mul",
+                            source="carry",
+                            source_shape="f32[8,4]", loop_of="while.1",
+                            trips=4),
+        "copy.carried": dict(owner_via="none", source="carry",
+                             source_shape="f32[4,8]",
+                             source_parameter=None),
+        "while.1": dict(owner_via="scope", owner_op_type="scan")}),
+    # every branch of a conditional has its own map
+    "both_branches_of_a_conditional": (_branches, {
+        "copy.b2": _owner(owner="fusion.b2", owner_op_type="mul",
+                          source="activation", branch_of="conditional.1"),
+        "copy.b3": _owner(owner="fusion.b3", owner_op_type="relu",
+                          source="activation", source_shape=None),
+        "x": _owner(owner="conditional.1", owner_op_type="moe")}),
+    "nobody": (_nobody, {"copy.1": dict(
+        owner=None, owner_via="none", owner_op_type=None,
+        owner_phase="other", owner_name_scope="", owner_consumers=0,
+        source="state", source_parameter=0)}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OWNER_CASES))
+def test_a_scopeless_instruction_is_handed_to_the_op_it_works_for(case):
+    build, expect = OWNER_CASES[case]
+    rows = {r["name"]: r
+            for r in cost.instruction_costs(build(), every_branch=True)}
+    for name, want in expect.items():
+        for key, value in want.items():
+            assert rows[name][key] == value, (name, key, rows[name][key])
+    for r in rows.values():
+        assert (r["source"] is not None) == (r["bucket"] == "layout")
+        assert (r["owner"] is None) == (r["owner_via"] == "none")
+        assert r["owner_via"] in cost.OWNER_VIAS + ("none",)
+
+
+def test_owner_keys_of_compiled_programs():
+    """The same on what XLA:CPU compiles: a step input is its first
+    scoped reader's, scopeless work behind a scoped product with no
+    consumer before the root is the product's, and a transposed step
+    input is `state` with its parameter."""
+    def step(x, w):
+        with jax.named_scope("mul:1"):
+            y = x @ w
+        return jnp.tanh(y), jnp.transpose(w).copy()
+
+    proto = cost.compiled_hlo_proto(
+        jax.jit(step).lower(jnp.ones((64, 32)),
+                            jnp.ones((32, 48))).compile())
+    rows = cost.instruction_costs(proto)
+    product, = [r for r in rows if r["op_type"] == "mul"]
+    assert product["owner_via"] == "scope"
+    for r in rows:
+        if r["opcode"] == "parameter":
+            assert (r["owner"], r["owner_via"]) == (product["name"],
+                                                    "consumer")
+    after = [r for r in rows if product["name"] in r["operands"]
+             and r["op_type"] is None and r["bucket"] == "elementwise"]
+    assert after and all((r["owner_op_type"], r["owner_via"])
+                         == ("mul", "producer") for r in after)
+    of_w = [r for r in rows if r["bucket"] == "layout"]
+    assert of_w and all(
+        (r["source"], r["source_parameter"], r["source_shape"],
+         r["owner_via"]) == ("state", 1, "f32[32,48]", "none")
+        for r in of_w)
+    # the old readers of the same rows, re-found on them
+    assert {r["name"] for r in cost.copyish_instructions(proto)} \
+        == {r["name"] for r in rows if r["copyish"]} \
+        >= {r["name"] for r in of_w}
+    assert cost.flash_boundary_layout(proto) == []
+
+
+def test_owner_keys_around_and_inside_a_compiled_scan():
+    """A `lax.scan` under a fluid scope: a copy of a step input on its
+    way into the loop is the loop's op's and `state`; the body's rows
+    carry the loop and its trips, own themselves by their scopes, and
+    no `layout` row in there reads `state`."""
+    def step(x, w):
+        def body(c, _):
+            with jax.named_scope("mul:2"):
+                return jnp.tanh(jnp.transpose(c) @ w), None
+        with jax.named_scope("scan:1"):
+            return jax.lax.scan(body, x, None, length=5)[0]
+
+    compiled = jax.jit(step).lower(jnp.ones((16, 16)),
+                                   jnp.ones((16, 16))).compile()
+    rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+    loop, = [r for r in rows if r["opcode"] == "while"]
+    assert (loop["owner_op_type"], loop["owner_via"]) == ("scan", "scope")
+    for r in rows:
+        if r["loop_of"] is None and r["source"] == "state":
+            assert (r["owner"], r["owner_via"]) == (loop["name"],
+                                                    "consumer")
+    inside = [r for r in rows if r["loop_of"]]
+    assert inside and all(r["trips"] == 5 for r in inside)
+    assert not [r for r in inside if r["source"] == "state"]
+    scoped = [r for r in inside if r["op_type"] == "mul"]
+    assert scoped and all(r["owner_via"] == "scope" for r in scoped)
+    assert not [r for r in inside if r["owner_via"] == "none"]
+
+
+def _owner_events():
+    """`program_map` of `_through_transparent` and one step's events:
+    the copy pair, the slice pair, the product, one unknown."""
+    from paddle_tpu.observe import trace
+
+    step = "jit_step(7)"
+    ops = [("%copy-start.5 = (f32[4,8]) copy-start(%bitcast.4)", 1.0, 0.1),
+           ("%copy-done.5 = f32[4,8] copy-done(%copy-start.5)", 1.1, 0.3),
+           ("%slice-done.6 = f32[2,8] async-done(%slice-start.6)",
+            1.4, 0.2),
+           ("%fusion.7 = f32[8,4] fusion(%slice-done.6)", 1.6, 1.0),
+           ("%fusion.999 = f32[4]{0} fusion(f32[4]{0} %p)", 2.6, 0.4)]
+    programs = {step: trace.program_map(_through_transparent())}
+    return ops, [(step, 0.9, 3.0)], programs
+
+
+def test_join_events_carries_the_owner_keys():
+    from paddle_tpu.observe import trace
+
+    ops, modules, programs = _owner_events()
+    rows = {r["instruction"]: r
+            for r in trace.join_events(ops, modules, programs)}
+    assert set(trace.OWNER_KEYS) <= set(rows["copy-done.5"])
+    done = rows["copy-done.5"]
+    assert (done["op_type"], done["owner_op_type"], done["owner_via"],
+            done["owner_phase"], done["owner"]) == (
+        None, "mul", "consumer", "forward", "fusion.7")
+    assert (done["bucket"], done["source"], done["source_parameter"],
+            done["source_shape"], done["shape"]) == (
+        "layout", "state", 1, "f32[4,8]", "f32[4,8]")
+    assert rows["slice-done.6"]["shape_bytes"] == 64.0
+    assert rows["fusion.7"]["owner_via"] == "scope"
+    # an instruction that is in no map has nobody
+    lost = rows["fusion.999"]
+    assert (lost["joined"], lost["owner_via"], lost["owner_op_type"],
+            lost["source"]) == (False, "none", None, None)
+    # self times still sum to the busy union
+    assert sum(r["self_s"] for r in rows.values()) == pytest.approx(2.0)
+    # a map from before the owner keys: an instruction owns itself by
+    # its scope, or nobody does
+    old = {"jit_step(7)": {
+        "fusion.7": {"op_name": MUL, "bucket": "matmul", "flops": 1.0,
+                     "bytes": 1.0},
+        "copy-done.5": {"op_name": "", "bucket": "layout", "flops": 0.0,
+                        "bytes": 1.0}}}
+    rows = {r["instruction"]: r
+            for r in trace.join_events(ops, modules, old)}
+    assert rows["fusion.7"]["owner_via"] == "scope"
+    assert rows["fusion.7"]["owner_op_type"] == "mul"
+    assert rows["copy-done.5"]["owner_via"] == "none"
+
+
+def test_the_profiler_report_sums_by_owner_and_to_the_busy_time(
+        monkeypatch):
+    """`profiler.profiler(sorted_key=...)` prints `format_op_table`:
+    a scopeless copy's time is its owner's there, `[unattributed]` is
+    what has none, and the rows still sum to the busy time."""
+    from paddle_tpu.observe import trace
+
+    ops, modules, programs = _owner_events()
+    monkeypatch.setattr(
+        trace, "op_rows",
+        lambda profile_dir, windows=None, chips=None:
+        trace.join_events(ops, modules, programs))
+    table = {r["op_type"]: r for r in trace.op_time_table("nowhere")}
+    assert set(table) == {"mul", "[unattributed]"}
+    assert table["mul"]["total_ms"] == pytest.approx(1600.0)
+    assert table["mul"]["calls"] == 4
+    assert table["[unattributed]"]["total_ms"] == pytest.approx(400.0)
+    assert sum(r["total_ms"] for r in table.values()) == pytest.approx(
+        2000.0)
+    assert sum(r["ratio"] for r in table.values()) == pytest.approx(1.0)
+    report = trace.format_op_table("nowhere", sorted_key="total")
+    assert report.index("mul") < report.index("[unattributed]")
