@@ -78,6 +78,7 @@ _FIELDS = Heard._fields[1:] + (
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "short_convs_kernel", "short_convs_xla",
     "ropes_kernel", "ropes_xla",
+    "share_rows_kernel", "share_rows_xla",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -202,6 +203,15 @@ class RuntimeStats:
         # (delta() around a build; a Program build counts nothing)
         self.ropes_kernel = 0
         self.ropes_xla = 0
+        # sorted-row sections of a share-holding `moe_dropless` traced
+        # (`_held_rows`, one a row buffer), by what the shape chose for
+        # the way back to token order: the kernel of
+        # `ops/pallas/rows_to_tokens.py` over the buffer's R rows, or
+        # the composition that gathers T x k rows (delta() around a
+        # build; a branch of a share's `switch` counts once, as traced,
+        # forward and again where the backward pass recomputes it)
+        self.share_rows_kernel = 0
+        self.share_rows_xla = 0
         # trips of the counted loops traced (`static_rnn` with a
         # `trip_count`): what a step runs of them (delta() around a
         # build: 4 where one stack runs 4 times), and what an early exit
@@ -310,6 +320,13 @@ class RuntimeStats:
                 self.ropes_kernel += 1
             else:
                 self.ropes_xla += 1
+
+    def record_share_rows(self, kernel: bool):
+        with self._lock:
+            if kernel:
+                self.share_rows_kernel += 1
+            else:
+                self.share_rows_xla += 1
 
     def record_loop_trips(self, trips: int):
         with self._lock:

@@ -85,6 +85,24 @@ third, `router_gradient`, is the caller's say over the backward pass):
   kernels in every branch, T*k included.  `RowBufferCountOut` =
   `RowBufferCount` (3,) + the one-hot of the size taken.
 
+  What the section MOVES is the buffer's R rows, never T*k (PR 50).
+  Tokens -> rows is a gather of R rows (the experts' input; the
+  output's gradient for the rows' and, as a dot a row, for the
+  weights' gradient, which `back` then places into (T, k): T*k
+  scalars out of R + 1).  Rows -> tokens (the combine; the gradient of
+  the gather) is `ops/pallas/rows_to_tokens.py`: the R rows in token
+  order (`by_token`: one sort of the layer's T*k sorted rows' tokens
+  serves every buffer, and carries the pairs' weights along, as the
+  sort by expert does) and one kernel that sums each token tile's
+  range of them on the MXU, float32 weights and a float32 sum.  No
+  (T, k, D) array is built, in any branch.  A shape that kernel does
+  not tile (a width that is no
+  multiple of 128, tokens or rows in no whole 128s: the tests' toy
+  shapes) keeps the composition it replaced, a (T, k, D) gather out of
+  the buffer summed over k (`_pairs_rows`); the shape chooses, and
+  `runtime_stats.share_rows_kernel` / `_xla` count the sections traced
+  each way.
+
 `router_gradient=False` (its own attribute, tied to neither of the
 above) makes the routing weights constants of the backward pass:
 nothing reaches `GateW`, or `X`, through them; the experts' inputs and
@@ -107,6 +125,8 @@ from ..core.registry import register_op
 from .common import first, opt_in
 from .decoder import silu_gate
 from .pallas.grouped_matmul import grouped_matmul, row_visits
+from .pallas.rows_to_tokens import (by_token, order_of, rows_to_tokens,
+                                    rows_to_tokens_takes)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -201,21 +221,100 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _held_rows(rows, xf, w1, w3, w2, weights, order, back, counts, visits):
+@jax.custom_vjp
+def _take_rows(x, index, tokens):
+    """`_take_head` where the kernel sums: `x[index]` for `index` (R,)
+    = the tokens of the buffer's rows, `tokens` their `order_of`.
+    The gradient sums a token's rows out of the R rows of `g`."""
+    return x[index]
+
+
+def _take_rows_fwd(x, index, tokens):
+    # (a token's count and dtype: an empty array of X's kind)
+    return x[index], (tokens, jnp.zeros((x.shape[0], 0), x.dtype))
+
+
+def _take_rows_bwd(res, g):
+    tokens, like = res
+    return (rows_to_tokens(g, tokens, like.shape[0], out_dtype=like.dtype),
+            None, None)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine_rows(ys, weights, of_row, back, index, n, tokens):
+    """`_combine` where the kernel sums: each row of `ys` times its
+    pair's weight, summed a token.  `index` (R,) is the rows' tokens,
+    `of_row` (R,) float32 `weights` (T, k) in the rows' order, and
+    `tokens` carries it in token order: the op's sort and `by_token`'s
+    brought them along, so no R scalars are gathered."""
+    return rows_to_tokens(ys, tokens, weights.shape[0], weighted=True)
+
+
+def _combine_rows_fwd(ys, weights, of_row, back, index, n, tokens):
+    return (_combine_rows(ys, weights, of_row, back, index, n, tokens),
+            (ys, weights, of_row, back, index, n))
+
+
+def _combine_rows_bwd(res, g):
+    ys, weights, of_row, back, index, n = res
+    rows = ys.shape[0]
+    # tokens -> rows: ONE gather of R rows of the output's gradient,
+    # for the rows' gradient and, a dot a row, for the weights'
+    g = g[index]
+    gys = (g * of_row[:, None]).astype(ys.dtype)
+    dots = jnp.sum(g * ys.astype(jnp.float32), axis=-1)
+    # placed into (T, k) by `back`: T x k scalars out of R + 1
+    gw = jnp.concatenate([dots, jnp.zeros(1, dots.dtype)])[
+        jnp.where(back < n, back, rows)]
+    return gys, gw.astype(weights.dtype), None, None, None, None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _held_rows(rows, xf, w1, w3, w2, weights, order, back, counts, visits,
+               sorted_weights, sorted_rows):
     """The sorted-row section of a layer that holds a share, on a
     buffer of `rows` rows (static; at least the held experts' rows):
     (T, D) float32, the partial sum.  `visits`: the grouped matmuls'
-    tables over T*k rows, the same for every buffer."""
+    tables over T*k rows, the same for every buffer; `sorted_weights`
+    (T*k,): `weights` in the sorted rows' order, constants (the op's
+    sort brought them along); `sorted_rows`: the T*k sorted rows
+    `by_token`, or None where no buffer of this layer runs the kernel.
+
+    Nothing here is T x k rows long where `rows_to_tokens_takes` the
+    shape: tokens -> rows is a gather of `rows` rows, rows -> tokens
+    the kernel of `ops/pallas/rows_to_tokens.py` over the rows in token
+    order (one sort a layer, `by_token`, serves every buffer; `order_of`
+    makes a buffer's table, for the combine and for the gradient of the
+    gather).  A shape it does not take (a width that is no multiple of
+    128, the tests' toy shapes) keeps the composition: a (T, k, D)
+    array gathered out of the buffer and summed over k."""
+    from ..observe.monitoring import runtime_stats
+
     k = weights.shape[1]
     n = jnp.sum(counts)
     head = order[:rows]
+    kernel = sorted_rows is not None
+    runtime_stats.record_share_rows(kernel)
     # rows past the held experts' belong to no group: a grouped matmul
     # writes them as zeros, forward and backward (the kernels as they
     # store, no pass over (rows, D))
-    xs = _take_head(xf, head // k, back, n)
+    index = head // k
+    if kernel:
+        of_row = sorted_weights[:rows]
+        tokens = order_of(sorted_rows, rows, xf.shape[0])
+        xs = _take_rows(xf, index, tokens)
+    else:
+        xs = _take_head(xf, index, back, n)
     h = silu_gate(grouped_matmul(xs, w1, counts, visits),
                   grouped_matmul(xs, w3, counts, visits))
     ys = grouped_matmul(h, w2, counts, visits)
+    if kernel:
+        return _combine_rows(ys, weights, of_row, back, index, n, tokens)
     return _combine(ys, weights, back, head, n)
 
 
@@ -348,7 +447,14 @@ def moe_dropless(ctx, ins, attrs):
     flat = experts.reshape(-1)                       # (T*k,) expert ids
     # held experts sort first, as groups 0..count-1; the rest follow
     key = flat if held is None else (flat - first_held) % e
-    order = jnp.argsort(key, stable=True)            # sorted row -> pair
+    if held is None:
+        order = jnp.argsort(key, stable=True)        # sorted row -> pair
+    else:
+        # a share's sort brings the pairs' weights along, for the
+        # section's combine: gathering R scalars costs more than this
+        _, order, sorted_weights = jax.lax.sort(
+            (key, jax.lax.iota(jnp.int32, t * k),
+             jax.lax.stop_gradient(weights).reshape(-1)), num_keys=1)
     back = jnp.argsort(order).astype(jnp.int32)      # pair -> sorted row
     counts = jnp.sum(key[:, None] == jnp.arange(groups, dtype=jnp.int32),
                      axis=0, dtype=jnp.int32)       # no scatter
@@ -368,8 +474,15 @@ def moe_dropless(ctx, ins, attrs):
         taken = jnp.sum(jnp.sum(counts) > jnp.asarray(sizes[:-1], jnp.int32),
                         dtype=jnp.int32)
         diff = (xf, w1, w3, w2, weights)
-        index = (order.astype(jnp.int32), back.reshape(t, k), counts,
-                 visits)
+        order = order.astype(jnp.int32)
+        # the rows by token, once for whichever buffer is taken (T*k
+        # keys; only a shape the kernel takes: every size is then one)
+        sorted_rows = None
+        if all(rows_to_tokens_takes(r, t, d) for r in sizes):
+            sorted_rows = by_token(order // k, jnp.sum(counts), t,
+                                   sorted_weights)
+        index = (order, back.reshape(t, k), counts, visits, sorted_weights,
+                 sorted_rows)
         y = (_held_rows(t * k, *diff, *index) if len(sizes) == 1
              else _switched(sizes, taken, diff, index))
 

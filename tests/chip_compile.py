@@ -42,6 +42,17 @@ EXPERT_CELLS = {
 }
 
 
+# the first row buffer of the five cells that hold a share (the sorted
+# rows a section runs on nearly always), tokens, experts a token, width
+SHARE_CELLS = {
+    "mellum2-16k": (24576, 16384, 8, 2304),
+    "sdar-8k": (24576, 16384, 8, 2048),
+    "qwen3next-16k": (7680, 16384, 10, 2048),
+    "lfm2-8k": (6144, 8192, 4, 2048),
+    "joyai-8k": (3072, 8192, 8, 2048),
+}
+
+
 def _compile_args(fn, *args):
     """Compile an already-jittable `fn` for the described chip from
     ShapeDtypeStruct arguments that carry its sharding."""

@@ -12,7 +12,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from chip_compile import BF16, EXPERT_CELLS, F32, I32, _compile_args
+from chip_compile import (BF16, EXPERT_CELLS, F32, I32, SHARE_CELLS,
+                          _compile_args)
 
 
 def test_olmoe_step_kernels_at_the_published_shapes(one_chip):
@@ -101,8 +102,10 @@ def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
     more: the down-projection's result would serve the router's
     gradient alone, which a program that runs a share holds back);
     static shapes whatever the routing, nothing 64 experts wide but
-    the router; the smallest branch writes one T*k-row buffer each
-    way, the gather back to token order; and the plan needs less
+    the router; the smallest branch writes NOTHING T*k rows long since
+    PR 50 (the way back to token order is `ops/pallas/
+    rows_to_tokens.py` over the buffer's rows; one T*k-row gather each
+    way before); and the plan needs less
     memory than the section differentiated on T*k rows (a quarter less
     before PR 40; an eighth since, the kernels having taken the masks'
     buffers out of the section differentiated as it stands).  The gated
@@ -151,9 +154,13 @@ def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
         lines = _computation(text, smallest)
         assert not any(f"[{t * k},{h}]" in line.split(" = ")[1].split("(")[0]
                        for line in lines if " = " in line)
-        wide = [line for line in lines if " = " in line and
-                f"[{t * k},{hidden}]" in line.split(" = ")[1].split("(")[0]]
-        assert len(wide) == 1 and " fusion(" in wide[0], wide  # the gather
+        # nothing T*k rows long, nor (T, k, D): the way back to token
+        # order is the kernel over the buffer's rows (PR 50; one T*k-row
+        # gather each way before)
+        assert not any(
+            f"[{t * k}," in line.split(" = ")[1].split("(")[0]
+            or f"[{t},{k},{hidden}]" in line.split(" = ")[1].split("(")[0]
+            for line in lines if " = " in line)
 
     proto = cost.compiled_hlo_proto(compiled)
     every = cost.instruction_costs(proto, every_branch=True)
@@ -192,8 +199,12 @@ def test_lfm2_share_layer_and_short_conv_at_the_published_shapes(
     # operands and a kernel takes from HBM (713 MiB; 556 at the parent,
     # whose section as it stands planned 994 to this one's 803).  No
     # step's peak is there: `lfm2-8k` reads `hbm_peak_gb` 4.07 for the
-    # parent's 4.12 (PERF.md, PR 40)
-    assert temporaries <= 720 << 20
+    # parent's 4.12 (PERF.md, PR 40).  PR 50 pins what it leaves: 708.5
+    # MiB (742,929,408 bytes; the parent 712.6), the T*k branch on the
+    # rows -> tokens kernel like the others: no (T, k, D) array, the
+    # rows in token order in its place.  (A `vmem_limit_bytes` of 64
+    # MiB on that kernel alone planned 900 MiB here.)
+    assert temporaries <= 710 << 20
 
     conv = get_op_impl("short_conv")
 
@@ -379,6 +390,58 @@ def test_grouped_matmul_kernels_at_the_cells_shapes(one_chip, cell):
             assert {r["flops"] for r in kernels} == {2.0 * rows * kk * nn}
 
 
+@pytest.mark.parametrize("cell", sorted(SHARE_CELLS))
+def test_rows_to_tokens_kernel_at_the_share_cells_shapes(one_chip, cell):
+    """`ops/pallas/rows_to_tokens.py` at the five share cells' first
+    row buffer: the shape rule takes it, and Mosaic compiles the kernel
+    with the float32 routing weights (three exact bf16 passes) and
+    without (one, its float32 sum in scratch and a bf16 result), on
+    bf16 rows and on float32 rows
+    (`Precision.HIGHEST`), and at the T x k rows of the last buffer;
+    `token_order` compiles beside it.
+    Nothing T x k rows long is planned at the first size: the
+    temporaries are the R rows in token order and the (T, D) sums."""
+    from paddle_tpu.observe import cost
+    from paddle_tpu.ops.pallas import rows_to_tokens as rt
+
+    rows, t, k, d = SHARE_CELLS[cell]
+    assert rt.rows_to_tokens_takes(rows, t, d)
+    assert rt.rows_to_tokens_takes(t * k, t, d)
+
+    def sums(vals, *order):
+        # the combine; the gradient of a gather, in the rows' dtype
+        return (rt.rows_to_tokens(vals, order, t, weighted=True),
+                rt.rows_to_tokens(vals, order, t, out_dtype=vals.dtype))
+
+    # (the order is an argument: a sort of 24,576 keys or more compiles
+    # for 17 s here, whatever it sorts, and the step has its like)
+    chunk, tile = rt.ROW_CHUNK, rt.TOKEN_TILE
+    for r, dtype in ((rows, BF16), (rows, F32), (t * k, BF16)):
+        compiled = _compile_args(jax.jit(sums), *[
+            jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in (((r, d), dtype), ((r,), I32),
+                          ((r // chunk, 1, chunk), I32),
+                          ((3, t // tile + r // chunk), I32), ((), I32),
+                          ((r // chunk, 1, chunk), F32))])
+        table = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+        kernels = [x for x in table if x["kernel"]]
+        assert [x["pallas_kernel"] for x in kernels] == ["rows_to_tokens"] * 2
+        assert {x["flops"] for x in kernels} == {0.0}
+        if r == rows:
+            itemsize = jnp.dtype(dtype).itemsize
+            assert compiled.memory_analysis().temp_size_in_bytes <= \
+                2 * r * d * itemsize + 2 * 4 * t * d
+            assert f"[{t},{k},{d}]" not in compiled.as_text()
+    if rows < 8192:
+        order = _compile_args(
+            jax.jit(lambda tokens, n, w: rt.token_order(tokens, n, t, w)),
+            jax.ShapeDtypeStruct((rows,), I32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), I32, sharding=one_chip),
+            jax.ShapeDtypeStruct((rows,), F32, sharding=one_chip))
+        assert " sort(" in order.as_text()
+        assert "scatter" not in order.as_text()
+
+
 def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
         one_chip):
     """The whole training step of `sdar-8k` as `benchmarks/run.py`
@@ -440,9 +503,12 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
     # q and k of six layers: normed and turned forward and recomputed,
     # one backward kernel each (PR 48)
     assert (kernels["rope_fwd"], kernels["rope_bwd"]) == (24, 12)
+    # a layer's three row buffers sum their rows a token in one kernel
+    # forward (the recomputed section's) and one backward (PR 50)
+    assert kernels["rows_to_tokens"] == 36
     assert set(kernels) == {"flash_block_diffusion_fwd",
                             "flash_block_diffusion_dkv", "ragged_dot",
-                            "rope_fwd", "rope_bwd"}
+                            "rope_fwd", "rope_bwd", "rows_to_tokens"}
     # the step was built through `Executor._prepare`, so under the
     # fluid scopes: the TPU compiler's own `copy-start` / `copy-done`
     # and `slice-start` pairs and relayout fusions carry none, and the
@@ -475,5 +541,8 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
     assert experts and {(r["owner_op_type"], r["shape"])
                         for r in experts} <= {
         ("moe_dropless", "f32[4,768,2048]"), ("adam", "f32[4,768,2048]"),
+        # (since PR 50 one slice's first consumer is a cast the compiler
+        # fused into the norm before the layer)
+        ("rms_norm", "f32[4,768,2048]"),
         ("moe_dropless", "f32[16,768,2048]"),
         ("adam", "f32[16,768,2048]")}

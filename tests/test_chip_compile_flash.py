@@ -292,13 +292,18 @@ STEP_TEXT = {
     # 5e45cfed.., e1f3219f..)
     "olmoe-4k":
     "edde7176fe33bb4d5429b1ced73b68db315c639ed19922b19640112a2b71fd8b",
-    # re-pinned, PR 48: its attention layer's QK-norm a head rides in
-    # the `rope` op (d_head 64: the op's composition, one op where two
-    # stood; parent: 8265f55d.., PR 46's short-convolution kernels)
+    # the five cells that hold a share re-pinned, PR 50 (this one,
+    # `joyai-8k`, `mellum2-16k`, `qwen3next-16k` and `sdar-8k` below):
+    # their sorted-row section sums a token's rows with the kernel of
+    # `ops/pallas/rows_to_tokens.py`, here through the interpreter, the
+    # sort by expert carries the pairs' weights and one sort by token
+    # stands in the op (parents: acccef44.., 46a623eb.., 7914857b..,
+    # 5a9ae15a.., 44ab655f..); `olmoe-4k`, which holds every expert,
+    # keeps its text.  (PR 48 before: QK-norm a head in the `rope` op)
     "lfm2-8k":
-    "acccef44be8bba8ada2cc1f04a56c7ccf272eed2c3c02106062aa3ad6924d41a",
+    "53baaa58347f736f1f3e4449c166389b7940cdac4e823f92d1bfbe9d01020e04",
     "joyai-8k":
-    "46a623eb6f4691120a58f68448449341b589b0b1f23fce5dd2eaca924a43b904",
+    "8dec48c80693b40a6ede0035f7ff2f7c4070bdd664975db9bbd7ab3caee346e2",
     # re-pinned, PR 39: the loop's segments keep (o, logsumexp), the
     # backward body holds no forward kernel (parent: 83ada587..)
     "ouro-4k":
@@ -309,11 +314,11 @@ STEP_TEXT = {
     # PR 46's SiLU short convolution, eeed81fb.., new in PR 47); every
     # other cell turns bare or over pairs and keeps its parent's text
     "mellum2-16k":
-    "7914857b0ab411c6d9c1d7dbfa7f4a7f88a103c8179cd76df5ed2d25e1622cfc",
+    "0fa427d2d79c67b2a37d069275e1beaa86e270099c3fb9456d46cca534239e07",
     "qwen3next-16k":
-    "5a9ae15aab5f7e765ce566b771900c50ef10f13d46733ff7c61373eb55ea5d9b",
+    "f591949a72f600695e2181346134a806a796406171dd2c60a3732885547c540a",
     "sdar-8k":
-    "44ab655f2aab54ebd3b73538a551e2ee79c62d560777bf61bfbbb5cdc8a4c57a",
+    "b012ad2dd91ad7ea22ec80bad7c11ced9f65f1b7f877c4a4d1ef9bdb7923f886",
 }
 
 

@@ -585,6 +585,100 @@ def test_the_row_buffer_counter_is_state_the_step_carries_on_the_device():
         assert not routing.row_buffer_counts(scope)[name].any()
 
 
+# the same on the kernel path: widths of whole 128-lane groups over
+# whole token tiles, where the section sums a token's rows out of the
+# buffer's R rows with `ops/pallas/rows_to_tokens.py` (interpret mode)
+# and builds no (T, k, D) array; 4 ranks of 4 of 16 experts, two row
+# buffer sizes each
+TK, DK, HK, EK, HELD_K = 256, 128, 128, 16, 4
+
+
+def wide_layer(seed, rows=None):
+    """A whole layer at the kernel's widths; with `rows`, steered the
+    way `steered` steers: exactly `rows` of the TK*K pairs go to rank
+    1's experts."""
+    r = R(seed)
+    f32 = np.float32
+    ins = {"X": r.normal(size=(TK, DK)).astype(f32),
+           "GateW": r.normal(size=(DK, EK)).astype(f32) * 0.05,
+           "Bias": r.normal(0, 0.1, size=(EK,)).astype(f32),
+           "W1": r.normal(size=(EK, DK, HK)).astype(f32) * 0.1,
+           "W3": r.normal(size=(EK, DK, HK)).astype(f32) * 0.1,
+           "W2": r.normal(size=(EK, HK, DK)).astype(f32) * 0.1}
+    if rows is not None:
+        picks = rows // TK + (np.arange(TK) < rows % TK)
+        ins["X"][:, :K] = np.where(np.arange(K) < picks[:, None], 1.0, -1.0)
+        ins["GateW"][:K] = 0
+        ins["GateW"][np.arange(K), HELD_K + np.arange(K)] = 3.0
+        ins["Bias"][:] = 0
+        ins["Bias"][HELD_K:2 * HELD_K] = ins["Bias"][-K:] = 5.0
+    return ins
+
+
+@pytest.mark.parametrize("routing, rows", [
+    ("sigmoid", None), ("softmax", None), ("sigmoid", 700), ("sigmoid", 0)],
+    ids=["sigmoid", "softmax", "second-buffer", "no-row"])
+def test_the_shares_add_up_on_the_kernel_path(routing, rows):
+    """Output and EVERY gradient, `GateW`'s included: the four ranks'
+    parts add up to the layer that holds all 16 experts (the op's own
+    all-experts path, which this kernel is no part of), each rank's
+    expert weights get the whole layer's gradient of those experts,
+    and every section traced took the kernel."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    sizes = moe_dropless.row_buffer_sizes(TK, K, EK, HELD_K)
+    assert sizes == (512, TK * K)
+    ins = {k: jnp.asarray(v) for k, v in wide_layer(21, rows).items()}
+    if routing == "softmax":
+        del ins["Bias"]
+    attrs = {"routing": routing, "norm_topk_prob": True, "top_k": K}
+    ct = jnp.asarray(R(22).normal(size=(TK, DK)).astype(np.float32))
+
+    def layer(held, fixed):
+        def f(*vals):
+            o = run(dict(fixed, **dict(zip(NAMES, vals)),
+                         **({} if held is None else {
+                             "RowBufferCount": jnp.zeros((3,), jnp.int32)})),
+                    dict(attrs, **({} if held is None
+                                   else {"experts_held": held})))
+            return jnp.sum(o["Out"][0] * ct), (
+                o["Out"][0], o.get("RowBufferCountOut", [None])[0])
+        (_, aux), grads = jax.jit(jax.value_and_grad(
+            f, argnums=range(5), has_aux=True))(*[fixed[k] for k in NAMES])
+        return aux + (dict(zip(NAMES, grads)),)
+
+    want_out, _, want = layer(None, ins)
+    # (a branch traced before at these shapes would not be traced, nor
+    # counted, again)
+    moe_dropless._branch.cache_clear()
+    before = runtime_stats.snapshot()
+    total = np.zeros((TK, DK), np.float64)
+    summed = {k: np.zeros(ins[k].shape, np.float64) for k in ("X", "GateW")}
+    taken = []
+    for rank in range(EK // HELD_K):
+        first = HELD_K * rank
+        out, slots, got = layer([first, HELD_K], {
+            k: jnp.asarray(v) for k, v in
+            share_of(ins, first, HELD_K).items()})
+        taken.append(int(np.argmax(slots)))
+        total += np.asarray(out)
+        for k in summed:
+            summed[k] += np.asarray(got[k], np.float64)
+        for k in ("W1", "W3", "W2"):
+            np.testing.assert_allclose(
+                got[k], want[k][first:first + HELD_K], rtol=2e-5, atol=2e-5,
+                err_msg=f"{k} of rank {rank}")
+    took = runtime_stats.delta(before)
+    assert took["share_rows_kernel"] > 0 and took["share_rows_xla"] == 0
+    if rows is not None:        # rank 1 got what the routing was forced to
+        assert taken[1] == int(rows > sizes[0])
+    np.testing.assert_allclose(total, want_out, rtol=2e-5, atol=2e-5)
+    for k, part in summed.items():
+        assert np.abs(np.asarray(want[k])).max() > 0, k
+        np.testing.assert_allclose(part, want[k], rtol=5e-5, atol=5e-5,
+                                   err_msg=k)
+
+
 def test_a_row_buffer_counter_without_a_share_is_an_error():
     with pytest.raises(ValueError, match="only a share chooses"):
         run(dict(whole_layer(), RowBufferCount=np.zeros(3, np.int32)),
