@@ -254,11 +254,11 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
         elif expert_bias_update_rate:
             raise ValueError("dropless_moe: expert_bias_update_rate "
                              "needs use_expert_bias")
-    elif (use_expert_bias or routed_scaling_factor != 1.0
-          or norm_topk_eps is not None):
-        raise ValueError("dropless_moe: the selection bias, the scaling "
-                         "factor and norm_topk_eps belong to "
-                         "routing='sigmoid'")
+    elif use_expert_bias or norm_topk_eps is not None:
+        raise ValueError("dropless_moe: the selection bias and "
+                         "norm_topk_eps belong to routing='sigmoid'")
+    elif routed_scaling_factor != 1.0:
+        attrs["routed_scaling_factor"] = float(routed_scaling_factor)
     eh.append_op(type="moe_dropless", inputs=ins, outputs=outs, attrs=attrs)
     out_v.desc.shape = tuple(input.shape)
     aux.desc.shape = z.desc.shape = (1,)

@@ -11,7 +11,8 @@ values are not built yet raises):
 - operators: causal attention with grouped-query heads and QK-norm
   (flash kernels at d_head 256, 128 and 64), over the whole prefix or
   under a sliding window, its RoPE unscaled or YaRN's, over the whole
-  head or its first lanes, by layer type, its context gated or not;
+  head or its first lanes, by layer type, its query head count the
+  model's or the layer's own, its context gated a lane, a head or not;
   the gated short convolution; the gated-delta-rule linear-attention
   mixer (a chunked scan, `ops/pallas/gated_delta.py`);
   latent attention (`kv_lora_rank` ...: queries, keys and values out
@@ -102,14 +103,35 @@ whose sequential part is a Pallas kernel at heads of 128 x 128.  Its
 ops lower under the `linear_attention` name scope.
 
 `partial_rotary_factor` f < 1: RoPE turns the first f x head_dim lanes
-of each head as a head of that size would, the rest pass through.
+of each head as a head of that size would, the rest pass through.  A
+`rope_parameters` group may carry its own (a layer type's share of the
+head: the whole head plainly in the window layers, half of it under
+YaRN in the full ones); scaled frequencies are then those of a head of
+f x head_dim lanes (`rope_frequencies(rotary_dim, ...)`: the
+correction range found with that dim, as transformers'
+`_compute_yarn_parameters` does).
+
+`num_attention_heads_per_layer` (one count a layer): layer l's q and o
+projections are `H_l * head_dim` wide and its attention runs `H_l`
+query heads over the SAME `num_key_value_heads` (query head j reads
+key/value head j // (H_l / Hkv); each `H_l` a multiple of it), so the
+shape of a layer's projections follows its type.  Built for the causal
+attention mixers only: beside latent or linear attention, a looped
+stack, a prediction module or the block-diffusion objective it raises.
+`mlp_layer_types` (one entry a layer) spells where the dense FFNs are:
+only leading "dense" entries before "sparse" ones are built (it sets
+`num_dense_layers`).
 Three equations more that no key spells, arguments named for the
 mechanism: `zero_centered_norm` (every norm of the decoder scales by
 1 + w, w from 0, so that decay pulls the scale to 1; the linear mixer's
 gated output norm keeps a plain scale from 1), `attention_gate`
 ("sigmoid": a second projection as wide as q, the context times its
 sigmoid before the out projection; the operator then lowers under the
-`gated_attention` name scope) and `shared_expert_gate` ("sigmoid": the
+`gated_attention` name scope; "head": a projection hidden -> the
+layer's heads, g = sigmoid(h W_g) from the layer's normed input, head
+j's whole context times g_j before the out projection, Qiu et al.,
+arXiv:2505.06708; under the name scope `attention_head_gate` inside the
+layer type's own) and `shared_expert_gate` ("sigmoid": the
 shared expert's result times sigmoid(h w), w (D, 1)).
 `shared_expert_intermediate_size` gives the shared expert's width where
 a configuration names it so (`n_shared_experts` x the routed width
@@ -271,7 +293,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             linear_conv_kernel_dim=None,
             shared_expert_intermediate_size=None, zero_centered_norm=False,
             attention_gate=None, shared_expert_gate=None,
-            objective="next_token", block_length=None):
+            objective="next_token", block_length=None,
+            num_attention_heads_per_layer=None, mlp_layer_types=None):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
     labels' own successors, with a prediction module); under
@@ -311,7 +334,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise NotImplementedError(f"exit_gate {exit_gate!r} is not built")
     if recompute not in (None, "layer"):
         raise NotImplementedError(f"recompute {recompute!r} is not built")
-    if attention_gate not in (None, "sigmoid"):
+    if attention_gate not in (None, "sigmoid", "head"):
         raise NotImplementedError(f"attention_gate {attention_gate!r} is "
                                   f"not built")
     if shared_expert_gate not in (None, "sigmoid"):
@@ -352,6 +375,19 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                 "under objective='block_diffusion' only full_attention "
                 "layers, straight, with an untied head are built; got "
                 + ", ".join(unbuilt))
+    if mlp_layer_types is not None:
+        # a config that spells each layer's FFN: the dense ones lead
+        dense = list(mlp_layer_types).count("dense")
+        if list(mlp_layer_types) != (["dense"] * dense + ["sparse"] * (
+                num_hidden_layers - dense)):
+            raise NotImplementedError(
+                f"mlp_layer_types {list(mlp_layer_types)}: only leading "
+                f"'dense' layers before 'sparse' ones, one entry a layer, "
+                f"are built")
+        if num_dense_layers not in (0, dense):
+            raise ValueError(f"num_dense_layers {num_dense_layers} beside "
+                             f"{dense} leading dense mlp_layer_types")
+        num_dense_layers = dense
     loops = total_ut_steps > 1 or exit_gate is not None
     if loops and exit_gate is None:
         raise ValueError("a stack run several times needs an exit_gate: "
@@ -395,17 +431,37 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                              "linear_num_key_heads")
     if head_dim is None:
         head_dim = hidden_size // num_attention_heads
-    rotary_dim = int(head_dim * partial_rotary_factor)
-    if rotary_dim % 2:
-        raise ValueError(f"partial_rotary_factor {partial_rotary_factor} of "
-                         f"a head of {head_dim} is no whole number of pairs")
-    q_size = num_attention_heads * head_dim
+    heads_of = list(num_attention_heads_per_layer
+                    or [num_attention_heads] * num_hidden_layers)
+    if len(heads_of) != num_hidden_layers:
+        raise ValueError(f"{len(heads_of)} num_attention_heads_per_layer "
+                         f"for {num_hidden_layers} layers")
+    if any(h < 1 or h % num_key_value_heads for h in heads_of):
+        raise ValueError(f"num_attention_heads_per_layer {heads_of}: a "
+                         f"layer's heads are not a multiple of "
+                         f"num_key_value_heads {num_key_value_heads}")
+    if num_attention_heads_per_layer is not None:
+        # q and o take the layer's width; the mixers and objectives
+        # whose head count means something else are not built with it
+        unbuilt = [what for what, asked in [
+            ("latent attention", kv_lora_rank is not None),
+            ("linear_attention layers", "linear_attention" in layer_types),
+            ("a looped stack", loops),
+            ("objective='block_diffusion'", diffusion),
+            ("a prediction module", num_nextn_predict_layers)] if asked]
+        if unbuilt:
+            raise NotImplementedError(
+                "num_attention_heads_per_layer beside "
+                + ", ".join(unbuilt) + " is not built")
     kv_size = num_key_value_heads * head_dim
-    # `rope_parameters`: one flat group, or one a layer type
-    by_kind = rope_parameters and all(
-        isinstance(v, dict) for v in rope_parameters.values())
+    # `rope_parameters`: one flat group, or one a layer type (beside
+    # which a config may repeat a number of its groups: not read)
+    attention_kinds = ("full_attention", "sliding_attention")
+    by_kind = rope_parameters and any(
+        isinstance(rope_parameters.get(kind), dict)
+        for kind in attention_kinds)
     rotary = {}
-    for kind in ("full_attention", "sliding_attention"):
+    for kind in attention_kinds:
         group = dict((rope_parameters.get(kind) if by_kind
                       else rope_parameters) or {})
         group.setdefault("rope_theta", rope_theta)
@@ -414,19 +470,19 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                 raise ValueError("decoder needs rope_theta or "
                                  "rope_parameters")
             continue
-        if group.get("rope_type", "default") == "default":
-            rotary[kind] = {"theta": group["rope_theta"]}
-            if rotary_dim != head_dim:
-                rotary[kind]["rotary_dim"] = rotary_dim
-        elif rotary_dim != head_dim:
-            raise NotImplementedError(
-                f"rope_type {group['rope_type']!r} over a part of the head "
-                f"is not built")
-        else:
-            inv_freq, factor = rope_frequencies(head_dim, **group)
-            rotary[kind] = {"theta": group["rope_theta"],
-                            "inv_freq": inv_freq,
-                            "attention_factor": factor}
+        # a layer type's own share of the head, or the model's
+        share = group.pop("partial_rotary_factor", partial_rotary_factor)
+        rotary_dim = int(head_dim * share)
+        if not 0.0 < share <= 1.0 or rotary_dim % 2:
+            raise ValueError(f"partial_rotary_factor {share} of a head of "
+                             f"{head_dim} is no whole number of pairs")
+        rotary[kind] = {"theta": group["rope_theta"]}
+        if rotary_dim != head_dim:
+            rotary[kind]["rotary_dim"] = rotary_dim
+        if group.get("rope_type", "default") != "default":
+            # scaled frequencies of a head of `rotary_dim` lanes
+            inv_freq, factor = rope_frequencies(rotary_dim, **group)
+            rotary[kind].update(inv_freq=inv_freq, attention_factor=factor)
     if eps is None or not rotary:
         raise ValueError("decoder needs rms_norm_eps or norm_eps, and "
                          "rope_theta or rope_parameters")
@@ -454,31 +510,39 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                                zero_centered=zero_centered_norm, **turn)
         return layers.rope(x if qk_norm is None else norm(x), n_head, **turn)
 
-    def attention(h, kind):
+    def attention(h, kind, heads=num_attention_heads):
         turn = rotary[kind]
+        q_size = heads * head_dim
         # a program that mixes kinds of layer tells their rows apart
-        scope = "gated_attention" if attention_gate else kind
+        scope = "gated_attention" if attention_gate == "sigmoid" else kind
         if diffusion:
             # both halves stand at positions 0 .. max_length - 1
             scope = "block_diffusion_attention"
             turn = dict(turn, period=max_length)
         with name_scope(scope) if windowed or attention_gate or diffusion \
                 else contextlib.nullcontext():
-            q = turn_qk(proj(h, q_size, "attn_qkv"), num_attention_heads,
-                        turn)
+            q = turn_qk(proj(h, q_size, "attn_qkv"), heads, turn)
             k = turn_qk(proj(h, kv_size, "attn_qkv"), num_key_value_heads,
                         turn)
             v = proj(h, kv_size, "attn_qkv")
             ctx = layers.flash_attention(
                 q, k, v, causal=not diffusion, use_pallas=True,
-                layout="nthd", n_head=num_attention_heads,
+                layout="nthd", n_head=heads,
                 n_kv_head=num_key_value_heads,
                 window=sliding_window if kind == "sliding_attention"
                 else None, block_diffusion=block_length)
-            if attention_gate:
+            if attention_gate == "sigmoid":
                 # a gate a lane of the context, from the layer's input
                 ctx = layers.elementwise_mul(ctx, layers.sigmoid(
                     proj(h, q_size, "attn_gate")))
+            elif attention_gate == "head":
+                # one gate a HEAD: its lanes all take sigmoid(h w_head)
+                with name_scope("attention_head_gate"):
+                    gate = layers.sigmoid(proj(h, heads, "attn_gate"))
+                    ctx = layers.reshape(layers.elementwise_mul(
+                        layers.reshape(ctx, [0, 0, heads, head_dim]),
+                        layers.unsqueeze(gate, axes=[3])), [0, 0, q_size])
+                runtime_stats.record_attention_head_gate()
             return proj(ctx, hidden_size, "attn_out")
 
     def linear_attention(h):
@@ -574,13 +638,15 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         mixers["full_attention"] = latent_attention
         del mixers["sliding_attention"]
 
-    def block(x, kind, dense):
+    def block(x, kind, dense, heads=num_attention_heads):
         op = mixers.get(kind)
         if op is None:
             raise NotImplementedError(
                 f"layer type {kind!r} is not built"
                 + (" beside latent attention" if kind == "sliding_attention"
                    else ""))
+        if heads != num_attention_heads and kind in attention_kinds:
+            op = functools.partial(op, heads=heads)
         post = norm if sandwich_norm else (lambda y: y)
         x = layers.elementwise_add(x, post(op(norm(x))))
         ffn = dense_ffn if dense else routed_ffn
@@ -593,7 +659,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     def stack(x):
         for i, kind in enumerate(layer_types):
             with segment():
-                x = block(x, kind, dense=i < num_dense_layers)
+                x = block(x, kind, dense=i < num_dense_layers,
+                          heads=heads_of[i])
         return x
 
     def head(x):
@@ -735,7 +802,11 @@ def build_model(max_length, learning_rate=4e-4, beta1=0.9, beta2=0.95,
     OLMoE paper's settings (`mtp_loss_weight` DeepSeek-V3's first);
     `architecture` holds the configuration's own keys and, where its
     equations need them, `qk_norm` / `router` / `norm_topk_eps` /
-    `sandwich_norm` / `exit_gate`; a recipe's `exit_entropy_weight` and
+    `sandwich_norm` / `exit_gate` / `attention_gate` ("sigmoid" a lane,
+    "head" a head) / `shared_expert_gate` / `zero_centered_norm`; the
+    keys that size a layer by its type, `num_attention_heads_per_layer`,
+    `mlp_layer_types` and a `rope_parameters` group's own
+    `partial_rotary_factor`; a recipe's `exit_entropy_weight` and
     `recompute` travel with them."""
     model = decoder(max_length=max_length, **architecture)
     ce, aux, z = model["ce"], model["aux"], model["z"]

@@ -70,6 +70,9 @@ _FIELDS = Heard._fields[1:] + (
     "flash_gqa_backward_fused", "flash_gqa_backward_split",
     "flash_attention_backward_fused", "flash_attention_backward_split",
     "flash_window_blocks_visited", "flash_window_blocks_allowed",
+    "flash_window_calls", "flash_grouped_calls",
+    "flash_window_pairs_allowed", "flash_window_entries_computed",
+    "attention_head_gate_calls",
     "flash_block_diffusion_calls", "flash_block_diffusion_blocks_visited",
     "flash_block_diffusion_blocks_allowed",
     "gated_delta_calls", "gated_delta_chunks",
@@ -156,6 +159,23 @@ class RuntimeStats:
         # times apart where skipping is lost (delta() around a build)
         self.flash_window_blocks_visited = 0
         self.flash_window_blocks_allowed = 0
+        # forward band calls traced, by geometry: under a window
+        # (`flash_window_fwd`), and over the whole causal prefix with
+        # grouped key/value heads (`flash_fwd` on the band's grid); a
+        # step that fell back to the XLA mask reads 0.  And what the
+        # blocks' ratio cannot see, a tile that is mostly masked: the
+        # score pairs ONE head's mask allows under the window and the
+        # score entries its forward grid's visited tiles compute
+        # (block_q x block_k each), summed over the window calls
+        # traced; their ratio is the tiles' fill (25% at 1024 x 1024
+        # tiles under a window of 512, 50% at 512 x 512)
+        self.flash_window_calls = 0
+        self.flash_grouped_calls = 0
+        self.flash_window_pairs_allowed = 0
+        self.flash_window_entries_computed = 0
+        # per-head output gates `models/decoder.py` built
+        # (`attention_gate="head"`), one a layer, at program build time
+        self.attention_head_gate_calls = 0
         # the same pair for the forward kernel under the block-diffusion
         # mask (`flash_attention.py _DiffusionBand`: tiles a head's grid
         # computes, and tiles that hold an allowed pair), and the calls
@@ -278,6 +298,22 @@ class RuntimeStats:
         with self._lock:
             self.flash_window_blocks_visited += visited
             self.flash_window_blocks_allowed += allowed
+
+    def record_flash_window_call(self, pairs: int, entries: int):
+        """One traced forward call under a window: a head's allowed
+        pairs and the score entries its visited tiles compute."""
+        with self._lock:
+            self.flash_window_calls += 1
+            self.flash_window_pairs_allowed += pairs
+            self.flash_window_entries_computed += entries
+
+    def record_flash_grouped_call(self):
+        with self._lock:
+            self.flash_grouped_calls += 1
+
+    def record_attention_head_gate(self):
+        with self._lock:
+            self.attention_head_gate_calls += 1
 
     def record_flash_block_diffusion(self, visited: int, allowed: int):
         with self._lock:

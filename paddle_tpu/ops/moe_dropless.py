@@ -356,14 +356,16 @@ def _switched_bwd(sizes, res, g):
 _switched.defvjp(_switched_fwd, _switched_bwd)
 
 
-def route(logits, top_k, norm_topk_prob=False):
+def route(logits, top_k, norm_topk_prob=False, scaling=1.0):
     """Router probabilities, the chosen experts and their weights from
     float32 `logits` (T, E): (probs (T, E), weights (T, k), experts
-    (T, k) int32)."""
+    (T, k) int32); the weights times `scaling`."""
     probs = jax.nn.softmax(logits, axis=-1)
     weights, experts = jax.lax.top_k(probs, top_k)
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scaling != 1.0:
+        weights = weights * scaling
     return probs, weights, experts.astype(jnp.int32)
 
 
@@ -431,12 +433,12 @@ def moe_dropless(ctx, ins, attrs):
     logits = jnp.dot(xf, gate_w, preferred_element_type=jnp.float32)
     norm = bool(attrs.get("norm_topk_prob", False))
     routing = attrs.get("routing", "softmax")
+    scaling = float(attrs.get("routed_scaling_factor", 1.0))
     if routing == "softmax":
-        probs, weights, experts = route(logits, k, norm)
+        probs, weights, experts = route(logits, k, norm, scaling)
     elif routing == "sigmoid":
         probs, weights, experts = route_sigmoid(
-            logits, k, opt_in(ins, "Bias"), norm,
-            float(attrs.get("routed_scaling_factor", 1.0)),
+            logits, k, opt_in(ins, "Bias"), norm, scaling,
             float(attrs.get("norm_topk_eps", SIGMOID_NORM_EPS)))
     else:
         raise ValueError(f"moe_dropless: routing {routing!r} is neither "

@@ -124,6 +124,12 @@ DEFAULT_BAND_BLOCK_Q = 1024
 DEFAULT_BAND_BLOCK_K = 1024
 DEFAULT_WINDOW_BWD_BLOCK_Q = 512
 DEFAULT_WINDOW_BWD_BLOCK_K = 512
+# the smallest forward tile a narrow window is given
+# (`_window_fwd_blocks`): under 512 keys at 64 / 8 heads of 128, 16384
+# positions, alone on v5e (PERF.md, PR 51), 512 x 512 takes 8.68 ms a
+# call forward against 9.47 at 1024 x 1024 and 14.69 at 256 x 256 (a
+# grid step costs ~0.9 us beside ~1.2 us of work a 512 x 512 tile)
+MIN_WINDOW_FWD_BLOCK = 256
 # The block-diffusion mask's square tiles, forward and backward, tuned
 # on v5e at 2 x 8192 rows, 32 / 4 heads of 128, blocks of 4, alone
 # (PERF.md, PR 47): 1024 x 1024 takes 11.2 ms forward and 33.7 forward +
@@ -500,6 +506,10 @@ def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
         # placeholder
         band.record_blocks()
         declared = band.cost_estimate("fwd", nh, d, q.dtype.itemsize, group)
+    elif band is not None and group > 1:
+        from ...observe.monitoring import runtime_stats
+
+        runtime_stats.record_flash_grouped_call()
     o, lse8 = _pallas_call(
         kern,
         name=(band.prefix if band is not None else "flash_") + "fwd",
@@ -1051,6 +1061,9 @@ class _Band:
 
         runtime_stats.record_flash_window_blocks(
             self.nq * self.k_steps, self.blocks_allowed)
+        runtime_stats.record_flash_window_call(
+            self.pairs(),
+            self.blocks_allowed * self.block_q * self.block_k)
 
     # A grid's last axis counts the blocks of the band: the key block
     # of a query block's `step` (`key_at`) and whether the pair holds a
@@ -1564,11 +1577,28 @@ def _flash_vjp_bwd(scale, causal, blocks, bwd_blocks, layout, n_head,
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _window_fwd_blocks(window):
+    """The forward tile under a window of `window` keys: square, its
+    side the largest power of two the window holds, no smaller than
+    `MIN_WINDOW_FWD_BLOCK` and no larger than the band's own
+    `DEFAULT_BAND_BLOCK_*`.  A query tile of side b meets the window's
+    W - 1 earlier keys and its own b, so a tile larger than the window
+    is mostly mask: 1024 x 1024 under 512 keys computes four score
+    entries for each pair the band allows, 512 x 512 two; at W = 1024
+    and above the tile stays 1024 x 1024."""
+    side = max(MIN_WINDOW_FWD_BLOCK, 1 << (int(window).bit_length() - 1))
+    return (min(side, DEFAULT_BAND_BLOCK_Q), min(side, DEFAULT_BAND_BLOCK_K))
+
+
 def _band_blocks(t, block_q, block_k, window):
     """(forward blocks, backward blocks) of a band call: a block size
     given holds for both passes; left out, the band kernels take their
-    own (`DEFAULT_BAND_BLOCK_*`, `DEFAULT_WINDOW_BWD_BLOCK_*`)."""
-    own = ((DEFAULT_BAND_BLOCK_Q, DEFAULT_BAND_BLOCK_K),
+    own (`DEFAULT_BAND_BLOCK_*`, `DEFAULT_WINDOW_BWD_BLOCK_*`), the
+    forward tile of a call with a window following the window
+    (`_window_fwd_blocks`)."""
+    fwd = _window_fwd_blocks(window) if window else \
+        (DEFAULT_BAND_BLOCK_Q, DEFAULT_BAND_BLOCK_K)
+    own = (fwd,
            (DEFAULT_WINDOW_BWD_BLOCK_Q, DEFAULT_WINDOW_BWD_BLOCK_K)
            if window else (DEFAULT_BWD_BLOCK_Q, DEFAULT_BWD_BLOCK_K))
     return tuple(
@@ -1659,6 +1689,16 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
     geometries: the causal prefix (grouped heads), the causal prefix
     under a window, and the block-diffusion mask; each refuses a bias,
     offsets, a returned logsumexp, cross lengths and a ragged block.
+    Their tiles (`_band_blocks`; a `block_q` / `block_k` given holds
+    for both passes): forward 1024 x 1024 and backward 1024 x 1024 over
+    the whole prefix; under a window the backward 512 x 512 and the
+    forward tile FOLLOWS THE WINDOW, from the shape alone
+    (`_window_fwd_blocks`): square, the largest power of two the window
+    holds, no smaller than 256 and no larger than 1024, so 512 keys run
+    512 x 512 tiles (half of what they compute is allowed, a quarter at
+    1024 x 1024) and 1024 keys and above keep 1024 x 1024.  Any group
+    size runs (query head j reads key/value head j // group: 8 over 4,
+    8 over 2, 6 and 8 over 8 are data to the grids).
 
     q_offset/k_offset: optional GLOBAL position offsets (python ints or
     traced scalars) applied in causal masking — ring attention passes the

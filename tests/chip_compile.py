@@ -32,17 +32,18 @@ from paddle_tpu.ops.pallas import force_mosaic_lowering
 
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
 
-# the sorted-row buffers of the four cells with routed experts: tokens,
+# the sorted-row buffers of the five cells with routed experts: tokens,
 # experts a token, experts, experts held (None: all), D, H
 EXPERT_CELLS = {
     "mellum2-16k": (16384, 8, 64, 8, 2304, 896),
     "lfm2-8k": (8192, 4, 64, 8, 2048, 1536),
     "joyai-8k": (8192, 8, 256, 8, 2048, 768),
     "olmoe-4k": (4 * 4096, 8, 64, None, 2048, 1024),
+    "laguna-16k": (16384, 8, 256, 32, 2048, 512),
 }
 
 
-# the first row buffer of the five cells that hold a share (the sorted
+# the first row buffer of the six cells that hold a share (the sorted
 # rows a section runs on nearly always), tokens, experts a token, width
 SHARE_CELLS = {
     "mellum2-16k": (24576, 16384, 8, 2304),
@@ -50,6 +51,7 @@ SHARE_CELLS = {
     "qwen3next-16k": (7680, 16384, 10, 2048),
     "lfm2-8k": (6144, 8192, 4, 2048),
     "joyai-8k": (3072, 8192, 8, 2048),
+    "laguna-16k": (24576, 16384, 8, 2048),
 }
 
 

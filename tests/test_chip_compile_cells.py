@@ -546,3 +546,84 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
         ("rms_norm", "f32[4,768,2048]"),
         ("moe_dropless", "f32[16,768,2048]"),
         ("adam", "f32[16,768,2048]")}
+
+
+def test_the_head_count_a_layer_cells_step_and_the_plan_its_share_rule_read(
+        one_chip):
+    """The whole training step of `laguna-16k` as `benchmarks/run.py`
+    builds it (5 layers at the published widths, 16384 rows, bf16 AMP,
+    every layer a recompute segment), compiled for the described chip,
+    nothing run.  The share rule of the configuration (ISSUE 51: 32 of
+    256 experts held, one chip of 8, if the step's plan is 15.0 GB or
+    less, else 16 of one chip of 16) read THIS plan: arguments (aliased
+    to the outputs) 8.30 GB + temporaries 6.18 GB = 14.48 GB; at 16
+    held 5.88 + 5.86 = 11.75 GB (PERF.md, PR 51).  A window layer is ONE
+    `flash_window_fwd` and one `flash_window_dkv` at 64 / 8 heads, a
+    full layer one `flash_fwd` and one `flash_dkv` at 48 / 8: the
+    segments keep the forward's residuals."""
+    import collections
+    import os
+    import re
+    import sys
+
+    import numpy as np
+
+    import paddle_tpu as fluid
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import run as bench_run
+
+    cell, config, family = bench_run.load_cell("laguna-16k", (bench,))
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    scope = fluid.Scope()
+    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
+            fluid.unique_name.guard():
+        loss = family.build(config)
+        for var in main.global_block().vars.values():
+            if var.persistable and all(int(s) > 0 for s in var.shape):
+                scope.set_var(var.name, jax.ShapeDtypeStruct(
+                    tuple(int(s) for s in var.shape),
+                    np.dtype(str(var.dtype))))
+        batch = family.make_batch(config, cell, np.random.default_rng(0))
+        before = runtime_stats.snapshot()
+        step, state, feeds = fluid.Executor()._prepare(
+            main, batch, [loss.name], scope, 1, True)
+
+        def described(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+        compiled = _compile_args(step, jax.tree.map(described, state),
+                                 jax.tree.map(described, feeds))
+        took = runtime_stats.delta(before)
+    assert sum(int(np.prod(p.shape)) for p in main.all_parameters()) \
+        == 691625216
+    plan = compiled.memory_analysis()
+    total = (plan.argument_size_in_bytes + plan.temp_size_in_bytes) / 1e9
+    assert plan.argument_size_in_bytes / 1e9 == pytest.approx(8.30, abs=0.01)
+    assert 13.5 < total <= 15.0, total          # the rule's side: 32 held
+    calls = " ".join(ln for ln in compiled.as_text().splitlines()
+                     if "tpu_custom_call" in ln)
+    kernels = collections.Counter(re.findall(r"pallas_(\w+?)/", calls))
+    assert (kernels["flash_window_fwd"], kernels["flash_window_dkv"]) == (3, 3)
+    assert (kernels["flash_fwd"], kernels["flash_dkv"]) == (2, 2)
+    # q and k of five layers: normed and turned forward and recomputed,
+    # one backward kernel each; the full layers' turn half the head
+    assert (kernels["rope_fwd"], kernels["rope_bwd"]) == (20, 10)
+    assert kernels["rows_to_tokens"] == 24          # four sparse layers
+    assert set(kernels) == {"flash_window_fwd", "flash_window_dkv",
+                            "flash_fwd", "flash_dkv", "ragged_dot",
+                            "rope_fwd", "rope_bwd", "rows_to_tokens"}
+    # no fall-back anywhere: by the step's trace
+    assert (took["flash_window_calls"], took["flash_grouped_calls"]) == (6, 4)
+    assert 2 * took["flash_window_pairs_allowed"] == pytest.approx(
+        took["flash_window_entries_computed"], rel=1e-3)
+    assert (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"]) == (5, 0)
+    assert took["recompute_kept_residuals"] == 5
+    assert (took["ropes_kernel"], took["ropes_xla"]) == (10, 0)
+    assert (took["share_rows_kernel"], took["share_rows_xla"]) == (9, 0)

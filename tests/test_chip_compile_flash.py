@@ -319,6 +319,12 @@ STEP_TEXT = {
     "f591949a72f600695e2181346134a806a796406171dd2c60a3732885547c540a",
     "sdar-8k":
     "b012ad2dd91ad7ea22ec80bad7c11ced9f65f1b7f877c4a4d1ef9bdb7923f886",
+    # new in PR 51 (a head count a layer type, the head gate, YaRN over
+    # half a head, 512 x 512 forward tiles under 512 keys); every other
+    # cell keeps its parent's text: `mellum2-16k`'s window of 1024 keeps
+    # its 1024 x 1024 forward tile
+    "laguna-16k":
+    "717664188564e58e142a8c724ac3ddf0fa662d129d19618cfb93535919a837c7",
 }
 
 

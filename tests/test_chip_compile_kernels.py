@@ -331,6 +331,57 @@ def test_grouped_flash_at_head_dim_256_takes_the_two_backward_kernels(
         assert f"pallas_{kernel}" in text
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads, window", [(64, 512), (48, None)],
+                         ids=["64h_window512", "48h_full"])
+def test_band_kernels_at_a_head_count_a_layer_type(one_chip, dtype, heads,
+                                                   window):
+    """`laguna-16k`'s two geometries, 1 x 16384 at d_head 128 over 8
+    key/value heads: 64 query heads (groups of 8) under a window of 512
+    keys, whose forward tile follows the window (512 x 512: 63 tiles a
+    head hold an allowed pair), and 48 query heads (groups of SIX) over
+    the whole prefix at 1024 x 1024.  One forward and ONE backward
+    kernel each (24 MiB of dq, dk, dv in VMEM), bfloat16 as the cell
+    runs them and float32 as `benchmarks/laguna_parity.py` does."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    n, t, hkv, d = 1, 16384, 8, 128
+    assert fa.band_backward_fits(t, d)
+    assert fa._band_blocks(t, None, None, window)[0] == (
+        (512, 512) if window else (1024, 1024))
+
+    def loss(q, k, v):
+        return jnp.sum(fa.pallas_flash_attention(
+            q, k, v, causal=True, layout="nthd", n_head=heads,
+            n_kv_head=hkv, window=window).astype(F32))
+
+    before = runtime_stats.snapshot()
+    args = [jax.ShapeDtypeStruct((n, t, h * d), dtype, sharding=one_chip)
+            for h in (heads, hkv, hkv)]
+    prec = "default" if dtype == BF16 else "highest"
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile().as_text()
+    took = runtime_stats.delta(before)
+    assert (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"]) == (1, 0)
+    assert _kernels(text) == 2
+    prefix = "flash_window_" if window else "flash_"
+    for kernel in ("fwd", "dkv"):
+        assert f"pallas_{prefix}{kernel}" in text
+    # dk, dv leave 8 heads wide, never the query heads' width
+    assert f"[{n},{t},{hkv * d}]" in text
+    if window:
+        assert (took["flash_window_calls"], took["flash_grouped_calls"]) \
+            == (1, 0)
+        assert took["flash_window_pairs_allowed"] == 8257792
+        assert took["flash_window_entries_computed"] == 63 * 512 * 512
+    else:
+        assert (took["flash_window_calls"], took["flash_grouped_calls"]) \
+            == (0, 1)
+
+
 @pytest.mark.parametrize("rows, dtype", [(16384, BF16), (16384, F32),
                                          (32768, BF16)],
                          ids=["sdar_8k-bf16", "sdar_8k-f32", "past_budget"])

@@ -670,10 +670,12 @@ def test_the_ungated_convolution_is_the_gated_ones_convolution():
     (dict(partial_rotary_factor=1.5), ValueError, "partial_rotary_factor"),
     (dict(partial_rotary_factor=0.25, head_dim=20), ValueError,
      "whole number of pairs"),
-    (dict(rope_parameters={"rope_type": "yarn", "rope_theta": 100.0,
+    # (YaRN over a part of the head is built since PR 51; a scaling
+    # `ops/decoder.py rope_frequencies` does not know still raises)
+    (dict(rope_parameters={"rope_type": "llama3", "rope_theta": 100.0,
                            "factor": 4.0,
                            "original_max_position_embeddings": 16}),
-     NotImplementedError, "part of the head"),
+     NotImplementedError, "llama3"),
     (dict(linear_conv_kernel_dim=None), ValueError, "linear_conv_kernel_dim"),
     (dict(linear_num_value_heads=3), ValueError, "multiple"),
     (dict(layer_types=["linear_attention"] * 3 + ["state_space"]),
@@ -682,7 +684,7 @@ def test_the_ungated_convolution_is_the_gated_ones_convolution():
           qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
           num_key_value_heads=2), NotImplementedError, "latent"),
 ], ids=["attention_gate", "shared_expert_gate", "shared-width-twice",
-        "rotary-0", "rotary-over-1", "rotary-odd", "rotary-part-yarn",
+        "rotary-0", "rotary-over-1", "rotary-odd", "rotary-part-llama3",
         "linear-keys-missing", "linear-heads", "layer-type", "gate-on-latent"])
 def test_unbuilt_values_of_the_new_keys_raise(over, error, match):
     cfg = config(**over)

@@ -1,0 +1,110 @@
+"""Time the band kernels of `ops/pallas/flash_attention.py` alone, on the
+chip, over a sweep of tiles.
+
+    chiprun -- python tools/time_flash_window.py [--rows 16384]
+        [--heads 64] [--kv-heads 8] [--window 512]
+        [--tiles 1024,512,256] [--bwd-tiles 512,256]
+
+One call of 1 x `--rows` positions at `--heads` query heads of 128 over
+`--kv-heads` key/value heads, bfloat16: under `--window` keys
+(`flash_window_fwd` / `_dkv`; 0 = the whole causal prefix, `flash_fwd` /
+`flash_dkv`).  The forward alone at each square tile of `--tiles`, with
+the tiles' fill (pairs the band allows over the score entries the
+visited tiles compute) and the call as `_band_blocks` chooses it
+(`chosen`); forward + backward (a VJP against a fixed cotangent) at
+the chosen forward tile and each backward tile of `--bwd-tiles`.
+Milliseconds a call: `--repeats` calls dispatched back to back and
+waited for once; the median of five such rounds after a warm-up.  The
+last stdout line is one JSON object; the same line goes to
+`chiprun_out/time_flash_window.log`.  It exits non-zero off a TPU: a CPU
+time is no device time.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+
+D = 128
+
+
+def ms_a_call(fn, args, repeats):
+    jax.block_until_ready(fn(*args))
+    rounds = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        outs = [fn(*args) for _ in range(repeats)]
+        jax.block_until_ready(outs)
+        rounds.append(1e3 * (time.perf_counter() - t0) / repeats)
+    return float(np.median(rounds))
+
+
+def main():
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--rows", type=int, default=16384)
+    parser.add_argument("--heads", type=int, default=64)
+    parser.add_argument("--kv-heads", type=int, default=8)
+    parser.add_argument("--window", type=int, default=512)
+    parser.add_argument("--tiles", default="1024,512,256")
+    parser.add_argument("--bwd-tiles", default="512,256")
+    parser.add_argument("--repeats", type=int, default=10)
+    parser.add_argument("--seed", type=int, default=0)
+    args = parser.parse_args()
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(json.dumps({"error": f"{device.platform} is no TPU"}))
+        return 1
+    t, h, hkv = args.rows, args.heads, args.kv_heads
+    window = args.window or None
+    r = np.random.default_rng(args.seed)
+
+    def draw(heads):
+        return jnp.asarray(r.normal(size=(1, t, heads * D)), jnp.bfloat16)
+
+    q, k, v, ct = draw(h), draw(hkv), draw(hkv), draw(h)
+    scale = D ** -0.5
+    chosen = fa._band_blocks(t, None, None, window)
+    out = {"device": device.device_kind, "rows": t, "heads": h,
+           "kv_heads": hkv, "window": window, "chosen": chosen,
+           "pairs_a_head": fa._Band(t, *chosen[0], window).pairs(),
+           "forward": {}, "forward_backward": {}}
+
+    def call(fwd, bwd):
+        return jax.jit(lambda q, k, v: fa._flash_band(
+            q, k, v, scale, fwd, bwd, h, h // hkv, window))
+
+    def vjp(fwd, bwd):
+        fn = call(fwd, bwd)
+        return jax.jit(lambda q, k, v, ct: jax.vjp(fn, q, k, v)[1](ct))
+
+    for tile in [int(x) for x in args.tiles.split(",")]:
+        band = fa._Band(t, tile, tile, window)
+        out["forward"][str(tile)] = {
+            "ms": ms_a_call(call((tile, tile), chosen[1]), (q, k, v),
+                            args.repeats),
+            "fill": band.pairs() / (band.blocks_allowed * tile * tile)}
+    bwd_tiles = [int(x) for x in args.bwd_tiles.split(",")] if window else []
+    for tile in dict.fromkeys(min(x, t) for x in bwd_tiles + [1024]):
+        out["forward_backward"][f"{chosen[0][0]}/{tile}"] = ms_a_call(
+            vjp(chosen[0], (tile, tile)), (q, k, v, ct), args.repeats)
+    line = json.dumps(out)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/time_flash_window.log", "a") as f:
+        f.write(line + "\n")
+    print(line)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
