@@ -244,9 +244,10 @@ def _run_checkpointed_segment(seg_ops, env, rng_key, start_index,
     the segment reads enter as EXPLICIT arguments (closed-over tracers
     would be saved as residuals, defeating the remat); names it writes
     that someone downstream reads (`keep`; None = all) merge back into
-    env.  The backward pass keeps the segment's inputs and what an
-    attention kernel names (ops/pallas `keep_residuals`: its output and
-    logsumexp), and recomputes everything else."""
+    env.  The backward pass keeps the segment's inputs and what is
+    named (ops/pallas `keep_residuals`: an attention kernel's output
+    and logsumexp, the delta rule's inverses), and recomputes
+    everything else."""
     import jax
 
     from ..ops.pallas import segment_policy, tracing_segment

@@ -585,11 +585,11 @@ def recompute_scope(main_program: Optional[Program] = None):
     RECOMPUTED during the backward instead of stored — the TPU way to
     trade FLOPs for HBM on deep stacks.  (The 1.2 reference predates
     RecomputeOptimizer; on TPU this is a one-liner around XLA's remat.)
-    A segment keeps its inputs and what an attention kernel names
-    (ops/pallas `keep_residuals`: the Pallas flash kernels' output and
-    logsumexp, whose recomputation would cost the square of the length
-    for bytes that grow with the length); everything else is
-    recomputed.
+    A segment keeps its inputs and what is named (ops/pallas
+    `keep_residuals`: the Pallas flash kernels' output and logsumexp,
+    whose recomputation would cost the square of the length for bytes
+    that grow with the length, and the chunked delta rule's inverses);
+    everything else is recomputed.
 
         with fluid.recompute_scope():
             x = encoder_layer(x, ...)

@@ -77,6 +77,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_block_diffusion_blocks_allowed",
     "gated_delta_calls", "gated_delta_chunks",
     "gated_delta_operand_calls", "gated_delta_operand_chunks",
+    "gated_delta_inverse_calls",
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "short_convs_kernel", "short_convs_xla",
@@ -196,13 +197,21 @@ class RuntimeStats:
         # `chunk_operands` ran
         self.gated_delta_operand_calls = 0
         self.gated_delta_operand_chunks = 0
-        # attention calls traced inside a recompute segment, whose
-        # backward pass therefore keeps the kernel's two residuals and
-        # does not run its forward kernel again, and the bytes of those
-        # residuals (the output in the operands' dtype + 8 float32
-        # sublanes of logsumexp a head) from their shapes (delta()
+        # calls traced of the kernel that solves for the chunks'
+        # (I + A)^-1 (`gated_delta_inverse`, before the custom VJP of
+        # the chunk-operand kernels: traced where the layer is, once,
+        # however often the kernels that read it are)
+        self.gated_delta_inverse_calls = 0
+        # calls traced inside a recompute segment that name what the
+        # segment keeps (`ops/pallas keep_residuals`): an attention
+        # call, whose backward pass therefore keeps the kernel's two
+        # residuals and does not run its forward kernel again, or the
+        # delta rule's inverses, which the recomputed chunk-operand
+        # kernel reads; and the bytes kept, from the shapes (the output
+        # in the operands' dtype + 8 float32 sublanes of logsumexp a
+        # head; (I + A)^-1 in float32, two heads a tile) (delta()
         # around a build; a loop's body counts once, as traced; 0
-        # where no segment holds a flash call)
+        # where no segment holds such a call)
         self.recompute_kept_residuals = 0
         self.recompute_kept_bytes = 0
         # grouped matmuls of the dropless expert op traced
@@ -330,6 +339,10 @@ class RuntimeStats:
         with self._lock:
             self.gated_delta_operand_calls += 1
             self.gated_delta_operand_chunks += chunks
+
+    def record_gated_delta_inverse(self):
+        with self._lock:
+            self.gated_delta_inverse_calls += 1
 
     def record_kept_residuals(self, nbytes: int):
         with self._lock:

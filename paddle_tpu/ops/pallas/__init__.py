@@ -39,18 +39,24 @@ def interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# What a recompute segment keeps besides its inputs: the two residuals
-# an attention kernel's forward rule hands its backward kernel, the
-# output and the logsumexp.  Attention is the one op of a layer whose
-# cost to recompute grows with the square of the length (or length x
-# window) while what it must keep grows with the length, so a segment
-# never runs a flash forward kernel a second time only to rebuild them.
-# ONE mechanism in two places: the forward rule of every attention
-# family names the pair through `keep_residuals`, and the executor's
-# `jax.checkpoint` saves exactly these names (`segment_policy`).  Names
-# without that policy are inert (a `name` equation lowers to nothing);
-# a family that does not name its residuals is recomputed.
-KEPT_RESIDUALS = ("attention_out", "attention_logsumexp")
+# What a recompute segment keeps besides its inputs: the residuals whose
+# cost to recompute is far above what they take to keep, by name.  The
+# two an attention kernel's forward rule hands its backward kernel, the
+# output and the logsumexp (recomputing them grows with the square of
+# the length, or length x window, keeping them with the length), so a
+# segment never runs a flash forward kernel a second time only to
+# rebuild them; and the chunked delta rule's (I + A)^-1 (`gated_delta.py
+# chunk_inverses`: a chain of dependent substitution steps a chunk, 6.6
+# ms a layer at 16384 positions for 134 MB), so a segment's backward pass
+# runs the chunk-operand forward kernel that READS the inverse and not
+# the one that solves for it.  ONE mechanism in two places: whoever
+# makes such a residual names it through `keep_residuals`, and the
+# executor's `jax.checkpoint` saves exactly these names
+# (`segment_policy`).  Names without that policy are inert (a `name`
+# equation lowers to nothing); a residual nobody names is recomputed.
+ATTENTION_RESIDUALS = ("attention_out", "attention_logsumexp")
+INVERSE_RESIDUAL = ("gated_delta_inverse",)
+KEPT_RESIDUALS = ATTENTION_RESIDUALS + INVERSE_RESIDUAL
 _open_segments = [0]
 
 
@@ -76,21 +82,24 @@ def tracing_segment():
         _open_segments[0] -= 1
 
 
-def keep_residuals(o, lse):
-    """Name an attention kernel's output and logsumexp where its
-    forward rule returns them as residuals; gives both back.  Inside a
-    segment's trace the call counts (`runtime_stats.
-    recompute_kept_residuals` / `_bytes`: a loop's body once, as
-    traced)."""
+def keep_residuals(*values, names=ATTENTION_RESIDUALS):
+    """Name what a recompute segment is to keep, where it is made (an
+    attention kernel's output and logsumexp where its forward rule
+    returns them as residuals, unless `names` says otherwise); gives
+    the values back.  Inside a segment's trace the call counts
+    (`runtime_stats.recompute_kept_residuals` / `_bytes`: a loop's body
+    once, as traced)."""
     from jax.ad_checkpoint import checkpoint_name
 
+    if len(values) != len(names) or not set(names) <= set(KEPT_RESIDUALS):
+        raise ValueError(f"keep_residuals: {len(values)} values for the "
+                         f"names {names} of {KEPT_RESIDUALS}")
     if _open_segments[0]:
         from ...observe.monitoring import runtime_stats
 
         runtime_stats.record_kept_residuals(
-            sum(x.size * x.dtype.itemsize for x in (o, lse)))
-    return tuple(checkpoint_name(x, name)
-                 for x, name in zip((o, lse), KEPT_RESIDUALS))
+            sum(x.size * x.dtype.itemsize for x in values))
+    return tuple(checkpoint_name(x, name) for x, name in zip(values, names))
 
 
 # kernel-name -> cost function registry (observe/cost.py injection

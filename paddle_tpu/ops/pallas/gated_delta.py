@@ -23,7 +23,7 @@ for i >= j (no exponent is ever positive):
     O  = (Q * exp(gamma)) S + lower_incl(Q K^T * G) V'
     S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
-Two parts, four kernels and one reference lowering of each part.
+Two parts, five kernels and one reference lowering of each part.
 
 **The chunk-local part**: everything that does not read S is a batch
 over all the chunks: the cumulative decay, the two (C, C) products a
@@ -34,35 +34,51 @@ what runs where `operand_kernels_take` is false.  There every (C, C)
 float32 matrix of a chunk is a tensor in HBM that the TPU's tiling pads
 to 128 lanes, (I + A)^-1 is XLA's batched triangular solve
 (`unit_lower_inverse`, with a VJP of its own, dA = -M^T dM M^T), and
-`chunk_decay` has a VJP of its own too.  `gated_delta_operands_fwd` /
-`gated_delta_operands_bwd` (since PR 45) are the same arithmetic with a
-chunk's matrices in VMEM: grid (batch x KEY head, blocks of chunks); a
-step holds a key head's q and k rows and the TWO value heads that read
-it, their two (C, C) matrices side by side in the 128 lanes of one
-float32 tile; q, k, v are read from the op's (N, T, H x 128) layout by
-lane block, K K^T and Q K^T are one MXU product a key head (the second
-head's copy is the MXU's, a product against [k; k]), and what leaves is
-what the scan kernels read: W, U, Qg, Kd, P in the operands' dtype.  The
-(C,)-vectors of a chunk (gamma, what is left of the chunk after a
-position, beta, g) come as one (8, 128) float32 tile a chunk and key
-head, which XLA makes (`_row_tiles`: a cumulative sum and a suffix sum
-over 2 MB, whose gradients XLA has), and exp(gamma_C) stays XLA's too.
-(I + A)^-1 is float32 forward substitution in blocks
-(`_inverse_side_by_side`): the diagonal blocks of `DIAGONAL_BLOCK` rows
-row by row on the VPU, all of them and both heads at once, then the
-blocks doubled by two MXU products a doubling at "highest".  The
-backward kernel is a `custom_vjp` around the part: the forward kernel
-also writes (I + A)^-1 (float32, two heads a tile: 134 MB a layer at
-16384 positions x 32 heads; the forward rule keeps it, alive inside a
-recompute segment's backward alone, and a call that is not
-differentiated drops it), the backward kernel rebuilds G, K K^T and
-Q K^T from q, k and the row tile, and returns dq and dk summed over
-the two value heads in VMEM, dv, and the row tile's gradient.  It keeps the two rules the XLA
-lowering has: dA = -M^T dM M^T, and the decay matrix's gradient summed
-over the pairs that straddle a position (it lands on the tile's `g`
-row, which the forward does not read; differentiating gamma_i - gamma_j
-term by term leaves their float32 cancellation in a decay parameter's
-gradient).
+`chunk_decay` has a VJP of its own too.  The kernels (since PR 45) are
+the same arithmetic with a chunk's matrices in VMEM: grid (batch x KEY
+head, blocks of chunks); a step holds a key head's q and k rows and the
+TWO value heads that read it, their two (C, C) matrices side by side in
+the 128 lanes of one float32 tile; q, k, v are read from the op's
+(N, T, H x 128) layout by lane block, K K^T and Q K^T are one MXU
+product a key head (the second head's copy is the MXU's, a product
+against [k; k]).  The (C,)-vectors of a chunk (gamma, what is left of
+the chunk after a position, beta, g) come as one (8, 128) float32 tile
+a chunk and key head, which XLA makes (`_row_tiles`: a cumulative sum
+and a suffix sum over 2 MB, whose gradients XLA has), and exp(gamma_C)
+stays XLA's too.  Three kernels (two before PR 52, whose forward kernel
+solved and multiplied in one):
+
+* `gated_delta_inverse` reads k and the row tile, builds G, K K^T and A
+  and writes (I + A)^-1 (float32, two heads a tile: 134 MB a layer at
+  16384 positions x 32 heads).  Float32 forward substitution in blocks
+  (`_inverse_side_by_side`): the diagonal blocks of `DIAGONAL_BLOCK`
+  rows row by row on the VPU, all of them and both heads at once, then
+  the blocks doubled by two MXU products a doubling at "highest".  A
+  chain of dependent steps a chunk: 7.3 ms a layer alone at the cell's
+  shape, of which nothing but the chain is 6.2, and 6.6 in the cell's
+  step (my chip runs, PR 52).
+* `gated_delta_operands_fwd` reads q, k, v, the row tile AND the
+  inverse, rebuilds G and Q K^T and writes what the scan kernels read:
+  W, U, Qg, Kd, P in the operands' dtype.  No substitution: 3.4 ms
+  alone, 2.0 in the step.
+* `gated_delta_operands_bwd` reads the same and the five cotangents,
+  rebuilds G, K K^T and Q K^T, and returns dq and dk summed over the
+  two value heads in VMEM, dv, and the row tile's gradient.  It keeps
+  the two rules the XLA lowering has: dA = -M^T dM M^T, and the decay
+  matrix's gradient summed over the pairs that straddle a position (it
+  lands on the tile's `g` row, which the forward does not read;
+  differentiating gamma_i - gamma_j term by term leaves their float32
+  cancellation in a decay parameter's gradient).
+
+The inverse is made BEFORE the `custom_vjp` that holds the other two
+(`chunk_inverses`, on k and the row tile as constants: the backward
+kernel's dk and row-tile gradient hold what flows through it), and is
+named as it is made (`ops/pallas keep_residuals`): a recompute segment
+keeps it, so the segment's backward pass runs `gated_delta_operands_fwd`
+on the kept inverse and solves nothing a second time (in the step a
+layer's recomputed forward is 2.0 ms for the 7.3 the one fused kernel
+took, its forward pass 8.6 for 7.3); outside a segment the name is
+inert.
 
 **The sequential part**: what reads S is sequential over the chunks:
 grid (batch x value head, blocks of `DEFAULT_BLOCK_CHUNKS` chunks), the
@@ -84,8 +100,11 @@ Which runs is the shape's alone: the scan kernels take Dk = Dv = 128
 heads a key head (`operand_kernels_take`); any other head size and the
 CPU presets run the XLA lowerings.  `runtime_stats.gated_delta_calls`
 / `_chunks` count the scan kernels' calls traced and their chunks x
-heads, `gated_delta_operand_calls` / `_operand_chunks` the
-chunk-operand kernels': a part that fell back reads 0.  The benchmark
+heads, `gated_delta_operand_calls` / `_operand_chunks` the calls of
+`gated_delta_operands_fwd` / `_bwd`, `gated_delta_inverse_calls` those
+of `gated_delta_inverse` (traced where the layer is, once: a segment's
+forward + backward counts 1 and 3): a part that fell back reads 0.  The
+benchmark
 finds the scan kernels by the PREFIXES `gated_delta_fwd` /
 `gated_delta_bwd`: no other kernel's name may start with either.
 """
@@ -113,7 +132,8 @@ def kernel_takes(dk, dv):
 # -- kernel cost registry (observe/cost.py) ----------------------------
 #
 # What the sequential part computes once, per chunk and head (the
-# chunk-local part's kernels say theirs at `operands_*_cost`): forward
+# chunk-local part's kernels say theirs at `inverse_cost` and
+# `operands_*_cost`): forward
 # W S, Q S, P V', K^T V' (three of 2 C Dk Dv and one of 2 C C Dv);
 # backward the forward's V' again is NOT credited, its eight products
 # are (dV' two, dP, dQ, dK, dW, dS two: six of 2 C Dk Dv, two of
@@ -141,13 +161,20 @@ def _operand_dims(operand_shapes):
     return n * (qw // HEAD_DIM), n * (vw // HEAD_DIM), t
 
 
+def inverse_cost(operand_shapes, result_shapes):
+    """K K^T a key head and the substitution's C^3 / 3 multiply-adds a
+    value head (k is the first operand; two value heads a key head)."""
+    (n, t, kw), _ = operand_shapes[0]
+    bk = n * (kw // HEAD_DIM)
+    return t * (bk * 2.0 * CHUNK * HEAD_DIM
+                + 2 * bk * 2.0 * CHUNK * CHUNK / 3), None
+
+
 def operands_fwd_cost(operand_shapes, result_shapes):
-    """K K^T and Q K^T a key head, W and U a value head, and the
-    substitution's C^3 / 3 multiply-adds a value head."""
+    """Q K^T a key head, W and U a value head."""
     bk, bh, t = _operand_dims(operand_shapes)
-    return t * (bk * 2 * 2.0 * CHUNK * HEAD_DIM
-                + bh * (2 * 2.0 * CHUNK * HEAD_DIM
-                        + 2.0 * CHUNK * CHUNK / 3)), None
+    return t * (bk * 2.0 * CHUNK * HEAD_DIM
+                + bh * 2 * 2.0 * CHUNK * HEAD_DIM), None
 
 
 def operands_bwd_cost(operand_shapes, result_shapes):
@@ -165,6 +192,7 @@ def _register_costs():
 
     register_kernel_cost("gated_delta_fwd", scan_fwd_cost)
     register_kernel_cost("gated_delta_bwd", scan_bwd_cost)
+    register_kernel_cost("gated_delta_inverse", inverse_cost)
     register_kernel_cost("gated_delta_operands_fwd", operands_fwd_cost)
     register_kernel_cost("gated_delta_operands_bwd", operands_bwd_cost)
 
@@ -406,10 +434,10 @@ def _inverse_side_by_side(a, iotas, block=None):
     return m
 
 
-def _chunk_matrices(q, k, x8, iotas):
-    """What forward and backward both build of a chunk: the columns of
-    the row tile, the decay matrix, K K^T and Q K^T (each product twice
-    side by side: the MXU repeats it for the second head), [k; k]."""
+def _tile_columns(x8, iotas):
+    """What every chunk-operand kernel builds of a chunk's row tile:
+    its columns (gamma, what is left after a position, beta: a pair of
+    (C, 1) a head each) and the decay matrix, side by side."""
     row, col, left = iotas
     xt = x8.T                                       # (2C, 8)
     columns = [(xt[:CHUNK, i:i + 1], xt[CHUNK:, i:i + 1])
@@ -417,9 +445,13 @@ def _chunk_matrices(q, k, x8, iotas):
     gamma_i = jnp.where(left, *columns[0])
     decay = jnp.where(row >= col,
                       jnp.exp(gamma_i - x8[ROW_GAMMA:ROW_GAMMA + 1]), 0.0)
-    kk2 = jnp.concatenate([k, k], axis=0)
-    return columns, decay, _dot(k, kk2, ((1,), (1,))), \
-        _dot(q, kk2, ((1,), (1,))), kk2
+    return columns, decay
+
+
+def _twice(k):
+    """[k; k]: a product against it is x K^T twice side by side (the
+    MXU repeats a key head's product for its second value head)."""
+    return jnp.concatenate([k, k], axis=0)
 
 
 def _for_each_chunk(block_chunks, chunk):
@@ -442,22 +474,33 @@ def _chunk_rows(c):
     return pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
 
 
-def _operands_fwd_kernel(q_ref, k_ref, v_ref, x_ref, w_ref, u_ref, qg_ref,
-                         kd_ref, p_ref, m_ref, *, block_chunks):
-    f32 = jnp.float32
+def _inverse_kernel(k_ref, x_ref, m_ref, *, block_chunks):
     iotas = _tile_iotas()
     row, col, left = iotas
 
     def chunk(c):
         r = _chunk_rows(c)
+        k = k_ref[0, r, :]
+        (_, _, beta), decay = _tile_columns(x_ref[0, c], iotas)
+        kk = _dot(k, _twice(k), ((1,), (1,)))
+        a = jnp.where(row > col, jnp.where(left, *beta) * kk * decay, 0.0)
+        m_ref[0, r, :] = _inverse_side_by_side(a, iotas)
+
+    _for_each_chunk(block_chunks, chunk)
+
+
+def _operands_fwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, w_ref, u_ref,
+                         qg_ref, kd_ref, p_ref, *, block_chunks):
+    f32 = jnp.float32
+    iotas = _tile_iotas()
+
+    def chunk(c):
+        r = _chunk_rows(c)
         q, k, x8 = q_ref[0, r, :], k_ref[0, r, :], x_ref[0, c]
         dt = k.dtype
-        (gamma, rest, beta), decay, kk, qk, _ = _chunk_matrices(
-            q, k, x8, iotas)
-        a = jnp.where(row > col, jnp.where(left, *beta) * kk * decay, 0.0)
-        m = _inverse_side_by_side(a, iotas)
-        m_ref[0, r, :] = m
-        solve = (m * x8[ROW_BETA:ROW_BETA + 1]).astype(dt)
+        (gamma, rest, _), decay = _tile_columns(x8, iotas)
+        qk = _dot(q, _twice(k), ((1,), (1,)))
+        solve = (m_ref[0, r, :] * x8[ROW_BETA:ROW_BETA + 1]).astype(dt)
         p = (qk * decay).astype(dt)
         kf, qf = k.astype(f32), q.astype(f32)
         for h in range(2):
@@ -506,8 +549,9 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
         q, k, x8, m = (q_ref[0, r, :], k_ref[0, r, :], x_ref[0, c],
                        m_ref[0, r, :])
         dt = k.dtype
-        (gamma, rest, beta), decay, kk, qk, kk2 = _chunk_matrices(
-            q, k, x8, iotas)
+        (gamma, rest, beta), decay = _tile_columns(x8, iotas)
+        kk2 = _twice(k)
+        kk, qk = _dot(k, kk2, ((1,), (1,))), _dot(q, kk2, ((1,), (1,)))
         beta_j = x8[ROW_BETA:ROW_BETA + 1]
         solve = (m * beta_j).astype(dt)
         kf, qf = k.astype(f32), q.astype(f32)
@@ -582,11 +626,25 @@ def _operand_specs(hk, bc):
 
 
 @functools.partial(jax.jit, static_argnames=("interpreted",))
-def _operands_fwd_call(q, k, v, x, interpreted=False):
-    """W, U, Qg, Kd, P and (I + A)^-1 (float32, two heads a tile: what
-    the backward kernel is handed; a call that is not differentiated
-    drops it: 134 MB written at the cell's shape, 0.16 ms, for one
-    kernel to trace, lower and compile, not two)."""
+def _inverse_call(k, x, interpreted=False):
+    """(I + A)^-1 of every chunk (float32, two heads a tile: 134 MB a
+    layer at the cell's shape), from k and the row tiles alone."""
+    n, t, width = k.shape
+    hk, nc = width // HEAD_DIM, t // CHUNK
+    bc = _block_chunks(nc)
+    narrow, _, tile, inverse, _, _ = _operand_specs(hk, bc)
+    return _pallas_call(
+        functools.partial(_inverse_kernel, block_chunks=bc),
+        name="gated_delta_inverse", grid=(n * hk, nc // bc),
+        in_specs=[narrow, tile], out_specs=inverse,
+        out_shape=jax.ShapeDtypeStruct((n * hk, t, 2 * CHUNK), jnp.float32),
+        compiler_params=_params(),
+    )(k, x)
+
+
+@functools.partial(jax.jit, static_argnames=("interpreted",))
+def _operands_fwd_call(q, k, v, x, m, interpreted=False):
+    """W, U, Qg, Kd, P of every chunk, given its (I + A)^-1."""
     n, t, width = k.shape
     hk, nc = width // HEAD_DIM, t // CHUNK
     bc = _block_chunks(nc)
@@ -596,12 +654,11 @@ def _operands_fwd_call(q, k, v, x, interpreted=False):
     return _pallas_call(
         functools.partial(_operands_fwd_kernel, block_chunks=bc),
         name="gated_delta_operands_fwd", grid=(n * hk, nc // bc),
-        in_specs=[narrow, narrow, pair, tile],
-        out_specs=[wide, wide, wide, wide, square, inverse],
-        out_shape=[flat(HEAD_DIM)] * 4 + [flat(CHUNK), jax.ShapeDtypeStruct(
-            (n * hk, t, 2 * CHUNK), jnp.float32)],
+        in_specs=[narrow, narrow, pair, tile, inverse],
+        out_specs=[wide, wide, wide, wide, square],
+        out_shape=[flat(HEAD_DIM)] * 4 + [flat(CHUNK)],
         compiler_params=_params(),
-    )(q, k, v, x)
+    )(q, k, v, x, m)
 
 
 @functools.partial(jax.jit, static_argnames=("interpreted",))
@@ -638,25 +695,45 @@ def _record_operands(k):
     return interpret()
 
 
+def chunk_inverses(k, x):
+    """(I + A)^-1 of every chunk by `gated_delta_inverse`: k (N, T,
+    Hk x 128) and the row tiles -> (N Hk, T, 2C) float32, NAMED: a
+    recompute segment keeps it (`ops/pallas keep_residuals`), so the
+    segment's backward pass reads it and runs no substitution again.
+    A constant of differentiation here: `operands_kernel`'s backward
+    kernel holds the inverse's own rule (dA = -M^T dM M^T) and returns
+    what flows through it with dk and the row tile's gradient."""
+    from ...observe.monitoring import runtime_stats
+    from . import INVERSE_RESIDUAL, interpret, keep_residuals
+
+    runtime_stats.record_gated_delta_inverse()
+    m, = keep_residuals(
+        _inverse_call(jax.lax.stop_gradient(k), jax.lax.stop_gradient(x),
+                      interpreted=interpret()),
+        names=INVERSE_RESIDUAL)
+    return m
+
+
 @jax.custom_vjp
-def operands_kernel(q, k, v, x):
+def operands_kernel(q, k, v, x, m):
     """`chunk_operands` less exp(gamma_C) by the Pallas kernels.  q, k
     (N, T, Hk x 128), v (N, T, 2 Hk x 128), x the row tiles
-    (`_row_tiles`)."""
-    return _operands_vjp_fwd(q, k, v, x)[0]
+    (`_row_tiles`), m `chunk_inverses(k, x)`."""
+    return _operands_vjp_fwd(q, k, v, x, m)[0]
 
 
-def _operands_vjp_fwd(q, k, v, x):
-    *operands, m = _operands_fwd_call(q, k, v, x,
-                                      interpreted=_record_operands(k))
+def _operands_vjp_fwd(q, k, v, x, m):
+    operands = _operands_fwd_call(q, k, v, x, m,
+                                  interpreted=_record_operands(k))
     return tuple(operands), (q, k, v, x, m)
 
 
 def _operands_vjp_bwd(res, cts):
     q, k, v, x, m = res
+    # m's own cotangent is none: its part is in dk and dx (above)
     return tuple(_operands_bwd_call(
         q, k, v, x, m, *(c.astype(v.dtype) for c in cts),
-        interpreted=_record_operands(k)))
+        interpreted=_record_operands(k))) + (jnp.zeros_like(m),)
 
 
 operands_kernel.defvjp(_operands_vjp_fwd, _operands_vjp_bwd)
@@ -696,8 +773,10 @@ def chunk_operands_kernel(q, k, v, g, beta):
     hv = v.shape[2]
     x, last = _row_tiles(g.astype(jnp.float32), beta.astype(jnp.float32),
                          hk)
-    return operands_kernel(q.reshape(n, t, hk * dk), k.reshape(n, t, hk * dk),
-                           v.reshape(n, t, hv * v.shape[3]), x) + (last,)
+    k = k.reshape(n, t, hk * dk)
+    return operands_kernel(q.reshape(n, t, hk * dk), k,
+                           v.reshape(n, t, hv * v.shape[3]), x,
+                           chunk_inverses(k, x)) + (last,)
 
 
 # -- the sequential part, as XLA runs it -------------------------------

@@ -1,8 +1,9 @@
 """The chip's compiler on what a cell's step hands it beside the attention
 kernels, at the published widths: `olmoe-4k`, `lfm2-8k`, the looped step
 of `ouro-4k`, `ops/pallas/grouped_matmul.py` at the four expert cells'
-shapes, and the whole step of `sdar-8k` with the plan its depth rule
-read (tests/chip_compile.py says why and how).
+shapes, and the whole steps of `sdar-8k` and `laguna-16k` with the
+plans their cut rules read and of `qwen3next-16k` with the inverses its
+linear layers' segments keep (tests/chip_compile.py says why and how).
 """
 
 from __future__ import annotations
@@ -442,17 +443,13 @@ def test_rows_to_tokens_kernel_at_the_share_cells_shapes(one_chip, cell):
         assert "scatter" not in order.as_text()
 
 
-def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
-        one_chip):
-    """The whole training step of `sdar-8k` as `benchmarks/run.py`
-    builds it (6 layers at the published widths, 16384 rows, bf16 AMP,
-    every layer a recompute segment), compiled for the described chip,
-    nothing run.  The depth rule of the configuration (ISSUE 47: 6
-    layers if the step's plan is 15.0 GB or less, else 4) read THIS
-    plan: arguments (aliased to the outputs) 7.75 GB + temporaries 6.06
-    GB = 13.81 GB; at 4 layers 5.48 + 5.41 = 10.89 GB (PERF.md, PR 47).
-    A layer is ONE forward and one backward flash kernel under the
-    block-diffusion mask: the segment keeps the forward's residuals."""
+def _cell_step(cell_name, one_chip):
+    """The whole training step of a cell as `benchmarks/run.py` builds
+    it (the published widths, the cell's rows, bf16 AMP, every layer a
+    recompute segment), compiled for the described chip, nothing run:
+    (the Program's parameter count, the compiled step, its plan in GB
+    (`arguments`, aliased to the outputs, `temporaries`, `total`), its
+    Mosaic calls by kernel name, the counters around its trace)."""
     import collections
     import os
     import re
@@ -461,6 +458,7 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
     import numpy as np
 
     import paddle_tpu as fluid
+    from paddle_tpu.observe.monitoring import runtime_stats
 
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks")
@@ -468,7 +466,7 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
         sys.path.insert(0, bench)
     import run as bench_run
 
-    cell, config, family = bench_run.load_cell("sdar-8k", (bench,))
+    cell, config, family = bench_run.load_cell(cell_name, (bench,))
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 7
     scope = fluid.Scope()
@@ -481,6 +479,7 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
                     tuple(int(s) for s in var.shape),
                     np.dtype(str(var.dtype))))
         batch = family.make_batch(config, cell, np.random.default_rng(0))
+        before = runtime_stats.snapshot()
         step, state, feeds = fluid.Executor()._prepare(
             main, batch, [loss.name], scope, 1, True)
 
@@ -489,15 +488,33 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
 
         compiled = _compile_args(step, jax.tree.map(described, state),
                                  jax.tree.map(described, feeds))
-    assert sum(int(np.prod(p.shape)) for p in main.all_parameters()) \
-        == 645623296
-    plan = compiled.memory_analysis()
-    total = (plan.argument_size_in_bytes + plan.temp_size_in_bytes) / 1e9
-    assert plan.argument_size_in_bytes / 1e9 == pytest.approx(7.75, abs=0.01)
-    assert 12.5 < total <= 15.0, total              # the rule's side: 6
+        took = runtime_stats.delta(before)
+    memory = compiled.memory_analysis()
+    plan = {"arguments": memory.argument_size_in_bytes / 1e9,
+            "temporaries": memory.temp_size_in_bytes / 1e9}
+    plan["total"] = plan["arguments"] + plan["temporaries"]
     calls = " ".join(ln for ln in compiled.as_text().splitlines()
                      if "tpu_custom_call" in ln)
-    kernels = collections.Counter(re.findall(r"pallas_(\w+?)/", calls))
+    return (sum(int(np.prod(p.shape)) for p in main.all_parameters()),
+            compiled, plan,
+            collections.Counter(re.findall(r"pallas_(\w+?)/", calls)), took)
+
+
+def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
+        one_chip):
+    """The whole training step of `sdar-8k` as `benchmarks/run.py`
+    builds it (6 layers at the published widths, 16384 rows, bf16 AMP,
+    every layer a recompute segment), compiled for the described chip,
+    nothing run.  The depth rule of the configuration (ISSUE 47: 6
+    layers if the step's plan is 15.0 GB or less, else 4) read THIS
+    plan: arguments (aliased to the outputs) 7.75 GB + temporaries 6.06
+    GB = 13.81 GB; at 4 layers 5.48 + 5.41 = 10.89 GB (PERF.md, PR 47).
+    A layer is ONE forward and one backward flash kernel under the
+    block-diffusion mask: the segment keeps the forward's residuals."""
+    parameters, compiled, plan, kernels, _ = _cell_step("sdar-8k", one_chip)
+    assert parameters == 645623296
+    assert plan["arguments"] == pytest.approx(7.75, abs=0.01)
+    assert 12.5 < plan["total"] <= 15.0, plan       # the rule's side: 6
     assert kernels["flash_block_diffusion_fwd"] == 6
     assert kernels["flash_block_diffusion_dkv"] == 6
     # q and k of six layers: normed and turned forward and recomputed,
@@ -561,54 +578,10 @@ def test_the_head_count_a_layer_cells_step_and_the_plan_its_share_rule_read(
     `flash_window_fwd` and one `flash_window_dkv` at 64 / 8 heads, a
     full layer one `flash_fwd` and one `flash_dkv` at 48 / 8: the
     segments keep the forward's residuals."""
-    import collections
-    import os
-    import re
-    import sys
-
-    import numpy as np
-
-    import paddle_tpu as fluid
-    from paddle_tpu.observe.monitoring import runtime_stats
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks")
-    if bench not in sys.path:
-        sys.path.insert(0, bench)
-    import run as bench_run
-
-    cell, config, family = bench_run.load_cell("laguna-16k", (bench,))
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 7
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        loss = family.build(config)
-        for var in main.global_block().vars.values():
-            if var.persistable and all(int(s) > 0 for s in var.shape):
-                scope.set_var(var.name, jax.ShapeDtypeStruct(
-                    tuple(int(s) for s in var.shape),
-                    np.dtype(str(var.dtype))))
-        batch = family.make_batch(config, cell, np.random.default_rng(0))
-        before = runtime_stats.snapshot()
-        step, state, feeds = fluid.Executor()._prepare(
-            main, batch, [loss.name], scope, 1, True)
-
-        def described(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
-        compiled = _compile_args(step, jax.tree.map(described, state),
-                                 jax.tree.map(described, feeds))
-        took = runtime_stats.delta(before)
-    assert sum(int(np.prod(p.shape)) for p in main.all_parameters()) \
-        == 691625216
-    plan = compiled.memory_analysis()
-    total = (plan.argument_size_in_bytes + plan.temp_size_in_bytes) / 1e9
-    assert plan.argument_size_in_bytes / 1e9 == pytest.approx(8.30, abs=0.01)
-    assert 13.5 < total <= 15.0, total          # the rule's side: 32 held
-    calls = " ".join(ln for ln in compiled.as_text().splitlines()
-                     if "tpu_custom_call" in ln)
-    kernels = collections.Counter(re.findall(r"pallas_(\w+?)/", calls))
+    parameters, _, plan, kernels, took = _cell_step("laguna-16k", one_chip)
+    assert parameters == 691625216
+    assert plan["arguments"] == pytest.approx(8.30, abs=0.01)
+    assert 13.5 < plan["total"] <= 15.0, plan   # the rule's side: 32 held
     assert (kernels["flash_window_fwd"], kernels["flash_window_dkv"]) == (3, 3)
     assert (kernels["flash_fwd"], kernels["flash_dkv"]) == (2, 2)
     # q and k of five layers: normed and turned forward and recomputed,
@@ -627,3 +600,29 @@ def test_the_head_count_a_layer_cells_step_and_the_plan_its_share_rule_read(
     assert took["recompute_kept_residuals"] == 5
     assert (took["ropes_kernel"], took["ropes_xla"]) == (10, 0)
     assert (took["share_rows_kernel"], took["share_rows_xla"]) == (9, 0)
+
+
+def test_the_linear_attention_cells_step_keeps_its_inverses_under_the_plan(
+        one_chip):
+    """The whole training step of `qwen3next-16k` (one period of four
+    layers: three linear, one full; 16384 rows), compiled for the
+    described chip, nothing run.  A linear layer's segment keeps
+    (I + A)^-1 (PR 52: 134,217,728 bytes a layer, float32, two heads a
+    tile), so the step holds `gated_delta_inverse` THREE times, once a
+    layer, and `gated_delta_operands_fwd` six (the forward pass's and
+    the recomputed one that reads the kept inverse); the plan with the
+    three inverses alive from forward to backward stays under the 15.0
+    GB the cells' cut rules use (12.37 GB before, PERF.md, PR 50)."""
+    _, _, plan, kernels, took = _cell_step("qwen3next-16k", one_chip)
+    assert 12.0 < plan["total"] <= 15.0, plan
+    assert (kernels["gated_delta_inverse"],
+            kernels["gated_delta_operands_fwd"],
+            kernels["gated_delta_operands_bwd"]) == (3, 6, 3)
+    assert (kernels["gated_delta_fwd"], kernels["gated_delta_bwd"]) == (6, 3)
+    assert (kernels["flash_fwd"], kernels["flash_dkv"]) == (1, 1)
+    assert (took["gated_delta_inverse_calls"],
+            took["gated_delta_operand_calls"],
+            took["gated_delta_operand_chunks"]) == (3, 9, 9 * 256 * 32)
+    # the full layer's (o, logsumexp) and three inverses
+    assert took["recompute_kept_residuals"] == 4
+    assert took["recompute_kept_bytes"] >= 3 * 134217728

@@ -315,8 +315,12 @@ STEP_TEXT = {
     # other cell turns bare or over pairs and keeps its parent's text
     "mellum2-16k":
     "0fa427d2d79c67b2a37d069275e1beaa86e270099c3fb9456d46cca534239e07",
+    # re-pinned, PR 52: (I + A)^-1 is `gated_delta_inverse`'s, named for
+    # the layers' segments to keep, and `gated_delta_operands_fwd` reads
+    # it (parent: f591949a..); no other cell builds the op, and a name
+    # in `KEPT_RESIDUALS` that a step never emits leaves its text alone
     "qwen3next-16k":
-    "f591949a72f600695e2181346134a806a796406171dd2c60a3732885547c540a",
+    "cdf57c01cb7a443cfc0ebfa8bc26ae3a52cc3580af53e2e332f26438db851337",
     "sdar-8k":
     "b012ad2dd91ad7ea22ec80bad7c11ced9f65f1b7f877c4a4d1ef9bdb7923f886",
     # new in PR 51 (a head count a layer type, the head gate, YaRN over

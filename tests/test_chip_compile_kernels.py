@@ -86,10 +86,11 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     """What `qwen3next-16k`'s step hands the chip's compiler that no
     other cell does, first half: the `gated_delta_rule` op at 1 x 16384
     positions, 16 key and 32 value heads of 128, in the cell's bfloat16
-    and in the parity script's float32 at "highest".  Four Mosaic
+    and in the parity script's float32 at "highest".  Five Mosaic
     kernels under a gradient: the chunk-local part's
-    `gated_delta_operands_fwd` (which also writes (I + A)^-1, two heads
-    a float32 tile) and `gated_delta_operands_bwd`, each a grid of 16
+    `gated_delta_inverse` (which writes (I + A)^-1, two heads a float32
+    tile: what a recompute segment keeps), `gated_delta_operands_fwd`
+    (which reads it) and `gated_delta_operands_bwd`, each a grid of 16
     key heads x 32 blocks of 8 chunks; the forward rule's
     `gated_delta_fwd` (which also writes the 256 chunk-entry states a
     head) and `gated_delta_bwd`, each a grid of 32 heads x 32 blocks
@@ -124,17 +125,18 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     for kind in ("gated_delta", "gated_delta_operand"):
         assert (took[f"{kind}_calls"], took[f"{kind}_chunks"]) == (
             2, 2 * 256 * 32), kind
+    assert took["gated_delta_inverse_calls"] == 1
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
-        "gated_delta_bwd", "gated_delta_fwd", "gated_delta_operands_bwd",
-        "gated_delta_operands_fwd"]
+        "gated_delta_bwd", "gated_delta_fwd", "gated_delta_inverse",
+        "gated_delta_operands_bwd", "gated_delta_operands_fwd"]
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "gated_delta_rule"}
     totals = cost.total_costs(proto)
-    assert totals["custom_calls"] == totals["pallas_matched"] == 4
-    # no scan reader may take the new kernels for scan kernels: they
-    # match by prefix (`benchmarks/kernel_counts.py kernel_ms_per_step`)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 5
+    # no scan reader may take the chunk-local kernels for scan kernels:
+    # they match by prefix (`benchmarks/kernel_counts.py kernel_ms_per_step`)
     scan = [r for r in rows if (r["kernel"] or "").startswith(
         ("gated_delta_fwd", "gated_delta_bwd"))]
     chunk = (3 + 6) * 2 * 64 * d * d + (1 + 2) * 2 * 64 * 64 * d

@@ -242,6 +242,16 @@ def test_a_step_counts_three_kernel_calls_a_linear_layer_and_a_build_none():
                  b[f"{kind}_chunks"] - a[f"{kind}_chunks"])
                 for a, b in zip(marks, marks[1:])]
         assert took == [(0, 0), (3, 3 * 2 * 2), (0, 0)], kind
+    # the inverse kernel is traced with the layer, once, and the layer's
+    # segment keeps what it wrote, (1 key head, 128, 2 x 64) float32,
+    # as the full layer's keeps its flash call's output (128 x 2 heads
+    # of 32, bfloat16) and logsumexp (8 float32 sublanes a head)
+    attention = 128 * 64 * 2 + 2 * 8 * 128 * 4
+    for name, step in (("gated_delta_inverse_calls", 1),
+                       ("recompute_kept_residuals", 2),
+                       ("recompute_kept_bytes", 128 * 128 * 4 + attention)):
+        assert [b[name] - a[name] for a, b in zip(marks, marks[1:])] == [
+            0, step, 0], name
 
 
 # -- (b) the chunked scan against the sequential recurrence -----------------
@@ -315,6 +325,10 @@ def test_the_chunked_scan_is_the_sequential_recurrence(lowering, t, decay):
     for kind in ("gated_delta", "gated_delta_operand"):
         assert (took[f"{kind}_calls"], took[f"{kind}_chunks"]) == (
             (3, 3 * chunks) if lowering == "kernel" else (0, 0)), kind
+    # the inverse: the forward call's and the differentiated call's; no
+    # segment is open, so nothing counts as kept
+    assert took["gated_delta_inverse_calls"] == 2 * (lowering == "kernel")
+    assert took["recompute_kept_residuals"] == 0
 
 
 def test_the_scan_in_bfloat16_misses_the_float32_tolerance():
@@ -370,6 +384,103 @@ def test_the_operand_kernels_are_chunk_operands(case):
         assert scale > 0, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
                                    atol=TOL * scale, err_msg=name)
+
+
+def _flat_operands(q, k, v, g, beta):
+    """What `chunk_operands_kernel` hands its kernels: q, k, v by lane
+    block and the row tiles."""
+    n, t, hk, d = k.shape
+    x, _ = gated_delta._row_tiles(g, beta, hk)
+    return (q.reshape(n, t, hk * d), k.reshape(n, t, hk * d),
+            v.reshape(n, t, -1), x)
+
+
+@pytest.mark.parametrize("case", sorted(OPERAND_CASES))
+def test_the_inverse_kernel_is_every_chunks_inverse(case):
+    """`gated_delta_inverse` (interpret mode) against numpy's float64
+    inverse of I + strict_lower(diag(beta) (K K^T * G)), two heads side
+    by side a key head; and the forward kernel GIVEN that inverse
+    returns what the op's forward returns (it solves nothing)."""
+    t, kind = OPERAND_CASES[case]
+    args = scan_case(t, seed=3, **kind)
+    q, k, v, g, beta = (np.asarray(x, np.float64) for x in args)
+    hk, hv, nc = k.shape[2], v.shape[2], t // 64
+    flat = _flat_operands(*args)
+    m = gated_delta.chunk_inverses(flat[1], flat[3])
+    assert m.shape == (hk, t, 128) and m.dtype == jnp.float32
+    got = np.asarray(m).reshape(hk, nc, 64, 2, 64)
+    for h in range(hv):
+        for c in range(nc):
+            rows = slice(c * 64, (c + 1) * 64)
+            gamma = np.cumsum(g[0, rows, h])
+            kc = k[0, rows, h // 2]
+            a = np.tril(beta[0, rows, h, None] * (kc @ kc.T)
+                        * np.exp(gamma[:, None] - gamma[None, :]), -1)
+            want = np.linalg.inv(np.eye(64) + a)
+            np.testing.assert_allclose(
+                got[h // 2, c, :, h % 2], want, rtol=0,
+                atol=TOL * np.abs(want).max(), err_msg=f"head {h} chunk {c}")
+    given = gated_delta._operands_fwd_call(*flat, m, interpreted=True)
+    for a, b in zip(given, gated_delta.chunk_operands_kernel(*args)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("case", ["weak-decay", "repeated-keys",
+                                  "ten-chunks-two-blocks-two-key-heads"])
+def test_a_segment_keeps_the_inverse_and_gives_the_same_gradients(case):
+    """The op inside a recompute segment (`jax.checkpoint` under the
+    executor's `segment_policy`, traced as the executor traces a
+    segment): loss and every gradient bit-equal to the op with no
+    segment; one traced forward + backward records ONE inverse call,
+    three chunk-operand calls (two forward: the segment's and the
+    forward rule's; one backward) and the inverse's bytes among the
+    kept residuals; and the differentiated segment holds the inverse
+    kernel ONCE and `gated_delta_operands_fwd` twice, where
+    `jax.checkpoint`'s default (the inputs alone) solves twice."""
+    from test_recompute import _pallas_calls
+
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops import pallas as pallas_tier
+
+    t, kind = OPERAND_CASES[case]
+    args = scan_case(t, seed=4, **kind)
+    hk, hv = args[1].shape[2], args[2].shape[2]
+    weight = jnp.asarray(np.random.default_rng(9).normal(
+        size=(1, t, hv, gated_delta.HEAD_DIM)), jnp.float32)
+
+    def loss(*a):
+        return jnp.sum(gated_delta.gated_delta_rule(*a, use_kernel=True)
+                       * weight)
+
+    def in_segment(policy):
+        def segment(*a):
+            with pallas_tier.tracing_segment():
+                return loss(*a)
+        return jax.checkpoint(segment, policy=policy)
+
+    both = lambda fn: jax.value_and_grad(fn, argnums=range(5))  # noqa: E731
+    want = both(loss)(*args)
+    before = runtime_stats.snapshot()
+    kept = both(in_segment(pallas_tier.segment_policy()))
+    got = kept(*args)
+    took = runtime_stats.delta(before)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert took["gated_delta_inverse_calls"] == 1
+    assert (took["gated_delta_operand_calls"],
+            took["gated_delta_operand_chunks"]) == (3, 3 * hv * t // 64)
+    assert (took["recompute_kept_residuals"],
+            took["recompute_kept_bytes"]) == (1, hk * t * 128 * 4)
+
+    def kernels(fn):
+        found = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)["kernels"]
+        return {name: found.count(name) for name in set(found)}
+
+    assert kernels(kept) == {
+        "gated_delta_inverse": 1, "gated_delta_operands_fwd": 2,
+        "gated_delta_operands_bwd": 1, "gated_delta_fwd": 2,
+        "gated_delta_bwd": 1}
+    assert kernels(both(in_segment(None)))["gated_delta_inverse"] == 2
 
 
 @pytest.mark.parametrize("diagonal", [8, 32, 64])
@@ -451,7 +562,8 @@ def test_other_heads_keep_chunk_operands():
     took = runtime_stats.delta(before)
     assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (1, 2)
     assert (took["gated_delta_operand_calls"],
-            took["gated_delta_operand_chunks"]) == (0, 0)
+            took["gated_delta_operand_chunks"],
+            took["gated_delta_inverse_calls"]) == (0, 0, 0)
     want = sequential(*args)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
                                atol=TOL * float(jnp.abs(want).max()))

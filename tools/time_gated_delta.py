@@ -6,9 +6,11 @@
 At `qwen3next-16k`'s shape by default (1 x 16384 positions, 16 key and
 32 value heads of 128): the chunk-local part by each lowering (XLA's
 `chunk_operands`, the Pallas kernels at each candidate size of the
-substitution's diagonal blocks), the inverse alone by each candidate
-(XLA's batched triangular solve, the in-kernel substitution), the scan
-kernels, the whole op; each forward and forward + backward (a VJP
+substitution's diagonal blocks), the part's three kernels each alone
+(`gated_delta_inverse`, `gated_delta_operands_fwd` given the inverse,
+`gated_delta_operands_bwd`), the inverse alone by each candidate (XLA's
+batched triangular solve, the in-kernel substitution on a given A), the
+scan kernels, the whole op; each forward and forward + backward (a VJP
 against fixed cotangents, every gradient a result).  Milliseconds a
 call, the median of `--repeats` timed calls after a warm-up.  The last
 stdout line is one JSON object; the same line goes to
@@ -123,7 +125,7 @@ def main():
     default = gd.DIAGONAL_BLOCK
     for diagonal in diagonals:
         gd.DIAGONAL_BLOCK = diagonal
-        gd._operands_fwd_call.clear_cache()
+        gd._inverse_call.clear_cache()
         tag = f"chunk_operands_kernel_{diagonal}"
         try:
             out[tag] = both(gd.chunk_operands_kernel, operands)
@@ -137,7 +139,21 @@ def main():
                   / jnp.abs(b.astype(jnp.float32)).max())
             for a, b in zip(got, want))
     gd.DIAGONAL_BLOCK = default
-    gd._operands_fwd_call.clear_cache()
+    gd._inverse_call.clear_cache()
+
+    # the part's kernels alone, as the op hands them their operands: a
+    # recompute segment's backward pass runs the second and third, the
+    # inverse is kept from the forward pass
+    flat = [x.reshape(1, t, -1) for x in (q, k, v)]
+    tiles, _ = jax.jit(functools.partial(gd._row_tiles, hk=hk))(g, beta)
+    inverse = jax.jit(gd._inverse_call)(flat[1], tiles)
+    out["inverse_kernel"] = timed(gd._inverse_call, (flat[1], tiles),
+                                  args.repeats)
+    out["operands_fwd_kernel_given_inverse"] = timed(
+        gd._operands_fwd_call, (*flat, tiles, inverse), args.repeats)
+    cts = jax.jit(gd._operands_fwd_call)(*flat, tiles, inverse)
+    out["operands_bwd_kernel"] = timed(
+        gd._operands_bwd_call, (*flat, tiles, inverse, *cts), args.repeats)
 
     # what XLA does around the kernels: the row tiles and exp(gamma_C)
     out["row_tiles_xla"] = both(
