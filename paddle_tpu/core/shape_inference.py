@@ -49,6 +49,7 @@ _SKIP_INFERENCE = {
     "backward_marker", "py_func", "print",
     "create_array", "array_write", "array_read", "array_length",
     "array_to_tensor", "gated_delta_rule", "short_conv", "rope",
+    "selective_scan",
 }
 
 

@@ -62,6 +62,35 @@ class LogUniform(Initializer):
                                outputs={"Out": [var]})
 
 
+class SoftplusInverseLogUniform(Initializer):
+    """x with softplus(x) = max(a draw from exp(U(log low, log high)),
+    floor): a state-space mixer's step bias, so that its step sizes
+    start log-uniform in [low, high] (Gu & Dao, arXiv:2312.00752)."""
+
+    def __init__(self, low: float, high: float, floor: float = 0.0,
+                 seed: int = 0):
+        if not 0.0 < low < high:
+            raise ValueError(f"SoftplusInverseLogUniform: not 0 < {low} < "
+                             f"{high}")
+        self.low, self.high, self.floor, self.seed = low, high, floor, seed
+
+    def __call__(self, var, block):
+        import math
+
+        def on(op, **attrs):
+            return block.append_op(type=op, inputs={"X": [var]},
+                                   outputs={"Out": [var]}, attrs=attrs)
+
+        Uniform(math.log(self.low), math.log(self.high), self.seed)(
+            var, block)
+        on("exp")
+        on("clip", min=float(self.floor), max=float(self.high))
+        # x = log(exp(step) - 1)
+        on("exp")
+        on("scale", scale=1.0, bias=-1.0)
+        return on("log")
+
+
 class Normal(Initializer):
     def __init__(self, loc: float = 0.0, scale: float = 1.0, seed: int = 0):
         self.mean, self.std, self.seed = loc, scale, seed

@@ -297,13 +297,16 @@ def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
 
 
 def short_conv(input, filter_size, param_attr=None, name=None,
-               activation=None):
+               activation=None, bias_attr=None):
     """The gated short convolution of a hybrid conv/attention decoder
     (ops/decoder.py `short_conv`): `input` is `BCu` (N, T, 3D), what
     the block's in-projection emits; returns `C * conv(B * u)`
     (N, T, D), a causal depthwise convolution of `filter_size` taps
     with one learned filter (D, filter_size).  `activation` "silu":
-    `input` is (N, T, D), ungated, and the result silu(conv(input))."""
+    `input` is (N, T, D), ungated, and the result silu(conv(input));
+    with a `bias_attr` (a ParamAttr, or True) silu(conv(input) + b), b
+    one learned number a channel from 0, added before the activation
+    inside the op."""
     helper = LayerHelper("short_conv", name=name)
     wide = 1 if activation else 3
     d = int(input.shape[-1]) // wide
@@ -313,8 +316,15 @@ def short_conv(input, filter_size, param_attr=None, name=None,
     w = helper.create_parameter(param_attr, shape=[d, int(filter_size)],
                                 dtype=input.dtype)
     out = helper.create_variable_for_type_inference(input.dtype)
-    helper.append_op(type="short_conv",
-                     inputs={"X": [input], "Filter": [w]},
+    ins = {"X": [input], "Filter": [w]}
+    if bias_attr:
+        if not activation:
+            raise ValueError("short_conv: a bias goes with "
+                             "activation='silu'")
+        ins["Bias"] = [helper.create_parameter(
+            ParamAttr._to_attr(None if bias_attr is True else bias_attr)
+            or ParamAttr(), shape=[d], dtype=input.dtype, is_bias=True)]
+    helper.append_op(type="short_conv", inputs=ins,
                      outputs={"Out": [out]},
                      attrs={"activation": activation} if activation else {})
     out.desc.shape = tuple(input.shape[:-1]) + (d,)
@@ -412,6 +422,66 @@ def gated_delta_rule(qkv, ba, n_key_head, n_value_head, key_dim, value_dim,
                "n_value_head": int(n_value_head), "key_dim": int(key_dim),
                "value_dim": int(value_dim), "use_pallas": bool(use_pallas)})
     out.desc.shape = tuple(qkv.shape[:-1]) + (int(n_value_head * value_dim),)
+    return out
+
+
+def selective_scan(u, delta, b, c, name=None):
+    """The scan of a Mamba-1 state-space mixer (ops/decoder.py
+    `selective_scan`): `u` (N, T, D) the convolved input, `delta`
+    (N, T, D) the step projection's output before its bias and the
+    softplus, `b`, `c` (N, T, S).  Three learned float32 parameters as
+    the published class starts them: `A_log` (D, S) = log(1 .. S) a
+    channel, `D` (D,) = 1, and the step's bias `dt_bias` (D,) = the
+    inverse softplus of a step drawn log-uniformly from [0.001, 0.1]
+    and floored at 1e-4.  Returns y (N, T, D), the read-out WITH the
+    D u term and before any gate."""
+    from ..initializer import NumpyArrayInitializer, SoftplusInverseLogUniform
+
+    helper = LayerHelper("selective_scan", name=name)
+    d, s = int(u.shape[-1]), int(b.shape[-1])
+    a_log = helper.create_parameter(
+        None, shape=[d, s], dtype="float32",
+        default_initializer=NumpyArrayInitializer(np.tile(np.log(
+            np.arange(1, s + 1, dtype=np.float32)), (d, 1))))
+    skip = helper.create_parameter(
+        None, shape=[d], dtype="float32",
+        default_initializer=Constant(1.0))
+    dt_bias = helper.create_parameter(
+        None, shape=[d], dtype="float32",
+        default_initializer=SoftplusInverseLogUniform(0.001, 0.1,
+                                                      floor=1e-4))
+    out = helper.create_variable_for_type_inference(u.dtype)
+    helper.append_op(
+        type="selective_scan",
+        inputs={"U": [u], "Delta": [delta], "B": [b], "C": [c],
+                "ALog": [a_log], "D": [skip], "DeltaBias": [dt_bias]},
+        outputs={"Out": [out]})
+    out.desc.shape = tuple(u.shape)
+    return out
+
+
+def diff_combine(x, n_kv_pair, lanes, lambda_init, epsilon=1e-5, name=None):
+    """Differential attention's subtraction and sub-layer norm
+    (ops/decoder.py `diff_combine`): `x` (N, T, H * lanes) the contexts
+    of ONE grouped attention call whose key/value heads are the two
+    keys of each of `n_kv_pair` pairs.  Five learned parameters: the
+    four vectors of lambda's re-parameterisation (lanes / 2 long,
+    N(0, 0.1)) and the norm's scale (`lanes`,) from 1.
+    Returns (N, T, H / 2 * lanes)."""
+    helper = LayerHelper("diff_combine", name=name)
+    ins = {"X": [x]}
+    for slot in ("LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"):
+        ins[slot] = [helper.create_parameter(
+            None, shape=[int(lanes) // 2], dtype="float32",
+            default_initializer=Normal(0.0, 0.1))]
+    ins["Scale"] = [helper.create_parameter(
+        None, shape=[int(lanes)], dtype="float32",
+        default_initializer=Constant(1.0))]
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="diff_combine", inputs=ins, outputs={"Out": [out]},
+                     attrs={"n_kv_pair": int(n_kv_pair),
+                            "lambda_init": float(lambda_init),
+                            "epsilon": float(epsilon)})
     return out
 
 

@@ -14,7 +14,13 @@ values are not built yet raises):
   head or its first lanes, by layer type, its query head count the
   model's or the layer's own, its context gated a lane, a head or not;
   the gated short convolution; the gated-delta-rule linear-attention
-  mixer (a chunked scan, `ops/pallas/gated_delta.py`);
+  mixer (a chunked scan, `ops/pallas/gated_delta.py`); the Mamba-1
+  state-space mixer (a selective scan, `ops/pallas/selective_scan.py`);
+  differential attention (two soft-max maps subtracted) on those flash
+  kernels; layers that READ another layer's work (gated memory units
+  on one layer's scan output, cross-attention on one layer's keys and
+  values); RMSNorm or LayerNorm, projections with or without a bias,
+  RoPE or no positions at all;
   latent attention (`kv_lora_rank` ...: queries, keys and values out
   of low-rank latents, a rotary part beside the unrotated one, its own
   flash kernels);
@@ -247,6 +253,67 @@ with w_i = 1 / t_b on a masked position and 0 elsewhere (feed
 window, a convolution, latent or linear attention, a prediction module
 and a loop raise.
 
+`layer_types[i]` = "mamba" (the `mamba_*` sizes; a Mamba-1 state-space
+mixer, Gu & Dao, arXiv:2312.00752) is a mixer whose state is a vector
+of `mamba_d_state` numbers a channel under a decay of the (channel,
+state) pair's own:
+
+    u = silu(conv(h W_u) + b_conv);  z = h W_z          (d_inner wide)
+    [r | B | C] = u W_x                  (mamba_dt_rank + 2 mamba_d_state)
+    dt = softplus(r W_dt + b_dt);  A = -exp(A_log)
+    s_t[c, n] = exp(dt_t[c] A[c, n]) s_{t-1}[c, n] + dt_t[c] u_t[c] B_t[n]
+    y_t[c] = sum_n C_t[n] s_t[c, n] + D[c] u_t[c]
+    out = (y * silu(z)) W_out
+
+d_inner = `mamba_expand` x hidden_size; the convolution is causal,
+depthwise, `mamba_d_conv` taps with a bias (`layers.short_conv(
+activation="silu", bias_attr=)`); the recurrence is ONE op,
+`selective_scan` (`ops/pallas/selective_scan.py`: two Pallas kernels at
+16 states, T whole chunks).  Its ops lower under the `state_space` name
+scope.
+
+**Values that cross layers** (a decoder-hybrid-decoder, Ren et al.,
+arXiv:2507.06607).  Two layers may EXPORT an intermediate that later
+layers read, state of the layer loop and nothing else:
+`shared_memory_layer` m names the "mamba" layer whose scan output y
+(with the D u term, before the gate) every later "gated_memory" layer
+reads, position by position:
+
+    out = (silu(h W_1) * y_m) W_2                    (name scope `gated_memory`)
+
+and `shared_kv_layer` a names the attention layer whose keys and values
+every later "cross_attention" layer reads: such a layer projects
+queries only and attends causally over layer a's K and V (name scope
+`cross_attention`).  Under `recompute="layer"` the exported value
+leaves its segment as an output and enters each reader's as an input;
+the readers' gradients add up into the one cotangent that enters the
+exporter's backward pass (autodiff's sum, nothing here).  A reader
+before its exporter, or without one, raises at build time.
+
+`attention` = "differential" (Ye et al., arXiv:2410.05258): adjacent
+heads pair, query pair j = heads (2j, 2j + 1) reads key/value pair
+j // (H / Hkv); with P_c = softmax(q_c k_c^T / sqrt(D) + mask) and
+V = [v_1 | v_2] (2 D lanes):
+
+    ctx_j = rms_norm_2D(P_1 V - lam P_2 V) * (1 - lam_init)
+    lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init
+    lam_init = 0.8 - 0.6 exp(-0.3 l)          l = `layer_indices`[i]
+
+lowered as ONE grouped flash call a layer at heads of 2 D lanes (a
+query or key head [x | 0], a value head [v_1 | v_2] for both of its
+pair's keys; on a 128 x 128 MXU a 64-lane contraction padded to 128
+costs what 64 cost) and one fused `diff_combine` op; the projections'
+columns are therefore in the order the call reads them: for key/value
+pair i the first heads of its H / Hkv query pairs, then their second
+heads (a checkpoint's published order, head 2j + c, is a loader's
+one-off permutation).  Under the name scope `differential_attention`
+(and the layer type's own inside it).  `attention_bias`: the q, k, v
+and out projections carry a bias.  `positions` = "none": no positional
+encoding of any kind (the state-space layers carry position); no
+`rope_theta` is then read.  `norm` = "layer_norm": every norm of the
+stream is a LayerNorm (mean and variance, scale and bias, eps
+`layer_norm_eps`).
+
 The training objective is the paper's: token cross-entropy + `aux_loss_weight`
 x the load-balancing loss + `z_loss_weight` x the router z-loss (both
 averaged over layers), or the exit-weighted loss above, AdamW,
@@ -266,7 +333,7 @@ from ..core.program import name_scope, recompute_scope
 from ..observe.monitoring import runtime_stats
 from ..ops.decoder import rope_frequencies
 from ..clip import GradientClipByGlobalNorm, set_gradient_clip
-from ..initializer import Normal
+from ..initializer import Normal, Uniform
 from ..param_attr import ParamAttr
 
 
@@ -294,7 +361,12 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             shared_expert_intermediate_size=None, zero_centered_norm=False,
             attention_gate=None, shared_expert_gate=None,
             objective="next_token", block_length=None,
-            num_attention_heads_per_layer=None, mlp_layer_types=None):
+            num_attention_heads_per_layer=None, mlp_layer_types=None,
+            norm="rms_norm", layer_norm_eps=None, attention="softmax",
+            attention_bias=False, positions="rope", layer_indices=None,
+            mamba_d_state=None, mamba_d_conv=None, mamba_expand=None,
+            mamba_dt_rank=None, shared_memory_layer=None,
+            shared_kv_layer=None):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
     labels' own successors, with a prediction module); under
@@ -353,6 +425,12 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise ValueError(f"total_ut_steps {total_ut_steps} is not positive")
     if objective not in ("next_token", "block_diffusion"):
         raise NotImplementedError(f"objective {objective!r} is not built")
+    if norm not in ("rms_norm", "layer_norm"):
+        raise NotImplementedError(f"norm {norm!r} is not built")
+    if attention not in ("softmax", "differential"):
+        raise NotImplementedError(f"attention {attention!r} is not built")
+    if positions not in ("rope", "none"):
+        raise NotImplementedError(f"positions {positions!r} is not built")
     diffusion = objective == "block_diffusion"
     if not diffusion and block_length is not None:
         raise ValueError("block_length without objective='block_diffusion'")
@@ -409,11 +487,71 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise ValueError("latent attention has one key and value a query "
                          "head: num_key_value_heads is num_attention_heads")
     eps = norm_eps if rms_norm_eps is None else rms_norm_eps
+    if norm == "layer_norm":
+        eps = layer_norm_eps if eps is None else eps
     layer_types = list(layer_types or
                        ["full_attention"] * num_hidden_layers)
     if len(layer_types) != num_hidden_layers:
         raise ValueError(f"{len(layer_types)} layer_types for "
                          f"{num_hidden_layers} layers")
+    layer_indices = list(layer_indices or range(num_hidden_layers))
+    if len(layer_indices) != num_hidden_layers:
+        raise ValueError(f"{len(layer_indices)} layer_indices for "
+                         f"{num_hidden_layers} layers")
+    mamba = (mamba_d_state, mamba_d_conv, mamba_expand, mamba_dt_rank)
+    if "mamba" in layer_types and None in mamba:
+        raise ValueError("a mamba layer needs mamba_d_state, mamba_d_conv, "
+                         "mamba_expand and mamba_dt_rank")
+    # what crosses layers: each reader's exporter is named, is of the
+    # kind that makes the value, and comes before it
+    for reader, key, at, makes in [
+            ("gated_memory", "shared_memory_layer", shared_memory_layer,
+             ("mamba",)),
+            ("cross_attention", "shared_kv_layer", shared_kv_layer,
+             ("full_attention", "sliding_attention"))]:
+        first_reader = (layer_types.index(reader) if reader in layer_types
+                        else num_hidden_layers)
+        if at is None:
+            if reader in layer_types:
+                raise ValueError(f"a {reader} layer needs {key}: the layer "
+                                 f"whose work it reads")
+        elif not 0 <= at < num_hidden_layers \
+                or layer_types[at] not in makes:
+            raise ValueError(f"{key} {at} is no {' / '.join(makes)} layer "
+                             f"of {layer_types}")
+        elif at > first_reader:
+            raise ValueError(f"layer {first_reader} ({reader}) reads layer "
+                             f"{at}'s work before it is made")
+    differential = attention == "differential"
+    crossing = [kind for kind in ("mamba", "gated_memory", "cross_attention")
+                if kind in layer_types]
+    if differential or crossing or norm != "rms_norm" or attention_bias \
+            or positions != "rope":
+        # built straight, on the causal attention mixers' path only
+        unbuilt = [what for what, asked in [
+            ("latent attention", kv_lora_rank is not None),
+            ("a looped stack", total_ut_steps > 1 or exit_gate),
+            ("objective='block_diffusion'", diffusion),
+            ("a prediction module", num_nextn_predict_layers),
+            ("num_attention_heads_per_layer",
+             num_attention_heads_per_layer is not None),
+            ("an attention_gate", attention_gate),
+            ("sandwich_norm", sandwich_norm),
+            ("zero_centered_norm", zero_centered_norm)] if asked]
+        if unbuilt:
+            raise NotImplementedError(
+                "differential attention, a LayerNorm stream, projection "
+                "biases, no positions and the mamba / gated_memory / "
+                "cross_attention layers beside " + ", ".join(unbuilt)
+                + " are not built")
+    if "cross_attention" in layer_types and not differential:
+        raise NotImplementedError("a cross_attention layer under "
+                                  "attention='softmax' is not built")
+    if differential and (num_attention_heads % 2 or num_key_value_heads % 2
+                         or positions != "none" or qk_norm is not None):
+        raise NotImplementedError(
+            "differential attention pairs adjacent heads (even head "
+            "counts) and is built without positions and QK-norm")
     windowed = "sliding_attention" in layer_types
     if windowed and not sliding_window:
         raise ValueError("a sliding_attention layer needs sliding_window")
@@ -461,7 +599,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         isinstance(rope_parameters.get(kind), dict)
         for kind in attention_kinds)
     rotary = {}
-    for kind in attention_kinds:
+    for kind in attention_kinds if positions == "rope" else ():
         group = dict((rope_parameters.get(kind) if by_kind
                       else rope_parameters) or {})
         group.setdefault("rope_theta", rope_theta)
@@ -483,7 +621,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             # scaled frequencies of a head of `rotary_dim` lanes
             inv_freq, factor = rope_frequencies(rotary_dim, **group)
             rotary[kind].update(inv_freq=inv_freq, attention_factor=factor)
-    if eps is None or not rotary:
+    if eps is None or not rotary and positions == "rope":
         raise ValueError("decoder needs rms_norm_eps or norm_eps, and "
                          "rope_theta or rope_parameters")
     theta = rotary.get("full_attention", {}).get("theta")
@@ -495,13 +633,19 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     def weight():
         return ParamAttr(initializer=Normal(0.0, initializer_range))
 
-    def proj(x, size, name):
-        return layers.fc(x, size=size, num_flatten_dims=2, bias_attr=False,
-                         param_attr=weight(), name=name)
+    def proj(x, size, name, bias=False, param_attr=None):
+        return layers.fc(x, size=size, num_flatten_dims=2,
+                         bias_attr=None if bias else False,
+                         param_attr=param_attr or weight(), name=name)
 
-    def norm(x):
+    def rms_norm(x):
         return layers.rms_norm(x, epsilon=eps,
                                zero_centered=zero_centered_norm)
+
+    def layer_norm(x):
+        return layers.layer_norm(x, begin_norm_axis=2, epsilon=eps)
+
+    norm = rms_norm if norm == "rms_norm" else layer_norm
 
     def turn_qk(x, n_head, turn):
         """QK-norm, then RoPE; a norm a head rides in the `rope` op."""
@@ -590,6 +734,84 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                     flash_mla.NOPE_DIM))
             return proj(ctx, hidden_size, "attn_out")
 
+    # what crosses layers, by what is exported: the exporting mamba
+    # layer's scan output, the exporting attention layer's (k, v) as the
+    # attention call reads them
+    shared = {}
+
+    def heads_of_twice(x, n_head):
+        """Heads of D lanes -> heads [x | 0] of 2 D lanes."""
+        x = layers.pad(layers.reshape(x, [0, 0, n_head, head_dim]),
+                       [0, 0, 0, 0, 0, 0, 0, head_dim])
+        return layers.reshape(x, [0, 0, 2 * n_head * head_dim])
+
+    def differential_attention(h, kind, at):
+        """Layer `at`: two soft-max maps a head pair, subtracted: ONE
+        grouped flash call at heads of 2 D lanes and `diff_combine`.  A
+        "cross_attention" layer projects q only and reads `shared`'s
+        keys and values; layer `shared_kv_layer` puts its own there."""
+        heads, kv_heads = num_attention_heads, num_key_value_heads
+        cross = kind == "cross_attention"
+        with name_scope("cross_attention" if cross
+                        else "differential_attention"), \
+                contextlib.nullcontext() if cross else name_scope(kind):
+            q = heads_of_twice(proj(h, heads * head_dim, "attn_qkv",
+                                    attention_bias), heads)
+            if cross:
+                k, v = shared["kv"]
+            else:
+                k = heads_of_twice(proj(h, kv_size, "attn_qkv",
+                                        attention_bias), kv_heads)
+                # a pair's values, side by side, under both of its keys
+                v = layers.reshape(layers.expand(layers.reshape(
+                    proj(h, kv_size, "attn_qkv", attention_bias),
+                    [0, 0, kv_heads // 2, 1, 2 * head_dim]),
+                    [1, 1, 1, 2, 1]), [0, 0, 2 * kv_size])
+                if at == shared_kv_layer:
+                    shared["kv"] = (k, v)
+            ctx = layers.flash_attention(
+                q, k, v, causal=True, use_pallas=True, layout="nthd",
+                n_head=heads, n_kv_head=kv_heads, scale=head_dim ** -0.5,
+                window=sliding_window if kind == "sliding_attention"
+                else None)
+            with name_scope("diff_combine"):
+                ctx = layers.diff_combine(
+                    ctx, kv_heads // 2, 2 * head_dim,
+                    0.8 - 0.6 * float(np.exp(-0.3 * layer_indices[at])),
+                    epsilon=eps)
+            runtime_stats.record_cross_layer(differential=1,
+                                             kv_reads=int(cross))
+            return proj(ctx, hidden_size, "attn_out", attention_bias)
+
+    def state_space(h, at):
+        """Layer `at`, a Mamba-1 mixer: u and z out of the input, u
+        through a short causal convolution and a SiLU, the step, B and C
+        out of u, the selective scan, the gate; layer
+        `shared_memory_layer` puts its scan output into `shared`."""
+        d_inner = mamba_expand * hidden_size
+        with name_scope("state_space"):
+            u = layers.short_conv(
+                proj(h, d_inner, "ssm_in"), mamba_d_conv,
+                param_attr=weight(), activation="silu", bias_attr=True)
+            z = proj(h, d_inner, "ssm_in")
+            r, b, c = layers.split(
+                proj(u, mamba_dt_rank + 2 * mamba_d_state, "ssm_x"),
+                [mamba_dt_rank, mamba_d_state, mamba_d_state], dim=2)
+            bound = mamba_dt_rank ** -0.5
+            delta = proj(r, d_inner, "ssm_dt", param_attr=ParamAttr(
+                initializer=Uniform(-bound, bound)))
+            y = layers.selective_scan(u, delta, b, c)
+            if at == shared_memory_layer:
+                shared["memory"] = y
+            return proj(layers.swiglu(z, y), hidden_size, "ssm_out")
+
+    def gated_memory(h):
+        with name_scope("gated_memory"):
+            runtime_stats.record_cross_layer(memory_reads=1)
+            m = shared["memory"]
+            return proj(layers.swiglu(proj(h, int(m.shape[-1]), "gmu_in"),
+                                      m), hidden_size, "gmu_out")
+
     def conv(h):
         y = layers.short_conv(proj(h, 3 * hidden_size, "conv_in"),
                               conv_L_cache, param_attr=weight())
@@ -637,8 +859,15 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     if kv_lora_rank is not None:
         mixers["full_attention"] = latent_attention
         del mixers["sliding_attention"]
+    mixers.update(mamba=state_space, gated_memory=gated_memory)
+    placed = ("mamba",)     # the mixers that are told which layer they are
+    if differential:
+        placed += attention_kinds + ("cross_attention",)
+        for kind in placed[1:]:
+            mixers[kind] = functools.partial(differential_attention,
+                                             kind=kind)
 
-    def block(x, kind, dense, heads=num_attention_heads):
+    def block(x, kind, dense, heads=num_attention_heads, at=None):
         op = mixers.get(kind)
         if op is None:
             raise NotImplementedError(
@@ -647,6 +876,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                    else ""))
         if heads != num_attention_heads and kind in attention_kinds:
             op = functools.partial(op, heads=heads)
+        if kind in placed:
+            op = functools.partial(op, at=at)     # what it exports, by place
         post = norm if sandwich_norm else (lambda y: y)
         x = layers.elementwise_add(x, post(op(norm(x))))
         ffn = dense_ffn if dense else routed_ffn
@@ -660,7 +891,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         for i, kind in enumerate(layer_types):
             with segment():
                 x = block(x, kind, dense=i < num_dense_layers,
-                          heads=heads_of[i])
+                          heads=heads_of[i], at=i)
         return x
 
     def head(x):

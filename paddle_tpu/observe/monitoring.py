@@ -80,7 +80,9 @@ _FIELDS = Heard._fields[1:] + (
     "gated_delta_inverse_calls",
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
-    "short_convs_kernel", "short_convs_xla",
+    "short_convs_kernel", "short_convs_xla", "short_conv_bias_calls",
+    "selective_scans_kernel", "selective_scans_xla", "selective_scan_chunks",
+    "differential_attention_calls", "shared_memory_reads", "shared_kv_reads",
     "ropes_kernel", "ropes_xla",
     "share_rows_kernel", "share_rows_xla",
     "loop_trips")
@@ -227,6 +229,27 @@ class RuntimeStats:
         # (delta() around a build; a Program build counts nothing)
         self.short_convs_kernel = 0
         self.short_convs_xla = 0
+        # those of them that add a bias a channel before the activation
+        self.short_conv_bias_calls = 0
+        # selective scans (`ops/pallas/selective_scan.py`) traced, by
+        # what the shape chose: the Pallas kernels (a layer's forward
+        # and its backward are a call each; a recompute segment keeps
+        # the forward's results and runs it once) or the XLA lowering,
+        # and the chunks x batch the kernels walk, summed over the calls
+        # (delta() around a build; a step on the fall-back reads 0
+        # chunks)
+        self.selective_scans_kernel = 0
+        self.selective_scans_xla = 0
+        self.selective_scan_chunks = 0
+        # differential attention calls built (`models/decoder.py`: two
+        # soft-max maps subtracted, a layer with its own K and V or a
+        # reader of another's), and the layers built that READ another
+        # layer's work: its scan output (a gated memory unit) or its
+        # keys and values (cross-attention).  Build-time counts: delta()
+        # around a Program build
+        self.differential_attention_calls = 0
+        self.shared_memory_reads = 0
+        self.shared_kv_reads = 0
         # `rope` ops traced, by what the shape and attrs chose: the
         # Pallas kernels (`ops/pallas/rope.py`) or the composition
         # (delta() around a build; a Program build counts nothing)
@@ -356,12 +379,27 @@ class RuntimeStats:
             else:
                 self.grouped_matmuls_xla += 1
 
-    def record_short_conv(self, kernel: bool):
+    def record_short_conv(self, kernel: bool, bias: bool = False):
         with self._lock:
             if kernel:
                 self.short_convs_kernel += 1
             else:
                 self.short_convs_xla += 1
+            self.short_conv_bias_calls += bool(bias)
+
+    def record_selective_scan(self, kernel: bool, chunks: int):
+        with self._lock:
+            if kernel:
+                self.selective_scans_kernel += 1
+                self.selective_scan_chunks += chunks
+            else:
+                self.selective_scans_xla += 1
+
+    def record_cross_layer(self, differential=0, memory_reads=0, kv_reads=0):
+        with self._lock:
+            self.differential_attention_calls += differential
+            self.shared_memory_reads += memory_reads
+            self.shared_kv_reads += kv_reads
 
     def record_rope(self, kernel: bool):
         with self._lock:

@@ -5,8 +5,11 @@ halves or over pairs, over a whole head or its first lanes), `swiglu`
 that stands where attention does in most layers of a hybrid
 conv/attention model, gated or under a SiLU), `latent_attention` (the
 attention core of a layer whose keys and values come out of a low-rank
-latent, with a rotary part beside it) and `gated_delta_rule` (the scan
-of a linear-attention layer).
+latent, with a rotary part beside it), `gated_delta_rule` (the scan
+of a linear-attention layer), `selective_scan` (the scan of a
+state-space mixer with a diagonal state a channel) and `diff_combine`
+(what differential attention does with its two soft-max maps'
+contexts).
 
 Not in the 1.2 reference (it predates them all); they are ops of
 their own, not compositions of `square` / `reduce_mean` / `slice` /
@@ -276,8 +279,11 @@ def _short_conv(bcu, w):
     return (c * _causal_conv(b * u, w)).astype(bcu.dtype)
 
 
-def _silu_conv(x, w):
-    return jax.nn.silu(_causal_conv(x.astype(jnp.float32), w)).astype(x.dtype)
+def _silu_conv(x, w, bias=None):
+    conv = _causal_conv(x.astype(jnp.float32), w)
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
+    return jax.nn.silu(conv).astype(x.dtype)
 
 
 @register_op("short_conv")
@@ -297,7 +303,8 @@ def short_conv(ctx, ins, attrs):
 
     `activation` "silu": no gates; X is (N, T, D) and
     Out = silu(conv(X)), the same convolution (what stands before the
-    scan of a linear-attention layer).
+    scan of a linear-attention layer); with a Bias (D,), one number a
+    channel, Out = silu(conv(X) + Bias) (a state-space mixer's).
 
     Two lowerings of the one algorithm, chosen by the shape alone
     (`ops/pallas/short_conv.py short_conv_kernel_takes`: D a multiple
@@ -308,9 +315,11 @@ def short_conv(ctx, ins, attrs):
     intermediates XLA writes to HBM.  `runtime_stats.
     short_convs_kernel` / `_xla` count the calls traced each way."""
     from ..observe.monitoring import runtime_stats
-    from .pallas.short_conv import short_conv_kernel, short_conv_kernel_takes
+    from .pallas.short_conv import (biased_conv_kernel, short_conv_kernel,
+                                    short_conv_kernel_takes)
 
     x, w = first(ins, "X"), first(ins, "Filter")
+    bias = opt_in(ins, "Bias")
     activation = attrs.get("activation")
     if activation not in (None, "silu"):
         raise NotImplementedError(f"short_conv: activation {activation!r} "
@@ -321,9 +330,16 @@ def short_conv(ctx, ins, attrs):
                          f"{wide if wide > 1 else ''}D) for a Filter "
                          f"{w.shape} of (D, L)")
     gated = not activation
+    if bias is not None and (gated or bias.shape != w.shape[:1]):
+        raise ValueError(f"short_conv: a Bias {bias.shape} goes with "
+                         f"activation='silu' and a Filter {w.shape} of "
+                         f"(D, L)")
     kernel = short_conv_kernel_takes(x.shape[1], w.shape[0], w.shape[1],
                                      gated, x.dtype.itemsize)
-    runtime_stats.record_short_conv(kernel)
+    runtime_stats.record_short_conv(kernel, bias=bias is not None)
+    if bias is not None:
+        return out(Out=(biased_conv_kernel if kernel
+                        else jax.checkpoint(_silu_conv))(x, w, bias))
     if kernel:
         return out(Out=short_conv_kernel(x, w, gated))
     return out(Out=jax.checkpoint(_short_conv if gated
@@ -434,3 +450,72 @@ def gated_delta_rule(ctx, ins, attrs):
     o = gated_delta.gated_delta_rule(
         q, k, v, g, beta, use_kernel=bool(attrs.get("use_pallas", False)))
     return out(Out=o.reshape(n, t, hv * dv))
+
+
+@register_op("selective_scan")
+def selective_scan(ctx, ins, attrs):
+    """The mixer core of a Mamba-1 state-space layer (arXiv:2312.00752),
+    over one sequence a row.  U (N, T, D): the convolved, activated
+    input; Delta (N, T, D): the step projection's output BEFORE its bias
+    and the softplus; B, C (N, T, S): one group, shared by every
+    channel; ALog (D, S), D and DeltaBias (D,).  In float32:
+
+        dt = softplus(Delta + DeltaBias);  A = -exp(ALog)
+        s_t[c, n] = exp(dt_t[c] A[c, n]) s_{t-1}[c, n] + dt_t[c] u_t[c] B_t[n]
+        Out_t[c] = sum_n C_t[n] s_t[c, n] + D[c] u_t[c]      (N, T, D)
+
+    with s (D, S) from 0; Out in U's dtype.  Two lowerings of the one
+    recurrence, chosen by the shape alone (`ops/pallas/selective_scan.py
+    selective_scan_takes`: S = 16, T whole chunks, D whole lane tiles):
+    the two Pallas kernels there, whose state never leaves VMEM, or a
+    `lax.scan` over chunks with an `associative_scan` inside.
+    `runtime_stats.selective_scans_kernel` / `_xla` count the calls
+    traced each way."""
+    from .pallas import selective_scan as scan
+
+    a = -jnp.exp(first(ins, "ALog").astype(jnp.float32))
+    return out(Out=scan.selective_scan(
+        first(ins, "U"), first(ins, "Delta"), a, first(ins, "B"),
+        first(ins, "C"), first(ins, "D"), first(ins, "DeltaBias")))
+
+
+@register_op("diff_combine")
+def diff_combine(ctx, ins, attrs):
+    """What differential attention (Ye et al., arXiv:2410.05258) does
+    with its two maps' contexts.  X (N, T, H * W): the contexts of H
+    query heads of W lanes as ONE grouped attention call over 2 x
+    `n_kv_pair` key/value heads left them: for key/value pair i the g
+    heads that read its FIRST key, then the g that read its second (H =
+    2 g n_kv_pair).  LambdaQ1, LambdaK1, LambdaQ2, LambdaK2 (any one
+    length) and Scale (W,).  In float32:
+
+        lam = exp(LambdaQ1 . LambdaK1) - exp(LambdaQ2 . LambdaK2) + lambda_init
+        ctx[i, e] = rms_norm(x[i, 0, e] - lam x[i, 1, e]) * Scale
+                    * (1 - lambda_init)
+
+    Out (N, T, H / 2 * W), pair i g + e at lanes (i g + e) W ..; X's
+    dtype."""
+    x = first(ins, "X")
+    f32 = jnp.float32
+    scale = first(ins, "Scale").astype(f32)
+    lam_init = float(attrs["lambda_init"])
+
+    def dot(a, b):
+        return jnp.sum(first(ins, a).astype(f32) * first(ins, b).astype(f32))
+
+    lam = (jnp.exp(dot("LambdaQ1", "LambdaK1"))
+           - jnp.exp(dot("LambdaQ2", "LambdaK2")) + lam_init)
+    n, t, width = x.shape
+    lanes, pairs = scale.shape[0], int(attrs["n_kv_pair"])
+    group = width // (2 * pairs * lanes)
+    if 2 * pairs * group * lanes != width:
+        raise ValueError(f"diff_combine: X {x.shape} is not two maps of "
+                         f"{pairs} key/value pairs' heads of {lanes}")
+    # a key/value pair's heads lie side by side, first keys then second:
+    # the two maps are the two halves of its lanes (no strided slice)
+    xf = x.astype(f32).reshape(n, t, pairs, 2 * group * lanes)
+    apart = (xf[..., :group * lanes] - lam * xf[..., group * lanes:]
+             ).reshape(n, t, pairs * group, lanes)
+    y = _over_rms(apart, -1, float(attrs.get("epsilon", 1e-5)))
+    y = y * (scale * (1.0 - lam_init))
+    return out(Out=y.reshape(n, t, pairs * group * lanes).astype(x.dtype))

@@ -49,14 +49,19 @@ def interpret() -> bool:
 # chunk_inverses`: a chain of dependent substitution steps a chunk, 6.6
 # ms a layer at 16384 positions for 134 MB), so a segment's backward pass
 # runs the chunk-operand forward kernel that READS the inverse and not
-# the one that solves for it.  ONE mechanism in two places: whoever
+# the one that solves for it; and the selective scan's output and the
+# states that enter its chunks (`selective_scan.py`: 84 + 10.5 MB a
+# layer at 8192 x 5120 for a forward scan of the whole sequence), which
+# are all its backward kernel reads besides the operands.  ONE mechanism
+# in three places: whoever
 # makes such a residual names it through `keep_residuals`, and the
 # executor's `jax.checkpoint` saves exactly these names
 # (`segment_policy`).  Names without that policy are inert (a `name`
 # equation lowers to nothing); a residual nobody names is recomputed.
 ATTENTION_RESIDUALS = ("attention_out", "attention_logsumexp")
 INVERSE_RESIDUAL = ("gated_delta_inverse",)
-KEPT_RESIDUALS = ATTENTION_RESIDUALS + INVERSE_RESIDUAL
+SCAN_RESIDUALS = ("selective_scan_out", "selective_scan_states")
+KEPT_RESIDUALS = ATTENTION_RESIDUALS + INVERSE_RESIDUAL + SCAN_RESIDUALS
 _open_segments = [0]
 
 

@@ -2,8 +2,8 @@
 
     conv[t] = sum_j Filter[:, j] * z[t - (L-1) + j]          (z[<0] = 0)
 
-    "silu":  z = X,      Out = silu(conv)        X (N, T, D)
-    gated:   z = B * u,  Out = C * conv          X = BCu (N, T, 3D)
+    "silu":  z = X,      Out = silu(conv [+ Bias])   X (N, T, D)
+    gated:   z = B * u,  Out = C * conv              X = BCu (N, T, 3D)
 
 `ops/decoder.py short_conv` is the op; this is its lowering for the
 shapes `short_conv_kernel_takes`, the `jax.checkpoint`-ed composition
@@ -28,6 +28,11 @@ Gated form: B, C and u are the three lane slabs of ONE full-width tile
 of `BCu`, and dB, dC, du those of one tile of its gradient: no slice
 and no concatenation of `BCu` in HBM.  So its channel tile is D, and
 the rule takes it only while that tile fits VMEM.
+
+A Bias (D,) of the "silu" form (a state-space mixer's convolution)
+rides as one more row of the filter operand, and its gradient as one
+more tap's worth of the filter's gradient block; without one both
+calls are what they were.
 
 Kernel names `short_conv_fwd` / `short_conv_bwd`, registered costs in
 bytes (no MXU work).
@@ -172,7 +177,8 @@ def _loader(g, lg, td, gated):
                               * ref[0, rows, u].astype(f32))
 
 
-def _fwd_kernel(x_ref, w_ref, o_ref, tail_ref, *, taps, gated, rc):
+def _fwd_kernel(x_ref, w_ref, o_ref, tail_ref, *, taps, gated, rc,
+                biased=False):
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -196,6 +202,8 @@ def _fwd_kernel(x_ref, w_ref, o_ref, tail_ref, *, taps, gated, rc):
             ext = lax.concatenate([tail_ref[:, lanes], z], 0)
             tail_ref[:, lanes] = _rows(z, rc - HALO, HALO)
             conv = _weighted(w, _behind(ext, taps, rc))
+            if biased:
+                conv = conv + w_ref[taps:taps + 1, lanes]
             if gated:
                 y = x_ref[0, rows, c_lanes].astype(f32) * conv
             else:
@@ -209,7 +217,7 @@ def _fwd_kernel(x_ref, w_ref, o_ref, tail_ref, *, taps, gated, rc):
 
 
 def _bwd_kernel(x_ref, halo_ref, dy_ref, w_ref, dx_ref, dw_ref, head_ref, *,
-                taps, gated, rc):
+                taps, gated, rc, biased=False):
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -236,6 +244,8 @@ def _bwd_kernel(x_ref, halo_ref, dy_ref, w_ref, dx_ref, dw_ref, head_ref, *,
             ext = lax.concatenate([prev, load(x_ref, rows)], 0)
             shifted = _behind(ext, taps, rc)
             conv = _weighted(w, shifted)
+            if biased:
+                conv = conv + w_ref[taps:taps + 1, lanes]
             dy = dy_ref[0, rows, lanes].astype(f32)
             if gated:
                 dconv = dy * x_ref[0, rows, c_lanes].astype(f32)
@@ -247,6 +257,9 @@ def _bwd_kernel(x_ref, halo_ref, dy_ref, w_ref, dx_ref, dw_ref, head_ref, *,
                 eight = lax.reshape(dconv * shifted[j],
                                     (rc // 8, 8, dconv.shape[1]))
                 dw_ref[0, j, :, lanes] += lax.reduce_sum(eight, [0])
+            if biased:      # the bias's gradient: one more "tap" of ones
+                dw_ref[0, taps, :, lanes] += lax.reduce_sum(
+                    lax.reshape(dconv, (rc // 8, 8, dconv.shape[1])), [0])
             ext = lax.concatenate([dconv, head_ref[:, lanes]], 0)
             head_ref[:, lanes] = _rows(dconv, 0, HALO)
             dz = _weighted(w, _ahead(ext, taps, rc))
@@ -297,9 +310,19 @@ def _geometry(x, w, gated, row_tile, channel_tile):
 _STATIC = ("gated", "row_tile", "channel_tile", "interpreted")
 
 
+def _filter_rows(w, bias):
+    """The filter operand: a row a tap, (taps, D) float32, and the bias
+    as one row more where there is one."""
+    rows = w.astype(jnp.float32).T
+    if bias is None:
+        return rows, {}
+    return jnp.concatenate([rows, bias.astype(jnp.float32)[None]]), \
+        {"biased": True}
+
+
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd_call(x, w, gated, row_tile=None, channel_tile=None,
-              interpreted=False):
+              interpreted=False, bias=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -307,21 +330,23 @@ def _fwd_call(x, w, gated, row_tile=None, channel_tile=None,
 
     n, t, d, taps, wide, tr, td, rc = _geometry(x, w, gated, row_tile,
                                                 channel_tile)
+    rows, biased = _filter_rows(w, bias)
     return pallas_call(
-        functools.partial(_fwd_kernel, taps=taps, gated=gated, rc=rc),
+        functools.partial(_fwd_kernel, taps=taps, gated=gated, rc=rc,
+                          **biased),
         name="short_conv_fwd", grid=(n, d // td, t // tr),
         in_specs=[pl.BlockSpec((1, tr, wide * td), lambda b, c, r: (b, r, c)),
-                  pl.BlockSpec((taps, td), lambda b, c, r: (0, c))],
+                  pl.BlockSpec((rows.shape[0], td), lambda b, c, r: (0, c))],
         out_specs=pl.BlockSpec((1, tr, td), lambda b, c, r: (b, r, c)),
         out_shape=jax.ShapeDtypeStruct((n, t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((HALO, td), jnp.float32)],
         compiler_params=_params(),
-    )(x, w.astype(jnp.float32).T)
+    )(x, rows)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_call(x, w, dy, gated, row_tile=None, channel_tile=None,
-              interpreted=False):
+              interpreted=False, bias=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -330,28 +355,34 @@ def _bwd_call(x, w, dy, gated, row_tile=None, channel_tile=None,
     n, t, d, taps, wide, tr, td, rc = _geometry(x, w, gated, row_tile,
                                                 channel_tile)
     last, per = t // tr - 1, tr // HALO
+    rows, biased = _filter_rows(w, bias)
+    held = rows.shape[0]    # the taps, and the bias's row
 
     def tile(width):
         return pl.BlockSpec((1, tr, width),
                             lambda b, c, r: (b, last - r, c))
 
     dx, dw = pallas_call(
-        functools.partial(_bwd_kernel, taps=taps, gated=gated, rc=rc),
+        functools.partial(_bwd_kernel, taps=taps, gated=gated, rc=rc,
+                          **biased),
         name="short_conv_bwd", grid=(n, d // td, t // tr),
         in_specs=[tile(wide * td),
                   pl.BlockSpec((1, HALO, wide * td), lambda b, c, r: (
                       b, jnp.maximum((last - r) * per - 1, 0), c)),
                   tile(td),
-                  pl.BlockSpec((taps, td), lambda b, c, r: (0, c))],
+                  pl.BlockSpec((held, td), lambda b, c, r: (0, c))],
         out_specs=[tile(wide * td),
-                   pl.BlockSpec((1, taps, 8, td),
+                   pl.BlockSpec((1, held, 8, td),
                                 lambda b, c, r: (b, 0, 0, c))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct((n, taps, 8, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((n, held, 8, d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((HALO, td), jnp.float32)],
         compiler_params=_params(),
-    )(x, x, dy, w.astype(jnp.float32).T)
-    return dx, jnp.sum(dw, axis=(0, 2)).T.astype(w.dtype)
+    )(x, x, dy, rows)
+    dw = jnp.sum(dw, axis=(0, 2))
+    if bias is None:
+        return dx, dw.T.astype(w.dtype)
+    return dx, dw[:taps].T.astype(w.dtype), dw[taps].astype(bias.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -378,3 +409,27 @@ def _vjp_bwd(gated, row_tile, channel_tile, res, dy):
 
 
 short_conv_kernel.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+@jax.custom_vjp
+def biased_conv_kernel(x, w, bias):
+    """silu(conv(x) + bias) by the same kernels: x (N, T, D), w (D, L),
+    bias (D,)."""
+    from . import interpret
+
+    return _fwd_call(x, w, False, interpreted=interpret(), bias=bias)
+
+
+def _biased_vjp_fwd(x, w, bias):
+    return biased_conv_kernel(x, w, bias), (x, w, bias)
+
+
+def _biased_vjp_bwd(res, dy):
+    from . import interpret
+
+    x, w, bias = res
+    return _bwd_call(x, w, dy.astype(x.dtype), False,
+                     interpreted=interpret(), bias=bias)
+
+
+biased_conv_kernel.defvjp(_biased_vjp_fwd, _biased_vjp_bwd)

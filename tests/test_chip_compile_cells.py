@@ -602,6 +602,47 @@ def test_the_head_count_a_layer_cells_step_and_the_plan_its_share_rule_read(
     assert (took["share_rows_kernel"], took["share_rows_xla"]) == (9, 0)
 
 
+def test_the_state_space_cells_step_and_the_plan_its_length_read(one_chip):
+    """The whole training step of `phi4flash-8k` as `benchmarks/run.py`
+    builds it (six layers at the published widths, 8192 rows, bf16 AMP,
+    every layer a recompute segment), compiled for the described chip,
+    nothing run.  The cell's length (ISSUE 53: 8192, because 16384 does
+    not fit) read THIS plan: arguments (aliased to the outputs) 8.37 GB
+    + temporaries 3.58 GB = 11.94 GB; at 16384 8.37 + 7.43 = 15.80 GB,
+    over the chip's 15.75 (PERF.md, PR 53).  A mamba layer is ONE
+    `selective_scan_fwd` and one `selective_scan_bwd` (its segment keeps
+    the scan's output and entry states) and its biased convolution's
+    kernels; every attention layer one forward and one backward flash
+    kernel at 40 / 20 heads, the window layer's the band kernels'."""
+    parameters, _, plan, kernels, took = _cell_step("phi4flash-8k", one_chip)
+    assert parameters == 697299072
+    assert plan["arguments"] == pytest.approx(8.37, abs=0.01)
+    assert 11.0 < plan["total"] <= 15.0, plan
+    assert (kernels["selective_scan_fwd"],
+            kernels["selective_scan_bwd"]) == (2, 2)
+    assert (kernels["short_conv_fwd"], kernels["short_conv_bwd"]) == (4, 2)
+    assert (kernels["flash_window_fwd"], kernels["flash_window_dkv"]) == (1, 1)
+    assert (kernels["flash_fwd"], kernels["flash_dkv"]) == (2, 2)
+    assert set(kernels) == {"selective_scan_fwd", "selective_scan_bwd",
+                            "short_conv_fwd", "short_conv_bwd",
+                            "flash_window_fwd", "flash_window_dkv",
+                            "flash_fwd", "flash_dkv"}
+    # no fall-back anywhere: by the step's trace (a mamba layer's
+    # forward, its forward traced again for the segment's backward
+    # pass, its backward: 32 chunks a call)
+    assert (took["selective_scans_kernel"], took["selective_scans_xla"],
+            took["selective_scan_chunks"]) == (6, 0, 6 * 32)
+    assert (took["short_convs_kernel"], took["short_convs_xla"],
+            took["short_conv_bias_calls"]) == (2, 0, 2)
+    assert (took["flash_window_calls"], took["flash_grouped_calls"]) == (2, 4)
+    assert (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"]) == (3, 0)
+    # three attention calls' (o, logsumexp) and two scans' (y, states)
+    assert took["recompute_kept_residuals"] == 5
+    assert took["recompute_kept_bytes"] >= 2 * (8192 * 5120 * 2
+                                                + 32 * 16 * 5120 * 4)
+
+
 def test_the_linear_attention_cells_step_keeps_its_inverses_under_the_plan(
         one_chip):
     """The whole training step of `qwen3next-16k` (one period of four

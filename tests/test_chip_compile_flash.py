@@ -329,6 +329,13 @@ STEP_TEXT = {
     # its 1024 x 1024 forward tile
     "laguna-16k":
     "717664188564e58e142a8c724ac3ddf0fa662d129d19618cfb93535919a837c7",
+    # new in PR 53 (the selective scan and the biased convolution
+    # through the interpreter, differential attention on the band and
+    # grouped kernels, values that cross recompute segments); every
+    # other cell keeps its parent's text: a bias that is absent and a
+    # name in `KEPT_RESIDUALS` that a step never emits leave it alone
+    "phi4flash-8k":
+    "8262a62f096c9ae6b27eeda5de0d5d97703040ecc95e775e371806904d511e32",
 }
 
 

@@ -213,6 +213,72 @@ def test_short_conv_kernels_at_the_published_shapes(one_chip, dtype, form):
     assert compiled.memory_analysis().temp_size_in_bytes < tile // 4
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_selective_scan_kernels_at_the_published_shapes(one_chip, dtype):
+    """The `selective_scan` op and its seven gradients at `phi4flash-8k`'s
+    shape, (1, 8192, 5120) channels x 16 states, in the cell's bfloat16
+    and the parity script's float32, and the biased SiLU convolution
+    that feeds it (5120 channels x 4 taps + a bias): the shape rule
+    takes both, so the scan with its gradient is TWO Mosaic kernels,
+    `selective_scan_fwd` and `selective_scan_bwd` (which rebuilds a
+    chunk's states from its entry state in VMEM), and the convolution
+    two more; each has a registered cost, none of it on the MXU, and
+    sits under its op's scope.  The backward kernel's state scratch
+    ((256 + 1) x 16 rows x 256 lanes float32) and its tiles are what
+    claim VMEM past Mosaic's default 16 MiB."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    t, d, s, taps = 8192, 5120, 16, 4
+    scan, conv = get_op_impl("selective_scan"), get_op_impl("short_conv")
+    slots = ("Delta", "ALog", "B", "C", "D", "DeltaBias")
+
+    def both(x, w, bias, ct, *rest):
+        def fn(x, w, bias, *rest):
+            ctx = OpContext(jax.random.PRNGKey(0), 0)
+            with jax.named_scope("state_space/short_conv:3"):
+                u = conv(ctx, {"X": [x], "Filter": [w], "Bias": [bias]},
+                         {"activation": "silu"})["Out"][0]
+            with jax.named_scope("state_space/selective_scan:9"):
+                return scan(ctx, dict({"U": [u]}, **{
+                    k: [v] for k, v in zip(slots, rest)}), {})["Out"][0]
+
+        o, vjp = jax.vjp(fn, x, w, bias, *rest)
+        return o, vjp(ct)
+
+    def spec(shape, kind):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
+
+    wide, narrow = spec((1, t, d), dtype), spec((1, t, s), dtype)
+    before = runtime_stats.snapshot()
+    compiled = _compile_args(
+        jax.jit(both), wide, spec((d, taps), F32), spec((d,), F32), wide,
+        wide, spec((d, s), F32), narrow, narrow, spec((d,), F32),
+        spec((d,), F32))
+    took = runtime_stats.delta(before)
+    assert (took["selective_scans_kernel"], took["selective_scans_xla"],
+            took["selective_scan_chunks"]) == (2, 0, 2 * 32)
+    assert (took["short_convs_kernel"], took["short_convs_xla"],
+            took["short_conv_bias_calls"]) == (1, 0, 1)
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "selective_scan_bwd", "selective_scan_fwd", "short_conv_bwd",
+        "short_conv_fwd"]
+    by_kernel = {r["kernel"]: r["op_type"] for r in rows if r["kernel"]}
+    assert by_kernel["selective_scan_fwd"] == "selective_scan"
+    assert by_kernel["short_conv_bwd"] == "short_conv"
+    assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 4
+    flops = {r["kernel"]: r["flops"] for r in rows if r["kernel"]}
+    assert flops["selective_scan_fwd"] == 8 * t * d * s
+    assert flops["selective_scan_bwd"] == 24 * t * d * s
+    # the states that enter the 32 chunks, float32: 10.5 MB
+    assert f"f32[1,32,{s},{d}]" in compiled.as_text()
+
+
 # heads, d_head, lanes that turn
 ROPE_SHAPES = {
     "q_128": (32, 128, None), "k_128": (4, 128, None),

@@ -1002,6 +1002,21 @@ S["gated_delta_rule"] = dict(
     attrs={"n_key_head": 1, "n_value_head": 2, "key_dim": 4,
            "value_dim": 4}, out="Out", shape=(1, 5, 8),
     grad=["QKV", "BA", "ALog", "DtBias"], tol=2e-2)
+S["selective_scan"] = dict(
+    ins={"U": f32((1, 6, 8), 291), "Delta": f32((1, 6, 8), 292),
+         "ALog": f32((8, 4), 293), "B": f32((1, 6, 4), 294),
+         "C": f32((1, 6, 4), 295), "D": f32((8,), 296),
+         "DeltaBias": f32((8,), 297)},
+    out="Out", shape=(1, 6, 8),
+    grad=["U", "Delta", "ALog", "B", "C", "D", "DeltaBias"], tol=2e-2)
+S["diff_combine"] = dict(
+    ins={"X": f32((1, 3, 32), 298), "LambdaQ1": f32((4,), 299),
+         "LambdaK1": f32((4,), 300), "LambdaQ2": f32((4,), 301),
+         "LambdaK2": f32((4,), 302), "Scale": f32((8,), 303)},
+    attrs={"n_kv_pair": 1, "lambda_init": 0.7, "epsilon": 1e-5},
+    out="Out", shape=(1, 3, 16),
+    grad=["X", "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2", "Scale"],
+    tol=2e-2)
 S["moe_dropless"] = dict(
     ins={"X": f32((6, 8), 273), "GateW": f32((8, 4), 274),
          "W1": f32((4, 8, 6), 275), "W3": f32((4, 8, 6), 276),
