@@ -40,9 +40,13 @@ option, attribute, environment variable or config key):
 
 - the single kernel: self-attention (T_q == T_k) over whole blocks,
   causal or not, no bias, no position offsets, no returned logsumexp,
-  the dq accumulator within `FUSED_ACCUMULATOR_BUDGET` (65536 positions
-  at d_head 128).  Both layouts; any d_head the forward takes.  What a
-  decoder layer asks, and Ulysses attention's local call;
+  the dq accumulator within `FUSED_ACCUMULATOR_BUDGET`, 48 MiB since
+  PR 54 (98304 positions at d_head 128; a band call, which holds dq,
+  dk and dv, 12 * d_head bytes a position: 32768 positions at d_head
+  128, 16384 at d_head 256, the widest call a cell makes: a
+  `full_attention` layer of 16 / 2 heads).  Both layouts; any d_head
+  the forward takes.  What a decoder layer asks, and Ulysses
+  attention's local call;
 - the two kernels (`flash_dkv` + `flash_dq`, each recomputing s and
   dp): a key-padding bias (its db is a third result of the dk / dv
   kernel), ring attention's calls (dynamic offsets: the step that
@@ -63,7 +67,10 @@ knows that name; `flash_dq` exists on the two-kernel path only.
 The backward's blocks are its own, 1024 x 1024 (`DEFAULT_BWD_BLOCK_*`;
 the forward keeps 256 x 1024): alone on the chip the single kernel took
 1.34 ms a call at 4096 x 16 heads against 1.40 at 512 x 1024, 1.53 at
-256 x 1024 and 2.69 for the parent's two kernels.  Their float32 score
+256 x 1024 and 2.69 for the parent's two kernels; the band kernel at
+16384 x 16 / 2 heads of 256, the budget's edge, 33.1 ms a call against
+33.5 at 512 x 1024, 34.6 at 1024 x 512, 36.2 at 512 x 512 and 45.6 for
+the two kernels (PERF.md, PR 54).  Their float32 score
 blocks pass Mosaic's default 16 MiB of scoped VMEM (17.35 MiB), so such
 a call names a limit (`_vmem_params`: only where blocks and accumulator
 need it; a call that fits names none).
@@ -142,10 +149,22 @@ DEFAULT_DIFFUSION_BLOCK = 1024
 DEFAULT_DIFFUSION_BWD_BLOCK = 1024
 NEG_INF = -1e30
 # The single backward kernel holds one head's dq, a whole sequence of
-# float32 (4 * d bytes a position).  It may take this much of v5e's
-# 128 MiB of VMEM; a longer sequence goes to the two kernels, which
-# hold blocks only
-FUSED_ACCUMULATOR_BUDGET = 32 << 20
+# float32 (4 * d bytes a position); a band call's holds dk and dv of
+# the key/value head beside it (12 * d).  They may take this much of
+# v5e's 128 MiB of VMEM; a longer sequence goes to the two kernels,
+# which hold blocks only.  48 MiB is what Mosaic was ASKED for the
+# widest call a cell makes (PR 54; 32 MiB before): the band kernel at
+# 16384 x 256 (16 / 2 heads) and at 32768 x 128 (32 / 4; with and
+# without a window, and under the block-diffusion mask) and the plain
+# kernel at 98304 x 128 compile for a described v5e under `_VMEM_LIMIT`
+# at the backward's own blocks, in bfloat16 and (the first) in float32
+# at "highest" precision
+# (tests/test_chip_compile_kernels.py, tests/test_chip_compile_flash.py),
+# and the chip ran the first in both (PERF.md, PR 54).  Not further
+# without a compile that says so: the accumulators, four float32 score
+# blocks (16 MiB) and the pipeline's two buffers of every tile have to
+# stay under that limit
+FUSED_ACCUMULATOR_BUDGET = 48 << 20
 # A backward kernel whose float32 score blocks and accumulators pass
 # Mosaic's default 16 MiB of scoped VMEM claims this much instead (the
 # verdict is Mosaic's: tests/test_chip_compile_flash.py); one that
@@ -1287,7 +1306,8 @@ def band_backward_fits(t, d):
     """Whether the backward pass of a band call is the single kernel:
     from the shape alone.  It holds dq of one query head and dk, dv of
     the key/value head it reads, whole sequences of float32 (12 * d
-    bytes a position: 24 MiB at 16384 x 128)."""
+    bytes a position: 24 MiB at 16384 x 128, 48 MiB, the budget's
+    edge, at 16384 x 256)."""
     return t * d * 4 * 3 <= FUSED_ACCUMULATOR_BUDGET
 
 

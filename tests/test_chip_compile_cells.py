@@ -653,9 +653,15 @@ def test_the_linear_attention_cells_step_keeps_its_inverses_under_the_plan(
     layer, and `gated_delta_operands_fwd` six (the forward pass's and
     the recomputed one that reads the kept inverse); the plan with the
     three inverses alive from forward to backward stays under the 15.0
-    GB the cells' cut rules use (12.37 GB before, PERF.md, PR 50)."""
+    GB the cells' cut rules use (12.37 GB before, PERF.md, PR 50).  The
+    full layer's backward pass is ONE kernel since PR 54 (16 / 2 heads
+    of 256: 48 MiB of dq, dk and dv, the budget's edge): no `flash_dq`
+    in the step, the counters 1 / 0."""
     _, _, plan, kernels, took = _cell_step("qwen3next-16k", one_chip)
     assert 12.0 < plan["total"] <= 15.0, plan
+    assert (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"]) == (1, 0)
+    assert kernels["flash_dq"] == 0
     assert (kernels["gated_delta_inverse"],
             kernels["gated_delta_operands_fwd"],
             kernels["gated_delta_operands_bwd"]) == (3, 6, 3)

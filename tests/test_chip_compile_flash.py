@@ -85,10 +85,10 @@ def test_the_cells_backward_pass_is_one_kernel(one_chip, n, dtype):
          no_limit=True),
     dict(n=1, t=12288, heads=1, dtype=BF16, block_q=256, block_k=1024,
          no_limit=True),
-    # the budget's edge: 32 MiB of dq beside 1024 x 1024 blocks
+    # the budget's edge: 48 MiB of dq beside 1024 x 1024 blocks
     dict(n=1, t=fa.FUSED_ACCUMULATOR_BUDGET // (4 * D), heads=1, dtype=BF16),
 ], ids=["folded_layout", "not_causal", "d_head_64", "512x1024_no_limit",
-        "12288_no_limit", "budget_edge_65536"])
+        "12288_no_limit", "budget_edge_98304"])
 def test_the_single_backward_kernel_elsewhere_in_its_rule(one_chip, case):
     """Everything else the rule sends to the single kernel that Mosaic
     could refuse: the folded layout, no causal mask (every dq block
@@ -169,17 +169,22 @@ def test_a_window_kernels_registered_cost_is_the_bands(one_chip):
 
 
 @pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
-def test_a_band_call_past_the_budget_compiles_the_two_kernels(one_chip,
-                                                              window):
-    """32768 positions: 48 MiB of dq, dk and dv: a kernel for dk / dv
-    over the group's heads and one for dq, blocks only in VMEM."""
-    assert not fa.band_backward_fits(32768, D)
-    kernels, took = _backward(one_chip, 1, 32768, 32, BF16, kv_heads=4,
+@pytest.mark.parametrize("past", [False, True], ids=["edge", "past"])
+def test_a_band_call_on_either_side_of_the_budget(one_chip, past, window):
+    """32768 positions at d_head 128: 48 MiB of dq, dk and dv, the most
+    the rule sends to the single kernel (at d_head 256 that is 16384
+    positions, `qwen3next-16k`'s call: tests/test_chip_compile_kernels.py),
+    under the 512 x 512 blocks of a window and the 1024 x 1024 without.
+    One block past it: a kernel for dk / dv over the group's heads and
+    one for dq, blocks only in VMEM."""
+    t = fa.FUSED_ACCUMULATOR_BUDGET // (12 * D) + 1024 * past
+    assert fa.band_backward_fits(t, D) != past
+    kernels, took = _backward(one_chip, 1, t, 32, BF16, kv_heads=4,
                               **({"window": window} if window else {}))
     prefix = "flash_window_" if window else "flash_"
-    assert kernels == [(prefix + "dkv", 2), (prefix + "dq", 1),
-                       (prefix + "fwd", 2)]
-    assert took == (0, 1)
+    backward = [("dkv", 2), ("dq", 1)] if past else [("dkv", 3)]
+    assert kernels == [(prefix + k, n) for k, n in backward + [("fwd", 2)]]
+    assert took == ((0, 1) if past else (1, 0))
 
 
 # -- a recompute segment keeps the forward kernel's residuals ---------------
@@ -318,9 +323,14 @@ STEP_TEXT = {
     # re-pinned, PR 52: (I + A)^-1 is `gated_delta_inverse`'s, named for
     # the layers' segments to keep, and `gated_delta_operands_fwd` reads
     # it (parent: f591949a..); no other cell builds the op, and a name
-    # in `KEPT_RESIDUALS` that a step never emits leaves its text alone
+    # in `KEPT_RESIDUALS` that a step never emits leaves its text alone.
+    # Re-pinned again, PR 54: its `full_attention` layer's backward pass
+    # (16 / 2 heads of 256, 48 MiB of dq, dk, dv) is inside the single
+    # kernel's budget, one `flash_dkv` with three results and no
+    # `flash_dq` (parent: cdf57c01..); the eleven other cells' calls
+    # were inside the old budget and keep their text
     "qwen3next-16k":
-    "cdf57c01cb7a443cfc0ebfa8bc26ae3a52cc3580af53e2e332f26438db851337",
+    "3587790bb726103f00f3b4dbc0bb79a162f162c72474ab4d3e0ae024b583bba6",
     "sdar-8k":
     "b012ad2dd91ad7ea22ec80bad7c11ced9f65f1b7f877c4a4d1ef9bdb7923f886",
     # new in PR 51 (a head count a layer type, the head gate, YaRN over

@@ -165,11 +165,47 @@ def test_the_band_forward_skips_what_lies_outside_the_band():
 
 
 def test_the_shape_alone_chooses_the_band_backward():
-    assert fa.band_backward_fits(16384, 128)         # 24 MiB of 32
-    assert not fa.band_backward_fits(32768, 128)
+    assert fa.band_backward_fits(16384, 128)         # 24 MiB of 48
+    assert fa.band_backward_fits(16384, 256)         # the edge (PR 54)
+    assert fa.band_backward_fits(32768, 128)
+    assert not fa.band_backward_fits(32768, 256)
     edge = fa.FUSED_ACCUMULATOR_BUDGET // (12 * 128)
     assert fa.band_backward_fits(edge, 128)
     assert not fa.band_backward_fits(edge + 1, 128)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_single_kernel_at_head_dim_256_gives_the_two_kernels_bits(
+        dtype, monkeypatch):
+    """`qwen3next-16k`'s geometry at a short length: 8 query heads of
+    256 over ONE key/value head, several blocks a side, so dk and dv sum
+    over the whole group inside the single kernel and over the group's
+    axis of the dk / dv kernel; the same bits either way."""
+    t, h, d = 128, 8, 256
+    rng = np.random.default_rng(11)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(1, t, heads * d)), dtype)
+                  for heads in (h, 1, 1, h))
+
+    def grads():
+        before = runtime_stats.snapshot()
+        out = jax.grad(lambda q, k, v: jnp.sum((w * fa.pallas_flash_attention(
+            q, k, v, None, None, True, layout="nthd", n_head=h, n_kv_head=1,
+            block_q=32, block_k=64)).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+        took = runtime_stats.delta(before)
+        return out, (took["flash_attention_backward_fused"],
+                     took["flash_attention_backward_split"])
+
+    fused, took = grads()
+    assert took == (1, 0)
+    monkeypatch.setattr(fa, "FUSED_ACCUMULATOR_BUDGET", 0)
+    split, took = grads()
+    assert took == (0, 1)
+    for name, g, s in zip("qkv", fused, split):
+        assert g.dtype == dtype and bool(jnp.any(g != 0)), name
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(s, np.float32),
+                                      err_msg="d" + name)
 
 
 @pytest.mark.parametrize("what, call", [

@@ -2,17 +2,25 @@
 chip, over a sweep of tiles.
 
     chiprun -- python tools/time_flash_window.py [--rows 16384]
-        [--heads 64] [--kv-heads 8] [--window 512]
-        [--tiles 1024,512,256] [--bwd-tiles 512,256]
+        [--heads 64] [--kv-heads 8] [--head-dim 128] [--window 512]
+        [--tiles 1024,512,256] [--bwd-tiles 512,256,512x1024]
+        [--budgets-mib 32,48]
 
-One call of 1 x `--rows` positions at `--heads` query heads of 128 over
-`--kv-heads` key/value heads, bfloat16: under `--window` keys
-(`flash_window_fwd` / `_dkv`; 0 = the whole causal prefix, `flash_fwd` /
-`flash_dkv`).  The forward alone at each square tile of `--tiles`, with
-the tiles' fill (pairs the band allows over the score entries the
-visited tiles compute) and the call as `_band_blocks` chooses it
-(`chosen`); forward + backward (a VJP against a fixed cotangent) at
-the chosen forward tile and each backward tile of `--bwd-tiles`.
+One call of 1 x `--rows` positions at `--heads` query heads of
+`--head-dim` over `--kv-heads` key/value heads, bfloat16: under
+`--window` keys (`flash_window_fwd` / `_dkv`; 0 = the whole causal
+prefix, `flash_fwd` / `flash_dkv`).  The forward alone at each square
+tile of `--tiles`, with the tiles' fill (pairs the band allows over the
+score entries the visited tiles compute) and the call as `_band_blocks`
+chooses it (`chosen`); forward + backward (a VJP against a fixed
+cotangent) at the chosen forward tile and each backward tile of
+`--bwd-tiles` (a side, or query x key sides; the chosen backward tile
+is always among them), each with the backward it ran: `kernels` 1 =
+the single kernel, 2 = `dkv` + `dq` (`band_backward_fits`, read from
+the counters around the trace).  `--budgets-mib` times the backward
+under each of these values of `FUSED_ACCUMULATOR_BUDGET`, set in THIS
+process only (how PR 54 held the single kernel at d_head 256 against
+the two before the constant moved); left out, the module's own.
 Milliseconds a call: `--repeats` calls dispatched back to back and
 waited for once; the median of five such rounds after a warm-up.  The
 last stdout line is one JSON object; the same line goes to
@@ -34,9 +42,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from paddle_tpu.observe.monitoring import runtime_stats  # noqa: E402
 from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
-
-D = 128
 
 
 def ms_a_call(fn, args, repeats):
@@ -55,9 +62,11 @@ def main():
     parser.add_argument("--rows", type=int, default=16384)
     parser.add_argument("--heads", type=int, default=64)
     parser.add_argument("--kv-heads", type=int, default=8)
+    parser.add_argument("--head-dim", type=int, default=128)
     parser.add_argument("--window", type=int, default=512)
     parser.add_argument("--tiles", default="1024,512,256")
     parser.add_argument("--bwd-tiles", default="512,256")
+    parser.add_argument("--budgets-mib", default="")
     parser.add_argument("--repeats", type=int, default=10)
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args()
@@ -65,18 +74,18 @@ def main():
     if device.platform != "tpu":
         print(json.dumps({"error": f"{device.platform} is no TPU"}))
         return 1
-    t, h, hkv = args.rows, args.heads, args.kv_heads
+    t, h, hkv, d = args.rows, args.heads, args.kv_heads, args.head_dim
     window = args.window or None
     r = np.random.default_rng(args.seed)
 
     def draw(heads):
-        return jnp.asarray(r.normal(size=(1, t, heads * D)), jnp.bfloat16)
+        return jnp.asarray(r.normal(size=(1, t, heads * d)), jnp.bfloat16)
 
     q, k, v, ct = draw(h), draw(hkv), draw(hkv), draw(h)
-    scale = D ** -0.5
+    scale = d ** -0.5
     chosen = fa._band_blocks(t, None, None, window)
     out = {"device": device.device_kind, "rows": t, "heads": h,
-           "kv_heads": hkv, "window": window, "chosen": chosen,
+           "kv_heads": hkv, "head_dim": d, "window": window, "chosen": chosen,
            "pairs_a_head": fa._Band(t, *chosen[0], window).pairs(),
            "forward": {}, "forward_backward": {}}
 
@@ -94,10 +103,20 @@ def main():
             "ms": ms_a_call(call((tile, tile), chosen[1]), (q, k, v),
                             args.repeats),
             "fill": band.pairs() / (band.blocks_allowed * tile * tile)}
-    bwd_tiles = [int(x) for x in args.bwd_tiles.split(",")] if window else []
-    for tile in dict.fromkeys(min(x, t) for x in bwd_tiles + [1024]):
-        out["forward_backward"][f"{chosen[0][0]}/{tile}"] = ms_a_call(
-            vjp(chosen[0], (tile, tile)), (q, k, v, ct), args.repeats)
+    bwd_tiles = [tuple(min(int(side), t) for side in (x.split("x") * 2)[:2])
+                 for x in args.bwd_tiles.split(",") if x]
+    budgets = [int(x) << 20 for x in args.budgets_mib.split(",") if x]
+    for budget in budgets or [fa.FUSED_ACCUMULATOR_BUDGET]:
+        fa.FUSED_ACCUMULATOR_BUDGET = budget
+        for bq, bk in dict.fromkeys(bwd_tiles + [chosen[1]]):
+            before = runtime_stats.snapshot()
+            ms = ms_a_call(vjp(chosen[0], (bq, bk)), (q, k, v, ct),
+                           args.repeats)
+            split = runtime_stats.delta(before)[
+                "flash_attention_backward_split"]
+            out["forward_backward"][
+                f"{chosen[0][0]}/{bq}x{bk}@{budget >> 20}MiB"] = {
+                    "ms": ms, "kernels": 2 if split else 1}
     line = json.dumps(out)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/time_flash_window.log", "a") as f:
