@@ -15,12 +15,30 @@ The fixtures (`topology`, `one_chip`, `dp4_mesh`) are in
 tests/conftest.py.  The tests are tests/test_chip_compile_*.py: four
 files, not one, because `--dist loadfile` gives a file to ONE worker;
 and four, not one a kernel family, because that scheduler starts the
-files in the order of their NUMBER OF TESTS, largest first, so a file
-of two compiles that take two minutes each starts last and the run
-waits for it alone (PR 41 measured it: one file 892 s, nine files
-963 s, these four 821 s, on one machine).  A heavy family rides with a
-many-test light one: keep every file here at ten tests or so, or under
-half a minute.
+files in the order of their NUMBER OF TESTS, largest first, and hands a
+worker its next file when two tests of the last are left, so a file of
+two compiles that take two minutes each starts last and the run waits
+for it alone.  The rule a reader can check: a file here must START in
+the first half of the run, which its number of tests decides, and what
+it holds must end well before the run does.  PR 55's reading (the
+driver's command, six workers, 8 cores; the run 1065 s, 6,189 s of test
+time, 101 files; tests, seconds, place in the starting order, start
+and end as the scheduler's rule replays the measured durations):
+
+    _kernels.py          38 tests  350 s  14th  starts  227  ends 578
+    _flash.py            33 tests  225 s  16th  starts  243  ends 468
+    _cells.py            18 tests  462 s  32nd  starts  438  ends 900
+    _flash_attention.py  13 tests  266 s  51st  starts  591  ends 857
+
+(at the parent 358 / 218 / 521 / 301 s, the last two ending at 1118 and
+1064 of 1336).  The run ended 34 s after its test time over six, most
+of it the workers' start-up: no file of this family is in the tail, and
+none was regrouped.  A new heavy case (the four whole-cell steps are
+50-100 s each, the latent kernels at their budget's edge 140) goes to
+`_kernels.py` or `_flash.py`, which start early; `_flash_attention.py`
+takes no more: at 13 tests it is the latest to start.  Within a file,
+cases that compile one function at one shape share the compiled object
+(`test_chip_compile_flash.py _compiled`).
 """
 
 from __future__ import annotations

@@ -59,7 +59,7 @@ def check_grad(op_type, ins_np, grad_slot, attrs=None, out_slot="Out",
         return jnp.sum(impl(ctx, ins, attrs)[out_slot][0])
 
     x0 = np.asarray(base[grad_slot][0], dtype=np.float64).astype(np.float32)
-    analytic = np.asarray(jax.grad(f)(jnp.asarray(x0)))
+    analytic = np.asarray(jax.jit(jax.grad(f))(jnp.asarray(x0)))
 
     # one vmapped+jitted evaluation over ALL 2*size perturbed inputs:
     # per-element eager loops retrace the op for every probe and made

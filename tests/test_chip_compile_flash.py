@@ -8,6 +8,7 @@ helpers; the fixtures are `tests/conftest.py`'s.
 
 from __future__ import annotations
 
+import functools
 import re
 
 import pytest
@@ -22,12 +23,12 @@ from chip_compile import BF16, F32, _compile_args
 D = 128
 
 
-def _backward(one_chip, n, t, heads, dtype, d=D, layout="nthd", causal=True,
+@functools.cache
+def _compiled(one_chip, n, t, heads, dtype, d=D, layout="nthd", causal=True,
               kv_heads=None, **blocks):
-    """Compile forward + backward of one call for the described chip:
-    ([kernel name, number of results] sorted by name, (fused, split))."""
-    from paddle_tpu.observe import cost
-
+    """Forward + backward of one call compiled for the described chip,
+    once a module for each shape: (the compiled step, (fused, split)
+    as counted around its trace)."""
     shape = (n, t, heads * d) if layout == "nthd" else (n, heads, t, d)
     kv_shape = shape if kv_heads is None else (n, t, kv_heads * d)
 
@@ -45,6 +46,16 @@ def _backward(one_chip, n, t, heads, dtype, d=D, layout="nthd", causal=True,
         *[jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
           for s in (shape, kv_shape, kv_shape)])
     took = runtime_stats.delta(before)
+    return compiled, (took["flash_attention_backward_fused"],
+                      took["flash_attention_backward_split"])
+
+
+def _backward(*args, **kwargs):
+    """([kernel name, number of results] sorted by name, (fused,
+    split)) of `_compiled`'s step."""
+    from paddle_tpu.observe import cost
+
+    compiled, took = _compiled(*args, **kwargs)
     rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
     assert {r["op_type"] for r in rows if r["kernel"]} == {"flash_attention"}
     assert all(r["flops"] > 0 for r in rows if r["kernel"])
@@ -55,8 +66,7 @@ def _backward(one_chip, n, t, heads, dtype, d=D, layout="nthd", causal=True,
             head = line.split(" custom-call(")[0].split(" = ", 1)[1]
             results[name.group(1)] = len(re.findall(r"\w+\[[\d,]*\]", head))
     assert sorted(results) == sorted(r["kernel"] for r in rows if r["kernel"])
-    return sorted(results.items()), (took["flash_attention_backward_fused"],
-                                     took["flash_attention_backward_split"])
+    return sorted(results.items()), took
 
 
 # (N, T, heads) at d_head 128, head-major, causal, no bias: what a
@@ -142,17 +152,10 @@ def test_the_window_cells_backward_pass_is_one_kernel(one_chip, window,
 def test_a_window_kernels_registered_cost_is_the_bands(one_chip):
     from paddle_tpu.observe import cost
 
-    def loss(q, k, v):
-        with jax.named_scope("flash_attention:9"):
-            o = fa.pallas_flash_attention(
-                q, k, v, None, D ** -0.5, True, layout="nthd", n_head=32,
-                n_kv_head=4, window=1024)
-        return jnp.sum(o.astype(F32))
-
-    compiled = _compile_args(
-        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-        *[jax.ShapeDtypeStruct((1, 16384, h * D), BF16, sharding=one_chip)
-          for h in (32, 4, 4)])
+    # the step `test_the_window_cells_backward_pass_is_one_kernel
+    # [window-bf16]` compiles
+    compiled, _ = _compiled(one_chip, 1, 16384, 32, BF16, kv_heads=4,
+                            window=1024)
     rows = {r["kernel"]: r for r in cost.instruction_costs(
         cost.compiled_hlo_proto(compiled)) if r["kernel"]}
     pairs = 32 * (1024 * 16384 - 1024 * 1023 // 2)

@@ -411,7 +411,7 @@ def test_gradients_of_the_sorted_form_match_the_dense_form():
         y, aux, z, _, _ = ref.experts(ins["X"], layer, cfg)
         return jnp.sum(jnp.sin(y)) + aux + z
 
-    got, want = jax.grad(system)(ins), jax.grad(dense)(ins)
+    got, want = jax.jit(jax.grad(system))(ins), jax.jit(jax.grad(dense))(ins)
     for name in ins:
         np.testing.assert_allclose(got[name], want[name], rtol=2e-5,
                                    atol=2e-5, err_msg=name)

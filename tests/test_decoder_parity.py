@@ -20,11 +20,12 @@ bound cannot be met by computing in a lower precision.
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.models import decoder, decoder_reference as ref
+
+from parity_harness import Family, batch, close, reference, system
 
 TOL = 5e-6
 SIZES = {
@@ -44,66 +45,30 @@ def config(**over):
     return cfg
 
 
-def batch(cfg, n=2, length=32, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 1))
-    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+def arguments(cfg):
+    return dict(cfg, **WEIGHTS)
 
 
-def system(cfg, feed, use_amp=False, seed=7):
-    """One forward and backward of the Program: fetched values by name
-    and the parameters' values in creation order."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False, **WEIGHTS, **cfg)
-        if use_amp:
-            main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        params = [np.asarray(scope.find_var(p.name))
-                  for p in main.all_parameters()]
-        names = ["loss", "logits", "ce", "aux", "z"]
-        fetched = exe.run(
-            main, feed=feed, scope=scope,
-            fetch_list=[m[k] for k in names] + m["counts"] + m["experts"]
-            + grads)
-    layers = cfg["num_hidden_layers"]
-    out = dict(zip(names, fetched))
-    rest = fetched[len(names):]
-    out["counts"], out["experts"] = rest[:layers], rest[layers:2 * layers]
-    out["grads"] = rest[2 * layers:]
-    return out, params
-
-
-def reference(cfg, feed, params):
-    tree = ref.params_from_list(params, cfg["num_hidden_layers"])
-    (total, parts), grads = ref.loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        cfg, **WEIGHTS)
+def _to_list(grads, cfg):
     flat = [grads["embed"]]
     for layer in grads["layers"]:
         flat += [layer[k] for k in ref.LAYER_KEYS]
-    flat += [grads["final_norm"], grads["head"]]
-    return total, parts, flat
+    return flat + [grads["final_norm"], grads["head"]]
 
 
-def close(got, want, what):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=TOL, atol=TOL, err_msg=what)
+FAMILY = Family(
+    lambda params, cfg: ref.params_from_list(params,
+                                             cfg["num_hidden_layers"]),
+    ref.loss_and_grads, _to_list)
+FETCH = ("loss", "logits", "ce", "aux", "z")
 
 
 @pytest.mark.parametrize("size", sorted(SIZES))
 def test_program_matches_the_float32_reference(size):
     cfg = config(**SIZES[size])
     feed = batch(cfg)
-    got, params = system(cfg, feed)
-    total, parts, grads = reference(cfg, feed, params)
+    got, params = system(arguments(cfg), feed, fetch=FETCH)
+    total, parts, grads = reference(FAMILY, cfg, feed, params, **WEIGHTS)
     close(got["logits"], parts["logits"], "logits")
     close(got["loss"], total, "loss")
     close(got["ce"], parts["ce"], "cross-entropy")
@@ -127,8 +92,8 @@ def test_program_matches_the_float32_reference(size):
 def test_bf16_compute_fails_the_tolerance():
     cfg = config(**SIZES["8-experts-top-2"])
     feed = batch(cfg)
-    got, params = system(cfg, feed, use_amp=True)
-    _, parts, _ = reference(cfg, feed, params)
+    got, params = system(arguments(cfg), feed, use_amp=True, fetch=FETCH)
+    _, parts, _ = reference(FAMILY, cfg, feed, params, **WEIGHTS)
     err = np.abs(np.asarray(got["logits"], np.float32)
                  - np.asarray(parts["logits"])).max()
     assert err > 20 * TOL, err
@@ -138,7 +103,7 @@ def test_tied_head_and_renormalised_top_k_follow_their_keys():
     cfg = config(tie_word_embeddings=True, norm_topk_prob=True,
                  num_hidden_layers=1, **SIZES["8-experts-top-2"])
     feed = batch(cfg, seed=3)
-    got, params = system(cfg, feed)
+    got, params = system(arguments(cfg), feed, fetch=FETCH)
     # no separate head: the reference reads the embedding transposed
     tree = ref.params_from_list(params + [np.zeros(1)], 1)
     parts = ref.forward(tree, jnp.asarray(feed["tokens"]), cfg)

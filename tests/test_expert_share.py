@@ -306,7 +306,9 @@ def test_the_gradients_of_eight_shares_add_up_to_the_uncut_references():
         with jax.default_matmul_precision("highest"):
             return jnp.sum(ref.lfm2_experts(x, layer, CFG)[0] * ct)
 
-    want = dict(zip(names, jax.grad(uncut, argnums=range(5))(
+    # (each gradient compiled as one function: op by op, the 64
+    # experts' loop compiles every primitive by itself)
+    want = dict(zip(names, jax.jit(jax.grad(uncut, argnums=range(5)))(
         *[ins[k] for k in names])))
     summed = {k: np.zeros(ins[k].shape, np.float64) for k in ("X", "GateW")}
     for rank in range(8):
@@ -318,7 +320,7 @@ def test_the_gradients_of_eight_shares_add_up_to_the_uncut_references():
             o = run(dict(mine, **dict(zip(names, vals))), attrs)
             return jnp.sum(o["Out"][0] * ct)
 
-        got = dict(zip(names, jax.grad(part, argnums=range(5))(
+        got = dict(zip(names, jax.jit(jax.grad(part, argnums=range(5)))(
             *[mine[k] for k in names])))
         for k in summed:
             summed[k] += np.asarray(got[k], np.float64)

@@ -114,8 +114,8 @@ def test_their_gradients_add_up_to_the_uncut_layers_too():
         def scalar(*vals):
             return jnp.sum(fn(dict(p, **dict(zip(NAMES, vals)))) * ct)
 
-        return dict(zip(NAMES, jax.grad(scalar, argnums=range(len(NAMES)))(
-            *[p[k] for k in NAMES])))
+        return dict(zip(NAMES, jax.jit(jax.grad(
+            scalar, argnums=range(len(NAMES))))(*[p[k] for k in NAMES])))
 
     want = of(lambda q: uncut(q)[0])
     once = of(shared_expert)

@@ -1,6 +1,8 @@
 """Pallas flash attention vs composed XLA reference (interpret mode on
 CPU; the same kernel runs compiled on TPU)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -224,6 +226,24 @@ BLOCK_IDS = ["1_block", "2_blocks", "4_blocks", "wide_k", "wide_q",
              "4_blocks_wide_k"]
 
 
+@functools.cache
+def _path_grads(path, blocks, block_q, block_k, causal, layout, dtype):
+    """q, k, v and the weight of one geometry (one sequence, two heads
+    of 128, T of `blocks` of the larger block) in float32, and dq, dk,
+    dv through the kernels on `path` with the operands in `dtype`,
+    which the counters must say the traced backward took.  Once a
+    module: the two tests below read the same calls."""
+    t = blocks * max(block_q, block_k)
+    q, k, v, w = _qkvw(1, 2, t, 128, seed=blocks + block_k)
+    with pytest.MonkeyPatch.context() as patch:
+        _backward_path(patch, path)
+        before = _snapshot()
+        got = _flash_grads(*(x.astype(dtype) for x in (q, k, v, w)), layout,
+                           causal, block_q, block_k)
+        assert _took(before) == ((1, 0) if path == "one_kernel" else (0, 1))
+    return (q, k, v, w), got
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("layout", ["nhtd", "nthd"])
@@ -231,23 +251,18 @@ BLOCK_IDS = ["1_block", "2_blocks", "4_blocks", "wide_k", "wide_q",
 @pytest.mark.parametrize("blocks, block_q, block_k", BLOCKS, ids=BLOCK_IDS)
 @pytest.mark.parametrize("path", ["one_kernel", "two_kernels"])
 def test_both_backward_paths_give_the_reference_gradients(
-        monkeypatch, path, blocks, block_q, block_k, causal, layout, dtype):
+        path, blocks, block_q, block_k, causal, layout, dtype):
     """dq, dk and dv, the single backward kernel and the two, over T of
     1, 2 and 4 blocks (diagonal, below-diagonal and skipped block pairs)
     and block_q != block_k (a dq block then completes off the
     diagonal's corner, and a pass over the query blocks may complete
     two or none), causal and not, in both operand layouts; the counters
     say which path a traced backward took."""
-    _backward_path(monkeypatch, path)
-    t = blocks * max(block_q, block_k)
-    q, k, v, w = _qkvw(1, 2, t, 128, seed=blocks + block_k)
+    (q, k, v, w), got = _path_grads(path, blocks, block_q, block_k, causal,
+                                    layout, dtype)
     want = jax.grad(
         lambda *a: jnp.sum(_ref_attention(*a, causal=causal) * w),
         argnums=(0, 1, 2))(q, k, v)
-    before = _snapshot()
-    got = _flash_grads(*(x.astype(dtype) for x in (q, k, v, w)), layout,
-                       causal, block_q, block_k)
-    assert _took(before) == ((1, 0) if path == "one_kernel" else (0, 1))
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape and g.dtype == dtype, name
         if dtype == jnp.float32:    # this file's limits for a gradient
@@ -259,25 +274,25 @@ def test_both_backward_paths_give_the_reference_gradients(
                 atol=4e-2 * float(jnp.abs(r).max()))
 
 
+# three of the geometries above (their calls are made once), and four
+# query blocks over ONE key block
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("layout", ["nhtd", "nthd"])
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-@pytest.mark.parametrize("block_q, block_k", [(128, 128), (128, 256),
-                                              (256, 128), (128, 512)],
-                         ids=["square", "wide_k", "wide_q", "4_q_a_k"])
-def test_the_two_backward_paths_agree_to_the_bit(monkeypatch, block_q,
-                                                  block_k, causal, layout,
-                                                  dtype):
+@pytest.mark.parametrize("blocks, block_q, block_k", [
+    (4, 128, 128), (2, 128, 256), (2, 256, 128), (1, 128, 512)],
+    ids=["square", "wide_k", "wide_q", "4_q_a_k"])
+def test_the_two_backward_paths_agree_to_the_bit(blocks, block_q, block_k,
+                                                  causal, layout, dtype):
     """Same terms in the same order: the single kernel sums dk / dv
     over the query blocks and dq over the key blocks as the two do, from
     the same p and ds."""
-    args = _qkvw(2, 2, 1024, 128, seed=11, dtype=dtype)
-    grads = {}
-    for path in ("one_kernel", "two_kernels"):
-        _backward_path(monkeypatch, path)
-        grads[path] = _flash_grads(*args, layout, causal, block_q, block_k)
-    for a, b in zip(grads["one_kernel"], grads["two_kernels"]):
+    _, one = _path_grads("one_kernel", blocks, block_q, block_k, causal,
+                         layout, dtype)
+    _, two = _path_grads("two_kernels", blocks, block_q, block_k, causal,
+                         layout, dtype)
+    for a, b in zip(one, two):
         np.testing.assert_array_equal(a, b)
 
 

@@ -7,6 +7,8 @@ forward pass and the op are tests/test_expert_share.py's, Mosaic's own
 checks tests/test_chip_compile_flash_attention.py's.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -61,6 +63,24 @@ def _took(before):
             took["flash_gqa_backward_split"])
 
 
+@functools.cache
+def _path_grads(path, blocks, block_q, block_k, heads, kv, dtype):
+    """q, k, v and the weight of one geometry (two sequences, T of
+    `blocks` of the larger block) in float32, and dq, dk, dv through
+    the kernels on `path` with the operands in `dtype`, which the
+    counters must say the traced backward took.  Once a module: the two
+    tests below read the same calls."""
+    t = blocks * max(block_q, block_k)
+    *args, w = operands(2, t, heads, kv, seed=blocks + heads)
+    with pytest.MonkeyPatch.context() as patch:
+        _backward_path(patch, path)
+        before = runtime_stats.snapshot()
+        got = _grads([a.astype(dtype) for a in args], w, heads, kv, block_q,
+                     block_k)
+        assert _took(before) == ((1, 0) if path == "one_kernel" else (0, 1))
+    return args, w, got
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("heads, kv", [(8, 2), (4, 4)], ids=["gqa", "mha"])
@@ -70,22 +90,17 @@ def _took(before):
                        "wide_k", "4_blocks_wide_k"])
 @pytest.mark.parametrize("path", ["one_kernel", "two_kernels"])
 def test_both_backward_paths_give_the_dense_gradients(
-        monkeypatch, path, blocks, block_q, block_k, heads, kv, dtype):
+        path, blocks, block_q, block_k, heads, kv, dtype):
     """dq, dk and dv, the single backward kernel and the two, over T of
     1, 2 and 4 blocks (diagonal, below-diagonal and skipped blocks) and
     block_q != block_k (a dq block then completes off the diagonal's
     corner, and a pass over the query blocks may complete two or none),
     at 4 query heads a key/value head (dk / dv sum over the group's
     query tiles, the outer axis of the single kernel) and at one."""
-    _backward_path(monkeypatch, path)
-    t = blocks * max(block_q, block_k)
-    *args, w = operands(2, t, heads, kv, seed=blocks + heads)
+    args, w, got = _path_grads(path, blocks, block_q, block_k, heads, kv,
+                               dtype)
     want = jax.grad(lambda *a: jnp.sum(dense(*a, heads, kv) * w),
                     argnums=(0, 1, 2))(*args)
-    before = runtime_stats.snapshot()
-    got = _grads([a.astype(dtype) for a in args], w, heads, kv, block_q,
-                 block_k)
-    assert _took(before) == ((1, 0) if path == "one_kernel" else (0, 1))
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape and g.dtype == dtype, name   # kv heads wide
         if dtype == jnp.float32:    # tests/test_expert_share.py's limits
@@ -97,24 +112,23 @@ def test_both_backward_paths_give_the_dense_gradients(
                 atol=4e-2 * float(jnp.abs(r).max()))
 
 
+# T of 128 in four blocks of the larger block (three of them geometries
+# of the test above: their calls are made once)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("heads, kv, block_q, block_k", [
     (8, 2, 32, 32), (8, 2, 16, 32), (8, 2, 32, 16), (4, 4, 16, 32),
     (16, 2, 32, 8)], ids=["gqa", "gqa_wide_k", "gqa_wide_q", "mha_wide_k",
                           "8_a_group_wide_q"])
-def test_the_two_backward_paths_agree_to_the_bit(monkeypatch, heads, kv,
-                                                  block_q, block_k, dtype):
+def test_the_two_backward_paths_agree_to_the_bit(heads, kv, block_q, block_k,
+                                                  dtype):
     """Same terms in the same order: the single kernel sums dq over the
     key blocks, and dk / dv over the group's query tiles and then the
     query blocks, as the two do."""
-    *args, w = operands(2, 128, heads, kv, seed=11)
-    args = [a.astype(dtype) for a in args]
-    grads = {}
-    for path in ("one_kernel", "two_kernels"):
-        _backward_path(monkeypatch, path)
-        grads[path] = _grads(args, w, heads, kv, block_q, block_k)
-    for a, b in zip(grads["one_kernel"], grads["two_kernels"]):
+    *_, one = _path_grads("one_kernel", 4, block_q, block_k, heads, kv, dtype)
+    *_, two = _path_grads("two_kernels", 4, block_q, block_k, heads, kv,
+                          dtype)
+    for a, b in zip(one, two):
         np.testing.assert_array_equal(a, b)
 
 

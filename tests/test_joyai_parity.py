@@ -34,6 +34,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
 
 import reference_joyai as ref  # noqa: E402
+import parity_harness as harness  # noqa: E402
+from parity_harness import (Family, draw_expert_biases,  # noqa: E402
+                            expert_bias_names, reference, system)
 
 TOL = 5e-6
 LAMBDA = 0.3
@@ -62,82 +65,25 @@ def builder_args(cfg):
     return dict({RENAMED.get(k, k): v for k, v in cfg.items()}, **EQUATIONS)
 
 
-def batch(cfg, n=2, length=16, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 2))
-    return {"tokens": ids[:, :-2], "labels": ids[:, 1:-1],
-            "next_labels": ids[:, 2:]}
+def arguments(cfg):
+    return dict(builder_args(cfg), mtp_loss_weight=LAMBDA, **NO_AUX)
 
 
-def draw_biases(main, scope, seed):
-    rng = np.random.default_rng(seed)
-    names = [n for n in main.global_block().vars
-             if n.endswith(".expert_bias")]
-    # creation order: the main model's routed layers, then the module's
-    names.sort(key=lambda n: (n.startswith("mtp/"), n))
-    biases = []
-    for name in names:
-        assert not np.asarray(scope.find_var(name)).any()
-        biases.append(rng.normal(0, 0.05, scope.find_var(name).shape)
-                      .astype(np.float32))
-        scope.set_var(name, biases[-1])
-    return names, biases
+FAMILY = Family(ref.params_from_list, ref.loss_and_grads, ref.grads_to_list)
+FETCH = ("loss", "ce", "mtp_ce", "logits", "mtp_logits")
+batch = functools.partial(harness.batch, length=16, ahead=2)
+close = functools.partial(harness.close, tol=TOL)
 
 
-def system(cfg, feed, use_amp=False, seed=7):
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False, mtp_loss_weight=LAMBDA,
-                                **NO_AUX, **builder_args(cfg))
-        if use_amp:
-            main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        _, biases = draw_biases(main, scope, seed)
-        params = [np.asarray(scope.find_var(p.name))
-                  for p in main.all_parameters()]
-        routed = len(m["counts"])
-        fetched = exe.run(
-            main, feed=feed, scope=scope,
-            fetch_list=[m["loss"], m["ce"], m["mtp_ce"], m["logits"],
-                        m["mtp_logits"]] + m["counts"] + m["experts"]
-            + grads)
-    out = dict(zip(("loss", "ce", "mtp_ce", "logits", "mtp_logits"),
-                   fetched))
-    out.update(counts=fetched[5:5 + routed],
-               experts=fetched[5 + routed:5 + 2 * routed],
-               grads=fetched[5 + 2 * routed:],
-               names=[p.name for p in main.all_parameters()])
-    return out, params, biases
-
-
-def reference(cfg, feed, params, biases, q_block=None):
-    tree = ref.params_from_list(params, cfg, biases)
-    (total, parts), grads = ref.loss_and_grads(
-        tree, *(jnp.asarray(feed[k]) for k in ("tokens", "labels",
-                                               "next_labels")),
-        cfg, LAMBDA, q_block)
-    return total, parts, ref.grads_to_list(grads, cfg)
-
-
-@functools.lru_cache(maxsize=None)
 def float32_run():
-    """One float32 run of the system and of the reference on it, for
-    the tests that only read them."""
+    """The float32 run of the system and of the reference on it that
+    most tests read (the harness remembers both)."""
     feed = batch(CONFIG)
-    got, params, biases = system(CONFIG, feed)
-    return feed, got, params, biases, reference(CONFIG, feed, params, biases)
-
-
-def close(got, want, what, tol=TOL):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=tol, atol=tol, err_msg=what)
+    got, params = system(arguments(CONFIG), feed, fetch=FETCH,
+                         after_startup=draw_expert_biases)
+    return feed, got, params, got["drawn"], reference(
+        FAMILY, CONFIG, feed, params, mtp_loss_weight=LAMBDA,
+        drawn=got["drawn"])
 
 
 def test_the_builders_creation_order_is_the_references_names():
@@ -199,7 +145,9 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
     """What `benchmarks/joyai_parity.py` runs on the chip so that 8192
     positions fit."""
     feed, _, params, biases, (plain, _, want) = float32_run()
-    blocked, _, got = reference(CONFIG, feed, params, biases, q_block=4)
+    blocked, _, got = reference(FAMILY, CONFIG, feed, params,
+                                mtp_loss_weight=LAMBDA, drawn=biases,
+                                q_block=4)
     close(blocked, plain, "loss")
     for w, g in zip(want, got):
         close(g, w, "gradient")
@@ -226,7 +174,8 @@ def test_the_mapping_between_the_two_layouts_is_a_permutation():
 
 def test_bf16_compute_fails_the_tolerance():
     feed, _, _, _, (_, parts, _) = float32_run()
-    got, _, _ = system(CONFIG, feed, use_amp=True)      # the same seed
+    got, _ = system(arguments(CONFIG), feed, use_amp=True, fetch=FETCH,
+                    after_startup=draw_expert_biases)     # the same seed
     for key in ("logits", "mtp_logits"):
         err = np.abs(np.asarray(got[key], np.float32)
                      - np.asarray(parts[key])).max()
@@ -255,7 +204,8 @@ def test_one_adamw_step_and_the_bias_update_follow_the_reference():
             expert_bias_update_rate=rate, **NO_AUX, **builder_args(CONFIG))
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
-        bias_names, biases = draw_biases(main, scope, 5)
+        bias_names = expert_bias_names(main)
+        biases = draw_expert_biases(main, scope, 5)
         names = [p.name for p in main.all_parameters()]
         before = [np.asarray(scope.find_var(n)).copy() for n in names]
         adam = [o for o in main.global_block().ops if o.type == "adam"][0]
@@ -273,7 +223,8 @@ def test_one_adamw_step_and_the_bias_update_follow_the_reference():
                                      "mtp_loss": m["mtp_ce"].name}
     lr_now = float(np.asarray(lr_now).reshape(-1)[0])
     assert 0 < lr_now <= lr
-    _, parts, grads = reference(CONFIG, feed, before, biases)
+    _, parts, grads = reference(FAMILY, CONFIG, feed, before,
+                                mtp_loss_weight=LAMBDA, drawn=biases)
     grads = [np.asarray(g, np.float64) for g in grads]
     norm = np.sqrt(sum((g * g).sum() for g in grads))
     assert norm > clip                  # the clip is in the comparison

@@ -25,6 +25,7 @@ tests/test_mellum_parity.py (largest seen here 2e-8 on a gradient,
 1e-6 on a logit).
 """
 
+import functools
 import os
 import sys
 
@@ -46,6 +47,8 @@ from op_test import run_op
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmarks"))
 import reference_laguna as ref  # noqa: E402
+import parity_harness as harness  # noqa: E402
+from parity_harness import Family, close, reference, system  # noqa: E402
 
 TOL = 5e-6
 NO_AUX = dict(aux_loss_weight=0.0, z_loss_weight=0.0)
@@ -104,54 +107,12 @@ def arguments(cfg):
     return args
 
 
-def batch(cfg, n=2, length=LENGTH, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 1))
-    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+def build_arguments(cfg, **build):
+    return dict(arguments(cfg), **NO_AUX, **build)
 
 
-def system(cfg, feed, use_amp=False, seed=7, **build):
-    """One forward and backward of the Program: what was fetched and
-    the parameters in creation order."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False, **NO_AUX, **build,
-                                **arguments(cfg))
-        if use_amp:
-            main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        params = [np.asarray(scope.find_var(p.name))
-                  for p in main.all_parameters()]
-        routed = len(m["counts"])
-        fetched = exe.run(
-            main, feed=feed, scope=scope,
-            fetch_list=[m["loss"], m["logits"]] + m["counts"]
-            + m["experts"] + grads)
-    out = {"loss": fetched[0], "logits": fetched[1],
-           "counts": fetched[2:2 + routed],
-           "experts": fetched[2 + routed:2 + 2 * routed],
-           "grads": fetched[2 + 2 * routed:], "main": main}
-    return out, params
-
-
-def reference(cfg, feed, params, q_block=None):
-    tree = ref.params_from_list(params, cfg)
-    (total, parts), grads = ref.loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]), cfg,
-        q_block)
-    return total, parts, ref.flat_leaves(grads, cfg)
-
-
-def close(got, want, what, tol=TOL):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=tol, atol=tol, err_msg=what)
+FAMILY = Family(ref.params_from_list, ref.loss_and_grads, ref.flat_leaves)
+batch = functools.partial(harness.batch, length=LENGTH)
 
 
 # -- (a) the program against the reference ---------------------------------
@@ -161,10 +122,9 @@ def close(got, want, what, tol=TOL):
 def test_program_matches_the_float32_reference(share, recompute):
     cfg = config(**SHARES[share])
     feed = batch(cfg)
-    before = runtime_stats.snapshot()
-    got, params = system(cfg, feed, recompute=recompute)
-    took = runtime_stats.delta(before)
-    total, parts, grads = reference(cfg, feed, params)
+    got, params = system(build_arguments(cfg, recompute=recompute), feed)
+    took = got["took"]
+    total, parts, grads = reference(FAMILY, cfg, feed, params)
     close(got["logits"], parts["logits"], "logits")
     close(got["loss"], total, "loss")
     assert len(got["counts"]) == 4                  # the sparse layers
@@ -222,7 +182,7 @@ def test_one_adamw_step_is_the_hand_rolled_one():
         before = [np.asarray(scope.find_var(n)).copy() for n in names]
         exe.run(main, feed=feed, scope=scope, fetch_list=[m["loss"]])
         after = [np.asarray(scope.find_var(n)) for n in names]
-    _, _, grads = reference(cfg, feed, before)
+    _, _, grads = reference(FAMILY, cfg, feed, before)
     grads = [np.asarray(g) for g in grads]
     norm = np.sqrt(sum(float((g.astype(np.float64) ** 2).sum())
                        for g in grads))
@@ -246,9 +206,9 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
     recomputed in its backward pass.  Same numbers."""
     cfg = config(**SHARES["rank-1-of-4"])
     feed = batch(cfg)
-    _, params = system(cfg, feed)
-    plain, _, want = reference(cfg, feed, params)
-    blocked, _, got = reference(cfg, feed, params, q_block=16)
+    _, params = system(build_arguments(cfg), feed)
+    plain, _, want = reference(FAMILY, cfg, feed, params)
+    blocked, _, got = reference(FAMILY, cfg, feed, params, q_block=16)
     close(blocked, plain, "loss")
     for w, g in zip(want, got):
         close(g, w, "gradient")
@@ -258,9 +218,9 @@ def test_the_gate_and_the_scopes_are_in_the_program():
     """`attention_head_gate` inside `sliding_attention` /
     `full_attention`, one a layer; without the gate no such scope and
     no gate parameter."""
-    cfg = config(**SHARES["rank-1-of-4"])
+    cfg = config()
     feed = batch(cfg, n=1)
-    got, params = system(cfg, feed)
+    got, params = system(build_arguments(cfg), feed)
 
     def scopes(main):
         return [op.attrs.get("__name_scope__", "") for b in main.blocks
@@ -284,8 +244,9 @@ def test_the_gate_and_the_scopes_are_in_the_program():
 def test_bf16_amp_stays_in_its_band_and_fails_the_float32_tolerance():
     cfg = config(**SHARES["rank-1-of-4"])
     feed = batch(cfg)
-    got, params = system(cfg, feed, use_amp=True, recompute="layer")
-    _, parts, grads = reference(cfg, feed, params)
+    got, params = system(build_arguments(cfg, recompute="layer"), feed,
+                         use_amp=True)
+    _, parts, grads = reference(FAMILY, cfg, feed, params)
     same = all(
         (np.sort(e, axis=-1) == np.sort(np.asarray(w), axis=-1)).all(-1).all()
         for e, w in zip(got["experts"], parts["experts"]))

@@ -7,6 +7,8 @@ gradients, the ONE rotary key's summed over the heads), the
 other.  Mosaic's own checks are tests/test_chip_compile_kernels.py's.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -78,6 +80,30 @@ def _backward_path(monkeypatch, path):
                         {"one_kernel": 1 << 40, "two_kernels": 0}[path])
 
 
+@functools.cache
+def _path_grads(path, blocks, block_q, block_k, heads, dtype):
+    """The operands and the weight of one geometry (one sequence, T of
+    `blocks` of the larger block) in float32, and all five gradients
+    through the kernels on `path` with the operands in `dtype`, which
+    the counters must say the traced backward took.  Once a module: the
+    two tests below read the same calls."""
+    t = blocks * max(block_q, block_k)
+    *args, w = operands(1, t, heads, seed=blocks + heads)
+    with pytest.MonkeyPatch.context() as patch, \
+            jax.default_matmul_precision("highest"):
+        _backward_path(patch, path)
+        before = runtime_stats.snapshot()
+        got = jax.grad(
+            lambda *a: jnp.sum(flash_mla.flash_mla(
+                *a, block_q=block_q, block_k=block_k).astype(jnp.float32) * w),
+            argnums=range(5))(*(a.astype(dtype) for a in args))
+        took = runtime_stats.delta(before)
+        assert (took["flash_mla_backward_fused"],
+                took["flash_mla_backward_split"]) == (
+                    (1, 0) if path == "one_kernel" else (0, 1))
+    return args, w, got
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("heads", [2, 4])
@@ -87,28 +113,15 @@ def _backward_path(monkeypatch, path):
                         "wide_k", "4_blocks_wide_k"])
 @pytest.mark.parametrize("path", ["one_kernel", "two_kernels"])
 def test_both_backward_paths_give_the_dense_gradients(
-        monkeypatch, path, blocks, block_q, block_k, heads, dtype):
+        path, blocks, block_q, block_k, heads, dtype):
     """All five gradients, the single backward kernel and the two, over
     T of 1, 2 and 4 blocks and block_q != block_k (a dq block then
     completes off the diagonal's corner, and a pass may complete two or
     none), 2 and 4 heads (the rotary key's gradient sums over the
     pairs, the outer axis of the single kernel)."""
-    _backward_path(monkeypatch, path)
-    t = blocks * max(block_q, block_k)
-    *args, w = operands(1, t, heads, seed=blocks + heads)
+    args, w, got = _path_grads(path, blocks, block_q, block_k, heads, dtype)
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=range(5))(
         *args)
-    args = [a.astype(dtype) for a in args]
-    before = runtime_stats.snapshot()
-    with jax.default_matmul_precision("highest"):
-        got = jax.grad(
-            lambda *a: jnp.sum(flash_mla.flash_mla(
-                *a, block_q=block_q, block_k=block_k).astype(jnp.float32) * w),
-            argnums=range(5))(*args)
-    took = runtime_stats.delta(before)
-    assert (took["flash_mla_backward_fused"],
-            took["flash_mla_backward_split"]) == (
-                (1, 0) if path == "one_kernel" else (0, 1))
     # float32: today's limit; bfloat16 operands: p and ds are cast to
     # 8 bits of mantissa before their dots
     limit = 5e-5 if dtype == jnp.float32 else 4e-2
@@ -120,17 +133,13 @@ def test_both_backward_paths_give_the_dense_gradients(
                                    err_msg=name)
 
 
-def test_the_two_backward_paths_agree_to_the_bit(monkeypatch):
+def test_the_two_backward_paths_agree_to_the_bit():
     """Same arithmetic in the same order: the single kernel sums dq
-    over the key blocks and dk over the query blocks as the two do."""
-    *args, w = operands(2, 256, 4, seed=11)
-    grads = {}
-    for path in ("one_kernel", "two_kernels"):
-        _backward_path(monkeypatch, path)
-        grads[path] = jax.grad(
-            lambda *a: jnp.sum(flash_mla.flash_mla(
-                *a, block_q=64, block_k=128) * w), argnums=range(5))(*args)
-    for a, b in zip(grads["one_kernel"], grads["two_kernels"]):
+    over the key blocks and dk over the query blocks as the two do (the
+    `wide_k` geometry at four heads, whose calls the test above makes)."""
+    *_, one = _path_grads("one_kernel", 2, 64, 128, 4, jnp.float32)
+    *_, two = _path_grads("two_kernels", 2, 64, 128, 4, jnp.float32)
+    for a, b in zip(one, two):
         np.testing.assert_array_equal(a, b)
 
 

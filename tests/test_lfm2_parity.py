@@ -19,10 +19,14 @@ tolerance by orders of magnitude.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.models import decoder, decoder_reference as ref
+
+from parity_harness import (Family, batch, close, draw_expert_biases,
+                            reference, system)
 
 TOL = 5e-6
 NO_AUX = dict(aux_loss_weight=0.0, z_loss_weight=0.0)   # the config has none
@@ -48,73 +52,28 @@ def config(**over):
     return cfg
 
 
-def batch(cfg, n=2, length=32, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 1))
-    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+def arguments(cfg):
+    return dict(cfg, **NO_AUX)
 
 
-def system(cfg, feed, use_amp=False, seed=7):
-    """One forward and backward of the Program: what was fetched, the
-    parameters in creation order and the selection biases it ran
-    with."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False, **NO_AUX, **cfg)
-        if use_amp:
-            main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        rng = np.random.default_rng(seed)
-        biases = []
-        for name in sorted(n for n in main.global_block().vars
-                           if n.endswith(".expert_bias")):
-            assert not np.asarray(scope.find_var(name)).any()
-            biases.append(rng.normal(0, 0.05, scope.find_var(name).shape)
-                          .astype(np.float32))
-            scope.set_var(name, biases[-1])
-        params = [np.asarray(scope.find_var(p.name))
-                  for p in main.all_parameters()]
-        routed = len(m["counts"])
-        fetched = exe.run(
-            main, feed=feed, scope=scope,
-            fetch_list=[m["loss"], m["logits"]] + m["counts"]
-            + m["experts"] + grads)
-    out = {"loss": fetched[0], "logits": fetched[1],
-           "counts": fetched[2:2 + routed],
-           "experts": fetched[2 + routed:2 + 2 * routed],
-           "grads": fetched[2 + 2 * routed:]}
-    return out, params, biases
-
-
-def reference(cfg, feed, params, biases):
-    tree = ref.lfm2_params_from_list(params, cfg, biases)
-    (total, parts), grads = ref.lfm2_loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]), cfg)
+def _to_list(grads, cfg):
     flat = [grads["embed"]]
     for i, layer in enumerate(grads["layers"]):
         flat += [layer[k] for k in ref.lfm2_layer_keys(cfg, i)]
-    flat += [grads["final_norm"], grads["head"]]
-    return total, parts, flat
+    return flat + [grads["final_norm"], grads["head"]]
 
 
-def close(got, want, what):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=TOL, atol=TOL, err_msg=what)
+FAMILY = Family(ref.lfm2_params_from_list, ref.lfm2_loss_and_grads, _to_list)
 
 
 @pytest.mark.parametrize("share", sorted(SHARES))
 def test_program_matches_the_float32_reference(share):
     cfg = config(**SHARES[share])
     feed = batch(cfg)
-    got, params, biases = system(cfg, feed)
-    total, parts, grads = reference(cfg, feed, params, biases)
+    got, params = system(arguments(cfg), feed,
+                         after_startup=draw_expert_biases)
+    total, parts, grads = reference(FAMILY, cfg, feed, params,
+                                     drawn=got["drawn"])
     close(got["logits"], parts["logits"], "logits")
     close(got["loss"], total, "loss")
     k, tokens = cfg["num_experts_per_tok"], feed["tokens"].size
@@ -148,14 +107,16 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
     recomputed in its backward pass.  Same numbers."""
     cfg = config(**SHARES["rank-1-of-4"])
     feed = batch(cfg)
-    _, params, biases = system(cfg, feed)
-    tree = ref.lfm2_params_from_list(params, cfg, biases)
-    args = (tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-            cfg)
-    (plain, _), want = ref.lfm2_loss_and_grads(*args)
-    (blocked, _), got = ref.lfm2_loss_and_grads(*args, q_block=8)
+    got, params = system(arguments(cfg), feed,
+                         after_startup=draw_expert_biases)
+    tree = ref.lfm2_params_from_list(params, cfg, got["drawn"])
+    args = (jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]), cfg)
+    # (each compiled as one function, as the chip's script runs them)
+    (plain, _), want = jax.jit(
+        lambda t: ref.lfm2_loss_and_grads(t, *args))(tree)
+    (blocked, _), got = jax.jit(
+        lambda t: ref.lfm2_loss_and_grads(t, *args, q_block=8))(tree)
     close(blocked, plain, "loss")
-    import jax
 
     leaves, other = jax.tree.leaves(want), jax.tree.leaves(got)
     assert len(leaves) == len(other) > len(params)      # + the biases
@@ -169,10 +130,11 @@ def test_the_selection_bias_moves_the_choice_and_not_the_weight():
     ones only: no gradient is made for a bias."""
     cfg = config()
     feed = batch(cfg)
-    got, params, biases = system(cfg, feed)
-    parts = ref.lfm2_forward(
-        ref.lfm2_params_from_list(params, cfg, None),
-        jnp.asarray(feed["tokens"]), cfg)
+    got, params = system(arguments(cfg), feed,
+                         after_startup=draw_expert_biases)
+    parts = jax.jit(lambda tree: ref.lfm2_forward(
+        tree, jnp.asarray(feed["tokens"]), cfg))(
+        ref.lfm2_params_from_list(params, cfg, None))
     assert (np.sort(got["experts"][0], axis=-1)
             != np.sort(np.asarray(parts["experts"][0]), axis=-1)).any()
     n_layer_params = sum(len(ref.lfm2_layer_keys(cfg, i)) for i in range(3))
@@ -182,8 +144,10 @@ def test_the_selection_bias_moves_the_choice_and_not_the_weight():
 def test_bf16_compute_fails_the_tolerance():
     cfg = config()
     feed = batch(cfg)
-    got, params, biases = system(cfg, feed, use_amp=True)
-    _, parts, _ = reference(cfg, feed, params, biases)
+    got, params = system(arguments(cfg), feed, use_amp=True,
+                         after_startup=draw_expert_biases)
+    _, parts, _ = reference(FAMILY, cfg, feed, params,
+                                     drawn=got["drawn"])
     err = np.abs(np.asarray(got["logits"], np.float32)
                  - np.asarray(parts["logits"])).max()
     assert err > 20 * TOL, err

@@ -23,14 +23,12 @@ leaf within 0.15 of its norm (seen 0.03-0.06) on a case whose routing
 does not flip, and it must MISS the float32 tolerance by 20 x.
 """
 
+import functools
 import os
 import sys
 
 import numpy as np
 import pytest
-
-import jax
-import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.models import decoder
@@ -39,6 +37,9 @@ from paddle_tpu.ops.decoder import rope_frequencies
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmarks"))
 import reference_mellum as ref  # noqa: E402
+import parity_harness as harness  # noqa: E402
+from parity_harness import (Family, build_and_run, close,  # noqa: E402
+                            reference, system)
 
 TOL = 5e-6
 NO_AUX = dict(aux_loss_weight=0.0, z_loss_weight=0.0)
@@ -52,6 +53,10 @@ PUBLISHED_ROPE = {
 SHARES = {"whole-layer": dict(num_experts=8),
           "rank-1-of-4": dict(num_experts=2, expert_parallel_size=4,
                               expert_parallel_rank=1)}
+# one layer of each kind with every expert held: the shallowest toy for
+# a test of ONE mechanism that is neither the period nor the share
+TWO_LAYERS = dict(num_hidden_layers=2,
+                  layer_types=["sliding_attention", "full_attention"])
 
 
 def config(**over):
@@ -78,54 +83,13 @@ def config(**over):
     return cfg
 
 
-def batch(cfg, n=2, length=48, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 1))
-    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+def arguments(cfg, **build):
+    return dict(cfg, **NO_AUX, **build)
 
 
-def system(cfg, feed, use_amp=False, seed=7, **build):
-    """One forward and backward of the Program: what was fetched and
-    the parameters in creation order."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False, **NO_AUX, **build,
-                                **cfg)
-        if use_amp:
-            main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        params = [np.asarray(scope.find_var(p.name))
-                  for p in main.all_parameters()]
-        routed = len(m["counts"])
-        fetched = exe.run(
-            main, feed=feed, scope=scope,
-            fetch_list=[m["loss"], m["logits"]] + m["counts"]
-            + m["experts"] + grads)
-    out = {"loss": fetched[0], "logits": fetched[1],
-           "counts": fetched[2:2 + routed],
-           "experts": fetched[2 + routed:2 + 2 * routed],
-           "grads": fetched[2 + 2 * routed:], "main": main}
-    return out, params
-
-
-def reference(cfg, feed, params, q_block=None):
-    tree = ref.params_from_list(params, cfg)
-    (total, parts), grads = ref.loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]), cfg,
-        q_block)
-    return total, parts, ref.flat_leaves(grads)
-
-
-def close(got, want, what, tol=TOL):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=tol, atol=tol, err_msg=what)
+FAMILY = Family(ref.params_from_list, ref.loss_and_grads,
+                lambda grads, cfg: ref.flat_leaves(grads))
+batch = functools.partial(harness.batch, length=48)
 
 
 @pytest.mark.parametrize("recompute", [None, "layer"])
@@ -133,8 +97,8 @@ def close(got, want, what, tol=TOL):
 def test_program_matches_the_float32_reference(share, recompute):
     cfg = config(**SHARES[share])
     feed = batch(cfg)
-    got, params = system(cfg, feed, recompute=recompute)
-    total, parts, grads = reference(cfg, feed, params)
+    got, params = system(arguments(cfg, recompute=recompute), feed)
+    total, parts, grads = reference(FAMILY, cfg, feed, params)
     close(got["logits"], parts["logits"], "logits")
     close(got["loss"], total, "loss")
     assert len(got["counts"]) == 4
@@ -160,12 +124,12 @@ def test_program_matches_the_float32_reference(share, recompute):
 def test_head_dim_beside_hidden_size_sizes_the_four_projections():
     """`head_dim` 24 x 4 heads = 96 beside `hidden_size` 64: q 64 -> 96,
     k, v 64 -> 48, o 96 -> 64; and the numbers still match."""
-    cfg = config(head_dim=24, **SHARES["rank-1-of-4"])
+    cfg = config(head_dim=24, **TWO_LAYERS)
     feed = batch(cfg, n=1)
-    got, params = system(cfg, feed)
+    got, params = system(arguments(cfg), feed)
     assert [p.shape for p in params[2:8]] == [
         (64, 96), (24,), (64, 48), (24,), (64, 48), (96, 64)]
-    total, parts, grads = reference(cfg, feed, params)
+    total, parts, grads = reference(FAMILY, cfg, feed, params)
     close(got["logits"], parts["logits"], "logits")
     for name, g, w in zip(ref.leaf_names(cfg), got["grads"], grads):
         close(g, w, f"gradient of {name}")
@@ -177,10 +141,12 @@ def test_a_window_that_holds_every_key_is_full_attention_bit_for_bit():
     same_rope = {"rope_type": "default", "rope_theta": 100.0}
     rope = {"full_attention": same_rope, "sliding_attention": same_rope}
     feed = batch(config(), length=32)
-    a, _ = system(config(sliding_window=32, rope_parameters=rope), feed)
-    b, _ = system(config(layer_types=["full_attention"] * 4,
-                         rope_parameters=rope), feed)
-    c, _ = system(config(sliding_window=31, rope_parameters=rope), feed)
+    a, _ = system(arguments(config(sliding_window=32, rope_parameters=rope,
+                                   **TWO_LAYERS)), feed)
+    b, _ = system(arguments(config(rope_parameters=rope, **dict(
+        TWO_LAYERS, layer_types=["full_attention"] * 2))), feed)
+    c, _ = system(arguments(config(sliding_window=31, rope_parameters=rope,
+                                   **TWO_LAYERS)), feed)
     np.testing.assert_array_equal(a["logits"], b["logits"])
     for g, w in zip(a["grads"], b["grads"]):
         np.testing.assert_array_equal(g, w)
@@ -193,18 +159,18 @@ def test_a_window_that_is_not_a_multiple_of_the_block(window):
     one block, and of one and a quarter."""
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    cfg = config(sliding_window=window, **SHARES["rank-1-of-4"])
+    cfg = config(sliding_window=window, **TWO_LAYERS)
     feed = batch(cfg, n=1)
     blocks = (fa.DEFAULT_BAND_BLOCK_Q, fa.DEFAULT_BAND_BLOCK_K,
               fa.DEFAULT_WINDOW_BWD_BLOCK_Q, fa.DEFAULT_WINDOW_BWD_BLOCK_K)
     fa.DEFAULT_BAND_BLOCK_Q = fa.DEFAULT_BAND_BLOCK_K = 16
     fa.DEFAULT_WINDOW_BWD_BLOCK_Q = fa.DEFAULT_WINDOW_BWD_BLOCK_K = 16
-    try:
-        got, params = system(cfg, feed)
+    try:            # constants of a module are no part of a key
+        got, params = build_and_run(arguments(cfg), feed)
     finally:
         (fa.DEFAULT_BAND_BLOCK_Q, fa.DEFAULT_BAND_BLOCK_K,
          fa.DEFAULT_WINDOW_BWD_BLOCK_Q, fa.DEFAULT_WINDOW_BWD_BLOCK_K) = blocks
-    total, parts, grads = reference(cfg, feed, params)
+    total, parts, grads = reference(FAMILY, cfg, feed, params)
     close(got["logits"], parts["logits"], "logits")
     for name, g, w in zip(ref.leaf_names(cfg), got["grads"], grads):
         close(g, w, f"gradient of {name}")
@@ -217,9 +183,9 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
     recomputed in its backward pass.  Same numbers."""
     cfg = config(**SHARES["rank-1-of-4"])
     feed = batch(cfg)
-    _, params = system(cfg, feed)
-    plain, _, want = reference(cfg, feed, params)
-    blocked, _, got = reference(cfg, feed, params, q_block=12)
+    _, params = system(arguments(cfg), feed)
+    plain, _, want = reference(FAMILY, cfg, feed, params)
+    blocked, _, got = reference(FAMILY, cfg, feed, params, q_block=12)
     close(blocked, plain, "loss")
     for w, g in zip(want, got):
         close(g, w, "gradient")
@@ -278,8 +244,9 @@ def test_a_score_carries_the_attention_factor_squared():
 def test_bf16_amp_stays_in_its_band_and_fails_the_float32_tolerance():
     cfg = config(**SHARES["rank-1-of-4"])
     feed = batch(cfg)
-    got, params = system(cfg, feed, use_amp=True, recompute="layer")
-    _, parts, grads = reference(cfg, feed, params)
+    got, params = system(arguments(cfg, recompute="layer"), feed,
+                         use_amp=True)
+    _, parts, grads = reference(FAMILY, cfg, feed, params)
     same = all(
         (np.sort(e, axis=-1) == np.sort(np.asarray(w), axis=-1)).all(-1).all()
         for e, w in zip(got["experts"], parts["experts"]))
@@ -318,7 +285,7 @@ def test_one_adamw_step_is_the_hand_rolled_one():
         before = [np.asarray(scope.find_var(n)).copy() for n in names]
         exe.run(main, feed=feed, scope=scope, fetch_list=[m["loss"]])
         after = [np.asarray(scope.find_var(n)) for n in names]
-    _, _, grads = reference(cfg, feed, before)
+    _, _, grads = reference(FAMILY, cfg, feed, before)
     grads = [np.asarray(g) for g in grads]
     norm = np.sqrt(sum(float((g.astype(np.float64) ** 2).sum())
                        for g in grads))
@@ -345,10 +312,10 @@ def test_the_two_kinds_of_layer_lower_under_scopes_of_their_own():
     layer; the backward pass counts 4 single kernels."""
     from paddle_tpu.observe.monitoring import runtime_stats
 
-    cfg = config(**SHARES["rank-1-of-4"])
+    cfg = config()
     feed = batch(cfg, n=1)
     before = runtime_stats.snapshot()
-    got, _ = system(cfg, feed)
+    got, _ = build_and_run(arguments(cfg), feed)
     took = runtime_stats.delta(before)
     assert took["flash_attention_backward_fused"] == 4
     assert took["flash_attention_backward_split"] == 0
@@ -361,5 +328,6 @@ def test_the_two_kinds_of_layer_lower_under_scopes_of_their_own():
     found = scopes(got["main"])
     assert sum(s == "sliding_attention" for s in found) \
         == 3 * sum(s == "full_attention" for s in found) > 0
-    plain, _ = system(config(layer_types=["full_attention"] * 4), feed)
+    plain, _ = system(arguments(config(layer_types=["full_attention"] * 4)),
+                      feed)
     assert "full_attention" not in scopes(plain["main"])

@@ -32,6 +32,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
 
 import reference_ouro as ref  # noqa: E402
+import parity_harness as harness  # noqa: E402
+from parity_harness import Family, reference, system  # noqa: E402
 
 TOL = 2e-5
 BETA = 0.1
@@ -50,12 +52,6 @@ def builder_args(cfg, **over):
                 exit_entropy_weight=BETA, **dict(EQUATIONS, **over))
 
 
-def batch(cfg, n=2, length=16, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 1))
-    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
-
-
 def draw_gates(main, scope, seed):
     """The gate's bias starts at 0; give it a value, so that the
     comparison sees it."""
@@ -65,55 +61,27 @@ def draw_gates(main, scope, seed):
         0, 0.5, (1,)).astype(np.float32))
 
 
-def system(cfg, feed, use_amp=False, seed=7, params=None, **over):
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False,
-                                **builder_args(cfg, **over))
-        if use_amp:
-            main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        names = [p.name for p in main.all_parameters()]
-        if params is None:
-            draw_gates(main, scope, seed)
-        else:
-            for name, value in zip(names, params):
-                scope.set_var(name, value)
-        params = [np.asarray(scope.find_var(n)) for n in names]
-        keys = ["loss", "logits"] + (["exit_p", "ut_ce", "ut_exit_p"]
-                                     if "exit_p" in m else [])
-        fetched = exe.run(main, feed=feed, scope=scope,
-                          fetch_list=[m[k] for k in keys] + grads)
-    out = dict(zip(keys, fetched), grads=fetched[len(keys):], names=names,
-               ops=[len(b.ops) for b in main.blocks])
-    return out, params
+FAMILY = Family(
+    lambda params, cfg: ref.params_from_list(params,
+                                             cfg["num_hidden_layers"]),
+    ref.loss_and_grads, lambda grads, cfg: ref.grads_to_list(grads))
+FETCH = ("loss", "logits", "exit_p", "ut_ce", "ut_exit_p")
+batch = functools.partial(harness.batch, length=16)
+close = functools.partial(harness.close, tol=TOL)
 
 
-def reference(cfg, feed, params, q_block=None):
-    tree = ref.params_from_list(params, cfg["num_hidden_layers"])
-    (total, parts), grads = ref.loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        cfg, BETA, q_block)
-    return total, parts, ref.grads_to_list(grads)
+def ops(got):
+    return [len(b.ops) for b in got["main"].blocks]
 
 
-@functools.lru_cache(maxsize=None)
 def float32_run():
+    """The float32 run of the system and of the reference on it that
+    most tests read (the harness remembers both)."""
     feed = batch(CONFIG)
-    got, params = system(CONFIG, feed)
-    return feed, got, params, reference(CONFIG, feed, params)
-
-
-def close(got, want, what, tol=TOL):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=tol, atol=tol, err_msg=what)
+    got, params = system(builder_args(CONFIG), feed, fetch=FETCH,
+                         after_startup=draw_gates)
+    return feed, got, params, reference(FAMILY, CONFIG, feed, params,
+                                        beta=BETA)
 
 
 def test_the_builders_creation_order_is_the_references_keys():
@@ -180,7 +148,7 @@ def test_a_shared_leafs_gradient_is_the_sum_over_trips_not_one_trips():
             return jnp.mean(jnp.sum(p * jnp.stack(ce), axis=0)
                             + BETA * jnp.sum(p * jnp.log(p), axis=0))
 
-    parts = jax.grad(untied)([tree["layers"]] * trips)
+    parts = jax.jit(jax.grad(untied))([tree["layers"]] * trips)
     per = len(ref.LAYER_KEYS)
     for i, key in enumerate(ref.LAYER_KEYS * layers):
         name = got["names"][1 + i]
@@ -198,7 +166,8 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
     """What `benchmarks/ouro_parity.py` runs on the chip so that 4096
     positions fit."""
     feed, _, params, (plain, _, want) = float32_run()
-    blocked, _, got = reference(CONFIG, feed, params, q_block=4)
+    blocked, _, got = reference(FAMILY, CONFIG, feed, params, beta=BETA,
+                                q_block=4)
     close(blocked, plain, "loss")
     for w, g in zip(want, got):
         close(g, w, "gradient")
@@ -208,8 +177,9 @@ def test_recomputed_layer_passes_give_the_same_numbers():
     """`recompute="layer"` (the cell's): a layer pass and a trip's head
     keep their inputs alone; no value moves."""
     feed, want, params, _ = float32_run()
-    got, _ = system(CONFIG, feed, params=params, recompute="layer")
-    assert got["ops"] == want["ops"]
+    got, _ = system(builder_args(CONFIG, recompute="layer"), feed,
+                    fetch=FETCH, params=params)
+    assert ops(got) == ops(want)
     np.testing.assert_array_equal(got["logits"], want["logits"])
     np.testing.assert_array_equal(got["loss"], want["loss"])
     for g, w in zip(got["grads"], want["grads"]):
@@ -224,9 +194,11 @@ def test_one_trip_is_the_stack_built_the_old_way_bit_for_bit():
     cross-entropy)."""
     feed = batch(CONFIG)
     one = dict(CONFIG, total_ut_steps=1)
-    looped, params = system(one, feed)
-    plain, _ = system(one, feed, params=params[:-2], exit_gate=None)
-    assert len(plain["ops"]) == 1 and len(looped["ops"]) == 2
+    looped, params = system(builder_args(one), feed, fetch=FETCH,
+                            after_startup=draw_gates)
+    plain, _ = system(builder_args(one, exit_gate=None), feed, fetch=FETCH,
+                      params=params[:-2])
+    assert len(ops(plain)) == 1 and len(ops(looped)) == 2
     assert plain["names"] == [n.replace("ut_loop/", "").replace(
         "exit_head/", "").replace("rms_norm_0.w_0", "rms_norm_8.w_0")
         if "exit_head" in n else n.replace("ut_loop/", "")
@@ -245,10 +217,10 @@ def test_two_and_four_trips_from_one_set_of_weights_and_one_op_count():
     Program is no longer for a larger one."""
     feed, four, params, _ = float32_run()
     two_cfg = dict(CONFIG, total_ut_steps=2)
-    two, _ = system(two_cfg, feed, params=params)
-    assert two["ops"] == four["ops"]
+    two, _ = system(builder_args(two_cfg), feed, fetch=FETCH, params=params)
+    assert ops(two) == ops(four)
     assert two["names"] == four["names"]
-    total, parts, grads = reference(two_cfg, feed, params)
+    total, parts, grads = reference(FAMILY, two_cfg, feed, params, beta=BETA)
     assert two["logits"].shape[0] == 2
     # the first two trips do not know how many follow
     np.testing.assert_array_equal(two["logits"], four["logits"][:2])
@@ -263,7 +235,8 @@ def test_bf16_amp_stays_in_its_bands_and_fails_the_float32_tolerance():
     float32's) and stays within the bands `benchmarks/ouro_parity.py`
     states for this depth."""
     feed, _, params, (total, parts, grads) = float32_run()
-    got, _ = system(CONFIG, feed, use_amp=True, params=params)
+    got, _ = system(builder_args(CONFIG), feed, use_amp=True, fetch=FETCH,
+                    params=params)
     for r in range(CONFIG["total_ut_steps"]):
         err = np.abs(np.asarray(got["logits"][r], np.float32)
                      - np.asarray(parts["logits"][r])).max()
@@ -309,7 +282,7 @@ def test_one_adamw_step_follows_the_reference():
         | {f"ut_exit_p_{r}" for r in range(1, 5)})
     lr_now = float(np.asarray(lr_now).reshape(-1)[0])
     assert 0 < lr_now <= lr
-    _, _, grads = reference(CONFIG, feed, before)
+    _, _, grads = reference(FAMILY, CONFIG, feed, before, beta=BETA)
     grads = [np.asarray(g, np.float64) for g in grads]
     norm = np.sqrt(sum((g * g).sum() for g in grads))
     assert norm > clip                  # the clip is in the comparison

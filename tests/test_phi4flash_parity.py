@@ -23,6 +23,7 @@ the exponentials of the scan and of lambda leave 2e-5 on a gradient
 (largest seen 8e-6), so gradients take 3e-5.
 """
 
+import functools
 import os
 import sys
 
@@ -32,14 +33,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import paddle_tpu as fluid
-from paddle_tpu.models import decoder
-from paddle_tpu.observe.monitoring import runtime_stats
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmarks"))
 import reference_phi4flash as ref  # noqa: E402
 from models import phi4flash as family  # noqa: E402
+import parity_harness as harness  # noqa: E402
+from parity_harness import Family, close, reference, system  # noqa: E402
 
 TOL, GRAD_TOL = 5e-6, 3e-5
 LENGTH = 32
@@ -65,54 +64,25 @@ def config(pattern="six", **over):
     return cfg
 
 
-def batch(cfg, n=2, length=LENGTH, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 1))
-    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+def arguments(cfg, **build):
+    return dict(family.architecture(cfg), aux_loss_weight=0.0,
+                z_loss_weight=0.0, **build)
 
 
-def system(cfg, feed, seed=7, **build):
-    """One forward and backward of the Program: what was fetched and
-    the parameters in creation order."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False, aux_loss_weight=0.0,
-                                z_loss_weight=0.0, **build,
-                                **family.architecture(cfg))
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        redraw = np.random.default_rng(seed)
-        params = []
-        for p in main.all_parameters():
-            value = np.asarray(scope.find_var(p.name))
-            if np.ptp(value) == 0.0:        # a bias, a scale, D
-                value = (value + redraw.normal(size=value.shape) * 0.3
-                         ).astype(value.dtype)
-                scope.set_var(p.name, jnp.asarray(value))
-            params.append(value)
-        fetched = exe.run(main, feed=feed, scope=scope,
-                          fetch_list=[m["loss"], m["logits"]] + grads)
-    return {"loss": fetched[0], "logits": fetched[1], "grads": fetched[2:],
-            "main": main}, params
+def off_the_constants(main, scope, seed):
+    """A parameter that starts at a constant (a bias, a scale, D) is
+    drawn again, so that the comparison sees it."""
+    redraw = np.random.default_rng(seed)
+    for p in main.all_parameters():
+        value = np.asarray(scope.find_var(p.name))
+        if np.ptp(value) == 0.0:
+            scope.set_var(p.name, jnp.asarray(
+                (value + redraw.normal(size=value.shape) * 0.3
+                 ).astype(value.dtype)))
 
 
-def reference(cfg, feed, params, **how):
-    tree = ref.params_from_list(params, cfg)
-    (total, parts), grads = ref.loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]), cfg,
-        **how)
-    return total, parts, ref.flat_leaves(grads, cfg)
-
-
-def close(got, want, what, tol=TOL):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=tol, atol=tol, err_msg=what)
+FAMILY = Family(ref.params_from_list, ref.loss_and_grads, ref.flat_leaves)
+batch = functools.partial(harness.batch, length=LENGTH)
 
 
 @pytest.mark.parametrize("recompute", [None, "layer"])
@@ -120,10 +90,10 @@ def close(got, want, what, tol=TOL):
 def test_program_matches_the_float32_reference(pattern, recompute):
     cfg = config(pattern)
     feed = batch(cfg)
-    before = runtime_stats.snapshot()
-    got, params = system(cfg, feed, recompute=recompute)
-    took = runtime_stats.delta(before)
-    total, parts, grads = reference(cfg, feed, params)
+    got, params = system(arguments(cfg, recompute=recompute), feed,
+                         after_startup=off_the_constants)
+    took = got["took"]
+    total, parts, grads = reference(FAMILY, cfg, feed, params)
     close(got["logits"], parts["logits"], "logits")
     close(got["loss"], total, "loss")
     names = ref.leaf_names(cfg)
@@ -145,7 +115,7 @@ def test_program_matches_the_float32_reference(pattern, recompute):
     assert took["short_conv_bias_calls"] > 0
 
 
-def _one_reader_reference(cfg, feed, params, cut):
+def _one_reader_reference(FAMILY, cfg, feed, params, cut):
     """The reference with the reads of the layers in `cut` held
     constant in the backward pass: what a program would compute that
     let only the OTHER readers' gradients reach the exporter."""
@@ -165,7 +135,7 @@ def _one_reader_reference(cfg, feed, params, cut):
             return -jnp.mean(jnp.take_along_axis(
                 logp, jnp.asarray(feed["labels"])[..., None], axis=-1))
 
-    return ref.flat_leaves(jax.grad(total)(tree), cfg)
+    return ref.flat_leaves(jax.jit(jax.grad(total))(tree), cfg)
 
 
 def test_an_exports_gradient_is_the_sum_over_its_readers():
@@ -176,10 +146,11 @@ def test_an_exports_gradient_is_the_sum_over_its_readers():
     both readers', and are NOT what one reader alone would give."""
     cfg = config("eight-two-readers")
     feed = batch(cfg)
-    got, params = system(cfg, feed, recompute="layer")
+    got, params = system(arguments(cfg, recompute="layer"), feed,
+                         after_startup=off_the_constants)
     names = ref.leaf_names(cfg)
-    _, _, both = reference(cfg, feed, params)
-    one = _one_reader_reference(cfg, feed, params, cut=(6, 7))
+    _, _, both = reference(FAMILY, cfg, feed, params)
+    one = _one_reader_reference(FAMILY, cfg, feed, params, cut=(6, 7))
     for leaf in ("layer2.w_x", "layer2.a_log", "layer3.wk", "layer3.wv"):
         at = names.index(leaf)
         close(got["grads"][at], both[at], leaf, GRAD_TOL)
@@ -200,7 +171,7 @@ def test_an_exports_gradient_is_the_sum_over_its_readers():
 def test_a_reader_without_its_exporter_raises_at_build_time(over, match):
     cfg = config(**over)
     with pytest.raises(ValueError, match=match):
-        system(cfg, batch(cfg))
+        system(arguments(cfg), batch(cfg))
 
 
 def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
@@ -210,9 +181,10 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
     backward pass.  Same numbers."""
     cfg = config()
     feed = batch(cfg)
-    _, params = system(cfg, feed)
-    plain, _, want = reference(cfg, feed, params)
-    blocked, _, got = reference(cfg, feed, params, q_block=8, time_block=8)
+    _, params = system(arguments(cfg), feed, after_startup=off_the_constants)
+    plain, _, want = reference(FAMILY, cfg, feed, params)
+    blocked, _, got = reference(FAMILY, cfg, feed, params, q_block=8,
+                                time_block=8)
     close(blocked, plain, "loss")
     for w, g in zip(want, got):
         close(g, w, "gradient")
@@ -223,7 +195,8 @@ def test_the_scopes_and_the_query_order_are_the_documented_ones():
     published ones under `reference_phi4flash.q_columns` (2 query pairs
     over one key/value pair: heads 0, 2 first, then 1, 3)."""
     cfg = config()
-    got, _ = system(cfg, batch(cfg))
+    got, _ = system(arguments(cfg), batch(cfg),
+                    after_startup=off_the_constants)
     scopes = {op.desc.attrs.get("__name_scope__", "")
               for op in got["main"].global_block().ops}
     for scope in ("state_space", "gated_memory", "cross_attention",

@@ -23,6 +23,7 @@ absolute-or-relative, as tests/test_mellum_parity.py (largest seen
 here 4e-8 on a gradient, 2e-6 on the logits).
 """
 
+import functools
 import os
 import sys
 
@@ -40,6 +41,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmarks"))
 sys.path.insert(0, os.path.dirname(__file__))
 import reference_qwen3next as ref  # noqa: E402
+import parity_harness as harness  # noqa: E402
+from parity_harness import (Family, build_and_run, close,  # noqa: E402
+                            reference, system)
 
 TOL = 5e-6
 NO_AUX = dict(aux_loss_weight=0.0, z_loss_weight=0.0)
@@ -70,62 +74,26 @@ def reference_config(cfg):
     return dict(cfg, full_attention_interval=4)
 
 
-def batch(cfg, n=2, length=80, seed=0):
-    ids = np.random.default_rng(seed).integers(
-        1, cfg["vocab_size"], size=(n, length + 1))
-    return {"tokens": ids[:, :-1], "labels": ids[:, 1:]}
+def arguments(cfg, **build):
+    return dict(cfg, **NO_AUX, **build)
 
 
-def system(cfg, feed, seed=7, **build):
-    """One forward and backward of the Program: what was fetched and
-    the parameters in creation order.  Parameters that start at a
-    constant (the norms' scales, `dt_bias`) are moved off it first, so
-    that a scale of 1 + w with w = 0 is not all that is compared."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["tokens"].shape[1],
-                                with_optimizer=False, **NO_AUX, **build,
-                                **cfg)
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        rng = np.random.default_rng(seed + 1)
-        for p in main.all_parameters():
-            value = np.asarray(scope.find_var(p.name))
-            if value.std() == 0:
-                scope.set_var(p.name, jnp.asarray(
-                    value + 0.1 * rng.normal(size=value.shape)
-                    .astype(np.float32)))
-        params = [np.asarray(scope.find_var(p.name))
-                  for p in main.all_parameters()]
-        routed = len(m["counts"])
-        fetched = exe.run(
-            main, feed=feed, scope=scope,
-            fetch_list=[m["loss"], m["logits"]] + m["counts"]
-            + m["experts"] + grads)
-    out = {"loss": fetched[0], "logits": fetched[1],
-           "counts": fetched[2:2 + routed],
-           "experts": fetched[2 + routed:2 + 2 * routed],
-           "grads": fetched[2 + 2 * routed:], "main": main}
-    return out, params
+def off_the_constants(main, scope, seed):
+    """Parameters that start at a constant (the norms' scales,
+    `dt_bias`) are moved off it first, so that a scale of 1 + w with
+    w = 0 is not all that is compared."""
+    rng = np.random.default_rng(seed + 1)
+    for p in main.all_parameters():
+        value = np.asarray(scope.find_var(p.name))
+        if value.std() == 0:
+            scope.set_var(p.name, jnp.asarray(
+                value + 0.1 * rng.normal(size=value.shape)
+                .astype(np.float32)))
 
 
-def reference(cfg, feed, params, q_block=None):
-    cfg = reference_config(cfg)
-    tree = ref.params_from_list(params, cfg)
-    (total, parts), grads = ref.loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]), cfg,
-        q_block)
-    return total, parts, ref.flat_leaves(grads)
-
-
-def close(got, want, what, tol=TOL):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=tol, atol=tol, err_msg=what)
+FAMILY = Family(ref.params_from_list, ref.loss_and_grads,
+                lambda grads, cfg: ref.flat_leaves(grads))
+batch = functools.partial(harness.batch, length=80)
 
 
 # -- (a) the builder's program against the reference ------------------------
@@ -135,8 +103,10 @@ def close(got, want, what, tol=TOL):
 def test_program_matches_the_float32_reference(share, recompute):
     cfg = config(**SHARES[share])
     feed = batch(cfg)
-    got, params = system(cfg, feed, recompute=recompute)
-    total, parts, grads = reference(cfg, feed, params)
+    got, params = system(arguments(cfg, recompute=recompute), feed,
+                         after_startup=off_the_constants)
+    total, parts, grads = reference(FAMILY, reference_config(cfg), feed,
+                                    params)
     close(got["logits"], parts["logits"], "logits")
     close(got["loss"], total, "loss")
     assert len(got["counts"]) == 4
@@ -175,9 +145,10 @@ def test_the_reference_in_runs_and_recomputed_gives_the_same_gradients():
     every layer recomputed in its backward pass.  Same numbers."""
     cfg = config(**SHARES["rank-1-of-4"])
     feed = batch(cfg)
-    _, params = system(cfg, feed)
-    plain, _, want = reference(cfg, feed, params)
-    blocked, _, got = reference(cfg, feed, params, q_block=16)
+    _, params = system(arguments(cfg), feed, after_startup=off_the_constants)
+    plain, _, want = reference(FAMILY, reference_config(cfg), feed, params)
+    blocked, _, got = reference(FAMILY, reference_config(cfg), feed, params,
+                                q_block=16)
     close(blocked, plain, "loss")
     for w, g in zip(want, got):
         close(g, w, "gradient")
@@ -189,9 +160,10 @@ def test_the_two_kinds_of_layer_lower_under_scopes_of_their_own():
     16 the scan is the XLA lowering and counts no kernel call."""
     from paddle_tpu.observe.monitoring import runtime_stats
 
-    cfg = config(**SHARES["rank-1-of-4"])
+    cfg = config()
     before = runtime_stats.snapshot()
-    got, _ = system(cfg, batch(cfg, n=1))
+    got, _ = build_and_run(arguments(cfg), batch(cfg, n=1),
+                           after_startup=off_the_constants)
     took = runtime_stats.delta(before)
     assert (took["gated_delta_calls"], took["gated_delta_chunks"]) == (0, 0)
     assert (took["gated_delta_operand_calls"],
@@ -220,8 +192,7 @@ def test_a_step_counts_three_kernel_calls_a_linear_layer_and_a_build_none():
     cfg = config(num_hidden_layers=2, linear_num_key_heads=1,
                  linear_num_value_heads=2, linear_key_head_dim=128,
                  linear_value_head_dim=128,
-                 layer_types=["linear_attention", "full_attention"],
-                 **SHARES["rank-1-of-4"])
+                 layer_types=["linear_attention", "full_attention"])
     feed = batch(cfg, n=1, length=128)
     main, startup = fluid.Program(), fluid.Program()
     scope = fluid.Scope()

@@ -263,12 +263,17 @@ def _built(family, looped, amp, policy=True, recompute=True):
             size=(1, T, HID)).astype(np.float32)}
         names = [loss.name] + [g.name for g in grads]
         before = runtime_stats.snapshot()
-        fetched = exe.run(main, feed=feed, scope=scope, fetch_list=names)
+        # the build with no segment is read for its jaxpr and for the
+        # counters around a trace: it is traced once, below, and not run
+        fetched = exe.run(main, feed=feed, scope=scope,
+                          fetch_list=names) if recompute else []
         counted = runtime_stats.delta(before)
         step, state, feeds = exe._prepare(
             main, {k: jax.numpy.asarray(v) for k, v in feed.items()}, names,
             scope, 1, True)
         found = _pallas_calls(jax.make_jaxpr(step)(state, feeds).jaxpr)
+        if not recompute:
+            counted = runtime_stats.delta(before)
     return dict(fetched=[np.asarray(f) for f in fetched],
                 kept=(counted["recompute_kept_residuals"],
                       counted["recompute_kept_bytes"]), **found)

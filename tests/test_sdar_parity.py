@@ -33,11 +33,11 @@ import paddle_tpu as fluid
 from paddle_tpu.core.registry import OpContext, get_op_impl
 from paddle_tpu.data.diffusion import block_diffusion_feeds
 from paddle_tpu.models import decoder
-from paddle_tpu.observe.monitoring import runtime_stats
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmarks"))
 import reference_sdar as ref  # noqa: E402
+from parity_harness import Family, close, reference, system  # noqa: E402
 
 TOL = 5e-6
 B, L, VOCAB = 4, 32, 96
@@ -59,41 +59,14 @@ def config(**over):
     return cfg
 
 
-def batch(n=2, length=L, seed=0, block_length=B, t_min=0.05):
+def noised_batch(n=2, length=L, seed=0, block_length=B, t_min=0.05):
     rng = np.random.default_rng(seed)
     x0 = rng.integers(1, MASK_ID, size=(n, length))
     return block_diffusion_feeds(x0, block_length, MASK_ID, rng, t_min=t_min)
 
 
-def system(cfg, feed, use_amp=False, seed=7, **build):
-    """One forward and backward of the Program: what was fetched and
-    the parameters in creation order."""
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = seed
-    scope = fluid.Scope()
-    with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
-            fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["labels"].shape[1],
-                                with_optimizer=False, **NO_AUX, **build,
-                                **cfg)
-        if use_amp:
-            main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
-        grads = [g for _, g in fluid.append_backward(m["loss"])]
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        params = [np.asarray(scope.find_var(p.name))
-                  for p in main.all_parameters()]
-        routed = len(m["counts"])
-        fetched = exe.run(
-            main, feed=feed, scope=scope,
-            fetch_list=[m["loss"], m["logits"], m["masked_share"]]
-            + m["counts"] + m["experts"] + grads)
-    out = {"loss": fetched[0], "logits": fetched[1],
-           "masked_share": fetched[2],
-           "counts": fetched[3:3 + routed],
-           "experts": fetched[3 + routed:3 + 2 * routed],
-           "grads": fetched[3 + 2 * routed:], "main": main}
-    return out, params
+def arguments(cfg, **build):
+    return dict(cfg, **NO_AUX, **build)
 
 
 def ref_config(cfg):
@@ -102,19 +75,9 @@ def ref_config(cfg):
                          ("expert_parallel_rank", 0))})
 
 
-def reference(cfg, feed, params, q_block=None):
-    cfg = ref_config(cfg)
-    tree = ref.params_from_list(params, cfg)
-    (total, parts), grads = ref.loss_and_grads(
-        tree, jnp.asarray(feed["tokens"]), jnp.asarray(feed["labels"]),
-        jnp.asarray(feed["loss_weights"]), cfg, q_block)
-    return total, parts, ref.flat_leaves(grads)
-
-
-def close(got, want, what, tol=TOL, scale=1.0):
-    np.testing.assert_allclose(np.asarray(got).reshape(-1),
-                               np.asarray(want).reshape(-1),
-                               rtol=tol, atol=tol * scale, err_msg=what)
+FAMILY = Family(ref.params_from_list, ref.loss_and_grads,
+                lambda grads, cfg: ref.flat_leaves(grads))
+FETCH = ("loss", "logits", "masked_share")
 
 
 def close_grads(cfg, got, want):
@@ -131,11 +94,11 @@ def close_grads(cfg, got, want):
 @pytest.mark.parametrize("share", sorted(SHARES))
 def test_program_matches_the_float32_reference(share, recompute):
     cfg = config(**SHARES[share])
-    feed = batch()
-    before = runtime_stats.snapshot()
-    got, params = system(cfg, feed, recompute=recompute)
-    took = runtime_stats.delta(before)
-    total, parts, grads = reference(cfg, feed, params)
+    feed = noised_batch()
+    got, params = system(arguments(cfg, recompute=recompute), feed,
+                         fetch=FETCH)
+    took = got["took"]
+    total, parts, grads = reference(FAMILY, ref_config(cfg), feed, params)
     assert got["logits"].shape == (2, L, VOCAB)         # the noised half
     close(got["logits"], parts["logits"], "logits")
     close(got["loss"], total, "loss")
@@ -169,10 +132,11 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
     rows fit: scores `q_block` rows at a time, every layer recomputed
     in its backward pass.  Same numbers."""
     cfg = config(**SHARES["rank-1-of-2"])
-    feed = batch()
-    _, params = system(cfg, feed)
-    plain, _, want = reference(cfg, feed, params)
-    blocked, _, got = reference(cfg, feed, params, q_block=16)
+    feed = noised_batch()
+    _, params = system(arguments(cfg), feed, fetch=FETCH)
+    plain, _, want = reference(FAMILY, ref_config(cfg), feed, params)
+    blocked, _, got = reference(FAMILY, ref_config(cfg), feed, params,
+                                q_block=16)
     close(blocked, plain, "loss")
     for w, g in zip(want, got):
         close(g, w, "gradient", scale=max(1.0, float(np.abs(w).max())))
@@ -180,9 +144,10 @@ def test_the_reference_in_blocks_and_recomputed_gives_the_same_gradients():
 
 def test_bf16_amp_fails_the_float32_tolerance():
     cfg = config(**SHARES["rank-1-of-2"])
-    feed = batch()
-    got, params = system(cfg, feed, use_amp=True, recompute="layer")
-    _, parts, _ = reference(cfg, feed, params)
+    feed = noised_batch()
+    got, params = system(arguments(cfg, recompute="layer"), feed,
+                         use_amp=True, fetch=FETCH)
+    _, parts, _ = reference(FAMILY, ref_config(cfg), feed, params)
     err = np.abs(np.asarray(got["logits"], np.float32)
                  - np.asarray(parts["logits"]))
     assert err.max() > 20 * TOL, err.max()
@@ -234,22 +199,14 @@ def test_a_noised_block_sees_its_clean_prefix_and_itself(block):
     those of the model run on [x_0 blocks < b ; x_t block b] ALONE,
     under a causal-prefix mask with the last block bidirectional."""
     cfg = config(**SHARES["rank-1-of-2"])
-    feed = batch(n=1, seed=11)
-    got, params = _system_once(cfg, feed)
+    feed = noised_batch(n=1, seed=11)
+    got, params = system(arguments(cfg), feed, fetch=FETCH)
     lo, hi = block * B, (block + 1) * B
     ids = np.concatenate([feed["tokens"][0, :lo],
                           feed["tokens"][0, L + lo:L + hi]])
-    want = _prefix_model(params, cfg, jnp.asarray(ids), jnp.arange(hi))
+    want = jax.jit(lambda ids, positions: _prefix_model(
+        params, cfg, ids, positions))(jnp.asarray(ids), jnp.arange(hi))
     close(got["logits"][0, lo:hi], want[lo:hi], f"block {block}", tol=2e-5)
-
-
-_ONCE = {}
-
-
-def _system_once(cfg, feed):
-    if "run" not in _ONCE:
-        _ONCE["run"] = system(cfg, feed)
-    return _ONCE["run"]
 
 
 # -- (d) the shares add up ----------------------------------------------------
@@ -396,7 +353,7 @@ def test_every_unbuilt_combination_raises(what, over, error):
 
 def test_the_attention_operator_lowers_under_a_scope_of_its_own():
     cfg = config(**SHARES["rank-1-of-2"])
-    got, _ = _system_once(cfg, batch(n=1, seed=11))
+    got, _ = system(arguments(cfg), noised_batch(n=1, seed=11), fetch=FETCH)
     scopes = [op.attrs.get("__name_scope__", "") for b in got["main"].blocks
               for op in b.ops]
     assert sum(s == "block_diffusion_attention" for s in scopes) > 0
@@ -415,7 +372,7 @@ def test_the_training_program_tracks_the_loss_and_the_masked_share():
     """`scalar.diffusion_loss` and `scalar.masked_share` in the
     telemetry, and one AdamW step runs through the whole Program."""
     cfg = config(**SHARES["rank-1-of-2"])
-    feed = batch()
+    feed = noised_batch()
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 3
     scope = fluid.Scope()
