@@ -22,9 +22,21 @@ block is a whole 128-lane tile holding TWO heads side by side:
   (`_both_halves`), and the lane masks above do the rest.  No value is
   ever sliced at a lane offset that is not a tile.
 
-Forward: grid (N*H/2, q blocks, k blocks).  Blocks above the diagonal
-are skipped and their DMA with them (the index maps clamp to the last
-block that is needed).  The soft-max statistics are the (N*H, 8, T)
+Forward: grid (N*Hkv, q blocks, k blocks): a step is ONE key/value head
+and the G/2 query pairs that read it (a q / o tile G/2 lane tiles wide;
+at G = 1 a step is one pair, each head reading its own), so a key/value
+tile is fetched, and its head spread over both halves, once for all of
+them.  q is scaled (a power of two rides on it exactly; any other scale
+multiplies the scores) and masked a head, `[q0 | 0]` and `[0 | q1]`,
+once a query tile into scratch, and meets `[k | k]` and `[v | v]`
+whole: no lane mask on a key or value tile, no multiply a score.  Tiles
+are 1024 x 1024 where T is a whole number of them, else 512 x 512
+(`default_blocks`: from the shape alone, for both passes; at 8192
+positions 4.7 ms a call where 512 x 512 tiles and a step a pair took
+7.8: `tools/time_flash_gqa.py`).  Blocks above the diagonal are
+skipped and their DMA with them (the index maps clamp to the last block
+that is needed); only the blocks the diagonal crosses build and apply
+the causal mask.  The soft-max statistics are the (N*H, 8, T)
 sublane-replicated form of `flash_attention.py`.
 
 The backward pass is ONE kernel where the sequence allows it, grid
@@ -71,6 +83,7 @@ sends head-major calls at d_head 64 here.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -81,22 +94,30 @@ from .flash_attention import (_SOFTMAX_BWD_PER_SCORE, _SOFTMAX_FWD_PER_SCORE,
 
 HEAD_DIM = 64
 LANES = 128
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 512
-# the backward pass's own blocks (the statistics are block-free, so it
-# need not take the forward's)
-DEFAULT_BWD_BLOCK_Q = 1024
-DEFAULT_BWD_BLOCK_K = 1024
+# a side of either pass's square tile (the statistics are block-free, so
+# the passes need not agree, but the same rule serves both): the first
+# where T is a whole number of them, the second elsewhere
+DEFAULT_BLOCK = 1024
+FALLBACK_BLOCK = 512
 NEG_INF = -1e30
-# what a backward kernel may claim of v5e's 128 MiB of VMEM (its blocks
-# of float32 scores and the single kernel's accumulators pass Mosaic's
-# default 16 MiB); Mosaic's verdict: tests/test_chip_compile_flash_attention.py
+# what a kernel may claim of v5e's 128 MiB of VMEM (two heads' blocks of
+# float32 scores and the single backward kernel's accumulators pass
+# Mosaic's default 16 MiB); Mosaic's verdict:
+# tests/test_chip_compile_flash_attention.py
 _VMEM_LIMIT = 100 << 20
 # The single backward kernel holds float32 sums of a whole sequence: dq
 # of one query tile, dk and dv of its key/value tile, 3 x 128 lanes =
 # 1.5 KiB a position.  They may take this much; a longer sequence goes
 # to the two kernels, which hold blocks only
 FUSED_ACCUMULATOR_BUDGET = 32 << 20
+
+
+def default_blocks(t):
+    """(block_q, block_k) of a sequence of `t` positions, for the
+    forward and the backward pass: from the shape alone, never from an
+    option."""
+    whole = t % min(DEFAULT_BLOCK, t) == 0
+    return (DEFAULT_BLOCK if whole else FALLBACK_BLOCK,) * 2
 
 
 def fused_backward_fits(t):
@@ -150,15 +171,11 @@ _register_costs()
 
 
 def _pallas_call(*args, **kw):
-    from . import pallas_call
-
-    return pallas_call(*args, **kw)
-
-
-def _bwd_pallas_call(*args, **kw):
     from jax.experimental.pallas import tpu as pltpu
 
-    return _pallas_call(
+    from . import pallas_call
+
+    return pallas_call(
         *args, compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT), **kw)
 
@@ -219,47 +236,78 @@ def _dot(a, b, contract):
 
 # -- forward ----------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, block_q, block_k, half_of):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_scr, m_scr, l_scr,
+                acc_scr, *, scale, block_q, block_k, half_of):
+    """One key/value head against the `pairs` query pairs that read it:
+    q / o tiles (block_q, pairs * 128), the statistics of their 2 *
+    pairs heads.  `q_scr` (2 * pairs, block_q, 128) holds each head's q
+    scaled, the other head's lanes zeroed, for the query tile's whole
+    row of key blocks."""
     from jax.experimental import pallas as pl
 
     qb, kb = pl.program_id(1), pl.program_id(2)
     half = half_of(pl.program_id(0))
+    pairs = acc_scr.shape[0]
+    # a power of two rides on q exactly; any other scale on the scores
+    on_q, on_s = (scale, 1.0) if math.frexp(scale)[0] == 0.5 else (1.0, scale)
+
+    def lanes(i):
+        return pl.ds(i * LANES, LANES)
 
     @pl.when(kb == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        for i in range(pairs):
+            q = q_ref[0, :, lanes(i)]
+            if on_q != 1.0:
+                q = q * jnp.asarray(on_q, q.dtype)
+            for j in (0, 1):
+                q_scr[2 * i + j] = _of_head(q, j)
 
-    @pl.when((qb + 1) * block_q > kb * block_k)
-    def _compute():
-        q = q_ref[0]
+    def _compute(masked):
         k = _both_halves(k_ref[0], half)
         v = _both_halves(v_ref[0], half)
-        keep = _causal(qb * block_q, kb * block_k, (block_q, block_k), True)
-        alpha, pv = [], []
-        for j in (0, 1):
-            s = _dot(q, _of_head(k, j), ((1,), (1,))) * scale
-            s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_scr[j]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            a = jnp.exp(m_prev - m_new)
-            l_scr[j] = a * l_scr[j] + jnp.sum(p, axis=1, keepdims=True)
-            m_scr[j] = m_new
-            alpha.append(a)
-            pv.append(_dot(p.astype(v.dtype), _of_head(v, j), ((1,), (0,))))
-        acc_scr[:] = (acc_scr[:] * _per_head(alpha[0], alpha[1])
-                      + pv[0] + pv[1])
+        if masked:
+            keep = _causal(qb * block_q, kb * block_k, (block_q, block_k),
+                           True)
+        for i in range(pairs):
+            alpha, pv = [], []
+            for h in (2 * i, 2 * i + 1):
+                s = _dot(q_scr[h], k, ((1,), (1,)))
+                if on_s != 1.0:
+                    s = s * on_s
+                if masked:
+                    s = jnp.where(keep, s, NEG_INF)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                a = jnp.exp(m_prev - m_new)
+                l_scr[h] = a * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+                m_scr[h] = m_new
+                alpha.append(a)
+                # [o | o] of the head: its half is taken below
+                pv.append(_dot(p.astype(v.dtype), v, ((1,), (0,))))
+            acc_scr[i] = (acc_scr[i] * _per_head(*alpha) + _per_head(*pv))
+
+    # a block that holds a score; one the diagonal crosses (its last key
+    # lies past its first query) masks, one below it need not
+    runs = (qb + 1) * block_q > kb * block_k
+    crosses = (kb + 1) * block_k - 1 > qb * block_q
+    pl.when(runs & crosses)(functools.partial(_compute, True))
+    pl.when(runs & jnp.logical_not(crosses))(
+        functools.partial(_compute, False))
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _finalize():
-        o_ref[0] = (acc_scr[:] / _per_head(l_scr[0], l_scr[1])
-                    ).astype(o_ref.dtype)
-        for j in (0, 1):
-            lse = (m_scr[j] + jnp.log(l_scr[j]))[:, 0]
-            lse_ref[j] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+        for i in range(pairs):
+            o_ref[0, :, lanes(i)] = (
+                acc_scr[i] / _per_head(l_scr[2 * i], l_scr[2 * i + 1])
+            ).astype(o_ref.dtype)
+            for h in (2 * i, 2 * i + 1):
+                lse = (m_scr[h] + jnp.log(l_scr[h]))[:, 0]
+                lse_ref[h] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
 
 
 # -- backward ---------------------------------------------------------------
@@ -433,6 +481,8 @@ class _Geometry:
         self.n, self.t, self.group = n, t, group
         self.q_pairs, self.kv_pairs = n_head // 2, n_kv_head // 2
         self.tiles = group              # query tiles a key/value tile
+        # query pairs a key/value HEAD: what a forward grid step holds
+        self.head_pairs = max(group // 2, 1)
         # the first `halves` of them read the tile's first head, the
         # rest its second; None: each head of a pair reads its own
         self.halves = None if group == 1 else group // 2
@@ -442,12 +492,13 @@ class _Geometry:
                              f"{self.block_q} / {self.block_k} blocks")
         self.nq, self.nk = t // self.block_q, t // self.block_k
 
-    def kv_half(self, g):
-        """Which half of its key/value tile query pair `g` (of the
-        grid's N*H/2) reads; None when each head reads its own."""
+    def kv_half(self, g, pairs=1):
+        """Which half of its key/value tile the `pairs` query pairs of
+        step `g` (of the grid's N*H/2 / pairs) read; None when each head
+        reads its own."""
         if self.group == 1:
             return None
-        return ((g % self.q_pairs) // (self.group // 2)) % 2
+        return ((g * pairs % self.q_pairs) // (self.group // 2)) % 2
 
     def last_k(self, qb):
         return ((qb + 1) * self.block_q - 1) // self.block_k
@@ -455,19 +506,21 @@ class _Geometry:
     def first_q(self, kb):
         return (kb * self.block_k) // self.block_q
 
-    def by_query_block(self):
-        """Specs of a grid (N*H/2, qb, kb): the q-side tile of pair g,
-        the key/value tile it reads, the pair's statistics."""
+    def by_query_block(self, pairs=1):
+        """Specs of a grid (N*H/2 / pairs, qb, kb): the q-side tile of
+        step g's `pairs` query pairs (neighbours that read one
+        key/value tile), that tile, the pairs' statistics."""
         from jax.experimental import pallas as pl
 
-        qp, grp = self.q_pairs, self.group
-        return {"q": pl.BlockSpec((1, self.block_q, LANES),
-                                  lambda g, a, b: (g // qp, a, g % qp)),
+        steps, grp = self.q_pairs // pairs, self.group
+        return {"q": pl.BlockSpec((1, self.block_q, pairs * LANES),
+                                  lambda g, a, b: (g // steps, a, g % steps)),
                 "kv": pl.BlockSpec(
                     (1, self.block_k, LANES),
-                    lambda g, a, b: (g // qp, jnp.minimum(b, self.last_k(a)),
-                                     (g % qp) // grp)),
-                "stat": pl.BlockSpec((2, 8, self.block_q),
+                    lambda g, a, b: (g // steps,
+                                     jnp.minimum(b, self.last_k(a)),
+                                     (g % steps) * pairs // grp)),
+                "stat": pl.BlockSpec((2 * pairs, 8, self.block_q),
                                      lambda g, a, b: (g, 0, a))}
 
     def by_key_block(self, tile_outside=False):
@@ -529,19 +582,21 @@ def _stat_shape(geo):
 def _flash_fwd(q, k, v, scale, geo):
     from jax.experimental.pallas import tpu as pltpu
 
-    bq, bk = geo.block_q, geo.block_k
-    s = geo.by_query_block()
-    kern = functools.partial(_fwd_kernel, scale=scale, block_q=bq,
-                             block_k=bk, half_of=geo.kv_half)
+    bq, bk, pairs = geo.block_q, geo.block_k, geo.head_pairs
+    s = geo.by_query_block(pairs)
+    kern = functools.partial(
+        _fwd_kernel, scale=scale, block_q=bq, block_k=bk,
+        half_of=functools.partial(geo.kv_half, pairs=pairs))
     return _pallas_call(
         kern, name="flash_gqa_fwd",
-        grid=(geo.n * geo.q_pairs, geo.nq, geo.nk),
+        grid=(geo.n * geo.q_pairs // pairs, geo.nq, geo.nk),
         in_specs=[s["q"], s["kv"], s["kv"]],
         out_specs=[s["q"], s["stat"]],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), _stat_shape(geo)],
-        scratch_shapes=[pltpu.VMEM((2, bq, 1), jnp.float32),
-                        pltpu.VMEM((2, bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((2 * pairs, bq, LANES), q.dtype),
+                        pltpu.VMEM((2 * pairs, bq, 1), jnp.float32),
+                        pltpu.VMEM((2 * pairs, bq, 1), jnp.float32),
+                        pltpu.VMEM((pairs, bq, LANES), jnp.float32)],
     )(q, k, v)
 
 
@@ -553,7 +608,7 @@ def _flash_bwd_split(q, k, v, o, lse8, do, scale, geo):
     s = geo.by_key_block()
     dkv = functools.partial(_dkv_kernel, scale=scale, block_q=bq, block_k=bk,
                             halves=geo.halves)
-    dk, dv = _bwd_pallas_call(
+    dk, dv = _pallas_call(
         dkv, name="flash_gqa_dkv",
         grid=(geo.n * geo.kv_pairs, geo.nk, geo.tiles, geo.nq),
         in_specs=[s["q"], s["kv"], s["kv"], s["q"], s["q"], s["stat"]],
@@ -565,7 +620,7 @@ def _flash_bwd_split(q, k, v, o, lse8, do, scale, geo):
     s = geo.by_query_block()
     dqk = functools.partial(_dq_kernel, scale=scale, block_q=bq, block_k=bk,
                             half_of=geo.kv_half)
-    dq = _bwd_pallas_call(
+    dq = _pallas_call(
         dqk, name="flash_gqa_dq",
         grid=(geo.n * geo.q_pairs, geo.nq, geo.nk),
         in_specs=[s["q"], s["kv"], s["kv"], s["q"], s["q"], s["stat"]],
@@ -586,7 +641,7 @@ def _flash_bwd_fused(q, k, v, o, lse8, do, scale, geo):
     s = geo.by_key_block(tile_outside=True)
     kern = functools.partial(_bwd_kernel, scale=scale, block_q=bq, block_k=bk,
                              halves=geo.halves, last_k=geo.last_k)
-    return _bwd_pallas_call(
+    return _pallas_call(
         kern, name="flash_gqa_dkv",
         grid=(geo.n * geo.kv_pairs, geo.tiles, geo.nk, geo.nq),
         in_specs=[s["q"], s["kv"], s["kv"], s["q"], s["q"], s["stat"]],
@@ -609,21 +664,20 @@ def _flash_bwd(q, k, v, o, lse8, do, scale, geo):
     return bwd(q, k, v, o, lse8, do, scale, geo)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, n_head, n_kv_head, blocks, bwd_blocks):
-    return _flash_vjp_fwd(q, k, v, scale, n_head, n_kv_head, blocks,
-                          bwd_blocks)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale, n_head, n_kv_head, blocks):
+    return _flash_vjp_fwd(q, k, v, scale, n_head, n_kv_head, blocks)[0]
 
 
-def _flash_vjp_fwd(q, k, v, scale, n_head, n_kv_head, blocks, bwd_blocks):
+def _flash_vjp_fwd(q, k, v, scale, n_head, n_kv_head, blocks):
     geo = _Geometry(q, k, n_head, n_kv_head, *blocks)
     o, lse8 = keep_residuals(*_flash_fwd(q, k, v, scale, geo))
     return o, (q, k, v, o, lse8)
 
 
-def _flash_vjp_bwd(scale, n_head, n_kv_head, blocks, bwd_blocks, res, do):
+def _flash_vjp_bwd(scale, n_head, n_kv_head, blocks, res, do):
     q, k, v, o, lse8 = res
-    geo = _Geometry(q, k, n_head, n_kv_head, *bwd_blocks)
+    geo = _Geometry(q, k, n_head, n_kv_head, *blocks)
     return _flash_bwd(q, k, v, o, lse8, do, scale, geo)
 
 
@@ -635,17 +689,10 @@ def flash_gqa(q, k, v, n_head, n_kv_head, scale=None, block_q=None,
     """Causal self-attention of q (N, T, n_head*64) over k, v
     (N, T, n_kv_head*64): query head j reads key/value head
     j // (n_head / n_kv_head).  Returns (N, T, n_head*64).  A block
-    size given holds for both passes; left out, each pass takes its
-    own, the backward pass the forward's where T is not a whole number
-    of its own."""
+    size given holds for both passes; left out, the sequence's length
+    chooses it (`default_blocks`)."""
     if scale is None:
         scale = HEAD_DIM ** -0.5
-    t = q.shape[1]
-    blocks = (int(block_q or DEFAULT_BLOCK_Q), int(block_k or DEFAULT_BLOCK_K))
-    bwd_blocks = tuple(
-        int(given or (own if t % min(own, t) == 0 else fwd))
-        for given, own, fwd in zip(
-            (block_q, block_k), (DEFAULT_BWD_BLOCK_Q, DEFAULT_BWD_BLOCK_K),
-            blocks))
-    return _flash(q, k, v, float(scale), int(n_head), int(n_kv_head),
-                  blocks, bwd_blocks)
+    blocks = tuple(int(given or own) for given, own in zip(
+        (block_q, block_k), default_blocks(q.shape[1])))
+    return _flash(q, k, v, float(scale), int(n_head), int(n_kv_head), blocks)

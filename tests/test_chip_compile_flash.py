@@ -307,9 +307,13 @@ STEP_TEXT = {
     # sort by expert carries the pairs' weights and one sort by token
     # stands in the op (parents: acccef44.., 46a623eb.., 7914857b..,
     # 5a9ae15a.., 44ab655f..); `olmoe-4k`, which holds every expert,
-    # keeps its text.  (PR 48 before: QK-norm a head in the `rope` op)
+    # keeps its text.  (PR 48 before: QK-norm a head in the `rope` op.)
+    # This one re-pinned again, PR 56: `flash_gqa_fwd` on 1024 x 1024
+    # tiles over the grid (N*Hkv, q blocks, k blocks), here through the
+    # interpreter (parent: 53baaa58..); no other cell reaches
+    # `ops/pallas/flash_gqa.py`
     "lfm2-8k":
-    "53baaa58347f736f1f3e4449c166389b7940cdac4e823f92d1bfbe9d01020e04",
+    "acebe18fff0e9f8427c8219f16235a4e792f0db56574bad723d8590b0d1188a7",
     "joyai-8k":
     "8dec48c80693b40a6ede0035f7ff2f7c4070bdd664975db9bbd7ab3caee346e2",
     # re-pinned, PR 39: the loop's segments keep (o, logsumexp), the

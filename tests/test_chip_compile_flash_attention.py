@@ -79,7 +79,11 @@ def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
     of a query tile's whole sequence, dk and dv of its key/value
     tile: 12 MiB at 8192) Mosaic must take in VMEM in both dtypes; at
     32768 positions they pass the budget and the two kernels that hold
-    blocks only stay.  The counter says which path the trace took."""
+    blocks only stay.  The counter says which path the trace took.  The
+    forward (PR 56) runs the largest tile its rule can choose, 1024 x
+    1024 over the two query pairs of a key/value head (four heads'
+    float32 score tiles a step, past Mosaic's default 16 MiB: it names
+    the VMEM limit as the backward does), in both dtypes."""
     from paddle_tpu.observe import cost
     from paddle_tpu.observe.monitoring import runtime_stats
     from paddle_tpu.ops.pallas import flash_gqa
@@ -89,6 +93,7 @@ def test_flash_attention_head_major_at_d_head_64_blocks_head_pairs(
     n, t, heads, kv = geometry
     fused = flash_gqa.fused_backward_fits(t)
     assert fused == (t <= 8192)
+    assert flash_gqa.default_blocks(t) == (1024, 1024)
 
     def loss(q, k, v):
         with jax.named_scope("flash_attention:9"):

@@ -1,9 +1,11 @@
-"""The backward pass of `ops/pallas/flash_gqa.py` on the CPU (interpret
-mode): the single kernel and the two that hold blocks only, against
-each other and against plain attention with the key/value heads
-REPEATED over their groups (which the kernels never do); the shape rule
-that chooses between them; the counters; the registered costs.  The
-forward pass and the op are tests/test_expert_share.py's, Mosaic's own
+"""The kernels of `ops/pallas/flash_gqa.py` on the CPU (interpret mode),
+against plain attention with the key/value heads REPEATED over their
+groups (which the kernels never do).  The forward: its output AND its
+logsumexp at the tiles the sequence's length chooses and at tiles given
+by hand, and which tile a length chooses.  The backward: the single
+kernel and the two that hold blocks only, against each other and the
+reference; the shape rule that chooses between them; the counters; the
+registered costs.  The op is tests/test_expert_share.py's, Mosaic's own
 checks tests/test_chip_compile_flash_attention.py's.
 """
 
@@ -29,6 +31,7 @@ def operands(n, t, heads, kv, seed=0):
     return [jax.random.normal(k, (n, t, h * D)) for k, h in zip(ks, widths)]
 
 
+@functools.partial(jax.jit, static_argnums=(3, 4))
 def dense(q, k, v, heads, kv):
     n, t, _ = q.shape
     q4 = q.reshape(n, t, heads, D)
@@ -38,6 +41,159 @@ def dense(q, k, v, heads, kv):
     s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
     return jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, -1),
                       v4).reshape(n, t, heads * D)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def dense_grads(q, k, v, w, heads, kv):
+    """dq, dk, dv of sum(dense * w): jitted, because an eager float32
+    reference compiles every primitive of every shape by itself."""
+    return jax.grad(lambda *a: jnp.sum(dense(*a, heads, kv) * w),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def dense_lse(q, k, heads, kv):
+    """(N*heads, T): the logsumexp of every query's allowed scores."""
+    n, t, _ = q.shape
+    q4 = q.reshape(n, t, heads, D)
+    k4 = jnp.repeat(k.reshape(n, t, kv, D), heads // kv, axis=2)
+    s = jnp.einsum("nqhd,nkhd->nhqk", q4, k4) * D ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jax.nn.logsumexp(s, -1).reshape(n * heads, t)
+
+
+def _small_tiles(monkeypatch):
+    """The shape rule at a size the interpreter runs: a tile of 32
+    where T is a whole number of them, of 16 elsewhere."""
+    monkeypatch.setattr(flash_gqa, "DEFAULT_BLOCK", 32)
+    monkeypatch.setattr(flash_gqa, "FALLBACK_BLOCK", 16)
+
+
+# T and the tile given by hand (None: the length chooses, under
+# `_small_tiles`), and the tile that must come of it
+FORWARD_TILES = {
+    "1_tile": (32, None, (32, 32)), "2_tiles": (64, None, (32, 32)),
+    "4_tiles": (128, None, (32, 32)),
+    "fall_back_tile": (48, None, (16, 16)),
+    "wide_q_by_hand": (64, (32, 16), (32, 16)),
+    "wide_k_by_hand": (64, (16, 32), (16, 32))}
+
+
+# every head geometry in float32; bfloat16 changes the casts, not the
+# geometry, and runs at the first
+@pytest.mark.parametrize("heads, kv, dtype", [
+    (8, 2, jnp.float32), (8, 2, jnp.bfloat16), (32, 8, jnp.float32),
+    (4, 4, jnp.float32)],
+    ids=["gqa_8_over_2-f32", "gqa_8_over_2-bf16", "gqa_32_over_8-f32",
+         "mha-f32"])
+@pytest.mark.parametrize("tiles", sorted(FORWARD_TILES))
+def test_the_forward_gives_the_dense_output_and_logsumexp(
+        monkeypatch, tiles, heads, kv, dtype):
+    """o and lse of the forward kernel, whose grid step is one key/value
+    head and the query pairs that read it (two pairs at 8 / 2 and 32 /
+    8, one pair of heads that read their own at MHA): over one tile
+    (the diagonal's, masked), two and four (tiles below the diagonal
+    run unmasked, tiles above it are skipped), a length only the
+    fall-back tile divides, and oblong tiles, where the diagonal
+    crosses two tiles of a row or a column.  The statistics keep the
+    (N*H, 8, T) form the backward reads: head n*H + h, eight equal
+    sublanes."""
+    _small_tiles(monkeypatch)
+    t, by_hand, want_tile = FORWARD_TILES[tiles]
+    q, k, v, _ = operands(2, t, heads, kv, seed=t + heads)
+    blocks = by_hand or flash_gqa.default_blocks(t)
+    geo = flash_gqa._Geometry(q, k, heads, kv, *blocks)
+    assert (geo.block_q, geo.block_k) == want_tile
+    with jax.default_matmul_precision("highest"):
+        o, lse8 = flash_gqa._flash_fwd(
+            q.astype(dtype), k.astype(dtype), v.astype(dtype), D ** -0.5,
+            geo)
+        want_o, want_lse = dense(q, k, v, heads, kv), dense_lse(q, k, heads,
+                                                                kv)
+    assert o.dtype == dtype and lse8.shape == (2 * heads, 8, t)
+    np.testing.assert_array_equal(lse8, jnp.broadcast_to(lse8[:, :1],
+                                                         lse8.shape))
+    if dtype == jnp.float32:        # tests/test_expert_share.py's limits
+        np.testing.assert_allclose(o, want_o, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(lse8[:, 0], want_lse, rtol=1e-5,
+                                   atol=1e-5)
+    else:       # operands and p at 8 bits of mantissa
+        np.testing.assert_allclose(o.astype(jnp.float32), want_o,
+                                   atol=4e-2 * float(jnp.abs(want_o).max()))
+        np.testing.assert_allclose(lse8[:, 0], want_lse, atol=4e-2)
+
+
+def test_a_scale_that_is_no_power_of_two_multiplies_the_scores():
+    """0.125 rides on q, exactly; any other scale would round q's
+    bfloat16 and stays on the float32 scores."""
+    heads, kv, t = 8, 2, 32
+    q, k, v, _ = operands(1, t, heads, kv, seed=5)
+    with jax.default_matmul_precision("highest"):
+        got = flash_gqa.flash_gqa(q, k, v, heads, kv, scale=0.1, block_q=16,
+                                  block_k=16)
+        want = dense(q * (0.1 * D ** 0.5), k, v, heads, kv)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _pallas_calls(t, heads=8, kv=2, **blocks):
+    """(grid, q block, k block) of the forward and the backward calls a
+    gradient of `t` positions traces."""
+    args = [jax.ShapeDtypeStruct((1, t, h * D), jnp.bfloat16)
+            for h in (heads, kv, kv)]
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(flash_gqa.flash_gqa(*a, heads, kv, **blocks)
+                           .astype(jnp.float32)),
+        argnums=(0, 1, 2)))(*args)
+    maps = [e.params["grid_mapping"] for e in jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+    def rows_lanes(block):      # a side is an int or a Blocked(int)
+        return tuple(getattr(b, "block_size", b) for b in block[1:])
+
+    return [(m.grid, *(rows_lanes(m.block_mappings[i].block_shape)
+                       for i in (0, 1))) for m in maps]
+
+
+@pytest.mark.parametrize("t, tile, steps", [
+    (512, 512, 1), (1024, 1024, 1), (1536, 512, 3), (8192, 1024, 8),
+    (32768, 1024, 32)])
+def test_the_sequence_length_alone_chooses_the_forwards_tile(t, tile, steps):
+    """1024 x 1024 where T is a whole number of them (a shorter
+    sequence is one tile), 512 x 512 elsewhere: read from the traced
+    call, which no option reaches.  The grid is (N*Hkv, q blocks, k
+    blocks) and a q block as wide as the G/2 = 2 pairs of a key/value
+    head."""
+    assert flash_gqa.default_blocks(t) == (
+        (1024, 1024) if t % 1024 == 0 or t < 1024 else (512, 512))
+    forward = _pallas_calls(t)[0]
+    assert forward == ((2, steps, steps), (tile, 2 * 128), (tile, 128))
+    # one pair a step where each head reads its own
+    assert _pallas_calls(t, 4, 4)[0] == (
+        (2, steps, steps), (tile, 128), (tile, 128))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("t", [32, 128, 48],
+                         ids=["1_tile", "4_tiles", "fall_back_tile"])
+def test_gradients_through_the_tiles_the_length_chooses(monkeypatch, t,
+                                                        dtype):
+    """The backward kernels on the new forward's residuals (o and lse
+    at the tile the length chose, summed in another order than a 16 x 16
+    forward's): dq, dk and dv against the dense reference, within the
+    limits of the test below."""
+    _small_tiles(monkeypatch)
+    heads, kv = 8, 2
+    *args, w = operands(2, t, heads, kv, seed=t)
+    got = _grads([a.astype(dtype) for a in args], w, heads, kv, None, None)
+    want = dense_grads(*args, w, heads, kv)
+    for name, g, r in zip("qkv", got, want):
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5,
+                                       err_msg="d" + name)
+        else:
+            np.testing.assert_allclose(
+                g.astype(jnp.float32), r, err_msg="d" + name,
+                atol=4e-2 * float(jnp.abs(r).max()))
 
 
 def _backward_path(monkeypatch, path):
@@ -99,8 +255,7 @@ def test_both_backward_paths_give_the_dense_gradients(
     query tiles, the outer axis of the single kernel) and at one."""
     args, w, got = _path_grads(path, blocks, block_q, block_k, heads, kv,
                                dtype)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a, heads, kv) * w),
-                    argnums=(0, 1, 2))(*args)
+    want = dense_grads(*args, w, heads, kv)
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape and g.dtype == dtype, name   # kv heads wide
         if dtype == jnp.float32:    # tests/test_expert_share.py's limits
@@ -182,28 +337,21 @@ def test_key_value_gradients_leave_the_kernels_kv_heads_wide(monkeypatch,
     assert f"x{t}x{heads * D}xf32>" in text and "x1024xf32>" not in text
 
 
-def test_the_backward_pass_takes_its_own_blocks_where_they_divide_t():
-    """The statistics are block-free, so the backward pass has blocks
-    of its own (1024 x 1024); a sequence that is not a whole number of
-    them keeps the forward's, as before, and a block size the caller
-    gives holds for both passes."""
-    def grid_of(t, **blocks):
-        args = [jax.ShapeDtypeStruct((1, t, h * D), jnp.bfloat16)
-                for h in (8, 2, 2)]
-        jaxpr = jax.make_jaxpr(jax.grad(
-            lambda *a: jnp.sum(flash_gqa.flash_gqa(*a, 8, 2, **blocks)
-                               .astype(jnp.float32)),
-            argnums=(0, 1, 2)))(*args)
-        grids = [e.params["grid_mapping"].grid for e in jaxpr.eqns
-                 if e.primitive.name == "pallas_call"]
-        assert len(grids) == 2
-        return grids
+def test_both_passes_take_the_tile_the_length_chooses():
+    """The statistics are block-free, so the passes need not agree, but
+    one rule serves both (1024 x 1024, or 512 x 512 where T is not a
+    whole number of those), and a block size the caller gives holds for
+    both passes."""
+    def grids(t, **blocks):
+        calls = _pallas_calls(t, **blocks)
+        assert len(calls) == 2
+        return [grid for grid, *_ in calls]
 
-    # forward (N*H/2, nq, nk) at 512; backward (N*Hkv/2, G, nk, nq)
-    assert grid_of(4096) == [(4, 8, 8), (1, 4, 4, 4)]
-    assert grid_of(1536) == [(4, 3, 3), (1, 4, 3, 3)]
-    assert grid_of(256) == [(4, 1, 1), (1, 4, 1, 1)]
-    assert grid_of(4096, block_q=256) == [(4, 16, 8), (1, 4, 4, 16)]
+    # forward (N*Hkv, nq, nk); backward (N*Hkv/2, G, nk, nq)
+    assert grids(4096) == [(2, 4, 4), (1, 4, 4, 4)]
+    assert grids(1536) == [(2, 3, 3), (1, 4, 3, 3)]
+    assert grids(256) == [(2, 1, 1), (1, 4, 1, 1)]
+    assert grids(4096, block_q=256) == [(2, 16, 4), (1, 4, 4, 16)]
 
 
 def test_kernel_costs_are_registered_under_the_kernels_names():
