@@ -460,6 +460,59 @@ def selective_scan(u, delta, b, c, name=None):
     return out
 
 
+def ssd_scan(x, dt, b, c, n_heads, n_groups=1, chunk_size=256, name=None):
+    """The scan of a Mamba-2 state-space mixer (ops/decoder.py
+    `ssd_scan`): `x` (N, T, H P) the convolved input, `n_heads` H heads
+    of P lanes; `dt` (N, T, H) the step's slice of the in projection
+    before its bias and the softplus; `b`, `c` (N, T, G S), `n_groups`
+    G groups of S states.  Three learned float32 parameters a head as
+    the published class starts them: `A_log` (H,) = log(1 .. H), `D`
+    (H,) = 1, and the step's bias `dt_bias` (H,) = the inverse softplus
+    of a step drawn log-uniformly from [0.001, 0.1] and floored at
+    1e-4.  `chunk_size`: the chunk of the matrix-product form.  Returns
+    y (N, T, H P), the read-out WITH the D x term and before any gate
+    or norm."""
+    from ..initializer import NumpyArrayInitializer, SoftplusInverseLogUniform
+
+    helper = LayerHelper("ssd_scan", name=name)
+    h = int(n_heads)
+    a_log = helper.create_parameter(
+        None, shape=[h], dtype="float32",
+        default_initializer=NumpyArrayInitializer(
+            np.log(np.arange(1, h + 1, dtype=np.float32))))
+    skip = helper.create_parameter(
+        None, shape=[h], dtype="float32", default_initializer=Constant(1.0))
+    dt_bias = helper.create_parameter(
+        None, shape=[h], dtype="float32",
+        default_initializer=SoftplusInverseLogUniform(0.001, 0.1,
+                                                      floor=1e-4))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="ssd_scan",
+        inputs={"X": [x], "Dt": [dt], "B": [b], "C": [c], "ALog": [a_log],
+                "D": [skip], "DtBias": [dt_bias]},
+        outputs={"Out": [out]},
+        attrs={"n_groups": int(n_groups), "chunk_size": int(chunk_size)})
+    out.desc.shape = tuple(x.shape)
+    return out
+
+
+def gated_rms_norm(x, gate, epsilon=1e-5, name=None):
+    """The output norm of a gated state-space mixer (ops/decoder.py
+    `gated_rms_norm`): rms_norm(x * silu(gate)) * w over the minor dim,
+    the gate BEFORE the norm, one learned scale w (minor dim,) from 1."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    scale = helper.create_parameter(
+        None, shape=[int(x.shape[-1])], dtype="float32",
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="gated_rms_norm",
+                     inputs={"X": [x], "Gate": [gate], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+    out.desc.shape = tuple(x.shape)
+    return out
+
+
 def diff_combine(x, n_kv_pair, lanes, lambda_init, epsilon=1e-5, name=None):
     """Differential attention's subtraction and sub-layer norm
     (ops/decoder.py `diff_combine`): `x` (N, T, H * lanes) the contexts

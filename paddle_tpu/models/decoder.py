@@ -15,12 +15,17 @@ values are not built yet raises):
   model's or the layer's own, its context gated a lane, a head or not;
   the gated short convolution; the gated-delta-rule linear-attention
   mixer (a chunked scan, `ops/pallas/gated_delta.py`); the Mamba-1
-  state-space mixer (a selective scan, `ops/pallas/selective_scan.py`);
+  state-space mixer (a selective scan, `ops/pallas/selective_scan.py`)
+  and the Mamba-2 one (one decay a head over a matrix state, its
+  chunked matrix-product form, `ops/pallas/ssd_scan.py`, under a gated
+  RMSNorm);
   differential attention (two soft-max maps subtracted) on those flash
   kernels; layers that READ another layer's work (gated memory units
   on one layer's scan output, cross-attention on one layer's keys and
   values); RMSNorm or LayerNorm, projections with or without a bias,
-  RoPE or no positions at all;
+  RoPE or no positions at all; attention under a scale of the
+  configuration's own; multipliers on the embedding, on every residual
+  branch and under the logits;
   latent attention (`kv_lora_rank` ...: queries, keys and values out
   of low-rank latents, a rotary part beside the unrotated one, its own
   flash kernels);
@@ -272,6 +277,47 @@ activation="silu", bias_attr=)`); the recurrence is ONE op,
 16 states, T whole chunks).  Its ops lower under the `state_space` name
 scope.
 
+`layer_types[i]` = "mamba" with `mamba_n_heads` / `mamba_d_head` /
+`mamba_n_groups` / `mamba_chunk_size` in place of `mamba_dt_rank` (a
+Mamba-2 state-space mixer, state-space duality, Dao & Gu,
+arXiv:2405.21060; WHICH mixer a "mamba" layer is follows from the keys
+a configuration has: both key sets, or neither, raises) is a mixer
+whose state is a `mamba_d_head` x `mamba_d_state` MATRIX a head under
+ONE decay a (position, head), B and C shared by all the heads of a
+group:
+
+    z = h W_z;  xBC = silu(conv(h W_xBC) + b_conv);  dt = h W_dt
+    [x | B | C] = xBC       (d_inner + 2 mamba_n_groups mamba_d_state)
+    dt = softplus(dt + dt_bias) a head;  A = -exp(A_log)      (heads,)
+    S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] B_t^T
+    y_t[h] = S_t[h] C_t + D[h] x_t[h]
+    out = (rms_norm(y * silu(z)) * w) W_out
+
+d_inner = `mamba_n_heads` x `mamba_d_head` (= `mamba_expand` x
+hidden_size; a share of a mixer's heads raises).  The in projection is
+three projections of the one input (a checkpoint's fused matrix splits
+[z | xBC | dt] by columns); ONE convolution runs over x, B and C
+together (`layers.short_conv(activation="silu", bias_attr=)`, causal,
+depthwise, `mamba_d_conv` taps); the step needs no low-rank projection,
+only its bias; the recurrence is ONE op, `ssd_scan`
+(`ops/pallas/ssd_scan.py`: the chunked matrix-product form, chunks of
+`mamba_chunk_size`, two Pallas kernels at heads of 64, 128 states, one
+group, chunks of 256); the gate comes BEFORE the norm, the norm runs
+over all d_inner lanes (`layers.gated_rms_norm`, one fused op, under
+the name scope `gated_rms_norm`).  `mamba_n_groups` > 1 raises.  Its
+ops lower under the `state_space_duality` name scope (Mamba-1 keeps
+`state_space`).
+
+**Four multipliers**, each a key of the configurations that have them
+and absent (None) elsewhere, where the step's ops are what they were:
+`embedding_multiplier` (x_0 = m E[tokens]), `residual_multiplier`
+(x = x + m op(norm(x)), every mixer's and every feed-forward's branch,
+a `scale` op before the add), `attention_multiplier` (the soft-max's
+scale in place of head_dim^-1/2: `layers.flash_attention(scale=)`; the
+operator then lowers under the name scope `full_attention`) and
+`logits_scaling` (logits = (x W_head) / m, a `scale` op on the logits,
+which XLA fuses into the loss's first pass).
+
 **Values that cross layers** (a decoder-hybrid-decoder, Ren et al.,
 arXiv:2507.06607).  Two layers may EXPORT an intermediate that later
 layers read, state of the layer loop and nothing else:
@@ -366,7 +412,10 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             attention_bias=False, positions="rope", layer_indices=None,
             mamba_d_state=None, mamba_d_conv=None, mamba_expand=None,
             mamba_dt_rank=None, shared_memory_layer=None,
-            shared_kv_layer=None):
+            shared_kv_layer=None, mamba_n_heads=None, mamba_d_head=None,
+            mamba_n_groups=None, mamba_chunk_size=None,
+            embedding_multiplier=None, residual_multiplier=None,
+            attention_multiplier=None, logits_scaling=None):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
     labels' own successors, with a prediction module); under
@@ -498,10 +547,44 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     if len(layer_indices) != num_hidden_layers:
         raise ValueError(f"{len(layer_indices)} layer_indices for "
                          f"{num_hidden_layers} layers")
-    mamba = (mamba_d_state, mamba_d_conv, mamba_expand, mamba_dt_rank)
-    if "mamba" in layer_types and None in mamba:
-        raise ValueError("a mamba layer needs mamba_d_state, mamba_d_conv, "
-                         "mamba_expand and mamba_dt_rank")
+    # which state-space mixer a "mamba" layer is follows from the keys
+    # a configuration has: a low-rank step (a vector state a channel) or
+    # heads (a matrix state a head under one decay)
+    vector_keys = {"mamba_dt_rank": mamba_dt_rank}
+    head_keys = {"mamba_n_heads": mamba_n_heads,
+                 "mamba_d_head": mamba_d_head,
+                 "mamba_n_groups": mamba_n_groups,
+                 "mamba_chunk_size": mamba_chunk_size}
+    by_head = any(v is not None for v in head_keys.values())
+    if by_head and mamba_dt_rank is not None:
+        raise ValueError(
+            f"a mamba layer is given twice: {sorted(vector_keys)} (a step "
+            f"of low rank: a vector state a channel) beside "
+            f"{sorted(k for k, v in head_keys.items() if v is not None)} "
+            f"(heads: a matrix state a head)")
+    if "mamba" in layer_types:
+        sizes = dict(mamba_d_state=mamba_d_state, mamba_d_conv=mamba_d_conv,
+                     mamba_expand=mamba_expand,
+                     **(head_keys if by_head else vector_keys))
+        if None in sizes.values():
+            raise ValueError(
+                f"a mamba layer needs mamba_d_state, mamba_d_conv, "
+                f"mamba_expand and either {sorted(vector_keys)} or "
+                f"{sorted(head_keys)}; missing "
+                f"{sorted(k for k, v in sizes.items() if v is None)}")
+        if by_head:
+            if mamba_n_groups != 1:
+                raise NotImplementedError(
+                    f"mamba_n_groups {mamba_n_groups}: several groups of B "
+                    f"and C are not built")
+            if mamba_n_heads * mamba_d_head != mamba_expand * hidden_size:
+                raise NotImplementedError(
+                    f"{mamba_n_heads} heads of {mamba_d_head} are not the "
+                    f"mixer's {mamba_expand * hidden_size} lanes: a share "
+                    f"of a mixer's heads is not built")
+            if shared_memory_layer is not None:
+                raise NotImplementedError(
+                    "a memory unit on a scan of heads is not built")
     # what crosses layers: each reader's exporter is named, is of the
     # kind that makes the value, and comes before it
     for reader, key, at, makes in [
@@ -649,14 +732,24 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
 
     def turn_qk(x, n_head, turn):
         """QK-norm, then RoPE; a norm a head rides in the `rope` op."""
+        if turn is None:        # no positions of any kind
+            if qk_norm == "head":
+                raise NotImplementedError(
+                    "qk_norm='head' without positions is not built")
+            return x if qk_norm is None else norm(x)
         if qk_norm == "head":
             return layers.rope(x, n_head, norm=True, epsilon=eps,
                                zero_centered=zero_centered_norm, **turn)
         return layers.rope(x if qk_norm is None else norm(x), n_head, **turn)
 
     def attention(h, kind, heads=num_attention_heads):
-        turn = rotary[kind]
+        turn = rotary[kind] if positions == "rope" else None
         q_size = heads * head_dim
+        scaled = {}
+        if attention_multiplier is not None:
+            # the soft-max's scale is the configuration's, not d_head^-1/2
+            scaled["scale"] = float(attention_multiplier)
+            runtime_stats.record_scaled_attention()
         # a program that mixes kinds of layer tells their rows apart
         scope = "gated_attention" if attention_gate == "sigmoid" else kind
         if diffusion:
@@ -664,7 +757,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             scope = "block_diffusion_attention"
             turn = dict(turn, period=max_length)
         with name_scope(scope) if windowed or attention_gate or diffusion \
-                else contextlib.nullcontext():
+                or scaled else contextlib.nullcontext():
             q = turn_qk(proj(h, q_size, "attn_qkv"), heads, turn)
             k = turn_qk(proj(h, kv_size, "attn_qkv"), num_key_value_heads,
                         turn)
@@ -674,7 +767,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                 layout="nthd", n_head=heads,
                 n_kv_head=num_key_value_heads,
                 window=sliding_window if kind == "sliding_attention"
-                else None, block_diffusion=block_length)
+                else None, block_diffusion=block_length, **scaled)
             if attention_gate == "sigmoid":
                 # a gate a lane of the context, from the layer's input
                 ctx = layers.elementwise_mul(ctx, layers.sigmoid(
@@ -783,6 +876,30 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                                              kv_reads=int(cross))
             return proj(ctx, hidden_size, "attn_out", attention_bias)
 
+    def state_space_duality(h, at):
+        """A Mamba-2 mixer: z, xBC and the step a head out of the input
+        (three projections of one input, as `dense_ffn` writes its two;
+        a checkpoint's one fused matrix splits [z | xBC | dt] by
+        columns), x, B and C TOGETHER through one short causal
+        convolution and a SiLU, the scan of heads, the gated norm."""
+        d_inner = mamba_n_heads * mamba_d_head
+        with name_scope("state_space_duality"):
+            z = proj(h, d_inner, "ssd_in")
+            xbc = layers.short_conv(
+                proj(h, d_inner + 2 * mamba_n_groups * mamba_d_state,
+                     "ssd_in"), mamba_d_conv, param_attr=weight(),
+                activation="silu", bias_attr=True)
+            dt = proj(h, mamba_n_heads, "ssd_in")
+            x, b, c = layers.split(
+                xbc, [d_inner, mamba_n_groups * mamba_d_state,
+                      mamba_n_groups * mamba_d_state], dim=2)
+            y = layers.ssd_scan(x, dt, b, c, mamba_n_heads,
+                                n_groups=mamba_n_groups,
+                                chunk_size=mamba_chunk_size)
+            with name_scope("gated_rms_norm"):
+                y = layers.gated_rms_norm(y, z, epsilon=eps)
+            return proj(y, hidden_size, "ssd_out")
+
     def state_space(h, at):
         """Layer `at`, a Mamba-1 mixer: u and z out of the input, u
         through a short causal convolution and a SiLU, the step, B and C
@@ -859,7 +976,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     if kv_lora_rank is not None:
         mixers["full_attention"] = latent_attention
         del mixers["sliding_attention"]
-    mixers.update(mamba=state_space, gated_memory=gated_memory)
+    mixers.update(mamba=state_space_duality if by_head else state_space,
+                  gated_memory=gated_memory)
     placed = ("mamba",)     # the mixers that are told which layer they are
     if differential:
         placed += attention_kinds + ("cross_attention",)
@@ -878,10 +996,17 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             op = functools.partial(op, heads=heads)
         if kind in placed:
             op = functools.partial(op, at=at)     # what it exports, by place
-        post = norm if sandwich_norm else (lambda y: y)
-        x = layers.elementwise_add(x, post(op(norm(x))))
+        def branch(y):
+            """What a sub-layer adds to the stream: normed again under
+            `sandwich_norm`, times `residual_multiplier` where given."""
+            y = norm(y) if sandwich_norm else y
+            if residual_multiplier is None:
+                return y
+            return layers.scale(y, scale=float(residual_multiplier))
+
+        x = layers.elementwise_add(x, branch(op(norm(x))))
         ffn = dense_ffn if dense else routed_ffn
-        return layers.elementwise_add(x, post(ffn(norm(x))))
+        return layers.elementwise_add(x, branch(ffn(norm(x))))
 
     def segment():
         return (recompute_scope() if recompute == "layer"
@@ -895,6 +1020,14 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         return x
 
     def head(x):
+        if logits_scaling is not None:
+            # the loss reads (x E^T) / logits_scaling: on the logits
+            # themselves (XLA fuses it into the loss's first pass)
+            return layers.scale(unscaled_head(x),
+                                scale=1.0 / float(logits_scaling))
+        return unscaled_head(x)
+
+    def unscaled_head(x):
         if tie_word_embeddings:
             table = x.block.program.global_block().var(embed_name)
             return layers.matmul(x, table, transpose_y=True)
@@ -972,6 +1105,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         param_attr=ParamAttr(name=embed_name, initializer=Normal(
             0.0, initializer_range if embedding_init_range is None
             else embedding_init_range)))
+    if embedding_multiplier is not None:
+        x = layers.scale(x, scale=float(embedding_multiplier))
     feeds = ["tokens", "labels"]
     if loops:
         return dict(looped(x), aux=None, z=None, counts=[], experts=[],

@@ -82,6 +82,8 @@ _FIELDS = Heard._fields[1:] + (
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "short_convs_kernel", "short_convs_xla", "short_conv_bias_calls",
     "selective_scans_kernel", "selective_scans_xla", "selective_scan_chunks",
+    "ssd_scans_kernel", "ssd_scans_xla", "ssd_scan_chunks",
+    "gated_rms_norm_calls", "scaled_attention_calls",
     "differential_attention_calls", "shared_memory_reads", "shared_kv_reads",
     "ropes_kernel", "ropes_xla",
     "share_rows_kernel", "share_rows_xla",
@@ -241,6 +243,19 @@ class RuntimeStats:
         self.selective_scans_kernel = 0
         self.selective_scans_xla = 0
         self.selective_scan_chunks = 0
+        # scans of the scalar-a-head state-space form
+        # (`ops/pallas/ssd_scan.py`) traced, as the three above: the
+        # Pallas kernels or the XLA lowering, and the chunks x batch the
+        # kernels walk
+        self.ssd_scans_kernel = 0
+        self.ssd_scans_xla = 0
+        self.ssd_scan_chunks = 0
+        # `gated_rms_norm` ops traced (the norm of y * silu(z) in one
+        # pass), and attention calls BUILT under a scale that a
+        # configuration gives (`models/decoder.py`: not d_head^-1/2;
+        # delta() around a Program build)
+        self.gated_rms_norm_calls = 0
+        self.scaled_attention_calls = 0
         # differential attention calls built (`models/decoder.py`: two
         # soft-max maps subtracted, a layer with its own K and V or a
         # reader of another's), and the layers built that READ another
@@ -394,6 +409,22 @@ class RuntimeStats:
                 self.selective_scan_chunks += chunks
             else:
                 self.selective_scans_xla += 1
+
+    def record_ssd_scan(self, kernel: bool, chunks: int):
+        with self._lock:
+            if kernel:
+                self.ssd_scans_kernel += 1
+                self.ssd_scan_chunks += chunks
+            else:
+                self.ssd_scans_xla += 1
+
+    def record_gated_rms_norm(self):
+        with self._lock:
+            self.gated_rms_norm_calls += 1
+
+    def record_scaled_attention(self):
+        with self._lock:
+            self.scaled_attention_calls += 1
 
     def record_cross_layer(self, differential=0, memory_reads=0, kv_reads=0):
         with self._lock:

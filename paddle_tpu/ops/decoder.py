@@ -6,7 +6,9 @@ that stands where attention does in most layers of a hybrid
 conv/attention model, gated or under a SiLU), `latent_attention` (the
 attention core of a layer whose keys and values come out of a low-rank
 latent, with a rotary part beside it), `gated_delta_rule` (the scan
-of a linear-attention layer), `selective_scan` (the scan of a
+of a linear-attention layer), `ssd_scan` and `gated_rms_norm` (the scan
+and the gated output norm of a Mamba-2 state-space mixer),
+`selective_scan` (the scan of a
 state-space mixer with a diagonal state a channel) and `diff_combine`
 (what differential attention does with its two soft-max maps'
 contexts).
@@ -477,6 +479,59 @@ def selective_scan(ctx, ins, attrs):
     return out(Out=scan.selective_scan(
         first(ins, "U"), first(ins, "Delta"), a, first(ins, "B"),
         first(ins, "C"), first(ins, "D"), first(ins, "DeltaBias")))
+
+
+@register_op("ssd_scan")
+def ssd_scan(ctx, ins, attrs):
+    """The mixer core of a Mamba-2 state-space layer (state-space
+    duality, arXiv:2405.21060), over one sequence a row.  X (N, T, H P):
+    the convolved, activated input, H heads of P lanes; Dt (N, T, H):
+    the step's slice of the in projection BEFORE its bias and the
+    softplus; B, C (N, T, G S): `n_groups` G groups of S states, a
+    group shared by H / G heads; ALog, D and DtBias (H,).  In float32:
+
+        dt = softplus(Dt + DtBias);  A = -exp(ALog)          (a head)
+        S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] B_t^T
+        Out_t[h] = S_t[h] C_t + D[h] x_t[h]                  (N, T, H P)
+
+    with S[h] (P, S) from 0; Out in X's dtype.  The bias and the
+    softplus are taken HERE, not in the kernels: the step is a
+    (position, head) scalar (2 MB a layer in float32 at 8192 x 64), so
+    XLA makes it, its cumulative sums and their exponentials, and
+    autodiff their gradients.  Two lowerings of the one recurrence in
+    its chunked matrix-product form (chunks of `chunk_size`), chosen by
+    the shape alone (`ops/pallas/ssd_scan.py ssd_scan_takes`: heads of
+    64 lanes, 128 states, one group, chunks of 256, T whole chunks):
+    the two Pallas kernels there, whose state and decay masks never
+    leave VMEM, or the same chunks as XLA einsums under a `lax.scan`.
+    `runtime_stats.ssd_scans_kernel` / `_xla` count the calls traced
+    each way."""
+    from .pallas import selective_scan, ssd_scan as scan
+
+    f32 = jnp.float32
+    dt = selective_scan.softplus(first(ins, "Dt").astype(f32)
+                                 + first(ins, "DtBias").astype(f32))
+    return out(Out=scan.ssd_scan(
+        first(ins, "X"), dt, -jnp.exp(first(ins, "ALog").astype(f32)),
+        first(ins, "B"), first(ins, "C"), first(ins, "D"),
+        chunk=int(attrs.get("chunk_size", scan.CHUNK)),
+        groups=int(attrs.get("n_groups", 1))))
+
+
+@register_op("gated_rms_norm")
+def gated_rms_norm(ctx, ins, attrs):
+    """The output norm of a gated state-space mixer, the gate BEFORE
+    the norm: Y = rms_norm(X * silu(Gate)) * Scale over the minor dim.
+    The product, the statistics and the scale in float32, one fusion; Y
+    in X's dtype."""
+    from ..observe.monitoring import runtime_stats
+
+    runtime_stats.record_gated_rms_norm()
+    x = first(ins, "X")
+    f32 = jnp.float32
+    y = x.astype(f32) * jax.nn.silu(first(ins, "Gate").astype(f32))
+    y = _over_rms(y, (-1,), attrs.get("epsilon", 1e-5))
+    return out(Y=(y * first(ins, "Scale").astype(f32)).astype(x.dtype))
 
 
 @register_op("diff_combine")

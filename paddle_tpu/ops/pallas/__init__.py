@@ -52,8 +52,10 @@ def interpret() -> bool:
 # the one that solves for it; and the selective scan's output and the
 # states that enter its chunks (`selective_scan.py`: 84 + 10.5 MB a
 # layer at 8192 x 5120 for a forward scan of the whole sequence), which
-# are all its backward kernel reads besides the operands.  ONE mechanism
-# in three places: whoever
+# are all its backward kernel reads besides the operands; and the same
+# two of the scalar-a-head scan (`ssd_scan.py`: 67 + 67 MB a layer at
+# 8192 positions x 64 heads of 64 x 128 states).  ONE mechanism in four
+# places: whoever
 # makes such a residual names it through `keep_residuals`, and the
 # executor's `jax.checkpoint` saves exactly these names
 # (`segment_policy`).  Names without that policy are inert (a `name`
@@ -61,7 +63,9 @@ def interpret() -> bool:
 ATTENTION_RESIDUALS = ("attention_out", "attention_logsumexp")
 INVERSE_RESIDUAL = ("gated_delta_inverse",)
 SCAN_RESIDUALS = ("selective_scan_out", "selective_scan_states")
-KEPT_RESIDUALS = ATTENTION_RESIDUALS + INVERSE_RESIDUAL + SCAN_RESIDUALS
+SSD_RESIDUALS = ("ssd_scan_out", "ssd_scan_states")
+KEPT_RESIDUALS = (ATTENTION_RESIDUALS + INVERSE_RESIDUAL + SCAN_RESIDUALS
+                  + SSD_RESIDUALS)
 _open_segments = [0]
 
 

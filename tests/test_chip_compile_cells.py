@@ -643,6 +643,51 @@ def test_the_state_space_cells_step_and_the_plan_its_length_read(one_chip):
                                                 + 32 * 16 * 5120 * 4)
 
 
+def test_the_state_space_duality_cells_step_and_the_plan_its_length_read(
+        one_chip):
+    """The whole training step of `granite4h-8k` as `benchmarks/run.py`
+    builds it (ten layers at the published widths, 8192 rows, bf16 AMP,
+    every layer a recompute segment), compiled for the described chip,
+    nothing run.  The cell's length (ISSUE 58: 8192 if the plan reads
+    15.0 GB or less) read THIS plan: arguments (aliased to the outputs)
+    9.27 GB + temporaries 3.69 GB = 12.96 GB; at 16384 9.27 + 7.37 =
+    16.64 GB, over the chip's 15.75 (PERF.md, PR 58).  A mamba layer is
+    ONE `ssd_scan_fwd` and one `ssd_scan_bwd` (its segment keeps the
+    scan's output and entry states, 67 + 67 MB) and its biased
+    convolution's kernels; the attention layer one forward and one
+    backward `flash_gqa` kernel; no (chunks, heads, 256, 256) float32
+    decay mask is a tensor of the step."""
+    import re
+
+    parameters, compiled, plan, kernels, took = _cell_step("granite4h-8k",
+                                                           one_chip)
+    assert parameters == 772160448
+    assert plan["arguments"] == pytest.approx(9.27, abs=0.01)
+    assert 12.0 < plan["total"] <= 15.0, plan
+    assert (kernels["ssd_scan_fwd"], kernels["ssd_scan_bwd"]) == (9, 9)
+    assert (kernels["short_conv_fwd"], kernels["short_conv_bwd"]) == (18, 9)
+    assert (kernels["flash_gqa_fwd"], kernels["flash_gqa_dkv"]) == (1, 1)
+    assert set(kernels) == {"ssd_scan_fwd", "ssd_scan_bwd", "short_conv_fwd",
+                            "short_conv_bwd", "flash_gqa_fwd",
+                            "flash_gqa_dkv"}
+    # no fall-back anywhere: by the step's trace (a mamba layer's
+    # forward, its forward traced again for the segment's backward
+    # pass, its backward: 32 chunks a call)
+    assert (took["ssd_scans_kernel"], took["ssd_scans_xla"],
+            took["ssd_scan_chunks"]) == (27, 0, 27 * 32)
+    assert (took["short_convs_kernel"], took["short_convs_xla"],
+            took["short_conv_bias_calls"]) == (9, 0, 9)
+    assert (took["flash_gqa_backward_fused"],
+            took["flash_gqa_backward_split"]) == (1, 0)
+    assert took["gated_rms_norm_calls"] == 9
+    assert took["selective_scans_kernel"] == took["selective_scans_xla"] == 0
+    # nine scans' (y, states) and the attention call's (o, logsumexp)
+    assert took["recompute_kept_residuals"] == 10
+    assert took["recompute_kept_bytes"] >= 9 * (8192 * 4096 * 2
+                                                + 32 * 32 * 128 * 128 * 4)
+    assert not re.search(r"f32\[[0-9,]*256,256\]", compiled.as_text())
+
+
 def test_the_linear_attention_cells_step_keeps_its_inverses_under_the_plan(
         one_chip):
     """The whole training step of `qwen3next-16k` (one period of four

@@ -353,6 +353,14 @@ STEP_TEXT = {
     # name in `KEPT_RESIDUALS` that a step never emits leave it alone
     "phi4flash-8k":
     "8262a62f096c9ae6b27eeda5de0d5d97703040ecc95e775e371806904d511e32",
+    # new in PR 58 (the scalar-a-head scan through the interpreter, the
+    # gated norm, one biased convolution over x, B and C, grouped flash
+    # attention under a scale of 2^-6, the four multipliers); every
+    # other cell keeps its parent's text: a multiplier that is absent
+    # appends no op, and the names `KEPT_RESIDUALS` gained are emitted
+    # by no other step
+    "granite4h-8k":
+    "d40ed03ddc9294461d897b9b08d6cf9a242f1d851e516f29864de0b10c8e37ac",
 }
 
 

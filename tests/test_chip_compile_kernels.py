@@ -1,7 +1,9 @@
 """The chip's compiler on the other kernel families, each alone: latent
 attention (`ops/pallas/flash_mla.py`) at `joyai-8k`'s shape, the chunked
 delta-rule scan (`ops/pallas/gated_delta.py`) and grouped flash
-attention at d_head 256 at `qwen3next-16k`'s, the short convolution
+attention at d_head 256 at `qwen3next-16k`'s, the scalar-a-head scan
+(`ops/pallas/ssd_scan.py`) and grouped flash attention under a scale of
+2^-6 at `granite4h-8k`'s, the short convolution
 (`ops/pallas/short_conv.py`) at that cell's and `lfm2-8k`'s, the fused
 vocabulary cross-entropy, paged attention, the fused LSTM recurrence;
 and the cost table over whole steps it compiled (every Mosaic kernel has
@@ -277,6 +279,122 @@ def test_selective_scan_kernels_at_the_published_shapes(one_chip, dtype):
     assert flops["selective_scan_bwd"] == 24 * t * d * s
     # the states that enter the 32 chunks, float32: 10.5 MB
     assert f"f32[1,32,{s},{d}]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_ssd_scan_kernels_at_the_published_shapes(one_chip, dtype):
+    """The `ssd_scan` op and its seven gradients at `granite4h-8k`'s
+    shape, (1, 8192) positions x 64 heads of 64 x 128 states in chunks
+    of 256, in the cell's bfloat16 and the parity script's float32, and
+    the biased SiLU convolution that feeds it (4352 = 34 x 128 channels
+    x 4 taps + a bias: x, B and C together): the shape rule takes both,
+    so the scan with its gradient is TWO Mosaic kernels, `ssd_scan_fwd`
+    and `ssd_scan_bwd` (which rebuilds a chunk's masks in VMEM and
+    transposes its G there), and the convolution two more; each has a
+    registered cost, the scan's the FLOP the chunked form executes, and
+    sits under its op's scope.  No (chunks, heads, 256, 256) decay mask
+    is a tensor of the compiled text: what leaves the kernels float32
+    is the entry states (67 MB) and a (position, head)'s scalars."""
+    import re
+
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas import ssd_scan as kernels
+
+    t, heads, p, s, taps = 8192, 64, 64, 128, 4
+    d, wide = heads * p, heads * p + 2 * s
+    scan, conv = get_op_impl("ssd_scan"), get_op_impl("short_conv")
+
+    def both(xbc, w, bias, ct, dt, a_log, skip, dt_bias):
+        def fn(xbc, w, bias, dt, a_log, skip, dt_bias):
+            ctx = OpContext(jax.random.PRNGKey(0), 0)
+            with jax.named_scope("state_space_duality/short_conv:3"):
+                u = conv(ctx, {"X": [xbc], "Filter": [w], "Bias": [bias]},
+                         {"activation": "silu"})["Out"][0]
+            with jax.named_scope("state_space_duality/ssd_scan:9"):
+                return scan(ctx, {
+                    "X": [u[..., :d]], "Dt": [dt], "B": [u[..., d:d + s]],
+                    "C": [u[..., d + s:]], "ALog": [a_log], "D": [skip],
+                    "DtBias": [dt_bias]}, {"n_groups": 1,
+                                           "chunk_size": 256})["Out"][0]
+
+        o, vjp = jax.vjp(fn, xbc, w, bias, dt, a_log, skip, dt_bias)
+        return o, vjp(ct)
+
+    def spec(shape, kind):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
+
+    assert kernels.ssd_scan_takes(t, heads, p, s, 1, 256)
+    before = runtime_stats.snapshot()
+    compiled = _compile_args(
+        jax.jit(both), spec((1, t, wide), dtype), spec((wide, taps), F32),
+        spec((wide,), F32), spec((1, t, d), dtype), spec((1, t, heads), dtype),
+        spec((heads,), F32), spec((heads,), F32), spec((heads,), F32))
+    took = runtime_stats.delta(before)
+    assert (took["ssd_scans_kernel"], took["ssd_scans_xla"],
+            took["ssd_scan_chunks"]) == (2, 0, 2 * 32)
+    assert (took["short_convs_kernel"], took["short_convs_xla"],
+            took["short_conv_bias_calls"]) == (1, 0, 1)
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "short_conv_bwd", "short_conv_fwd", "ssd_scan_bwd", "ssd_scan_fwd"]
+    by_kernel = {r["kernel"]: r["op_type"] for r in rows if r["kernel"]}
+    assert by_kernel["ssd_scan_fwd"] == by_kernel["ssd_scan_bwd"] == "ssd_scan"
+    assert by_kernel["short_conv_bwd"] == "short_conv"
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 4
+    flops = {r["kernel"]: r["flops"] for r in rows if r["kernel"]}
+    a_chunk = 2 * 256 * 256 * 128, 2 * 256 * 256 * 64, 2 * 256 * 128 * 64
+    assert flops["ssd_scan_fwd"] == 32 * (
+        a_chunk[0] + heads * (a_chunk[1] + 2 * a_chunk[2]))
+    assert flops["ssd_scan_bwd"] == 32 * (
+        3 * a_chunk[0] + heads * (3 * a_chunk[1] + 5 * a_chunk[2]))
+    text = compiled.as_text()
+    # the states that enter the 32 chunks, a pair of heads a tile: 67 MB
+    assert f"f32[1,32,{heads // 2},{s},128]" in text
+    assert not re.search(r"f32\[[0-9,]*256,256\]", text)
+
+
+def test_flash_gqa_under_a_scale_that_is_a_power_of_two(one_chip):
+    """`granite4h-8k`'s attention call: `lfm2-8k`'s geometry (32 / 8
+    heads of 64, 8192 positions, bfloat16) under the scale 2^-6 where
+    that cell's is 64^-1/2.  A power of two rides on q exactly
+    (`flash_gqa.py`), any other scale on the scores: the kernels the
+    compiler gets are the same three names, forward at the 1024 x 1024
+    tiles PR 56 chose and ONE backward kernel, under both scales."""
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas import flash_gqa
+    from paddle_tpu.ops.pallas.flash_attention import \
+        pallas_flash_attention
+
+    n, t, heads, kv = 1, 8192, 32, 8
+    assert flash_gqa.default_blocks(t) == (1024, 1024)
+    args = [jax.ShapeDtypeStruct((n, t, h * 64), BF16, sharding=one_chip)
+            for h in (heads, kv, kv)]
+    kernels = {}
+    for scale in (2.0 ** -6, 64 ** -0.5):
+        def loss(q, k, v, scale=scale):
+            with jax.named_scope("full_attention/flash_attention:9"):
+                o = pallas_flash_attention(q, k, v, None, scale, True,
+                                           layout="nthd", n_head=heads,
+                                           n_kv_head=kv)
+            return jnp.sum(o.astype(F32))
+
+        before = runtime_stats.snapshot()
+        compiled = _compile_args(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                                 *args)
+        took = runtime_stats.delta(before)
+        assert (took["flash_gqa_backward_fused"],
+                took["flash_gqa_backward_split"]) == (1, 0)
+        rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
+        kernels[scale] = sorted(
+            (r["kernel"], r["flops"]) for r in rows if r["kernel"])
+    assert kernels[2.0 ** -6] == kernels[64 ** -0.5]
+    assert [k for k, _ in kernels[2.0 ** -6]] == ["flash_gqa_dkv",
+                                                 "flash_gqa_fwd"]
 
 
 # heads, d_head, lanes that turn
