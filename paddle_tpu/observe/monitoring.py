@@ -75,6 +75,9 @@ _FIELDS = Heard._fields[1:] + (
     "attention_head_gate_calls",
     "flash_block_diffusion_calls", "flash_block_diffusion_blocks_visited",
     "flash_block_diffusion_blocks_allowed",
+    "flash_block_diffusion_grid_steps",
+    "flash_block_diffusion_pairs_allowed",
+    "flash_block_diffusion_entries_computed",
     "gated_delta_calls", "gated_delta_chunks",
     "gated_delta_operand_calls", "gated_delta_operand_chunks",
     "gated_delta_inverse_calls",
@@ -182,13 +185,20 @@ class RuntimeStats:
         # (`attention_gate="head"`), one a layer, at program build time
         self.attention_head_gate_calls = 0
         # the same pair for the forward kernel under the block-diffusion
-        # mask (`flash_attention.py _DiffusionBand`: tiles a head's grid
-        # computes, and tiles that hold an allowed pair), and the calls
+        # mask (`flash_block_diffusion.py _DiffusionBand`: tiles a head's
+        # grid computes, and tiles that hold an allowed pair), and the calls
         # of it traced, forward and recomputed; 0 calls = a step that
         # fell back to the XLA lowering under an explicit mask
         self.flash_block_diffusion_calls = 0
         self.flash_block_diffusion_blocks_visited = 0
         self.flash_block_diffusion_blocks_allowed = 0
+        # what the visit table is for (PR 59), a head's pass each: the
+        # grid steps it takes (beside `_blocks_allowed`: 1.0 = no step
+        # computes nothing), the pairs the mask allows and the score
+        # entries the visits compute (their fill)
+        self.flash_block_diffusion_grid_steps = 0
+        self.flash_block_diffusion_pairs_allowed = 0
+        self.flash_block_diffusion_entries_computed = 0
         # calls of the chunked delta-rule scan's Pallas kernels traced
         # (`ops/pallas/gated_delta.py`: a layer's forward, its
         # recomputed forward and its backward are a call each) and their
@@ -362,11 +372,16 @@ class RuntimeStats:
         with self._lock:
             self.attention_head_gate_calls += 1
 
-    def record_flash_block_diffusion(self, visited: int, allowed: int):
+    def record_flash_block_diffusion(self, visited: int, allowed: int,
+                                     steps: int = 0, pairs: int = 0,
+                                     entries: int = 0):
         with self._lock:
             self.flash_block_diffusion_calls += 1
             self.flash_block_diffusion_blocks_visited += visited
             self.flash_block_diffusion_blocks_allowed += allowed
+            self.flash_block_diffusion_grid_steps += steps
+            self.flash_block_diffusion_pairs_allowed += pairs
+            self.flash_block_diffusion_entries_computed += entries
 
     def record_gated_delta(self, chunks: int):
         with self._lock:

@@ -230,7 +230,7 @@ def flash_attention(ctx, ins, attrs):
         # no sp axis in this compile: fall through to the local kernel
     use_pallas = attrs.get("use_pallas", False)
     if use_pallas and block_diffusion is not None:
-        from .pallas.flash_attention import block_diffusion_takes
+        from .pallas.flash_block_diffusion import block_diffusion_takes
 
         # a half the kernels' tiles do not divide runs the explicit mask
         use_pallas = block_diffusion_takes(t, block_diffusion)

@@ -137,14 +137,15 @@ DEFAULT_WINDOW_BWD_BLOCK_K = 512
 # call forward against 9.47 at 1024 x 1024 and 14.69 at 256 x 256 (a
 # grid step costs ~0.9 us beside ~1.2 us of work a 512 x 512 tile)
 MIN_WINDOW_FWD_BLOCK = 256
-# The block-diffusion mask's square tiles, forward and backward, tuned
-# on v5e at 2 x 8192 rows, 32 / 4 heads of 128, blocks of 4, alone
-# (PERF.md, PR 47): 1024 x 1024 takes 11.2 ms forward and 33.7 forward +
-# backward, 512 x 512 19.4 and 47.7, 256 x 256 45.7 and 109.8 (the
-# causal band kernels over the same 2 L rows 19.0 and 55.2).  Of a
-# head's 256 tiles of 1024 x 1024 80 hold an allowed pair and 24 of
-# those a mask (of 1024 tiles of 512 x 512: 288 and 48: fewer pairs
-# computed, and three times the grid steps)
+# The block-diffusion mask's square tiles (`flash_block_diffusion.py`),
+# forward and backward, tuned on v5e at 2 x 8192 rows, 32 / 4 heads of
+# 128, blocks of 4, alone: on the list of visits 1024 x 1024 takes 10.1
+# ms forward and 28.6 forward + backward, 512 x 512 18.1 and 39.4
+# (PERF.md, PR 59; on the rectangles of PR 47 11.2 / 33.7 and 19.4 /
+# 47.7, 256 x 256 45.7 / 109.8).  Of a head's 256 tiles of 1024 x 1024
+# 80 hold an allowed pair, 16 of them half a mask and 8 a diagonal of
+# 128 x 128 squares (of 1024 tiles of 512 x 512: 288, 32 and 16: fewer
+# pairs computed, and three and a half times the grid steps)
 DEFAULT_DIFFUSION_BLOCK = 1024
 DEFAULT_DIFFUSION_BWD_BLOCK = 1024
 NEG_INF = -1e30
@@ -265,8 +266,8 @@ def _register_costs():
     register_kernel_cost("flash_fwd", flash_fwd_cost)
     register_kernel_cost("flash_dkv", flash_dkv_cost)
     register_kernel_cost("flash_dq", flash_dq_cost)
-    # a call with a window or under the block-diffusion mask: the
-    # band's pairs (`_Band.cost_estimate`)
+    # a call with a window or under the block-diffusion mask
+    # (`flash_block_diffusion.py`): the band's pairs (`_Band.cost_estimate`)
     for kernel in ("fwd", "dkv", "dq"):
         register_kernel_cost("flash_window_" + kernel, DECLARED_AT_CALL)
         register_kernel_cost("flash_block_diffusion_" + kernel,
@@ -365,6 +366,38 @@ def _stat_spec(block_q, tsel):
 
 # -- forward ----------------------------------------------------------------
 
+def _softmax_step(s, v_rows, m_scr, l_scr, acc_scr, at=slice(None)):
+    """One score block `s` (queries, keys) into the running max, sum and
+    accumulator of query rows `at`; `v_rows()` loads the value rows."""
+    m_prev = m_scr[at]                # (block_q, 1)
+    m_cur = jnp.max(s, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(s - m_new)            # (block_q, block_k)
+    alpha = jnp.exp(m_prev - m_new)   # (block_q, 1)
+    l_new = alpha * l_scr[at] + jnp.sum(p, axis=1, keepdims=True)
+    vv = v_rows()
+    acc_scr[at] = acc_scr[at] * alpha + jax.lax.dot_general(
+        p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[at] = m_new
+    l_scr[at] = l_new
+
+
+def _init_softmax(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _write_o_lse(o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    l = jnp.maximum(l_scr[:], 1e-30)
+    o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+    # lse replicated over 8 sublanes to satisfy TPU tiling of the
+    # (nh, 8, t_q) output layout
+    lse = (m_scr[:] + jnp.log(l))[:, 0]
+    lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
                 t_k, band=None):
@@ -372,12 +405,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
 
     kb = pl.program_id(2)
     nk = pl.num_programs(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    pl.when(kb == 0)(functools.partial(_init_softmax, m_scr, l_scr, acc_scr))
 
     qb = pl.program_id(1)
     q_off, k_off = _offs(offs_ref)
@@ -390,9 +418,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
         # the grid's last axis counts the key blocks of the band
         kb = band.key_at(qb, step)
         run = band.key_runs(qb, step, kb)
-    diffusion = band is not None and band.block_length
 
-    def _compute(masked=True):
+    @pl.when(run)
+    def _compute():
         q = _tile(q_ref)                  # (block_q, d)
         k = _tile(k_ref)                  # (block_k, d)
         s = jax.lax.dot_general(
@@ -402,59 +430,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, offs_ref, o_ref, lse_ref,
         if bias_ref is not None:
             s = s + bias_ref[0, 0].astype(jnp.float32)
 
-        if diffusion:
-            # whole blocks: no padding; only a diagonal tile is masked
-            if masked:
-                s = jnp.where(band.allowed(qb, kb, 0), s, NEG_INF)
-        else:
-            # Always mask k-positions past the true sequence length:
-            # when t_k % block_k != 0 the last k-block is padded and its
-            # garbage columns would otherwise corrupt the online softmax
-            # and lse.
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = k_pos < t_k
-            if causal:
-                q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                valid = valid & (q_off + q_pos >= k_off + k_pos)
-                if band is not None and band.window:
-                    valid = valid & (q_pos - k_pos < band.window)
-            s = jnp.where(valid, s, NEG_INF)
+        # Always mask k-positions past the true sequence length: when
+        # t_k % block_k != 0 the last k-block is padded and its garbage
+        # columns would otherwise corrupt the online softmax and lse.
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        valid = k_pos < t_k
+        if causal:
+            q_pos = qb * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            valid = valid & (q_off + q_pos >= k_off + k_pos)
+            if band is not None and band.window:
+                valid = valid & (q_pos - k_pos < band.window)
+        s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_scr[:]                 # (block_q, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)            # (block_q, block_k)
-        alpha = jnp.exp(m_prev - m_new)   # (block_q, 1)
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        # Zero padded v-rows: block padding is undefined memory and
-        # 0 * NaN would poison the accumulator even though p==0 there.
-        if diffusion:
-            vv = _tile(v_ref)
-        else:
-            v_rows = kb * block_k + jax.lax.broadcasted_iota(
+        def v_rows():
+            # Zero padded v-rows: block padding is undefined memory and
+            # 0 * NaN would poison the accumulator even though p==0 there.
+            rows = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, 1), 0)
-            vv = jnp.where(v_rows < t_k, _tile(v_ref), 0)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(vv.dtype), vv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+            return jnp.where(rows < t_k, _tile(v_ref), 0)
 
-    if band is None:
-        pl.when(run)(_compute)
-    else:
-        band.when_runs(run, qb, kb, _compute)
+        _softmax_step(s, v_rows, m_scr, l_scr, acc_scr)
 
-    @pl.when(step == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        # lse replicated over 8 sublanes to satisfy TPU tiling of the
-        # (nh, 8, t_q) output layout
-        lse = (m_scr[:] + jnp.log(l))[:, 0]
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+    pl.when(step == nk - 1)(functools.partial(
+        _write_o_lse, o_ref, lse_ref, m_scr, l_scr, acc_scr))
 
 
 def _fwd_dims(q, k, layout, n_head):
@@ -605,7 +605,7 @@ def _runs(offs_ref, kb, qb, *, causal, block_q, block_k, **_):
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
               bias_ref, offs_ref, kb, qb, *, scale, causal, block_q,
-              block_k, t_q, t_k, window=None, diffusion=None, masked=True):
+              block_k, t_q, t_k, window=None, mask=None):
     """(q, k, do, p, ds) of block pair (kb, qb): the tiles with their
     padding zeroed, and the float32 (block_k, block_q) p and ds every
     gradient is a dot of.  All three backward kernels take their terms
@@ -623,21 +623,22 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
     sT = _dot(k, q, ((1,), (1,))) * scale
     if bias_ref is not None:    # a (block_k, 1) column over the lanes
         sT = sT + bias_ref[0].astype(jnp.float32)
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 0)
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 1)
-    q_off, k_off = _offs(offs_ref)
-    # a sequence of whole blocks has no padding to mask (static)
-    valid = [k_pos < t_k] * bool(t_k % block_k) \
-        + [q_pos < t_q] * bool(t_q % block_q) \
-        + [q_off + q_pos >= k_off + k_pos] * bool(causal)
-    if window:
-        valid.append(q_pos - k_pos < window)
-    if diffusion is not None:
-        # block diffusion (`_DiffusionBand`): whole blocks, and only a
-        # diagonal tile is `masked`
-        valid = [diffusion.allowed(qb, kb, 1)] * bool(masked)
+    if mask is not None:
+        # the block-diffusion kernels' own, over whole blocks: what its
+        # visit's kind leaves of the tile (nothing to mask: [])
+        valid = mask
+    else:
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 0)
+        q_pos = qb * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, block_q), 1)
+        q_off, k_off = _offs(offs_ref)
+        # a sequence of whole blocks has no padding to mask (static)
+        valid = [k_pos < t_k] * bool(t_k % block_k) \
+            + [q_pos < t_q] * bool(t_q % block_q) \
+            + [q_off + q_pos >= k_off + k_pos] * bool(causal)
+        if window:
+            valid.append(q_pos - k_pos < window)
     p = jnp.exp(sT - lse_ref[0, 0][None, :])
     ds = p * (_dot(_tile(v_ref), do, ((1,), (1,)))
               - _delta_row(do, o, dlse_ref))
@@ -1004,14 +1005,14 @@ def _flash_bwd_split(q, k, v, bias, offsets, o, lse8, do, dlse8, scale,
     return dq, dk, dv, dbias
 
 
-# -- the band: a window, grouped key/value heads, the block-diffusion mask ----
+# -- the band: a window, grouped key/value heads -----------------------------
 #
 # A causal self-attention call, head-major, over whole blocks, in which
 # query i reads keys i - window < j <= i (window None: every j <= i)
-# and `group` query heads read one key/value head; or, in place of the
-# causal prefix, the block-diffusion training mask over a clean and a
-# noised half (`_DiffusionBand`, a third geometry of the same kernels).
-# The grids below run
+# and `group` query heads read one key/value head.  (The block-diffusion
+# training mask, whose tiles lie in two runs, walks a list of visits:
+# `flash_block_diffusion.py`, over this file's tile arithmetic.)  The
+# grids below run
 # over the block pairs of the band alone: an axis counts from the
 # band's first block, and the index maps stop at its last, so a block
 # pair outside the band costs no compute and no DMA.  k, v, dk and dv
@@ -1065,8 +1066,6 @@ class _Band:
         return _lo(((kb + 1) * self.block_k + self.window - 2)
                    // self.block_q, self.nq - 1)
 
-    block_length = None     # the block-diffusion geometry's own
-
     @property
     def prefix(self):
         """What the kernels' names begin with.  A call with a window
@@ -1086,8 +1085,7 @@ class _Band:
 
     # A grid's last axis counts the blocks of the band: the key block
     # of a query block's `step` (`key_at`) and whether the pair holds a
-    # score (`key_runs`); the same from a key block's side.  Two calls,
-    # because a kernel asks the second where it branches on it.
+    # score (`key_runs`); the same from a key block's side.
     def key_at(self, qb, step):
         return self.first_k(qb) + step
 
@@ -1099,21 +1097,6 @@ class _Band:
 
     def query_runs(self, kb, step, qb):
         return qb <= self.last_q(kb)
-
-    def key_block(self, qb, step):
-        kb = self.key_at(qb, step)
-        return kb, self.key_runs(qb, step, kb)
-
-    def query_block(self, kb, step):
-        qb = self.query_at(kb, step)
-        return qb, self.query_runs(kb, step, qb)
-
-    def when_runs(self, run, qb, kb, compute):
-        """Run `compute(masked)` where the block pair holds a score;
-        every pair of this band goes through its position masks."""
-        from jax.experimental import pallas as pl
-
-        pl.when(run)(functools.partial(compute, True))
 
     def k_time(self, qb, step):
         """The key block of a query block's `step`: past the diagonal
@@ -1158,150 +1141,6 @@ class _Band:
                                * (tiles[0] + tiles[1] / group)))}
 
 
-def _pick(cond, a, b):
-    if isinstance(cond, (bool, np.bool_)):
-        return a if cond else b
-    return jnp.where(cond, a, b)
-
-
-class _DiffusionBand(_Band):
-    """The block geometry of block-diffusion training (Arriola et al.,
-    arXiv:2503.09573) over `t` = 2 L rows, the clean half x_0 FIRST and
-    the noised half x_t after it, row L + p standing at position p.
-    With blk(p) = p // block_length, a row reads
-
-        clean  -> clean :  blk(s) <= blk(r)   (block-causal, a block whole)
-        noised -> clean :  blk(s) <  blk(r)   (the clean prefix)
-        noised -> noised:  blk(s) == blk(r)   (its own block, both ways)
-        clean  -> noised:  never
-
-    so every allowed key lies at or before its query's tile and
-    `last_k` stays the diagonal's.  Tiles are square, a whole number of
-    blocks, `n` a half.  A clean query tile meets clean tiles 0 .. its
-    own; a noised one (position tile qp) meets clean tiles 0 .. qp (the
-    last holds its strictly earlier blocks: none where a tile is ONE
-    block, and it is then not visited) and then its own noised tile: TWO
-    runs of key tiles, joined in `key_at`.  A clean key tile kc meets
-    clean query tiles kc .. n - 1 and then the noised ones from n + kc
-    (n + kc + 1 where a tile is one block); a noised key tile its own
-    query tile alone.  Only tiles at the query tile's own position are
-    masked (by block id: two iotas and a division, `allowed`)."""
-
-    window = None
-    prefix = "flash_block_diffusion_"
-
-    def __init__(self, t, block, block_length):
-        self.t, self.block_length = t, block_length
-        self.block_q = self.block_k = block
-        self.n = n = t // 2 // block
-        self.nq = self.nk = 2 * n
-        # whether a noised query tile reads the clean tile at its own
-        # position: it holds strictly earlier blocks
-        self.own = int(block_length < block)
-        self.k_steps = n + self.own
-        self.q_steps = 2 * n - 1 + self.own
-        self.blocks_allowed = n * (n + 1) // 2 + n * (n - 1) // 2 \
-            + n * self.own + n
-
-    def record_blocks(self, backward=False):
-        """One traced call: the tiles its grid computes for a head (the
-        forward's over query tiles, the backward's over key tiles) and
-        those that hold an allowed pair."""
-        from ...observe.monitoring import runtime_stats
-
-        if backward:
-            visited = sum(bool(self.query_block(kb, step)[1])
-                          for kb in range(self.nk)
-                          for step in range(self.q_steps))
-        else:
-            visited = sum(bool(self.key_block(qb, step)[1])
-                          for qb in range(self.nq)
-                          for step in range(self.k_steps))
-        runtime_stats.record_flash_block_diffusion(visited,
-                                                   self.blocks_allowed)
-
-    def _clean_keys(self, qb):
-        """Clean key tiles a query tile meets: 0 .. this many - 1."""
-        return _pick(qb >= self.n, qb - self.n + self.own, qb + 1)
-
-    def key_at(self, qb, step):
-        # past its last step a query tile stays on its last key tile
-        return _pick(step < self._clean_keys(qb), step, qb)
-
-    def key_runs(self, qb, step, kb):
-        return step < self._clean_keys(qb) + _pick(qb >= self.n, 1, 0)
-
-    k_time = key_at
-
-    def first_k(self, qb):
-        if self.own:
-            return 0
-        return _pick(qb == self.n, self.n, 0)
-
-    def last_k(self, qb):
-        return qb
-
-    def _queries(self, kb):
-        """Query tiles a key tile meets: a noised one its own alone."""
-        return _pick(kb >= self.n, 1, 2 * (self.n - kb) - 1 + self.own)
-
-    def query_at(self, kb, step):
-        n = self.n
-        clean = n - kb          # clean query tiles of a clean key tile
-        at = _lo(step, self._queries(kb) - 1)
-        qb = _pick(at < clean, kb + at, 2 * kb + 1 - self.own + at)
-        return _pick(kb >= n, kb, qb)
-
-    def query_runs(self, kb, step, qb):
-        return step < self._queries(kb)
-
-    q_time = query_at
-
-    def dq_time(self, kb, step):
-        """Query tile kb is complete at the FIRST step of key tile kb's
-        pass (its diagonal, a clean tile's and a noised tile's alike)
-        and none other is completed in that pass: the index stays there,
-        and the tile leaves when the next pass begins."""
-        return kb
-
-    def interior(self, qb, kb):
-        """Whether every pair of tile (qb, kb) is allowed: a key tile
-        before the query tile's own position."""
-        n = self.n
-        return _pick(kb >= n, kb - n, kb) < _pick(qb >= n, qb - n, qb)
-
-    def allowed(self, qb, kb, q_axis):
-        """The mask of a tile at the query tile's own position, from
-        local block ids; queries along `q_axis` of the square tile."""
-        block, n = self.block_q, self.n
-        shape = (block, block)
-
-        def blk(axis):
-            i = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
-            b = self.block_length
-            if b & (b - 1) == 0:
-                return jax.lax.shift_right_logical(i, b.bit_length() - 1)
-            return jax.lax.div(i, b)
-
-        ahead = blk(q_axis) - blk(1 - q_axis)   # blk(r) - blk(s)
-        # clean -> clean: >= 0; noised -> clean: >= 1; noised -> noised: 0
-        least = jnp.where((qb >= n) & (kb < n), 1, 0)
-        return (ahead >= least) & ((kb < n) | (ahead == 0))
-
-    def when_runs(self, run, qb, kb, compute):
-        from jax.experimental import pallas as pl
-
-        inner = self.interior(qb, kb)
-        pl.when(run & inner)(functools.partial(compute, False))
-        pl.when(run & jnp.logical_not(inner))(functools.partial(compute, True))
-
-    def pairs(self):
-        half, b = self.t // 2, self.block_length
-        blocks = half // b
-        return half * b + b * b * (blocks * (blocks - 1) // 2
-                                   + blocks * (blocks + 1) // 2)
-
-
 def band_backward_fits(t, d):
     """Whether the backward pass of a band call is the single kernel:
     from the shape alone.  It holds dq of one query head and dk, dv of
@@ -1338,14 +1177,13 @@ def _bwd_band_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     def _init_dq():
         dq_acc[qb] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
-    def _compute(masked):
+    @pl.when(run)
+    def _compute():
         q, k, do, p, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
-            kb, qb, masked=masked, **dims)
+            kb, qb, **dims)
         _add_dk_dv(p, ds, q, do, dk_acc, dv_acc, dims["scale"], at=kb)
         _add_dq(ds, k, dq_acc, dims["scale"], at=qb)
-
-    band.when_runs(run, qb, kb, _compute)
 
     @pl.when((gi == group - 1) & (step == band.q_steps - 1))
     def _finalize():
@@ -1372,13 +1210,12 @@ def _bwd_band_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute(masked):
+    @pl.when(band.query_runs(kb, step, qb))
+    def _compute():
         q, _, do, p, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
-            kb, qb, masked=masked, **dims)
+            kb, qb, **dims)
         _add_dk_dv(p, ds, q, do, dk_scr, dv_scr, dims["scale"])
-
-    band.when_runs(band.query_runs(kb, step, qb), qb, kb, _compute)
 
     @pl.when((gi == group - 1) & (step == band.q_steps - 1))
     def _finalize():
@@ -1398,13 +1235,12 @@ def _bwd_band_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute(masked):
+    @pl.when(band.key_runs(qb, step, kb))
+    def _compute():
         _, k, _, _, ds = _bwd_p_ds(
             q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, None, None, None,
-            kb, qb, masked=masked, **dims)
+            kb, qb, **dims)
         _add_dq(ds, k, dq_scr, dims["scale"])
-
-    band.when_runs(band.key_runs(qb, step, kb), qb, kb, _compute)
 
     @pl.when(step == band.k_steps - 1)
     def _finalize():
@@ -1415,8 +1251,7 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
     """(dq, dk, dv) of a band call: one kernel where `band_backward_fits`,
     the two that hold blocks only beyond; both take every term from
     `_bwd_p_ds` and add it in the same order.  The kernels of a call
-    with a window run under names of their own (`flash_window_*`), as
-    do those of the block-diffusion mask (`flash_block_diffusion_*`)."""
+    with a window run under names of their own (`flash_window_*`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1429,9 +1264,6 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
     runtime_stats.record_flash_backward("flash_attention", fused)
     dims = dict(scale=scale, causal=True, block_q=bq, block_k=bk, t_q=t,
                 t_k=t, window=band.window)
-    if band.block_length:
-        dims.update(causal=False, diffusion=band)
-        band.record_blocks(backward=True)
     item = q.dtype.itemsize
 
     def name(kernel):
@@ -1527,32 +1359,25 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
     return dq, dk, dv
 
 
-def _band_of(t, blocks, window, block_diffusion):
-    if block_diffusion:
-        return _DiffusionBand(t, blocks[0], block_diffusion)
-    return _Band(t, *blocks, window)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window,
-                block_diffusion=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window):
     return _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
-                           window, block_diffusion)[0]
+                           window)[0]
 
 
 def _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
-                    window, block_diffusion):
-    band = _band_of(q.shape[1], blocks, window, block_diffusion)
+                    window):
+    band = _Band(q.shape[1], *blocks, window)
     o, lse8 = keep_residuals(*_flash_fwd(
-        q, k, v, None, None, scale, not block_diffusion, *blocks, "nthd",
-        n_head, band, group))
+        q, k, v, None, None, scale, True, *blocks, "nthd", n_head, band,
+        group))
     return o, (q, k, v, o, lse8)
 
 
-def _flash_band_bwd(scale, blocks, bwd_blocks, n_head, group, window,
-                    block_diffusion, res, do):
+def _flash_band_bwd(scale, blocks, bwd_blocks, n_head, group, window, res,
+                    do):
     q, k, v, o, lse8 = res
-    band = _band_of(q.shape[1], bwd_blocks, window, block_diffusion)
+    band = _Band(q.shape[1], *bwd_blocks, window)
     dq, dk, dv = _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head,
                                  group)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -1627,62 +1452,6 @@ def _band_blocks(t, block_q, block_k, window):
         for pair in own)
 
 
-def _diffusion_blocks(t, length, block=None):
-    """(forward tile, backward tile) of a call under the block-diffusion
-    mask over `t` rows in blocks of `length`: a size given holds for
-    both passes; else each pass's own, or its half or its quarter where
-    that is the largest that cuts a half into whole tiles of whole
-    blocks (a half under a tile is one tile)."""
-    half = t // 2
-
-    def tile(own):
-        sizes = [min(own >> halvings, half) for halvings in range(3)]
-        return next((b for b in sizes
-                     if b and half % b == 0 and b % length == 0), sizes[0])
-
-    return tuple(min(int(block), half) if block else tile(own) for own in
-                 (DEFAULT_DIFFUSION_BLOCK, DEFAULT_DIFFUSION_BWD_BLOCK))
-
-
-def block_diffusion_takes(t, length):
-    """Whether the kernels run `t` rows under the block-diffusion mask
-    at one of their own tiles: each half a whole number of tiles, a
-    tile a whole number of blocks of `length`.  From the shape alone;
-    another shape runs the XLA lowering under the explicit mask."""
-    return t % 2 == 0 and length >= 1 and all(
-        b and (t // 2) % b == 0 and b % length == 0
-        for b in _diffusion_blocks(t, length))
-
-
-def _flash_block_diffusion(q, k, v, scale, h, hkv, length, block_q, block_k,
-                           bare):
-    """The head-major call under the block-diffusion mask, or the
-    reason it is not one.  `bare`: nothing beside q, k, v came with it
-    (a bias, offsets, a returned logsumexp, a causal mask or a window)."""
-    n, t, hd = q.shape
-    d = hd // h
-    if length < 1:
-        raise ValueError(f"block_diffusion {length} is no block length")
-    if block_q != block_k:
-        raise NotImplementedError(
-            f"the block-diffusion mask's tiles are square; got blocks "
-            f"{block_q} x {block_k}")
-    blocks = _diffusion_blocks(t, length, block_q)
-    if (not bare or k.shape[1] != t or t % 2
-            or any(b < 1 or (t // 2) % b or b % length for b in blocks)):
-        raise NotImplementedError(
-            f"flash attention under the block-diffusion mask is "
-            f"self-attention over a clean and a noised half of whole "
-            f"blocks each, a tile a whole number of blocks of "
-            f"{length}, with no bias, offsets, returned logsumexp, "
-            f"window or causal mask beside its own; got T_q {t}, T_k "
-            f"{k.shape[1]}, tiles {blocks}")
-    return _flash_band(
-        q, k, v, float(d ** -0.5 if scale is None else scale),
-        (blocks[0],) * 2, (blocks[1],) * 2, int(h), int(h // hkv), None,
-        length)
-
-
 def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
                            block_q=None, block_k=None,
                            q_offset=None, k_offset=None,
@@ -1704,12 +1473,13 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
     `block_diffusion` B (head-major, not causal, no window): the rows
     are a clean half and a noised half of T / 2 positions each, cut
     into blocks of B, under the block-diffusion training mask
-    (`_DiffusionBand`), with or without grouped key/value heads, at
-    d_head 128 or more on the chip.  The band kernels thus take THREE
-    geometries: the causal prefix (grouped heads), the causal prefix
-    under a window, and the block-diffusion mask; each refuses a bias,
-    offsets, a returned logsumexp, cross lengths and a ragged block.
-    Their tiles (`_band_blocks`; a `block_q` / `block_k` given holds
+    (`flash_block_diffusion.py`: kernels of its own on a grid of
+    visits), with or without grouped key/value heads, at d_head 128 or
+    more on the chip.  The three geometries (the causal prefix over
+    grouped heads, the causal prefix under a window, the block-diffusion
+    mask) each refuse a bias, offsets, a returned logsumexp, cross
+    lengths and a ragged block.  The band kernels'
+    tiles (`_band_blocks`; a `block_q` / `block_k` given holds
     for both passes): forward 1024 x 1024 and backward 1024 x 1024 over
     the whole prefix; under a window the backward 512 x 512 and the
     forward tile FOLLOWS THE WINDOW, from the shape alone
@@ -1753,7 +1523,9 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
         if window is not None and plain and window >= t_q:
             window = None
         if block_diffusion is not None:
-            return _flash_block_diffusion(
+            from .flash_block_diffusion import flash_block_diffusion
+
+            return flash_block_diffusion(
                 q, k, v, scale, h, n_kv_head, int(block_diffusion), block_q,
                 block_k, bare=bias is None and not (
                     causal or return_lse or window is not None

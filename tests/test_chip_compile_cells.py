@@ -510,13 +510,24 @@ def test_the_block_diffusion_cells_step_and_the_plan_its_depth_rule_read(
     plan: arguments (aliased to the outputs) 7.75 GB + temporaries 6.06
     GB = 13.81 GB; at 4 layers 5.48 + 5.41 = 10.89 GB (PERF.md, PR 47).
     A layer is ONE forward and one backward flash kernel under the
-    block-diffusion mask: the segment keeps the forward's residuals."""
-    parameters, compiled, plan, kernels, _ = _cell_step("sdar-8k", one_chip)
+    block-diffusion mask: the segment keeps the forward's residuals.
+    Since PR 59 both walk a list of visits
+    (`ops/pallas/flash_block_diffusion.py`): 80 grid steps a head's
+    pass where the rectangles took 144 and 256, and no `_dq` kernel."""
+    parameters, compiled, plan, kernels, took = _cell_step("sdar-8k",
+                                                           one_chip)
     assert parameters == 645623296
     assert plan["arguments"] == pytest.approx(7.75, abs=0.01)
     assert 12.5 < plan["total"] <= 15.0, plan       # the rule's side: 6
     assert kernels["flash_block_diffusion_fwd"] == 6
     assert kernels["flash_block_diffusion_dkv"] == 6
+    assert took["flash_attention_backward_split"] == 0
+    assert took["flash_block_diffusion_grid_steps"] \
+        == took["flash_block_diffusion_blocks_allowed"] \
+        == 80 * took["flash_block_diffusion_calls"]
+    assert took["flash_block_diffusion_entries_computed"] == (
+        72 * 1024 * 1024 + 8 * 8 * 128 * 128) * took[
+            "flash_block_diffusion_calls"]
     # q and k of six layers: normed and turned forward and recomputed,
     # one backward kernel each (PR 48)
     assert (kernels["rope_fwd"], kernels["rope_bwd"]) == (24, 12)

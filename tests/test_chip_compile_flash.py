@@ -338,8 +338,14 @@ STEP_TEXT = {
     # were inside the old budget and keep their text
     "qwen3next-16k":
     "3587790bb726103f00f3b4dbc0bb79a162f162c72474ab4d3e0ae024b583bba6",
+    # re-pinned, PR 59: its block-diffusion flash kernels walk a
+    # scalar-prefetched list of visits (`ops/pallas/
+    # flash_block_diffusion.py`, here through the interpreter, a pass
+    # jitted so that the six layers share one trace; parent:
+    # b012ad2d..); the eleven other cells keep their text: the band and
+    # plain kernels' bodies trace the same equations in the same order
     "sdar-8k":
-    "b012ad2dd91ad7ea22ec80bad7c11ced9f65f1b7f877c4a4d1ef9bdb7923f886",
+    "691f66043cc39cad12659fa8a365e6f998a923580e7a4fea1d0302a3dac2f57e",
     # new in PR 51 (a head count a layer type, the head gate, YaRN over
     # half a head, 512 x 512 forward tiles under 512 keys); every other
     # cell keeps its parent's text: `mellum2-16k`'s window of 1024 keeps

@@ -577,25 +577,30 @@ def test_band_kernels_at_a_head_count_a_layer_type(one_chip, dtype, heads,
                               "past_budget"])
 def test_flash_under_the_block_diffusion_mask_at_the_cells_shape(
         one_chip, rows, dtype):
-    """The band kernels' third geometry as `sdar-8k` asks it, 6 calls a
-    step: one document of 8192 positions as 16384 rows (clean, then
-    noised), blocks of 4, 32 query heads of 128 over 4 key/value heads,
-    1024 x 1024 tiles of which only the 24 at a query tile's own
-    position carry the mask (two branches of a kernel).  Two custom calls: the
+    """`ops/pallas/flash_block_diffusion.py` as `sdar-8k` asks it, 6
+    calls a step: one document of 8192 positions as 16384 rows (clean,
+    then noised), blocks of 4, 32 query heads of 128 over 4 key/value
+    heads, 1024 x 1024 tiles on a grid of 80 VISITS a head (a
+    scalar-prefetched table; three branches of a kernel: a whole tile,
+    a tile under the mask by block id, and eight unrolled 128 x 128
+    squares of a noised tile against itself, on dynamic slices of the
+    refs, the logsumexp row's lanes among them).  Two custom calls: the
     forward kernel and ONE backward kernel that holds dq of a query
     head and dk, dv of its key/value head full-length (24 MiB); in the
     parity script's float32 at "highest" too.  A document of 16384 (48
     MiB) is the budget's edge and still one kernel (PR 54); one of
-    17408 takes the two kernels that hold tiles only.  Each declares the
-    cost of the pairs the MASK allows, 67,141,632 a head at 8192."""
+    17408 takes the two kernels that hold tiles only, `_dkv` over a
+    table that holds the group's heads.  Each declares the cost of the
+    pairs the MASK allows, 67,141,632 a head at 8192."""
     from paddle_tpu.observe import cost
     from paddle_tpu.observe.monitoring import runtime_stats
     from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.pallas import flash_block_diffusion as fbd
 
     h, hkv, d = 32, 4, 128
     fused = fa.band_backward_fits(rows, d)
     assert fused == (rows <= 32768)
-    assert fa.block_diffusion_takes(rows, 4)
+    assert fbd.block_diffusion_takes(rows, 4)
 
     def loss(q, k, v):
         with jax.named_scope("flash_attention:9"):
@@ -619,7 +624,8 @@ def test_flash_under_the_block_diffusion_mask_at_the_cells_shape(
     assert took["flash_block_diffusion_calls"] == 2
     assert took["flash_block_diffusion_blocks_visited"] \
         == took["flash_block_diffusion_blocks_allowed"] \
-        == 2 * fa._DiffusionBand(rows, 1024, 4).blocks_allowed
+        == took["flash_block_diffusion_grid_steps"] \
+        == 2 * fbd._DiffusionBand(rows, 1024, 4).blocks_allowed
     assert _kernels(compiled.as_text()) == (2 if fused else 3)
     rows_ = {r["kernel"]: r for r in cost.instruction_costs(
         cost.compiled_hlo_proto(compiled)) if r["kernel"]}

@@ -1,11 +1,13 @@
-"""`ops/pallas/flash_attention.py`'s band kernels under their THIRD
-geometry, the block-diffusion training mask (`_DiffusionBand`;
-interpret mode on the CPU, the same kernels Mosaic compiles in
+"""`ops/pallas/flash_block_diffusion.py`: flash attention under the
+block-diffusion training mask on a grid of VISITS (interpret mode on the
+CPU, the same kernels Mosaic compiles in
 tests/test_chip_compile_kernels.py), and the XLA lowering of
 `ops/attention.py`, against a soft-max under the mask WRITTEN OUT from
-(half, position): forward and the gradients of q, k, v; the one-kernel
-and the two-kernel backward to the bit; the tiles the grids visit; what
-the geometry does not take raises.
+(half, position): the visit table against that mask (every tile that
+holds an allowed pair once in each order, the runs' brackets, the kinds,
+the dq tile an output holds); forward and the gradients of q, k, v; the
+one-kernel and the two-kernel backward to the bit; what the geometry
+does not take raises.
 """
 
 import numpy as np
@@ -16,6 +18,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.observe.monitoring import runtime_stats
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import flash_block_diffusion as fbd
 
 D, H = 8, 4
 
@@ -71,11 +74,19 @@ def _grads(fn, q, k, v, w):
         lambda q, k, v: jnp.sum(w * fn(q, k, v)), (0, 1, 2))(q, k, v)
 
 
-# (L, B, tile): one tile a half and four; B = 4 and B = the tile
-GEOMETRIES = {"one_tile-B4": (16, 4, 16), "four_tiles-B4": (64, 4, 16),
-              "four_tiles-B_is_the_tile": (64, 16, 16),
-              "one_tile-B_is_the_tile": (16, 16, 16),
-              "two_tiles-B8": (64, 8, 32)}
+# (L, B, tile, lanes): one tile a half and four; B = 4 and B = the tile.
+# `lanes` is the quantum of an OWN_BLOCKS visit's squares (128 on the
+# chip: no tile of 16 holds one, so the first five run no such visit);
+# at 8 the small tiles do, and the last runs the real quantum
+GEOMETRIES = {"one_tile-B4": (16, 4, 16, 128),
+              "four_tiles-B4": (64, 4, 16, 128),
+              "four_tiles-B_is_the_tile": (64, 16, 16, 128),
+              "one_tile-B_is_the_tile": (16, 16, 16, 128),
+              "two_tiles-B8": (64, 8, 32, 128),
+              "own_blocks-one_tile-B4": (16, 4, 16, 8),
+              "own_blocks-four_tiles-B4": (64, 4, 16, 8),
+              "own_blocks-two_tiles-B8": (64, 8, 32, 8),
+              "own_blocks-128_lanes": (512, 4, 256, 128)}
 
 
 @pytest.mark.parametrize("hkv", [H, H // 4], ids=["mha", "gqa4"])
@@ -83,7 +94,11 @@ GEOMETRIES = {"one_tile-B4": (16, 4, 16), "four_tiles-B4": (64, 4, 16),
 def test_the_kernels_match_the_mask_written_out(geometry, hkv, monkeypatch):
     """Forward and dq, dk, dv; then the same call with the single
     kernel's budget at zero: the two kernels give the same bits."""
-    length, block_length, tile = GEOMETRIES[geometry]
+    length, block_length, tile, lanes = GEOMETRIES[geometry]
+    monkeypatch.setattr(fbd, "LANES", lanes)
+    band = fbd._DiffusionBand(2 * length, tile, block_length)
+    kinds = {kind for *_, kind in band.tiles()}
+    assert (fbd.OWN_BLOCKS in kinds) == geometry.startswith("own_blocks")
     q, k, v, w = _qkvw(2 * length, hkv, seed=3)
     kw = dict(block_q=tile, block_k=tile)
     before = runtime_stats.snapshot()
@@ -101,7 +116,8 @@ def test_the_kernels_match_the_mask_written_out(geometry, hkv, monkeypatch):
     # forward and backward, a call each; what is visited is allowed
     assert took["flash_block_diffusion_calls"] == 2
     assert took["flash_block_diffusion_blocks_visited"] \
-        == took["flash_block_diffusion_blocks_allowed"] > 0
+        == took["flash_block_diffusion_blocks_allowed"] \
+        == took["flash_block_diffusion_grid_steps"] > 0
     assert took["flash_window_blocks_visited"] == 0
     monkeypatch.setattr(fa, "FUSED_ACCUMULATOR_BUDGET", 0)
     before = runtime_stats.snapshot()
@@ -138,10 +154,10 @@ def test_the_flash_attention_op_takes_the_mask_on_both_paths(
     assert (calls > 0) == (use_pallas and tile is not None)
     np.testing.assert_allclose(
         got, _dense(q, k, v, H // 4, block_length), rtol=1e-5, atol=1e-5)
-    assert fa.block_diffusion_takes(2 * length, block_length) \
+    assert fbd.block_diffusion_takes(2 * length, block_length) \
         == (tile is not None)
     if tile:
-        assert fa._diffusion_blocks(2 * length, block_length) == (tile, tile)
+        assert fbd._diffusion_blocks(2 * length, block_length) == (tile, tile)
 
 
 def test_the_tile_is_the_largest_of_three_that_cuts_a_half():
@@ -150,15 +166,15 @@ def test_the_tile_is_the_largest_of_three_that_cuts_a_half():
     timed on the chip); a half under one tile is one tile; a block that
     cuts no tile, an odd row count and a half no tile cuts are not
     taken."""
-    assert fa._diffusion_blocks(2 * 8192, 4) == (1024, 1024)
-    assert fa._diffusion_blocks(2 * 8704, 4) == (512, 512)
-    assert fa._diffusion_blocks(2 * 8448, 4) == (256, 256)
-    assert fa._diffusion_blocks(2 * 8192, 4, 512) == (512, 512)
+    assert fbd._diffusion_blocks(2 * 8192, 4) == (1024, 1024)
+    assert fbd._diffusion_blocks(2 * 8704, 4) == (512, 512)
+    assert fbd._diffusion_blocks(2 * 8448, 4) == (256, 256)
+    assert fbd._diffusion_blocks(2 * 8192, 4, 512) == (512, 512)
     for rows, block_length, takes in [
             (2 * 8704, 4, True), (2 * 8448, 4, True), (2 * 8320, 4, False),
             (24, 4, True), (24, 5, False), (25, 5, False),
             (2 * 8192, 3, False)]:
-        assert fa.block_diffusion_takes(rows, block_length) == takes
+        assert fbd.block_diffusion_takes(rows, block_length) == takes
 
 
 def test_the_xla_lowering_has_the_gradients_of_the_mask_written_out():
@@ -173,55 +189,149 @@ def test_the_xla_lowering_has_the_gradients_of_the_mask_written_out():
         np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5)
 
 
-def test_the_grids_visit_the_tiles_that_hold_an_allowed_pair_and_no_other():
-    """At the cell's shape, 2 x 8192 rows: in 512 x 512 tiles 288 of a
-    head's 1024 tiles (causal over 2 L would visit 528), in the kernels'
-    own 1024 x 1024 80 of 256; a query tile's two runs of key tiles and
-    a key tile's two runs of query tiles."""
-    own = fa._DiffusionBand(16384, fa.DEFAULT_DIFFUSION_BLOCK, 4)
-    assert (own.block_q, own.n, own.blocks_allowed) == (1024, 8, 80)
-    assert (own.k_steps, own.q_steps) == (9, 16)
-    band = fa._DiffusionBand(16384, 512, 4)
-    assert (band.n, band.nq, band.nk) == (16, 32, 32)
-    assert (band.k_steps, band.q_steps) == (17, 32)
-    assert band.blocks_allowed == 288
-    assert band.pairs() == 67141632
+ORDERS = {"query_major": dict(), "key_major": dict(key_major=True),
+          "key_major-group4": dict(key_major=True, group=4)}
 
-    def keys(qb):
-        return [kb for kb, run in (band.key_block(qb, s)
-                                   for s in range(band.k_steps)) if run]
 
-    def queries(kb):
-        return [qb for qb, run in (band.query_block(kb, s)
-                                   for s in range(band.q_steps)) if run]
+@pytest.mark.parametrize("order", sorted(ORDERS))
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES)[:-1])
+def test_the_table_is_the_mask_written_out(geometry, order, monkeypatch):
+    """Every visit's tile holds an allowed pair and every such tile is
+    visited exactly once (a head), in the order's runs; `FULL` is a tile
+    the mask leaves whole; FIRST / LAST bracket each run; the dq tile an
+    output holds is complete, or being completed."""
+    length, block_length, tile, lanes = GEOMETRIES[geometry]
+    monkeypatch.setattr(fbd, "LANES", lanes)
+    band = fbd._DiffusionBand(2 * length, tile, block_length)
+    kw = ORDERS[order]
+    table = band.visits(**kw)
+    group, key_major = kw.get("group", 1), kw.get("key_major", False)
+    assert table.dtype == np.int32 and table.shape[0] == 9
+    q, k, head, kind, first, last, dq, dq_first, dq_last = table
+    tiles = _mask(length, block_length).reshape(
+        2 * length // tile, tile, 2 * length // tile, tile)
+    holds = set(zip(*np.nonzero(tiles.any(axis=(1, 3)))))
+    whole = set(zip(*np.nonzero(tiles.all(axis=(1, 3)))))
+    for gi in range(group):
+        mine = list(zip(q[head == gi], k[head == gi]))
+        assert len(mine) == len(set(mine)) == band.blocks_allowed
+        assert set(mine) == holds
+    full = {(a, b) for a, b, c in zip(q, k, kind) if c == fbd.FULL}
+    # (a tile of ONE block at the query tile's own position is whole
+    # too, and masked all the same: no kind for a geometry no cell has)
+    assert full == whole if block_length < tile else full < whole
+    assert all(a % band.n == b % band.n for a, b in holds - full)
+    assert set(kind) <= {fbd.FULL, fbd.DIAGONAL, fbd.OWN_BLOCKS}
+    assert all(a == b >= band.n for a, b, c in zip(q, k, kind)
+               if c == fbd.OWN_BLOCKS)
+    # the runs: a major tile's visits in a row, a head after a head
+    # inside a key tile's, the other side ascending
+    major, minor = (k, q) if key_major else (q, k)
+    keys = list(zip(major, head, minor))
+    assert keys == sorted(keys)
+    run = list(zip(major, head))
+    for v in range(len(run)):
+        assert first[v] == (v == 0 or run[v] != run[v - 1])
+        assert last[v] == (v == len(run) - 1 or run[v] != run[v + 1])
+    # a query tile's visits, and what the dq output's index map holds
+    if group == 1:
+        for qb in range(band.nq):
+            met = np.flatnonzero(q == qb)
+            assert list(np.flatnonzero(dq_first & (q == qb))) == [met[0]]
+            assert list(np.flatnonzero(dq_last & (q == qb))) == [met[-1]]
+        for v in range(len(q)):
+            # written by now: it may leave whenever the index moves on
+            assert np.flatnonzero(dq_last & (q == dq[v]))[0] <= max(
+                v, np.flatnonzero(dq_last)[0])
+            if v and dq[v] != dq[v - 1]:
+                assert dq_last[v] and q[v] == dq[v]
+        if key_major:
+            assert list(dq) == list(k)      # `dq_time`'s answer
 
-    assert keys(0) == [0] and keys(3) == [0, 1, 2, 3]
-    assert keys(16) == [0, 16]              # noised tile 0: clean 0, itself
-    assert keys(19) == [0, 1, 2, 3, 19]
-    assert queries(0) == list(range(32))
-    assert queries(3) == list(range(3, 16)) + list(range(19, 32))
-    assert queries(15) == [15, 31] and queries(16) == [16]
-    pairs = {(qb, kb) for qb in range(32) for kb in keys(qb)}
-    assert pairs == {(qb, kb) for kb in range(32) for qb in queries(kb)}
-    assert len(pairs) == 288
-    # against the mask itself, at a size where it can be written out
-    small = fa._DiffusionBand(128, 16, 4)
-    seen = _mask(64, 4).reshape(8, 16, 8, 16).any(axis=(1, 3))
-    assert {(qb, kb) for qb in range(8)
-            for kb, run in (small.key_block(qb, s)
-                            for s in range(small.k_steps)) if run} \
-        == set(zip(*np.nonzero(seen)))
-    # a skipped step fetches nothing new: the index maps stay in place
-    assert [band.k_time(19, s) for s in range(band.k_steps)][4:] == [19] * 13
-    assert [band.q_time(15, s) for s in (0, 1, 2, 31)] == [15, 31, 31, 31]
-    # a tile that is ONE block: the clean tile at the query's own
-    # position holds no strictly earlier block, and is not visited
-    whole = fa._DiffusionBand(128, 16, 16)
+
+@pytest.mark.parametrize("tile, visits, kinds", [
+    (1024, 80, (56, 16, 8)), (512, 288, (240, 32, 16))])
+def test_the_table_at_the_cells_shape(tile, visits, kinds):
+    """2 x 8192 rows in blocks of 4: 80 of a head's 256 tiles at the
+    kernels' own 1024 x 1024 (causal over 2 L would visit 136), 288 of
+    1024 at 512 x 512; the rectangles they replace took 144 + 256 steps
+    for the 80 + 80."""
+    assert fbd._diffusion_blocks(16384, 4) == (1024, 1024)
+    band = fbd._DiffusionBand(16384, tile, 4)
+    assert (band.n, band.nq, band.nk) == (8192 // tile, 16384 // tile,
+                                          16384 // tile)
+    assert band.blocks_allowed == visits and band.pairs() == 67141632
+    assert band.sub == 128
+    for kw in ORDERS.values():
+        table = band.visits(**kw)
+        assert table.shape == (9, visits * kw.get("group", 1))
+        assert tuple(np.bincount(table[fbd.V_KIND])) \
+            == tuple(n * kw.get("group", 1) for n in kinds)
+    before = runtime_stats.snapshot()
+    band.record_blocks()
+    took = runtime_stats.delta(before)
+    assert took["flash_block_diffusion_calls"] == 1
+    assert took["flash_block_diffusion_grid_steps"] \
+        == took["flash_block_diffusion_blocks_visited"] \
+        == took["flash_block_diffusion_blocks_allowed"] == visits
+    assert took["flash_block_diffusion_pairs_allowed"] == band.pairs()
+    entries = (kinds[0] + kinds[1]) * tile * tile + kinds[2] * tile * 128
+    assert took["flash_block_diffusion_entries_computed"] == entries
+    if tile == 1024:        # whole tiles read 80.04
+        assert 100 * band.pairs() / entries == pytest.approx(87.71, abs=0.01)
+
+
+@pytest.mark.parametrize("block_length, sub", [
+    (4, 128), (16, 128), (128, 128), (256, 256), (1024, None)])
+def test_an_own_blocks_visit_covers_the_tiles_allowed_pairs(block_length,
+                                                            sub):
+    """A noised tile of 1024 against itself allows blk(s) == blk(r)
+    alone: the `sub` x `sub` squares on its diagonal hold every such
+    pair, and each square's mask is the tile's mask there.  A tile of
+    ONE block has no smaller square and stays `DIAGONAL`."""
+    band = fbd._DiffusionBand(16384, 1024, block_length)
+    own = [kind for qb, kb, kind in band.tiles() if qb == kb >= band.n]
+    assert len(own) == band.n
+    if sub is None:
+        assert band.sub == 1024 and band.own == 0
+        assert set(own) == {fbd.DIAGONAL}
+        assert fbd.OWN_BLOCKS not in {kind for *_, kind in band.tiles()}
+        return
+    assert band.sub == sub and set(own) == {fbd.OWN_BLOCKS}
+    blk = np.arange(1024) // block_length
+    allowed = blk[:, None] == blk[None, :]
+    covered = np.zeros_like(allowed)
+    square = np.asarray(band._ahead(sub, 0) == 0)
+    np.testing.assert_array_equal(square, np.asarray(band._ahead(sub, 1) == 0))
+    for i in range(1024 // sub):
+        at = slice(i * sub, (i + 1) * sub)
+        covered[at, at] = True
+        np.testing.assert_array_equal(allowed[at, at], square)
+    assert not (allowed & ~covered).any()
+    # the tile's own mask (a `DIAGONAL` visit's) is the same pairs
+    np.testing.assert_array_equal(
+        np.asarray(band.allowed(band.n, band.n, 0)), allowed)
+
+
+def test_the_diagonal_masks_by_half():
+    """A tile at the query tile's own position: clean -> clean sees its
+    own block and earlier ones, noised -> clean strictly earlier ones;
+    a tile that is one block is not met by the noised half at all."""
+    band = fbd._DiffusionBand(128, 16, 4)
+    blk = np.arange(16) // 4
+    ahead = blk[:, None] - blk[None, :]             # blk(r) - blk(s)
+    np.testing.assert_array_equal(band.allowed(1, 1, 0), ahead >= 0)
+    np.testing.assert_array_equal(band.allowed(5, 1, 0), ahead >= 1)
+    np.testing.assert_array_equal(band.allowed(5, 1, 1), (ahead >= 1).T)
+    assert band.interior(5, 0) and not band.interior(5, 1)
+    whole = fbd._DiffusionBand(128, 16, 16)
     assert whole.blocks_allowed == 4 * 5 // 2 + 4 * 3 // 2 + 4
-    assert [kb for kb, run in (whole.key_block(4, s)
-                               for s in range(whole.k_steps)) if run] == [4]
-    assert whole.first_k(4) == 4 and whole.first_k(5) == 0
+    assert [kb for qb, kb, _ in whole.tiles() if qb == 4] == [4]
+    assert [kb for qb, kb, _ in whole.tiles() if qb == 5] == [0, 5]
     assert whole.pairs() == 64 * 16 + 256 * (4 * 3 // 2 + 4 * 5 // 2)
+    for name in ("key_at", "key_runs", "query_at", "query_runs", "dq_time",
+                 "k_time", "q_time", "first_k"):
+        assert name not in vars(fbd._DiffusionBand)     # `_Band`'s, unused
 
 
 def test_the_kernels_run_under_names_and_costs_of_their_own():
@@ -243,7 +353,7 @@ def test_the_kernels_run_under_names_and_costs_of_their_own():
 
     assert names() == ["flash_block_diffusion_dkv",
                        "flash_block_diffusion_fwd"]
-    band = fa._DiffusionBand(64, 16, 4)
+    band = fbd._DiffusionBand(64, 16, 4)
     cost = band.cost_estimate("fwd", 2 * H, D, 4, 4)["cost_estimate"]
     assert cost.flops == 2 * H * band.pairs() * (4 * D + 8)
     assert cost.bytes_accessed == 2 * H * 64 * D * 4 * (2 + 2 / 4)
