@@ -72,6 +72,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_window_blocks_visited", "flash_window_blocks_allowed",
     "flash_window_calls", "flash_grouped_calls",
     "flash_window_pairs_allowed", "flash_window_entries_computed",
+    "flash_window_forward_whole_band", "flash_window_forward_tiled",
     "attention_head_gate_calls",
     "flash_block_diffusion_calls", "flash_block_diffusion_blocks_visited",
     "flash_block_diffusion_blocks_allowed",
@@ -181,6 +182,14 @@ class RuntimeStats:
         self.flash_grouped_calls = 0
         self.flash_window_pairs_allowed = 0
         self.flash_window_entries_computed = 0
+        # the same calls by the forward the call's shape chose
+        # (`flash_attention.py whole_band_forward_fits`): a grid step a
+        # query tile's WHOLE band, the soft-max in one pass, or the
+        # online soft-max over the band's key tiles (delta() around a
+        # step build; a window layer in a recompute segment is traced
+        # forward and once more for the segment's backward pass)
+        self.flash_window_forward_whole_band = 0
+        self.flash_window_forward_tiled = 0
         # per-head output gates `models/decoder.py` built
         # (`attention_gate="head"`), one a layer, at program build time
         self.attention_head_gate_calls = 0
@@ -355,6 +364,14 @@ class RuntimeStats:
         with self._lock:
             self.flash_window_blocks_visited += visited
             self.flash_window_blocks_allowed += allowed
+
+    def record_flash_window_forward(self, whole_band: bool):
+        """Which forward a traced call under a window took."""
+        with self._lock:
+            if whole_band:
+                self.flash_window_forward_whole_band += 1
+            else:
+                self.flash_window_forward_tiled += 1
 
     def record_flash_window_call(self, pairs: int, entries: int):
         """One traced forward call under a window: a head's allowed

@@ -75,6 +75,22 @@ blocks pass Mosaic's default 16 MiB of scoped VMEM (17.35 MiB), so such
 a call names a limit (`_vmem_params`: only where blocks and accumulator
 need it; a call that fits names none).
 
+Under a WINDOW the forward is not online at all where the band is
+short (PR 60, `_flash_fwd_whole_band`): a query tile's whole band is
+the window's W - 1 earlier keys and the tile's own, 1023 keys at W =
+b = 512, and its float32 scores fit VMEM (2 MiB).  One grid step is
+then a query tile against its WHOLE band: scores, mask, max, exp, sum
+and the value product once, no running statistics, no rescale, no
+init / finalize predicates; a key/value head's whole group of query
+heads a step.  The online grid paid the soft-max's row-wise work
+(lane reductions, (b, 1) statistics, the accumulator's rescale) once
+a VISIT, two visits a row, and ran at 32 % of the MXU passes it
+executes; the whole-band step at 72-77 % (PERF.md, PR 60).  The
+call's shapes alone choose (`whole_band_forward_fits`: a window of
+1025 keys at the most); the residuals (`o`, `lse8`), the kernel's name
+and its declared cost are the same, so the backward kernels see the
+same inputs.
+
 Two operand layouts, selected by `layout=`:
 
 - "nhtd" (historical): q/k/v arrive (N, H, T, D) and are folded to
@@ -122,9 +138,11 @@ DEFAULT_BWD_BLOCK_K = 1024
 # The band kernels' own (a window, grouped key/value heads), tuned on
 # v5e at 16384 positions, 32 / 4 heads of 128, alone (PERF.md, PR 38).
 # Their forward takes 1024 x 1024: 18.9 ms a call over the whole prefix
-# against 28.6 at the 256 x 1024 above and 21.9 at 512 x 1024; under a
-# window of 1024 keys 4.78 ms against 6.50, 5.26 and 6.20 at 512 x 512,
-# though half of what it then visits is masked.  The window's backward
+# against 28.6 at the 256 x 1024 above and 21.9 at 512 x 1024; the
+# online soft-max under a window of 1024 keys 4.78 ms against 6.50,
+# 5.26 and 6.20 at 512 x 512, though half of what it then visits is
+# masked (a window that narrow takes the whole-band step below since
+# PR 60; one of 1026 keys or more keeps these tiles).  The window's backward
 # takes 512 x 512 (7.19 ms; 8.52 at 1024 x 1024, 8.26 at 256 x 512),
 # the backward over the whole prefix the 1024 x 1024 above (36.7).
 DEFAULT_BAND_BLOCK_Q = 1024
@@ -132,11 +150,24 @@ DEFAULT_BAND_BLOCK_K = 1024
 DEFAULT_WINDOW_BWD_BLOCK_Q = 512
 DEFAULT_WINDOW_BWD_BLOCK_K = 512
 # the smallest forward tile a narrow window is given
-# (`_window_fwd_blocks`): under 512 keys at 64 / 8 heads of 128, 16384
-# positions, alone on v5e (PERF.md, PR 51), 512 x 512 takes 8.68 ms a
-# call forward against 9.47 at 1024 x 1024 and 14.69 at 256 x 256 (a
-# grid step costs ~0.9 us beside ~1.2 us of work a 512 x 512 tile)
+# (`_window_fwd_blocks`)
 MIN_WINDOW_FWD_BLOCK = 256
+# The window forward's whole-band step (`_flash_fwd_whole_band`, PR 60):
+# wherever the float32 scores of a query tile's whole band fit the
+# budget (3 MiB: 512 x 1536, a window of 1025 keys at the most), the
+# tile is 512 and a grid step is a key/value head's whole group against
+# the band.  Alone on v5e at 16384 positions, bf16 (PERF.md, PR 60), ms
+# a call forward: under 512 keys at 64 / 8 heads of 128 3.63 (3.73 at
+# 256 x 768 keys, fewer entries and twice the steps; a head a step
+# 3.86; the scores in (k, q) orientation 4.41) against 8.69 for the
+# online soft-max over 512 x 512 tiles (9.47 at 1024 x 1024, 14.69 at
+# 256 x 256: PR 51); under 1024 keys at 32 / 4 heads 2.90 (3.61 at
+# 1024 x 2048 keys, which also needs a VMEM limit) against 4.80 for
+# 1024 x 1024 tiles and 6.24 for 512 x 512; at 8192 positions, 40 / 20
+# heads, 512 keys 1.27 against 2.75.  A wider window keeps the online
+# soft-max over 1024 x 1024 tiles
+WHOLE_BAND_FWD_BLOCK = 512
+WHOLE_BAND_SCORE_BUDGET = 3 << 20
 # The block-diffusion mask's square tiles (`flash_block_diffusion.py`),
 # forward and backward, tuned on v5e at 2 x 8192 rows, 32 / 4 heads of
 # 128, blocks of 4, alone: on the list of visits 1024 x 1024 takes 10.1
@@ -1073,15 +1104,21 @@ class _Band:
         no operand's shape says what a band allows."""
         return "flash_window_" if self.window else "flash_"
 
-    def record_blocks(self):
-        """The forward grid of a call with a window, a head's."""
+    def record_blocks(self, whole_band=False):
+        """The forward grid of a call with a window, a head's: the key
+        tiles it visits (a whole-band step holds `k_steps` of them and
+        computes every one; the tiled grid skips the compute of those
+        past the diagonal), and which forward ran."""
         from ...observe.monitoring import runtime_stats
 
-        runtime_stats.record_flash_window_blocks(
-            self.nq * self.k_steps, self.blocks_allowed)
+        visited = self.nq * self.k_steps
+        runtime_stats.record_flash_window_blocks(visited,
+                                                 self.blocks_allowed)
         runtime_stats.record_flash_window_call(
             self.pairs(),
-            self.blocks_allowed * self.block_q * self.block_k)
+            (visited if whole_band else self.blocks_allowed)
+            * self.block_q * self.block_k)
+        runtime_stats.record_flash_window_forward(whole_band)
 
     # A grid's last axis counts the blocks of the band: the key block
     # of a query block's `step` (`key_at`) and whether the pair holds a
@@ -1139,6 +1176,127 @@ class _Band:
             flops=int(pairs * (dots * d + soft)), transcendentals=int(pairs),
             bytes_accessed=int(nh * self.t * d * itemsize
                                * (tiles[0] + tiles[1] / group)))}
+
+
+# -- the whole-band forward ---------------------------------------------------
+#
+# A window forward whose band is short enough for VMEM (the module
+# docstring says why and what it returned; `whole_band_forward_fits`
+# says when).
+
+def _whole_band_fwd_kernel(q_ref, *refs, scale, block, tiles, window, heads,
+                           d):
+    """One query tile against its WHOLE band: `tiles` key / value tiles
+    (the same arrays under one BlockSpec a tile: key tile qb - back of
+    the step's query tile qb, clamped at 0), the scores of all of them,
+    the soft-max in one pass.  `heads` query heads a step, all reading
+    the step's key/value head: a `fori_loop` over the lane tiles of the
+    (block, heads * d) q / o block."""
+    from jax.experimental import pallas as pl
+
+    k_refs, v_refs = refs[:tiles], refs[tiles:2 * tiles]
+    o_ref, lse_ref = refs[2 * tiles:]
+    qb = pl.program_id(1)
+    # query position minus key position, in a tile `back` tiles before
+    # the diagonal's, is back * block + rel
+    shape = (block, block)
+    rel = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+           - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+    def scores(q, j):
+        back = tiles - 1 - j
+        s = _dot(q, k_refs[j][0], ((1,), (1,))) * scale
+        keep = [rel >= 0] * (back == 0)         # the diagonal's: causal
+        if (back + 1) * block > window:
+            # the window's edge crosses the tile; a tile before the
+            # sequence's first (its index clamped) keeps nothing
+            edge = window - back * block
+            if back:
+                edge = jnp.where(qb >= back, edge, -block)
+            keep.append(rel < edge)
+        elif back:
+            keep.append(jnp.broadcast_to(qb >= back, shape))
+        return jnp.where(functools.reduce(jnp.logical_and, keep), s, NEG_INF)
+
+    def head(i, carry):
+        lanes = pl.ds(pl.multiple_of(i * d, d), d)
+        q = q_ref[0, :, lanes]
+        s = [scores(q, j) for j in range(tiles)]
+        # every row holds its own key, so its max is a score's
+        m = jnp.max(functools.reduce(jnp.maximum, s), axis=1, keepdims=True)
+        p = [jnp.exp(x - m) for x in s]
+        l = jnp.sum(functools.reduce(jnp.add, p), axis=1, keepdims=True)
+        acc = functools.reduce(jnp.add, (
+            _dot(p[j].astype(v_refs[j].dtype), v_refs[j][0], ((1,), (0,)))
+            for j in range(tiles)))
+        o_ref[0, :, lanes] = (acc / l).astype(o_ref.dtype)
+        # lse replicated over 8 sublanes, as the tiled forward writes it
+        lse = (m + jnp.log(l))[:, 0]
+        lse_ref[i] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+        return carry
+
+    jax.lax.fori_loop(0, heads, head, 0)
+
+
+def whole_band_forward_fits(window, block_q, block_k):
+    """Whether the forward pass of a band call is the whole-band kernel:
+    from the shape alone.  A window, square tiles, and the float32
+    scores of a query tile's whole band (the window's W - 1 earlier
+    keys and the tile's own, in whole key tiles) within
+    `WHOLE_BAND_SCORE_BUDGET`."""
+    if not window or block_q != block_k:
+        return False
+    tiles = -(-(window - 1) // block_k) + 1
+    return 4 * block_q * tiles * block_k <= WHOLE_BAND_SCORE_BUDGET
+
+
+def _flash_fwd_whole_band(q, k, v, scale, block, n_head, group, window):
+    """The forward pass of a call with a window whose band fits VMEM
+    (`whole_band_forward_fits`): grid (N x Hkv, query tiles), a step a
+    key/value head's whole group of query heads against a query tile's
+    whole band.  The q / o block is (1, block, group * d), contiguous
+    lanes of the head-major layout; k and v are fetched once a group.
+    Same residuals as the tiled forward (`o`, `lse8`), so the backward
+    kernels see the same inputs."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, t, hd = q.shape
+    h, hkv, d = n_head, n_head // group, hd // n_head
+    band = _Band(t, block, block, window)
+    tiles = band.k_steps
+    q_spec = pl.BlockSpec((1, block, group * d),
+                          lambda g, qb: (g // hkv, qb, g % hkv))
+    kv_specs = [
+        pl.BlockSpec((1, block, d), lambda g, qb, back=tiles - 1 - j: (
+            g // hkv, jnp.maximum(qb - back, 0), g % hkv))
+        for j in range(tiles)]
+    # the q and o blocks and the key / value tiles twice over (the
+    # pipeline's two buffers) and two float32 copies of the band's
+    # scores (Mosaic scopes 7.5 MiB for bfloat16 at 64 / 8 heads under
+    # 512 keys, where this counts 9): near Mosaic's default 16 MiB only
+    # in float32 at a group of 8 (the parity scripts' "highest" runs),
+    # so no cell's step is planned around a call that names a limit
+    item = q.dtype.itemsize
+    vmem = {}
+    if (4 * block * (group + tiles) * d * item
+            + 8 * tiles * block * block) > 12 << 20:
+        vmem = {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT)}
+    band.record_blocks(whole_band=True)
+    return _pallas_call(
+        functools.partial(_whole_band_fwd_kernel, scale=scale, block=block,
+                          tiles=tiles, window=window, heads=group, d=d),
+        name=band.prefix + "fwd",
+        **band.cost_estimate("fwd", n * h, d, item, group),
+        grid=(n * hkv, band.nq),
+        in_specs=[q_spec] + kv_specs + kv_specs,
+        out_specs=[q_spec, pl.BlockSpec((group, 8, block),
+                                        lambda g, qb: (g, 0, qb))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((n * h, 8, t), jnp.float32)],
+        **vmem,
+    )(q, *([k] * tiles), *([v] * tiles))
 
 
 def band_backward_fits(t, d):
@@ -1367,10 +1525,14 @@ def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window):
 
 def _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
                     window):
-    band = _Band(q.shape[1], *blocks, window)
-    o, lse8 = keep_residuals(*_flash_fwd(
-        q, k, v, None, None, scale, True, *blocks, "nthd", n_head, band,
-        group))
+    if whole_band_forward_fits(window, *blocks):
+        o, lse8 = _flash_fwd_whole_band(q, k, v, scale, blocks[0], n_head,
+                                        group, window)
+    else:
+        o, lse8 = _flash_fwd(
+            q, k, v, None, None, scale, True, *blocks, "nthd", n_head,
+            _Band(q.shape[1], *blocks, window), group)
+    o, lse8 = keep_residuals(o, lse8)
     return o, (q, k, v, o, lse8)
 
 
@@ -1423,16 +1585,19 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def _window_fwd_blocks(window):
-    """The forward tile under a window of `window` keys: square, its
-    side the largest power of two the window holds, no smaller than
-    `MIN_WINDOW_FWD_BLOCK` and no larger than the band's own
-    `DEFAULT_BAND_BLOCK_*`.  A query tile of side b meets the window's
-    W - 1 earlier keys and its own b, so a tile larger than the window
-    is mostly mask: 1024 x 1024 under 512 keys computes four score
-    entries for each pair the band allows, 512 x 512 two; at W = 1024
-    and above the tile stays 1024 x 1024."""
+    """The forward tile under a window of `window` keys: square, from
+    the window alone.  Where the whole band of a `WHOLE_BAND_FWD_BLOCK`
+    tile fits (`whole_band_forward_fits`: up to 1025 keys) that tile,
+    512, or the largest power of two a narrower window holds, no
+    smaller than `MIN_WINDOW_FWD_BLOCK`; the forward is then the
+    whole-band step.  A wider window keeps the online soft-max over the
+    band's own `DEFAULT_BAND_BLOCK_*`, 1024 x 1024."""
     side = max(MIN_WINDOW_FWD_BLOCK, 1 << (int(window).bit_length() - 1))
-    return (min(side, DEFAULT_BAND_BLOCK_Q), min(side, DEFAULT_BAND_BLOCK_K))
+    tiled = (min(side, DEFAULT_BAND_BLOCK_Q), min(side, DEFAULT_BAND_BLOCK_K))
+    whole = min(*tiled, WHOLE_BAND_FWD_BLOCK)
+    if whole_band_forward_fits(window, whole, whole):
+        return (whole, whole)
+    return tiled
 
 
 def _band_blocks(t, block_q, block_k, window):
@@ -1482,11 +1647,14 @@ def pallas_flash_attention(q, k, v, bias=None, scale=None, causal=False,
     tiles (`_band_blocks`; a `block_q` / `block_k` given holds
     for both passes): forward 1024 x 1024 and backward 1024 x 1024 over
     the whole prefix; under a window the backward 512 x 512 and the
-    forward tile FOLLOWS THE WINDOW, from the shape alone
-    (`_window_fwd_blocks`): square, the largest power of two the window
-    holds, no smaller than 256 and no larger than 1024, so 512 keys run
-    512 x 512 tiles (half of what they compute is allowed, a quarter at
-    1024 x 1024) and 1024 keys and above keep 1024 x 1024.  Any group
+    forward's tile AND PATH follow the window, from the shape alone
+    (`_window_fwd_blocks`, `whole_band_forward_fits`): up to 1025 keys
+    a query tile of 512 (the largest power of two a narrower window
+    holds, no smaller than 256) against its WHOLE band in one grid
+    step, the soft-max in one pass, a key/value head's group of query
+    heads a step; a wider window keeps the online soft-max over 1024 x
+    1024 tiles, as does a tile given that is not square or whose band
+    passes the budget.  Any group
     size runs (query head j reads key/value head j // group: 8 over 4,
     8 over 2, 6 and 8 over 8 are data to the grids).
 

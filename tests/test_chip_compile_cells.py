@@ -604,8 +604,13 @@ def test_the_head_count_a_layer_cells_step_and_the_plan_its_share_rule_read(
                             "rope_fwd", "rope_bwd", "rows_to_tokens"}
     # no fall-back anywhere: by the step's trace
     assert (took["flash_window_calls"], took["flash_grouped_calls"]) == (6, 4)
-    assert 2 * took["flash_window_pairs_allowed"] == pytest.approx(
-        took["flash_window_entries_computed"], rel=1e-3)
+    # every window forward (three layers, traced forward and for the
+    # segment's backward pass) is the whole-band step (PR 60): 32 query
+    # tiles x 2 key tiles of 512 x 512 a head, 49.2 % of them allowed
+    assert (took["flash_window_forward_whole_band"],
+            took["flash_window_forward_tiled"]) == (6, 0)
+    assert took["flash_window_entries_computed"] == 6 * 64 * 512 * 512
+    assert took["flash_window_pairs_allowed"] == 6 * 8257792
     assert (took["flash_attention_backward_fused"],
             took["flash_attention_backward_split"]) == (5, 0)
     assert took["recompute_kept_residuals"] == 5
@@ -646,6 +651,10 @@ def test_the_state_space_cells_step_and_the_plan_its_length_read(one_chip):
     assert (took["short_convs_kernel"], took["short_convs_xla"],
             took["short_conv_bias_calls"]) == (2, 0, 2)
     assert (took["flash_window_calls"], took["flash_grouped_calls"]) == (2, 4)
+    # the window layer's forward, traced twice: the whole-band step at
+    # a group of two heads (PR 60)
+    assert (took["flash_window_forward_whole_band"],
+            took["flash_window_forward_tiled"]) == (2, 0)
     assert (took["flash_attention_backward_fused"],
             took["flash_attention_backward_split"]) == (3, 0)
     # three attention calls' (o, logsumexp) and two scans' (y, states)

@@ -149,6 +149,64 @@ def test_the_window_cells_backward_pass_is_one_kernel(one_chip, window,
     assert took == (1, 0)
 
 
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32_highest"])
+@pytest.mark.parametrize("t, heads, kv_heads, window, whole", [
+    (16384, 64, 8, 512, True), (16384, 32, 4, 1024, True),
+    (8192, 40, 20, 512, True), (16384, 32, 4, 2048, False)],
+    ids=["laguna_16k", "mellum2_16k", "phi4flash_8k", "window_2048_tiled"])
+def test_the_window_forward_the_shape_chooses(one_chip, t, heads, kv_heads,
+                                              window, whole, dtype):
+    """The window forward alone at the three cells' shapes, in bfloat16
+    as the cells run it and in float32 at "highest" as their parity
+    scripts do: a query tile of 512 against its WHOLE band (two key
+    tiles under 512 keys, three under 1024), a key/value head's group
+    (8, 8, 2) a grid step, the soft-max in one pass (PR 60); a window
+    of 2048 keys keeps the online soft-max over 1024 x 1024 tiles.
+    Either is ONE custom call named `flash_window_fwd` that declares
+    the band's pairs.  In bfloat16 no call names a VMEM limit (XLA
+    plans a step differently around one that does); float32 operands
+    at a group of 8 pass the default 16 MiB and name one."""
+    from chip_compile import force_mosaic_lowering
+    from paddle_tpu.observe import cost
+
+    blocks, _ = fa._band_blocks(t, None, None, window)
+    assert blocks == ((512, 512) if whole else (1024, 1024))
+    assert fa.whole_band_forward_fits(window, *blocks) == whole
+
+    def forward(q, k, v):
+        return fa._flash_band_fwd(q, k, v, D ** -0.5, blocks, (512, 512),
+                                  heads, heads // kv_heads, window)[0]
+
+    before = runtime_stats.snapshot()
+    args = [jax.ShapeDtypeStruct((1, t, h * D), dtype, sharding=one_chip)
+            for h in (heads, kv_heads, kv_heads)]
+    with force_mosaic_lowering(), jax.default_matmul_precision(
+            "default" if dtype == BF16 else "highest"):
+        compiled = jax.jit(forward).lower(*args).compile()
+    took = runtime_stats.delta(before)
+    assert (took["flash_window_forward_whole_band"],
+            took["flash_window_forward_tiled"]) == (
+        (1, 0) if whole else (0, 1))
+    row, = [r for r in cost.instruction_costs(
+        cost.compiled_hlo_proto(compiled)) if r["kernel"]]
+    pairs = heads * (window * t - window * (window - 1) // 2)
+    assert row["kernel"] == "flash_window_fwd"
+    assert row["flops"] == pairs * (4 * D + 8)
+    assert row["bytes"] == t * D * dtype.dtype.itemsize * 2 * (
+        heads + kv_heads)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert (f'"size":"{fa._VMEM_LIMIT}"' in text) == (
+        whole and dtype == F32 and heads // kv_heads == 8)
+    # key tiles a head's grid holds and those with an allowed pair
+    band = fa._Band(t, *blocks, window)
+    assert took["flash_window_blocks_visited"] == band.nq * band.k_steps
+    assert took["flash_window_blocks_allowed"] == band.blocks_allowed
+    assert took["flash_window_entries_computed"] == (
+        band.nq * band.k_steps if whole else band.blocks_allowed
+    ) * blocks[0] * blocks[1]
+
+
 def test_a_window_kernels_registered_cost_is_the_bands(one_chip):
     from paddle_tpu.observe import cost
 
@@ -325,8 +383,13 @@ STEP_TEXT = {
     # here through the interpreter (parents: 692f6104.., acbeb427..,
     # PR 46's SiLU short convolution, eeed81fb.., new in PR 47); every
     # other cell turns bare or over pairs and keeps its parent's text
+    # Re-pinned, PR 60, with `laguna-16k` and `phi4flash-8k` below: a
+    # window forward's grid step is a query tile of 512 against its
+    # whole band, here through the interpreter (parents: 0fa427d2..,
+    # 71766418.., 8262a62f..); the ten other cells trace no window
+    # kernel and keep their text
     "mellum2-16k":
-    "0fa427d2d79c67b2a37d069275e1beaa86e270099c3fb9456d46cca534239e07",
+    "7c286e0cf17ce068d5d6756cdb0e8442e032e8e5ecba9cf4094be70573bc741e",
     # re-pinned, PR 52: (I + A)^-1 is `gated_delta_inverse`'s, named for
     # the layers' segments to keep, and `gated_delta_operands_fwd` reads
     # it (parent: f591949a..); no other cell builds the op, and a name
@@ -351,14 +414,14 @@ STEP_TEXT = {
     # cell keeps its parent's text: `mellum2-16k`'s window of 1024 keeps
     # its 1024 x 1024 forward tile
     "laguna-16k":
-    "717664188564e58e142a8c724ac3ddf0fa662d129d19618cfb93535919a837c7",
+    "562da214d669931fd3a4184ded037df76bb622256170e0d01a054ce191e87e66",
     # new in PR 53 (the selective scan and the biased convolution
     # through the interpreter, differential attention on the band and
     # grouped kernels, values that cross recompute segments); every
     # other cell keeps its parent's text: a bias that is absent and a
     # name in `KEPT_RESIDUALS` that a step never emits leave it alone
     "phi4flash-8k":
-    "8262a62f096c9ae6b27eeda5de0d5d97703040ecc95e775e371806904d511e32",
+    "b4728adc7b753bd1160ac65f0f1f1515a97251cb2ff4d3ddd628d8232e03b8d9",
     # new in PR 58 (the scalar-a-head scan through the interpreter, the
     # gated norm, one biased convolution over x, B and C, grouped flash
     # attention under a scale of 2^-6, the four multipliers); every
@@ -401,11 +464,23 @@ def step_text(cell_name):
         return step.lower(state, feeds).as_text()
 
 
+# window forwards this lowering of a cell's step traces: three a window
+# layer, a recompute segment (the AOT build of
+# tests/test_chip_compile_cells.py counts two), all of them the
+# whole-band step (PR 60); no other cell traces a window kernel
+WINDOW_FORWARDS = {"laguna-16k": 9, "mellum2-16k": 18, "phi4flash-8k": 3}
+
+
 @pytest.mark.parametrize("cell", sorted(STEP_TEXT))
 def test_every_existing_cells_step_is_the_parents_text(cell):
     """`tbase-256-dp4` runs `tbase-256`'s Program under a mesh, which is
     a placement of the same step (PR 28)."""
     import hashlib
 
+    before = runtime_stats.snapshot()
     text = _NUMBERED.sub(r"\1", step_text(cell))
+    took = runtime_stats.delta(before)
+    assert (took["flash_window_forward_whole_band"],
+            took["flash_window_forward_tiled"]) == (
+        WINDOW_FORWARDS.get(cell, 0), 0)
     assert hashlib.sha256(text.encode()).hexdigest() == STEP_TEXT[cell]

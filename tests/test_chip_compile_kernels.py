@@ -527,8 +527,11 @@ def test_band_kernels_at_a_head_count_a_layer_type(one_chip, dtype, heads,
                                                    window):
     """`laguna-16k`'s two geometries, 1 x 16384 at d_head 128 over 8
     key/value heads: 64 query heads (groups of 8) under a window of 512
-    keys, whose forward tile follows the window (512 x 512: 63 tiles a
-    head hold an allowed pair), and 48 query heads (groups of SIX) over
+    keys, whose forward is the whole-band step since PR 60 (grid (8
+    key/value heads, 32 query tiles of 512): the group's eight heads a
+    `fori_loop` over the lane tiles of a (512, 1024) q / o block, two
+    key tiles a step, 64 a head computed, the first query tile's
+    clamped one masked whole), and 48 query heads (groups of SIX) over
     the whole prefix at 1024 x 1024.  One forward and ONE backward
     kernel each (24 MiB of dq, dk, dv in VMEM), bfloat16 as the cell
     runs them and float32 as `benchmarks/laguna_parity.py` does."""
@@ -565,7 +568,11 @@ def test_band_kernels_at_a_head_count_a_layer_type(one_chip, dtype, heads,
         assert (took["flash_window_calls"], took["flash_grouped_calls"]) \
             == (1, 0)
         assert took["flash_window_pairs_allowed"] == 8257792
-        assert took["flash_window_entries_computed"] == 63 * 512 * 512
+        assert took["flash_window_entries_computed"] == 64 * 512 * 512
+        assert (took["flash_window_blocks_visited"],
+                took["flash_window_blocks_allowed"]) == (64, 63)
+        assert (took["flash_window_forward_whole_band"],
+                took["flash_window_forward_tiled"]) == (1, 0)
     else:
         assert (took["flash_window_calls"], took["flash_grouped_calls"]) \
             == (0, 1)

@@ -3,8 +3,13 @@ key/value heads at any d_head the forward takes; interpret mode on the
 CPU, the same kernels Mosaic compiles in tests/test_chip_compile_flash.py)
 against the XLA composition under an EXPLICIT mask: forward and all
 three gradients; the one-kernel and the two-kernel backward to the bit;
-k, v, dk, dv never repeated; what the band kernels do not take raises.
+the window forward's two paths (a query tile against its whole band,
+the soft-max in one pass; the online soft-max over the band's key
+tiles) and what chooses between them; k, v, dk, dv never repeated; what
+the band kernels do not take raises.
 """
+
+import itertools
 
 import numpy as np
 import pytest
@@ -53,41 +58,139 @@ def _flash(q, k, v, hkv, window, **kw):
 
 def _grads(fn, q, k, v, w):
     return jax.value_and_grad(
-        lambda q, k, v: jnp.sum(w * fn(q, k, v)), (0, 1, 2))(q, k, v)
+        lambda q, k, v: jnp.sum((w * fn(q, k, v)).astype(jnp.float32)),
+        (0, 1, 2))(q, k, v)
 
 
 # under a block, one block, one and a half, and every key (no window)
 WINDOWS = [None, 5, BLOCK, 24, T, T + 9]
+SQUARE, WIDE_K, WIDE_Q = (16, 16), (16, 32), (32, 16)
+F32, BF16 = jnp.float32, jnp.bfloat16
+# (window, key/value heads, blocks, dtype).  Square tiles under a window
+# take the whole-band forward (W < b, W = b, W = 1.5 b, W = 2 b: two and
+# three key tiles a step, the first query tiles' clamped), the others
+# the online soft-max; groups of 1, 2 and 8 query heads a step
+BAND_CASES = [
+    case + (F32,) for case in itertools.product(
+        WINDOWS, [H, H // 8], [SQUARE, WIDE_K, WIDE_Q])
+] + [(32, hkv, SQUARE, F32) for hkv in (H, H // 8)] + [
+    (window, H // 2, SQUARE, F32) for window in (5, BLOCK, 32)
+] + [(5, H, SQUARE, BF16), (BLOCK, H // 2, SQUARE, BF16),
+     (32, H // 8, SQUARE, BF16)]
 
 
-@pytest.mark.parametrize("blocks", [(16, 16), (16, 32), (32, 16)],
-                         ids=["square", "wide_k", "wide_q"])
-@pytest.mark.parametrize("hkv", [H, H // 8], ids=["mha", "gqa8"])
-@pytest.mark.parametrize("window", WINDOWS)
+def _case_id(case):
+    window, hkv, blocks, dtype = case
+    return (f"w{window}-group{H // hkv}-{blocks[0]}x{blocks[1]}-"
+            f"{jnp.dtype(dtype).name}")
+
+
+@pytest.mark.parametrize("window, hkv, blocks, dtype", BAND_CASES,
+                         ids=[_case_id(c) for c in BAND_CASES])
 def test_band_kernels_match_the_masked_composition(window, hkv, blocks,
-                                                   monkeypatch):
+                                                   dtype, monkeypatch):
     """Forward and dq, dk, dv; then the same call with the single
-    kernel's budget at zero: the two kernels give the same bits."""
-    q, k, v, w = _qkvw(hkv, seed=3)
+    kernel's budget at zero: the two kernels give the same bits.  The
+    backward kernels are the same behind either forward."""
+    q, k, v, w = _qkvw(hkv, seed=3, dtype=dtype)
     kw = dict(block_q=blocks[0], block_k=blocks[1])
     before = runtime_stats.snapshot()
     out, got = _grads(lambda *a: _flash(*a, hkv, window, **kw), q, k, v, w)
     took = runtime_stats.delta(before)
-    want_out, want = _grads(lambda *a: _dense(*a, hkv, window), q, k, v, w)
-    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+    f32 = [x.astype(F32) for x in (q, k, v, w)]
+    want_out, want = _grads(lambda *a: _dense(*a, hkv, window), *f32[:3],
+                            f32[3])
+    # bfloat16: p rounded to 8 bits before each product, as o and the
+    # gradients are
+    tol = 2e-5 if dtype == F32 else 6e-2
+    if dtype == F32:
+        np.testing.assert_allclose(out, want_out, rtol=tol, atol=tol)
+    else:       # a sum of 1024 rounded products: o itself, by the element
+        np.testing.assert_allclose(
+            _flash(q, k, v, hkv, window, **kw).astype(F32),
+            _dense(*f32[:3], hkv, window), rtol=tol, atol=tol)
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape           # dk, dv: key/value heads wide
-        np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5,
+        assert g.dtype == dtype
+        np.testing.assert_allclose(g.astype(F32), r, rtol=tol, atol=tol,
                                    err_msg="d" + name)
     assert took["flash_attention_backward_fused"] == 1
     windowed = window is not None and window < T
     assert (took["flash_window_blocks_visited"] > 0) == windowed
+    assert (took["flash_window_forward_whole_band"],
+            took["flash_window_forward_tiled"]) == (
+        (0, 0) if not windowed else (1, 0) if blocks == SQUARE else (0, 1))
     monkeypatch.setattr(fa, "FUSED_ACCUMULATOR_BUDGET", 0)
     before = runtime_stats.snapshot()
     _, split = _grads(lambda *a: _flash(*a, hkv, window, **kw), q, k, v, w)
     assert runtime_stats.delta(before)["flash_attention_backward_split"] == 1
     for g, s in zip(got, split):
-        np.testing.assert_array_equal(g, s)
+        np.testing.assert_array_equal(g.astype(F32), s.astype(F32))
+
+
+@pytest.mark.parametrize("window, hkv, dtype", [
+    (5, H, F32), (BLOCK, H // 2, F32), (24, H // 8, F32), (32, H // 8, F32),
+    (BLOCK, H // 8, BF16)])
+def test_the_whole_band_forward_gives_the_tiled_forwards_o_and_lse(
+        window, hkv, dtype):
+    """The two forwards on the same operands: the same `o` and
+    logsumexp to float32 rounding (a bfloat16 `o` to its last bit), the
+    first query tiles (their clamped key tiles masked whole) included;
+    the same declared cost (the kernel's name is read off
+    the lowered text below and in tests/test_chip_compile_flash.py)."""
+    q, k, v, _ = _qkvw(hkv, seed=7, dtype=dtype)
+    band = fa._Band(T, BLOCK, BLOCK, window)
+    o, lse = fa._flash_fwd(q, k, v, None, None, D ** -0.5, True, BLOCK,
+                           BLOCK, "nthd", H, band, H // hkv)
+    o2, lse2 = fa._flash_fwd_whole_band(q, k, v, D ** -0.5, BLOCK, H,
+                                        H // hkv, window)
+    assert (o2.shape, o2.dtype, lse2.shape) == (o.shape, o.dtype, lse.shape)
+    tol = 2e-6 if dtype == F32 else 2 ** -7
+    np.testing.assert_allclose(o2.astype(F32), o.astype(F32), rtol=tol,
+                               atol=tol)
+    np.testing.assert_allclose(lse2, lse, rtol=2e-6, atol=2e-6)
+    np.testing.assert_array_equal(lse2[:, 0], lse2[:, 7])    # 8 sublanes
+
+    def declared(fn, *args):
+        eqn, = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+                if e.primitive.name == "pallas_call"]
+        return eqn.params["cost_estimate"]
+
+    cost = declared(lambda q, k, v: fa._flash_fwd_whole_band(
+        q, k, v, 1.0, BLOCK, H, H // hkv, window), q, k, v)
+    assert cost == declared(lambda q, k, v: fa._flash_fwd(
+        q, k, v, None, None, 1.0, True, BLOCK, BLOCK, "nthd", H, band,
+        H // hkv), q, k, v)
+    assert cost.flops == 2 * H * band.pairs() * (4 * D + 8)
+
+
+@pytest.mark.parametrize("window, blocks, whole", [
+    (1, (256, 256), True), (100, (256, 256), True), (300, (256, 256), True),
+    (512, (512, 512), True), (700, (512, 512), True),
+    (1024, (512, 512), True), (1025, (512, 512), True),
+    (1026, (1024, 1024), False), (2048, (1024, 1024), False),
+    (4096, (1024, 1024), False), (None, (1024, 1024), False)])
+def test_the_shape_alone_chooses_the_window_forward(window, blocks, whole):
+    """The forward tile and the forward's path from the window alone: up
+    to 1025 keys a tile of 512 (256 under 512 keys) against its whole
+    band, whose float32 scores stay within the budget (512 x 1536 at the
+    most); a wider window, and a call without one, the online soft-max
+    over 1024 x 1024 tiles.  A tile given holds, and chooses: square and
+    within the budget, the whole band."""
+    fwd, bwd = fa._band_blocks(16384, None, None, window)
+    assert fwd == blocks
+    assert bwd == ((512, 512) if window else (1024, 1024))
+    assert fa.whole_band_forward_fits(window, *fwd) == whole
+    assert fa._band_blocks(16384, 128, 256, window) == ((128, 256),) * 2
+    assert not fa.whole_band_forward_fits(window, 128, 256)
+    if window:
+        tiles = -(-(window - 1) // fwd[0]) + 1
+        assert fa._Band(16384, *fwd, window).k_steps == tiles
+        assert (4 * fwd[0] * tiles * fwd[1]
+                <= fa.WHOLE_BAND_SCORE_BUDGET) == whole
+    # 1024 x 1024 given: 4 MiB of scores a key tile, the online soft-max
+    # whatever the window
+    assert not fa.whole_band_forward_fits(window, 1024, 1024)
 
 
 def test_a_window_that_holds_every_key_takes_the_kernels_without_one():
@@ -130,7 +233,10 @@ def test_grouped_keys_and_values_are_never_repeated():
                   if getattr(x.aval, "shape", None) == k.shape]
         if narrow:                      # only the kernels touch k, v, dk, dv
             assert eqn in kernels, eqn.primitive.name
-    assert sum(x.aval.shape == k.shape for x in kernels[0].invars) == 2
+    # the whole-band forward reads k and v under one BlockSpec a key
+    # tile of the band: the same two arrays, three times each
+    narrow = [x for x in kernels[0].invars if x.aval.shape == k.shape]
+    assert len(narrow) == 6 and len(set(narrow)) == 2
     assert sum(x.aval.shape == k.shape for x in kernels[1].outvars) == 2
     dq, dk, dv = jaxpr.out_avals
     assert (dq.shape, dk.shape, dv.shape) == (q.shape, k.shape, v.shape)

@@ -327,30 +327,42 @@ def test_band_kernels_at_groups_of_six_and_eight_over_eight_heads(
         band = fa._Band(T, BLOCK, BLOCK, window)
         assert took["flash_window_pairs_allowed"] == band.pairs() \
             == window * T - window * (window - 1) // 2
+        # square tiles within the budget: the whole-band forward, which
+        # computes (and masks) the first query tile's clamped key tile
         assert took["flash_window_entries_computed"] \
-            == band.blocks_allowed * BLOCK * BLOCK
+            == band.nq * band.k_steps * BLOCK * BLOCK
+        assert (took["flash_window_forward_whole_band"],
+                took["flash_window_forward_tiled"]) == (1, 0)
 
 
 @pytest.mark.parametrize("window, forward", [
-    (512, (512, 512)), (1024, (1024, 1024)), (2048, (1024, 1024)),
+    (512, (512, 512)), (1024, (512, 512)), (2048, (1024, 1024)),
     (700, (512, 512)), (100, (256, 256)), (None, (1024, 1024))])
 def test_the_forward_tile_follows_the_window(window, forward):
-    """A window's forward tile is the largest power of two the window
-    holds, within [256, 1024]; 1024 keys and above, and a call without a
-    window, keep 1024 x 1024; the backward tiles do not move; a tile
-    given holds."""
+    """A window's forward tile from the window alone: up to 1025 keys a
+    query tile of 512 (the largest power of two a narrower window
+    holds, no smaller than 256) against its WHOLE band (PR 60); a wider
+    window, and a call without one, keep the online soft-max over 1024 x
+    1024 tiles; the backward tiles do not move; a tile given holds."""
     blocks, bwd = fa._band_blocks(16384, None, None, window)
     assert blocks == forward
+    assert fa.whole_band_forward_fits(window, *blocks) == (
+        window is not None and window <= 1025)
     assert bwd == ((512, 512) if window else (1024, 1024))
     assert fa._band_blocks(16384, 128, 256, window) == ((128, 256),) * 2
-    # what the choice buys under 512 keys: the tiles' fill
+    # what a tile's side buys under 512 keys: the fill of the tiles a
+    # whole-band step computes (two of 512, three of 256 or of 1024's
+    # clamped pair), against the tiled grid's, which skips the first
+    # query tile's missing neighbour
     if window == 512:
-        fill = {b: fa._Band(16384, b, b, 512).pairs()
-                / (fa._Band(16384, b, b, 512).blocks_allowed * b * b)
-                for b in (1024, 512, 256)}
-        assert round(100 * fill[1024], 1) == 25.4
-        assert round(100 * fill[512], 1) == 50.0
-        assert round(100 * fill[256], 1) == 66.7
+        def fill(b, whole):
+            band = fa._Band(16384, b, b, 512)
+            tiles = band.nq * band.k_steps if whole else band.blocks_allowed
+            return round(100 * band.pairs() / (tiles * b * b), 1)
+
+        assert [fill(b, False) for b in (1024, 512, 256)] \
+            == [25.4, 50.0, 66.7]
+        assert [fill(b, True) for b in (512, 256)] == [49.2, 65.6]
 
 
 # -- (c) YaRN over a part of the head ---------------------------------------

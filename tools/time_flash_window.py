@@ -10,9 +10,17 @@ One call of 1 x `--rows` positions at `--heads` query heads of
 `--head-dim` over `--kv-heads` key/value heads, bfloat16: under
 `--window` keys (`flash_window_fwd` / `_dkv`; 0 = the whole causal
 prefix, `flash_fwd` / `flash_dkv`).  The forward alone at each square
-tile of `--tiles`, with the tiles' fill (pairs the band allows over the
-score entries the visited tiles compute) and the call as `_band_blocks`
-chooses it (`chosen`); forward + backward (a VJP against a fixed
+tile of `--tiles`, the online soft-max over the band's key tiles
+(`tiled:<b>`) beside the whole-band step (`whole_band:<b>`: a query
+tile against its whole band, the soft-max in one pass; under a window,
+where the band's float32 scores stay under 8 MiB), each with its grid
+steps, the tiles' fill (pairs the band allows over the score entries
+the computed tiles hold), the two products of every computed tile at
+the bf16 peak (`products_ms_at_peak`) and that over the measured time
+(`products_share`: 0.32 for the tiled forward under 512 keys, which is
+where PR 60 began), and how far the two forwards' o and logsumexp lie
+apart on the same operands; the call as `_band_blocks` chooses it
+(`chosen`); forward + backward (a VJP against a fixed
 cotangent) at the chosen forward tile and each backward tile of
 `--bwd-tiles` (a side, or query x key sides; the chosen backward tile
 is always among them), each with the backward it ran: `kernels` 1 =
@@ -44,6 +52,9 @@ import numpy as np  # noqa: E402
 
 from paddle_tpu.observe.monitoring import runtime_stats  # noqa: E402
 from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+
+
+PEAK = 197e12      # v5e, bf16 (Google Cloud documentation, "TPU v5e")
 
 
 def ms_a_call(fn, args, repeats):
@@ -89,20 +100,44 @@ def main():
            "pairs_a_head": fa._Band(t, *chosen[0], window).pairs(),
            "forward": {}, "forward_backward": {}}
 
-    def call(fwd, bwd):
-        return jax.jit(lambda q, k, v: fa._flash_band(
-            q, k, v, scale, fwd, bwd, h, h // hkv, window))
-
     def vjp(fwd, bwd):
-        fn = call(fwd, bwd)
-        return jax.jit(lambda q, k, v, ct: jax.vjp(fn, q, k, v)[1](ct))
+        return jax.jit(lambda q, k, v, ct: jax.vjp(
+            lambda q, k, v: fa._flash_band(q, k, v, scale, fwd, bwd, h,
+                                           h // hkv, window),
+            q, k, v)[1](ct))
 
-    for tile in [int(x) for x in args.tiles.split(",")]:
+    def forward(ms, band, tiles_computed, steps):
+        # the two products of every tile a head's grid computes, at the
+        # bf16 peak, over the measured time
+        entries = tiles_computed * band.block_q * band.block_k
+        products_ms = 1e3 * h * entries * 4 * d / PEAK
+        return {"ms": ms, "steps": h * steps,
+                "us_a_step": 1e3 * ms / (h * steps),
+                "fill": band.pairs() / entries,
+                "products_ms_at_peak": products_ms,
+                "products_share": products_ms / ms}
+
+    for tile in [int(x) for x in args.tiles.split(",") if x]:
         band = fa._Band(t, tile, tile, window)
-        out["forward"][str(tile)] = {
-            "ms": ms_a_call(call((tile, tile), chosen[1]), (q, k, v),
-                            args.repeats),
-            "fill": band.pairs() / (band.blocks_allowed * tile * tile)}
+        tiled = jax.jit(lambda q, k, v, tile=tile, band=band: fa._flash_fwd(
+            q, k, v, None, None, scale, True, tile, tile, "nthd", h, band,
+            h // hkv))
+        out["forward"][f"tiled:{tile}"] = forward(
+            ms_a_call(tiled, (q, k, v), args.repeats), band,
+            band.blocks_allowed, band.nq * band.k_steps)
+        if not window or band.k_steps * tile * tile * 4 > 8 << 20:
+            continue        # no band, or one whose scores VMEM does not hold
+        whole = jax.jit(lambda q, k, v, tile=tile: fa._flash_fwd_whole_band(
+            q, k, v, scale, tile, h, h // hkv, window))
+        row = forward(ms_a_call(whole, (q, k, v), args.repeats), band,
+                      band.nq * band.k_steps, band.nq / (h // hkv))
+        # the two forwards on the same operands: o (bfloat16) and the
+        # logsumexp (float32)
+        (o, lse), (o2, lse2) = tiled(q, k, v), whole(q, k, v)
+        row["o_max_abs_diff"] = float(jnp.max(jnp.abs(
+            o.astype(jnp.float32) - o2.astype(jnp.float32))))
+        row["lse_max_abs_diff"] = float(jnp.max(jnp.abs(lse - lse2)))
+        out["forward"][f"whole_band:{tile}"] = row
     bwd_tiles = [tuple(min(int(side), t) for side in (x.split("x") * 2)[:2])
                  for x in args.bwd_tiles.split(",") if x]
     budgets = [int(x) << 20 for x in args.budgets_mib.split(",") if x]
