@@ -74,6 +74,8 @@ _FIELDS = Heard._fields[1:] + (
     "flash_window_pairs_allowed", "flash_window_entries_computed",
     "flash_window_forward_whole_band", "flash_window_forward_tiled",
     "attention_head_gate_calls",
+    "flash_prefix_grid_steps", "flash_prefix_visits_full",
+    "flash_prefix_visits_diagonal",
     "flash_block_diffusion_calls", "flash_block_diffusion_blocks_visited",
     "flash_block_diffusion_blocks_allowed",
     "flash_block_diffusion_grid_steps",
@@ -190,6 +192,16 @@ class RuntimeStats:
         # forward and once more for the segment's backward pass)
         self.flash_window_forward_whole_band = 0
         self.flash_window_forward_tiled = 0
+        # the band calls over the whole causal prefix (no window:
+        # `flash_fwd` / `flash_dkv` / `flash_dq` on a list of visits, PR
+        # 63), a head's pass each, forward and backward: the grid steps
+        # it takes and its visits by kind.  Steps over visits 1.0 = no
+        # step computes nothing (the rectangle: 256 / 136 at 16 x 16
+        # tiles); `_full` over the visits = the tiles computed with no
+        # mask (120 / 136 there)
+        self.flash_prefix_grid_steps = 0
+        self.flash_prefix_visits_full = 0
+        self.flash_prefix_visits_diagonal = 0
         # per-head output gates `models/decoder.py` built
         # (`attention_gate="head"`), one a layer, at program build time
         self.attention_head_gate_calls = 0
@@ -384,6 +396,13 @@ class RuntimeStats:
     def record_flash_grouped_call(self):
         with self._lock:
             self.flash_grouped_calls += 1
+
+    def record_flash_prefix_visits(self, steps: int, full: int,
+                                   diagonal: int):
+        with self._lock:
+            self.flash_prefix_grid_steps += steps
+            self.flash_prefix_visits_full += full
+            self.flash_prefix_visits_diagonal += diagonal
 
     def record_attention_head_gate(self):
         with self._lock:

@@ -155,7 +155,10 @@ def pallas_call(*args, name=None, **kw):
     if name is None:
         name = getattr(kernel, "__name__", None) or getattr(
             getattr(kernel, "func", None), "__name__", "kernel")
-    inner = pl.pallas_call(*args, interpret=interpret(), **kw)
+    # (a jitted pass hands the gate on as it was when the pass was
+    # called: it is part of what the pass is traced for)
+    kw.setdefault("interpret", interpret())
+    inner = pl.pallas_call(*args, **kw)
 
     def scoped(*call_args, **call_kw):
         with jax.named_scope(f"pallas_{name}"):

@@ -91,6 +91,19 @@ call's shapes alone choose (`whole_band_forward_fits`: a window of
 and its declared cost are the same, so the backward kernels see the
 same inputs.
 
+WITHOUT a window a band call (grouped key/value heads over the whole
+causal prefix: `flash_fwd`, `flash_dkv`, past the budget `flash_dq`
+too) walks a scalar-prefetched LIST OF VISITS (PR 63, the section "a
+grid of visits" below; `flash_block_diffusion.py` walks the same
+shells over its own geometry): a grid step a tile that holds a score,
+136 a head at 16384 rows where the rectangle took 256, and the 120
+tiles wholly under the diagonal (`FULL`) computed with no iota, compare
+or select.  Same arithmetic in the same order; a window's grids (two
+or three tiles a run, none empty) and the plain calls (`band is None`:
+a bias, traced offsets, ragged blocks, a returned logsumexp, cross
+lengths, no causal mask; their diagonal may move with a traced offset,
+so no host-made table fits) keep their rectangles.
+
 Two operand layouts, selected by `layout=`:
 
 - "nhtd" (historical): q/k/v arrive (N, H, T, D) and are folded to
@@ -125,7 +138,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import keep_residuals
+from . import interpret, keep_residuals
 
 # Tuned on v5e (seq 2048, d 128): q=256/k=1024 beats the XLA-composed
 # attention; both dims are clamped to the actual sequence length.
@@ -238,6 +251,13 @@ def _attn_dims(operand_shapes, stat_dims):
     return nh, t_q, kd[1], qd[2] // heads
 
 
+def _tensors(operand_shapes):
+    """The operands without a leading table of visits (scalar prefetch,
+    (9, V) int32: the band call over the whole prefix, whose kernels
+    keep the plain names): q, k, v, .. as every other call has them."""
+    return operand_shapes[len(operand_shapes[0][0]) == 2:]
+
+
 def _io_bytes(operand_shapes, result_shapes):
     total = 0
     for dims, elem in list(operand_shapes) + list(result_shapes):
@@ -250,6 +270,7 @@ def _io_bytes(operand_shapes, result_shapes):
 
 def flash_fwd_cost(operand_shapes, result_shapes):
     # result_shapes[-1] is the (nh, 8, t_q) lse output
+    operand_shapes = _tensors(operand_shapes)
     nh, t_q, t_k, d = _attn_dims(operand_shapes, result_shapes[-1][0])
     flops = nh * t_q * t_k * (4.0 * d + _SOFTMAX_FWD_PER_SCORE)
     return flops, _io_bytes(operand_shapes, result_shapes)
@@ -267,6 +288,7 @@ def flash_dkv_cost(operand_shapes, result_shapes):
     # this name that emits dq too (the single backward kernel: three
     # gradients out, where a bias's third result is a (nh, t_k, 1)
     # column) does dq's work as well.
+    operand_shapes = _tensors(operand_shapes)
     nh, t_q, t_k, d = _attn_dims(operand_shapes, operand_shapes[5][0])
     flops = nh * t_q * t_k * (6.0 * d + 0.625 * _SOFTMAX_BWD_PER_SCORE)
     if len(result_shapes) == 3 and result_shapes[2][0] == result_shapes[1][0]:
@@ -275,6 +297,7 @@ def flash_dkv_cost(operand_shapes, result_shapes):
 
 
 def flash_dq_cost(operand_shapes, result_shapes):
+    operand_shapes = _tensors(operand_shapes)
     return _dq_flops(operand_shapes), _io_bytes(operand_shapes, result_shapes)
 
 
@@ -551,15 +574,12 @@ def _flash_fwd(q, k, v, bias, offsets, scale, causal, block_q, block_k,
     else:
         o_shape = jax.ShapeDtypeStruct((nh, t_q, d), q.dtype)
     declared = {}
-    if band is not None and band.prefix != "flash_":
-        # a head's: the batch of a build-time shape inference is a
-        # placeholder
-        band.record_blocks()
-        declared = band.cost_estimate("fwd", nh, d, q.dtype.itemsize, group)
-    elif band is not None and group > 1:
-        from ...observe.monitoring import runtime_stats
-
-        runtime_stats.record_flash_grouped_call()
+    if band is not None:
+        if band.window:
+            # a head's: the batch of a build-time shape inference is a
+            # placeholder
+            band.record_blocks()
+        declared = band.declared_cost("fwd", nh, d, q.dtype.itemsize, group)
     o, lse8 = _pallas_call(
         kern,
         name=(band.prefix if band is not None else "flash_") + "fwd",
@@ -1040,15 +1060,24 @@ def _flash_bwd_split(q, k, v, bias, offsets, o, lse8, do, dlse8, scale,
 #
 # A causal self-attention call, head-major, over whole blocks, in which
 # query i reads keys i - window < j <= i (window None: every j <= i)
-# and `group` query heads read one key/value head.  (The block-diffusion
-# training mask, whose tiles lie in two runs, walks a list of visits:
-# `flash_block_diffusion.py`, over this file's tile arithmetic.)  The
-# grids below run
-# over the block pairs of the band alone: an axis counts from the
-# band's first block, and the index maps stop at its last, so a block
-# pair outside the band costs no compute and no DMA.  k, v, dk and dv
-# stay n_kv_head heads wide in HBM: query head a reads head a // group
-# through the index maps, and dk / dv sum over the group in VMEM.
+# and `group` query heads read one key/value head.  Under a window the
+# grids below run over the block pairs of the band alone: an axis counts
+# from the band's first block, and the index maps stop at its last, so
+# a block pair outside the band costs no compute and no DMA.  Without
+# one (and under the block-diffusion training mask, whose tiles lie in
+# two runs: `flash_block_diffusion.py`) a rectangle would leave half its
+# steps empty, and the grid walks a list of visits (further down).  k,
+# v, dk and dv stay n_kv_head heads wide in HBM: query head a reads head
+# a // group through the index maps, and dk / dv sum over the group in
+# VMEM.
+
+# Rows of a visit table (scalar prefetch, a column a grid step), and
+# what a visit computes of its tile: all of it with no mask, or all of
+# it under the mask (`flash_block_diffusion.py` has a third kind)
+V_Q, V_K, V_HEAD, V_KIND, V_FIRST, V_LAST, V_DQ, V_DQ_FIRST, V_DQ_LAST = \
+    range(9)
+FULL, DIAGONAL = range(2)
+
 
 def _hi(a, b):
     return max(a, b) if isinstance(a, int) and isinstance(b, int) \
@@ -1103,6 +1132,96 @@ class _Band:
         runs under names of its own and declares its cost at the call:
         no operand's shape says what a band allows."""
         return "flash_window_" if self.window else "flash_"
+
+    def _shape(self):       # a static argument of the jitted passes
+        return type(self), self.t, self.block_q, self.block_k, self.window
+
+    def __eq__(self, other):
+        return self._shape() == other._shape()
+
+    def __hash__(self):
+        return hash(self._shape())
+
+    def interior(self, qb, kb):
+        """Whether every pair of tile (qb, kb) is allowed: its last key
+        no later than its first query, and inside the window."""
+        first_q, last_q = qb * self.block_q, (qb + 1) * self.block_q - 1
+        first_k, last_k = kb * self.block_k, (kb + 1) * self.block_k - 1
+        return last_k <= first_q and not (
+            self.window and last_q - first_k >= self.window)
+
+    def tiles(self):
+        """(query tile, key tile, kind) of every tile that holds an
+        allowed pair, a query tile's in a row."""
+        return [(qb, kb, FULL if self.interior(qb, kb) else DIAGONAL)
+                for qb in range(self.nq)
+                for kb in range(self.first_k(qb), self.last_k(qb) + 1)]
+
+    def visits(self, key_major=False, group=1):
+        """The int32 (9, V) table a grid's last axis walks, from the
+        shape, on the host: query-major (the forward, `_dq`) or
+        key-major (the backward; with `group` each key tile meets its
+        query tiles once a head of the group, `V_HEAD`: the kernel that
+        holds tiles only).  `V_FIRST` / `V_LAST` bracket the run of one
+        (major tile, head), `V_DQ_FIRST` / `V_DQ_LAST` a query tile's
+        visits; `V_DQ` is the dq tile the output's index map holds, the
+        last one completed (before any is, the first to be): it moves
+        on only on the step that writes the next tile, so no half-summed
+        tile is ever what Pallas writes back."""
+        rows = sorted(
+            ((qb, kb, head, kind) for head in range(group)
+             for qb, kb, kind in self.tiles()),
+            key=lambda r: (r[1], r[2], r[0]) if key_major else r[:2])
+        q, k, head, kind = np.array(rows, np.int32).T
+        run = (k if key_major else q) * group + head
+        first = np.r_[True, run[1:] != run[:-1]]
+        at = np.arange(q.size)
+        met = [np.flatnonzero(q == qb) for qb in range(self.nq)]
+        dq_first = np.isin(at, [m[0] for m in met])
+        dq_last = np.isin(at, [m[-1] for m in met])
+        done = np.maximum.accumulate(np.where(dq_last, at, -1))
+        dq = q[np.where(done < 0, np.flatnonzero(dq_last)[0], done)]
+        return np.stack([q, k, head, kind, first, np.r_[first[1:], True],
+                         dq, dq_first, dq_last]).astype(np.int32)
+
+    def allowed(self, qb, kb, q_axis):
+        """The mask of a tile the diagonal (or the window's edge)
+        crosses, by position; queries along `q_axis` of the tile."""
+        shape = (self.block_q, self.block_k) if q_axis == 0 else \
+            (self.block_k, self.block_q)
+        q_pos = qb * self.block_q + jax.lax.broadcasted_iota(
+            jnp.int32, shape, q_axis)
+        k_pos = kb * self.block_k + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1 - q_axis)
+        if not self.window:
+            return q_pos >= k_pos
+        return (q_pos >= k_pos) & (q_pos - k_pos < self.window)
+
+    def visit(self, visits, v, q_axis, compute):
+        """Run `compute(mask, at)` as visit `v`'s kind says: rows `at`
+        of the tile's two sides, under the masks in `mask` (`FULL`:
+        none, so no iota, no compare and no select)."""
+        from jax.experimental import pallas as pl
+
+        kind = visits[V_KIND, v]
+        pl.when(kind == FULL)(lambda: compute([], slice(None)))
+        pl.when(kind == DIAGONAL)(lambda: compute(
+            [self.allowed(visits[V_Q, v], visits[V_K, v], q_axis)],
+            slice(None)))
+
+    def record_visits(self):
+        """One traced pass over the whole causal prefix, a head's: the
+        grid steps it takes and its visits by kind."""
+        from ...observe.monitoring import runtime_stats
+
+        kinds = [kind for _, _, kind in self.tiles()]
+        runtime_stats.record_flash_prefix_visits(
+            len(kinds), kinds.count(FULL), kinds.count(DIAGONAL))
+
+    def declared_cost(self, *call):
+        """`cost_estimate` where the kernels' names are the band's own;
+        under the plain names the registry reads the operands' shapes."""
+        return {} if self.prefix == "flash_" else self.cost_estimate(*call)
 
     def record_blocks(self, whole_band=False):
         """The forward grid of a call with a window, a head's: the key
@@ -1428,9 +1547,7 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
         return band.prefix + kernel
 
     def cost(kernel):
-        if band.prefix == "flash_":
-            return {}
-        return band.cost_estimate(kernel, n * h, d, item, group)
+        return band.declared_cost(kernel, n * h, d, item, group)
 
     dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     dk_shape = jax.ShapeDtypeStruct(k.shape, q.dtype)
@@ -1517,6 +1634,209 @@ def _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head, group):
     return dq, dk, dv
 
 
+# -- a grid of visits ---------------------------------------------------------
+#
+# The band call WITHOUT a window (the whole causal prefix over grouped
+# key/value heads) and the block-diffusion mask
+# (`flash_block_diffusion.py`) walk a scalar-prefetched LIST OF VISITS
+# on their grids' last axis (`_Band.visits`, made on the host from the
+# shape when the call is traced): a column a visit, with its query
+# tile, its key tile, FIRST / LAST of its run, the dq tile the output
+# holds and a KIND.  A rectangle of (tiles) x (the longest run) takes
+# 256 grid steps a head for the 136 tiles of a causal prefix of 16 x 16
+# and masks every one as if the diagonal crossed it; the list takes
+# 136, 120 of them `FULL`: no iota, no compare, no select (PERF.md, PR
+# 63).  Same tile arithmetic in the same order as the rectangles
+# (`_softmax_step`, `_bwd_p_ds`, `_add_dk_dv`, `_add_dq`): the same
+# bits.  A pass is jitted on its shapes, its geometry and its lowering,
+# so a program's layers share one trace and one lowering of it.
+
+def _visits_fwd_kernel(visits, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       m_scr, l_scr, acc_scr, *, scale, band):
+    """The forward pass on a list of visits, query-major: `_fwd_kernel`'s
+    online soft-max, a visit a grid step."""
+    from jax.experimental import pallas as pl
+
+    v = pl.program_id(2)
+    pl.when(visits[V_FIRST, v] == 1)(functools.partial(
+        _init_softmax, m_scr, l_scr, acc_scr))
+
+    def _compute(mask, at):
+        s = _dot(q_ref[0, at], k_ref[0, at], ((1,), (1,))) * scale
+        for allowed in mask:
+            s = jnp.where(allowed, s, NEG_INF)
+        _softmax_step(s, lambda: v_ref[0, at], m_scr, l_scr, acc_scr, at)
+
+    band.visit(visits, v, 0, _compute)
+    pl.when(visits[V_LAST, v] == 1)(functools.partial(
+        _write_o_lse, o_ref, lse_ref, m_scr, l_scr, acc_scr))
+
+
+def _visits_bwd_kernel(visits, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                       *, band, group, fused, dq_ref=None, dk_ref=None,
+                       dv_ref=None, dq_acc=None, dk_acc=None, dv_acc=None,
+                       **dims):
+    """The backward pass on a list of visits: p and ds once a visit
+    and, of dq, dk and dv, the sums it was given.  `fused`
+    (`_bwd_band_kernel`'s layout, key-major): all three, whole sequences
+    in VMEM, a dq tile leaving on the visit that completes it, dk and dv
+    during the group's last head.  Past that budget a kernel holds ONE
+    tile of each: dk and dv key-major, the group's heads inside a key
+    tile's run (`V_HEAD`), and dq query-major."""
+    from jax.experimental import pallas as pl
+
+    v = pl.program_id(2)
+    gi = pl.program_id(1) + visits[V_HEAD, v]
+    qb, kb = (visits[V_Q, v], visits[V_K, v]) if fused else (0, 0)
+
+    def zero(acc, at):
+        acc[at] = jnp.zeros(acc.shape[1:], acc.dtype)
+
+    if dk_acc is not None:
+        @pl.when((gi == 0) & (visits[V_FIRST, v] == 1))
+        def _init():
+            zero(dk_acc, kb)
+            zero(dv_acc, kb)
+
+    if dq_acc is not None:
+        pl.when(visits[V_DQ_FIRST, v] == 1)(lambda: zero(dq_acc, qb))
+
+    def _compute(mask, at):
+        refs = [r.at[:, at] for r in (q_ref, k_ref, v_ref, do_ref, o_ref)]
+        q, k, do, p, ds = _bwd_p_ds(
+            *refs, lse_ref.at[:, :, at], None, None, None, kb, qb, mask=mask,
+            **dims)
+        if dk_acc is not None:
+            _add_dk_dv(p, ds, q, do, dk_acc, dv_acc, dims["scale"],
+                       at=(kb, at))
+        if dq_acc is not None:
+            _add_dq(ds, k, dq_acc, dims["scale"], at=(qb, at))
+
+    band.visit(visits, v, 1, _compute)
+
+    if dk_acc is not None:
+        @pl.when((gi == group - 1) & (visits[V_LAST, v] == 1))
+        def _finalize():
+            dk_ref[0] = dk_acc[kb].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[kb].astype(dv_ref.dtype)
+
+    if dq_acc is not None:
+        @pl.when(visits[V_DQ_LAST, v] == 1)
+        def _finalize_dq():
+            dq_ref[0] = dq_acc[qb].astype(dq_ref.dtype)
+
+
+def _visits_call(kernel, name, band, table, hkv, group, outs, scratch,
+                 operands, **params):
+    """One kernel over the grid (N*Hkv, the group's heads, the visits of
+    `table`; a table with a `V_HEAD` holds the heads itself).  `outs`:
+    (what, shape) pairs, `what` a tile at the table's `V_Q` ("q"), at
+    `V_DQ` ("dq"), at `V_K` ("kv"; "kv_last": during the group's last
+    head alone, when dk and dv of the single kernel leave, tile by tile)
+    or the statistic ("stat")."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    q = operands[0]
+    bq, bk, d = band.block_q, band.block_k, q.shape[2] // (hkv * group)
+
+    def head(g, a, v, visits):
+        return (g % hkv) * group + a + visits[V_HEAD, v]
+
+    def q_tile(row):
+        return pl.BlockSpec((1, bq, d), lambda g, a, v, visits: (
+            g // hkv, visits[row, v], head(g, a, v, visits)))
+
+    def kv_tile(last):
+        return pl.BlockSpec((1, bk, d), lambda g, a, v, visits: (
+            g // hkv, jnp.where(a == group - 1, visits[V_K, v], 0) if last
+            else visits[V_K, v], g % hkv))
+
+    spec = {"q": q_tile(V_Q), "dq": q_tile(V_DQ), "kv": kv_tile(False),
+            "kv_last": kv_tile(True),
+            "stat": pl.BlockSpec((1, 8, bq), lambda g, a, v, visits: (
+                g * group + a + visits[V_HEAD, v], 0, visits[V_Q, v]))}
+    ins = ["q", "kv", "kv", "q", "q", "stat"][:len(operands)]
+    return _pallas_call(
+        kernel, name=band.prefix + name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(q.shape[0] * hkv, group // (int(table[V_HEAD].max()) + 1),
+                  table.shape[1]),
+            in_specs=[spec[what] for what in ins],
+            out_specs=[spec[what] for what, _ in outs],
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                            for shape in scratch]),
+        out_shape=[shape for _, shape in outs], **params,
+    )(table, *operands)
+
+
+# (jitted, as `grouped_matmul.py`'s kernels are: a program's layers
+# share their shapes, so a pass is traced and lowered once a shape and
+# called from every layer.  `interpret`, the package's gate as the
+# caller reads it, keys the trace: a process that lowers the same shapes
+# through the interpreter and for Mosaic keeps the two apart.  Counters
+# are the callers': a jitted pass is traced once, not once a layer)
+@functools.partial(jax.jit, static_argnames=("scale", "band", "n_head",
+                                             "group", "interpret"))
+def _flash_fwd_visits(q, k, v, *, scale, band, n_head, group, interpret):
+    n, t, hd = q.shape
+    bq, bk, d = band.block_q, band.block_k, hd // n_head
+    item = q.dtype.itemsize
+    return _visits_call(
+        functools.partial(_visits_fwd_kernel, scale=scale, band=band),
+        "fwd", band, band.visits(), n_head // group, group,
+        [("q", jax.ShapeDtypeStruct(q.shape, q.dtype)),
+         ("stat", jax.ShapeDtypeStruct((n * n_head, 8, t), jnp.float32))],
+        [(bq, 1), (bq, 1), (bq, d)], (q, k, v), interpret=interpret,
+        **band.declared_cost("fwd", n * n_head, d, item, group),
+        **_fwd_vmem_params(bq, bk, d, item))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "band", "n_head",
+                                             "group", "fused", "interpret"))
+def _flash_bwd_visits(q, k, v, o, lse8, do, *, scale, band, n_head, group,
+                      fused, interpret):
+    """(dq, dk, dv) on a list of visits: one kernel where `fused`
+    (`band_backward_fits`), `_dkv` and `_dq` that hold tiles only
+    beyond; every term from `_bwd_p_ds`, added in the same order."""
+    n, t, hd = q.shape
+    bq, bk, d = band.block_q, band.block_k, hd // n_head
+    dk_shape = jax.ShapeDtypeStruct(k.shape, q.dtype)
+    shape = {"dq": jax.ShapeDtypeStruct(q.shape, q.dtype), "dk": dk_shape,
+             "dv": dk_shape}
+    kv_held = (band.nk if fused else 1, bk, d)
+    held = {"dq": (band.nq if fused else 1, bq, d), "dk": kv_held,
+            "dv": kv_held}
+
+    def call(name, cost, parts, table, accumulators):
+        names = [part + kind for kind in ("_ref", "_acc") for part in parts]
+
+        def kern(visits, *refs):
+            _visits_bwd_kernel(
+                visits, *refs[:6], band=band, group=group, fused=fused,
+                scale=scale, causal=False, block_q=bq, block_k=bk, t_q=t,
+                t_k=t, **dict(zip(names, refs[6:])))
+
+        return _visits_call(
+            kern, name, band, table, n_head // group, group,
+            [("dq" if part == "dq" else "kv_last" if fused else "kv",
+              shape[part]) for part in parts],
+            [held[part] for part in parts],
+            (q, k, v, do, o, lse8), interpret=interpret,
+            **band.declared_cost(cost, n * n_head, d, q.dtype.itemsize,
+                                 group),
+            **_vmem_params(accumulators, bq, bk))
+
+    if fused:
+        return tuple(call("dkv", "bwd", ("dq", "dk", "dv"),
+                          band.visits(key_major=True), 3 * t * d * 4))
+    dk, dv = call("dkv", "dkv", ("dk", "dv"),
+                  band.visits(key_major=True, group=group), 0)
+    dq, = call("dq", "dq", ("dq",), band.visits(), 0)
+    return dq, dk, dv
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window):
     return _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
@@ -1525,23 +1845,45 @@ def _flash_band(q, k, v, scale, blocks, bwd_blocks, n_head, group, window):
 
 def _flash_band_fwd(q, k, v, scale, blocks, bwd_blocks, n_head, group,
                     window):
+    from ...observe.monitoring import runtime_stats
+
     if whole_band_forward_fits(window, *blocks):
         o, lse8 = _flash_fwd_whole_band(q, k, v, scale, blocks[0], n_head,
                                         group, window)
+    elif window:
+        o, lse8 = _flash_fwd(q, k, v, None, None, scale, True, *blocks,
+                             "nthd", n_head,
+                             _Band(q.shape[1], *blocks, window), group)
     else:
-        o, lse8 = _flash_fwd(
-            q, k, v, None, None, scale, True, *blocks, "nthd", n_head,
-            _Band(q.shape[1], *blocks, window), group)
+        # the counters count the calls traced, a layer's each, whichever
+        # of them the jitted pass is traced for
+        if group > 1:
+            runtime_stats.record_flash_grouped_call()
+        band = _Band(q.shape[1], *blocks, None)
+        band.record_visits()
+        o, lse8 = _flash_fwd_visits(
+            q, k, v, scale=scale, band=band, n_head=n_head, group=group,
+            interpret=interpret())
     o, lse8 = keep_residuals(o, lse8)
     return o, (q, k, v, o, lse8)
 
 
 def _flash_band_bwd(scale, blocks, bwd_blocks, n_head, group, window, res,
                     do):
+    from ...observe.monitoring import runtime_stats
+
     q, k, v, o, lse8 = res
     band = _Band(q.shape[1], *bwd_blocks, window)
-    dq, dk, dv = _flash_bwd_band(q, k, v, o, lse8, do, scale, band, n_head,
-                                 group)
+    if window:
+        dq, dk, dv = _flash_bwd_band(q, k, v, o, lse8, do, scale, band,
+                                     n_head, group)
+    else:
+        fused = band_backward_fits(q.shape[1], q.shape[2] // n_head)
+        runtime_stats.record_flash_backward("flash_attention", fused)
+        band.record_visits()
+        dq, dk, dv = _flash_bwd_visits(
+            q, k, v, o, lse8, do, scale=scale, band=band, n_head=n_head,
+            group=group, fused=fused, interpret=interpret())
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

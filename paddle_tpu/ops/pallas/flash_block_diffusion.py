@@ -1,5 +1,5 @@
 """Flash attention under the block-diffusion training mask (Pallas, TPU):
-`pallas_flash_attention(block_diffusion=B)`'s kernels, on a grid whose
+`pallas_flash_attention(block_diffusion=B)`'s geometry, on a grid whose
 steps are a scalar-prefetched LIST OF VISITS.
 
 The rows are a clean half and a noised half of T / 2 positions each, cut
@@ -12,21 +12,24 @@ against itself is computed whole for its diagonal of B x B blocks.  So
 the three kernels (`flash_block_diffusion_fwd`, `_dkv`, and `_dq` past
 `flash_attention.band_backward_fits`; the names are the benchmark's
 closed list) walk ONE int32 table a pass, made on the host from the
-shape when the call is traced (`_DiffusionBand.visits`): a column a
-visit, with its query tile, its key tile, FIRST / LAST of its run, the
-dq tile the output holds and a KIND that says how much of the tile to
-compute.  No option chooses a grid or a kind.  A pass is jitted on its
-shapes and its geometry, so a program's layers share one trace and one
-lowering of it (three branches a kernel, one unrolled eight times).
+shape when the call is traced (`_Band.visits` over this geometry's
+`tiles`): a column a visit, with its query tile, its key tile, FIRST /
+LAST of its run, the dq tile the output holds and a KIND that says how
+much of the tile to compute.  No option chooses a grid or a kind.
 
-The tile arithmetic is `flash_attention.py`'s (`_softmax_step`,
-`_bwd_p_ds`, `_add_dk_dv`, `_add_dq`), which also serves the plain,
-grouped and window calls of every other cell: their grids are rectangles
-with no empty run worth a table, so the shells and the `pallas_call`s
-here are this geometry's own and their steps are not touched.  The
-declared cost stays the band's (`_Band.cost_estimate` over the pairs the
-MASK allows), the VMEM rules `_vmem_params` / `_fwd_vmem_params`, the
-tile sizes `flash_attention.DEFAULT_DIFFUSION_BLOCK` / `_BWD_BLOCK`.
+The shells that walk the table, their `pallas_call`s and the jitted
+passes are `flash_attention.py`'s (`_visits_fwd_kernel`,
+`_visits_bwd_kernel`, `_visits_call`, `_flash_fwd_visits`,
+`_flash_bwd_visits`), shared since PR 63 with the band call over the
+whole causal prefix, whose rectangle left 120 of a head's 256 steps
+empty at 16384 rows; here live the geometry (which tiles, of which
+kind, under which mask; the third kind, `OWN_BLOCKS`), the counters and
+the call.  The tile arithmetic is that file's too (`_softmax_step`,
+`_bwd_p_ds`, `_add_dk_dv`, `_add_dq`), as for the plain and window
+calls, whose grids have no empty run worth a table.  The declared cost
+stays the band's (`_Band.cost_estimate` over the pairs the MASK
+allows), the VMEM rules `_vmem_params` / `_fwd_vmem_params`, the tile
+sizes `flash_attention.DEFAULT_DIFFUSION_BLOCK` / `_BWD_BLOCK`.
 """
 
 from __future__ import annotations
@@ -36,19 +39,13 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from . import flash_attention as fa, keep_residuals
+from . import flash_attention as fa, interpret, keep_residuals
 from .flash_attention import (
-    NEG_INF, _Band, _add_dk_dv, _add_dq, _bwd_p_ds, _dot, _fwd_vmem_params,
-    _init_softmax, _pallas_call, _softmax_step, _vmem_params, _write_o_lse)
+    DIAGONAL, FULL, V_KIND, _Band, _flash_bwd_visits, _flash_fwd_visits)
 
-# Rows of a visit table (scalar prefetch, a column a grid step), and
-# what a visit computes of its tile: all of it, all of it under the mask
-# by block id, or the squares on a noised tile's diagonal alone
-V_Q, V_K, V_HEAD, V_KIND, V_FIRST, V_LAST, V_DQ, V_DQ_FIRST, V_DQ_LAST = \
-    range(9)
-FULL, DIAGONAL, OWN_BLOCKS = range(3)
+# the third kind of visit: the squares on a noised tile's diagonal alone
+OWN_BLOCKS = 2
 LANES = 128
 
 
@@ -92,13 +89,7 @@ class _DiffusionBand(_Band):
             + n * self.own + n
 
     def _shape(self):       # a static argument of the jitted passes
-        return self.t, self.block_q, self.block_length, self.sub
-
-    def __eq__(self, other):
-        return self._shape() == other._shape()
-
-    def __hash__(self):
-        return hash(self._shape())
+        return type(self), self.t, self.block_q, self.block_length, self.sub
 
     def tiles(self):
         """(query tile, key tile, kind) of every tile that holds an
@@ -112,33 +103,6 @@ class _DiffusionBand(_Band):
                 found.append((qb, qb, OWN_BLOCKS if self.sub < self.block_q
                               else DIAGONAL))
         return found
-
-    def visits(self, key_major=False, group=1):
-        """The int32 (9, V) table a grid's last axis walks, from the
-        shape, on the host: query-major (the forward, `_dq`) or
-        key-major (the backward; with `group` each key tile meets its
-        query tiles once a head of the group, `V_HEAD`: the kernel that
-        holds tiles only).  `V_FIRST` / `V_LAST` bracket the run of one
-        (major tile, head), `V_DQ_FIRST` / `V_DQ_LAST` a query tile's
-        visits; `V_DQ` is the dq tile the output's index map holds, the
-        last one completed (before any is, the first to be): it moves
-        on only on the step that writes the next tile, so no half-summed
-        tile is ever what Pallas writes back."""
-        rows = sorted(
-            ((qb, kb, head, kind) for head in range(group)
-             for qb, kb, kind in self.tiles()),
-            key=lambda r: (r[1], r[2], r[0]) if key_major else r[:2])
-        q, k, head, kind = np.array(rows, np.int32).T
-        run = (k if key_major else q) * group + head
-        first = np.r_[True, run[1:] != run[:-1]]
-        at = np.arange(q.size)
-        met = [np.flatnonzero(q == qb) for qb in range(self.nq)]
-        dq_first = np.isin(at, [m[0] for m in met])
-        dq_last = np.isin(at, [m[-1] for m in met])
-        done = np.maximum.accumulate(np.where(dq_last, at, -1))
-        dq = q[np.where(done < 0, np.flatnonzero(dq_last)[0], done)]
-        return np.stack([q, k, head, kind, first, np.r_[first[1:], True],
-                         dq, dq_first, dq_last]).astype(np.int32)
 
     def record_blocks(self):
         """One traced pass, a head's: grid steps, tiles computed, tiles
@@ -178,19 +142,17 @@ class _DiffusionBand(_Band):
         return (ahead >= least) & ((kb < n) | (ahead == 0))
 
     def visit(self, visits, v, q_axis, compute):
-        """Run `compute(mask, at)` as visit `v`'s kind says: rows `at`
-        of the tile's two sides, under the masks in `mask`."""
+        """The band's two kinds, and the third where a side cuts the
+        tile: the `sub` x `sub` squares on its diagonal, one after the
+        other."""
         from jax.experimental import pallas as pl
 
-        kind, sub = visits[V_KIND, v], self.sub
-        pl.when(kind == FULL)(lambda: compute([], slice(None)))
-        pl.when(kind == DIAGONAL)(lambda: compute(
-            [self.allowed(visits[V_Q, v], visits[V_K, v], q_axis)],
-            slice(None)))
+        super().visit(visits, v, q_axis, compute)
+        sub = self.sub
         if sub == self.block_q:
             return
 
-        @pl.when(kind == OWN_BLOCKS)
+        @pl.when(visits[V_KIND, v] == OWN_BLOCKS)
         def _own_blocks():
             mask = [self._ahead(sub, q_axis) == 0]
             # unrolled: the squares share nothing, and a rolled loop
@@ -208,185 +170,6 @@ class _DiffusionBand(_Band):
                                    + blocks * (blocks + 1) // 2)
 
 
-def _diffusion_fwd_kernel(visits, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                          m_scr, l_scr, acc_scr, *, scale, band):
-    """The forward pass under the block-diffusion mask, query-major:
-    `_fwd_kernel`'s online soft-max, a visit a grid step."""
-    from jax.experimental import pallas as pl
-
-    v = pl.program_id(2)
-    pl.when(visits[V_FIRST, v] == 1)(functools.partial(
-        _init_softmax, m_scr, l_scr, acc_scr))
-
-    def _compute(mask, at):
-        s = _dot(q_ref[0, at], k_ref[0, at], ((1,), (1,))) * scale
-        for allowed in mask:
-            s = jnp.where(allowed, s, NEG_INF)
-        _softmax_step(s, lambda: v_ref[0, at], m_scr, l_scr, acc_scr, at)
-
-    band.visit(visits, v, 0, _compute)
-    pl.when(visits[V_LAST, v] == 1)(functools.partial(
-        _write_o_lse, o_ref, lse_ref, m_scr, l_scr, acc_scr))
-
-
-def _diffusion_bwd_kernel(visits, q_ref, k_ref, v_ref, do_ref, o_ref,
-                          lse_ref, *, band, group, fused, dq_ref=None,
-                          dk_ref=None, dv_ref=None, dq_acc=None, dk_acc=None,
-                          dv_acc=None, **dims):
-    """The backward pass under the block-diffusion mask: p and ds once
-    a visit and, of dq, dk and dv, the sums it was given.  `fused`
-    (`_bwd_band_kernel`'s layout, key-major): all three, whole sequences
-    in VMEM, a dq tile leaving on the visit that completes it, dk and dv
-    during the group's last head.  Past that budget a kernel holds ONE
-    tile of each: dk and dv key-major, the group's heads inside a key
-    tile's run (`V_HEAD`), and dq query-major."""
-    from jax.experimental import pallas as pl
-
-    v = pl.program_id(2)
-    gi = pl.program_id(1) + visits[V_HEAD, v]
-    qb, kb = (visits[V_Q, v], visits[V_K, v]) if fused else (0, 0)
-
-    def zero(acc, at):
-        acc[at] = jnp.zeros(acc.shape[1:], acc.dtype)
-
-    if dk_acc is not None:
-        @pl.when((gi == 0) & (visits[V_FIRST, v] == 1))
-        def _init():
-            zero(dk_acc, kb)
-            zero(dv_acc, kb)
-
-    if dq_acc is not None:
-        pl.when(visits[V_DQ_FIRST, v] == 1)(lambda: zero(dq_acc, qb))
-
-    def _compute(mask, at):
-        refs = [r.at[:, at] for r in (q_ref, k_ref, v_ref, do_ref, o_ref)]
-        q, k, do, p, ds = _bwd_p_ds(
-            *refs, lse_ref.at[:, :, at], None, None, None, kb, qb, mask=mask,
-            **dims)
-        if dk_acc is not None:
-            _add_dk_dv(p, ds, q, do, dk_acc, dv_acc, dims["scale"],
-                       at=(kb, at))
-        if dq_acc is not None:
-            _add_dq(ds, k, dq_acc, dims["scale"], at=(qb, at))
-
-    band.visit(visits, v, 1, _compute)
-
-    if dk_acc is not None:
-        @pl.when((gi == group - 1) & (visits[V_LAST, v] == 1))
-        def _finalize():
-            dk_ref[0] = dk_acc[kb].astype(dk_ref.dtype)
-            dv_ref[0] = dv_acc[kb].astype(dv_ref.dtype)
-
-    if dq_acc is not None:
-        @pl.when(visits[V_DQ_LAST, v] == 1)
-        def _finalize_dq():
-            dq_ref[0] = dq_acc[qb].astype(dq_ref.dtype)
-
-
-def _diffusion_call(kernel, name, band, table, hkv, group, outs, scratch,
-                    operands, **params):
-    """One kernel over the grid (N*Hkv, the group's heads, the visits of
-    `table`; a table with a `V_HEAD` holds the heads itself).  `outs`:
-    (what, shape) pairs, `what` a tile at the table's `V_Q` ("q"), at
-    `V_DQ` ("dq"), at `V_K` ("kv"; "kv_last": during the group's last
-    head alone, when dk and dv of the single kernel leave, tile by tile)
-    or the statistic ("stat")."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    q = operands[0]
-    b, d = band.block_q, q.shape[2] // (hkv * group)
-
-    def head(g, a, v, visits):
-        return (g % hkv) * group + a + visits[V_HEAD, v]
-
-    def q_tile(row):
-        return pl.BlockSpec((1, b, d), lambda g, a, v, visits: (
-            g // hkv, visits[row, v], head(g, a, v, visits)))
-
-    def kv_tile(last):
-        return pl.BlockSpec((1, b, d), lambda g, a, v, visits: (
-            g // hkv, jnp.where(a == group - 1, visits[V_K, v], 0) if last
-            else visits[V_K, v], g % hkv))
-
-    spec = {"q": q_tile(V_Q), "dq": q_tile(V_DQ), "kv": kv_tile(False),
-            "kv_last": kv_tile(True),
-            "stat": pl.BlockSpec((1, 8, b), lambda g, a, v, visits: (
-                g * group + a + visits[V_HEAD, v], 0, visits[V_Q, v]))}
-    ins = ["q", "kv", "kv", "q", "q", "stat"][:len(operands)]
-    return _pallas_call(
-        kernel, name=band.prefix + name,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(q.shape[0] * hkv, group // (int(table[V_HEAD].max()) + 1),
-                  table.shape[1]),
-            in_specs=[spec[what] for what in ins],
-            out_specs=[spec[what] for what, _ in outs],
-            scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
-                            for shape in scratch]),
-        out_shape=[shape for _, shape in outs], **params,
-    )(table, *operands)
-
-
-# (jitted, as `grouped_matmul.py`'s kernels are: a program's layers
-# share their shapes, so a pass is traced and lowered once a shape and
-# called from every layer)
-@functools.partial(jax.jit, static_argnames=("scale", "band", "n_head",
-                                             "group"))
-def _flash_fwd_diffusion(q, k, v, *, scale, band, n_head, group):
-    n, t, hd = q.shape
-    b, d = band.block_q, hd // n_head
-    return _diffusion_call(
-        functools.partial(_diffusion_fwd_kernel, scale=scale, band=band),
-        "fwd", band, band.visits(), n_head // group, group,
-        [("q", jax.ShapeDtypeStruct(q.shape, q.dtype)),
-         ("stat", jax.ShapeDtypeStruct((n * n_head, 8, t), jnp.float32))],
-        [(b, 1), (b, 1), (b, d)], (q, k, v),
-        **band.cost_estimate("fwd", n * n_head, d, q.dtype.itemsize, group),
-        **_fwd_vmem_params(b, b, d, q.dtype.itemsize))
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "band", "n_head",
-                                             "group", "fused"))
-def _flash_bwd_diffusion(q, k, v, o, lse8, do, *, scale, band, n_head, group,
-                         fused):
-    """(dq, dk, dv) under the block-diffusion mask: one kernel where
-    `fused` (`band_backward_fits`), `_dkv` and `_dq` that hold tiles
-    only beyond; every term from `_bwd_p_ds`, added in the same order."""
-    n, t, hd = q.shape
-    b, d = band.block_q, hd // n_head
-    dk_shape = jax.ShapeDtypeStruct(k.shape, q.dtype)
-    shape = {"dq": jax.ShapeDtypeStruct(q.shape, q.dtype), "dk": dk_shape,
-             "dv": dk_shape}
-
-    def call(name, cost, parts, table, accumulators):
-        names = [part + kind for kind in ("_ref", "_acc") for part in parts]
-
-        def kern(visits, *refs):
-            _diffusion_bwd_kernel(
-                visits, *refs[:6], band=band, group=group, fused=fused,
-                scale=scale, causal=False, block_q=b, block_k=b, t_q=t,
-                t_k=t, **dict(zip(names, refs[6:])))
-
-        return _diffusion_call(
-            kern, name, band, table, n_head // group, group,
-            [("dq" if part == "dq" else "kv_last" if fused else "kv",
-              shape[part]) for part in parts],
-            [(band.nq if fused else 1, b, d)] * len(parts),
-            (q, k, v, do, o, lse8),
-            **band.cost_estimate(cost, n * n_head, d, q.dtype.itemsize,
-                                 group),
-            **_vmem_params(accumulators, b, b))
-
-    if fused:
-        return tuple(call("dkv", "bwd", ("dq", "dk", "dv"),
-                          band.visits(key_major=True), 3 * t * d * 4))
-    dk, dv = call("dkv", "dkv", ("dk", "dv"),
-                  band.visits(key_major=True, group=group), 0)
-    dq, = call("dq", "dq", ("dq",), band.visits(), 0)
-    return dq, dk, dv
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, scale, tiles, n_head, group, length):
     return _flash_fwd(q, k, v, scale, tiles, n_head, group, length)[0]
@@ -397,8 +180,9 @@ def _flash_fwd(q, k, v, scale, tiles, n_head, group, length):
     # them the jitted pass is traced for
     band = _DiffusionBand(q.shape[1], tiles[0], length)
     band.record_blocks()
-    o, lse8 = keep_residuals(*_flash_fwd_diffusion(
-        q, k, v, scale=scale, band=band, n_head=n_head, group=group))
+    o, lse8 = keep_residuals(*_flash_fwd_visits(
+        q, k, v, scale=scale, band=band, n_head=n_head, group=group,
+        interpret=interpret()))
     return o, (q, k, v, o, lse8)
 
 
@@ -410,9 +194,9 @@ def _flash_bwd(scale, tiles, n_head, group, length, res, do):
     runtime_stats.record_flash_backward("flash_attention", fused)
     band = _DiffusionBand(q.shape[1], tiles[1], length)
     band.record_blocks()
-    dq, dk, dv = _flash_bwd_diffusion(
+    dq, dk, dv = _flash_bwd_visits(
         q, k, v, o, lse8, do, scale=scale, band=band, n_head=n_head,
-        group=group, fused=fused)
+        group=group, fused=fused, interpret=interpret())
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

@@ -147,6 +147,24 @@ def test_the_window_cells_backward_pass_is_one_kernel(one_chip, window,
     prefix = "flash_window_" if window else "flash_"
     assert kernels == [(prefix + "dkv", 3), (prefix + "fwd", 2)]
     assert took == (1, 0)
+    if window:
+        return
+    # over the whole prefix the kernels walk a table of visits (PR 63),
+    # the custom calls' first operand: the registry still reads q, k, v
+    # and the logsumexp behind it, the dense-equivalent work of the
+    # plain names
+    from paddle_tpu.observe import cost
+
+    compiled, _ = _compiled(one_chip, 1, 16384, 32, dtype, kv_heads=4)
+    rows = {r["kernel"]: r for r in cost.instruction_costs(
+        cost.compiled_hlo_proto(compiled)) if r["kernel"]}
+    scores = 32 * 16384 * 16384
+    assert rows["flash_fwd"]["flops"] == scores * (4 * D + 8)
+    assert rows["flash_dkv"]["flops"] == scores * (8 * D + 8)
+    size = dtype.dtype.itemsize
+    assert rows["flash_fwd"]["bytes"] == 16384 * (
+        D * size * 2 * (32 + 4) + 32 * 8 * 4)
+    assert "s32[9,136]" in compiled.as_text()
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32_highest"])
@@ -388,8 +406,16 @@ STEP_TEXT = {
     # whole band, here through the interpreter (parents: 0fa427d2..,
     # 71766418.., 8262a62f..); the ten other cells trace no window
     # kernel and keep their text
+    # Re-pinned, PR 63, with `qwen3next-16k`, `laguna-16k`,
+    # `phi4flash-8k` and `sdar-8k` below: a band call over the whole
+    # causal prefix walks a scalar-prefetched list of visits, a jitted
+    # pass the full layers share, here through the interpreter (parents:
+    # 7c286e0c.., 3587790b.., 562da214.., b4728adc..); `sdar-8k` walks
+    # the same shells under their new names (`_flash_fwd_visits`,
+    # `_flash_bwd_visits`; parent: 691f6604..).  The eight other cells
+    # trace no such call and keep their text
     "mellum2-16k":
-    "7c286e0cf17ce068d5d6756cdb0e8442e032e8e5ecba9cf4094be70573bc741e",
+    "70ec8bb9b8c86d063d442bbee56679d63044540b4d36ec8675ba95d0d2f3cfb6",
     # re-pinned, PR 52: (I + A)^-1 is `gated_delta_inverse`'s, named for
     # the layers' segments to keep, and `gated_delta_operands_fwd` reads
     # it (parent: f591949a..); no other cell builds the op, and a name
@@ -400,7 +426,7 @@ STEP_TEXT = {
     # `flash_dq` (parent: cdf57c01..); the eleven other cells' calls
     # were inside the old budget and keep their text
     "qwen3next-16k":
-    "3587790bb726103f00f3b4dbc0bb79a162f162c72474ab4d3e0ae024b583bba6",
+    "9df7a66ef15df504b925b32bd386d11d9d203e467d5f508e39f8031ac7990420",
     # re-pinned, PR 59: its block-diffusion flash kernels walk a
     # scalar-prefetched list of visits (`ops/pallas/
     # flash_block_diffusion.py`, here through the interpreter, a pass
@@ -408,20 +434,20 @@ STEP_TEXT = {
     # b012ad2d..); the eleven other cells keep their text: the band and
     # plain kernels' bodies trace the same equations in the same order
     "sdar-8k":
-    "691f66043cc39cad12659fa8a365e6f998a923580e7a4fea1d0302a3dac2f57e",
+    "af7a5ef6943bc8b15e626e9457d781d6b69cc71403dac83d046184ce45a5ce35",
     # new in PR 51 (a head count a layer type, the head gate, YaRN over
     # half a head, 512 x 512 forward tiles under 512 keys); every other
     # cell keeps its parent's text: `mellum2-16k`'s window of 1024 keeps
     # its 1024 x 1024 forward tile
     "laguna-16k":
-    "562da214d669931fd3a4184ded037df76bb622256170e0d01a054ce191e87e66",
+    "b8675525a43fd1d91de22eeebe4ff38529017f56c7f87faa3c50ded1d643985b",
     # new in PR 53 (the selective scan and the biased convolution
     # through the interpreter, differential attention on the band and
     # grouped kernels, values that cross recompute segments); every
     # other cell keeps its parent's text: a bias that is absent and a
     # name in `KEPT_RESIDUALS` that a step never emits leave it alone
     "phi4flash-8k":
-    "b4728adc7b753bd1160ac65f0f1f1515a97251cb2ff4d3ddd628d8232e03b8d9",
+    "e1f37d39bd46c61613919b9ebbf19e7b476e8bb849ca43a062058ac03b61863d",
     # new in PR 58 (the scalar-a-head scan through the interpreter, the
     # gated norm, one biased convolution over x, B and C, grouped flash
     # attention under a scale of 2^-6, the four multipliers); every

@@ -199,24 +199,26 @@ def _head_major(x):
     return jnp.moveaxis(x, 1, 2).reshape(n, t, h * d)
 
 
-def _flash_grads(q, k, v, w, layout, causal, block_q, block_k):
-    """dq, dk, dv of sum(o * w) through the kernels, as (N, H, T, D)
-    whatever the layout the kernels saw."""
+def _flash_pull(q, k, v, w, layout, causal, block_q, block_k):
+    """The kernels' forward pass, run once, and what gives dq, dk, dv of
+    sum(o * w) through them, as (N, H, T, D) whatever the layout the
+    kernels saw: the backward rule is traced whenever it is called, so
+    one forward serves both backward paths."""
     import paddle_tpu.ops.pallas.flash_attention as fa
 
     n, h, t, d = q.shape
     if layout == "nthd":
         q, k, v, w = (_head_major(x) for x in (q, k, v, w))
+    _, pull = jax.vjp(lambda q, k, v: fa.pallas_flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        layout=layout, n_head=h if layout == "nthd" else None), q, k, v)
 
-    def loss(q, k, v):
-        o = fa.pallas_flash_attention(
-            q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-            layout=layout, n_head=h if layout == "nthd" else None)
-        return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
+    def grads():
+        got = pull(w)
+        if layout == "nthd":
+            got = [jnp.moveaxis(g.reshape(n, t, h, d), 2, 1) for g in got]
+        return got
 
-    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    if layout == "nthd":
-        grads = [jnp.moveaxis(g.reshape(n, t, h, d), 2, 1) for g in grads]
     return grads
 
 
@@ -226,22 +228,44 @@ BLOCK_IDS = ["1_block", "2_blocks", "4_blocks", "wide_k", "wide_q",
              "4_blocks_wide_k"]
 
 
+def _operands(blocks, block_q, block_k):
+    """q, k, v and the weight of one geometry in float32: one sequence,
+    two heads of 128, T of `blocks` of the larger block."""
+    t = blocks * max(block_q, block_k)
+    return _qkvw(1, 2, t, 128, seed=blocks + block_k)
+
+
+@functools.cache
+def _forward(blocks, block_q, block_k, causal, layout, dtype):
+    return _flash_pull(*(x.astype(dtype)
+                         for x in _operands(blocks, block_q, block_k)),
+                       layout, causal, block_q, block_k)
+
+
 @functools.cache
 def _path_grads(path, blocks, block_q, block_k, causal, layout, dtype):
-    """q, k, v and the weight of one geometry (one sequence, two heads
-    of 128, T of `blocks` of the larger block) in float32, and dq, dk,
-    dv through the kernels on `path` with the operands in `dtype`,
-    which the counters must say the traced backward took.  Once a
-    module: the two tests below read the same calls."""
-    t = blocks * max(block_q, block_k)
-    q, k, v, w = _qkvw(1, 2, t, 128, seed=blocks + block_k)
+    """dq, dk, dv of one geometry through the kernels on `path` with the
+    operands in `dtype`, which the counters must say the traced
+    backward took.  Once a module: the two tests below read the same
+    calls, and both paths the same forward pass."""
+    grads = _forward(blocks, block_q, block_k, causal, layout, dtype)
     with pytest.MonkeyPatch.context() as patch:
         _backward_path(patch, path)
         before = _snapshot()
-        got = _flash_grads(*(x.astype(dtype) for x in (q, k, v, w)), layout,
-                           causal, block_q, block_k)
+        got = grads()
         assert _took(before) == ((1, 0) if path == "one_kernel" else (0, 1))
-    return (q, k, v, w), got
+    return got
+
+
+@functools.cache
+def _reference_grads(blocks, block_q, block_k, causal):
+    """dq, dk, dv of the dense composition on `_path_grads`' float32
+    operands of one geometry: once a module, whatever the path, the
+    layout and the kernels' dtype."""
+    q, k, v, w = _operands(blocks, block_q, block_k)
+    return jax.grad(
+        lambda *a: jnp.sum(_ref_attention(*a, causal=causal) * w),
+        argnums=(0, 1, 2))(q, k, v)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -258,11 +282,8 @@ def test_both_backward_paths_give_the_reference_gradients(
     diagonal's corner, and a pass over the query blocks may complete
     two or none), causal and not, in both operand layouts; the counters
     say which path a traced backward took."""
-    (q, k, v, w), got = _path_grads(path, blocks, block_q, block_k, causal,
-                                    layout, dtype)
-    want = jax.grad(
-        lambda *a: jnp.sum(_ref_attention(*a, causal=causal) * w),
-        argnums=(0, 1, 2))(q, k, v)
+    got = _path_grads(path, blocks, block_q, block_k, causal, layout, dtype)
+    want = _reference_grads(blocks, block_q, block_k, causal)
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape and g.dtype == dtype, name
         if dtype == jnp.float32:    # this file's limits for a gradient
@@ -288,10 +309,10 @@ def test_the_two_backward_paths_agree_to_the_bit(blocks, block_q, block_k,
     """Same terms in the same order: the single kernel sums dk / dv
     over the query blocks and dq over the key blocks as the two do, from
     the same p and ds."""
-    _, one = _path_grads("one_kernel", blocks, block_q, block_k, causal,
-                         layout, dtype)
-    _, two = _path_grads("two_kernels", blocks, block_q, block_k, causal,
-                         layout, dtype)
+    one = _path_grads("one_kernel", blocks, block_q, block_k, causal, layout,
+                      dtype)
+    two = _path_grads("two_kernels", blocks, block_q, block_k, causal, layout,
+                      dtype)
     for a, b in zip(one, two):
         np.testing.assert_array_equal(a, b)
 

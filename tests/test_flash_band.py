@@ -5,10 +5,13 @@ against the XLA composition under an EXPLICIT mask: forward and all
 three gradients; the one-kernel and the two-kernel backward to the bit;
 the window forward's two paths (a query tile against its whole band,
 the soft-max in one pass; the online soft-max over the band's key
-tiles) and what chooses between them; k, v, dk, dv never repeated; what
-the band kernels do not take raises.
+tiles) and what chooses between them; the call without a window on its
+list of visits (PR 63) against the rectangle grids it left, to the bit,
+and the table itself on the host; k, v, dk, dv never repeated; what the
+band kernels do not take raises.
 """
 
+import functools
 import itertools
 
 import numpy as np
@@ -62,6 +65,16 @@ def _grads(fn, q, k, v, w):
         (0, 1, 2))(q, k, v)
 
 
+@functools.cache
+def _dense_grads(window, hkv, dtype):
+    """o and (dq, dk, dv) against the weight of the masked composition
+    in float32, on `_qkvw(hkv, seed=3, dtype=dtype)`: once a module,
+    whatever the tiles."""
+    q, k, v, w = (x.astype(F32) for x in _qkvw(hkv, seed=3, dtype=dtype))
+    out, pull = jax.vjp(lambda *a: _dense(*a, hkv, window), q, k, v)
+    return out, pull(w)
+
+
 # under a block, one block, one and a half, and every key (no window)
 WINDOWS = [None, 5, BLOCK, 24, T, T + 9]
 SQUARE, WIDE_K, WIDE_Q = (16, 16), (16, 32), (32, 16)
@@ -95,20 +108,15 @@ def test_band_kernels_match_the_masked_composition(window, hkv, blocks,
     q, k, v, w = _qkvw(hkv, seed=3, dtype=dtype)
     kw = dict(block_q=blocks[0], block_k=blocks[1])
     before = runtime_stats.snapshot()
-    out, got = _grads(lambda *a: _flash(*a, hkv, window, **kw), q, k, v, w)
+    # (the forward once: a backward rule runs whenever `pull` is called)
+    out, pull = jax.vjp(lambda *a: _flash(*a, hkv, window, **kw), q, k, v)
+    got = pull(w)
     took = runtime_stats.delta(before)
-    f32 = [x.astype(F32) for x in (q, k, v, w)]
-    want_out, want = _grads(lambda *a: _dense(*a, hkv, window), *f32[:3],
-                            f32[3])
+    want_out, want = _dense_grads(window, hkv, dtype)
     # bfloat16: p rounded to 8 bits before each product, as o and the
     # gradients are
     tol = 2e-5 if dtype == F32 else 6e-2
-    if dtype == F32:
-        np.testing.assert_allclose(out, want_out, rtol=tol, atol=tol)
-    else:       # a sum of 1024 rounded products: o itself, by the element
-        np.testing.assert_allclose(
-            _flash(q, k, v, hkv, window, **kw).astype(F32),
-            _dense(*f32[:3], hkv, window), rtol=tol, atol=tol)
+    np.testing.assert_allclose(out.astype(F32), want_out, rtol=tol, atol=tol)
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape           # dk, dv: key/value heads wide
         assert g.dtype == dtype
@@ -122,10 +130,176 @@ def test_band_kernels_match_the_masked_composition(window, hkv, blocks,
         (0, 0) if not windowed else (1, 0) if blocks == SQUARE else (0, 1))
     monkeypatch.setattr(fa, "FUSED_ACCUMULATOR_BUDGET", 0)
     before = runtime_stats.snapshot()
-    _, split = _grads(lambda *a: _flash(*a, hkv, window, **kw), q, k, v, w)
+    split = pull(w)
     assert runtime_stats.delta(before)["flash_attention_backward_split"] == 1
     for g, s in zip(got, split):
         np.testing.assert_array_equal(g.astype(F32), s.astype(F32))
+
+
+# (key/value heads, tiles, rows, dtype): groups of 1, 2 and 8 query
+# heads; 2, 3 and 4 tiles a side, square and not
+RECTANGLE_CASES = [
+    (H, SQUARE, 32, F32), (H // 2, SQUARE, 48, BF16), (H // 8, SQUARE, 64, F32),
+    (H // 8, WIDE_K, 64, BF16), (H // 2, WIDE_Q, 64, F32)]
+
+
+@pytest.mark.parametrize(
+    "hkv, blocks, t, dtype", RECTANGLE_CASES,
+    ids=[f"group{H // c[0]}-{c[1][0]}x{c[1][1]}-t{c[2]}-"
+         f"{jnp.dtype(c[3]).name}" for c in RECTANGLE_CASES])
+def test_the_list_of_visits_gives_the_rectangles_bits(hkv, blocks, t, dtype,
+                                                      monkeypatch):
+    """The call over the whole causal prefix walks a list of visits, its
+    tiles under the diagonal with no mask (PR 63); the rectangle grids
+    it left (query tiles x the longest run, every tile masked: they
+    still serve a window) give the same o, logsumexp, dq, dk and dv to
+    the bit, by the single backward kernel and by the two.  The scale
+    is a power of two: the CPU's compiler, which runs the interpreter's
+    steps, contracts `s * scale - m` where no select stands between the
+    two, and only such a product rounds the same either way."""
+    q, k, v, do = (x[:1] for x in _qkvw(hkv, seed=13, t=t, dtype=dtype))
+    group, scale = H // hkv, 0.25
+    band = fa._Band(t, *blocks, None)
+    o, lse = fa._flash_fwd(q, k, v, None, None, scale, True, *blocks, "nthd",
+                           H, band, group)
+    before = runtime_stats.snapshot()
+    res = fa._flash_band_fwd(q, k, v, scale, blocks, blocks, H, group,
+                             None)[1]
+    took = runtime_stats.delta(before)
+    steps = len(band.tiles())
+    assert took["flash_prefix_grid_steps"] == steps == band.blocks_allowed
+    assert took["flash_prefix_visits_full"] \
+        + took["flash_prefix_visits_diagonal"] == steps
+    assert took["flash_grouped_calls"] == (group > 1)
+    np.testing.assert_array_equal(res[3].astype(F32), o.astype(F32))
+    np.testing.assert_array_equal(res[4], lse)
+    for budget, fused in ((fa.FUSED_ACCUMULATOR_BUDGET, 1), (0, 0)):
+        monkeypatch.setattr(fa, "FUSED_ACCUMULATOR_BUDGET", budget)
+        want = fa._flash_bwd_band(q, k, v, o, lse, do, scale, band, H, group)
+        before = runtime_stats.snapshot()
+        got = fa._flash_band_bwd(scale, blocks, blocks, H, group, None, res,
+                                 do)
+        took = runtime_stats.delta(before)
+        assert (took["flash_attention_backward_fused"],
+                took["flash_attention_backward_split"]) == (fused, 1 - fused)
+        assert took["flash_prefix_grid_steps"] == steps
+        for name, g, w in zip("qkv", got, want):
+            assert g.dtype == dtype and bool(jnp.any(g != 0)), name
+            np.testing.assert_array_equal(g.astype(F32), w.astype(F32),
+                                          err_msg="d" + name)
+
+
+ORDERS = {"query-major": {}, "key-major": {"key_major": True},
+          "key-major-heads": {"key_major": True, "group": 2}}
+# (rows, query tile, key tile, window): the causal prefix in 2, 3 and 4
+# tiles, square and not; and under a window, whose tiles the same
+# method lists (no kernel walks them: a window's grids have no empty run)
+GEOMETRIES = [(32, 16, 16, None), (48, 16, 16, None), (64, 16, 16, None),
+              (64, 16, 32, None), (64, 32, 16, None), (64, 16, 16, 24),
+              (64, 16, 16, 5), (64, 32, 16, 33)]
+
+
+@pytest.mark.parametrize("order", sorted(ORDERS))
+@pytest.mark.parametrize("t, bq, bk, window", GEOMETRIES)
+def test_the_table_of_visits_is_the_mask_written_out(t, bq, bk, window,
+                                                     order):
+    """No kernel: every tile that holds an allowed pair is visited
+    exactly once (a head), `FULL` exactly where the mask leaves the tile
+    whole (square tiles without a window: the key tile before the query
+    tile's), a major tile's visits in a row with FIRST / LAST around
+    them, and the dq tile an output holds is complete or being
+    completed: never half-summed."""
+    band = fa._Band(t, bq, bk, window)
+    kw = ORDERS[order]
+    group, key_major = kw.get("group", 1), kw.get("key_major", False)
+    table = band.visits(**kw)
+    assert table.dtype == np.int32 and table.shape[0] == 9
+    q, k, head, kind, first, last, dq, dq_first, dq_last = table
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = (j <= i) & (True if window is None else j > i - window)
+    tiles = seen.reshape(t // bq, bq, t // bk, bk)
+    holds = set(zip(*np.nonzero(tiles.any(axis=(1, 3)))))
+    whole = set(zip(*np.nonzero(tiles.all(axis=(1, 3)))))
+    for gi in range(group):
+        mine = list(zip(q[head == gi], k[head == gi]))
+        assert len(mine) == len(set(mine)) == band.blocks_allowed
+        assert set(mine) == holds
+    assert {(a, b) for a, b, c in zip(q, k, kind) if c == fa.FULL} == whole
+    assert set(kind) <= {fa.FULL, fa.DIAGONAL}
+    if window is None and bq == bk:
+        assert all((c == fa.FULL) == (b < a) for a, b, c in zip(q, k, kind))
+    # the mask of a tile the diagonal or the window's edge crosses, by
+    # position, in both orientations
+    for a, b in sorted(holds - whole)[:3]:
+        np.testing.assert_array_equal(band.allowed(a, b, 0), tiles[a, :, b])
+        np.testing.assert_array_equal(band.allowed(a, b, 1), tiles[a, :, b].T)
+    assert_runs_and_dq_tiles(table, band.nq, group, key_major)
+
+
+def assert_runs_and_dq_tiles(table, nq, group, key_major):
+    """What every table of visits holds, whatever its geometry
+    (tests/test_flash_block_diffusion.py reads this too): a major tile's
+    visits in a row, a head after a head inside a key tile's, the other
+    side ascending, FIRST / LAST around each run; a query tile's first
+    and last visit; and the dq tile an output's index map holds is
+    complete or being completed."""
+    q, k, head, _, first, last, dq, dq_first, dq_last = table
+    major, minor = (k, q) if key_major else (q, k)
+    keys = list(zip(major, head, minor))
+    assert keys == sorted(keys)
+    run = list(zip(major, head))
+    for v in range(len(run)):
+        assert first[v] == (v == 0 or run[v] != run[v - 1])
+        assert last[v] == (v == len(run) - 1 or run[v] != run[v + 1])
+    if group > 1:
+        return
+    for qb in range(nq):
+        met = np.flatnonzero(q == qb)
+        assert list(np.flatnonzero(dq_first & (q == qb))) == [met[0]]
+        assert list(np.flatnonzero(dq_last & (q == qb))) == [met[-1]]
+    for v in range(len(q)):
+        # written by now: it may leave whenever the index moves on
+        assert np.flatnonzero(dq_last & (q == dq[v]))[0] <= max(
+            v, np.flatnonzero(dq_last)[0])
+        if v and dq[v] != dq[v - 1]:
+            assert dq_last[v] and q[v] == dq[v]
+
+
+@pytest.mark.parametrize("t, heads, visits, full", [
+    (16384, (48, 8), 136, 120), (16384, (32, 4), 136, 120),
+    (8192, (40, 20), 36, 28)])
+def test_the_counters_say_what_a_call_over_the_whole_prefix_walks(
+        t, heads, visits, full):
+    """`laguna-16k`'s, `mellum2-16k`'s and `phi4flash-8k`'s full layers
+    in the kernels' own 1024 x 1024 tiles, traced and not run: a grid
+    step a tile that holds a score (the rectangle took 256 for 136, 64
+    for 36), 88 % and 78 % of them computed with no mask; forward and
+    backward walk the same tiles."""
+    h, hkv = heads
+    q, k, v = (jax.ShapeDtypeStruct((1, t, n * 128), BF16)
+               for n in (h, hkv, hkv))
+    assert fa._band_blocks(t, None, None, None) == ((1024, 1024),) * 2
+    band = fa._Band(t, 1024, 1024, None)
+    assert (band.nq * band.k_steps, band.blocks_allowed) == (
+        (t // 1024) ** 2, visits)
+    for kw in ORDERS.values():
+        table = band.visits(**kw)
+        assert table.shape == (9, visits * kw.get("group", 1))
+        assert tuple(np.bincount(table[fa.V_KIND])) == tuple(
+            n * kw.get("group", 1) for n in (full, visits - full))
+    before = runtime_stats.snapshot()
+    jax.eval_shape(jax.grad(lambda *a: jnp.sum(fa.pallas_flash_attention(
+        *a, None, None, True, layout="nthd", n_head=h,
+        n_kv_head=hkv).astype(F32)), (0, 1, 2)), q, k, v)
+    took = runtime_stats.delta(before)
+    assert took["flash_grouped_calls"] == 1
+    assert took["flash_attention_backward_fused"] == 1
+    steps = took["flash_prefix_grid_steps"]
+    tiles = took["flash_prefix_visits_full"] \
+        + took["flash_prefix_visits_diagonal"]
+    assert steps == tiles == 2 * visits         # steps over tiles: 1.0
+    assert took["flash_prefix_visits_full"] == 2 * full
+    assert round(100 * full / visits) == (88 if t == 16384 else 78)
 
 
 @pytest.mark.parametrize("window, hkv, dtype", [

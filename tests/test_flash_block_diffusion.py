@@ -102,12 +102,15 @@ def test_the_kernels_match_the_mask_written_out(geometry, hkv, monkeypatch):
     q, k, v, w = _qkvw(2 * length, hkv, seed=3)
     kw = dict(block_q=tile, block_k=tile)
     before = runtime_stats.snapshot()
-    out, got = _grads(lambda *a: _flash(*a, hkv, block_length, **kw),
-                      q, k, v, w)
+    # (the forward once: a backward rule runs whenever `pull` is called)
+    out, pull = jax.vjp(lambda *a: _flash(*a, hkv, block_length, **kw),
+                        q, k, v)
+    got = pull(w)
     took = runtime_stats.delta(before)
     want_out, want = _grads(lambda *a: _dense(*a, hkv, block_length),
                             q, k, v, w)
-    np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(jnp.sum(w * out), want_out, rtol=2e-5,
+                               atol=2e-5)
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape           # dk, dv: key/value heads wide
         np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5,
@@ -121,8 +124,7 @@ def test_the_kernels_match_the_mask_written_out(geometry, hkv, monkeypatch):
     assert took["flash_window_blocks_visited"] == 0
     monkeypatch.setattr(fa, "FUSED_ACCUMULATOR_BUDGET", 0)
     before = runtime_stats.snapshot()
-    _, split = _grads(lambda *a: _flash(*a, hkv, block_length, **kw),
-                      q, k, v, w)
+    split = pull(w)
     assert runtime_stats.delta(before)["flash_attention_backward_split"] == 1
     for g, s in zip(got, split):
         np.testing.assert_array_equal(g, s)
@@ -224,29 +226,11 @@ def test_the_table_is_the_mask_written_out(geometry, order, monkeypatch):
     assert set(kind) <= {fbd.FULL, fbd.DIAGONAL, fbd.OWN_BLOCKS}
     assert all(a == b >= band.n for a, b, c in zip(q, k, kind)
                if c == fbd.OWN_BLOCKS)
-    # the runs: a major tile's visits in a row, a head after a head
-    # inside a key tile's, the other side ascending
-    major, minor = (k, q) if key_major else (q, k)
-    keys = list(zip(major, head, minor))
-    assert keys == sorted(keys)
-    run = list(zip(major, head))
-    for v in range(len(run)):
-        assert first[v] == (v == 0 or run[v] != run[v - 1])
-        assert last[v] == (v == len(run) - 1 or run[v] != run[v + 1])
-    # a query tile's visits, and what the dq output's index map holds
-    if group == 1:
-        for qb in range(band.nq):
-            met = np.flatnonzero(q == qb)
-            assert list(np.flatnonzero(dq_first & (q == qb))) == [met[0]]
-            assert list(np.flatnonzero(dq_last & (q == qb))) == [met[-1]]
-        for v in range(len(q)):
-            # written by now: it may leave whenever the index moves on
-            assert np.flatnonzero(dq_last & (q == dq[v]))[0] <= max(
-                v, np.flatnonzero(dq_last)[0])
-            if v and dq[v] != dq[v - 1]:
-                assert dq_last[v] and q[v] == dq[v]
-        if key_major:
-            assert list(dq) == list(k)      # `dq_time`'s answer
+    from test_flash_band import assert_runs_and_dq_tiles
+
+    assert_runs_and_dq_tiles(table, band.nq, group, key_major)
+    if group == 1 and key_major:
+        assert list(dq) == list(k)          # `dq_time`'s answer
 
 
 @pytest.mark.parametrize("tile, visits, kinds", [
