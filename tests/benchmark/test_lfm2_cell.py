@@ -216,7 +216,10 @@ def test_new_readers_match_benchmark_json_and_read_none_without_a_trace():
     # every all-cell reader is the cell's too
     readers = bench_run.layer_readers("lfm2-8k", (BENCH,))
     everywhere = {m["name"] for m in bj["per_layer"] if "workloads" not in m}
-    assert set(readers) == everywhere | set(NEW_READERS)
+    assert everywhere | set(NEW_READERS) <= set(readers)
+    # a later PR may add a reader for this cell: it names the cell
+    for name in set(readers) - everywhere - set(NEW_READERS):
+        assert "lfm2-8k" in readers[name].META["cells"]
 
 
 def rows_fixture():

@@ -112,7 +112,10 @@ def test_configuration_holds_the_published_numbers_and_exactly_its_cuts():
     assert (t["learning_rate"], t["beta1"], t["beta2"], t["epsilon"],
             t["weight_decay"], t["warmup_steps"], t["clip_norm"],
             t["aux_loss_weight"], t["recompute"], t["use_amp"]) == (
-        4e-4, 0.9, 0.95, 1e-8, 0.1, 2000, 1.0, 0.0, "layer", True)
+        2e-5, 0.9, 0.95, 1e-8, 0.1, 2000, 1.0, 0.0, "layer", True)
+    # a continued pre-training's peak rate, as sdar-30b-a3b states it: at
+    # OLMoE's 4e-4 the routing drifts inside a run and the tail follows
+    # the seed (PERF.md section 2, PR 64)
     assert {"qk_norm", "router", "window edge", "rope", "prediction module",
             "unread keys", "router update", "weights", "training",
             "sequence_length", "recomputation"} <= set(config["assumed"])
@@ -282,7 +285,10 @@ def test_new_readers_match_benchmark_json_and_read_none_without_a_trace():
     readers = bench_run.layer_readers("mellum2-16k", (BENCH,))
     everywhere = {m["name"] for m in benchmark_json()["per_layer"]
                   if "workloads" not in m}
-    assert set(readers) == everywhere | set(NEW_READERS)
+    assert everywhere | set(NEW_READERS) <= set(readers)
+    # a later PR may add a reader for this cell: it names the cell
+    for name in set(readers) - everywhere - set(NEW_READERS):
+        assert "mellum2-16k" in readers[name].META["cells"]
     assert not set(NEW_READERS) & set(
         bench_run.layer_readers("ouro-4k", (BENCH,)))
 

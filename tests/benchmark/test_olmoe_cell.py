@@ -78,8 +78,12 @@ def test_cell_is_the_issues_and_joins_tokens_per_s():
                               4, 4096, "host", 8)
     bj = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     tokens = [m for m in bj["end_to_end"] if m["name"] == "tokens_per_s"][0]
-    assert tokens["workloads"][-1] == "olmoe-4k"
-    assert [w["name"] for w in bj["workloads"]][-1] == "olmoe-4k"
+    # looked up by name: every later PR appends to both lists
+    assert "olmoe-4k" in tokens["workloads"]
+    entry = [w for w in bj["workloads"] if w["name"] == "olmoe-4k"]
+    assert len(entry) == 1
+    assert (entry[0]["config"], entry[0]["traffic"], entry[0]["chips"]) == (
+        cell["config"], cell["traffic"], cell["chips"])
     assert family.units(config, cell) == {
         "tokens_per_s": {"per_step": 16384, "unit": "tokens/s"}}
 
@@ -131,7 +135,7 @@ def test_make_batch_is_shifted_by_one_and_seeded():
 def test_new_readers_match_benchmark_json_and_read_none_without_a_trace():
     bj = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     listed = {m["name"]: m for m in bj["per_layer"]}
-    assert list(listed)[-len(NEW_READERS):] == list(NEW_READERS)
+    assert set(NEW_READERS) <= set(listed)
     cell, config, _ = real()
     no_trace = {"cell": cell, "config": config, "trace": None, "steps": 5}
     for name, cells in NEW_READERS.items():

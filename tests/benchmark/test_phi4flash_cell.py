@@ -287,7 +287,10 @@ def test_new_readers_match_benchmark_json_and_read_none_without_a_trace():
     readers = bench_run.layer_readers("phi4flash-8k", (BENCH,))
     everywhere = {m["name"] for m in benchmark_json()["per_layer"]
                   if "workloads" not in m}
-    assert set(readers) == everywhere | set(NEW_READERS)
+    assert everywhere | set(NEW_READERS) <= set(readers)
+    # a later PR may add a reader for this cell: it names the cell
+    for name in set(readers) - everywhere - set(NEW_READERS):
+        assert "phi4flash-8k" in readers[name].META["cells"]
     assert not set(NEW_READERS) & set(
         bench_run.layer_readers("laguna-16k", (BENCH,)))
 
