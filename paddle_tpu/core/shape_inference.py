@@ -45,12 +45,13 @@ def _decode_shape(shape):
 # declares its output likewise, and its kernels are neither traced nor
 # counted (`runtime_stats.short_convs_*`) by a Program build; `rope`'s
 # likewise (`runtime_stats.ropes_*`), and `selective_scan`'s, `ssd_scan`'s
-# and `gated_rms_norm`'s (their counters count traces of a step).
+# and `gated_rms_norm`'s (their counters count traces of a step), and
+# `channel_delta_rule`'s (`runtime_stats.channel_delta_*`).
 _SKIP_INFERENCE = {
     "backward_marker", "py_func", "print",
     "create_array", "array_write", "array_read", "array_length",
     "array_to_tensor", "gated_delta_rule", "short_conv", "rope",
-    "selective_scan", "ssd_scan", "gated_rms_norm",
+    "selective_scan", "ssd_scan", "gated_rms_norm", "channel_delta_rule",
 }
 
 

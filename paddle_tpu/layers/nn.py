@@ -267,14 +267,20 @@ def dropless_moe(input, num_experts, d_inner, top_k, norm_topk_prob=False,
 
 
 def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
-             name=None, group_size=None, zero_centered=False, gate=None):
+             name=None, group_size=None, zero_centered=False, gate=None,
+             gate_activation="silu"):
     """Root-mean-square norm over the axes from `begin_norm_axis`, with
     a learned scale initialised to 1 (no shift, no mean subtraction).
     `group_size` g: the minor dim is groups of g (the heads of a
     head-grouped projection), each normalised alone under one shared
     scale (g,).  `zero_centered`: the learned parameter w starts at 0
     and the scale is 1 + w, so weight decay pulls the scale to 1 and
-    not to 0.  `gate` (input's shape): the result times silu(gate)."""
+    not to 0.  `gate` (input's shape): the result times silu(gate), or
+    times sigmoid(gate) under `gate_activation` "sigmoid" (one fused op
+    either way)."""
+    if gate_activation not in ("silu", "sigmoid"):
+        raise NotImplementedError(f"rms_norm: gate_activation "
+                                  f"{gate_activation!r} is not built")
     helper = LayerHelper("rms_norm", name=name)
     begin = begin_norm_axis % len(input.shape)
     attrs = {"begin_norm_axis": begin, "epsilon": epsilon}
@@ -291,6 +297,8 @@ def rms_norm(input, begin_norm_axis=-1, epsilon=1e-5, param_attr=None,
     ins = {"X": [input], "Scale": [scale]}
     if gate is not None:
         ins["Gate"] = [gate]
+        if gate_activation != "silu":
+            attrs["gate_activation"] = gate_activation
     helper.append_op(type="rms_norm", inputs=ins, outputs={"Y": [y]},
                      attrs=attrs)
     return y
@@ -422,6 +430,39 @@ def gated_delta_rule(qkv, ba, n_key_head, n_value_head, key_dim, value_dim,
                "n_value_head": int(n_value_head), "key_dim": int(key_dim),
                "value_dim": int(value_dim), "use_pallas": bool(use_pallas)})
     out.desc.shape = tuple(qkv.shape[:-1]) + (int(n_value_head * value_dim),)
+    return out
+
+
+def channel_delta_rule(qkv, gate, beta, n_head, key_dim, value_dim,
+                       name=None):
+    """The scan of a delta-rule linear-attention layer whose decay is a
+    key lane's own (ops/decoder.py `channel_delta_rule`): `qkv`
+    (N, T, 2 H Dk + H Dv) is the convolved projection, q, k and v side
+    by side; `gate` (N, T, H Dk) the decay's pre-activation, one a head
+    AND key lane; `beta` (N, T, H) the write strength's.  Two learned
+    float32 vectors: `A_log` (H,), the log of a head's decay rate, from
+    log U(1, 16), and `dt_bias` (H Dk,), a lane's, the inverse softplus
+    of a step drawn log-uniformly from [1e-3, 1e-1].  Returns
+    (N, T, H Dv).  The kernels of ops/pallas/channel_delta.py run
+    wherever `kernel_takes` the shape; nothing else chooses."""
+    from ..initializer import LogUniform, SoftplusInverseLogUniform
+
+    helper = LayerHelper("channel_delta_rule", name=name)
+    a_log = helper.create_parameter(
+        None, shape=[int(n_head)], dtype="float32",
+        default_initializer=LogUniform(1.0, 16.0))
+    dt_bias = helper.create_parameter(
+        None, shape=[int(n_head * key_dim)], dtype="float32",
+        default_initializer=SoftplusInverseLogUniform(0.001, 0.1))
+    out = helper.create_variable_for_type_inference(qkv.dtype)
+    helper.append_op(
+        type="channel_delta_rule",
+        inputs={"QKV": [qkv], "Gate": [gate], "Beta": [beta],
+                "ALog": [a_log], "DtBias": [dt_bias]},
+        outputs={"Out": [out]},
+        attrs={"n_head": int(n_head), "key_dim": int(key_dim),
+               "value_dim": int(value_dim)})
+    out.desc.shape = tuple(qkv.shape[:-1]) + (int(n_head * value_dim),)
     return out
 
 

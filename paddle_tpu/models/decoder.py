@@ -14,7 +14,9 @@ values are not built yet raises):
   head or its first lanes, by layer type, its query head count the
   model's or the layer's own, its context gated a lane, a head or not;
   the gated short convolution; the gated-delta-rule linear-attention
-  mixer (a chunked scan, `ops/pallas/gated_delta.py`); the Mamba-1
+  mixer (a chunked scan, `ops/pallas/gated_delta.py`) and the same
+  rule under a decay a key lane (`ops/pallas/channel_delta.py`); the
+  Mamba-1
   state-space mixer (a selective scan, `ops/pallas/selective_scan.py`)
   and the Mamba-2 one (one decay a head over a matrix state, its
   chunked matrix-product form, `ops/pallas/ssd_scan.py`, under a gated
@@ -27,8 +29,9 @@ values are not built yet raises):
   configuration's own; multipliers on the embedding, on every residual
   branch and under the logits;
   latent attention (`kv_lora_rank` ...: queries, keys and values out
-  of low-rank latents, a rotary part beside the unrotated one, its own
-  flash kernels);
+  of low-rank latents, or the queries out of one direct projection, a
+  rotary part beside the unrotated one, turned or left as it is made,
+  its own flash kernels);
 - feed-forward layers: dense SwiGLU; dropless routed SwiGLU experts
   under a soft-max or a sigmoid router with a selection bias, whole or
   as one expert-parallel rank's share; shared experts beside the
@@ -113,6 +116,29 @@ activation="silu")`); the recurrence is ONE op, `gated_delta_rule`,
 whose sequential part is a Pallas kernel at heads of 128 x 128.  Its
 ops lower under the `linear_attention` name scope.
 
+`layer_types[i]` = "channel_delta_attention" (`linear_attn_config`:
+`num_heads` H heads of `head_dim` D, `short_conv_kernel_size` taps; Kimi
+Delta Attention, Kimi Team, arXiv:2510.26692) is the same delta rule
+under a decay that is each key LANE's own, its gates made by low-rank
+pairs of projections (their rank is the head size: the paper's, no
+key gives another):
+
+    [q | k | v] = silu(conv(h W_qkv))                  (3 H D wide)
+    q, k = l2norm a head (q x D^-1/2);  beta = sigmoid(h W_b)   (a head)
+    g = -exp(A_log[head]) softplus((h W_f1) W_f2 + dt_bias)
+                                         (float32, one a head AND lane)
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    out = (rms_norm(S_t^T q_t) * w * sigmoid((h W_g1) W_g2)) W_out
+
+the convolution as above; the recurrence is ONE op,
+`channel_delta_rule` (`ops/pallas/channel_delta.py`: five Pallas
+kernels at an even number of heads of 128 x 128), the norm a head under
+the SIGMOID of its gate ONE fused op (`layers.rms_norm(gate=,
+gate_activation="sigmoid")`).  Its ops lower under the
+`channel_delta_attention` name scope.  Beside a looped stack, the
+block-diffusion objective, a head count a layer or a prediction module
+it raises.
+
 `partial_rotary_factor` f < 1: RoPE turns the first f x head_dim lanes
 of each head as a head of that size would, the rest pass through.  A
 `rope_parameters` group may carry its own (a layer type's share of the
@@ -173,7 +199,15 @@ heads' rotary parts side by side, all heads' keys, all heads' values
 (a checkpoint's per-head column order is a loader's one-off
 permutation), so nothing is sliced at a stride and the kernels
 (`ops/pallas/flash_mla.py`) read each where the projection wrote it.
-Its ops lower under the `latent_attention` name scope.
+Its ops lower under the `latent_attention` name scope.  `q_lora_rank`
+None beside `kv_lora_rank`: no query latent and no norm, [q_nope |
+q_rope] = h W_q, ONE direct projection (two column blocks).
+`mla_use_nope`: NOTHING is rotated, `rope` above is the identity on
+both rotary parts and the layer carries no position of any kind (the
+scans beside it do); no `rope_theta` is then read, the same kernels
+take the 64 lanes as they are made, and the name scope stays
+`latent_attention`.  `num_expert_group` / `topk_group` above 1 (a
+top-k over groups of experts) raise.
 
 `n_shared_experts` s > 0: beside the routed experts every token also
 goes through ONE dense SwiGLU of width s x `moe_intermediate_size`
@@ -415,7 +449,9 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             shared_kv_layer=None, mamba_n_heads=None, mamba_d_head=None,
             mamba_n_groups=None, mamba_chunk_size=None,
             embedding_multiplier=None, residual_multiplier=None,
-            attention_multiplier=None, logits_scaling=None):
+            attention_multiplier=None, logits_scaling=None,
+            linear_attn_config=None, mla_use_nope=False,
+            num_expert_group=1, topk_group=1):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
     labels' own successors, with a prediction module); under
@@ -524,14 +560,24 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         raise NotImplementedError(
             "routed experts, a prediction module or a tied head inside "
             "the loop are not built")
-    latent = (kv_lora_rank, q_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
-              v_head_dim)
+    if num_expert_group != 1 or topk_group != 1:
+        raise NotImplementedError(
+            f"num_expert_group {num_expert_group} / topk_group {topk_group}: "
+            f"a top-k over groups of experts is not built (the top "
+            f"num_experts_per_tok are taken over all experts at once)")
+    # q_lora_rank None beside kv_lora_rank: the queries are ONE direct
+    # projection, no latent and no norm
+    latent = (kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim, v_head_dim)
     if kv_lora_rank is None:
-        if any(v is not None for v in latent):
+        if any(v is not None for v in latent + (q_lora_rank,)):
             raise ValueError("latent attention's sizes without kv_lora_rank")
+        if mla_use_nope:
+            raise ValueError("mla_use_nope without kv_lora_rank: there is "
+                             "no latent attention to leave unrotated")
     elif None in latent:
-        raise ValueError("latent attention needs kv_lora_rank, q_lora_rank, "
-                         "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+        raise ValueError("latent attention needs kv_lora_rank, "
+                         "qk_nope_head_dim, qk_rope_head_dim and v_head_dim "
+                         "(q_lora_rank or None beside them)")
     elif num_key_value_heads != num_attention_heads:
         raise ValueError("latent attention has one key and value a query "
                          "head: num_key_value_heads is num_attention_heads")
@@ -650,6 +696,24 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         if linear_num_value_heads % linear_num_key_heads:
             raise ValueError("linear_num_value_heads is not a multiple of "
                              "linear_num_key_heads")
+    if "channel_delta_attention" in layer_types:
+        sizes = ("num_heads", "head_dim", "short_conv_kernel_size")
+        if not linear_attn_config or any(
+                linear_attn_config.get(key) is None for key in sizes):
+            raise ValueError(
+                f"a channel_delta_attention layer needs linear_attn_config "
+                f"with {', '.join(sizes)}")
+        # built straight, under the next-token objective
+        unbuilt = [what for what, asked in [
+            ("a looped stack", loops),
+            ("objective='block_diffusion'", diffusion),
+            ("num_attention_heads_per_layer",
+             num_attention_heads_per_layer is not None),
+            ("a prediction module", num_nextn_predict_layers)] if asked]
+        if unbuilt:
+            raise NotImplementedError(
+                "a channel_delta_attention layer beside "
+                + ", ".join(unbuilt) + " is not built")
     if head_dim is None:
         head_dim = hidden_size // num_attention_heads
     heads_of = list(num_attention_heads_per_layer
@@ -687,7 +751,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                       else rope_parameters) or {})
         group.setdefault("rope_theta", rope_theta)
         if group["rope_theta"] is None:
-            if kind in layer_types:
+            if kind in layer_types and not mla_use_nope:
                 raise ValueError("decoder needs rope_theta or "
                                  "rope_parameters")
             continue
@@ -704,7 +768,10 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             # scaled frequencies of a head of `rotary_dim` lanes
             inv_freq, factor = rope_frequencies(rotary_dim, **group)
             rotary[kind].update(inv_freq=inv_freq, attention_factor=factor)
-    if eps is None or not rotary and positions == "rope":
+    # (an unrotated latent attention is the only attention there is
+    # beside it: nothing reads a rope_theta)
+    if eps is None or not rotary and positions == "rope" \
+            and not mla_use_nope:
         raise ValueError("decoder needs rms_norm_eps or norm_eps, and "
                          "rope_theta or rope_parameters")
     theta = rotary.get("full_attention", {}).get("theta")
@@ -803,14 +870,43 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             return proj(layers.rms_norm(o, epsilon=eps, group_size=dv,
                                         gate=z), hidden_size, "linear_out")
 
+    def channel_delta_attention(h):
+        """The delta-rule mixer whose decay is a key lane's own: q, k, v
+        out of ONE projection through a short causal convolution and a
+        SiLU, the decay and the output gate each out of a low-rank pair
+        of projections, the scan, and an output norm a head under the
+        gate's sigmoid."""
+        heads, dim = (linear_attn_config[key]
+                      for key in ("num_heads", "head_dim"))
+        rank = dim              # of the two low-rank pairs: the head size
+        with name_scope("channel_delta_attention"):
+            qkv = layers.short_conv(
+                proj(h, 3 * heads * dim, "delta_qkv"),
+                linear_attn_config["short_conv_kernel_size"],
+                param_attr=weight(), activation="silu")
+            decay = proj(proj(h, rank, "delta_decay_a"), heads * dim,
+                         "delta_decay_b")
+            o = layers.channel_delta_rule(
+                qkv, decay, proj(h, heads, "delta_beta"), heads, dim, dim)
+            z = proj(proj(h, rank, "delta_gate_a"), heads * dim,
+                     "delta_gate_b")
+            # this norm's scale starts at 1 whatever the others do
+            return proj(layers.rms_norm(o, epsilon=eps, group_size=dim,
+                                        gate=z, gate_activation="sigmoid"),
+                        hidden_size, "delta_out")
+
     def latent_attention(h):
         heads = num_attention_heads
 
         def rotary(x, n_head):
+            if mla_use_nope:        # no positions: the lanes stay as made
+                return x
             return layers.rope(x, n_head, theta, interleave=rope_interleave)
 
         with name_scope("latent_attention"):
-            c_q = norm(proj(h, q_lora_rank, "attn_q_a"))
+            # the queries' latent, or the layer's input itself
+            c_q = h if q_lora_rank is None \
+                else norm(proj(h, q_lora_rank, "attn_q_a"))
             q_nope = proj(c_q, heads * qk_nope_head_dim, "attn_q_b")
             q_rope = rotary(proj(c_q, heads * qk_rope_head_dim, "attn_q_b"),
                             heads)
@@ -969,6 +1065,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     # layer type -> mixer; latent attention stands where full attention
     # does and is not built under a window
     mixers = {"conv": conv, "linear_attention": linear_attention,
+              "channel_delta_attention": channel_delta_attention,
               "full_attention": functools.partial(attention,
                                                   kind="full_attention"),
               "sliding_attention": functools.partial(

@@ -84,6 +84,8 @@ _FIELDS = Heard._fields[1:] + (
     "gated_delta_calls", "gated_delta_chunks",
     "gated_delta_operand_calls", "gated_delta_operand_chunks",
     "gated_delta_inverse_calls",
+    "channel_delta_calls", "channel_delta_chunks",
+    "channel_delta_operand_calls", "channel_delta_operand_chunks",
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "short_convs_kernel", "short_convs_xla", "short_conv_bias_calls",
@@ -237,6 +239,15 @@ class RuntimeStats:
         # the chunk-operand kernels: traced where the layer is, once,
         # however often the kernels that read it are)
         self.gated_delta_inverse_calls = 0
+        # the same two pairs for the delta rule whose decay is a key
+        # lane's own (`ops/pallas/channel_delta.py`): the scan kernels
+        # `channel_delta_fwd` / `_bwd`, and the chunk-local kernels
+        # `channel_delta_inverse` / `_operands_fwd` / `_operands_bwd`;
+        # 0 where the XLA lowering of the chunks ran
+        self.channel_delta_calls = 0
+        self.channel_delta_chunks = 0
+        self.channel_delta_operand_calls = 0
+        self.channel_delta_operand_chunks = 0
         # calls traced inside a recompute segment that name what the
         # segment keeps (`ops/pallas keep_residuals`): an attention
         # call, whose backward pass therefore keeps the kernel's two
@@ -432,6 +443,16 @@ class RuntimeStats:
     def record_gated_delta_inverse(self):
         with self._lock:
             self.gated_delta_inverse_calls += 1
+
+    def record_channel_delta(self, chunks: int):
+        with self._lock:
+            self.channel_delta_calls += 1
+            self.channel_delta_chunks += chunks
+
+    def record_channel_delta_operands(self, chunks: int):
+        with self._lock:
+            self.channel_delta_operand_calls += 1
+            self.channel_delta_operand_chunks += chunks
 
     def record_kept_residuals(self, nbytes: int):
         with self._lock:

@@ -6,7 +6,8 @@ that stands where attention does in most layers of a hybrid
 conv/attention model, gated or under a SiLU), `latent_attention` (the
 attention core of a layer whose keys and values come out of a low-rank
 latent, with a rotary part beside it), `gated_delta_rule` (the scan
-of a linear-attention layer), `ssd_scan` and `gated_rms_norm` (the scan
+of a linear-attention layer), `channel_delta_rule` (the same scan under
+a decay of each key lane's own), `ssd_scan` and `gated_rms_norm` (the scan
 and the gated output norm of a Mamba-2 state-space mixer),
 `selective_scan` (the scan of a
 state-space mixer with a diagonal state a channel) and `diff_combine`
@@ -37,6 +38,24 @@ def _over_rms(xf, axes, eps):
                           + eps)
 
 
+def _head_norm_under_sigmoid(x, scale, gate, group, eps):
+    """rms_norm a head of `group` lanes, times its scale and the gate's
+    sigmoid, on the (.., H x group) tensor as it lies: the heads' mean
+    squares by `channel_delta.head_sums` (a product with a 0 / 1
+    matrix), no (.., H, group) view, which the chip would re-lay (134 MB
+    a float32 operand at 8192 x 4096, PR 65).  The silu-gated form
+    below keeps its reshape: the step texts of the cells that run it are
+    pinned."""
+    from .pallas.channel_delta import head_spread, head_sums
+
+    f32 = jnp.float32
+    xf = x.astype(f32)
+    heads = x.shape[-1] // group
+    inv = lax.rsqrt(head_sums(xf * xf, heads) / group + eps)
+    y = xf * head_spread(inv, group) * jnp.tile(scale.astype(f32), heads)
+    return (y * jax.nn.sigmoid(gate.astype(f32))).astype(x.dtype)
+
+
 @register_op("rms_norm")
 def rms_norm(ctx, ins, attrs):
     """Y = X * rsqrt(mean(X^2 over the axes from begin_norm_axis) + eps)
@@ -46,7 +65,8 @@ def rms_norm(ctx, ins, attrs):
     and scaled by the one Scale (g,) they share.  `zero_centered`: the
     scale is 1 + Scale (a Scale that starts at 0 and that weight decay
     pulls to 0 leaves the norm ON).  Gate (X's shape): Y is multiplied
-    by silu(Gate), in float32 (the output norm of a gated mixer)."""
+    by silu(Gate), or by sigmoid(Gate) under `gate_activation`
+    "sigmoid", in float32 (the output norm of a gated mixer)."""
     x = first(ins, "X")
     scale = opt_in(ins, "Scale")
     gate = opt_in(ins, "Gate")
@@ -55,13 +75,18 @@ def rms_norm(ctx, ins, attrs):
         if x.shape[-1] % int(group):
             raise ValueError(f"rms_norm: minor dim {x.shape[-1]} is not "
                              f"whole groups of {group}")
+        if attrs.get("gate_activation", "silu") == "sigmoid" \
+                and gate is not None and scale is not None:
+            return out(Y=_head_norm_under_sigmoid(
+                x, scale, gate, int(group), attrs.get("epsilon", 1e-5)))
         split = x.shape[:-1] + (-1, int(group))
         y = rms_norm(ctx, {"X": [x.reshape(split)],
                            "Scale": ins.get("Scale", []),
                            "Gate": [g.reshape(split)
                                     for g in ins.get("Gate", [])]},
                      {k: v for k, v in attrs.items()
-                      if k in ("epsilon", "zero_centered")})["Y"][0]
+                      if k in ("epsilon", "zero_centered",
+                               "gate_activation")})["Y"][0]
         return out(Y=y.reshape(x.shape))
     begin = attrs.get("begin_norm_axis", -1) % x.ndim
     eps = attrs.get("epsilon", 1e-5)
@@ -71,7 +96,13 @@ def rms_norm(ctx, ins, attrs):
         scale = scale.reshape(x.shape[begin:]).astype(jnp.float32)
         y = y * (1.0 + scale if attrs.get("zero_centered") else scale)
     if gate is not None:
-        y = y * jax.nn.silu(gate.astype(jnp.float32))
+        squash = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}.get(
+            attrs.get("gate_activation", "silu"))
+        if squash is None:
+            raise NotImplementedError(
+                f"rms_norm: gate_activation "
+                f"{attrs['gate_activation']!r} is not built")
+        y = y * squash(gate.astype(jnp.float32))
     return out(Y=y.astype(x.dtype))
 
 
@@ -452,6 +483,63 @@ def gated_delta_rule(ctx, ins, attrs):
     o = gated_delta.gated_delta_rule(
         q, k, v, g, beta, use_kernel=bool(attrs.get("use_pallas", False)))
     return out(Out=o.reshape(n, t, hv * dv))
+
+
+@register_op("channel_delta_rule")
+def channel_delta_rule(ctx, ins, attrs):
+    """The mixer core of a delta-rule linear-attention layer whose decay
+    is a key lane's own (Kimi Delta Attention, arXiv:2510.26692), over
+    one sequence a row.  QKV (N, T, 2 H Dk + H Dv): the convolved,
+    activated projection, q, k (H heads of Dk) and v (H heads of Dv) side
+    by side; Gate (N, T, H Dk) and Beta (N, T, H): the decay's and the
+    write strength's PRE-activations; ALog (H,), DtBias (H Dk,).  In
+    float32:
+
+        q = l2norm(q) * Dk^-1/2;  k = l2norm(k)         (eps 1e-6, a head)
+        beta = sigmoid(Beta)
+        g = -exp(ALog[head]) * softplus(Gate + DtBias)  (a head AND lane)
+        S'_t = Diag(exp(g_t)) S_{t-1};  u_t = beta_t (v_t - S'_t^T k_t)
+        S_t = S'_t + k_t u_t^T;   Out_t = S_t^T q_t      (N, T, H Dv)
+
+    with S (Dk, Dv) a head from 0.  g is the one float32 (N, T, H Dk)
+    tensor the op makes; the scan runs in chunks of 64 positions
+    (`ops/pallas/channel_delta.py`): its dots in QKV's dtype, state,
+    decay, every exponential and the chunk's inverse in float32.  Two
+    lowerings of the one recurrence, chosen by the shape alone
+    (`channel_delta.kernel_takes`: an even number of heads of 128 x 128,
+    chunk blocks of 8): the five Pallas kernels there, or XLA's batch
+    over the chunks and a `lax.scan`.  `runtime_stats.channel_delta_calls`
+    / `_operand_calls` count the kernel calls traced; a call that fell
+    back reads 0."""
+    from .pallas import channel_delta
+    from .pallas.selective_scan import softplus
+
+    qkv, gate, beta = first(ins, "QKV"), first(ins, "Gate"), first(ins, "Beta")
+    a_log, dt_bias = first(ins, "ALog"), first(ins, "DtBias")
+    h = int(attrs["n_head"])
+    dk, dv = int(attrs["key_dim"]), int(attrs["value_dim"])
+    n, t, width = qkv.shape
+    if width != h * (2 * dk + dv) or gate.shape != (n, t, h * dk) \
+            or beta.shape != (n, t, h):
+        raise ValueError(
+            f"channel_delta_rule: QKV {qkv.shape}, Gate {gate.shape} and "
+            f"Beta {beta.shape} are not {h} heads of {dk} key and {dv} "
+            f"value lanes, a decay a key lane and a beta a head")
+    f32 = jnp.float32
+
+    def l2norm(x):
+        # a head's sum of squares without a (.., H, Dk) view of x
+        x = x.astype(f32)
+        scale = lax.rsqrt(channel_delta.head_sums(x * x, h) + 1e-6)
+        return x * channel_delta.head_spread(scale, dk)
+
+    q = (l2norm(qkv[..., :h * dk]) * dk ** -0.5).astype(qkv.dtype)
+    k = l2norm(qkv[..., h * dk:2 * h * dk]).astype(qkv.dtype)
+    rate = jnp.repeat(jnp.exp(a_log.astype(f32)), dk)
+    g = -rate * softplus(gate.astype(f32) + dt_bias.astype(f32))
+    return out(Out=channel_delta.channel_delta_rule(
+        q, k, qkv[..., 2 * h * dk:], g, jax.nn.sigmoid(beta.astype(f32)),
+        use_kernel=channel_delta.kernel_takes(h, dk, dv, t)))
 
 
 @register_op("selective_scan")

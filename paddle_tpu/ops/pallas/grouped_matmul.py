@@ -109,7 +109,12 @@ def _kept_bytes(tm, tk, tn, k, itemsize):
     store makes of it (three tiles at most), and the float32 sum where
     K is cut."""
     blocks = 2 * itemsize * (tm * tk + tk * tn + tm * tn)
-    return blocks + 4 * tm * tn * (3 if tk == k else 4)
+    # float32 rows under a "highest" product (the parity scripts'): the
+    # row tile's bfloat16 parts (calibrated: Mosaic took 16.33 MiB for
+    # (128, 2304, 512) where the lines above count 12.5, and takes every
+    # tiling the five earlier cells' float32 shapes get: PR 65)
+    parts = 12 * tm * tk if itemsize == 4 else 0
+    return blocks + parts + 4 * tm * tn * (3 if tk == k else 4)
 
 
 def _kept_traffic(m, k, n, groups, tm, tk, tn):

@@ -391,6 +391,36 @@ def test_grouped_matmul_kernels_at_the_cells_shapes(one_chip, cell):
             assert {r["flops"] for r in kernels} == {2.0 * rows * kk * nn}
 
 
+def test_grouped_matmul_in_float32_at_highest_stays_within_vmem(one_chip):
+    """What a parity script hands the chip's compiler: float32 rows
+    under "highest" products, whose bfloat16 parts Mosaic keeps beside
+    the blocks.  At `kimilinear-8k`'s up-projection (2304 -> 1024, 8
+    held) the rule's first choice, (128, 2304, 512), took 16.33 MiB of
+    the 16 MiB a call may claim (PR 65); the rule now counts the row
+    tile's parts and takes (128, 2304, 256).  The case above compiles
+    float32 at the default precision, where that tiling fits."""
+    from paddle_tpu.ops.pallas import force_mosaic_lowering
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul, tiles_for
+
+    # the cell's first row buffer, its experts held, D and H
+    rows, held, d, h = 3072, 8, 2304, 1024
+    assert tiles_for(rows, d, h, held, 4)[0] == (128, 2304, 256)
+    assert tiles_for(rows, d, h, held, 2)[0] == (128, 2304, 1024)
+
+    def vjp(lhs, rhs, counts, ct):
+        out, pull = jax.vjp(lambda l, r: grouped_matmul(l, r, counts),
+                            lhs, rhs)
+        return (out,) + pull(ct)
+
+    for kk, nn in ((d, h), (h, d)):
+        args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                for s, dt in (((rows, kk), F32), ((held, kk, nn), F32),
+                              ((held,), I32), ((rows, nn), F32))]
+        with force_mosaic_lowering(), jax.default_matmul_precision("highest"):
+            text = jax.jit(vjp).lower(*args).compile().as_text()
+        assert text.count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("cell", sorted(SHARE_CELLS))
 def test_rows_to_tokens_kernel_at_the_share_cells_shapes(one_chip, cell):
     """`ops/pallas/rows_to_tokens.py` at the five share cells' first

@@ -414,8 +414,11 @@ STEP_TEXT = {
     # the same shells under their new names (`_flash_fwd_visits`,
     # `_flash_bwd_visits`; parent: 691f6604..).  The eight other cells
     # trace no such call and keep their text
+    # Re-pinned, PR 65, as PERF.md section 7 "From PR 64" asks: PR 64
+    # changed this configuration's peak rate (4e-4 -> 2e-5), a constant
+    # of the text, and could not edit this file (parent: 70ec8bb9..)
     "mellum2-16k":
-    "70ec8bb9b8c86d063d442bbee56679d63044540b4d36ec8675ba95d0d2f3cfb6",
+    "95d77d9202f88ec87b6bca10681c330fee251618c28122d173992f3ee5654f5f",
     # re-pinned, PR 52: (I + A)^-1 is `gated_delta_inverse`'s, named for
     # the layers' segments to keep, and `gated_delta_operands_fwd` reads
     # it (parent: f591949a..); no other cell builds the op, and a name
@@ -456,6 +459,14 @@ STEP_TEXT = {
     # by no other step
     "granite4h-8k":
     "d40ed03ddc9294461d897b9b08d6cf9a242f1d851e516f29864de0b10c8e37ac",
+    # new in PR 65 (the lane-decayed delta rule's five kernels through
+    # the interpreter, the sigmoid-gated norm a head, latent attention
+    # under one direct q projection with nothing rotated); every other
+    # cell keeps its parent's text: an argument at its default appends no
+    # op, and the two names `KEPT_RESIDUALS` gained are emitted by no
+    # other step
+    "kimilinear-8k":
+    "7678dc76d9b498dedc580b93ed2401ac175adcf422f4d369bf1d5dc555cff521",
 }
 
 
@@ -510,3 +521,51 @@ def test_every_existing_cells_step_is_the_parents_text(cell):
             took["flash_window_forward_tiled"]) == (
         WINDOW_FORWARDS.get(cell, 0), 0)
     assert hashlib.sha256(text.encode()).hexdigest() == STEP_TEXT[cell]
+
+
+def test_the_channel_delta_cells_step_holds_its_kernels_under_the_plan(
+        one_chip):
+    """The whole training step of `kimilinear-8k` (the published layers
+    1-5: four delta layers whose decay is a key lane's own and one
+    unrotated latent-attention layer; 8192 rows, bf16 AMP, every layer a
+    recompute segment), compiled for the described chip, nothing run.  A
+    delta layer's segment keeps (I + A)^-1 and P (67,108,864 +
+    33,554,432 bytes a layer), so the step holds `channel_delta_inverse`
+    FOUR times, once a layer, `channel_delta_operands_fwd` and
+    `channel_delta_fwd` eight (the forward pass's and the recomputed one
+    that reads what was kept) and the two backward kernels four; the
+    latent layer runs `flash_mla`'s kernels at `joyai-8k`'s shape, its
+    backward ONE kernel; no fall-back anywhere.  The plan the
+    configuration states (ISSUE 65: under 15.0 GB, the length and the
+    cut fixed before the step existed).  (Here and not beside the other
+    cells' steps in tests/test_chip_compile_cells.py, whose helper it
+    borrows: that file is the suite's longest and starts late,
+    tests/chip_compile.py.)"""
+    from test_chip_compile_cells import _cell_step
+
+    parameters, _, plan, kernels, took = _cell_step("kimilinear-8k", one_chip)
+    assert parameters == 602433408
+    assert plan["arguments"] == pytest.approx(7.23, abs=0.01)
+    assert 9.0 < plan["total"] <= 15.0, plan
+    assert (kernels["channel_delta_inverse"],
+            kernels["channel_delta_operands_fwd"],
+            kernels["channel_delta_operands_bwd"]) == (4, 8, 4)
+    assert (kernels["channel_delta_fwd"],
+            kernels["channel_delta_bwd"]) == (8, 4)
+    assert (kernels["flash_mla_fwd"], kernels["flash_mla_dkv"],
+            kernels["flash_mla_dq"]) == (1, 1, 0)
+    assert (kernels["short_conv_fwd"], kernels["short_conv_bwd"]) == (8, 4)
+    # a delta layer's forward, its forward traced again for the
+    # segment's backward pass, its backward: 128 chunks x 32 heads a call
+    assert (took["channel_delta_calls"], took["channel_delta_chunks"]) == (
+        12, 12 * 128 * 32)
+    # the inverse kernel once a layer, the operand kernels as the scan's
+    assert (took["channel_delta_operand_calls"],
+            took["channel_delta_operand_chunks"]) == (16, 16 * 128 * 32)
+    assert (took["short_convs_kernel"], took["short_convs_xla"]) == (4, 0)
+    assert (took["flash_mla_backward_fused"],
+            took["flash_mla_backward_split"]) == (1, 0)
+    assert took["gated_delta_calls"] == took["gated_delta_operand_calls"] == 0
+    # four layers' (inverse, P) and the latent layer's (o, logsumexp)
+    assert took["recompute_kept_residuals"] == 5
+    assert took["recompute_kept_bytes"] >= 4 * (67108864 + 33554432)

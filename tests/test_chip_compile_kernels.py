@@ -1,7 +1,8 @@
 """The chip's compiler on the other kernel families, each alone: latent
 attention (`ops/pallas/flash_mla.py`) at `joyai-8k`'s shape, the chunked
 delta-rule scan (`ops/pallas/gated_delta.py`) and grouped flash
-attention at d_head 256 at `qwen3next-16k`'s, the scalar-a-head scan
+attention at d_head 256 at `qwen3next-16k`'s, the lane-decayed delta rule
+(`ops/pallas/channel_delta.py`) at `kimilinear-8k`'s, the scalar-a-head scan
 (`ops/pallas/ssd_scan.py`) and grouped flash attention under a scale of
 2^-6 at `granite4h-8k`'s, the short convolution
 (`ops/pallas/short_conv.py`) at that cell's and `lfm2-8k`'s, the fused
@@ -153,6 +154,72 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     # the states that enter the chunks, in the operands' dtype
     kind = "bf16" if dtype == BF16 else "f32"
     assert f"{kind}[{hv},{256 * d},{d}]" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_channel_delta_kernels_at_the_published_shapes(one_chip, dtype):
+    """What `kimilinear-8k`'s step hands the chip's compiler that no
+    other cell does: the `channel_delta_rule` op at 1 x 8192 positions,
+    32 heads of 128 x 128 under a decay a key lane, in the cell's
+    bfloat16 and in the parity script's float32 at "highest".  Five
+    Mosaic kernels under a gradient: the chunk-local part's
+    `channel_delta_inverse` (which writes (I + A)^-1, two heads a float32
+    tile, and P: what a recompute segment keeps), `_operands_fwd` (which
+    reads the inverse) and `_operands_bwd`, each a grid of 16 head pairs
+    x 32 blocks of 4 chunks; the forward rule's `channel_delta_fwd`
+    (which also writes the 128 chunk-entry states a head, transposed) and
+    `channel_delta_bwd`, each a grid of 32 heads x 16 blocks carrying a
+    (128, 128) float32 state in VMEM scratch."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    n, t, h, d = 1, 8192, 32, 128
+    impl = get_op_impl("channel_delta_rule")
+
+    def loss(qkv, gate, beta, a_log, dt_bias):
+        with jax.named_scope("channel_delta_attention/channel_delta_rule:9"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"QKV": [qkv], "Gate": [gate], "Beta": [beta],
+                      "ALog": [a_log], "DtBias": [dt_bias]},
+                     {"n_head": h, "key_dim": d, "value_dim": d})["Out"][0]
+        return jnp.sum(o.astype(F32))
+
+    args = [jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)
+            for shape, kind in (((n, t, 3 * h * d), dtype),
+                                ((n, t, h * d), dtype), ((n, t, h), dtype),
+                                ((h,), F32), ((h * d,), F32))]
+    prec = "default" if dtype == BF16 else "highest"
+    before = runtime_stats.snapshot()
+    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
+            .lower(*args).compile()
+    took = runtime_stats.delta(before)
+    # the forward rule's call and the backward's: 128 chunks x 32 heads;
+    # the inverse kernel is a third chunk-local call
+    assert (took["channel_delta_calls"], took["channel_delta_chunks"]) == (
+        2, 2 * 128 * 32)
+    assert (took["channel_delta_operand_calls"],
+            took["channel_delta_operand_chunks"]) == (3, 3 * 128 * 32)
+    assert took["gated_delta_calls"] == 0
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "channel_delta_bwd", "channel_delta_fwd", "channel_delta_inverse",
+        "channel_delta_operands_bwd", "channel_delta_operands_fwd"]
+    assert {r["op_type"] for r in rows if r["kernel"]} == {
+        "channel_delta_rule"}
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 5
+    scan = (3 + 6) * 2 * 64 * d * d + (1 + 2) * 2 * 64 * 64 * d
+    local = 64 * ((2 + 2 + 8) * 2 * 64 * d + 2 * 64 * 64 / 3
+                  + 2 * 2 * 64 * 64)
+    assert totals["pallas_flops"] == pytest.approx(
+        128 * 32 * (scan + local), rel=1e-9)
+    # the states that enter the chunks, in the operands' dtype, and the
+    # one float32 tensor a lane: g (and its gradient)
+    kind = "bf16" if dtype == BF16 else "f32"
+    assert f"{kind}[{h},{128 * d},{d}]" in compiled.as_text()
 
 
 @pytest.mark.parametrize("form", ["silu", "gated"])

@@ -1002,6 +1002,13 @@ S["gated_delta_rule"] = dict(
     attrs={"n_key_head": 1, "n_value_head": 2, "key_dim": 4,
            "value_dim": 4}, out="Out", shape=(1, 5, 8),
     grad=["QKV", "BA", "ALog", "DtBias"], tol=2e-2)
+S["channel_delta_rule"] = dict(
+    ins={"QKV": f32((1, 5, 24), 321), "Gate": f32((1, 5, 8), 322),
+         "Beta": f32((1, 5, 2), 323), "ALog": f32((2,), 324),
+         "DtBias": f32((8,), 325)},
+    attrs={"n_head": 2, "key_dim": 4, "value_dim": 4}, out="Out",
+    shape=(1, 5, 8), grad=["QKV", "Gate", "Beta", "ALog", "DtBias"],
+    tol=2e-2)
 S["selective_scan"] = dict(
     ins={"U": f32((1, 6, 8), 291), "Delta": f32((1, 6, 8), 292),
          "ALog": f32((8, 4), 293), "B": f32((1, 6, 4), 294),
