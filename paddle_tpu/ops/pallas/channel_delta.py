@@ -27,13 +27,22 @@ gamma_i = sum_{j<=i} g_j, a (C, Dk) matrix:
 
 The decay sits INSIDE the contraction of A and P, and exp(-gamma_j)
 alone overflows, so neither is one product of two pre-scaled matrices
-(`decayed_products`): a chunk is cut into sub-blocks of `SUB` = 16
-positions; a row sub-block against the EARLIER columns is one MXU
-product, rows scaled by exp(gamma_i - gamma_r) and columns by
-exp(gamma_r - gamma_j) with r the row sub-block's first position (both
-exponents <= 0); a sub-block against itself is taken column by column
-on the VPU, exp(gamma_i - gamma_j) for the rows at or below j alone.  No
-exponent is ever positive.
+(`decayed_products`).  For ANY r with j < r <= i the decay splits as
+exp(gamma_i - gamma_r) exp(gamma_r - gamma_j), both exponents <= 0
+because gamma falls, so a chunk's pairs are halved: a LEVEL of
+half-width w (32, 16, .. ) takes the pairs that share a block of 2 w
+positions with i in its lower and j in its upper half (the highest bit
+in which i and j differ is w), r the block's midpoint, as ONE MXU
+product for the whole chunk: all rows scaled by exp(-|gamma - gamma_r|)
+(one tile serves rows and columns), rounded to the operands' dtype after
+the scaling, the level's pairs picked out of the result.  The backward
+halves all the way down (levels 32 .. 1 and the undecayed diagonal as
+one more product); the forward stops at diagonal sub-blocks of `SUB` = 4
+positions, which it takes column by column on the VPU,
+exp(gamma_i - gamma_j) for the rows at or below j alone (timed on the
+chip, PR 66: its last two levels cost more than four columns; the
+backward's columns cost twice the forward's).  No exponent is ever
+positive.
 
 Two parts, five kernels and one reference lowering of each part.
 
@@ -49,11 +58,12 @@ product with a 0 / 1 triangle at "highest" inside the kernel: no float32
 (N, T, H x 128) tensor but g itself is in HBM.
 
 * `channel_delta_inverse` reads q, k, kb, g; writes (I + A)^-1 (float32,
-  two heads a tile) and P (the operands' dtype): both column loops share
-  their exponentials.  Made BEFORE the `custom_vjp` that holds the other
-  two, on constants, and NAMED (`ops/pallas keep_residuals`): a
-  recompute segment keeps both, so its backward pass neither solves nor
-  walks the sub-blocks' columns a second time.
+  two heads a tile) and P (the operands' dtype): kb and q are stacked
+  into one product a level and share its exponentials and the columns'.
+  Made BEFORE the `custom_vjp` that holds the other two, on constants,
+  and NAMED (`ops/pallas keep_residuals`): a recompute segment keeps
+  both, so its backward pass neither solves nor takes the decayed
+  products a second time.
 * `channel_delta_operands_fwd` reads q, k, kb, vb, g and the inverse;
   writes W, U, Q exp(gamma), K exp(gamma_C - gamma).  MXU work only.
 * `channel_delta_operands_bwd` reads the same and the five cotangents
@@ -99,7 +109,9 @@ from .gated_delta import (CHUNK, _HI, _block_chunks, _chunk_rows, _dot,
                           _lower, _pallas_call, _params, _rows, _suffix_sum,
                           _tile_iotas, unit_lower_inverse)
 
-SUB = 16                # positions of a sub-block
+# positions of a diagonal sub-block the forward takes by columns: where
+# its halving stops (the backward's goes all the way: `decayed_products`)
+SUB = 4
 HEAD_DIM = 128          # the kernels' Dk and Dv
 PAIR = 2                # heads a grid step of the chunk-local kernels
 # chunks a grid step of the chunk-local kernels: 256 rows of every
@@ -107,6 +119,7 @@ PAIR = 2                # heads a grid step of the chunk-local kernels
 # results, double-buffered: 7 MB of VMEM at 4, tuned nowhere yet)
 OPERAND_BLOCK_CHUNKS = 4
 _NEG = -1e30            # an exponent that gives 0 (never +inf - inf)
+_LOG2E = 1.4426950408889634
 
 
 def kernel_takes(heads, dk, dv, t):
@@ -124,9 +137,10 @@ def kernel_takes(heads, dk, dv, t):
 # kernels as `gated_delta.py` counts its own (forward three products of
 # 2 C Dk Dv and one of 2 C C Dv a chunk and head; backward six and two).
 # The chunk-local ones by head and position: a decayed product is 2 C Dk
-# a row (the MXU slabs and the VPU columns together compute every pair
-# of the lower triangle once: C Dk multiply-adds a row at the full
-# square, half of it real).
+# a row (the levels' MXU products and the forward's VPU columns pick
+# every pair of the lower triangle once: C Dk multiply-adds a row at the
+# full square, half of it real; what a level's product computes beside
+# its own pairs is not work).
 
 def _scan_dims(operand_shapes):
     (bh, t, dk), _ = operand_shapes[0]
@@ -224,14 +238,14 @@ def chunk_operands(q, k, kb, vb, g):
 
 # -- the batch part, as the kernels run it -----------------------------
 
-def _sub_row(x, j):
-    """(C, D) -> (C, D): row i holds row j of i's sub-block."""
+def _sub_row(x, j, span):
+    """(C, D) -> (C, D): row i holds row j of i's span of `span` rows."""
     lax = jax.lax
     d = x.shape[1]
     return lax.concatenate([
         lax.broadcast_in_dim(lax.slice(x, (s + j, 0), (s + j + 1, d)),
-                             (SUB, d), (0, 1))
-        for s in range(0, CHUNK, SUB)], 0)
+                             (span, d), (0, 1))
+        for s in range(0, CHUNK, span)], 0)
 
 
 def _square_iotas():
@@ -240,20 +254,34 @@ def _square_iotas():
     return row, col
 
 
-def _slab_scales(gamma, first):
-    """A row sub-block that starts at `first` against the columns before
-    it: the rows' scale exp(gamma_i - gamma_first) (SUB, D) and the
-    columns' exp(gamma_first - gamma_j) (C, D); the columns at or after
-    `first` (masked by the caller) get exponent 0."""
-    ref = gamma[first:first + 1, :]
-    return (jnp.exp(gamma[first:first + SUB, :] - ref),
-            jnp.exp(jnp.minimum(ref - gamma, 0.0)))
+def _levels(least):
+    """The levels' half-widths, rising: `least` up to C / 2."""
+    return [least << n for n in range((CHUNK // (2 * least)).bit_length())]
 
 
-def _column_decay(gamma, j, below):
-    """exp(gamma_i - gamma_j') (C, D) for column j of every diagonal
-    sub-block (j' = j of i's sub-block), 0 for the rows above it."""
-    return jnp.exp(jnp.where(below, gamma - _sub_row(gamma, j), _NEG))
+def _pair_levels():
+    """(C, C) int32: i ^ j where i > j, whose highest bit is the
+    half-width of the level that takes the pair (i in the lower half and
+    j in the upper half of one block of twice that many positions); 0
+    where i = j, -1 above the diagonal."""
+    row, col = _square_iotas()
+    return jnp.where(row > col, row ^ col, jnp.where(row == col, 0, -1))
+
+
+def _level_decay(gamma, w):
+    """exp(-|gamma_i - gamma_m|) (C, D), m the midpoint of i's block of
+    2 w positions: what the level's rows (i >= m) take as
+    exp(gamma_i - gamma_m) and its columns (j < m) as
+    exp(gamma_m - gamma_j), one tile for both.  gamma falls, so that is
+    what it is, and no exponent is positive whatever the rounding."""
+    span = max(2 * w, 8)    # whole sublane groups are broadcast
+    mid = _sub_row(gamma, span - w, span)
+    if span > 2 * w:        # several blocks a sublane group
+        sub = jax.lax.broadcasted_iota(jnp.int32, gamma.shape, 0) & (span - 1)
+        for m in reversed(range(w, span - w, 2 * w)):
+            mid = jnp.where(sub < m + w, _sub_row(gamma, m, span), mid)
+    # (exp as the chip takes it, 2^x: the sign rides on the constant)
+    return jnp.exp2(jnp.abs(gamma - mid) * -_LOG2E)
 
 
 def decayed_products(rows, k, gamma, dt):
@@ -263,67 +291,49 @@ def decayed_products(rows, k, gamma, dt):
     f32 = jnp.float32
     row, col = _square_iotas()
     d = k.shape[1]
-    count = len(rows)
-    # (an iota of its own: Mosaic does not slice one)
-    slab_col = jax.lax.broadcasted_iota(jnp.int32, (count * SUB, CHUNK), 1)
-    slabs = [jnp.zeros((count * SUB, CHUNK), f32)]
-    for first in range(SUB, CHUNK, SUB):
-        er, ec = _slab_scales(gamma, first)
-        xr = jnp.concatenate([x[first:first + SUB, :] * er for x in rows], 0)
-        s = _dot(xr.astype(dt), (k * ec).astype(dt), ((1,), (1,)))
-        slabs.append(jnp.where(slab_col < first, s, 0.0))
+    # the diagonal sub-blocks of SUB positions, column by column
     sub = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, d), 0) & (SUB - 1)
     base = row - (row & (SUB - 1))
-    diagonal = [jnp.zeros((CHUNK, CHUNK), f32) for _ in rows]
+    out = [jnp.zeros((CHUNK, CHUNK), f32) for _ in rows]
     for j in range(SUB):
-        ke = _sub_row(k, j) * _column_decay(gamma, j, sub >= j)
+        # exp(gamma_i - gamma_j) for the rows at or below j, 0 above
+        ke = _sub_row(k, j, SUB) * jnp.exp(
+            jnp.where(sub >= j, gamma - _sub_row(gamma, j, SUB), _NEG))
         at = col == base + j
-        diagonal = [
-            jnp.where(at, jnp.sum(x * ke, axis=1, keepdims=True), acc)
-            for x, acc in zip(rows, diagonal)]
-    return [jnp.concatenate([s[i * SUB:(i + 1) * SUB] for s in slabs], 0)
-            + diagonal[i] for i in range(count)]
+        out = [jnp.where(at, jnp.sum(x * ke, axis=1, keepdims=True), acc)
+               for x, acc in zip(rows, out)]
+    # the rest a level a product, each level's pairs picked out of its own
+    pairs = _pair_levels()
+    for w in _levels(SUB):
+        e = _level_decay(gamma, w)
+        xr = jnp.concatenate([x * e for x in rows], 0)
+        s = _dot(xr.astype(dt), (k * e).astype(dt), ((1,), (1,)))
+        out = [jnp.where(pairs >= w, s[i * CHUNK:(i + 1) * CHUNK], acc)
+               for i, acc in enumerate(out)]
+    return out
 
 
 def decayed_products_bwd(rows, cts, k, gamma, dt):
     """The gradients of `decayed_products` given the (C, C) cotangents
     `cts` (0 above the diagonal): ([dx for x in rows], dk), float32
-    (C, D).  gamma's is sum_x x * dx - k * dk, the caller's."""
-    f32 = jnp.float32
-    row, col = _square_iotas()
-    d = k.shape[1]
+    (C, D).  gamma's is sum_x x * dx - k * dk, the caller's.  Levels all
+    the way down, and the diagonal (no decay) as one more."""
+    pairs = _pair_levels()
     count = len(rows)
-    dk = jnp.zeros((CHUNK, d), f32)
-    slab_col = jax.lax.broadcasted_iota(jnp.int32, (count * SUB, CHUNK), 1)
-    slabs = [jnp.zeros((count * SUB, d), f32)]
-    for first in range(SUB, CHUNK, SUB):
-        er, ec = _slab_scales(gamma, first)
-        c = jnp.concatenate([ct[first:first + SUB, :] for ct in cts], 0)
-        c = jnp.where(slab_col < first, c, 0.0).astype(dt)
-        xr = jnp.concatenate([x[first:first + SUB, :] * er for x in rows], 0)
-        dxr = _dot(c, (k * ec).astype(dt), ((1,), (0,)))
-        slabs.append(dxr * jnp.concatenate([er] * count, 0))
-        dk = dk + _dot(c, xr.astype(dt), ((0,), (0,))) * ec
-    dxs = [jnp.concatenate([s[i * SUB:(i + 1) * SUB] for s in slabs], 0)
-           for i in range(count)]
-    sub = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, d), 0) & (SUB - 1)
-    base = row - (row & (SUB - 1))
-    for j in range(SUB):
-        e = _column_decay(gamma, j, sub >= j)
-        kj = _sub_row(k, j)
-        at = col == base + j
-        into_k = jnp.zeros((CHUNK, d), f32)
-        for i, (x, ct) in enumerate(zip(rows, cts)):
-            t = jnp.sum(jnp.where(at, ct, 0.0), axis=1, keepdims=True) * e
-            dxs[i] = dxs[i] + t * kj
-            into_k = into_k + t * x
-        # a sub-block's rows add up into its row j
-        sums = jax.lax.concatenate([
-            jax.lax.broadcast_in_dim(
-                jnp.sum(into_k[s:s + SUB], axis=0, keepdims=True),
-                (SUB, d), (0, 1)) for s in range(0, CHUNK, SUB)], 0)
-        dk = dk + jnp.where(sub == j, sums, 0.0)
-    return dxs, dk
+
+    def level(on, xs, ks):
+        c = jnp.concatenate([jnp.where(on, ct, 0.0) for ct in cts],
+                            0).astype(dt)
+        return (_dot(c, ks.astype(dt), ((1,), (0,))),
+                _dot(c, jnp.concatenate(xs, 0).astype(dt), ((0,), (0,))))
+
+    dxr, dk = level(pairs == 0, rows, k)
+    for w in _levels(1):
+        e = _level_decay(gamma, w)
+        a, b = level((pairs >> (w.bit_length() - 1)) == 1,
+                     [x * e for x in rows], k * e)
+        dxr, dk = dxr + a * jnp.concatenate([e] * count, 0), dk + b * e
+    return [dxr[i * CHUNK:(i + 1) * CHUNK] for i in range(count)], dk
 
 
 def _triangles():

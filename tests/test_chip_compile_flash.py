@@ -464,9 +464,13 @@ STEP_TEXT = {
     # under one direct q projection with nothing rotated); every other
     # cell keeps its parent's text: an argument at its default appends no
     # op, and the two names `KEPT_RESIDUALS` gained are emitted by no
-    # other step
+    # other step.  Re-pinned by PR 66 (`channel_delta.py`'s decayed
+    # products take a chunk's pairs by levels on the MXU, the forward
+    # down to sub-blocks of 4, the backward all the way; the kernels
+    # lower through the interpreter into this text); every other cell
+    # keeps its parent's text: none builds the op
     "kimilinear-8k":
-    "7678dc76d9b498dedc580b93ed2401ac175adcf422f4d369bf1d5dc555cff521",
+    "3e0cc1d71171408338df1157ff0616498219723251367ee8503d2dd39178187c",
 }
 
 

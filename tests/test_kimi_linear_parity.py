@@ -258,6 +258,58 @@ def test_the_chunked_scan_is_the_sequential_recurrence(lowering, decay):
         (5, 20) if kernel else (0, 0))
 
 
+def products_case(decay):
+    """One head's float32 chunk: the rows kb and q, k, gamma (C, 128),
+    and cotangents of A (strictly lower) and P (lower)."""
+    c, d = channel_delta.CHUNK, channel_delta.HEAD_DIM
+    if decay == "cliff":
+        # -80 a position on a quarter of the lanes: exp(gamma) alone is
+        # 0 from the second position on, a pair two apart underflows
+        g = np.where(np.arange(d) % 4 == 0, -80.0, 0.0) * np.ones((c, 1))
+    else:
+        g = np.asarray(scan_case(c, decay, h=1, d=d)[3])[0]
+    r = np.random.default_rng(3)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    kb, q, k = (f32(r.normal(size=(c, d))) for _ in range(3))
+    cts = [f32(np.tril(r.normal(size=(c, c)), -lower)) for lower in (1, 0)]
+    return [kb, q], k, f32(np.cumsum(g, axis=0)), cts
+
+
+def dense_products(rows, k, gamma):
+    """What `chunk_operands` writes out: the (C, C, Dk) decay tensor."""
+    decay = jnp.exp(jnp.where(
+        gated_delta._lower(channel_delta.CHUNK)[..., None],
+        gamma[:, None, :] - gamma[None, :, :], -jnp.inf))
+    return [jnp.einsum("id,jd,ijd->ij", x, k, decay) for x in rows]
+
+
+@pytest.mark.parametrize("decay", ["mild", "strong", "uneven", "cliff"])
+def test_the_decayed_products_are_the_dense_decay_einsum(decay):
+    """`decayed_products` (what `channel_delta_inverse` runs a head and
+    chunk: the levels' MXU products) for the rows (kb, q) together; the
+    pairs across a cliff come out 0 or tiny, never inf - inf."""
+    rows, k, gamma, _ = products_case(decay)
+    got = channel_delta.decayed_products(rows, k, gamma, jnp.float32)
+    for name, g, w in zip(("a", "p"), got, dense_products(rows, k, gamma)):
+        assert np.isfinite(np.asarray(g)).all(), name
+        assert not np.triu(np.asarray(g), 1).any(), name
+        close(g, w, name, scale=float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("decay", ["mild", "strong", "uneven", "cliff"])
+def test_the_decayed_products_gradients_are_the_dense_einsums(decay):
+    """`decayed_products_bwd` (`channel_delta_operands_bwd`'s) against
+    `jax.vjp` of the dense form: dkb, dq and dk."""
+    rows, k, gamma, cts = products_case(decay)
+    dxs, dk = channel_delta.decayed_products_bwd(rows, cts, k, gamma,
+                                                 jnp.float32)
+    _, vjp = jax.vjp(lambda kb, q, k: dense_products([kb, q], k, gamma),
+                     *rows, k)
+    for name, g, w in zip(("dkb", "dq", "dk"), dxs + [dk], vjp(cts)):
+        assert np.isfinite(np.asarray(g)).all(), name
+        close(g, w, name, scale=float(jnp.abs(w).max()))
+
+
 def test_a_decay_constant_over_the_lanes_gives_gated_deltas_numbers():
     """Gated DeltaNet is the case g_t constant over the lanes: the same
     o, to float32's order of summation, from `gated_delta.py`'s chunks

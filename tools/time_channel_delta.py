@@ -3,6 +3,7 @@
 hold them against the position-by-position recurrence there.
 
     chiprun -- python tools/time_channel_delta.py [--rows 8192] [--heads 32]
+                                                  [--parent CHECKOUT]
 
 One call of 1 x `--rows` positions x `--heads` heads of 128 x 128, q, k,
 v bfloat16, g and beta float32, at `kimilinear-8k`'s shape by default:
@@ -11,10 +12,18 @@ each kernel alone (`channel_delta_inverse`, `_operands_fwd`,
 whole op forward and forward + backward (a VJP against a fixed
 cotangent); milliseconds a call (`--repeats` calls dispatched back to
 back and waited for once, the median of five such rounds after a
-warm-up).  `against_scan`: the kernels in float32 on the first two
-heads against a `lax.scan` over positions, o and the five gradients, as
-the norm of the difference over the norm; `bf16_against_scan` the same
-with bfloat16 operands.  The last stdout line is one JSON object; the
+warm-up).  `inverse_less_substitution` is `channel_delta_inverse` with
+its substitution taken out (A written where (I + A)^-1 goes) and
+`products_share_of_inverse` its share of the whole kernel: the decayed
+products' part, as far as the two add up (the kernel less its
+substitution by the other road, `inverse` - `inverse_less_substitution`,
+is the substitution's).  `against_scan`: the kernels in float32 on the
+first two heads against a `lax.scan` over positions, o and the five
+gradients, as the norm of the difference over the norm;
+`bf16_against_scan` the same with bfloat16 operands.  `--parent`: a
+checkout of the parent commit (`git archive <commit> | tar -x -C
+_parent`), whose `channel_delta.py` is measured the same way in the same
+call, under `"parent"`.  The last stdout line is one JSON object; the
 same line goes to `chiprun_out/time_channel_delta.log`.  It exits
 non-zero off a TPU: a CPU time is no device time.
 """
@@ -85,22 +94,41 @@ def vjp_of(fn):
     return jax.jit(lambda ct, *xs: jax.vjp(fn, *xs)[1](ct))
 
 
-def main():
-    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    parser.add_argument("--rows", type=int, default=8192)
-    parser.add_argument("--heads", type=int, default=32)
-    parser.add_argument("--repeats", type=int, default=5)
-    parser.add_argument("--seed", type=int, default=0)
-    args = parser.parse_args()
-    device = jax.devices()[0]
-    if device.platform != "tpu":
-        print(json.dumps({"error": f"{device.platform} is no TPU"}))
-        return 1
+def load_parent(checkout):
+    """`checkout`'s channel_delta.py as a module beside this tree's: its
+    relative imports are this tree's (`gated_delta.py` and the package)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        cd.__name__ + "_parent",
+        os.path.join(checkout, "paddle_tpu/ops/pallas/channel_delta.py"))
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+def inverse_less_substitution(cd):
+    """`channel_delta_inverse` with A written where (I + A)^-1 goes: its
+    decayed products (A and P) and its reads and writes alone."""
+    def call(*xs):
+        kept = cd._inverse_side_by_side
+        cd._inverse_side_by_side = lambda a, iotas: a
+        try:
+            return cd._inverse_call.__wrapped__(*xs)
+        finally:
+            cd._inverse_side_by_side = kept
+
+    return jax.jit(call)
+
+
+def measure(cd, args):
+    """The kernels' ms a call and the op's errors against the
+    recurrence, by module `cd`."""
     bf16 = jnp.bfloat16
     (q, k, v, g, beta), ct = operands(args.rows, args.heads, args.seed, bf16)
     h = args.heads
-    out = {"device": device.device_kind, "rows": args.rows, "heads": h,
-           "chunk": cd.CHUNK, "ms": {}}
+    out = {"ms": {}}
     m, p = jax.jit(cd._inverse_call)(q, k, k, g)
     ops = jax.jit(cd._operands_fwd_call)(q, k, k, v, g, m)
     dec = jnp.full((h, args.rows // cd.CHUNK, cd.HEAD_DIM), 0.9, jnp.float32)
@@ -108,6 +136,8 @@ def main():
     op = lambda *xs: cd.channel_delta_rule(*xs, use_kernel=True)  # noqa: E731
     for name, fn, xs in [
             ("inverse", jax.jit(cd._inverse_call), (q, k, k, g)),
+            ("inverse_less_substitution", inverse_less_substitution(cd),
+             (q, k, k, g)),
             ("operands_fwd", jax.jit(cd._operands_fwd_call),
              (q, k, k, v, g, m)),
             ("operands_bwd", jax.jit(cd._operands_bwd_call),
@@ -117,6 +147,9 @@ def main():
             ("op_fwd", jax.jit(op), (q, k, v, g, beta)),
             ("op_fwd_bwd", vjp_of(op), (ct, q, k, v, g, beta))]:
         out["ms"][name] = ms_a_call(fn, xs, args.repeats)
+    # the decayed products' share of `channel_delta_inverse`
+    out["products_share_of_inverse"] = (
+        out["ms"]["inverse_less_substitution"] / out["ms"]["inverse"])
 
     def err(got, want):
         got, want = (np.asarray(x, np.float64) for x in (got, want))
@@ -133,6 +166,26 @@ def main():
     got = (jax.jit(op)(*xs16),) + vjp_of(op)(ct32.astype(bf16), *xs16)
     out["bf16_against_scan"] = {n: err(a, b)
                                 for n, a, b in zip(names, got, want)}
+    return out
+
+
+def main():
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--rows", type=int, default=8192)
+    parser.add_argument("--heads", type=int, default=32)
+    parser.add_argument("--repeats", type=int, default=5)
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--parent", help="a checkout of the parent commit: "
+                        "its channel_delta.py is measured in the same call")
+    args = parser.parse_args()
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(json.dumps({"error": f"{device.platform} is no TPU"}))
+        return 1
+    out = {"device": device.device_kind, "rows": args.rows,
+           "heads": args.heads, "chunk": cd.CHUNK, **measure(cd, args)}
+    if args.parent:
+        out["parent"] = measure(load_parent(args.parent), args)
     line = json.dumps(out)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/time_channel_delta.log", "a") as f:
