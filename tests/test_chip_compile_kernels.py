@@ -20,9 +20,53 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import force_mosaic_lowering
-from chip_compile import BF16, F32, I32, I8, _compile, _compile_args, _kernels
+from chip_compile import (BF16, F32, I32, I8, _compile, _compile_args,
+                          _kernels, _lower_args, _precision, _sites)
 
 
+def _latent_attention_lowered(one_chip, dtype):
+    """The gradient of the `latent_attention` op at `joyai-8k`'s shape,
+    lowered for the described chip, with what needs no compile
+    asserted: the backward pass the trace took is the single kernel
+    (the counters), and the step lowers to two kernels by name."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+
+    n, t, heads = 1, 8192, 32
+    impl = get_op_impl("latent_attention")
+
+    def loss(q_nope, q_rope, k_nope, k_rope, v):
+        with jax.named_scope("latent_attention/latent_attention:9"):
+            o = impl(OpContext(jax.random.PRNGKey(0), 0),
+                     {"QNope": [q_nope], "QRope": [q_rope],
+                      "KNope": [k_nope], "KRope": [k_rope], "V": [v]},
+                     {"n_head": heads, "use_pallas": True})["Out"][0]
+        return jnp.sum(o.astype(F32))
+
+    widths = (heads * 128, heads * 64, heads * 128, 64, heads * 128)
+    args = [jax.ShapeDtypeStruct((n, t, w), dtype, sharding=one_chip)
+            for w in widths]
+    lowered, took = _lower_args(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))), *args,
+        precision=_precision(dtype))
+    assert (took["flash_mla_backward_fused"],
+            took["flash_mla_backward_split"]) == (1, 0)
+    assert _sites(lowered) == {"flash_mla_fwd": 1, "flash_mla_dkv": 1}
+    return lowered
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_latent_attention_kernels_at_the_published_shapes_by_their_trace(
+        one_chip, dtype):
+    """Tier-1's stand-in for the two cases below, which are `slow`."""
+    _latent_attention_lowered(one_chip, dtype)
+
+
+# slow, 63 s and 162 s.  The driver's chip runs of `joyai-8k` and
+# `kimilinear-8k` guard the bfloat16 case (a kernel Mosaic refuses is a
+# failed cell); float32 at "highest" is `benchmarks/joyai_parity.py`'s,
+# which no driver's run reaches: nothing on the chip guards it between
+# runs of `-m slow -k latent`.  The stand-in above holds the traces
+@pytest.mark.slow
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
 def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
     """What `joyai-8k`'s step hands the chip's compiler that no other
@@ -40,32 +84,10 @@ def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
     sequence, the rotary key's gradient) Mosaic must take in VMEM in
     both dtypes; no partial of dq (32 heads x 192 = 6144 wide) reaches
     HBM."""
-    from paddle_tpu.core.registry import OpContext, get_op_impl
     from paddle_tpu.observe import cost
-    from paddle_tpu.observe.monitoring import runtime_stats
 
     n, t, heads = 1, 8192, 32
-    impl = get_op_impl("latent_attention")
-
-    def loss(q_nope, q_rope, k_nope, k_rope, v):
-        with jax.named_scope("latent_attention/latent_attention:9"):
-            o = impl(OpContext(jax.random.PRNGKey(0), 0),
-                     {"QNope": [q_nope], "QRope": [q_rope],
-                      "KNope": [k_nope], "KRope": [k_rope], "V": [v]},
-                     {"n_head": heads, "use_pallas": True})["Out"][0]
-        return jnp.sum(o.astype(F32))
-
-    widths = (heads * 128, heads * 64, heads * 128, 64, heads * 128)
-    args = [jax.ShapeDtypeStruct((n, t, w), dtype, sharding=one_chip)
-            for w in widths]
-    prec = "default" if dtype == BF16 else "highest"
-    before = runtime_stats.snapshot()
-    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
-        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
-            .lower(*args).compile()
-    took = runtime_stats.delta(before)
-    assert (took["flash_mla_backward_fused"],
-            took["flash_mla_backward_split"]) == (1, 0)
+    compiled = _latent_attention_lowered(one_chip, dtype).compile()
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
@@ -424,6 +446,48 @@ def test_ssd_scan_kernels_at_the_published_shapes(one_chip, dtype):
     assert not re.search(r"f32\[[0-9,]*256,256\]", text)
 
 
+def _flash_gqa_lowered_under(one_chip, scale):
+    """The gradient of `lfm2-8k`'s and `granite4h-8k`'s attention call
+    (32 / 8 heads of 64, 8192 positions, bfloat16) under `scale`,
+    lowered for the described chip: the single backward kernel by the
+    trace's counters, two kernels by name."""
+    from paddle_tpu.ops.pallas import flash_gqa
+    from paddle_tpu.ops.pallas.flash_attention import \
+        pallas_flash_attention
+
+    n, t, heads, kv = 1, 8192, 32, 8
+    assert flash_gqa.default_blocks(t) == (1024, 1024)
+
+    def loss(q, k, v):
+        with jax.named_scope("full_attention/flash_attention:9"):
+            o = pallas_flash_attention(q, k, v, None, scale, True,
+                                       layout="nthd", n_head=heads,
+                                       n_kv_head=kv)
+        return jnp.sum(o.astype(F32))
+
+    lowered, took = _lower_args(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        *[jax.ShapeDtypeStruct((n, t, h * 64), BF16, sharding=one_chip)
+          for h in (heads, kv, kv)])
+    assert (took["flash_gqa_backward_fused"],
+            took["flash_gqa_backward_split"]) == (1, 0)
+    assert _sites(lowered) == {"flash_gqa_fwd": 1, "flash_gqa_dkv": 1}
+    return lowered
+
+
+@pytest.mark.parametrize("scale", [2.0 ** -6, 64 ** -0.5],
+                         ids=["power_of_two", "root"])
+def test_flash_gqa_under_either_scale_by_its_trace(one_chip, scale):
+    """Tier-1's stand-in for the test below, which is `slow`: under both
+    scales the trace takes the single backward kernel and the step
+    lowers to the same two names."""
+    _flash_gqa_lowered_under(one_chip, scale)
+
+
+# slow, 53 s (two compiles).  The driver's chip runs of `granite4h-8k`
+# (2^-6) and `lfm2-8k` (64^-1/2) guard that Mosaic takes each; that both
+# hand the compiler the SAME kernels at the same cost waits for this test
+@pytest.mark.slow
 def test_flash_gqa_under_a_scale_that_is_a_power_of_two(one_chip):
     """`granite4h-8k`'s attention call: `lfm2-8k`'s geometry (32 / 8
     heads of 64, 8192 positions, bfloat16) under the scale 2^-6 where
@@ -432,30 +496,10 @@ def test_flash_gqa_under_a_scale_that_is_a_power_of_two(one_chip):
     compiler gets are the same three names, forward at the 1024 x 1024
     tiles PR 56 chose and ONE backward kernel, under both scales."""
     from paddle_tpu.observe import cost
-    from paddle_tpu.observe.monitoring import runtime_stats
-    from paddle_tpu.ops.pallas import flash_gqa
-    from paddle_tpu.ops.pallas.flash_attention import \
-        pallas_flash_attention
 
-    n, t, heads, kv = 1, 8192, 32, 8
-    assert flash_gqa.default_blocks(t) == (1024, 1024)
-    args = [jax.ShapeDtypeStruct((n, t, h * 64), BF16, sharding=one_chip)
-            for h in (heads, kv, kv)]
     kernels = {}
     for scale in (2.0 ** -6, 64 ** -0.5):
-        def loss(q, k, v, scale=scale):
-            with jax.named_scope("full_attention/flash_attention:9"):
-                o = pallas_flash_attention(q, k, v, None, scale, True,
-                                           layout="nthd", n_head=heads,
-                                           n_kv_head=kv)
-            return jnp.sum(o.astype(F32))
-
-        before = runtime_stats.snapshot()
-        compiled = _compile_args(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-                                 *args)
-        took = runtime_stats.delta(before)
-        assert (took["flash_gqa_backward_fused"],
-                took["flash_gqa_backward_split"]) == (1, 0)
+        compiled = _flash_gqa_lowered_under(one_chip, scale).compile()
         rows = cost.instruction_costs(cost.compiled_hlo_proto(compiled))
         kernels[scale] = sorted(
             (r["kernel"], r["flops"]) for r in rows if r["kernel"])
@@ -539,7 +583,61 @@ def test_rope_kernels_at_the_published_shapes(one_chip, dtype, shape):
         tile // 4, 4 * t * d * 4)
 
 
-@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def _band_call_lowered(one_chip, dtype, heads, hkv, d, window=None):
+    """The gradient of a causal band call (`heads` query heads over
+    `hkv` key/value heads of `d`, 1 x 16384, under a window or none),
+    lowered for the described chip, with what needs no compile
+    asserted: the single backward kernel is inside its budget at this
+    shape and the trace took it (the counters), and the step lowers to
+    one forward and one backward kernel by name, no `_dq`.  (the lowered
+    function, the counters)"""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    n, t = 1, 16384
+    assert fa.band_backward_fits(t, d)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.pallas_flash_attention(
+            q, k, v, causal=True, layout="nthd", n_head=heads,
+            n_kv_head=hkv, window=window).astype(F32))
+
+    lowered, took = _lower_args(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        *[jax.ShapeDtypeStruct((n, t, h * d), dtype, sharding=one_chip)
+          for h in (heads, hkv, hkv)],
+        precision=_precision(dtype))
+    assert (took["flash_attention_backward_fused"],
+            took["flash_attention_backward_split"]) == (1, 0)
+    prefix = "flash_window_" if window else "flash_"
+    assert _sites(lowered) == {prefix + "fwd": 1, prefix + "dkv": 1}
+    return lowered, took
+
+
+def _head_dim_256_lowered(one_chip, dtype):
+    from paddle_tpu.ops.pallas.flash_attention import (_fwd_vmem_params,
+                                                       band_backward_fits)
+
+    t, d = 16384, 256
+    assert band_backward_fits(t, d) and not band_backward_fits(t + 1024, d)
+    assert bool(_fwd_vmem_params(1024, 1024, d, 4)) and not any(
+        _fwd_vmem_params(1024, 1024, width, size)
+        for width, size in ((256, 2), (128, 4), (128, 2)))
+    return _band_call_lowered(one_chip, dtype, 16, 2, d)[0]
+
+
+def test_grouped_flash_at_head_dim_256_in_float32_by_its_trace(one_chip):
+    """Tier-1's stand-in for `[f32]` below, which is `slow`: both sides
+    of the 48 MiB budget, which forward claims the VMEM limit, the path
+    the trace took and the kernels' names."""
+    _head_dim_256_lowered(one_chip, F32)
+
+
+@pytest.mark.parametrize("dtype", [
+    BF16,
+    # slow, 49 s.  `qwen3next-16k` runs bfloat16; float32 at "highest" is
+    # `benchmarks/qwen3next_parity.py`'s: nothing on the chip guards it
+    # between runs of `-m slow -k head_dim_256`
+    pytest.param(F32, marks=pytest.mark.slow)], ids=["bf16", "f32"])
 def test_grouped_flash_at_head_dim_256_compiles_one_backward_kernel(
         one_chip, dtype):
     """Second half: causal flash attention at 16 query heads of 256
@@ -554,83 +652,23 @@ def test_grouped_flash_at_head_dim_256_compiles_one_backward_kernel(
     default 16 MiB of scoped VMEM too (27.5 MiB: the chip refused the
     call, PR 44) and it claims the limit (`_fwd_vmem_params`), which the
     bfloat16 call does not."""
-    from paddle_tpu.observe.monitoring import runtime_stats
-    from paddle_tpu.ops.pallas.flash_attention import (
-        band_backward_fits, pallas_flash_attention)
-
-    n, t, h, hkv, d = 1, 16384, 16, 2, 256
-    assert band_backward_fits(t, d) and not band_backward_fits(t + 1024, d)
-
-    def loss(q, k, v):
-        return jnp.sum(pallas_flash_attention(
-            q, k, v, causal=True, layout="nthd", n_head=h,
-            n_kv_head=hkv).astype(F32))
-
-    before = runtime_stats.snapshot()
-    args = [jax.ShapeDtypeStruct((n, t, heads * d), dtype, sharding=one_chip)
-            for heads in (h, hkv, hkv)]
-    prec = "default" if dtype == BF16 else "highest"
-    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            *args).compile().as_text()
-    took = runtime_stats.delta(before)
-    from paddle_tpu.ops.pallas.flash_attention import _fwd_vmem_params
-
-    assert bool(_fwd_vmem_params(1024, 1024, d, 4)) and not any(
-        _fwd_vmem_params(1024, 1024, width, size)
-        for width, size in ((256, 2), (128, 4), (128, 2)))
-    assert (took["flash_attention_backward_fused"],
-            took["flash_attention_backward_split"]) == (1, 0)
+    text = _head_dim_256_lowered(one_chip, dtype).compile().as_text()
     assert _kernels(text) == 2
     for kernel in ("flash_fwd", "flash_dkv"):
         assert f"pallas_{kernel}" in text
     assert "pallas_flash_dq" not in text
 
 
-@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
-@pytest.mark.parametrize("heads, window", [(64, 512), (48, None)],
-                         ids=["64h_window512", "48h_full"])
-def test_band_kernels_at_a_head_count_a_layer_type(one_chip, dtype, heads,
-                                                   window):
-    """`laguna-16k`'s two geometries, 1 x 16384 at d_head 128 over 8
-    key/value heads: 64 query heads (groups of 8) under a window of 512
-    keys, whose forward is the whole-band step since PR 60 (grid (8
-    key/value heads, 32 query tiles of 512): the group's eight heads a
-    `fori_loop` over the lane tiles of a (512, 1024) q / o block, two
-    key tiles a step, 64 a head computed, the first query tile's
-    clamped one masked whole), and 48 query heads (groups of SIX) over
-    the whole prefix at 1024 x 1024.  One forward and ONE backward
-    kernel each (24 MiB of dq, dk, dv in VMEM), bfloat16 as the cell
-    runs them and float32 as `benchmarks/laguna_parity.py` does."""
-    from paddle_tpu.observe.monitoring import runtime_stats
+def _head_count_lowered(one_chip, dtype, heads, window):
+    """`_band_call_lowered` at one of `laguna-16k`'s two geometries,
+    with the tiles its rule takes and which kind of call the trace
+    counted."""
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    n, t, hkv, d = 1, 16384, 8, 128
-    assert fa.band_backward_fits(t, d)
-    assert fa._band_blocks(t, None, None, window)[0] == (
+    assert fa._band_blocks(16384, None, None, window)[0] == (
         (512, 512) if window else (1024, 1024))
-
-    def loss(q, k, v):
-        return jnp.sum(fa.pallas_flash_attention(
-            q, k, v, causal=True, layout="nthd", n_head=heads,
-            n_kv_head=hkv, window=window).astype(F32))
-
-    before = runtime_stats.snapshot()
-    args = [jax.ShapeDtypeStruct((n, t, h * d), dtype, sharding=one_chip)
-            for h in (heads, hkv, hkv)]
-    prec = "default" if dtype == BF16 else "highest"
-    with force_mosaic_lowering(), jax.default_matmul_precision(prec):
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            *args).compile().as_text()
-    took = runtime_stats.delta(before)
-    assert (took["flash_attention_backward_fused"],
-            took["flash_attention_backward_split"]) == (1, 0)
-    assert _kernels(text) == 2
-    prefix = "flash_window_" if window else "flash_"
-    for kernel in ("fwd", "dkv"):
-        assert f"pallas_{prefix}{kernel}" in text
-    # dk, dv leave 8 heads wide, never the query heads' width
-    assert f"[{n},{t},{hkv * d}]" in text
+    lowered, took = _band_call_lowered(one_chip, dtype, heads, 8, 128,
+                                       window)
     if window:
         assert (took["flash_window_calls"], took["flash_grouped_calls"]) \
             == (1, 0)
@@ -643,6 +681,43 @@ def test_band_kernels_at_a_head_count_a_layer_type(one_chip, dtype, heads,
     else:
         assert (took["flash_window_calls"], took["flash_grouped_calls"]) \
             == (0, 1)
+    return lowered
+
+
+def test_the_full_layers_band_kernels_in_float32_by_their_trace(one_chip):
+    """Tier-1's stand-in for `[48h_full-f32]` below, which is `slow`."""
+    _head_count_lowered(one_chip, F32, 48, None)
+
+
+@pytest.mark.parametrize("heads, window, dtype", [
+    (64, 512, BF16), (48, None, BF16), (64, 512, F32),
+    # slow, 50 s.  `laguna-16k` runs bfloat16; float32 at "highest" is
+    # `benchmarks/laguna_parity.py`'s: nothing on the chip guards it
+    # between runs of `-m slow -k head_count`
+    pytest.param(48, None, F32, marks=pytest.mark.slow)],
+    ids=["64h_window512-bf16", "48h_full-bf16", "64h_window512-f32",
+         "48h_full-f32"])
+def test_band_kernels_at_a_head_count_a_layer_type(one_chip, dtype, heads,
+                                                   window):
+    """`laguna-16k`'s two geometries, 1 x 16384 at d_head 128 over 8
+    key/value heads: 64 query heads (groups of 8) under a window of 512
+    keys, whose forward is the whole-band step since PR 60 (grid (8
+    key/value heads, 32 query tiles of 512): the group's eight heads a
+    `fori_loop` over the lane tiles of a (512, 1024) q / o block, two
+    key tiles a step, 64 a head computed, the first query tile's
+    clamped one masked whole), and 48 query heads (groups of SIX) over
+    the whole prefix at 1024 x 1024.  One forward and ONE backward
+    kernel each (24 MiB of dq, dk, dv in VMEM), bfloat16 as the cell
+    runs them and float32 as `benchmarks/laguna_parity.py` does."""
+    n, t, hkv, d = 1, 16384, 8, 128
+    text = _head_count_lowered(one_chip, dtype, heads,
+                               window).compile().as_text()
+    assert _kernels(text) == 2
+    prefix = "flash_window_" if window else "flash_"
+    for kernel in ("fwd", "dkv"):
+        assert f"pallas_{prefix}{kernel}" in text
+    # dk, dv leave 8 heads wide, never the query heads' width
+    assert f"[{n},{t},{hkv * d}]" in text
 
 
 @pytest.mark.parametrize("rows, dtype", [(16384, BF16), (16384, F32),

@@ -23,7 +23,7 @@ from paddle_tpu.core.registry import OpContext, get_op_impl
 from paddle_tpu.models import decoder_reference as ref
 from paddle_tpu.ops import moe_dropless
 
-from op_test import run_op
+from op_test import run_op, with_pull_back
 
 E, K, D, H, T = 64, 4, 16, 8, 48
 ROUTING = {"routing": "sigmoid", "norm_topk_prob": True, "top_k": K}
@@ -788,11 +788,13 @@ def test_flash_gqa_matches_dense_attention_forward_and_backward(geometry):
     def kernel(q, k, v):
         return flash_gqa(q, k, v, heads, kv, block_q=bq, block_k=bk)
 
-    np.testing.assert_allclose(kernel(q, k, v), dense_gqa(q, k, v, heads, kv),
-                               rtol=1e-5, atol=1e-5)
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense_gqa(*a, heads, kv) * w),
-                    (0, 1, 2))(q, k, v)
+    # one forward pass each, its pull-back called on the weight (the
+    # gradients of sum(out * w)); the dense form as ONE compiled function
+    out, pull = jax.vjp(kernel, q, k, v)
+    ref, *want = with_pull_back(
+        lambda *a: dense_gqa(*a, heads, kv), w)(q, k, v)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    got = pull(w)
     for name, g, r in zip("qkv", got, want):
         assert g.shape == r.shape           # dk, dv: kv heads wide
         np.testing.assert_allclose(g, r, rtol=2e-5, atol=2e-5,

@@ -263,9 +263,9 @@ def _reference_grads(blocks, block_q, block_k, causal):
     operands of one geometry: once a module, whatever the path, the
     layout and the kernels' dtype."""
     q, k, v, w = _operands(blocks, block_q, block_k)
-    return jax.grad(
+    return jax.jit(jax.grad(
         lambda *a: jnp.sum(_ref_attention(*a, causal=causal) * w),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],

@@ -10,6 +10,8 @@ one-kernel and the two-kernel backward to the bit; what the geometry
 does not take raises.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -74,6 +76,16 @@ def _grads(fn, q, k, v, w):
         lambda q, k, v: jnp.sum(w * fn(q, k, v)), (0, 1, 2))(q, k, v)
 
 
+@functools.cache
+def _dense_grads(length, block_length, hkv):
+    """The weighted sum of the mask written out and its (dq, dk, dv) on
+    `_qkvw(2 * length, hkv, seed=3)`: ONE compiled function, once a
+    (length, block length, head geometry), whatever the tiles."""
+    q, k, v, w = _qkvw(2 * length, hkv, seed=3)
+    return jax.jit(lambda *a: _grads(
+        lambda *b: _dense(*b, hkv, block_length), *a, w))(q, k, v)
+
+
 # (L, B, tile, lanes): one tile a half and four; B = 4 and B = the tile.
 # `lanes` is the quantum of an OWN_BLOCKS visit's squares (128 on the
 # chip: no tile of 16 holds one, so the first five run no such visit);
@@ -107,8 +119,7 @@ def test_the_kernels_match_the_mask_written_out(geometry, hkv, monkeypatch):
                         q, k, v)
     got = pull(w)
     took = runtime_stats.delta(before)
-    want_out, want = _grads(lambda *a: _dense(*a, hkv, block_length),
-                            q, k, v, w)
+    want_out, want = _dense_grads(length, block_length, hkv)
     np.testing.assert_allclose(jnp.sum(w * out), want_out, rtol=2e-5,
                                atol=2e-5)
     for name, g, r in zip("qkv", got, want):

@@ -203,14 +203,19 @@ def _backward_path(monkeypatch, path):
                         {"one_kernel": 1 << 40, "two_kernels": 0}[path])
 
 
-def _grads(args, w, heads, kv, block_q, block_k):
+def _loss(w, heads, kv, block_q, block_k):
     def loss(q, k, v):
         o = flash_gqa.flash_gqa(q, k, v, heads, kv, block_q=block_q,
                                 block_k=block_k)
         return jnp.sum(o.astype(jnp.float32) * w)
 
+    return loss
+
+
+def _grads(args, w, heads, kv, block_q, block_k):
     with jax.default_matmul_precision("highest"):
-        return jax.grad(loss, argnums=(0, 1, 2))(*args)
+        return jax.grad(_loss(w, heads, kv, block_q, block_k),
+                        argnums=(0, 1, 2))(*args)
 
 
 def _took(before):
@@ -220,19 +225,32 @@ def _took(before):
 
 
 @functools.cache
-def _path_grads(path, blocks, block_q, block_k, heads, kv, dtype):
+def _forward(blocks, block_q, block_k, heads, kv, dtype):
     """q, k, v and the weight of one geometry (two sequences, T of
-    `blocks` of the larger block) in float32, and dq, dk, dv through
-    the kernels on `path` with the operands in `dtype`, which the
-    counters must say the traced backward took.  Once a module: the two
-    tests below read the same calls."""
+    `blocks` of the larger block) in float32, and the pull-back of the
+    weighted loss through the kernels with the operands in `dtype`: ONE
+    forward pass a geometry, which both backward paths read (the shape
+    rule is asked when the pull-back is called)."""
     t = blocks * max(block_q, block_k)
     *args, w = operands(2, t, heads, kv, seed=blocks + heads)
-    with pytest.MonkeyPatch.context() as patch:
+
+    with jax.default_matmul_precision("highest"):
+        _, pull = jax.vjp(_loss(w, heads, kv, block_q, block_k),
+                          *(a.astype(dtype) for a in args))
+    return args, w, pull
+
+
+@functools.cache
+def _path_grads(path, blocks, block_q, block_k, heads, kv, dtype):
+    """`_forward`'s operands and weight, and dq, dk, dv through the
+    kernels on `path`, which the counters must say the traced backward
+    took.  Once a module: the two tests below read the same calls."""
+    args, w, pull = _forward(blocks, block_q, block_k, heads, kv, dtype)
+    with pytest.MonkeyPatch.context() as patch, \
+            jax.default_matmul_precision("highest"):
         _backward_path(patch, path)
         before = runtime_stats.snapshot()
-        got = _grads([a.astype(dtype) for a in args], w, heads, kv, block_q,
-                     block_k)
+        got = pull(jnp.ones((), jnp.float32))
         assert _took(before) == ((1, 0) if path == "one_kernel" else (0, 1))
     return args, w, got
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from paddle_tpu.core.registry import OpContext, get_op_impl
 from paddle_tpu.observe.monitoring import runtime_stats
 from paddle_tpu.ops.pallas import KERNEL_COSTS, flash_mla
+from op_test import with_pull_back
 
 NOPE, ROPE = flash_mla.NOPE_DIM, flash_mla.ROPE_DIM
 
@@ -55,15 +56,15 @@ def test_flash_mla_matches_dense_attention_forward_and_backward(geometry):
     n, t, h, bq, bk = geometry
     *args, w = operands(n, t, h)
 
-    def via(fn):
-        return lambda *a: jnp.sum(fn(*a) * w)
-
     def kernel(*a):
         return flash_mla.flash_mla(*a, block_q=bq, block_k=bk)
 
-    np.testing.assert_allclose(kernel(*args), dense(*args), atol=2e-5)
-    got = jax.grad(via(kernel), argnums=range(5))(*args)
-    want = jax.grad(via(dense), argnums=range(5))(*args)
+    # one forward pass each, its pull-back called on the weight: the
+    # gradients of sum(out * w)
+    out, pull = jax.vjp(kernel, *args)
+    ref, *want = with_pull_back(dense, w)(*args)
+    np.testing.assert_allclose(out, ref, atol=2e-5)
+    got = pull(w)
     for name, g, r in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"),
                           got, want):
         assert g.shape == r.shape, name
@@ -81,27 +82,48 @@ def _backward_path(monkeypatch, path):
 
 
 @functools.cache
-def _path_grads(path, blocks, block_q, block_k, heads, dtype):
+def _forward(blocks, block_q, block_k, heads, dtype):
     """The operands and the weight of one geometry (one sequence, T of
-    `blocks` of the larger block) in float32, and all five gradients
-    through the kernels on `path` with the operands in `dtype`, which
-    the counters must say the traced backward took.  Once a module: the
-    two tests below read the same calls."""
+    `blocks` of the larger block) in float32, and the pull-back of the
+    weighted loss through the kernels with the operands in `dtype`: ONE
+    forward pass a geometry, which both backward paths read (the shape
+    rule is asked when the pull-back is called)."""
     t = blocks * max(block_q, block_k)
     *args, w = operands(1, t, heads, seed=blocks + heads)
+    with jax.default_matmul_precision("highest"):
+        _, pull = jax.vjp(
+            lambda *a: jnp.sum(flash_mla.flash_mla(
+                *a, block_q=block_q, block_k=block_k).astype(jnp.float32) * w),
+            *(a.astype(dtype) for a in args))
+    return args, w, pull
+
+
+@functools.cache
+def _path_grads(path, blocks, block_q, block_k, heads, dtype):
+    """`_forward`'s operands and weight, and all five gradients through
+    the kernels on `path`, which the counters must say the traced
+    backward took.  Once a module: the two tests below read the same
+    calls."""
+    args, w, pull = _forward(blocks, block_q, block_k, heads, dtype)
     with pytest.MonkeyPatch.context() as patch, \
             jax.default_matmul_precision("highest"):
         _backward_path(patch, path)
         before = runtime_stats.snapshot()
-        got = jax.grad(
-            lambda *a: jnp.sum(flash_mla.flash_mla(
-                *a, block_q=block_q, block_k=block_k).astype(jnp.float32) * w),
-            argnums=range(5))(*(a.astype(dtype) for a in args))
+        got = pull(jnp.ones((), jnp.float32))
         took = runtime_stats.delta(before)
         assert (took["flash_mla_backward_fused"],
                 took["flash_mla_backward_split"]) == (
                     (1, 0) if path == "one_kernel" else (0, 1))
     return args, w, got
+
+
+@functools.cache
+def _dense_grads(blocks, block_q, block_k, heads):
+    """The reference's gradients on `_path_grads`' float32 operands:
+    once a geometry, whatever the path and the kernels' dtype."""
+    *args, w = operands(1, blocks * max(block_q, block_k), heads,
+                        seed=blocks + heads)
+    return with_pull_back(dense, w)(*args)[1:]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -120,8 +142,7 @@ def test_both_backward_paths_give_the_dense_gradients(
     none), 2 and 4 heads (the rotary key's gradient sums over the
     pairs, the outer axis of the single kernel)."""
     args, w, got = _path_grads(path, blocks, block_q, block_k, heads, dtype)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=range(5))(
-        *args)
+    want = _dense_grads(blocks, block_q, block_k, heads)
     # float32: today's limit; bfloat16 operands: p and ds are cast to
     # 8 bits of mantissa before their dots
     limit = 5e-5 if dtype == jnp.float32 else 4e-2

@@ -19,6 +19,7 @@ import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.observe.monitoring import runtime_stats
 from paddle_tpu.ops import pallas as pallas_tier
+from chip_compile import _state_by_shape
 
 
 def _build(use_recompute):
@@ -171,7 +172,18 @@ FAMILIES = {
     "flash_gqa": ("flash_gqa_fwd", 64, 4, 2, None),     # heads in pairs
     "flash_mla": ("flash_mla_fwd", 128, 2, 2, None),
 }
-LAYERS, TRIPS = 2, 3
+TRIPS = 3
+
+
+def _layers(family):
+    """Two layers where "a call a LAYER" is the mechanism: the plain
+    family's second segment keeps its own residuals beside the first's
+    (and every cell's whole step counts a call a layer by its trace,
+    tests/test_chip_compile_cells.py).  One layer for the other
+    families, whose mechanism is that THEIR forward rule's names reach
+    the policy: a second layer builds and compiles every interpreted
+    kernel twice and proves what the plain family's does."""
+    return 2 if family == "flash" else 1
 
 
 def _proj(x, width, name):
@@ -241,8 +253,8 @@ def _pallas_calls(jaxpr, found=None):
 
 @functools.lru_cache(maxsize=None)
 def _built(family, looped, amp, policy=True, recompute=True):
-    """One build: LAYERS attention layers, each a recompute segment, in
-    a straight stack or as the body of a counted loop of TRIPS trips;
+    """One build: `_layers(family)` attention layers, each a recompute
+    segment, in a straight stack or as the body of a counted loop of TRIPS trips;
     loss and the gradient of every parameter, the kernels of the step's
     jaxpr and the counters around the build."""
     main, startup = fluid.Program(), fluid.Program()
@@ -252,13 +264,16 @@ def _built(family, looped, amp, policy=True, recompute=True):
             fluid.unique_name.guard(), pytest.MonkeyPatch.context() as patch:
         if not policy:
             patch.setattr(pallas_tier, "segment_policy", lambda: None)
-        loss = attention_stack(FAMILIES[family], T, HID, LAYERS,
+        loss = attention_stack(FAMILIES[family], T, HID, _layers(family),
                                TRIPS if looped else 0, recompute)
         if amp:
             main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
         grads = [g for _, g in fluid.append_backward(loss)]
         exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
+        if recompute:
+            exe.run(startup)
+        else:       # traced and never run
+            _state_by_shape(main, scope)
         feed = {"x": np.random.default_rng(5).normal(
             size=(1, T, HID)).astype(np.float32)}
         names = [loss.name] + [g.name for g in grads]
@@ -292,15 +307,15 @@ def test_a_segments_backward_runs_no_forward_kernel_again(family, looped,
     """(a) The step holds each layer's forward kernel ONCE; with the
     policy taken away, twice: the names reach the policy, and without
     it the segment keeps its inputs alone, as before."""
-    forward = FAMILIES[family][0]
+    forward, layers = FAMILIES[family][0], _layers(family)
     kept = _built(family, looped, amp)["kernels"]
     alone = _built(family, looped, amp, policy=False)["kernels"]
-    assert kept.count(forward) == LAYERS
-    assert alone.count(forward) == 2 * LAYERS
+    assert kept.count(forward) == layers
+    assert alone.count(forward) == 2 * layers
     # the backward kernels are the same ones
     assert sorted(k for k in kept if k != forward) \
         == sorted(k for k in alone if k != forward)
-    assert len(kept) == len(alone) - LAYERS
+    assert len(kept) == len(alone) - layers
 
 
 @pytest.mark.parametrize("family, looped, amp", CASES)
@@ -309,7 +324,7 @@ def test_kept_residuals_change_no_bit(family, looped, amp):
     for bit: the same kernels on the same operands in the same order."""
     kept = _built(family, looped, amp)["fetched"]
     alone = _built(family, looped, amp, policy=False)["fetched"]
-    assert len(kept) == len(alone) >= 1 + 4 * LAYERS
+    assert len(kept) == len(alone) >= 1 + 4 * _layers(family)
     assert np.isfinite(kept[0]).all() and any(np.abs(g).max() > 0
                                               for g in kept[1:])
     for got, want in zip(kept, alone):
@@ -328,8 +343,8 @@ def test_the_projections_before_the_kernel_are_still_recomputed(
     plain = _built(family, looped, amp, recompute=False)
     assert kept == alone
     projections = 5 if family == "flash_mla" else 3
-    assert kept >= plain["dots"] + projections * LAYERS
-    assert plain["kernels"].count(FAMILIES[family][0]) == LAYERS
+    assert kept >= plain["dots"] + projections * _layers(family)
+    assert plain["kernels"].count(FAMILIES[family][0]) == _layers(family)
 
 
 @pytest.mark.parametrize("family, looped, amp", CASES)
@@ -340,13 +355,14 @@ def test_the_counters_read_the_kept_calls_and_their_bytes(family, looped,
     operands' dtype + 8 float32 sublanes of logsumexp a head; 0 where
     no segment is open."""
     calls, nbytes = _built(family, looped, amp)["kept"]
-    assert calls == LAYERS
+    layers = _layers(family)
+    assert calls == layers
     _, d, heads, _, _ = FAMILIES[family]
-    assert nbytes == LAYERS * (T * heads * d * (2 if amp else 4)
+    assert nbytes == layers * (T * heads * d * (2 if amp else 4)
                                + heads * 8 * T * 4)
     assert _built(family, looped, amp, recompute=False)["kept"] == (0, 0)
     # the policy is not what counts: the segment's trace is
-    assert _built(family, looped, amp, policy=False)["kept"][0] == LAYERS
+    assert _built(family, looped, amp, policy=False)["kept"][0] == layers
 
 
 @pytest.mark.parametrize("looped", [False, True], ids=["stack", "loop"])

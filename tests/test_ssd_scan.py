@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from paddle_tpu.core.registry import OpContext, get_op_impl
 from paddle_tpu.observe.monitoring import runtime_stats
 from paddle_tpu.ops.pallas import ssd_scan as scan
+from op_test import with_pull_back
 
 TOL = 2e-5
 SLOTS = ("X", "Dt", "ALog", "B", "C", "D", "DtBias")
@@ -82,13 +83,12 @@ def check(xs, kernel, chunk=scan.CHUNK, groups=1, tol=TOL):
     ct = jnp.asarray(np.random.default_rng(9).normal(size=xs[0].shape),
                      jnp.float32)
     before = runtime_stats.snapshot()
-    y, vjp = jax.vjp(functools.partial(op, chunk=chunk, groups=groups), *xs)
-    got = vjp(ct)
+    got = with_pull_back(
+        functools.partial(op, chunk=chunk, groups=groups), ct)(*xs)
     took = runtime_stats.delta(before)
-    want_y, want_vjp = jax.vjp(
-        functools.partial(recurrence, groups=groups), *xs)
-    want = want_vjp(ct)
-    for name, g, w in zip(("y",) + SLOTS, (y,) + got, (want_y,) + want):
+    want = with_pull_back(
+        functools.partial(recurrence, groups=groups), ct)(*xs)
+    for name, g, w in zip(("y",) + SLOTS, got, want):
         w = np.asarray(w)
         assert np.abs(w).max() > 0, name
         np.testing.assert_allclose(np.asarray(g), w, rtol=0,
@@ -159,11 +159,11 @@ def test_db_and_dc_are_the_sums_over_the_heads(kernel):
 
     assert scan.ssd_scan_takes(t, heads, x.shape[2], b.shape[2],
                                chunk=chunk) == kernel
-    y, vjp = jax.vjp(many, b, c)
-    want_y, want_vjp = jax.vjp(one, b, c)
+    y, *got = with_pull_back(many, tiled(ct, 2))(b, c)
+    want_y, *want = with_pull_back(one, ct)(b, c)
     np.testing.assert_allclose(np.asarray(y), np.asarray(tiled(want_y, 2)),
                                rtol=0, atol=TOL * np.abs(want_y).max())
-    for g, w in zip(vjp(tiled(ct, 2)), want_vjp(ct)):
+    for g, w in zip(got, want):
         w = heads * np.asarray(w)
         np.testing.assert_allclose(np.asarray(g), w, rtol=0,
                                    atol=TOL * np.abs(w).max())
