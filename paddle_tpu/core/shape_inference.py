@@ -55,6 +55,18 @@ _SKIP_INFERENCE = {
 }
 
 
+# Depth of `infer_op_shapes` calls in flight: an op evaluated for its
+# shapes alone traces whatever its lowering traces, at the stand-in
+# batch (`rms_norm(group_size=128)` a Pallas pass over a million
+# sequences); a counter of what STEPS trace asks `inferring_shapes()`
+# and does not count then (`ops/pallas/head_norm.py`).
+_inferring = [0]
+
+
+def inferring_shapes() -> bool:
+    return bool(_inferring[0])
+
+
 def infer_op_shapes(op_desc, block) -> bool:
     """Best-effort shape inference for one appended op.  Returns True when
     output VarDescs were updated."""
@@ -90,11 +102,14 @@ def infer_op_shapes(op_desc, block) -> bool:
                         is_test=bool(op_desc.attrs.get("is_test", False)))
         return impl(ctx, abstract_ins, op_desc.attrs)
 
+    _inferring[0] += 1
     try:
         outs = jax.eval_shape(absfn, ins,
                               jax.ShapeDtypeStruct((2,), jnp.uint32))
     except Exception:
         return False  # leave declared shapes; executor will still run it
+    finally:
+        _inferring[0] -= 1
 
     for slot, names in op_desc.outputs.items():
         specs = outs.get(slot, [])

@@ -86,6 +86,7 @@ _FIELDS = Heard._fields[1:] + (
     "gated_delta_inverse_calls",
     "channel_delta_calls", "channel_delta_chunks",
     "channel_delta_operand_calls", "channel_delta_operand_chunks",
+    "head_norm_calls", "head_norm_rows",
     "recompute_kept_residuals", "recompute_kept_bytes",
     "grouped_matmuls_kernel", "grouped_matmuls_xla",
     "short_convs_kernel", "short_convs_xla", "short_conv_bias_calls",
@@ -248,6 +249,13 @@ class RuntimeStats:
         self.channel_delta_chunks = 0
         self.channel_delta_operand_calls = 0
         self.channel_delta_operand_chunks = 0
+        # calls traced of the kernels that take a head's lane statistic
+        # on the flat tensor (`ops/pallas/head_norm.py`: `head_norm_fwd`
+        # / `_bwd`, the l2norm of a delta rule's q and k and the gated
+        # norm a head), and the rows they walk; 0 where the composition
+        # over a (.., H, group) view ran
+        self.head_norm_calls = 0
+        self.head_norm_rows = 0
         # calls traced inside a recompute segment that name what the
         # segment keeps (`ops/pallas keep_residuals`): an attention
         # call, whose backward pass therefore keeps the kernel's two
@@ -453,6 +461,11 @@ class RuntimeStats:
         with self._lock:
             self.channel_delta_operand_calls += 1
             self.channel_delta_operand_chunks += chunks
+
+    def record_head_norm(self, rows: int):
+        with self._lock:
+            self.head_norm_calls += 1
+            self.head_norm_rows += rows
 
     def record_kept_residuals(self, nbytes: int):
         with self._lock:

@@ -38,24 +38,6 @@ def _over_rms(xf, axes, eps):
                           + eps)
 
 
-def _head_norm_under_sigmoid(x, scale, gate, group, eps):
-    """rms_norm a head of `group` lanes, times its scale and the gate's
-    sigmoid, on the (.., H x group) tensor as it lies: the heads' mean
-    squares by `channel_delta.head_sums` (a product with a 0 / 1
-    matrix), no (.., H, group) view, which the chip would re-lay (134 MB
-    a float32 operand at 8192 x 4096, PR 65).  The silu-gated form
-    below keeps its reshape: the step texts of the cells that run it are
-    pinned."""
-    from .pallas.channel_delta import head_spread, head_sums
-
-    f32 = jnp.float32
-    xf = x.astype(f32)
-    heads = x.shape[-1] // group
-    inv = lax.rsqrt(head_sums(xf * xf, heads) / group + eps)
-    y = xf * head_spread(inv, group) * jnp.tile(scale.astype(f32), heads)
-    return (y * jax.nn.sigmoid(gate.astype(f32))).astype(x.dtype)
-
-
 @register_op("rms_norm")
 def rms_norm(ctx, ins, attrs):
     """Y = X * rsqrt(mean(X^2 over the axes from begin_norm_axis) + eps)
@@ -66,28 +48,28 @@ def rms_norm(ctx, ins, attrs):
     scale is 1 + Scale (a Scale that starts at 0 and that weight decay
     pulls to 0 leaves the norm ON).  Gate (X's shape): Y is multiplied
     by silu(Gate), or by sigmoid(Gate) under `gate_activation`
-    "sigmoid", in float32 (the output norm of a gated mixer)."""
+    "sigmoid", in float32 (the output norm of a gated mixer).  Groups
+    of 128 lanes go to the kernels of `ops/pallas/head_norm.py` on the
+    tensor as it lies (`head_norm_takes`), any other to the composition
+    over a (.., H, g) view there; `runtime_stats.head_norm_calls`
+    counts the kernel calls traced."""
     x = first(ins, "X")
     scale = opt_in(ins, "Scale")
     gate = opt_in(ins, "Gate")
     group = attrs.get("group_size")
     if group:
+        from .pallas.head_norm import head_norm
+
         if x.shape[-1] % int(group):
             raise ValueError(f"rms_norm: minor dim {x.shape[-1]} is not "
                              f"whole groups of {group}")
-        if attrs.get("gate_activation", "silu") == "sigmoid" \
-                and gate is not None and scale is not None:
-            return out(Y=_head_norm_under_sigmoid(
-                x, scale, gate, int(group), attrs.get("epsilon", 1e-5)))
-        split = x.shape[:-1] + (-1, int(group))
-        y = rms_norm(ctx, {"X": [x.reshape(split)],
-                           "Scale": ins.get("Scale", []),
-                           "Gate": [g.reshape(split)
-                                    for g in ins.get("Gate", [])]},
-                     {k: v for k, v in attrs.items()
-                      if k in ("epsilon", "zero_centered",
-                               "gate_activation")})["Y"][0]
-        return out(Y=y.reshape(x.shape))
+        # a Pallas pass on the tensor as it lies where a group is a
+        # lane tile, else the composition over a (.., H, g) view
+        return out(Y=head_norm(
+            x, scale, gate, group=int(group), denom=float(group),
+            eps=attrs.get("epsilon", 1e-5),
+            zero_centered=bool(attrs.get("zero_centered")),
+            gate_activation=attrs.get("gate_activation", "silu")))
     begin = attrs.get("begin_norm_axis", -1) % x.ndim
     eps = attrs.get("epsilon", 1e-5)
     axes = tuple(range(begin, x.ndim))
@@ -433,6 +415,20 @@ def latent_attention(ctx, ins, attrs):
                                           n_head, scale))
 
 
+def _unit_q_and_k(qkv, heads, dk):
+    """(l2norm(q) * Dk^-1/2, l2norm(k)) of a delta rule: the first two
+    `heads` x `dk` lanes of QKV (N, T, ..), each head's lanes over the
+    root of their sum of squares + 1e-6, in float32; QKV's dtype.  On
+    QKV as it lies (`ops/pallas/head_norm.py`: no slice of it and no
+    float32 (N, T, H, Dk) view, which the chip would re-lay) where a
+    head is 128 lanes."""
+    from .pallas.head_norm import head_norm
+
+    width = heads * dk
+    return (head_norm(qkv, group=dk, lanes=(0, width), constant=dk ** -0.5),
+            head_norm(qkv, group=dk, lanes=(width, width)))
+
+
 @register_op("gated_delta_rule")
 def gated_delta_rule(ctx, ins, attrs):
     """The mixer core of a gated-delta-rule linear-attention layer
@@ -470,12 +466,7 @@ def gated_delta_rule(ctx, ins, attrs):
             f"gates a value head")
     f32 = jnp.float32
 
-    def l2norm(x):
-        x = x.astype(f32).reshape(n, t, hk, dk)
-        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-    q = (l2norm(qkv[..., :hk * dk]) * dk ** -0.5).astype(qkv.dtype)
-    k = l2norm(qkv[..., hk * dk:2 * hk * dk]).astype(qkv.dtype)
+    q, k = (x.reshape(n, t, hk, dk) for x in _unit_q_and_k(qkv, hk, dk))
     v = qkv[..., 2 * hk * dk:].reshape(n, t, hv, dv)
     beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
     g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
@@ -527,14 +518,7 @@ def channel_delta_rule(ctx, ins, attrs):
             f"value lanes, a decay a key lane and a beta a head")
     f32 = jnp.float32
 
-    def l2norm(x):
-        # a head's sum of squares without a (.., H, Dk) view of x
-        x = x.astype(f32)
-        scale = lax.rsqrt(channel_delta.head_sums(x * x, h) + 1e-6)
-        return x * channel_delta.head_spread(scale, dk)
-
-    q = (l2norm(qkv[..., :h * dk]) * dk ** -0.5).astype(qkv.dtype)
-    k = l2norm(qkv[..., h * dk:2 * h * dk]).astype(qkv.dtype)
+    q, k = _unit_q_and_k(qkv, h, dk)
     rate = jnp.repeat(jnp.exp(a_log.astype(f32)), dk)
     g = -rate * softplus(gate.astype(f32) + dt_bias.astype(f32))
     return out(Out=channel_delta.channel_delta_rule(

@@ -766,20 +766,14 @@ def _scan_vjp_bwd(heads, res, do):
 scan_kernel.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)
 
 
-def head_sums(x, heads):
-    """(.., H x D) -> (.., H): each head's lanes added up, as a product
-    with a 0 / 1 matrix at "highest".  NOT a reshape to (.., H, D) and a
-    reduction: on the chip that reshape re-lays the whole tensor (its
-    tiles hold 8 rows x 128 lanes, the reshaped one's 8 heads x 128
-    lanes: 134 MB a float32 operand at 8192 x 4096, 12 copies a layer
-    in the first cell's first trace, PR 65)."""
-    return jnp.dot(x, _heads_matrix(heads, x.shape[-1] // heads, x.dtype),
-                   precision=_HI)
-
-
 def head_spread(x, lanes):
     """(.., H) -> (.., H x lanes): a head's number on each of its lanes,
-    the transpose of `head_sums`, by the same product."""
+    as a product with a 0 / 1 matrix at "highest".  NOT a broadcast to
+    (.., H, lanes) and a reshape: on the chip that reshape re-lays the
+    whole tensor (its tiles hold 8 rows x 128 lanes, the reshaped one's
+    8 heads x 128 lanes: 134 MB a float32 operand at 8192 x 4096, PR
+    65).  `times_beta` alone reads it; a head's lane SUMS are
+    `head_norm.py`'s."""
     return jnp.dot(x, _heads_matrix(x.shape[-1], lanes, x.dtype).T,
                    precision=_HI)
 

@@ -679,6 +679,12 @@ def _qwen3next_traced(parameters, took):
     # the full layer's (o, logsumexp) and three inverses
     assert took["recompute_kept_residuals"] == 4
     assert took["recompute_kept_bytes"] >= 3 * 134217728
+    # a head's lane statistic (`ops/pallas/head_norm.py`): q's l2norm,
+    # k's and the silu-gated norm a head, three passes a delta layer's
+    # forward, three its forward traced again for the segment's
+    # backward pass, three its backward; three layers of 16384 rows
+    assert (took["head_norm_calls"], took["head_norm_rows"]) == (
+        3 * 9, 3 * 9 * 16384)
 
 
 def _kimilinear_traced(parameters, took):
@@ -697,6 +703,12 @@ def _kimilinear_traced(parameters, took):
     # four layers' (inverse, P) and the latent layer's (o, logsumexp)
     assert took["recompute_kept_residuals"] == 5
     assert took["recompute_kept_bytes"] >= 4 * (67108864 + 33554432)
+    # a head's lane statistic (`ops/pallas/head_norm.py`): q's l2norm,
+    # k's and the sigmoid-gated norm a head, three passes a delta
+    # layer's forward, three its forward traced again for the segment's
+    # backward pass, three its backward; four layers of 8192 rows
+    assert (took["head_norm_calls"], took["head_norm_rows"]) == (
+        4 * 9, 4 * 9 * 8192)
 
 
 # cell -> (what its trace holds, the kernels its step lowers to and
@@ -718,12 +730,14 @@ CELL_TRACES = {
     "qwen3next-16k": (_qwen3next_traced, {
         "gated_delta_inverse", "gated_delta_operands_fwd",
         "gated_delta_operands_bwd", "gated_delta_fwd", "gated_delta_bwd",
+        "head_norm_fwd", "head_norm_bwd",
         "flash_fwd", "flash_dkv", "short_conv_fwd", "short_conv_bwd",
         "rope_fwd", "rope_bwd", "ragged_dot", "rows_to_tokens"}),
     "kimilinear-8k": (_kimilinear_traced, {
         "channel_delta_inverse", "channel_delta_operands_fwd",
         "channel_delta_operands_bwd", "channel_delta_fwd",
-        "channel_delta_bwd", "flash_mla_fwd", "flash_mla_dkv",
+        "channel_delta_bwd", "head_norm_fwd", "head_norm_bwd",
+        "flash_mla_fwd", "flash_mla_dkv",
         "short_conv_fwd", "short_conv_bwd", "ragged_dot",
         "rows_to_tokens"}),
 }
@@ -940,3 +954,6 @@ def test_the_linear_attention_cells_step_keeps_its_inverses_under_the_plan(
             kernels["gated_delta_operands_bwd"]) == (3, 6, 3)
     assert (kernels["gated_delta_fwd"], kernels["gated_delta_bwd"]) == (6, 3)
     assert (kernels["flash_fwd"], kernels["flash_dkv"]) == (1, 1)
+    # q's, k's and the output norm's head statistic in a linear layer's
+    # forward and recomputed forward, and their one backward pass (PR 68)
+    assert (kernels["head_norm_fwd"], kernels["head_norm_bwd"]) == (18, 9)

@@ -416,9 +416,16 @@ STEP_TEXT = {
     # (16 / 2 heads of 256, 48 MiB of dq, dk, dv) is inside the single
     # kernel's budget, one `flash_dkv` with three results and no
     # `flash_dq` (parent: cdf57c01..); the eleven other cells' calls
-    # were inside the old budget and keep their text
+    # were inside the old budget and keep their text.
+    # Re-pinned, PR 68, with `kimilinear-8k` below: q's and k's l2norm
+    # and the gated norm a head are the kernels of
+    # `ops/pallas/head_norm.py` on the flat tensor, here through the
+    # interpreter, no float32 (.., H, 128) view and no product with a
+    # 0 / 1 matrix (parents: 9df7a66e.., 3e0cc1d7..); the twelve other
+    # cells build neither delta rule nor a grouped `rms_norm` and keep
+    # their text
     "qwen3next-16k":
-    "9df7a66ef15df504b925b32bd386d11d9d203e467d5f508e39f8031ac7990420",
+    "068ea25cc30818c09bd46c533d40654ac00a7d2f89cc0a298e75dfa290d02b0f",
     # re-pinned, PR 59: its block-diffusion flash kernels walk a
     # scalar-prefetched list of visits (`ops/pallas/
     # flash_block_diffusion.py`, here through the interpreter, a pass
@@ -459,7 +466,7 @@ STEP_TEXT = {
     # lower through the interpreter into this text); every other cell
     # keeps its parent's text: none builds the op
     "kimilinear-8k":
-    "3e0cc1d71171408338df1157ff0616498219723251367ee8503d2dd39178187c",
+    "96c8ef2e7ab03c97bd5bccfe096e24626c963aea9a703c98006766ff37aa61e3",
 }
 
 
@@ -554,3 +561,6 @@ def test_the_channel_delta_cells_step_holds_its_kernels_under_the_plan(
     assert (kernels["flash_mla_fwd"], kernels["flash_mla_dkv"],
             kernels["flash_mla_dq"]) == (1, 1, 0)
     assert (kernels["short_conv_fwd"], kernels["short_conv_bwd"]) == (8, 4)
+    # q's, k's and the output norm's head statistic in a delta layer's
+    # forward and recomputed forward, and their one backward pass (PR 68)
+    assert (kernels["head_norm_fwd"], kernels["head_norm_bwd"]) == (24, 12)

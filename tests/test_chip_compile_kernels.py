@@ -2,7 +2,8 @@
 attention (`ops/pallas/flash_mla.py`) at `joyai-8k`'s shape, the chunked
 delta-rule scan (`ops/pallas/gated_delta.py`) and grouped flash
 attention at d_head 256 at `qwen3next-16k`'s, the lane-decayed delta rule
-(`ops/pallas/channel_delta.py`) at `kimilinear-8k`'s, the scalar-a-head scan
+(`ops/pallas/channel_delta.py`) at `kimilinear-8k`'s, a head's lane
+statistic (`ops/pallas/head_norm.py`) at both, the scalar-a-head scan
 (`ops/pallas/ssd_scan.py`) and grouped flash attention under a scale of
 2^-6 at `granite4h-8k`'s, the short convolution
 (`ops/pallas/short_conv.py`) at that cell's and `lfm2-8k`'s, the fused
@@ -153,13 +154,20 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     assert took["gated_delta_inverse_calls"] == 1
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
+    # q's and k's l2norm, each a head-statistic pass forward and one
+    # backward on QKV as it lies (`ops/pallas/head_norm.py`), under the
+    # op's own scope
+    assert (took["head_norm_calls"], took["head_norm_rows"]) == (4, 4 * t)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
         "gated_delta_bwd", "gated_delta_fwd", "gated_delta_inverse",
-        "gated_delta_operands_bwd", "gated_delta_operands_fwd"]
+        "gated_delta_operands_bwd", "gated_delta_operands_fwd",
+        "head_norm_bwd", "head_norm_bwd", "head_norm_fwd", "head_norm_fwd"]
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "gated_delta_rule"}
     totals = cost.total_costs(proto)
-    assert totals["custom_calls"] == totals["pallas_matched"] == 5
+    assert totals["custom_calls"] == totals["pallas_matched"] == 9
+    # no float32 view of q or k a head: nothing for the chip to re-lay
+    assert f"f32[{n},{t},{hk},{d}]" not in compiled.as_text()
     # no scan reader may take the chunk-local kernels for scan kernels:
     # they match by prefix (`benchmarks/kernel_counts.py kernel_ms_per_step`)
     scan = [r for r in rows if (r["kernel"] or "").startswith(
@@ -226,13 +234,18 @@ def test_channel_delta_kernels_at_the_published_shapes(one_chip, dtype):
     assert took["gated_delta_calls"] == 0
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
+    # q's and k's l2norm, each a head-statistic pass forward and one
+    # backward on QKV as it lies (`ops/pallas/head_norm.py`), under the
+    # op's own scope
+    assert (took["head_norm_calls"], took["head_norm_rows"]) == (4, 4 * t)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
         "channel_delta_bwd", "channel_delta_fwd", "channel_delta_inverse",
-        "channel_delta_operands_bwd", "channel_delta_operands_fwd"]
+        "channel_delta_operands_bwd", "channel_delta_operands_fwd",
+        "head_norm_bwd", "head_norm_bwd", "head_norm_fwd", "head_norm_fwd"]
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "channel_delta_rule"}
     totals = cost.total_costs(proto)
-    assert totals["custom_calls"] == totals["pallas_matched"] == 5
+    assert totals["custom_calls"] == totals["pallas_matched"] == 9
     scan = (3 + 6) * 2 * 64 * d * d + (1 + 2) * 2 * 64 * 64 * d
     local = 64 * ((2 + 2 + 8) * 2 * 64 * d + 2 * 64 * 64 / 3
                   + 2 * 2 * 64 * 64)
@@ -581,6 +594,75 @@ def test_rope_kernels_at_the_published_shapes(one_chip, dtype, shape):
     # nothing as large as X is written between the kernels
     assert compiled.memory_analysis().temp_size_in_bytes < max(
         tile // 4, 4 * t * d * 4)
+
+
+# rows, heads, the gate's squash: the output norm a head of the two
+# delta-rule mixers
+HEAD_NORM_SHAPES = {
+    "16384_x_32_silu": (16384, 32, "silu"),
+    "8192_x_32_sigmoid": (8192, 32, "sigmoid"),
+}
+
+
+@pytest.mark.parametrize("shape", list(HEAD_NORM_SHAPES))
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_head_norm_kernels_at_the_published_shapes(one_chip, dtype, shape):
+    """`rms_norm(group_size=128)` under a gate and its gradient at the
+    shapes of `qwen3next-16k`'s and `kimilinear-8k`'s output norms (32
+    heads of 128, silu and sigmoid), in the cells' bfloat16 and the
+    parity scripts' float32: TWO Mosaic kernels, `head_norm_fwd` and
+    `head_norm_bwd` (which recomputes a head's rstd from X), each with
+    a registered cost in bytes and no FLOP, under the op's scope; no
+    dot, no float32 view a head and nothing as large as X between the
+    kernels.  (The l2norm form, X a lane range of QKV, compiles inside
+    the two delta-rule ops' cases above.)"""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+    from paddle_tpu.observe import cost
+    from paddle_tpu.observe.monitoring import runtime_stats
+    from paddle_tpu.ops.pallas import head_norm
+
+    t, heads, squash = HEAD_NORM_SHAPES[shape]
+    d = 128
+    impl = get_op_impl("rms_norm")
+
+    def both(x, w, gate, ct):
+        def fn(x, w, gate):
+            with jax.named_scope("linear_attention/rms_norm:7"):
+                return impl(OpContext(jax.random.PRNGKey(0), 0),
+                            {"X": [x], "Scale": [w], "Gate": [gate]},
+                            {"group_size": d, "epsilon": 1e-6,
+                             "gate_activation": squash})["Y"][0]
+
+        o, vjp = jax.vjp(fn, x, w, gate)
+        return o, vjp(ct)
+
+    wide = jax.ShapeDtypeStruct((1, t, heads * d), dtype, sharding=one_chip)
+    before = runtime_stats.snapshot()
+    compiled = _compile_args(
+        jax.jit(both), wide,
+        jax.ShapeDtypeStruct((d,), F32, sharding=one_chip), wide, wide)
+    took = runtime_stats.delta(before)
+    assert (took["head_norm_calls"], took["head_norm_rows"]) == (2, 2 * t)
+    proto = cost.compiled_hlo_proto(compiled)
+    rows = cost.instruction_costs(proto)
+    assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
+        "head_norm_bwd", "head_norm_fwd"]
+    assert {r["op_type"] for r in rows if r["op_type"]} == {"rms_norm"}
+    assert not any(r["bucket"] in ("matmul", "conv") for r in rows)
+    totals = cost.total_costs(proto)
+    assert totals["custom_calls"] == totals["pallas_matched"] == 2
+    assert totals["pallas_flops"] == 0
+    # X, Gate and Y forward; X, dY, Gate, dX and dGate backward, once
+    # each; the scale, and backward its 8 sublanes of partial sums a
+    # grid step
+    tile = t * heads * d * (2 if dtype == BF16 else 4)
+    by = {r["kernel"]: r["bytes"] for r in rows if r["kernel"]}
+    assert by["head_norm_fwd"] == 3 * tile + d * 4
+    tr, lb = head_norm._tiles(t, heads * d, 0, tile // (t * heads * d), 5)
+    assert by["head_norm_bwd"] == 5 * tile + d * 4 + (
+        (t // tr) * (heads * d // lb) * 8 * d * 4)
+    assert f"f32[1,{t},{heads},{d}]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < tile // 4
 
 
 def _band_call_lowered(one_chip, dtype, heads, hkv, d, window=None):
