@@ -415,20 +415,6 @@ def latent_attention(ctx, ins, attrs):
                                           n_head, scale))
 
 
-def _unit_q_and_k(qkv, heads, dk):
-    """(l2norm(q) * Dk^-1/2, l2norm(k)) of a delta rule: the first two
-    `heads` x `dk` lanes of QKV (N, T, ..), each head's lanes over the
-    root of their sum of squares + 1e-6, in float32; QKV's dtype.  On
-    QKV as it lies (`ops/pallas/head_norm.py`: no slice of it and no
-    float32 (N, T, H, Dk) view, which the chip would re-lay) where a
-    head is 128 lanes."""
-    from .pallas.head_norm import head_norm
-
-    width = heads * dk
-    return (head_norm(qkv, group=dk, lanes=(0, width), constant=dk ** -0.5),
-            head_norm(qkv, group=dk, lanes=(width, width)))
-
-
 @register_op("gated_delta_rule")
 def gated_delta_rule(ctx, ins, attrs):
     """The mixer core of a gated-delta-rule linear-attention layer
@@ -450,7 +436,11 @@ def gated_delta_rule(ctx, ins, attrs):
     where two value heads read a key head, the chunk-local part to its
     own two (a chunk's matrices then stay in VMEM); without it the
     first is a `lax.scan` over the chunks and the second XLA's batch
-    over all of them."""
+    over all of them.  q and k go in as the projection wrote them, QKV
+    twice with their first lanes (`gated_delta.RawQK`: no slice and no
+    float32 (N, T, H, Dk) view, which the chip would re-lay): the
+    chunk-local kernels take the l2norm of the head they hold, any
+    other lowering goes through `ops/pallas/head_norm.py` first."""
     from .pallas import gated_delta
 
     qkv, ba = first(ins, "QKV"), first(ins, "BA")
@@ -466,13 +456,13 @@ def gated_delta_rule(ctx, ins, attrs):
             f"gates a value head")
     f32 = jnp.float32
 
-    q, k = (x.reshape(n, t, hk, dk) for x in _unit_q_and_k(qkv, hk, dk))
     v = qkv[..., 2 * hk * dk:].reshape(n, t, hv, dv)
     beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
     g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
         ba[..., hv:].astype(f32) + dt_bias.astype(f32))
     o = gated_delta.gated_delta_rule(
-        q, k, v, g, beta, use_kernel=bool(attrs.get("use_pallas", False)))
+        qkv, qkv, v, g, beta, use_kernel=bool(attrs.get("use_pallas", False)),
+        raw=gated_delta.RawQK(q=0, k=hk * dk, heads=hk, dim=dk))
     return out(Out=o.reshape(n, t, hv * dv))
 
 
@@ -501,7 +491,8 @@ def channel_delta_rule(ctx, ins, attrs):
     chunk blocks of 8): the five Pallas kernels there, or XLA's batch
     over the chunks and a `lax.scan`.  `runtime_stats.channel_delta_calls`
     / `_operand_calls` count the kernel calls traced; a call that fell
-    back reads 0."""
+    back reads 0.  q and k go in as the projection wrote them, as
+    `gated_delta_rule`'s do: the chunk-local kernels take the l2norm."""
     from .pallas import channel_delta
     from .pallas.selective_scan import softplus
 
@@ -518,12 +509,12 @@ def channel_delta_rule(ctx, ins, attrs):
             f"value lanes, a decay a key lane and a beta a head")
     f32 = jnp.float32
 
-    q, k = _unit_q_and_k(qkv, h, dk)
     rate = jnp.repeat(jnp.exp(a_log.astype(f32)), dk)
     g = -rate * softplus(gate.astype(f32) + dt_bias.astype(f32))
     return out(Out=channel_delta.channel_delta_rule(
-        q, k, qkv[..., 2 * h * dk:], g, jax.nn.sigmoid(beta.astype(f32)),
-        use_kernel=channel_delta.kernel_takes(h, dk, dv, t)))
+        qkv, qkv, qkv[..., 2 * h * dk:], g, jax.nn.sigmoid(beta.astype(f32)),
+        use_kernel=channel_delta.kernel_takes(h, dk, dv, t),
+        raw=channel_delta.RawQK(q=0, k=h * dk, heads=h, dim=dk)))
 
 
 @register_op("selective_scan")

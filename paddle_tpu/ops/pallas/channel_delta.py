@@ -73,6 +73,15 @@ product with a 0 / 1 triangle at "highest" inside the kernel: no float32
   +x to gamma_i and -x to gamma_j, lane by lane), and dg its suffix sum,
   again a product with a triangle.
 
+With `raw` (a `gated_delta.RawQK`: what the op hands them since PR 69)
+the three read q and k AS THE PROJECTION WROTE THEM, QKV (N, T, W) twice
+by lane block, and kb = beta x the RAW k: a chunk takes the l2norm of
+its rows as it reads them (`_unit_heads`; kb takes k's 1 / norm
+there) and `_operands_bwd` returns the gradients of
+the raw lanes and of the raw kb (`gated_delta._raw_gradient`; what
+reaches k's norm through kb rides k's).  Unit q and k (no `raw`) remain
+the tests', the references' and `tools/time_*`'s entry.
+
 **The sequential part** reads S: grid (batch x head, blocks of chunks),
 the state TRANSPOSED, (Dv, Dk) float32, in VMEM scratch across the grid
 (so that the decay of a chunk, a row of Dk lanes, scales it along the
@@ -104,10 +113,13 @@ import jax.numpy as jnp
 
 # the chunk arithmetic's structure is `gated_delta.py`'s: its pure
 # functions and its kernels' plumbing serve both files
-from .gated_delta import (CHUNK, _HI, _block_chunks, _chunk_rows, _dot,
-                          _dot_hi, _for_each_chunk, _inverse_side_by_side,
-                          _lower, _pallas_call, _params, _rows, _suffix_sum,
-                          _tile_iotas, unit_lower_inverse)
+from .gated_delta import (CHUNK, _HI, _Q_SCALE, RawQK, _block_chunks,
+                          _chunk_rows, _dot, _dot_hi, _for_each_chunk,
+                          _inverse_side_by_side, _lower, _pallas_call,
+                          _l2norm, _params, _raw_gradient, _rows, _suffix_sum,
+                          _tile_iotas, ranged_bytes, unit_lower_inverse,
+                          unit_q_and_k)
+from .head_norm import lane_range_gradient
 
 # positions of a diagonal sub-block the forward takes by columns: where
 # its halving stops (the backward's goes all the way: `decayed_products`)
@@ -157,29 +169,35 @@ def scan_bwd_cost(operand_shapes, result_shapes):
     return bh * t * (6 * 2.0 * dk * dv + 2 * 2.0 * CHUNK * dv), None
 
 
-def _rows_heads(operand_shapes):
-    (n, t, width), _ = operand_shapes[0]
-    return n * t * (width // HEAD_DIM)
+def _operand_cost(a_row, operand_shapes, result_shapes):
+    """`a_row` FLOP a position and head, by kb (the third operand: q
+    and k may come inside the projection, and count as their lanes)."""
+    (n, t, width), _ = operand_shapes[2]
+    return n * t * (width // HEAD_DIM) * a_row, ranged_bytes(
+        operand_shapes, result_shapes, width)
 
 
 def inverse_cost(operand_shapes, result_shapes):
     """A and P (a decayed product each) and the substitution's C^2 / 3
     multiply-adds a row."""
-    return _rows_heads(operand_shapes) * (
-        2 * 2.0 * CHUNK * HEAD_DIM + 2.0 * CHUNK * CHUNK / 3), None
+    return _operand_cost(
+        2 * 2.0 * CHUNK * HEAD_DIM + 2.0 * CHUNK * CHUNK / 3,
+        operand_shapes, result_shapes)
 
 
 def operands_fwd_cost(operand_shapes, result_shapes):
     """W and U."""
-    return _rows_heads(operand_shapes) * 2 * 2.0 * CHUNK * HEAD_DIM, None
+    return _operand_cost(2 * 2.0 * CHUNK * HEAD_DIM, operand_shapes,
+                         result_shapes)
 
 
 def operands_bwd_cost(operand_shapes, result_shapes):
     """dM (two products), dkbg, dvb, the two decayed products' row and
     column sides (four), of 2 C D a row; the inverse's gradient, two of
     2 C C."""
-    return _rows_heads(operand_shapes) * (
-        8 * 2.0 * CHUNK * HEAD_DIM + 2 * 2.0 * CHUNK * CHUNK), None
+    return _operand_cost(
+        8 * 2.0 * CHUNK * HEAD_DIM + 2 * 2.0 * CHUNK * CHUNK,
+        operand_shapes, result_shapes)
 
 
 def _register_costs():
@@ -348,22 +366,46 @@ def _head(x, h):
     return x[:, h * HEAD_DIM:(h + 1) * HEAD_DIM]
 
 
-def _inverse_kernel(q_ref, k_ref, kb_ref, g_ref, m_ref, p_ref, *,
-                    block_chunks):
+def _unit_heads(q, k, kb, raw):
+    """[(q, k, kb, q's rstd, k's rstd) a head of the pair], float32
+    (C, 128) and (C, 1), of a chunk's (C, 256) blocks as they were read.
+    Without `raw` the blocks hold unit q and k and kb = beta k (the
+    rstds are None).  With it they hold the projection's q and k and kb
+    = beta times the RAW k, and the l2norm is taken here
+    (`gated_delta._l2norm`), kb taking k's 1 / norm.  A chunk at a
+    time, NOT a grid step's rows at once into scratch as
+    `gated_delta._head_rows` does: these kernels' chunks hide the
+    reduction behind gamma's products, and the three (256, 256) float32
+    buffers cost more than they saved (the layer's four calls 11.69 ms
+    this way, 11.98 that way, 13.75 on unit operands with their six
+    `head_norm_*` calls: my chip runs, PR 69, calls 1 and 2)."""
     f32 = jnp.float32
+    heads = []
+    for h in range(PAIR):
+        qh, kh, kbh = (_head(x, h).astype(f32) for x in (q, k, kb))
+        q_rstd = k_rstd = None
+        if raw:
+            qh, q_rstd = _l2norm(qh, _Q_SCALE)
+            kh, k_rstd = _l2norm(kh)
+            kbh = kbh * k_rstd
+        heads.append((qh, kh, kbh, q_rstd, k_rstd))
+    return heads
+
+
+def _inverse_kernel(q_ref, k_ref, kb_ref, g_ref, m_ref, p_ref, *,
+                    block_chunks, raw):
     iotas = _tile_iotas()
     row, col, _ = iotas
     upto, _ = _triangles()
 
     def chunk(c):
         r = _chunk_rows(c)
-        q, k, kb = q_ref[0, r, :], k_ref[0, r, :], kb_ref[0, r, :]
         gamma = _dot_hi(upto, g_ref[0, r, :], ((1,), (0,)))
         a = []
-        for h in range(PAIR):
-            ah, ph = decayed_products(
-                [_head(kb, h).astype(f32), _head(q, h).astype(f32)],
-                _head(k, h).astype(f32), _head(gamma, h), k.dtype)
+        for h, (q, k, kb, _, _) in enumerate(_unit_heads(
+                q_ref[0, r, :], k_ref[0, r, :], kb_ref[0, r, :], raw)):
+            ah, ph = decayed_products([kb, q], k, _head(gamma, h),
+                                      k_ref.dtype)
             a.append(ah)
             p_ref[h, r, :] = ph.astype(p_ref.dtype)
         a = jnp.where(row > col, jnp.concatenate(a, axis=1), 0.0)
@@ -373,35 +415,33 @@ def _inverse_kernel(q_ref, k_ref, kb_ref, g_ref, m_ref, p_ref, *,
 
 
 def _operands_fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, w_ref,
-                         u_ref, qg_ref, kd_ref, *, block_chunks):
-    f32 = jnp.float32
+                         u_ref, qg_ref, kd_ref, *, block_chunks, raw):
     upto, after = _triangles()
 
     def chunk(c):
         r = _chunk_rows(c)
         g = g_ref[0, r, :]
         dt = k_ref.dtype
-        e = jnp.exp(_dot_hi(upto, g, ((1,), (0,))))
+        e2 = jnp.exp(_dot_hi(upto, g, ((1,), (0,))))
         left = jnp.exp(_dot_hi(after, g, ((1,), (0,))))
-        qg = q_ref[0, r, :].astype(f32) * e
-        kd = k_ref[0, r, :].astype(f32) * left
-        kbg = (kb_ref[0, r, :].astype(f32) * e).astype(dt)
         m = m_ref[0, r, :].astype(dt)
-        for h in range(PAIR):
+        for h, (q, k, kb, _, _) in enumerate(_unit_heads(
+                q_ref[0, r, :], k_ref[0, r, :], kb_ref[0, r, :], raw)):
+            e = _head(e2, h)
             mh = m[:, h * CHUNK:(h + 1) * CHUNK]
-            w_ref[h, r, :] = _dot(mh, _head(kbg, h),
+            w_ref[h, r, :] = _dot(mh, (kb * e).astype(dt),
                                   ((1,), (0,))).astype(dt)
             u_ref[h, r, :] = _dot(mh, _head(vb_ref[0, r, :], h),
                                   ((1,), (0,))).astype(dt)
-            qg_ref[h, r, :] = _head(qg, h).astype(dt)
-            kd_ref[h, r, :] = _head(kd, h).astype(dt)
+            qg_ref[h, r, :] = (q * e).astype(dt)
+            kd_ref[h, r, :] = (k * _head(left, h)).astype(dt)
 
     _for_each_chunk(block_chunks, chunk)
 
 
 def _operands_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, dw_ref,
                          du_ref, dqg_ref, dkd_ref, dp_ref, dq_ref, dk_ref,
-                         dkb_ref, dvb_ref, dg_ref, *, block_chunks):
+                         dkb_ref, dvb_ref, dg_ref, *, block_chunks, raw):
     f32 = jnp.float32
     row, col = _square_iotas()
     upto, after = _triangles()
@@ -413,13 +453,11 @@ def _operands_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, dw_ref,
         gamma2 = _dot_hi(upto, g, ((1,), (0,)))
         e2 = jnp.exp(gamma2)
         left2 = jnp.exp(_dot_hi(after, g, ((1,), (0,))))
-        q2, k2 = q_ref[0, r, :].astype(f32), k_ref[0, r, :].astype(f32)
-        kb2, vb2 = kb_ref[0, r, :].astype(f32), vb_ref[0, r, :]
-        m2 = m_ref[0, r, :]
+        vb2, m2 = vb_ref[0, r, :], m_ref[0, r, :]
         dq, dk, dkb, dvb, dgamma, drest = [], [], [], [], [], []
-        for h in range(PAIR):
-            q, k, kb, gamma, e, left = (_head(x, h) for x in (
-                q2, k2, kb2, gamma2, e2, left2))
+        for h, (q, k, kb, q_rstd, k_rstd) in enumerate(_unit_heads(
+                q_ref[0, r, :], k_ref[0, r, :], kb_ref[0, r, :], raw)):
+            gamma, e, left = (_head(x, h) for x in (gamma2, e2, left2))
             vb = _head(vb2, h)
             m = m2[:, h * CHUNK:(h + 1) * CHUNK]
             md = m.astype(dt)
@@ -437,9 +475,17 @@ def _operands_bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, m_ref, dw_ref,
             dp = jnp.where(row >= col, dp_ref[h, r, :].astype(f32), 0.0)
             (dkb_a, dq_p), dk_ap = decayed_products_bwd(
                 [kb, q], [da, dp], k, gamma, dt)
-            dq.append(dqg * e + dq_p)
-            dk.append(dkd * left + dk_ap)
-            dkb.append(dkbg * e + dkb_a)
+            dqh, dkh = dqg * e + dq_p, dkd * left + dk_ap
+            dkbh = dkbg * e + dkb_a
+            if raw:     # the l2norm's rule; kb = (beta k) / |k| rides k's
+                dqh = _raw_gradient(dqh, q, q_rstd, _Q_SCALE)
+                dkh = _raw_gradient(
+                    dkh, k, k_rstd,
+                    through=jnp.sum(dkbh * kb, axis=1, keepdims=True))
+                dkbh = dkbh * k_rstd
+            dq.append(dqh)
+            dk.append(dkh)
+            dkb.append(dkbh)
             dgamma.append(dkbg * kbg + dqg * q * e + kb * dkb_a + q * dq_p
                           - k * dk_ap)
             drest.append(dkd * k * left)
@@ -458,88 +504,108 @@ def _operand_block_chunks(nc):
     return max(b for b in range(1, OPERAND_BLOCK_CHUNKS + 1) if nc % b == 0)
 
 
-def _operand_specs(pairs, bc):
-    """A pair of heads' lanes of (N, T, H x 128); their (I + A)^-1 side
+def _operand_specs(pairs, bc, raw=None):
+    """A pair of heads' lanes of (N, T, H x 128): q's and k's (inside
+    the arrays `raw` describes) and the others'; their (I + A)^-1 side
     by side; their rows of (N H, T, width)."""
     from jax.experimental import pallas as pl
 
     rows = bc * CHUNK
-    lanes = pl.BlockSpec((1, rows, PAIR * HEAD_DIM),
-                         lambda b, i: (b // pairs, i, b % pairs))
+
+    def lanes(start=0):
+        first = start // (PAIR * HEAD_DIM)
+        return pl.BlockSpec((1, rows, PAIR * HEAD_DIM),
+                            lambda b, i: (b // pairs, i, first + b % pairs))
+
     inverse = pl.BlockSpec((1, rows, PAIR * CHUNK), lambda b, i: (b, i, 0))
 
     def heads(width):
         return pl.BlockSpec((PAIR, rows, width), lambda b, i: (b, i, 0))
 
-    return lanes, inverse, heads(HEAD_DIM), heads(CHUNK)
+    return (lanes(raw.q) if raw else lanes(), lanes(raw.k) if raw else lanes(),
+            lanes(), inverse, heads(HEAD_DIM), heads(CHUNK))
 
 
-def _operand_grid(k):
-    n, t, width = k.shape
+def _operand_grid(kb, raw):
+    """By kb, which is (N, T, H x 128) whatever arrays hold q and k."""
+    n, t, width = kb.shape
     pairs, nc = width // (PAIR * HEAD_DIM), t // CHUNK
+    if raw and (raw.heads, raw.dim, raw.q % (PAIR * HEAD_DIM),
+                raw.k % (PAIR * HEAD_DIM)) != (PAIR * pairs, HEAD_DIM, 0, 0):
+        raise ValueError(f"channel_delta kernels: {raw} is not kb's "
+                         f"{PAIR * pairs} heads of {HEAD_DIM} lanes in pairs")
     bc = _operand_block_chunks(nc)
     return n, t, pairs, bc, (n * pairs, nc // bc)
 
 
-@functools.partial(jax.jit, static_argnames=("interpreted",))
-def _inverse_call(q, k, kb, g, interpreted=False):
+_STATIC = ("raw", "interpreted")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _inverse_call(q, k, kb, g, raw=None, interpreted=False):
     """(I + A)^-1 (float32, two heads a tile) and P of every chunk."""
-    n, t, pairs, bc, grid = _operand_grid(k)
-    lanes, inverse, _, square = _operand_specs(pairs, bc)
+    n, t, pairs, bc, grid = _operand_grid(kb, raw)
+    query, key, lanes, inverse, _, square = _operand_specs(pairs, bc, raw)
     return _pallas_call(
-        functools.partial(_inverse_kernel, block_chunks=bc),
+        functools.partial(_inverse_kernel, block_chunks=bc, raw=bool(raw)),
         name="channel_delta_inverse", grid=grid,
-        in_specs=[lanes] * 4, out_specs=[inverse, square],
+        in_specs=[query, key, lanes, lanes], out_specs=[inverse, square],
         out_shape=[
             jax.ShapeDtypeStruct((n * pairs, t, PAIR * CHUNK), jnp.float32),
-            jax.ShapeDtypeStruct((n * pairs * PAIR, t, CHUNK), k.dtype)],
+            jax.ShapeDtypeStruct((n * pairs * PAIR, t, CHUNK), kb.dtype)],
         compiler_params=_params(),
     )(q, k, kb, g)
 
 
-@functools.partial(jax.jit, static_argnames=("interpreted",))
-def _operands_fwd_call(q, k, kb, vb, g, m, interpreted=False):
-    n, t, pairs, bc, grid = _operand_grid(k)
-    lanes, inverse, wide, _ = _operand_specs(pairs, bc)
-    flat = jax.ShapeDtypeStruct((n * pairs * PAIR, t, HEAD_DIM), k.dtype)
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _operands_fwd_call(q, k, kb, vb, g, m, raw=None, interpreted=False):
+    n, t, pairs, bc, grid = _operand_grid(kb, raw)
+    query, key, lanes, inverse, wide, _ = _operand_specs(pairs, bc, raw)
+    flat = jax.ShapeDtypeStruct((n * pairs * PAIR, t, HEAD_DIM), kb.dtype)
     return _pallas_call(
-        functools.partial(_operands_fwd_kernel, block_chunks=bc),
+        functools.partial(_operands_fwd_kernel, block_chunks=bc,
+                          raw=bool(raw)),
         name="channel_delta_operands_fwd", grid=grid,
-        in_specs=[lanes] * 5 + [inverse], out_specs=[wide] * 4,
-        out_shape=[flat] * 4, compiler_params=_params(),
+        in_specs=[query, key] + [lanes] * 3 + [inverse],
+        out_specs=[wide] * 4, out_shape=[flat] * 4,
+        compiler_params=_params(),
     )(q, k, kb, vb, g, m)
 
 
-@functools.partial(jax.jit, static_argnames=("interpreted",))
-def _operands_bwd_call(q, k, kb, vb, g, m, dw, du, dqg, dkd, dp,
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _operands_bwd_call(q, k, kb, vb, g, m, dw, du, dqg, dkd, dp, raw=None,
                        interpreted=False):
-    n, t, pairs, bc, grid = _operand_grid(k)
-    lanes, inverse, wide, square = _operand_specs(pairs, bc)
+    """(dq, dk, dkb, dvb, dg), kb's shape each; dq, dk and dkb the unit
+    vectors', or with `raw` the projection's lanes' and the raw kb's."""
+    n, t, pairs, bc, grid = _operand_grid(kb, raw)
+    query, key, lanes, inverse, wide, square = _operand_specs(pairs, bc, raw)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     return _pallas_call(
-        functools.partial(_operands_bwd_kernel, block_chunks=bc),
+        functools.partial(_operands_bwd_kernel, block_chunks=bc,
+                          raw=bool(raw)),
         name="channel_delta_operands_bwd", grid=grid,
-        in_specs=[lanes] * 5 + [inverse] + [wide] * 4 + [square],
+        in_specs=[query, key] + [lanes] * 3 + [inverse] + [wide] * 4
+        + [square],
         out_specs=[lanes] * 5,
-        out_shape=[like(q), like(k), like(kb), like(vb), like(g)],
+        out_shape=[like(kb), like(kb), like(kb), like(vb), like(g)],
         compiler_params=_params(),
     )(q, k, kb, vb, g, m, dw, du, dqg, dkd, dp)
 
 
-def _record_operands(k):
+def _record_operands(kb):
     """Count a call of a chunk-local kernel where it is traced (outside
     the jitted call, which is traced once a shape); gives the interpret
     gate, which keys that call's cache."""
     from ...observe.monitoring import runtime_stats
     from . import interpret
 
-    n, t, width = k.shape
+    n, t, width = kb.shape
     runtime_stats.record_channel_delta_operands(
         n * (width // HEAD_DIM) * (t // CHUNK))
     return interpret()
 
 
-def chunk_inverses(q, k, kb, g):
+def chunk_inverses(q, k, kb, g, raw=None):
     """(I + A)^-1 and P of every chunk by `channel_delta_inverse`, NAMED:
     a recompute segment keeps both (`ops/pallas keep_residuals`).
     Constants of differentiation here: `operands_kernel`'s backward
@@ -549,42 +615,47 @@ def chunk_inverses(q, k, kb, g):
 
     stop = jax.lax.stop_gradient
     return keep_residuals(
-        *_inverse_call(stop(q), stop(k), stop(kb), stop(g),
-                       interpreted=_record_operands(k)),
+        *_inverse_call(stop(q), stop(k), stop(kb), stop(g), raw=raw,
+                       interpreted=_record_operands(kb)),
         names=CHANNEL_DELTA_RESIDUALS)
 
 
-@jax.custom_vjp
-def operands_kernel(q, k, kb, vb, g, m, p):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def operands_kernel(q, k, kb, vb, g, m, p, raw=None):
     """`chunk_operands` less exp(gamma_C) by the Pallas kernels.  q, k,
     kb, vb (N, T, H x 128), g the same in float32, (m, p) =
-    `chunk_inverses(q, k, kb, g)`."""
-    return _operands_vjp_fwd(q, k, kb, vb, g, m, p)[0]
+    `chunk_inverses(q, k, kb, g)`.  With `raw` (a `gated_delta.RawQK`)
+    q and k are the arrays that hold the projection's and kb is beta
+    times the RAW k: the kernels take the l2norm of all three."""
+    return _operands_vjp_fwd(q, k, kb, vb, g, m, p, raw)[0]
 
 
-def _operands_vjp_fwd(q, k, kb, vb, g, m, p):
-    w, u, qg, kd = _operands_fwd_call(q, k, kb, vb, g, m,
-                                      interpreted=_record_operands(k))
+def _operands_vjp_fwd(q, k, kb, vb, g, m, p, raw):
+    w, u, qg, kd = _operands_fwd_call(q, k, kb, vb, g, m, raw=raw,
+                                      interpreted=_record_operands(kb))
     return (w, u, qg, kd, p), (q, k, kb, vb, g, m, p)
 
 
-def _operands_vjp_bwd(res, cts):
+def _operands_vjp_bwd(raw, res, cts):
     q, k, kb, vb, g, m, p = res
     # m's and p's own cotangents are none: their parts are in the five
-    grads = _operands_bwd_call(
-        q, k, kb, vb, g, m, *(c.astype(vb.dtype) for c in cts),
-        interpreted=_record_operands(k))
-    return tuple(grads) + (jnp.zeros_like(m), jnp.zeros_like(p))
+    dq, dk, *grads = _operands_bwd_call(
+        q, k, kb, vb, g, m, *(c.astype(vb.dtype) for c in cts), raw=raw,
+        interpreted=_record_operands(kb))
+    if raw:
+        dq, dk = (lane_range_gradient(dq, q.shape[-1], raw.q),
+                  lane_range_gradient(dk, k.shape[-1], raw.k))
+    return (dq, dk, *grads, jnp.zeros_like(m), jnp.zeros_like(p))
 
 
 operands_kernel.defvjp(_operands_vjp_fwd, _operands_vjp_bwd)
 
 
-def chunk_operands_kernel(q, k, kb, vb, g):
+def chunk_operands_kernel(q, k, kb, vb, g, raw=None):
     """`chunk_operands` on (N, T, H x 128) operands where `kernel_takes`
     the heads: the same six results, the chunks' matrices and gamma
-    never in HBM."""
-    n, t, width = k.shape
+    never in HBM.  `raw`: as `operands_kernel`."""
+    n, t, width = kb.shape
     h = width // HEAD_DIM
     # exp(gamma_C) is XLA's: a sum over each chunk of g, and its gradient
     # (the rows are split, never the lanes: no tile moves)
@@ -592,7 +663,7 @@ def chunk_operands_kernel(q, k, kb, vb, g):
     last = jnp.moveaxis(last.reshape(n, t // CHUNK, h, HEAD_DIM), 2,
                         1).reshape(n * h, t // CHUNK, HEAD_DIM)
     return operands_kernel(q, k, kb, vb, g,
-                           *chunk_inverses(q, k, kb, g)) + (last,)
+                           *chunk_inverses(q, k, kb, g, raw), raw) + (last,)
 
 
 # -- the sequential part -----------------------------------------------
@@ -782,18 +853,26 @@ def _heads_matrix(heads, lanes, dtype):
     return jnp.repeat(jnp.eye(heads, dtype=dtype), lanes, axis=0)
 
 
-def channel_delta_rule(q, k, v, g, beta, use_kernel=False):
+def channel_delta_rule(q, k, v, g, beta, use_kernel=False, raw=None):
     """O (N, T, H x Dv) of the recurrence at the top of this file.  q, k
     (N, T, H x Dk), v (N, T, H x Dv) in one dtype, heads side by side;
     g (N, T, H x Dk) float32, the log decay a key lane (<= 0); beta
     (N, T, H) float32.  A T that is no whole number of chunks is padded
     with positions that write nothing (beta 0, no decay).  `use_kernel`:
     the Pallas kernels (`kernel_takes` the heads), else the XLA
-    lowering of the same chunks."""
+    lowering of the same chunks.  `raw` (a `gated_delta.RawQK`): q and k
+    are not the unit vectors but the (N, T, W) arrays that hold the
+    projection's, and the rule takes their l2norm: in the chunk-local
+    kernels, through `gated_delta.unit_q_and_k` for the XLA lowering."""
     n, t, h = beta.shape
-    dk, dv = k.shape[2] // h, v.shape[2] // h
-    if (q.shape != k.shape or k.shape != (n, t, h * dk)
-            or v.shape != (n, t, h * dv) or g.shape != k.shape):
+    dv = v.shape[2] // h
+    if raw and not use_kernel:
+        q, k = unit_q_and_k(q, k, raw)
+        raw = None
+    dk = raw.dim if raw else k.shape[2] // h
+    if ((not raw and (q.shape != k.shape or k.shape != (n, t, h * dk)))
+            or (raw and raw.heads != h)
+            or v.shape != (n, t, h * dv) or g.shape != (n, t, h * dk)):
         raise ValueError(
             f"channel_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, g "
             f"{g.shape}, beta {beta.shape} are not {h} heads side by side, "
@@ -808,14 +887,16 @@ def channel_delta_rule(q, k, v, g, beta, use_kernel=False):
     def times_beta(x, d):
         return (x.astype(f32) * head_spread(beta.astype(f32), d)).astype(dt)
 
-    kb, vb = times_beta(k, dk), times_beta(v, dv)
+    # (of the raw k where the kernels take the l2norm: kb takes it there)
+    kb = times_beta(k[..., raw.k:raw.k + h * dk] if raw else k, dk)
+    vb = times_beta(v, dv)
     g = g.astype(f32)
     tail = -t % CHUNK
     if tail:
         q, k, kb, vb, g = (jnp.pad(x, ((0, 0), (0, tail), (0, 0)))
                            for x in (q, k, kb, vb, g))
     if use_kernel:
-        o = scan_kernel(*chunk_operands_kernel(q, k, kb, vb, g), h)
+        o = scan_kernel(*chunk_operands_kernel(q, k, kb, vb, g, raw), h)
     else:
         heads = lambda x, d: x.reshape(n, t + tail, h, d)  # noqa: E731
         o = scan_xla(*chunk_operands(heads(q, dk), heads(k, dk),

@@ -70,6 +70,21 @@ solved and multiplied in one):
   differentiating gamma_i - gamma_j term by term leaves their float32
   cancellation in a decay parameter's gradient).
 
+The three take q and k either as unit vectors (N, T, Hk x 128) or, with
+`raw` (a `RawQK`: what the op hands them since PR 69), AS THE PROJECTION
+WROTE THEM: the convolved QKV (N, T, W) twice, q's and k's lanes picked
+by the block's lane index as `head_norm_fwd` picked them.  A grid step
+then takes the l2norm of its rows (x * rsqrt(sum x^2 + 1e-6), q's times
+Dk^-1/2; `head_norm.py`'s float32 arithmetic) into VMEM scratch before
+its chunk loop (`_head_rows`), and `_operands_bwd` returns the gradient
+of the RAW lanes (`_raw_gradient`: dx = (g - y <g, y>) * rstd, one more
+lane reduction a row), which `lane_range_gradient` pads to QKV's width
+as a slice's is.  No unit q or k is in HBM, and no `head_norm_*` call
+stands before the kernels (two forward and two backward passes a layer
+and call site before).  Unit operands remain the tests', the references'
+and `tools/time_*`'s entry; a shape the kernels do not take goes through
+`unit_q_and_k` (`head_norm.py`) first.
+
 The inverse is made BEFORE the `custom_vjp` that holds the other two
 (`chunk_inverses`, on k and the row tile as constants: the backward
 kernel's dk and row-tile gradient hold what flows through it), and is
@@ -112,9 +127,12 @@ finds the scan kernels by the PREFIXES `gated_delta_fwd` /
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from .head_norm import Form, _rstd, head_norm, lane_range_gradient
 
 CHUNK = 64
 HEAD_DIM = 128          # the kernels' Dk and Dv
@@ -127,6 +145,32 @@ _HI = jax.lax.Precision.HIGHEST
 def kernel_takes(dk, dv):
     """Whether the Pallas kernels run a call: from the shape alone."""
     return (dk, dv) == (HEAD_DIM, HEAD_DIM)
+
+
+class RawQK(NamedTuple):
+    """Where q and k lie when a call hands them AS THE PROJECTION WROTE
+    THEM, before the l2norm: arrays (N, T, W) that hold `heads` heads of
+    `dim` lanes from lane `q` and from lane `k` on (the convolved QKV
+    twice, as it lies: no slice of it is made).  The rule then takes
+    q = l2norm(q) * dim^-1/2 and k = l2norm(k) itself (eps 1e-6, a
+    head, float32): the chunk-local kernels in VMEM, on the 128-lane
+    block of a head they hold anyway (`_head_rows`; the backward kernel
+    returns the raw q's and k's gradient), any other lowering through
+    `head_norm.py` first (`unit_q_and_k`)."""
+    q: int
+    k: int
+    heads: int
+    dim: int
+
+
+def unit_q_and_k(q, k, raw):
+    """(l2norm(q) * dim^-1/2, l2norm(k)), each (N, T, heads x dim), of
+    the arrays `raw` describes: `head_norm.py`'s kernels where a head
+    is 128 lanes, its (.., H, dim) view elsewhere."""
+    width = raw.heads * raw.dim
+    return (head_norm(q, group=raw.dim, lanes=(raw.q, width),
+                      constant=raw.dim ** -0.5),
+            head_norm(k, group=raw.dim, lanes=(raw.k, width)))
 
 
 # -- kernel cost registry (observe/cost.py) ----------------------------
@@ -155,26 +199,44 @@ def scan_bwd_cost(operand_shapes, result_shapes):
     return bh * t * (6 * 2.0 * dk * dv + 2 * 2.0 * CHUNK * dv), None
 
 
-def _operand_dims(operand_shapes):
-    (n, t, qw), _ = operand_shapes[0]
-    vw = operand_shapes[2][0][2]
-    return n * (qw // HEAD_DIM), n * (vw // HEAD_DIM), t
+def _operand_dims(operand_shapes, tile):
+    """(batch x key heads, positions, q's and k's lanes) by the row
+    tiles (operand `tile`: (N Hk, T / C, 8, 2C)), which say the heads
+    whether q and k come alone or inside the projection."""
+    (bk, nc, _, _), _ = operand_shapes[tile]
+    return bk, nc * CHUNK, bk // operand_shapes[0][0][0] * HEAD_DIM
+
+
+def ranged_bytes(operand_shapes, result_shapes, lanes, first=2):
+    """None (the default model: every buffer once) unless one of the
+    `first` operands (q and k) is wider than `lanes`: read inside the
+    projection, it counts as its lane range."""
+    import math
+
+    if all(dims[-1] <= lanes for dims, _ in operand_shapes[:first]):
+        return None
+    return float(sum(
+        size * math.prod(dims[:-1]) * (min(dims[-1], lanes) if i < first
+                                       else dims[-1])
+        for i, (dims, size) in enumerate(list(operand_shapes)
+                                         + list(result_shapes))))
 
 
 def inverse_cost(operand_shapes, result_shapes):
     """K K^T a key head and the substitution's C^3 / 3 multiply-adds a
-    value head (k is the first operand; two value heads a key head)."""
-    (n, t, kw), _ = operand_shapes[0]
-    bk = n * (kw // HEAD_DIM)
+    value head (two value heads a key head)."""
+    bk, t, lanes = _operand_dims(operand_shapes, 1)
     return t * (bk * 2.0 * CHUNK * HEAD_DIM
-                + 2 * bk * 2.0 * CHUNK * CHUNK / 3), None
+                + 2 * bk * 2.0 * CHUNK * CHUNK / 3), ranged_bytes(
+        operand_shapes, result_shapes, lanes, first=1)
 
 
 def operands_fwd_cost(operand_shapes, result_shapes):
     """Q K^T a key head, W and U a value head."""
-    bk, bh, t = _operand_dims(operand_shapes)
+    bk, t, lanes = _operand_dims(operand_shapes, 3)
     return t * (bk * 2.0 * CHUNK * HEAD_DIM
-                + bh * 2 * 2.0 * CHUNK * HEAD_DIM), None
+                + 2 * bk * 2 * 2.0 * CHUNK * HEAD_DIM), ranged_bytes(
+        operand_shapes, result_shapes, lanes)
 
 
 def operands_bwd_cost(operand_shapes, result_shapes):
@@ -182,9 +244,10 @@ def operands_bwd_cost(operand_shapes, result_shapes):
     products that turn d(K K^T) and d(Q K^T) into dq and dk, of
     2 C C D; the inverse's gradient (two) and the decay's pair sums, of
     2 C^3.  The rebuilt K K^T and Q K^T are not credited."""
-    _, bh, t = _operand_dims(operand_shapes)
-    return bh * t * (8 * 2.0 * CHUNK * HEAD_DIM
-                     + 3 * 2.0 * CHUNK * CHUNK), None
+    bk, t, lanes = _operand_dims(operand_shapes, 3)
+    return 2 * bk * t * (8 * 2.0 * CHUNK * HEAD_DIM
+                         + 3 * 2.0 * CHUNK * CHUNK), ranged_bytes(
+        operand_shapes, result_shapes, lanes)
 
 
 def _register_costs():
@@ -474,13 +537,71 @@ def _chunk_rows(c):
     return pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)
 
 
-def _inverse_kernel(k_ref, x_ref, m_ref, *, block_chunks):
+def _l2norm(x, constant=1.0):
+    """(x * rstd [* constant], rstd = 1 / sqrt(sum x^2 + 1e-6) (R, 1)) of
+    float32 rows (R, 128), a head's lanes: an XLU lane reduction a row,
+    `head_norm_fwd`'s float32 arithmetic."""
+    rstd = _rstd(x, Form(0, HEAD_DIM))
+    y = x * rstd
+    return (y * constant if constant != 1 else y), rstd
+
+
+def _head_rows(x_ref, scratch, constant=1.0):
+    """r -> a chunk's float32 rows of a head (C, 128), for a kernel's
+    chunk loop.  Without `scratch` `x_ref` (1, rows, 128) holds unit
+    vectors, read as they are.  With it (VMEM, (rows, 128) float32: a
+    call with `raw`) `x_ref` holds the projection's rows, and their
+    l2norm [* constant] is taken HERE, before the loop, for all the rows
+    of the grid step at once, into the scratch the chunks then read.
+    Not a chunk at a time: the square, the lane reduction, the rsqrt and
+    the product are one chain of dependent steps at the head of a
+    chunk's own chain, which nothing in these kernels' chunk bodies
+    hides (+0.31 ms a call of `gated_delta_inverse`, +0.41 of
+    `_operands_fwd` at 16384 x 16 heads, ~70 cycles a chunk and as much
+    as the `head_norm_fwd` passes they replace; a grid step's 512 rows
+    pipeline: +0.18 and +0.21; my chip runs, PR 69, calls 1 and 2)."""
+    if scratch is None:
+        return lambda r: x_ref[0, r, :].astype(jnp.float32)
+    scratch[...] = _l2norm(x_ref[0].astype(jnp.float32), constant)[0]
+    return lambda r: scratch[r, :]
+
+
+def _rstd_rows(x_ref, r):
+    """A chunk's 1 / norm (C, 1) of the raw rows again, where a backward
+    kernel needs it: at the chunk's END (the l2norm's rule), off the
+    chain."""
+    return _l2norm(x_ref[0, r, :].astype(jnp.float32))[1]
+
+
+def _raw_gradient(dy, y, rstd, constant=1.0, through=0.0):
+    """The l2norm's VJP: the gradient of the raw rows x given `dy`, that
+    of y = x * rstd [* constant] (C, 128) float32; `through` (C, 1):
+    what reaches rstd by other ways (kb = beta k rides k's)."""
+    if constant != 1:
+        dy, y = dy * constant, y * (1.0 / constant)
+    along = jnp.sum(dy * y, axis=1, keepdims=True) + through
+    return rstd * (dy - y * along)
+
+
+_Q_SCALE = HEAD_DIM ** -0.5     # q's constant where a head is 128 lanes
+
+
+def _unit_q_and_k_rows(q_ref, k_ref, scratch):
+    """(`_head_rows` of q, of k); `scratch`: a kernel's trailing refs,
+    q's and k's buffers or none."""
+    q_scratch, k_scratch = scratch or (None, None)
+    return (_head_rows(q_ref, q_scratch, _Q_SCALE),
+            _head_rows(k_ref, k_scratch))
+
+
+def _inverse_kernel(k_ref, x_ref, m_ref, *scratch, block_chunks):
     iotas = _tile_iotas()
     row, col, left = iotas
+    rows_of_k = _head_rows(k_ref, scratch[0] if scratch else None)
 
     def chunk(c):
         r = _chunk_rows(c)
-        k = k_ref[0, r, :]
+        k = rows_of_k(r).astype(k_ref.dtype)
         (_, _, beta), decay = _tile_columns(x_ref[0, c], iotas)
         kk = _dot(k, _twice(k), ((1,), (1,)))
         a = jnp.where(row > col, jnp.where(left, *beta) * kk * decay, 0.0)
@@ -490,19 +611,19 @@ def _inverse_kernel(k_ref, x_ref, m_ref, *, block_chunks):
 
 
 def _operands_fwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, w_ref, u_ref,
-                         qg_ref, kd_ref, p_ref, *, block_chunks):
-    f32 = jnp.float32
+                         qg_ref, kd_ref, p_ref, *scratch, block_chunks):
     iotas = _tile_iotas()
+    rows_of_q, rows_of_k = _unit_q_and_k_rows(q_ref, k_ref, scratch)
 
     def chunk(c):
         r = _chunk_rows(c)
-        q, k, x8 = q_ref[0, r, :], k_ref[0, r, :], x_ref[0, c]
-        dt = k.dtype
+        x8, dt = x_ref[0, c], k_ref.dtype
+        qf, kf = rows_of_q(r), rows_of_k(r)
+        q, k = qf.astype(dt), kf.astype(dt)
         (gamma, rest, _), decay = _tile_columns(x8, iotas)
         qk = _dot(q, _twice(k), ((1,), (1,)))
         solve = (m_ref[0, r, :] * x8[ROW_BETA:ROW_BETA + 1]).astype(dt)
         p = (qk * decay).astype(dt)
-        kf, qf = k.astype(f32), q.astype(f32)
         for h in range(2):
             lanes = slice(h * CHUNK, (h + 1) * CHUNK)
             e = jnp.exp(gamma[h])
@@ -521,7 +642,7 @@ def _operands_fwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, w_ref, u_ref,
 
 def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
                          dqg_ref, dkd_ref, dp_ref, dq_ref, dk_ref, dv_ref,
-                         dx_ref, *, block_chunks):
+                         dx_ref, *scratch, block_chunks):
     f32 = jnp.float32
     iotas = _tile_iotas()
     row, col, left = iotas
@@ -536,6 +657,8 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
     ).astype(f32)
     sublane = jax.lax.broadcasted_iota(jnp.int32, (8, 2 * CHUNK), 0)
 
+    rows_of_q, rows_of_k = _unit_q_and_k_rows(q_ref, k_ref, scratch)
+
     def to_row(columns):        # two (C, 1) columns -> a (1, 2C) row
         return jnp.sum(jnp.where(row == col, jnp.where(left, *columns), 0.0),
                        axis=0, keepdims=True)
@@ -546,15 +669,14 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
 
     def chunk(c):
         r = _chunk_rows(c)
-        q, k, x8, m = (q_ref[0, r, :], k_ref[0, r, :], x_ref[0, c],
-                       m_ref[0, r, :])
-        dt = k.dtype
+        x8, m, dt = x_ref[0, c], m_ref[0, r, :], k_ref.dtype
+        qf, kf = rows_of_q(r), rows_of_k(r)
+        q, k = qf.astype(dt), kf.astype(dt)
         (gamma, rest, beta), decay = _tile_columns(x8, iotas)
         kk2 = _twice(k)
         kk, qk = _dot(k, kk2, ((1,), (1,))), _dot(q, kk2, ((1,), (1,)))
         beta_j = x8[ROW_BETA:ROW_BETA + 1]
         solve = (m * beta_j).astype(dt)
-        kf, qf = k.astype(f32), q.astype(f32)
         dq = jnp.zeros((CHUNK, HEAD_DIM), f32)
         dk = jnp.zeros((CHUNK, HEAD_DIM), f32)
         dsolve, dgamma, drest = [], [], []
@@ -594,9 +716,14 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
             axis=0, keepdims=True)
         dkk, dqk = (da * beta_i).astype(dt), (dp * decay).astype(dt)
         folded = _dot(dqk, q, ((0,), (0,))) + _dot(dkk, k, ((0,), (0,)))
-        dq_ref[0, r, :] = (dq + _dot(dqk, kk2, ((1,), (0,)))).astype(dt)
-        dk_ref[0, r, :] = (dk + _dot(dkk, kk2, ((1,), (0,)))
-                           + folded[:CHUNK] + folded[CHUNK:]).astype(dt)
+        dq = dq + _dot(dqk, kk2, ((1,), (0,)))
+        dk = (dk + _dot(dkk, kk2, ((1,), (0,)))
+              + folded[:CHUNK] + folded[CHUNK:])
+        if scratch:     # raw q and k: the l2norm's rule
+            dq = _raw_gradient(dq, qf, _rstd_rows(q_ref, r), _Q_SCALE)
+            dk = _raw_gradient(dk, kf, _rstd_rows(k_ref, r))
+        dq_ref[0, r, :] = dq.astype(dt)
+        dk_ref[0, r, :] = dk.astype(dt)
         rows = {ROW_GAMMA: to_row(dgamma), ROW_REST: to_row(drest),
                 ROW_BETA: dbeta, ROW_G: pairs}
         tile = jnp.zeros((8, 2 * CHUNK), f32)
@@ -607,97 +734,137 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
     _for_each_chunk(block_chunks, chunk)
 
 
-def _operand_specs(hk, bc):
+def _operand_specs(hk, bc, raw=None):
+    """The chunk-operand kernels' blocks, by name: `query` / `key` (a
+    key head's 128 lanes of q's and k's arrays: their own, or the ones
+    `raw` describes), `narrow` (the same of an (N, T, Hk x 128) array),
+    `pair` (its two value heads' lanes of v), `tile` (its row tiles),
+    `inverse`, and `wide` / `square` (its two value heads of (N Hv, T,
+    128) / (.., C))."""
+    import types
+
     from jax.experimental import pallas as pl
 
     rows = bc * CHUNK
 
-    def key_head(width):        # a key head's lanes of (N, T, H x 128)
+    def key_head(width, start=0):   # a key head's lanes of (N, T, ..)
+        first = start // width
         return pl.BlockSpec((1, rows, width),
-                            lambda b, i: (b // hk, i, b % hk))
+                            lambda b, i: (b // hk, i, first + b % hk))
 
     def value_heads(width):     # its two value heads of (N Hv, T, width)
         return pl.BlockSpec((2, rows, width), lambda b, i: (b, i, 0))
 
-    return (key_head(HEAD_DIM), key_head(2 * HEAD_DIM),
-            pl.BlockSpec((1, bc, 8, 2 * CHUNK), lambda b, i: (b, i, 0, 0)),
-            pl.BlockSpec((1, rows, 2 * CHUNK), lambda b, i: (b, i, 0)),
-            value_heads(HEAD_DIM), value_heads(CHUNK))
+    narrow = key_head(HEAD_DIM)
+    return types.SimpleNamespace(
+        query=key_head(HEAD_DIM, raw.q) if raw else narrow,
+        key=key_head(HEAD_DIM, raw.k) if raw else narrow,
+        narrow=narrow, pair=key_head(2 * HEAD_DIM),
+        tile=pl.BlockSpec((1, bc, 8, 2 * CHUNK), lambda b, i: (b, i, 0, 0)),
+        inverse=pl.BlockSpec((1, rows, 2 * CHUNK), lambda b, i: (b, i, 0)),
+        wide=value_heads(HEAD_DIM), square=value_heads(CHUNK))
 
 
-@functools.partial(jax.jit, static_argnames=("interpreted",))
-def _inverse_call(k, x, interpreted=False):
+def _unit_scratch(raw, rows, count):
+    """`count` float32 (rows, 128) buffers for the unit vectors a kernel
+    makes of raw rows (`_head_rows`); none for unit operands."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [pltpu.VMEM((rows, HEAD_DIM), jnp.float32)] * count if raw else []
+
+
+def _operand_grid(k, x, raw):
+    """(N, T, Hk, chunks a grid step, the grid) of a chunk-operand call:
+    the heads by the row tiles, whatever array holds k."""
+    n, t = k.shape[:2]
+    hk, nc = x.shape[0] // n, t // CHUNK
+    if raw:
+        whole = (raw.heads, raw.dim, raw.q % HEAD_DIM, raw.k % HEAD_DIM) \
+            == (hk, HEAD_DIM, 0, 0)
+    else:
+        whole = k.shape[2] == hk * HEAD_DIM
+    if not whole:
+        raise ValueError(f"gated_delta kernels: k {k.shape} at {raw} is "
+                         f"not {hk} heads of {HEAD_DIM} lanes")
+    bc = _block_chunks(nc)
+    return n, t, hk, bc, (n * hk, nc // bc)
+
+
+_STATIC = ("raw", "interpreted")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _inverse_call(k, x, raw=None, interpreted=False):
     """(I + A)^-1 of every chunk (float32, two heads a tile: 134 MB a
     layer at the cell's shape), from k and the row tiles alone."""
-    n, t, width = k.shape
-    hk, nc = width // HEAD_DIM, t // CHUNK
-    bc = _block_chunks(nc)
-    narrow, _, tile, inverse, _, _ = _operand_specs(hk, bc)
+    n, t, hk, bc, grid = _operand_grid(k, x, raw)
+    at = _operand_specs(hk, bc, raw)
     return _pallas_call(
         functools.partial(_inverse_kernel, block_chunks=bc),
-        name="gated_delta_inverse", grid=(n * hk, nc // bc),
-        in_specs=[narrow, tile], out_specs=inverse,
+        name="gated_delta_inverse", grid=grid,
+        in_specs=[at.key, at.tile], out_specs=at.inverse,
         out_shape=jax.ShapeDtypeStruct((n * hk, t, 2 * CHUNK), jnp.float32),
+        scratch_shapes=_unit_scratch(raw, bc * CHUNK, 1),
         compiler_params=_params(),
     )(k, x)
 
 
-@functools.partial(jax.jit, static_argnames=("interpreted",))
-def _operands_fwd_call(q, k, v, x, m, interpreted=False):
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _operands_fwd_call(q, k, v, x, m, raw=None, interpreted=False):
     """W, U, Qg, Kd, P of every chunk, given its (I + A)^-1."""
-    n, t, width = k.shape
-    hk, nc = width // HEAD_DIM, t // CHUNK
-    bc = _block_chunks(nc)
-    narrow, pair, tile, inverse, wide, square = _operand_specs(hk, bc)
+    n, t, hk, bc, grid = _operand_grid(k, x, raw)
+    at = _operand_specs(hk, bc, raw)
     flat = lambda d: jax.ShapeDtypeStruct((2 * n * hk, t, d),  # noqa: E731
                                           v.dtype)
     return _pallas_call(
         functools.partial(_operands_fwd_kernel, block_chunks=bc),
-        name="gated_delta_operands_fwd", grid=(n * hk, nc // bc),
-        in_specs=[narrow, narrow, pair, tile, inverse],
-        out_specs=[wide, wide, wide, wide, square],
+        name="gated_delta_operands_fwd", grid=grid,
+        in_specs=[at.query, at.key, at.pair, at.tile, at.inverse],
+        out_specs=[at.wide] * 4 + [at.square],
         out_shape=[flat(HEAD_DIM)] * 4 + [flat(CHUNK)],
+        scratch_shapes=_unit_scratch(raw, bc * CHUNK, 2),
         compiler_params=_params(),
     )(q, k, v, x, m)
 
 
-@functools.partial(jax.jit, static_argnames=("interpreted",))
-def _operands_bwd_call(q, k, v, x, m, dw, du, dqg, dkd, dp,
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _operands_bwd_call(q, k, v, x, m, dw, du, dqg, dkd, dp, raw=None,
                        interpreted=False):
-    n, t, width = k.shape
-    hk, nc = width // HEAD_DIM, t // CHUNK
-    bc = _block_chunks(nc)
-    narrow, pair, tile, inverse, wide, square = _operand_specs(hk, bc)
+    """(dq, dk, dv, the row tiles' gradient); dq and dk (N, T, Hk x 128)
+    each: the unit vectors', or with `raw` the projection's own lanes'."""
+    n, t, hk, bc, grid = _operand_grid(k, x, raw)
+    at = _operand_specs(hk, bc, raw)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    heads = jax.ShapeDtypeStruct((n, t, hk * HEAD_DIM), v.dtype)
     return _pallas_call(
         functools.partial(_operands_bwd_kernel, block_chunks=bc),
-        name="gated_delta_operands_bwd", grid=(n * hk, nc // bc),
-        in_specs=[narrow, narrow, pair, tile, inverse, wide, wide, wide,
-                  wide, square],
-        out_specs=[narrow, narrow, pair, tile],
-        out_shape=[like(q), like(k), like(v), like(x)],
+        name="gated_delta_operands_bwd", grid=grid,
+        in_specs=[at.query, at.key, at.pair, at.tile, at.inverse]
+        + [at.wide] * 4 + [at.square],
+        out_specs=[at.narrow, at.narrow, at.pair, at.tile],
+        out_shape=[heads, heads, like(v), like(x)],
+        scratch_shapes=_unit_scratch(raw, bc * CHUNK, 2),
         compiler_params=_params(),
     )(q, k, v, x, m, dw, du, dqg, dkd, dp)
 
 
-def _record_operands(k):
+def _record_operands(x):
     """Count a call of a chunk-operand kernel where it is traced
-    (outside the jitted call, which is traced once a shape); gives the
-    interpret gate, which keys that call's cache: the same shapes are
-    lowered through the interpreter and through Mosaic in one test
-    process."""
+    (outside the jitted call, which is traced once a shape), by its row
+    tiles; gives the interpret gate, which keys that call's cache: the
+    same shapes are lowered through the interpreter and through Mosaic
+    in one test process."""
     from ...observe.monitoring import runtime_stats
     from . import interpret
 
-    n, t, width = k.shape
-    runtime_stats.record_gated_delta_operands(
-        2 * n * (width // HEAD_DIM) * (t // CHUNK))
+    runtime_stats.record_gated_delta_operands(2 * x.shape[0] * x.shape[1])
     return interpret()
 
 
-def chunk_inverses(k, x):
+def chunk_inverses(k, x, raw=None):
     """(I + A)^-1 of every chunk by `gated_delta_inverse`: k (N, T,
-    Hk x 128) and the row tiles -> (N Hk, T, 2C) float32, NAMED: a
+    Hk x 128), or with `raw` the array that holds it, and the row tiles
+    -> (N Hk, T, 2C) float32, NAMED: a
     recompute segment keeps it (`ops/pallas keep_residuals`), so the
     segment's backward pass reads it and runs no substitution again.
     A constant of differentiation here: `operands_kernel`'s backward
@@ -709,31 +876,36 @@ def chunk_inverses(k, x):
     runtime_stats.record_gated_delta_inverse()
     m, = keep_residuals(
         _inverse_call(jax.lax.stop_gradient(k), jax.lax.stop_gradient(x),
-                      interpreted=interpret()),
+                      raw=raw, interpreted=interpret()),
         names=INVERSE_RESIDUAL)
     return m
 
 
-@jax.custom_vjp
-def operands_kernel(q, k, v, x, m):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def operands_kernel(q, k, v, x, m, raw=None):
     """`chunk_operands` less exp(gamma_C) by the Pallas kernels.  q, k
-    (N, T, Hk x 128), v (N, T, 2 Hk x 128), x the row tiles
-    (`_row_tiles`), m `chunk_inverses(k, x)`."""
-    return _operands_vjp_fwd(q, k, v, x, m)[0]
+    (N, T, Hk x 128), or with `raw` the arrays that hold them before the
+    l2norm; v (N, T, 2 Hk x 128), x the row tiles (`_row_tiles`), m
+    `chunk_inverses(k, x, raw)`."""
+    return _operands_vjp_fwd(q, k, v, x, m, raw)[0]
 
 
-def _operands_vjp_fwd(q, k, v, x, m):
-    operands = _operands_fwd_call(q, k, v, x, m,
-                                  interpreted=_record_operands(k))
+def _operands_vjp_fwd(q, k, v, x, m, raw):
+    operands = _operands_fwd_call(q, k, v, x, m, raw=raw,
+                                  interpreted=_record_operands(x))
     return tuple(operands), (q, k, v, x, m)
 
 
-def _operands_vjp_bwd(res, cts):
+def _operands_vjp_bwd(raw, res, cts):
     q, k, v, x, m = res
     # m's own cotangent is none: its part is in dk and dx (above)
-    return tuple(_operands_bwd_call(
-        q, k, v, x, m, *(c.astype(v.dtype) for c in cts),
-        interpreted=_record_operands(k))) + (jnp.zeros_like(m),)
+    dq, dk, dv, dx = _operands_bwd_call(
+        q, k, v, x, m, *(c.astype(v.dtype) for c in cts), raw=raw,
+        interpreted=_record_operands(x))
+    if raw:
+        dq, dk = (lane_range_gradient(dq, q.shape[-1], raw.q),
+                  lane_range_gradient(dk, k.shape[-1], raw.k))
+    return dq, dk, dv, dx, jnp.zeros_like(m)
 
 
 operands_kernel.defvjp(_operands_vjp_fwd, _operands_vjp_bwd)
@@ -766,17 +938,19 @@ def _row_tiles(g, beta, hk):
     return rows, last.reshape(n * hv, nc)
 
 
-def chunk_operands_kernel(q, k, v, g, beta):
+def chunk_operands_kernel(q, k, v, g, beta, raw=None):
     """`chunk_operands` where `operand_kernels_take` the heads: the
-    same six results, the chunk's matrices never in HBM."""
-    n, t, hk, dk = k.shape
-    hv = v.shape[2]
+    same six results, the chunk's matrices never in HBM.  With `raw`, q
+    and k are the (N, T, W) arrays it describes and the kernels take
+    their l2norm."""
+    n, t, hv, dv = v.shape
+    hk = raw.heads if raw else k.shape[2]
     x, last = _row_tiles(g.astype(jnp.float32), beta.astype(jnp.float32),
                          hk)
-    k = k.reshape(n, t, hk * dk)
-    return operands_kernel(q.reshape(n, t, hk * dk), k,
-                           v.reshape(n, t, hv * v.shape[3]), x,
-                           chunk_inverses(k, x)) + (last,)
+    if not raw:
+        q, k = (a.reshape(n, t, hk * HEAD_DIM) for a in (q, k))
+    return operands_kernel(q, k, v.reshape(n, t, hv * dv), x,
+                           chunk_inverses(k, x, raw), raw) + (last,)
 
 
 # -- the sequential part, as XLA runs it -------------------------------
@@ -982,18 +1156,27 @@ def _scan_vjp_bwd(res, do):
 scan_kernel.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, use_kernel=False):
+def gated_delta_rule(q, k, v, g, beta, use_kernel=False, raw=None):
     """O (N, T, Hv, Dv) of the recurrence at the top of this file.  q,
     k (N, T, Hk, Dk), v (N, T, Hv, Dv) in one dtype, g (the log decay,
     <= 0) and beta (N, T, Hv); value head h reads key head
     h // (Hv / Hk).  A T that is no whole number of chunks is padded
     with positions that write nothing (beta 0, no decay).
     `use_kernel`: the Pallas kernels (`kernel_takes` the head sizes),
-    else `scan_xla`."""
-    n, t, hk, dk = k.shape
+    else `scan_xla`.  `raw` (a `RawQK`): q and k are not the unit
+    vectors but the (N, T, W) arrays that hold the projection's, and
+    the rule takes their l2norm: in the chunk-operand kernels where
+    they run, through `unit_q_and_k` elsewhere."""
+    n, t = v.shape[:2]
     hv, dv = v.shape[2], v.shape[3]
+    hk, dk = (raw.heads, raw.dim) if raw else k.shape[2:]
+    in_kernels = use_kernel and operand_kernels_take(hk, hv, dk, dv)
+    if raw and not in_kernels:
+        q, k = (x.reshape(n, t, hk, dk) for x in unit_q_and_k(q, k, raw))
+        raw = None
     if hv % hk or q.shape != k.shape or g.shape != (n, t, hv) \
-            or beta.shape != g.shape:
+            or beta.shape != g.shape \
+            or not (raw or k.shape == (n, t, hk, dk)):
         raise ValueError(
             f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, g "
             f"{g.shape}, beta {beta.shape} are not Hk key heads, a "
@@ -1007,8 +1190,10 @@ def gated_delta_rule(q, k, v, g, beta, use_kernel=False):
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, tail)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    batch = chunk_operands_kernel if use_kernel and operand_kernels_take(
-        hk, hv, dk, dv) else chunk_operands
-    operands = batch(q.astype(v.dtype), k.astype(v.dtype), v, g, beta)
+    q, k = q.astype(v.dtype), k.astype(v.dtype)
+    if in_kernels:
+        operands = chunk_operands_kernel(q, k, v, g, beta, raw)
+    else:
+        operands = chunk_operands(q, k, v, g, beta)
     o = (scan_kernel if use_kernel else scan_xla)(*operands)
     return jnp.moveaxis(o.reshape(n, hv, t + tail, dv), 1, 2)[:, :t]

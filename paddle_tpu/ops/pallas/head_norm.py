@@ -4,7 +4,9 @@
     y = x * rsqrt(s / denom + eps) [* scale] [* squash(gate)] [* constant]
 
 `denom` 1 and `eps` 1e-6 is the l2norm of a delta rule's q and k
-(`constant` their Dk^-1/2); `denom` 128 is `rms_norm(group_size=128)`,
+(`constant` their Dk^-1/2) wherever the rule's chunk-local kernels do
+not take it themselves (`gated_delta.py RawQK`, PR 69: they share
+`_rstd` below); `denom` 128 is `rms_norm(group_size=128)`,
 alone or under silu(gate) / sigmoid(gate), with the one `scale` (128,)
 the heads share.  `head_norm` below is what `ops/decoder.py` calls: the
 kernels where `head_norm_takes` the shape, else `head_norm_xla`, the
@@ -341,14 +343,20 @@ def _vjp_fwd(x, scale, gate, form):
     return head_norm_kernel(x, scale, gate, form), (x, scale, gate)
 
 
+def lane_range_gradient(dx, width, start):
+    """The gradient of lanes `start` .. of an array `width` lanes wide
+    as that array's: a slice's (zeros beside it)."""
+    if dx.shape[-1] == width:
+        return dx
+    return jnp.pad(dx, ((0, 0),) * (dx.ndim - 1)
+                   + ((start, width - start - dx.shape[-1]),))
+
+
 def _vjp_bwd(form, res, dy):
     x, scale, gate = res
     dx, dscale, dgate = _bwd_call(x, scale, gate, dy.astype(x.dtype), form,
                                   interpreted=_record(x))
-    if dx.shape != x.shape:     # a lane range's gradient: a slice's
-        dx = jnp.pad(dx, ((0, 0), (form.start,
-                                   x.shape[1] - form.start - dx.shape[1])))
-    return dx, dscale, dgate
+    return lane_range_gradient(dx, x.shape[1], form.start), dscale, dgate
 
 
 head_norm_kernel.defvjp(_vjp_fwd, _vjp_bwd)

@@ -679,12 +679,14 @@ def _qwen3next_traced(parameters, took):
     # the full layer's (o, logsumexp) and three inverses
     assert took["recompute_kept_residuals"] == 4
     assert took["recompute_kept_bytes"] >= 3 * 134217728
-    # a head's lane statistic (`ops/pallas/head_norm.py`): q's l2norm,
-    # k's and the silu-gated norm a head, three passes a delta layer's
-    # forward, three its forward traced again for the segment's
-    # backward pass, three its backward; three layers of 16384 rows
+    # a head's lane statistic (`ops/pallas/head_norm.py`): the
+    # silu-gated norm a head alone since PR 69 (q's and k's l2norm is
+    # taken inside the chunk-operand kernels: 27 calls, 442,368 rows
+    # before): one pass a delta layer's forward, one its forward traced
+    # again for the segment's backward pass, one its backward; three
+    # layers of 16384 rows: 9 calls, 147,456 rows
     assert (took["head_norm_calls"], took["head_norm_rows"]) == (
-        3 * 9, 3 * 9 * 16384)
+        3 * 3, 3 * 3 * 16384)
 
 
 def _kimilinear_traced(parameters, took):
@@ -703,12 +705,14 @@ def _kimilinear_traced(parameters, took):
     # four layers' (inverse, P) and the latent layer's (o, logsumexp)
     assert took["recompute_kept_residuals"] == 5
     assert took["recompute_kept_bytes"] >= 4 * (67108864 + 33554432)
-    # a head's lane statistic (`ops/pallas/head_norm.py`): q's l2norm,
-    # k's and the sigmoid-gated norm a head, three passes a delta
-    # layer's forward, three its forward traced again for the segment's
-    # backward pass, three its backward; four layers of 8192 rows
+    # a head's lane statistic (`ops/pallas/head_norm.py`): the
+    # sigmoid-gated norm a head alone since PR 69 (q's and k's l2norm is
+    # taken inside the chunk-local kernels: 36 calls, 294,912 rows
+    # before): one pass a delta layer's forward, one its forward traced
+    # again for the segment's backward pass, one its backward; four
+    # layers of 8192 rows: 12 calls, 98,304 rows
     assert (took["head_norm_calls"], took["head_norm_rows"]) == (
-        4 * 9, 4 * 9 * 8192)
+        4 * 3, 4 * 3 * 8192)
 
 
 # cell -> (what its trace holds, the kernels its step lowers to and
@@ -954,6 +958,7 @@ def test_the_linear_attention_cells_step_keeps_its_inverses_under_the_plan(
             kernels["gated_delta_operands_bwd"]) == (3, 6, 3)
     assert (kernels["gated_delta_fwd"], kernels["gated_delta_bwd"]) == (6, 3)
     assert (kernels["flash_fwd"], kernels["flash_dkv"]) == (1, 1)
-    # q's, k's and the output norm's head statistic in a linear layer's
-    # forward and recomputed forward, and their one backward pass (PR 68)
-    assert (kernels["head_norm_fwd"], kernels["head_norm_bwd"]) == (18, 9)
+    # the output norm's head statistic in a linear layer's forward and
+    # recomputed forward, and its one backward pass (PR 68; q's and k's
+    # are the chunk-operand kernels' own since PR 69: 18 / 9 before)
+    assert (kernels["head_norm_fwd"], kernels["head_norm_bwd"]) == (6, 3)

@@ -423,9 +423,13 @@ STEP_TEXT = {
     # interpreter, no float32 (.., H, 128) view and no product with a
     # 0 / 1 matrix (parents: 9df7a66e.., 3e0cc1d7..); the twelve other
     # cells build neither delta rule nor a grouped `rms_norm` and keep
-    # their text
+    # their text.  Re-pinned, PR 69, with `kimilinear-8k` below: the
+    # chunk-local kernels of both delta rules read q and k on QKV as it
+    # lies and take the l2norm themselves (`gated_delta.py RawQK`; no
+    # `head_norm_*` call before them; parents: 068ea25c.., 96c8ef2e..);
+    # the twelve other cells build neither op and keep their text
     "qwen3next-16k":
-    "068ea25cc30818c09bd46c533d40654ac00a7d2f89cc0a298e75dfa290d02b0f",
+    "6377ad3f12cd522827f8152edc5bd7a990baf2ba503638b51683d4418d2d4e7f",
     # re-pinned, PR 59: its block-diffusion flash kernels walk a
     # scalar-prefetched list of visits (`ops/pallas/
     # flash_block_diffusion.py`, here through the interpreter, a pass
@@ -466,7 +470,7 @@ STEP_TEXT = {
     # lower through the interpreter into this text); every other cell
     # keeps its parent's text: none builds the op
     "kimilinear-8k":
-    "96c8ef2e7ab03c97bd5bccfe096e24626c963aea9a703c98006766ff37aa61e3",
+    "30d48358de96dc053390f5736db72865f32816efab60ff0f0fa217bf459cf01c",
 }
 
 
@@ -561,6 +565,7 @@ def test_the_channel_delta_cells_step_holds_its_kernels_under_the_plan(
     assert (kernels["flash_mla_fwd"], kernels["flash_mla_dkv"],
             kernels["flash_mla_dq"]) == (1, 1, 0)
     assert (kernels["short_conv_fwd"], kernels["short_conv_bwd"]) == (8, 4)
-    # q's, k's and the output norm's head statistic in a delta layer's
-    # forward and recomputed forward, and their one backward pass (PR 68)
-    assert (kernels["head_norm_fwd"], kernels["head_norm_bwd"]) == (24, 12)
+    # the output norm's head statistic in a delta layer's forward and
+    # recomputed forward, and its one backward pass (PR 68; q's and k's
+    # are the chunk-local kernels' own since PR 69: 24 / 12 before)
+    assert (kernels["head_norm_fwd"], kernels["head_norm_bwd"]) == (8, 4)

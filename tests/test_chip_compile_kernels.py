@@ -154,18 +154,26 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     assert took["gated_delta_inverse_calls"] == 1
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
-    # q's and k's l2norm, each a head-statistic pass forward and one
-    # backward on QKV as it lies (`ops/pallas/head_norm.py`), under the
-    # op's own scope
-    assert (took["head_norm_calls"], took["head_norm_rows"]) == (4, 4 * t)
+    # q's and k's l2norm is the chunk-local kernels' own since PR 69
+    # (they read QKV as it lies and return the raw lanes' gradient): no
+    # head-statistic pass of `ops/pallas/head_norm.py` (4 calls, 4 * t
+    # rows before), and q and k inside QKV count as their lanes' bytes
+    assert (took["head_norm_calls"], took["head_norm_rows"]) == (0, 0)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
         "gated_delta_bwd", "gated_delta_fwd", "gated_delta_inverse",
-        "gated_delta_operands_bwd", "gated_delta_operands_fwd",
-        "head_norm_bwd", "head_norm_bwd", "head_norm_fwd", "head_norm_fwd"]
+        "gated_delta_operands_bwd", "gated_delta_operands_fwd"]
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "gated_delta_rule"}
     totals = cost.total_costs(proto)
-    assert totals["custom_calls"] == totals["pallas_matched"] == 9
+    assert totals["custom_calls"] == totals["pallas_matched"] == 5
+    size = 2 if dtype == BF16 else 4
+    by = {r["kernel"]: r["bytes"] for r in rows if r["kernel"]}
+    heads, tiles = n * t * d * size, n * hk * 256 * 8 * 128 * 4
+    assert by["gated_delta_inverse"] == (
+        hk * heads + tiles + n * hk * t * 128 * 4)
+    assert by["gated_delta_operands_fwd"] == (
+        (2 * hk + hv) * heads + tiles + n * hk * t * 128 * 4
+        + 4 * hv * heads + hv * n * t * 64 * size)
     # no float32 view of q or k a head: nothing for the chip to re-lay
     assert f"f32[{n},{t},{hk},{d}]" not in compiled.as_text()
     # no scan reader may take the chunk-local kernels for scan kernels:
@@ -234,18 +242,28 @@ def test_channel_delta_kernels_at_the_published_shapes(one_chip, dtype):
     assert took["gated_delta_calls"] == 0
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
-    # q's and k's l2norm, each a head-statistic pass forward and one
-    # backward on QKV as it lies (`ops/pallas/head_norm.py`), under the
-    # op's own scope
-    assert (took["head_norm_calls"], took["head_norm_rows"]) == (4, 4 * t)
+    # q's and k's l2norm is the chunk-local kernels' own since PR 69
+    # (they read QKV as it lies; kb = beta k is made of the raw k and
+    # takes k's 1 / norm there): no head-statistic pass of
+    # `ops/pallas/head_norm.py` (4 calls, 4 * t rows before), and q and
+    # k inside QKV count as their lanes' bytes
+    assert (took["head_norm_calls"], took["head_norm_rows"]) == (0, 0)
     assert sorted(r["kernel"] for r in rows if r["kernel"]) == [
         "channel_delta_bwd", "channel_delta_fwd", "channel_delta_inverse",
-        "channel_delta_operands_bwd", "channel_delta_operands_fwd",
-        "head_norm_bwd", "head_norm_bwd", "head_norm_fwd", "head_norm_fwd"]
+        "channel_delta_operands_bwd", "channel_delta_operands_fwd"]
     assert {r["op_type"] for r in rows if r["kernel"]} == {
         "channel_delta_rule"}
     totals = cost.total_costs(proto)
-    assert totals["custom_calls"] == totals["pallas_matched"] == 9
+    assert totals["custom_calls"] == totals["pallas_matched"] == 5
+    size = 2 if dtype == BF16 else 4
+    by = {r["kernel"]: r["bytes"] for r in rows if r["kernel"]}
+    lanes = n * t * h * d
+    assert by["channel_delta_inverse"] == (
+        3 * lanes * size + lanes * 4            # q, k, kb and g
+        + n * h // 2 * t * 128 * 4 + n * h * t * 64 * size)
+    assert by["channel_delta_operands_fwd"] == (
+        4 * lanes * size + lanes * 4 + n * h // 2 * t * 128 * 4
+        + 4 * lanes * size)
     scan = (3 + 6) * 2 * 64 * d * d + (1 + 2) * 2 * 64 * 64 * d
     local = 64 * ((2 + 2 + 8) * 2 * 64 * d + 2 * 64 * 64 / 3
                   + 2 * 2 * 64 * 64)

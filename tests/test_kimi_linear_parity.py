@@ -319,6 +319,104 @@ def test_the_decayed_products_gradients_are_the_dense_einsums(decay):
         close(g, w, name, scale=float(jnp.abs(w).max()))
 
 
+def raw_case(dtype, t=128, h=4, d=128, seed=11):
+    """QKV (1, T, 3 h d) as a projection writes it (rows of any norm),
+    g, beta, and what `channel_delta_rule` makes of them before its
+    chunk-local kernels each way: (q, k, kb) with `raw` = the l2norm
+    INSIDE the kernels (QKV twice as it lies, kb = beta x the raw k) and
+    with None = `head_norm_xla` first, then today's kernels (unit q and
+    k, kb = beta x the unit k); vb is the same."""
+    from paddle_tpu.ops.pallas import head_norm
+
+    r = np.random.default_rng(seed)
+    f32 = jnp.float32
+    qkv = jnp.asarray(r.normal(size=(1, t, 3 * h * d))
+                      * np.exp(r.normal(size=(1, t, 1))), dtype)
+    g = jnp.asarray(-0.1 * np.log1p(np.exp(r.normal(size=(1, t, h * d)))),
+                    f32)
+    beta = jnp.asarray(1 / (1 + np.exp(-r.normal(size=(1, t, h)))), f32)
+    raw = channel_delta.RawQK(q=0, k=h * d, heads=h, dim=d)
+
+    def times_beta(x, beta):
+        return (x.astype(f32) * channel_delta.head_spread(beta, d)).astype(
+            x.dtype)
+
+    def operands(qkv, beta, inside):
+        if inside:
+            q = k = qkv
+            kb = times_beta(qkv[..., h * d:2 * h * d], beta)
+        else:
+            form = head_norm.Form(0, h * d)
+            q = head_norm.head_norm_xla(
+                qkv[..., :h * d], None, None,
+                form._replace(constant=d ** -0.5), d)
+            k = head_norm.head_norm_xla(qkv[..., h * d:2 * h * d], None,
+                                        None, form, d)
+            kb = times_beta(k, beta)
+        return q, k, kb, times_beta(qkv[..., 2 * h * d:], beta)
+
+    return qkv, g, beta, raw, operands
+
+
+INSIDE_TOL = {"float32": 1e-6, "bfloat16": 0.02}
+
+
+@pytest.mark.parametrize("dtype", sorted(INSIDE_TOL))
+@pytest.mark.parametrize("kernel", ["inverse", "operands_fwd",
+                                    "operands_bwd"])
+def test_the_l2norm_inside_a_chunk_local_kernel_is_head_norm_before_it(
+        kernel, dtype):
+    """Each chunk-local kernel (interpret mode) on QKV as it lies, the
+    l2norm of q and k taken inside (`RawQK`), against `head_norm_xla`
+    followed by the same kernel on unit q and k: float32 to 1e-6 of the
+    largest entry, bfloat16 within the scan's own 2 %.  The backward
+    kernel through `operands_kernel`'s VJP: the gradients of the RAW
+    QKV (q's and k's lanes through the l2norm's rule, kb's riding k's),
+    g and beta, under one cotangent of the five results."""
+    qkv, g, beta, raw, operands = raw_case(jnp.dtype(dtype))
+    tol = INSIDE_TOL[dtype]
+
+    def same(got, want, names):
+        for name, a, b in zip(names, got, want):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
+            close(a.astype(jnp.float32), b.astype(jnp.float32), name, tol,
+                  scale=float(jnp.abs(b.astype(jnp.float32)).max()))
+
+    unit = operands(qkv, beta, False)
+    inside = operands(qkv, beta, True)
+    kept = channel_delta._inverse_call(*unit[:3], g, interpreted=True)
+    if kernel == "inverse":
+        same(channel_delta._inverse_call(*inside[:3], g, raw=raw,
+                                         interpreted=True), kept, "mp")
+        return
+    if kernel == "operands_fwd":
+        same(channel_delta._operands_fwd_call(*inside, g, kept[0], raw=raw,
+                                              interpreted=True),
+             channel_delta._operands_fwd_call(*unit, g, kept[0],
+                                              interpreted=True),
+             ("w", "u", "qg", "kd"))
+        return
+    cts = [jnp.asarray(np.random.default_rng(5 + i).normal(size=shape), dtype)
+           for i, shape in enumerate(
+               [(4, 128, 128)] * 4 + [(4, 128, channel_delta.CHUNK)])]
+
+    def results(way):
+        def fn(qkv, g, beta):
+            return channel_delta.operands_kernel(
+                *operands(qkv, beta, way is not None), g, *kept, way)
+        return jax.vjp(fn, qkv, g, beta)[1](tuple(cts))
+
+    before = runtime_stats.snapshot()
+    got = results(raw)
+    # the forward rule's kernel and the backward kernel, 2 chunks x 4 heads
+    assert runtime_stats.delta(before)["channel_delta_operand_chunks"] == 16
+    same(got, results(None), ("dqkv", "dg", "dbeta"))
+    third = qkv.shape[2] // 3
+    for i, name in enumerate(("raw q", "raw k")):
+        assert float(jnp.abs(got[0][..., i * third:(i + 1) * third]
+                             .astype(jnp.float32)).max()) > 0, name
+
+
 def test_a_decay_constant_over_the_lanes_gives_gated_deltas_numbers():
     """Gated DeltaNet is the case g_t constant over the lanes: the same
     o, to float32's order of summation, from `gated_delta.py`'s chunks

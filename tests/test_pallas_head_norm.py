@@ -285,21 +285,34 @@ def _delta_ops(t):
 @pytest.mark.parametrize("op", ["gated_delta_rule", "channel_delta_rule"])
 def test_a_delta_rules_l2norm_goes_by_the_shape_alone(op, t, calls,
                                                       monkeypatch):
-    """q and k of both delta-rule ops: two kernel calls a traced
-    forward where the rows are whole tiles, none where they are not,
-    and the op's result the same whichever ran (the kernels against the
-    view, switched off by the rule)."""
+    """q and k of both delta-rule ops.  Where the chunk-local kernels
+    run (`channel_delta_rule` here: two heads of 128) they take the
+    l2norm themselves (PR 69) and no head-statistic call is traced;
+    elsewhere (`gated_delta_rule` off its kernels) two kernel calls a
+    traced forward where the rows are whole tiles, none where they are
+    not.  The op's result is the same whichever ran: against the view
+    before the XLA lowering, everything switched off by the rules."""
     # (the shared helper: the op as ONE compiled function)
     from op_test import run_op as compiled_op
 
+    from paddle_tpu.ops.pallas import channel_delta
+
     ins, attrs = _delta_ops(t)[op]
+    inside = op == "channel_delta_rule"
     before = runtime_stats.snapshot()
     got = compiled_op(op, ins, attrs)
     took = runtime_stats.delta(before)
     assert (took["head_norm_calls"], took["head_norm_rows"]) == (
-        calls, calls * t)
+        (0, 0) if inside else (calls, calls * t))
+    # (the inverse kernel and the forward one)
+    assert took["channel_delta_operand_calls"] == (2 if inside else 0)
     monkeypatch.setattr(hn, "head_norm_takes", lambda *a: False)
+    monkeypatch.setattr(channel_delta, "kernel_takes", lambda *a: False)
+    before = runtime_stats.snapshot()
     want = compiled_op(op, ins, attrs)
+    took = runtime_stats.delta(before)
+    assert (took["head_norm_calls"],
+            took["channel_delta_operand_calls"]) == (0, 0)
     np.testing.assert_allclose(f32(got), f32(want), rtol=0,
                                atol=2e-5 * np.abs(f32(want)).max())
 

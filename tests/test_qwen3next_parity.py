@@ -411,6 +411,97 @@ def test_the_inverse_kernel_is_every_chunks_inverse(case):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def raw_case(dtype, t=128, hk=2, hv=4, d=gated_delta.HEAD_DIM, seed=11):
+    """QKV (1, T, (2 hk + hv) d) as a projection writes it (rows of any
+    norm), the row tiles of a g and a beta, and what `gated_delta_rule`
+    hands its chunk-operand kernels each way: (q, k, v) with `raw` = the
+    l2norm INSIDE the kernels (QKV twice, as it lies) and with None =
+    `head_norm_xla` first, then today's kernels on unit q and k."""
+    from paddle_tpu.ops.pallas import head_norm
+
+    r = np.random.default_rng(seed)
+    qkv = jnp.asarray(r.normal(size=(1, t, (2 * hk + hv) * d))
+                      * np.exp(r.normal(size=(1, t, 1))), dtype)
+    g = jnp.asarray(-0.05 * np.abs(r.normal(size=(1, t, hv))), jnp.float32)
+    beta = jnp.asarray(1 / (1 + np.exp(-r.normal(size=(1, t, hv)))),
+                       jnp.float32)
+    raw = gated_delta.RawQK(q=0, k=hk * d, heads=hk, dim=d)
+
+    def operands(qkv, inside):
+        v = qkv[..., 2 * hk * d:]
+        if inside:
+            return qkv, qkv, v
+        form = head_norm.Form(0, hk * d)
+        return (head_norm.head_norm_xla(
+                    qkv[..., :hk * d], None, None,
+                    form._replace(constant=d ** -0.5), d),
+                head_norm.head_norm_xla(qkv[..., hk * d:2 * hk * d], None,
+                                        None, form, d), v)
+
+    return qkv, gated_delta._row_tiles(g, beta, hk)[0], raw, operands
+
+
+INSIDE_TOL = {"float32": 1e-6, "bfloat16": 0.02}
+
+
+@pytest.mark.parametrize("dtype", sorted(INSIDE_TOL))
+@pytest.mark.parametrize("kernel", ["inverse", "operands_fwd",
+                                    "operands_bwd"])
+def test_the_l2norm_inside_a_chunk_operand_kernel_is_head_norm_before_it(
+        kernel, dtype):
+    """Each chunk-operand kernel (interpret mode) on QKV as it lies, the
+    l2norm of q and k taken inside (`RawQK`), against `head_norm_xla`
+    followed by the same kernel on unit q and k: float32 to 1e-6 of the
+    largest entry, bfloat16 within the scan's own 2 %.  The backward
+    kernel through `operands_kernel`'s VJP: the gradients of the RAW QKV
+    (q's and k's lanes through the l2norm's rule) and of the row tiles,
+    under one cotangent of the five results."""
+    from paddle_tpu.observe.monitoring import runtime_stats
+
+    qkv, x, raw, operands = raw_case(jnp.dtype(dtype))
+    tol = INSIDE_TOL[dtype]
+
+    def same(got, want, names):
+        for name, a, b in zip(names, got, want):
+            assert a.shape == b.shape and a.dtype == b.dtype, name
+            a, b = (np.asarray(y.astype(jnp.float32)) for y in (a, b))
+            np.testing.assert_allclose(a, b, rtol=0,
+                                       atol=tol * np.abs(b).max(),
+                                       err_msg=name)
+
+    unit, inside = operands(qkv, False), operands(qkv, True)
+    kept = gated_delta._inverse_call(unit[1], x, interpreted=True)
+    if kernel == "inverse":
+        same([gated_delta._inverse_call(inside[1], x, raw=raw,
+                                        interpreted=True)], [kept], "m")
+        return
+    if kernel == "operands_fwd":
+        same(gated_delta._operands_fwd_call(*inside, x, kept, raw=raw,
+                                            interpreted=True),
+             gated_delta._operands_fwd_call(*unit, x, kept,
+                                            interpreted=True),
+             ("w", "u", "qg", "kd", "p"))
+        return
+    cts = [jnp.asarray(np.random.default_rng(5 + i).normal(size=shape), dtype)
+           for i, shape in enumerate(
+               [(4, 128, 128)] * 4 + [(4, 128, gated_delta.CHUNK)])]
+
+    def results(way):
+        def fn(qkv, x):
+            return gated_delta.operands_kernel(
+                *operands(qkv, way is not None), x, kept, way)
+        return jax.vjp(fn, qkv, x)[1](tuple(cts))
+
+    before = runtime_stats.snapshot()
+    got = results(raw)
+    # the forward rule's kernel and the backward kernel, 2 chunks x 4 heads
+    assert runtime_stats.delta(before)["gated_delta_operand_chunks"] == 16
+    same(got, results(None), ("dqkv", "dx"))
+    for i, name in enumerate(("raw q", "raw k")):
+        assert np.abs(np.asarray(got[0][..., i * 256:(i + 1) * 256]
+                                 .astype(jnp.float32))).max() > 0, name
+
+
 @pytest.mark.parametrize("case", ["weak-decay", "repeated-keys",
                                   "ten-chunks-two-blocks-two-key-heads"])
 def test_a_segment_keeps_the_inverse_and_gives_the_same_gradients(case):
