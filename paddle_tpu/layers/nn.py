@@ -501,18 +501,21 @@ def selective_scan(u, delta, b, c, name=None):
     return out
 
 
-def ssd_scan(x, dt, b, c, n_heads, n_groups=1, chunk_size=256, name=None):
+def ssd_scan(xbc, dt, n_heads, d_state, n_groups=1, chunk_size=256,
+             name=None):
     """The scan of a Mamba-2 state-space mixer (ops/decoder.py
-    `ssd_scan`): `x` (N, T, H P) the convolved input, `n_heads` H heads
-    of P lanes; `dt` (N, T, H) the step's slice of the in projection
-    before its bias and the softplus; `b`, `c` (N, T, G S), `n_groups`
-    G groups of S states.  Three learned float32 parameters a head as
-    the published class starts them: `A_log` (H,) = log(1 .. H), `D`
-    (H,) = 1, and the step's bias `dt_bias` (H,) = the inverse softplus
-    of a step drawn log-uniformly from [0.001, 0.1] and floored at
-    1e-4.  `chunk_size`: the chunk of the matrix-product form.  Returns
-    y (N, T, H P), the read-out WITH the D x term and before any gate
-    or norm."""
+    `ssd_scan`): `xbc` (N, T, H P + 2 G S) the convolved [x | B | C] as
+    the mixer's one convolution leaves it, `n_heads` H heads of P lanes,
+    then `n_groups` G groups of `d_state` S states of B, then of C (the
+    op's kernels read the three out of it where it lies: do not split
+    it first); `dt` (N, T, H) the step's slice of the in projection
+    before its bias and the softplus.  Three learned float32 parameters
+    a head as the published class starts them: `A_log` (H,) = log(1 ..
+    H), `D` (H,) = 1, and the step's bias `dt_bias` (H,) = the inverse
+    softplus of a step drawn log-uniformly from [0.001, 0.1] and
+    floored at 1e-4.  `chunk_size`: the chunk of the matrix-product
+    form.  Returns y (N, T, H P), the read-out WITH the D x term and
+    before any gate or norm."""
     from ..initializer import NumpyArrayInitializer, SoftplusInverseLogUniform
 
     helper = LayerHelper("ssd_scan", name=name)
@@ -527,14 +530,16 @@ def ssd_scan(x, dt, b, c, n_heads, n_groups=1, chunk_size=256, name=None):
         None, shape=[h], dtype="float32",
         default_initializer=SoftplusInverseLogUniform(0.001, 0.1,
                                                       floor=1e-4))
-    out = helper.create_variable_for_type_inference(x.dtype)
+    out = helper.create_variable_for_type_inference(xbc.dtype)
     helper.append_op(
         type="ssd_scan",
-        inputs={"X": [x], "Dt": [dt], "B": [b], "C": [c], "ALog": [a_log],
-                "D": [skip], "DtBias": [dt_bias]},
+        inputs={"XBC": [xbc], "Dt": [dt], "ALog": [a_log], "D": [skip],
+                "DtBias": [dt_bias]},
         outputs={"Out": [out]},
-        attrs={"n_groups": int(n_groups), "chunk_size": int(chunk_size)})
-    out.desc.shape = tuple(x.shape)
+        attrs={"n_groups": int(n_groups), "d_state": int(d_state),
+               "chunk_size": int(chunk_size)})
+    out.desc.shape = tuple(xbc.shape[:-1]) + (
+        int(xbc.shape[-1]) - 2 * int(n_groups) * int(d_state),)
     return out
 
 

@@ -333,10 +333,13 @@ three projections of the one input (a checkpoint's fused matrix splits
 [z | xBC | dt] by columns); ONE convolution runs over x, B and C
 together (`layers.short_conv(activation="silu", bias_attr=)`, causal,
 depthwise, `mamba_d_conv` taps); the step needs no low-rank projection,
-only its bias; the recurrence is ONE op, `ssd_scan`
-(`ops/pallas/ssd_scan.py`: the chunked matrix-product form, chunks of
-`mamba_chunk_size`, two Pallas kernels at heads of 64, 128 states, one
-group, chunks of 256); the gate comes BEFORE the norm, the norm runs
+only its bias; the recurrence is ONE op, `ssd_scan`, whose input is
+xBC WHOLE, as the convolution leaves it (`ops/pallas/ssd_scan.py`: the
+chunked matrix-product form, chunks of `mamba_chunk_size`, two Pallas
+kernels at heads of 64, 128 states, one group, chunks of 256, which
+read x, B and C out of xBC's lanes and write d xBC as one array; a
+layer's recompute segment keeps xBC for them, so it convolves once);
+the gate comes BEFORE the norm, the norm runs
 over all d_inner lanes (`layers.gated_rms_norm`, one fused op, under
 the name scope `gated_rms_norm`).  `mamba_n_groups` > 1 raises.  Its
 ops lower under the `state_space_duality` name scope (Mamba-1 keeps
@@ -977,7 +980,9 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         (three projections of one input, as `dense_ffn` writes its two;
         a checkpoint's one fused matrix splits [z | xBC | dt] by
         columns), x, B and C TOGETHER through one short causal
-        convolution and a SiLU, the scan of heads, the gated norm."""
+        convolution and a SiLU, the scan of heads on xBC as it lies (no
+        split: the scan's kernels block the three out of its lanes),
+        the gated norm."""
         d_inner = mamba_n_heads * mamba_d_head
         with name_scope("state_space_duality"):
             z = proj(h, d_inner, "ssd_in")
@@ -986,10 +991,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                      "ssd_in"), mamba_d_conv, param_attr=weight(),
                 activation="silu", bias_attr=True)
             dt = proj(h, mamba_n_heads, "ssd_in")
-            x, b, c = layers.split(
-                xbc, [d_inner, mamba_n_groups * mamba_d_state,
-                      mamba_n_groups * mamba_d_state], dim=2)
-            y = layers.ssd_scan(x, dt, b, c, mamba_n_heads,
+            y = layers.ssd_scan(xbc, dt, mamba_n_heads, mamba_d_state,
                                 n_groups=mamba_n_groups,
                                 chunk_size=mamba_chunk_size)
             with name_scope("gated_rms_norm"):

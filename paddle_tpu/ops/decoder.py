@@ -547,17 +547,18 @@ def selective_scan(ctx, ins, attrs):
 @register_op("ssd_scan")
 def ssd_scan(ctx, ins, attrs):
     """The mixer core of a Mamba-2 state-space layer (state-space
-    duality, arXiv:2405.21060), over one sequence a row.  X (N, T, H P):
-    the convolved, activated input, H heads of P lanes; Dt (N, T, H):
-    the step's slice of the in projection BEFORE its bias and the
-    softplus; B, C (N, T, G S): `n_groups` G groups of S states, a
-    group shared by H / G heads; ALog, D and DtBias (H,).  In float32:
+    duality, arXiv:2405.21060), over one sequence a row.  XBC (N, T,
+    H P + 2 G S): the convolved, activated [x | B | C] AS THE
+    CONVOLUTION LEAVES IT, H heads of P lanes, then `n_groups` G groups
+    of `d_state` S states of B, then of C, a group shared by H / G
+    heads; Dt (N, T, H): the step's slice of the in projection BEFORE
+    its bias and the softplus; ALog, D and DtBias (H,).  In float32:
 
         dt = softplus(Dt + DtBias);  A = -exp(ALog)          (a head)
         S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] B_t^T
         Out_t[h] = S_t[h] C_t + D[h] x_t[h]                  (N, T, H P)
 
-    with S[h] (P, S) from 0; Out in X's dtype.  The bias and the
+    with S[h] (P, S) from 0; Out in XBC's dtype.  The bias and the
     softplus are taken HERE, not in the kernels: the step is a
     (position, head) scalar (2 MB a layer in float32 at 8192 x 64), so
     XLA makes it, its cumulative sums and their exponentials, and
@@ -566,17 +567,19 @@ def ssd_scan(ctx, ins, attrs):
     the shape alone (`ops/pallas/ssd_scan.py ssd_scan_takes`: heads of
     64 lanes, 128 states, one group, chunks of 256, T whole chunks):
     the two Pallas kernels there, whose state and decay masks never
-    leave VMEM, or the same chunks as XLA einsums under a `lax.scan`.
-    `runtime_stats.ssd_scans_kernel` / `_xla` count the calls traced
-    each way."""
+    leave VMEM, which block x, B and C out of XBC's lanes and write its
+    gradient as ONE array (no slice of XBC and no concatenation of
+    three gradients in HBM), or the same chunks as XLA einsums under a
+    `lax.scan` on the three slices.  `runtime_stats.ssd_scans_kernel` /
+    `_xla` count the calls traced each way."""
     from .pallas import selective_scan, ssd_scan as scan
 
     f32 = jnp.float32
     dt = selective_scan.softplus(first(ins, "Dt").astype(f32)
                                  + first(ins, "DtBias").astype(f32))
-    return out(Out=scan.ssd_scan(
-        first(ins, "X"), dt, -jnp.exp(first(ins, "ALog").astype(f32)),
-        first(ins, "B"), first(ins, "C"), first(ins, "D"),
+    return out(Out=scan.scan_joint(
+        first(ins, "XBC"), dt, -jnp.exp(first(ins, "ALog").astype(f32)),
+        first(ins, "D"), d_state=int(attrs["d_state"]),
         chunk=int(attrs.get("chunk_size", scan.CHUNK)),
         groups=int(attrs.get("n_groups", 1))))
 

@@ -54,7 +54,10 @@ def interpret() -> bool:
 # layer at 8192 x 5120 for a forward scan of the whole sequence), which
 # are all its backward kernel reads besides the operands; and the same
 # two of the scalar-a-head scan (`ssd_scan.py`: 67 + 67 MB a layer at
-# 8192 positions x 64 heads of 64 x 128 states); and the lane-decayed
+# 8192 positions x 64 heads of 64 x 128 states) with its operand xBC
+# (71 MB: the convolution's output, which the scan's kernels read as it
+# lies, so a segment that keeps it runs no convolution forward a second
+# time only to hand the backward kernel its operand); and the lane-decayed
 # delta rule's (I + A)^-1 and P (`channel_delta.py chunk_inverses`: 67 +
 # 34 MB a layer at 8192 positions x 32 heads for the column loops of
 # every chunk's diagonal sub-blocks and the substitution).  ONE
@@ -66,7 +69,8 @@ def interpret() -> bool:
 ATTENTION_RESIDUALS = ("attention_out", "attention_logsumexp")
 INVERSE_RESIDUAL = ("gated_delta_inverse",)
 SCAN_RESIDUALS = ("selective_scan_out", "selective_scan_states")
-SSD_RESIDUALS = ("ssd_scan_out", "ssd_scan_states")
+SSD_RESIDUALS = ("ssd_scan_out", "ssd_scan_states",
+                 "ssd_scan_operand")
 CHANNEL_DELTA_RESIDUALS = ("channel_delta_inverse", "channel_delta_scores")
 KEPT_RESIDUALS = (ATTENTION_RESIDUALS + INVERSE_RESIDUAL + SCAN_RESIDUALS
                   + SSD_RESIDUALS + CHANNEL_DELTA_RESIDUALS)

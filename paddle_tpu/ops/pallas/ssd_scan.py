@@ -9,10 +9,16 @@ the heads of a group:
     S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] x_t[h] B_t^T
     y_t[h] = S_t[h] C_t + D[h] x_t[h]
 
-`dt` is the step AFTER its bias and the softplus, float32 (N, T, H): a
-(position, head) scalar, 2 MB a layer at 8192 x 64, so the op
-(`ops/decoder.py ssd_scan`) makes it in XLA where the softplus's
-gradient is autodiff's; `a` = -exp(A_log) (H,).  The benchmark's
+x, B and C arrive as the mixer's ONE convolution leaves them, side by
+side in one array `xbc` (N, T, H P + 2 S) = [x | B | C] (71 MB a layer
+in bfloat16 at 8192 x 4352): the scan's operand is the convolution's
+result, whole, and its gradient is one array of that shape
+(`scan_joint`; `ssd_scan` is the same call for operands that lie
+apart, which it lays side by side first).  `dt` is the step AFTER its
+bias and the softplus, float32 (N, T, H): a (position, head) scalar,
+2 MB a layer at 8192 x 64, so the op (`ops/decoder.py ssd_scan`) makes
+it in XLA where the softplus's gradient is autodiff's; `a` =
+-exp(A_log) (H,).  The benchmark's
 reference and the tests write the recurrence as a `lax.scan` over
 positions.  What runs here is the paper's chunked form, chunks of L
 positions, with a_t = dt_t A (<= 0) and gamma_i = sum_{j<=i} a_j inside
@@ -30,7 +36,13 @@ lanes in whole blocks of `HEAD_BLOCK`, 128 states, ONE group, chunks of
 `CHUNK` = 256 and T whole chunks):
 
 **The kernels.**  Grid (batch, chunk, head block), the last two
-sequential.  Two heads of 64 lie side by side in a 128-lane tile, as
+sequential.  xBC is handed to the call THREE times, under three block
+specs: a head block's 512 lanes of x (lane block h of 512), B's 128
+lanes (lane block H P / 128 of 128) and C's (the next): no x, B or C
+is cut out of xBC in HBM (the cut of x alone was 67 MB written and read
+a layer, forward and recomputed, and the three gradients' glue as much
+again backward).
+Two heads of 64 lie side by side in a 128-lane tile, as
 `flash_gqa.py` lays its pairs; a pair's states are ONE (N = 128, 128)
 float32 tile (the state transposed: states down the sublanes, the
 pair's 2 x 64 lanes across), and the states of ALL pairs of a sequence
@@ -43,19 +55,25 @@ exp(gamma), exp(gamma_L - gamma)) and gamma again as rows (positions
 across the lanes), so the (L, L) decay mask exp(gamma_i - gamma_j) of
 a head is ONE broadcast subtraction, a select and an exponential in
 VMEM and is never a tensor in HBM (537 MB a layer in float32 at 8192 x
-64 otherwise).  B arrives as it lies and transposed (2 MB), C too
-backward, so every product is a plain or an NT matmul.  Matmul
+64 otherwise).  B arrives as it lies in xBC and transposed (2 MB, cut
+from xBC and turned by XLA), C too backward, so every product is a
+plain or an NT matmul.  Matmul
 operands are in the operands' dtype (bfloat16 under AMP, float32 at
 "highest" otherwise), every accumulator, decay and the carried state
 float32.  `ssd_scan_fwd` writes y and the state that ENTERS each chunk
 (float32: T / L x H x 64 x 128 x 4 B = 67 MB a layer at 8192).
 `ssd_scan_bwd` walks the chunks in reverse with dL/dS carried in VMEM,
 rebuilds a chunk's masks (both orientations) and its local y, and
-writes dx, the gradient of dt's direct uses and of gamma (a (position,
-head) each; XLA turns them into d dt and dA by a reversed cumulative
-sum a chunk), dB and dC (SUMS over the heads: accumulated in the
-output block in VMEM across the head blocks of a chunk, dG's part by
-one product at the last block), and dD's (1, lanes) partial sums a
+writes d xBC as ONE array in xBC's dtype: its output block is a
+chunk's WHOLE 4352 lanes under an index that does not move with the
+head block, so it stays in VMEM across a chunk's head blocks (2.2 MB
+in bfloat16) and goes back to HBM once; a head block stores dx at its
+own lanes (a dynamic, 128-aligned lane offset), dB and dC (SUMS over
+the heads) are accumulated in float32 scratch across the head blocks
+and rounded ONCE into the last 256 lanes at the last block, dG's part
+by one product there.  Beside it the gradient of dt's direct uses and
+of gamma (a (position, head) each; XLA turns them into d dt and dA by
+a reversed cumulative sum a chunk) and dD's (1, lanes) partial sums a
 chunk (XLA sums them).
 
     dgamma_j = dy_j . (Y_diag + Y_off)_j - dt_j x_j . dXdt_j
@@ -74,10 +92,14 @@ positions that neither write nor decay.  The fall-back for shapes the
 kernels do not tile and the path the CPU presets run.
 
 Tied by ONE `custom_vjp`, `scan_kernel`, under `jax.jit`.  Inside a
-recompute segment the forward rule's two results are named
-(`ops/pallas keep_residuals`): the segment keeps y and the entry
-states, so its backward pass runs `ssd_scan_bwd` on them and no forward
-scan a second time.  `runtime_stats.ssd_scans_kernel` / `_xla` count
+recompute segment the forward rule's two results AND its operand xBC
+are named (`ops/pallas keep_residuals`, `SSD_RESIDUALS`): the segment
+keeps y, the entry states and xBC (67 + 67 + 71 MB a layer), so its
+backward pass runs `ssd_scan_bwd` on them, no forward scan a second
+time and no convolution forward either: with the scan's operand the
+convolution's own output, re-making that one residual was all the
+second `short_conv_fwd` was for (the convolution's backward reads its
+input and filter).  `runtime_stats.ssd_scans_kernel` / `_xla` count
 the scans traced each way, `ssd_scan_chunks` the chunks x batch the
 kernels walk.  The benchmark finds the kernels by the PREFIX `ssd_scan`.
 """
@@ -112,18 +134,20 @@ def ssd_scan_takes(t, heads, head_dim, d_state, groups=1, chunk=CHUNK):
 # -- kernel cost registry (observe/cost.py) ----------------------------
 #
 # The FLOP the kernels EXECUTE on the MXU, from the operands' shapes
-# (x (N, T, H P) first, B (N, T, S) among them): a chunk's G once, and a
+# (xBC (N, T, H P + 2 S) first: x's width is what B and C leave): a
+# chunk's G once, and a
 # head's mask product, read-out and state update forward (2 L P (L + 2
 # S) a head a chunk); backward the forward's two again, the three mask
 # products (dG's, dXdt's and the rebuilt Y_diag) and the five state-side
 # products, G and dG's two a chunk (G^T is a transpose).  The decay masks' vector
 # work (an exponential, a select and two multiplies an entry) is not
 # counted: `peaks.json` has no row for it.  Bytes: operands and results
-# once (the default model).
+# once (the default model, which counts xBC ONCE though three block
+# specs read it: one buffer is one operand there).
 
 def _chunk_products(operand_shapes):
     (n, t, width), _ = operand_shapes[0]
-    heads = width // HEAD_DIM
+    heads = (width - 2 * STATE) // HEAD_DIM
     chunks = n * (t // CHUNK)
     shared = 2.0 * CHUNK * CHUNK * STATE
     mask = 2.0 * CHUNK * CHUNK * HEAD_DIM
@@ -314,15 +338,17 @@ def _fwd_kernel(x_ref, cols_ref, rows_ref, last_ref, b_ref, bt_ref, c_ref,
 
 
 def _bwd_kernel(x_ref, cols_ref, rows_ref, last_ref, b_ref, bt_ref, c_ref,
-                ct_ref, d_ref, entry_ref, next_ref, dy_ref, dx_ref, ddt_ref,
-                dgamma_ref, dlast_ref, db_ref, dc_ref, dd_ref, dstate,
-                g_scr, gt_scr, dg_scr):
+                ct_ref, d_ref, entry_ref, next_ref, dy_ref, dxbc_ref, ddt_ref,
+                dgamma_ref, dlast_ref, dd_ref, dstate, g_scr, gt_scr, dg_scr,
+                db_scr, dc_scr):
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
     j, hb = pl.program_id(1), pl.program_id(2)
     kind = x_ref.dtype
     pairs = HEAD_BLOCK // 2
+    wide = HEAD_BLOCK * HEAD_DIM
+    width = dxbc_ref.shape[2] - 2 * STATE       # x's lanes of a chunk's dxBC
 
     @pl.when(j == 0)
     def _nothing_follows_the_last_chunk():
@@ -335,8 +361,8 @@ def _bwd_kernel(x_ref, cols_ref, rows_ref, last_ref, b_ref, bt_ref, c_ref,
         gt_scr[...] = g_scr[...].T     # G^T to the bit: B C^T by a
         # product of its own may round an entry the other way
         dg_scr[...] = jnp.zeros(dg_scr.shape, f32)
-        db_ref[...] = jnp.zeros(db_ref.shape, f32)
-        dc_ref[...] = jnp.zeros(dc_ref.shape, f32)
+        db_scr[...] = jnp.zeros(db_scr.shape, f32)
+        dc_scr[...] = jnp.zeros(dc_scr.shape, f32)
 
     cols, rows = cols_ref[0, 0], rows_ref[0, 0]
     g, gt = g_scr[...], gt_scr[...]
@@ -379,8 +405,9 @@ def _bwd_kernel(x_ref, cols_ref, rows_ref, last_ref, b_ref, bt_ref, c_ref,
         back = _per_head(*back, length)
         reads = _dot(b, ds_k)               # B dS^T, before its decay
         dxdt = back + own * dyf + rest * reads
-        dx_ref[0, :, lanes] = (dt2 * dxdt + d_ref[:, lanes] * dyf).astype(
-            dx_ref.dtype)
+        dxbc_ref[0, :, pl.ds(pl.multiple_of(hb * wide + k * LANES, LANES),
+                             LANES)] = (
+            dt2 * dxdt + d_ref[:, lanes] * dyf).astype(dxbc_ref.dtype)
         # a (position, head)'s two scalars: row sums over a head's lanes.
         # gamma's: each later output's pull on it less its own pull on
         # the earlier ones, a sum that cancels all but the pairs that
@@ -396,19 +423,21 @@ def _bwd_kernel(x_ref, cols_ref, rows_ref, last_ref, b_ref, bt_ref, c_ref,
         dd_ref[0, 0, :, lanes] = jnp.sum(dyf * xf, axis=0, keepdims=True)
         down += jnp.sum(dyf * xdt_f, axis=1, keepdims=True)
         dyg = (dyf * grow).astype(kind)
-        dc_ref[0] += _dot(dyg, s_in.astype(kind), _NT)
-        db_ref[0] += _dot(write, ds_k, _NT)
+        dc_scr[...] += _dot(dyg, s_in.astype(kind), _NT)
+        db_scr[...] += _dot(write, ds_k, _NT)
         dstate[at] = last_ref[0, 0, 0, k:k + 1, :] * ds + _dot(ct, dyg)
     ddt_ref[0, 0] = ddt
     dgamma_ref[0, 0] = dgamma
-    dc_ref[0] += down * b.astype(f32)
-    db_ref[0] += down * c.astype(f32)
+    dc_scr[...] += down * b.astype(f32)
+    db_scr[...] += down * c.astype(f32)
 
     @pl.when(hb == pl.num_programs(2) - 1)
-    def _the_sum_over_heads_of_dg():
+    def _the_sum_over_heads_rounded_once_into_dxbc():
         dg = dg_scr[...].astype(kind)
-        dc_ref[0] += _dot(dg, b)
-        db_ref[0] += _dot(dg, c, _TN)
+        dxbc_ref[0, :, width:width + STATE] = (
+            db_scr[...] + _dot(dg, c, _TN)).astype(dxbc_ref.dtype)
+        dxbc_ref[0, :, width + STATE:] = (
+            dc_scr[...] + _dot(dg, b)).astype(dxbc_ref.dtype)
 
 
 def _halves(zg, zu, left):
@@ -449,14 +478,22 @@ def _scalars(dt, a):
             leaves.reshape(n, nc, nb, HEAD_BLOCK // 2, LANES))
 
 
-def _specs(nc, time):
-    """Block specs of a grid (batch, chunk, head block): x's layout,
-    the columns, the rows, exp(gamma_L), B or C, B or C transposed, D's
-    lanes, a chunk's entry states."""
+def _specs(time, heads):
+    """Block specs of a grid (batch, chunk, head block): a head block's
+    lanes of x (of xBC: block h of its 512-lane blocks; of y and dy as
+    they lie), the columns, the rows, exp(gamma_L), B's lanes of xBC
+    (the 128-lane block after x's) and C's (the next), B or C
+    transposed, D's lanes, a chunk's entry states."""
     from jax.experimental import pallas as pl
 
     wide = HEAD_BLOCK * HEAD_DIM
     pairs = HEAD_BLOCK // 2
+    after_x = heads * HEAD_DIM // STATE
+
+    def slab(at):           # 128 lanes of xBC, the same for every head
+        return pl.BlockSpec((1, CHUNK, STATE),
+                            lambda b, j, h: (b, time(j), at))
+
     return (
         pl.BlockSpec((1, CHUNK, wide), lambda b, j, h: (b, time(j), h)),
         pl.BlockSpec((1, 1, CHUNK, 4 * HEAD_BLOCK),
@@ -465,62 +502,71 @@ def _specs(nc, time):
                      lambda b, j, h: (b, h, 0, time(j))),
         pl.BlockSpec((1, 1, 1, pairs, LANES),
                      lambda b, j, h: (b, time(j), h, 0, 0)),
-        pl.BlockSpec((1, CHUNK, STATE), lambda b, j, h: (b, time(j), 0)),
+        slab(after_x), slab(after_x + 1),
         pl.BlockSpec((1, STATE, CHUNK), lambda b, j, h: (b, 0, time(j))),
         pl.BlockSpec((1, wide), lambda b, j, h: (0, h)),
         pl.BlockSpec((1, 1, pairs, STATE, LANES),
                      lambda b, j, h: (b, time(j), h, 0, 0)))
 
 
-def _transposed(v):
-    return jnp.swapaxes(v, 1, 2)
+def _transposed(xbc, at):
+    """B's (or C's) 128 lanes from lane `at` of xBC, states down the
+    sublanes: (N, S, T), 2 MB a layer, cut and turned by XLA."""
+    return jnp.swapaxes(xbc[:, :, at:at + STATE], 1, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("interpreted",))
-def _fwd_call(x, dt, a, b, c, d, interpreted=False):
+def _fwd_call(xbc, dt, a, d, interpreted=False):
     from jax.experimental.pallas import tpu as pltpu
 
     from . import pallas_call
 
     f32 = jnp.float32
-    n, t, width = x.shape
+    n, t, _ = xbc.shape
     heads = dt.shape[2]
+    width = heads * HEAD_DIM
     nc, nb = t // CHUNK, heads // HEAD_BLOCK
-    wide, col, row, last, shared, shared_t, lane, entry = _specs(
-        nc, lambda j: j)
+    wide, col, row, last, b_lanes, c_lanes, shared_t, lane, entry = _specs(
+        lambda j: j, heads)
     cols, rows, leaves = _scalars(dt.astype(f32), a.astype(f32))
+    # ONE array under three block specs: x's, B's and C's lanes of xBC
     return pallas_call(
         _fwd_kernel, name="ssd_scan_fwd", grid=(n, nc, nb),
-        in_specs=[wide, col, row, last, shared, shared_t, shared, lane],
+        in_specs=[wide, col, row, last, b_lanes, shared_t, c_lanes, lane],
         out_specs=[wide, entry],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=[jax.ShapeDtypeStruct((n, t, width), xbc.dtype),
                    jax.ShapeDtypeStruct((n, nc, heads // 2, STATE, LANES),
                                         f32)],
         scratch_shapes=[pltpu.VMEM((heads // 2, STATE, LANES), f32),
                         pltpu.VMEM((CHUNK, CHUNK), f32)],
         compiler_params=_params(),
-    )(x, cols, rows, leaves, b, _transposed(b), c,
+    )(xbc, cols, rows, leaves, xbc, _transposed(xbc, width), xbc,
       jnp.repeat(d.astype(f32), HEAD_DIM).reshape(1, width))
 
 
 @functools.partial(jax.jit, static_argnames=("interpreted",))
-def _bwd_call(x, dt, a, b, c, d, entry, dy, interpreted=False):
+def _bwd_call(xbc, dt, a, d, entry, dy, interpreted=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     from . import pallas_call
 
     f32 = jnp.float32
-    n, t, width = x.shape
+    n, t, joint = xbc.shape
     heads = dt.shape[2]
+    width = heads * HEAD_DIM
     nc, nb = t // CHUNK, heads // HEAD_BLOCK
     pairs = HEAD_BLOCK // 2
 
     def time(j):
         return nc - 1 - j
 
-    wide, col, row, last, shared, shared_t, lane, entry_spec = _specs(
-        nc, time)
+    (wide, col, row, last, b_lanes, c_lanes, shared_t, lane,
+     entry_spec) = _specs(time, heads)
+    # a chunk's WHOLE dxBC: the same block under every head block of a
+    # chunk, so it stays in VMEM while they fill x's lanes and goes back
+    # once, with dB and dC rounded into their lanes at the last
+    whole = pl.BlockSpec((1, CHUNK, joint), lambda b, j, h: (b, time(j), 0))
     # the state that LEAVES a chunk enters the next (after the last
     # chunk nothing reads it: its cotangent is 0)
     leaves_spec = pl.BlockSpec(
@@ -533,22 +579,22 @@ def _bwd_call(x, dt, a, b, c, d, entry, dy, interpreted=False):
     dtf, af = dt.astype(f32), a.astype(f32)
     cols, rows, leaves = _scalars(dtf, af)
     tile = pltpu.VMEM((CHUNK, CHUNK), f32)
-    dx, ddt, dgamma, dlast, db, dc, dd = pallas_call(
+    summed = pltpu.VMEM((CHUNK, STATE), f32)    # dB, dC over a chunk's heads
+    dxbc, ddt, dgamma, dlast, dd = pallas_call(
         _bwd_kernel, name="ssd_scan_bwd", grid=(n, nc, nb),
-        in_specs=[wide, col, row, last, shared, shared_t, shared, shared_t,
+        in_specs=[wide, col, row, last, b_lanes, shared_t, c_lanes, shared_t,
                   lane, entry_spec, leaves_spec, wide],
-        out_specs=[wide, scalar, scalar, last, shared, shared, partial],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_specs=[whole, scalar, scalar, last, partial],
+        out_shape=[jax.ShapeDtypeStruct(xbc.shape, xbc.dtype),
                    jax.ShapeDtypeStruct((n, nb, t, HEAD_BLOCK), f32),
                    jax.ShapeDtypeStruct((n, nb, t, HEAD_BLOCK), f32),
                    jax.ShapeDtypeStruct((n, nc, nb, pairs, LANES), f32),
-                   jax.ShapeDtypeStruct(b.shape, f32),
-                   jax.ShapeDtypeStruct(c.shape, f32),
                    jax.ShapeDtypeStruct((n, nc, 1, width), f32)],
         scratch_shapes=[pltpu.VMEM((heads // 2, STATE, LANES), f32),
-                        tile, tile, tile],
+                        tile, tile, tile, summed, summed],
         compiler_params=_params(),
-    )(x, cols, rows, leaves, b, _transposed(b), c, _transposed(c),
+    )(xbc, cols, rows, leaves, xbc, _transposed(xbc, width), xbc,
+      _transposed(xbc, width + STATE),
       jnp.repeat(d.astype(f32), HEAD_DIM).reshape(1, width), entry, entry,
       dy)
 
@@ -562,17 +608,18 @@ def _bwd_call(x, dt, a, b, c, d, entry, dy, interpreted=False):
     da = jnp.flip(jnp.cumsum(jnp.flip(dgamma, 2), axis=2), 2).reshape(
         n, t, heads)
     ddt = by_head(ddt).reshape(n, t, heads) + da * af
-    return (dx, ddt.astype(dt.dtype),
+    return (dxbc, ddt.astype(dt.dtype),
             jnp.sum(da * dtf, axis=(0, 1)).astype(a.dtype),
-            db.astype(b.dtype), dc.astype(c.dtype),
             jnp.sum(dd.reshape(-1, heads, HEAD_DIM),
                     axis=(0, 2)).astype(d.dtype))
 
 
 @jax.custom_vjp
-def scan_kernel(x, dt, a, b, c, d):
-    """`scan_xla` by the Pallas kernels (`ssd_scan_takes`)."""
-    return _vjp_fwd(x, dt, a, b, c, d)[0]
+def scan_kernel(xbc, dt, a, d):
+    """`scan_xla` of xBC's three slabs by the Pallas kernels
+    (`ssd_scan_takes`): xbc (N, T, H P + 2 S) = [x | B | C] as the
+    convolution leaves it; its gradient is ONE array of that shape."""
+    return _vjp_fwd(xbc, dt, a, d)[0]
 
 
 def _record(x):
@@ -583,42 +630,60 @@ def _record(x):
     return interpret()
 
 
-def _vjp_fwd(x, dt, a, b, c, d):
+def _vjp_fwd(xbc, dt, a, d):
     from . import SSD_RESIDUALS, keep_residuals
 
-    y, entry = keep_residuals(
-        *_fwd_call(x, dt, a, b, c, d, interpreted=_record(x)),
+    y, entry, xbc = keep_residuals(
+        *_fwd_call(xbc, dt, a, d, interpreted=_record(xbc)), xbc,
         names=SSD_RESIDUALS)
-    return y, (x, dt, a, b, c, d, entry)
+    return y, (xbc, dt, a, d, entry)
 
 
 def _vjp_bwd(res, dy):
-    x, *_ = res
-    return _bwd_call(*res, dy.astype(x.dtype), interpreted=_record(x))
+    xbc, *_ = res
+    return _bwd_call(*res, dy.astype(xbc.dtype), interpreted=_record(xbc))
 
 
 scan_kernel.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def ssd_scan(x, dt, a, b, c, d, chunk=CHUNK, groups=1):
-    """y (N, T, H P) of the recurrence at the top of this file.  x
-    (N, T, H P); dt (N, T, H) the step, after its bias and the
-    softplus; a (H,) the decay rates (negative: -exp(A_log)); b, c
-    (N, T, G S); d (H,).  The kernels where `ssd_scan_takes` the shape,
-    else `scan_xla`."""
-    n, t, width = x.shape
+def scan_joint(xbc, dt, a, d, d_state=STATE, chunk=CHUNK, groups=1):
+    """y (N, T, H P) of the recurrence at the top of this file on the
+    operand as the mixer's convolution leaves it: xbc (N, T, H P + 2 G
+    S) = [x | B | C]; dt (N, T, H) the step, after its bias and the
+    softplus; a (H,) the decay rates (negative: -exp(A_log)); d (H,).
+    The kernels where `ssd_scan_takes` the shape: they block x, B and C
+    out of xbc's lanes and write its gradient as one array.  Else
+    `scan_xla` on the three slices."""
+    n, t, joint = xbc.shape
     heads = a.shape[0]
-    if dt.shape != (n, t, heads) or width % heads or heads % groups \
-            or b.shape[:2] != (n, t) or b.shape[2] % groups \
-            or c.shape != b.shape or d.shape != (heads,):
+    width = joint - 2 * groups * d_state
+    if dt.shape != (n, t, heads) or width <= 0 or width % heads \
+            or heads % groups or d.shape != (heads,):
         raise ValueError(
-            f"ssd_scan: x {x.shape}, dt {dt.shape}, a {a.shape}, b "
-            f"{b.shape}, c {c.shape}, d {d.shape} are not H heads over T "
-            f"positions with {groups} groups of B and C")
-    if ssd_scan_takes(t, heads, width // heads, b.shape[2] // groups, groups,
-                      chunk):
-        return scan_kernel(x, dt, a, b.astype(x.dtype), c.astype(x.dtype), d)
+            f"ssd_scan: xbc {xbc.shape}, dt {dt.shape}, a {a.shape}, d "
+            f"{d.shape} are not H heads over T positions beside {groups} "
+            f"groups of {d_state} states of B and C")
+    if ssd_scan_takes(t, heads, width // heads, d_state, groups, chunk):
+        return scan_kernel(xbc, dt, a, d)
     from ...observe.monitoring import runtime_stats
 
     runtime_stats.record_ssd_scan(False, 0)
-    return scan_xla(x, dt, a, b, c, d, chunk, groups)
+    after_b = width + groups * d_state
+    return scan_xla(xbc[..., :width], dt, a, xbc[..., width:after_b],
+                    xbc[..., after_b:], d, chunk, groups)
+
+
+def ssd_scan(x, dt, a, b, c, d, chunk=CHUNK, groups=1):
+    """`scan_joint` of operands that lie apart: x (N, T, H P); b, c
+    (N, T, G S), taken into x's dtype.  They are laid side by side once
+    (autodiff cuts the joint gradient); the step's own path
+    (`ops/decoder.py ssd_scan`) hands over xBC as it lies."""
+    if b.shape[:2] != x.shape[:2] or b.shape[2] % groups \
+            or c.shape != b.shape:
+        raise ValueError(
+            f"ssd_scan: x {x.shape}, b {b.shape}, c {c.shape} are not H "
+            f"heads over T positions with {groups} groups of B and C")
+    return scan_joint(
+        jnp.concatenate([x, b.astype(x.dtype), c.astype(x.dtype)], axis=2),
+        dt, a, d, b.shape[2] // groups, chunk, groups)

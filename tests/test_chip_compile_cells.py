@@ -663,10 +663,13 @@ def _granite4h_traced(parameters, took):
             took["flash_gqa_backward_split"]) == (1, 0)
     assert took["gated_rms_norm_calls"] == 9
     assert took["selective_scans_kernel"] == took["selective_scans_xla"] == 0
-    # nine scans' (y, states) and the attention call's (o, logsumexp)
+    # nine scans' (y, states, xBC: the operand the kernels read as the
+    # convolution leaves it, PR 70) and the attention call's (o,
+    # logsumexp)
     assert took["recompute_kept_residuals"] == 10
     assert took["recompute_kept_bytes"] >= 9 * (8192 * 4096 * 2
-                                                + 32 * 32 * 128 * 128 * 4)
+                                                + 32 * 32 * 128 * 128 * 4
+                                                + 8192 * 4352 * 2)
 
 
 def _qwen3next_traced(parameters, took):
@@ -912,10 +915,15 @@ def test_the_state_space_duality_cells_step_and_the_plan_its_length_read(
     9.27 GB + temporaries 3.69 GB = 12.96 GB; at 16384 9.27 + 7.37 =
     16.64 GB, over the chip's 15.75 (PERF.md, PR 58).  A mamba layer is
     ONE `ssd_scan_fwd` and one `ssd_scan_bwd` (its segment keeps the
-    scan's output and entry states, 67 + 67 MB) and its biased
-    convolution's kernels; the attention layer one forward and one
-    backward `flash_gqa` kernel; no (chunks, heads, 256, 256) float32
-    decay mask is a tensor of the step."""
+    scan's output, entry states and operand xBC, 67 + 67 + 71 MB: PR
+    70) and ONE forward and one backward kernel of its biased
+    convolution (the segment's backward pass re-made xBC by a second
+    forward before the scan kept it: 18 / 9); the attention layer one
+    forward and one backward `flash_gqa` kernel; no (chunks, heads,
+    256, 256) float32 decay mask is a tensor of the step, and under the
+    mixers' scope (the scan's with it) no x is cut out of xBC and no d
+    xBC glued together: the kernels block the three out of its lanes
+    and write its gradient as one array."""
     import re
 
     parameters, compiled, plan, kernels, took = _cell_step("granite4h-8k",
@@ -924,9 +932,16 @@ def test_the_state_space_duality_cells_step_and_the_plan_its_length_read(
     assert plan["arguments"] == pytest.approx(9.27, abs=0.01)
     assert 12.0 < plan["total"] <= 15.0, plan
     assert (kernels["ssd_scan_fwd"], kernels["ssd_scan_bwd"]) == (9, 9)
-    assert (kernels["short_conv_fwd"], kernels["short_conv_bwd"]) == (18, 9)
+    assert (kernels["short_conv_fwd"], kernels["short_conv_bwd"]) == (9, 9)
     assert (kernels["flash_gqa_fwd"], kernels["flash_gqa_dkv"]) == (1, 1)
-    assert not re.search(r"f32\[[0-9,]*256,256\]", compiled.as_text())
+    text = compiled.as_text()
+    assert not re.search(r"f32\[[0-9,]*256,256\]", text)
+    in_a_mixer = [line for line in text.splitlines()
+                  if "state_space_duality/" in line]
+    assert [line for line in in_a_mixer if "/ssd_scan" in line]
+    assert not [line for line in in_a_mixer if re.search(
+        r"= bf16\[1,8192,4096\]\S* slice\(|"
+        r"= bf16\[1,8192,4352\]\S* concatenate\(", line)]
 
 
 # slow, 84 s.  Between its runs the driver's chip run of `qwen3next-16k`

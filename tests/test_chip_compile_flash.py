@@ -456,9 +456,16 @@ STEP_TEXT = {
     # attention under a scale of 2^-6, the four multipliers); every
     # other cell keeps its parent's text: a multiplier that is absent
     # appends no op, and the names `KEPT_RESIDUALS` gained are emitted
-    # by no other step
+    # by no other step.  Re-pinned, PR 70: the scan's operand is the
+    # convolution's xBC whole (no `split` op before `ssd_scan`; the
+    # kernels, here through the interpreter, read x, B and C under three
+    # block specs of the one array and write d xBC as one result; the
+    # forward rule names xBC beside y and the entry states; parent:
+    # d40ed03d..); the thirteen other cells build no such op and keep
+    # their text: the name `KEPT_RESIDUALS` gained is emitted by no
+    # other step
     "granite4h-8k":
-    "d40ed03ddc9294461d897b9b08d6cf9a242f1d851e516f29864de0b10c8e37ac",
+    "184e1704c8ebe55cdeda156072317e49c68e6b9150890b761defdb85f1905b80",
     # new in PR 65 (the lane-decayed delta rule's five kernels through
     # the interpreter, the sigmoid-gated norm a head, latent attention
     # under one direct q projection with nothing rotated); every other

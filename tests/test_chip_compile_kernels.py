@@ -407,14 +407,19 @@ def test_ssd_scan_kernels_at_the_published_shapes(one_chip, dtype):
     shape, (1, 8192) positions x 64 heads of 64 x 128 states in chunks
     of 256, in the cell's bfloat16 and the parity script's float32, and
     the biased SiLU convolution that feeds it (4352 = 34 x 128 channels
-    x 4 taps + a bias: x, B and C together): the shape rule takes both,
+    x 4 taps + a bias: x, B and C together), whose output IS the scan's
+    operand (PR 70: xBC whole under three block specs, d xBC one array
+    from a chunk-wide output block with a dynamic 128-aligned lane
+    offset): the shape rule takes both,
     so the scan with its gradient is TWO Mosaic kernels, `ssd_scan_fwd`
     and `ssd_scan_bwd` (which rebuilds a chunk's masks in VMEM and
     transposes its G there), and the convolution two more; each has a
     registered cost, the scan's the FLOP the chunked form executes, and
     sits under its op's scope.  No (chunks, heads, 256, 256) decay mask
     is a tensor of the compiled text: what leaves the kernels float32
-    is the entry states (67 MB) and a (position, head)'s scalars."""
+    is the entry states (67 MB) and a (position, head)'s scalars.  No x
+    is cut out of xBC and no d xBC glued together: nothing of x's width
+    or wider is a slice or a concatenation there."""
     import re
 
     from paddle_tpu.core.registry import OpContext, get_op_impl
@@ -434,9 +439,8 @@ def test_ssd_scan_kernels_at_the_published_shapes(one_chip, dtype):
                          {"activation": "silu"})["Out"][0]
             with jax.named_scope("state_space_duality/ssd_scan:9"):
                 return scan(ctx, {
-                    "X": [u[..., :d]], "Dt": [dt], "B": [u[..., d:d + s]],
-                    "C": [u[..., d + s:]], "ALog": [a_log], "D": [skip],
-                    "DtBias": [dt_bias]}, {"n_groups": 1,
+                    "XBC": [u], "Dt": [dt], "ALog": [a_log], "D": [skip],
+                    "DtBias": [dt_bias]}, {"n_groups": 1, "d_state": s,
                                            "chunk_size": 256})["Out"][0]
 
         o, vjp = jax.vjp(fn, xbc, w, bias, dt, a_log, skip, dt_bias)
@@ -475,6 +479,8 @@ def test_ssd_scan_kernels_at_the_published_shapes(one_chip, dtype):
     # the states that enter the 32 chunks, a pair of heads a tile: 67 MB
     assert f"f32[1,32,{heads // 2},{s},128]" in text
     assert not re.search(r"f32\[[0-9,]*256,256\]", text)
+    assert not re.search(
+        rf"= \w+\[1,{t},({d}|{wide})\]\S* (slice|concatenate)\(", text)
 
 
 def _flash_gqa_lowered_under(one_chip, scale):

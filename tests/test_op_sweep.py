@@ -1017,12 +1017,11 @@ S["selective_scan"] = dict(
     out="Out", shape=(1, 6, 8),
     grad=["U", "Delta", "ALog", "B", "C", "D", "DeltaBias"], tol=2e-2)
 S["ssd_scan"] = dict(
-    ins={"X": f32((1, 6, 8), 311), "Dt": f32((1, 6, 2), 312),
-         "ALog": f32((2,), 313), "B": f32((1, 6, 3), 314),
-         "C": f32((1, 6, 3), 315), "D": f32((2,), 316),
+    ins={"XBC": f32((1, 6, 8 + 2 * 3), 311), "Dt": f32((1, 6, 2), 312),
+         "ALog": f32((2,), 313), "D": f32((2,), 316),
          "DtBias": f32((2,), 317)},
-    attrs={"n_groups": 1, "chunk_size": 4}, out="Out", shape=(1, 6, 8),
-    grad=["X", "Dt", "ALog", "B", "C", "D", "DtBias"], tol=2e-2)
+    attrs={"n_groups": 1, "d_state": 3, "chunk_size": 4}, out="Out",
+    shape=(1, 6, 8), grad=["XBC", "Dt", "ALog", "D", "DtBias"], tol=2e-2)
 S["gated_rms_norm"] = dict(
     ins={"X": f32((1, 3, 8), 318), "Gate": f32((1, 3, 8), 319),
          "Scale": f32((8,), 320)},

@@ -69,10 +69,20 @@ def operands(n, t, heads, p=scan.HEAD_DIM, s=scan.STATE, groups=1, seed=0,
             jnp.asarray(r.uniform(*bias, size=(heads,)), f32))
 
 
-def op(*xs, chunk=scan.CHUNK, groups=1):
+def joint_op(xbc, dt, a_log, d, bias, states, chunk=scan.CHUNK, groups=1):
+    """The op on its own operand: [x | B | C] as one array."""
     impl = get_op_impl("ssd_scan")
-    return impl(OpContext(None), {k: [x] for k, x in zip(SLOTS, xs)},
-                {"chunk_size": chunk, "n_groups": groups})["Out"][0]
+    ins = {"XBC": xbc, "Dt": dt, "ALog": a_log, "D": d, "DtBias": bias}
+    return impl(OpContext(None), {k: [x] for k, x in ins.items()},
+                {"chunk_size": chunk, "n_groups": groups,
+                 "d_state": states})["Out"][0]
+
+
+def op(x, dt, a_log, b, c, d, bias, chunk=scan.CHUNK, groups=1):
+    """The op with x, B and C laid side by side for it: autodiff cuts
+    the joint gradient back into the three."""
+    return joint_op(jnp.concatenate([x, b, c], axis=2), dt, a_log, d, bias,
+                    b.shape[2] // groups, chunk, groups)
 
 
 def check(xs, kernel, chunk=scan.CHUNK, groups=1, tol=TOL):
@@ -174,9 +184,17 @@ def test_operands_that_are_no_heads_over_positions_raise():
     with pytest.raises(ValueError, match="are not H heads"):
         op(x[:, :, :63], dt, a_log, b, c, d, bias, chunk=8)
     with pytest.raises(ValueError, match="are not H heads"):
-        op(x, dt, a_log, b, c[:, :8], d, bias, chunk=8)
-    with pytest.raises(ValueError, match="are not H heads"):
         op(x, dt, a_log, b, c, d, bias, chunk=8, groups=3)
+    a = -jnp.exp(a_log)
+    with pytest.raises(ValueError, match="are not H heads"):
+        scan.scan_joint(jnp.concatenate([x, b, c], axis=2), dt[:, :, :3], a,
+                        d, d_state=32, chunk=8)
+    # what only operands that lie apart can get wrong
+    with pytest.raises(ValueError, match="are not H heads"):
+        scan.ssd_scan(x, dt, a, b, c[:, :8], d, chunk=8)
+    with pytest.raises(ValueError, match="are not H heads"):
+        scan.ssd_scan(x, dt, a, b[:, :, :31], c[:, :, :31], d, chunk=8,
+                      groups=2)
 
 
 def test_the_gated_norm_gates_before_it_normalises():
@@ -216,9 +234,185 @@ def test_the_registered_cost_is_the_chunked_forms_products():
     """What `observe/cost.py` injects at the custom calls: the FLOP the
     kernels execute, 4.26 M a token a layer forward at 64 heads (the
     sequential form has 2.10 M)."""
-    shapes = [((1, 8192, 4096), 2)]
+    shapes = [((1, 8192, 4096 + 2 * 128), 2)]        # xBC first
     flops, nbytes = scan.fwd_cost(shapes, None)
     assert nbytes is None
     assert flops / 8192 == 2 * 256 * 128 + 64 * (2 * 256 * 64 + 4 * 128 * 64)
     assert scan.bwd_cost(shapes, None)[0] / 8192 == 3 * 2 * 256 * 128 + 64 * (
         3 * 2 * 256 * 64 + 5 * 2 * 128 * 64)
+
+
+# -- the scan's operand is the convolution's result, whole (PR 70) ------
+#
+# The kernels block x, B and C out of xBC's lanes (one array under three
+# block specs), write d xBC as ONE array (dB and dC rounded once into its
+# last 256 lanes), and a recompute segment keeps xBC beside y and the
+# entry states, so its backward pass convolves no second time.
+
+def joint_operands(heads, dtype, t=2 * scan.CHUNK, seed=4):
+    """(xbc, dt, a, d) as `scan_joint` takes them (the step after its
+    softplus, the rates negative) and a cotangent of y."""
+    r = np.random.default_rng(seed)
+    f32 = jnp.float32
+    width = heads * scan.HEAD_DIM
+    step = np.exp(r.uniform(np.log(1e-3), np.log(1e-1), size=(1, t, heads)))
+    return (jnp.asarray(r.normal(size=(1, t, width + 2 * scan.STATE)), dtype),
+            jnp.asarray(step, f32),
+            -jnp.arange(1, heads + 1, dtype=f32),
+            jnp.asarray(1 + 0.1 * r.normal(size=(heads,)), f32)), \
+        jnp.asarray(r.normal(size=(1, t, width)), dtype)
+
+
+def sliced(xbc, dt, a, d):
+    """`scan_xla` on the three slices of xBC: what the kernels are held
+    to, and the form the step ran before (a split, then the scan)."""
+    width = xbc.shape[2] - 2 * scan.STATE
+    return scan.scan_xla(xbc[..., :width], dt, a,
+                         xbc[..., width:width + scan.STATE],
+                         xbc[..., width + scan.STATE:], d)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it, a
+    kernel's own body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _kernels(jaxpr):
+    import re
+
+    return sorted(re.search(r"pallas_(\w+)",
+                            str(e.source_info.name_stack)).group(1)
+                  for e in _eqns(jaxpr) if e.primitive.name == "pallas_call")
+
+
+@pytest.mark.parametrize("heads", [8, 16])
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 6e-3)],
+                         ids=["float32", "bfloat16"])
+def test_the_kernels_on_the_joint_operand(heads, dtype, tol):
+    """y, d xBC (x's lanes, B's and C's), d dt, dA and dD of the kernels
+    on xBC as it lies against `scan_xla` on its three slices, by the
+    norm of the difference over the norm: float32 to rounding; under
+    bfloat16 operands two roundings of every result to bfloat16 (3.1e-3
+    the largest seen here, d xBC; the chip's parity run holds the same
+    seven under 3e-3 at 8192 positions)."""
+    xs, ct = joint_operands(heads, dtype)
+    assert scan.ssd_scan_takes(xs[0].shape[1], heads, scan.HEAD_DIM,
+                               scan.STATE)
+    before = runtime_stats.snapshot()
+    got = with_pull_back(scan.scan_joint, ct)(*xs)
+    took = runtime_stats.delta(before)
+    assert (took["ssd_scans_kernel"], took["ssd_scans_xla"]) == (2, 0)
+    want = with_pull_back(sliced, ct)(*xs)
+    width = heads * scan.HEAD_DIM
+    assert got[1].shape == xs[0].shape and got[1].dtype == dtype
+
+    def parts(y, dxbc, *rest):
+        return (y, dxbc[..., :width], dxbc[..., width:width + scan.STATE],
+                dxbc[..., width + scan.STATE:]) + rest
+
+    for name, g, w in zip(("y", "dx", "dB", "dC", "ddt", "dA", "dD"),
+                          parts(*got), parts(*want)):
+        g, w = (np.asarray(v, np.float64) for v in (g, w))
+        assert np.linalg.norm(w) > 0, name
+        assert np.linalg.norm(g - w) <= tol * np.linalg.norm(w), name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_operands_that_lie_apart_are_the_joint_call_to_the_bit(dtype):
+    """`ssd_scan(x, dt, a, b, c, d)` lays the three side by side and is
+    `scan_joint` on that: y and every gradient bit for bit, d x, dB and
+    dC the three cuts of d xBC."""
+    (xbc, dt, a, d), ct = joint_operands(8, dtype)
+    width = xbc.shape[2] - 2 * scan.STATE
+    x, b, c = (xbc[..., :width], xbc[..., width:width + scan.STATE],
+               xbc[..., width + scan.STATE:])
+    y, dx, ddt, da, db, dc, dd = with_pull_back(scan.ssd_scan, ct)(
+        x, dt, a, b, c, d)
+    want = with_pull_back(scan.scan_joint, ct)(xbc, dt, a, d)
+    for g, w in zip((y, jnp.concatenate([dx, db, dc], axis=2), ddt, da, dd),
+                    want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+def test_the_backward_pass_writes_one_gradient_of_xbc_and_glues_nothing():
+    """The backward rule's jaxpr: `ssd_scan_bwd` alone, ONE result as
+    wide as xBC (no d x beside a dB and a dC), and no `concatenate` of
+    anything as wide as x; the forward's: no slice as wide as x (B^T and
+    C^T, 128 lanes each, are all that is cut from xBC)."""
+    (xbc, dt, a, d), ct = joint_operands(8, jnp.bfloat16)
+    width = xbc.shape[2] - 2 * scan.STATE
+    _, pull = jax.vjp(scan.scan_joint, xbc, dt, a, d)
+    backward = jax.make_jaxpr(pull)(ct).jaxpr
+    assert _kernels(backward) == ["ssd_scan_bwd"]
+    call, = (e for e in _eqns(backward) if e.primitive.name == "pallas_call")
+    wide = [v.aval.shape for v in call.outvars
+            if v.aval.shape[:2] == xbc.shape[:2]]   # a row a position
+    assert wide == [xbc.shape]
+    assert backward.outvars[0].aval.shape == xbc.shape
+    both = list(_eqns(backward)) + list(_eqns(
+        jax.make_jaxpr(scan.scan_joint)(xbc, dt, a, d).jaxpr))
+    assert not [e for e in both if e.primitive.name == "concatenate"
+                and e.outvars[0].aval.shape[-1] >= width]
+    assert not [e for e in both if e.primitive.name in (
+        "slice", "dynamic_slice") and e.outvars[0].aval.shape[-1] >= width]
+
+
+@pytest.mark.parametrize("policy", [True, False], ids=["kept", "no_policy"])
+def test_a_segment_around_convolution_and_scan_convolves_once(policy):
+    """A recompute segment (`jax.checkpoint` under the executor's
+    policy) around the mixer's biased convolution and its scan: the
+    forward rule names THREE values, y, the entry states and xBC, so
+    the differentiated step holds each forward kernel once; with the
+    policy taken away (the inputs alone are kept) both run twice."""
+    from paddle_tpu.ops import pallas as pallas_tier
+
+    heads, t, taps = 8, 2 * scan.CHUNK, 4
+    (xbc, dt, a, d), ct = joint_operands(heads, jnp.bfloat16)
+    r = np.random.default_rng(2)
+    w = jnp.asarray(r.normal(size=(xbc.shape[2], taps)) / taps, jnp.float32)
+    bias = jnp.asarray(r.normal(size=(xbc.shape[2],)), jnp.float32)
+    conv = get_op_impl("short_conv")
+
+    def mixer(u, w, bias, dt, a, d):
+        with pallas_tier.tracing_segment():
+            v = conv(OpContext(None), {"X": [u], "Filter": [w],
+                                       "Bias": [bias]},
+                     {"activation": "silu"})["Out"][0]
+            return scan.scan_joint(v, dt, a, d)
+
+    segment = jax.checkpoint(
+        mixer, policy=pallas_tier.segment_policy() if policy else None)
+
+    def step(*xs):
+        return jax.vjp(segment, *xs)[1](ct)
+
+    before = runtime_stats.snapshot()
+    jaxpr = jax.make_jaxpr(step)(xbc, w, bias, dt, a, d).jaxpr
+    took = runtime_stats.delta(before)
+    assert took["short_convs_kernel"] >= 1 and took["short_convs_xla"] == 0
+    names = [e.params["name"] for e in _eqns(jaxpr)
+             if e.primitive.name == "name"]
+    assert set(names) == set(pallas_tier.SSD_RESIDUALS) and len(
+        pallas_tier.SSD_RESIDUALS) == 3
+    y_bytes = t * heads * scan.HEAD_DIM * 2
+    states = (t // scan.CHUNK) * (heads // 2) * scan.STATE * scan.LANES * 4
+    calls = took["recompute_kept_residuals"]
+    assert calls >= 1 and took["recompute_kept_bytes"] == calls * (
+        y_bytes + states + xbc.size * 2)
+    again = [] if policy else ["short_conv_fwd", "ssd_scan_fwd"]
+    assert _kernels(jaxpr) == sorted(
+        ["short_conv_fwd", "ssd_scan_fwd", "short_conv_bwd", "ssd_scan_bwd"]
+        + again)
