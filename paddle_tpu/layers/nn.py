@@ -401,16 +401,16 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
 
 
 def gated_delta_rule(qkv, ba, n_key_head, n_value_head, key_dim, value_dim,
-                     use_pallas=False, name=None):
+                     name=None):
     """The scan of a gated-delta-rule linear-attention layer
     (ops/decoder.py `gated_delta_rule`): `qkv` (N, T, 2 Hk Dk + Hv Dv)
     is the convolved projection, q, k and v side by side; `ba`
     (N, T, 2 Hv) the write strength's and the decay's pre-activations,
     one of each a value head.  Two learned (Hv,) vectors: `A_log`, the
     log of the decay's rate, from log U(2^-10, 16), and `dt_bias`, from
-    1.  Returns (N, T, Hv Dv).  `use_pallas`: the kernels of
-    ops/pallas/gated_delta.py (Dk = Dv = 128; the chunk-local part's
-    too where Hv = 2 Hk)."""
+    1.  Returns (N, T, Hv Dv).  Heads of Dk = Dv = 128 run the kernels
+    of ops/pallas/gated_delta.py (the chunk-local part's too where
+    Hv = 2 Hk): the op chooses, from the shape."""
     from ..initializer import LogUniform
 
     helper = LayerHelper("gated_delta_rule", name=name)
@@ -428,7 +428,7 @@ def gated_delta_rule(qkv, ba, n_key_head, n_value_head, key_dim, value_dim,
         outputs={"Out": [out]},
         attrs={"n_key_head": int(n_key_head),
                "n_value_head": int(n_value_head), "key_dim": int(key_dim),
-               "value_dim": int(value_dim), "use_pallas": bool(use_pallas)})
+               "value_dim": int(value_dim)})
     out.desc.shape = tuple(qkv.shape[:-1]) + (int(n_value_head * value_dim),)
     return out
 
@@ -584,16 +584,15 @@ def diff_combine(x, n_kv_pair, lanes, lambda_init, epsilon=1e-5, name=None):
     return out
 
 
-def latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head,
-                     use_pallas=False, name=None):
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head, name=None):
     """The causal attention core of a latent-attention layer
     (ops/decoder.py `latent_attention`): head-major `q_nope`, `k_nope`
     (N, T, n_head*Dn), `q_rope` (N, T, n_head*Dr) and `v`
     (N, T, n_head*Dv), and ONE rotary key head `k_rope` (N, T, Dr) that
     every query head reads; a score is the unrotated and the rotary
     dot product together, times (Dn + Dr)^-1/2.  Returns
-    (N, T, n_head*Dv).  `use_pallas`: the flash kernels of
-    ops/pallas/flash_mla.py (Dn 128, Dr 64, Dv 128)."""
+    (N, T, n_head*Dv).  Heads of Dn 128, Dr 64, Dv 128 run the flash
+    kernels of ops/pallas/flash_mla.py: the op chooses, from the shape."""
     helper = LayerHelper("latent_attention", name=name)
     out = helper.create_variable_for_type_inference(v.dtype)
     helper.append_op(
@@ -601,7 +600,7 @@ def latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head,
         inputs={"QNope": [q_nope], "QRope": [q_rope], "KNope": [k_nope],
                 "KRope": [k_rope], "V": [v]},
         outputs={"Out": [out]},
-        attrs={"n_head": int(n_head), "use_pallas": bool(use_pallas)})
+        attrs={"n_head": int(n_head)})
     out.desc.shape = tuple(v.shape)
     return out
 

@@ -856,8 +856,6 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
         """The gated-delta-rule mixer: q, k, v out of ONE projection
         through a short causal convolution and a SiLU, the scan, and an
         output norm a head gated by a fourth projection z."""
-        from ..ops.pallas import gated_delta
-
         hk, hv = linear_num_key_heads, linear_num_value_heads
         dk, dv = linear_key_head_dim, linear_value_head_dim
         with name_scope("linear_attention"):
@@ -867,8 +865,7 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                 activation="silu")
             z = proj(h, hv * dv, "linear_qkvz")
             o = layers.gated_delta_rule(
-                qkv, proj(h, 2 * hv, "linear_ba"), hk, hv, dk, dv,
-                use_pallas=gated_delta.kernel_takes(dk, dv))
+                qkv, proj(h, 2 * hv, "linear_ba"), hk, hv, dk, dv)
             # this norm's scale starts at 1 whatever the others do
             return proj(layers.rms_norm(o, epsilon=eps, group_size=dv,
                                         gate=z), hidden_size, "linear_out")
@@ -917,13 +914,8 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             k_rope = rotary(proj(h, qk_rope_head_dim, "attn_kv_a"), 1)
             k_nope = proj(c_kv, heads * qk_nope_head_dim, "attn_kv_b")
             v = proj(c_kv, heads * v_head_dim, "attn_kv_b")
-            from ..ops.pallas import flash_mla
-
-            ctx = layers.latent_attention(
-                q_nope, q_rope, k_nope, k_rope, v, heads,
-                use_pallas=(qk_nope_head_dim, qk_rope_head_dim, v_head_dim)
-                == (flash_mla.NOPE_DIM, flash_mla.ROPE_DIM,
-                    flash_mla.NOPE_DIM))
+            ctx = layers.latent_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                          heads)
             return proj(ctx, hidden_size, "attn_out")
 
     # what crosses layers, by what is exported: the exporting mamba

@@ -390,10 +390,13 @@ def latent_attention(ctx, ins, attrs):
         s_ij = (q_nope_i . k_nope_j + q_rope_i . k_rope_j) / sqrt(Dn + Dr)
         Out  = causal_softmax(s) V                       (N, T, H*Dv)
 
-    `use_pallas` sends it to the kernels of `ops/pallas/flash_mla.py`
-    (Dn 128, Dr 64, Dv 128), which read the operands where they lie:
-    the rotary key is never repeated over the heads and V is never
-    padded to the score's width."""
+    Two lowerings, chosen by the shape alone (`flash_mla.flash_mla_takes`:
+    Dn 128, Dr 64, Dv 128): the kernels of `ops/pallas/flash_mla.py`,
+    which read the operands where they lie (the rotary key is never
+    repeated over the heads and V is never padded to the score's
+    width), or `plain_latent_attention`."""
+    from .pallas.flash_mla import flash_mla, flash_mla_takes
+
     q_nope, q_rope = first(ins, "QNope"), first(ins, "QRope")
     k_nope, k_rope = first(ins, "KNope"), first(ins, "KRope")
     v = first(ins, "V")
@@ -407,9 +410,7 @@ def latent_attention(ctx, ins, attrs):
             f"KNope {k_nope.shape}, KRope {k_rope.shape}, V {v.shape} are "
             f"not {n_head} heads and one rotary key head")
     scale = (nope + rope_dim) ** -0.5
-    if attrs.get("use_pallas", False):
-        from .pallas.flash_mla import flash_mla
-
+    if flash_mla_takes(nope, rope_dim, v.shape[-1] // n_head):
         return out(Out=flash_mla(q_nope, q_rope, k_nope, k_rope, v, scale))
     return out(Out=plain_latent_attention(q_nope, q_rope, k_nope, k_rope, v,
                                           n_head, scale))
@@ -431,13 +432,13 @@ def gated_delta_rule(ctx, ins, attrs):
     with S (Dk, Dv) a value head from 0, value head h reading key head
     h // (Hv / Hk).  The scan runs in chunks of 64 positions
     (`ops/pallas/gated_delta.py`): its dots in QKV's dtype, state,
-    decay and the chunk's inverse in float32.  `use_pallas` sends the
-    sequential part to the Pallas kernels there (Dk = Dv = 128) and,
-    where two value heads read a key head, the chunk-local part to its
-    own two (a chunk's matrices then stay in VMEM); without it the
-    first is a `lax.scan` over the chunks and the second XLA's batch
-    over all of them.  q and k go in as the projection wrote them, QKV
-    twice with their first lanes (`gated_delta.RawQK`: no slice and no
+    decay and the chunk's inverse in float32.  The shape alone sends the
+    sequential part to the Pallas kernels there (`gated_delta.kernel_takes`:
+    Dk = Dv = 128) and, where two value heads read a key head, the
+    chunk-local part to its own two (a chunk's matrices then stay in
+    VMEM); elsewhere the first is a `lax.scan` over the chunks and the
+    second XLA's batch over all of them.  q and k go in as the
+    projection wrote them, QKV twice with their first lanes (`gated_delta.RawQK`: no slice and no
     float32 (N, T, H, Dk) view, which the chip would re-lay): the
     chunk-local kernels take the l2norm of the head they hold, any
     other lowering goes through `ops/pallas/head_norm.py` first."""
@@ -461,7 +462,7 @@ def gated_delta_rule(ctx, ins, attrs):
     g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
         ba[..., hv:].astype(f32) + dt_bias.astype(f32))
     o = gated_delta.gated_delta_rule(
-        qkv, qkv, v, g, beta, use_kernel=bool(attrs.get("use_pallas", False)),
+        qkv, qkv, v, g, beta, use_kernel=gated_delta.kernel_takes(dk, dv),
         raw=gated_delta.RawQK(q=0, k=hk * dk, heads=hk, dim=dk))
     return out(Out=o.reshape(n, t, hv * dv))
 

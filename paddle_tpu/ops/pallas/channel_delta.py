@@ -80,7 +80,7 @@ its rows as it reads them (`_unit_heads`; kb takes k's 1 / norm
 there) and `_operands_bwd` returns the gradients of
 the raw lanes and of the raw kb (`gated_delta._raw_gradient`; what
 reaches k's norm through kb rides k's).  Unit q and k (no `raw`) remain
-the tests', the references' and `tools/time_*`'s entry.
+the tests', the references' and `tools/time_kernel.py`'s entry.
 
 **The sequential part** reads S: grid (batch x head, blocks of chunks),
 the state TRANSPOSED, (Dv, Dk) float32, in VMEM scratch across the grid

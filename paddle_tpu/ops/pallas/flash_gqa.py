@@ -31,9 +31,9 @@ multiplies the scores) and masked a head, `[q0 | 0]` and `[0 | q1]`,
 once a query tile into scratch, and meets `[k | k]` and `[v | v]`
 whole: no lane mask on a key or value tile, no multiply a score.  Tiles
 are 1024 x 1024 where T is a whole number of them, else 512 x 512
-(`default_blocks`: from the shape alone, for both passes; at 8192
-positions 4.7 ms a call where 512 x 512 tiles and a step a pair took
-7.8: `tools/time_flash_gqa.py`).  Blocks above the diagonal are
+(`default_blocks`: from the shape alone, for both passes; at 8192 rows
+4.7 ms a call where 512 x 512 tiles and a step a pair took 7.8: PR 56,
+`tools/time_kernel.py flash_gqa --sweep`).  Blocks above the diagonal are
 skipped and their DMA with them (the index maps clamp to the last block
 that is needed); only the blocks the diagonal crosses build and apply
 the causal mask.  The soft-max statistics are the (N*H, 8, T)

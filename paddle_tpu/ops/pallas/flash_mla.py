@@ -89,6 +89,12 @@ _VMEM_LIMIT = 100 << 20
 FUSED_ACCUMULATOR_BUDGET = 32 << 20
 
 
+def flash_mla_takes(nope, rope, value):
+    """Whether the kernels run heads of `nope` + `rope` score lanes and
+    `value` value lanes: from the shape alone."""
+    return (nope, rope, value) == (NOPE_DIM, ROPE_DIM, NOPE_DIM)
+
+
 def fused_backward_fits(t):
     """Whether the backward pass of a sequence of `t` positions is the
     single kernel: from the shape alone, never from an option."""

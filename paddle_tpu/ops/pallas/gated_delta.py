@@ -82,8 +82,8 @@ lane reduction a row), which `lane_range_gradient` pads to QKV's width
 as a slice's is.  No unit q or k is in HBM, and no `head_norm_*` call
 stands before the kernels (two forward and two backward passes a layer
 and call site before).  Unit operands remain the tests', the references'
-and `tools/time_*`'s entry; a shape the kernels do not take goes through
-`unit_q_and_k` (`head_norm.py`) first.
+and `tools/time_kernel.py`'s entry; a shape the kernels do not take goes
+through `unit_q_and_k` (`head_norm.py`) first.
 
 The inverse is made BEFORE the `custom_vjp` that holds the other two
 (`chunk_inverses`, on k and the row tile as constants: the backward

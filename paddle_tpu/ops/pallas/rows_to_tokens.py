@@ -54,8 +54,8 @@ KERNEL = "rows_to_tokens"
 LANES = 128
 # tokens a tile, rows a chunk: a share's tile of 128 tokens holds ~128
 # rows, so a visit is one square MXU pass a lane group and the work is
-# ~3 x 2 x 128 x R x D; timed alone on the chip against (256, 128),
-# (256, 256) and (512, 256) (tools/time_share_rows.py; PERF.md, PR 50)
+# ~3 x 2 x 128 x R x D; timed alone on the chip against (256, 128), (256,
+# 256), (512, 256) (PR 50; `tools/time_kernel.py share_rows --sweep ...`)
 TOKEN_TILE = 128
 ROW_CHUNK = 128
 V_TILE, V_CHUNK, V_FIRST = range(3)

@@ -40,7 +40,7 @@ def _latent_attention_lowered(one_chip, dtype):
             o = impl(OpContext(jax.random.PRNGKey(0), 0),
                      {"QNope": [q_nope], "QRope": [q_rope],
                       "KNope": [k_nope], "KRope": [k_rope], "V": [v]},
-                     {"n_head": heads, "use_pallas": True})["Out"][0]
+                     {"n_head": heads})["Out"][0]
         return jnp.sum(o.astype(F32))
 
     widths = (heads * 128, heads * 64, heads * 128, 64, heads * 128)
@@ -134,7 +134,7 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
                      {"QKV": [qkv], "BA": [ba], "ALog": [a_log],
                       "DtBias": [dt_bias]},
                      {"n_key_head": hk, "n_value_head": hv, "key_dim": d,
-                      "value_dim": d, "use_pallas": True})["Out"][0]
+                      "value_dim": d})["Out"][0]
         return jnp.sum(o.astype(F32))
 
     args = [jax.ShapeDtypeStruct(shape, kind, sharding=one_chip)

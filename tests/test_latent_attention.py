@@ -42,8 +42,8 @@ def dense(q_nope, q_rope, k_nope, k_rope, v):
     s = jnp.einsum("nqhd,nkhd->nhqk", q, k) * (NOPE + ROPE) ** -0.5
     s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
     o = jnp.einsum("nhqk,nkhd->nqhd", jax.nn.softmax(s, axis=-1),
-                   v.reshape(n, t, h, NOPE))
-    return o.reshape(n, t, h * NOPE)
+                   v.reshape(n, t, h, -1))
+    return o.reshape(n, t, -1)
 
 
 # (N, T, H, block_q, block_k): square blocks, q blocks of two k blocks
@@ -256,14 +256,25 @@ def test_kernel_costs_are_registered_under_the_kernels_names():
     assert one == two
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_the_op_gives_dense_attention_on_both_paths(use_pallas):
+@pytest.mark.parametrize("dims, kernel_calls", [
+    ((NOPE, ROPE, NOPE), 1), ((NOPE, ROPE, 64), 0)],
+    ids=["the_kernels_shape", "a_shape_they_do_not_take"])
+def test_the_op_gives_dense_attention_on_both_paths(dims, kernel_calls,
+                                                    monkeypatch):
+    """The op chooses by the shape alone (`flash_mla_takes`): heads of
+    128 + 64 score lanes and 128 value lanes go to the kernels, a value
+    head of 64 to `plain_latent_attention`; both are dense attention."""
     *args, _ = operands(1, 128, 2, seed=3)
+    args[4] = args[4][..., :2 * dims[2]]
+    calls = []
+    kernel = flash_mla.flash_mla
+    monkeypatch.setattr(flash_mla, "flash_mla",
+                        lambda *a, **kw: calls.append(1) or kernel(*a, **kw))
     impl = get_op_impl("latent_attention")
     out = impl(OpContext(jax.random.PRNGKey(0)),
                dict(zip(("QNope", "QRope", "KNope", "KRope", "V"),
-                        ([a] for a in args))),
-               {"n_head": 2, "use_pallas": use_pallas})["Out"][0]
+                        ([a] for a in args))), {"n_head": 2})["Out"][0]
+    assert len(calls) == kernel_calls
     np.testing.assert_allclose(out, dense(*args), atol=2e-5)
 
 

@@ -273,7 +273,7 @@ def _delta_ops(t):
             {"QKV": draw(1, t, 3 * h * d), "BA": draw(1, t, 2 * h),
              "ALog": draw(h), "DtBias": draw(h)},
             {"n_key_head": h, "n_value_head": h, "key_dim": d,
-             "value_dim": d, "use_pallas": False}),
+             "value_dim": d}),
         "channel_delta_rule": (
             {"QKV": draw(1, t, 3 * h * d), "Gate": draw(1, t, h * d),
              "Beta": draw(1, t, h), "ALog": draw(h), "DtBias": draw(h * d)},
@@ -288,14 +288,15 @@ def test_a_delta_rules_l2norm_goes_by_the_shape_alone(op, t, calls,
     """q and k of both delta-rule ops.  Where the chunk-local kernels
     run (`channel_delta_rule` here: two heads of 128) they take the
     l2norm themselves (PR 69) and no head-statistic call is traced;
-    elsewhere (`gated_delta_rule` off its kernels) two kernel calls a
+    elsewhere (`gated_delta_rule` at one value head a key head, which
+    its chunk-local kernels do not take) two kernel calls a
     traced forward where the rows are whole tiles, none where they are
     not.  The op's result is the same whichever ran: against the view
     before the XLA lowering, everything switched off by the rules."""
     # (the shared helper: the op as ONE compiled function)
     from op_test import run_op as compiled_op
 
-    from paddle_tpu.ops.pallas import channel_delta
+    from paddle_tpu.ops.pallas import channel_delta, gated_delta
 
     ins, attrs = _delta_ops(t)[op]
     inside = op == "channel_delta_rule"
@@ -308,6 +309,7 @@ def test_a_delta_rules_l2norm_goes_by_the_shape_alone(op, t, calls,
     assert took["channel_delta_operand_calls"] == (2 if inside else 0)
     monkeypatch.setattr(hn, "head_norm_takes", lambda *a: False)
     monkeypatch.setattr(channel_delta, "kernel_takes", lambda *a: False)
+    monkeypatch.setattr(gated_delta, "kernel_takes", lambda *a: False)
     before = runtime_stats.snapshot()
     want = compiled_op(op, ins, attrs)
     took = runtime_stats.delta(before)

@@ -597,7 +597,7 @@ def test_the_op_with_its_gates_is_the_sequential_recurrence():
                     {"QKV": [qkv], "BA": [ba], "ALog": [a_log],
                      "DtBias": [dt_bias]},
                     {"n_key_head": hk, "n_value_head": hv, "key_dim": d,
-                     "value_dim": d, "use_pallas": True})["Out"][0]
+                     "value_dim": d})["Out"][0]
 
     def recurrence(qkv, ba, a_log, dt_bias):
         def l2norm(x):

@@ -201,7 +201,7 @@ def _attention_layer(h, geometry, tag, recompute=True):
         if kernel == "flash_mla_fwd":
             o = layers.latent_attention(
                 q, _proj(h, heads * 64, f"qr{tag}"), k,
-                _proj(h, 64, f"kr{tag}"), v, heads, use_pallas=True)
+                _proj(h, 64, f"kr{tag}"), v, heads)
         else:
             o = layers.flash_attention(
                 q, k, v, causal=True, use_pallas=True, layout="nthd",
