@@ -83,7 +83,7 @@ _FIELDS = Heard._fields[1:] + (
     "flash_block_diffusion_entries_computed",
     "gated_delta_calls", "gated_delta_chunks",
     "gated_delta_operand_calls", "gated_delta_operand_chunks",
-    "gated_delta_inverse_calls",
+    "gated_delta_inverse_calls", "gated_delta_flat_calls",
     "channel_delta_calls", "channel_delta_chunks",
     "channel_delta_operand_calls", "channel_delta_operand_chunks",
     "head_norm_calls", "head_norm_rows",
@@ -235,6 +235,13 @@ class RuntimeStats:
         # `chunk_operands` ran
         self.gated_delta_operand_calls = 0
         self.gated_delta_operand_chunks = 0
+        # of both kinds, the calls whose blocks address the op's own
+        # arrays (PR 72): a scan call, which writes o and reads dO as
+        # (N, T, Hv x 128) by lane block (every one: the kernels have
+        # no other form), and a chunk-operand call that took q, k AND v
+        # on QKV as it lies and handed back one dQKV (`RawQK.v`; 0 for
+        # operands apart).  The cell: 9 + 9
+        self.gated_delta_flat_calls = 0
         # calls traced of the kernel that solves for the chunks'
         # (I + A)^-1 (`gated_delta_inverse`, before the custom VJP of
         # the chunk-operand kernels: traced where the layer is, once,
@@ -442,11 +449,13 @@ class RuntimeStats:
         with self._lock:
             self.gated_delta_calls += 1
             self.gated_delta_chunks += chunks
+            self.gated_delta_flat_calls += 1
 
-    def record_gated_delta_operands(self, chunks: int):
+    def record_gated_delta_operands(self, chunks: int, flat: bool = False):
         with self._lock:
             self.gated_delta_operand_calls += 1
             self.gated_delta_operand_chunks += chunks
+            self.gated_delta_flat_calls += int(flat)
 
     def record_gated_delta_inverse(self):
         with self._lock:

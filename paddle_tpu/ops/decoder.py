@@ -437,11 +437,16 @@ def gated_delta_rule(ctx, ins, attrs):
     Dk = Dv = 128) and, where two value heads read a key head, the
     chunk-local part to its own two (a chunk's matrices then stay in
     VMEM); elsewhere the first is a `lax.scan` over the chunks and the
-    second XLA's batch over all of them.  q and k go in as the
-    projection wrote them, QKV twice with their first lanes (`gated_delta.RawQK`: no slice and no
-    float32 (N, T, H, Dk) view, which the chip would re-lay): the
-    chunk-local kernels take the l2norm of the head they hold, any
-    other lowering goes through `ops/pallas/head_norm.py` first."""
+    second XLA's batch over all of them.  q, k AND v go in as the
+    projection wrote them: QKV once, with where each lies
+    (`gated_delta.RawQK`: no slice and no float32 (N, T, H, Dk) view,
+    which the chip would re-lay).  The chunk-local kernels block the
+    three out of QKV's lanes, take the l2norm of the head they hold and
+    hand back ONE dQKV, each lane written once; the scan kernels write
+    Out as it lies here, (N, T, Hv Dv), a value head's lanes a grid
+    step, and read its cotangent so.  Any other lowering cuts v out,
+    goes through `ops/pallas/head_norm.py` first and transposes the
+    scan's head-major result."""
     from .pallas import gated_delta
 
     qkv, ba = first(ins, "QKV"), first(ins, "BA")
@@ -457,13 +462,14 @@ def gated_delta_rule(ctx, ins, attrs):
             f"gates a value head")
     f32 = jnp.float32
 
-    v = qkv[..., 2 * hk * dk:].reshape(n, t, hv, dv)
     beta = jax.nn.sigmoid(ba[..., :hv].astype(f32))
     g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
         ba[..., hv:].astype(f32) + dt_bias.astype(f32))
     o = gated_delta.gated_delta_rule(
-        qkv, qkv, v, g, beta, use_kernel=gated_delta.kernel_takes(dk, dv),
-        raw=gated_delta.RawQK(q=0, k=hk * dk, heads=hk, dim=dk))
+        qkv, None, None, g, beta,
+        use_kernel=gated_delta.kernel_takes(dk, dv),
+        raw=gated_delta.RawQK(q=0, k=hk * dk, heads=hk, dim=dk,
+                              v=2 * hk * dk, value_dim=dv))
     return out(Out=o.reshape(n, t, hv * dv))
 
 

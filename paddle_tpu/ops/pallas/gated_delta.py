@@ -72,18 +72,35 @@ solved and multiplied in one):
 
 The three take q and k either as unit vectors (N, T, Hk x 128) or, with
 `raw` (a `RawQK`: what the op hands them since PR 69), AS THE PROJECTION
-WROTE THEM: the convolved QKV (N, T, W) twice, q's and k's lanes picked
-by the block's lane index as `head_norm_fwd` picked them.  A grid step
-then takes the l2norm of its rows (x * rsqrt(sum x^2 + 1e-6), q's times
+WROTE THEM: the convolved QKV (N, T, W), q's and k's lanes picked by the
+block's lane index as `head_norm_fwd` picked them.  A grid step then
+takes the l2norm of its rows (x * rsqrt(sum x^2 + 1e-6), q's times
 Dk^-1/2; `head_norm.py`'s float32 arithmetic) into VMEM scratch before
 its chunk loop (`_head_rows`), and `_operands_bwd` returns the gradient
 of the RAW lanes (`_raw_gradient`: dx = (g - y <g, y>) * rstd, one more
-lane reduction a row), which `lane_range_gradient` pads to QKV's width
-as a slice's is.  No unit q or k is in HBM, and no `head_norm_*` call
-stands before the kernels (two forward and two backward passes a layer
-and call site before).  Unit operands remain the tests', the references'
-and `tools/time_kernel.py`'s entry; a shape the kernels do not take goes
-through `unit_q_and_k` (`head_norm.py`) first.
+lane reduction a row).  No unit q or k is in HBM, and no `head_norm_*`
+call stands before the kernels (two forward and two backward passes a
+layer and call site before).  Since PR 72 `raw` also says where v lies
+(`RawQK.v`), and QKV is then the call's ONE operand (`operands_kernel`):
+a key head's two value heads are block `raw.v / 256 + head` of its
+lanes, no slice of QKV is made, and `gated_delta_operands_bwd` writes
+ONE gradient, dQKV as QKV lies: the result stays in HBM (`pl.ANY`),
+a grid step writes its key head's dq and dk and its two value heads' dv
+into VMEM buffers and sends the three itself, as async copies, to lanes
+`raw.q + 128 h`, `raw.k + 128 h` and `raw.v + 256 h` of its rows (two
+slots of each buffer: a step awaits the copies of two steps before, a
+key head's last step all in flight).  Every lane is written once,
+rounded to the operands' dtype once, where XLA padded three gradients
+to W lanes, added them in float32 and rounded the sum (adding zeros is
+exact: the values are the same to the bit).  (One result array cannot
+take three block specs; the other way, a row block's every lane
+resident in VMEM across the key heads as `ssd_scan_bwd` holds d xBC,
+was built and timed too: 0.12-0.15 ms a call slower, `PERF.md` "From PR
+72".)  Without `raw.v` (v an array of its
+own: the tests') dq and dk come back a key head's block each and
+`lane_range_gradient` pads them to QKV's width as a slice's is.  Unit
+operands remain the tests' and the references' entry; a shape the
+kernels do not take goes through `unit_q_and_k` (`head_norm.py`) first.
 
 The inverse is made BEFORE the `custom_vjp` that holds the other two
 (`chunk_inverses`, on k and the row tile as constants: the backward
@@ -100,15 +117,23 @@ grid (batch x value head, blocks of `DEFAULT_BLOCK_CHUNKS` chunks), the
 state in a (Dk, Dv) float32 VMEM scratch across the grid as
 `recurrence.py` carries (h, c), four MXU dots a chunk
 (`gated_delta_fwd`).  Operands of the dots are the operands' dtype
-(bfloat16 under AMP), accumulation, S and gamma float32.  Backward: a
-custom VJP around the sequential part alone.  The forward rule's kernel
-also writes the state that ENTERS each chunk (in the operands' dtype,
-which is what the dots read: 268 MB a layer at 16384 positions x 32
-heads in bfloat16); the backward kernel (`gated_delta_bwd`) walks the
+(bfloat16 under AMP), accumulation, S and gamma float32.  The five
+operands come a value head at a time, (N Hv, T, ..), as the chunk-local
+kernels write them; o leaves AS THE OP LAYS IT, (N, T, Hv x 128): a grid
+step writes its head's 128 lanes of a row block (`_specs`, the last
+block; `channel_delta.py`'s scan does the same), so nothing transposes
+a head-major o and nothing re-lays it for the gated norm behind (nine
+copies and six re-lays of 268 MB a step at 16384 positions x 32 heads
+and three layers before PR 72).  Backward: a custom VJP around the sequential part alone.  The
+forward rule's kernel also writes the state that ENTERS each chunk (in
+the operands' dtype, which is what the dots read: 268 MB a layer at
+16384 positions x 32 heads in bfloat16); the backward kernel
+(`gated_delta_bwd`) reads dO through the same lane block, walks the
 blocks in reverse carrying dS in scratch, rebuilds V' = U - W S from
 the saved state (one dot), and emits dW, dU, d(Q exp gamma),
 d(K exp ..), dP and d exp(gamma_C).  `scan_xla` is its XLA lowering,
-the same chunk steps as a `lax.scan` that XLA differentiates.
+the same chunk steps as a `lax.scan` that XLA differentiates; its o is
+head-major, and `gated_delta_rule` moves it.
 
 Which runs is the shape's alone: the scan kernels take Dk = Dv = 128
 (`kernel_takes`), the chunk-operand kernels besides that two value
@@ -118,7 +143,11 @@ CPU presets run the XLA lowerings.  `runtime_stats.gated_delta_calls`
 heads, `gated_delta_operand_calls` / `_operand_chunks` the calls of
 `gated_delta_operands_fwd` / `_bwd`, `gated_delta_inverse_calls` those
 of `gated_delta_inverse` (traced where the layer is, once: a segment's
-forward + backward counts 1 and 3): a part that fell back reads 0.  The
+forward + backward counts 1 and 3): a part that fell back reads 0.
+`gated_delta_flat_calls` counts, of both kinds, the calls whose blocks
+address the op's own arrays: every scan call (o and dO by lane block)
+and a chunk-operand call that took QKV whole (`RawQK.v`); the cell reads
+9 + 9.  The
 benchmark
 finds the scan kernels by the PREFIXES `gated_delta_fwd` /
 `gated_delta_bwd`: no other kernel's name may start with either.
@@ -150,17 +179,25 @@ def kernel_takes(dk, dv):
 class RawQK(NamedTuple):
     """Where q and k lie when a call hands them AS THE PROJECTION WROTE
     THEM, before the l2norm: arrays (N, T, W) that hold `heads` heads of
-    `dim` lanes from lane `q` and from lane `k` on (the convolved QKV
-    twice, as it lies: no slice of it is made).  The rule then takes
+    `dim` lanes from lane `q` and from lane `k` on (the convolved QKV,
+    as it lies: no slice of it is made).  The rule then takes
     q = l2norm(q) * dim^-1/2 and k = l2norm(k) itself (eps 1e-6, a
     head, float32): the chunk-local kernels in VMEM, on the 128-lane
     block of a head they hold anyway (`_head_rows`; the backward kernel
     returns the raw q's and k's gradient), any other lowering through
-    `head_norm.py` first (`unit_q_and_k`)."""
+    `head_norm.py` first (`unit_q_and_k`).
+
+    `v` (a lane, or None: v is an array of its own, as `channel_delta.py`
+    hands it): v lies in that array too, `value_dim` (None: `dim`) lanes
+    a value head from lane `v` on.  The call then has ONE operand, QKV,
+    and one gradient, dQKV, which `gated_delta_operands_bwd` writes
+    whole (`operands_kernel`)."""
     q: int
     k: int
     heads: int
     dim: int
+    v: int | None = None
+    value_dim: int | None = None
 
 
 def unit_q_and_k(q, k, raw):
@@ -207,19 +244,22 @@ def _operand_dims(operand_shapes, tile):
     return bk, nc * CHUNK, bk // operand_shapes[0][0][0] * HEAD_DIM
 
 
-def ranged_bytes(operand_shapes, result_shapes, lanes, first=2):
+def ranged_bytes(operand_shapes, result_shapes, lanes, first=2, pair=False):
     """None (the default model: every buffer once) unless one of the
     `first` operands (q and k) is wider than `lanes`: read inside the
-    projection, it counts as its lane range."""
+    projection, it counts as its lane range.  `pair`: so does the
+    operand after them (v, two value heads a key head: twice the
+    lanes)."""
     import math
 
-    if all(dims[-1] <= lanes for dims, _ in operand_shapes[:first]):
+    ranges = [lanes] * first + [2 * lanes] * pair
+    if all(dims[-1] <= r for (dims, _), r in zip(operand_shapes, ranges)):
         return None
+    ranges += [None] * (len(operand_shapes) + len(result_shapes))
     return float(sum(
-        size * math.prod(dims[:-1]) * (min(dims[-1], lanes) if i < first
-                                       else dims[-1])
-        for i, (dims, size) in enumerate(list(operand_shapes)
-                                         + list(result_shapes))))
+        size * math.prod(dims[:-1]) * min(dims[-1], r or dims[-1])
+        for (dims, size), r in zip(list(operand_shapes)
+                                   + list(result_shapes), ranges)))
 
 
 def inverse_cost(operand_shapes, result_shapes):
@@ -236,7 +276,7 @@ def operands_fwd_cost(operand_shapes, result_shapes):
     bk, t, lanes = _operand_dims(operand_shapes, 3)
     return t * (bk * 2.0 * CHUNK * HEAD_DIM
                 + 2 * bk * 2 * 2.0 * CHUNK * HEAD_DIM), ranged_bytes(
-        operand_shapes, result_shapes, lanes)
+        operand_shapes, result_shapes, lanes, pair=True)
 
 
 def operands_bwd_cost(operand_shapes, result_shapes):
@@ -247,7 +287,7 @@ def operands_bwd_cost(operand_shapes, result_shapes):
     bk, t, lanes = _operand_dims(operand_shapes, 3)
     return 2 * bk * t * (8 * 2.0 * CHUNK * HEAD_DIM
                          + 3 * 2.0 * CHUNK * CHUNK), ranged_bytes(
-        operand_shapes, result_shapes, lanes)
+        operand_shapes, result_shapes, lanes, pair=True)
 
 
 def _register_costs():
@@ -641,9 +681,47 @@ def _operands_fwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, w_ref, u_ref,
 
 
 def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
-                         dqg_ref, dkd_ref, dp_ref, dq_ref, dk_ref, dv_ref,
-                         dx_ref, *scratch, block_chunks):
+                         dqg_ref, dkd_ref, dp_ref, *rest, block_chunks,
+                         raw=None, heads=None):
+    """`rest`: the results, then the scratch.  The results are dq, dk
+    and dv, a key head's block of each, and the row tiles' gradient;
+    or, where `_joint(raw)` (`heads`: the key heads), ONE array dQKV,
+    left in HBM (`pl.ANY`), and the row tiles' gradient: a grid step
+    then writes its dq, dk and dv into VMEM buffers (two slots of each,
+    the trailing scratch, with a DMA semaphore a copy) and sends the
+    three to their lanes of dQKV itself, dq to `raw.q + 128 h`, dk to
+    `raw.k + 128 h`, dv to `raw.v + 256 h`; it waits for the copies of
+    two steps before, whose slot it takes, and a key head's last step
+    waits for all that are in flight."""
+    from jax.experimental import pallas as pl
+
     f32 = jnp.float32
+    slot, send = 0, None
+    if _joint(raw):
+        from jax.experimental.pallas import tpu as pltpu
+
+        dqkv_ref, dx_ref, *scratch, dq_ref, dk_ref, dv_ref, sent = rest
+        b, i, blocks = (pl.program_id(0), pl.program_id(1),
+                        pl.num_programs(1))
+        slot, rows = i % 2, block_chunks * CHUNK
+
+        def send(slot, step):   # a step's three copies, to start or await
+            r = pl.ds(pl.multiple_of(step * rows, rows), rows)
+            return [pltpu.make_async_copy(
+                ref.at[slot], dqkv_ref.at[b // heads, r, pl.ds(
+                    pl.multiple_of(first + b % heads * lanes, HEAD_DIM),
+                    lanes)], sent.at[slot, j])
+                for j, (ref, first, lanes) in enumerate((
+                    (dq_ref, raw.q, HEAD_DIM), (dk_ref, raw.k, HEAD_DIM),
+                    (dv_ref, raw.v, 2 * HEAD_DIM)))]
+
+        @pl.when(i >= 2)
+        def _the_slot_is_free():
+            for copy in send(slot, i - 2):
+                copy.wait()
+    else:
+        dq_ref, dk_ref, dv_ref, dx_ref, *scratch = rest
+
     iotas = _tile_iotas()
     row, col, left = iotas
     shape = (2 * CHUNK, 2 * CHUNK)
@@ -691,8 +769,8 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
                 _dot(dw, (kf * e).astype(dt), ((1,), (1,)))
                 + _dot(du, v_ref[0, r, wide], ((1,), (1,))))
             dkg = _dot(solve[:, lanes], dw, ((0,), (0,)))
-            dv_ref[0, r, wide] = _dot(solve[:, lanes], du,
-                                      ((0,), (0,))).astype(dt)
+            dv_ref[slot, r, wide] = _dot(solve[:, lanes], du,
+                                         ((0,), (0,))).astype(dt)
             dk = dk + dkg * e + dkd * left_after
             dq = dq + dqg * e
             dgamma.append(e * jnp.sum(dkg * kf + dqg * qf, axis=1,
@@ -722,8 +800,8 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
         if scratch:     # raw q and k: the l2norm's rule
             dq = _raw_gradient(dq, qf, _rstd_rows(q_ref, r), _Q_SCALE)
             dk = _raw_gradient(dk, kf, _rstd_rows(k_ref, r))
-        dq_ref[0, r, :] = dq.astype(dt)
-        dk_ref[0, r, :] = dk.astype(dt)
+        dq_ref[slot, r, :] = dq.astype(dt)
+        dk_ref[slot, r, :] = dk.astype(dt)
         rows = {ROW_GAMMA: to_row(dgamma), ROW_REST: to_row(drest),
                 ROW_BETA: dbeta, ROW_G: pairs}
         tile = jnp.zeros((8, 2 * CHUNK), f32)
@@ -732,15 +810,33 @@ def _operands_bwd_kernel(q_ref, k_ref, v_ref, x_ref, m_ref, dw_ref, du_ref,
         dx_ref[0, c] = tile
 
     _for_each_chunk(block_chunks, chunk)
+    if send:
+        for copy in send(slot, i):
+            copy.start()
+
+        @pl.when(i == blocks - 1)
+        def _nothing_stays_in_flight():
+            for copy in send(slot, i):
+                copy.wait()
+
+            @pl.when(blocks > 1)
+            def _nor_the_step_before():
+                for copy in send(1 - slot, i - 1):
+                    copy.wait()
+
+
+def _joint(raw):
+    """Whether a call's q, k AND v are one array, QKV (`RawQK.v`)."""
+    return raw is not None and raw.v is not None
 
 
 def _operand_specs(hk, bc, raw=None):
     """The chunk-operand kernels' blocks, by name: `query` / `key` (a
     key head's 128 lanes of q's and k's arrays: their own, or the ones
     `raw` describes), `narrow` (the same of an (N, T, Hk x 128) array),
-    `pair` (its two value heads' lanes of v), `tile` (its row tiles),
-    `inverse`, and `wide` / `square` (its two value heads of (N Hv, T,
-    128) / (.., C))."""
+    `pair` (its two value heads' lanes of v: v's own array, or QKV from
+    `raw.v` on), `tile` (its row tiles), `inverse`, and `wide` /
+    `square` (its two value heads of (N Hv, T, 128) / (.., C))."""
     import types
 
     from jax.experimental import pallas as pl
@@ -759,7 +855,8 @@ def _operand_specs(hk, bc, raw=None):
     return types.SimpleNamespace(
         query=key_head(HEAD_DIM, raw.q) if raw else narrow,
         key=key_head(HEAD_DIM, raw.k) if raw else narrow,
-        narrow=narrow, pair=key_head(2 * HEAD_DIM),
+        narrow=narrow,
+        pair=key_head(2 * HEAD_DIM, raw.v if _joint(raw) else 0),
         tile=pl.BlockSpec((1, bc, 8, 2 * CHUNK), lambda b, i: (b, i, 0, 0)),
         inverse=pl.BlockSpec((1, rows, 2 * CHUNK), lambda b, i: (b, i, 0)),
         wide=value_heads(HEAD_DIM), square=value_heads(CHUNK))
@@ -781,6 +878,13 @@ def _operand_grid(k, x, raw):
     if raw:
         whole = (raw.heads, raw.dim, raw.q % HEAD_DIM, raw.k % HEAD_DIM) \
             == (hk, HEAD_DIM, 0, 0)
+        if _joint(raw):     # a value-head pair's block, and every lane
+            # of QKV some head's: dQKV is written and never added to
+            parts = sorted([(raw.q, hk), (raw.k, hk), (raw.v, 2 * hk)])
+            ends = [first + heads * HEAD_DIM for first, heads in parts]
+            whole = whole and raw.v % (2 * HEAD_DIM) == 0 \
+                and (raw.value_dim or raw.dim) == HEAD_DIM \
+                and [first for first, _ in parts] + [k.shape[2]] == [0] + ends
     else:
         whole = k.shape[2] == hk * HEAD_DIM
     if not whole:
@@ -831,33 +935,50 @@ def _operands_fwd_call(q, k, v, x, m, raw=None, interpreted=False):
 def _operands_bwd_call(q, k, v, x, m, dw, du, dqg, dkd, dp, raw=None,
                        interpreted=False):
     """(dq, dk, dv, the row tiles' gradient); dq and dk (N, T, Hk x 128)
-    each: the unit vectors', or with `raw` the projection's own lanes'."""
+    each: the unit vectors', or with `raw` the projection's own lanes'.
+    Where q, k and v are the one QKV (`_joint`): (dQKV, the row tiles'
+    gradient), dQKV as QKV lies, every lane written once by the
+    kernel's own copies."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     n, t, hk, bc, grid = _operand_grid(k, x, raw)
     at = _operand_specs(hk, bc, raw)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     heads = jax.ShapeDtypeStruct((n, t, hk * HEAD_DIM), v.dtype)
+    scratch = _unit_scratch(raw, bc * CHUNK, 2)
+    if _joint(raw):
+        out_specs = [pl.BlockSpec(memory_space=pl.ANY), at.tile]
+        out_shape = [like(v), like(x)]
+        # two slots of dq, dk and dv, and a semaphore a copy in flight
+        scratch += [pltpu.VMEM((2, bc * CHUNK, lanes), v.dtype)
+                    for lanes in (HEAD_DIM, HEAD_DIM, 2 * HEAD_DIM)]
+        scratch.append(pltpu.SemaphoreType.DMA((2, 3)))
+    else:
+        out_specs = [at.narrow, at.narrow, at.pair, at.tile]
+        out_shape = [heads, heads, like(v), like(x)]
     return _pallas_call(
-        functools.partial(_operands_bwd_kernel, block_chunks=bc),
+        functools.partial(_operands_bwd_kernel, block_chunks=bc, raw=raw,
+                          heads=hk),
         name="gated_delta_operands_bwd", grid=grid,
         in_specs=[at.query, at.key, at.pair, at.tile, at.inverse]
         + [at.wide] * 4 + [at.square],
-        out_specs=[at.narrow, at.narrow, at.pair, at.tile],
-        out_shape=[heads, heads, like(v), like(x)],
-        scratch_shapes=_unit_scratch(raw, bc * CHUNK, 2),
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
         compiler_params=_params(),
     )(q, k, v, x, m, dw, du, dqg, dkd, dp)
 
 
-def _record_operands(x):
+def _record_operands(x, raw):
     """Count a call of a chunk-operand kernel where it is traced
     (outside the jitted call, which is traced once a shape), by its row
-    tiles; gives the interpret gate, which keys that call's cache: the
-    same shapes are lowered through the interpreter and through Mosaic
-    in one test process."""
+    tiles, and whether it took QKV whole; gives the interpret gate,
+    which keys that call's cache: the same shapes are lowered through
+    the interpreter and through Mosaic in one test process."""
     from ...observe.monitoring import runtime_stats
     from . import interpret
 
-    runtime_stats.record_gated_delta_operands(2 * x.shape[0] * x.shape[1])
+    runtime_stats.record_gated_delta_operands(2 * x.shape[0] * x.shape[1],
+                                              flat=_joint(raw))
     return interpret()
 
 
@@ -881,31 +1002,46 @@ def chunk_inverses(k, x, raw=None):
     return m
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def operands_kernel(q, k, v, x, m, raw=None):
-    """`chunk_operands` less exp(gamma_C) by the Pallas kernels.  q, k
-    (N, T, Hk x 128), or with `raw` the arrays that hold them before the
-    l2norm; v (N, T, 2 Hk x 128), x the row tiles (`_row_tiles`), m
-    `chunk_inverses(k, x, raw)`."""
-    return _operands_vjp_fwd(q, k, v, x, m, raw)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def operands_kernel(operands, x, m, raw=None):
+    """`chunk_operands` less exp(gamma_C) by the Pallas kernels.
+    `operands`: (q, k, v), q and k (N, T, Hk x 128) or with `raw` the
+    arrays that hold them before the l2norm, v (N, T, 2 Hk x 128); or,
+    where `raw.v` says that v lies there too, the ONE array QKV
+    (N, T, W), whose gradient is then one array as well: every lane
+    written once by the backward kernel, no pad and no sum of three.  x
+    the row tiles (`_row_tiles`), m `chunk_inverses(k, x, raw)`."""
+    return _operands_vjp_fwd(operands, x, m, raw)[0]
 
 
-def _operands_vjp_fwd(q, k, v, x, m, raw):
-    operands = _operands_fwd_call(q, k, v, x, m, raw=raw,
-                                  interpreted=_record_operands(x))
-    return tuple(operands), (q, k, v, x, m)
+def _three(operands, raw):
+    """q's, k's and v's arrays as a kernel call takes them: QKV thrice
+    where it is the one operand."""
+    return (operands,) * 3 if _joint(raw) else operands
+
+
+def _operands_vjp_fwd(operands, x, m, raw):
+    results = _operands_fwd_call(*_three(operands, raw), x, m, raw=raw,
+                                 interpreted=_record_operands(x, raw))
+    return tuple(results), (operands, x, m)
 
 
 def _operands_vjp_bwd(raw, res, cts):
-    q, k, v, x, m = res
+    operands, x, m = res
+    q, k, v = _three(operands, raw)
     # m's own cotangent is none: its part is in dk and dx (above)
-    dq, dk, dv, dx = _operands_bwd_call(
+    *grads, dx = _operands_bwd_call(
         q, k, v, x, m, *(c.astype(v.dtype) for c in cts), raw=raw,
-        interpreted=_record_operands(x))
-    if raw:
-        dq, dk = (lane_range_gradient(dq, q.shape[-1], raw.q),
-                  lane_range_gradient(dk, k.shape[-1], raw.k))
-    return dq, dk, dv, dx, jnp.zeros_like(m)
+        interpreted=_record_operands(x, raw))
+    if _joint(raw):
+        grads, = grads          # dQKV, as QKV lies
+    elif raw:
+        dq, dk, dv = grads
+        grads = (lane_range_gradient(dq, q.shape[-1], raw.q),
+                 lane_range_gradient(dk, k.shape[-1], raw.k), dv)
+    else:
+        grads = tuple(grads)
+    return grads, dx, jnp.zeros_like(m)
 
 
 operands_kernel.defvjp(_operands_vjp_fwd, _operands_vjp_bwd)
@@ -942,15 +1078,20 @@ def chunk_operands_kernel(q, k, v, g, beta, raw=None):
     """`chunk_operands` where `operand_kernels_take` the heads: the
     same six results, the chunk's matrices never in HBM.  With `raw`, q
     and k are the (N, T, W) arrays it describes and the kernels take
-    their l2norm."""
-    n, t, hv, dv = v.shape
+    their l2norm; where it says that v lies there too, q is QKV and k
+    and v are None."""
+    n, t, hv = g.shape
     hk = raw.heads if raw else k.shape[2]
     x, last = _row_tiles(g.astype(jnp.float32), beta.astype(jnp.float32),
                          hk)
-    if not raw:
-        q, k = (a.reshape(n, t, hk * HEAD_DIM) for a in (q, k))
-    return operands_kernel(q, k, v.reshape(n, t, hv * dv), x,
-                           chunk_inverses(k, x, raw), raw) + (last,)
+    if _joint(raw):
+        operands = k = q
+    else:
+        if not raw:
+            q, k = (a.reshape(n, t, hk * HEAD_DIM) for a in (q, k))
+        operands = (q, k, v.reshape(n, t, hv * HEAD_DIM))
+    return operands_kernel(operands, x, chunk_inverses(k, x, raw),
+                           raw) + (last,)
 
 
 # -- the sequential part, as XLA runs it -------------------------------
@@ -1067,15 +1208,19 @@ def _block_chunks(nc):
     return max(b for b in range(1, DEFAULT_BLOCK_CHUNKS + 1) if nc % b == 0)
 
 
-def _specs(bc, time):
+def _specs(heads, bc, time):
+    """Blocks of (N Hv, T, ..) operands a value head, and (the last) the
+    head's lanes of the op's (N, T, Hv x 128) layout: o and its
+    cotangent."""
     from jax.experimental import pallas as pl
 
     def tile(rows, lanes):
         return pl.BlockSpec((1, rows, lanes), lambda b, i: (b, time(i), 0))
 
-    wide = tile(bc * CHUNK, HEAD_DIM)
-    return wide, tile(bc * CHUNK, CHUNK), tile(bc, HEAD_DIM), \
-        tile(bc * HEAD_DIM, HEAD_DIM)
+    return (tile(bc * CHUNK, HEAD_DIM), tile(bc * CHUNK, CHUNK),
+            tile(bc, HEAD_DIM), tile(bc * HEAD_DIM, HEAD_DIM),
+            pl.BlockSpec((1, bc * CHUNK, HEAD_DIM),
+                         lambda b, i: (b // heads, time(i), b % heads)))
 
 
 def _lanes(dec):
@@ -1091,17 +1236,23 @@ def _params():
         dimension_semantics=("parallel", "arbitrary"))
 
 
-def _scan_fwd_call(w, u, qg, kd, p, dec, keep_states):
-    from jax.experimental.pallas import tpu as pltpu
-
+def _record_scan(bh, nc):
     from ...observe.monitoring import runtime_stats
+
+    runtime_stats.record_gated_delta(bh * nc)
+
+
+def _scan_fwd_call(w, u, qg, kd, p, dec, heads, keep_states):
+    from jax.experimental.pallas import tpu as pltpu
 
     bh, t, _ = w.shape
     nc = t // CHUNK
     bc = _block_chunks(nc)
-    runtime_stats.record_gated_delta(bh * nc)
-    wide, narrow, scalar, state = _specs(bc, lambda i: i)
-    out_specs, out_shape = [wide], [jax.ShapeDtypeStruct(u.shape, u.dtype)]
+    _record_scan(bh, nc)
+    wide, narrow, scalar, state, lanes = _specs(heads, bc, lambda i: i)
+    out_specs = [lanes]
+    out_shape = [jax.ShapeDtypeStruct((bh // heads, t, heads * HEAD_DIM),
+                                      u.dtype)]
     if keep_states:
         out_specs.append(state)
         out_shape.append(jax.ShapeDtypeStruct((bh, nc * HEAD_DIM, HEAD_DIM),
@@ -1116,34 +1267,36 @@ def _scan_fwd_call(w, u, qg, kd, p, dec, keep_states):
     )(w, u, qg, kd, p, _lanes(dec))
 
 
-@jax.custom_vjp
-def scan_kernel(w, u, qg, kd, p, dec):
-    """`scan_xla` by the Pallas kernels (Dk = Dv = 128)."""
-    return _scan_fwd_call(w, u, qg, kd, p, dec, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def scan_kernel(w, u, qg, kd, p, dec, heads):
+    """`scan_xla` by the Pallas kernels (Dk = Dv = 128), its result in
+    the op's layout, (N, T, Hv x 128): a grid step writes its value
+    head's 128 lanes, and the backward kernel reads dO the same way (no
+    head-major o is made and none transposed)."""
+    return _scan_fwd_call(w, u, qg, kd, p, dec, heads, False)[0]
 
 
-def _scan_vjp_fwd(w, u, qg, kd, p, dec):
-    o, states = _scan_fwd_call(w, u, qg, kd, p, dec, True)
+def _scan_vjp_fwd(w, u, qg, kd, p, dec, heads):
+    o, states = _scan_fwd_call(w, u, qg, kd, p, dec, heads, True)
     return o, (w, u, qg, kd, p, dec, states)
 
 
-def _scan_vjp_bwd(res, do):
+def _scan_vjp_bwd(heads, res, do):
     from jax.experimental.pallas import tpu as pltpu
-
-    from ...observe.monitoring import runtime_stats
 
     w, u, qg, kd, p, dec, states = res
     bh, t, _ = w.shape
     nc = t // CHUNK
     bc = _block_chunks(nc)
     nb = nc // bc
-    runtime_stats.record_gated_delta(bh * nc)
-    wide, narrow, scalar, state = _specs(bc, lambda i: nb - 1 - i)
+    _record_scan(bh, nc)
+    wide, narrow, scalar, state, lanes = _specs(heads, bc,
+                                                lambda i: nb - 1 - i)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
     dw, du, dqg, dkd, dp, ddec = _pallas_call(
         functools.partial(_bwd_kernel, block_chunks=bc),
         name="gated_delta_bwd", grid=(bh, nb),
-        in_specs=[wide, wide, wide, wide, narrow, scalar, state, wide],
+        in_specs=[wide, wide, wide, wide, narrow, scalar, state, lanes],
         out_specs=[wide, wide, wide, wide, narrow, scalar],
         out_shape=[like(w), like(u), like(qg), like(kd), like(p),
                    jax.ShapeDtypeStruct((bh, nc, HEAD_DIM), jnp.float32)],
@@ -1166,21 +1319,32 @@ def gated_delta_rule(q, k, v, g, beta, use_kernel=False, raw=None):
     else `scan_xla`.  `raw` (a `RawQK`): q and k are not the unit
     vectors but the (N, T, W) arrays that hold the projection's, and
     the rule takes their l2norm: in the chunk-operand kernels where
-    they run, through `unit_q_and_k` elsewhere."""
-    n, t = v.shape[:2]
-    hv, dv = v.shape[2], v.shape[3]
-    hk, dk = (raw.heads, raw.dim) if raw else k.shape[2:]
+    they run, through `unit_q_and_k` elsewhere.  Where `raw.v` says
+    that v lies there too, q is that one array, QKV, and k and v are
+    None: the kernels block q, k and v out of its lanes and hand back
+    one dQKV; any other lowering cuts v out here."""
+    n, t, hv = g.shape
+    if raw:
+        hk, dk = raw.heads, raw.dim
+    else:
+        hk, dk = k.shape[2:]
+    dv = (raw.value_dim or dk) if _joint(raw) else v.shape[3]
     in_kernels = use_kernel and operand_kernels_take(hk, hv, dk, dv)
+    if _joint(raw) and not in_kernels:
+        k, v = q, q[..., raw.v:raw.v + hv * dv].reshape(n, t, hv, dv)
+        raw = raw._replace(v=None)
     if raw and not in_kernels:
         q, k = (x.reshape(n, t, hk, dk) for x in unit_q_and_k(q, k, raw))
         raw = None
-    if hv % hk or q.shape != k.shape or g.shape != (n, t, hv) \
-            or beta.shape != g.shape \
-            or not (raw or k.shape == (n, t, hk, dk)):
+    apart = not _joint(raw)     # q, k and v are arrays of their own
+    if hv % hk or beta.shape != g.shape or (apart and (
+            q.shape != k.shape or v.shape[:3] != g.shape
+            or not (raw or k.shape == (n, t, hk, dk)))):
         raise ValueError(
-            f"gated_delta_rule: q {q.shape}, k {k.shape}, v {v.shape}, g "
-            f"{g.shape}, beta {beta.shape} are not Hk key heads, a "
-            f"multiple Hv of value heads and a gate a value head")
+            f"gated_delta_rule: q {q.shape}, k {jnp.shape(k)}, v "
+            f"{jnp.shape(v)}, g {g.shape}, beta {beta.shape} at {raw} are "
+            f"not Hk key heads, a multiple Hv of value heads and a gate a "
+            f"value head")
     if use_kernel and not kernel_takes(dk, dv):
         raise NotImplementedError(
             f"gated_delta_rule: the kernels take heads of {HEAD_DIM}, not "
@@ -1188,12 +1352,18 @@ def gated_delta_rule(q, k, v, g, beta, use_kernel=False, raw=None):
     tail = -t % CHUNK
     if tail:
         q, k, v, g, beta = (
+            x if x is None else
             jnp.pad(x, ((0, 0), (0, tail)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    q, k = q.astype(v.dtype), k.astype(v.dtype)
+    if apart:
+        q, k = q.astype(v.dtype), k.astype(v.dtype)
     if in_kernels:
         operands = chunk_operands_kernel(q, k, v, g, beta, raw)
     else:
         operands = chunk_operands(q, k, v, g, beta)
-    o = (scan_kernel if use_kernel else scan_xla)(*operands)
-    return jnp.moveaxis(o.reshape(n, hv, t + tail, dv), 1, 2)[:, :t]
+    if use_kernel:      # o as the op lays it, (N, T, Hv x Dv)
+        o = scan_kernel(*operands, hv).reshape(n, t + tail, hv, dv)
+    else:
+        o = jnp.moveaxis(scan_xla(*operands).reshape(n, hv, t + tail, dv),
+                         1, 2)
+    return o[:, :t]

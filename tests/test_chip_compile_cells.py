@@ -679,6 +679,11 @@ def _qwen3next_traced(parameters, took):
     assert (took["gated_delta_inverse_calls"],
             took["gated_delta_operand_calls"],
             took["gated_delta_operand_chunks"]) == (3, 9, 9 * 256 * 32)
+    # every scan and chunk-operand call on the op's own arrays since PR
+    # 72: o and dO by lane block, q, k, v and dQKV on QKV (a fall-back
+    # to `scan_xla` / `chunk_operands` or operands apart would read less)
+    assert (took["gated_delta_calls"], took["gated_delta_flat_calls"]) == (
+        9, 18)
     # the full layer's (o, logsumexp) and three inverses
     assert took["recompute_kept_residuals"] == 4
     assert took["recompute_kept_bytes"] >= 3 * 134217728
@@ -963,10 +968,28 @@ def test_the_linear_attention_cells_step_keeps_its_inverses_under_the_plan(
     full layer's backward pass is ONE kernel since PR 54 (16 / 2 heads
     of 256: 48 MiB of dq, dk and dv, the budget's edge): no `flash_dq`
     in the step, the counters 1 / 0."""
-    parameters, _, plan, kernels, took = _cell_step("qwen3next-16k",
-                                                    one_chip)
+    parameters, compiled, plan, kernels, took = _cell_step("qwen3next-16k",
+                                                           one_chip)
     holds_its_trace("qwen3next-16k", parameters, took, kernels)
     assert 12.0 < plan["total"] <= 15.0, plan
+    # nothing of XLA's at the delta kernels' boundary since PR 72 (12.12
+    # GB planned, 12.26 before): no head-major o copied to the op's
+    # layout and back (9 `copy bf16[1,16384,32,128]` a step before), none
+    # re-laid for the gated norm (6 `copy_bitcast_fusion` + 3 `copy
+    # bf16[16384,4096]`), no v sliced out of QKV (6), no dq, dk, dv
+    # padded to 8192 lanes and added (3 `pad_add_fusion` over 9 `pad`)
+    made = [line.strip().split(" = ", 1)
+            for line in compiled.as_text().splitlines()
+            if " = " in line and "linear_attention/" in line]
+    assert made
+    for shape, ops in (("bf16[1,16384,32,128]", ("copy(", "transpose(")),
+                       ("bf16[1,16384,4096]", ("slice(",)),
+                       ("bf16[16384,4096]", ("copy(",)),
+                       ("bf16[1,16384,8192]", ("pad(",))):
+        assert not [name for name, what in made if what.startswith(shape)
+                    and any(f" {op}" in what for op in ops)], shape
+    assert not [name for name, _ in made
+                if "copy_bitcast_fusion" in name or "pad_add_fusion" in name]
     assert kernels["flash_dq"] == 0
     assert (kernels["gated_delta_inverse"],
             kernels["gated_delta_operands_fwd"],

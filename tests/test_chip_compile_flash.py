@@ -427,9 +427,17 @@ STEP_TEXT = {
     # chunk-local kernels of both delta rules read q and k on QKV as it
     # lies and take the l2norm themselves (`gated_delta.py RawQK`; no
     # `head_norm_*` call before them; parents: 068ea25c.., 96c8ef2e..);
-    # the twelve other cells build neither op and keep their text
+    # the twelve other cells build neither op and keep their text.
+    # Re-pinned, PR 72, ALONE: the gated-delta kernels address the op's
+    # own arrays, here through the interpreter (QKV the one operand of
+    # the chunk-operand kernels and dQKV their one gradient, sent by the
+    # backward kernel's own async copies; o and dO by lane block of
+    # (N, T, Hv x 128): no slice, no transpose, no pad; parent:
+    # 6377ad3f..); the thirteen other cells keep their text,
+    # `kimilinear-8k` included: `RawQK`'s new fields default, and
+    # `channel_delta.py` is not touched
     "qwen3next-16k":
-    "6377ad3f12cd522827f8152edc5bd7a990baf2ba503638b51683d4418d2d4e7f",
+    "667b63e5613e4b029514a078ba72ad8c09515a66265d520f70598b04992d7561",
     # re-pinned, PR 59: its block-diffusion flash kernels walk a
     # scalar-prefetched list of visits (`ops/pallas/
     # flash_block_diffusion.py`, here through the interpreter, a pass

@@ -120,7 +120,11 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     key heads x 32 blocks of 8 chunks; the forward rule's
     `gated_delta_fwd` (which also writes the 256 chunk-entry states a
     head) and `gated_delta_bwd`, each a grid of 32 heads x 32 blocks
-    carrying a (128, 128) float32 state in VMEM scratch."""
+    carrying a (128, 128) float32 state in VMEM scratch.  Since PR 72
+    the kernels address the op's own arrays (QKV in, Out (N, T, Hv x
+    128) out, dOut in by lane block; dQKV out, left in HBM and written
+    by the backward kernel's own async copies, three a grid step, under
+    DMA semaphores): nothing of XLA's stands at their boundary."""
     from paddle_tpu.core.registry import OpContext, get_op_impl
     from paddle_tpu.observe import cost
     from paddle_tpu.observe.monitoring import runtime_stats
@@ -152,6 +156,8 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
         assert (took[f"{kind}_calls"], took[f"{kind}_chunks"]) == (
             2, 2 * 256 * 32), kind
     assert took["gated_delta_inverse_calls"] == 1
+    # all four of them on the op's own arrays
+    assert took["gated_delta_flat_calls"] == 4
     proto = cost.compiled_hlo_proto(compiled)
     rows = cost.instruction_costs(proto)
     # q's and k's l2norm is the chunk-local kernels' own since PR 69
@@ -174,8 +180,30 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     assert by["gated_delta_operands_fwd"] == (
         (2 * hk + hv) * heads + tiles + n * hk * t * 128 * 4
         + 4 * hv * heads + hv * n * t * 64 * size)
+    assert by["gated_delta_operands_bwd"] == (
+        by["gated_delta_operands_fwd"] + (2 * hk + hv) * heads + tiles)
     # no float32 view of q or k a head: nothing for the chip to re-lay
-    assert f"f32[{n},{t},{hk},{d}]" not in compiled.as_text()
+    text = compiled.as_text()
+    assert f"f32[{n},{t},{hk},{d}]" not in text
+    # and no pass of XLA's over an activation beside the kernels (PR
+    # 72): no head-major o to transpose, no v cut out of QKV, no three
+    # gradients padded to QKV's width and added (which ran in float32)
+    kind = "bf16" if dtype == BF16 else "f32"
+    made = [line.split(" = ", 1)[1] for line in text.splitlines()
+            if " = " in line and "parameter(" not in line
+            and "get-tuple-element(" not in line]
+
+    def xla_makes(*dims):       # what XLA writes of that shape
+        shape = "[" + ",".join(map(str, dims)) + "]"
+        return [m for m in made
+                if m.startswith((kind + shape, "f32" + shape))]
+
+    assert not xla_makes(n, t, hv, d) and not xla_makes(hv, t, d)
+    assert not [m for m in xla_makes(n, t, hv * d)
+                if " slice(" in m or " copy(" in m]
+    assert not xla_makes(n, t, (2 * hk + hv) * d)
+    # dQKV is the backward kernel's own result
+    assert f"({kind}[{n},{t},{(2 * hk + hv) * d}]" in text
     # no scan reader may take the chunk-local kernels for scan kernels:
     # they match by prefix (`benchmarks/kernel_counts.py kernel_ms_per_step`)
     scan = [r for r in rows if (r["kernel"] or "").startswith(
@@ -190,8 +218,7 @@ def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     assert totals["pallas_flops"] == pytest.approx(
         256 * 32 * chunk + local, rel=1e-9)
     # the states that enter the chunks, in the operands' dtype
-    kind = "bf16" if dtype == BF16 else "f32"
-    assert f"{kind}[{hv},{256 * d},{d}]" in compiled.as_text()
+    assert f"{kind}[{hv},{256 * d},{d}]" in text
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])

@@ -214,6 +214,10 @@ def test_a_step_counts_three_kernel_calls_a_linear_layer_and_a_build_none():
                  b[f"{kind}_chunks"] - a[f"{kind}_chunks"])
                 for a, b in zip(marks, marks[1:])]
         assert took == [(0, 0), (3, 3 * 2 * 2), (0, 0)], kind
+    # every one of the six on the op's own arrays: the scan's o and dO
+    # by lane block, the chunk-local kernels' q, k, v and dQKV on QKV
+    assert [b["gated_delta_flat_calls"] - a["gated_delta_flat_calls"]
+            for a, b in zip(marks, marks[1:])] == [0, 6, 0]
     # the inverse kernel is traced with the layer, once, and the layer's
     # segment keeps what it wrote, (1 key head, 128, 2 x 64) float32,
     # as the full layer's keeps its flash call's output (128 x 2 heads
@@ -314,6 +318,9 @@ def test_the_chunked_scan_is_the_sequential_recurrence(lowering, t, decay):
     # the inverse: the forward call's and the differentiated call's; no
     # segment is open, so nothing counts as kept
     assert took["gated_delta_inverse_calls"] == 2 * (lowering == "kernel")
+    # the scan kernels write o as the op lays it; q, k and v apart are
+    # no QKV, and a fall-back counts nothing
+    assert took["gated_delta_flat_calls"] == 3 * (lowering == "kernel")
     assert took["recompute_kept_residuals"] == 0
 
 
@@ -414,9 +421,11 @@ def test_the_inverse_kernel_is_every_chunks_inverse(case):
 def raw_case(dtype, t=128, hk=2, hv=4, d=gated_delta.HEAD_DIM, seed=11):
     """QKV (1, T, (2 hk + hv) d) as a projection writes it (rows of any
     norm), the row tiles of a g and a beta, and what `gated_delta_rule`
-    hands its chunk-operand kernels each way: (q, k, v) with `raw` = the
-    l2norm INSIDE the kernels (QKV twice, as it lies) and with None =
-    `head_norm_xla` first, then today's kernels on unit q and k."""
+    hands its chunk-operand kernels each way, `operands(qkv, raw)`:
+    QKV alone where `raw` says where q, k AND v lie (what the op hands
+    them), (QKV, QKV, v) where it leaves v out (the l2norm INSIDE the
+    kernels, v cut out by XLA), and with None `head_norm_xla` first,
+    then the kernels on unit q and k."""
     from paddle_tpu.ops.pallas import head_norm
 
     r = np.random.default_rng(seed)
@@ -427,9 +436,11 @@ def raw_case(dtype, t=128, hk=2, hv=4, d=gated_delta.HEAD_DIM, seed=11):
                        jnp.float32)
     raw = gated_delta.RawQK(q=0, k=hk * d, heads=hk, dim=d)
 
-    def operands(qkv, inside):
+    def operands(qkv, raw):
+        if raw is not None and raw.v is not None:
+            return qkv
         v = qkv[..., 2 * hk * d:]
-        if inside:
+        if raw:
             return qkv, qkv, v
         form = head_norm.Form(0, hk * d)
         return (head_norm.head_norm_xla(
@@ -444,24 +455,34 @@ def raw_case(dtype, t=128, hk=2, hv=4, d=gated_delta.HEAD_DIM, seed=11):
 INSIDE_TOL = {"float32": 1e-6, "bfloat16": 0.02}
 
 
+@pytest.mark.parametrize("v", ["apart", "in_qkv"])
 @pytest.mark.parametrize("dtype", sorted(INSIDE_TOL))
 @pytest.mark.parametrize("kernel", ["inverse", "operands_fwd",
                                     "operands_bwd"])
 def test_the_l2norm_inside_a_chunk_operand_kernel_is_head_norm_before_it(
-        kernel, dtype):
+        kernel, dtype, v):
     """Each chunk-operand kernel (interpret mode) on QKV as it lies, the
     l2norm of q and k taken inside (`RawQK`), against `head_norm_xla`
     followed by the same kernel on unit q and k: float32 to 1e-6 of the
     largest entry, bfloat16 within the scan's own 2 %.  The backward
     kernel through `operands_kernel`'s VJP: the gradients of the RAW QKV
     (q's and k's lanes through the l2norm's rule) and of the row tiles,
-    under one cotangent of the five results."""
+    under one cotangent of the five results.  `v`: cut out of QKV by
+    XLA and handed over apart, or blocked out of QKV's lanes by the
+    kernels (what the op does since PR 72: QKV is the one operand).
+    Then the five results are the same TO THE BIT, and so is the one
+    dQKV the backward kernel writes, each lane once, to the three
+    gradients padded to QKV's width and added (that case is held to
+    those alone: they are held to the unit operands' beside it);
+    `gated_delta_flat_calls` counts the calls that took QKV whole and
+    no other."""
     from paddle_tpu.observe.monitoring import runtime_stats
 
-    qkv, x, raw, operands = raw_case(jnp.dtype(dtype))
+    qkv, x, apart, operands = raw_case(jnp.dtype(dtype))
+    raw = apart if v == "apart" else apart._replace(v=2 * apart.k)
     tol = INSIDE_TOL[dtype]
 
-    def same(got, want, names):
+    def same(got, want, names, tol=tol):
         for name, a, b in zip(names, got, want):
             assert a.shape == b.shape and a.dtype == b.dtype, name
             a, b = (np.asarray(y.astype(jnp.float32)) for y in (a, b))
@@ -469,18 +490,27 @@ def test_the_l2norm_inside_a_chunk_operand_kernel_is_head_norm_before_it(
                                        atol=tol * np.abs(b).max(),
                                        err_msg=name)
 
-    unit, inside = operands(qkv, False), operands(qkv, True)
+    def three(raw):     # q's, k's and v's arrays, as a kernel call takes them
+        given = operands(qkv, raw)
+        return given if isinstance(given, tuple) else (given,) * 3
+
+    unit = three(None)
     kept = gated_delta._inverse_call(unit[1], x, interpreted=True)
     if kernel == "inverse":
-        same([gated_delta._inverse_call(inside[1], x, raw=raw,
+        same([gated_delta._inverse_call(three(raw)[1], x, raw=raw,
                                         interpreted=True)], [kept], "m")
         return
     if kernel == "operands_fwd":
-        same(gated_delta._operands_fwd_call(*inside, x, kept, raw=raw,
-                                            interpreted=True),
-             gated_delta._operands_fwd_call(*unit, x, kept,
-                                            interpreted=True),
-             ("w", "u", "qg", "kd", "p"))
+        got = gated_delta._operands_fwd_call(*three(raw), x, kept, raw=raw,
+                                             interpreted=True)
+        names = ("w", "u", "qg", "kd", "p")
+        if raw is apart:
+            same(got, gated_delta._operands_fwd_call(
+                *unit, x, kept, interpreted=True), names)
+        else:
+            same(got, gated_delta._operands_fwd_call(
+                *three(apart), x, kept, raw=apart, interpreted=True),
+                names, 0)
         return
     cts = [jnp.asarray(np.random.default_rng(5 + i).normal(size=shape), dtype)
            for i, shape in enumerate(
@@ -488,18 +518,50 @@ def test_the_l2norm_inside_a_chunk_operand_kernel_is_head_norm_before_it(
 
     def results(way):
         def fn(qkv, x):
-            return gated_delta.operands_kernel(
-                *operands(qkv, way is not None), x, kept, way)
+            return gated_delta.operands_kernel(operands(qkv, way), x, kept,
+                                               way)
         return jax.vjp(fn, qkv, x)[1](tuple(cts))
 
     before = runtime_stats.snapshot()
     got = results(raw)
+    took = runtime_stats.delta(before)
     # the forward rule's kernel and the backward kernel, 2 chunks x 4 heads
-    assert runtime_stats.delta(before)["gated_delta_operand_chunks"] == 16
-    same(got, results(None), ("dqkv", "dx"))
-    for i, name in enumerate(("raw q", "raw k")):
+    assert took["gated_delta_operand_chunks"] == 16
+    assert took["gated_delta_flat_calls"] == 2 * (v == "in_qkv")
+    if raw is apart:
+        same(got, results(None), ("dqkv", "dx"))
+    else:
+        same(got, results(apart), ("dqkv", "dx"), 0)
+    for i, name in enumerate(("raw q", "raw k", "v", "v")):
         assert np.abs(np.asarray(got[0][..., i * 256:(i + 1) * 256]
                                  .astype(jnp.float32))).max() > 0, name
+
+
+def test_the_scan_kernels_write_o_and_read_do_as_the_op_lays_them():
+    """`scan_kernel` (interpret mode) returns o (N, T, Hv x 128), a
+    value head's lanes a grid step, and takes its cotangent so: equal
+    to `scan_xla`'s head-major (N Hv, T, 128) moved, forward and the six
+    gradients, over two blocks of chunks and four heads of two batch
+    rows."""
+    n, t, hv, d = 2, 640, 2, gated_delta.HEAD_DIM
+    q, k, v, g, beta = scan_case(t, "mild", seed=6, hk=1, hv=hv)
+    both = lambda x: jnp.concatenate([x, x[:, ::-1]])  # noqa: E731
+    operands = gated_delta.chunk_operands(*map(both, (q, k, v, g, beta)))
+    ct = jnp.asarray(np.random.default_rng(8).normal(size=(n, t, hv * d)),
+                     jnp.float32)
+
+    def moved(*a):
+        o = gated_delta.scan_xla(*a).reshape(n, hv, t, d)
+        return jnp.moveaxis(o, 1, 2).reshape(n, t, hv * d)
+
+    got, pull = jax.vjp(lambda *a: gated_delta.scan_kernel(*a, hv), *operands)
+    want, pull_xla = jax.vjp(moved, *operands)
+    assert got.shape == (n, t, hv * d)
+    for name, a, b in zip(("o", "dw", "du", "dqg", "dkd", "dp", "ddec"),
+                          (got,) + pull(ct), (want,) + pull_xla(ct)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0,
+            atol=TOL * float(jnp.abs(b).max()), err_msg=name)
 
 
 @pytest.mark.parametrize("case", ["weak-decay", "repeated-keys",

@@ -83,6 +83,29 @@ def test_a_sweep_takes_what_the_entry_lists_and_nothing_else(family):
             time_kernel.parse_sweep(family, text)
 
 
+def test_an_operand_in_parts_is_all_of_its_parts():
+    """`parts` (gated_delta's QKV: q, k, v) name operands the entry has
+    and tile each one's lanes, so no lane's gradient goes unread."""
+    assert [f for f, e in FAMILIES.items()
+            if e.parts(next(iter(e.cells.values())))] == ["gated_delta"]
+    entry = FAMILIES["gated_delta"]
+    shape = entry.cells["qwen3next-16k"]
+    xs, _ = jax.eval_shape(lambda: entry.operands(shape, 0))
+    for name, cuts in entry.parts(shape).items():
+        edges = sorted(cuts.values())
+        assert [first for first, _ in edges] + [
+            xs[entry.names.index(name)].shape[-1]] == [0] + [
+            end for _, end in edges], name
+    import numpy as np
+
+    values = [np.arange(6.0).reshape(1, 6) + i for i in range(3)]
+    got = time_kernel.by_part(("qkv", "g"), {"qkv": {"q": (0, 2),
+                                                     "v": (2, 6)}}, values)
+    assert list(got) == ["y", "dq", "dv", "dg"]
+    assert got["dv"].tolist() == [[3., 4., 5., 6.]] and got["dg"] is not None
+    assert got["dg"].shape == (1, 6)
+
+
 def test_off_a_tpu_the_tool_times_nothing(capsys):
     assert time_kernel.main(["rope"]) == 1
     assert json.loads(capsys.readouterr().out) == {"error": "cpu is no TPU"}

@@ -4,7 +4,8 @@ kernel file of `paddle_tpu/ops/pallas/`, found by name in `FAMILIES`.
 A family says at which shape each cell of `BENCHMARK.json` runs it
 (`cells`; the first is the tool's default), how to draw its operands
 (`operands(shape, seed)` -> the differentiable ones as a tuple, and a
-dict of whatever else the ways read), and the WAYS one call can be
+dict of whatever else the ways read; `parts` where one of them is
+several side by side), and the WAYS one call can be
 made: builders `way(mod, shape, aux, **keywords)` -> a function of the
 operands, where `mod` is the kernel file's module (this tree's, or a
 parent checkout's under `--parent`).  `ways` all compute the same
@@ -42,6 +43,10 @@ class Family(NamedTuple):
     ways: dict                  # name -> builder; kernel, the fall-back, ...
     composites: dict = {}       # name -> builder: a layer around the kernel
     sweepable: tuple = ()       # constants and keywords `--sweep` may set
+    # shape -> {operand: {label: (first lane, end)}}: an operand that is
+    # several side by side, whose gradient is held to the fall-back's a
+    # part at a time
+    parts: Callable = lambda shape: {}
 
 
 def _keys(seed, n):
@@ -83,14 +88,42 @@ def _channel_delta_operands(shape, seed):
 
 
 def _gated_delta_operands(shape, seed):
+    """QKV as the convolved projection writes it (rows of any norm: the
+    rule takes q's and k's l2norm), the log decay and beta."""
     t, hk, d = shape["rows"], shape["key_heads"], 128
-    hv = 2 * hk
-    ks = _keys(seed, 5)
-    q, k = (_unit(key, (1, t, hk, d)) for key in ks[:2])
-    g = -jnp.abs(_normal(ks[3], (1, t, hv), F32)) * 0.05
-    beta = jax.nn.sigmoid(_normal(ks[4], (1, t, hv), F32))
-    return ((q * d ** -0.5).astype(BF16), k.astype(BF16),
-            _normal(ks[2], (1, t, hv, d)), g, beta), {}
+    ks = _keys(seed, 3)
+    g = -jnp.abs(_normal(ks[1], (1, t, 2 * hk), F32)) * 0.05
+    beta = jax.nn.sigmoid(_normal(ks[2], (1, t, 2 * hk), F32))
+    return (_normal(ks[0], (1, t, 4 * hk * d)), g, beta), {}
+
+
+def _gated_delta_parts(shape):
+    lanes = shape["key_heads"] * 128
+    return {"qkv": {"q": (0, lanes), "k": (lanes, 2 * lanes),
+                    "v": (2 * lanes, 4 * lanes)}}
+
+
+def _gated_delta(use_kernel):
+    """The rule as `ops/decoder.py gated_delta_rule` calls it, QKV in
+    and Out (N, T, Hv x 128) out: the kernels and whatever XLA runs
+    between them.  A checkout from before PR 72 has v cut out of QKV
+    for it and hands back o a head at a time."""
+    def build(mod, shape, aux):
+        hk, d = shape["key_heads"], 128
+        raw = mod.RawQK(q=0, k=hk * d, heads=hk, dim=d)
+
+        def fn(qkv, g, beta):
+            n, t, _ = qkv.shape
+            if "v" in mod.RawQK._fields:
+                k, v, at = None, None, raw._replace(v=2 * hk * d)
+            else:
+                k, at = qkv, raw
+                v = qkv[..., 2 * hk * d:].reshape(n, t, 2 * hk, d)
+            return mod.gated_delta_rule(
+                qkv, k, v, g, beta, use_kernel=use_kernel,
+                raw=at).reshape(n, t, 2 * hk * d)
+        return fn
+    return build
 
 
 def _channel_delta_xla(mod, shape, aux):
@@ -110,11 +143,6 @@ def _channel_delta_xla(mod, shape, aux):
         o = jax.lax.map(pair, jnp.arange(shape["heads"] // 2))
         return jnp.moveaxis(o, 0, 2).reshape(q.shape)   # (P, N, T, 256)
     return fn
-
-
-def _delta_ways(rule):
-    return {"kernel": _with(rule, use_kernel=True),
-            "xla": _with(rule, use_kernel=False)}
 
 
 # -- the flash kernels ------------------------------------------------------
@@ -398,9 +426,10 @@ FAMILIES = {
                    "WHOLE_BAND_SCORE_BUDGET", "WHOLE_BAND_FWD_BLOCK")),
     "gated_delta": Family(
         "gated_delta", {"qwen3next-16k": dict(rows=16384, key_heads=16)},
-        _gated_delta_operands, ("q", "k", "v", "g", "beta"),
-        _delta_ways("gated_delta_rule"),
-        sweepable=("DIAGONAL_BLOCK", "DEFAULT_BLOCK_CHUNKS")),
+        _gated_delta_operands, ("qkv", "g", "beta"),
+        {"kernel": _gated_delta(True), "xla": _gated_delta(False)},
+        sweepable=("DIAGONAL_BLOCK", "DEFAULT_BLOCK_CHUNKS"),
+        parts=_gated_delta_parts),
     "head_norm": Family(
         "head_norm",
         {"kimilinear-8k": dict(rows=8192, heads=32, gate="sigmoid"),

@@ -19,7 +19,8 @@ the median, in ms a call; a way too slow for that (XLA's attention at
 16384 rows) gets the calls half a second holds, two at the least.
 `against_<fall-back>`: the result and every gradient of `kernel` (and
 `parent`) against the fall-back's on the same operands, as the norm of
-the difference over the norm, in float64.
+the difference over the norm, in float64 (an operand that is several
+side by side, `gated_delta`'s QKV, a part at a time: dq, dk, dv).
 
 `--sweep NAME=v1,v2,...` (may be given again) times `kernel` alone once a
 value: NAME a module constant of the kernel file (`CHANNEL_TILE`,
@@ -84,6 +85,17 @@ def err(got, want):
     where there is nothing to be off from)."""
     got, want = (np.asarray(x, np.float64) for x in (got, want))
     return float(np.linalg.norm(got - want) / (np.linalg.norm(want) or 1.0))
+
+
+def by_part(names, parts, values):
+    """{label: value} of a way's result and gradients: `y`, then `d<name>`
+    an operand, one that `parts` cuts (`{name: {part: (first lane,
+    end)}}`: several operands side by side) a `d<part>` a range."""
+    labelled = {"y": values[0]}
+    for name, grad in zip(names, values[1:]):
+        for part, (first, end) in parts.get(name, {name: (0, None)}).items():
+            labelled["d" + part] = grad[..., first:end]
+    return labelled
 
 
 def load_parent(checkout, module):
@@ -182,10 +194,11 @@ def run(family, cell=None, sweeps=(), parent=None, repeats=10, seed=0):
     def results(fn):
         return (jax.jit(fn)(*xs),) + tuple(vjp_of(fn)(ct, *xs))
 
-    want = results(fns[fallback])
-    labels = ("y",) + tuple("d" + name for name in entry.names)
+    parts = entry.parts(shape)
+    want = by_part(entry.names, parts, results(fns[fallback]))
     out["against_" + fallback] = {
-        name: dict(zip(labels, map(err, results(fn), want)))
+        name: {label: err(got, want[label]) for label, got in by_part(
+            entry.names, parts, results(fn)).items()}
         for name, fn in fns.items()
         if name != fallback and name not in entry.composites}
     return out
