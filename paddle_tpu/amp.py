@@ -29,6 +29,7 @@ DEFAULT_WHITE: Set[str] = {
     "mul", "matmul", "conv2d", "conv3d", "depthwise_conv2d",
     "conv2d_transpose", "conv3d_transpose", "flash_attention",
     "sequence_conv", "moe_dropless", "latent_attention",
+    "segment_attention",
 }
 
 # Numerically sensitive ops: force f32 inputs.

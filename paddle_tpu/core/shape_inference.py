@@ -52,6 +52,7 @@ _SKIP_INFERENCE = {
     "create_array", "array_write", "array_read", "array_length",
     "array_to_tensor", "gated_delta_rule", "short_conv", "rope",
     "selective_scan", "ssd_scan", "gated_rms_norm", "channel_delta_rule",
+    "segment_attention",
 }
 
 

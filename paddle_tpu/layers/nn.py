@@ -342,7 +342,7 @@ def short_conv(input, filter_size, param_attr=None, name=None,
 def rope(input, n_head, theta=10000.0, offset=None, name=None,
          interleave=False, inv_freq=None, attention_factor=None,
          rotary_dim=None, period=None, norm=False, epsilon=1e-5,
-         zero_centered=False, param_attr=None):
+         zero_centered=False, param_attr=None, positions=None):
     """Rotary position embedding of a head-grouped (N, T, n_head * D)
     projection (ops/decoder.py): rotate-half, or with `interleave` the
     pairs (2i, 2i + 1) of every head.  `offset`: a (1,) integer
@@ -353,7 +353,11 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
     `rope_parameters`).  `rotary_dim`: only the first so many lanes of
     each head turn (a config's `partial_rotary_factor` x the head).
     `period` P: positions restart every P rows (row r stands at
-    r mod P).
+    r mod P).  `positions`: an (N, T, 2) int32 variable, a (row,
+    column) a row, for rotary positions over TWO axes (a patch of an
+    image's grid): with f_m = theta^(-4m/D) pair 2m of a head turns by
+    column x f_m and pair 2m + 1 by row x f_m; over pairs and the whole
+    head, alone (no offset, period, norm or scaled frequencies).
 
     `norm`: each head is RMS-normed before it turns (QK-norm a head),
     under one learned scale (D,) with `epsilon` and `zero_centered` as
@@ -371,6 +375,9 @@ def rope(input, n_head, theta=10000.0, offset=None, name=None,
     ins = {"X": [input]}
     if offset is not None:
         ins["Offset"] = [offset]
+    if positions is not None:
+        ins["Positions"] = [positions]
+        interleave = True
     attrs = {"n_head": int(n_head), "theta": float(theta)}
     if norm:
         head_dim = int(input.shape[-1]) // int(n_head)
@@ -602,6 +609,78 @@ def latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head, name=None):
         outputs={"Out": [out]},
         attrs={"n_head": int(n_head)})
     out.desc.shape = tuple(v.shape)
+    return out
+
+
+def segment_attention(q, k, v, segment_ids, n_head, max_segment_rows=None,
+                      scale=None, name=None):
+    """Bidirectional attention over a PACKED row axis (ops/vision.py
+    `segment_attention`): head-major `q`, `k`, `v` (N, P, n_head * d)
+    and `segment_ids` (N, P) int32; row i reads the rows of its own
+    segment, all of them, and no other (a segment is a run of
+    consecutive rows of one id, its bounds data; a negative id is a
+    padding row).  What a native-resolution vision tower runs over the
+    patches of a step's images.  `max_segment_rows`: the most rows a
+    segment may have (a processor's patch limit an image), which bounds
+    the kernels' list of visits; a row axis whose longer segments pass
+    that list comes out NaN, not wrong.  Whole tiles of 512 rows run the flash kernels of
+    ops/pallas/flash_segment.py (heads of 72 lanes too): the op
+    chooses, from the shape.  The layer keeps `<name>.tiles_visited`
+    and `<name>.tiles_total`, int32 (1,) persistable state the op adds
+    to on the device (observe/routing.py `segment_tile_visits`)."""
+    helper = LayerHelper("segment_attention", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    base = helper.name
+    ins = {"Q": [q], "K": [k], "V": [v], "SegmentIds": [segment_ids]}
+    outs = {"Out": [out]}
+    for slot, suffix in (("TilesVisited", ".tiles_visited"),
+                         ("TilesTotal", ".tiles_total")):
+        state = helper.create_or_get_global_variable(
+            base + suffix, [1], "int32")
+        state.desc.stop_gradient = True
+        ins[slot], outs[slot + "Out"] = [state], [state]
+    attrs = {"n_head": int(n_head)}
+    if max_segment_rows is not None:
+        attrs["max_segment_rows"] = int(max_segment_rows)
+    if scale is not None:
+        attrs["scale"] = float(scale)
+    helper.append_op(type="segment_attention", inputs=ins, outputs=outs,
+                     attrs=attrs)
+    out.desc.shape = tuple(q.shape)
+    return out
+
+
+def table_interp(taps, weights, size, param_attr=None, name=None):
+    """A learnt (size[0] * size[1], size[2]) position table read through
+    `taps` / `weights` (N, P, K): row p is sum_k weights[p, k] *
+    table[taps[p, k]] (ops/vision.py `table_interp`; bicubic
+    interpolation of a (H, W, D) table to an image's grid is 16 such
+    taps a patch).  float32; returns (N, P, size[2])."""
+    helper = LayerHelper("table_interp", name=name)
+    table = helper.create_parameter(
+        param_attr, shape=[int(size[0]) * int(size[1]), int(size[2])],
+        dtype="float32")
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(type="table_interp",
+                     inputs={"Table": [table], "Taps": [taps],
+                             "Weights": [weights]},
+                     outputs={"Out": [out]})
+    out.desc.shape = tuple(taps.shape[:2]) + (int(size[2]),)
+    return out
+
+
+def image_merge(x, rows, tokens, placeholder, name=None):
+    """The embedded token stream `x` (N, T, D) with the rows of a second
+    tower, `rows` (N, R, D), in place of the embedding rows at the
+    positions where `tokens` (N, T) is `placeholder`: the r-th
+    placeholder takes the r-th row (ops/vision.py `image_merge`)."""
+    helper = LayerHelper("image_merge", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(type="image_merge",
+                     inputs={"X": [x], "Rows": [rows], "Tokens": [tokens]},
+                     outputs={"Out": [out]},
+                     attrs={"placeholder": int(placeholder)})
+    out.desc.shape = tuple(x.shape)
     return out
 
 

@@ -36,9 +36,14 @@ values are not built yet raises):
   under a soft-max or a sigmoid router with a selection bias, whole or
   as one expert-parallel rank's share; shared experts beside the
   routed ones (`n_shared_experts`);
-- heads: the vocabulary head and token cross-entropy, tied or not; a
+- heads: the vocabulary head and token cross-entropy, tied or not, over
+  every position or under per-position `loss_weights`; a
   multi-token-prediction module (`num_nextn_predict_layers`) that
-  re-enters the embedding table and the head.
+  re-enters the embedding table and the head;
+- inputs: `tokens`, and optionally a SECOND input, the output rows of
+  another network built before it (`image_rows`, e.g.
+  `models/vision_tower.py`'s), which replace the embedding rows at the
+  positions of `media_placeholder_token_id`.
 
 The first two configurations it was written for are OLMoE-1B-7B
 (Muennighoff et al. 2024, arXiv:2409.02060) and a hybrid
@@ -208,6 +213,31 @@ scans beside it do); no `rope_theta` is then read, the same kernels
 take the 64 lanes as they are made, and the name scope stays
 `latent_attention`.  `num_expert_group` / `topk_group` above 1 (a
 top-k over groups of experts) raise.
+
+`q_lora_rank` None WITHOUT `mla_use_nope` is the rotated direct-q
+latent attention: the one direct projection's rotary block and the
+one rotary key head turn under `rope_theta` (over pairs with
+`rope_interleave`), the rest as above.
+
+**A second input** (`image_rows` (N, R, hidden_size), a Variable made by
+another builder in the same Program, and `media_placeholder_token_id`):
+
+    x_0[n, t] = image_rows[n, r]   where tokens[n, t] is the r-th
+                                   placeholder id of sequence n
+              = Emb(tokens[n, t])  elsewhere
+
+ONE op (`layers.image_merge`, name scope `image_merge`); its gradient
+reaches the rows' maker (the matching scatter, read as a gather), and
+the table takes none at the placeholders.  The decoder then runs on the
+merged stream with its ordinary positions 0 .. T-1.  `loss_weights`
+(True: feed float32 `loss_weights` (N, max_length)) under
+`objective="next_token"`:
+
+    loss = sum_i w_i CE(logits_i, labels_i) / sum_i w_i
+
+(a collator gives weight 0 where the TARGET is a placeholder).  Beside
+the block-diffusion objective, a looped stack or a prediction module
+both raise; `image_rows` without its id, or the id without rows, raises.
 
 `n_shared_experts` s > 0: beside the routed experts every token also
 goes through ONE dense SwiGLU of width s x `moe_intermediate_size`
@@ -454,10 +484,13 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             embedding_multiplier=None, residual_multiplier=None,
             attention_multiplier=None, logits_scaling=None,
             linear_attn_config=None, mla_use_nope=False,
-            num_expert_group=1, topk_group=1):
+            num_expert_group=1, topk_group=1, image_rows=None,
+            media_placeholder_token_id=None, loss_weights=False):
     """Append the forward pass to the default program.  Feeds `tokens`
     and `labels`, both (N, max_length) int64 (and `next_labels`, the
-    labels' own successors, with a prediction module); under
+    labels' own successors, with a prediction module; float32
+    `loss_weights` (N, max_length) where `loss_weights` asks for the
+    weighted next-token loss); under
     `objective="block_diffusion"` `tokens` (N, 2 * max_length), `labels`
     and float32 `loss_weights` (N, max_length), `logits` over the noised
     half, `ce` the weighted loss and `masked_share` (1,), the share of
@@ -520,6 +553,28 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
     if positions not in ("rope", "none"):
         raise NotImplementedError(f"positions {positions!r} is not built")
     diffusion = objective == "block_diffusion"
+    if (image_rows is None) != (media_placeholder_token_id is None):
+        raise ValueError("a second input needs both image_rows (its rows) "
+                         "and media_placeholder_token_id (where they go)")
+    if image_rows is not None or loss_weights:
+        # built straight, under the plain next-token objective
+        unbuilt = [what for what, asked in [
+            ("objective='block_diffusion'", diffusion),
+            ("a looped stack", total_ut_steps > 1 or exit_gate),
+            ("a prediction module", num_nextn_predict_layers),
+            ("an embedding_multiplier", embedding_multiplier is not None
+             and image_rows is not None)] if asked]
+        if unbuilt:
+            raise NotImplementedError(
+                "a second input (image_rows) or loss_weights under "
+                "objective='next_token' beside " + ", ".join(unbuilt)
+                + " is not built")
+        if image_rows is not None and (
+                len(image_rows.shape) != 3
+                or int(image_rows.shape[-1]) != hidden_size):
+            raise ValueError(
+                f"image_rows {tuple(image_rows.shape)} are not rows of the "
+                f"stream's {hidden_size} lanes")
     if not diffusion and block_length is not None:
         raise ValueError("block_length without objective='block_diffusion'")
     if diffusion:
@@ -1198,6 +1253,11 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
             else embedding_init_range)))
     if embedding_multiplier is not None:
         x = layers.scale(x, scale=float(embedding_multiplier))
+    if image_rows is not None:
+        # the second input's rows stand where the placeholder ids are
+        with name_scope("image_merge"):
+            x = layers.image_merge(x, image_rows, tokens,
+                                   media_placeholder_token_id)
     feeds = ["tokens", "labels"]
     if loops:
         return dict(looped(x), aux=None, z=None, counts=[], experts=[],
@@ -1219,6 +1279,18 @@ def decoder(hidden_size, num_hidden_layers, num_attention_heads,
                 logits, layers.unsqueeze(labels, axes=[2])),
             layers.unsqueeze(weights, axes=[2])))
         masked_share = layers.mean(layers.sign(weights))
+    elif loss_weights:
+        # the mean over the weighted positions (a second input's
+        # placeholders are no targets: their weight is 0)
+        weights = layers.data(name="loss_weights", shape=[max_length],
+                              dtype="float32")
+        feeds.append("loss_weights")
+        ce = layers.elementwise_div(
+            layers.reduce_sum(layers.elementwise_mul(
+                layers.softmax_with_cross_entropy(
+                    logits, layers.unsqueeze(labels, axes=[2])),
+                layers.unsqueeze(weights, axes=[2]))),
+            layers.reduce_sum(weights))
     else:
         ce = token_ce(logits, labels)
     mtp_logits = mtp_ce = None

@@ -96,6 +96,8 @@ _FIELDS = Heard._fields[1:] + (
     "differential_attention_calls", "shared_memory_reads", "shared_kv_reads",
     "ropes_kernel", "ropes_xla",
     "share_rows_kernel", "share_rows_xla",
+    "flash_segment_calls", "flash_segment_xla_calls",
+    "flash_segment_tiles_total", "image_patches", "image_rows",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -254,6 +256,20 @@ class RuntimeStats:
         # 0 where the XLA lowering of the chunks ran
         self.channel_delta_calls = 0
         self.channel_delta_chunks = 0
+        # segment-confined attention (`ops/pallas/flash_segment.py`): the
+        # calls traced on the kernels and on the XLA lowering, and the
+        # tiles of the whole rectangle the kernel calls stand for
+        # (every head's); how many of them a step VISITS is data, a
+        # device counter of the layer (`observe/routing.py
+        # segment_tile_visits`).  A second tower's step inputs as a step
+        # build traces them (`ops/vision.py`): patches on the packed row
+        # axis (`table_interp`), and the rows that enter the decoder's
+        # stream (`image_merge`)
+        self.flash_segment_calls = 0
+        self.flash_segment_xla_calls = 0
+        self.flash_segment_tiles_total = 0
+        self.image_patches = 0
+        self.image_rows = 0
         self.channel_delta_operand_calls = 0
         self.channel_delta_operand_chunks = 0
         # calls traced of the kernels that take a head's lane statistic
@@ -470,6 +486,19 @@ class RuntimeStats:
         with self._lock:
             self.channel_delta_operand_calls += 1
             self.channel_delta_operand_chunks += chunks
+
+    def record_flash_segment(self, kernel: bool, tiles_total: int = 0):
+        with self._lock:
+            if kernel:
+                self.flash_segment_calls += 1
+                self.flash_segment_tiles_total += tiles_total
+            else:
+                self.flash_segment_xla_calls += 1
+
+    def record_image_feed(self, patches: int, rows: int):
+        with self._lock:
+            self.image_patches += patches
+            self.image_rows += rows
 
     def record_head_norm(self, rows: int):
         with self._lock:

@@ -30,6 +30,8 @@ import numpy as np
 TOKEN_COUNT_SUFFIX = ".token_count"
 OFF_SHARE_COUNT_SUFFIX = ".off_share_count"
 ROW_BUFFER_COUNT_SUFFIX = ".row_buffer_count"
+TILES_VISITED_SUFFIX = ".tiles_visited"
+TILES_TOTAL_SUFFIX = ".tiles_total"
 
 
 def _counters(suffix, scope, reset) -> Dict[str, np.ndarray]:
@@ -94,3 +96,21 @@ def load_max_over_mean(counts) -> Optional[float]:
     any token was routed."""
     ratios = [c.max() / c.mean() for c in counts.values() if c.sum() > 0]
     return float(np.mean(ratios)) if ratios else None
+
+
+def segment_tile_visits(scope=None):
+    """(tiles visited, tiles of the whole rectangle) of every
+    `layers.segment_attention` whose state lives in `scope` (any live
+    Scope with none), summed over the layers and over every forward
+    pass of the process: each layer keeps `<name>.tiles_visited` and
+    `<name>.tiles_total`, int32 (1,) state its op adds to INSIDE the
+    step, what its list of visits held (data: where the segments'
+    bounds fell) and what a kernel that skipped nothing would have
+    run.  None where no layer keeps them or none ran on the kernels
+    (the XLA lowering adds 0 to both)."""
+    visited = _counters(TILES_VISITED_SUFFIX, scope, False)
+    total = _counters(TILES_TOTAL_SUFFIX, scope, False)
+    if not total or not sum(int(c.sum()) for c in total.values()):
+        return None
+    return (sum(int(c.sum()) for c in visited.values()),
+            sum(int(c.sum()) for c in total.values()))

@@ -22,6 +22,7 @@ from . import rnn  # noqa: F401
 from . import sequence  # noqa: F401
 from . import sparse  # noqa: F401
 from . import structured  # noqa: F401
+from . import vision  # noqa: F401
 from . import vision_extra  # noqa: F401
 
 

@@ -165,6 +165,19 @@ def _cos_sin(t, rotary, attrs, offset):
     return cos, sin
 
 
+def _cos_sin_two_axes(positions, head_dim, theta):
+    """cos, sin (N, T, 1, head_dim/2) float32 of (row, column)
+    positions: pair 2m turns by column * f_m, pair 2m + 1 by row * f_m,
+    f_m = theta^(-4m/head_dim) (host constants, as `rope_angles`')."""
+    freq = (1.0 / theta ** (np.arange(0, head_dim, 4, dtype=np.float32)
+                            / head_dim)).astype(np.float32)
+    pos = positions.astype(jnp.float32)
+    row, column = pos[..., 0:1], pos[..., 1:2]
+    ang = jnp.stack([column * freq, row * freq], axis=-1)
+    ang = ang.reshape(positions.shape[:2] + (1, head_dim // 2))
+    return jnp.cos(ang), jnp.sin(ang)
+
+
 def _turn(xf, cos, sin, n_head, pairs):
     """float32 heads of R lanes, (N, T, H*R) or (N, T, H, R), turned by
     cos, sin: a head as (2, R/2) halves, or as (R/2, 2) pairs; as
@@ -221,6 +234,14 @@ def rope(ctx, ins, attrs):
     between and without the round trip through HBM.  float32 from X to
     Out, Out in X's dtype.
 
+    Positions (N, T, 2) int32, a (row, column) a row: rotary positions
+    over TWO axes (a patch of an image's grid), interleaved by
+    frequency: a head's D lanes are D/2 consecutive PAIRS, and with
+    f_m = theta^(-4m/D), m = 0 .. D/4 - 1, pair 2m turns by
+    column x f_m and pair 2m + 1 by row x f_m.  Always over pairs and
+    the whole head; beside an Offset, a `period`, `rotary_dim` or
+    scaled frequencies it raises.
+
     Two lowerings of the one algorithm, chosen by the shape and attrs
     alone (`ops/pallas/rope.py rope_kernel_takes`: a Scale, rotate-half,
     D a multiple of 128, R even, T whole row tiles that fit VMEM): the
@@ -255,6 +276,19 @@ def rope(ctx, ins, attrs):
         scale = scale.astype(jnp.float32)
         if attrs.get("zero_centered"):
             scale = 1.0 + scale
+    positions = opt_in(ins, "Positions")
+    if positions is not None:
+        if offset is not None or rotary != d or d % 4 or scale is not None \
+                or any(attrs.get(key) for key in
+                       ("period", "inv_freq", "attention_factor")):
+            raise NotImplementedError(
+                "rope: positions over two axes turn a whole head of a "
+                "multiple of 4 lanes, with no Offset, period, Scale or "
+                "scaled frequencies")
+        cos, sin = _cos_sin_two_axes(positions, d,
+                                     float(attrs.get("theta", 10000.0)))
+        runtime_stats.record_rope(False)
+        return out(Out=_rope(x, None, cos, sin, n_head, pairs=True))
     cos, sin = _cos_sin(t, rotary, attrs, offset)
     kernel = rope_kernel_takes(t, n_head, d, rotary, pairs, scale is not None,
                                x.dtype.itemsize)

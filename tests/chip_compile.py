@@ -49,7 +49,7 @@ in tier-1 as a stand-in that stops at the LOWERED text (`_lower_args`,
 `_sites`: the Program's parameter count, the counters around the trace,
 the kernels' names; 5-9 s a whole step, under 2 s a kernel) and shares
 the slow test's build, so the two cannot drift.  Run a slow test with
-tier-1's environment, `pytest -m slow <file> -k <word>`; all fourteen
+tier-1's environment, `pytest -m slow <file> -k <word>`; all sixteen
 take 7 min on four workers.  The `slow` compiles (seconds in the
 parent's six-worker run, PR 67) and what guards each between such runs:
 
@@ -66,6 +66,7 @@ parent's six-worker run, PR 67) and what guards each between such runs:
     _cells.py  ..linear_attention_cells_step_keeps_its_inverses 100 qwen3next-16k
     _cells.py  ..state_space_cells_step..length_read            49  phi4flash-8k
     _cells.py  lfm2_share_layer_and_short_conv_..               79  lfm2-8k (a layer)
+    _cells.py  ..vision_language_cells_step..under_the_plan    170  kimivl-8k
   kernels at a shape no cell runs: NOTHING on the chip guards these
   (float32 at "highest" is a parity script's, which no driver's run
   reaches); a PR that touches the kernel's file runs them
@@ -74,6 +75,7 @@ parent's six-worker run, PR 67) and what guards each between such runs:
     _kernels.py  latent_attention_kernels_at_the_published_shapes[f32]        115
     _kernels.py  band_kernels_at_a_head_count_a_layer_type[48h_full-f32]       46
     _kernels.py  grouped_flash_at_head_dim_256_..[f32]                         51
+    _kernels.py  segment_attention_kernels_in_float32_at_the_cells_shape       17
   kernels a cell runs in bfloat16 (the cell's chip run guards the compile)
     _kernels.py  latent_attention_kernels_at_the_published_shapes[bf16]        70  joyai-8k
     _kernels.py  flash_gqa_under_a_scale_that_is_a_power_of_two                22  granite4h-8k, lfm2-8k

@@ -51,7 +51,8 @@ def close(got, want, what, tol=5e-6, scale=1.0):
 
 
 def build_and_run(arguments, feed, use_amp=False, seed=7,
-                  fetch=("loss", "logits"), after_startup=None, params=None):
+                  fetch=("loss", "logits"), after_startup=None, params=None,
+                  builder=None):
     """One forward and backward of `decoder.build_model(**arguments)`
     (no optimizer) on `feed`: what was fetched, by name, and the
     parameters in creation order.  `fetch` names the model's scalars and
@@ -68,8 +69,9 @@ def build_and_run(arguments, feed, use_amp=False, seed=7,
     before = runtime_stats.snapshot()
     with fluid.program_guard(main, startup), fluid.scope_guard(scope), \
             fluid.unique_name.guard():
-        m = decoder.build_model(max_length=feed["labels"].shape[1],
-                                with_optimizer=False, **arguments)
+        m = (builder or decoder.build_model)(
+            max_length=feed["labels"].shape[1], with_optimizer=False,
+            **arguments)
         if use_amp:
             main._amp_lists = fluid.amp.AutoMixedPrecisionLists()
         grads = [g for _, g in fluid.append_backward(m["loss"])]
@@ -116,13 +118,14 @@ def draw_expert_biases(main, scope, seed):
 
 
 def system(arguments, feed, use_amp=False, seed=7, fetch=("loss", "logits"),
-           after_startup=None, params=None):
+           after_startup=None, params=None, builder=None):
     """`build_and_run`, remembered."""
     key = ("system", _canonical(arguments), _digest(feed), bool(use_amp),
-           seed, tuple(fetch), after_startup, _digest(params))
+           seed, tuple(fetch), after_startup, _digest(params), builder)
     if key not in _REMEMBERED:
         _REMEMBERED[key] = _frozen(build_and_run(
-            arguments, feed, use_amp, seed, fetch, after_startup, params))
+            arguments, feed, use_amp, seed, fetch, after_startup, params,
+            builder))
     return _REMEMBERED[key]
 
 
@@ -131,7 +134,7 @@ def system(arguments, feed, use_amp=False, seed=7, fetch=("loss", "logits"),
 # **how)` is the module's own, `to_list(grads, cfg)` brings
 # the gradient tree back into the system's order.
 Family = collections.namedtuple("Family", "to_tree loss_and_grads to_list")
-_FEEDS = ("tokens", "labels", "next_labels", "loss_weights")
+_FEEDS = ("tokens", "labels", "next_labels", "loss_weights", "pixel_values")
 
 
 def reference(family, cfg, feed, params, drawn=None, **how):

@@ -486,6 +486,12 @@ STEP_TEXT = {
     # keeps its parent's text: none builds the op
     "kimilinear-8k":
     "30d48358de96dc053390f5736db72865f32816efab60ff0f0fa217bf459cf01c",
+    # new in PR 73 (the vision tower's `flash_segment_*` kernels, the
+    # position table's taps, the merge, rotary lanes beside a direct
+    # query projection); every other cell keeps its parent's text: none
+    # builds a second input, and an argument at its default appends no op
+    "kimivl-8k":
+    "3e59c88dff08504bd4e138ed5f7d919aadd272358432e7f69f6a915af65fccf1",
 }
 
 

@@ -107,6 +107,61 @@ def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
         assert f"bf16[{n},{t},64]" in text      # the rotary key's gradient
 
 
+def _segment_attention_lowered(one_chip, dtype):
+    """`kimivl-8k`'s tower attention, forward and backward through the
+    `segment_attention` op, traced and lowered for the described chip,
+    nothing compiled: 24576 packed rows, 16 heads of 72 lanes (laid out
+    at 128 around the kernels), segments of at most 4096 rows."""
+    from paddle_tpu.core.registry import OpContext, get_op_impl
+
+    n, p, heads, d = 1, 24576, 16, 72
+    op = get_op_impl("segment_attention")
+
+    def loss(q, k, v, seg):
+        o = op(OpContext(None, 0), {"Q": [q], "K": [k], "V": [v],
+                                    "SegmentIds": [seg]},
+               {"n_head": heads, "max_segment_rows": 4096})["Out"][0]
+        return jnp.sum(o.astype(F32))
+
+    x = jax.ShapeDtypeStruct((n, p, heads * d), dtype, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((n, p), I32, sharding=one_chip)
+    lowered, took = _lower_args(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                                x, x, x, seg, precision=_precision(dtype))
+    assert (took["flash_segment_calls"], took["flash_segment_xla_calls"],
+            took["flash_segment_tiles_total"]) == (1, 0, 16 * 24 * 24)
+    assert _sites(lowered) == {"flash_segment_fwd": 1,
+                               "flash_segment_bwd": 1}
+    return lowered
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+def test_segment_attention_kernels_at_the_cells_shape_by_their_trace(
+        one_chip, dtype):
+    """Tier-1's stand-in for the float32 case below, which is `slow`;
+    the bfloat16 case compiles here (12-31 s): the list of visits is a
+    scalar-prefetched table made from a DEVICE array, which only
+    Mosaic's own compile proves."""
+    lowered = _segment_attention_lowered(one_chip, dtype)
+    if dtype == BF16:
+        text = lowered.compile().as_text()
+        assert _kernels(text) == 2
+        # heads of 72 lanes meet the kernels at 128: q, k, v, o and
+        # their gradients are (1, 24576, 2048) there
+        assert "bf16[1,24576,2048]" in text
+
+
+# slow, 26 s.  float32 at "highest" is `benchmarks/kimi_vl_parity.py`'s,
+# which no driver's run reaches: nothing on the chip guards it between
+# runs of `-m slow -k segment_attention`
+@pytest.mark.slow
+def test_segment_attention_kernels_in_float32_at_the_cells_shape(one_chip):
+    """The parity script's float32 run at tiles of 1024 x 1024: float32
+    operand tiles, four float32 score blocks and 12.6 MB of dq under
+    the limit the backward kernel asks for."""
+    text = _segment_attention_lowered(one_chip, F32).compile().as_text()
+    assert _kernels(text) == 2
+
+
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
 def test_gated_delta_scan_kernels_at_the_published_shapes(one_chip, dtype):
     """What `qwen3next-16k`'s step hands the chip's compiler that no

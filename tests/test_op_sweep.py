@@ -1035,6 +1035,23 @@ S["diff_combine"] = dict(
     out="Out", shape=(1, 3, 16),
     grad=["X", "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2", "Scale"],
     tol=2e-2)
+S["segment_attention"] = dict(
+    ins={"Q": f32((1, 6, 8), 331), "K": f32((1, 6, 8), 332),
+         "V": f32((1, 6, 8), 333),
+         "SegmentIds": np.array([[0, 0, 0, 1, 1, -1]], np.int32)},
+    attrs={"n_head": 2}, out="Out", shape=(1, 6, 8),
+    grad=["Q", "K", "V"], tol=2e-2)
+S["table_interp"] = dict(
+    ins={"Table": f32((9, 4), 334),
+         "Taps": np.array([[[0, 1, 3, 4], [4, 5, 7, 8], [8, 8, 8, 8]]],
+                          np.int32),
+         "Weights": f32((1, 3, 4), 335)},
+    attrs={}, out="Out", shape=(1, 3, 4), grad=["Table"], tol=2e-2)
+S["image_merge"] = dict(
+    ins={"X": f32((1, 5, 4), 336), "Rows": f32((1, 2, 4), 337),
+         "Tokens": np.array([[3, 0, 0, 2, 1]], np.int32)},
+    attrs={"placeholder": 0}, out="Out", shape=(1, 5, 4),
+    grad=["X", "Rows"], tol=2e-2)
 S["moe_dropless"] = dict(
     ins={"X": f32((6, 8), 273), "GateW": f32((8, 4), 274),
          "W1": f32((4, 8, 6), 275), "W3": f32((4, 8, 6), 276),

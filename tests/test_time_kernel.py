@@ -29,7 +29,8 @@ CASES = [(family, cell) for family, entry in FAMILIES.items()
 
 def test_the_registry_holds_every_family_the_scripts_timed():
     assert sorted(FAMILIES) == [
-        "channel_delta", "flash_block_diffusion", "flash_gqa", "flash_window",
+        "channel_delta", "flash_block_diffusion", "flash_gqa", "flash_segment",
+        "flash_window",
         "gated_delta", "head_norm", "rope", "selective_scan", "share_rows",
         "short_conv", "ssd_scan"]
     for entry in FAMILIES.values():

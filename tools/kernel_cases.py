@@ -206,6 +206,49 @@ def _flash_block_diffusion(mod, shape, aux, block=None):
         shape["block_length"], block, block, bare=True)
 
 
+def _segment_operands(shape, seed):
+    """q, k, v (1, P, heads x head_dim) and the rows' segment ids: the
+    cell's multiset of images on the packed axis, in an order drawn."""
+    t, lanes = shape["rows"], shape["heads"] * shape["head_dim"]
+    sizes = np.random.default_rng(seed).permutation(shape["images"])
+    seg = np.repeat(np.arange(len(sizes)), sizes).astype(np.int32)[None]
+    return (tuple(_normal(key, (1, t, lanes)) for key in _keys(seed, 3)),
+            {"segment_ids": jnp.asarray(seg)})
+
+
+def _flash_segment(mod, shape, aux, block=None, sub_block=None,
+                   max_segment_rows=None):
+    # `max_segment_rows` swept: the list's static length, M / block + 4
+    # visits a tile (7168 at tiles of 512: the 18 a tile that a
+    # rectangle of every tile's longest possible run would take);
+    # `sub_block` at the tile's own size: a product a whole tile
+    return lambda q, k, v: mod.flash_segment(
+        q, k, v, aux["segment_ids"], shape["heads"],
+        max_segment_rows=max_segment_rows or shape["max_segment_rows"],
+        block=block, sub_block=sub_block)[0]
+
+
+def _segment_xla(mod, shape, aux):
+    return lambda q, k, v: mod.segment_attention_xla(
+        q, k, v, aux["segment_ids"], shape["heads"])
+
+
+def _segment_rectangle(mod, shape, aux):
+    """The whole rectangle: `flash_attention` over all P x P pairs with a
+    key bias (a mask's worth of it), heads laid out at 128 lanes."""
+    from paddle_tpu.ops.pallas.flash_attention import pallas_flash_attention
+
+    def fn(q, k, v):
+        bias = jnp.zeros((1, 1, 1, q.shape[1]), F32)
+        o = pallas_flash_attention(
+            *(mod._to_lane_tiles(x, shape["heads"]) for x in (q, k, v)),
+            bias=bias, scale=shape["head_dim"] ** -0.5, causal=False,
+            layout="nthd", n_head=shape["heads"])
+        return o.reshape(q.shape[:2] + (shape["heads"], -1))[
+            ..., :shape["head_dim"]].reshape(q.shape)
+    return fn
+
+
 # -- the row-wise kernels ---------------------------------------------------
 
 def _head_norm_operands(shape, seed):
@@ -410,6 +453,15 @@ FAMILIES = {
         {"kernel": _flash_gqa,
          "xla": _attention_xla(lambda s: dict(causal=True))},
         sweepable=("block", "FUSED_ACCUMULATOR_BUDGET")),
+    "flash_segment": Family(
+        "flash_segment",
+        {"kimivl-8k": dict(
+            rows=24576, heads=16, head_dim=72, max_segment_rows=4096,
+            images=(4096,) * 2 + (2304,) * 4 + (1024,) * 6 + (256,) * 4)},
+        _segment_operands, ("q", "k", "v"),
+        {"kernel": _flash_segment, "xla": _segment_xla},
+        composites={"whole_rectangle": _segment_rectangle},
+        sweepable=("block", "sub_block", "max_segment_rows")),
     "flash_window": Family(
         "flash_attention",
         {"laguna-16k": dict(rows=16384, heads=64, kv_heads=8, head_dim=128,
