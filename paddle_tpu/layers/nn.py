@@ -613,7 +613,8 @@ def latent_attention(q_nope, q_rope, k_nope, k_rope, v, n_head, name=None):
 
 
 def segment_attention(q, k, v, segment_ids, n_head, max_segment_rows=None,
-                      scale=None, name=None):
+                      scale=None, name=None, positions=None,
+                      rope_theta=10000.0):
     """Bidirectional attention over a PACKED row axis (ops/vision.py
     `segment_attention`): head-major `q`, `k`, `v` (N, P, n_head * d)
     and `segment_ids` (N, P) int32; row i reads the rows of its own
@@ -625,13 +626,19 @@ def segment_attention(q, k, v, segment_ids, n_head, max_segment_rows=None,
     the kernels' list of visits; a row axis whose longer segments pass
     that list comes out NaN, not wrong.  Whole tiles of 512 rows run the flash kernels of
     ops/pallas/flash_segment.py (heads of 72 lanes too): the op
-    chooses, from the shape.  The layer keeps `<name>.tiles_visited`
+    chooses, from the shape.  `positions` (N, P, 2) int32, a (row,
+    column) a row: `q` and `k` are handed over UNTURNED and the op turns
+    them as `rope(positions=)` would, under `rope_theta` (where the
+    kernels run, inside the one pass that lays a head's lanes out for
+    them: ops/pallas/head_lanes.py).  The layer keeps `<name>.tiles_visited`
     and `<name>.tiles_total`, int32 (1,) persistable state the op adds
     to on the device (observe/routing.py `segment_tile_visits`)."""
     helper = LayerHelper("segment_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     base = helper.name
     ins = {"Q": [q], "K": [k], "V": [v], "SegmentIds": [segment_ids]}
+    if positions is not None:
+        ins["Positions"] = [positions]
     outs = {"Out": [out]}
     for slot, suffix in (("TilesVisited", ".tiles_visited"),
                          ("TilesTotal", ".tiles_total")):
@@ -644,6 +651,8 @@ def segment_attention(q, k, v, segment_ids, n_head, max_segment_rows=None,
         attrs["max_segment_rows"] = int(max_segment_rows)
     if scale is not None:
         attrs["scale"] = float(scale)
+    if positions is not None:
+        attrs["theta"] = float(rope_theta)
     helper.append_op(type="segment_attention", inputs=ins, outputs=outs,
                      attrs=attrs)
     out.desc.shape = tuple(q.shape)

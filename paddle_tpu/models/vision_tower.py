@@ -33,8 +33,8 @@ with them.  The three projections of one input are three matrices (a
 checkpoint's fused W_qkv splits by columns), as the decoder's are.
 
 Name scopes: `vision_tower` (everything up to the last norm),
-`vision_attention` inside it (q, k, v, the rotary turn, the attention
-op and the out projection), `vision_projector` (LN_p .. rows).  Under
+`vision_attention` inside it (q, k, v, the attention op, which turns q
+and k, and the out projection), `vision_projector` (LN_p .. rows).  Under
 `recompute="layer"` every tower layer is a recompute segment that
 keeps its input and the attention kernels' output and logsumexp, and
 the last norm with the projector is one more.  A value the builder
@@ -120,13 +120,11 @@ def vision_tower(hidden_size, num_hidden_layers, num_attention_heads,
 
     def attention(h):
         with name_scope("vision_attention"):
-            q = layers.rope(proj(h, d, "vit_qkv"), heads, rope_theta,
-                            positions=yx)
-            k = layers.rope(proj(h, d, "vit_qkv"), heads, rope_theta,
-                            positions=yx)
+            # q and k turn inside the attention op, over (row, column)
             ctx = layers.segment_attention(
-                q, k, proj(h, d, "vit_qkv"), segments, heads,
-                max_segment_rows=in_token_limit)
+                *(proj(h, d, "vit_qkv") for _ in "qkv"), segments, heads,
+                max_segment_rows=in_token_limit, positions=yx,
+                rope_theta=rope_theta)
             return proj(ctx, d, "vit_out")
 
     with name_scope("vision_tower"):

@@ -97,7 +97,8 @@ _FIELDS = Heard._fields[1:] + (
     "ropes_kernel", "ropes_xla",
     "share_rows_kernel", "share_rows_xla",
     "flash_segment_calls", "flash_segment_xla_calls",
-    "flash_segment_tiles_total", "image_patches", "image_rows",
+    "flash_segment_tiles_total", "flash_segment_lane_kernel_calls",
+    "flash_segment_lane_xla_calls", "image_patches", "image_rows",
     "loop_trips")
 # the host phases of one step, in the order a step enters them
 STEP_PHASES = ("prepare", "place", "call", "writeback")
@@ -261,13 +262,18 @@ class RuntimeStats:
         # tiles of the whole rectangle the kernel calls stand for
         # (every head's); how many of them a step VISITS is data, a
         # device counter of the layer (`observe/routing.py
-        # segment_tile_visits`).  A second tower's step inputs as a step
+        # segment_tile_visits`); and of the kernel calls, those whose
+        # 128-lane layout (and rotary turn) `ops/pallas/head_lanes.py`
+        # made in one pass an array and those XLA's pad and slice made.
+        # A second tower's step inputs as a step
         # build traces them (`ops/vision.py`): patches on the packed row
         # axis (`table_interp`), and the rows that enter the decoder's
         # stream (`image_merge`)
         self.flash_segment_calls = 0
         self.flash_segment_xla_calls = 0
         self.flash_segment_tiles_total = 0
+        self.flash_segment_lane_kernel_calls = 0
+        self.flash_segment_lane_xla_calls = 0
         self.image_patches = 0
         self.image_rows = 0
         self.channel_delta_operand_calls = 0
@@ -487,13 +493,17 @@ class RuntimeStats:
             self.channel_delta_operand_calls += 1
             self.channel_delta_operand_chunks += chunks
 
-    def record_flash_segment(self, kernel: bool, tiles_total: int = 0):
+    def record_flash_segment(self, kernel: bool, tiles_total: int = 0,
+                             lane_kernels=None):
         with self._lock:
             if kernel:
                 self.flash_segment_calls += 1
                 self.flash_segment_tiles_total += tiles_total
             else:
                 self.flash_segment_xla_calls += 1
+            if lane_kernels is not None:
+                self.flash_segment_lane_kernel_calls += int(lane_kernels)
+                self.flash_segment_lane_xla_calls += int(not lane_kernels)
 
     def record_image_feed(self, patches: int, rows: int):
         with self._lock:

@@ -12,10 +12,15 @@ Operands head-major (N, P, H * d) as a projection emits them,
 `segment_ids` (N, P) int32.  Heads of a lane count Mosaic cannot
 address as a tile of the head-major array (72: a slab of 72 lanes at
 offset 72 h is no tile) are laid out at the next multiple of 128 with
-zero lanes behind them, here, in XLA, around the kernels
-(`_to_lane_tiles`): a contraction of 72 fills a 128-deep MXU pass
-either way, the zero lanes add nothing to a score and carry no
-gradient, and the price is the q / k / v / o bytes at 128 / 72.
+zero lanes behind them, around the kernels: a contraction of 72 fills a
+128-deep MXU pass either way, the zero lanes add nothing to a score and
+carry no gradient, and the price is the q / k / v / o bytes at
+128 / 72.  That layout is ONE Pallas pass an array each way
+(`head_lanes.py`, wherever `lane_kernels_take` the shape; PR 74), and
+with `rotary` q and k turn over (row, column) pairs inside it; as XLA
+compositions (`_to_lane_tiles`, `_from_lane_tiles`, the caller's
+`_rope`: the lowering of every other shape and the kernels' reference)
+the same passes cost `kimivl-8k` four times the kernels' time.
 
 **A list of visits made from a device array.**  Because segments are
 contiguous, the keys a query TILE may read are one run of key tiles:
@@ -90,8 +95,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import interpret, keep_residuals, pallas_call, register_kernel_cost
-from . import DECLARED_AT_CALL
+from . import head_lanes, interpret, keep_residuals, pallas_call
+from . import DECLARED_AT_CALL, register_kernel_cost
 from .flash_attention import (_VMEM_LIMIT, FUSED_ACCUMULATOR_BUDGET, NEG_INF,
                               _add_dk_dv, _add_dq, _dot, _init_softmax,
                               _vmem_params)
@@ -113,7 +118,6 @@ from .flash_attention import (_VMEM_LIMIT, FUSED_ACCUMULATOR_BUDGET, NEG_INF,
 # score, not the MXU, bounds a product at 72 (128) lanes a head
 DEFAULT_BLOCK = 512
 LARGE_BLOCK = 1024
-LANES = 128
 SUB_BLOCK = 256
 # the rows of the table of visits, and of the table of sub-blocks
 V_A, V_B, V_FIRST, V_LAST, V_REAL, V_HELD, V_B_FIRST, V_B_LAST = range(8)
@@ -447,42 +451,43 @@ def _backward(q, k, v, do, o, lse8, col, row, table, subs, *, scale, heads,
     )(table, subs, q, k, v, do, o, lse8, col, row)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
-def _flash_segment(q, k, v, col, row, table, subs, cut, scale, heads, block,
-                   sub, length):
-    return _flash_segment_fwd(q, k, v, col, row, table, subs, cut, scale,
-                              heads, block, sub, length)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11, 12, 13))
+def _flash_segment(q, k, v, rotary, col, row, table, subs, cut, scale, heads,
+                   block, sub, length):
+    return _flash_segment_fwd(q, k, v, rotary, col, row, table, subs, cut,
+                              scale, heads, block, sub, length)[0]
 
 
-def _flash_segment_fwd(q, k, v, col, row, table, subs, cut, scale, heads,
-                       block, sub, length):
-    """Operands and result at the heads' OWN lanes: the kernels' 128-lane
-    layout is made around each call, so what a recompute segment keeps
-    (the output and the logsumexp) is kept at 72 lanes a head, not 128.
-    A row axis whose list was `cut` is NaN, with the copy that takes the
-    lanes back."""
+def _flash_segment_fwd(q, k, v, rotary, col, row, table, subs, cut, scale,
+                       heads, block, sub, length):
+    """Operands and result at the heads' OWN lanes, q and k UNTURNED
+    where `rotary` is given: the kernels' 128-lane layout is made around
+    each call (`_to_kernel_layout`), the turn with it, so what a
+    recompute segment keeps (the output and the logsumexp) is kept at 72
+    lanes a head, not 128.  A row axis whose list was `cut` is NaN."""
     o, lse8 = _forward(
-        *(_to_lane_tiles(x, heads) for x in (q, k, v)), col, row, table,
+        *_to_kernel_layout((q, k, v), heads, rotary, 2), col, row, table,
         subs, scale=scale, heads=heads, block=block, sub=sub, length=length,
         interpret=interpret())
-    o = jnp.where(cut[:, None, None], jnp.nan,
-                  _from_lane_tiles(o, heads, q.shape[-1]))
+    o, = _from_kernel_layout((o,), heads, q.shape[-1])
+    o = jnp.where(cut[:, None, None], jnp.nan, o)
     o, lse8 = keep_residuals(o, lse8)
-    return o, (q, k, v, col, row, table, subs, o, lse8)
+    return o, (q, k, v, rotary, col, row, table, subs, o, lse8)
 
 
 def _flash_segment_bwd(scale, heads, block, sub, length, res, do):
-    q, k, v, col, row, table, subs, o, lse8 = res
-    wide = [_to_lane_tiles(x, heads) for x in (q, k, v, do.astype(q.dtype),
-                                               o)]
+    q, k, v, rotary, col, row, table, subs, o, lse8 = res
     grads = _backward(
-        *wide, lse8, col, row, table, subs, scale=scale, heads=heads,
+        *_to_kernel_layout((q, k, v), heads, rotary, 2),
+        *_to_kernel_layout((do.astype(q.dtype), o), heads),
+        lse8, col, row, table, subs, scale=scale, heads=heads,
         block=block, sub=sub, length=length, interpret=interpret())
+    grads = _from_kernel_layout(grads, heads, q.shape[-1], rotary, 2)
     none = [np.zeros(shape, jax.dtypes.float0) for shape in (
         col.shape, row.shape, table.shape, subs.shape,
         q.shape[:1])]                                        # .., cut
-    return (*(_from_lane_tiles(g, heads, x.shape[-1]).astype(x.dtype)
-              for g, x in zip(grads, (q, k, v))), *none)
+    return (*(g.astype(x.dtype) for g, x in zip(grads, (q, k, v))),
+            jax.tree.map(jnp.zeros_like, rotary), *none)
 
 
 _flash_segment.defvjp(_flash_segment_fwd, _flash_segment_bwd)
@@ -490,16 +495,43 @@ _flash_segment.defvjp(_flash_segment_fwd, _flash_segment_bwd)
 
 # -- the op's two lowerings ---------------------------------------------------
 
-def _lane_tiles(d):
-    return -(-d // LANES) * LANES
+def _to_kernel_layout(xs, heads, rotary=None, turned=0):
+    """Arrays (N, P, H * d) at the lanes the kernels read, the first
+    `turned` of them turned by `rotary`: `head_lanes`' kernel where it
+    takes the shape, the XLA composition elsewhere (which is handed
+    nothing to turn: `lane_kernels_take`)."""
+    _, p, hd = xs[0].shape
+    if head_lanes.head_lanes_take(p, heads, hd // heads):
+        return head_lanes.to_tiles(xs, heads, rotary, turned)
+    assert rotary is None
+    return tuple(_to_lane_tiles(x, heads) for x in xs)
+
+
+def _from_kernel_layout(xs, heads, lanes, rotary=None, turned=0):
+    """The mirror, back to `lanes` = H * d; what turns, turns back."""
+    if head_lanes.head_lanes_take(xs[0].shape[1], heads, lanes // heads):
+        return head_lanes.from_tiles(xs, heads, lanes // heads, rotary,
+                                     turned)
+    assert rotary is None
+    return tuple(_from_lane_tiles(x, heads, lanes) for x in xs)
+
+
+def lane_kernels_take(rows, heads, d):
+    """Whether a call's layout, and with it the rotary turn of its q and
+    k, is `head_lanes`' kernels' (by the shape alone); elsewhere the
+    layout is XLA's and the caller turns."""
+    return (segment_attention_takes(rows, heads, d)
+            and head_lanes.head_lanes_take(rows, heads, d))
 
 
 def _to_lane_tiles(x, heads):
     """(N, P, H * d) -> (N, P, H * D), D the next multiple of 128, each
-    head's lanes first and zeros behind them; as it is where D == d."""
+    head's lanes first and zeros behind them; as it is where D == d.
+    The layout as XLA makes it: where `head_lanes` does not take the
+    shape, and its kernels' reference."""
     n, p, hd = x.shape
     d = hd // heads
-    wide = _lane_tiles(d)
+    wide = head_lanes.lane_tiles(d)
     if wide == d:
         return x
     return jnp.pad(x.reshape(n, p, heads, d),
@@ -527,7 +559,8 @@ def segment_attention_takes(rows, heads, d):
     row axis of one head, float32, at the lanes the head is laid out
     at) within the single backward kernel's budget."""
     return (rows % DEFAULT_BLOCK == 0 and rows >= DEFAULT_BLOCK
-            and 4 * rows * _lane_tiles(d) <= FUSED_ACCUMULATOR_BUDGET)
+            and 4 * rows * head_lanes.lane_tiles(d)
+            <= FUSED_ACCUMULATOR_BUDGET)
 
 
 def tiles_total(n, rows, heads, block=None):
@@ -547,12 +580,16 @@ def segment_runs(segment_ids):
 
 
 def flash_segment(q, k, v, segment_ids, n_head, scale=None,
-                  max_segment_rows=None, block=None, sub_block=None):
+                  max_segment_rows=None, block=None, sub_block=None,
+                  rotary=None):
     """(Out (N, P, H * d), tiles visited (1,) int32: the real visits of
     the forward pass's list x heads, a device value): the kernels.
     `block` and `sub_block` (tests, and the timing's sweeps): the square
     tile a visit fetches and the sub-block its products are made in,
-    `default_block(P)` and `SUB_BLOCK` within it."""
+    `default_block(P)` and `SUB_BLOCK` within it.  `rotary` (cos, sin)
+    (N, P, 1, d / 2) float32, where `lane_kernels_take` the shape: q and
+    k arrive UNTURNED and turn over pairs (`ops/decoder.py rope` with
+    `Positions`) inside the pass that lays them out."""
     n, p, hd = q.shape
     d = hd // n_head
     block = block or default_block(p)
@@ -567,8 +604,13 @@ def flash_segment(q, k, v, segment_ids, n_head, scale=None,
     # a padding row matches nobody: not another padding row either
     col = seg[:, :, None]
     row = jnp.where(seg < 0, -2, seg)[:, None, :]
-    o = _flash_segment(q, k, v, col, row, table, sub_table(seg, sub), cut,
-                       float(scale), n_head, block, sub, length)
+    if rotary is not None:
+        if not head_lanes.head_lanes_take(p, n_head, d):
+            raise ValueError(f"flash_segment: no kernel turns {n_head} "
+                             f"heads of {d} lanes over {p} rows")
+        rotary = head_lanes.tables(*rotary, d)
+    o = _flash_segment(q, k, v, rotary, col, row, table, sub_table(seg, sub),
+                       cut, float(scale), n_head, block, sub, length)
     return o, (jnp.sum(visits) * n_head).astype(jnp.int32).reshape(1)
 
 

@@ -3,7 +3,8 @@ confined to the segments of a packed row axis (`segment_attention`), a
 learnt position table read through bicubic taps (`table_interp`) and
 the merge of a tower's output rows into the decoder's embedded stream
 (`image_merge`).  `rope` over two axes is the `rope` op's own
-(`ops/decoder.py`, its `Positions` input).
+(`ops/decoder.py`, its `Positions` input); `segment_attention` takes the
+same input and turns its own Q and K by it.
 """
 
 from __future__ import annotations
@@ -29,16 +30,29 @@ def segment_attention(ctx, ins, attrs):
     segments need more visits than the list holds, that row axis's Out
     is NaN.  Scores and soft-max float32; Out in Q's dtype.
 
+    Positions (N, P, 2) int32, a (row, column) a row, with `theta`: Q
+    and K arrive UNTURNED and the op turns them, the `rope` op's
+    two-axis turn to the letter (pair 2m by column x f_m, pair 2m + 1 by
+    row x f_m, f_m = theta^(-4m/d); float32 from Q to one rounding).
+    Without it, nothing turns.
+
     Two lowerings, chosen by the shape alone
     (`flash_segment.segment_attention_takes`): the kernels of
     `ops/pallas/flash_segment.py` (heads of 72 lanes laid out at 128
-    around them), or `segment_attention_xla`.  TilesVisited /
+    around them), or `segment_attention_xla`.  Where the kernels run,
+    their layout and the turn inside it are ONE Pallas pass an array
+    each way wherever `flash_segment.lane_kernels_take` the shape
+    (`ops/pallas/head_lanes.py`), XLA's pad, slice and `_rope`
+    elsewhere.  TilesVisited /
     TilesTotal (1,) int32: state the op adds the forward pass's visited
     tiles (data) and the whole rectangle's to (0 on the XLA lowering);
     `runtime_stats.flash_segment_calls` / `_xla_calls` /
-    `_tiles_total` count the calls traced."""
+    `_tiles_total` count the calls traced, `flash_segment_lane_kernel_calls`
+    / `_lane_xla_calls` those of them whose layout the kernels made and
+    XLA made (neither: heads of whole tiles that do not turn)."""
     from ..core.shape_inference import inferring_shapes
     from ..observe.monitoring import runtime_stats
+    from . import decoder
     from .pallas import flash_segment as fs
 
     q, k, v = first(ins, "Q"), first(ins, "K"), first(ins, "V")
@@ -52,12 +66,34 @@ def segment_attention(ctx, ins, attrs):
             f"not {heads} heads over SegmentIds {seg.shape}")
     scale = attrs.get("scale")
     limit = attrs.get("max_segment_rows")
-    kernel = fs.segment_attention_takes(p, heads, hd // heads)
+    d = hd // heads
+    kernel = fs.segment_attention_takes(p, heads, d)
+    lanes = fs.lane_kernels_take(p, heads, d)
     total = fs.tiles_total(n, p, heads) if kernel else 0
+    rotary = None
+    positions = opt_in(ins, "Positions")
+    if positions is not None:
+        if d % 4 or positions.shape != (n, p, 2):
+            raise ValueError(
+                f"segment_attention: Positions {positions.shape} over "
+                f"heads of {d} lanes are not a (row, column) a row of "
+                f"{(n, p)} over heads of a multiple of 4 lanes")
+        # looked up at the call: whoever replaces it rounds both lowerings
+        rotary = decoder._cos_sin_two_axes(
+            positions, d, float(attrs.get("theta", 10000.0)))
+        if not lanes:
+            q, k = (decoder._rope(x, None, *rotary, heads, pairs=True)
+                    for x in (q, k))
+            rotary = None
+    # who lays the lanes out for the kernels: nobody where heads are
+    # whole tiles and nothing turns
+    laid_out = kernel and (d % 128 != 0 or rotary is not None)
     if not inferring_shapes():
-        runtime_stats.record_flash_segment(kernel, total)
+        runtime_stats.record_flash_segment(
+            kernel, total, lane_kernels=lanes if laid_out else None)
     if kernel:
-        o, visited = fs.flash_segment(q, k, v, seg, heads, scale, limit)
+        o, visited = fs.flash_segment(q, k, v, seg, heads, scale, limit,
+                                      rotary=rotary)
     else:
         o = fs.segment_attention_xla(q, k, v, seg, heads, scale)
         visited = jnp.zeros((1,), jnp.int32)

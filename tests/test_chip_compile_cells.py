@@ -732,6 +732,10 @@ def _kimivl_traced(parameters, took):
     # layer): 16 heads x 24 x 24 tiles of 1024 x 1024 a call
     assert (took["flash_segment_calls"], took["flash_segment_xla_calls"],
             took["flash_segment_tiles_total"]) == (8, 0, 8 * 16 * 24 * 24)
+    # every call's 128-lane layout, and q's and k's turn inside it, is
+    # `ops/pallas/head_lanes.py`'s kernels' (PR 74), none XLA's
+    assert (took["flash_segment_lane_kernel_calls"],
+            took["flash_segment_lane_xla_calls"]) == (8, 0)
     assert (took["image_patches"], took["image_rows"]) == (24576, 6144)
     assert (took["flash_mla_backward_fused"],
             took["flash_mla_backward_split"]) == (5, 0)
@@ -741,9 +745,10 @@ def _kimivl_traced(parameters, took):
     assert took["recompute_kept_bytes"] == 8 * (
         24576 * 1152 * 2 + 16 * 8 * 24576 * 4) + 5 * (
         8192 * 2048 * 2 + 16 * 8 * 8192 * 4)
-    # rotary turns: q and k of a tower layer over two axes, the rotary
-    # lanes of q and the one key head of a decoder layer, all bare turns
-    assert (took["ropes_kernel"], took["ropes_xla"]) == (0, 26)
+    # rotary turns as ops: the rotary lanes of q and the one key head of
+    # a decoder layer, bare turns (the tower's sixteen over two axes are
+    # the attention op's own since PR 74)
+    assert (took["ropes_kernel"], took["ropes_xla"]) == (0, 10)
     assert (took["grouped_matmuls_kernel"],
             took["grouped_matmuls_xla"]) == (27, 0)
     assert (took["share_rows_kernel"], took["share_rows_xla"]) == (9, 0)
@@ -779,7 +784,8 @@ CELL_TRACES = {
         "short_conv_fwd", "short_conv_bwd", "ragged_dot",
         "rows_to_tokens"}),
     "kimivl-8k": (_kimivl_traced, {
-        "flash_segment_fwd", "flash_segment_bwd", "flash_mla_fwd",
+        "flash_segment_fwd", "flash_segment_bwd", "head_lanes_to_tiles",
+        "head_lanes_from_tiles", "flash_mla_fwd",
         "flash_mla_dkv", "ragged_dot", "rows_to_tokens"}),
 }
 
@@ -1047,16 +1053,28 @@ def test_the_vision_language_cells_step_holds_tower_and_decoder_under_the_plan(
     `flash_segment_fwd` and one `flash_segment_bwd` (its segment keeps
     the forward's output, at the heads' own 72 lanes, and logsumexp), a
     decoder layer one `flash_mla_fwd` and one `flash_mla_dkv` at 16
-    heads; no fall-back anywhere.  The plan ISSUE 73's rule read, set
+    heads; no fall-back anywhere.  Around a tower layer's two kernels,
+    since PR 74, five passes of `ops/pallas/head_lanes.py` and nothing
+    of XLA's: q, k, v to the kernels' 128 lanes a head with q's and k's
+    rotary turn (forward, and once more for the backward kernel; the
+    segment's recomputed forward needs none), o back; do and o there,
+    three gradients back through the turn's transpose; no view of a
+    head's 72 lanes or of its 36 pairs is left in the step.  The plan
+    ISSUE 73's rule read, set
     before the step existed ("under 15.0 GB, else give memory back
     inside the step, else 6 tower layers"): arguments (aliased to the
     outputs) 8.78 GB + temporaries 5.83 GB = 14.61 GB (14.97 with the
     kept outputs at the kernels' 128 lanes: PERF.md, PR 73)."""
-    parameters, _, plan, kernels, took = _cell_step("kimivl-8k", one_chip)
+    parameters, compiled, plan, kernels, took = _cell_step("kimivl-8k",
+                                                           one_chip)
     holds_its_trace("kimivl-8k", parameters, took, kernels)
     assert plan["arguments"] == pytest.approx(8.78, abs=0.01)
     assert 12.5 < plan["total"] <= 15.0, plan
     assert (kernels["flash_segment_fwd"], kernels["flash_segment_bwd"]) \
         == (8, 8)
+    assert (kernels["head_lanes_to_tiles"],
+            kernels["head_lanes_from_tiles"]) == (8 * 3, 8 * 2)
+    text = compiled.as_text()
+    assert "[1,24576,16,72]" not in text and "[1,24576,16,36,2]" not in text
     assert (kernels["flash_mla_fwd"], kernels["flash_mla_dkv"],
             kernels["flash_mla_dq"]) == (5, 5, 0)

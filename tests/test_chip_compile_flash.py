@@ -489,9 +489,14 @@ STEP_TEXT = {
     # new in PR 73 (the vision tower's `flash_segment_*` kernels, the
     # position table's taps, the merge, rotary lanes beside a direct
     # query projection); every other cell keeps its parent's text: none
-    # builds a second input, and an argument at its default appends no op
+    # builds a second input, and an argument at its default appends no op.
+    # Re-pinned, PR 74: the tower's q and k turn inside `segment_attention`
+    # (its `Positions`; no `rope` op over two axes is built) and the
+    # kernels' 128-lane layout is `ops/pallas/head_lanes.py`'s two kernels,
+    # here through the interpreter (parent: 3e59c88d..); the fourteen
+    # other cells build neither and keep their text
     "kimivl-8k":
-    "3e59c88dff08504bd4e138ed5f7d919aadd272358432e7f69f6a915af65fccf1",
+    "15050e8b3ce5caac085adf9c3fe0b92d4804eafc0fcb9ae520392bdaa7f3d4c0",
 }
 
 

@@ -109,28 +109,37 @@ def test_latent_attention_kernels_at_the_published_shapes(one_chip, dtype):
 
 def _segment_attention_lowered(one_chip, dtype):
     """`kimivl-8k`'s tower attention, forward and backward through the
-    `segment_attention` op, traced and lowered for the described chip,
-    nothing compiled: 24576 packed rows, 16 heads of 72 lanes (laid out
-    at 128 around the kernels), segments of at most 4096 rows."""
+    `segment_attention` op with its `Positions`, traced and lowered for
+    the described chip, nothing compiled: 24576 packed rows, 16 heads of
+    72 lanes (laid out at 128 for the kernels and turned by
+    `ops/pallas/head_lanes.py`'s two), segments of at most 4096 rows."""
     from paddle_tpu.core.registry import OpContext, get_op_impl
 
     n, p, heads, d = 1, 24576, 16, 72
     op = get_op_impl("segment_attention")
 
-    def loss(q, k, v, seg):
+    def loss(q, k, v, seg, yx):
         o = op(OpContext(None, 0), {"Q": [q], "K": [k], "V": [v],
-                                    "SegmentIds": [seg]},
-               {"n_head": heads, "max_segment_rows": 4096})["Out"][0]
+                                    "SegmentIds": [seg], "Positions": [yx]},
+               {"n_head": heads, "max_segment_rows": 4096,
+                "theta": 10000.0})["Out"][0]
         return jnp.sum(o.astype(F32))
 
     x = jax.ShapeDtypeStruct((n, p, heads * d), dtype, sharding=one_chip)
     seg = jax.ShapeDtypeStruct((n, p), I32, sharding=one_chip)
+    yx = jax.ShapeDtypeStruct((n, p, 2), I32, sharding=one_chip)
     lowered, took = _lower_args(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-                                x, x, x, seg, precision=_precision(dtype))
+                                x, x, x, seg, yx, precision=_precision(dtype))
     assert (took["flash_segment_calls"], took["flash_segment_xla_calls"],
             took["flash_segment_tiles_total"]) == (1, 0, 16 * 24 * 24)
+    assert (took["flash_segment_lane_kernel_calls"],
+            took["flash_segment_lane_xla_calls"]) == (1, 0)
+    # call sites: q, k, v to the kernels' lanes (one jitted pass, which
+    # the backward rule calls again) and do, o; o and three gradients back
     assert _sites(lowered) == {"flash_segment_fwd": 1,
-                               "flash_segment_bwd": 1}
+                               "flash_segment_bwd": 1,
+                               "head_lanes_to_tiles": 2,
+                               "head_lanes_from_tiles": 2}
     return lowered
 
 
@@ -144,10 +153,14 @@ def test_segment_attention_kernels_at_the_cells_shape_by_their_trace(
     lowered = _segment_attention_lowered(one_chip, dtype)
     if dtype == BF16:
         text = lowered.compile().as_text()
-        assert _kernels(text) == 2
+        # the two attention kernels and the five passes around them
+        assert _kernels(text) == 7
         # heads of 72 lanes meet the kernels at 128: q, k, v, o and
-        # their gradients are (1, 24576, 2048) there
+        # their gradients are (1, 24576, 2048) there, and no view of a
+        # head's 72 lanes or of its 36 pairs is made on the way
         assert "bf16[1,24576,2048]" in text
+        assert "[1,24576,16,72]" not in text
+        assert "[1,24576,16,36,2]" not in text
 
 
 # slow, 26 s.  float32 at "highest" is `benchmarks/kimi_vl_parity.py`'s,
@@ -159,7 +172,7 @@ def test_segment_attention_kernels_in_float32_at_the_cells_shape(one_chip):
     operand tiles, four float32 score blocks and 12.6 MB of dq under
     the limit the backward kernel asks for."""
     text = _segment_attention_lowered(one_chip, F32).compile().as_text()
-    assert _kernels(text) == 2
+    assert _kernels(text) == 7
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])

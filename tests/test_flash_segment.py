@@ -15,6 +15,14 @@ run, the dq tile an output holds, the static bound; a pair of
 sub-blocks runs exactly where it shares a segment, unmasked exactly
 where no boundary crosses it, and the pairs a call runs are the same
 in every order of the cell's sixteen images.
+
+The layout the kernels read and the rotary turn inside it
+(`ops/pallas/head_lanes.py`; `segment_attention`'s `Positions`): the
+two lane kernels alone against the pad, the slice and `_rope` (there
+and back, the zeros behind a head, the turn's transpose), and the
+attention that turns its own q and k against `_rope` followed by the
+XLA lowering and by the kernels on XLA's layout, every gradient, in
+float32 and bfloat16, heads of 72, 32 and 128 lanes.
 """
 
 import os
@@ -33,7 +41,9 @@ import reference_kimi_vl as ref  # noqa: E402
 
 from paddle_tpu.core.registry import OpContext, get_op_impl  # noqa: E402
 from paddle_tpu.observe.monitoring import runtime_stats  # noqa: E402
+from paddle_tpu.ops import decoder  # noqa: E402
 from paddle_tpu.ops.pallas import flash_segment as fs  # noqa: E402
+from paddle_tpu.ops.pallas import head_lanes  # noqa: E402
 
 BLOCK = 128
 
@@ -352,6 +362,231 @@ def test_the_shape_alone_chooses_the_lowering():
         op(OpContext(jax.random.PRNGKey(0), 0),
            {"Q": [x], "K": [x], "V": [x], "SegmentIds": [seg]},
            {"n_head": 5})
+
+
+# -- the kernels' layout and the turn inside it ------------------------------
+
+def rotary_of(rng, n, rows, d):
+    """cos, sin of drawn (row, column) positions, and the positions."""
+    yx = jnp.asarray(rng.integers(0, 48, (n, rows, 2)), jnp.int32)
+    return decoder._cos_sin_two_axes(yx, d, 10000.0), yx
+
+
+def turned(x, rotary, heads, sign=1.0):
+    cos, sin = rotary
+    return decoder._rope(x, None, cos, sign * sin, heads, pairs=True)
+
+
+# heads, lanes a head: a head in two of the row's tiles at sixteen
+# offsets, four heads a tile, heads that are whole tiles
+LANE_SHAPES = [(16, 72), (4, 32), (2, 128), (1, 256)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads, d", LANE_SHAPES)
+def test_the_lane_kernels_are_the_pad_the_slice_and_the_turn(heads, d, dtype):
+    """`head_lanes.to_tiles` / `from_tiles` (interpret mode) against
+    `_to_lane_tiles`, `_from_lane_tiles` and `_rope` over pairs: there
+    and back is the identity to the bit, an array that does not turn is
+    moved to the bit, the lanes behind a head are exactly 0 turned or
+    not, and `from_tiles` turns by the negated angle (the turn's
+    transpose)."""
+    rng = np.random.default_rng(heads)
+    n, rows = 2, 128
+    x, y, z = (jnp.asarray(rng.normal(size=(n, rows, heads * d)), dtype)
+               for _ in range(3))
+    rotary, _ = rotary_of(rng, n, rows, d)
+    tables = head_lanes.tables(*rotary, d)
+    tol = dict(atol=2e-6, rtol=2e-6) if dtype == jnp.float32 else dict(
+        atol=0.02, rtol=0.02)
+    wide = head_lanes.lane_tiles(d)
+    assert head_lanes.head_lanes_take(rows, heads, d)
+
+    plain = head_lanes.to_tiles((x, y), heads)
+    for got, a in zip(plain, (x, y)):
+        np.testing.assert_array_equal(got, fs._to_lane_tiles(a, heads))
+    for got, a in zip(head_lanes.from_tiles(plain, heads, d), (x, y)):
+        np.testing.assert_array_equal(got, a)
+
+    moved = head_lanes.to_tiles((x, y, z), heads, tables, 2)
+    for got, a in zip(moved, (x, y)):
+        np.testing.assert_allclose(
+            got.astype(jnp.float32),
+            fs._to_lane_tiles(turned(a, rotary, heads), heads)
+            .astype(jnp.float32), **tol)
+    np.testing.assert_array_equal(moved[2], fs._to_lane_tiles(z, heads))
+    for got in moved:
+        behind = np.asarray(got.astype(jnp.float32)).reshape(
+            n, rows, heads, wide)[..., d:]
+        assert not behind.any()
+
+    back = head_lanes.from_tiles(plain + (moved[2],), heads, d, tables, 2)
+    for got, a in zip(back, (x, y)):
+        np.testing.assert_allclose(
+            got.astype(jnp.float32),
+            turned(a, rotary, heads, -1.0).astype(jnp.float32), **tol)
+    np.testing.assert_array_equal(back[2], z)
+    if dtype == jnp.float32:
+        # <to_tiles(x), w> = <x, from_tiles(w)>: the pair is a transpose
+        w = jnp.asarray(rng.normal(size=moved[0].shape), dtype)
+        there = float(jnp.vdot(moved[0], w))
+        here = float(jnp.vdot(x, head_lanes.from_tiles(
+            (w,), heads, d, tables, 1)[0]))
+        np.testing.assert_allclose(there, here, rtol=1e-4)
+
+
+def test_the_shape_alone_chooses_who_lays_the_lanes_out():
+    takes = head_lanes.head_lanes_take
+    assert takes(24576, 16, 72) and takes(512, 4, 32) and takes(512, 2, 128)
+    # a row of 2 x 72 lanes is no whole tile; a head of 192 lies in two
+    # of its own; 100 rows are no row tile
+    assert not takes(512, 2, 72) and not takes(512, 2, 192)
+    assert not takes(100, 16, 72)
+    assert fs.lane_kernels_take(512, 16, 72)
+    assert not fs.lane_kernels_take(256, 16, 72)    # XLA's attention
+    # whole tiles that do not turn enter no kernel
+    x = jnp.ones((1, 128, 256))
+    assert head_lanes.to_tiles((x,), 2)[0] is x
+    with pytest.raises(ValueError, match="no kernel turns"):
+        fs.flash_segment(*[jnp.ones((1, 512, 144))] * 3,
+                         jnp.zeros((1, 512), jnp.int32), 2,
+                         rotary=rotary_of(np.random.default_rng(0), 1, 512,
+                                          72)[0])
+
+
+# name: (heads, lanes, dtype, segment lengths, rows, tile, sub-block)
+TURNS = {
+    "72_lanes_f32": (16, 72, jnp.float32, [100, 190, 60], 512, 128, None),
+    "72_lanes_bf16": (16, 72, jnp.bfloat16, [100, 190, 60], 512, 128, None),
+    "32_lanes_four_sub_blocks": (4, 32, jnp.float32, [20, 7, 130, 60, 150],
+                                 512, 256, 64),
+    "128_lanes_f32": (2, 128, jnp.float32, [128, 256, 100], 512, 128, None),
+    "128_lanes_bf16": (2, 128, jnp.bfloat16, [128, 256, 100], 512, 128,
+                       None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TURNS))
+def test_the_attention_that_turns_is_the_turn_then_the_attention(
+        case, monkeypatch):
+    """`flash_segment(rotary=)` on UNTURNED q and k (the lane kernels
+    lay the heads out and turn, interpret mode) against `_rope` followed
+    by `segment_attention_xla`, and against `_rope` followed by the
+    kernels on XLA's pad and slice (the path before PR 74): the result
+    and the gradients of the unturned q, k and v, a padding tail's
+    output and gradients exactly 0."""
+    heads, d, dtype, lengths, rows, block, sub = TURNS[case]
+    seg = jnp.asarray(segments(lengths, rows))[None]
+    rng = np.random.default_rng(len(case))
+    q, k, v, ct = (jnp.asarray(rng.normal(size=(1, rows, heads * d)), dtype)
+                   for _ in range(4))
+    rotary, _ = rotary_of(rng, 1, rows, d)
+
+    def by_the_kernels(q, k, v):
+        return fs.flash_segment(q, k, v, seg, heads, block=block,
+                                sub_block=sub, max_segment_rows=256,
+                                rotary=rotary)[0]
+
+    def turn_then(attend):
+        return lambda q, k, v: attend(turned(q, rotary, heads),
+                                      turned(k, rotary, heads), v)
+
+    with jax.default_matmul_precision("highest"):
+        got, back = jax.vjp(by_the_kernels, q, k, v)
+        grads = back(ct)
+        want, back_xla = jax.vjp(turn_then(
+            lambda *x: fs.segment_attention_xla(*x, seg, heads, block=128)),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        grads_xla = back_xla(ct.astype(jnp.float32))
+        monkeypatch.setattr(head_lanes, "head_lanes_take",
+                            lambda *shape: False)
+        before, back_before = jax.vjp(turn_then(
+            lambda *x: fs.flash_segment(*x, seg, heads, block=block,
+                                        sub_block=sub,
+                                        max_segment_rows=256)[0]), q, k, v)
+        grads_before = back_before(ct)
+    f32 = dtype == jnp.float32
+    tol = dict(atol=5e-6, rtol=5e-6) if f32 else dict(atol=0.06, rtol=0.06)
+    np.testing.assert_allclose(got.astype(jnp.float32), want, **tol)
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               before.astype(jnp.float32), **tol)
+    for name, g, w, b in zip("qkv", grads, grads_xla, grads_before):
+        scale = float(jnp.abs(w).max())
+        for other in (w, b.astype(jnp.float32)):
+            np.testing.assert_allclose(
+                g.astype(jnp.float32) / scale, other / scale,
+                atol=5e-6 if f32 else 0.03, err_msg="d" + name)
+    pad = np.asarray(seg[0]) < 0
+    assert pad.any()
+    assert not np.asarray(got.astype(jnp.float32))[0, pad].any()
+    assert not any(np.asarray(g.astype(jnp.float32))[0, pad].any()
+                   for g in grads)
+
+
+@pytest.mark.parametrize("rows, heads, d, counted", [
+    (512, 16, 72, (1, 0, 1, 0)),    # the kernels, the lane kernels
+    (512, 2, 72, (1, 0, 0, 1)),     # the kernels on XLA's pad and `_rope`
+    (512, 2, 128, (1, 0, 1, 0)),    # whole tiles: the kernel only turns
+    (64, 2, 72, (0, 1, 0, 0))])     # the XLA lowering after `_rope`
+def test_the_op_with_positions_turns_its_own_q_and_k(rows, heads, d, counted):
+    """The `segment_attention` op with `Positions` on unturned Q and K
+    against the same op without it on the `rope` op's output (the
+    Program before PR 74), on every path the shape may choose; where
+    XLA turns, to the bit.  The counters say who laid the lanes out."""
+    op, rope = get_op_impl("segment_attention"), get_op_impl("rope")
+    rng = np.random.default_rng(rows + heads)
+    lengths = [rows // 4, rows // 8, rows // 2]
+    seg = jnp.asarray(segments(lengths, rows))[None]
+    q, k, v = (jnp.asarray(rng.normal(size=(1, rows, heads * d)),
+                           jnp.float32) for _ in range(3))
+    _, yx = rotary_of(rng, 1, rows, d)
+    ctx = OpContext(jax.random.PRNGKey(0), 0)
+
+    def attend(q, k, **positions):
+        return op(ctx, {"Q": [q], "K": [k], "V": [v], "SegmentIds": [seg],
+                        **positions}, {"n_head": heads, "theta": 100.0,
+                                       "max_segment_rows": rows})["Out"][0]
+
+    def turn(x):
+        return rope(ctx, {"X": [x], "Positions": [yx]},
+                    {"n_head": heads, "theta": 100.0,
+                     "interleave": True})["Out"][0]
+
+    with jax.default_matmul_precision("highest"):
+        before = runtime_stats.snapshot()
+        got = attend(q, k, Positions=[yx])
+        took = runtime_stats.delta(before)
+        want = attend(turn(q), turn(k))
+    assert tuple(took["flash_segment" + name] for name in (
+        "_calls", "_xla_calls", "_lane_kernel_calls",
+        "_lane_xla_calls")) == counted
+    if counted[2]:
+        np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+    else:
+        np.testing.assert_array_equal(got, want)
+    with pytest.raises(ValueError, match="a \\(row, column\\) a row"):
+        attend(q, k, Positions=[yx[:, :-1]])
+
+
+def test_a_cut_list_is_nan_through_the_lane_kernels_too():
+    """The cut list's NaN with the layout made by the lane kernels (4
+    heads of 32 lanes): every row of the cut row axis, none of the
+    other's."""
+    seg = jnp.asarray(np.stack([segments([1024], 1024),
+                                segments([128, 100, 60, 128, 90], 1024)]))
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 1024, 128)), jnp.float32)
+               for _ in range(3))
+    rotary, _ = rotary_of(rng, 2, 1024, 32)
+    with jax.default_matmul_precision("highest"):
+        got = fs.flash_segment(q, k, v, seg, 4, block=BLOCK,
+                               max_segment_rows=128, rotary=rotary)[0]
+        want = fs.segment_attention_xla(
+            turned(q, rotary, 4), turned(k, rotary, 4), v, seg, 4,
+            block=BLOCK)
+    assert np.isnan(np.asarray(got[0])).all()
+    np.testing.assert_allclose(got[1], want[1], atol=2e-6, rtol=2e-6)
 
 
 # -- rope over two axes -------------------------------------------------------

@@ -163,6 +163,10 @@ def test_program_matches_the_float32_reference(share, recompute):
     # layers is traced once, under a segment too
     assert got["took"]["flash_segment_calls"] == 2
     assert got["took"]["flash_segment_xla_calls"] == 0
+    # 2 heads of 72 are no whole 128-lane tiles a row: the kernels'
+    # layout is XLA's pad here and the op turns q and k through `_rope`
+    assert (got["took"]["flash_segment_lane_kernel_calls"],
+            got["took"]["flash_segment_lane_xla_calls"]) == (0, 2)
     assert (got["took"]["image_patches"], got["took"]["image_rows"]) \
         == (2 * PATCH_ROWS, 2 * PATCH_ROWS // 4)
 
@@ -204,8 +208,13 @@ def test_the_scopes_are_the_documented_ones():
     for op in got["main"].global_block().ops:
         for part in op.desc.attrs.get("__name_scope__", "").split("/"):
             scopes.setdefault(part, set()).add(op.type)
-    assert "segment_attention" in scopes["vision_attention"]
-    assert {"rope", "mul"} <= scopes["vision_attention"]
+    assert {"segment_attention", "mul"} <= scopes["vision_attention"]
+    # the tower's q and k turn inside the attention op, by its Positions
+    assert "rope" not in scopes["vision_attention"]
+    assert all(op.desc.inputs["Positions"] == ["patch_yx"]
+               and op.desc.attrs["theta"] == 10000.0
+               for op in got["main"].global_block().ops
+               if op.type == "segment_attention")
     assert {"table_interp", "layer_norm", "gelu"} <= scopes["vision_tower"]
     assert {"layer_norm", "gelu", "mul", "reshape2"} & \
         scopes["vision_projector"]

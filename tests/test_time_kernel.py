@@ -31,7 +31,7 @@ def test_the_registry_holds_every_family_the_scripts_timed():
     assert sorted(FAMILIES) == [
         "channel_delta", "flash_block_diffusion", "flash_gqa", "flash_segment",
         "flash_window",
-        "gated_delta", "head_norm", "rope", "selective_scan", "share_rows",
+        "gated_delta", "head_lanes", "head_norm", "rope", "selective_scan", "share_rows",
         "short_conv", "ssd_scan"]
     for entry in FAMILIES.values():
         ways = list(entry.ways)
@@ -66,7 +66,9 @@ def test_every_way_traces_at_the_cells_shape(family, cell, workloads):
         grads = jax.eval_shape(backward, y, xs, aux)
         assert [(g.shape, g.dtype) for g in grads] == [
             (x.shape, x.dtype) for x in xs], name
-    assert len({(y.shape, y.dtype) for y in results.values()}) == 1, results
+    # (a result may be several arrays: `head_lanes`' q, k and v)
+    assert len({tuple((y.shape, y.dtype) for y in jax.tree.leaves(r))
+                for r in results.values()}) == 1, results
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))

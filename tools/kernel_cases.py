@@ -21,6 +21,7 @@ builder here; `tests/test_time_kernel.py` fails until it does.
 from __future__ import annotations
 
 import functools
+import inspect
 import os
 import sys
 from typing import Callable, NamedTuple
@@ -207,30 +208,57 @@ def _flash_block_diffusion(mod, shape, aux, block=None):
 
 
 def _segment_operands(shape, seed):
-    """q, k, v (1, P, heads x head_dim) and the rows' segment ids: the
-    cell's multiset of images on the packed axis, in an order drawn."""
+    """q, k, v (1, P, heads x head_dim) as the projections write them
+    (q and k UNTURNED), the rows' segment ids (the cell's multiset of
+    images on the packed axis, in an order drawn) and cos, sin of a
+    (row, column) a row: what the `segment_attention` op has when it
+    turns, lays out and attends."""
+    from paddle_tpu.ops import decoder
+
     t, lanes = shape["rows"], shape["heads"] * shape["head_dim"]
-    sizes = np.random.default_rng(seed).permutation(shape["images"])
+    rng = np.random.default_rng(seed)
+    sizes = rng.permutation(shape["images"])
     seg = np.repeat(np.arange(len(sizes)), sizes).astype(np.int32)[None]
+    yx = jnp.asarray(rng.integers(0, 64, (1, t, 2)), jnp.int32)
     return (tuple(_normal(key, (1, t, lanes)) for key in _keys(seed, 3)),
-            {"segment_ids": jnp.asarray(seg)})
+            {"segment_ids": jnp.asarray(seg),
+             "rotary": decoder._cos_sin_two_axes(yx, shape["head_dim"],
+                                                 10000.0)})
+
+
+def _turned(shape, aux, *xs):
+    """The turn as XLA makes it (`ops/decoder.py _rope` over pairs)."""
+    from paddle_tpu.ops.decoder import _rope
+
+    return tuple(_rope(x, None, *aux["rotary"], shape["heads"], pairs=True)
+                 for x in xs)
 
 
 def _flash_segment(mod, shape, aux, block=None, sub_block=None,
                    max_segment_rows=None):
-    # `max_segment_rows` swept: the list's static length, M / block + 4
-    # visits a tile (7168 at tiles of 512: the 18 a tile that a
-    # rectangle of every tile's longest possible run would take);
-    # `sub_block` at the tile's own size: a product a whole tile
-    return lambda q, k, v: mod.flash_segment(
-        q, k, v, aux["segment_ids"], shape["heads"],
-        max_segment_rows=max_segment_rows or shape["max_segment_rows"],
-        block=block, sub_block=sub_block)[0]
+    """The op's kernel path from unturned q and k: the turn and the
+    128-lane layout with the attention.  A checkout from before PR 74
+    is handed q and k turned by XLA, as its op was.
+    `max_segment_rows` swept: the list's static length, M / block + 4
+    visits a tile (7168 at tiles of 512: the 18 a tile that a
+    rectangle of every tile's longest possible run would take);
+    `sub_block` at the tile's own size: a product a whole tile."""
+    turns = "rotary" in inspect.signature(mod.flash_segment).parameters
+
+    def fn(q, k, v):
+        if not turns:
+            q, k = _turned(shape, aux, q, k)
+        return mod.flash_segment(
+            q, k, v, aux["segment_ids"], shape["heads"],
+            max_segment_rows=max_segment_rows or shape["max_segment_rows"],
+            block=block, sub_block=sub_block,
+            **({"rotary": aux["rotary"]} if turns else {}))[0]
+    return fn
 
 
 def _segment_xla(mod, shape, aux):
     return lambda q, k, v: mod.segment_attention_xla(
-        q, k, v, aux["segment_ids"], shape["heads"])
+        *_turned(shape, aux, q, k), v, aux["segment_ids"], shape["heads"])
 
 
 def _segment_rectangle(mod, shape, aux):
@@ -241,12 +269,41 @@ def _segment_rectangle(mod, shape, aux):
     def fn(q, k, v):
         bias = jnp.zeros((1, 1, 1, q.shape[1]), F32)
         o = pallas_flash_attention(
-            *(mod._to_lane_tiles(x, shape["heads"]) for x in (q, k, v)),
+            *(mod._to_lane_tiles(x, shape["heads"])
+              for x in (*_turned(shape, aux, q, k), v)),
             bias=bias, scale=shape["head_dim"] ** -0.5, causal=False,
             layout="nthd", n_head=shape["heads"])
         return o.reshape(q.shape[:2] + (shape["heads"], -1))[
             ..., :shape["head_dim"]].reshape(q.shape)
     return fn
+
+
+def _head_lanes(mod, shape, aux):
+    """The lane kernels alone, as the attention's forward and backward
+    rules call them: q, k, v to the kernels' layout with q's and k's
+    turn, and three gradients back through the turn's transpose (the
+    backward pass needs nothing of the forward's, so `fwd_bwd` times
+    the way back alone)."""
+    heads, d = shape["heads"], shape["head_dim"]
+    rotary = mod.tables(*aux["rotary"], d)
+
+    @jax.custom_vjp
+    def fn(q, k, v):
+        return mod.to_tiles((q, k, v), heads, rotary, 2)
+
+    fn.defvjp(lambda *xs: (fn(*xs), None),
+              lambda _, cts: mod.from_tiles(cts, heads, d, rotary, 2))
+    return fn
+
+
+def _head_lanes_xla(mod, shape, aux):
+    """The passes they replace: `_rope` x 2 and the pad of a (.., H, d)
+    view x 3, and what XLA differentiates them to."""
+    from paddle_tpu.ops.pallas.flash_segment import _to_lane_tiles
+
+    return lambda q, k, v: tuple(
+        _to_lane_tiles(x, shape["heads"])
+        for x in (*_turned(shape, aux, q, k), v))
 
 
 # -- the row-wise kernels ---------------------------------------------------
@@ -429,6 +486,10 @@ def _ssd_layer_segment(mod, shape, aux):
                           policy=segment_policy())
 
 
+KIMIVL_TOWER = dict(
+    rows=24576, heads=16, head_dim=72, max_segment_rows=4096,
+    images=(4096,) * 2 + (2304,) * 4 + (1024,) * 6 + (256,) * 4)
+
 FAMILIES = {
     "channel_delta": Family(
         "channel_delta", {"kimilinear-8k": dict(rows=8192, heads=32)},
@@ -454,10 +515,7 @@ FAMILIES = {
          "xla": _attention_xla(lambda s: dict(causal=True))},
         sweepable=("block", "FUSED_ACCUMULATOR_BUDGET")),
     "flash_segment": Family(
-        "flash_segment",
-        {"kimivl-8k": dict(
-            rows=24576, heads=16, head_dim=72, max_segment_rows=4096,
-            images=(4096,) * 2 + (2304,) * 4 + (1024,) * 6 + (256,) * 4)},
+        "flash_segment", {"kimivl-8k": KIMIVL_TOWER},
         _segment_operands, ("q", "k", "v"),
         {"kernel": _flash_segment, "xla": _segment_xla},
         composites={"whole_rectangle": _segment_rectangle},
@@ -482,6 +540,11 @@ FAMILIES = {
         {"kernel": _gated_delta(True), "xla": _gated_delta(False)},
         sweepable=("DIAGONAL_BLOCK", "DEFAULT_BLOCK_CHUNKS"),
         parts=_gated_delta_parts),
+    "head_lanes": Family(
+        "head_lanes", {"kimivl-8k": KIMIVL_TOWER},
+        _segment_operands, ("q", "k", "v"),
+        {"kernel": _head_lanes, "xla": _head_lanes_xla},
+        sweepable=("ROW_TILE", "ROW_CHUNK")),
     "head_norm": Family(
         "head_norm",
         {"kimilinear-8k": dict(rows=8192, heads=32, gate="sigmoid"),

@@ -111,10 +111,12 @@ def load_parent(checkout, module):
 
 
 def cotangent(fn, xs, seed):
-    """A fixed cotangent of `fn(*xs)`, drawn from the result's shape."""
-    out = jax.eval_shape(fn, *xs)
+    """A fixed cotangent of `fn(*xs)`, drawn from the result's shape (a
+    result of several arrays: one each)."""
     key = jax.random.PRNGKey(seed + 1)
-    return jax.random.normal(key, out.shape, jnp.float32).astype(out.dtype)
+    return jax.tree.map(
+        lambda out: jax.random.normal(key, out.shape, jnp.float32)
+        .astype(out.dtype), jax.eval_shape(fn, *xs))
 
 
 def timings(fn, xs, ct, repeats):
